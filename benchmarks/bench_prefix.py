@@ -40,11 +40,7 @@ sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
 
-from benchmarks._tpu_probe import wait_for_tpu  # noqa: E402
-
 CPU_MODE = "--cpu" in sys.argv
-if not CPU_MODE:
-    wait_for_tpu()
 
 import jax  # noqa: E402
 

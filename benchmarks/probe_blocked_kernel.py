@@ -1,10 +1,8 @@
 """Standalone compile+execute probe for the blocked decode kernel.
 
-Run DETACHED in its own process with a wall-clock budget enforced by
-the CALLER (scripts/r5_session.sh): if Mosaic hangs (the r4 quant-
-kernel failure mode), the caller skips the blocked A/B grid and leaves
-this process alone — killing a device process wedges the grant (memory:
-tpu-grant-discipline).
+Run in its own process with a wall-clock budget enforced by the caller
+(the chip tool's ``--timeout``): a Mosaic compile that hangs must cost
+one bounded call, not the A/B grid that would follow it.
 
 Compiles the Qwen2.5-1.5B serving decode shape (B=128, H=12, KV=2,
 hd=128, page 32) at each block_slots the session grid would use, and

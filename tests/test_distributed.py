@@ -82,11 +82,10 @@ _WORKER = textwrap.dedent(
     import numpy as np
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from vgate_tpu.parallel._compat import shard_map
 
     mesh = Mesh(np.array(jax.devices()).reshape(4), ("dp",))
     f = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda a: jax.lax.psum(a, "dp"),
             mesh=mesh, in_specs=P("dp"), out_specs=P(),
         )

@@ -397,131 +397,47 @@ def test_paged_decode_window_matches_truncated_context():
         )
 
 
-# ------------------------------------------- carry-threaded KV parity
+# ------------------------------------------- KV write index helpers
 
-def test_kv_carry_parity_all_forwards():
-    """tpu.kv_carry (A/B handle; default OFF — measured 5.2x decode
-    regression on v5e, RESULTS_r4.md) must be numerically
-    identical to the r2 xs/ys threading across decode, prefill and
-    suffix-prefill, for a global-attention family AND the sliding-window
-    /softcap family (the carry paths use mixed scalar/slice/array
-    indexed writes and layer-flattened gathers — this pins them)."""
+def test_kv_write_helpers_match_numpy():
+    """kv_write_tokens / kv_write_pages (ops/kv_quant.py) index the
+    kv-head dim explicitly (the TPU layout fix, PERF.md "Bring-up");
+    pin them against plain numpy assignment for both pool forms: one
+    layer's slice (the pp relay) and the full stacked pool with a layer
+    index (the plain-mesh scan)."""
     import numpy as np
 
-    from vgate_tpu.models.decoder import (
-        decode_forward, init_params, prefill_forward,
-        prefill_suffix_forward,
-    )
-    from vgate_tpu.models.specs import TINY_DENSE, TINY_GEMMA2
+    from vgate_tpu.ops.kv_quant import kv_write_pages, kv_write_tokens
 
-    for spec in (TINY_DENSE, TINY_GEMMA2):
-        ps, pps, B, S = 4, 8, 2, 16
-        params = init_params(spec, jax.random.PRNGKey(3), jnp.float32)
-        P = 1 + B * pps
-        shape = (spec.num_layers, spec.num_kv_heads, P, ps, spec.head_dim)
-        k0 = jnp.zeros(shape, jnp.float32)
-        v0 = jnp.zeros(shape, jnp.float32)
-        pt = jnp.asarray(
-            1 + np.arange(B * pps).reshape(B, pps), jnp.int32
-        )
-        rng = np.random.default_rng(0)
-        toks = jnp.asarray(
-            rng.integers(2, spec.vocab_size, (B, S)), jnp.int32
-        )
-        lens = jnp.asarray([14, 9], jnp.int32)
+    L, KV, P, ps, hd, B, S = 3, 2, 9, 4, 8, 2, 3
+    rng = np.random.default_rng(0)
+    pool = rng.standard_normal((L, KV, P, ps, hd)).astype(np.float32)
+    page_ids = np.asarray([[1, 1, 2], [5, 6, 6]], np.int32)  # [B, S]
+    page_off = np.asarray([[2, 3, 0], [3, 0, 1]], np.int32)
+    tok = rng.standard_normal((B, S, KV, hd)).astype(np.float32)
+    pt = np.asarray([[1, 2], [5, 6]], np.int32)  # [B, n_pages]
+    pages = rng.standard_normal((B, 2, KV, ps, hd)).astype(np.float32)
 
-        def pin(a, b, msg):
-            for x, y, nm in zip(a, b, ("logits", "k", "v")):
-                np.testing.assert_allclose(
-                    np.asarray(x), np.asarray(y), rtol=1e-5, atol=1e-5,
-                    err_msg=f"{spec.name} {msg} {nm}",
-                )
+    for layer in (None, 1):
+        base = pool[0] if layer is None else pool
+        view = (lambda a: a) if layer is None else (lambda a: a[layer])
 
-        pin(
-            prefill_forward(
-                params, spec, toks, lens, k0, v0, pt[:, : S // ps],
-                kv_carry=False,
-            ),
-            prefill_forward(
-                params, spec, toks, lens, k0, v0, pt[:, : S // ps],
-                kv_carry=True,
-            ),
-            "prefill",
+        want = base.copy()
+        for b in range(B):
+            for s_ in range(S):
+                view(want)[:, page_ids[b, s_], page_off[b, s_]] = tok[b, s_]
+        got = kv_write_tokens(
+            jnp.asarray(base), jnp.asarray(page_ids),
+            jnp.asarray(page_off), jnp.asarray(tok), layer=layer,
         )
+        np.testing.assert_array_equal(np.asarray(got), want)
 
-        # resident prefix of 8 tokens, then the suffix pass both ways
-        _, kf, vf = prefill_forward(
-            params, spec, toks[:, :8], jnp.asarray([8, 8], jnp.int32),
-            k0, v0, pt[:, :2],
+        want = base.copy()
+        for b in range(B):
+            for n in range(2):
+                view(want)[:, pt[b, n]] = pages[b, n]
+        got = kv_write_pages(
+            jnp.asarray(base), jnp.asarray(pt), jnp.asarray(pages),
+            layer=layer,
         )
-        args = (
-            params, spec, toks[:, 8:], jnp.asarray([8, 8], jnp.int32),
-            jnp.asarray([6, 4], jnp.int32), kf, vf, pt[:, 2:4],
-            pt[:, :4],
-        )
-        pin(
-            prefill_suffix_forward(*args, kv_carry=False),
-            prefill_suffix_forward(*args, kv_carry=True),
-            "suffix",
-        )
-
-        dargs = (
-            params, spec, jnp.asarray([7, 11], jnp.int32),
-            jnp.asarray([8, 8], jnp.int32), kf, vf, pt,
-        )
-        pin(
-            decode_forward(
-                *dargs, active=jnp.asarray([True, True]), kv_carry=False
-            ),
-            decode_forward(
-                *dargs, active=jnp.asarray([True, True]), kv_carry=True
-            ),
-            "decode",
-        )
-
-
-def test_kv_carry_parity_spec_verify():
-    """Carry vs xs/ys parity for the speculative verify forward (valid
-    candidate rows only — rows past input_lens are unspecified)."""
-    import numpy as np
-
-    from vgate_tpu.models.decoder import (
-        init_params, prefill_forward, spec_verify_forward,
-    )
-    from vgate_tpu.models.specs import TINY_DENSE as spec
-
-    ps, pps, B, S = 4, 8, 2, 4
-    params = init_params(spec, jax.random.PRNGKey(3), jnp.float32)
-    P = 1 + B * pps
-    shape = (spec.num_layers, spec.num_kv_heads, P, ps, spec.head_dim)
-    k0 = jnp.zeros(shape, jnp.float32)
-    v0 = jnp.zeros(shape, jnp.float32)
-    pt = jnp.asarray(1 + np.arange(B * pps).reshape(B, pps), jnp.int32)
-    rng = np.random.default_rng(2)
-    prompts = jnp.asarray(
-        rng.integers(2, spec.vocab_size, (B, 8)), jnp.int32
-    )
-    _, kf, vf = prefill_forward(
-        params, spec, prompts, jnp.asarray([8, 6], jnp.int32), k0, v0,
-        pt[:, :2],
-    )
-    cand = jnp.asarray(
-        rng.integers(2, spec.vocab_size, (B, S)), jnp.int32
-    )
-    args = (
-        params, spec, cand, jnp.asarray([8, 6], jnp.int32),
-        jnp.asarray([4, 2], jnp.int32), kf, vf, pt,
-    )
-    a = spec_verify_forward(
-        *args, active=jnp.asarray([True, True]), kv_carry=False
-    )
-    b = spec_verify_forward(
-        *args, active=jnp.asarray([True, True]), kv_carry=True
-    )
-    in_lens = [4, 2]
-    for bb in range(B):
-        n = in_lens[bb]
-        np.testing.assert_allclose(
-            np.asarray(a[0][bb, :n]), np.asarray(b[0][bb, :n]),
-            rtol=1e-5, atol=1e-5,
-        )
+        np.testing.assert_array_equal(np.asarray(got), want)

@@ -264,13 +264,18 @@ def test_admission_deadline_spares_preempted():
 
 
 def test_auto_num_pages_dtype_and_hbm_aware():
-    """fp32 KV halves the page budget of bf16; hbm_bytes scales it
-    (VERDICT r1 weak-6)."""
+    """fp32 KV halves the page budget of bf16; hbm_bytes scales it; a
+    mesh that splits each page `shards` ways fits that many more; and an
+    accelerator that reports no memory_stats is an error unless
+    tpu.hbm_bytes names its size — never an assumed 16 GiB."""
+    import pytest
+
     from vgate_tpu.models.specs import TINY_DENSE
     from vgate_tpu.runtime.kv_cache import auto_num_pages
 
     class FakeTPU:
         platform = "tpu"
+        device_kind = "fake"
 
         @staticmethod
         def memory_stats():
@@ -280,13 +285,30 @@ def test_auto_num_pages_dtype_and_hbm_aware():
         spec=TINY_DENSE, page_size=16, hbm_utilization=0.5,
         device=FakeTPU(), params_bytes=0, hard_cap=1 << 40,
     )
-    bf16 = auto_num_pages(dtype_bytes=2, **common)
-    fp32 = auto_num_pages(dtype_bytes=4, **common)
+    gib16 = 16 * 1024**3
+    bf16 = auto_num_pages(dtype_bytes=2, hbm_bytes=gib16, **common)
+    fp32 = auto_num_pages(dtype_bytes=4, hbm_bytes=gib16, **common)
     assert fp32 == bf16 // 2
-    double = auto_num_pages(
-        dtype_bytes=2, hbm_bytes=32 * 1024**3, **common
-    )
+    double = auto_num_pages(dtype_bytes=2, hbm_bytes=2 * gib16, **common)
     assert double == bf16 * 2
+    assert (
+        auto_num_pages(dtype_bytes=2, hbm_bytes=gib16, shards=2, **common)
+        == bf16 * 2
+    )
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        auto_num_pages(dtype_bytes=2, **common)
+
+    class ReportingTPU(FakeTPU):
+        @staticmethod
+        def memory_stats():
+            return {"bytes_limit": gib16, "bytes_in_use": gib16 // 4}
+
+    # the device's own limit wins over tpu.hbm_bytes
+    reported = auto_num_pages(
+        dtype_bytes=2, hbm_bytes=2 * gib16,
+        **{**common, "device": ReportingTPU()},
+    )
+    assert reported == bf16 // 2
 
 
 def _pressure_sched(num_pages=32, max_slots=2, page_size=4):

@@ -1,0 +1,263 @@
+"""Ahead-of-time compiles for the v5e, with no chip present.
+
+libtpu ships next to jax in this installation, and
+``jax.experimental.topologies.get_topology_desc("v5e:2x2", "tpu")``
+describes four ``TPU v5 lite`` devices without one being attached.
+Abstract arguments placed on such a device run the real XLA:TPU and
+Mosaic compilers through ``jitted.lower(...).compile()`` — so a kernel
+Mosaic refuses, or a step program whose temporaries outgrow the chip, is
+found here on the CPU instead of on chip time.  What these tests assert
+was confirmed on the chip by ``chip_smoke.py`` (PERF.md "Bring-up").
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from vgate_tpu.models.specs import spec_for_model_id
+
+PAGE = 32
+# (num_heads, kv_heads, head_dim): Qwen2.5-1.5B on one chip, and one
+# tp=4 shard of Qwen2.5-7B (28 heads / 4 KV heads over four chips)
+GEOM_1P5B = (12, 2, 128)
+GEOM_7B_TP4_SHARD = (7, 1, 128)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    except Exception as exc:  # noqa: BLE001 — any failure means "no libtpu"
+        pytest.skip(f"no TPU AOT topology in this installation: {exc!r}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _abstract(sharding):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding
+    )
+
+
+def _pool(A, kv, hd, pages, layers=2, int8=False):
+    """Stacked [L, KV, P, ps, hd] pool, as the plain-mesh forwards pass
+    it (layer-indexed), optionally int8 pages + bf16 scales."""
+    if not int8:
+        return A((layers, kv, pages, PAGE, hd), jnp.bfloat16)
+    from vgate_tpu.ops.kv_quant import QuantPages
+
+    return QuantPages(
+        A((layers, kv, pages, PAGE, hd), jnp.int8),
+        A((layers, kv, pages, PAGE), jnp.bfloat16),
+    )
+
+
+def _compile_decode_kernel(A, geom, int8=False):
+    from vgate_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention_pallas,
+    )
+
+    H, KV, hd = geom
+    B, pages_per_seq = 8, 16
+    pool = _pool(A, KV, hd, 64, int8=int8)
+    return paged_decode_attention_pallas.lower(
+        A((B, H, hd), jnp.bfloat16), pool, pool,
+        A((B, pages_per_seq), jnp.int32), A((B,), jnp.int32),
+        layer=A((), jnp.int32),
+    ).compile()
+
+
+def _compile_multitok_kernel(A, geom, int8=False):
+    from vgate_tpu.ops.pallas.paged_attention import (
+        paged_multitok_attention_pallas,
+    )
+
+    H, KV, hd = geom
+    B, S, pages_per_seq = 2, 128, 16
+    pool = _pool(A, KV, hd, 64, int8=int8)
+    return paged_multitok_attention_pallas.lower(
+        A((B, S, H, hd), jnp.bfloat16), pool, pool,
+        A((B, pages_per_seq), jnp.int32), A((B,), jnp.int32),
+        A((B,), jnp.int32), layer=A((), jnp.int32),
+    ).compile()
+
+
+def _compile_flash_kernel(A, geom):
+    from vgate_tpu.ops.pallas.flash_prefill import (
+        flash_prefill_attention_pallas,
+    )
+
+    H, KV, hd = geom
+    B, S = 2, 256
+    return flash_prefill_attention_pallas.lower(
+        A((B, S, H, hd), jnp.bfloat16), A((B, S, KV, hd), jnp.bfloat16),
+        A((B, S, KV, hd), jnp.bfloat16), A((B,), jnp.int32),
+    ).compile()
+
+
+@pytest.mark.parametrize(
+    "geom", [GEOM_1P5B, GEOM_7B_TP4_SHARD], ids=["1.5B", "7B-tp4-shard"]
+)
+@pytest.mark.parametrize(
+    "compile_kernel",
+    [_compile_decode_kernel, _compile_flash_kernel, _compile_multitok_kernel],
+    ids=["paged_decode", "flash_prefill", "paged_multitok"],
+)
+def test_default_path_kernels_compile_for_v5e(v5e, geom, compile_kernel):
+    compile_kernel(_abstract(v5e), geom)
+
+
+class MosaicRefusal(Exception):
+    """The compile failed inside Mosaic with the message on record."""
+
+
+def _compile_expecting(fragment, compile_kernel, *args, **kwargs):
+    """Only the recorded Mosaic message counts as the expected failure;
+    any other error (an API change, a wrong shape here) stays an error."""
+    try:
+        compile_kernel(*args, **kwargs)
+    except Exception as exc:  # noqa: BLE001 — re-raised below
+        if type(exc).__name__ == "MosaicError" and fragment in str(exc):
+            raise MosaicRefusal(str(exc)) from exc
+        raise
+
+
+@pytest.mark.xfail(
+    strict=True,
+    raises=MosaicRefusal,
+    reason="Mosaic: 'Slice shape along dimension 2 must be aligned to "
+    "tiling (8), but is 1' — the per-page scale-row DMA of int8 KV pages "
+    "(ops/pallas/paged_attention.py _chunk_dma); engine construction "
+    "refuses the combination on a TPU (refuse_unbuildable_kernels)",
+)
+@pytest.mark.parametrize(
+    "compile_kernel",
+    [_compile_decode_kernel, _compile_multitok_kernel],
+    ids=["paged_decode", "paged_multitok"],
+)
+def test_int8_kv_kernels_compile_for_v5e(v5e, compile_kernel):
+    _compile_expecting(
+        "aligned to tiling (8), but is 1",
+        compile_kernel, _abstract(v5e), GEOM_1P5B, int8=True,
+    )
+
+
+@pytest.mark.xfail(
+    strict=True,
+    raises=MosaicRefusal,
+    reason="Mosaic: 'Slice shape along dimension 4 must be aligned to "
+    "tiling (128), but is 64' (decode) / 'unsupported shape cast' "
+    "(multi-token) — a head_dim-64 page is half a lane tile "
+    "(Qwen2.5-0.5B, the draft model); engine construction refuses it on "
+    "a TPU (refuse_unbuildable_kernels)",
+)
+@pytest.mark.parametrize(
+    "compile_kernel,fragment",
+    [
+        (_compile_decode_kernel, "aligned to tiling (128), but is 64"),
+        (_compile_multitok_kernel, "unsupported shape cast"),
+    ],
+    ids=["paged_decode", "paged_multitok"],
+)
+def test_head_dim_64_kernels_compile_for_v5e(v5e, compile_kernel, fragment):
+    _compile_expecting(
+        fragment, compile_kernel, _abstract(v5e), (14, 2, 64)
+    )
+
+
+def test_engine_refuses_what_mosaic_refuses():
+    from vgate_tpu.runtime.engine_core import refuse_unbuildable_kernels
+
+    qwen_1p5b = spec_for_model_id("Qwen/Qwen2.5-1.5B-Instruct")
+    refuse_unbuildable_kernels(qwen_1p5b, kv_quant=False)
+    with pytest.raises(ValueError, match=r"aligned to tiling \(8\)"):
+        refuse_unbuildable_kernels(qwen_1p5b, kv_quant=True)
+    with pytest.raises(ValueError, match=r"aligned to tiling \(128\)"):
+        refuse_unbuildable_kernels(
+            spec_for_model_id("Qwen/Qwen2.5-0.5B-Instruct"), kv_quant=False
+        )
+
+
+# ------------------------------------------------- step-program memory
+
+# A step program may hold weights + ONE KV pool.  Before PR 21 each held
+# two: per-layer xs/ys threading cannot alias the ys stack onto the xs
+# stack, and a sliced kv-head dim in the KV scatter made XLA re-lay the
+# whole pool out (models/decoder.py, ops/kv_quant.py kv_write_tokens).
+# The rule: temporaries stay under a quarter of the pool, and donation
+# aliases the whole pool onto the output.
+TEMP_SHARE_OF_POOL = 0.25
+
+
+def _qwen_1p5b(A):
+    from vgate_tpu.models.decoder import init_params
+
+    spec = spec_for_model_id("Qwen/Qwen2.5-1.5B-Instruct")
+    params = jax.eval_shape(
+        lambda: init_params(spec, jax.random.PRNGKey(0), jnp.bfloat16)
+    )
+    params = jax.tree.map(lambda x: A(x.shape, x.dtype), params)
+    pages = 2049  # 1.75 GiB of K+V: any pool-sized temporary shows
+    pool = A(
+        (spec.num_layers, spec.num_kv_heads, pages, PAGE, spec.head_dim),
+        jnp.bfloat16,
+    )
+    pool_bytes = 2 * (
+        spec.num_layers * spec.num_kv_heads * pages * PAGE
+        * spec.head_dim * 2
+    )
+    return spec, params, pool, pool_bytes
+
+
+def _assert_one_pool(compiled, pool_bytes):
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, (
+        "donated pools are not aliased onto the output"
+    )
+    assert mem.temp_size_in_bytes < TEMP_SHARE_OF_POOL * pool_bytes, (
+        f"temporaries {mem.temp_size_in_bytes / 2**30:.2f} GiB next to a "
+        f"{pool_bytes / 2**30:.2f} GiB pool: a step program holds a "
+        "second pool again"
+    )
+    assert "{4,1,3,2,0" not in compiled.as_text(), (
+        "XLA re-laid the KV pool out (kv heads minor): the Pallas "
+        "kernels read the default layout, so this costs whole-pool copies"
+    )
+
+
+def test_decode_chunk_holds_one_pool_on_v5e(v5e):
+    from vgate_tpu.runtime.engine_core import _decode_chunk
+
+    A = _abstract(v5e)
+    spec, params, pool, pool_bytes = _qwen_1p5b(A)
+    B, ctx = 32, 2048
+    compiled = _decode_chunk.lower(
+        params, spec, A((B,), jnp.int32), A((B,), jnp.int32), pool, pool,
+        A((B, ctx // PAGE), jnp.int32), A((B,), jnp.bool_),
+        A((B,), jnp.float32), A((B,), jnp.float32), A((B,), jnp.int32),
+        A((2,), jnp.uint32), A((), jnp.uint32),
+        num_steps=8, use_pallas=True, max_position=ctx - 1,
+        seeds=A((B,), jnp.int32), steps=A((B,), jnp.int32),
+        all_greedy=True, guard=True,
+    ).compile()
+    _assert_one_pool(compiled, pool_bytes)
+
+
+def test_prefill_step_holds_one_pool_on_v5e(v5e):
+    from vgate_tpu.runtime.engine_core import _prefill_step
+
+    A = _abstract(v5e)
+    spec, params, pool, pool_bytes = _qwen_1p5b(A)
+    B, bucket = 2, 128
+    compiled = _prefill_step.lower(
+        params, spec, A((B, bucket), jnp.int32), A((B,), jnp.int32),
+        pool, pool, A((B, bucket // PAGE), jnp.int32),
+        A((B,), jnp.float32), A((B,), jnp.float32), A((B,), jnp.int32),
+        A((2,), jnp.uint32), use_pallas=True,
+        seeds=A((B,), jnp.int32), steps=A((B,), jnp.int32),
+    ).compile()
+    _assert_one_pool(compiled, pool_bytes)

@@ -52,6 +52,53 @@ def apply_platform(tpu_cfg) -> None:
             "before any jax.devices()/device computation happens"
         )
 
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# A FIXED path under the checkout: the directory is part of the cache
+# key's lookup, so a path built from tempfile, a pid or the clock would
+# never hit on the next boot.  Listed in .gitignore.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
+
+
+def _requested_platform() -> str:
+    """First entry of the platform list JAX was asked for (JAX_PLATFORMS
+    or ``apply_platform``'s pin), "" when JAX chooses — read from the
+    config so no backend is initialised to find out."""
+    import jax
+
+    return (jax.config.jax_platforms or "").split(",")[0]
+
+
+def apply_compile_cache() -> Optional[str]:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it
+    itself and no directory is set in code; otherwise the cache lives at
+    ``<checkout>/.jax_cache``.  Child processes (pod workers, loadlab's
+    server) inherit the variable through ``os.environ``.  Call sites are
+    the same as ``apply_platform``: engine construction and server
+    startup, plus chip_smoke.py's kernel child.
+
+    A process asked to run on the CPU gets no cache unless the variable
+    places one (returns None): XLA:CPU reloads executables through its
+    AOT loader, whose code need not match a fresh JIT compile bit for
+    bit, and Tier-1's token-identity tests compare engines inside one
+    process.  Cold compiles cost minutes on the chip, not here."""
+    import jax
+
+    placed = os.environ.get(COMPILE_CACHE_ENV)
+    if not placed and _requested_platform() == "cpu":
+        return None
+    # cache every program, not only those that took over a second to
+    # compile: a warm boot then compiles nothing, and whether a program
+    # is cached does not depend on how long a compile happened to take
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if placed:
+        return placed
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
+    return DEFAULT_COMPILE_CACHE_DIR
+
+
 # "vllm" is the optional comparison backend (backends/vllm_backend.py):
 # selectable everywhere, fails with a clear error unless a vllm wheel is
 # installed (the reference benchmarks vLLM/SGLang side by side)
@@ -239,9 +286,10 @@ class TPUConfig(BaseModel):
     ep: int = 1
     sp: int = 1
     # JAX platform to pin before device init: "auto" keeps whatever the
-    # environment selects; "cpu" forces the host platform (the CPU/dry-run
-    # serving target — some TPU plugins override the JAX_PLATFORMS env var,
-    # so an explicit config knob is the only reliable switch).
+    # environment selects (JAX_PLATFORMS, else JAX's own default); "cpu"
+    # and "tpu" are the config-file form of that pin.  "tpu" makes a
+    # machine whose chip failed to initialise fail at start instead of
+    # serving from the CPU.
     platform: str = "auto"
 
     @field_validator("platform")
@@ -255,9 +303,9 @@ class TPUConfig(BaseModel):
         return v
     num_devices: int = 0  # 0 => every visible device; else use a subslice
     # Paged KV cache geometry.
-    # tokens per page: 32 measured best on v5e (4038 vs 3729 tok/s at 16
-    # — a 16-token page is a 4 KB DMA per kv head, too narrow for HBM;
-    # 64 gained nothing further.  RESULTS_r4.md page sweep)
+    # tokens per page: a 16-token page is a 4 KB DMA per kv head at
+    # head_dim 128; 32 doubles it.  Not measured on the current
+    # toolchain (ROADMAP D14).
     kv_page_size: int = 32
     kv_num_pages: int = 0  # 0 => auto-size from free HBM
     hbm_utilization: float = 0.9
@@ -270,16 +318,14 @@ class TPUConfig(BaseModel):
     # implementations (needed on CPU test meshes).
     use_pallas: bool = True
     # Fused dequant-matmul Pallas kernels for int8/int4 weights.
-    # Default OFF: the int8 serving warmup hung Mosaic compile >19 min
-    # on first v5e contact (r4, benchmarks/RESULTS_r4.md) and a default
-    # must never be able to hang a fresh deployment — quantized serving
-    # rides the jnp dequant path until the standalone compile probe
-    # adjudicates slow-compile vs hang (VERDICT r4 weak-3).  Opt in via
-    # VGT_TPU__QUANT_KERNEL=true once proven on your toolchain.
+    # Default OFF: they have never finished a compile on a chip and are
+    # not measured on the current toolchain (ROADMAP S6) — quantized
+    # serving rides the jnp dequant path.  Opt in via
+    # VGT_TPU__QUANT_KERNEL=true.
     quant_kernel: bool = False
     # W8A8/W4A8: dynamically quantize activations per-token (int8) and
-    # run projection GEMMs on the MXU's NATIVE s8 x s8 -> s32 path (2x
-    # bf16 matmul throughput on v5e) — pure jnp, no Pallas/Mosaic, and
+    # run projection GEMMs on the MXU's NATIVE s8 x s8 -> s32 path (twice
+    # the v5e's published bf16 peak) — pure jnp, no Pallas/Mosaic, and
     # it auto-partitions under any mesh.  Changes numerics (~1% per-GEMM
     # quantization error on top of weight quant), so opt-in until the
     # accuracy/throughput trade is measured on hardware
@@ -292,32 +338,9 @@ class TPUConfig(BaseModel):
     # (default 1 = per-slot kernel) until measured on hardware; A/B via
     # VGT_TPU__DECODE_BLOCK_SLOTS=8.
     decode_block_slots: int = 1
-    # Thread the FULL [L, ...] KV pools through the decode AND prefill
-    # scans as carry (layer-indexed in-place updates + layer-indexed
-    # attention reads) instead of per-layer xs/ys slices.  MEASURED ON
-    # TPU v5e (r4, benchmarks/RESULTS_r4.md): carry is a 5.2x decode
-    # REGRESSION at the 1.5B serving shape (719 vs 3729 tok/s/chip) —
-    # XLA handles the xs/ys slice threading without materializing the
-    # pools, while the layer-indexed dynamic reads/writes on the full
-    # [L,...] carry defeat its aliasing.  Default OFF; kept as an A/B
-    # handle.  Applies to plain (sp=1, pp=1) meshes only.
-    kv_carry: bool = False
-
-    @model_validator(mode="before")
-    @classmethod
-    def _reject_renamed_kv_carry(cls, values):
-        # the knob briefly shipped as kv_carry_decode; extra="ignore"
-        # would silently drop the old name and re-enable carry under an
-        # operator who pinned it off — fail loudly instead
-        if isinstance(values, dict) and "kv_carry_decode" in values:
-            raise ValueError(
-                "tpu.kv_carry_decode was renamed to tpu.kv_carry "
-                "(it now covers prefill too); update the config"
-            )
-        return values
-    # Per-chip HBM budget in bytes for KV auto-sizing when the runtime
-    # reports no memory stats (0 => 16 GiB, the v5e default; set for other
-    # parts, e.g. 32 GiB for v4/v5p).
+    # Per-chip HBM budget in bytes for KV auto-sizing, read ONLY when the
+    # device reports no memory_stats (the v5e does report).  0 => such a
+    # device is an error at engine start, not an assumed size.
     hbm_bytes: int = 0
     # Decode steps fused into one device program (lax.scan over the step
     # body).  The host reads tokens back once per chunk, amortizing the
@@ -369,9 +392,8 @@ class TPUConfig(BaseModel):
     # verify by exact argmax match; sampled rows by rejection sampling
     # (both distribution-exact, runtime/speculative.py).  Drafts come
     # from prompt-lookup, or from a draft MODEL when
-    # model.draft_model_id is set.  0 = off (the default — chunked
-    # decode wins on high-RTT device links; this mode wins
-    # single-stream latency on local hardware).
+    # model.draft_model_id is set.  0 = off (the default; neither mode
+    # is measured on the current toolchain — ROADMAP R7).
     speculative_k: int = 0
     # Match length for the prompt-lookup drafter.
     speculative_ngram: int = 2

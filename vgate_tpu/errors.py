@@ -99,7 +99,7 @@ class EngineRecoveringError(RetryableError):
 class EngineStalledError(RuntimeError):
     """The engine loop stopped heartbeating: a decode/prefill dispatch
     (or its readback) has been stuck past ``recovery.step_stall_s`` —
-    the wedged-engine failure mode (Mosaic hang, stuck TPU grant) that
+    the wedged-engine failure mode (Mosaic hang, stuck device call) that
     a crash-only supervisor never sees, because nothing ever *raises*.
     Declared by the watchdog (supervisor / dp repair thread) OFF the
     engine thread; ``fault_kind`` classifies it transient so the

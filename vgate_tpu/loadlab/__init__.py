@@ -14,8 +14,9 @@ Entry points:
     python -m vgate_tpu.loadlab run --scenario smoke_mixed --launch
     python -m vgate_tpu.loadlab.compare old.jsonl new.jsonl
 
-This package is deliberately jax-free: it must run from any client
-host, including one with a wedged TPU grant.
+This package is deliberately jax-free: it runs from any client host,
+and a process that imports jax next to the server would take the chip
+from it.
 """
 
 from .scenario import (  # noqa: F401

@@ -210,7 +210,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             )
             return 2
     # the scenario hash only covers the YAML; env-exported overrides
-    # (r6_session re-points one scenario at 7B / int8 KV) change the
+    # (a sweep re-points one scenario at 7B / int8 KV) change the
     # SERVER, which the config fingerprint (hashed /stats config block)
     # catches — a different config is a different experiment
     o_fp = o_meta.get("config_fingerprint")

@@ -404,8 +404,8 @@ def run_scenario(scenario: Scenario, base_url: str, **kwargs: Any):
 
 def scenario_server_env(scenario: Scenario) -> Dict[str, str]:
     """The scenario's server_env as DEFAULTS: any variable the operator
-    already exported wins (r6_session.sh re-points the same scenario at
-    a 7B model / int8 KV by exporting over it)."""
+    already exported wins (a sweep re-points the same scenario at another
+    model or KV dtype by exporting over it)."""
     return {
         k: str(v)
         for k, v in scenario.server_env.items()
@@ -418,18 +418,21 @@ def launch_server(
     env_overrides: Dict[str, str],
     port: int = 8790,
     ready_timeout_s: float = 300.0,
+    log_path: Optional[str] = None,
 ):
     """Boot ``python main.py`` on ``port`` with ``env_overrides`` and
     yield its base URL once /health/ready answers; always tears the
     process down.  The scenario's ``server_env`` plus the caller's env
-    decide platform/model — the lab itself never imports jax."""
+    decide platform/model — the lab itself never imports jax.  The
+    server's output goes to ``log_path`` when given: without it a
+    server that dies before ready leaves an exit code and no reason."""
     env = dict(os.environ)
     env.update(env_overrides)
     env["VGT_SERVER__PORT"] = str(port)
+    log = open(log_path, "wb") if log_path else subprocess.DEVNULL
     proc = subprocess.Popen(
         [sys.executable, os.path.join(_REPO_DIR, "main.py")],
-        env=env, cwd=_REPO_DIR,
-        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
+        env=env, cwd=_REPO_DIR, stdout=log, stderr=subprocess.STDOUT,
     )
     base = f"http://127.0.0.1:{port}"
     try:
@@ -439,6 +442,7 @@ def launch_server(
             if proc.poll() is not None:
                 raise RuntimeError(
                     f"server exited rc={proc.returncode} before ready"
+                    + (f"; see {log_path}" if log_path else "")
                 )
             try:
                 with urllib.request.urlopen(
@@ -462,3 +466,5 @@ def launch_server(
         except subprocess.TimeoutExpired:
             proc.kill()
             proc.wait(timeout=10)
+        if log_path:
+            log.close()

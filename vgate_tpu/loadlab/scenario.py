@@ -259,7 +259,7 @@ class Scenario:
         """Stable hash of everything that affects the offered load —
         compare refuses cross-scenario diffs on it.  server_env is
         included, but only the YAML's view of it: env-EXPORTED server
-        overrides bypass this hash by design (r6_session re-points one
+        overrides bypass this hash by design (a sweep re-points one
         scenario at other models), which is why compare.py additionally
         gates on the artifact's config_fingerprint (hashed from the
         live server's /stats config block)."""

@@ -7,11 +7,14 @@ Design (TPU-first, not a torch port):
   pytree with a leading layer axis, and the forward pass scans over it.  One
   layer body is traced/compiled regardless of depth, keeping compile times
   flat (SURVEY.md section 7: recompile-avoidance discipline).
-* **Paged KV cache threaded through the scan as per-layer xs/ys** — the scan
-  consumes ``k_pages[l]`` and emits the updated slice, so XLA sees a clean
-  per-layer in-place update with no cross-layer scatter.  Pages are written
-  with the reserved *trash page 0* trick: padded positions scatter into page
-  0, so no masking is needed on the write path.
+* **Paged KV cache threaded through the scan as carry** — the FULL
+  ``[L, ...]`` pools ride the layer scan's carry with layer-indexed
+  in-place writes and layer-indexed attention reads, so a step program
+  needs weights + ONE pool.  (Threading them as per-layer xs/ys cannot
+  alias the ys stack onto the xs stack: every program then held a second
+  pool — PERF.md "Bring-up".)  Pages are written with the reserved *trash
+  page 0* trick: padded positions scatter into page 0, so no masking is
+  needed on the write path.
 * **Static shapes everywhere** — prompt lengths are bucketed by the caller;
   decode is a fixed ``[B]`` step.  fp32 softmax/norms, bf16 matmuls on MXU.
 
@@ -34,7 +37,7 @@ from vgate_tpu.ops.attention import (
     paged_decode_attention,
     paged_suffix_attention,
 )
-from vgate_tpu.ops.kv_quant import kv_write
+from vgate_tpu.ops.kv_quant import kv_write_pages, kv_write_tokens
 from vgate_tpu.ops.norms import rms_norm
 from vgate_tpu.ops.quant import weighted_einsum
 from vgate_tpu.ops.rope import apply_rope
@@ -288,41 +291,96 @@ def _layer_windows(spec: ModelSpec) -> jnp.ndarray:
     return jnp.asarray(spec.layer_windows, jnp.int32)
 
 
-def _kv_layer_scan(params, spec: ModelSpec, body, x0, k_pages, v_pages,
-                   kv_carry: bool):
-    """The one layer-scan scaffold every forward shares.
+def _axis(mesh, name: str) -> int:
+    return int(mesh.shape.get(name, 1)) if mesh is not None else 1
 
-    ``body(h, lp, win, kp, vp, layer)`` runs one transformer layer and
-    returns ``(h, kp, vp)``; ``layer`` is ``None`` under xs/ys threading
-    (kp/vp are that layer's pool slices) and a traced layer index under
-    carry threading (kp/vp are the FULL stacked pools, updated in place).
-    Returns ``(x, k_pages, v_pages)``."""
-    windows = _layer_windows(spec)
-    if kv_carry:
-        def fn(carry, per_layer):
-            h, kp, vp = carry
-            lp, win, l = per_layer
-            h, kp, vp = body(h, lp, win, kp, vp, l)
-            return (h, kp, vp), None
 
-        (x, k_pages, v_pages), _ = jax.lax.scan(
-            fn,
-            (x0, k_pages, v_pages),
-            (
-                params["layers"],
-                windows,
-                jnp.arange(spec.num_layers, dtype=jnp.int32),
-            ),
-        )
-    else:
-        def fn(h, per_layer):
-            lp, win, kp, vp = per_layer
-            h, kp, vp = body(h, lp, win, kp, vp, None)
-            return h, (kp, vp)
+def _kernel_or_twin(spec: ModelSpec, use_pallas: bool, mesh) -> str:
+    """Shared tail of the selectors below: the Pallas kernel, the kernel
+    per tp shard (parallel/tp_attention.py), or the auto-partitioned jnp
+    twin when heads don't divide tp (strictly better than a GSPMD-
+    replicated pallas_call)."""
+    if not use_pallas:
+        return "jnp"
+    if _axis(mesh, "tp") > 1:
+        from vgate_tpu.parallel.tp_attention import tp_divisible
 
-        x, (k_pages, v_pages) = jax.lax.scan(
-            fn, x0, (params["layers"], windows, k_pages, v_pages)
-        )
+        if tp_divisible(mesh, spec.num_heads, spec.num_kv_heads):
+            return "pallas_tp"
+        return "jnp"
+    return "pallas"
+
+
+def prefill_attention_impl(
+    spec: ModelSpec, use_pallas: bool, mesh=None
+) -> str:
+    """The attention implementation ``prefill_forward`` traces for these
+    static arguments.  The forwards select through these functions, and
+    the engine reports their answer per compiled program in /stats →
+    engine.attention, so a shape or mesh gate that swaps a kernel for
+    its jnp twin is visible instead of silent."""
+    if _axis(mesh, "pp") > 1:
+        return "pp_relay"
+    if _axis(mesh, "sp") > 1:
+        return "ring"
+    return _kernel_or_twin(spec, use_pallas, mesh)
+
+
+def decode_attention_impl(
+    spec: ModelSpec, use_pallas: bool, mesh=None
+) -> str:
+    """As ``prefill_attention_impl``, for ``decode_forward``."""
+    if _axis(mesh, "pp") > 1:
+        return "pp_relay"
+    if _axis(mesh, "sp") > 1:
+        return "sp_shard"
+    impl = _kernel_or_twin(spec, use_pallas, mesh)
+    if impl != "jnp" and spec.decode_block_slots > 1:
+        impl += "_blocked"
+    return impl
+
+
+def multitok_attention_impl(
+    use_pallas: bool, mesh=None, rows: int = 1, unaligned: bool = False
+) -> str:
+    """As above, for the paged multi-token attention of
+    ``prefill_suffix_forward`` (``rows`` = suffix bucket) and
+    ``spec_verify_forward``.  The kernel holds all query rows in VMEM
+    (it was sized for speculative verify): at 1024 rows, G=6, hd=128 the
+    f32 acc/m/l/scores blocks total ~15 MB; 2048 doubles that and
+    serializes huge per-program dots, so wider buckets keep the
+    blockwise jnp path (row-tiling the kernel is the future fix).  Its
+    DMA ranges assume page-aligned starts, so the COW ``unaligned``
+    variant rides jnp too.  tp>1: the jnp path auto-partitions; the
+    kernel would be GSPMD-replicated (parallel/tp_attention.py)."""
+    if _axis(mesh, "sp") > 1:
+        return "sp_shard"
+    kernel_fits = rows <= 1024 and not unaligned and _axis(mesh, "tp") == 1
+    return "pallas" if use_pallas and kernel_fits else "jnp"
+
+
+def _kv_layer_scan(params, spec: ModelSpec, body, x0, k_pages, v_pages):
+    """The one layer-scan scaffold every plain-mesh forward shares.
+
+    ``body(h, lp, win, kp, vp, layer)`` runs one transformer layer
+    against the FULL stacked pools (``layer`` is the traced layer index;
+    writes and attention reads are layer-indexed, updated in place) and
+    returns ``(h, kp, vp)``.  Returns ``(x, k_pages, v_pages)``."""
+    def fn(carry, per_layer):
+        h, kp, vp = carry
+        lp, win, l = per_layer
+        h, kp, vp = body(h, lp, win, kp, vp, l)
+        return (h, kp, vp), None
+
+    (x, k_pages, v_pages), _ = jax.lax.scan(
+        fn,
+        (x0, k_pages, v_pages),
+        (
+            params["layers"],
+            _layer_windows(spec),
+            jnp.arange(spec.num_layers, dtype=jnp.int32),
+        ),
+    )
     return x, k_pages, v_pages
 
 
@@ -336,7 +394,6 @@ def prefill_forward(
     page_tables: jnp.ndarray,  # [B, S // ps] page ids for this prompt
     mesh=None,  # jax.sharding.Mesh; sp>1 routes attention through the ring
     use_pallas: bool = False,
-    kv_carry: bool = False,  # thread FULL KV buffers as scan carry
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Run the prompt pass: returns (last-token logits [B, V], k_pages, v_pages).
 
@@ -350,15 +407,15 @@ def prefill_forward(
     divide by sp.
     """
     B, S = tokens.shape
-    if mesh is not None and mesh.shape.get("pp", 1) > 1:
+    impl = prefill_attention_impl(spec, use_pallas, mesh)
+    if impl == "pp_relay":
         from vgate_tpu.parallel.pipeline import pp_prefill_forward
 
         return pp_prefill_forward(
             params, spec, tokens, seq_lens, k_pages, v_pages, page_tables,
             mesh=mesh, use_pallas=use_pallas,
         )
-    use_ring = mesh is not None and mesh.shape.get("sp", 1) > 1
-    if use_ring:
+    if impl == "ring":
         # sliding-window/softcap families (Gemma-2) ride the ring too:
         # per-layer window masks compose with the ring's global block-
         # position masks (parallel/ring_attention.py ring_attention_shard)
@@ -368,54 +425,35 @@ def prefill_forward(
             ring_prefill_attention, mesh=mesh, softcap=spec.attn_softcap,
             scale=_query_scale(spec),
         )
-    elif use_pallas:
-        from vgate_tpu.ops.pallas.flash_prefill import (
-            flash_prefill_attention_pallas,
-        )
-
-        kernel = functools.partial(
-            flash_prefill_attention_pallas,
-            softcap=spec.attn_softcap,
-            scale=_query_scale(spec),
-        )
-        # tp>1: run the kernel per shard (parallel/tp_attention.py) —
-        # GSPMD has no partition rule for pallas_call and would
-        # replicate the sharded q/k/v heads otherwise
-        tp_mesh = (
-            mesh
-            if mesh is not None and mesh.shape.get("tp", 1) > 1
-            else None
-        )
-        if tp_mesh is None:
-            attn_fn = kernel
-        else:
-            from vgate_tpu.parallel.tp_attention import (
-                tp_divisible,
-                tp_flash_prefill_attention,
-            )
-
-            if tp_divisible(
-                tp_mesh, spec.num_heads, spec.num_kv_heads
-            ):
-                attn_fn = functools.partial(
-                    tp_flash_prefill_attention, kernel, tp_mesh
-                )
-            else:
-                attn_fn = functools.partial(
-                    flash_prefill_attention,
-                    softcap=spec.attn_softcap,
-                    scale=_query_scale(spec),
-                )
-    else:
+    elif impl == "jnp":
         attn_fn = functools.partial(
             flash_prefill_attention,
             softcap=spec.attn_softcap,
             scale=_query_scale(spec),
         )
+    else:
+        from vgate_tpu.ops.pallas.flash_prefill import (
+            flash_prefill_attention_pallas,
+        )
+
+        attn_fn = functools.partial(
+            flash_prefill_attention_pallas,
+            softcap=spec.attn_softcap,
+            scale=_query_scale(spec),
+        )
+        if impl == "pallas_tp":
+            # run the kernel per shard — GSPMD has no partition rule for
+            # pallas_call and would replicate the sharded q/k/v heads
+            from vgate_tpu.parallel.tp_attention import (
+                tp_flash_prefill_attention,
+            )
+
+            attn_fn = functools.partial(
+                tp_flash_prefill_attention, attn_fn, mesh
+            )
     x = _embed(params, spec, tokens)  # [B, S, D]
     # the prompt pass only WRITES pages (attention runs over the fresh
-    # k/v), so carry threading just swaps xs/ys slice threading for
-    # layer-indexed in-place writes
+    # k/v): layer-indexed in-place writes on the carried pools
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
 
     def body(h, lp, win, kp, vp, layer):
@@ -430,7 +468,7 @@ def prefill_forward(
         return _finish_layer(h, attn, lp, spec), kp, vp
 
     x, k_pages, v_pages = _kv_layer_scan(
-        params, spec, body, x, k_pages, v_pages, kv_carry
+        params, spec, body, x, k_pages, v_pages
     )
     last_idx = jnp.clip(seq_lens - 1, 0, S - 1)
     last_hidden = jnp.take_along_axis(
@@ -446,10 +484,11 @@ def _prefill_qkv_write(
     """Shared prompt-pass front half: norm + qkv projection + rope at the
     given (possibly offset) positions, then write this layer's KV into its
     pages (trash-page-0 absorbs padding).  Pages are head-major
-    [KV, P, ps, hd]: the fresh KV transposes to [KV, B, n_pages, ps, hd]
-    so each head's pages land contiguously.  With ``layer`` (a traced
-    scalar) the pools carry a leading [L] dim and the write is a
-    layer-indexed in-place update — the carry-threaded prompt pass.
+    [KV, P, ps, hd]: the fresh KV transposes to [B, n_pages, KV, ps, hd]
+    so each (head, page) lands as one contiguous (ps, hd) tile.  With
+    ``layer`` (a traced scalar) the pools carry a leading [L] dim and
+    the write is a layer-indexed in-place update (the plain-mesh scan);
+    without it they are one layer's slice (the pp stage relay).
 
     ``offsets`` ([B] int32) switches to the UNALIGNED write used by
     copy-on-write prefix sharing (runtime/radix_cache.py): row ``b``'s
@@ -472,60 +511,20 @@ def _prefill_qkv_write(
         idx = offsets[:, None] + jnp.arange(S)[None, :]  # [B, S] in-suffix
         slot = idx % ps
         pages_bs = jnp.take_along_axis(page_tables, idx // ps, axis=1)
-        k_t = k.reshape(B, S, spec.num_kv_heads, spec.head_dim)
-        v_t = v.reshape(B, S, spec.num_kv_heads, spec.head_dim)
-        if layer is None:
-            # advanced indices (dims 1, 2) are adjacent: update shape
-            # [KV, B, S, hd].  kv_write = .at[idx].set for plain pools,
-            # quantize-on-write for int8 pools (ops/kv_quant.py) —
-            # identical index on the scale pool minus the trailing hd.
-            k_pages_l = kv_write(
-                k_pages_l, (slice(None), pages_bs, slot),
-                jnp.transpose(k_t, (2, 0, 1, 3)),
-            )
-            v_pages_l = kv_write(
-                v_pages_l, (slice(None), pages_bs, slot),
-                jnp.transpose(v_t, (2, 0, 1, 3)),
-            )
-        else:
-            # scalar layer + slice + advanced: broadcast (B, S) dims
-            # move to the FRONT — update shape [B, S, KV, hd]
-            k_pages_l = kv_write(
-                k_pages_l, (layer, slice(None), pages_bs, slot), k_t
-            )
-            v_pages_l = kv_write(
-                v_pages_l, (layer, slice(None), pages_bs, slot), v_t
-            )
+        k_pages_l = kv_write_tokens(
+            k_pages_l, pages_bs, slot, k, layer=layer
+        )
+        v_pages_l = kv_write_tokens(
+            v_pages_l, pages_bs, slot, v, layer=layer
+        )
         return q, k, v, k_pages_l, v_pages_l
     pt = page_tables[:, :n_pages]
-    if layer is None:
-        k_resh = jnp.transpose(
-            k.reshape(B, n_pages, ps, spec.num_kv_heads, spec.head_dim),
-            (3, 0, 1, 2, 4),
-        )
-        v_resh = jnp.transpose(
-            v.reshape(B, n_pages, ps, spec.num_kv_heads, spec.head_dim),
-            (3, 0, 1, 2, 4),
-        )
-        k_pages_l = kv_write(k_pages_l, (slice(None), pt), k_resh)
-        v_pages_l = kv_write(v_pages_l, (slice(None), pt), v_resh)
-    else:
-        # mixed scalar/slice/array indexing moves the broadcast (B,
-        # n_pages) dims to the FRONT: update shape [B, n_pages, KV, ps, hd]
-        k_resh = jnp.transpose(
-            k.reshape(B, n_pages, ps, spec.num_kv_heads, spec.head_dim),
-            (0, 1, 3, 2, 4),
-        )
-        v_resh = jnp.transpose(
-            v.reshape(B, n_pages, ps, spec.num_kv_heads, spec.head_dim),
-            (0, 1, 3, 2, 4),
-        )
-        k_pages_l = kv_write(
-            k_pages_l, (layer, slice(None), pt), k_resh
-        )
-        v_pages_l = kv_write(
-            v_pages_l, (layer, slice(None), pt), v_resh
-        )
+    to_pages = lambda t: jnp.transpose(
+        t.reshape(B, n_pages, ps, spec.num_kv_heads, spec.head_dim),
+        (0, 1, 3, 2, 4),
+    )  # [B, n_pages, KV, ps, hd]
+    k_pages_l = kv_write_pages(k_pages_l, pt, to_pages(k), layer=layer)
+    v_pages_l = kv_write_pages(v_pages_l, pt, to_pages(v), layer=layer)
     return q, k, v, k_pages_l, v_pages_l
 
 
@@ -614,14 +613,8 @@ def decode_layer(
             softcap=spec.attn_softcap, scale=_query_scale(spec),
         )
         return _finish_layer(h, attn, lp, spec), k_pages_l, v_pages_l
-    k_pages_l = kv_write(
-        k_pages_l, (slice(None), page_ids, page_off),
-        jnp.transpose(k, (1, 0, 2)),
-    )
-    v_pages_l = kv_write(
-        v_pages_l, (slice(None), page_ids, page_off),
-        jnp.transpose(v, (1, 0, 2)),
-    )
+    k_pages_l = kv_write_tokens(k_pages_l, page_ids, page_off, k)
+    v_pages_l = kv_write_tokens(v_pages_l, page_ids, page_off, v)
     if window is None:
         attn = attn_fn(q, k_pages_l, v_pages_l, page_tables, seq_lens)
     else:
@@ -655,21 +648,17 @@ def decode_forward(
     active: Optional[jnp.ndarray] = None,  # [B] bool; inactive slots write page 0
     use_pallas: bool = False,
     mesh=None,  # pp>1 routes through the pipeline-parallel stage relay
-    kv_carry: bool = False,  # thread FULL KV buffers as scan carry
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One continuous-batching decode step: returns (logits [B, V], caches)."""
-    if mesh is not None and mesh.shape.get("pp", 1) > 1:
+    impl = decode_attention_impl(spec, use_pallas, mesh)
+    if impl == "pp_relay":
         from vgate_tpu.parallel.pipeline import pp_decode_forward
 
         return pp_decode_forward(
             params, spec, tokens, positions, k_pages, v_pages, page_tables,
             active=active, mesh=mesh, use_pallas=use_pallas,
         )
-    sp_mesh = (
-        mesh
-        if mesh is not None and mesh.shape.get("sp", 1) > 1
-        else None
-    )
+    sp_mesh = mesh if impl == "sp_shard" else None
     if sp_mesh is not None:
         # sequence-parallel decode: attention + KV write run per-shard
         # over the sp-sharded page pool (parallel/sp_decode.py)
@@ -696,69 +685,44 @@ def decode_forward(
             sp_layer_fn, x, (params["layers"], windows, k_pages, v_pages)
         )
         return _logits(params, spec, x), k_pages, v_pages
-    # tp>1 (no sp/pp): params and the pool's kv-head dim are GSPMD-
-    # sharded.  The jnp twin partitions automatically; a pallas_call
-    # does NOT — it must run per shard via shard_map
-    # (parallel/tp_attention.py) or GSPMD would all-gather the pool.
-    tp_mesh = (
-        mesh
-        if mesh is not None and mesh.shape.get("tp", 1) > 1
-        else None
-    )
-    if use_pallas:
-        # the decode kernel supports window/softcap/scale natively (and
-        # skips DMA for pages below the window), so local-attention
-        # families ride it too.  decode_block_slots > 1 selects the
-        # multi-slot blocked grid (B/N x KV programs instead of B x KV).
-        if spec.decode_block_slots > 1:
-            from vgate_tpu.ops.pallas.paged_attention import (
-                paged_decode_attention_pallas_blocked as _decode_kernel,
-            )
-
-            kernel = functools.partial(
-                _decode_kernel,
-                softcap=spec.attn_softcap,
-                scale=_query_scale(spec),
-                block_slots=spec.decode_block_slots,
-            )
-        else:
-            from vgate_tpu.ops.pallas.paged_attention import (
-                paged_decode_attention_pallas as _decode_kernel,
-            )
-
-            kernel = functools.partial(
-                _decode_kernel,
-                softcap=spec.attn_softcap,
-                scale=_query_scale(spec),
-            )
-        if tp_mesh is None:
-            attn_fn = kernel
-        else:
-            from vgate_tpu.parallel.tp_attention import (
-                tp_divisible,
-                tp_paged_decode_attention,
-            )
-
-            if tp_divisible(
-                tp_mesh, spec.num_heads, spec.num_kv_heads
-            ):
-                attn_fn = functools.partial(
-                    tp_paged_decode_attention, kernel, tp_mesh
-                )
-            else:
-                # heads don't divide tp: the auto-partitioned jnp twin
-                # is strictly better than a replicated pallas_call
-                attn_fn = functools.partial(
-                    paged_decode_attention,
-                    softcap=spec.attn_softcap,
-                    scale=_query_scale(spec),
-                )
-    else:
+    if impl == "jnp":
         attn_fn = functools.partial(
             paged_decode_attention,
             softcap=spec.attn_softcap,
             scale=_query_scale(spec),
         )
+    else:
+        # the decode kernel supports window/softcap/scale natively (and
+        # skips DMA for pages below the window), so local-attention
+        # families ride it too.  decode_block_slots > 1 selects the
+        # multi-slot blocked grid (B/N x KV programs instead of B x KV).
+        from vgate_tpu.ops.pallas import paged_attention as _pa
+
+        if impl.endswith("_blocked"):
+            attn_fn = functools.partial(
+                _pa.paged_decode_attention_pallas_blocked,
+                softcap=spec.attn_softcap,
+                scale=_query_scale(spec),
+                block_slots=spec.decode_block_slots,
+            )
+        else:
+            attn_fn = functools.partial(
+                _pa.paged_decode_attention_pallas,
+                softcap=spec.attn_softcap,
+                scale=_query_scale(spec),
+            )
+        if impl.startswith("pallas_tp"):
+            # params and the pool's kv-head dim are GSPMD-sharded over
+            # tp; a pallas_call does NOT partition automatically — it
+            # must run per shard via shard_map (parallel/tp_attention.py)
+            # or GSPMD would all-gather the pool
+            from vgate_tpu.parallel.tp_attention import (
+                tp_paged_decode_attention,
+            )
+
+            attn_fn = functools.partial(
+                tp_paged_decode_attention, attn_fn, mesh
+            )
     ps = k_pages.shape[3]
     seq_lens, page_ids, page_off = decode_attn_inputs(
         positions, page_tables, active, ps
@@ -766,30 +730,13 @@ def decode_forward(
 
     x = _embed(params, spec, tokens)  # [B, D]
 
-    # Carry threading (kv_carry=True): the FULL [L, ...] pools ride the
-    # scan carry with layer-indexed in-place updates, and attention reads
-    # the pool at layer l directly (Pallas: layer-indexed DMA; jnp: one
-    # composed gather).  The xs/ys form dynamic-slices each layer's whole
-    # [KV, P, ps, hd] pool into a fresh buffer per layer to feed the
-    # attention op — at serving pool sizes that is ~2x67 MB of pure copy
-    # per layer per step, larger than the live KV itself.
+    # the FULL [L, ...] pools ride the scan carry with layer-indexed
+    # in-place updates, and attention reads the pool at layer l directly
+    # (Pallas: layer-indexed DMA; jnp: one composed gather)
     def body(h, lp, win, kp, vp, layer):
         q, k, v = _decode_qkv(h, lp, spec, positions)
-        if layer is None:
-            kp = kv_write(
-                kp, (slice(None), page_ids, page_off),
-                jnp.transpose(k, (1, 0, 2)),
-            )
-            vp = kv_write(
-                vp, (slice(None), page_ids, page_off),
-                jnp.transpose(v, (1, 0, 2)),
-            )
-        else:
-            # mixed scalar/slice/array indexing: the broadcast (batch)
-            # dim moves to the FRONT, so the update shape is [B, KV, hd]
-            # — k/v as projected, no transpose
-            kp = kv_write(kp, (layer, slice(None), page_ids, page_off), k)
-            vp = kv_write(vp, (layer, slice(None), page_ids, page_off), v)
+        kp = kv_write_tokens(kp, page_ids, page_off, k, layer=layer)
+        vp = kv_write_tokens(vp, page_ids, page_off, v, layer=layer)
         attn = attn_fn(
             q, kp, vp, page_tables, seq_lens, layer=layer,
             window=win if spec.sliding_window > 0 else None,
@@ -797,7 +744,7 @@ def decode_forward(
         return _finish_layer(h, attn, lp, spec), kp, vp
 
     x, k_pages, v_pages = _kv_layer_scan(
-        params, spec, body, x, k_pages, v_pages, kv_carry
+        params, spec, body, x, k_pages, v_pages
     )
     return _logits(params, spec, x), k_pages, v_pages
 
@@ -812,7 +759,6 @@ def prefill_suffix_forward(
     v_pages: jnp.ndarray,
     suffix_page_tables: jnp.ndarray,  # [B, S // ps (+1 if unaligned)]
     ctx_page_tables: jnp.ndarray,  # [B, ctx_pages] window covering prefix+suffix
-    kv_carry: bool = False,  # thread FULL KV buffers as scan carry
     use_pallas: bool = False,  # multitok kernel for the context attention
     mesh=None,  # sp>1 routes write+attention through the sp shard path
     unaligned: bool = False,  # COW prefix sharing: prefix_lens % ps != 0
@@ -843,11 +789,10 @@ def prefill_suffix_forward(
     offsets = (prefix_lens % k_pages.shape[-2]) if unaligned else None
     x = _embed(params, spec, tokens)  # [B, S, D]
 
-    sp_mesh = (
-        mesh
-        if mesh is not None and mesh.shape.get("sp", 1) > 1
-        else None
+    impl = multitok_attention_impl(
+        use_pallas, mesh, rows=S, unaligned=unaligned
     )
+    sp_mesh = mesh if impl == "sp_shard" else None
     if sp_mesh is not None:
         # prefix caching on the sp-sharded pool: per-layer write +
         # blockwise partial attention run per shard, partials LSE-merge
@@ -883,26 +828,15 @@ def prefill_suffix_forward(
         )[:, 0]
         return _logits(params, spec, last_hidden), k_pages, v_pages
 
-    # The multitok kernel holds all S query rows in VMEM (it was sized
-    # for speculative verify): at S=1024, G=6, hd=128 the f32
-    # acc/m/l/scores blocks total ~15 MB — comfortable; S=2048 doubles
-    # that and serializes huge per-program dots.  Cap the kernel route
-    # at the default chunked-prefill width and keep the blockwise jnp
-    # path beyond (row-tiling the kernel is the future fix).  tp>1:
-    # the jnp path auto-partitions; the kernel would be GSPMD-
-    # replicated (parallel/tp_attention.py rationale), so gate it off.
-    use_pallas = use_pallas and S <= 1024 and not unaligned
-    if mesh is not None and mesh.shape.get("tp", 1) > 1:
-        use_pallas = False
+    use_pallas = impl == "pallas"
     if use_pallas:
         from vgate_tpu.ops.pallas.paged_attention import (
             paged_multitok_attention_pallas,
         )
 
-    # carry threading: both the suffix write AND the paged context read
-    # are layer-indexed on the full [L, ...] buffers — no per-layer pool
-    # slice ever materializes (the chunked-prefill hot path runs this
-    # once per chunk)
+    # both the suffix write AND the paged context read are layer-indexed
+    # on the full [L, ...] buffers — no per-layer pool slice ever
+    # materializes (the chunked-prefill hot path runs this once per chunk)
     def body(h, lp, win, kp, vp, layer):
         q, _k, _v, kp, vp = _prefill_qkv_write(
             h, lp, spec, positions, suffix_page_tables, kp, vp,
@@ -927,7 +861,7 @@ def prefill_suffix_forward(
         return _finish_layer(h, attn, lp, spec), kp, vp
 
     x, k_pages, v_pages = _kv_layer_scan(
-        params, spec, body, x, k_pages, v_pages, kv_carry
+        params, spec, body, x, k_pages, v_pages
     )
     last_idx = jnp.clip(suffix_lens - 1, 0, S - 1)
     last_hidden = jnp.take_along_axis(
@@ -947,7 +881,6 @@ def spec_verify_forward(
     page_tables: jnp.ndarray,  # [B, pages_per_seq]
     active: Optional[jnp.ndarray] = None,  # [B] bool
     use_pallas: bool = False,
-    kv_carry: bool = False,  # thread FULL KV buffers as scan carry
     mesh=None,  # sp>1 routes write+attention through the sp shard path
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Speculative-decoding verification: score ``S`` candidate tokens per
@@ -982,15 +915,9 @@ def spec_verify_forward(
     total_lens = positions0 + input_lens
     x = _embed(params, spec, tokens)  # [B, S, D]
 
-    sp_mesh = (
-        mesh
-        if mesh is not None and mesh.shape.get("sp", 1) > 1
-        else None
-    )
-    if mesh is not None and mesh.shape.get("tp", 1) > 1:
-        # tp>1: the blockwise jnp verify path auto-partitions over the
-        # head dims; the multitok kernel would be GSPMD-replicated
-        use_pallas = False
+    impl = multitok_attention_impl(use_pallas, mesh, rows=S)
+    sp_mesh = mesh if impl == "sp_shard" else None
+    use_pallas = impl == "pallas"
     if sp_mesh is not None:
         # speculative verify on an sp-sharded pool: per-token scatter
         # writes + blockwise partials per shard, LSE merge over sp
@@ -1029,29 +956,15 @@ def spec_verify_forward(
         )
 
     def body(h, lp, win, kp, vp, layer):
-        """One verify layer against either a per-layer pool slice
-        (layer=None; xs/ys threading) or the full stacked pools with a
-        layer index (carry threading)."""
+        """One verify layer against the full stacked pools."""
         normed = rms_norm(
             h, lp["input_norm"], spec.rms_eps, spec.unit_offset_norm
         )
         q, k, v = _project_qkv(normed, lp, spec)
         q = apply_rope(q, positions, spec.rope_theta, spec.rope_scaling)
         k = apply_rope(k, positions, spec.rope_theta, spec.rope_scaling)
-        if layer is None:
-            kp = kv_write(
-                kp, (slice(None), page_ids, page_off),
-                jnp.transpose(k, (2, 0, 1, 3)),
-            )
-            vp = kv_write(
-                vp, (slice(None), page_ids, page_off),
-                jnp.transpose(v, (2, 0, 1, 3)),
-            )
-        else:
-            # mixed scalar/slice/array indexing: broadcast (B, S) dims
-            # move to the front — update shape [B, S, KV, hd], k/v as-is
-            kp = kv_write(kp, (layer, slice(None), page_ids, page_off), k)
-            vp = kv_write(vp, (layer, slice(None), page_ids, page_off), v)
+        kp = kv_write_tokens(kp, page_ids, page_off, k, layer=layer)
+        vp = kv_write_tokens(vp, page_ids, page_off, v, layer=layer)
         window = win if spec.sliding_window > 0 else None
         if use_pallas:
             attn = paged_multitok_attention_pallas(
@@ -1068,6 +981,6 @@ def spec_verify_forward(
         return _finish_layer(h, attn, lp, spec), kp, vp
 
     x, k_pages, v_pages = _kv_layer_scan(
-        params, spec, body, x, k_pages, v_pages, kv_carry
+        params, spec, body, x, k_pages, v_pages
     )
     return _logits(params, spec, x), k_pages, v_pages

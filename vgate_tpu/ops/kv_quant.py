@@ -35,8 +35,9 @@ Design choices:
   KV tile.
 
 The pool rides through jit/scan/donation as a ``QuantPages`` NamedTuple
-(an automatic JAX pytree), so the engine's threading — xs/ys layer
-scan slices, carry threading, buffer donation — is unchanged.
+(an automatic JAX pytree), so the engine's threading — scan carry on
+plain meshes, per-layer slices in the sp/pp relays, buffer donation — is
+unchanged.
 """
 
 from __future__ import annotations
@@ -143,6 +144,42 @@ def kv_write(pool: KVPool, idx: tuple, value: jax.Array) -> KVPool:
     return pool.at[idx].set(value)
 
 
+def kv_write_tokens(
+    pool: KVPool, page_ids: jax.Array, page_off: jax.Array,
+    value: jax.Array, layer=None,
+) -> KVPool:
+    """Per-token KV write: ``value`` ``[..., KV, hd]`` (k/v as projected)
+    lands at ``pool[(layer,) kv, page_ids[...], page_off[...]]``.
+
+    The kv-head dim is indexed EXPLICITLY rather than sliced, so the
+    scatter's update window is one head_dim row.  With a slice there the
+    window is (KV, hd), and XLA:TPU's layout assignment then makes those
+    two the minor dims of the WHOLE pool (``{4,1,3,2,0:T(2,128)}``) —
+    a layout the Pallas kernels cannot read, so every step program paid
+    whole-pool relayout copies at entry, at exit and per layer inside
+    the decode loop, plus a pool-sized temporary (PERF.md "Bring-up",
+    memory_analysis table; tests/test_tpu_aot.py pins the fix)."""
+    kv = jnp.arange(value.shape[-2], dtype=jnp.int32)
+    idx = (kv, page_ids[..., None], page_off[..., None])
+    if layer is not None:
+        idx = (layer,) + idx
+    return kv_write(pool, idx, value)
+
+
+def kv_write_pages(
+    pool: KVPool, page_tables: jax.Array, value: jax.Array, layer=None
+) -> KVPool:
+    """Whole-page KV write (aligned prompt passes): ``value``
+    ``[..., KV, ps, hd]`` lands at ``pool[(layer,) kv, page_tables[...]]``
+    — the kv-head dim indexed explicitly for the same reason as
+    ``kv_write_tokens``, so the window is one contiguous (ps, hd) page."""
+    kv = jnp.arange(value.shape[-3], dtype=jnp.int32)
+    idx = (kv, page_tables[..., None])
+    if layer is not None:
+        idx = (layer,) + idx
+    return kv_write(pool, idx, value)
+
+
 def gather_pages(pool: KVPool, page_tables: jax.Array, layer=None):
     """Gather each slot's page window from the pool — the shared front
     half of the jnp paged-attention twins (ops/attention.py).
@@ -150,26 +187,24 @@ def gather_pages(pool: KVPool, page_tables: jax.Array, layer=None):
     Returns ``[KV, B, n_pages, ps, hd]``: raw dtype for plain pools,
     dequantized f32 for int8 pools (the same f32 the Pallas kernel
     computes its dots in).  With ``layer`` (a traced scalar) the pool
-    carries a leading [L] dim and the gather composes (layer, head,
-    page) in ONE fancy index — only the live pages of that layer are
-    ever read, never a full per-layer slice.
+    carries a leading [L] dim and ONE gather indexes (layer, page) —
+    only the live pages of that layer are read, never a full per-layer
+    slice — with the kv-head dim passed through as a window dim, so a
+    tp-sharded pool partitions along it.  (Folding layer and head into
+    one flat index reshapes across the sharded dim, and GSPMD then
+    all-gathers the whole pool onto every chip.)
     """
     quant = is_quantized(pool)
     data = pool.data if quant else pool
     if layer is not None:
-        L, KV = data.shape[0], data.shape[1]
-        head_idx = (layer * KV + jnp.arange(KV))[:, None, None]  # [KV,1,1]
-        flat = data.reshape(L * KV, *data.shape[2:])
-        sel = flat[head_idx, page_tables[None]]  # [KV, B, n, ps, hd]
-        if quant:
-            s_flat = pool.scale.reshape(L * KV, *pool.scale.shape[2:])
-            s_sel = s_flat[head_idx, page_tables[None]]  # [KV, B, n, ps]
-            return dequantize(sel, s_sel)
-        return sel
-    sel = data[:, page_tables]
+        # advanced (layer, pages) indices around a slice: the broadcast
+        # [B, n] dims lead, the sliced kv-head dim follows
+        take = lambda x: jnp.moveaxis(x[layer, :, page_tables], 2, 0)
+    else:
+        take = lambda x: x[:, page_tables]
     if quant:
-        return dequantize(sel, pool.scale[:, page_tables])
-    return sel
+        return dequantize(take(data), take(pool.scale))
+    return take(data)
 
 
 def copy_page_prefix(

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from vgate_tpu.ops.pallas._compat import CompilerParams as _CompilerParams
 
 
 def _kernel(
@@ -201,7 +200,7 @@ def flash_prefill_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, S, hd), q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024,

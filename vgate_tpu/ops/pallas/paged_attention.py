@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from vgate_tpu.ops.pallas._compat import CompilerParams as _CompilerParams
-
 from vgate_tpu.utils.math import cdiv
 
 # pages DMA'd per double-buffer slot (VGT_CHUNK_PAGES sweeps on-device:
@@ -388,7 +386,7 @@ def paged_decode_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
     )(page_tables, seq_lens, window_arr, layer_arr, *inputs)
@@ -691,7 +689,7 @@ def paged_decode_attention_pallas_blocked(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B // BS, KV, BS, G, hd), q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=96 * 1024 * 1024,
         ),
     )(
@@ -945,7 +943,7 @@ def paged_multitok_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, S, G, hd), q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
     )(

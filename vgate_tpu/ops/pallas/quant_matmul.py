@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from vgate_tpu.ops.pallas._compat import CompilerParams as _CompilerParams
 
 from vgate_tpu.utils.math import cdiv
 
@@ -141,7 +140,7 @@ def _tiled_matmul(
         out_shape=jax.ShapeDtypeStruct((Rp, out), out_dtype),
         scratch_shapes=[pltpu.VMEM((T_r, T_out), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(*operands)

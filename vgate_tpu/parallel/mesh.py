@@ -155,12 +155,21 @@ def build_mesh(tpu_config=None, devices=None) -> Mesh:
         device_array = mesh_utils.create_device_mesh(
             plan.shape, devices=devices
         )
-    except (ValueError, AssertionError):
+        order = "create_device_mesh"
+    except (ValueError, AssertionError) as exc:
+        # enumeration order, NOT topology-aware: tp may not sit on
+        # adjacent chips — said out loud below
         device_array = np.asarray(devices).reshape(plan.shape)
+        order = f"plain reshape (create_device_mesh refused: {exc})"
     mesh = Mesh(device_array, MESH_AXES)
     logger.info(
         "mesh built",
-        extra={"extra_data": {"shape": dict(zip(MESH_AXES, plan.shape))}},
+        extra={
+            "extra_data": {
+                "shape": dict(zip(MESH_AXES, plan.shape)),
+                "device_order": order,
+            }
+        },
     )
     return mesh
 

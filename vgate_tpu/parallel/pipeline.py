@@ -51,7 +51,6 @@ from vgate_tpu.ops.attention import (
     flash_prefill_attention,
     paged_decode_attention,
 )
-from vgate_tpu.parallel._compat import shard_map
 from vgate_tpu.parallel.mesh import AXIS_PP
 
 
@@ -161,7 +160,7 @@ def _decode_staged_fn(mesh, spec, M, mb, use_pallas, layers_treedef):
         out = jax.lax.psum(jnp.where(s == pp - 1, out_acc, 0), AXIS_PP)
         return out, k_loc, v_loc
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         staged,
         mesh=mesh,
         in_specs=(
@@ -276,7 +275,7 @@ def _prefill_staged_fn(mesh, spec, M, mb, use_pallas, layers_treedef):
         out = jax.lax.psum(jnp.where(s == pp - 1, out_acc, 0), AXIS_PP)
         return out, k_loc, v_loc
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         staged,
         mesh=mesh,
         in_specs=(

@@ -70,9 +70,7 @@ def ring_attention_shard(
     masks, so local-attention layers ride the same ring — blocks wholly
     outside a query's window contribute only masked (-1e30) scores, which
     the online softmax absorbs."""
-    from vgate_tpu.parallel._compat import axis_size
-
-    sp = axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, S_local, H, hd = q.shape
     if scale is None:
@@ -144,10 +142,8 @@ def ring_prefill_attention(
         0 if window is None else window, jnp.int32
     )
 
-    from vgate_tpu.parallel._compat import shard_map
-
     seq_sharded = P(None, AXIS_SP, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             ring_attention_shard, axis_name=AXIS_SP, softcap=softcap,
             scale=scale,
@@ -155,6 +151,6 @@ def ring_prefill_attention(
         mesh=mesh,
         in_specs=(seq_sharded, seq_sharded, seq_sharded, P(), P()),
         out_specs=seq_sharded,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k, v, seq_lens, window_arr)

@@ -172,18 +172,16 @@ def sp_decode_attention_and_write(
         out = acc_g / jnp.maximum(l_g, 1e-30)[..., None]
         return out.astype(q.dtype), kp, vp
 
-    from vgate_tpu.parallel._compat import shard_map
-
     tp_ax = _tp_axis(mesh, H, k_t.shape[1])
     pool = P(tp_ax, AXIS_SP, None, None)
     heads = P(None, tp_ax, None)  # q [B,H,hd] / k_t,v_t [B,KV,hd]
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(pool, pool, heads, heads, heads, P(), P(), P(), P(),
                   P()),
         out_specs=(heads, pool, pool),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(
         k_pages_l, v_pages_l, q, k_t, v_t, page_ids, page_off,
@@ -370,18 +368,16 @@ def sp_suffix_attention_and_write(
         out = acc_g / jnp.maximum(l_g, 1e-30)[..., None]
         return out.astype(q.dtype), kp, vp
 
-    from vgate_tpu.parallel._compat import shard_map
-
     tp_ax = _tp_axis(mesh, H, KV)
     pool = P(tp_ax, AXIS_SP, None, None)
     heads = P(None, None, tp_ax, None)  # [B,S,H|KV,hd]
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(pool, pool, heads, heads, heads, P(), P(), P(), P(),
                   P()),
         out_specs=(heads, pool, pool),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(
         k_pages_l, v_pages_l, q, k_s, v_s, suffix_page_tables,
@@ -453,18 +449,16 @@ def sp_multitok_attention_and_write(
         out = acc_g / jnp.maximum(l_g, 1e-30)[..., None]
         return out.astype(q.dtype), kp, vp
 
-    from vgate_tpu.parallel._compat import shard_map
-
     tp_ax = _tp_axis(mesh, H, k_t.shape[2])
     pool = P(tp_ax, AXIS_SP, None, None)
     heads = P(None, None, tp_ax, None)  # [B,S,H|KV,hd]
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(pool, pool, heads, heads, heads, P(), P(), P(), P(),
                   P(), P()),
         out_specs=(heads, pool, pool),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(
         k_pages_l, v_pages_l, q, k_t, v_t, page_ids, page_off,

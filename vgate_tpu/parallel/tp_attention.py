@@ -7,8 +7,7 @@ their einsums/gathers carry the head dim through — but a ``pallas_call``
 has NO partitioning rule, so GSPMD falls back to replicating its
 operands: an all-gather of the whole KV page pool per layer per decode
 step, silently erasing tp's point on real multi-chip hardware (never
-visible on the single-chip grant or the CPU dryrun, which runs the jnp
-twins).
+visible on one chip or on CPU meshes, which run the jnp twins).
 
 These wrappers run the kernel per tp shard inside a ``shard_map``:
 each shard holds ``KV/tp`` kv heads of the pool and ``H/tp`` query
@@ -22,6 +21,7 @@ tp; callers fall back to the jnp twin otherwise.  Traced per-layer
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -69,9 +69,7 @@ def tp_paged_decode_attention(
             window=w, layer=l,
         )
 
-    from jax.experimental.shard_map import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -79,7 +77,7 @@ def tp_paged_decode_attention(
             + tuple(P() for _ in extras)
         ),
         out_specs=P(None, AXIS_TP, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k_pages, v_pages, page_tables, seq_lens, *extras)
 
@@ -105,16 +103,14 @@ def tp_flash_prefill_attention(
             return kernel_fn(q, k, v, seq_lens)
         return kernel_fn(q, k, v, seq_lens, window=w)
 
-    from jax.experimental.shard_map import shard_map
-
     heads = P(None, None, AXIS_TP, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(heads, heads, heads, P())
         + tuple(P() for _ in extras),
         out_specs=heads,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k, v, seq_lens, *extras)
 

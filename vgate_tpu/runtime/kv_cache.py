@@ -76,10 +76,6 @@ class KVGeometry:
         return (self.num_pages - self.num_reserved) * self.page_size
 
 
-# Per-chip HBM when the runtime exposes no memory stats (TPU v5e class).
-_DEFAULT_HBM_BYTES = 16 * 1024**3
-
-
 def auto_num_pages(
     spec: ModelSpec,
     page_size: int,
@@ -91,35 +87,45 @@ def auto_num_pages(
     dtype_bytes: int = 2,
     hbm_bytes: int = 0,
     scale_bytes: int = 0,
+    shards: int = 1,
 ) -> int:
     """Size the page pool from free device HBM after weights are resident
     (the serving analogue of vLLM's gpu_memory_utilization knob,
     reference config: vgate/config.py:47).
 
-    When the runtime reports memory stats they are authoritative; otherwise
-    on accelerators we budget against ``hbm_bytes`` (config
-    ``tpu.hbm_bytes``; default 16 GiB/chip, the v5e part) minus the actual
-    parameter bytes, and on CPU test platforms we return ``fallback``.
-    ``dtype_bytes`` is the KV cache element width (fp32 KV needs twice the
-    page budget of bf16); ``scale_bytes`` the per-token-per-head
-    quantization-scale overhead (int8 KV: dtype_bytes=1, scale_bytes=2 —
-    the same budget then yields ~2x the bf16 page count, the capacity
-    half of the roofline lever).
+    The device's own ``memory_stats()["bytes_limit"]`` is the budget.  An
+    accelerator that reports none is budgeted against ``hbm_bytes``
+    (config ``tpu.hbm_bytes``) minus the parameter bytes, and with that
+    unset it is an error — never an assumed chip size.  CPU test
+    platforms return ``fallback``.  The step programs hold weights plus
+    ONE pool (PERF.md "Bring-up", memory_analysis table), so what
+    ``hbm_utilization`` leaves over is head-room for their temporaries
+    only.  ``dtype_bytes`` is the KV cache element width (fp32 KV needs
+    twice the page budget of bf16); ``scale_bytes`` the per-token-per-
+    head quantization-scale overhead (int8 KV: dtype_bytes=1,
+    scale_bytes=2 — the same budget then yields ~2x the bf16 page
+    count).  ``shards`` is how many ways the mesh splits each page
+    (layers over pp, kv heads over tp): a chip stores 1/shards of it.
     """
     device = device or jax.devices()[0]
     stats = getattr(device, "memory_stats", lambda: None)()
     page_bytes = _page_bytes(
         spec.num_layers, page_size, spec.num_kv_heads, spec.head_dim,
         dtype_bytes, scale_bytes,
-    )
+    ) // max(1, shards)
     if stats and "bytes_limit" in stats:
         limit = stats["bytes_limit"] * hbm_utilization
         free = max(0, limit - stats.get("bytes_in_use", 0))
-    elif device.platform != "cpu":
-        budget = hbm_bytes or _DEFAULT_HBM_BYTES
-        free = max(0, budget * hbm_utilization - params_bytes)
-    else:
+    elif device.platform == "cpu":
         return fallback
+    elif hbm_bytes:
+        free = max(0, hbm_bytes * hbm_utilization - params_bytes)
+    else:
+        raise RuntimeError(
+            f"{device.device_kind} reports no memory_stats()['bytes_limit'] "
+            "to size the KV pool from; set tpu.kv_num_pages, or "
+            "tpu.hbm_bytes to this part's per-chip HBM"
+        )
     pages = int(free // page_bytes)
     return max(16, min(pages, hard_cap))
 
@@ -316,7 +322,10 @@ class PageAllocator:
 
 
 def make_kv_buffers(geometry: KVGeometry, dtype=jnp.bfloat16, sharding=None):
-    """Allocate the K/V page pools (zeros) directly on device.
+    """Allocate the K/V page pools (zeros) directly on device, each chip
+    of a mesh creating only its own shard (``device=sharding``): a global
+    pool drawn on the default device and spread afterwards does not fit
+    the one chip it is drawn on.
 
     With ``geometry.kv_dtype == "int8"`` each pool is a
     :class:`~vgate_tpu.ops.kv_quant.QuantPages` pair — int8 data plus
@@ -336,9 +345,6 @@ def make_kv_buffers(geometry: KVGeometry, dtype=jnp.bfloat16, sharding=None):
         geometry.head_dim,
     )
 
-    def _place(arr, shard):
-        return arr if shard is None else jax.device_put(arr, shard)
-
     if geometry.kv_dtype == "int8":
         scale_sharding = None
         if sharding is not None and hasattr(sharding, "spec"):
@@ -353,16 +359,16 @@ def make_kv_buffers(geometry: KVGeometry, dtype=jnp.bfloat16, sharding=None):
 
         def pool():
             return QuantPages(
-                data=_place(jnp.zeros(shape, jnp.int8), sharding),
-                scale=_place(
-                    jnp.ones(shape[:-1], SCALE_DTYPE), scale_sharding
+                data=jnp.zeros(shape, jnp.int8, device=sharding),
+                scale=jnp.ones(
+                    shape[:-1], SCALE_DTYPE, device=scale_sharding
                 ),
             )
 
         k, v = pool(), pool()
     else:
-        k = _place(jnp.zeros(shape, dtype), sharding)
-        v = _place(jnp.zeros(shape, dtype), sharding)
+        k = jnp.zeros(shape, dtype, device=sharding)
+        v = jnp.zeros(shape, dtype, device=sharding)
     pool_bytes = 2 * sum(
         x.size * x.dtype.itemsize for x in jax.tree.leaves(k)
     )

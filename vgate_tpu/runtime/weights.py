@@ -216,12 +216,17 @@ def load_or_init_params(
     dtype=jnp.bfloat16,
     seed: int = 0,
     log_digests: bool = False,
+    mesh=None,
 ) -> Params:
     """Checkpoint when available, random init otherwise (zero-egress path).
 
     ``log_digests`` (integrity.enabled callers) logs the per-shard
     load-time digest summary — one full host pass over the tree, paid
-    once at load."""
+    once at load.  With a multi-device ``mesh`` the random init runs
+    under jit with the serving shardings as ``out_shardings``, so every
+    chip draws only its own shard: drawn eagerly, a 7B tree (one gate
+    tensor alone is 7.6 GB in float32) lands whole on the default device
+    and runs it out of memory before ``shard_params`` can spread it."""
     from vgate_tpu import faults
 
     faults.check("weight_load", payload=checkpoint_path)
@@ -236,7 +241,17 @@ def load_or_init_params(
                 "extra_data": {"model": spec.name, "path": checkpoint_path}
             },
         )
-        params = init_params(spec, jax.random.PRNGKey(seed), dtype)
+        key = jax.random.PRNGKey(seed)
+        if mesh is not None and mesh.devices.size > 1:
+            from vgate_tpu.parallel.sharding import named, param_pspecs
+
+            params = jax.jit(
+                init_params,
+                static_argnums=(0, 2),
+                out_shardings=named(mesh, param_pspecs(spec, mesh)),
+            )(spec, key, dtype)
+        else:
+            params = init_params(spec, key, dtype)
     if log_digests:
         try:
             logger.info(

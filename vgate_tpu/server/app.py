@@ -27,7 +27,12 @@ from pydantic import ValidationError
 from vgate_tpu import metrics
 from vgate_tpu.admission import tier_rank
 from vgate_tpu.batcher import RequestBatcher
-from vgate_tpu.config import VGTConfig, apply_platform, get_config
+from vgate_tpu.config import (
+    VGTConfig,
+    apply_compile_cache,
+    apply_platform,
+    get_config,
+)
 from vgate_tpu.engine import VGTEngine
 from vgate_tpu.errors import (
     ClientDisconnectError,
@@ -92,13 +97,6 @@ VGT_OBLIGATIONS = {
         "release": ("release_slot",),
     },
 }
-
-# asyncio.timeout is 3.11+; aiohttp's async_timeout dependency is the
-# same context manager for the 3.10 interpreters this serves on
-if hasattr(asyncio, "timeout"):  # pragma: no cover - py3.11+ images
-    _timeout_ctx = asyncio.timeout
-else:
-    from async_timeout import timeout as _timeout_ctx
 
 _QUIET_PATHS = {"/health", "/health/live", "/health/ready", "/metrics"}
 # excluded from the drain's in-flight count: probes/scrapes (and /stats
@@ -1014,7 +1012,7 @@ async def _stream_chat(
                     request_id=request.get("request_id"),
                     trace_ctx=capture_context(),
                 )
-            async with _timeout_ctx(timeout_s):
+            async with asyncio.timeout(timeout_s):
                 async for piece in stream_fn(prompt, params, **kwargs):
                     if isinstance(piece, dict):  # logprobs-carrying delta
                         await resp.write(
@@ -1029,10 +1027,7 @@ async def _stream_chat(
                 batcher.admission.observe_completion(
                     usage_box["value"].get("completion_tokens", 0)
                 )
-        # both spellings: on py3.10 the async_timeout shim raises
-        # asyncio.TimeoutError, which is NOT the builtin TimeoutError
-        # there (they merged in 3.11)
-        except (TimeoutError, asyncio.TimeoutError):
+        except TimeoutError:
             await resp.write(
                 b'data: {"error": {"message": "request timed out", '
                 b'"type": "timeout_error"}}\n\n'
@@ -2294,10 +2289,10 @@ async def _on_startup(app: web.Application) -> None:
     config: VGTConfig = app["config"]
     app["profile_lock"] = asyncio.Lock()
     init_tracing(config)
-    # pin the JAX platform before the first device touch (some TPU plugins
-    # override the JAX_PLATFORMS env var, so the config knob is the only
-    # reliable CPU/dry-run switch)
+    # pin the JAX platform and place the compile cache before the first
+    # device touch
     apply_platform(config.tpu)
+    apply_compile_cache()
     loop = asyncio.get_running_loop()
     # Model load can take minutes; do it off the event loop.
     engine = await loop.run_in_executor(None, lambda: VGTEngine(config))
