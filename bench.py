@@ -97,7 +97,6 @@ def _run_kv_quant_scenario(
 
     import jax
 
-    from vgate_tpu import metrics as vgt_metrics
     from vgate_tpu.backends.base import SamplingParams
     from vgate_tpu.runtime.engine_core import EngineCore
 
@@ -235,8 +234,6 @@ def _run_kv_quant_scenario(
     # stream stayed identical — the longest stream fully verified (a
     # lower bound, not a divergence)
     horizon = min(diverged_at) if diverged_at else compared
-    if diverged_tokens:
-        vgt_metrics.KV_QUANT_DRIFT_TOKENS.inc(diverged_tokens)
     oracle, int8 = arms["oracle"], arms["int8"]
     print(json.dumps({
         "scenario": "kv_quant",

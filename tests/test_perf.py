@@ -12,6 +12,7 @@ tolerance and whose ledger counts each variant's first compile exactly
 once.
 """
 
+import os
 import time
 
 import pytest
@@ -491,6 +492,14 @@ def test_engine_phase_attribution_sums_and_ledger_counts_once():
     try:
         params = [SamplingParams(max_tokens=8, temperature=0.0)] * 2
         core.generate(["perf probe one", "perf probe two"], params)
+        # generate() returns from inside the last tick's emit bracket;
+        # that tick's tokens reach the totals at its tick_end
+        deadline = time.monotonic() + 5.0
+        while (
+            core.perf_snapshot()["totals"]["tokens"] < 16
+            and time.monotonic() < deadline
+        ):
+            time.sleep(0.01)
         snap = core.perf_snapshot()
         assert snap["enabled"] is True
         totals = snap["totals"]
@@ -561,5 +570,488 @@ def test_engine_decode_window_reports_live_throughput():
         assert win["tokens"] >= 24
         assert win["tokens_per_s"] > 0
         assert win["decode_steps"] > 0
+    finally:
+        core.stop()
+
+
+# ------------------------------ ISSUE 24: one bracket, two sinks
+
+
+@pytest.mark.parametrize("span, phase", sorted(perf_mod.SPAN_PHASE.items()))
+def test_bracket_accrues_exactly_what_phase_did(span, phase):
+    """The bracket on the recorder's clock books the same seconds into
+    the same phase as the old ``phase(name, seconds)`` call."""
+    clock = FakeClock()
+    old, new = recorder(clock=clock), recorder(clock=clock)
+    old.tick_begin()
+    new.tick_begin()
+    with new.span(span) as bracket:
+        clock.advance(0.0125)
+    old.phase(phase, 0.0125)
+    clock.advance(0.004)
+    old.tick_end(worked=True)
+    new.tick_end(worked=True)
+    assert bracket.seconds == pytest.approx(0.0125)
+    assert new.totals()["phase_seconds"] == pytest.approx(
+        old.totals()["phase_seconds"]
+    )
+    assert new.totals()["phase_seconds"][phase] == pytest.approx(0.0125)
+    assert new.totals()["wall_s"] == pytest.approx(0.0165)
+
+
+def test_old_phases_plus_schedule_and_state_sum_to_the_tick_wall():
+    clock = FakeClock()
+    rec = recorder(clock=clock)
+    rec.tick_begin()
+    for span, seconds in (
+        ("schedule", 0.002), ("state", 0.003), ("decode_dispatch", 0.010),
+        ("prefill_dispatch", 0.005), ("device_wait", 0.030),
+        ("readback", 0.004), ("emit", 0.006),
+    ):
+        with rec.span(span):
+            clock.advance(seconds)
+    clock.advance(0.040)  # what no bracket covers
+    rec.tick_end(worked=True)
+    phases = rec.totals()["phase_seconds"]
+    assert set(phases) == set(PHASES)
+    assert phases["dispatch"] == pytest.approx(0.015)
+    assert phases["schedule"] == pytest.approx(0.002)
+    assert phases["state"] == pytest.approx(0.003)
+    assert phases["host"] == pytest.approx(0.040)
+    assert sum(phases.values()) == pytest.approx(rec.totals()["wall_s"])
+    # idle_wait annotates only: outside a tick it books nothing
+    with rec.span("idle_wait"):
+        clock.advance(1.0)
+    assert rec.totals()["wall_s"] == pytest.approx(0.100)
+
+
+def test_an_idle_poll_with_a_schedule_bracket_is_still_idle():
+    clock = FakeClock()
+    rec = recorder(clock=clock)
+    rec.tick_begin()
+    with rec.span("schedule"):
+        clock.advance(0.0001)
+    rec.tick_end(worked=False)
+    assert rec.totals()["ticks"] == 0 and rec.totals()["idle_ticks"] == 1
+
+
+def _numbers(totals, prefix=""):
+    out = {}
+    for key, value in totals.items():
+        if isinstance(value, dict):
+            out.update(_numbers(value, f"{prefix}{key}."))
+        elif isinstance(value, (int, float)):
+            out[prefix + key] = value
+    return out
+
+
+NEW_TOTALS = (
+    "chunks_by_steps.1", "chunks_by_steps.8", "decode_device_s",
+    "decode_ctx_token_steps", "engine_cpu_s",
+    "engine_cpu_in_wait_s", "admitted", "queue_wait_s", "first_tokens",
+    "prefill_s",
+)
+
+
+def test_new_totals_are_monotone_and_survive_the_dp_merge(monkeypatch):
+    from vgate_tpu.observability.flight import FlightRecorder
+
+    monkeypatch.setattr(perf_mod, "BOOT_SECONDS", {"weights": 3.0})
+    clock = FakeClock()
+    rec = recorder(clock=clock)
+    flight = FlightRecorder(ObservabilityConfig())
+    rec.request_totals = flight.phase_totals
+
+    class Seq:
+        seq_id, request_id, trace, preempt_count = 1, "r", None, 0
+        num_prompt_tokens = 5
+        arrival_t = time.perf_counter() - 0.25
+
+        class params:
+            timeout_s = None
+
+    snaps = [rec.snapshot()]
+    for steps in (8, 1, 8):
+        rec.tick_begin()
+        with rec.span("device_wait") as wait:
+            clock.advance(0.010)
+        with rec.span("readback"):
+            clock.advance(0.001)
+        rec.note_decode(
+            steps=steps, ctx_tokens=1000, device_s=wait.seconds
+        )
+        clock.advance(0.002)
+        rec.tick_end(worked=True)
+        if steps == 1:
+            flight.on_admit(Seq, bucket=8)
+            flight.on_first_token(Seq)
+        snaps.append(rec.snapshot())
+    series = [_numbers(s["totals"]) for s in snaps]
+    for key in NEW_TOTALS:
+        # a chunk length appears with its first chunk
+        values = [s.get(key, 0) for s in series]
+        assert values == sorted(values), key
+    last = series[-1]
+    assert last["chunks_by_steps.8"] == 2 and last["chunks_by_steps.1"] == 1
+    assert last["decode_device_s"] == pytest.approx(0.030)
+    assert last["decode_ctx_token_steps"] == 17 * 1000
+    assert last["admitted"] == 1 and last["first_tokens"] == 1
+    assert last["queue_wait_s"] == pytest.approx(0.25, abs=0.05)
+    assert 0 <= last["engine_cpu_in_wait_s"] <= last["engine_cpu_s"]
+    assert snaps[-1]["totals"]["boot_seconds"] == {"weights": 3.0}
+
+    merged = perf_mod.merge_snapshots([snaps[-1], snaps[-1]])["totals"]
+    both = _numbers(merged)
+    for key in NEW_TOTALS:
+        assert both[key] == pytest.approx(2 * last[key]), key
+    assert merged["boot_seconds"] == {"weights": 3.0}  # one process
+
+
+def test_chunk_lengths_are_whatever_the_engine_ran():
+    """No fixed ladder: tpu.decode_chunk is configurable, so a 16-step
+    chunk counts; a spec-verify pass is steps, not a chunk; the dp merge
+    runs over the union of the replicas' lengths."""
+    rec = recorder()
+    rec.tick_begin()
+    rec.note_decode(steps=16, ctx_tokens=10, device_s=0.01)
+    rec.note_decode(steps=16, ctx_tokens=10, device_s=0.01)
+    rec.note_decode(steps=1, ctx_tokens=10, device_s=0.01, chunk=False)
+    rec.tick_end(worked=True)
+    totals = rec.snapshot()["totals"]
+    assert totals["chunks_by_steps"] == {"16": 2}
+    assert totals["decode_steps"] == 33
+    assert totals["decode_ctx_token_steps"] == 330
+    other = recorder()
+    other.tick_begin()
+    other.note_decode(steps=4, ctx_tokens=10, device_s=0.01)
+    other.note_decode(steps=16, ctx_tokens=10, device_s=0.01)
+    other.tick_end(worked=True)
+    merged = perf_mod.merge_snapshots([rec.snapshot(), other.snapshot()])
+    assert merged["totals"]["chunks_by_steps"] == {"4": 1, "16": 3}
+
+
+def test_host_overhead_ratio_counts_schedule_and_state_as_host():
+    """The gauge behind VgtHostOverheadHigh keeps the meaning it had
+    before schedule/state got brackets of their own: the engine's own
+    Python outside the jitted call and the device, over the wall."""
+    clock = FakeClock()
+    bracketed = recorder(clock=clock)
+    plain = recorder(clock=clock)  # the parent: the same work unbracketed
+    for rec, spans in ((bracketed, True), (plain, False)):
+        rec.tick_begin()
+        for name, seconds in (("schedule", 0.020), ("state", 0.010)):
+            if spans:
+                with rec.span(name):
+                    clock.advance(seconds)
+            else:
+                clock.advance(seconds)
+        with rec.span("decode_dispatch"):
+            clock.advance(0.010)
+        with rec.span("device_wait"):
+            clock.advance(0.050)
+        clock.advance(0.010)
+        rec.tick_end(worked=True)
+    assert bracketed.totals()["phase_seconds"]["host"] == pytest.approx(0.010)
+    assert plain.totals()["phase_seconds"]["host"] == pytest.approx(0.040)
+    ratio = bracketed.window()["host_overhead_ratio"]
+    assert ratio == pytest.approx(0.4)
+    assert ratio == plain.window()["host_overhead_ratio"]
+    assert bracketed.get_stats()["host_overhead_ratio"] == ratio
+
+
+def test_no_annotation_is_built_without_a_capture(monkeypatch):
+    """Off = one flag test: nothing is constructed, the span arguments
+    are never evaluated; on, every bracket lands in the trace."""
+    opened = []
+
+    class Ann:
+        def __exit__(self, *exc):
+            opened.append("closed")
+
+        def set_metadata(self, **args):
+            opened.append(args)
+
+    def fake_open(name, args):
+        opened.append((name, args() if args is not None else {}))
+        return Ann()
+
+    monkeypatch.setattr(perf_mod, "_open_annotation", fake_open)
+    boom = lambda: (_ for _ in ()).throw(AssertionError("args evaluated"))
+    rec = recorder()
+    gateway = perf_mod.GatewayPerf(clock=FakeClock())
+    assert perf_mod.capturing() is False
+    rec.tick_begin()
+    with rec.span("decode_dispatch", boom) as b:
+        b.note(tokens=3)
+    rec.tick_end(worked=True)
+    gateway.ingress_begin()
+    gateway.ingress_end()
+    gateway.detok_end(gateway.detok_begin(gateway.stream_clock(), 1))
+    gateway.write_end(gateway.write_begin())
+    assert opened == []
+
+    perf_mod.set_capturing(True)
+    try:
+        rec.tick_begin()
+        with rec.span("emit", lambda: {"rows": 2}) as b:
+            b.note(tokens=3)
+        rec.tick_end(worked=True)
+        gateway.ingress_begin()
+        gateway.ingress_end()
+        # while a capture runs every token is timed, not one in eight
+        gateway.detok_end(gateway.detok_begin(gateway.stream_clock(), 2))
+        gateway.write_end(gateway.write_begin())
+    finally:
+        perf_mod.set_capturing(False)
+    names = [o[0] for o in opened if isinstance(o, tuple)]
+    assert names == ["vgt.engine.tick", "vgt.engine.emit",
+                     "vgt.gateway.ingress", "vgt.gateway.stream_detok",
+                     "vgt.gateway.sse_write"]
+    assert ("vgt.engine.emit", {"rows": 2}) in opened
+    assert {"tokens": 3} in opened
+    assert opened.count("closed") == 5
+
+
+def test_thread_time_is_read_per_tick_and_per_wait_never_per_token():
+    """Acceptance: no per-token thread_time call exists."""
+    import inspect
+
+    from vgate_tpu.backends import jax_backend
+    from vgate_tpu.runtime import engine_core, sequence
+
+    for module in (engine_core, jax_backend, sequence):
+        assert "thread_time" not in inspect.getsource(module)
+    source = inspect.getsource(perf_mod)
+    assert source.count("time.thread_time()") == 4  # tick x2, wait x2
+
+
+def test_gateway_counters_follow_one_stream():
+    clock = FakeClock()
+    gateway = perf_mod.GatewayPerf(clock=clock)
+    gateway.ingress_begin()
+    clock.advance(0.020)
+    stream = gateway.stream_clock()
+    gateway.ingress_end()
+    gateway.ingress_end()  # closing twice counts once
+    def token(n, detok_s=0.0001, write_s=0.001):
+        """The stream's n-th token through decode and its SSE write;
+        True when it was one of the timed ones."""
+        t0 = gateway.detok_begin(stream, n)
+        clock.advance(detok_s)
+        if t0 is not None:
+            gateway.detok_end(t0)
+        t1 = gateway.write_begin()
+        clock.advance(write_s)
+        if t1 is not None:
+            gateway.write_end(t1)
+        return t0 is not None and t1 is not None
+
+    stream.t_first_token = clock()  # the engine thread's stamp
+    clock.advance(0.004)
+    timed = [n for n in range(1, 20) if token(n)]
+    # one token in SAMPLE_EVERY is timed, the stream's first among them
+    assert timed == [1, 9, 17] and perf_mod.SAMPLE_EVERY == 8
+    totals = gateway.totals()
+    assert totals["ingress_n"] == 1
+    assert totals["ingress_s"] == pytest.approx(0.020)
+    assert totals["stream_tokens"] == 3
+    assert totals["stream_detok_s"] == pytest.approx(0.0003)
+    assert totals["stream_write_s"] == pytest.approx(0.003)
+    assert totals["first_chunk_n"] == 1  # the first chunk on the wire
+    assert totals["first_chunk_s"] == pytest.approx(0.0051)
+    gateway.ingress_close()
+    assert gateway.stream_clock() is None
+    off = perf_mod.GatewayPerf(clock=clock)
+    off.enabled = False
+    off.ingress_begin()  # observability off: the stream gets no clock
+    off.ingress_end()
+    assert off.stream_clock() is None
+    assert off.detok_begin(off.stream_clock(), 1) is None
+    assert off.write_begin() is None
+    assert set(off.totals().values()) == {0, 0.0}
+
+
+def test_first_chunk_is_timed_when_the_first_token_writes_nothing():
+    """A stop-string hold-back (or half a UTF-8 piece) yields no text
+    for the stream's first tokens: every token is timed until the first
+    chunk is on the wire, so first_chunk_s ends at THAT write and not
+    at the next sampled token."""
+    clock = FakeClock()
+    gateway = perf_mod.GatewayPerf(clock=clock)
+    gateway.ingress_begin()
+    stream = gateway.stream_clock()
+    gateway.ingress_end()
+    stream.t_first_token = clock()
+    for n in (1, 2):  # decoded, held back: no write
+        t0 = gateway.detok_begin(stream, n)
+        assert t0 is not None
+        clock.advance(0.001)
+        gateway.detok_end(t0)
+        assert gateway.totals()["first_chunk_n"] == 0
+    t0 = gateway.detok_begin(stream, 3)  # 3 % 8 != 1, timed all the same
+    assert t0 is not None
+    clock.advance(0.001)
+    gateway.detok_end(t0)
+    t1 = gateway.write_begin()
+    clock.advance(0.002)
+    gateway.write_end(t1)
+    totals = gateway.totals()
+    assert totals["first_chunk_n"] == 1
+    assert totals["first_chunk_s"] == pytest.approx(0.005)
+    # after the first chunk the one-in-eight sampling is back
+    assert gateway.detok_begin(stream, 4) is None
+    assert gateway.write_begin() is None
+    assert gateway.detok_begin(stream, 9) is not None
+
+
+def test_a_request_that_is_not_streamed_leaves_no_annotation_open(
+    monkeypatch,
+):
+    """ingress_begin runs for every chat request, ingress_end only in
+    stream_async: the handler's finally closes what is still open."""
+    events = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __exit__(self, *exc):
+            events.append(("close", self.name))
+
+    def fake_open(name, args):
+        events.append(("open", name))
+        return Ann(name)
+
+    monkeypatch.setattr(perf_mod, "_open_annotation", fake_open)
+    gateway = perf_mod.GatewayPerf(clock=FakeClock())
+    perf_mod.set_capturing(True)
+    try:
+        gateway.ingress_begin()  # a non-stream or rejected (422) request
+        gateway.ingress_close()
+        assert events == [("open", "vgt.gateway.ingress"),
+                          ("close", "vgt.gateway.ingress")]
+        assert gateway.stream_clock() is None
+        assert gateway.totals()["ingress_n"] == 0
+        gateway.ingress_close()  # nothing open: nothing to do
+        # a write cut by a cancel is closed on the way out too
+        del events[:]
+        gateway.ingress_begin()
+        gateway.ingress_end()
+        gateway.detok_end(gateway.detok_begin(gateway.stream_clock(), 1))
+        gateway.write_begin()
+        gateway.ingress_close()
+    finally:
+        perf_mod.set_capturing(False)
+    assert [e for e in events if e[0] == "open"] == [
+        ("open", "vgt.gateway.ingress"),
+        ("open", "vgt.gateway.stream_detok"),
+        ("open", "vgt.gateway.sse_write"),
+    ]
+    assert len([e for e in events if e[0] == "close"]) == 3
+
+
+async def test_the_chat_handler_closes_its_ingress_annotation(monkeypatch):
+    """Through the real handler, while a capture runs: a non-streamed
+    chat and a rejected one (422) each open vgt.gateway.ingress at
+    entry and leave it closed."""
+    from vgate_tpu.server import app as app_mod
+
+    events = []
+
+    class Ann:
+        def __exit__(self, *exc):
+            events.append("close")
+
+    def fake_open(name, args):
+        events.append(name)
+        return Ann()
+
+    monkeypatch.setattr(perf_mod, "_open_annotation", fake_open)
+    monkeypatch.setattr(app_mod.GATEWAY, "enabled", True)
+    client = await _client()
+    perf_mod.set_capturing(True)
+    try:
+        ok = await client.post("/v1/chat/completions", json={
+            "model": "m", "max_tokens": 4,
+            "messages": [{"role": "user", "content": "hi"}],
+        })
+        assert ok.status == 200
+        bad = await client.post(
+            "/v1/chat/completions", json={"messages": []}
+        )
+        assert bad.status == 422
+    finally:
+        perf_mod.set_capturing(False)
+        await client.close()
+    assert events == ["vgt.gateway.ingress", "close"] * 2
+
+
+def _trace_names(trace_dir):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = max(
+        glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                  recursive=True),
+        key=os.path.getmtime,
+    )
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            lines.append([ev.name for ev in line.events])
+    return lines
+
+
+def test_capture_profile_is_quiet_by_default_and_frames_on_request(tmp_path):
+    """A default capture holds the engine's tick and its children and
+    not one Python frame; ``python_tracer`` brings the frames back."""
+    import re
+    import threading
+
+    from vgate_tpu.backends.base import SamplingParams
+    from vgate_tpu.runtime.engine_core import EngineCore
+
+    frame = re.compile(r"\.py:\d+")
+    core = EngineCore(_engine_config())
+    core.start()
+    try:
+        params = [SamplingParams(max_tokens=24, min_tokens=24,
+                                 temperature=0.0)]
+        # same length, different first page: every probe runs the
+        # programs this one compiles, and none hits the prefix cache
+        core.generate(["0 probe"], params)
+        results = {}
+
+        def load(prompt):
+            time.sleep(0.15)  # let the capture begin first
+            core.generate([prompt], params)
+
+        for i, (key, tracer) in enumerate(
+            (("quiet", False), ("frames", True)), start=1
+        ):
+            thread = threading.Thread(target=load, args=(f"{i} probe",))
+            thread.start()
+            results[key] = core.capture_profile(
+                0.6, str(tmp_path / key), python_tracer=tracer
+            )
+            thread.join()
+        assert perf_mod.capturing() is False
+        quiet = results["quiet"]
+        assert quiet["python_tracer"] is False and quiet["files"] >= 1
+        assert quiet["file_bytes"] > 0 and quiet["stop_s"] >= 0
+        lines = _trace_names(quiet["trace_dir"])
+        engine = max(lines, key=lambda n: n.count("vgt.engine.tick"))
+        assert engine.count("vgt.engine.tick") >= 2
+        for child in ("schedule", "decode_dispatch", "device_wait",
+                      "readback", "emit"):
+            assert f"vgt.engine.{child}" in engine, child
+        assert not [n for names in lines for n in names if frame.search(n)]
+        frames = _trace_names(results["frames"]["trace_dir"])
+        assert results["frames"]["python_tracer"] is True
+        assert [n for names in frames for n in names if frame.search(n)]
+        assert any("vgt.engine.tick" in names for names in frames)
+        assert (results["frames"]["file_bytes"]
+                > results["quiet"]["file_bytes"])
     finally:
         core.stop()

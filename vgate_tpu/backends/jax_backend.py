@@ -17,6 +17,7 @@ import contextlib
 import functools
 import os
 import threading
+import time
 from typing import Any, AsyncIterator, Dict, List, Optional, Sequence
 
 import jax
@@ -29,6 +30,7 @@ from vgate_tpu.config import get_config
 from vgate_tpu.errors import state_is_alive, state_is_ready
 from vgate_tpu.logging_config import get_logger
 from vgate_tpu.models.specs import ModelSpec, spec_for_model_id
+from vgate_tpu.observability.perf import GATEWAY
 from vgate_tpu.runtime.engine_core import EngineCore
 from vgate_tpu.runtime.sequence import SeqStatus
 from vgate_tpu.utils.math import bucket_for, round_up
@@ -376,7 +378,13 @@ class JaxTPUBackend:
         loop = asyncio.get_running_loop()
         q: "asyncio.Queue[Optional[int]]" = asyncio.Queue()
 
+        clock = GATEWAY.stream_clock()  # None outside the gateway
+
         def on_token(token: int) -> None:
+            if clock is not None and clock.t_first_token is None:
+                # engine thread: first token handed to the gateway
+                # (gateway.first_chunk_* measures from here to the wire)
+                clock.t_first_token = time.perf_counter()
             try:
                 loop.call_soon_threadsafe(q.put_nowait, token)
             except RuntimeError:
@@ -385,6 +393,7 @@ class JaxTPUBackend:
         seq = self.core.submit_prompt(
             prompt, params, stream_cb=on_token, meta=request_meta
         )
+        GATEWAY.ingress_end()
 
         def on_done() -> None:
             seq.done_event.wait()
@@ -424,7 +433,10 @@ class JaxTPUBackend:
                 if params.logprobs and len(seq.logprob_data) >= len(ids):
                     lp, top = seq.logprob_data[len(ids) - 1]
                     pending_lp.append(self.core.lp_entry(token, lp, top))
+                t_detok = GATEWAY.detok_begin(clock, len(ids))
                 text = self.core.tokenizer.decode(ids)
+                if t_detok is not None:
+                    GATEWAY.detok_end(t_detok)
                 if stops:
                     cut = min(
                         (

@@ -675,9 +675,6 @@ class RequestBatcher:
             return
         with tracer.start_as_current_span("batcher.process_batch") as span:
             start = time.perf_counter()
-            now = start
-            for req in batch:
-                metrics.QUEUE_TIME.observe(now - req.enqueued_at)
             # In-batch dedup: group by cache key (reference: batcher.py:236-266).
             groups: Dict[str, List[BatchRequest]] = {}
             for req in batch:
@@ -703,7 +700,6 @@ class RequestBatcher:
             metrics.DEDUP_RATIO.set(n_duplicates / len(batch))
             metrics.BATCH_SIZE.observe(len(batch))
             metrics.UNIQUE_PROMPTS.observe(len(unique))
-            metrics.BATCHES_TOTAL.inc()
             self._total_batches += 1
             span.set_attribute("batch.size", len(batch))
             span.set_attribute("batch.unique", len(unique))
@@ -711,9 +707,6 @@ class RequestBatcher:
             try:
                 results = await self._run_batch_inference(unique, groups)
             except Exception as exc:  # fail the whole batch (batcher.py:310-324)
-                metrics.INFERENCE_ERRORS.labels(
-                    error_type=type(exc).__name__
-                ).inc()
                 logger.error(
                     "batch inference failed",
                     extra={"extra_data": {"batch_size": len(batch)}},
@@ -730,9 +723,6 @@ class RequestBatcher:
                 if isinstance(result, BaseException):
                     # settled path: only THIS group failed (e.g. deadline
                     # shed); its neighbours keep their completions
-                    metrics.INFERENCE_ERRORS.labels(
-                        error_type=type(result).__name__
-                    ).inc()
                     for req in groups[lead.cache_key]:
                         if not req.future.done():
                             req.future.set_exception(result)

@@ -83,13 +83,10 @@ class ResultCache:
                 while len(self._store) > self.max_size:
                     self._store.popitem(last=False)
                     self._evictions += 1
-                    metrics.CACHE_EVICTIONS.inc()
-                metrics.CACHE_SIZE.set(len(self._store))
 
     async def clear(self) -> None:
         async with self._lock:
             self._store.clear()
-            metrics.CACHE_SIZE.set(0)
 
     def get_stats(self) -> Dict[str, Any]:
         total = self._hits + self._misses

@@ -54,9 +54,6 @@ REQUEST_LATENCY = _safe_metric(
     labelnames=("method", "endpoint"),
     buckets=(0.001, 0.005, 0.01, 0.05, 0.1, 0.25, 0.5, 1, 2.5, 5, 10, 30, 60),
 )
-REQUESTS_IN_PROGRESS = _safe_metric(
-    Gauge, "vgt_requests_in_progress", "In-flight HTTP requests"
-)
 
 # --- batching metrics (reference: vgate/metrics.py:83-114) ---
 BATCH_SIZE = _safe_metric(
@@ -71,16 +68,9 @@ BATCH_PROCESSING_TIME = _safe_metric(
     "Wall time to process one batch",
     buckets=(0.01, 0.05, 0.1, 0.25, 0.5, 1, 2.5, 5, 10, 30, 60),
 )
-QUEUE_TIME = _safe_metric(
-    Histogram,
-    "vgt_queue_time_seconds",
-    "Time a request waited in the batch queue",
-    buckets=(0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1),
-)
 PENDING_REQUESTS = _safe_metric(
     Gauge, "vgt_pending_requests", "Requests waiting in the batch queue"
 )
-BATCHES_TOTAL = _safe_metric(Counter, "vgt_batches", "Batches processed")
 
 # --- inference metrics (reference: vgate/metrics.py:120-152) ---
 TTFT = _safe_metric(
@@ -101,12 +91,6 @@ GENERATED_TOKENS = _safe_metric(
 PROMPT_TOKENS = _safe_metric(
     Counter, "vgt_prompt_tokens", "Prompt tokens processed"
 )
-INFERENCE_ERRORS = _safe_metric(
-    Counter,
-    "vgt_inference_errors",
-    "Inference failures",
-    labelnames=("error_type",),
-)
 UNIQUE_PROMPTS = _safe_metric(
     Histogram,
     "vgt_unique_prompts_per_batch",
@@ -117,10 +101,6 @@ UNIQUE_PROMPTS = _safe_metric(
 # --- cache metrics (reference: vgate/metrics.py:158-180) ---
 CACHE_HITS = _safe_metric(Counter, "vgt_cache_hits", "Result-cache hits")
 CACHE_MISSES = _safe_metric(Counter, "vgt_cache_misses", "Result-cache misses")
-CACHE_SIZE = _safe_metric(Gauge, "vgt_cache_size", "Entries in result cache")
-CACHE_EVICTIONS = _safe_metric(
-    Counter, "vgt_cache_evictions", "Result-cache LRU evictions"
-)
 
 # --- dedup metrics (reference: vgate/metrics.py:186-196) ---
 DEDUP_REQUESTS = _safe_metric(
@@ -157,13 +137,6 @@ KV_QUANTIZED_PAGES = _safe_metric(
     "vgt_kv_quantized_pages",
     "KV pages currently holding int8-quantized content (equals pages "
     "in use under kv_cache.dtype=int8, 0 otherwise)",
-)
-KV_QUANT_DRIFT_TOKENS = _safe_metric(
-    Counter,
-    "vgt_kv_quant_drift_tokens",
-    "Greedy tokens that diverged from the full-precision KV oracle in "
-    "the kv_quant A/B (bench.py VGT_BENCH_SCENARIO=kv_quant; counts "
-    "tokens past the first divergence across compared streams)",
 )
 ACTIVE_SEQUENCES = _safe_metric(
     Gauge, "vgt_active_sequences", "Sequences resident in decode slots"

@@ -101,6 +101,12 @@ def init_params(
     return params
 
 
+# jax.named_scope below is HLO metadata only: the device trace's op
+# names then say which block of a layer a fusion belongs to (embed /
+# qkv / kv_write / attention / o_proj / mlp / logits; the step programs
+# add "sample").  Nothing runs for it.
+
+@jax.named_scope("qkv_proj")
 def _project_qkv(x, lp, spec: ModelSpec):
     """x: [..., D] -> q [..., H, hd], k/v [..., KV, hd]."""
     ik, i8 = spec.quant_kernel, spec.int8_native
@@ -241,10 +247,12 @@ def _moe_mlp(x, lp, spec: ModelSpec, capacity_factor: float = 2.0):
     return out.reshape(orig_shape)
 
 
+@jax.named_scope("mlp")
 def _mlp(x, lp, spec: ModelSpec):
     return _moe_mlp(x, lp, spec) if spec.is_moe else _dense_mlp(x, lp, spec)
 
 
+@jax.named_scope("logits")
 def _logits(params: Params, spec: ModelSpec, x: jnp.ndarray) -> jnp.ndarray:
     from vgate_tpu.ops.attention import _softcap
 
@@ -276,6 +284,7 @@ def _query_scale(spec: ModelSpec):
     return spec.query_scale ** -0.5 if spec.query_scale > 0 else None
 
 
+@jax.named_scope("embed")
 def _embed(params: Params, spec: ModelSpec, tokens: jnp.ndarray):
     x = params["embed"][tokens]
     if spec.embed_scale:
@@ -461,10 +470,11 @@ def prefill_forward(
             h, lp, spec, positions, page_tables, kp, vp, layer=layer
         )
         win_arg = win if spec.sliding_window > 0 else None
-        if win_arg is None:
-            attn = attn_fn(q, k, v, seq_lens)
-        else:
-            attn = attn_fn(q, k, v, seq_lens, window=win_arg)
+        with jax.named_scope("attention"):
+            if win_arg is None:
+                attn = attn_fn(q, k, v, seq_lens)
+            else:
+                attn = attn_fn(q, k, v, seq_lens, window=win_arg)
         return _finish_layer(h, attn, lp, spec), kp, vp
 
     x, k_pages, v_pages = _kv_layer_scan(
@@ -477,6 +487,7 @@ def prefill_forward(
     return _logits(params, spec, last_hidden), k_pages, v_pages
 
 
+@jax.named_scope("qkv")
 def _prefill_qkv_write(
     h, lp, spec: ModelSpec, positions, page_tables, k_pages_l, v_pages_l,
     layer=None, offsets=None,
@@ -536,10 +547,11 @@ def _finish_layer(h, attn, lp, spec: ModelSpec):
     own pre/post norms (sandwich normalization)."""
     attn = attn.reshape(*h.shape[:-1], spec.q_dim)
     uo = spec.unit_offset_norm
-    attn_out = weighted_einsum(
-        "...h,hd->...d", attn, lp["o"]["w"], quant_kernel=spec.quant_kernel,
-        int8_native=spec.int8_native,
-    )
+    with jax.named_scope("o_proj"):
+        attn_out = weighted_einsum(
+            "...h,hd->...d", attn, lp["o"]["w"],
+            quant_kernel=spec.quant_kernel, int8_native=spec.int8_native,
+        )
     if spec.ffn_sandwich:
         attn_out = rms_norm(attn_out, lp["post_norm"], spec.rms_eps, uo)
         h = h + attn_out
@@ -566,13 +578,15 @@ def prefill_layer(
     q, k, v, k_pages_l, v_pages_l = _prefill_qkv_write(
         h, lp, spec, positions, page_tables, k_pages_l, v_pages_l
     )
-    if window is None:
-        attn = attn_fn(q, k, v, seq_lens)
-    else:
-        attn = attn_fn(q, k, v, seq_lens, window=window)
+    with jax.named_scope("attention"):
+        if window is None:
+            attn = attn_fn(q, k, v, seq_lens)
+        else:
+            attn = attn_fn(q, k, v, seq_lens, window=window)
     return _finish_layer(h, attn, lp, spec), k_pages_l, v_pages_l
 
 
+@jax.named_scope("qkv")
 def _decode_qkv(h, lp, spec: ModelSpec, positions):
     """Per-layer decode prologue shared by every decode path (xs/ys
     scan, carry scan, sp shard, pp relay): input norm + qkv projection +
@@ -613,14 +627,17 @@ def decode_layer(
             softcap=spec.attn_softcap, scale=_query_scale(spec),
         )
         return _finish_layer(h, attn, lp, spec), k_pages_l, v_pages_l
-    k_pages_l = kv_write_tokens(k_pages_l, page_ids, page_off, k)
-    v_pages_l = kv_write_tokens(v_pages_l, page_ids, page_off, v)
-    if window is None:
-        attn = attn_fn(q, k_pages_l, v_pages_l, page_tables, seq_lens)
-    else:
-        attn = attn_fn(
-            q, k_pages_l, v_pages_l, page_tables, seq_lens, window=window
-        )
+    with jax.named_scope("kv_write"):
+        k_pages_l = kv_write_tokens(k_pages_l, page_ids, page_off, k)
+        v_pages_l = kv_write_tokens(v_pages_l, page_ids, page_off, v)
+    with jax.named_scope("attention"):
+        if window is None:
+            attn = attn_fn(q, k_pages_l, v_pages_l, page_tables, seq_lens)
+        else:
+            attn = attn_fn(
+                q, k_pages_l, v_pages_l, page_tables, seq_lens,
+                window=window,
+            )
     return _finish_layer(h, attn, lp, spec), k_pages_l, v_pages_l
 
 
@@ -735,12 +752,14 @@ def decode_forward(
     # (Pallas: layer-indexed DMA; jnp: one composed gather)
     def body(h, lp, win, kp, vp, layer):
         q, k, v = _decode_qkv(h, lp, spec, positions)
-        kp = kv_write_tokens(kp, page_ids, page_off, k, layer=layer)
-        vp = kv_write_tokens(vp, page_ids, page_off, v, layer=layer)
-        attn = attn_fn(
-            q, kp, vp, page_tables, seq_lens, layer=layer,
-            window=win if spec.sliding_window > 0 else None,
-        )
+        with jax.named_scope("kv_write"):
+            kp = kv_write_tokens(kp, page_ids, page_off, k, layer=layer)
+            vp = kv_write_tokens(vp, page_ids, page_off, v, layer=layer)
+        with jax.named_scope("attention"):
+            attn = attn_fn(
+                q, kp, vp, page_tables, seq_lens, layer=layer,
+                window=win if spec.sliding_window > 0 else None,
+            )
         return _finish_layer(h, attn, lp, spec), kp, vp
 
     x, k_pages, v_pages = _kv_layer_scan(
@@ -843,21 +862,23 @@ def prefill_suffix_forward(
             layer=layer, offsets=offsets,
         )
         window = win if spec.sliding_window > 0 else None
-        if use_pallas:
-            # the multitok kernel IS suffix attention: S query rows
-            # starting at an arbitrary position, causal within the
-            # rows, live-page DMA only (the suffix KV was just written)
-            attn = paged_multitok_attention_pallas(
-                q, kp, vp, ctx_page_tables, prefix_lens, suffix_lens,
-                window=window, layer=layer,
-                softcap=spec.attn_softcap, scale=_query_scale(spec),
-            )
-        else:
-            attn = paged_suffix_attention(
-                q, kp, vp, ctx_page_tables, prefix_lens,
-                total_lens, softcap=spec.attn_softcap,
-                window=window, scale=_query_scale(spec), layer=layer,
-            )
+        with jax.named_scope("attention"):
+            if use_pallas:
+                # the multitok kernel IS suffix attention: S query rows
+                # starting at an arbitrary position, causal within the
+                # rows, live-page DMA only (the suffix KV was just
+                # written)
+                attn = paged_multitok_attention_pallas(
+                    q, kp, vp, ctx_page_tables, prefix_lens, suffix_lens,
+                    window=window, layer=layer,
+                    softcap=spec.attn_softcap, scale=_query_scale(spec),
+                )
+            else:
+                attn = paged_suffix_attention(
+                    q, kp, vp, ctx_page_tables, prefix_lens,
+                    total_lens, softcap=spec.attn_softcap,
+                    window=window, scale=_query_scale(spec), layer=layer,
+                )
         return _finish_layer(h, attn, lp, spec), kp, vp
 
     x, k_pages, v_pages = _kv_layer_scan(
@@ -966,18 +987,19 @@ def spec_verify_forward(
         kp = kv_write_tokens(kp, page_ids, page_off, k, layer=layer)
         vp = kv_write_tokens(vp, page_ids, page_off, v, layer=layer)
         window = win if spec.sliding_window > 0 else None
-        if use_pallas:
-            attn = paged_multitok_attention_pallas(
-                q, kp, vp, page_tables, positions0,
-                input_lens, window=window, layer=layer,
-                softcap=spec.attn_softcap, scale=_query_scale(spec),
-            )
-        else:
-            attn = paged_suffix_attention(
-                q, kp, vp, page_tables, positions0,
-                total_lens, softcap=spec.attn_softcap, window=window,
-                scale=_query_scale(spec), layer=layer,
-            )
+        with jax.named_scope("attention"):
+            if use_pallas:
+                attn = paged_multitok_attention_pallas(
+                    q, kp, vp, page_tables, positions0,
+                    input_lens, window=window, layer=layer,
+                    softcap=spec.attn_softcap, scale=_query_scale(spec),
+                )
+            else:
+                attn = paged_suffix_attention(
+                    q, kp, vp, page_tables, positions0,
+                    total_lens, softcap=spec.attn_softcap, window=window,
+                    scale=_query_scale(spec), layer=layer,
+                )
         return _finish_layer(h, attn, lp, spec), kp, vp
 
     x, k_pages, v_pages = _kv_layer_scan(

@@ -59,6 +59,14 @@ class FlightRecorder:
         # (admit and close both run there), snapshots copy defensively
         self._live: Dict[int, Dict[str, Any]] = {}
         self._tick_counter = itertools.count()
+        # monotone sums over the phase transitions below, served in
+        # /debug/perf totals (observability/perf.py REQUEST_TOTALS):
+        # queue_wait_s / admitted and prefill_s / first_tokens are the
+        # window's mean queue wait and mean prefill time
+        self._phase_totals: Dict[str, Any] = {
+            "admitted": 0, "queue_wait_s": 0.0,
+            "first_tokens": 0, "prefill_s": 0.0,
+        }
 
     # ------------------------------------------------------------- ticks
 
@@ -104,6 +112,24 @@ class FlightRecorder:
         rec["_phase"] = phase
         rec["_phase_start"] = now
 
+    def _leave(self, rec: Dict[str, Any], now: float) -> None:
+        """Accrue the LIVE record's open phase and add it to the window
+        sums (the same timestamps, no new ones)."""
+        phase, start = rec.get("_phase"), rec.get("_phase_start")
+        if start is not None:
+            if phase == "queue_s":
+                self._phase_totals["queue_wait_s"] += now - start
+            elif phase == "prefill_s":
+                self._phase_totals["first_tokens"] += 1
+                self._phase_totals["prefill_s"] += now - start
+        self._accrue(rec, now)
+
+    def phase_totals(self) -> Dict[str, Any]:
+        return {
+            k: round(v, 6) if isinstance(v, float) else v
+            for k, v in self._phase_totals.items()
+        }
+
     def on_admit(
         self,
         seq: Any,
@@ -136,10 +162,12 @@ class FlightRecorder:
             if preview is not None and not self.redact_prompts:
                 rec["prompt_preview"] = preview[: self.preview_chars]
             self._live[seq.seq_id] = rec
+            self._phase_totals["admitted"] += 1
+            self._phase_totals["queue_wait_s"] += now_pc - seq.arrival_t
         else:
             # re-admission after preemption: close the renewed queue
             # phase (opened by on_preempt) and note the new bucket
-            self._accrue(rec, now_pc)
+            self._leave(rec, now_pc)
             rec["bucket"] = bucket
             rec["cached_tokens"] = cached_len
         rec["preemptions"] = seq.preempt_count
@@ -152,7 +180,7 @@ class FlightRecorder:
         if rec is None:
             return
         now = time.perf_counter()
-        self._accrue(rec, now)
+        self._leave(rec, now)
         self._enter(rec, "decode_s", now)
 
     def on_preempt(self, seq: Any) -> None:

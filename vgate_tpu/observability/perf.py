@@ -12,9 +12,13 @@ snapshot readers copy defensively under the GIL):
 
 * **TickProfile** — every engine tick decomposed into phases:
 
-  - ``host_s``     scheduler/admission/bookkeeping between dispatches
-                   (derived: tick wall minus the measured phases, so the
-                   five phases sum to the tick wall by construction);
+  - ``host_s``     whatever no bracket covers (derived: tick wall minus
+                   the measured phases, so the phases sum to the tick
+                   wall by construction);
+  - ``schedule_s`` evacuations/handoffs/aborts, admission and decode
+                   scheduling up to the dispatch;
+  - ``state_s``    host-side input preparation (``_build_decode_state``,
+                   prefill group arrays, logit-bias/min-token arrays);
   - ``dispatch_s`` jitted-call return, i.e. trace + enqueue (a FIRST
                    dispatch of a program variant includes its XLA
                    compile — the compile ledger records that share);
@@ -29,6 +33,17 @@ snapshot readers copy defensively under the GIL):
   Host-side KV swap traffic (runtime/kv_swap.py) is currently left in
   ``host_s`` — it is host-paid recovery work, not steady-state decode.
 
+  The engine measures a phase with ONE bracket, :meth:`PerfRecorder.span`
+  (a context manager).  It accrues the phase seconds and, only while a
+  ``POST /v1/profile`` capture runs (:func:`set_capturing`), also opens
+  a ``jax.profiler.TraceAnnotation`` named ``vgt.engine.<span>`` on the
+  engine thread, so the device trace carries the engine's own clock.
+  With no capture a bracket costs one flag test.  :class:`GatewayPerf`
+  (the ``GATEWAY`` singleton) does the same on the gateway's event-loop
+  thread with begin/end pairs (two of its three sites run once a
+  token): ``vgt.gateway.ingress`` / ``stream_detok`` / ``sse_write``,
+  with monotone window counters.
+
 * **Compile ledger** — one entry per compiled program variant
   (program family, signature, trigger, count, seconds), hooked exactly
   where the engine already stamps ``compiling=True`` heartbeats.  In
@@ -38,8 +53,9 @@ snapshot readers copy defensively under the GIL):
 * **Rolling window** — live tok/s, MFU and %-of-HBM-roofline computed
   from the engine's own geometry (observability/roofline.py — the same
   peak table the benches use) plus the host-overhead ratio
-  (host_s / wall over the window): the single number the megatick
-  refactor exists to drive down.
+  ((host_s + schedule_s + state_s) / wall over the window — the
+  engine's own Python outside the jitted call and the device): the
+  single number the megatick refactor exists to drive down.
 
 Surfaces: ``GET /debug/perf`` (auth-gated, drain-uncounted), the
 ``/stats`` engine block (``perf``), metrics
@@ -52,15 +68,122 @@ QPS cell).
 
 from __future__ import annotations
 
+import contextvars
+import os
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 from vgate_tpu import metrics
 from vgate_tpu.observability.roofline import EngineRoofline
 
+# per-request phase sums the flight recorder keeps (flight.py)
+REQUEST_TOTALS = ("admitted", "queue_wait_s", "first_tokens", "prefill_s")
+# scalar totals() keys that add across dp replicas (merge_snapshots)
+ADDITIVE_TOTALS = (
+    "decode_device_s", "decode_ctx_token_steps",
+    "engine_cpu_s", "engine_cpu_in_wait_s",
+) + REQUEST_TOTALS
+
 # the fixed phase taxonomy (docs/observability.md "Perf attribution")
-PHASES = ("host", "dispatch", "device", "readback", "detok")
+PHASES = (
+    "host", "schedule", "state", "dispatch", "device", "readback", "detok",
+)
+# the phase each engine span accrues into; spans not listed here
+# (``idle_wait``) only annotate the trace
+SPAN_PHASE = {
+    "schedule": "schedule",
+    "state": "state",
+    "prefill_dispatch": "dispatch",
+    "decode_dispatch": "dispatch",
+    "device_wait": "device",
+    "readback": "readback",
+    "emit": "detok",
+}
+# spans in which the engine thread is blocked on the device: their CPU
+# time is ``engine_cpu_in_wait_s`` (time.thread_time, per chunk)
+WAIT_SPANS = frozenset(("device_wait", "readback"))
+# the gateway times one streamed token in this many (GatewayPerf)
+SAMPLE_EVERY = 8
+
+# True only while EngineCore.capture_profile runs a profiler session:
+# the one flag every bracket tests before building a TraceAnnotation
+_capturing = False
+
+
+def set_capturing(flag: bool) -> None:
+    global _capturing
+    _capturing = bool(flag)
+
+
+def capturing() -> bool:
+    return _capturing
+
+
+def _open_annotation(name: str, args: Optional[Callable[[], dict]]):
+    """An entered ``TraceAnnotation`` (capture running) — the arguments
+    become the trace event's stats."""
+    from jax.profiler import TraceAnnotation
+
+    ann = TraceAnnotation(name, **(args() if args is not None else {}))
+    ann.__enter__()
+    return ann
+
+
+class _Bracket:
+    """``with perf.span(...) as b:`` — times the block on the recorder's
+    clock, hands ``(name, seconds)`` to the recorder, and mirrors the
+    block into the profiler trace while a capture runs."""
+
+    __slots__ = ("_sink", "name", "_args", "_t0", "_ann", "seconds")
+
+    def __init__(self, sink: "PerfRecorder", name: str, args) -> None:
+        self._sink = sink
+        self.name = name
+        self._args = args
+        self._ann = None
+        self.seconds = 0.0
+
+    def __enter__(self) -> "_Bracket":
+        if _capturing:
+            self._ann = _open_annotation(
+                "vgt.engine." + self.name, self._args
+            )
+        self._t0 = self._sink._span_begin(self.name)
+        return self
+
+    def note(self, **args: Any) -> None:
+        """Arguments known only at the block's end (tokens emitted)."""
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
+    def __exit__(self, *exc) -> bool:
+        self.seconds = self._sink._span_end(self.name, self._t0)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+
+# boot phases of the process (seconds; the program's part of setup_s):
+# weights / digest / ready — first boot only, a supervised rebuild does
+# not overwrite them
+BOOT_SECONDS: Dict[str, float] = {}
+
+
+def note_boot(phase: str, seconds: float) -> None:
+    BOOT_SECONDS.setdefault(phase, round(float(seconds), 3))
+
+
+def process_age_s() -> Optional[float]:
+    """Seconds since this process started (``/proc``), None elsewhere."""
+    try:
+        with open("/proc/self/stat") as fh:
+            start_ticks = int(fh.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as fh:
+            uptime = float(fh.read().split()[0])
+        return uptime - start_ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
 
 # gauges + ledger-size trims run at most this often (engine thread)
 _FLUSH_INTERVAL_S = 0.5
@@ -71,14 +194,17 @@ class TickProfile:
     the tick runs; frozen by :meth:`PerfRecorder.tick_end`)."""
 
     __slots__ = (
-        "t", "wall", "host", "dispatch", "device", "readback", "detok",
-        "tokens", "decode_steps", "decode_bytes", "decode_device_s",
+        "t", "wall", "host", "schedule", "state", "dispatch", "device",
+        "readback", "detok", "tokens", "decode_steps", "decode_bytes",
+        "decode_device_s", "cpu0", "cpu_in_wait",
     )
 
     def __init__(self, t: float) -> None:
         self.t = t
         self.wall = 0.0
         self.host = 0.0
+        self.schedule = 0.0
+        self.state = 0.0
         self.dispatch = 0.0
         self.device = 0.0
         self.readback = 0.0
@@ -87,13 +213,24 @@ class TickProfile:
         self.decode_steps = 0
         self.decode_bytes = 0
         self.decode_device_s = 0.0
+        # engine-thread CPU clock at tick begin, and the CPU seconds
+        # burnt inside the device_wait/readback brackets
+        self.cpu0 = time.thread_time()
+        self.cpu_in_wait = 0.0
 
     def measured(self) -> float:
+        return self.schedule + self.state + self.device_work()
+
+    def device_work(self) -> float:
+        """Seconds in the phases only a tick that touched the device
+        has (an idle poll still runs its schedule bracket)."""
         return self.dispatch + self.device + self.readback + self.detok
 
     def phases(self) -> Dict[str, float]:
         return {
             "host": self.host,
+            "schedule": self.schedule,
+            "state": self.state,
             "dispatch": self.dispatch,
             "device": self.device,
             "readback": self.readback,
@@ -148,6 +285,18 @@ class PerfRecorder:
         self.total_wall_s = 0.0
         self.total_compile_s = 0.0
         self._phase_totals = {name: 0.0 for name in PHASES}
+        # window counters measured where the work happens (monotone;
+        # perfbench differences two /debug/perf scrapes)
+        self.total_decode_device_s = 0.0
+        self.total_decode_ctx_token_steps = 0
+        # chunk length (steps) -> decode chunks read back
+        self._chunks_by_steps: Dict[int, int] = {}
+        self.total_engine_cpu_s = 0.0
+        self.total_engine_cpu_in_wait_s = 0.0
+        # the flight recorder's per-request phase sums (admitted /
+        # queue_wait_s / first_tokens / prefill_s), folded into totals()
+        self.request_totals: Optional[Callable[[], Dict[str, Any]]] = None
+        self._tick_ann = None
         # monotone per-program compile counters — NOT derived from the
         # evicting ledger, so a recompile storm (which evicts old
         # entries) can never make the loadlab delta go negative
@@ -162,13 +311,45 @@ class PerfRecorder:
     # ------------------------------------------------- engine hot path
 
     def tick_begin(self) -> None:
+        if _capturing:
+            n = self.total_ticks + self.total_idle_ticks
+            self._tick_ann = _open_annotation(
+                "vgt.engine.tick", lambda: {"tick": n}
+            )
         if not self.enabled:
             return
         self._cur = TickProfile(self._clock())
 
+    def span(
+        self, name: str, args: Optional[Callable[[], dict]] = None
+    ) -> _Bracket:
+        """THE bracket (engine thread): ``with perf.span("emit"): ...``
+        accrues the block's seconds into the span's phase (SPAN_PHASE)
+        and, while a profile capture runs, records it in the trace as
+        ``vgt.engine.<name>`` with ``args()`` as its arguments (``args``
+        is only called then).  ``.seconds`` holds the duration after
+        the block."""
+        return _Bracket(self, name, args)
+
+    def _span_begin(self, name: str) -> float:
+        if name in WAIT_SPANS and self._cur is not None:
+            self._cur.cpu_in_wait -= time.thread_time()
+        return self._clock()
+
+    def _span_end(self, name: str, t0: float) -> float:
+        seconds = self._clock() - t0
+        cur = self._cur
+        if cur is not None:
+            if name in WAIT_SPANS:
+                cur.cpu_in_wait += time.thread_time()
+            phase = SPAN_PHASE.get(name)
+            if phase is not None:
+                self.phase(phase, seconds)
+        return seconds
+
     def phase(self, name: str, seconds: float) -> None:
         """Accrue measured time into the current tick's ``name`` phase
-        (dispatch/device/readback/detok; host is derived)."""
+        (everything in PHASES but host, which is derived)."""
         cur = self._cur
         if cur is None or seconds <= 0:
             return
@@ -182,17 +363,26 @@ class PerfRecorder:
             cur.tokens += n
 
     def note_decode(
-        self, steps: int, ctx_tokens: int, device_s: float
+        self, steps: int, ctx_tokens: int, device_s: float,
+        chunk: bool = True,
     ) -> None:
-        """One decode-chunk (or spec-verify) readback: ``steps`` fused
-        steps over ``ctx_tokens`` total resident context tokens, with
-        ``device_s`` of host-observed device time — feeds the modeled
-        HBM traffic the roofline gauge divides by."""
+        """One decode-chunk (or spec-verify, ``chunk=False``) readback:
+        ``steps`` fused steps over sequences holding ``ctx_tokens``
+        resident context tokens in all, with ``device_s`` of
+        host-observed device time — feeds the modeled HBM traffic the
+        roofline gauge divides by, and the chunk-length / live-context
+        window counters."""
         cur = self._cur
         if cur is None:
             return
         cur.decode_steps += steps
         cur.decode_device_s += device_s
+        self.total_decode_device_s += device_s
+        self.total_decode_ctx_token_steps += steps * ctx_tokens
+        if chunk:
+            self._chunks_by_steps[steps] = (
+                self._chunks_by_steps.get(steps, 0) + 1
+            )
         if self.roofline is not None:
             cur.decode_bytes += steps * self.roofline.step_bytes(
                 ctx_tokens
@@ -203,14 +393,18 @@ class PerfRecorder:
         remainder (clamped at 0 — the explained phases can overshoot
         the wall only by clock noise), push the profile into the
         rolling ring, and feed the phase counters."""
+        if self._tick_ann is not None:
+            self._tick_ann.__exit__(None, None, None)
+            self._tick_ann = None
         cur = self._cur
         self._cur = None
         if cur is None:
             return
         now = self._clock()
         cur.wall = now - cur.t
-        if not worked and cur.measured() == 0.0 and cur.tokens == 0:
-            # no-work ticks are idle polls, not attribution evidence —
+        if not worked and cur.device_work() == 0.0 and cur.tokens == 0:
+            # no-work ticks are idle polls, not attribution evidence
+            # (their schedule bracket found nothing to schedule) —
             # but the gauge flush still runs on cadence, so an engine
             # going idle decays its window gauges instead of freezing
             # them at the last loaded value
@@ -225,6 +419,11 @@ class PerfRecorder:
         self.total_tokens += cur.tokens
         self.total_decode_steps += cur.decode_steps
         self.total_wall_s += cur.wall
+        # one thread_time per worked tick: wall - device - readback -
+        # (cpu - cpu_in_wait) is the time the engine thread neither ran
+        # nor waited on the device (GIL, or the OS)
+        self.total_engine_cpu_s += time.thread_time() - cur.cpu0
+        self.total_engine_cpu_in_wait_s += cur.cpu_in_wait
         for name, value in cur.phases().items():
             self._phase_totals[name] += value
             if value > 0:
@@ -342,8 +541,15 @@ class PerfRecorder:
                 k: round(v, 6) for k, v in phases.items()
             },
             "wall_s": round(wall, 6),
+            # everything the engine's own Python spends outside the
+            # jitted call and the device: the unbracketed remainder plus
+            # scheduling/admission and state building (what ``host`` was
+            # before those two got brackets of their own)
             "host_overhead_ratio": (
-                round(phases["host"] / wall, 4) if wall > 0 else None
+                round(
+                    (phases["host"] + phases["schedule"]
+                     + phases["state"]) / wall, 4
+                ) if wall > 0 else None
             ),
             "mfu": None if mfu is None else round(mfu, 4),
             "hbm_roofline_pct": (
@@ -374,7 +580,7 @@ class PerfRecorder:
         ledger eviction under a recompile storm must never make a
         delta go negative."""
         compiles = dict(self._compile_counts)
-        return {
+        out = {
             "ticks": self.total_ticks,
             "idle_ticks": self.total_idle_ticks,
             "tokens": self.total_tokens,
@@ -385,7 +591,21 @@ class PerfRecorder:
             },
             "compiles": compiles,
             "compile_seconds": round(self.total_compile_s, 6),
+            "chunks_by_steps": {
+                str(k): v for k, v in self._chunks_by_steps.items()
+            },
+            "decode_device_s": round(self.total_decode_device_s, 6),
+            "decode_ctx_token_steps": self.total_decode_ctx_token_steps,
+            "engine_cpu_s": round(self.total_engine_cpu_s, 6),
+            "engine_cpu_in_wait_s": round(
+                self.total_engine_cpu_in_wait_s, 6
+            ),
+            **{name: 0 for name in REQUEST_TOTALS},
+            "boot_seconds": dict(BOOT_SECONDS),
         }
+        if self.request_totals is not None:
+            out.update(self.request_totals())
+        return out
 
     def snapshot(self) -> Dict[str, Any]:
         """The full /debug/perf payload for one engine core."""
@@ -422,6 +642,172 @@ class PerfRecorder:
             "compiles": self.totals()["compiles"],
             "compile_seconds": round(self.total_compile_s, 6),
         }
+
+
+# --------------------------------------------------- the gateway's side
+
+class _StreamClock:
+    """One streamed request's stamps, carried by a context variable from
+    the handler's entry to the stream's writes (same asyncio task)."""
+
+    __slots__ = ("t_in", "ann", "t_first_token", "first_written",
+                 "sampled")
+
+    def __init__(self, t_in: Optional[float], ann: Any) -> None:
+        self.t_in = t_in
+        self.ann = ann
+        # is the token now on its way to the wire a timed one
+        self.sampled = False
+        # stamped by the ENGINE thread at the stream's first on_token
+        self.t_first_token: Optional[float] = None
+        self.first_written = False
+
+
+_stream_clock: "contextvars.ContextVar[Optional[_StreamClock]]" = (
+    contextvars.ContextVar("vgt_stream_clock", default=None)
+)
+
+
+class GatewayPerf:
+    """Window counters and trace spans of the gateway's event-loop
+    thread (server/app.py, backends/jax_backend.py).  Every counter is
+    monotone and is only written on the event loop; ``/debug/perf``
+    serves them as ``totals.gateway``.  With ``enabled`` false
+    (observability off) no stream gets a clock and nothing is timed."""
+
+    def __init__(self, clock: Any = time.perf_counter) -> None:
+        self._clock = clock
+        self.enabled = True
+        self.ingress_n = 0
+        self.ingress_s = 0.0
+        self.first_chunk_n = 0
+        self.first_chunk_s = 0.0
+        self.stream_tokens = 0
+        self.stream_detok_s = 0.0
+        self.stream_write_s = 0.0
+        self._detok_ann = None
+
+    def ingress_begin(self) -> None:
+        """Handler entry of a chat request: opens
+        ``vgt.gateway.ingress`` (closed by :meth:`ingress_end` when the
+        request is streamed, else by :meth:`ingress_close`)."""
+        if not self.enabled:
+            _stream_clock.set(None)
+            return
+        ann = (
+            _open_annotation("vgt.gateway.ingress", None)
+            if _capturing else None
+        )
+        _stream_clock.set(_StreamClock(self._clock(), ann))
+
+    @staticmethod
+    def stream_clock() -> Optional[_StreamClock]:
+        """This task's stream clock, for the ``on_token`` stamp (None
+        when the stream did not enter through the gateway)."""
+        return _stream_clock.get()
+
+    def ingress_end(self) -> None:
+        """``submit_prompt`` returned: the request is the engine's now."""
+        clock = _stream_clock.get()
+        if clock is None or clock.t_in is None:
+            return
+        self.ingress_n += 1
+        self.ingress_s += self._clock() - clock.t_in
+        clock.t_in = None
+        if clock.ann is not None:
+            clock.ann.__exit__(None, None, None)
+            clock.ann = None
+
+    def ingress_close(self) -> None:
+        """Handler exit, in a ``finally``: a request that never reached
+        ``submit_prompt`` (not streamed, rejected) or was cancelled
+        inside a write leaves no annotation open, and the task's next
+        request (keep-alive) no stale clock."""
+        clock = _stream_clock.get()
+        if clock is not None:
+            if clock.ann is not None:
+                clock.ann.__exit__(None, None, None)
+                clock.ann = None
+            _stream_clock.set(None)
+
+    # The two per-token sites are begin/end pairs, not ``with`` blocks,
+    # and time one token in SAMPLE_EVERY of a stream (every token while
+    # a capture runs, so the trace shows them all): at 4,000 tokens/s
+    # the event loop is the contended thread, a bracket object per
+    # token cost decode-heavy 2-3 % of its throughput and four clock
+    # reads per token still 1-2 % (PERF.md, PR 24).  ``stream_tokens``
+    # counts the TIMED tokens; the sums over it are what a mean needs.
+
+    def detok_begin(
+        self, clock: Optional[_StreamClock], n_ids: int
+    ) -> Optional[float]:
+        """Before ``tokenizer.decode`` of the stream's ``n_ids``-th
+        token in stream_async; None = this token is not timed (then
+        neither is its write).  No await until :meth:`detok_end`, so
+        the open annotation lives on ``self``."""
+        if clock is None:
+            return None
+        # every token up to the stream's first chunk on the wire is
+        # timed (a stop-string hold-back or a partial UTF-8 piece can
+        # make the first write later than the first token)
+        clock.sampled = (
+            _capturing or not clock.first_written
+            or n_ids % SAMPLE_EVERY == 1
+        )
+        if not clock.sampled:
+            return None
+        if _capturing:
+            self._detok_ann = _open_annotation(
+                "vgt.gateway.stream_detok", None
+            )
+        return self._clock()
+
+    def detok_end(self, t0: float) -> None:
+        self.stream_detok_s += self._clock() - t0
+        self.stream_tokens += 1
+        if self._detok_ann is not None:
+            self._detok_ann.__exit__(None, None, None)
+            self._detok_ann = None
+
+    def write_begin(self) -> Optional[float]:
+        """Before one SSE chunk's JSON + ``resp.write``; None = the
+        chunk's token is not timed.  The write may await, so the open
+        annotation lives on the stream's clock."""
+        clock = _stream_clock.get()
+        if clock is None or not clock.sampled:
+            return None
+        if _capturing:
+            clock.ann = _open_annotation("vgt.gateway.sse_write", None)
+        return self._clock()
+
+    def write_end(self, t0: float) -> None:
+        now = self._clock()
+        self.stream_write_s += now - t0
+        clock = _stream_clock.get()
+        if clock.ann is not None:
+            clock.ann.__exit__(None, None, None)
+            clock.ann = None
+        if not clock.first_written and clock.t_first_token is not None:
+            # engine's first on_token -> the stream's first chunk on
+            # the wire (both perf_counter, one process); every token
+            # up to that chunk is a timed one
+            clock.first_written = True
+            self.first_chunk_n += 1
+            self.first_chunk_s += now - clock.t_first_token
+
+    def totals(self) -> Dict[str, Any]:
+        return {
+            "ingress_n": self.ingress_n,
+            "ingress_s": round(self.ingress_s, 6),
+            "first_chunk_n": self.first_chunk_n,
+            "first_chunk_s": round(self.first_chunk_s, 6),
+            "stream_tokens": self.stream_tokens,
+            "stream_detok_s": round(self.stream_detok_s, 6),
+            "stream_write_s": round(self.stream_write_s, 6),
+        }
+
+
+GATEWAY = GatewayPerf()
 
 
 # ------------------------------------------------------- dp aggregation
@@ -508,6 +894,19 @@ def merge_snapshots(
         "compile_seconds": round(
             sum(t["compile_seconds"] for t in totals), 6
         ),
+        "chunks_by_steps": {
+            n: sum(t.get("chunks_by_steps", {}).get(n, 0) for t in totals)
+            for n in sorted(
+                {n for t in totals for n in t.get("chunks_by_steps", {})},
+                key=int,
+            )
+        },
+        **{
+            name: round(sum(t.get(name, 0) for t in totals), 6)
+            for name in ADDITIVE_TOTALS
+        },
+        # one process, one boot: replicas share it
+        "boot_seconds": dict(totals[0].get("boot_seconds", {})),
     }
     out["window"] = agg_window
     out["totals"] = agg_totals

@@ -2057,11 +2057,16 @@ class ReplicatedEngine:
         return sum(core.warmup(buckets) for core in self.replicas)
 
     def capture_profile(
-        self, duration_s: float = 1.0, out_dir: Optional[str] = None
+        self,
+        duration_s: float = 1.0,
+        out_dir: Optional[str] = None,
+        python_tracer: bool = False,
     ) -> Dict[str, Any]:
         """jax.profiler traces are process-wide; one capture covers all
         replicas (they share the process and its device set)."""
-        return self.replicas[0].capture_profile(duration_s, out_dir)
+        return self.replicas[0].capture_profile(
+            duration_s, out_dir, python_tracer=python_tracer
+        )
 
     def device_health(self) -> Dict[str, Any]:
         healths = [core.device_health() for core in self.replicas]

@@ -80,7 +80,12 @@ from vgate_tpu.ops.sampling import (
     verify_and_sample,
 )
 from vgate_tpu.observability.flight import FlightRecorder
-from vgate_tpu.observability.perf import PerfRecorder
+from vgate_tpu.observability.perf import (
+    BOOT_SECONDS,
+    PerfRecorder,
+    note_boot,
+    set_capturing,
+)
 from vgate_tpu.observability.reqtrace import RequestMeta, RequestTrace
 from vgate_tpu.observability.roofline import (
     EngineRoofline,
@@ -144,6 +149,38 @@ _DTYPES = {
 }
 
 
+@jax.named_scope("sample")
+def _sample_first(
+    logits, temps, top_ps, top_ks, key, seeds, steps, num_logprobs,
+    counts, freq_pens, pres_pens, min_toks, stop_id_mat, bias_ids,
+    bias_vals,
+):
+    """A prompt pass's fused first-token sampling (traced inside
+    _prefill_step / _suffix_prefill_step): logit post-processing, then
+    the sample.  Returns ``(next_tokens, logprob triple or None)``."""
+    if counts is not None:
+        # post-preemption re-prefill: folded outputs still count toward
+        # the penalties of the re-sampled first token
+        logits = apply_penalties(logits, counts, freq_pens, pres_pens)
+    if bias_ids is not None:
+        logits = apply_logit_bias(logits, bias_ids, bias_vals)
+    if min_toks is not None:
+        logits = suppress_stop_tokens(logits, steps, min_toks, stop_id_mat)
+    if num_logprobs > 0:
+        next_tokens, lp, tids, tlps = sample_tokens_with_logprobs(
+            logits, temps, top_ps, top_ks, key, seeds=seeds, steps=steps,
+            num_top=num_logprobs,
+        )
+        return next_tokens, (lp, tids, tlps)
+    # NOTE: no all_greedy fast path in prefill programs — one sample per
+    # PROMPT makes the top-k cost negligible, and skipping the variant
+    # split halves the (expensive) batched-prefill compile ladder
+    next_tokens = sample_tokens(
+        logits, temps, top_ps, top_ks, key, seeds=seeds, steps=steps
+    )
+    return next_tokens, None
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -162,27 +199,12 @@ def _prefill_step(
         params, spec, tokens, seq_lens, k_pages, v_pages, page_tables,
         mesh=mesh, use_pallas=use_pallas,
     )
-    if counts is not None:
-        # post-preemption re-prefill: folded outputs still count toward
-        # the penalties of the re-sampled first token
-        logits = apply_penalties(logits, counts, freq_pens, pres_pens)
-    if bias_ids is not None:
-        logits = apply_logit_bias(logits, bias_ids, bias_vals)
-    if min_toks is not None:
-        logits = suppress_stop_tokens(logits, steps, min_toks, stop_id_mat)
-    if num_logprobs > 0:
-        next_tokens, lp, tids, tlps = sample_tokens_with_logprobs(
-            logits, temps, top_ps, top_ks, key, seeds=seeds, steps=steps,
-            num_top=num_logprobs,
-        )
-        return (next_tokens, (lp, tids, tlps)), k_pages, v_pages
-    # NOTE: no all_greedy fast path in prefill programs — one sample per
-    # PROMPT makes the top-k cost negligible, and skipping the variant
-    # split halves the (expensive) batched-prefill compile ladder
-    next_tokens = sample_tokens(
-        logits, temps, top_ps, top_ks, key, seeds=seeds, steps=steps
+    out = _sample_first(
+        logits, temps, top_ps, top_ks, key, seeds, steps, num_logprobs,
+        counts, freq_pens, pres_pens, min_toks, stop_id_mat, bias_ids,
+        bias_vals,
     )
-    return (next_tokens, None), k_pages, v_pages
+    return out, k_pages, v_pages
 
 
 @functools.partial(
@@ -208,22 +230,12 @@ def _suffix_prefill_step(
         suffix_page_tables, ctx_page_tables, use_pallas=use_pallas,
         mesh=mesh, unaligned=unaligned,
     )
-    if counts is not None:
-        logits = apply_penalties(logits, counts, freq_pens, pres_pens)
-    if bias_ids is not None:
-        logits = apply_logit_bias(logits, bias_ids, bias_vals)
-    if min_toks is not None:
-        logits = suppress_stop_tokens(logits, steps, min_toks, stop_id_mat)
-    if num_logprobs > 0:
-        next_tokens, lp, tids, tlps = sample_tokens_with_logprobs(
-            logits, temps, top_ps, top_ks, key, seeds=seeds, steps=steps,
-            num_top=num_logprobs,
-        )
-        return (next_tokens, (lp, tids, tlps)), k_pages, v_pages
-    next_tokens = sample_tokens(
-        logits, temps, top_ps, top_ks, key, seeds=seeds, steps=steps
+    out = _sample_first(
+        logits, temps, top_ps, top_ks, key, seeds, steps, num_logprobs,
+        counts, freq_pens, pres_pens, min_toks, stop_id_mat, bias_ids,
+        bias_vals,
     )
-    return (next_tokens, None), k_pages, v_pages
+    return out, k_pages, v_pages
 
 
 @functools.partial(jax.jit, donate_argnames=("k_pages", "v_pages"))
@@ -400,28 +412,31 @@ def _decode_chunk(
         )
         if guard:
             step_flags = integrity.logit_guard(logits, guard_threshold)
-        if counts is not None:
-            # frequency/presence penalties over the generated-token
-            # histogram (ops/sampling.py apply_penalties)
-            logits = apply_penalties(logits, counts, freq_pens, pres_pens)
-        if bias_ids is not None:
-            logits = apply_logit_bias(logits, bias_ids, bias_vals)
-        if min_toks is not None:
-            logits = suppress_stop_tokens(
-                logits, steps, min_toks, stop_id_mat
-            )
-        if num_logprobs > 0:
-            next_tokens, lp, tids, tlps = sample_tokens_with_logprobs(
-                logits, temps, top_ps, top_ks, key, seeds=seeds,
-                steps=steps, num_top=num_logprobs,
-            )
-            ys = (next_tokens, lp, tids, tlps)
-        else:
-            next_tokens = sample_tokens(
-                logits, temps, top_ps, top_ks, key, seeds=seeds,
-                steps=steps, all_greedy=all_greedy,
-            )
-            ys = (next_tokens,)
+        with jax.named_scope("sample"):
+            if counts is not None:
+                # frequency/presence penalties over the generated-token
+                # histogram (ops/sampling.py apply_penalties)
+                logits = apply_penalties(
+                    logits, counts, freq_pens, pres_pens
+                )
+            if bias_ids is not None:
+                logits = apply_logit_bias(logits, bias_ids, bias_vals)
+            if min_toks is not None:
+                logits = suppress_stop_tokens(
+                    logits, steps, min_toks, stop_id_mat
+                )
+            if num_logprobs > 0:
+                next_tokens, lp, tids, tlps = sample_tokens_with_logprobs(
+                    logits, temps, top_ps, top_ks, key, seeds=seeds,
+                    steps=steps, num_top=num_logprobs,
+                )
+                ys = (next_tokens, lp, tids, tlps)
+            else:
+                next_tokens = sample_tokens(
+                    logits, temps, top_ps, top_ks, key, seeds=seeds,
+                    steps=steps, all_greedy=all_greedy,
+                )
+                ys = (next_tokens,)
         if guard:
             ys = ys + (step_flags,)
         positions = positions + active.astype(positions.dtype)
@@ -889,6 +904,11 @@ class EngineCore:
                 )
         jax.block_until_ready(jax.tree.leaves(self.params)[0])
         self.load_time_s = time.perf_counter() - load_start
+        # boot phases for /debug/perf totals.boot_seconds (the digest
+        # pass inside load_or_init_params notes its own share first)
+        note_boot("weights", self.load_time_s - BOOT_SECONDS.get(
+            "digest", 0.0
+        ))
         # silent-corruption defense (vgate_tpu/integrity.py): sentinel
         # scanner + weight-checksum baseline over the FINAL serving tree
         # (post-quantize/shard — the tree supervised rebuilds keep).
@@ -1098,6 +1118,7 @@ class EngineCore:
                 ),
             ),
         )
+        self.perf.request_totals = self.flight.phase_totals
         # see the long rationale further down where the readback paths
         # use it; constructed here so the swap manager can share it
         self._readback_lock = named_lock("EngineCore._readback_lock")
@@ -1424,6 +1445,8 @@ class EngineCore:
         self.total_prefills = 0
         self.total_decode_tokens = 0
         self.total_state_rebuilds = 0
+        # loop iterations completed (capture_profile waits on it)
+        self._ticks_done = 0
 
     # ------------------------------------------------------------- lifecycle
 
@@ -1622,15 +1645,17 @@ class EngineCore:
             try:
                 self._beat("tick")
                 # perf attribution brackets the whole tick: phases
-                # measured inside (dispatch/device/readback/detok) are
-                # subtracted from the tick wall, the remainder is
-                # host_s — so the five phases sum to the wall by
-                # construction (observability/perf.py)
+                # measured inside (perf.span: schedule/state/dispatch/
+                # device/readback/detok) are subtracted from the tick
+                # wall, the remainder is host_s — so the phases sum to
+                # the wall by construction (observability/perf.py)
                 self.perf.tick_begin()
                 worked = self._tick()
                 self.perf.tick_end(worked)
+                self._ticks_done += 1
                 if not worked:
-                    self._wakeup.wait(timeout=0.005)
+                    with self.perf.span("idle_wait"):
+                        self._wakeup.wait(timeout=0.005)
                     self._wakeup.clear()
             except Exception as exc:
                 logger.error("engine loop fatal error", exc_info=True)
@@ -2208,35 +2233,38 @@ class EngineCore:
 
         Returns False when there was no work (the loop then sleeps).
         """
-        self._drain_submissions()
-        # planned evacuations before anything dispatches: a drain/
-        # rebalance coordinator is blocked on this, and the selected
-        # sequences must not burn another decode chunk here first
-        self._process_evacuations()
-        # then handoff staging (disaggregated prefill→decode): fold
-        # first-token'd handoff candidates off the device before they
-        # burn decode chunks that belong on the decode pool
-        self._process_handoffs()
-        # stall fault probe (vgate_tpu/faults.py): a `delay` armed here
-        # past recovery.step_stall_s simulates a wedged loop for the
-        # hang watchdog.  Only probed while work is resident, so chaos
-        # arming cannot stall an idle engine into a pointless restart.
-        if faults.is_active() and self.scheduler.has_work():
-            faults.check("stall")
-            if not self._running:
-                # the watchdog declared this core stalled while the
-                # armed delay slept: containment already swept the
-                # residents — touching scheduler state now would race
-                # the replay on the rebuilt core
-                return False
-        self._drain_abort_requests()
-        self._handle_aborts()
-        self._handle_deadlines()
-        # proactive prefix-cache trim (two int compares when healthy):
-        # keep truly-free pages above the evict watermark so allocation
-        # bursts never pay the eviction walk synchronously and
-        # admission's kv_pressure shedding only ever sees a drained cache
-        self.scheduler.maybe_trim()
+        with self.perf.span("schedule", self._schedule_args):
+            self._drain_submissions()
+            # planned evacuations before anything dispatches: a drain/
+            # rebalance coordinator is blocked on this, and the selected
+            # sequences must not burn another decode chunk here first
+            self._process_evacuations()
+            # then handoff staging (disaggregated prefill→decode): fold
+            # first-token'd handoff candidates off the device before
+            # they burn decode chunks that belong on the decode pool
+            self._process_handoffs()
+            # stall fault probe (vgate_tpu/faults.py): a `delay` armed
+            # here past recovery.step_stall_s simulates a wedged loop
+            # for the hang watchdog.  Only probed while work is
+            # resident, so chaos arming cannot stall an idle engine
+            # into a pointless restart.
+            if faults.is_active() and self.scheduler.has_work():
+                faults.check("stall")
+                if not self._running:
+                    # the watchdog declared this core stalled while the
+                    # armed delay slept: containment already swept the
+                    # residents — touching scheduler state now would
+                    # race the replay on the rebuilt core
+                    return False
+            self._drain_abort_requests()
+            self._handle_aborts()
+            self._handle_deadlines()
+            # proactive prefix-cache trim (two int compares when
+            # healthy): keep truly-free pages above the evict watermark
+            # so allocation bursts never pay the eviction walk
+            # synchronously and admission's kv_pressure shedding only
+            # ever sees a drained cache
+            self.scheduler.maybe_trim()
         if self.spec_k > 0 and not self.spec_suspended:
             if self._pending_chunks:
                 # chunked decode ran while a brownout suspended
@@ -2259,9 +2287,13 @@ class EngineCore:
             return worked
         worked = self._admit_and_prefill()
 
+        # decode scheduling is bracketed statement by statement so the
+        # leaf spans never overlap: _process_chunks, _build_decode_state
+        # and _dispatch_chunk open their own
         active = self._running_seqs()
         if active:
-            signature = self._decode_signature(active)
+            with self.perf.span("schedule"):
+                signature = self._decode_signature(active)
             if signature != self._decode_signature_cache:
                 # membership changed: all in-flight chunks must be folded
                 # into host state before rebuilding the device state.  The
@@ -2270,47 +2302,62 @@ class EngineCore:
                 # membership dispatch against stale device tokens/positions.
                 self._decode_signature_cache = None
                 self._process_chunks(drain=True)
-                active = self._running_seqs()
-                if active:
-                    chunk = self._pick_chunk(active)
-                    if self.scheduler.prepare_decode(active, horizon=chunk):
+                with self.perf.span("schedule"):
+                    active = self._running_seqs()
+                    chunk = self._pick_chunk(active) if active else 0
+                    if active and self.scheduler.prepare_decode(
+                        active, horizon=chunk
+                    ):
                         active = self._running_seqs()  # minus any victims
-                        if active:
-                            self._build_decode_state(active)
-                            self._decode_signature_cache = (
-                                self._decode_signature(active)
-                            )
-                            self._dispatch_chunk(active, chunk)
+                    else:
+                        chunk = 0
+                if chunk and active:
+                    self._build_decode_state(active)
+                    self._decode_signature_cache = (
+                        self._decode_signature(active)
+                    )
+                    self._dispatch_chunk(active, chunk)
                 worked = True
             elif len(self._pending_chunks) < self.pipeline_depth:
-                in_flight = sum(c[1] for c in self._pending_chunks)
-                chunk = self._pick_chunk(active, lead=in_flight)
+                survivors: List[Sequence] = []
+                new_sig = None
+                go = refresh = False
+                with self.perf.span("schedule"):
+                    in_flight = sum(c[1] for c in self._pending_chunks)
+                    chunk = self._pick_chunk(active, lead=in_flight)
+                    if chunk and self.scheduler.prepare_decode(
+                        active, horizon=in_flight + chunk
+                    ):
+                        # preemption changes membership -> handled next
+                        # tick; dispatch when the slot set survived
+                        # intact, refreshing only the page-table upload
+                        # when pages merely grew (tokens/positions stay
+                        # device-resident — a drain here would collapse
+                        # the pipeline at every page boundary)
+                        survivors = self._running_seqs()
+                        new_sig = self._decode_signature(survivors)
+                        if new_sig == self._decode_signature_cache:
+                            go = True
+                        elif [
+                            t[:3] for t in new_sig
+                        ] == [
+                            t[:3]
+                            for t in self._decode_signature_cache or ()
+                        ]:
+                            # identity (incl. preempt epoch) intact, only
+                            # page counts grew -> page-table refresh is
+                            # sufficient
+                            go = refresh = True
                 if chunk == 0:
                     # every sequence's budget is already covered by the
                     # in-flight steps — a new chunk would be pure overshoot
                     self._process_chunks()
-                elif self.scheduler.prepare_decode(
-                    active, horizon=in_flight + chunk
-                ):
-                    # preemption changes membership -> handled next tick;
-                    # dispatch when the slot set survived intact, refreshing
-                    # only the page-table upload when pages merely grew
-                    # (tokens/positions stay device-resident — a drain here
-                    # would collapse the pipeline at every page boundary)
-                    survivors = self._running_seqs()
-                    new_sig = self._decode_signature(survivors)
-                    if new_sig == self._decode_signature_cache:
-                        self._dispatch_chunk(active, chunk)
-                    elif [
-                        t[:3] for t in new_sig
-                    ] == [
-                        t[:3] for t in self._decode_signature_cache or ()
-                    ]:
-                        # identity (incl. preempt epoch) intact, only page
-                        # counts grew -> page-table refresh is sufficient
-                        self._refresh_page_tables(survivors)
+                elif go:
+                    if refresh:
+                        with self.perf.span("state"):
+                            self._refresh_page_tables(survivors)
                         self._decode_signature_cache = new_sig
-                        self._dispatch_chunk(active, chunk)
+                    self._dispatch_chunk(active, chunk)
                 worked = True
 
         if self._pending_chunks and (
@@ -2340,6 +2387,14 @@ class EngineCore:
             or bool(self._pending_chunks)
             or self.scheduler.has_admissible_waiting()
         )
+
+    def _schedule_args(self) -> Dict[str, int]:
+        """Arguments of a ``vgt.engine.schedule`` trace span (only
+        evaluated while a profile capture runs)."""
+        return {
+            "waiting": len(self.scheduler.waiting),
+            "running": len(self.scheduler.running),
+        }
 
     @engine_thread_only
     def _running_seqs(self) -> List[Sequence]:
@@ -2512,26 +2567,96 @@ class EngineCore:
         weak-2; the capability vLLM's continuous batching provides opaquely
         at the reference's vgate/backends/vllm_backend.py:51)."""
         limit = self.config.tpu.prefill_admit_limit
-        decoding = bool(self._running_seqs())
         plans: List[PrefillPlan] = []
         swap_plans: List[SwapInPlan] = []
         start = time.perf_counter()
-        while True:
-            if decoding and limit and len(plans) + len(swap_plans) >= limit:
-                break
-            plan = self.scheduler.try_admit()
-            if plan is None:
-                break
-            if isinstance(plan, SwapInPlan):
-                swap_plans.append(plan)
-            else:
-                plans.append(plan)
+        with self.perf.span("schedule", self._schedule_args):
+            decoding = bool(self._running_seqs())
+            while True:
+                if (
+                    decoding and limit
+                    and len(plans) + len(swap_plans) >= limit
+                ):
+                    break
+                plan = self.scheduler.try_admit()
+                if plan is None:
+                    break
+                if isinstance(plan, SwapInPlan):
+                    swap_plans.append(plan)
+                else:
+                    plans.append(plan)
         for plan in swap_plans:
             # host-swap re-admission: a jitted host->device scatter
             # replaces the re-prefill entirely — zero recompute tokens
             self._dispatch_swap_in(plan)
         if not plans:
             return bool(swap_plans)
+        with self.perf.span("schedule"):
+            plan_epochs, chunked, by_bucket = self._stage_prefills(plans)
+        dispatched = [  # (group plans, [B] device tokens)
+            ([plan], self._dispatch_chunked_prefill(plan))
+            for plan in chunked
+        ]
+        batch_max = max(1, self.config.tpu.prefill_batch_max)
+        for (bucket, cached, unaligned), group in sorted(by_bucket.items()):
+            for i in range(0, len(group), batch_max):
+                chunk = group[i : i + batch_max]
+                if cached:
+                    handle = self._dispatch_suffix_group(
+                        chunk, bucket, unaligned=unaligned
+                    )
+                else:
+                    handle = self._dispatch_prefill_group(chunk, bucket)
+                dispatched.append((chunk, handle))
+        # index the freshly-filled prompt pages only now, with every
+        # writer program enqueued: a reader admitted in a LATER tick is
+        # guaranteed to dispatch after the writer (device program order).
+        # A sequence a watchdog containment checkpointed mid-dispatch
+        # (its pages are already released) must not be indexed — the
+        # epoch guard mirrors the readback one below.
+        with self.perf.span("schedule"):
+            for plan in plans:
+                stale = (
+                    plan.seq.status is not SeqStatus.RUNNING
+                    or plan.seq.preempt_count != plan_epochs[id(plan)]
+                )
+                self.scheduler.commit_prefill(plan, stale=stale)
+        self._beat("prefill_readback", batch=len(plans))
+        # the perf split of the one existing sync (see _process_chunks):
+        # wait-for-compute (device_s), then the device_get transfer
+        # (readback_s)
+        handles = [h for _, h in dispatched]
+        with self.perf.span("device_wait") as wait:
+            jax.block_until_ready(handles)
+        with self.perf.span("readback") as read:
+            firsts = jax.device_get(handles)  # [(tok, lp)]
+        device_s, readback_s = wait.seconds, read.seconds
+        # batched admission costs one combined dispatch+readback; attribute
+        # an equal share to each prefill so observation count stays
+        # one-per-prefill and the histogram sum stays the true wall time
+        share = (time.perf_counter() - start) / len(plans)
+        for plan in plans:
+            metrics.observe_with_exemplar(
+                metrics.ENGINE_STEP_TIME.labels(kind="prefill"),
+                share,
+                trace_id=getattr(plan.seq.trace, "trace_id", None),
+            )
+        with self.perf.span("emit") as emit:
+            delivered = self._emit_first_tokens(
+                dispatched, firsts, plans, plan_epochs,
+                share, device_s, readback_s,
+            )
+            emit.note(tokens=delivered)
+        self.perf.note_tokens(delivered)
+        return True
+
+    @engine_thread_only
+    def _stage_prefills(self, plans: List[PrefillPlan]):
+        """Admission bookkeeping between try_admit and the dispatches
+        (one ``schedule`` bracket in _admit_and_prefill): the stale-wake
+        epochs, the flight/trace records, the fault probe, and the
+        grouping into batched programs.  Returns ``(plan_epochs,
+        chunked plans, {(bucket, cached, unaligned): plans})``."""
         # stale-wake epochs: if a watchdog-declared stall checkpoints
         # (preempt_count bump) and replays these sequences while this
         # thread is stuck in the device_get below, the replay may
@@ -2582,12 +2707,10 @@ class EngineCore:
         # Chunked plans (prompt > the bucket cap) run serial suffix
         # passes and never batch with others.
         by_bucket: Dict[tuple, List[PrefillPlan]] = {}
-        dispatched = []  # (group plans, [B] device tokens)
+        chunked: List[PrefillPlan] = []
         for plan in plans:
             if plan.chunked:
-                dispatched.append(
-                    ([plan], self._dispatch_chunked_prefill(plan))
-                )
+                chunked.append(plan)
                 continue
             key = (
                 plan.bucket,
@@ -2595,52 +2718,16 @@ class EngineCore:
                 plan.cached_len % self.geometry.page_size != 0,
             )
             by_bucket.setdefault(key, []).append(plan)
-        batch_max = max(1, self.config.tpu.prefill_batch_max)
-        for (bucket, cached, unaligned), group in sorted(by_bucket.items()):
-            for i in range(0, len(group), batch_max):
-                chunk = group[i : i + batch_max]
-                if cached:
-                    handle = self._dispatch_suffix_group(
-                        chunk, bucket, unaligned=unaligned
-                    )
-                else:
-                    handle = self._dispatch_prefill_group(chunk, bucket)
-                dispatched.append((chunk, handle))
-        # index the freshly-filled prompt pages only now, with every
-        # writer program enqueued: a reader admitted in a LATER tick is
-        # guaranteed to dispatch after the writer (device program order).
-        # A sequence a watchdog containment checkpointed mid-dispatch
-        # (its pages are already released) must not be indexed — the
-        # epoch guard mirrors the readback one below.
-        for plan in plans:
-            stale = (
-                plan.seq.status is not SeqStatus.RUNNING
-                or plan.seq.preempt_count != plan_epochs[id(plan)]
-            )
-            self.scheduler.commit_prefill(plan, stale=stale)
-        self._beat("prefill_readback", batch=len(plans))
-        # the perf split of the one existing sync (see _process_chunks):
-        # wait-for-compute (device_s), then the device_get transfer
-        # (readback_s)
-        readback_t0 = time.perf_counter()
-        handles = [h for _, h in dispatched]
-        jax.block_until_ready(handles)
-        device_s = time.perf_counter() - readback_t0
-        firsts = jax.device_get(handles)  # [(tok, lp)]
-        readback_s = time.perf_counter() - readback_t0 - device_s
-        self.perf.phase("device", device_s)
-        self.perf.phase("readback", readback_s)
-        # batched admission costs one combined dispatch+readback; attribute
-        # an equal share to each prefill so observation count stays
-        # one-per-prefill and the histogram sum stays the true wall time
-        share = (time.perf_counter() - start) / len(plans)
-        for plan in plans:
-            metrics.observe_with_exemplar(
-                metrics.ENGINE_STEP_TIME.labels(kind="prefill"),
-                share,
-                trace_id=getattr(plan.seq.trace, "trace_id", None),
-            )
-        detok_t0 = time.perf_counter()
+        return plan_epochs, chunked, by_bucket
+
+    @engine_thread_only
+    def _emit_first_tokens(
+        self, dispatched, firsts, plans, plan_epochs,
+        share: float, device_s: float, readback_s: float,
+    ) -> int:
+        """Fold a prefill wave's first tokens into host state (the
+        ``emit`` bracket of _admit_and_prefill); returns how many were
+        delivered."""
         delivered = 0
         for (group, _), (tokens, lp) in zip(dispatched, firsts):
             self.flight.record_tick(
@@ -2698,9 +2785,7 @@ class EngineCore:
                         tr.end("prefill", end_pc=boundary)
                         tr.start("decode", start_pc=boundary)
                     self._maybe_finish(plan.seq, token)
-        self.perf.phase("detok", time.perf_counter() - detok_t0)
-        self.perf.note_tokens(delivered)
-        return True
+        return delivered
 
     @engine_thread_only
     def _dispatch_swap_in(self, plan: SwapInPlan) -> None:
@@ -2840,42 +2925,43 @@ class EngineCore:
         discarded at readback."""
         n = len(plans)
         B = 1 << (n - 1).bit_length()  # next power of two
-        ps = self.geometry.page_size
-        n_bucket_pages = bucket // ps
-        tokens = np.zeros((B, bucket), np.int32)
-        seq_lens = np.ones((B,), np.int32)
-        prefill_pt = np.zeros((B, n_bucket_pages), np.int32)
-        temps = np.zeros((B,), np.float32)
-        top_ps = np.ones((B,), np.float32)
-        top_ks = np.zeros((B,), np.int32)
-        seeds = np.full((B,), -1, np.int32)
-        steps = np.zeros((B,), np.int32)
-        for row, plan in enumerate(plans):
-            seq = plan.seq
-            n_prompt = seq.num_prompt_tokens
-            tokens[row, :n_prompt] = seq.prompt_ids
-            seq_lens[row] = n_prompt
-            # decode-side page table row: real pages then trash padding
-            slot_row = self._page_tables_np[plan.slot]
-            slot_row[:] = 0
-            slot_row[: len(seq.pages)] = seq.pages
-            prefill_pt[row, : len(seq.pages)] = seq.pages[:n_bucket_pages]
-            sp = seq.params
-            temps[row] = sp.temperature
-            top_ps[row] = sp.top_p
-            top_ks[row] = sp.top_k
-            if sp.seed is not None:
-                # token i always draws from (seed, i): the prefill samples
-                # token index num_generated (0 fresh, >0 after preemption)
-                seeds[row] = sp.seed
-            steps[row] = seq.num_generated
-        pen_counts, pen_freq, pen_pres = self._group_penalties(plans, B)
-        mt, mt_ids = self._min_token_arrays(
-            B, ((row, p.seq) for row, p in enumerate(plans))
-        )
-        lb_ids, lb_vals = self._logit_bias_arrays(
-            B, ((row, p.seq) for row, p in enumerate(plans))
-        )
+        with self.perf.span("state", lambda: {"rows": B}):
+            ps = self.geometry.page_size
+            n_bucket_pages = bucket // ps
+            tokens = np.zeros((B, bucket), np.int32)
+            seq_lens = np.ones((B,), np.int32)
+            prefill_pt = np.zeros((B, n_bucket_pages), np.int32)
+            temps = np.zeros((B,), np.float32)
+            top_ps = np.ones((B,), np.float32)
+            top_ks = np.zeros((B,), np.int32)
+            seeds = np.full((B,), -1, np.int32)
+            steps = np.zeros((B,), np.int32)
+            for row, plan in enumerate(plans):
+                seq = plan.seq
+                n_prompt = seq.num_prompt_tokens
+                tokens[row, :n_prompt] = seq.prompt_ids
+                seq_lens[row] = n_prompt
+                # decode-side page table row: real pages then trash padding
+                slot_row = self._page_tables_np[plan.slot]
+                slot_row[:] = 0
+                slot_row[: len(seq.pages)] = seq.pages
+                prefill_pt[row, : len(seq.pages)] = seq.pages[:n_bucket_pages]
+                sp = seq.params
+                temps[row] = sp.temperature
+                top_ps[row] = sp.top_p
+                top_ks[row] = sp.top_k
+                if sp.seed is not None:
+                    # token i always draws from (seed, i): the prefill samples
+                    # token index num_generated (0 fresh, >0 after preemption)
+                    seeds[row] = sp.seed
+                steps[row] = seq.num_generated
+            pen_counts, pen_freq, pen_pres = self._group_penalties(plans, B)
+            mt, mt_ids = self._min_token_arrays(
+                B, ((row, p.seq) for row, p in enumerate(plans))
+            )
+            lb_ids, lb_vals = self._logit_bias_arrays(
+                B, ((row, p.seq) for row, p in enumerate(plans))
+            )
         num_lp = (
             LOGPROBS_K
             if any(p.seq.params.logprobs for p in plans)
@@ -2900,37 +2986,41 @@ class EngineCore:
                 if plan.seq.trace is not None:
                     plan.seq.trace.event("xla_compile", bucket=bucket)
         self._beat("prefill", compiling=fresh, bucket=bucket, batch=B)
-        dispatch_t0 = time.perf_counter()
-        out, self.k_pages, self.v_pages = _prefill_step(
-            self.params,
-            self.spec,
-            jnp.asarray(tokens),
-            jnp.asarray(seq_lens),
-            self.k_pages,
-            self.v_pages,
-            jnp.asarray(prefill_pt),
-            jnp.asarray(temps),
-            jnp.asarray(top_ps),
-            jnp.asarray(top_ks),
-            self._step_key(),
-            mesh=self._attn_mesh,
-            use_pallas=self.use_pallas,
-            seeds=jnp.asarray(seeds),
-            steps=jnp.asarray(steps),
-            num_logprobs=num_lp,
-            counts=pen_counts,
-            freq_pens=pen_freq,
-            pres_pens=pen_pres,
-            min_toks=mt,
-            stop_id_mat=mt_ids,
-            bias_ids=lb_ids,
-            bias_vals=lb_vals,
-        )
-        dispatch_s = time.perf_counter() - dispatch_t0
-        self.perf.phase("dispatch", dispatch_s)
+        with self.perf.span(
+            "prefill_dispatch",
+            lambda: {
+                "program": "prefill", "bucket": bucket, "rows": B,
+                "ctx_tokens": sum(p.seq.num_prompt_tokens for p in plans),
+            },
+        ) as disp:
+            out, self.k_pages, self.v_pages = _prefill_step(
+                self.params,
+                self.spec,
+                jnp.asarray(tokens),
+                jnp.asarray(seq_lens),
+                self.k_pages,
+                self.v_pages,
+                jnp.asarray(prefill_pt),
+                jnp.asarray(temps),
+                jnp.asarray(top_ps),
+                jnp.asarray(top_ks),
+                self._step_key(),
+                mesh=self._attn_mesh,
+                use_pallas=self.use_pallas,
+                seeds=jnp.asarray(seeds),
+                steps=jnp.asarray(steps),
+                num_logprobs=num_lp,
+                counts=pen_counts,
+                freq_pens=pen_freq,
+                pres_pens=pen_pres,
+                min_toks=mt,
+                stop_id_mat=mt_ids,
+                bias_ids=lb_ids,
+                bias_vals=lb_vals,
+            )
         if fresh:
             self.perf.record_compile(
-                "prefill", key, dispatch_s, trigger="bucket"
+                "prefill", key, disp.seconds, trigger="bucket"
             )
         return out  # (first tokens [B], logprob triple or None)
 
@@ -2964,67 +3054,68 @@ class EngineCore:
         B = 1 << (n - 1).bit_length()
         ps = self.geometry.page_size
         n_suffix_pages = bucket // ps + (1 if unaligned else 0)
-        # copy-on-write: duplicate the shared head of each diverging
-        # page into the sequence's own first page BEFORE the suffix
-        # program that writes the rest of that page
-        for plan in plans:
-            if plan.cow is not None:
-                src, dst, upto = plan.cow
-                self.k_pages, self.v_pages = _cow_copy_pages(
-                    self.k_pages, self.v_pages,
-                    jnp.asarray(src, jnp.int32),
-                    jnp.asarray(dst, jnp.int32),
-                    jnp.asarray(upto, jnp.int32),
-                )
-                if self.radix_cache is not None:
-                    self.radix_cache.total_cow_copies += 1
-                metrics.PREFIX_COW_COPIES.inc()
-        # context window bucketed to a power of two of pages: bounds both
-        # the KV gather and the compile-variant count
-        max_ctx_pages = max(
-            cdiv(p.seq.num_prompt_tokens, ps) for p in plans
-        )
-        ctx_pages = min(
-            self.geometry.pages_per_seq,
-            1 << max(0, max_ctx_pages - 1).bit_length(),
-        )
-        tokens = np.zeros((B, bucket), np.int32)
-        prefix_lens = np.zeros((B,), np.int32)
-        suffix_lens = np.ones((B,), np.int32)
-        suffix_pt = np.zeros((B, n_suffix_pages), np.int32)
-        full_pt = np.zeros((B, ctx_pages), np.int32)
-        temps = np.zeros((B,), np.float32)
-        top_ps = np.ones((B,), np.float32)
-        top_ks = np.zeros((B,), np.int32)
-        seeds = np.full((B,), -1, np.int32)
-        steps = np.zeros((B,), np.int32)
-        for row, plan in enumerate(plans):
-            seq = plan.seq
-            cached_pages = plan.cached_len // ps
-            suffix = seq.prompt_ids[plan.cached_len :]
-            tokens[row, : len(suffix)] = suffix
-            prefix_lens[row] = plan.cached_len
-            suffix_lens[row] = len(suffix)
-            own = seq.pages[cached_pages:]
-            suffix_pt[row, : len(own)] = own[:n_suffix_pages]
-            slot_row = self._page_tables_np[plan.slot]
-            slot_row[:] = 0
-            slot_row[: len(seq.pages)] = seq.pages
-            full_pt[row, : len(seq.pages)] = seq.pages[:ctx_pages]
-            sp = seq.params
-            temps[row] = sp.temperature
-            top_ps[row] = sp.top_p
-            top_ks[row] = sp.top_k
-            if sp.seed is not None:
-                seeds[row] = sp.seed
-            steps[row] = seq.num_generated
-        pen_counts, pen_freq, pen_pres = self._group_penalties(plans, B)
-        mt, mt_ids = self._min_token_arrays(
-            B, ((row, p.seq) for row, p in enumerate(plans))
-        )
-        lb_ids, lb_vals = self._logit_bias_arrays(
-            B, ((row, p.seq) for row, p in enumerate(plans))
-        )
+        with self.perf.span("state", lambda: {"rows": B}):
+            # copy-on-write: duplicate the shared head of each diverging
+            # page into the sequence's own first page BEFORE the suffix
+            # program that writes the rest of that page
+            for plan in plans:
+                if plan.cow is not None:
+                    src, dst, upto = plan.cow
+                    self.k_pages, self.v_pages = _cow_copy_pages(
+                        self.k_pages, self.v_pages,
+                        jnp.asarray(src, jnp.int32),
+                        jnp.asarray(dst, jnp.int32),
+                        jnp.asarray(upto, jnp.int32),
+                    )
+                    if self.radix_cache is not None:
+                        self.radix_cache.total_cow_copies += 1
+                    metrics.PREFIX_COW_COPIES.inc()
+            # context window bucketed to a power of two of pages: bounds both
+            # the KV gather and the compile-variant count
+            max_ctx_pages = max(
+                cdiv(p.seq.num_prompt_tokens, ps) for p in plans
+            )
+            ctx_pages = min(
+                self.geometry.pages_per_seq,
+                1 << max(0, max_ctx_pages - 1).bit_length(),
+            )
+            tokens = np.zeros((B, bucket), np.int32)
+            prefix_lens = np.zeros((B,), np.int32)
+            suffix_lens = np.ones((B,), np.int32)
+            suffix_pt = np.zeros((B, n_suffix_pages), np.int32)
+            full_pt = np.zeros((B, ctx_pages), np.int32)
+            temps = np.zeros((B,), np.float32)
+            top_ps = np.ones((B,), np.float32)
+            top_ks = np.zeros((B,), np.int32)
+            seeds = np.full((B,), -1, np.int32)
+            steps = np.zeros((B,), np.int32)
+            for row, plan in enumerate(plans):
+                seq = plan.seq
+                cached_pages = plan.cached_len // ps
+                suffix = seq.prompt_ids[plan.cached_len :]
+                tokens[row, : len(suffix)] = suffix
+                prefix_lens[row] = plan.cached_len
+                suffix_lens[row] = len(suffix)
+                own = seq.pages[cached_pages:]
+                suffix_pt[row, : len(own)] = own[:n_suffix_pages]
+                slot_row = self._page_tables_np[plan.slot]
+                slot_row[:] = 0
+                slot_row[: len(seq.pages)] = seq.pages
+                full_pt[row, : len(seq.pages)] = seq.pages[:ctx_pages]
+                sp = seq.params
+                temps[row] = sp.temperature
+                top_ps[row] = sp.top_p
+                top_ks[row] = sp.top_k
+                if sp.seed is not None:
+                    seeds[row] = sp.seed
+                steps[row] = seq.num_generated
+            pen_counts, pen_freq, pen_pres = self._group_penalties(plans, B)
+            mt, mt_ids = self._min_token_arrays(
+                B, ((row, p.seq) for row, p in enumerate(plans))
+            )
+            lb_ids, lb_vals = self._logit_bias_arrays(
+                B, ((row, p.seq) for row, p in enumerate(plans))
+            )
         num_lp = (
             LOGPROBS_K
             if any(p.seq.params.logprobs for p in plans)
@@ -3055,40 +3146,44 @@ class EngineCore:
                 if plan.seq.trace is not None:
                     plan.seq.trace.event("xla_compile", bucket=bucket)
         self._beat("prefill", compiling=fresh, bucket=bucket, batch=B)
-        dispatch_t0 = time.perf_counter()
-        out, self.k_pages, self.v_pages = _suffix_prefill_step(
-            self.params,
-            self.spec,
-            jnp.asarray(tokens),
-            jnp.asarray(prefix_lens),
-            jnp.asarray(suffix_lens),
-            self.k_pages,
-            self.v_pages,
-            jnp.asarray(suffix_pt),
-            jnp.asarray(full_pt),
-            jnp.asarray(temps),
-            jnp.asarray(top_ps),
-            jnp.asarray(top_ks),
-            self._step_key(),
-            seeds=jnp.asarray(seeds),
-            steps=jnp.asarray(steps),
-            num_logprobs=num_lp,
-            counts=pen_counts,
-            freq_pens=pen_freq,
-            pres_pens=pen_pres,
-            min_toks=mt,
-            stop_id_mat=mt_ids,
-            bias_ids=lb_ids,
-            bias_vals=lb_vals,
-            use_pallas=self.use_pallas,
-            mesh=self._mt_mesh,
-            unaligned=unaligned,
-        )
-        dispatch_s = time.perf_counter() - dispatch_t0
-        self.perf.phase("dispatch", dispatch_s)
+        with self.perf.span(
+            "prefill_dispatch",
+            lambda: {
+                "program": "suffix_prefill", "bucket": bucket, "rows": B,
+                "ctx_tokens": sum(p.seq.num_prompt_tokens for p in plans),
+            },
+        ) as disp:
+            out, self.k_pages, self.v_pages = _suffix_prefill_step(
+                self.params,
+                self.spec,
+                jnp.asarray(tokens),
+                jnp.asarray(prefix_lens),
+                jnp.asarray(suffix_lens),
+                self.k_pages,
+                self.v_pages,
+                jnp.asarray(suffix_pt),
+                jnp.asarray(full_pt),
+                jnp.asarray(temps),
+                jnp.asarray(top_ps),
+                jnp.asarray(top_ks),
+                self._step_key(),
+                seeds=jnp.asarray(seeds),
+                steps=jnp.asarray(steps),
+                num_logprobs=num_lp,
+                counts=pen_counts,
+                freq_pens=pen_freq,
+                pres_pens=pen_pres,
+                min_toks=mt,
+                stop_id_mat=mt_ids,
+                bias_ids=lb_ids,
+                bias_vals=lb_vals,
+                use_pallas=self.use_pallas,
+                mesh=self._mt_mesh,
+                unaligned=unaligned,
+            )
         if fresh:
             self.perf.record_compile(
-                "suffix_prefill", key, dispatch_s, trigger="bucket"
+                "suffix_prefill", key, disp.seconds, trigger="bucket"
             )
         return out  # (first tokens [B], logprob triple or None)
 
@@ -3146,31 +3241,35 @@ class EngineCore:
             self._beat(
                 "prefill_chunk", compiling=fresh, bucket=chunk, batch=1
             )
-            dispatch_t0 = time.perf_counter()
-            _out, self.k_pages, self.v_pages = _suffix_prefill_step(
-                self.params,
-                self.spec,
-                jnp.asarray(tokens),
-                jnp.asarray([start], jnp.int32),
-                jnp.asarray([n], jnp.int32),
-                self.k_pages,
-                self.v_pages,
-                jnp.asarray(suffix_pt),
-                jnp.asarray(full_pt),
-                jnp.zeros((1,), jnp.float32),
-                jnp.ones((1,), jnp.float32),
-                jnp.zeros((1,), jnp.int32),
-                self._step_key(),
-                seeds=jnp.full((1,), -1, jnp.int32),
-                steps=jnp.zeros((1,), jnp.int32),
-                use_pallas=self.use_pallas,
-                mesh=self._mt_mesh,
-            )
-            dispatch_s = time.perf_counter() - dispatch_t0
-            self.perf.phase("dispatch", dispatch_s)
+            with self.perf.span(
+                "prefill_dispatch",
+                lambda: {
+                    "program": "chunked_prefill", "bucket": chunk,
+                    "rows": 1, "ctx_tokens": start + n,
+                },
+            ) as disp:
+                _out, self.k_pages, self.v_pages = _suffix_prefill_step(
+                    self.params,
+                    self.spec,
+                    jnp.asarray(tokens),
+                    jnp.asarray([start], jnp.int32),
+                    jnp.asarray([n], jnp.int32),
+                    self.k_pages,
+                    self.v_pages,
+                    jnp.asarray(suffix_pt),
+                    jnp.asarray(full_pt),
+                    jnp.zeros((1,), jnp.float32),
+                    jnp.ones((1,), jnp.float32),
+                    jnp.zeros((1,), jnp.int32),
+                    self._step_key(),
+                    seeds=jnp.full((1,), -1, jnp.int32),
+                    steps=jnp.zeros((1,), jnp.int32),
+                    use_pallas=self.use_pallas,
+                    mesh=self._mt_mesh,
+                )
             if fresh:
                 self.perf.record_compile(
-                    "chunked_prefill", key, dispatch_s,
+                    "chunked_prefill", key, disp.seconds,
                     trigger="ctx_width",
                 )
             start += n
@@ -3209,6 +3308,11 @@ class EngineCore:
 
     @engine_thread_only
     def _build_decode_state(self, seqs: List[Sequence]) -> None:
+        with self.perf.span("state", lambda: {"rows": len(seqs)}):
+            self._build_decode_state_arrays(seqs)
+
+    @engine_thread_only
+    def _build_decode_state_arrays(self, seqs: List[Sequence]) -> None:
         self.total_state_rebuilds += 1
         B = self.max_slots
         tokens = np.zeros((B,), np.int32)
@@ -3353,61 +3457,68 @@ class EngineCore:
         guard = (
             self.integrity is not None and self.integrity.guard_enabled
         )
-        dispatch_t0 = time.perf_counter()
-        start = dispatch_t0
-        (
-            chunk_tokens,
-            chunk_lp,
-            state["tokens"],
-            state["positions"],
-            state["counter"],
-            state["steps"],
-            state["counts"],
-            self.k_pages,
-            self.v_pages,
-            chunk_flags,
-        ) = _decode_chunk(
-            self.params,
-            self.spec,
-            state["tokens"],
-            state["positions"],
-            self.k_pages,
-            self.v_pages,
-            state["page_tables"],
-            state["active"],
-            state["temps"],
-            state["top_ps"],
-            state["top_ks"],
-            self._base_key,
-            state["counter"],
-            num_steps=chunk,
-            use_pallas=self.use_pallas,
-            max_position=self.config.model.max_model_len - 1,
-            seeds=state["seeds"],
-            steps=state["steps"],
-            mesh=self._attn_mesh,
-            num_logprobs=num_lp,
-            counts=state["counts"],
-            freq_pens=state["freq_pens"],
-            pres_pens=state["pres_pens"],
-            min_toks=state["min_toks"],
-            stop_id_mat=state["stop_id_mat"],
-            all_greedy=all_greedy,
-            bias_ids=state["bias_ids"],
-            bias_vals=state["bias_vals"],
-            guard=guard,
-            guard_threshold=(
-                self.config.integrity.saturate_threshold if guard else 1.0e4
-            ),
-        )
+        start = time.perf_counter()
         # the jitted-call return is trace+enqueue (dispatch_s); a fresh
         # variant's call also compiles synchronously, so its duration
         # IS the compile cost the ledger records
-        dispatch_s = time.perf_counter() - dispatch_t0
-        self.perf.phase("dispatch", dispatch_s)
+        with self.perf.span(
+            "decode_dispatch",
+            lambda: {
+                "program": "decode", "steps": chunk, "rows": len(active),
+                "ctx_tokens": sum(s.total_len for s in active),
+                # steps in flight that ctx_tokens does not hold yet
+                "lead": sum(c[1] for c in self._pending_chunks),
+            },
+        ) as disp:
+            (
+                chunk_tokens,
+                chunk_lp,
+                state["tokens"],
+                state["positions"],
+                state["counter"],
+                state["steps"],
+                state["counts"],
+                self.k_pages,
+                self.v_pages,
+                chunk_flags,
+            ) = _decode_chunk(
+                self.params,
+                self.spec,
+                state["tokens"],
+                state["positions"],
+                self.k_pages,
+                self.v_pages,
+                state["page_tables"],
+                state["active"],
+                state["temps"],
+                state["top_ps"],
+                state["top_ks"],
+                self._base_key,
+                state["counter"],
+                num_steps=chunk,
+                use_pallas=self.use_pallas,
+                max_position=self.config.model.max_model_len - 1,
+                seeds=state["seeds"],
+                steps=state["steps"],
+                mesh=self._attn_mesh,
+                num_logprobs=num_lp,
+                counts=state["counts"],
+                freq_pens=state["freq_pens"],
+                pres_pens=state["pres_pens"],
+                min_toks=state["min_toks"],
+                stop_id_mat=state["stop_id_mat"],
+                all_greedy=all_greedy,
+                bias_ids=state["bias_ids"],
+                bias_vals=state["bias_vals"],
+                guard=guard,
+                guard_threshold=(
+                    self.config.integrity.saturate_threshold
+                    if guard else 1.0e4
+                ),
+            )
         if fresh:
             self.perf.record_compile(
-                "decode", chunk_key, dispatch_s, trigger="chunk_variant"
+                "decode", chunk_key, disp.seconds, trigger="chunk_variant"
             )
         self._step_counter += chunk
         # snapshot preempt_count as an epoch: a sequence preempted while
@@ -3431,24 +3542,22 @@ class EngineCore:
             # dispatch-to-now would double-count deliberate pipeline
             # queueing when more than one chunk is in flight
             self._beat("decode_readback", chunk=chunk, batch=len(seqs))
-            block_start = time.perf_counter()
             # perf attribution splits the ONE sync this path already
             # had: block_until_ready is the wait-for-compute share
             # (device_s), the asarray transfers after it (readback_s) —
             # no sync is added the np.asarray would not have paid
-            jax.block_until_ready(tokens_dev)
-            device_t = time.perf_counter()
-            sampled = np.asarray(tokens_dev)  # [chunk, B]
-            sampled = faults.corrupt_array("decode_step", sampled)
-            lp_np = (
-                None
-                if lp_dev is None
-                else tuple(np.asarray(a) for a in lp_dev)
-            )
-            block_s = time.perf_counter() - block_start
-            device_s = device_t - block_start
-            self.perf.phase("device", device_s)
-            self.perf.phase("readback", block_s - device_s)
+            with self.perf.span("device_wait") as wait:
+                jax.block_until_ready(tokens_dev)
+            with self.perf.span("readback") as read:
+                sampled = np.asarray(tokens_dev)  # [chunk, B]
+                sampled = faults.corrupt_array("decode_step", sampled)
+                lp_np = (
+                    None
+                    if lp_dev is None
+                    else tuple(np.asarray(a) for a in lp_dev)
+                )
+            device_s = wait.seconds
+            block_s = device_s + read.seconds
             if self.perf.enabled:
                 self.perf.note_decode(
                     steps=chunk,
@@ -3520,9 +3629,8 @@ class EngineCore:
             # is above): see _admit_and_prefill — the epoch guard is
             # check-then-append, and containment's fold must not
             # interleave with it
-            detok_t0 = time.perf_counter()
             delivered = 0
-            with self._readback_lock:
+            with self.perf.span("emit") as emit, self._readback_lock:
                 for seq, epoch in seqs:
                     if (
                         seq.status is not SeqStatus.RUNNING
@@ -3540,9 +3648,7 @@ class EngineCore:
                         self._maybe_finish(seq, token)
                         if seq.status is not SeqStatus.RUNNING:
                             break
-            self.perf.phase(
-                "detok", time.perf_counter() - detok_t0
-            )
+                emit.note(tokens=delivered)
             self.perf.note_tokens(delivered)
             self.total_steps += chunk
             if not drain:
@@ -3707,12 +3813,18 @@ class EngineCore:
             batch=len(active),
         )
         self._compiled_spec.add(spec_key)
-        dispatch_t0 = time.perf_counter()
-        (
-            model_toks, accepted, lp_data, counts_out,
-            self.k_pages, self.v_pages,
-        ) = (
-            _spec_verify_step(
+        with self.perf.span(
+            "decode_dispatch",
+            lambda: {
+                "program": "spec_verify", "steps": 1,
+                "rows": len(active),
+                "ctx_tokens": sum(s.total_len for s in active),
+            },
+        ) as disp:
+            (
+                model_toks, accepted, lp_data, counts_out,
+                self.k_pages, self.v_pages,
+            ) = _spec_verify_step(
                 self.params,
                 self.spec,
                 jnp.asarray(tokens),
@@ -3747,12 +3859,9 @@ class EngineCore:
                 bias_vals=spec_lb_vals,
                 mesh=self._mt_mesh,
             )
-        )
-        dispatch_s = time.perf_counter() - dispatch_t0
-        self.perf.phase("dispatch", dispatch_s)
         if fresh:
             self.perf.record_compile(
-                "spec_verify", spec_key, dispatch_s,
+                "spec_verify", spec_key, disp.seconds,
                 trigger="spec_width",
             )
             self._note_attention("spec_verify", multitok_attention_impl(
@@ -3762,29 +3871,28 @@ class EngineCore:
             self._spec_pen["counts"] = counts_out
         self._step_counter += 1
         # perf split of the existing sync (see _process_chunks)
-        device_t0 = time.perf_counter()
-        jax.block_until_ready((model_toks, accepted))
-        device_s = time.perf_counter() - device_t0
-        toks_np = np.asarray(model_toks)  # [B, S]
-        acc_np = np.asarray(accepted)
-        lp_np = None
-        if lp_data is not None:
-            # transpose to step-major so _attach_logprob's [step][slot]
-            # indexing applies
-            lp_np = (
-                np.asarray(lp_data[0]).T,
-                np.transpose(np.asarray(lp_data[1]), (1, 0, 2)),
-                np.transpose(np.asarray(lp_data[2]), (1, 0, 2)),
-            )
+        with self.perf.span("device_wait") as wait:
+            jax.block_until_ready((model_toks, accepted))
+        with self.perf.span("readback") as read:
+            toks_np = np.asarray(model_toks)  # [B, S]
+            acc_np = np.asarray(accepted)
+            lp_np = None
+            if lp_data is not None:
+                # transpose to step-major so _attach_logprob's
+                # [step][slot] indexing applies
+                lp_np = (
+                    np.asarray(lp_data[0]).T,
+                    np.transpose(np.asarray(lp_data[1]), (1, 0, 2)),
+                    np.transpose(np.asarray(lp_data[2]), (1, 0, 2)),
+                )
+        device_s, readback_s = wait.seconds, read.seconds
         spec_s = time.perf_counter() - start
-        readback_s = time.perf_counter() - device_t0 - device_s
-        self.perf.phase("device", device_s)
-        self.perf.phase("readback", readback_s)
         if self.perf.enabled:
             self.perf.note_decode(
                 steps=1,
                 ctx_tokens=sum(s.total_len for s in active),
                 device_s=device_s,
+                chunk=False,  # a verify pass, not a decode chunk
             )
         metrics.observe_with_exemplar(
             metrics.ENGINE_STEP_TIME.labels(kind="decode"),
@@ -3811,9 +3919,8 @@ class EngineCore:
         )
         # append under the readback lock (device waits all happened
         # above): see _admit_and_prefill for the interleaving hazard
-        detok_t0 = time.perf_counter()
         delivered = 0
-        with self._readback_lock:
+        with self.perf.span("emit") as emit, self._readback_lock:
             for seq in active:
                 # stale-wake guard (see _admit_and_prefill): status AND
                 # the epoch captured at dispatch — a watchdog stall
@@ -3840,7 +3947,7 @@ class EngineCore:
                     self._maybe_finish(seq, token)
                     if seq.status is not SeqStatus.RUNNING:
                         break
-        self.perf.phase("detok", time.perf_counter() - detok_t0)
+            emit.note(tokens=delivered)
         self.perf.note_tokens(delivered)
         self.total_steps += 1
         return True
@@ -4030,35 +4137,64 @@ class EngineCore:
         return time.perf_counter() - start
 
     def capture_profile(
-        self, duration_s: float = 1.0, out_dir: Optional[str] = None
+        self,
+        duration_s: float = 1.0,
+        out_dir: Optional[str] = None,
+        python_tracer: bool = False,
     ) -> Dict[str, Any]:
         """Capture a ``jax.profiler`` device trace while serving continues
         (SURVEY.md section 5.1: the reference has request-scoped OTel spans
         but no low-level profiler; on TPU the device timeline — kernel
         times, HBM traffic, infeed stalls — comes from the JAX profiler,
-        viewable in TensorBoard/XProf)."""
+        viewable in TensorBoard/XProf).
+
+        The Python tracer is OFF unless ``python_tracer``: it slows the
+        host loop it measures and bloats the trace.  What the host was
+        doing comes from the ``vgt.engine.*`` / ``vgt.gateway.*``
+        annotations the perf brackets open while the capture runs
+        (observability/perf.py); ``stop_s`` is how long writing the
+        trace stalled, ``file_bytes`` what it wrote."""
         out_dir = out_dir or os.path.join(
             tempfile.gettempdir(),
             f"vgt_profile_{int(time.time())}",
         )
         duration_s = max(0.05, min(duration_s, 60.0))
         capture_start = time.time()
-        jax.profiler.start_trace(out_dir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 1 if python_tracer else 0
+        jax.profiler.start_trace(out_dir, profiler_options=options)
+        set_capturing(True)
         try:
             time.sleep(duration_s)
         finally:
+            set_capturing(False)
+            # let the tick in progress close its spans inside the
+            # session (a prefill wave's tick lasts a second): spans
+            # still open at stop_trace are lost from the trace
+            ticks, deadline = self._ticks_done, time.monotonic() + 2.0
+            while (
+                self._running
+                and self._ticks_done == ticks
+                and time.monotonic() < deadline
+            ):
+                time.sleep(0.002)
+            stop_t0 = time.perf_counter()
             jax.profiler.stop_trace()
+            stop_s = time.perf_counter() - stop_t0
         # count only files this capture wrote (out_dir may be reused)
-        n_files = sum(
-            1
+        written = [
+            os.path.join(root, f)
             for root, _, files in os.walk(out_dir)
             for f in files
             if os.path.getmtime(os.path.join(root, f)) >= capture_start - 1
-        )
+        ]
         result = {
             "trace_dir": out_dir,
             "duration_s": duration_s,
-            "files": n_files,
+            "files": len(written),
+            "file_bytes": sum(os.path.getsize(f) for f in written),
+            "stop_s": round(stop_s, 4),
+            "python_tracer": bool(python_tracer),
         }
         # link the device-timeline capture to the attribution layer:
         # the flight ring shows WHEN the capture window sat relative to

@@ -14,6 +14,7 @@ into the einsum-friendly [in, out] layout used by models/decoder.py.
 from __future__ import annotations
 
 import os
+import time
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -22,6 +23,7 @@ import numpy as np
 
 from vgate_tpu.logging_config import get_logger
 from vgate_tpu.models.specs import ModelSpec
+from vgate_tpu.observability.perf import note_boot
 
 logger = get_logger(__name__)
 
@@ -253,6 +255,7 @@ def load_or_init_params(
         else:
             params = init_params(spec, key, dtype)
     if log_digests:
+        digest_start = time.perf_counter()
         try:
             logger.info(
                 "load-time weight digests",
@@ -260,4 +263,5 @@ def load_or_init_params(
             )
         except Exception:  # digest provenance must never block a load
             logger.warning("load-time digest pass failed", exc_info=True)
+        note_boot("digest", time.perf_counter() - digest_start)
     return params

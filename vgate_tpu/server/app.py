@@ -49,6 +49,7 @@ from vgate_tpu.errors import (
 )
 from vgate_tpu.lifecycle import CancelToken, DrainController
 from vgate_tpu.logging_config import get_logger, setup_logging
+from vgate_tpu.observability.perf import GATEWAY, note_boot, process_age_s
 from vgate_tpu.observability.reqtrace import RequestMeta
 from vgate_tpu.runtime.journal import (
     PENDING as _JOURNAL_PENDING,
@@ -147,7 +148,6 @@ async def observability_middleware(request: web.Request, handler):
     # sequence so /debug/requests/{X-Request-ID} finds the record)
     request["request_id"] = request_id
     start = time.perf_counter()
-    metrics.REQUESTS_IN_PROGRESS.inc()
     counted = _drain_counted(request.path)
     if counted:
         request.app["inflight"].value += 1
@@ -170,7 +170,6 @@ async def observability_middleware(request: web.Request, handler):
         logger.error("unhandled error", exc_info=True)
         return _error(500, "Internal server error", "server_error")
     finally:
-        metrics.REQUESTS_IN_PROGRESS.dec()
         if counted:
             request.app["inflight"].value -= 1
     elapsed = time.perf_counter() - start
@@ -655,6 +654,18 @@ def _chat_snapshot(
 
 async def chat_completions(request: web.Request) -> web.Response:
     """POST /v1/chat/completions (reference: main.py:207-252)."""
+    # vgt.gateway.ingress: handler entry -> submit_prompt returning
+    # (closed in the backend's stream_async for a streamed request;
+    # whatever is still open — a request not streamed or rejected, a
+    # write cut by a cancel — is closed on the way out)
+    GATEWAY.ingress_begin()
+    try:
+        return await _chat_completions(request)
+    finally:
+        GATEWAY.ingress_close()
+
+
+async def _chat_completions(request: web.Request) -> web.Response:
     try:
         payload = ChatCompletionRequest(**await request.json())
     except (ValidationError, ValueError) as exc:
@@ -1014,6 +1025,7 @@ async def _stream_chat(
                 )
             async with asyncio.timeout(timeout_s):
                 async for piece in stream_fn(prompt, params, **kwargs):
+                    t_write = GATEWAY.write_begin()
                     if isinstance(piece, dict):  # logprobs-carrying delta
                         await resp.write(
                             _chunk(
@@ -1023,6 +1035,8 @@ async def _stream_chat(
                         )
                     else:
                         await resp.write(_chunk({"content": piece}))
+                    if t_write is not None:
+                        GATEWAY.write_end(t_write)
             if usage_box["value"] is not None:
                 batcher.admission.observe_completion(
                     usage_box["value"].get("completion_tokens", 0)
@@ -1620,9 +1634,11 @@ async def debug_perf(request: web.Request) -> web.Response:
     """GET /debug/perf — the engine's perf-attribution snapshot
     (observability/perf.py): rolling-window phase decomposition +
     tok/s / MFU / HBM-roofline / host-overhead gauges, the compile
-    ledger, and the last /v1/profile capture.  dp>1 returns the merged
-    pod view with per-replica payloads attached.  Auth-gated like every
-    non-exempt path; excluded from drain accounting like /debug."""
+    ledger, the last /v1/profile capture, and monotone window counters
+    in ``totals`` (the gateway's own under ``totals.gateway``).  dp>1
+    returns the merged pod view with per-replica payloads attached.
+    Auth-gated like every non-exempt path; excluded from drain
+    accounting like /debug."""
     engine: Optional[VGTEngine] = request.app.get("engine")
     core = getattr(engine.backend, "core", None) if engine else None
     snapshot_fn = getattr(core, "perf_snapshot", None)
@@ -1632,7 +1648,10 @@ async def debug_perf(request: web.Request) -> web.Response:
              "reason": "engine has no perf recorder"}
         )
     try:
-        return web.json_response(snapshot_fn())
+        payload = snapshot_fn()
+        if isinstance(payload.get("totals"), dict):
+            payload["totals"]["gateway"] = GATEWAY.totals()
+        return web.json_response(payload)
     except Exception as exc:
         # a mid-rebuild engine must not 500 the attribution surface —
         # operators read it exactly while chasing a perf problem
@@ -1971,9 +1990,13 @@ async def capture_profile(request: web.Request) -> web.Response:
     """POST /v1/profile — capture a JAX device-profiler trace while serving
     continues (SURVEY.md section 5.1: adds the low-level profiler the
     reference lacks; OTel request tracing stays separate).  Body:
-    ``{"duration_ms": 1000, "out_dir": "/tmp/..."}`` (both optional;
-    out_dir must live under the system temp dir — traces are written as
-    the service user, so arbitrary paths are rejected)."""
+    ``{"duration_ms": 1000, "out_dir": "/tmp/...", "python_tracer":
+    false}`` (all optional; out_dir must live under the system temp dir
+    — traces are written as the service user, so arbitrary paths are
+    rejected).  The Python tracer is off by default: it slows the host
+    it measures; the engine's and the gateway's own ``vgt.*`` spans are
+    in the trace either way.  The response adds ``file_bytes`` and
+    ``stop_s`` (how long writing the trace stalled)."""
     engine: Optional[VGTEngine] = request.app.get("engine")
     core = getattr(engine.backend, "core", None) if engine else None
     if core is None or not hasattr(core, "capture_profile"):
@@ -1997,6 +2020,11 @@ async def capture_profile(request: web.Request) -> web.Response:
     except (TypeError, ValueError):
         return _error(
             422, "duration_ms must be a number", "invalid_request_error"
+        )
+    python_tracer = raw.get("python_tracer", False)
+    if not isinstance(python_tracer, bool):
+        return _error(
+            422, "python_tracer must be a boolean", "invalid_request_error"
         )
     out_dir = raw.get("out_dir")
     if out_dir is not None:
@@ -2023,7 +2051,13 @@ async def capture_profile(request: web.Request) -> web.Response:
     try:
         loop = asyncio.get_running_loop()
         result = await loop.run_in_executor(
-            None, lambda: core.capture_profile(duration_s, out_dir)
+            None,
+            lambda: core.capture_profile(
+                duration_s, out_dir,
+                # only named when asked for: the quiet default is every
+                # core's own
+                **({"python_tracer": True} if python_tracer else {}),
+            ),
         )
     finally:
         lock.release()
@@ -2297,6 +2331,13 @@ async def _on_startup(app: web.Application) -> None:
     # Model load can take minutes; do it off the event loop.
     engine = await loop.run_in_executor(None, lambda: VGTEngine(config))
     app["engine"] = engine
+    GATEWAY.enabled = bool(
+        config.observability.enabled and config.observability.perf_enabled
+    )
+    # /debug/perf totals.boot_seconds: process start -> engine ready
+    age = process_age_s()
+    if age is not None:
+        note_boot("ready", age)
     batcher = RequestBatcher(engine, config)
     app["batcher"] = batcher
     drain = _build_drain_controller(app, config)
