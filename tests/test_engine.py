@@ -254,7 +254,7 @@ def test_decode_flows_during_prefill_burst():
     try:
         long_seq = core.submit_prompt(
             "resident decoder", greedy(48),
-            stream_cb=lambda tok: events.append(
+            stream_cb=lambda toks, done: events.append(
                 ("decode", _time.perf_counter())
             ),
         )
@@ -268,7 +268,7 @@ def test_decode_flows_during_prefill_burst():
         for i in range(8):
             first_done = []
 
-            def cb(tok, first_done=first_done):
+            def cb(toks, done, first_done=first_done):
                 if not first_done:
                     first_done.append(True)
                     events.append(("first", _time.perf_counter()))
@@ -313,12 +313,21 @@ def test_engine_queue_full_fails_cleanly():
 
 
 def test_streaming_callback_order(engine):
-    tokens = []
+    """stream_cb takes what each readback appended, as a list, in
+    order; the call that settles the sequence says so, and is the
+    last."""
+    calls = []
     seq = engine.submit_prompt(
-        "stream probe", greedy(5), stream_cb=tokens.append
+        "stream probe", greedy(5),
+        stream_cb=lambda toks, done: calls.append((list(toks), done)),
     )
     seq.done_event.wait(timeout=120)
-    assert tokens == seq.generated_ids
+    assert [t for toks, _ in calls for t in toks] == seq.generated_ids
+    assert calls[0][0] == seq.generated_ids[:1]  # the prefill's token
+    assert len(calls) < 5  # decode chunks deliver several at a time
+    assert [done for _, done in calls] == [False] * (len(calls) - 1) + [
+        True
+    ]
 
 
 def test_chunk_overshoot_discarded(engine):

@@ -786,7 +786,8 @@ def test_no_annotation_is_built_without_a_capture(monkeypatch):
     rec.tick_end(worked=True)
     gateway.ingress_begin()
     gateway.ingress_end()
-    gateway.detok_end(gateway.detok_begin(gateway.stream_clock(), 1))
+    stream = gateway.stream_clock()
+    gateway.detok_end(stream, gateway.detok_begin(stream), 1)
     gateway.write_end(gateway.write_begin())
     assert opened == []
 
@@ -798,8 +799,8 @@ def test_no_annotation_is_built_without_a_capture(monkeypatch):
         rec.tick_end(worked=True)
         gateway.ingress_begin()
         gateway.ingress_end()
-        # while a capture runs every token is timed, not one in eight
-        gateway.detok_end(gateway.detok_begin(gateway.stream_clock(), 2))
+        stream = gateway.stream_clock()
+        gateway.detok_end(stream, gateway.detok_begin(stream), 2)
         gateway.write_end(gateway.write_begin())
     finally:
         perf_mod.set_capturing(False)
@@ -833,30 +834,35 @@ def test_gateway_counters_follow_one_stream():
     stream = gateway.stream_clock()
     gateway.ingress_end()
     gateway.ingress_end()  # closing twice counts once
-    def token(n, detok_s=0.0001, write_s=0.001):
-        """The stream's n-th token through decode and its SSE write;
-        True when it was one of the timed ones."""
-        t0 = gateway.detok_begin(stream, n)
+    def delivery(n_tokens, detok_s=0.0001, write_s=0.001, write=True):
+        """One delivery of ``n_tokens`` through detokenisation and,
+        unless its text is held back, its SSE write."""
+        t0 = gateway.detok_begin(stream)
         clock.advance(detok_s)
-        if t0 is not None:
-            gateway.detok_end(t0)
-        t1 = gateway.write_begin()
-        clock.advance(write_s)
-        if t1 is not None:
+        gateway.detok_end(stream, t0, n_tokens)
+        if write:
+            t1 = gateway.write_begin()
+            clock.advance(write_s)
             gateway.write_end(t1)
-        return t0 is not None and t1 is not None
 
     stream.t_first_token = clock()  # the engine thread's stamp
     clock.advance(0.004)
-    timed = [n for n in range(1, 20) if token(n)]
-    # one token in SAMPLE_EVERY is timed, the stream's first among them
-    assert timed == [1, 9, 17] and perf_mod.SAMPLE_EVERY == 8
+    # EVERY delivery is timed, whatever it carries; a held-back one's
+    # tokens ride in the next write
+    delivery(1)
+    delivery(8)
+    delivery(3, write=False)
+    delivery(5)
+    gateway.note_handoff()
     totals = gateway.totals()
     assert totals["ingress_n"] == 1
     assert totals["ingress_s"] == pytest.approx(0.020)
-    assert totals["stream_tokens"] == 3
-    assert totals["stream_detok_s"] == pytest.approx(0.0003)
+    assert totals["stream_tokens"] == 17  # tokens, not deliveries
+    assert totals["stream_detok_s"] == pytest.approx(0.0004)
     assert totals["stream_write_s"] == pytest.approx(0.003)
+    assert totals["stream_deliveries"] == 3  # content writes
+    assert totals["stream_tokens_delivered"] == 17
+    assert totals["stream_handoffs"] == 1
     assert totals["first_chunk_n"] == 1  # the first chunk on the wire
     assert totals["first_chunk_s"] == pytest.approx(0.0051)
     gateway.ingress_close()
@@ -866,42 +872,40 @@ def test_gateway_counters_follow_one_stream():
     off.ingress_begin()  # observability off: the stream gets no clock
     off.ingress_end()
     assert off.stream_clock() is None
-    assert off.detok_begin(off.stream_clock(), 1) is None
+    assert off.detok_begin(off.stream_clock()) is None
     assert off.write_begin() is None
+    off.note_handoff()
     assert set(off.totals().values()) == {0, 0.0}
 
 
 def test_first_chunk_is_timed_when_the_first_token_writes_nothing():
     """A stop-string hold-back (or half a UTF-8 piece) yields no text
-    for the stream's first tokens: every token is timed until the first
-    chunk is on the wire, so first_chunk_s ends at THAT write and not
-    at the next sampled token."""
+    for the stream's first deliveries: first_chunk_s ends at the first
+    write, and that write's delivery counts the tokens held until it."""
     clock = FakeClock()
     gateway = perf_mod.GatewayPerf(clock=clock)
     gateway.ingress_begin()
     stream = gateway.stream_clock()
     gateway.ingress_end()
     stream.t_first_token = clock()
-    for n in (1, 2):  # decoded, held back: no write
-        t0 = gateway.detok_begin(stream, n)
-        assert t0 is not None
+    for _ in range(2):  # decoded, held back: no write
+        t0 = gateway.detok_begin(stream)
         clock.advance(0.001)
-        gateway.detok_end(t0)
+        gateway.detok_end(stream, t0, 1)
         assert gateway.totals()["first_chunk_n"] == 0
-    t0 = gateway.detok_begin(stream, 3)  # 3 % 8 != 1, timed all the same
-    assert t0 is not None
+        assert gateway.totals()["stream_deliveries"] == 0
+    t0 = gateway.detok_begin(stream)
     clock.advance(0.001)
-    gateway.detok_end(t0)
+    gateway.detok_end(stream, t0, 4)
     t1 = gateway.write_begin()
     clock.advance(0.002)
     gateway.write_end(t1)
     totals = gateway.totals()
     assert totals["first_chunk_n"] == 1
     assert totals["first_chunk_s"] == pytest.approx(0.005)
-    # after the first chunk the one-in-eight sampling is back
-    assert gateway.detok_begin(stream, 4) is None
-    assert gateway.write_begin() is None
-    assert gateway.detok_begin(stream, 9) is not None
+    assert totals["stream_deliveries"] == 1
+    assert totals["stream_tokens_delivered"] == 6
+    assert totals["stream_tokens"] == 6
 
 
 def test_a_request_that_is_not_streamed_leaves_no_annotation_open(
@@ -937,7 +941,8 @@ def test_a_request_that_is_not_streamed_leaves_no_annotation_open(
         del events[:]
         gateway.ingress_begin()
         gateway.ingress_end()
-        gateway.detok_end(gateway.detok_begin(gateway.stream_clock(), 1))
+        stream = gateway.stream_clock()
+        gateway.detok_end(stream, gateway.detok_begin(stream), 1)
         gateway.write_begin()
         gateway.ingress_close()
     finally:

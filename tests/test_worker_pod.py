@@ -122,6 +122,7 @@ def test_current_epoch_frame_dispatched():
         generated_ids=[],
         tokens=[],
         append_token=lambda t: seq.tokens.append(t),
+        deliver=lambda: None,
     )
     pod._inflight[9] = seq
     pod._on_frame(0, 3, {"op": "tok", "sid": 9, "t": 42, "e": 3})

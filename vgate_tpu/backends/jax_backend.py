@@ -6,8 +6,8 @@ external GPU engines (vllm_backend.py:48-70), this backend owns the whole
 stack: JAX model runner, paged KV cache, continuous-batching scheduler and
 device-side sampling (runtime/engine_core.py).  Additional capabilities the
 gateway exploits when present: ``generate_async`` (sequences join the running
-engine between decode steps), ``stream_async`` (per-token SSE), ``embed``
-(real encoder embeddings) and ``device_health``.
+engine between decode steps), ``stream_async`` (SSE, one delta per engine
+readback), ``embed`` (real encoder embeddings) and ``device_health``.
 """
 
 from __future__ import annotations
@@ -18,7 +18,15 @@ import functools
 import os
 import threading
 import time
-from typing import Any, AsyncIterator, Dict, List, Optional, Sequence
+from typing import (
+    Any,
+    AsyncIterator,
+    Dict,
+    List,
+    Optional,
+    Sequence,
+    Tuple,
+)
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +41,7 @@ from vgate_tpu.models.specs import ModelSpec, spec_for_model_id
 from vgate_tpu.observability.perf import GATEWAY
 from vgate_tpu.runtime.engine_core import EngineCore
 from vgate_tpu.runtime.sequence import SeqStatus
+from vgate_tpu.runtime.tokenizer import IncrementalDetokenizer
 from vgate_tpu.utils.math import bucket_for, round_up
 from vgate_tpu.analysis.witness import named_lock
 
@@ -112,6 +121,47 @@ class Embedder:
         return out
 
 
+class _LoopHandoff:
+    """The seam between the engine thread(s) and ONE event loop.  A
+    readback posts what it appended to each of the loop's streams and
+    then wakes the loop once (``Sequence.deliver`` gathers the wakes,
+    the engine calls them after its loop over the sequences); the one
+    callback that wake-up schedules fans the deliveries out to the
+    streams' queues.  A wake-up already on its way serves whatever is
+    posted before it runs, so no second one is issued for it."""
+
+    def __init__(self, loop: asyncio.AbstractEventLoop) -> None:
+        self._loop = loop
+        self._lock = threading.Lock()
+        self._pending: List[tuple] = []  # (queue, item), in post order
+        self._armed = False
+
+    def post(self, q: "asyncio.Queue", item: Any) -> None:
+        with self._lock:
+            self._pending.append((q, item))
+
+    def wake(self) -> None:
+        with self._lock:
+            if self._armed or not self._pending:
+                return
+            self._armed = True
+        try:
+            self._loop.call_soon_threadsafe(self._drain)
+        except RuntimeError:
+            # loop closed: its streams are gone, aborts follow
+            with self._lock:
+                self._pending.clear()
+                self._armed = False
+
+    def _drain(self) -> None:
+        with self._lock:
+            batch, self._pending = self._pending, []
+            self._armed = False
+        GATEWAY.note_handoff()
+        for q, item in batch:
+            q.put_nowait(item)
+
+
 class JaxTPUBackend:
     """Continuous-batching TPU backend behind the 4-method protocol."""
 
@@ -121,6 +171,19 @@ class JaxTPUBackend:
         self.core: Optional[Any] = None
         self._embedder: Optional[Embedder] = None
         self._config = None
+        self._handoffs: Dict[Any, _LoopHandoff] = {}
+
+    def _handoff_for(
+        self, loop: asyncio.AbstractEventLoop
+    ) -> _LoopHandoff:
+        handoff = self._handoffs.get(loop)
+        if handoff is None:
+            self._handoffs = {
+                lp: h for lp, h in self._handoffs.items()
+                if not lp.is_closed()
+            }
+            handoff = self._handoffs[loop] = _LoopHandoff(loop)
+        return handoff
 
     # -- protocol --
 
@@ -363,7 +426,10 @@ class JaxTPUBackend:
         on_usage: Optional[Any] = None,
         request_meta: Optional[Any] = None,
     ) -> AsyncIterator[str]:
-        """Token-by-token text deltas for SSE streaming.  ``on_finish`` (if
+        """Text deltas for SSE streaming, one per delivery: what ONE
+        engine readback appended to this stream (a decode chunk's
+        tokens, several at a time under load) is detokenised and
+        yielded as a unit.  ``on_finish`` (if
         given) is called with the sequence's finish_reason after the last
         delta, so the gateway can close the stream with the true reason;
         ``on_usage`` (if given) receives the request's token usage dict
@@ -371,41 +437,32 @@ class JaxTPUBackend:
 
         With ``params.logprobs`` each yield is a dict ``{"text": delta,
         "logprobs": [entries for the tokens consumed since the previous
-        yield]}`` (deltas are text-level, and stop-string holdback means
-        a delta can span several tokens); plain requests yield bare
-        strings, the original contract."""
+        yield]}``; plain requests yield bare strings, the original
+        contract."""
         assert self.core is not None
-        loop = asyncio.get_running_loop()
-        q: "asyncio.Queue[Optional[int]]" = asyncio.Queue()
+        handoff = self._handoff_for(asyncio.get_running_loop())
+        # one item per delivery: (what a readback appended, settled?)
+        q: "asyncio.Queue[Tuple[List[int], bool]]" = asyncio.Queue()
 
         clock = GATEWAY.stream_clock()  # None outside the gateway
 
-        def on_token(token: int) -> None:
+        def on_tokens(tokens: List[int], done: bool):
             if clock is not None and clock.t_first_token is None:
-                # engine thread: first token handed to the gateway
+                # engine thread: first tokens handed to the gateway
                 # (gateway.first_chunk_* measures from here to the wire)
                 clock.t_first_token = time.perf_counter()
-            try:
-                loop.call_soon_threadsafe(q.put_nowait, token)
-            except RuntimeError:
-                pass  # loop closed: consumer disconnected, abort follows
+            handoff.post(q, (tokens, done))
+            return handoff.wake
 
         seq = self.core.submit_prompt(
-            prompt, params, stream_cb=on_token, meta=request_meta
+            prompt, params, stream_cb=on_tokens, meta=request_meta
         )
         GATEWAY.ingress_end()
 
-        def on_done() -> None:
-            seq.done_event.wait()
-            try:
-                loop.call_soon_threadsafe(q.put_nowait, None)
-            except RuntimeError:
-                pass  # loop closed: nothing left to notify
-
-        threading.Thread(target=on_done, daemon=True).start()
-
-        emitted = ""
-        ids: List[int] = []
+        detok = IncrementalDetokenizer(self.core.tokenizer)
+        held = ""  # text the tokens gave that no delta has carried yet
+        n_emitted = 0  # characters the deltas have carried
+        n_ids = 0
         pending_lp: List[Any] = []
 
         def wrap(delta: str):
@@ -415,52 +472,41 @@ class JaxTPUBackend:
             pending_lp.clear()
             return out
 
-        stops = params.stop or []
-        longest_stop = max((len(s) for s in stops), default=0)
+        # a stop-length tail is held back, so that a stop string that
+        # arrives across several tokens or deliveries is never partly
+        # emitted; WHERE the text is cut is the engine's verdict alone
+        # (final_text), which the stream's last delivery brings along
+        hold = max((len(s) for s in params.stop or []), default=0)
         completed = False
         try:
             while True:
-                token = await q.get()
-                if token is None:
-                    # flush the held-back tail: the engine's own stop
-                    # detection is authoritative (final_text truncates
-                    # at a stop match)
-                    final = self.core.final_text(seq)
-                    if len(final) > len(emitted) or pending_lp:
-                        yield wrap(final[len(emitted):])
-                    break
-                ids.append(token)
-                if params.logprobs and len(seq.logprob_data) >= len(ids):
-                    lp, top = seq.logprob_data[len(ids) - 1]
-                    pending_lp.append(self.core.lp_entry(token, lp, top))
-                t_detok = GATEWAY.detok_begin(clock, len(ids))
-                text = self.core.tokenizer.decode(ids)
+                tokens, done = await q.get()
+                t_detok = GATEWAY.detok_begin(clock)
+                if params.logprobs:
+                    for i, token in enumerate(tokens, start=n_ids):
+                        if len(seq.logprob_data) > i:
+                            lp, top = seq.logprob_data[i]
+                            pending_lp.append(
+                                self.core.lp_entry(token, lp, top)
+                            )
+                n_ids += len(tokens)
+                if done:
+                    # the held-back tail and whatever this delivery
+                    # adds, truncated where the engine stopped
+                    delta = self.core.final_text(seq)[n_emitted:]
+                else:
+                    held += detok.feed(tokens)
+                    delta = held[: max(0, len(held) - hold)]
+                    held = held[len(delta):]
+                    n_emitted += len(delta)
                 if t_detok is not None:
-                    GATEWAY.detok_end(t_detok)
-                if stops:
-                    cut = min(
-                        (
-                            i
-                            for i in (text.find(s) for s in stops)
-                            if i != -1
-                        ),
-                        default=-1,
-                    )
-                    if cut >= 0:
-                        if cut > len(emitted) or pending_lp:
-                            # flush even a zero-length delta: the entries
-                            # for the stop-completing tokens must not
-                            # vanish
-                            yield wrap(text[len(emitted):cut])
-                        break
-                    # hold back a stop-length tail so a stop string
-                    # arriving across several tokens is never partially
-                    # emitted
-                    text = text[: max(len(emitted), len(text) - longest_stop)]
-                if len(text) > len(emitted):
-                    delta = text[len(emitted):]
-                    emitted = text
+                    GATEWAY.detok_end(clock, t_detok, len(tokens))
+                if delta or (done and pending_lp):
+                    # even a zero-length last delta: the entries of the
+                    # tokens that completed a stop must not vanish
                     yield wrap(delta)
+                if done:
+                    break
             completed = True
         finally:
             if not completed and not seq.done_event.is_set():

@@ -103,8 +103,6 @@ SPAN_PHASE = {
 # spans in which the engine thread is blocked on the device: their CPU
 # time is ``engine_cpu_in_wait_s`` (time.thread_time, per chunk)
 WAIT_SPANS = frozenset(("device_wait", "readback"))
-# the gateway times one streamed token in this many (GatewayPerf)
-SAMPLE_EVERY = 8
 
 # True only while EngineCore.capture_profile runs a profiler session:
 # the one flag every bracket tests before building a TraceAnnotation
@@ -651,14 +649,15 @@ class _StreamClock:
     the handler's entry to the stream's writes (same asyncio task)."""
 
     __slots__ = ("t_in", "ann", "t_first_token", "first_written",
-                 "sampled")
+                 "tokens")
 
     def __init__(self, t_in: Optional[float], ann: Any) -> None:
         self.t_in = t_in
         self.ann = ann
-        # is the token now on its way to the wire a timed one
-        self.sampled = False
-        # stamped by the ENGINE thread at the stream's first on_token
+        # tokens detokenised since the stream's last write: the next
+        # write's delivery carries them
+        self.tokens = 0
+        # stamped by the ENGINE thread at the stream's first delivery
         self.t_first_token: Optional[float] = None
         self.first_written = False
 
@@ -685,6 +684,11 @@ class GatewayPerf:
         self.stream_tokens = 0
         self.stream_detok_s = 0.0
         self.stream_write_s = 0.0
+        # how often the hand-off engages: SSE content writes, the
+        # tokens they carried, and the wake-ups that brought them
+        self.stream_deliveries = 0
+        self.stream_tokens_delivered = 0
+        self.stream_handoffs = 0
         self._detok_ann = None
 
     def ingress_begin(self) -> None:
@@ -730,31 +734,22 @@ class GatewayPerf:
                 clock.ann = None
             _stream_clock.set(None)
 
-    # The two per-token sites are begin/end pairs, not ``with`` blocks,
-    # and time one token in SAMPLE_EVERY of a stream (every token while
-    # a capture runs, so the trace shows them all): at 4,000 tokens/s
-    # the event loop is the contended thread, a bracket object per
-    # token cost decode-heavy 2-3 % of its throughput and four clock
-    # reads per token still 1-2 % (PERF.md, PR 24).  ``stream_tokens``
-    # counts the TIMED tokens; the sums over it are what a mean needs.
+    # The two per-delivery sites are begin/end pairs, not ``with``
+    # blocks: the event loop is the contended thread, and a bracket
+    # object per token once cost decode-heavy 2-3 % of its throughput
+    # (PERF.md, PR 24).  A delivery is what one readback appended to
+    # one stream, several times rarer than a token, so EVERY delivery
+    # is timed; ``stream_tokens`` counts the tokens of the timed
+    # deliveries, which keeps the sums over it means per token.
 
     def detok_begin(
-        self, clock: Optional[_StreamClock], n_ids: int
+        self, clock: Optional[_StreamClock]
     ) -> Optional[float]:
-        """Before ``tokenizer.decode`` of the stream's ``n_ids``-th
-        token in stream_async; None = this token is not timed (then
-        neither is its write).  No await until :meth:`detok_end`, so
+        """Before stream_async detokenises one delivery; None = the
+        stream has no clock (it did not enter through the gateway, or
+        observability is off).  No await until :meth:`detok_end`, so
         the open annotation lives on ``self``."""
         if clock is None:
-            return None
-        # every token up to the stream's first chunk on the wire is
-        # timed (a stop-string hold-back or a partial UTF-8 piece can
-        # make the first write later than the first token)
-        clock.sampled = (
-            _capturing or not clock.first_written
-            or n_ids % SAMPLE_EVERY == 1
-        )
-        if not clock.sampled:
             return None
         if _capturing:
             self._detok_ann = _open_annotation(
@@ -762,19 +757,22 @@ class GatewayPerf:
             )
         return self._clock()
 
-    def detok_end(self, t0: float) -> None:
+    def detok_end(
+        self, clock: _StreamClock, t0: float, n_tokens: int
+    ) -> None:
         self.stream_detok_s += self._clock() - t0
-        self.stream_tokens += 1
+        self.stream_tokens += n_tokens
+        clock.tokens += n_tokens
         if self._detok_ann is not None:
             self._detok_ann.__exit__(None, None, None)
             self._detok_ann = None
 
     def write_begin(self) -> Optional[float]:
         """Before one SSE chunk's JSON + ``resp.write``; None = the
-        chunk's token is not timed.  The write may await, so the open
+        stream has no clock.  The write may await, so the open
         annotation lives on the stream's clock."""
         clock = _stream_clock.get()
-        if clock is None or not clock.sampled:
+        if clock is None:
             return None
         if _capturing:
             clock.ann = _open_annotation("vgt.gateway.sse_write", None)
@@ -787,13 +785,21 @@ class GatewayPerf:
         if clock.ann is not None:
             clock.ann.__exit__(None, None, None)
             clock.ann = None
+        self.stream_deliveries += 1
+        self.stream_tokens_delivered += clock.tokens
+        clock.tokens = 0
         if not clock.first_written and clock.t_first_token is not None:
-            # engine's first on_token -> the stream's first chunk on
-            # the wire (both perf_counter, one process); every token
-            # up to that chunk is a timed one
+            # engine's first delivery -> the stream's first chunk on
+            # the wire (both perf_counter, one process)
             clock.first_written = True
             self.first_chunk_n += 1
             self.first_chunk_s += now - clock.t_first_token
+
+    def note_handoff(self) -> None:
+        """One cross-thread wake-up (engine thread -> event loop) was
+        served: a readback's deliveries fanned out to their streams."""
+        if self.enabled:
+            self.stream_handoffs += 1
 
     def totals(self) -> Dict[str, Any]:
         return {
@@ -804,6 +810,9 @@ class GatewayPerf:
             "stream_tokens": self.stream_tokens,
             "stream_detok_s": round(self.stream_detok_s, 6),
             "stream_write_s": round(self.stream_write_s, 6),
+            "stream_deliveries": self.stream_deliveries,
+            "stream_tokens_delivered": self.stream_tokens_delivered,
+            "stream_handoffs": self.stream_handoffs,
         }
 
 

@@ -1989,7 +1989,7 @@ class ReplicatedEngine:
         self,
         prompt_ids: List[int],
         params: SamplingParams,
-        stream_cb: Optional[Callable[[int], Any]] = None,
+        stream_cb: Optional[Callable[[List[int], bool], Any]] = None,
         meta: Optional[Any] = None,
     ) -> Sequence:
         ids = list(prompt_ids)
@@ -2002,7 +2002,7 @@ class ReplicatedEngine:
         self,
         prompt: str,
         params: SamplingParams,
-        stream_cb: Optional[Callable[[int], Any]] = None,
+        stream_cb: Optional[Callable[[List[int], bool], Any]] = None,
         meta: Optional[Any] = None,
     ) -> Sequence:
         ids = self.tokenizer.encode(prompt)

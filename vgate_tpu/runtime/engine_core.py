@@ -1537,7 +1537,7 @@ class EngineCore:
         self,
         prompt_ids: List[int],
         params: SamplingParams,
-        stream_cb: Optional[Callable[[int], Any]] = None,
+        stream_cb: Optional[Callable[[List[int], bool], Any]] = None,
         meta: Optional[RequestMeta] = None,
     ) -> Sequence:
         if self._fatal is not None:
@@ -1596,7 +1596,7 @@ class EngineCore:
         self,
         prompt: str,
         params: SamplingParams,
-        stream_cb: Optional[Callable[[int], Any]] = None,
+        stream_cb: Optional[Callable[[List[int], bool], Any]] = None,
         meta: Optional[RequestMeta] = None,
     ) -> Sequence:
         return self.submit_tokens(
@@ -2729,6 +2729,7 @@ class EngineCore:
         ``emit`` bracket of _admit_and_prefill); returns how many were
         delivered."""
         delivered = 0
+        wakes: Dict[Any, None] = {}
         for (group, _), (tokens, lp) in zip(dispatched, firsts):
             self.flight.record_tick(
                 "prefill",
@@ -2745,7 +2746,7 @@ class EngineCore:
                 kv_free=self.allocator.num_free,
                 queue_depth=len(self.scheduler.waiting),
             )
-            arr = np.asarray(tokens)
+            arr = np.asarray(tokens).tolist()
             # append under the readback lock (device waits all happened
             # above): the stale-wake guard is check-then-append, and a
             # watchdog containment folding these sequences mid-loop
@@ -2764,7 +2765,7 @@ class EngineCore:
                         != plan_epochs[id(plan)]
                     ):
                         continue
-                    token = int(arr[row])
+                    token = arr[row]
                     self.total_prefills += 1
                     if lp is not None and plan.seq.params.logprobs:
                         self._attach_logprob(plan.seq, lp, 0, row)
@@ -2784,7 +2785,9 @@ class EngineCore:
                         )
                         tr.end("prefill", end_pc=boundary)
                         tr.start("decode", start_pc=boundary)
-                    self._maybe_finish(plan.seq, token)
+                    self._maybe_finish(plan.seq, token, wakes)
+                    plan.seq.deliver(wakes)
+        self._wake_streams(wakes)
         return delivered
 
     @engine_thread_only
@@ -3630,24 +3633,37 @@ class EngineCore:
             # check-then-append, and containment's fold must not
             # interleave with it
             delivered = 0
-            with self.perf.span("emit") as emit, self._readback_lock:
-                for seq, epoch in seqs:
-                    if (
-                        seq.status is not SeqStatus.RUNNING
-                        or seq.preempt_count != epoch
-                    ):
-                        continue  # stopped or preempted since dispatch
-                    slot = seq.slot
-                    for k in range(chunk):
-                        token = int(sampled[k, slot])
-                        if lp_np is not None and seq.params.logprobs:
-                            self._attach_logprob(seq, lp_np, k, slot)
-                        seq.append_token(token)
-                        self.total_decode_tokens += 1
-                        delivered += 1
-                        self._maybe_finish(seq, token)
-                        if seq.status is not SeqStatus.RUNNING:
-                            break
+            wakes: Dict[Any, None] = {}
+            with self.perf.span("emit") as emit:
+                # one host copy of the chunk, a sequence's steps side
+                # by side: rows[slot] is its column as ints
+                rows = sampled[:chunk].T.tolist()
+                with self._readback_lock:
+                    for seq, epoch in seqs:
+                        if (
+                            seq.status is not SeqStatus.RUNNING
+                            or seq.preempt_count != epoch
+                        ):
+                            continue  # stopped or preempted since dispatch
+                        slot = seq.slot
+                        want_lp = (
+                            lp_np is not None and seq.params.logprobs
+                        )
+                        for k, token in enumerate(rows[slot]):
+                            if want_lp:
+                                self._attach_logprob(seq, lp_np, k, slot)
+                            seq.append_token(token)
+                            delivered += 1
+                            self._maybe_finish(seq, token, wakes)
+                            if seq.status is not SeqStatus.RUNNING:
+                                break
+                        # the readback's tokens as ONE list (a sequence
+                        # it finished took them with its end notice)
+                        seq.deliver(wakes)
+                # one cross-thread wake-up per consumer for the whole
+                # readback, outside the lock
+                self._wake_streams(wakes)
+                self.total_decode_tokens += delivered
                 emit.note(tokens=delivered)
             self.perf.note_tokens(delivered)
             self.total_steps += chunk
@@ -3920,33 +3936,39 @@ class EngineCore:
         # append under the readback lock (device waits all happened
         # above): see _admit_and_prefill for the interleaving hazard
         delivered = 0
-        with self.perf.span("emit") as emit, self._readback_lock:
-            for seq in active:
-                # stale-wake guard (see _admit_and_prefill): status AND
-                # the epoch captured at dispatch — a watchdog stall
-                # during the blocking readback above may have
-                # checkpointed + replayed this sequence already
-                if (
-                    seq.status is not SeqStatus.RUNNING
-                    or seq.preempt_count != spec_epochs[seq.seq_id]
-                ):
-                    continue
-                slot = seq.slot
-                self.total_spec_drafted += int(input_lens[slot]) - 1
-                self.total_spec_accepted += int(acc_np[slot])
-                # model_toks[:, j] for j < accepted IS draft j+1;
-                # position `accepted` holds the bonus token — one loop
-                # covers both
-                for j in range(int(acc_np[slot]) + 1):
-                    token = int(toks_np[slot, j])
-                    if lp_np is not None and seq.params.logprobs:
-                        self._attach_logprob(seq, lp_np, j, slot)
-                    seq.append_token(token)
-                    self.total_decode_tokens += 1
-                    delivered += 1
-                    self._maybe_finish(seq, token)
-                    if seq.status is not SeqStatus.RUNNING:
-                        break
+        wakes: Dict[Any, None] = {}
+        with self.perf.span("emit") as emit:
+            with self._readback_lock:
+                for seq in active:
+                    # stale-wake guard (see _admit_and_prefill): status
+                    # AND the epoch captured at dispatch — a watchdog
+                    # stall during the blocking readback above may have
+                    # checkpointed + replayed this sequence already
+                    if (
+                        seq.status is not SeqStatus.RUNNING
+                        or seq.preempt_count != spec_epochs[seq.seq_id]
+                    ):
+                        continue
+                    slot = seq.slot
+                    accepted_n = int(acc_np[slot])
+                    self.total_spec_drafted += int(input_lens[slot]) - 1
+                    self.total_spec_accepted += accepted_n
+                    want_lp = lp_np is not None and seq.params.logprobs
+                    # model_toks[:, j] for j < accepted IS draft j+1;
+                    # position `accepted` holds the bonus token — one
+                    # loop covers both
+                    run = toks_np[slot, : accepted_n + 1].tolist()
+                    for j, token in enumerate(run):
+                        if want_lp:
+                            self._attach_logprob(seq, lp_np, j, slot)
+                        seq.append_token(token)
+                        delivered += 1
+                        self._maybe_finish(seq, token, wakes)
+                        if seq.status is not SeqStatus.RUNNING:
+                            break
+                    seq.deliver(wakes)  # the accepted run as one list
+            self._wake_streams(wakes)
+            self.total_decode_tokens += delivered
             emit.note(tokens=delivered)
         self.perf.note_tokens(delivered)
         self.total_steps += 1
@@ -3995,8 +4017,17 @@ class EngineCore:
             )
         )
 
+    @staticmethod
+    def _wake_streams(wakes: Dict[Any, None]) -> None:
+        """A readback's ONE cross-thread wake-up per stream consumer
+        (``Sequence.deliver`` collected them)."""
+        for wake in wakes:
+            wake()
+
     @engine_thread_only
-    def _maybe_finish(self, seq: Sequence, token: int) -> None:
+    def _maybe_finish(
+        self, seq: Sequence, token: int, wakes: Dict[Any, None]
+    ) -> None:
         reason = None
         # min_tokens gates STOP kinds only (device masking already
         # prevents stop tokens; this also holds back stop strings).  The
@@ -4021,7 +4052,7 @@ class EngineCore:
                 reason = "length"
         if reason is not None:
             self.scheduler.remove(seq)
-            seq.finish(reason)
+            seq.finish(reason, wakes)
 
     @engine_thread_only
     def _hit_stop_string(self, seq: Sequence) -> bool:

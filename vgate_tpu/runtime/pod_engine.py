@@ -1107,6 +1107,7 @@ class PodEngine:
                 (float(lp[0]), [(int(t), float(l)) for t, l in lp[1]])
             )
         seq.append_token(int(frame["t"]))
+        seq.deliver()  # the wire carries one tok frame per token
 
     def _on_token(self, idx: int, frame: Dict[str, Any]) -> None:
         if self._handoff_intercept(idx, frame):
@@ -1594,6 +1595,7 @@ class PodEngine:
                 # suffix here so the client stream loses nothing
                 for t in rec.generated_ids[len(seq.generated_ids):]:
                     seq.append_token(int(t))
+                seq.deliver()
                 handoff_mod.advance(rec.state, handoff_mod.DECODING)
                 rec.state = handoff_mod.DECODING
         if not ok:
@@ -1868,7 +1870,7 @@ class PodEngine:
         self,
         prompt_ids: List[int],
         params: Any,
-        stream_cb: Optional[Callable[[int], Any]] = None,
+        stream_cb: Optional[Callable[[List[int], bool], Any]] = None,
         meta: Optional[Any] = None,
     ) -> Sequence:
         raise_for_state(
@@ -1996,7 +1998,7 @@ class PodEngine:
         self,
         prompt: str,
         params: Any,
-        stream_cb: Optional[Callable[[int], Any]] = None,
+        stream_cb: Optional[Callable[[List[int], bool], Any]] = None,
         meta: Optional[Any] = None,
     ) -> Sequence:
         return self.submit_tokens(

@@ -41,7 +41,20 @@ class Sequence:
     finish_t: Optional[float] = None
     # delivery
     done_event: threading.Event = field(default_factory=threading.Event)
-    stream_cb: Optional[Callable[[int], Any]] = None
+    # ``stream_cb(tokens, done)``: called by :meth:`deliver` with what
+    # ONE readback appended to this sequence (a first token is a list of
+    # one, a decode chunk up to its steps, a speculative round its
+    # accepted run), and with ``done`` true on the call that settles the
+    # sequence — the same call as the last tokens when the readback
+    # finished it, an empty list when the settle came from elsewhere
+    # (abort, shed, containment).  It may return a callable that wakes
+    # its consumer; callbacks that share one consumer thread return the
+    # same callable, so a readback wakes it once (see :meth:`deliver`).
+    stream_cb: Optional[
+        Callable[[List[int], bool], Optional[Callable[[], Any]]]
+    ] = None
+    # how many of generated_ids stream_cb has been handed
+    _delivered: int = 0
     preempt_count: int = 0
     orig_prompt_len: int = 0
     # set when a stop string matched: the final text truncated at the match
@@ -117,6 +130,8 @@ class Sequence:
             self.orig_prompt_len = len(self.prompt_ids)
         if self.deadline_t is None and self.params.timeout_s is not None:
             self.deadline_t = self.arrival_t + self.params.timeout_s
+        # a resubmitted generation's prefix reached its stream before
+        self._delivered = len(self.generated_ids)
 
     def past_deadline(self, now: Optional[float] = None) -> bool:
         if self.deadline_t is None:
@@ -162,8 +177,27 @@ class Sequence:
             self.first_token_t = time.perf_counter()
         self.output_ids.append(token)
         self.generated_ids.append(token)
-        if self.stream_cb is not None:
-            self.stream_cb(token)
+
+    def deliver(
+        self, wakes: Optional[dict] = None, done: bool = False
+    ) -> None:
+        """Hand stream_cb, as one list, the tokens appended since the
+        last delivery.  A caller that delivers to many sequences at once
+        (the engine's readback) passes ``wakes`` and calls each key once
+        when it is through; without it the consumer is woken here."""
+        if self.stream_cb is None:
+            return
+        tokens = self.generated_ids[self._delivered:]
+        if not tokens and not done:
+            return
+        self._delivered += len(tokens)
+        wake = self.stream_cb(tokens, done)
+        if wake is None:
+            return
+        if wakes is None:
+            wake()
+        else:
+            wakes[wake] = None
 
     def request_abort(self, reason: str = "client_disconnect") -> None:
         """Ask the engine to drop this sequence (thread-safe, advisory:
@@ -182,12 +216,21 @@ class Sequence:
         except Exception:
             pass  # observability must never break delivery
 
-    def finish(self, reason: str) -> None:
+    def _end_stream(self, wakes: Optional[dict]) -> None:
+        """The stream's end notice, in the channel its tokens took and
+        behind them: whatever this readback appended goes with it."""
+        try:
+            self.deliver(wakes, done=True)
+        except Exception:
+            pass  # a consumer that is gone must never break a settle
+
+    def finish(self, reason: str, wakes: Optional[dict] = None) -> None:
         self.status = SeqStatus.FINISHED
         self.finish_reason = reason
         self.finish_t = time.perf_counter()
         self._notify_settle()
         self.done_event.set()
+        self._end_stream(wakes)
 
     def fail(self, exc: BaseException) -> None:
         self.status = SeqStatus.FAILED
@@ -195,6 +238,7 @@ class Sequence:
         self.finish_t = time.perf_counter()
         self._notify_settle()
         self.done_event.set()
+        self._end_stream(None)
 
     def reset_for_recompute(self) -> None:
         """Preemption: drop residency, keep generated tokens in the prompt so

@@ -850,7 +850,7 @@ class EngineSupervisor:
         self,
         prompt_ids: List[int],
         params: SamplingParams,
-        stream_cb: Optional[Callable[[int], Any]] = None,
+        stream_cb: Optional[Callable[[List[int], bool], Any]] = None,
         meta: Optional[Any] = None,
     ) -> Sequence:
         self._gate(list(prompt_ids))
@@ -873,7 +873,7 @@ class EngineSupervisor:
         self,
         prompt: str,
         params: SamplingParams,
-        stream_cb: Optional[Callable[[int], Any]] = None,
+        stream_cb: Optional[Callable[[List[int], bool], Any]] = None,
         meta: Optional[Any] = None,
     ) -> Sequence:
         return self.submit_tokens(

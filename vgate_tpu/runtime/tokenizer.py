@@ -81,6 +81,52 @@ class HFTokenizer:
         )
 
 
+class IncrementalDetokenizer:
+    """The text of a growing id list, a delivery at a time, without
+    decoding the whole list again (the prefix-offset / read-offset
+    scheme of vLLM's incremental detokeniser).
+
+    Each :meth:`feed` decodes two short slices that START at the same
+    id, the tokens whose text is already out (context) and those plus
+    the new ones, and returns what the second has beyond the first, so
+    whatever a tokenizer does at the start of a slice (a dropped leading
+    space, merged bytes) is the same in both and cancels.  Text that
+    ends in U+FFFD is an incomplete character and waits for its rest;
+    the concatenation of the returns is a prefix of ``decode(ids)``."""
+
+    # context kept before the new tokens, and how long a tail may end
+    # in U+FFFD before it is taken for an invalid sequence (a character
+    # has at most four bytes) and let out: both bound the decoded slice
+    CONTEXT = 16
+    MAX_HOLD = 16
+
+    __slots__ = ("_decode", "ids", "_prefix", "_read")
+
+    def __init__(self, tokenizer: Tokenizer) -> None:
+        self._decode = tokenizer.decode
+        self.ids: List[int] = []
+        self._prefix = 0  # the decoded slices start here
+        self._read = 0  # the text of ids[:_read] has been returned
+
+    def feed(self, tokens: List[int]) -> str:
+        ids = self.ids
+        ids.extend(tokens)
+        before = self._decode(ids[self._prefix:self._read])
+        now = self._decode(ids[self._prefix:])
+        if now.endswith("\ufffd") and (
+            len(ids) - self._read <= self.MAX_HOLD
+        ):
+            return ""
+        delta = now[len(before):]
+        if delta:
+            self._prefix = self._read
+        # tokens that added no text (specials, ids outside the bytes)
+        # are read all the same: nothing later completes them
+        self._read = len(ids)
+        self._prefix = max(self._prefix, self._read - self.CONTEXT)
+        return delta
+
+
 def get_tokenizer(spec: ModelSpec, tokenizer_path: Optional[str]) -> Tokenizer:
     if tokenizer_path and os.path.exists(tokenizer_path):
         try:

@@ -480,6 +480,31 @@ class WorkerServer:
         seq.trace = RequestTrace(meta)
         seq.trace.start("queue", start_pc=seq.arrival_t)
 
+    def _tok_frames(self, sid: int, entry_cell: List["_Entry"]):
+        """A sequence's ``stream_cb``: the wire keeps ONE ``tok`` frame
+        per token (journal, resume and handoff count them), so a
+        readback's list is unrolled here."""
+
+        def on_tokens(tokens: List[int], done: bool) -> None:
+            entry = entry_cell[0]
+            if entry.cancelled:
+                return
+            seq = entry.seq
+            # _attach_logprob runs before append_token on every engine
+            # path, so logprob_data is aligned with generated_ids, whose
+            # tail these tokens are
+            base = len(seq.generated_ids) - len(tokens)
+            want_lp = seq.params.logprobs
+            for i, token in enumerate(tokens):
+                lp = None
+                if want_lp and len(seq.logprob_data) > base + i:
+                    lp = seq.logprob_data[base + i]
+                self._enqueue(
+                    {"op": "tok", "sid": sid, "t": int(token), "lp": lp}
+                )
+
+        return on_tokens
+
     def _verb_submit(self, frame: Dict[str, Any]) -> Dict[str, Any]:
         if self._orphaned:
             # an orphan that took new work could never be reconciled
@@ -508,21 +533,7 @@ class WorkerServer:
 
         entry_cell: List[_Entry] = []
 
-        def on_token(token: int) -> None:
-            entry = entry_cell[0]
-            if entry.cancelled:
-                return
-            lp = None
-            seq = entry.seq
-            # _attach_logprob runs before append_token on every engine
-            # path, so the just-appended token's data is the last entry
-            if seq.params.logprobs and len(seq.logprob_data) >= len(
-                seq.generated_ids
-            ):
-                lp = seq.logprob_data[len(seq.generated_ids) - 1]
-            self._enqueue(
-                {"op": "tok", "sid": sid, "t": int(token), "lp": lp}
-            )
+        on_token = self._tok_frames(sid, entry_cell)
 
         # Build the Sequence ourselves (both fresh and resubmit paths)
         # and admit it via submit_existing: the entry is fully wired
@@ -887,19 +898,7 @@ class WorkerServer:
 
         entry_cell: List[_Entry] = []
 
-        def on_token(token: int) -> None:
-            entry = entry_cell[0]
-            if entry.cancelled:
-                return
-            lp = None
-            seq = entry.seq
-            if seq.params.logprobs and len(seq.logprob_data) >= len(
-                seq.generated_ids
-            ):
-                lp = seq.logprob_data[len(seq.generated_ids) - 1]
-            self._enqueue(
-                {"op": "tok", "sid": sid, "t": int(token), "lp": lp}
-            )
+        on_token = self._tok_frames(sid, entry_cell)
 
         # swap-shape construction: prompt/output split at the PREFILL
         # worker's fold point so total_len ↔ shipped page count agree;

@@ -1024,6 +1024,9 @@ async def _stream_chat(
                     trace_ctx=capture_context(),
                 )
             async with asyncio.timeout(timeout_s):
+                # one content event and one write per delivery (what
+                # one engine readback gave this stream): a delta may
+                # carry several tokens
                 async for piece in stream_fn(prompt, params, **kwargs):
                     t_write = GATEWAY.write_begin()
                     if isinstance(piece, dict):  # logprobs-carrying delta
