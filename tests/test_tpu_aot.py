@@ -24,6 +24,8 @@ PAGE = 32
 # tp=4 shard of Qwen2.5-7B (28 heads / 4 KV heads over four chips)
 GEOM_1P5B = (12, 2, 128)
 GEOM_7B_TP4_SHARD = (7, 1, 128)
+# Qwen3-Next's gated full-attention layers: head size 256
+GEOM_QWEN3_NEXT = (16, 2, 256)
 
 
 @pytest.fixture(scope="module")
@@ -100,7 +102,8 @@ def _compile_flash_kernel(A, geom):
 
 
 @pytest.mark.parametrize(
-    "geom", [GEOM_1P5B, GEOM_7B_TP4_SHARD], ids=["1.5B", "7B-tp4-shard"]
+    "geom", [GEOM_1P5B, GEOM_7B_TP4_SHARD, GEOM_QWEN3_NEXT],
+    ids=["1.5B", "7B-tp4-shard", "qwen3-next-hd256"],
 )
 @pytest.mark.parametrize(
     "compile_kernel",
@@ -109,6 +112,43 @@ def _compile_flash_kernel(A, geom):
 )
 def test_default_path_kernels_compile_for_v5e(v5e, geom, compile_kernel):
     compile_kernel(_abstract(v5e), geom)
+
+
+def test_gated_delta_step_kernel_compiles_for_v5e(v5e):
+    """The recurrent-step kernel at Qwen3-Next's sizes (256 slots, 32
+    value heads of 128 x 128 float32), the state updated in place."""
+    from vgate_tpu.ops.pallas.gated_delta import gated_delta_step_pallas
+
+    A = _abstract(v5e)
+    B, H, dk, dv, layers = 256, 32, 128, 128, 6
+    f32 = jnp.float32
+    state_bytes = layers * B * H * dk * dv * 4
+    compiled = gated_delta_step_pallas.lower(
+        A((B, H, dk), f32), A((B, H, dk), f32), A((B, H, dk), f32),
+        A((B, H, dv), f32), A((B, H, dv), f32),
+        A((layers, B, H, dk, dv), f32), A((), jnp.int32),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state_bytes, "the state is copied"
+    assert mem.temp_size_in_bytes < state_bytes // 8
+
+
+@pytest.mark.parametrize(
+    "rows, k, n, tm, tn",
+    [(2560, 2048, 512, 32, 512), (2560, 512, 2048, 32, 2048),
+     (40960, 2048, 512, 128, 512)],
+    ids=["decode-gate-up", "decode-down", "prompt-block"],
+)
+def test_grouped_matmul_kernel_compiles_for_v5e(v5e, rows, k, n, tm, tn):
+    """The held experts' grouped product on the full [layers, experts,
+    k, n] stack (128 experts of Qwen3-Next's widths), layer-indexed."""
+    from vgate_tpu.ops.pallas.grouped_matmul import grouped_matmul_pallas
+
+    A = _abstract(v5e)
+    grouped_matmul_pallas.lower(
+        A((rows, k), jnp.bfloat16), A((6, 128, k, n), jnp.bfloat16),
+        A((128,), jnp.int32), A((), jnp.int32), tm=tm, tn=tn,
+    ).compile()
 
 
 class MosaicRefusal(Exception):

@@ -312,3 +312,156 @@ def test_hf_tokenizer_chat_template(tmp_path):
     }))
     got2 = get_tokenizer(TINY_DENSE, str(tok_dir))
     assert got2.apply_chat_template([{"role": "user", "content": "x"}]) is None
+
+
+# ------------------------------------------------- Qwen3NextForCausalLM
+
+def _qwen3_next_tensors(cfg, seed=11):
+    """Random tensors for tiny-hybrid TWICE: as the plain reference takes
+    them ([q | k | v | z], [b | a], stacked by period) and under the
+    checkpoint's names and layouts (torch [out, in]; in_proj_qkvz and
+    in_proj_ba grouped by key head, as the published modelling code
+    splits them)."""
+    from perfbench.references import qwen3_next as ref
+
+    z = ref.sizes(cfg)
+    rng = np.random.default_rng(seed)
+    rand = lambda *shape, scale=0.05: (
+        rng.normal(size=shape) * scale).astype(np.float32)
+    D, P, n, E = z["D"], z["P"], z["n"], z["E"]
+    H, KV, hd, Hk, Hv = z["H"], z["KV"], z["hd"], z["Hk"], z["Hv"]
+    dk, dv, r = z["dk"], z["dv"], z["Hv"] // z["Hk"]
+
+    def moe(lead):
+        return {
+            "router": rand(*lead, D, z["R"], scale=0.5),
+            "gate": rand(*lead, E, D, z["Fe"]),
+            "up": rand(*lead, E, D, z["Fe"]),
+            "down": rand(*lead, E, z["Fe"], D),
+            "shared_gate": rand(*lead, D, z["Fs"]),
+            "shared_up": rand(*lead, D, z["Fs"]),
+            "shared_down": rand(*lead, z["Fs"], D),
+            "shared_router": rand(*lead, D, scale=0.5),
+        }
+
+    full = {
+        "input_norm": rand(P, D, scale=0.2), "post_norm": rand(P, D, scale=0.2),
+        "q_norm": rand(P, hd, scale=0.2), "k_norm": rand(P, hd, scale=0.2),
+        "q": rand(P, D, 2 * H * hd), "k": rand(P, D, KV * hd),
+        "v": rand(P, D, KV * hd), "o": rand(P, H * hd, D), **moe((P,)),
+    }
+    linear = {
+        "input_norm": rand(P, n, D, scale=0.2),
+        "post_norm": rand(P, n, D, scale=0.2),
+        "gdn_norm": 1.0 + rand(P, n, dv, scale=0.2),
+        "in_qkvz": rand(P, n, D, z["C"] + z["vd"]),
+        "in_ba": rand(P, n, D, 2 * Hv, scale=0.5),
+        "conv": rand(P, n, z["C"], z["taps"], scale=0.5),
+        "a_log": rand(P, n, Hv, scale=0.3),
+        "dt_bias": rand(P, n, Hv, scale=1.0) - 2.0,
+        "out": rand(P, n, z["vd"], D), **moe((P, n)),
+    }
+    plain = {
+        "embed": rand(z["V"], D, scale=0.5), "lm_head": rand(D, z["V"]),
+        "final_norm": rand(D, scale=0.2), "full": full, "linear": linear,
+    }
+
+    hf = {
+        "model.embed_tokens.weight": plain["embed"],
+        "model.norm.weight": plain["final_norm"],
+        "lm_head.weight": plain["lm_head"].T,
+    }
+
+    def put_moe(pre, w):
+        hf[pre + "mlp.gate.weight"] = w["router"].T
+        for e in range(E):
+            for name, ours in (("gate_proj", "gate"), ("up_proj", "up"),
+                               ("down_proj", "down")):
+                hf[f"{pre}mlp.experts.{e}.{name}.weight"] = w[ours][e].T
+        for name, ours in (("gate_proj", "shared_gate"),
+                           ("up_proj", "shared_up"),
+                           ("down_proj", "shared_down")):
+            hf[f"{pre}mlp.shared_expert.{name}.weight"] = w[ours].T
+        hf[pre + "mlp.shared_expert_gate.weight"] = w["shared_router"][None]
+
+    for p in range(P):
+        for j in range(n + 1):
+            pre = f"model.layers.{p * (n + 1) + j}."
+            src = full if j == n else linear
+            w = {k: (v[p] if j == n else v[p, j]) for k, v in src.items()}
+            hf[pre + "input_layernorm.weight"] = w["input_norm"]
+            hf[pre + "post_attention_layernorm.weight"] = w["post_norm"]
+            put_moe(pre, w)
+            if j == n:
+                for name in "qkvo":
+                    hf[f"{pre}self_attn.{name}_proj.weight"] = w[name].T
+                hf[pre + "self_attn.q_norm.weight"] = w["q_norm"]
+                hf[pre + "self_attn.k_norm.weight"] = w["k_norm"]
+                continue
+            kd, vd = z["kd"], z["vd"]
+            q, k, v, zz = np.split(
+                w["in_qkvz"], np.cumsum([kd, kd, vd]), axis=-1)
+            # per key head: its q, its k, its r value heads' v, their z
+            grouped = np.concatenate([
+                q.reshape(D, Hk, dk), k.reshape(D, Hk, dk),
+                v.reshape(D, Hk, r * dv), zz.reshape(D, Hk, r * dv)], -1)
+            hf[pre + "linear_attn.in_proj_qkvz.weight"] = (
+                grouped.reshape(D, -1).T)
+            b, a = w["in_ba"][:, :Hv], w["in_ba"][:, Hv:]
+            hf[pre + "linear_attn.in_proj_ba.weight"] = np.concatenate(
+                [b.reshape(D, Hk, r), a.reshape(D, Hk, r)], -1
+            ).reshape(D, -1).T
+            hf[pre + "linear_attn.conv1d.weight"] = w["conv"][:, None, :]
+            hf[pre + "linear_attn.A_log"] = w["a_log"]
+            hf[pre + "linear_attn.dt_bias"] = w["dt_bias"]
+            hf[pre + "linear_attn.norm.weight"] = w["gdn_norm"]
+            hf[pre + "linear_attn.out_proj.weight"] = w["out"].T
+    return plain, {k: np.ascontiguousarray(v) for k, v in hf.items()}
+
+
+@pytest.mark.parametrize("first, held", [(0, 8), (2, 4)],
+                         ids=["whole", "a-share-of-the-experts"])
+def test_qwen3_next_checkpoint_equals_the_reference(tmp_path, first, held):
+    """A synthetic ``Qwen3NextForCausalLM`` safetensors file through the
+    loader and the engine against the plain reference fed the same
+    tensors: whole, and holding experts 2..5 of the router's 8."""
+    import dataclasses
+
+    from safetensors.numpy import save_file
+
+    from perfbench.references import qwen3_next as ref
+    from tests.test_hybrid_model import TINY, TOL, hybrid_config, lp_params
+    from vgate_tpu.models.specs import spec_for_model_id
+    from vgate_tpu.runtime.engine_core import EngineCore
+
+    plain, hf = _qwen3_next_tensors(TINY)
+    ckpt = tmp_path / "ckpt"
+    ckpt.mkdir()
+    save_file(hf, str(ckpt / "model.safetensors"))
+    spec = dataclasses.replace(
+        spec_for_model_id("tiny-hybrid"), num_experts=held,
+        first_expert=first)
+    cfg = dict(TINY, num_experts=held, router_width=8, first_expert=first)
+    cut = lambda part: dict(part, **{
+        name: part[name][..., first:first + held, :, :]
+        for name in ("gate", "up", "down")})
+    weights = jax.tree.map(jnp.asarray, dict(
+        plain, full=cut(plain["full"]), linear=cut(plain["linear"])))
+
+    config = hybrid_config()
+    config.model.checkpoint_path = str(ckpt)
+    core = EngineCore(config, spec=spec, devices=jax.devices()[:1])
+    core.start()
+    try:
+        prompt = [int(t) for t in
+                  np.random.default_rng(0).integers(3, 259, size=13)]
+        seq = core.submit_tokens(prompt, lp_params(5))
+        assert seq.done_event.wait(timeout=600) and seq.error is None
+        want = ref.logprobs(cfg, weights, [prompt + seq.generated_ids],
+                            [len(prompt)])[0]
+        diffs = [abs(t["logprob"] - want[pos, t["token_id"]])
+                 for pos, e in enumerate(core.logprob_entries(seq))
+                 for t in e["top_logprobs"]]
+        assert diffs and max(diffs) < 10 * TOL, max(diffs)
+    finally:
+        core.stop()

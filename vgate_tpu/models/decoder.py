@@ -65,6 +65,23 @@ def init_params(
     # Gemma-family RMSNorm stores a delta around 1 (unit_offset_norm), so
     # identity init is zeros there, ones elsewhere.
     norm_init = jnp.zeros if spec.unit_offset_norm else jnp.ones
+    if spec.is_hybrid:
+        from vgate_tpu.models.hybrid import init_layers
+
+        # one fused program a tensor (bits, scale and cast together):
+        # drawn eagerly, every primitive of every shape compiles on its
+        # own and a 0.8 G-element tensor stands in float32 twice; the
+        # values are the eager draw's
+        fused = jax.jit(normal, static_argnums=(1, 2))
+        draw = lambda k, shape, scale=0.02: fused(k, tuple(shape), scale)
+        params = {
+            "embed": draw(keys[8], (V, D)),
+            "layers": init_layers(spec, key, dtype, draw, norm_init),
+            "final_norm": norm_init((D,), dtype),
+        }
+        if not spec.tie_embeddings:
+            params["lm_head"] = draw(keys[9], (D, V))
+        return params
     layers: Dict[str, Any] = {
         "input_norm": norm_init((L, D), dtype),
         "post_norm": norm_init((L, D), dtype),
@@ -81,11 +98,11 @@ def init_params(
         layers["pre_ffn_norm"] = norm_init((L, D), dtype)
         layers["post_ffn_norm"] = norm_init((L, D), dtype)
     if spec.is_moe:
-        E = spec.num_experts
-        layers["router"] = normal(keys[4], (L, D, E))
-        layers["gate"] = {"w": normal(keys[5], (L, E, D, F))}
-        layers["up"] = {"w": normal(keys[6], (L, E, D, F))}
-        layers["down"] = {"w": normal(keys[7], (L, E, F, D))}
+        E, Fe = spec.num_experts, spec.expert_width
+        layers["router"] = normal(keys[4], (L, D, spec.router_experts))
+        layers["gate"] = {"w": normal(keys[5], (L, E, D, Fe))}
+        layers["up"] = {"w": normal(keys[6], (L, E, D, Fe))}
+        layers["down"] = {"w": normal(keys[7], (L, E, Fe, D))}
     else:
         layers["gate"] = {"w": normal(keys[5], (L, D, F))}
         layers["up"] = {"w": normal(keys[6], (L, D, F))}
@@ -149,107 +166,15 @@ def _dense_mlp(x, lp, spec: ModelSpec):
     )
 
 
-def _expert_einsum(subscripts, x, w, int8_native=False):
-    """Per-expert einsum accepting plain or quantized expert weights
-    (QTensor scale is per (expert, out-channel): [E, out] broadcasts as
-    [E, 1, out] against the [E, C, out] einsum result).  With
-    ``int8_native`` (tpu.int8_native) the expert GEMMs run the native
-    s8 x s8 -> s32 MXU path with per-(expert, token-row) activation
-    quantization (ops/quant.py int8_native_partial)."""
-    from vgate_tpu.ops.quant import (
-        PackedQTensor,
-        QTensor,
-        int8_native_partial,
-        packed_einsum,
-    )
-
-    if int8_native and isinstance(w, (QTensor, PackedQTensor)):
-        out = int8_native_partial(subscripts, x, w)
-        return (out * w.scale[:, None, :]).astype(x.dtype)
-    if isinstance(w, PackedQTensor):
-        out = packed_einsum(subscripts, x, w)
-        return out * w.scale[:, None, :].astype(x.dtype)
-    if isinstance(w, QTensor):
-        out = jnp.einsum(subscripts, x, w.q.astype(x.dtype))
-        return out * w.scale[:, None, :].astype(x.dtype)
-    return jnp.einsum(subscripts, x, w)
-
-
-def _moe_mlp(x, lp, spec: ModelSpec, capacity_factor: float = 2.0):
-    """Top-k expert routing with sort-based ragged dispatch.
-
-    The ``T*K`` (token, expert-choice) assignments are sorted by expert id
-    and scattered into per-expert ``[E, capacity(+1 trash), D]`` buffers at
-    their position within the expert's group — O(T*K) gathers/scatters plus
-    the per-expert GEMMs, with **no [T, E, C] one-hot dispatch/combine
-    tensors** (the TPU-native replacement for the reference's absent MoE
-    path; SURVEY.md section 2.2 ragged dispatch).  The buffers keep a
-    leading E axis so ``ep`` sharding propagates into the expert GEMMs and
-    XLA emits the token all-to-all around the scatter/gather.  Tokens
-    overflowing an expert's capacity land in the trash column and are
-    dropped (their residual passes through), the standard serving
-    trade-off.
-    """
-    orig_shape = x.shape
-    D = orig_shape[-1]
-    T = 1
-    for s in orig_shape[:-1]:
-        T *= s
-    xt = x.reshape(T, D)
-    E, K = spec.num_experts, spec.experts_per_token
-    capacity = max(4, int((T * K / E) * capacity_factor + 0.5))
-
-    router_logits = jnp.einsum(
-        "td,de->te", xt.astype(jnp.float32), lp["router"].astype(jnp.float32)
-    )
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, K)  # [T, K]
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
-
-    TK = T * K
-    flat_expert = gate_idx.reshape(TK)
-    flat_gate = gate_vals.reshape(TK)
-    flat_token = jnp.arange(TK, dtype=jnp.int32) // K
-    order = jnp.argsort(flat_expert, stable=True)
-    sorted_expert = flat_expert[order]
-    sorted_token = flat_token[order]
-    sorted_gate = flat_gate[order]
-
-    counts = jnp.zeros((E,), jnp.int32).at[sorted_expert].add(1)
-    starts = jnp.cumsum(counts) - counts  # first sorted index per expert
-    pos = jnp.arange(TK, dtype=jnp.int32) - starts[sorted_expert]
-    within = pos < capacity
-
-    buf = jnp.zeros((E, capacity + 1, D), xt.dtype)
-    buf = buf.at[sorted_expert, jnp.minimum(pos, capacity)].set(
-        xt[sorted_token]
-    )
-    expert_in = buf[:, :capacity]  # [E, C, D]
-    i8 = spec.int8_native
-    gate_h = _expert_einsum(
-        "ecd,edf->ecf", expert_in, lp["gate"]["w"], int8_native=i8
-    )
-    up_h = _expert_einsum(
-        "ecd,edf->ecf", expert_in, lp["up"]["w"], int8_native=i8
-    )
-    act = _act(gate_h.astype(jnp.float32), spec).astype(xt.dtype) * up_h
-    expert_out = _expert_einsum(
-        "ecf,efd->ecd", act, lp["down"]["w"], int8_native=i8
-    )
-
-    contrib = expert_out[sorted_expert, jnp.minimum(pos, capacity - 1)]
-    contrib = jnp.where(within[:, None], contrib, 0)
-    out = (
-        jnp.zeros((T, D), xt.dtype)
-        .at[sorted_token]
-        .add(contrib * sorted_gate[:, None].astype(xt.dtype))
-    )
-    return out.reshape(orig_shape)
-
-
 @jax.named_scope("mlp")
 def _mlp(x, lp, spec: ModelSpec):
-    return _moe_mlp(x, lp, spec) if spec.is_moe else _dense_mlp(x, lp, spec)
+    """Dense SwiGLU, or the ONE expert layer (ops/moe.py): Mixtral is
+    its case "holds every expert, no shared expert", and drops nothing."""
+    if not spec.is_moe:
+        return _dense_mlp(x, lp, spec)
+    from vgate_tpu.ops.moe import expert_layer
+
+    return expert_layer(x, lp, spec, lambda x32: _act(x32, spec))[0]
 
 
 @jax.named_scope("logits")
@@ -368,6 +293,14 @@ def multitok_attention_impl(
     return "pallas" if use_pallas and kernel_fits else "jnp"
 
 
+def _last_rows(x, lens):
+    """x [B, S, D] -> the row at ``lens - 1`` of each sequence, [B, D]."""
+    last_idx = jnp.clip(lens - 1, 0, x.shape[1] - 1)
+    return jnp.take_along_axis(
+        x, last_idx[:, None, None].repeat(x.shape[-1], axis=-1), axis=1
+    )[:, 0]
+
+
 def _kv_layer_scan(params, spec: ModelSpec, body, x0, k_pages, v_pages):
     """The one layer-scan scaffold every plain-mesh forward shares.
 
@@ -403,8 +336,11 @@ def prefill_forward(
     page_tables: jnp.ndarray,  # [B, S // ps] page ids for this prompt
     mesh=None,  # jax.sharding.Mesh; sp>1 routes attention through the ring
     use_pallas: bool = False,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Run the prompt pass: returns (last-token logits [B, V], k_pages, v_pages).
+    state=None,  # hybrid specs: the recurrent state (models/hybrid.py)
+    slots=None,  # [B] decode slot of each row (its row of the state)
+) -> Tuple[jnp.ndarray, ...]:
+    """Run the prompt pass: returns (last-token logits [B, V], k_pages,
+    v_pages), and the recurrent state after them for a hybrid spec.
 
     Attention is flash-style on every path — blockwise online softmax, no
     [B,H,S,S] score materialization: the Pallas kernel
@@ -464,6 +400,17 @@ def prefill_forward(
     # the prompt pass only WRITES pages (attention runs over the fresh
     # k/v): layer-indexed in-place writes on the carried pools
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+    if spec.is_hybrid:
+        from vgate_tpu.models import hybrid
+
+        x, k_pages, v_pages, state = hybrid.prompt_forward(
+            params, spec, x, seq_lens, positions, k_pages, v_pages, state,
+            slots, jnp.ones((B,), bool), page_tables,
+            lambda q, k, v, kp, vp, layer: attn_fn(q, k, v, seq_lens),
+            use_pallas,
+        )
+        return (_logits(params, spec, _last_rows(x, seq_lens)),
+                k_pages, v_pages, state)
 
     def body(h, lp, win, kp, vp, layer):
         q, k, v, kp, vp = _prefill_qkv_write(
@@ -480,11 +427,7 @@ def prefill_forward(
     x, k_pages, v_pages = _kv_layer_scan(
         params, spec, body, x, k_pages, v_pages
     )
-    last_idx = jnp.clip(seq_lens - 1, 0, S - 1)
-    last_hidden = jnp.take_along_axis(
-        x, last_idx[:, None, None].repeat(x.shape[-1], axis=-1), axis=1
-    )[:, 0]
-    return _logits(params, spec, last_hidden), k_pages, v_pages
+    return _logits(params, spec, _last_rows(x, seq_lens)), k_pages, v_pages
 
 
 @jax.named_scope("qkv")
@@ -665,8 +608,11 @@ def decode_forward(
     active: Optional[jnp.ndarray] = None,  # [B] bool; inactive slots write page 0
     use_pallas: bool = False,
     mesh=None,  # pp>1 routes through the pipeline-parallel stage relay
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One continuous-batching decode step: returns (logits [B, V], caches)."""
+    state=None,  # hybrid specs: the recurrent state, row = slot
+) -> Tuple[jnp.ndarray, ...]:
+    """One continuous-batching decode step: returns (logits [B, V],
+    caches); a hybrid spec adds the recurrent state and the expert
+    layers' counters (ops/moe.py STAT_NAMES)."""
     impl = decode_attention_impl(spec, use_pallas, mesh)
     if impl == "pp_relay":
         from vgate_tpu.parallel.pipeline import pp_decode_forward
@@ -746,6 +692,15 @@ def decode_forward(
     )
 
     x = _embed(params, spec, tokens)  # [B, D]
+    if spec.is_hybrid:
+        from vgate_tpu.models import hybrid
+
+        x, k_pages, v_pages, state, stats = hybrid.decode_forward(
+            params, spec, x, positions, k_pages, v_pages, state,
+            page_tables, seq_lens, page_ids, page_off, active, attn_fn,
+            use_pallas,
+        )
+        return _logits(params, spec, x), k_pages, v_pages, state, stats
 
     # the FULL [L, ...] pools ride the scan carry with layer-indexed
     # in-place updates, and attention reads the pool at layer l directly
@@ -781,7 +736,9 @@ def prefill_suffix_forward(
     use_pallas: bool = False,  # multitok kernel for the context attention
     mesh=None,  # sp>1 routes write+attention through the sp shard path
     unaligned: bool = False,  # COW prefix sharing: prefix_lens % ps != 0
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    state=None,  # hybrid specs: the recurrent state (models/hybrid.py)
+    slots=None,  # [B] decode slot of each row
+) -> Tuple[jnp.ndarray, ...]:
     """Prompt pass for only the uncached suffix of a prefix-cache hit.
 
     The first ``prefix_lens`` tokens' KV is already resident in shared
@@ -811,6 +768,7 @@ def prefill_suffix_forward(
     impl = multitok_attention_impl(
         use_pallas, mesh, rows=S, unaligned=unaligned
     )
+    kernels = use_pallas  # below, use_pallas narrows to the multitok kernel
     sp_mesh = mesh if impl == "sp_shard" else None
     if sp_mesh is not None:
         # prefix caching on the sp-sharded pool: per-layer write +
@@ -841,17 +799,41 @@ def prefill_suffix_forward(
         x, (k_pages, v_pages) = jax.lax.scan(
             sp_layer_fn, x, (params["layers"], windows, k_pages, v_pages)
         )
-        last_idx = jnp.clip(suffix_lens - 1, 0, S - 1)
-        last_hidden = jnp.take_along_axis(
-            x, last_idx[:, None, None].repeat(x.shape[-1], axis=-1), axis=1
-        )[:, 0]
-        return _logits(params, spec, last_hidden), k_pages, v_pages
+        return (_logits(params, spec, _last_rows(x, suffix_lens)),
+                k_pages, v_pages)
 
     use_pallas = impl == "pallas"
     if use_pallas:
         from vgate_tpu.ops.pallas.paged_attention import (
             paged_multitok_attention_pallas,
         )
+
+    if spec.is_hybrid:
+        # a later chunk of a chunked prefill: the rows continue from the
+        # slot's recurrent state (zeros where nothing precedes them);
+        # prefix-cache hits never get here (the engine turns matching
+        # off for a spec with recurrent layers)
+        assert not unaligned, "hybrid specs have no copy-on-write prefix"
+        from vgate_tpu.models import hybrid
+
+        def attend(q, k, v, kp, vp, layer):
+            if use_pallas:
+                return paged_multitok_attention_pallas(
+                    q, kp, vp, ctx_page_tables, prefix_lens, suffix_lens,
+                    layer=layer, scale=_query_scale(spec),
+                )
+            return paged_suffix_attention(
+                q, kp, vp, ctx_page_tables, prefix_lens, total_lens,
+                scale=_query_scale(spec), layer=layer,
+            )
+
+        x, k_pages, v_pages, state = hybrid.prompt_forward(
+            params, spec, x, suffix_lens, positions, k_pages, v_pages,
+            state, slots, prefix_lens == 0, suffix_page_tables, attend,
+            kernels,
+        )
+        return (_logits(params, spec, _last_rows(x, suffix_lens)),
+                k_pages, v_pages, state)
 
     # both the suffix write AND the paged context read are layer-indexed
     # on the full [L, ...] buffers — no per-layer pool slice ever
@@ -884,11 +866,7 @@ def prefill_suffix_forward(
     x, k_pages, v_pages = _kv_layer_scan(
         params, spec, body, x, k_pages, v_pages
     )
-    last_idx = jnp.clip(suffix_lens - 1, 0, S - 1)
-    last_hidden = jnp.take_along_axis(
-        x, last_idx[:, None, None].repeat(x.shape[-1], axis=-1), axis=1
-    )[:, 0]
-    return _logits(params, spec, last_hidden), k_pages, v_pages
+    return _logits(params, spec, _last_rows(x, suffix_lens)), k_pages, v_pages
 
 
 def spec_verify_forward(
@@ -920,6 +898,11 @@ def spec_verify_forward(
     paged-KV form of "no rollback needed".  Returns (logits [B, S, V],
     k_pages, v_pages).
     """
+    if spec.is_hybrid:
+        raise NotImplementedError(
+            "speculative verification needs a recurrent state that can "
+            "roll back; the engine refuses it for hybrid specs"
+        )
     B, S = tokens.shape
     ps = k_pages.shape[3]
     width = page_tables.shape[1]

@@ -72,10 +72,89 @@ class ModelSpec:
     # win is measured on hardware (threaded on the spec like
     # quant_kernel so it reaches the jitted decode as a static arg)
     decode_block_slots: int = 1
+    # ---- expert layer (models/decoder.py _expert_layer).  num_experts
+    # is the experts HELD here; the router scores `router_width` experts
+    # (0 = the held ones: a chip that holds them all) and the held ones
+    # are `first_expert .. first_expert + num_experts - 1` of those.
+    moe_intermediate_size: int = 0  # 0 = intermediate_size
+    shared_expert_intermediate_size: int = 0  # 0 = no shared expert
+    router_width: int = 0
+    first_expert: int = 0
+    # ---- gated full attention (Qwen3-Next): per-head RMSNorm on q and
+    # k, a sigmoid gate on the attention output projected beside q, and
+    # rope on the first `partial_rotary_factor` of each head
+    qk_norm: bool = False
+    attn_output_gate: bool = False
+    partial_rotary_factor: float = 1.0
+    # ---- layer kinds by period: layer l is full attention when
+    # (l + 1) % full_attention_interval == 0 and Gated DeltaNet linear
+    # attention otherwise (0 = every layer is full attention)
+    full_attention_interval: int = 0
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 0
+
+    def __post_init__(self):
+        # a preset changed from JSON (perfbench/serve.py overrides)
+        # brings lists; the spec is a static jit argument and must hash
+        if not isinstance(self.extra_stop_ids, tuple):
+            object.__setattr__(
+                self, "extra_stop_ids", tuple(self.extra_stop_ids)
+            )
 
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def is_hybrid(self) -> bool:
+        """Linear-attention layers beside full-attention ones: a
+        per-slot recurrent state beside the paged pool."""
+        return self.full_attention_interval > 1
+
+    @property
+    def num_periods(self) -> int:
+        return self.num_layers // max(1, self.full_attention_interval)
+
+    @property
+    def attn_layers(self) -> int:
+        """Layers that hold K/V pages."""
+        return self.num_periods if self.is_hybrid else self.num_layers
+
+    @property
+    def linear_layers(self) -> int:
+        return self.num_layers - self.attn_layers
+
+    @property
+    def linear_per_period(self) -> int:
+        return self.full_attention_interval - 1 if self.is_hybrid else 0
+
+    @property
+    def router_experts(self) -> int:
+        return self.router_width or self.num_experts
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def linear_key_dim(self) -> int:
+        return self.linear_num_key_heads * self.linear_key_head_dim
+
+    @property
+    def linear_value_dim(self) -> int:
+        return self.linear_num_value_heads * self.linear_value_head_dim
+
+    @property
+    def linear_conv_dim(self) -> int:
+        """Channels the causal convolution runs over: q, k and v."""
+        return 2 * self.linear_key_dim + self.linear_value_dim
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor)
 
     @property
     def num_params(self) -> int:
@@ -87,16 +166,34 @@ class ModelSpec:
         q_dim = self.num_heads * self.head_dim
         kv_dim = self.num_kv_heads * self.head_dim
         attn = D * q_dim + 2 * D * kv_dim + q_dim * D
+        if self.attn_output_gate:
+            attn += D * q_dim
+        if self.qk_norm:
+            attn += 2 * self.head_dim
         if self.qkv_bias:
             attn += q_dim + 2 * kv_dim
         if self.is_moe:
-            mlp = self.num_experts * 3 * D * F + D * self.num_experts
+            Fe, Fs = self.expert_width, self.shared_expert_intermediate_size
+            mlp = self.num_experts * 3 * D * Fe + D * self.router_experts
+            if Fs:
+                mlp += 3 * D * Fs + D
         else:
             mlp = 3 * D * F
         norms = 2 * D + (2 * D if self.ffn_sandwich else 0)
         embed = self.vocab_size * D
         head = 0 if self.tie_embeddings else self.vocab_size * D
-        return L * (attn + mlp + norms) + embed + head + D
+        linear = 0
+        if self.is_hybrid:
+            Hv, vd = self.linear_num_value_heads, self.linear_value_dim
+            linear = (
+                D * (self.linear_conv_dim + vd) + D * 2 * Hv
+                + self.linear_conv_dim * self.linear_conv_kernel_dim
+                + 2 * Hv + self.linear_value_head_dim + vd * D
+            )
+        return (
+            self.attn_layers * attn + self.linear_layers * linear
+            + L * (mlp + norms) + embed + head + D
+        )
 
     @property
     def layer_windows(self) -> tuple:
@@ -109,6 +206,18 @@ class ModelSpec:
             self.sliding_window if i % 2 == 0 else 0
             for i in range(self.num_layers)
         )
+
+    def check_expert_share(self) -> None:
+        """The held experts lie inside the router's width."""
+        if self.is_moe and (
+            self.first_expert < 0
+            or self.first_expert + self.num_experts > self.router_experts
+        ):
+            raise ValueError(
+                f"{self.name}: experts {self.first_expert}.."
+                f"{self.first_expert + self.num_experts - 1} are not "
+                f"inside the router's {self.router_experts}"
+            )
 
     @property
     def rope_scaling(self):
@@ -365,6 +474,40 @@ GEMMA2_9B = _register(
     )
 )
 
+QWEN3_NEXT_80B = _register(
+    ModelSpec(
+        name="Qwen/Qwen3-Next-80B-A3B-Instruct",
+        extra_stop_ids=(151643,),  # <|endoftext|>
+        vocab_size=151936,
+        hidden_size=2048,
+        num_layers=48,
+        num_heads=16,
+        num_kv_heads=2,
+        head_dim=256,
+        intermediate_size=5120,  # published; no layer is dense
+        rope_theta=10_000_000.0,
+        rms_eps=1e-6,
+        qkv_bias=False,
+        tie_embeddings=False,
+        max_position_embeddings=262144,
+        unit_offset_norm=True,
+        num_experts=512,
+        experts_per_token=10,
+        moe_intermediate_size=512,
+        shared_expert_intermediate_size=512,
+        router_width=512,
+        qk_norm=True,
+        attn_output_gate=True,
+        partial_rotary_factor=0.25,
+        full_attention_interval=4,
+        linear_num_key_heads=16,
+        linear_num_value_heads=32,
+        linear_key_head_dim=128,
+        linear_value_head_dim=128,
+        linear_conv_kernel_dim=4,
+    )
+)
+
 BGE_BASE = _register(
     ModelSpec(
         name="BAAI/bge-base-en-v1.5",
@@ -436,6 +579,42 @@ TINY_GEMMA2 = _register(
         embed_scale=True,
         unit_offset_norm=True,
         ffn_sandwich=True,
+    )
+)
+
+# one period of the hybrid stack (three Gated DeltaNet layers, one gated
+# full-attention layer), every mechanism of Qwen3-Next at toy widths
+TINY_HYBRID = _register(
+    ModelSpec(
+        name="tiny-hybrid",
+        vocab_size=512,
+        hidden_size=64,
+        num_layers=4,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        intermediate_size=128,
+        rope_theta=10000.0,
+        rms_eps=1e-6,
+        qkv_bias=False,
+        tie_embeddings=False,
+        eos_token_id=0,
+        bos_token_id=1,
+        unit_offset_norm=True,
+        num_experts=8,
+        experts_per_token=2,
+        moe_intermediate_size=32,
+        shared_expert_intermediate_size=32,
+        router_width=8,
+        qk_norm=True,
+        attn_output_gate=True,
+        partial_rotary_factor=0.25,
+        full_attention_interval=4,
+        linear_num_key_heads=2,
+        linear_num_value_heads=4,
+        linear_key_head_dim=16,
+        linear_value_head_dim=16,
+        linear_conv_kernel_dim=4,
     )
 )
 

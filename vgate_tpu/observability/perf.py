@@ -289,6 +289,10 @@ class PerfRecorder:
         self.total_decode_ctx_token_steps = 0
         # chunk length (steps) -> decode chunks read back
         self._chunks_by_steps: Dict[int, int] = {}
+        # expert-layer and recurrent-state counters (a hybrid spec's
+        # decode chunks; empty otherwise and then left out of totals())
+        self._moe: Dict[str, int] = {}
+        self._state: Dict[str, int] = {}
         self.total_engine_cpu_s = 0.0
         self.total_engine_cpu_in_wait_s = 0.0
         # the flight recorder's per-request phase sums (admitted /
@@ -385,6 +389,32 @@ class PerfRecorder:
             cur.decode_bytes += steps * self.roofline.step_bytes(
                 ctx_tokens
             )
+
+    def note_moe(self, stats, rows: int, moe_layers: int,
+                 linear_layers: int) -> None:
+        """One decode chunk's device counters, booked once per readback.
+        ``stats`` is ``[steps, 4]``: per step, over its expert layers,
+        the (row, choice) assignments made, those that fell on experts
+        held here, the held experts hit (summed over layers) and the
+        largest load of a held expert (the max over layers).  ``rows``
+        sequences rode the chunk: each step updated that many rows of
+        the recurrent state in each linear layer."""
+        steps = int(stats.shape[0])
+        sums = stats.sum(axis=0)
+        for name, add in (
+            ("assignments", int(sums[0])),
+            ("held_assignments", int(sums[1])),
+            ("experts_hit", int(sums[2])),
+            ("load_max_sum", int(sums[3])),
+            ("layer_steps", steps * moe_layers),
+            ("steps", steps),
+        ):
+            self._moe[name] = self._moe.get(name, 0) + add
+        for name, add in (
+            ("rows_updated", steps * rows * linear_layers),
+            ("layer_steps", steps * linear_layers),
+        ):
+            self._state[name] = self._state.get(name, 0) + add
 
     def tick_end(self, worked: bool) -> None:
         """Close the tick: derive ``host_s`` as the unexplained wall
@@ -603,6 +633,9 @@ class PerfRecorder:
         }
         if self.request_totals is not None:
             out.update(self.request_totals())
+        if self._moe:
+            out["moe"] = dict(self._moe)
+            out["state"] = dict(self._state)
         return out
 
     def snapshot(self) -> Dict[str, Any]:

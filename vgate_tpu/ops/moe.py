@@ -1,0 +1,187 @@
+"""The expert layer: dropless top-k routing over the router's full
+width, the held experts' products as ONE grouped product over rows
+sorted by expert, and a gated shared expert.
+
+The layer is told which experts it holds (``spec.num_experts`` of the
+router's ``spec.router_experts``, from ``spec.first_expert``).  A chip
+that holds them all (Mixtral, ``tiny-moe``) computes the whole layer; a
+chip that holds a share computes its own experts' part of the result
+for the rows routed to them and leaves out what the absent experts
+would have added (model-configs guide, section 4) -- nothing stands in
+for the absent chips.  No row is ever dropped, whatever the imbalance:
+the grouped product takes every (row, choice) pair that fell on a held
+expert, however many fell on one.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from vgate_tpu.models.specs import ModelSpec
+from vgate_tpu.utils.math import cdiv
+
+# the device counters a routed layer returns beside its output
+STAT_NAMES = ("assignments", "held_assignments", "experts_hit", "load_max")
+
+
+def combine_stats(stats):
+    """[n, 4] counters of n layers (or blocks) -> [4]: the first three
+    add up, the largest load is the largest."""
+    return jnp.concatenate(
+        [jnp.sum(stats[:, :3], axis=0), jnp.max(stats[:, 3:], axis=0)])
+
+
+def _plain(w, dtype):
+    """Expert weights as a plain array: a quantized tree (Mixtral under
+    model.quantization) dequantizes to the activations' type, the
+    grouped product being a plain product."""
+    from vgate_tpu.ops.quant import PackedQTensor, QTensor, unpack_int4
+
+    if isinstance(w, PackedQTensor):
+        q = unpack_int4(w.q_packed)
+    elif isinstance(w, QTensor):
+        q = w.q
+    else:
+        return w
+    return (q.astype(jnp.float32) * w.scale[..., None, :]).astype(dtype)
+
+
+def grouped_product(rows, w, group_sizes, layer, use_pallas: bool):
+    """rows [M, K] sorted by expert; w either ONE layer's ``[E, K, N]``
+    (``layer`` None) or the stack ``[L, E, K, N]`` with the traced
+    ``layer``.  The jnp twin is XLA's own ragged product."""
+    w = _plain(w, rows.dtype)
+    if use_pallas and layer is not None:
+        from vgate_tpu.ops.pallas.grouped_matmul import grouped_matmul_pallas
+
+        K, N = w.shape[-2:]
+        M = rows.shape[0]
+        tm = 128 if M >= 4096 else 32
+        tn = N if K * N * w.dtype.itemsize <= (2 << 20) else 512
+        pad = cdiv(M, tm) * tm - M
+        if pad:
+            rows = jnp.pad(rows, ((0, pad), (0, 0)))
+        out = grouped_matmul_pallas(
+            rows.astype(w.dtype), w, group_sizes, layer, tm=tm, tn=tn
+        )
+        return out[:M] if pad else out
+    if layer is not None:
+        w = w[layer]
+    return jax.lax.ragged_dot(rows.astype(w.dtype), w, group_sizes)
+
+
+# rows a call routes at once: a prompt wave of 8 x 2,048 tokens would
+# otherwise hold (tokens x choices) x hidden temporaries of gigabytes
+BLOCK_TOKENS = 4096
+
+
+def expert_layer(x, lp, spec: ModelSpec, act, row_mask=None,
+                 use_pallas: bool = False, layer=None, stack=None):
+    """``_expert_block`` over blocks of ``BLOCK_TOKENS`` rows (one block
+    for a decode step or a small wave): the weights are read once a
+    block, the temporaries stay bounded.  Arguments and result as
+    ``_expert_block``."""
+    D = x.shape[-1]
+    T = x.size // D
+    if T <= BLOCK_TOKENS:
+        return _expert_block(x, lp, spec, act, row_mask, use_pallas,
+                             layer, stack)
+    # blocks unrolled: XLA runs them one after the other and reuses one
+    # block's temporaries for the next
+    xt = x.reshape(T, D)
+    mask = None if row_mask is None else row_mask.reshape(T)
+    outs, stats = [], []
+    for lo in range(0, T, BLOCK_TOKENS):
+        out, st = _expert_block(
+            xt[lo:lo + BLOCK_TOKENS], lp, spec, act,
+            None if mask is None else mask[lo:lo + BLOCK_TOKENS],
+            use_pallas, layer, stack,
+        )
+        outs.append(out)
+        stats.append(st)
+    return (jnp.concatenate(outs).reshape(x.shape),
+            combine_stats(jnp.stack(stats)))
+
+
+def _expert_block(x, lp, spec: ModelSpec, act, row_mask=None,
+                  use_pallas: bool = False, layer=None, stack=None):
+    """x: [..., D].  ``lp`` holds this layer's ``router`` [D, R] and
+    either its experts' ``gate``/``up``/``down`` (``{"w": [E, ., .]}``)
+    or, with ``stack``/``layer``, nothing of them: ``stack`` is then the
+    ``{"gate","up","down"}`` dict of ``[L, E, ., .]`` stacks and
+    ``layer`` the traced index into them (the Pallas path must not see
+    a scan's per-layer slice).  ``row_mask`` ([...] bool) marks the rows
+    that are real: padding and idle slots route nowhere.  Returns
+    (out [..., D], stats [4] int32 in ``STAT_NAMES`` order)."""
+    orig_shape = x.shape
+    D = orig_shape[-1]
+    xt = x.reshape(-1, D)
+    T = xt.shape[0]
+    E, K, first = spec.num_experts, spec.experts_per_token, spec.first_expert
+
+    with jax.named_scope("moe_route"):
+        logits = jnp.einsum(
+            "td,de->te", xt.astype(jnp.float32),
+            lp["router"].astype(jnp.float32),
+        )
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, K)  # [T, K]
+        gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+        local = gate_idx - first
+        real = jnp.ones((T, 1), bool) if row_mask is None else (
+            row_mask.reshape(T, 1)
+        )
+        held = (local >= 0) & (local < E) & real
+        # choices on experts held elsewhere sort last, under group E
+        flat_e = jnp.where(held, local, E).reshape(T * K)
+        order = jnp.argsort(flat_e, stable=True)
+        sorted_tok = order // K
+        counts = jnp.zeros((E + 1,), jnp.int32).at[flat_e].add(1)
+        group_sizes = counts[:E]
+        n_real = jnp.sum(real.astype(jnp.int32))
+        stats = jnp.stack([
+            n_real * K, jnp.sum(group_sizes),
+            jnp.sum((group_sizes > 0).astype(jnp.int32)),
+            jnp.max(group_sizes),
+        ]).astype(jnp.int32)
+
+    with jax.named_scope("moe_experts"):
+        rows = xt[sorted_tok]  # [T*K, D], sorted by expert
+        ws = stack if stack is not None else lp
+        lay = layer if stack is not None else None
+        gp = lambda r, name: grouped_product(
+            r, ws[name]["w"], group_sizes, lay, use_pallas
+        )
+        hidden = act(gp(rows, "gate").astype(jnp.float32)).astype(
+            xt.dtype) * gp(rows, "up").astype(xt.dtype)
+        y = gp(hidden, "down")  # [T*K, D]
+        w_sorted = jnp.where(held, gate_vals, 0.0).reshape(T * K)[order]
+        in_group = jnp.arange(T * K) < jnp.sum(group_sizes)
+        y = jnp.where(
+            in_group[:, None],
+            y.astype(jnp.float32) * w_sorted[:, None], 0.0,
+        )
+        # back to (token, choice) order, then the choices add up
+        inverse = jnp.zeros((T * K,), jnp.int32).at[order].set(
+            jnp.arange(T * K, dtype=jnp.int32)
+        )
+        out = jnp.sum(y[inverse].reshape(T, K, D), axis=1)
+
+    if spec.shared_expert_intermediate_size:
+        with jax.named_scope("shared_expert"):
+            g = jnp.einsum("td,df->tf", xt, lp["shared_gate"]["w"])
+            u = jnp.einsum("td,df->tf", xt, lp["shared_up"]["w"])
+            s = jnp.einsum(
+                "tf,fd->td",
+                act(g.astype(jnp.float32)).astype(xt.dtype) * u,
+                lp["shared_down"]["w"],
+            )
+            sg = jax.nn.sigmoid(jnp.einsum(
+                "td,d->t", xt.astype(jnp.float32),
+                lp["shared_router"].astype(jnp.float32),
+            ))
+            out = out + s.astype(jnp.float32) * sg[:, None]
+    return out.astype(x.dtype).reshape(orig_shape), stats

@@ -11,7 +11,7 @@ surrounding computation), cutting weight HBM traffic 2x (int8) or 4x
 Every weight in the decoder layout keeps its output dim LAST, so one
 broadcast rule covers q/k/v/o/gate/up/down and lm_head.  MoE expert weights
 [L, E, in, out] quantize per (layer, expert, out-channel) and dequantize
-inside the per-expert GEMMs (models/decoder.py _expert_einsum); the router
+inside the per-expert GEMMs (ops/moe.py dequantizes them into its grouped product); the router
 stays fp32 (it is tiny and drives top-k selection).
 """
 

@@ -43,12 +43,24 @@ def apply_rope(
     positions: jnp.ndarray,
     theta: float = 10000.0,
     scaling=None,
+    rotary_dim: int = 0,
 ) -> jnp.ndarray:
     """Rotate ``x`` of shape [..., seq, heads, head_dim] by per-token angles.
 
     ``positions`` has shape broadcastable to x.shape[:-2] (i.e. [..., seq]).
-    Computed in fp32, returned in the input dtype.
+    Computed in fp32, returned in the input dtype.  With ``rotary_dim``
+    (partial rotary, Qwen3-Next's 64 of 256) only the first
+    ``rotary_dim`` dimensions of a head rotate, with frequencies counted
+    over those; the rest pass through.
     """
+    if 0 < rotary_dim < x.shape[-1]:
+        return jnp.concatenate(
+            [
+                apply_rope(x[..., :rotary_dim], positions, theta, scaling),
+                x[..., rotary_dim:],
+            ],
+            axis=-1,
+        )
     head_dim = x.shape[-1]
     inv_freq = rope_frequencies(head_dim, theta, scaling)
     angles = positions.astype(jnp.float32)[..., None] * inv_freq  # [..., S, hd/2]

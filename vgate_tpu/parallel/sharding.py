@@ -38,6 +38,17 @@ def param_pspecs(spec: ModelSpec, mesh: Mesh) -> Dict[str, Any]:
     D, L = spec.hidden_size, spec.num_layers
     Q, KVD = spec.q_dim, spec.kv_dim
     F, V, E = spec.intermediate_size, spec.vocab_size, spec.num_experts
+    if spec.is_hybrid:
+        # plain meshes only (the engine refuses tp/pp/sp/ep for a spec
+        # with recurrent layers): every tensor whole on every chip
+        from vgate_tpu.models.decoder import init_params
+
+        shapes = jax.eval_shape(
+            lambda: init_params(spec, jax.random.PRNGKey(0))
+        )
+        return jax.tree.map(lambda _: P(), shapes)
+    if spec.is_moe:
+        F = spec.expert_width
 
     # the stacked layer axis L shards over pp: each pipeline stage holds
     # only its own layers' weights (and KV pages, kv_pspec below)

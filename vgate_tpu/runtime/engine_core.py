@@ -70,6 +70,10 @@ from vgate_tpu.models.decoder import (
     prefill_suffix_forward,
     spec_verify_forward,
 )
+from vgate_tpu.models.hybrid import make_state as make_hybrid_state
+from vgate_tpu.models.hybrid import (
+    state_bytes_per_slot as hybrid_state_bytes_per_slot,
+)
 from vgate_tpu.models.specs import ModelSpec, spec_for_model_id
 from vgate_tpu.ops.sampling import (
     apply_logit_bias,
@@ -149,6 +153,15 @@ _DTYPES = {
 }
 
 
+def _state_kw(state, slots=None) -> Dict[str, Any]:
+    """The forwards' extra arguments for a spec with recurrent layers;
+    nothing for the others, whose programs stay what they were."""
+    if state is None:
+        return {}
+    return {"state": state} if slots is None else {
+        "state": state, "slots": slots}
+
+
 @jax.named_scope("sample")
 def _sample_first(
     logits, temps, top_ps, top_ks, key, seeds, steps, num_logprobs,
@@ -186,7 +199,7 @@ def _sample_first(
     static_argnames=(
         "spec", "mesh", "use_pallas", "num_logprobs"
     ),
-    donate_argnames=("k_pages", "v_pages"),
+    donate_argnames=("k_pages", "v_pages", "state"),
 )
 def _prefill_step(
     params, spec: ModelSpec, tokens, seq_lens, k_pages, v_pages,
@@ -194,24 +207,31 @@ def _prefill_step(
     seeds=None, steps=None, num_logprobs: int = 0,
     counts=None, freq_pens=None, pres_pens=None,
     min_toks=None, stop_id_mat=None, bias_ids=None, bias_vals=None,
+    state=None, slots=None,
 ):
-    logits, k_pages, v_pages = prefill_forward(
+    """The cache is threaded and donated as ONE value: the K/V pools
+    and, for a spec with recurrent layers, the per-slot ``state``
+    (models/hybrid.py), whose rows ``slots`` this pass overwrites.  It
+    comes back as the result's tail: ``(k_pages, v_pages)`` or
+    ``(k_pages, v_pages, state)``."""
+    logits, *cache = prefill_forward(
         params, spec, tokens, seq_lens, k_pages, v_pages, page_tables,
         mesh=mesh, use_pallas=use_pallas,
+        **_state_kw(state, slots),
     )
     out = _sample_first(
         logits, temps, top_ps, top_ks, key, seeds, steps, num_logprobs,
         counts, freq_pens, pres_pens, min_toks, stop_id_mat, bias_ids,
         bias_vals,
     )
-    return out, k_pages, v_pages
+    return (out, *cache)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("spec", "num_logprobs", "use_pallas", "mesh",
                      "unaligned"),
-    donate_argnames=("k_pages", "v_pages"),
+    donate_argnames=("k_pages", "v_pages", "state"),
 )
 def _suffix_prefill_step(
     params, spec: ModelSpec, tokens, prefix_lens, suffix_lens, k_pages,
@@ -220,22 +240,23 @@ def _suffix_prefill_step(
     counts=None, freq_pens=None, pres_pens=None,
     min_toks=None, stop_id_mat=None, bias_ids=None, bias_vals=None,
     use_pallas: bool = False, mesh=None, unaligned: bool = False,
+    state=None, slots=None,
 ):
     """Prompt pass for the uncached suffix of a prefix-cache hit, with
     fused first-token sampling (models/decoder.py prefill_suffix_forward).
     ``unaligned`` is the copy-on-write variant: prefix_lens may fall
     mid-page and the KV write becomes a per-token scatter."""
-    logits, k_pages, v_pages = prefill_suffix_forward(
+    logits, *cache = prefill_suffix_forward(
         params, spec, tokens, prefix_lens, suffix_lens, k_pages, v_pages,
         suffix_page_tables, ctx_page_tables, use_pallas=use_pallas,
-        mesh=mesh, unaligned=unaligned,
+        mesh=mesh, unaligned=unaligned, **_state_kw(state, slots),
     )
     out = _sample_first(
         logits, temps, top_ps, top_ks, key, seeds, steps, num_logprobs,
         counts, freq_pens, pres_pens, min_toks, stop_id_mat, bias_ids,
         bias_vals,
     )
-    return out, k_pages, v_pages
+    return (out, *cache)
 
 
 @functools.partial(jax.jit, donate_argnames=("k_pages", "v_pages"))
@@ -370,7 +391,7 @@ def _decode_step(
     static_argnames=("spec", "num_steps", "use_pallas", "max_position",
                      "mesh", "num_logprobs", "all_greedy", "guard",
                      "guard_threshold"),
-    donate_argnames=("k_pages", "v_pages", "counts"),
+    donate_argnames=("k_pages", "v_pages", "counts", "state"),
 )
 def _decode_chunk(
     params, spec: ModelSpec, tokens, positions, k_pages, v_pages,
@@ -380,7 +401,7 @@ def _decode_chunk(
     counts=None, freq_pens=None, pres_pens=None,
     min_toks=None, stop_id_mat=None, all_greedy: bool = False,
     bias_ids=None, bias_vals=None, guard: bool = False,
-    guard_threshold: float = 1.0e4,
+    guard_threshold: float = 1.0e4, state=None,
 ):
     """``num_steps`` decode steps fused into one device program.
 
@@ -398,18 +419,29 @@ def _decode_chunk(
     must not trip the NaN/Inf check — returned as ``[num_steps, B]``
     uint8 (integrity.logit_guard flag bits).  Static, so the guard-off
     program is byte-identical to the pre-integrity one.
+
+    ``state`` (a spec with recurrent layers) rides the scan's carry
+    beside the pools, rows of active slots updated in place; the result
+    then ends ``..., chunk_flags, state, moe_stats`` with ``moe_stats``
+    ``[num_steps, 4]`` int32, the expert layers' device counters summed
+    over the layers of each step (ops/moe.py STAT_NAMES), read back
+    with the chunk's tokens.
     """
 
     if steps is None:
         steps = jnp.zeros_like(positions)
 
     def body(carry, _):
-        tokens, positions, counter, steps, counts, k_pages, v_pages = carry
+        (tokens, positions, counter, steps, counts, k_pages, v_pages,
+         state) = carry
         key = jax.random.fold_in(base_key, counter)
-        logits, k_pages, v_pages = decode_forward(
+        logits, k_pages, v_pages, *more = decode_forward(
             params, spec, tokens, positions, k_pages, v_pages, page_tables,
             active=active, use_pallas=use_pallas, mesh=mesh,
+            **_state_kw(state),
         )
+        if more:
+            state, moe_stats = more
         if guard:
             step_flags = integrity.logit_guard(logits, guard_threshold)
         with jax.named_scope("sample"):
@@ -439,6 +471,8 @@ def _decode_chunk(
                 ys = (next_tokens,)
         if guard:
             ys = ys + (step_flags,)
+        if more:
+            ys = ys + (moe_stats,)
         positions = positions + active.astype(positions.dtype)
         steps = steps + active.astype(steps.dtype)
         if counts is not None:
@@ -453,16 +487,21 @@ def _decode_chunk(
             positions = jnp.minimum(positions, max_position)
         return (
             next_tokens, positions, counter + 1, steps, counts,
-            k_pages, v_pages,
+            k_pages, v_pages, state,
         ), ys
 
     carry, ys = jax.lax.scan(
         body,
-        (tokens, positions, counter, steps, counts, k_pages, v_pages),
+        (tokens, positions, counter, steps, counts, k_pages, v_pages,
+         state),
         None,
         length=num_steps,
     )
-    tokens, positions, counter, steps, counts, k_pages, v_pages = carry
+    (tokens, positions, counter, steps, counts, k_pages, v_pages,
+     state) = carry
+    tail = ()
+    if state is not None:
+        tail, ys = (state, ys[-1]), ys[:-1]
     # [num_steps, B] uint8 sentinel words when guarded (host ORs the
     # step axis at readback), None otherwise
     chunk_flags = ys[-1] if guard else None
@@ -473,7 +512,7 @@ def _decode_chunk(
     chunk_lp = ys[1:] if num_logprobs > 0 else None
     return (
         chunk_tokens, chunk_lp, tokens, positions, counter, steps, counts,
-        k_pages, v_pages, chunk_flags,
+        k_pages, v_pages, chunk_flags, *tail,
     )
 
 
@@ -617,6 +656,7 @@ def rebuild_core(
     old.stop()
     old.k_pages = None
     old.v_pages = None
+    old.state = None
     old._dec_state = None
     old._pending_chunks.clear()
     old._spec_pen = None
@@ -758,6 +798,49 @@ def refuse_unbuildable_kernels(spec: ModelSpec, kv_quant: bool) -> None:
         )
 
 
+def refuse_unsupported_recurrent(spec: ModelSpec, config: VGTConfig,
+                                 mesh) -> None:
+    """Engine-construction gate for a spec with recurrent layers
+    (models/hybrid.py): everything that moves, shares or rolls back a
+    sequence's cache knows pages only, and a page without the recurrent
+    state that belongs to it is a WRONG cache.  Each is refused here by
+    name, at boot, not at the first request that would need it.  (Prefix
+    matching is not refused but turned off: it is on by default.)"""
+    if not spec.is_hybrid:
+        return
+    what = None
+    bad_axes = {
+        a: int(mesh.shape.get(a, 1)) for a in ("tp", "pp", "sp", "ep")
+        if int(mesh.shape.get(a, 1)) > 1
+    }
+    if bad_axes:
+        what = (f"a {bad_axes} mesh: the recurrent state and its kernel "
+                "are not partitioned (dp composes: a replica owns its "
+                "state)")
+    elif config.tpu.speculative_k > 0:
+        what = ("speculative decoding (tpu.speculative_k): rejected "
+                "drafts would have to be rolled back out of the state")
+    elif int(config.kv_cache.host_swap_bytes) > 0:
+        what = ("the host swap tier (kv_cache.host_swap_bytes): it parks "
+                "pages and would leave the state behind")
+    elif any(r in ("prefill", "decode") for r in config.pod.roles):
+        what = ("disaggregated prefill/decode roles (pod.roles): the "
+                "handoff of a live sequence moves pages and would leave "
+                "the state behind")
+    elif config.kv_cache.dtype == "int8":
+        what = "kv_cache.dtype=int8: the gated attention path writes bf16"
+    elif config.model.quantization not in (None, "", "none"):
+        what = (f"model.quantization={config.model.quantization}: the "
+                "grouped expert product and the recurrent layers take "
+                "plain weights")
+    if what:
+        raise ValueError(
+            f"{spec.name} has recurrent (linear-attention) layers, which "
+            f"cannot run with {what}.  Preemption by recompute and "
+            "journal replay rebuild the state and are supported."
+        )
+
+
 class _EvacRequest:
     """One planned-evacuation command in flight between a caller thread
     (dp drain/rebalance coordinator, admin surface) and the engine
@@ -807,6 +890,8 @@ class EngineCore:
         initialize_distributed()
         self.dtype = _DTYPES[self.config.model.dtype]
         self.mesh = build_mesh(tpu_cfg, devices)
+        self.spec.check_expert_share()
+        refuse_unsupported_recurrent(self.spec, self.config, self.mesh)
         # Pallas kernels require a real TPU backend (tests run interpret-
         # mode kernels separately; the engine's jnp twins serve CPU meshes)
         platform = self.mesh.devices.flat[0].platform
@@ -925,6 +1010,7 @@ class EngineCore:
         params_bytes = sum(
             x.size * x.dtype.itemsize for x in jax.tree.leaves(self.params)
         )
+        self._params_bytes = int(params_bytes)
         # KV storage format (kv_cache.dtype — ops/kv_quant.py): int8
         # halves page data bytes (plus a bf16 scale per page/head/slot),
         # so the same HBM budget below yields ~2x the bf16 page count —
@@ -969,6 +1055,15 @@ class EngineCore:
         max_useful = (
             tpu_cfg.max_batch_slots * pages_per_seq + sp_shards
         )
+        # the recurrent state of a hybrid spec: one row a decode slot,
+        # sized before the pool so that the pool gets what is left
+        self._state_dtype = self.dtype
+        self._state_slot_bytes = (
+            hybrid_state_bytes_per_slot(
+                self.spec, jnp.dtype(self.dtype).itemsize)
+            if self.spec.is_hybrid else 0
+        )
+        state_bytes = self._state_slot_bytes * tpu_cfg.max_batch_slots
         if tpu_cfg.kv_num_pages:
             num_pages, sized_by = tpu_cfg.kv_num_pages, "config"
         else:
@@ -989,6 +1084,7 @@ class EngineCore:
                 hbm_bytes=tpu_cfg.hbm_bytes,
                 scale_bytes=kv_scale_bytes,
                 shards=kv_shards,
+                reserved_bytes=state_bytes,
             )
             num_pages = min(max_useful, fits)
             # what set the pool size, for /stats: the chip's memory, the
@@ -1006,7 +1102,7 @@ class EngineCore:
             # sp-1 extra pages, noise next to the pool)
             num_pages = num_pages + (-num_pages) % sp_shards
         self.geometry = KVGeometry(
-            num_layers=self.spec.num_layers,
+            num_layers=self.spec.attn_layers,
             num_pages=num_pages,
             page_size=tpu_cfg.kv_page_size,
             kv_heads=self.spec.num_kv_heads,
@@ -1023,6 +1119,13 @@ class EngineCore:
         self.k_pages, self.v_pages = make_kv_buffers(
             self.geometry, kv_pool_dtype, kv_sharding
         )
+        # None for every spec without recurrent layers: an empty pytree
+        # in the step programs, which then are what they were
+        self.state = (
+            make_hybrid_state(
+                self.spec, tpu_cfg.max_batch_slots, self._state_dtype)
+            if self.spec.is_hybrid else None
+        )
         self.allocator = PageAllocator(num_pages, num_shards=sp_shards)
         self.allocator.quantized = self._kv_quant
         for name in ("bf16", "f32", "f16", "int8"):
@@ -1038,7 +1141,11 @@ class EngineCore:
         mesh_sp = int(self.mesh.shape.get("sp", 1))
         mesh_pp = int(self.mesh.shape.get("pp", 1))
         pc = tpu_cfg.prefix_cache
-        self.prefix_cache_enabled = bool(pc.enabled and mesh_pp == 1)
+        # a prefix hit without the recurrent state that belongs to it
+        # would be wrong: matching is off for a hybrid spec
+        self.prefix_cache_enabled = bool(
+            pc.enabled and mesh_pp == 1 and not self.spec.is_hybrid
+        )
         # radix-tree prefix index (runtime/radix_cache.py): page-granular
         # cross-request sharing with COW partial pages and
         # pressure-integrated eviction; the tree registers itself as the
@@ -1110,7 +1217,7 @@ class EngineCore:
                     self.params, self.spec.tie_embeddings
                 ),
                 kv_token_bytes=kv_bytes_per_token(
-                    self.spec.num_layers,
+                    self.spec.attn_layers,
                     self.spec.num_kv_heads,
                     self.spec.head_dim,
                     dtype_bytes=kv_dtype_bytes,
@@ -2996,7 +3103,7 @@ class EngineCore:
                 "ctx_tokens": sum(p.seq.num_prompt_tokens for p in plans),
             },
         ) as disp:
-            out, self.k_pages, self.v_pages = _prefill_step(
+            out, *cache = _prefill_step(
                 self.params,
                 self.spec,
                 jnp.asarray(tokens),
@@ -3020,7 +3127,9 @@ class EngineCore:
                 stop_id_mat=mt_ids,
                 bias_ids=lb_ids,
                 bias_vals=lb_vals,
+                **self._state_args(self._prompt_slots(plans, B)),
             )
+            self._set_cache(cache)
         if fresh:
             self.perf.record_compile(
                 "prefill", key, disp.seconds, trigger="bucket"
@@ -3156,7 +3265,7 @@ class EngineCore:
                 "ctx_tokens": sum(p.seq.num_prompt_tokens for p in plans),
             },
         ) as disp:
-            out, self.k_pages, self.v_pages = _suffix_prefill_step(
+            out, *cache = _suffix_prefill_step(
                 self.params,
                 self.spec,
                 jnp.asarray(tokens),
@@ -3183,7 +3292,9 @@ class EngineCore:
                 use_pallas=self.use_pallas,
                 mesh=self._mt_mesh,
                 unaligned=unaligned,
+                **self._state_args(self._prompt_slots(plans, B)),
             )
+            self._set_cache(cache)
         if fresh:
             self.perf.record_compile(
                 "suffix_prefill", key, disp.seconds, trigger="bucket"
@@ -3251,7 +3362,7 @@ class EngineCore:
                     "rows": 1, "ctx_tokens": start + n,
                 },
             ) as disp:
-                _out, self.k_pages, self.v_pages = _suffix_prefill_step(
+                _out, *cache = _suffix_prefill_step(
                     self.params,
                     self.spec,
                     jnp.asarray(tokens),
@@ -3269,7 +3380,9 @@ class EngineCore:
                     steps=jnp.zeros((1,), jnp.int32),
                     use_pallas=self.use_pallas,
                     mesh=self._mt_mesh,
+                    **self._state_args(self._prompt_slots([plan], 1)),
                 )
+                self._set_cache(cache)
             if fresh:
                 self.perf.record_compile(
                     "chunked_prefill", key, disp.seconds,
@@ -3484,6 +3597,7 @@ class EngineCore:
                 self.k_pages,
                 self.v_pages,
                 chunk_flags,
+                *more,
             ) = _decode_chunk(
                 self.params,
                 self.spec,
@@ -3518,7 +3632,13 @@ class EngineCore:
                     self.config.integrity.saturate_threshold
                     if guard else 1.0e4
                 ),
+                **self._state_args(),
             )
+            # a spec with recurrent layers: the state, and the expert
+            # layers' counters [chunk, 4], read back with the tokens
+            moe_stats = None
+            if more:
+                self.state, moe_stats = more
         if fresh:
             self.perf.record_compile(
                 "decode", chunk_key, disp.seconds, trigger="chunk_variant"
@@ -3529,7 +3649,7 @@ class EngineCore:
         # readback is processed) must NOT receive the stale tokens
         self._pending_chunks.append(
             ([(s, s.preempt_count) for s in active], chunk, chunk_tokens,
-             start, chunk_lp, chunk_flags)
+             start, chunk_lp, chunk_flags, moe_stats)
         )
 
     @engine_thread_only
@@ -3538,7 +3658,7 @@ class EngineCore:
         host state: append tokens in order, detect EOS/length stops, discard
         steps past a stop."""
         while self._pending_chunks:
-            seqs, chunk, tokens_dev, _start, lp_dev, flags_dev = (
+            seqs, chunk, tokens_dev, _start, lp_dev, flags_dev, moe_dev = (
                 self._pending_chunks.pop(0)
             )
             # observe only the host-blocking readback time (kind="decode"):
@@ -3559,6 +3679,15 @@ class EngineCore:
                     if lp_dev is None
                     else tuple(np.asarray(a) for a in lp_dev)
                 )
+                if moe_dev is not None:
+                    # the expert layers' counters of these steps, and
+                    # the state rows each step updated: one readback
+                    # with the tokens (engine thread, once per chunk)
+                    self.perf.note_moe(
+                        np.asarray(moe_dev), rows=len(seqs),
+                        moe_layers=self.spec.num_layers,
+                        linear_layers=self.spec.linear_layers,
+                    )
             device_s = wait.seconds
             block_s = device_s + read.seconds
             if self.perf.enabled:
@@ -4309,6 +4438,31 @@ class EngineCore:
         except Exception as exc:  # pragma: no cover
             return {"alive": False, "error": str(exc)}
 
+    def _state_args(self, slots=None) -> Dict[str, Any]:
+        """The step programs' extra arguments for a spec with recurrent
+        layers (the state, and for a prompt pass each row's slot);
+        nothing for the others."""
+        return _state_kw(
+            self.state,
+            None if slots is None else jnp.asarray(slots, jnp.int32),
+        )
+
+    def _prompt_slots(self, plans, rows: int):
+        """Row -> decode slot for a prompt pass of ``rows`` padded rows;
+        padding rows point past the state (their update is dropped)."""
+        if self.state is None:
+            return None
+        slots = np.full((rows,), self.max_slots, np.int32)
+        for row, plan in enumerate(plans):
+            slots[row] = plan.slot
+        return slots
+
+    def _set_cache(self, cache) -> None:
+        """Take back what a step program returned of the cache."""
+        self.k_pages, self.v_pages, *rest = cache
+        if rest:
+            (self.state,) = rest
+
     def _note_attention(self, program: str, impl: str) -> None:
         self._attention.setdefault(program, set()).add(impl)
 
@@ -4335,6 +4489,21 @@ class EngineCore:
             # these so every recorded number names its KV config
             "kv_dtype": self.geometry.kv_dtype,
             "kv_page_bytes": self.geometry.page_bytes,
+            **(
+                {
+                    "state_cache": {
+                        "slots": self.max_slots,
+                        "bytes_per_slot": self._state_slot_bytes,
+                        "bytes": self._state_slot_bytes * self.max_slots,
+                        "linear_layers": self.spec.linear_layers,
+                        "dtype": "float32 state, "
+                        f"{dtype_short_name(self._state_dtype)} "
+                        "convolution tail",
+                    }
+                }
+                if self.spec.is_hybrid else {}
+            ),
+            "weights_bytes": self._params_bytes,
             "model": self.spec.name,
             "mesh": {
                 axis: int(size) for axis, size in self.mesh.shape.items()

@@ -88,6 +88,7 @@ def auto_num_pages(
     hbm_bytes: int = 0,
     scale_bytes: int = 0,
     shards: int = 1,
+    reserved_bytes: int = 0,
 ) -> int:
     """Size the page pool from free device HBM after weights are resident
     (the serving analogue of vLLM's gpu_memory_utilization knob,
@@ -110,16 +111,18 @@ def auto_num_pages(
     device = device or jax.devices()[0]
     stats = getattr(device, "memory_stats", lambda: None)()
     page_bytes = _page_bytes(
-        spec.num_layers, page_size, spec.num_kv_heads, spec.head_dim,
+        spec.attn_layers, page_size, spec.num_kv_heads, spec.head_dim,
         dtype_bytes, scale_bytes,
     ) // max(1, shards)
     if stats and "bytes_limit" in stats:
         limit = stats["bytes_limit"] * hbm_utilization
-        free = max(0, limit - stats.get("bytes_in_use", 0))
+        free = max(0, limit - stats.get("bytes_in_use", 0) - reserved_bytes)
     elif device.platform == "cpu":
         return fallback
     elif hbm_bytes:
-        free = max(0, hbm_bytes * hbm_utilization - params_bytes)
+        free = max(
+            0, hbm_bytes * hbm_utilization - params_bytes - reserved_bytes
+        )
     else:
         raise RuntimeError(
             f"{device.device_kind} reports no memory_stats()['bytes_limit'] "

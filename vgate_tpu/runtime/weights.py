@@ -40,10 +40,124 @@ def _stack(getter: TensorGetter, template: str, num_layers: int, transpose=False
     return np.stack(arrs)
 
 
+def _hybrid_params_from_getter(
+    spec: ModelSpec, getter: TensorGetter, dtype
+) -> Params:
+    """``Qwen3NextForCausalLM`` names -> the hybrid pytree of
+    models/hybrid.py (``layers = {"linear": [P, n, ...], "full": [P,
+    ...]}``).  The checkpoint holds ``in_proj_qkvz`` and ``in_proj_ba``
+    grouped by KEY head (per key head ``[q | k | its value heads' v |
+    their z]`` and ``[their b | their a]``); both are un-interleaved
+    here into ``[q | k | v | z]`` and ``[b | a]``.  A chip's share: the
+    experts ``first_expert ..`` of the router's width, and the first
+    ``vocab_size`` rows of embedding and head."""
+    P, n = spec.num_periods, spec.linear_per_period
+    period = spec.full_attention_interval
+    Hk, Hv = spec.linear_num_key_heads, spec.linear_num_value_heads
+    dk, dv = spec.linear_key_head_dim, spec.linear_value_head_dim
+    r = Hv // Hk
+    E, first = spec.num_experts, spec.first_expert
+    get = lambda i, name: np.asarray(getter(f"model.layers.{i}.{name}"))
+    lin = lambda i, name: get(i, f"{name}.weight").T  # torch [out, in]
+
+    def moe(i):
+        ex = lambda e, w: lin(i, f"mlp.experts.{first + e}.{w}")
+        out = {
+            "router": lin(i, "mlp.gate"),
+            "gate": {"w": np.stack([ex(e, "gate_proj") for e in range(E)])},
+            "up": {"w": np.stack([ex(e, "up_proj") for e in range(E)])},
+            "down": {"w": np.stack([ex(e, "down_proj") for e in range(E)])},
+        }
+        if spec.shared_expert_intermediate_size:
+            out.update({
+                "shared_gate": {"w": lin(i, "mlp.shared_expert.gate_proj")},
+                "shared_up": {"w": lin(i, "mlp.shared_expert.up_proj")},
+                "shared_down": {"w": lin(i, "mlp.shared_expert.down_proj")},
+                "shared_router": lin(i, "mlp.shared_expert_gate")[:, 0],
+            })
+        return out
+
+    def norms(i):
+        return {
+            "input_norm": get(i, "input_layernorm.weight"),
+            "post_norm": get(i, "post_attention_layernorm.weight"),
+        }
+
+    def full_layer(i):
+        out = {
+            **norms(i), **moe(i),
+            "q": {"w": lin(i, "self_attn.q_proj")},
+            "k": {"w": lin(i, "self_attn.k_proj")},
+            "v": {"w": lin(i, "self_attn.v_proj")},
+            "o": {"w": lin(i, "self_attn.o_proj")},
+        }
+        if spec.qk_norm:
+            out["q_norm"] = get(i, "self_attn.q_norm.weight")
+            out["k_norm"] = get(i, "self_attn.k_norm.weight")
+        return out
+
+    def linear_layer(i):
+        D = spec.hidden_size
+        qkvz = lin(i, "linear_attn.in_proj_qkvz").reshape(
+            D, Hk, 2 * dk + 2 * r * dv)
+        cuts = np.cumsum([dk, dk, r * dv])
+        parts = np.split(qkvz, cuts, axis=-1)  # q, k, v, z by key head
+        ba = lin(i, "linear_attn.in_proj_ba").reshape(D, Hk, 2 * r)
+        return {
+            **norms(i), **moe(i),
+            "in_qkvz": {"w": np.concatenate(
+                [p.reshape(D, -1) for p in parts], axis=-1)},
+            "in_ba": {"w": np.concatenate(
+                [ba[..., :r].reshape(D, Hv), ba[..., r:].reshape(D, Hv)],
+                axis=-1)},
+            "conv": get(i, "linear_attn.conv1d.weight")[:, 0, :],
+            "a_log": get(i, "linear_attn.A_log"),
+            "dt_bias": get(i, "linear_attn.dt_bias"),
+            "gdn_norm": get(i, "linear_attn.norm.weight"),
+            "out": {"w": lin(i, "linear_attn.out_proj")},
+        }
+
+    stack = lambda trees: jax.tree.map(lambda *xs: np.stack(xs), *trees)
+    np_dtype = np.dtype(dtype)
+    keep_f32 = ("a_log", "dt_bias")
+
+    def cast(tree):
+        return {
+            k: (np.asarray(v, np.float32) if k in keep_f32
+                else jax.tree.map(lambda x: np.asarray(x).astype(np_dtype),
+                                  v))
+            for k, v in tree.items()
+        }
+
+    V = spec.vocab_size
+    params: Params = {
+        "embed": np.asarray(getter("model.embed_tokens.weight"))[:V]
+        .astype(np_dtype),
+        "layers": {
+            "linear": cast(stack([
+                stack([linear_layer(p * period + j) for j in range(n)])
+                for p in range(P)
+            ])),
+            "full": cast(stack([
+                full_layer(p * period + n) for p in range(P)
+            ])),
+        },
+        "final_norm": np.asarray(getter("model.norm.weight"))
+        .astype(np_dtype),
+    }
+    if not spec.tie_embeddings:
+        params["lm_head"] = (
+            np.asarray(getter("lm_head.weight"))[:V].T.astype(np_dtype)
+        )
+    return params
+
+
 def params_from_getter(
     spec: ModelSpec, getter: TensorGetter, dtype=jnp.bfloat16
 ) -> Params:
     """Assemble the decoder pytree from HF-named tensors (host numpy)."""
+    if spec.is_hybrid:
+        return _hybrid_params_from_getter(spec, getter, dtype)
     L = spec.num_layers
     pre = "model.layers.{}."
     layers: Dict[str, Any] = {
