@@ -162,23 +162,16 @@ def main() -> None:
 
         report("rtt", timed(rtt_fn, tokens, iters_inside=1))
 
-    # --- full serving chunk (pallas / jnp / blocked) ---
-    import dataclasses
-
-    spec_blocked = dataclasses.replace(spec, decode_block_slots=8)
-    for name, use_pallas, chunk_spec in (
-        ("chunk-pallas", True, spec),
-        ("chunk-pallas-blocked", True, spec_blocked),
-        ("chunk-jnp", False, spec),
-    ):
+    # --- full serving chunk (pallas / jnp) ---
+    for name, use_pallas in (("chunk-pallas", True), ("chunk-jnp", False)):
         if only and name not in only:
             continue
         if use_pallas and platform != "tpu":
             continue
 
-        def run(k_pages, v_pages, up=use_pallas, sp_=chunk_spec):
+        def run(k_pages, v_pages, up=use_pallas):
             return _decode_chunk(
-                params, sp_, tokens, positions, k_pages, v_pages,
+                params, spec, tokens, positions, k_pages, v_pages,
                 page_tables, active, temps, top_ps, top_ks, key, counter,
                 num_steps=STEPS, use_pallas=up, max_position=ctx - 1,
                 seeds=seeds, steps=steps0,
@@ -351,7 +344,7 @@ def main() -> None:
     seq_lens = positions + 1
     L = spec.num_layers
 
-    for name in ("attn-pallas", "attn-pallas-blocked", "attn-jnp"):
+    for name in ("attn-pallas", "attn-jnp"):
         if only and name not in only:
             continue
         if name == "attn-pallas":
@@ -359,18 +352,6 @@ def main() -> None:
                 continue
             from vgate_tpu.ops.pallas.paged_attention import (
                 paged_decode_attention_pallas as attn,
-            )
-        elif name == "attn-pallas-blocked":
-            if platform != "tpu":
-                continue
-            import functools as _ft
-
-            from vgate_tpu.ops.pallas.paged_attention import (
-                paged_decode_attention_pallas_blocked,
-            )
-
-            attn = _ft.partial(
-                paged_decode_attention_pallas_blocked, block_slots=8
             )
         else:
             from vgate_tpu.ops.attention import (

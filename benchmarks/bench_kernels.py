@@ -53,8 +53,10 @@ def _looped(op):
 _sync = jax.block_until_ready
 
 
-def _median_time(fn, *args, iters: int = 10, warmup: int = 2) -> float:
-    """Median per-op time of the looped program."""
+def _median_time(
+    fn, *args, iters: int = 10, warmup: int = 2, loop: int = LOOP
+) -> float:
+    """Median per-op time of a program that runs the op `loop` times."""
     for _ in range(warmup):
         _sync(fn(*args))
     times = []
@@ -62,7 +64,7 @@ def _median_time(fn, *args, iters: int = 10, warmup: int = 2) -> float:
         t0 = time.perf_counter()
         _sync(fn(*args))
         times.append(time.perf_counter() - t0)
-    return float(np.median(times)) / LOOP
+    return float(np.median(times)) / loop
 
 
 def bench_paged_decode(B=128, H=12, KV=2, hd=128, ps=16, ctx=512):
@@ -111,6 +113,93 @@ def bench_paged_decode(B=128, H=12, KV=2, hd=128, ps=16, ctx=512):
         "pallas_us": round(t_kern * 1e6, 1),
         "speedup": round(t_twin / t_kern, 2),
     }
+
+
+# The four cells' decode shapes (BENCHMARK.json), as the traced runs hold
+# them (PERF.md section 5): (KV, G, hd), live slots of 256, and the live
+# streams' lengths.  A dead slot has length 0 (models/decoder.py hands the
+# kernel that for an inactive row).
+DECODE_CELLS = {
+    "qwen2.5-1.5b.decode-heavy": ((2, 6, 128), 251, (80, 720)),
+    "qwen2.5-1.5b.chat": ((2, 6, 128), 55, (70, 690)),
+    "qwen2.5-7b-l14.prefill-heavy": ((4, 7, 128), 54, (1040, 1880)),
+    "qwen3-next-80b-a3b-l8e128.decode-heavy": ((2, 8, 256), 251, (80, 720)),
+}
+with open(os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "perfbench", "peaks.json",
+)) as _fh:
+    HBM_BYTES_PER_S = json.load(_fh)["TPU v5 lite"]["hbm_bytes_per_s"]
+
+
+def decode_cell_case(name, B=256, ps=32, ctx=2048, layers=2, dead_len=0,
+                     seed=0):
+    """Inputs of one decode-kernel launch at a cell's shape: a stacked
+    bf16 pool, scattered pages, `live` streams at random slots with
+    lengths uniform over the cell's range."""
+    (KV, G, hd), live, (lo, hi) = DECODE_CELLS[name]
+    rng = np.random.default_rng(seed)
+    pages_per_seq = ctx // ps
+    P = 1 + B * pages_per_seq
+    key = jax.random.PRNGKey(seed)
+    kq, kk, kv_ = jax.random.split(key, 3)
+    q = jax.random.normal(kq, (B, KV * G, hd), jnp.bfloat16)
+    k_pages = jax.random.normal(kk, (layers, KV, P, ps, hd), jnp.bfloat16)
+    v_pages = jax.random.normal(kv_, (layers, KV, P, ps, hd), jnp.bfloat16)
+    page_tables = jnp.asarray(
+        (rng.permutation(P - 1)[: B * pages_per_seq] + 1).reshape(B, -1),
+        jnp.int32,
+    )
+    lens = np.full((B,), dead_len, np.int64)
+    lens[rng.permutation(B)[:live]] = rng.integers(lo, hi + 1, size=live)
+    return q, k_pages, v_pages, page_tables, jnp.asarray(lens, jnp.int32)
+
+
+def time_decode_kernel(kernel, case, loop=28):
+    """Median seconds of ONE launch: `loop` launches chained in one
+    program (a decode step's layers), layer index alternating."""
+
+    @jax.jit
+    def run(q, k_pages, v_pages, page_tables, seq_lens):
+        def body(carry, i):
+            out = kernel(
+                q + 0 * carry.astype(q.dtype), k_pages, v_pages,
+                page_tables, seq_lens, layer=i % k_pages.shape[0],
+            )
+            return out.astype(jnp.float32), None
+
+        out, _ = jax.lax.scan(
+            body, jnp.zeros(q.shape, jnp.float32),
+            jnp.arange(loop, dtype=jnp.int32),
+        )
+        return out
+
+    return _median_time(run, *case, loop=loop)
+
+
+def bench_decode_cells():
+    """The decode kernel alone at the cells' shapes: time a launch and
+    the share of the HBM roofline its LIVE tokens' bytes make of it (what
+    `kernel.decode_attn_roofline_live` reads in a traced run)."""
+    from vgate_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention_pallas,
+    )
+
+    for name, ((KV, G, hd), live, _) in DECODE_CELLS.items():
+        case = decode_cell_case(name)
+        seconds = time_decode_kernel(paged_decode_attention_pallas, case)
+        live_tokens = int(np.asarray(case[4]).sum())
+        live_bytes = live_tokens * 2 * KV * hd * 2
+        yield {
+            "kernel": "paged_decode_attention",
+            "cell": name,
+            "shape": f"B256 KV{KV} G{G} hd{hd} ps32, {live} live",
+            "live_tokens": live_tokens,
+            "launch_us": round(seconds * 1e6, 1),
+            "hbm_roofline_pct": round(
+                100 * live_bytes / HBM_BYTES_PER_S / seconds, 1
+            ),
+        }
 
 
 def bench_flash_prefill(B=8, S=1024, H=12, KV=2, hd=128):
@@ -241,6 +330,8 @@ def main() -> None:
         )
     print(json.dumps(bench_paged_decode()))
     print(json.dumps(bench_paged_decode(ctx=2048)))
+    for line in bench_decode_cells():
+        print(json.dumps(line))
     print(json.dumps(bench_flash_prefill()))
     print(json.dumps(bench_flash_prefill(S=2048)))
     print(json.dumps(bench_decode_window()))

@@ -261,23 +261,28 @@ def test_multitok_kernel_dequant_matches_jnp_twin():
         )
 
 
-def test_blocked_kernel_falls_back_for_quant_pools():
+def test_decode_kernel_int8_scale_rows_across_blocks():
+    """int8 pages and their scale rows through the block-of-slots
+    kernel: two programs (64 slots each at this geometry), live slots
+    among dead ones, so a chunk's scale rows follow its pages across a
+    slot boundary.  A dead row comes out zero."""
+    from tests.test_pallas_kernels import _live_rows_match
+    from vgate_tpu.ops.attention import paged_decode_attention
     from vgate_tpu.ops.pallas.paged_attention import (
         paged_decode_attention_pallas,
-        paged_decode_attention_pallas_blocked,
     )
 
-    q, kq, vq, pt = _quant_case(seed=5)
-    seq_lens = jnp.asarray([3, 40, 64, 128], jnp.int32)
-    per_slot = paged_decode_attention_pallas(
-        q, kq, vq, pt, seq_lens, interpret=True
+    B = 70
+    q, kq, vq, pt = _quant_case(B=B, n=8, seed=5)
+    lens = np.zeros((B,), np.int32)
+    lens[[0, 1, 7, 63, 64, 69]] = [3, 40, 64, 128, 17, 100]
+    expect = paged_decode_attention(
+        q, kq, vq, pt, jnp.asarray(np.maximum(lens, 1))
     )
-    blocked = paged_decode_attention_pallas_blocked(
-        q, kq, vq, pt, seq_lens, interpret=True, block_slots=2
+    got = paged_decode_attention_pallas(
+        q, kq, vq, pt, jnp.asarray(lens), interpret=True
     )
-    np.testing.assert_allclose(
-        np.asarray(blocked), np.asarray(per_slot), rtol=1e-6, atol=1e-6
-    )
+    _live_rows_match(got, expect, lens)
 
 
 # ------------------------------------------------- engine-level quality

@@ -592,97 +592,215 @@ def test_suffix_prefill_pallas_matches_jnp():
     )
 
 
-# ---------------------------------------- multi-slot blocked decode kernel
+# ------------------------------- decode kernel: one program, a block of slots
+
+def _live_rows_match(got, expect, seq_lens, tol=2e-5):
+    """Live rows are held to the twin; a row of length 0 is held to
+    ZEROS (the twin averages garbage there, and the logits' integrity
+    guard reduces over every row)."""
+    got, lens = np.asarray(got), np.asarray(seq_lens)
+    np.testing.assert_allclose(
+        got[lens > 0], np.asarray(expect)[lens > 0], rtol=tol, atol=tol
+    )
+    assert not got[lens == 0].any(), "a row of length 0 must come out zero"
+
+
+def _decode_both(q, k_pages, v_pages, page_tables, seq_lens, **kw):
+    # the twin never sees a 0: it would divide by an empty sum
+    expect = paged_decode_attention(
+        q, k_pages, v_pages, page_tables, jnp.maximum(seq_lens, 1), **kw
+    )
+    got = paged_decode_attention_pallas(
+        q, k_pages, v_pages, page_tables, seq_lens, interpret=True, **kw
+    )
+    return got, expect
+
+
+def test_decode_kernel_every_length_in_one_batch():
+    """Page and chunk edges (a chunk is 256 tokens at this geometry), an
+    empty row and a full context, side by side in one block."""
+    lens = [0, 1, 31, 32, 33, 255, 256, 257, 2048]
+    case = make_case(
+        B=len(lens), H=4, KV=2, ps=32, pages_per_seq=64, lens=lens, seed=21
+    )
+    got, expect = _decode_both(*case)
+    _live_rows_match(got, expect, case[4])
+
+
+def _blocks_of_32(pattern, seed):
+    """(2, 8, 256) in float32 gives 32 slots a program: `pattern` maps a
+    slot to its length, every other slot is dead."""
+    from vgate_tpu.ops.pallas.paged_attention import _decode_sizes
+
+    B = 70  # three programs, the last one mostly outside the batch
+    assert _decode_sizes(
+        B, 2, 8, 256, 16, 8, jnp.float32, jnp.float32
+    ) == (8, 32)
+    lens = [pattern.get(b, 0) for b in range(B)]
+    return make_case(
+        B=B, H=16, KV=2, hd=256, ps=16, pages_per_seq=8, lens=lens,
+        seed=seed,
+    )
+
 
 @pytest.mark.parametrize(
-    "lens",
+    "pattern",
     [
-        None,  # random lengths (mixed chunk counts within a block)
-        [1, 16, 255, 256],  # page/chunk boundary edges in ONE block
+        # a block that is all dead, then one with a single live slot
+        {40: 77},
+        # live slots separated by dead ones: the pipeline crosses them,
+        # and a block boundary (31 | 32) and the ragged last block (64+)
+        {0: 5, 3: 128, 4: 1, 9: 100, 31: 33, 32: 127, 63: 64, 65: 17, 69: 90},
+        # every slot live, lengths all over
+        {b: 1 + (37 * b) % 128 for b in range(70)},
+        # nothing live anywhere
+        {},
     ],
+    ids=["dead-block-then-one-live", "live-among-dead", "all-live", "all-dead"],
 )
-def test_blocked_decode_kernel_matches_jnp(lens):
-    """The multi-slot blocked kernel (block_slots sequences per program,
-    RESULTS_r3 decision-tree item 4) must match the jnp oracle for
-    mixed-length blocks where the fori_loop runs to the block max."""
-    from vgate_tpu.ops.pallas.paged_attention import (
-        paged_decode_attention_pallas_blocked,
-    )
-
-    q, k_pages, v_pages, page_tables, seq_lens = make_case(
-        B=4, lens=lens, seed=11 if lens is None else 12
-    )
-    expect = paged_decode_attention(
-        q, k_pages, v_pages, page_tables, seq_lens
-    )
-    got = paged_decode_attention_pallas_blocked(
-        q, k_pages, v_pages, page_tables, seq_lens, interpret=True,
-        block_slots=2,
-    )
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(expect), rtol=2e-5, atol=2e-5
-    )
+def test_decode_kernel_block_patterns(pattern):
+    case = _blocks_of_32(pattern, seed=22)
+    got, expect = _decode_both(*case)
+    _live_rows_match(got, expect, case[4])
 
 
-def test_blocked_decode_kernel_window_and_softcap():
-    """Sliding window + softcap through the blocked kernel: per-slot
-    window starts differ inside one block (lo_block = min)."""
-    from vgate_tpu.ops.pallas.paged_attention import (
-        paged_decode_attention_pallas_blocked,
+@pytest.mark.parametrize(
+    "KV, G, hd",
+    [(2, 6, 128), (4, 7, 128), (2, 8, 256), (1, 7, 128)],
+    ids=["1.5B", "7B", "qwen3-next", "one-kv-head-tp-shard"],
+)
+def test_decode_kernel_cell_geometries(KV, G, hd):
+    """The three cells' (KV, G, hd) and one KV head (a tp shard of the
+    7B): all KV heads ride one iteration, whatever their number."""
+    lens = [0, 200, 3, 0, 129, 64]
+    case = make_case(
+        B=len(lens), H=KV * G, KV=KV, hd=hd, ps=32, pages_per_seq=8,
+        lens=lens, seed=23,
     )
-
-    q, k_pages, v_pages, page_tables, seq_lens = make_case(
-        B=4, lens=[40, 200, 96, 130], seed=13
-    )
-    w = jnp.asarray(64, jnp.int32)
-    expect = paged_decode_attention(
-        q, k_pages, v_pages, page_tables, seq_lens, window=w,
-        softcap=30.0,
-    )
-    got = paged_decode_attention_pallas_blocked(
-        q, k_pages, v_pages, page_tables, seq_lens, interpret=True,
-        block_slots=2, window=w, softcap=30.0,
-    )
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(expect), rtol=2e-5, atol=2e-5
-    )
+    got, expect = _decode_both(*case)
+    _live_rows_match(got, expect, case[4])
 
 
-def test_blocked_decode_kernel_layer_indexed_and_fallback():
-    """Layer-indexed pools ride the blocked kernel too; B not divisible
-    by block_slots falls back to the per-slot kernel."""
-    from vgate_tpu.ops.pallas.paged_attention import (
-        paged_decode_attention_pallas_blocked,
+def test_decode_kernel_window_softcap_scale_across_blocks():
+    """Per-slot window starts differ inside one block and chunks below a
+    window are never fetched; softcap and the query scale ride along."""
+    case = _blocks_of_32(
+        {1: 40, 2: 128, 7: 96, 33: 127, 34: 5, 67: 70}, seed=24
     )
+    for win in (16, 64, 100):
+        got, expect = _decode_both(
+            *case, window=jnp.asarray(win, jnp.int32), softcap=30.0,
+            scale=0.25,
+        )
+        _live_rows_match(got, expect, case[4])
 
-    q, k_pages, v_pages, page_tables, seq_lens = make_case(
-        B=2, lens=[33, 97], seed=14
+
+def test_decode_kernel_layer_indexed_ragged_batch():
+    """Layer-indexed pools under a B that is no multiple of the block."""
+    q, k_pages, v_pages, page_tables, seq_lens = _blocks_of_32(
+        {0: 33, 30: 97, 45: 1, 69: 128}, seed=25
     )
     L = 3
-    rng = np.random.default_rng(15)
-    kL = jnp.asarray(
-        rng.normal(size=(L,) + k_pages.shape), jnp.float32
-    )
-    vL = jnp.asarray(
-        rng.normal(size=(L,) + v_pages.shape), jnp.float32
-    )
-    expect = paged_decode_attention(
+    rng = np.random.default_rng(26)
+    kL = jnp.asarray(rng.normal(size=(L,) + k_pages.shape), jnp.float32)
+    vL = jnp.asarray(rng.normal(size=(L,) + v_pages.shape), jnp.float32)
+    got, expect = _decode_both(
         q, kL, vL, page_tables, seq_lens, layer=jnp.asarray(1)
     )
-    got = paged_decode_attention_pallas_blocked(
-        q, kL, vL, page_tables, seq_lens, interpret=True,
-        block_slots=2, layer=jnp.asarray(1),
+    _live_rows_match(got, expect, seq_lens)
+
+
+def test_decode_kernel_bf16_pages_keep_float32_softmax_weights():
+    """bf16 pages go to the MXU as they lie; the softmax weights must
+    not drop to ONE bf16 term on the way.  Against the twin in float32 on
+    the same values, the kernel stays under a tolerance that the twin
+    with bf16 weights (what `probs.astype(v.dtype)` gives it) fails."""
+    lens = [2048, 1500, 700, 257]
+    q, k_pages, v_pages, page_tables, seq_lens = make_case(
+        B=len(lens), H=12, KV=2, ps=32, pages_per_seq=64, lens=lens, seed=27
     )
+    # float32 q holding bf16 values: the output stays float32, so the
+    # comparison sees the weights' precision and not the output's
+    q = q.astype(jnp.bfloat16).astype(jnp.float32)
+    k16, v16 = k_pages.astype(jnp.bfloat16), v_pages.astype(jnp.bfloat16)
+    exact = np.asarray(paged_decode_attention(
+        q, k16.astype(jnp.float32), v16.astype(jnp.float32), page_tables,
+        seq_lens,
+    ))
+    one_term = np.asarray(paged_decode_attention(
+        q, k16, v16, page_tables, seq_lens
+    ))
+    got = np.asarray(paged_decode_attention_pallas(
+        q, k16, v16, page_tables, seq_lens, interpret=True
+    ))
+    tol = 2e-5
+    assert np.abs(got - exact).max() < tol
+    assert np.abs(one_term - exact).max() > 2 * tol
+
+
+def test_decode_forward_hands_the_kernel_length_zero_for_inactive_rows():
+    """The model step's side of the contract: with the Pallas kernel an
+    inactive slot reaches it as length 0 (so it costs nothing), the KV
+    write still targets the trash page, every row's logits stay finite
+    (the integrity guard reduces over all of them), and the active rows
+    agree with the jnp path."""
+    import unittest.mock as mock
+
+    from vgate_tpu.models.decoder import decode_forward, init_params
+    from vgate_tpu.models.specs import TINY_DENSE
+    from vgate_tpu.ops.pallas import paged_attention as pa
+
+    spec = TINY_DENSE
+    B, ps, pages_per_seq = 3, 4, 4
+    params = init_params(spec, jax.random.PRNGKey(0), jnp.float32)
+    shape = (spec.num_layers, spec.num_kv_heads, 1 + B * pages_per_seq, ps,
+             spec.head_dim)
+    rng = np.random.default_rng(31)
+    k = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    v = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    pt = jnp.asarray(
+        np.arange(B * pages_per_seq, dtype=np.int32).reshape(B, -1) + 1
+    )
+    tokens = jnp.asarray([7, 11, 5], jnp.int32)
+    positions = jnp.asarray([3, 0, 9], jnp.int32)
+    active = jnp.asarray([True, False, True])
+
+    expect, k_jnp, _ = decode_forward(
+        params, spec, tokens, positions, k, v, pt, active=active,
+        use_pallas=False,
+    )
+    real = pa.paged_decode_attention_pallas
+    seen = []
+
+    def interp(q, kp, vp, page_tables, seq_lens, **kw):
+        seen.append(seq_lens)
+        # zero exactly the inactive row (the trace sees the expression,
+        # so check it by value on the way through)
+        seq_lens = jax.lax.cond(
+            jnp.array_equal(seq_lens, jnp.asarray([4, 0, 10])),
+            lambda: seq_lens, lambda: seq_lens - 1000,
+        )
+        return real(q, kp, vp, page_tables, seq_lens, interpret=True, **kw)
+
+    with mock.patch.object(
+        pa, "paged_decode_attention_pallas", side_effect=interp
+    ):
+        got, k_pal, _ = jax.jit(
+            lambda: decode_forward(
+                params, spec, tokens, positions, k, v, pt, active=active,
+                use_pallas=True,
+            )
+        )()
+    assert seen, "use_pallas must reach the kernel"
+    got = np.asarray(got)
+    assert np.isfinite(got).all()
+    live = np.asarray(active)
     np.testing.assert_allclose(
-        np.asarray(got), np.asarray(expect), rtol=2e-5, atol=2e-5
+        got[live], np.asarray(expect)[live], rtol=2e-4, atol=2e-4
     )
-    # B=3 % block_slots=2 -> falls back (still correct)
-    q3, k3, v3, pt3, sl3 = make_case(B=3, H=8, KV=2, lens=[5, 60, 100],
-                                     seed=16, pages_per_seq=8)
-    expect3 = paged_decode_attention(q3, k3, v3, pt3, sl3)
-    got3 = paged_decode_attention_pallas_blocked(
-        q3, k3, v3, pt3, sl3, interpret=True, block_slots=2,
-    )
+    # the live rows' new K landed where the jnp path puts it (page 0 is
+    # the trash page the inactive row writes)
     np.testing.assert_allclose(
-        np.asarray(got3), np.asarray(expect3), rtol=2e-5, atol=2e-5
+        np.asarray(k_pal)[:, :, 1:], np.asarray(k_jnp)[:, :, 1:],
+        rtol=2e-4, atol=2e-4,
     )

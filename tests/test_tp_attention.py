@@ -212,14 +212,16 @@ def test_decode_forward_tp_mesh_selects_wrapped_kernel():
     )
 
 
-def test_tp_wrapper_with_blocked_kernel():
-    """decode_block_slots > 1 composes with tp: the blocked kernel runs
-    per shard inside the wrapper."""
+def test_tp_wrapper_one_kv_head_a_shard_and_dead_rows():
+    """The block-of-slots kernel composes with tp: each shard runs it
+    with KV / tp = ONE kv head, and a row of length 0 (an inactive slot
+    as decode_forward hands it over) comes out zero on every shard."""
     if jax.device_count() < 2:
         pytest.skip("needs devices")
+    from tests.test_pallas_kernels import _live_rows_match
     from vgate_tpu.ops.attention import paged_decode_attention
     from vgate_tpu.ops.pallas.paged_attention import (
-        paged_decode_attention_pallas_blocked,
+        paged_decode_attention_pallas,
     )
     from vgate_tpu.parallel.tp_attention import tp_paged_decode_attention
 
@@ -235,17 +237,14 @@ def test_tp_wrapper_with_blocked_kernel():
         ),
         jnp.int32,
     )
-    seq_lens = jnp.asarray([5, 33, 64, 17], jnp.int32)
+    seq_lens = jnp.asarray([5, 0, 64, 17], jnp.int32)
     mesh = tp_mesh(2)
 
-    expect = paged_decode_attention(q, k_pages, v_pages, pt, seq_lens)
-    kernel = functools.partial(
-        paged_decode_attention_pallas_blocked, interpret=True,
-        block_slots=2,
+    expect = paged_decode_attention(
+        q, k_pages, v_pages, pt, jnp.maximum(seq_lens, 1)
     )
+    kernel = functools.partial(paged_decode_attention_pallas, interpret=True)
     got = tp_paged_decode_attention(
         kernel, mesh, q, k_pages, v_pages, pt, seq_lens
     )
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(expect), rtol=2e-5, atol=2e-5
-    )
+    _live_rows_match(got, expect, seq_lens)

@@ -24,6 +24,7 @@ PAGE = 32
 # tp=4 shard of Qwen2.5-7B (28 heads / 4 KV heads over four chips)
 GEOM_1P5B = (12, 2, 128)
 GEOM_7B_TP4_SHARD = (7, 1, 128)
+GEOM_7B = (28, 4, 128)
 # Qwen3-Next's gated full-attention layers: head size 256
 GEOM_QWEN3_NEXT = (16, 2, 256)
 
@@ -58,13 +59,12 @@ def _pool(A, kv, hd, pages, layers=2, int8=False):
     )
 
 
-def _compile_decode_kernel(A, geom, int8=False):
+def _compile_decode_kernel(A, geom, int8=False, B=8, pages_per_seq=16):
     from vgate_tpu.ops.pallas.paged_attention import (
         paged_decode_attention_pallas,
     )
 
     H, KV, hd = geom
-    B, pages_per_seq = 8, 16
     pool = _pool(A, KV, hd, 64, int8=int8)
     return paged_decode_attention_pallas.lower(
         A((B, H, hd), jnp.bfloat16), pool, pool,
@@ -112,6 +112,27 @@ def _compile_flash_kernel(A, geom):
 )
 def test_default_path_kernels_compile_for_v5e(v5e, geom, compile_kernel):
     compile_kernel(_abstract(v5e), geom)
+
+
+@pytest.mark.parametrize(
+    "geom", [GEOM_1P5B, GEOM_7B, GEOM_QWEN3_NEXT],
+    ids=["1.5B", "7B", "qwen3-next-hd256"],
+)
+def test_decode_kernel_compiles_at_the_cells_shapes_for_v5e(v5e, geom):
+    """The benchmark's three geometries as the engine launches them: 256
+    slots of 2,048 tokens.  The slots a program serves differ between
+    them and come, with the chunk's pages, from one rule
+    (paged_attention._decode_sizes)."""
+    from vgate_tpu.ops.pallas.paged_attention import _decode_sizes
+
+    H, KV, hd = geom
+    sizes = _decode_sizes(
+        256, KV, H // KV, hd, PAGE, 64, jnp.bfloat16, jnp.bfloat16
+    )
+    assert sizes == {
+        GEOM_1P5B: (8, 64), GEOM_7B: (8, 32), GEOM_QWEN3_NEXT: (8, 32),
+    }[geom]
+    _compile_decode_kernel(_abstract(v5e), geom, B=256, pages_per_seq=64)
 
 
 def test_gated_delta_step_kernel_compiles_for_v5e(v5e):
@@ -171,8 +192,9 @@ def _compile_expecting(fragment, compile_kernel, *args, **kwargs):
     raises=MosaicRefusal,
     reason="Mosaic: 'Slice shape along dimension 2 must be aligned to "
     "tiling (8), but is 1' — the per-page scale-row DMA of int8 KV pages "
-    "(ops/pallas/paged_attention.py _chunk_dma); engine construction "
-    "refuses the combination on a TPU (refuse_unbuildable_kernels)",
+    "(ops/pallas/paged_attention.py start_chunk / _chunk_dma); engine "
+    "construction refuses the combination on a TPU "
+    "(refuse_unbuildable_kernels)",
 )
 @pytest.mark.parametrize(
     "compile_kernel",

@@ -332,12 +332,6 @@ class TPUConfig(BaseModel):
     # (VGT_TPU__INT8_NATIVE=true; applies when model.quantization is
     # int8 or int4).
     int8_native: bool = False
-    # >1: the decode attention kernel serves this many slots per Pallas
-    # program (grid B/N x KV instead of B x KV — at B=128, KV=2, 28
-    # layers that is 7,168 vs 896 programs per decode step).  Opt-in
-    # (default 1 = per-slot kernel) until measured on hardware; A/B via
-    # VGT_TPU__DECODE_BLOCK_SLOTS=8.
-    decode_block_slots: int = 1
     # Per-chip HBM budget in bytes for KV auto-sizing, read ONLY when the
     # device reports no memory_stats (the v5e does report).  0 => such a
     # device is an error at engine start, not an assumed size.

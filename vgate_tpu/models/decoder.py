@@ -268,10 +268,7 @@ def decode_attention_impl(
         return "pp_relay"
     if _axis(mesh, "sp") > 1:
         return "sp_shard"
-    impl = _kernel_or_twin(spec, use_pallas, mesh)
-    if impl != "jnp" and spec.decode_block_slots > 1:
-        impl += "_blocked"
-    return impl
+    return _kernel_or_twin(spec, use_pallas, mesh)
 
 
 def multitok_attention_impl(
@@ -657,24 +654,17 @@ def decode_forward(
     else:
         # the decode kernel supports window/softcap/scale natively (and
         # skips DMA for pages below the window), so local-attention
-        # families ride it too.  decode_block_slots > 1 selects the
-        # multi-slot blocked grid (B/N x KV programs instead of B x KV).
-        from vgate_tpu.ops.pallas import paged_attention as _pa
+        # families ride it too
+        from vgate_tpu.ops.pallas.paged_attention import (
+            paged_decode_attention_pallas,
+        )
 
-        if impl.endswith("_blocked"):
-            attn_fn = functools.partial(
-                _pa.paged_decode_attention_pallas_blocked,
-                softcap=spec.attn_softcap,
-                scale=_query_scale(spec),
-                block_slots=spec.decode_block_slots,
-            )
-        else:
-            attn_fn = functools.partial(
-                _pa.paged_decode_attention_pallas,
-                softcap=spec.attn_softcap,
-                scale=_query_scale(spec),
-            )
-        if impl.startswith("pallas_tp"):
+        attn_fn = functools.partial(
+            paged_decode_attention_pallas,
+            softcap=spec.attn_softcap,
+            scale=_query_scale(spec),
+        )
+        if impl == "pallas_tp":
             # params and the pool's kv-head dim are GSPMD-sharded over
             # tp; a pallas_call does NOT partition automatically — it
             # must run per shard via shard_map (parallel/tp_attention.py)
@@ -690,6 +680,11 @@ def decode_forward(
     seq_lens, page_ids, page_off = decode_attn_inputs(
         positions, page_tables, active, ps
     )
+    if impl != "jnp" and active is not None:
+        # the kernel spends nothing on a row of length 0 (no DMA, no
+        # iteration, zeros out); the twin keeps length 1 for such a row,
+        # whose mean over nothing would be garbage
+        seq_lens = jnp.where(active, seq_lens, 0)
 
     x = _embed(params, spec, tokens)  # [B, D]
     if spec.is_hybrid:

@@ -66,12 +66,6 @@ class ModelSpec:
     # auto-partitions under any mesh, no Pallas/Mosaic involvement.
     # Threaded per-engine like quant_kernel.
     int8_native: bool = False
-    # >1: decode attention serves this many slots per Pallas program
-    # (paged_attention.py _blocked_kernel) — cuts grid steps B/BS x and
-    # per-program overhead; opt-in via tpu.decode_block_slots until the
-    # win is measured on hardware (threaded on the spec like
-    # quant_kernel so it reaches the jitted decode as a static arg)
-    decode_block_slots: int = 1
     # ---- expert layer (models/decoder.py _expert_layer).  num_experts
     # is the experts HELD here; the router scores `router_width` experts
     # (0 = the held ones: a chip that holds them all) and the held ones

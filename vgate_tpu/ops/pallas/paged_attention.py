@@ -1,27 +1,29 @@
-"""Pallas paged-attention decode kernel.
+"""Pallas paged-attention decode kernels.
 
 The TPU-native replacement for the CUDA paged-attention the reference gets
 opaquely through vLLM (SURVEY.md section 2.1; technique family: "Ragged
 Paged Attention", PAPERS.md).  Semantics are pinned by the jnp twin
 ``vgate_tpu.ops.attention.paged_decode_attention`` (kernel tests compare the
-two); the kernel's advantage is the memory path:
+two); the kernels' advantage is the memory path:
 
 * the jnp twin gathers every slot's full ``pages_per_seq`` window into a
   contiguous HBM buffer (write + re-read), touching ``ctx_max`` tokens even
   for short sequences;
-* this kernel DMAs **only the live pages** of each sequence directly from the
-  HBM page pool into VMEM, double-buffered in chunks of
-  ``CHUNK_PAGES`` pages, and runs an online-softmax
-  accumulation entirely in VMEM — no gathered copy, no dead-token traffic.
+* the kernels DMA **only the live pages** of each sequence directly from
+  the HBM page pool into VMEM, buffered in chunks of pages, and run an
+  online-softmax accumulation entirely in VMEM — no gathered copy, no
+  dead-token traffic.
 
-Grid: one program per (slot, kv_head); each program serves the G = H/KV
-query heads of that group (GQA).
+``paged_decode_attention_pallas`` (one query token a slot) runs one program
+per BLOCK of slots, which walks the block's live chunks and serves all KV
+heads an iteration; ``paged_multitok_attention_pallas`` (speculative
+verify: S candidate tokens a slot) still runs one program per (slot,
+kv_head) over ``_chunk_dma``.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -30,14 +32,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from vgate_tpu.utils.math import cdiv
 
-# pages DMA'd per double-buffer slot (VGT_CHUNK_PAGES sweeps on-device:
-# wider chunks amortize per-page DMA issue overhead for long contexts)
-CHUNK_PAGES = int(os.environ.get("VGT_CHUNK_PAGES", 8))
-if CHUNK_PAGES <= 0:
-    raise ValueError(
-        f"VGT_CHUNK_PAGES must be a positive integer, got {CHUNK_PAGES}"
-    )
-
+# pages DMA'd per double-buffer slot of the multi-token kernel
+CHUNK_PAGES = 8
 
 
 def _chunk_dma(
@@ -45,7 +41,7 @@ def _chunk_dma(
     b, g, n_pages, page_size, layer=None,
     k_scale_ref=None, v_scale_ref=None, sk_buf=None, sv_buf=None,
 ):
-    """Shared double-buffered page-DMA machinery for the paged kernels.
+    """Double-buffered page-DMA machinery of the multi-token kernel.
 
     Returns ``(start_chunk, wait_chunk)`` closures: ``start_chunk(c, slot)``
     kicks off the async copies of chunk ``c``'s live pages into buffer
@@ -165,147 +161,336 @@ def _scale_row(buf, slot):
     ).astype(jnp.float32)
 
 
-def _kernel(
+# VMEM the single-token decode kernel spends, half on its K and V chunk
+# buffers (which bounds the chunk's tokens) and half on the pipelined q and
+# out blocks (which sets the slots a program serves): _decode_sizes.
+DECODE_VMEM_BUDGET = 4 << 20
+# K/V chunk buffers: one computed, two in flight.  Two keep the HBM busy
+# only while an iteration's arithmetic outlasts its pages' transfer.
+DECODE_BUFFERS = 3
+# A chunk holds at most this many tokens: two 128-token MXU weight tiles
+# a head.  Wider chunks mostly add tail work at the lengths served
+# (PERF.md section 6, PR 28: 512 tokens cost the 1.5B 5 % and the 7B 20 %).
+DECODE_CHUNK_TOKENS = 256
+
+
+def _decode_sizes(B, KV, G, hd, page_size, pages_per_seq, kv_dtype, q_dtype):
+    """(pages a chunk, slots a program) for one geometry."""
+    kv_bytes = jnp.dtype(kv_dtype).itemsize
+    q_bytes = jnp.dtype(q_dtype).itemsize
+    # K and V of every KV head in every buffer; a power of two, so the
+    # kernel's page arithmetic is shifts
+    token_bytes = DECODE_BUFFERS * 2 * KV * hd * kv_bytes
+    chunk_tokens = min(
+        DECODE_VMEM_BUDGET // 2 // token_bytes, DECODE_CHUNK_TOKENS
+    )
+    chunk_pages = max(1, chunk_tokens // page_size)
+    chunk_pages = 1 << (chunk_pages.bit_length() - 1)
+    # q and out rows of every head (G pads to the dtype's sublane tile),
+    # two buffers each
+    g_rows = cdiv(G, 32 // q_bytes) * (32 // q_bytes)
+    slot_bytes = 2 * 2 * KV * g_rows * hd * q_bytes
+    block_slots = DECODE_VMEM_BUDGET // 2 // slot_bytes
+    return min(chunk_pages, pages_per_seq), max(1, min(block_slots, B))
+
+
+def _div(x, d: int):
+    """x // d for a traced x >= 0 and a static d."""
+    if d & (d - 1) == 0:
+        return jax.lax.shift_right_logical(x, jnp.int32(d.bit_length() - 1))
+    return jax.lax.div(x, jnp.int32(d))
+
+
+# rows of the decode kernel's per-slot SMEM table
+_LEN, _LO, _PAGES, _FIRST, _END, _NEXT = range(6)
+
+
+def _decode_kernel(
     # scalar prefetch
     page_tables_ref,  # [B, pages_per_seq] int32 (SMEM)
-    seq_lens_ref,  # [B] int32 (SMEM)
+    seq_lens_ref,  # [B] int32 (SMEM); 0 => the slot holds nothing
     window_ref,  # [1] int32 (SMEM); >0 => attend only to the last `window`
     layer_ref,  # [1] int32 (SMEM); pool layer index (-1 => no layer dim)
-    # inputs: q_ref [1, 1, G, hd] VMEM block for (b, g); k/v_pages_ref
-    # [KV, P, ps, hd] in ANY/HBM (head-major: one page of one head is a
-    # contiguous (ps, hd) DMA tile), or [L, KV, P, ps, hd] when
-    # has_layer (carry decode).  `quant` (int8 KV) adds k/v_scale_ref
-    # [KV, P, ps] bf16 pools after them.
-    # outputs: out_ref [1, 1, G, hd]
-    # scratch: k_buf/v_buf [2, CHUNK*ps, hd] VMEM (+ sk/sv_buf
-    # [2, 1, CHUNK*ps] when quant), acc [G, hd] f32, m/l [G, 128] f32
-    # running max/denom (col-broadcast), DMA sems [2, 2 or 4, CHUNK]
+    # inputs: q_ref [BS, KV, G, hd] VMEM block of this program's slots;
+    # k/v_pages_ref [KV, P, ps, hd] in ANY/HBM (head-major), or
+    # [L, KV, P, ps, hd] when has_layer.  `quant` (int8 KV) adds
+    # k/v_scale_ref [KV, P, ps] bf16 pools after them.
+    # outputs: out_ref [BS, KV, G, hd]
+    # scratch: k_buf/v_buf [DECODE_BUFFERS, KV, CP*ps, hd] VMEM (+ sk/sv_buf
+    # [DECODE_BUFFERS, KV, CP*ps] when quant), acc [KV, G, hd] f32, m/l
+    # [KV, G, 128] f32 running max/denom (col-broadcast), slots_ref
+    # [6, BS + 1] int32 SMEM (the block's slots, see below), DMA sems, one
+    # a buffer
     *refs,
     page_size: int,
+    chunk_pages: int,
+    batch: int,
     softcap: float,
     scale: float,
-    has_layer: bool = False,
-    quant: bool = False,
+    has_layer: bool,
+    quant: bool,
 ):
+    """One program serves a BLOCK of slots: its work list is the live
+    chunks of those slots in order, and the next chunks' pages are in
+    flight while a chunk is computed whether or not they belong to one
+    slot, so the pipeline is primed once a program and never drains
+    inside it.  A slot of length 0 is not on the list: no DMA, no
+    iteration, zeros out.  One iteration serves one chunk of one slot for
+    all KV heads.
+
+    What an iteration costs is scalar work and MXU weight loads, not
+    bytes (PERF.md section 6, PR 28).  A page of 32 tokens is one
+    descriptor for K and one for V, and the compiler does not overlap a
+    descriptor's scalar work with the vector work: hence one descriptor a
+    page for ALL heads, the issue code once per buffer (static
+    destinations), no DMA bounds checks (the page id is clamped instead),
+    per-slot scalars computed once a program into SMEM, and merged
+    waits.  The products take 8 query rows against 128-token weight
+    tiles, so the MXU's time is its weight loads: operands stay bf16."""
     if quant:
         (
             q_ref, k_pages_ref, v_pages_ref, k_scale_ref, v_scale_ref,
             out_ref, k_buf, v_buf, sk_buf, sv_buf, acc_ref, m_ref, l_ref,
-            sems,
+            slots_ref, sems,
         ) = refs
     else:
         (
             q_ref, k_pages_ref, v_pages_ref,
-            out_ref, k_buf, v_buf, acc_ref, m_ref, l_ref, sems,
+            out_ref, k_buf, v_buf, acc_ref, m_ref, l_ref, slots_ref, sems,
         ) = refs
         k_scale_ref = v_scale_ref = sk_buf = sv_buf = None
-    b = pl.program_id(0)
-    g = pl.program_id(1)
-    seq_len = seq_lens_ref[b]
-    n_pages = jax.lax.div(seq_len + page_size - 1, page_size)
-    n_chunks = jax.lax.div(n_pages + CHUNK_PAGES - 1, CHUNK_PAGES)
-    chunk_tokens = CHUNK_PAGES * page_size
-    # Sliding window: tokens below `lo` contribute nothing, so whole chunks
-    # below the window start are never DMA'd at all — the kernel's traffic
-    # is O(window), not O(context), for local-attention layers.
+    BS, KV, G, _ = q_ref.shape
+    num_pages = k_pages_ref.shape[2 if has_layer else 1]
+    CP = chunk_pages
+    T = CP * page_size
+    base = pl.program_id(0) * BS
     window = window_ref[0]
-    lo = jnp.where(
-        window > 0, jnp.maximum(seq_len - window, 0), 0
-    )
-    lo_chunk = jax.lax.div(lo, chunk_tokens)
+    layer = layer_ref[0] if has_layer else None
 
-    start_chunk, wait_chunk = _chunk_dma(
-        page_tables_ref, k_pages_ref, v_pages_ref, k_buf, v_buf, sems,
-        b, g, n_pages, page_size,
-        layer=layer_ref[0] if has_layer else None,
-        k_scale_ref=k_scale_ref, v_scale_ref=v_scale_ref,
-        sk_buf=sk_buf, sv_buf=sv_buf,
-    )
+    # the block's slots, once a program: length, window start, live pages,
+    # first chunk and one past the last, and the next live slot after this
+    # one.  Entry BS is the end of the work list.  `total` = live chunks.
+    next_live = jnp.int32(BS)
+    total = jnp.int32(0)
+    for j in reversed(range(BS + 1)):
+        if j == BS:
+            sl = jnp.int32(0)
+        else:
+            sl = jnp.where(
+                base + j < batch,
+                seq_lens_ref[jnp.minimum(base + j, batch - 1)], 0,
+            )
+        # tokens below `lo` contribute nothing: chunks wholly below the
+        # window start are never fetched
+        lo = jnp.where(window > 0, jnp.maximum(sl - window, 0), 0)
+        n_pages = _div(sl + page_size - 1, page_size)
+        first, end = _div(lo, T), _div(n_pages + CP - 1, CP)
+        for row, value in (
+            (_LEN, sl), (_LO, lo), (_PAGES, n_pages), (_FIRST, first),
+            (_END, end), (_NEXT, next_live),
+        ):
+            slots_ref[row, j] = value
+        next_live = jnp.where(sl > 0, j, next_live)
+        total = total + end - first
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale  # [G, hd]
+    out_ref[...] = jnp.zeros_like(out_ref)
+    # a tail chunk leaves the rows past its live pages as they were:
+    # masked scores drop K's, but softmax weight 0 x stale NaN would
+    # poison the accumulator through V (and V's scales), so the buffers
+    # start finite and only ever hold pool rows after that
+    v_buf[...] = jnp.zeros_like(v_buf)
+    if quant:
+        sv_buf[...] = jnp.zeros_like(sv_buf)
 
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-    m_ref[...] = jnp.full_like(m_ref, -1e30)
-    l_ref[...] = jnp.zeros_like(l_ref)
+    def pages_of(item):
+        """Pool row, first page position and live pages of a chunk."""
+        j, c = item
+        b = jnp.minimum(base + j, batch - 1)
+        return b, c * CP, jnp.minimum(slots_ref[_PAGES, j] - c * CP, CP)
 
-    start_chunk(lo_chunk, jax.lax.rem(lo_chunk, 2))
+    def start_chunk(item, buf: int):
+        """Issue the copies of the chunk's live pages into buffer `buf`:
+        per page one descriptor for K and one for V, all KV heads in
+        each (and one each for an int8 pool's scale rows)."""
+        b, page0, live = pages_of(item)
+        for i in range(CP):  # static unroll
+            rows = pl.ds(i * page_size, page_size)
 
-    def body(c, _):
-        slot = jax.lax.rem(c, 2)
-        next_slot = jax.lax.rem(c + 1, 2)
+            def page(i=i, rows=rows):
+                # in range by construction: the kernel is compiled
+                # without the DMA bounds checks, which cost more scalar
+                # work than the descriptor itself
+                page_id = jnp.clip(
+                    page_tables_ref[b, page0 + i], 0, num_pages - 1
+                )
 
-        @pl.when(c + 1 < n_chunks)
+                def src(ref):
+                    return (
+                        ref.at[layer, :, page_id] if has_layer
+                        else ref.at[:, page_id]
+                    )
+
+                for pool, buffer in (
+                    (k_pages_ref, k_buf), (v_pages_ref, v_buf),
+                ):
+                    pltpu.make_async_copy(
+                        src(pool), buffer.at[buf, :, rows, :], sems.at[buf]
+                    ).start()
+                if quant:
+                    for pool, buffer in (
+                        (k_scale_ref, sk_buf), (v_scale_ref, sv_buf),
+                    ):
+                        pltpu.make_async_copy(
+                            src(pool), buffer.at[buf, :, rows], sems.at[buf]
+                        ).start()
+
+            pl.when(i < live)(page)
+
+    def wait_chunk(item, buf):
+        """Wait for what start_chunk issued: the semaphore counts bytes,
+        so the live pages are waited for in power-of-two runs."""
+        _, _, live = pages_of(item)
+
+        def wait_pages(n):
+            rows = pl.ds(0, n * page_size)
+            for buffer in (k_buf, v_buf):
+                dst = buffer.at[buf, :, rows, :]
+                pltpu.make_async_copy(dst, dst, sems.at[buf]).wait()
+            if quant:
+                for buffer in (sk_buf, sv_buf):
+                    dst = buffer.at[buf, :, rows]
+                    pltpu.make_async_copy(dst, dst, sems.at[buf]).wait()
+
+        for bit in range(CP.bit_length()):
+            pl.when((live & (1 << bit)) != 0)(
+                functools.partial(wait_pages, 1 << bit)
+            )
+
+    def following(item):
+        """The next item of the work list: the slot's next chunk, or the
+        first chunk of the next live slot."""
+        j, c = item
+        same = c + 1 < slots_ref[_END, j]
+        j2 = jnp.where(same, j, slots_ref[_NEXT, j])
+        return j2, jnp.where(same, c + 1, slots_ref[_FIRST, j2])
+
+    nbuf = k_buf.shape[0]
+    D = nbuf - 1  # chunks in flight ahead of the one computed
+    items = [(next_live, slots_ref[_FIRST, next_live])]
+    for d in range(D):
+        pl.when(d < total)(functools.partial(start_chunk, items[d], d))
+        items.append(following(items[d]))
+
+    # operands go to the MXU in the pages' own type (int8 pages as
+    # float32, as their scales are)
+    mxu = jnp.float32 if quant else k_buf.dtype
+
+    def body(i, items):
+        j, c = items[0]
+        buf = jax.lax.rem(i, nbuf)
+        ahead = jax.lax.rem(i + D, nbuf)
+        # the issue code once per buffer: static destination addresses
+        for s in range(nbuf):
+            pl.when((ahead == s) & (i + D < total))(
+                functools.partial(start_chunk, items[D], s)
+            )
+        wait_chunk(items[0], buf)
+
+        sl, lo = slots_ref[_LEN, j], slots_ref[_LO, j]
+        # the softmax state restarts at a slot boundary
+        first = c == slots_ref[_FIRST, j]
+        token_pos = c * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+        valid = (token_pos >= lo) & (token_pos < sl)
+        for kv in range(KV):  # static unroll: all KV heads an iteration
+            q = q_ref[j, kv].astype(mxu)  # [G, hd]
+            k = k_buf[buf, kv].astype(mxu)  # [T, hd]
+            # operands in the pages' own type, float32 accumulation, the
+            # query scale on the float32 scores
+            scores = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [G, T]
+            if quant:
+                # linearity-exact in-VMEM dequant (ops/kv_quant.py): the
+                # per-token scale is constant over hd, so q . (k_q * s)
+                # == (q . k_q) * s — fold it into the score row.  BEFORE
+                # softcap/masking: those act on real scores.
+                scores = scores * sk_buf[buf, pl.ds(kv, 1), :].astype(
+                    jnp.float32
+                )
+            if softcap:
+                scores = jnp.tanh(scores / softcap) * softcap
+            scores = jnp.where(valid, scores, -1e30)
+
+            m_prev = jnp.where(first, -1e30, m_ref[kv, :, :1])  # [G, 1]
+            l_prev = jnp.where(first, 0.0, l_ref[kv, :, :1])
+            acc_prev = jnp.where(first, 0.0, acc_ref[kv])
+            m_new = jnp.maximum(
+                m_prev, jnp.max(scores, axis=-1, keepdims=True)
+            )
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(scores - m_new)  # [G, T]
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            if quant:
+                # V-side twin: weight the softmax row by the per-token
+                # scale, dot int8 V.  l uses the UNWEIGHTED p (it
+                # normalizes probabilities, not values).
+                p = p * sv_buf[buf, pl.ds(kv, 1), :].astype(jnp.float32)
+            v = v_buf[buf, kv]  # [T, hd]
+            pv_dims = (((1,), (0,)), ((), ()))
+            if mxu == jnp.bfloat16:
+                # the float32 weights as two bf16 terms (<= 2^-16
+                # relative): V goes to the MXU as it lies in the pages
+                p_hi = p.astype(jnp.bfloat16)
+                p_lo = (p - p_hi.astype(jnp.float32)).astype(jnp.bfloat16)
+                pv_acc = jax.lax.dot_general(
+                    p_hi, v, pv_dims, preferred_element_type=jnp.float32
+                ) + jax.lax.dot_general(
+                    p_lo, v, pv_dims, preferred_element_type=jnp.float32
+                )
+            else:
+                pv_acc = jax.lax.dot_general(
+                    p, v.astype(jnp.float32), pv_dims,
+                    preferred_element_type=jnp.float32,
+                )
+            acc_ref[kv] = acc_prev * alpha + pv_acc
+            m_ref[kv] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[kv] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+        @pl.when(c == slots_ref[_END, j] - 1)
         def _():
-            start_chunk(c + 1, next_slot)
+            for kv in range(KV):
+                denom = jnp.maximum(l_ref[kv, :, :1], 1e-30)
+                out_ref[j, kv] = (acc_ref[kv] / denom).astype(out_ref.dtype)
 
-        wait_chunk(c, slot)
+        return items[1:] + (following(items[D]),)
 
-        k = jax.lax.cond(
-            slot == 0, lambda: k_buf[0], lambda: k_buf[1]
-        ).astype(jnp.float32)  # [chunk_tokens, hd]
-        v = jax.lax.cond(
-            slot == 0, lambda: v_buf[0], lambda: v_buf[1]
-        ).astype(jnp.float32)
-
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [G, chunk_tokens]
-        if quant:
-            # linearity-exact in-VMEM dequant (ops/kv_quant.py): the
-            # per-token scale is constant over hd, so q . (k_q * s) ==
-            # (q . k_q) * s — fold it into the score row instead of
-            # materializing a dequantized K tile.  Applied BEFORE
-            # softcap/masking: those act on real scores.
-            scores = scores * _scale_row(sk_buf, slot)
-        if softcap:
-            scores = jnp.tanh(scores / softcap) * softcap
-        token_pos = c * chunk_tokens + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 1
-        )
-        valid = (token_pos >= lo) & (token_pos < seq_len)
-        scores = jnp.where(valid, scores, -1e30)
-
-        m_prev = m_ref[:, :1]  # [G, 1]
-        m_cur = jnp.max(scores, axis=-1, keepdims=True)  # [G, 1]
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)  # [G, 1]
-        p = jnp.exp(scores - m_new)  # [G, chunk_tokens]
-        l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        if quant:
-            # V-side twin: sum_t p_t * (v_q_t * s_t) == sum_t
-            # (p_t * s_t) . v_q_t — weight the softmax row, dot int8 V.
-            # The denominator l uses the UNWEIGHTED p (it normalizes
-            # probabilities, not values).
-            p = p * _scale_row(sv_buf, slot)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-        return 0
-
-    jax.lax.fori_loop(lo_chunk, n_chunks, body, 0)
-    denom = jnp.maximum(l_ref[:, :1], 1e-30)
-    out_ref[0, 0] = (acc_ref[...] / denom).astype(out_ref.dtype)
+    jax.lax.fori_loop(0, total, body, tuple(items))
 
 
 @functools.partial(
-    jax.jit, static_argnames=("interpret", "softcap", "scale")
+    jax.jit,
+    static_argnames=("interpret", "softcap", "scale"),
 )
 def paged_decode_attention_pallas(
     q: jnp.ndarray,  # [B, H, hd]
     k_pages: jnp.ndarray,  # [KV, P, ps, hd] (head-major, kv_cache.py)
     v_pages: jnp.ndarray,  # or [L, KV, P, ps, hd] with `layer` given
     page_tables: jnp.ndarray,  # [B, pages_per_seq]
-    seq_lens: jnp.ndarray,  # [B]
+    seq_lens: jnp.ndarray,  # [B]; 0 => the row holds nothing: zeros out
     window=None,  # int32 scalar; >0 => attend only to the last `window`
     layer=None,  # int32 scalar: pool layer index (carry-threaded decode)
     interpret: bool = False,
     softcap: float = 0.0,
     scale=None,  # static query scale; default hd**-0.5
 ) -> jnp.ndarray:
+    """Single-token decode attention over the paged pool, [B, H, hd].
+
+    The jnp twin ``ops.attention.paged_decode_attention`` pins the
+    semantics, with two differences: a row of length 0 costs nothing and
+    comes out ZERO (the twin has no such row), and q meets K in the
+    pages' own float type (a float32 q over bf16 pages is rounded to
+    bf16, as the model's q already is)."""
     from vgate_tpu.ops.kv_quant import is_quantized
 
     B, H, hd = q.shape
@@ -319,7 +504,10 @@ def paged_decode_attention_pallas(
     )
     KV, P, ps, _ = k_data.shape[1:] if has_layer else k_data.shape
     G = H // KV
-    chunk_tokens = CHUNK_PAGES * ps
+    CP, BS = _decode_sizes(
+        B, KV, G, hd, ps, page_tables.shape[1], k_data.dtype, q.dtype
+    )
+    chunk_tokens = CP * ps
 
     if window is None:
         window_arr = jnp.zeros((1,), jnp.int32)
@@ -331,51 +519,48 @@ def paged_decode_attention_pallas(
         else jnp.full((1,), -1, jnp.int32)
     )
     kernel = functools.partial(
-        _kernel,
+        _decode_kernel,
         page_size=ps,
+        chunk_pages=CP,
+        batch=B,
         softcap=float(softcap),
         scale=float(scale) if scale is not None else hd ** -0.5,
         has_layer=has_layer,
         quant=quant,
     )
-    # q is laid out [B, KV, G, hd] so each program's block covers the FULL
+    # q is laid out [B, KV, G, hd] so a program's block covers the FULL
     # trailing (G, hd) dims — Mosaic requires trailing block dims either
     # tile-aligned (8, 128) or equal to the array dims, and G (q heads per
-    # kv group, e.g. 6 or 7) is rarely tile-aligned.
+    # kv group, e.g. 6 or 7) is rarely tile-aligned.  A last block past B
+    # reads padding and its rows are never written back.
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    block = pl.BlockSpec(
+        (BS, KV, G, hd), lambda bb, *prefetch: (bb, 0, 0, 0),
+        memory_space=pltpu.VMEM,
+    )
     scratch = [
-        pltpu.VMEM((2, chunk_tokens, hd), k_data.dtype),
-        pltpu.VMEM((2, chunk_tokens, hd), v_data.dtype),
+        pltpu.VMEM((DECODE_BUFFERS, KV, chunk_tokens, hd), k_data.dtype),
+        pltpu.VMEM((DECODE_BUFFERS, KV, chunk_tokens, hd), v_data.dtype),
     ]
     if quant:
-        # per-token bf16 scale rows ride their own chunk buffers; the
-        # extra sem pair (indices 2/3) covers their DMAs
+        # per-token bf16 scale rows ride their own chunk buffers
         scratch += [
-            pltpu.VMEM((2, 1, chunk_tokens), k_scale.dtype),
-            pltpu.VMEM((2, 1, chunk_tokens), v_scale.dtype),
+            pltpu.VMEM((DECODE_BUFFERS, KV, chunk_tokens), k_scale.dtype),
+            pltpu.VMEM((DECODE_BUFFERS, KV, chunk_tokens), v_scale.dtype),
         ]
     scratch += [
-        pltpu.VMEM((G, hd), jnp.float32),
-        pltpu.VMEM((G, 128), jnp.float32),
-        pltpu.VMEM((G, 128), jnp.float32),
-        pltpu.SemaphoreType.DMA((2, 4 if quant else 2, CHUNK_PAGES)),
+        pltpu.VMEM((KV, G, hd), jnp.float32),
+        pltpu.VMEM((KV, G, 128), jnp.float32),
+        pltpu.VMEM((KV, G, 128), jnp.float32),
+        pltpu.SMEM((6, BS + 1), jnp.int32),
+        pltpu.SemaphoreType.DMA((DECODE_BUFFERS,)),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(B, KV),
-        in_specs=[
-            pl.BlockSpec(
-                (1, 1, G, hd), lambda b, g, *prefetch: (b, g, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            any_spec,
-            any_spec,
-        ]
+        grid=(cdiv(B, BS),),
+        in_specs=[block, any_spec, any_spec]
         + ([any_spec, any_spec] if quant else []),
-        out_specs=pl.BlockSpec(
-            (1, 1, G, hd), lambda b, g, *prefetch: (b, g, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
+        out_specs=block,
         scratch_shapes=scratch,
     )
     inputs = [q.reshape(B, KV, G, hd), k_data, v_data]
@@ -388,318 +573,12 @@ def paged_decode_attention_pallas(
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024,
+            # a descriptor's bounds checks cost the scalar core more than
+            # the descriptor; page ids are clamped in the kernel instead
+            disable_bounds_checks=True,
         ),
     )(page_tables, seq_lens, window_arr, layer_arr, *inputs)
     return out.reshape(B, H, hd)
-
-
-def _blocked_kernel(
-    # scalar prefetch
-    page_tables_ref,  # [B, pages_per_seq] int32 (SMEM)
-    seq_lens_ref,  # [B] int32 (SMEM)
-    window_ref,  # [1] int32 (SMEM)
-    layer_ref,  # [1] int32 (SMEM); -1 => no layer dim
-    # inputs
-    q_ref,  # [1, 1, BS, G, hd] VMEM block for (bb, g)
-    k_pages_ref,  # [KV, P, ps, hd] ANY/HBM ([L, KV, ...] when has_layer)
-    v_pages_ref,
-    # output
-    out_ref,  # [1, 1, BS, G, hd]
-    # scratch
-    k_buf,  # [2, BS, CHUNK*ps, hd] VMEM
-    v_buf,
-    acc_ref,  # [BS*G, hd] f32
-    m_ref,  # [BS*G, 128] f32
-    l_ref,  # [BS*G, 128] f32
-    sems,  # DMA semaphores [2, 2, BS, CHUNK]
-    *,
-    page_size: int,
-    softcap: float,
-    scale: float,
-    block_slots: int,
-    has_layer: bool = False,
-):
-    """Multi-slot decode attention: ``block_slots`` sequences per program.
-
-    The per-(slot, kv_head) kernel above runs B*KV tiny programs per
-    layer (7,168 grid steps per decode step at B=128, KV=2, 28 layers);
-    per-program iteration overhead is a prime suspect for the measured
-    gap to the HBM roofline (RESULTS_r3.md decision tree item 4).  This
-    variant serves ``BS`` slots per program — grid B/BS x KV — with the
-    same double-buffered live-page DMA per slot and a static unroll of
-    the per-slot 2D dots (Mosaic-safe; no batched dot_general).  The
-    fori_loop runs to the block's MAX chunk count; shorter slots mask.
-    """
-    BS = block_slots
-    bb = pl.program_id(0)
-    g = pl.program_id(1)
-    window = window_ref[0]
-    chunk_tokens = CHUNK_PAGES * page_size
-    G = q_ref.shape[3]
-
-    # per-slot page counts; loop bound is the block max
-    n_pages_j = [
-        jax.lax.div(
-            seq_lens_ref[bb * BS + j] + page_size - 1, page_size
-        )
-        for j in range(BS)
-    ]
-    n_chunks = jax.lax.div(
-        n_pages_j[0] + CHUNK_PAGES - 1, CHUNK_PAGES
-    )
-    for j in range(1, BS):
-        n_chunks = jnp.maximum(
-            n_chunks,
-            jax.lax.div(n_pages_j[j] + CHUNK_PAGES - 1, CHUNK_PAGES),
-        )
-    # sliding window: chunks wholly below the BLOCK's earliest window
-    # start are skipped (per-slot masks handle the rest)
-    lo_block = jnp.where(
-        window > 0,
-        jnp.maximum(seq_lens_ref[bb * BS] - window, 0),
-        0,
-    )
-    for j in range(1, BS):
-        lo_block = jnp.minimum(
-            lo_block,
-            jnp.where(
-                window > 0,
-                jnp.maximum(seq_lens_ref[bb * BS + j] - window, 0),
-                0,
-            ),
-        )
-    lo_chunk = jax.lax.div(lo_block, chunk_tokens)
-
-    def src(ref, page_id):
-        if has_layer:
-            return ref.at[layer_ref[0], g, page_id]
-        return ref.at[g, page_id]
-
-    def start_chunk(c, slot):
-        for j in range(BS):
-            b = bb * BS + j
-            for i in range(CHUNK_PAGES):  # static unroll
-                page_pos = c * CHUNK_PAGES + i
-
-                @pl.when(page_pos < n_pages_j[j])
-                def _():
-                    page_id = page_tables_ref[b, page_pos]
-                    pltpu.make_async_copy(
-                        src(k_pages_ref, page_id),
-                        k_buf.at[
-                            slot, j, pl.ds(i * page_size, page_size), :
-                        ],
-                        sems.at[slot, 0, j, i],
-                    ).start()
-                    pltpu.make_async_copy(
-                        src(v_pages_ref, page_id),
-                        v_buf.at[
-                            slot, j, pl.ds(i * page_size, page_size), :
-                        ],
-                        sems.at[slot, 1, j, i],
-                    ).start()
-
-                @pl.when(page_pos >= n_pages_j[j])
-                def _():
-                    k_buf[
-                        slot, j, pl.ds(i * page_size, page_size), :
-                    ] = jnp.zeros(
-                        (page_size, k_buf.shape[-1]), k_buf.dtype
-                    )
-                    v_buf[
-                        slot, j, pl.ds(i * page_size, page_size), :
-                    ] = jnp.zeros(
-                        (page_size, v_buf.shape[-1]), v_buf.dtype
-                    )
-
-    def wait_chunk(c, slot):
-        for j in range(BS):
-            for i in range(CHUNK_PAGES):
-                page_pos = c * CHUNK_PAGES + i
-
-                @pl.when(page_pos < n_pages_j[j])
-                def _():
-                    pltpu.make_async_copy(
-                        src(k_pages_ref, 0),
-                        k_buf.at[
-                            slot, j, pl.ds(i * page_size, page_size), :
-                        ],
-                        sems.at[slot, 0, j, i],
-                    ).wait()
-                    pltpu.make_async_copy(
-                        src(v_pages_ref, 0),
-                        v_buf.at[
-                            slot, j, pl.ds(i * page_size, page_size), :
-                        ],
-                        sems.at[slot, 1, j, i],
-                    ).wait()
-
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-    m_ref[...] = jnp.full_like(m_ref, -1e30)
-    l_ref[...] = jnp.zeros_like(l_ref)
-
-    start_chunk(lo_chunk, jax.lax.rem(lo_chunk, 2))
-
-    def body(c, _):
-        slot = jax.lax.rem(c, 2)
-        next_slot = jax.lax.rem(c + 1, 2)
-
-        @pl.when(c + 1 < n_chunks)
-        def _():
-            start_chunk(c + 1, next_slot)
-
-        wait_chunk(c, slot)
-
-        k_all = jax.lax.cond(
-            slot == 0, lambda: k_buf[0], lambda: k_buf[1]
-        )  # [BS, chunk_tokens, hd]
-        v_all = jax.lax.cond(
-            slot == 0, lambda: v_buf[0], lambda: v_buf[1]
-        )
-        token_base = c * chunk_tokens
-        for j in range(BS):  # static unroll: 2D dots only
-            b = bb * BS + j
-            q = q_ref[0, 0, j].astype(jnp.float32) * scale  # [G, hd]
-            k = k_all[j].astype(jnp.float32)  # [chunk_tokens, hd]
-            v = v_all[j].astype(jnp.float32)
-            scores = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [G, chunk_tokens]
-            if softcap:
-                scores = jnp.tanh(scores / softcap) * softcap
-            token_pos = token_base + jax.lax.broadcasted_iota(
-                jnp.int32, scores.shape, 1
-            )
-            sl = seq_lens_ref[b]
-            lo = jnp.where(
-                window > 0, jnp.maximum(sl - window, 0), 0
-            )
-            valid = (token_pos >= lo) & (token_pos < sl)
-            scores = jnp.where(valid, scores, -1e30)
-            r = slice(j * G, (j + 1) * G)
-            m_prev = m_ref[r, :1]
-            m_cur = jnp.max(scores, axis=-1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(scores - m_new)
-            l_new = alpha * l_ref[r, :1] + jnp.sum(
-                p, axis=-1, keepdims=True
-            )
-            acc_ref[r, :] = acc_ref[r, :] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_ref[r, :] = jnp.broadcast_to(m_new, (G, 128))
-            l_ref[r, :] = jnp.broadcast_to(l_new, (G, 128))
-        return 0
-
-    jax.lax.fori_loop(lo_chunk, n_chunks, body, 0)
-    for j in range(BS):
-        r = slice(j * G, (j + 1) * G)
-        denom = jnp.maximum(l_ref[r, :1], 1e-30)
-        out_ref[0, 0, j] = (acc_ref[r, :] / denom).astype(out_ref.dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("interpret", "softcap", "scale", "block_slots"),
-)
-def paged_decode_attention_pallas_blocked(
-    q: jnp.ndarray,  # [B, H, hd]
-    k_pages: jnp.ndarray,  # [KV, P, ps, hd] ([L, KV, ...] with `layer`)
-    v_pages: jnp.ndarray,
-    page_tables: jnp.ndarray,  # [B, pages_per_seq]
-    seq_lens: jnp.ndarray,  # [B]
-    window=None,
-    layer=None,
-    interpret: bool = False,
-    softcap: float = 0.0,
-    scale=None,
-    block_slots: int = 8,
-) -> jnp.ndarray:
-    """Multi-slot-blocked variant of ``paged_decode_attention_pallas``:
-    grid (B/block_slots, KV) instead of (B, KV).  Opt-in via
-    ``tpu.decode_block_slots`` until its win is measured on hardware
-    (the r3 lesson: no unmeasured default flips).  Falls back to the
-    per-slot kernel when ``B % block_slots != 0`` — and for int8 KV
-    pools: the blocked grid is itself unmeasured, so it doesn't carry
-    the scale-DMA plumbing yet (the per-slot kernel dequantizes
-    in-VMEM; revisit if the hardware A/B picks the blocked grid)."""
-    from vgate_tpu.ops.kv_quant import is_quantized
-
-    B, H, hd = q.shape
-    has_layer = layer is not None
-    BS = block_slots
-    if BS <= 1 or B % BS or is_quantized(k_pages):
-        return paged_decode_attention_pallas(
-            q, k_pages, v_pages, page_tables, seq_lens, window=window,
-            layer=layer, interpret=interpret, softcap=softcap,
-            scale=scale,
-        )
-    KV, P, ps, _ = k_pages.shape[1:] if has_layer else k_pages.shape
-    G = H // KV
-    chunk_tokens = CHUNK_PAGES * ps
-
-    if window is None:
-        window_arr = jnp.zeros((1,), jnp.int32)
-    else:
-        window_arr = jnp.asarray(window, jnp.int32).reshape(1)
-    layer_arr = (
-        jnp.asarray(layer, jnp.int32).reshape(1)
-        if has_layer
-        else jnp.full((1,), -1, jnp.int32)
-    )
-    kernel = functools.partial(
-        _blocked_kernel,
-        page_size=ps,
-        softcap=float(softcap),
-        scale=float(scale) if scale is not None else hd ** -0.5,
-        block_slots=BS,
-        has_layer=has_layer,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B // BS, KV),
-        in_specs=[
-            pl.BlockSpec(
-                (1, 1, BS, G, hd),
-                lambda bb, g, *prefetch: (bb, g, 0, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, BS, G, hd),
-            lambda bb, g, *prefetch: (bb, g, 0, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((2, BS, chunk_tokens, hd), k_pages.dtype),
-            pltpu.VMEM((2, BS, chunk_tokens, hd), v_pages.dtype),
-            pltpu.VMEM((BS * G, hd), jnp.float32),
-            pltpu.VMEM((BS * G, 128), jnp.float32),
-            pltpu.VMEM((BS * G, 128), jnp.float32),
-            pltpu.SemaphoreType.DMA((2, 2, BS, CHUNK_PAGES)),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B // BS, KV, BS, G, hd), q.dtype),
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=96 * 1024 * 1024,
-        ),
-    )(
-        page_tables, seq_lens, window_arr, layer_arr,
-        # q [B, H, hd] = [NB*BS, KV*G, hd] -> [NB, KV, BS, G, hd]
-        jnp.swapaxes(q.reshape(B // BS, BS, KV, G, hd), 1, 2),
-        k_pages, v_pages,
-    )
-    # out [NB, KV, BS, G, hd] -> [B, H, hd]
-    return jnp.swapaxes(out, 1, 2).reshape(B, H, hd)
 
 
 def _mt_kernel(

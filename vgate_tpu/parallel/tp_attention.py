@@ -11,7 +11,7 @@ visible on one chip or on CPU meshes, which run the jnp twins).
 
 These wrappers run the kernel per tp shard inside a ``shard_map``:
 each shard holds ``KV/tp`` kv heads of the pool and ``H/tp`` query
-heads, the kernel's (slot, kv_head) grid simply shrinks, and NO
+heads, the kernel simply serves fewer heads a program, and NO
 collective is needed at all — attention is embarrassingly parallel
 over heads (the Megatron layout).  Requires both H and KV divisible by
 tp; callers fall back to the jnp twin otherwise.  Traced per-layer

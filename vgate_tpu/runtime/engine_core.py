@@ -1398,17 +1398,6 @@ class EngineCore:
         # Local-attention families (Gemma-2) ride both kernels: they take
         # window/softcap/scale natively, and the decode kernel skips DMA
         # for pages below the window.
-        if (
-            self.use_pallas
-            and int(getattr(tpu_cfg, "decode_block_slots", 1)) > 1
-        ):
-            import dataclasses as _dc
-
-            # threaded on the spec (a static jit arg), like quant_kernel
-            self.spec = _dc.replace(
-                self.spec,
-                decode_block_slots=int(tpu_cfg.decode_block_slots),
-            )
         # tp>1 with Pallas on: the forwards need the mesh so attention
         # kernels run per tp shard (parallel/tp_attention.py) instead of
         # GSPMD replicating the pallas_call's operands.  The sp/pp
