@@ -36,7 +36,7 @@ def main() -> None:
 
     from vgate_tpu.models.decoder import init_params
     from vgate_tpu.models.specs import spec_for_model_id
-    from vgate_tpu.runtime.engine_core import _decode_chunk
+    from vgate_tpu.runtime.step_programs import _decode_chunk
 
     spec = spec_for_model_id(args.model)
     dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32

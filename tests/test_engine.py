@@ -371,12 +371,12 @@ def test_decode_chunk_ladder_compiles_powers_of_two():
     core.start()
     try:
         core.generate(["ladder probe"], [greedy(16)])
-        # keys are (chunk_len, penalties_active, min_tokens_width)
-        lens = {k[0] for k in core._compiled_chunks}
+        # keys are (chunk_len, penalties_active, min_tokens_width, ...)
+        chunks = {k for family, k in core._compiled if family == "decode"}
+        lens = {k[0] for k in chunks}
         assert lens <= {1, 2, 4, 8}
         assert max(lens) == 8
-        assert all(k[1] is False and k[2] is None
-                   for k in core._compiled_chunks)
+        assert all(k[1] is False and k[2] is None for k in chunks)
     finally:
         core.stop()
 
@@ -1241,3 +1241,142 @@ def test_submit_fatal_toctou_drain():
     with pytest.raises(RuntimeError, match="engine is dead"):
         core.submit_tokens([1, 2, 3], greedy(2))
     assert core._submit_q.empty()
+
+
+# ------------------------------------------- the programs a prompt compiles
+
+def _variant_cfg(**tpu_overrides):
+    """decode_chunk 1 and groups of one row: the variants a case compiles
+    then depend on its prompt layout alone, not on when a token stops a
+    stream or on which tick a request arrives."""
+    return tiny_config(
+        decode_chunk=1, prefill_batch_max=1,
+        prefix_cache={"enabled": True, "cow_min_tokens": 2},
+        **tpu_overrides,
+    )
+
+
+def _run(core, ids, params=None):
+    seq = core.submit_tokens(list(ids), params or greedy(3))
+    assert seq.done_event.wait(300)
+    assert seq.error is None
+    return seq
+
+
+def _fresh(core, salt):
+    _run(core, [salt + i for i in range(10)])
+
+
+def _aligned_hit(core, salt):
+    base = [salt + i for i in range(12)]
+    _run(core, base)
+    hits = core.scheduler.total_prefix_hit_tokens
+    _run(core, base[:8] + [salt + 40 + i for i in range(4)])
+    assert core.scheduler.total_prefix_hit_tokens == hits + 8
+
+
+def _cow_hit(core, salt):
+    base = [salt + i for i in range(14)]
+    _run(core, base)
+    cows = core.radix_cache.total_cow_copies
+    _run(core, base[:10] + [salt + 40 + i for i in range(4)])
+    assert core.radix_cache.total_cow_copies == cows + 1
+
+
+def _chunked(core, salt):
+    _run(core, [salt + (i % 29) for i in range(40)])
+
+
+def _re_prefill_with_penalties(core, salt):
+    """What a preemption leaves: the generated tokens folded into the
+    prompt, still counted by the penalties of the re-sampled token."""
+    from vgate_tpu.runtime.sequence import Sequence
+
+    done = [salt + 50, salt + 51, salt + 50]
+    seq = Sequence(
+        prompt_ids=[salt + i for i in range(10)],
+        params=SamplingParams(
+            max_tokens=6, temperature=0.0, frequency_penalty=0.5
+        ),
+        output_ids=list(done),
+        generated_ids=list(done),
+    )
+    seq.reset_for_recompute()
+    core.submit_existing(seq)
+    assert seq.done_event.wait(300)
+    assert seq.error is None
+    assert seq.preempt_count == 1 and seq.num_prompt_tokens == 13
+
+
+def _sampling_extras(core, salt):
+    seq = _run(core, [salt + i for i in range(10)], SamplingParams(
+        max_tokens=3, min_tokens=3, temperature=0.0, logprobs=True,
+        top_logprobs=2, logit_bias={salt + 3: 4.0, salt + 4: -4.0,
+                                    salt + 5: 1.0},
+    ))
+    assert len(seq.logprob_data) == 3
+
+
+def _suffix(bucket, ctx_pages, unaligned=False):
+    return str(
+        ("suffix", bucket, 1, ctx_pages, False, None, 0, None, unaligned)
+    )
+
+
+# (record_compile kind, variant key, trigger), read from the tree before
+# the dispatchers were merged (PR 28's)
+_DECODE_PLAIN = ("decode", "(1, False, None, 0, True, None)", "chunk_variant")
+_PREFILL_PLAIN = ("prefill", "(16, 1, False, None, 0, None)", "bucket")
+_VARIANT_CASES = {
+    "fresh-prompt": (_variant_cfg, _fresh, {_PREFILL_PLAIN, _DECODE_PLAIN}),
+    "aligned-prefix-hit": (_variant_cfg, _aligned_hit, {
+        _PREFILL_PLAIN, _DECODE_PLAIN,
+        ("suffix_prefill", _suffix(8, 4), "bucket"),
+    }),
+    "copy-on-write-hit": (_variant_cfg, _cow_hit, {
+        _PREFILL_PLAIN, _DECODE_PLAIN,
+        ("suffix_prefill", _suffix(8, 4, unaligned=True), "bucket"),
+    }),
+    "chunked-prompt": (
+        lambda: _variant_cfg(prefill_chunk=16, prefill_buckets=[8, 16]),
+        _chunked,
+        {
+            ("chunked_prefill", _suffix(16, 4), "ctx_width"),
+            ("chunked_prefill", _suffix(16, 8), "ctx_width"),
+            ("suffix_prefill", _suffix(8, 16), "bucket"),
+            _DECODE_PLAIN,
+        },
+    ),
+    "re-prefill-with-penalties": (_variant_cfg, _re_prefill_with_penalties, {
+        ("prefill", "(16, 1, True, None, 0, None)", "bucket"),
+        ("decode", "(1, True, None, 0, True, None)", "chunk_variant"),
+    }),
+    "logprobs-min-tokens-logit-bias": (_variant_cfg, _sampling_extras, {
+        ("prefill", "(16, 1, False, 1, 8, 4)", "bucket"),
+        ("decode", "(1, False, 1, 8, False, 4)", "chunk_variant"),
+    }),
+}
+
+
+@pytest.mark.fast  # seconds a case: runs in tier-1, unlike this file
+@pytest.mark.parametrize("case", sorted(_VARIANT_CASES))
+def test_prompt_layout_compiles_exactly_these_programs(case):
+    """Pins, per prompt layout, the (kind, variant key) pairs the compile
+    ledger records, and that the same layout a second time (other
+    tokens, so nothing is cached) compiles nothing more."""
+    make_cfg, drive, expected = _VARIANT_CASES[case]
+    core = EngineCore(make_cfg(), devices=jax.devices()[:1])
+    core.start()
+    try:
+        def ledger():
+            return {
+                (e["program"], e["signature"], e["trigger"]): e["count"]
+                for e in core.perf.compile_ledger()
+            }
+
+        drive(core, 3)
+        assert ledger() == dict.fromkeys(expected, 1)
+        drive(core, 60)
+        assert ledger() == dict.fromkeys(expected, 1)
+    finally:
+        core.stop()

@@ -76,20 +76,7 @@ def test_peak_table_covers_tpu_v4_v5_v6():
     assert peaks_for("cpu") is None
 
 
-def test_benchmarks_shim_reexports_the_same_objects():
-    """benchmarks/_roofline.py is a re-export shim: the benches and the
-    live gauges literally share the peak table, so they can never
-    disagree on a device's peak."""
-    from benchmarks import _roofline as shim
-
-    assert shim.DEVICE_PEAKS is DEVICE_PEAKS
-    assert shim.peaks_for is peaks_for
-    assert shim.roofline_row is roofline_row
-    assert shim.kv_bytes_per_token is kv_bytes_per_token
-
-
 def test_roofline_row_and_step_bytes_unchanged_semantics():
-    """The shim move must not change the bench-facing math."""
     kb = kv_bytes_per_token(2, 4, 8, dtype_bytes=2, scale_bytes=0)
     assert kb == 2 * 2 * 4 * 8 * 2
     assert decode_step_bytes(100, 2, 10, kb) == 100 + 2 * 10 * kb

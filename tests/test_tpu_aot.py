@@ -292,7 +292,7 @@ def _assert_one_pool(compiled, pool_bytes):
 
 
 def test_decode_chunk_holds_one_pool_on_v5e(v5e):
-    from vgate_tpu.runtime.engine_core import _decode_chunk
+    from vgate_tpu.runtime.step_programs import _decode_chunk
 
     A = _abstract(v5e)
     spec, params, pool, pool_bytes = _qwen_1p5b(A)
@@ -310,7 +310,7 @@ def test_decode_chunk_holds_one_pool_on_v5e(v5e):
 
 
 def test_prefill_step_holds_one_pool_on_v5e(v5e):
-    from vgate_tpu.runtime.engine_core import _prefill_step
+    from vgate_tpu.runtime.step_programs import _prefill_step
 
     A = _abstract(v5e)
     spec, params, pool, pool_bytes = _qwen_1p5b(A)

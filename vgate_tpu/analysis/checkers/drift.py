@@ -14,8 +14,7 @@ agree; known single-definition-site registries must stay single.
   tuple/list/set literal of exactly {"interactive", "standard",
   "batch"} in package code is a drifting copy.
 * **D004** — ``DEVICE_PEAKS`` (TPU roofline peaks) is assigned only in
-  vgate_tpu/observability/roofline.py; everything else imports it
-  (benchmarks/_roofline.py is the sanctioned re-export shim).
+  vgate_tpu/observability/roofline.py; everything else imports it.
 * **D005** — drill scripts must take their ports from the
   ``VGT_DRILL_PORTS`` registry in scripts/_drill_lib.sh; a literal
   ``873x`` port in any other script is the foot-gun PR 6 removed.

@@ -92,7 +92,7 @@ class JitPurityChecker(Checker):
         "host clocks / RNG / set-iteration / print inside "
         "jit-traced functions (recompile + staleness hazards)"
     )
-    scope = ("vgate_tpu/**/*.py", "benchmarks/**/*.py", "bench.py")
+    scope = ("vgate_tpu/**/*.py", "benchmarks/**/*.py")
 
     def run(self, project: Project) -> List[Violation]:
         out: List[Violation] = []

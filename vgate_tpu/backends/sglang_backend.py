@@ -1,11 +1,10 @@
 """Optional SGLang comparison backend.
 
-The reference's headline benchmark tables vLLM AND SGLang side by side
-(/root/reference/benchmarks/bench_compare.py:145-178); the vLLM half
-landed in r3 (backends/vllm_backend.py) and this adapter completes the
-pair, so ``benchmarks/bench_compare.py --engines jax_tpu vllm sglang``
-reproduces the reference's full comparison matrix on a machine that has
-those wheels.
+The reference's headline benchmark tables vLLM AND SGLang side by side;
+the vLLM half landed in r3 (backends/vllm_backend.py) and this adapter
+completes the pair, so that a machine that has those wheels can serve
+either behind the same gateway and be driven over HTTP by the same load
+(``model.engine_type: "sglang"``).
 
 SGLang is deliberately NOT a dependency — this image has no GPU and no
 egress — so the import is lazy and the error explicit.  The adapter

@@ -1,9 +1,9 @@
 """Optional vLLM comparison backend.
 
 The reference's headline benchmark runs vLLM and SGLang side by side
-(/root/reference/benchmarks/bench_compare.py:145-178 — both backends in
-one table); this adapter restores that capability for apples-to-apples
-GPU-vs-TPU comparisons when a ``vllm`` wheel is present.  It is a thin
+(both backends in one table); this adapter restores that capability for
+apples-to-apples GPU-vs-TPU comparisons when a ``vllm`` wheel is
+present.  It is a thin
 adapter over ``vllm.LLM.generate`` mapped onto OUR 4-method seam and
 per-request ``SamplingParams`` (the reference applies the first
 request's temperature to the whole batch, vgate/batcher.py:271; vLLM
@@ -12,8 +12,9 @@ prompt).
 
 vLLM is deliberately NOT a dependency — this image has no GPU and no
 egress — so the import is lazy and the error is explicit.  Select with
-``model.engine_type: "vllm"`` or benchmark side by side via
-``benchmarks/bench_compare.py --engines jax_tpu vllm``.
+``model.engine_type: "vllm"``; the gateway, and so the workload lab
+and the benchmark (perfbench/README.md), then drive it over HTTP like
+any other engine.
 """
 
 from __future__ import annotations

@@ -181,10 +181,11 @@ class KVCacheConfig(BaseModel):
       Requires a plain mesh (tp/pp/sp/ep == 1; dp composes — each
       replica owns its pool).  Quality: per-token-per-head symmetric
       scales bound the per-element error at ~0.4% of the row absmax;
-      the kv_quant bench A/B (bench.py) measures the end-to-end
-      logprob drift and greedy token-identity horizon vs the bf16
-      oracle.  bf16 stays the default until the hardware A/B
-      adjudicates the flip (docs/operations.md capacity planning).
+      tests/test_kv_quant.py holds the logprob drift and the greedy
+      token-identity horizon vs the bf16 oracle on the tiny model.
+      bf16 stays the default until a cell of the benchmark
+      (perfbench/README.md) adjudicates the flip on the chip
+      (docs/operations.md capacity planning).
     """
 
     dtype: str = "auto"

@@ -11,8 +11,8 @@ claims and what clients experience is visible in one file (the smoke
 drill asserts the two agree on an unloaded cell).
 
 ``launch_server`` boots ``python main.py`` as a subprocess with the
-scenario's ``server_env`` — the path bench.py's scenario mode and the
-drills share.
+scenario's ``server_env`` — the path the lab's CLI (``--launch``) and
+the drills share.
 """
 
 from __future__ import annotations
@@ -396,7 +396,7 @@ async def run_scenario_async(
 
 
 def run_scenario(scenario: Scenario, base_url: str, **kwargs: Any):
-    """Sync wrapper (scripts / bench.py)."""
+    """Sync wrapper (the lab's CLI)."""
     return asyncio.run(run_scenario_async(scenario, base_url, **kwargs))
 
 

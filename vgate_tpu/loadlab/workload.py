@@ -13,8 +13,8 @@ Open-loop note: multi-turn transcripts are PRE-generated (the
 answers).  A closed-loop chat replay would condition turn N+1's send
 time on turn N's completion — exactly the feedback loop this lab
 refuses.  Prompt-side prefix reuse (the dominant term) is preserved;
-generated-token reuse is measured separately by
-benchmarks/bench_prefix.py.
+generated-token reuse needs a closed loop (the benchmark's
+``sharing: sessions`` traffic, perfbench/README.md).
 """
 
 from __future__ import annotations

@@ -225,7 +225,7 @@ DECODE_MFU = _safe_metric(
     "vgt_decode_mfu",
     "Live model-FLOPs utilization over the perf window (2 FLOPs per "
     "param per generated token vs the mesh's peak, "
-    "observability/roofline.py — the same peak table bench.py reads).  "
+    "observability/roofline.py).  "
     "0 off the peak table (e.g. CPU dry-runs); dp>1 reports the last-"
     "flushed replica (exact per-replica values: /debug/perf)",
 )

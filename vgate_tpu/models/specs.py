@@ -153,7 +153,7 @@ class ModelSpec:
     @property
     def num_params(self) -> int:
         """Analytic parameter count (embeddings + decoder stack), used
-        for MFU accounting in bench.py.  Matches init_params' layout:
+        for the MFU gauge (observability/roofline.py).  Matches init_params' layout:
         q/k/v/o (+bias), gate/up/down (per expert for MoE, + router),
         norms, embed, united or separate lm_head."""
         D, L, F = self.hidden_size, self.num_layers, self.intermediate_size

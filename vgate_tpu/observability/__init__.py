@@ -18,8 +18,7 @@ is absent:
   rolling-window MFU / HBM-roofline / host-overhead gauges served via
   ``/debug/perf``;
 * :mod:`~vgate_tpu.observability.roofline` — the device peak table and
-  roofline/MFU math shared with the benches (benchmarks/_roofline.py is
-  a re-export shim of it).
+  roofline/MFU math behind the live gauges.
 """
 
 from vgate_tpu.observability.flight import FlightRecorder

@@ -1,15 +1,13 @@
 """Device peak table + HBM roofline / MFU math — the single definition
 site.
 
-Promoted from ``benchmarks/_roofline.py`` (now a re-export shim) so the
-offline benches (bench.py's headline roofline fraction,
-bench_decode_ablate's per-row achieved-GB/s columns) and the engine's
-LIVE gauges (observability/perf.py -> ``vgt_decode_mfu`` /
-``vgt_decode_hbm_roofline_pct``) can never disagree on what a device's
-peak is.  Peaks are per chip; unknown device kinds return None so
-callers omit the roofline fields rather than mislabel them.
+The engine's LIVE gauges (observability/perf.py -> ``vgt_decode_mfu`` /
+``vgt_decode_hbm_roofline_pct``) read a device's peak here.  Peaks are
+per chip; unknown device kinds return None so callers omit the roofline
+fields rather than mislabel them.  The benchmark keeps its own copy
+(perfbench/peaks.json): it may not import the program.
 
-Modeling conventions (shared by bench.py and the live gauges):
+Modeling conventions of the live gauges:
 
 * one decode step streams the weights once (untied embedding tables are
   GATHERED row-wise, not streamed — callers exclude them via
@@ -72,7 +70,7 @@ def roofline_row(
     step_bytes: int,
     device_kind: str,
 ) -> dict:
-    """The per-row roofline fields bench_decode_ablate attaches:
+    """The roofline fields of one timed row:
     achieved HBM GB/s over the step's modeled traffic, and the percent
     of the device's HBM peak that represents.  Empty for unknown
     devices or non-timed rows."""
@@ -110,8 +108,7 @@ def stream_weight_bytes(params: Any, tie_embeddings: bool) -> int:
 @dataclasses.dataclass(frozen=True)
 class EngineRoofline:
     """The static geometry the live gauges need, captured once at engine
-    build (observability/perf.py holds one; bench.py derives the same
-    numbers ad hoc).  ``num_chips`` scales the per-chip peaks to the
+    build (observability/perf.py holds one).  ``num_chips`` scales the per-chip peaks to the
     serving mesh; dp replicas each carry their own (their meshes are
     disjoint)."""
 

@@ -210,7 +210,7 @@ def gather_pages(pool: KVPool, page_tables: jax.Array, layer=None):
 def copy_page_prefix(
     pool: KVPool, src, dst, keep_mask: jax.Array
 ) -> KVPool:
-    """Radix copy-on-write page copy (engine_core._cow_copy_pages):
+    """Radix copy-on-write page copy (step_programs._cow_copy_pages):
     overwrite the first slots of page ``dst`` with page ``src``'s where
     ``keep_mask`` ([ps] bool) holds, across every layer and head.  For
     int8 pools the SCALES copy with the data — a shared head keeps the

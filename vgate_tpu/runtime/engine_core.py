@@ -27,7 +27,7 @@ Architecture (SURVEY.md section 7, stages 3-4):
 
 from __future__ import annotations
 
-import functools
+import contextlib
 import os
 import queue
 import tempfile
@@ -63,26 +63,14 @@ from vgate_tpu.config import (
 from vgate_tpu.logging_config import bound_request, get_logger
 from vgate_tpu.models.decoder import (
     decode_attention_impl,
-    decode_forward,
     multitok_attention_impl,
     prefill_attention_impl,
-    prefill_forward,
-    prefill_suffix_forward,
-    spec_verify_forward,
 )
 from vgate_tpu.models.hybrid import make_state as make_hybrid_state
 from vgate_tpu.models.hybrid import (
     state_bytes_per_slot as hybrid_state_bytes_per_slot,
 )
 from vgate_tpu.models.specs import ModelSpec, spec_for_model_id
-from vgate_tpu.ops.sampling import (
-    apply_logit_bias,
-    apply_penalties,
-    sample_tokens,
-    sample_tokens_with_logprobs,
-    suppress_stop_tokens,
-    verify_and_sample,
-)
 from vgate_tpu.observability.flight import FlightRecorder
 from vgate_tpu.observability.perf import (
     BOOT_SECONDS,
@@ -96,11 +84,7 @@ from vgate_tpu.observability.roofline import (
     kv_bytes_per_token,
     stream_weight_bytes,
 )
-from vgate_tpu.ops.kv_quant import (
-    SCALE_BYTES,
-    copy_page_prefix,
-    dtype_short_name,
-)
+from vgate_tpu.ops.kv_quant import SCALE_BYTES, dtype_short_name
 from vgate_tpu.parallel.mesh import build_mesh, initialize_distributed
 from vgate_tpu.parallel.sharding import kv_pspec, named, shard_params
 from vgate_tpu.runtime.kv_cache import (
@@ -113,6 +97,16 @@ from vgate_tpu.runtime.kv_swap import KVSwapManager
 from vgate_tpu.runtime.radix_cache import RadixCache
 from vgate_tpu.runtime.scheduler import PrefillPlan, Scheduler, SwapInPlan
 from vgate_tpu.runtime.sequence import Sequence, SeqStatus
+from vgate_tpu.runtime.step_programs import (
+    _cow_copy_pages,
+    _decode_chunk,
+    _gather_swap_pages,
+    _prefill_step,
+    _scatter_swap_pages,
+    _spec_verify_step,
+    _state_kw,
+    _suffix_prefill_step,
+)
 from vgate_tpu.runtime.tokenizer import get_tokenizer
 from vgate_tpu.runtime.weights import load_or_init_params
 from vgate_tpu.utils.math import bucket_for, cdiv
@@ -152,160 +146,12 @@ _DTYPES = {
     "float16": jnp.float16,
 }
 
-
-def _state_kw(state, slots=None) -> Dict[str, Any]:
-    """The forwards' extra arguments for a spec with recurrent layers;
-    nothing for the others, whose programs stay what they were."""
-    if state is None:
-        return {}
-    return {"state": state} if slots is None else {
-        "state": state, "slots": slots}
-
-
-@jax.named_scope("sample")
-def _sample_first(
-    logits, temps, top_ps, top_ks, key, seeds, steps, num_logprobs,
-    counts, freq_pens, pres_pens, min_toks, stop_id_mat, bias_ids,
-    bias_vals,
-):
-    """A prompt pass's fused first-token sampling (traced inside
-    _prefill_step / _suffix_prefill_step): logit post-processing, then
-    the sample.  Returns ``(next_tokens, logprob triple or None)``."""
-    if counts is not None:
-        # post-preemption re-prefill: folded outputs still count toward
-        # the penalties of the re-sampled first token
-        logits = apply_penalties(logits, counts, freq_pens, pres_pens)
-    if bias_ids is not None:
-        logits = apply_logit_bias(logits, bias_ids, bias_vals)
-    if min_toks is not None:
-        logits = suppress_stop_tokens(logits, steps, min_toks, stop_id_mat)
-    if num_logprobs > 0:
-        next_tokens, lp, tids, tlps = sample_tokens_with_logprobs(
-            logits, temps, top_ps, top_ks, key, seeds=seeds, steps=steps,
-            num_top=num_logprobs,
-        )
-        return next_tokens, (lp, tids, tlps)
-    # NOTE: no all_greedy fast path in prefill programs — one sample per
-    # PROMPT makes the top-k cost negligible, and skipping the variant
-    # split halves the (expensive) batched-prefill compile ladder
-    next_tokens = sample_tokens(
-        logits, temps, top_ps, top_ks, key, seeds=seeds, steps=steps
-    )
-    return next_tokens, None
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "spec", "mesh", "use_pallas", "num_logprobs"
-    ),
-    donate_argnames=("k_pages", "v_pages", "state"),
-)
-def _prefill_step(
-    params, spec: ModelSpec, tokens, seq_lens, k_pages, v_pages,
-    page_tables, temps, top_ps, top_ks, key, mesh=None, use_pallas=False,
-    seeds=None, steps=None, num_logprobs: int = 0,
-    counts=None, freq_pens=None, pres_pens=None,
-    min_toks=None, stop_id_mat=None, bias_ids=None, bias_vals=None,
-    state=None, slots=None,
-):
-    """The cache is threaded and donated as ONE value: the K/V pools
-    and, for a spec with recurrent layers, the per-slot ``state``
-    (models/hybrid.py), whose rows ``slots`` this pass overwrites.  It
-    comes back as the result's tail: ``(k_pages, v_pages)`` or
-    ``(k_pages, v_pages, state)``."""
-    logits, *cache = prefill_forward(
-        params, spec, tokens, seq_lens, k_pages, v_pages, page_tables,
-        mesh=mesh, use_pallas=use_pallas,
-        **_state_kw(state, slots),
-    )
-    out = _sample_first(
-        logits, temps, top_ps, top_ks, key, seeds, steps, num_logprobs,
-        counts, freq_pens, pres_pens, min_toks, stop_id_mat, bias_ids,
-        bias_vals,
-    )
-    return (out, *cache)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("spec", "num_logprobs", "use_pallas", "mesh",
-                     "unaligned"),
-    donate_argnames=("k_pages", "v_pages", "state"),
-)
-def _suffix_prefill_step(
-    params, spec: ModelSpec, tokens, prefix_lens, suffix_lens, k_pages,
-    v_pages, suffix_page_tables, ctx_page_tables, temps, top_ps, top_ks,
-    key, seeds=None, steps=None, num_logprobs: int = 0,
-    counts=None, freq_pens=None, pres_pens=None,
-    min_toks=None, stop_id_mat=None, bias_ids=None, bias_vals=None,
-    use_pallas: bool = False, mesh=None, unaligned: bool = False,
-    state=None, slots=None,
-):
-    """Prompt pass for the uncached suffix of a prefix-cache hit, with
-    fused first-token sampling (models/decoder.py prefill_suffix_forward).
-    ``unaligned`` is the copy-on-write variant: prefix_lens may fall
-    mid-page and the KV write becomes a per-token scatter."""
-    logits, *cache = prefill_suffix_forward(
-        params, spec, tokens, prefix_lens, suffix_lens, k_pages, v_pages,
-        suffix_page_tables, ctx_page_tables, use_pallas=use_pallas,
-        mesh=mesh, unaligned=unaligned, **_state_kw(state, slots),
-    )
-    out = _sample_first(
-        logits, temps, top_ps, top_ks, key, seeds, steps, num_logprobs,
-        counts, freq_pens, pres_pens, min_toks, stop_id_mat, bias_ids,
-        bias_vals,
-    )
-    return (out, *cache)
-
-
-@functools.partial(jax.jit, donate_argnames=("k_pages", "v_pages"))
-def _cow_copy_pages(k_pages, v_pages, src, dst, upto):
-    """Copy-on-write page copy (runtime/radix_cache.py): duplicate the
-    first ``upto`` token slots of page ``src`` into page ``dst`` across
-    every layer and head, so a sequence diverging mid-page gets the
-    shared head's KV without recomputing it.  Scalars are traced — one
-    compile serves every (src, dst, upto) combination.  int8 pools copy
-    the per-slot SCALES with the data (ops/kv_quant.copy_page_prefix):
-    a COW'd head dequantizes bit-identically to the page it came from,
-    so shared and diverged readers never disagree."""
-    ps = k_pages.shape[-2]
-    keep = jnp.arange(ps) < upto  # [ps]
-    return (
-        copy_page_prefix(k_pages, src, dst, keep),
-        copy_page_prefix(v_pages, src, dst, keep),
-    )
-
-
 # pages moved per device call when swapping KV to/from host RAM
 # (runtime/kv_swap.py): fixed so each direction compiles exactly one
 # program per pool dtype — short runs pad their index vector with the
 # reserved trash page 0, which absorbs the padding writes on swap-in
 # and whose padding rows are dropped host-side on swap-out
 SWAP_CHUNK_PAGES = 16
-
-
-@jax.jit
-def _gather_swap_pages(k_pages, v_pages, idx):
-    """Device->host half of a KV swap: pull ``idx``'s page slices out
-    of the pools (page axis 2 on data AND int8 scale leaves) in one
-    program; the caller device_gets the result.  NOT donated — the
-    pools stay resident."""
-    return jax.tree.map(
-        lambda x: jnp.take(x, idx, axis=2), (k_pages, v_pages)
-    )
-
-
-@functools.partial(jax.jit, donate_argnames=("k_pages", "v_pages"))
-def _scatter_swap_pages(k_pages, v_pages, idx, k_data, v_data):
-    """Host->device half: scatter saved page content back into freshly
-    allocated pages.  Duplicate padding indices all target trash page
-    0, whose content is never read."""
-    put = lambda x, d: x.at[:, :, idx].set(d)
-    return (
-        jax.tree.map(put, k_pages, k_data),
-        jax.tree.map(put, v_pages, v_data),
-    )
 
 
 class _DeviceSwapExecutor:
@@ -368,265 +214,6 @@ class _DeviceSwapExecutor:
             )
 
 
-def _decode_step(
-    params, spec: ModelSpec, tokens, positions, k_pages, v_pages,
-    page_tables, active, temps, top_ps, top_ks, base_key, counter,
-    use_pallas=False, mesh=None,
-):
-    """One decode step — thin wrapper over ``_decode_chunk(num_steps=1)``
-    kept for single-step callers (e.g. __graft_entry__.dryrun_multichip)."""
-    (
-        chunk_tokens, _lp, _tokens, positions, counter, _steps, _counts,
-        k_pages, v_pages, _flags,
-    ) = _decode_chunk(
-        params, spec, tokens, positions, k_pages, v_pages, page_tables,
-        active, temps, top_ps, top_ks, base_key, counter,
-        num_steps=1, use_pallas=use_pallas, mesh=mesh,
-    )
-    return chunk_tokens[0], positions, counter, k_pages, v_pages
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("spec", "num_steps", "use_pallas", "max_position",
-                     "mesh", "num_logprobs", "all_greedy", "guard",
-                     "guard_threshold"),
-    donate_argnames=("k_pages", "v_pages", "counts", "state"),
-)
-def _decode_chunk(
-    params, spec: ModelSpec, tokens, positions, k_pages, v_pages,
-    page_tables, active, temps, top_ps, top_ks, base_key, counter,
-    num_steps: int = 1, use_pallas=False, max_position: int = 0,
-    seeds=None, steps=None, mesh=None, num_logprobs: int = 0,
-    counts=None, freq_pens=None, pres_pens=None,
-    min_toks=None, stop_id_mat=None, all_greedy: bool = False,
-    bias_ids=None, bias_vals=None, guard: bool = False,
-    guard_threshold: float = 1.0e4, state=None,
-):
-    """``num_steps`` decode steps fused into one device program.
-
-    The host reads sampled tokens once per *chunk* instead of once per
-    step (fewer dispatches and readbacks).  EOS /
-    max_tokens are detected on the host after readback; steps a sequence ran
-    past its stopping point are discarded there, and their KV writes land in
-    pages the scheduler reserved for the horizon (harmless: the sequence is
-    removed and its pages freed).  Returns ``chunk_tokens`` of shape
-    ``[num_steps, B]`` plus the threaded device state.
-
-    ``guard`` (integrity.logit_guard) additionally computes a per-step
-    per-slot sentinel flag word over the RAW model logits — before
-    penalties/bias/min-token suppression, whose deliberate -inf writes
-    must not trip the NaN/Inf check — returned as ``[num_steps, B]``
-    uint8 (integrity.logit_guard flag bits).  Static, so the guard-off
-    program is byte-identical to the pre-integrity one.
-
-    ``state`` (a spec with recurrent layers) rides the scan's carry
-    beside the pools, rows of active slots updated in place; the result
-    then ends ``..., chunk_flags, state, moe_stats`` with ``moe_stats``
-    ``[num_steps, 4]`` int32, the expert layers' device counters summed
-    over the layers of each step (ops/moe.py STAT_NAMES), read back
-    with the chunk's tokens.
-    """
-
-    if steps is None:
-        steps = jnp.zeros_like(positions)
-
-    def body(carry, _):
-        (tokens, positions, counter, steps, counts, k_pages, v_pages,
-         state) = carry
-        key = jax.random.fold_in(base_key, counter)
-        logits, k_pages, v_pages, *more = decode_forward(
-            params, spec, tokens, positions, k_pages, v_pages, page_tables,
-            active=active, use_pallas=use_pallas, mesh=mesh,
-            **_state_kw(state),
-        )
-        if more:
-            state, moe_stats = more
-        if guard:
-            step_flags = integrity.logit_guard(logits, guard_threshold)
-        with jax.named_scope("sample"):
-            if counts is not None:
-                # frequency/presence penalties over the generated-token
-                # histogram (ops/sampling.py apply_penalties)
-                logits = apply_penalties(
-                    logits, counts, freq_pens, pres_pens
-                )
-            if bias_ids is not None:
-                logits = apply_logit_bias(logits, bias_ids, bias_vals)
-            if min_toks is not None:
-                logits = suppress_stop_tokens(
-                    logits, steps, min_toks, stop_id_mat
-                )
-            if num_logprobs > 0:
-                next_tokens, lp, tids, tlps = sample_tokens_with_logprobs(
-                    logits, temps, top_ps, top_ks, key, seeds=seeds,
-                    steps=steps, num_top=num_logprobs,
-                )
-                ys = (next_tokens, lp, tids, tlps)
-            else:
-                next_tokens = sample_tokens(
-                    logits, temps, top_ps, top_ks, key, seeds=seeds,
-                    steps=steps, all_greedy=all_greedy,
-                )
-                ys = (next_tokens,)
-        if guard:
-            ys = ys + (step_flags,)
-        if more:
-            ys = ys + (moe_stats,)
-        positions = positions + active.astype(positions.dtype)
-        steps = steps + active.astype(steps.dtype)
-        if counts is not None:
-            counts = counts.at[
-                jnp.arange(counts.shape[0]), next_tokens
-            ].add(active.astype(counts.dtype))
-        if max_position > 0:
-            # overshoot steps (chunk sized by MAX headroom across slots) must
-            # stay in-bounds: on the Pallas path seq_len = position+1 drives
-            # the page loop, and past max_pages the DMA reads are undefined
-            # rather than clamped like XLA gathers
-            positions = jnp.minimum(positions, max_position)
-        return (
-            next_tokens, positions, counter + 1, steps, counts,
-            k_pages, v_pages, state,
-        ), ys
-
-    carry, ys = jax.lax.scan(
-        body,
-        (tokens, positions, counter, steps, counts, k_pages, v_pages,
-         state),
-        None,
-        length=num_steps,
-    )
-    (tokens, positions, counter, steps, counts, k_pages, v_pages,
-     state) = carry
-    tail = ()
-    if state is not None:
-        tail, ys = (state, ys[-1]), ys[:-1]
-    # [num_steps, B] uint8 sentinel words when guarded (host ORs the
-    # step axis at readback), None otherwise
-    chunk_flags = ys[-1] if guard else None
-    if guard:
-        ys = ys[:-1]
-    chunk_tokens = ys[0]
-    # ([steps, B], [steps, B, K], [steps, B, K]) when logprobs, else None
-    chunk_lp = ys[1:] if num_logprobs > 0 else None
-    return (
-        chunk_tokens, chunk_lp, tokens, positions, counter, steps, counts,
-        k_pages, v_pages, chunk_flags, *tail,
-    )
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "spec", "use_pallas", "num_logprobs", "all_greedy", "mesh",
-    ),
-    donate_argnames=("k_pages", "v_pages"),
-)
-def _spec_verify_step(
-    params, spec: ModelSpec, tokens, positions0, input_lens, k_pages,
-    v_pages, page_tables, active, temps, top_ps, top_ks, base_key, counter,
-    seeds=None, steps=None, use_pallas=False, num_logprobs: int = 0,
-    counts=None, freq_pens=None, pres_pens=None,
-    min_toks=None, stop_id_mat=None, all_greedy: bool = False,
-    bias_ids=None, bias_vals=None, mesh=None,
-):
-    """One speculative round: score current token + drafts in a single
-    forward (models/decoder.py spec_verify_forward), then verify every
-    draft position with the per-slot sampling params — greedy slots by
-    exact argmax match, temperature>0 slots by distribution-preserving
-    rejection sampling (ops/sampling.py verify_and_sample: accept draft
-    t with prob p(t), resample from p minus t on rejection) — and count
-    accepted drafts on device.  Returns (model_toks [B, S], accepted
-    [B], caches)."""
-    from vgate_tpu.runtime.speculative import count_accepted
-
-    logits, k_pages, v_pages = spec_verify_forward(
-        params, spec, tokens, positions0, input_lens, k_pages, v_pages,
-        page_tables, active=active, use_pallas=use_pallas, mesh=mesh,
-    )  # [B, S, V]
-    B, S = tokens.shape
-    if counts is not None:
-        # position j's penalties include the drafts accepted before it
-        # (run 1..j); if draft j+1 is later rejected, position j+1's
-        # output is discarded anyway, so exactness holds for every token
-        # actually appended
-        run = counts
-        pen = []
-        for j in range(S):
-            pen.append(
-                apply_penalties(logits[:, j], run, freq_pens, pres_pens)
-            )
-            if j + 1 < S:
-                inc = ((j + 1) < input_lens) & active
-                run = run.at[jnp.arange(B), tokens[:, j + 1]].add(
-                    inc.astype(run.dtype)
-                )
-        logits = jnp.stack(pen, axis=1)
-    key = jax.random.fold_in(base_key, counter)
-    # one batched sampler over all (slot, position) rows — per-position
-    # step indices keep seeded reproducibility aligned with the token
-    # index, exactly like the decode chunk's per-step `steps` increment
-    rep = functools.partial(jnp.repeat, repeats=S, axis=0)
-    steps_flat = (
-        None
-        if steps is None
-        else (steps[:, None] + jnp.arange(S)[None, :]).reshape(-1)
-    )
-    if bias_ids is not None:
-        # per-slot biases apply at every candidate position
-        flat = apply_logit_bias(
-            logits.reshape(B * S, -1), rep(bias_ids), rep(bias_vals)
-        )
-        logits = flat.reshape(logits.shape)
-    if min_toks is not None:
-        assert steps_flat is not None, "min_tokens requires steps"
-        flat = suppress_stop_tokens(
-            logits.reshape(B * S, -1),
-            steps_flat,
-            rep(min_toks),
-            rep(stop_id_mat),
-        )
-        logits = flat.reshape(logits.shape)
-    # row (b, j) verifies draft tokens[b, j+1]; the row at input_len-1
-    # (and any garbage row past it) draws the plain bonus sample instead
-    draft_next = jnp.concatenate(
-        [tokens[:, 1:], jnp.zeros((B, 1), tokens.dtype)], axis=1
-    )
-    is_bonus = jnp.arange(S)[None, :] >= (input_lens[:, None] - 1)
-    flat_toks, _accept, lp_flat = verify_and_sample(
-        logits.reshape(B * S, -1),
-        draft_next.reshape(-1),
-        is_bonus.reshape(-1),
-        rep(temps), rep(top_ps), rep(top_ks), key,
-        seeds=None if seeds is None else rep(seeds),
-        steps=steps_flat,
-        num_top=num_logprobs,
-        all_greedy=all_greedy,
-    )
-    model_toks = flat_toks.reshape(B, S)
-    if num_logprobs > 0:
-        lp, tids, tlps = lp_flat
-        lp_data = (
-            lp.reshape(B, S),
-            tids.reshape(B, S, -1),
-            tlps.reshape(B, S, -1),
-        )
-    else:
-        lp_data = None
-    accepted = count_accepted(model_toks, tokens, input_lens)
-    if counts is not None:
-        # fold the tokens this round actually appends (accepted run +
-        # bonus) into the histogram on device
-        app = (
-            (jnp.arange(S)[None, :] <= accepted[:, None])
-            & active[:, None]
-        )
-        b_idx = jnp.broadcast_to(jnp.arange(B)[:, None], (B, S))
-        counts = counts.at[b_idx, model_toks].add(app.astype(counts.dtype))
-    return model_toks, accepted, lp_data, counts, k_pages, v_pages
-
-
 def rebuild_core(
     old: "EngineCore",
     config: VGTConfig,
@@ -659,7 +246,7 @@ def rebuild_core(
     old.state = None
     old._dec_state = None
     old._pending_chunks.clear()
-    old._spec_pen = None
+    old._spec_rows = None
     # the host swap pool dies with its core: every parked ticket's
     # epoch went stale when containment folded the owners, and the new
     # core builds a fresh (empty) pool — free the host RAM now rather
@@ -1266,8 +853,9 @@ class EngineCore:
         )
         self._base_key = jax.random.PRNGKey(self.config.model.max_model_len)
         self._step_counter = 0
-        self._compiled_buckets: set = set()
-        self._compiled_chunks: set = set()
+        # (family, variant key) of every program variant launched so
+        # far (_launch): a key not in it compiles at its first call
+        self._compiled: set = set()
         self._dec_state: Optional[Dict[str, Any]] = None
         self._decode_signature_cache: Optional[tuple] = None
         # in-flight decode chunks awaiting host readback:
@@ -1332,12 +920,11 @@ class EngineCore:
                 )
         self.total_spec_drafted = 0
         self.total_spec_accepted = 0
-        # device-resident penalty histogram for speculative mode, keyed
-        # by a membership signature (rebuilt from host token lists when
-        # membership changes; updated in-program otherwise)
-        self._spec_pen: Optional[Dict[str, Any]] = None
-        # membership-cached min-token arrays (immutable per sequence)
-        self._spec_mt: Optional[Dict[str, Any]] = None
+        # speculative mode's sampling rows (_sampling_rows) under a
+        # membership signature: rebuilt from host state when membership
+        # changes; in between only the device-resident penalty histogram
+        # moves (updated in-program) and each round's step indices
+        self._spec_rows: Optional[Dict[str, Any]] = None
 
         # sp>1: prefill attention runs sequence-parallel (ring attention
         # over the sp axis); buckets must then split evenly across shards.
@@ -1518,10 +1105,6 @@ class EngineCore:
         self._max_resume_attempts = max(
             0, int(self.config.recovery.max_resume_attempts)
         )
-        # first-dispatch tracking for spec-verify program variants (the
-        # prefill/decode ladders have their own sets): heartbeat
-        # compile-grace only — spec rounds recompile on width changes
-        self._compiled_spec: set = set()
         # flight snapshot taken on the dying engine thread, while the
         # crashed tick's residents are still live (supervisor reads it)
         self._crash_snapshot: Optional[Dict[str, Any]] = None
@@ -2592,15 +2175,6 @@ class EngineCore:
                 ):
                     seq.request_abort(reason)
 
-    @staticmethod
-    def _all_greedy(seqs, num_lp: int) -> bool:
-        """Static all-greedy program-variant predicate, shared by the
-        decode-chunk and spec-verify dispatches (one definition so the
-        compile-cache split can never diverge between paths)."""
-        return num_lp == 0 and all(
-            s.params.temperature == 0.0 for s in seqs
-        )
-
     # ------------------------------------------------------------- prefill
 
     @engine_thread_only
@@ -2697,13 +2271,9 @@ class EngineCore:
         for (bucket, cached, unaligned), group in sorted(by_bucket.items()):
             for i in range(0, len(group), batch_max):
                 chunk = group[i : i + batch_max]
-                if cached:
-                    handle = self._dispatch_suffix_group(
-                        chunk, bucket, unaligned=unaligned
-                    )
-                else:
-                    handle = self._dispatch_prefill_group(chunk, bucket)
-                dispatched.append((chunk, handle))
+                dispatched.append((chunk, self._dispatch_prompt(
+                    chunk, bucket, cached=cached, unaligned=unaligned
+                )))
         # index the freshly-filled prompt pages only now, with every
         # writer program enqueued: a reader admitted in a LATER tick is
         # guaranteed to dispatch after the writer (device program order).
@@ -2921,374 +2491,306 @@ class EngineCore:
             request_id=seq.request_id,
         )
 
-    @engine_thread_only
-    def _penalty_arrays(self, B: int, rows):
-        """Build (counts [B, V] uint16, freq [B], pres [B]) device arrays
-        from ``rows`` = iterable of (row_index, Sequence) — the one
-        histogram constructor shared by prefill groups, the decode state
-        and the speculative round (callers decide gating/row mapping)."""
-        counts = np.zeros((B, self.spec.vocab_size), np.uint16)
-        freq = np.zeros((B,), np.float32)
-        pres = np.zeros((B,), np.float32)
-        for row, seq in rows:
-            freq[row] = seq.params.frequency_penalty
-            pres[row] = seq.params.presence_penalty
-            if seq.generated_ids:
-                # histogram over everything generated (generated_ids
-                # survives preemption folds, matching OpenAI's "tokens
-                # generated so far")
-                np.add.at(
-                    counts[row], np.asarray(seq.generated_ids, np.int64), 1
-                )
-        return jnp.asarray(counts), jnp.asarray(freq), jnp.asarray(pres)
+    # what differs between the launches, by the program's name in the
+    # compile ledger and on the heartbeat: (family whose variants share
+    # one "compiled" set and one vgt_engine_compilations label, span,
+    # what makes a new variant).  A chunk of a long prompt runs the
+    # suffix program, so its variants are a suffix group's.
+    _PROGRAMS = {
+        "prefill": ("prefill", "prefill_dispatch", "bucket"),
+        "suffix_prefill": ("prefill", "prefill_dispatch", "bucket"),
+        "chunked_prefill": ("prefill", "prefill_dispatch", "ctx_width"),
+        "decode": ("decode", "decode_dispatch", "chunk_variant"),
+        "spec_verify": ("spec_verify", "decode_dispatch", "spec_width"),
+    }
 
+    @contextlib.contextmanager
     @engine_thread_only
-    def _min_token_arrays(self, B: int, rows):
-        """(min_toks [B], stop_id_mat [B, K]) device arrays, or
-        (None, None) when no row sets min_tokens.  Each row's stop set is
-        the model stop set plus its request stop_token_ids; padding uses
-        an out-of-vocab id (scatter drops it).  K buckets to a power of
-        two so the program-variant count stays bounded."""
-        rows = list(rows)
-        if not any(seq.params.min_tokens > 0 for _, seq in rows):
-            return None, None
-        base = [self.tokenizer.eos_id, *self.spec.extra_stop_ids]
-        # only floor rows ever have their ids scattered, so only they
-        # size K (a zero-floor neighbour with many stop_token_ids must
-        # not widen the matrix and fork extra compiled variants)
-        per = {
-            row: base + list(seq.params.stop_token_ids or [])
-            for row, seq in rows
-            if seq.params.min_tokens > 0
-        }
-        K = max(len(v) for v in per.values())
-        K = 1 << (max(1, K) - 1).bit_length()
-        V = self.spec.vocab_size
-        mat = np.full((B, K), V, np.int32)
-        min_toks = np.zeros((B,), np.int32)
-        for row, seq in rows:
-            if row not in per:
-                continue  # zero floor: never suppressed, ids irrelevant
-            ids = per[row]  # K = next_pow2(max floor-row len)
-            mat[row, : len(ids)] = ids
-            min_toks[row] = seq.params.min_tokens
-        return jnp.asarray(min_toks), jnp.asarray(mat)
-
-    @engine_thread_only
-    def _logit_bias_arrays(self, B: int, rows):
-        """(bias_ids [B, K] int32, bias_vals [B, K] f32) device arrays,
-        or (None, None) when no row carries a logit_bias.  Padding uses
-        an out-of-vocab id (scatter-add drops it); K buckets to a power
-        of two so the program-variant count stays bounded — the same
-        discipline as _min_token_arrays."""
-        per = {
-            row: seq.params.logit_bias
-            for row, seq in rows
-            if seq.params.logit_bias
-        }
-        if not per:
-            return None, None
-        K = 1 << (max(len(v) for v in per.values()) - 1).bit_length()
-        V = self.spec.vocab_size
-        ids = np.full((B, K), V, np.int32)
-        vals = np.zeros((B, K), np.float32)
-        for row, items in per.items():
-            for j, (tid, b) in enumerate(sorted(items.items())):
-                ids[row, j] = tid
-                vals[row, j] = b
-        return jnp.asarray(ids), jnp.asarray(vals)
-
-    @engine_thread_only
-    def _group_penalties(self, plans: List[PrefillPlan], B: int):
-        """Penalty arrays for a prefill group, or (None, None, None).
-        Counts only matter when a penalized plan already generated tokens
-        (post-preemption re-prefill) — an all-zero histogram is a
-        mathematical no-op, so fresh prompts skip the upload and the
-        counts program variant entirely."""
-        if not any(
-            p.seq.params.has_penalties and p.seq.generated_ids
-            for p in plans
-        ):
-            return None, None, None
-        return self._penalty_arrays(
-            B, ((row, p.seq) for row, p in enumerate(plans))
-        )
-
-    @engine_thread_only
-    def _dispatch_prefill_group(self, plans: List[PrefillPlan], bucket: int):
-        """Launch ONE prefill program for up to prefill_batch_max same-
-        bucket sequences; returns the (async) [B] first-token device array.
-        B pads to a power of two so the compile ladder stays small
-        ({1,2,4,...,prefill_batch_max} x buckets); padding rows use trash
-        page tables, temp 0 and seq_len 1 — their sampled tokens are
-        discarded at readback."""
-        n = len(plans)
-        B = 1 << (n - 1).bit_length()  # next power of two
-        with self.perf.span("state", lambda: {"rows": B}):
-            ps = self.geometry.page_size
-            n_bucket_pages = bucket // ps
-            tokens = np.zeros((B, bucket), np.int32)
-            seq_lens = np.ones((B,), np.int32)
-            prefill_pt = np.zeros((B, n_bucket_pages), np.int32)
-            temps = np.zeros((B,), np.float32)
-            top_ps = np.ones((B,), np.float32)
-            top_ks = np.zeros((B,), np.int32)
-            seeds = np.full((B,), -1, np.int32)
-            steps = np.zeros((B,), np.int32)
-            for row, plan in enumerate(plans):
-                seq = plan.seq
-                n_prompt = seq.num_prompt_tokens
-                tokens[row, :n_prompt] = seq.prompt_ids
-                seq_lens[row] = n_prompt
-                # decode-side page table row: real pages then trash padding
-                slot_row = self._page_tables_np[plan.slot]
-                slot_row[:] = 0
-                slot_row[: len(seq.pages)] = seq.pages
-                prefill_pt[row, : len(seq.pages)] = seq.pages[:n_bucket_pages]
-                sp = seq.params
-                temps[row] = sp.temperature
-                top_ps[row] = sp.top_p
-                top_ks[row] = sp.top_k
-                if sp.seed is not None:
-                    # token i always draws from (seed, i): the prefill samples
-                    # token index num_generated (0 fresh, >0 after preemption)
-                    seeds[row] = sp.seed
-                steps[row] = seq.num_generated
-            pen_counts, pen_freq, pen_pres = self._group_penalties(plans, B)
-            mt, mt_ids = self._min_token_arrays(
-                B, ((row, p.seq) for row, p in enumerate(plans))
-            )
-            lb_ids, lb_vals = self._logit_bias_arrays(
-                B, ((row, p.seq) for row, p in enumerate(plans))
-            )
-        num_lp = (
-            LOGPROBS_K
-            if any(p.seq.params.logprobs for p in plans)
-            else 0
-        )
-        key = (
-            bucket, B, pen_counts is not None,
-            None if mt is None else mt_ids.shape[1], num_lp,
-            None if lb_ids is None else lb_ids.shape[1],
-        )
-        fresh = key not in self._compiled_buckets
+    def _launch(
+        self, program: str, key: tuple, seqs, attention, span_args,
+        **fields,
+    ):
+        """THE bracket around a step program's call: ``with
+        self._launch(...): <the jitted call>``.  A variant ``key`` not
+        launched before compiles inside the call, so it is counted, put
+        on the flight recorder and on the rows' request traces, its
+        attention implementation (``attention`` = name, impl()) noted,
+        the heartbeat given the compile grace, and the span's seconds
+        entered in the compile ledger as the compile's cost.  ``fields``
+        (bucket or chunk, batch) go to the heartbeat and those records."""
+        family, span, trigger = self._PROGRAMS[program]
+        fresh = (family, key) not in self._compiled
         if fresh:
-            metrics.RECOMPILES.labels(kind="prefill").inc()
-            self._compiled_buckets.add(key)
-            self.flight.record_tick(
-                "recompile", program="prefill", bucket=bucket, batch=B
-            )
-            self._note_attention("prefill", prefill_attention_impl(
-                self.spec, self.use_pallas, self._attn_mesh
-            ))
-            for plan in plans:
-                if plan.seq.trace is not None:
-                    plan.seq.trace.event("xla_compile", bucket=bucket)
-        self._beat("prefill", compiling=fresh, bucket=bucket, batch=B)
-        with self.perf.span(
-            "prefill_dispatch",
-            lambda: {
-                "program": "prefill", "bucket": bucket, "rows": B,
-                "ctx_tokens": sum(p.seq.num_prompt_tokens for p in plans),
-            },
-        ) as disp:
-            out, *cache = _prefill_step(
-                self.params,
-                self.spec,
-                jnp.asarray(tokens),
-                jnp.asarray(seq_lens),
-                self.k_pages,
-                self.v_pages,
-                jnp.asarray(prefill_pt),
-                jnp.asarray(temps),
-                jnp.asarray(top_ps),
-                jnp.asarray(top_ks),
-                self._step_key(),
-                mesh=self._attn_mesh,
-                use_pallas=self.use_pallas,
-                seeds=jnp.asarray(seeds),
-                steps=jnp.asarray(steps),
-                num_logprobs=num_lp,
-                counts=pen_counts,
-                freq_pens=pen_freq,
-                pres_pens=pen_pres,
-                min_toks=mt,
-                stop_id_mat=mt_ids,
-                bias_ids=lb_ids,
-                bias_vals=lb_vals,
-                **self._state_args(self._prompt_slots(plans, B)),
-            )
-            self._set_cache(cache)
+            self._compiled.add((family, key))
+            metrics.RECOMPILES.labels(kind=family).inc()
+            self.flight.record_tick("recompile", program=program, **fields)
+            name, impl = attention
+            self._attention.setdefault(name, set()).add(impl())
+            for seq in seqs:
+                if seq.trace is not None:
+                    seq.trace.event("xla_compile", **fields)
+        self._beat(program, compiling=fresh, **fields)
+        # the jitted call's return is trace+enqueue; a fresh variant's
+        # call also compiles synchronously, so its duration IS the
+        # compile cost the ledger records
+        with self.perf.span(span, span_args) as disp:
+            yield
         if fresh:
             self.perf.record_compile(
-                "prefill", key, disp.seconds, trigger="bucket"
+                program, key, disp.seconds, trigger=trigger
             )
-        return out  # (first tokens [B], logprob triple or None)
 
-    @staticmethod
     @engine_thread_only
-    def _suffix_key(
-        bucket, B, ctx_pages, has_pen, mt_width, num_lp, lb_width,
-        unaligned=False,
-    ):
-        """Compile-variant key for one _suffix_prefill_step shape — the
-        single definition both the batched suffix-group dispatch and
-        the chunked-prefill loop count RECOMPILES against."""
-        return (
-            "suffix", bucket, B, ctx_pages, has_pen, mt_width, num_lp,
-            lb_width, unaligned,
+    def _sampling_rows(self, B: int, rows) -> Dict[str, Any]:
+        """The step programs' per-row sampling arguments, as device
+        arrays under the programs' own argument names, from ``rows`` =
+        (row index, Sequence) pairs in ONE pass; rows not named keep the
+        padding defaults (temperature 0, top_p 1, top_k 0, no seed, step
+        0).  The three optional groups are None unless a row needs them,
+        so that a batch without them runs the program variant without:
+
+        * ``counts`` [B, V] uint16 with ``freq_pens`` / ``pres_pens``:
+          only when a penalised row has generated tokens (a decoding row
+          always has; a fresh prompt's all-zero histogram is a no-op, a
+          re-prefill after a preemption still counts what it folded);
+        * ``min_toks`` [B] with ``stop_id_mat`` [B, K]: a floor row's
+          stop set is the model's plus its request's ``stop_token_ids``;
+        * ``bias_ids`` / ``bias_vals`` [B, K]: ``logit_bias``.
+
+        Both K bucket to a power of two (bounded variant count) and pad
+        with an out-of-vocab id, which the scatters drop.  With them the
+        two static arguments the rows decide, ``num_logprobs`` (a row
+        asked for them) and ``all_greedy`` (no row samples and none wants
+        logprobs: the decode and verify programs' argmax variant), and
+        ``variant``: what of all this forks a compiled program, the
+        part of a variant key the rows decide (penalties, width of the
+        stop-id matrix, logprobs, argmax, width of the bias matrix)."""
+        temps = np.zeros((B,), np.float32)
+        top_ps = np.ones((B,), np.float32)
+        top_ks = np.zeros((B,), np.int32)
+        seeds = np.full((B,), -1, np.int32)
+        steps = np.zeros((B,), np.int32)
+        rows = list(rows)
+        penalised = want_lp = sampled = False
+        floors: list = []
+        biased: list = []
+        for row, seq in rows:
+            sp = seq.params
+            temps[row] = sp.temperature
+            top_ps[row] = sp.top_p
+            top_ks[row] = sp.top_k
+            if sp.seed is not None:
+                # token i always draws from (seed, i): a prompt pass
+                # samples token index num_generated (0 fresh, >0 after
+                # a preemption)
+                seeds[row] = sp.seed
+            steps[row] = seq.num_generated
+            if sp.temperature != 0.0:
+                sampled = True
+            if sp.logprobs:
+                want_lp = True
+            if sp.has_penalties and seq.generated_ids:
+                penalised = True
+            if sp.min_tokens > 0:
+                # only floor rows ever have their ids scattered, so only
+                # they size K (a zero-floor neighbour with many
+                # stop_token_ids must not fork extra compiled variants)
+                floors.append((row, sp))
+            if sp.logit_bias:
+                biased.append((row, sp.logit_bias))
+        out: Dict[str, Any] = {
+            "temps": jnp.asarray(temps),
+            "top_ps": jnp.asarray(top_ps),
+            "top_ks": jnp.asarray(top_ks),
+            "seeds": jnp.asarray(seeds),
+            "steps": jnp.asarray(steps),
+            "counts": None, "freq_pens": None, "pres_pens": None,
+            "min_toks": None, "stop_id_mat": None,
+            "bias_ids": None, "bias_vals": None,
+            "num_logprobs": LOGPROBS_K if want_lp else 0,
+            "all_greedy": not (want_lp or sampled),
+        }
+        V = self.spec.vocab_size
+        mt_width = lb_width = None
+        if penalised:
+            counts = np.zeros((B, V), np.uint16)
+            freq = np.zeros((B,), np.float32)
+            pres = np.zeros((B,), np.float32)
+            for row, seq in rows:
+                freq[row] = seq.params.frequency_penalty
+                pres[row] = seq.params.presence_penalty
+                if seq.generated_ids:
+                    # histogram over everything generated (generated_ids
+                    # survives preemption folds, matching OpenAI's
+                    # "tokens generated so far")
+                    np.add.at(
+                        counts[row],
+                        np.asarray(seq.generated_ids, np.int64), 1,
+                    )
+            out["counts"] = jnp.asarray(counts)
+            out["freq_pens"] = jnp.asarray(freq)
+            out["pres_pens"] = jnp.asarray(pres)
+        if floors:
+            base = [self.tokenizer.eos_id, *self.spec.extra_stop_ids]
+            stops = [
+                base + list(sp.stop_token_ids or []) for _, sp in floors
+            ]
+            mt_width = 1 << (max(map(len, stops)) - 1).bit_length()
+            mat = np.full((B, mt_width), V, np.int32)
+            min_toks = np.zeros((B,), np.int32)
+            for (row, sp), stop_ids in zip(floors, stops):
+                mat[row, : len(stop_ids)] = stop_ids
+                min_toks[row] = sp.min_tokens
+            out["min_toks"] = jnp.asarray(min_toks)
+            out["stop_id_mat"] = jnp.asarray(mat)
+        if biased:
+            lb_width = 1 << (
+                max(len(b) for _, b in biased) - 1
+            ).bit_length()
+            ids = np.full((B, lb_width), V, np.int32)
+            vals = np.zeros((B, lb_width), np.float32)
+            for row, items in biased:
+                for j, (tid, b) in enumerate(sorted(items.items())):
+                    ids[row, j] = tid
+                    vals[row, j] = b
+            out["bias_ids"] = jnp.asarray(ids)
+            out["bias_vals"] = jnp.asarray(vals)
+        out["variant"] = (
+            penalised, mt_width, out["num_logprobs"], out["all_greedy"],
+            lb_width,
         )
+        return out
 
     @engine_thread_only
-    def _dispatch_suffix_group(
-        self, plans: List[PrefillPlan], bucket: int, unaligned: bool = False
+    def _dispatch_prompt(
+        self, plans: List[PrefillPlan], bucket: int, cached: bool = False,
+        unaligned: bool = False, upto: Optional[int] = None,
     ):
-        """Launch ONE suffix-prefill program for up to prefill_batch_max
-        prefix-cache hits whose suffix lengths share a bucket.  The cached
-        prefix pages are read-only shared KV; only the suffix pages are
-        written.  ``unaligned`` is the COW group: each plan's page copy
-        is dispatched first (device program order guarantees the copy
-        reads the source before any later program could reuse it), the
-        suffix then starts mid-page and the suffix table carries one
-        extra column.  Returns the (async) [B] first-token device array."""
-        n = len(plans)
-        B = 1 << (n - 1).bit_length()
+        """Launch ONE prompt-pass program for up to prefill_batch_max
+        plans whose uncached lengths share ``bucket``; returns the
+        (async) result ``(first tokens [B], logprob triple or None)``.
+        B pads to a power of two so the compile ladder stays small
+        ({1,2,4,...,prefill_batch_max} x buckets); padding rows use
+        trash page tables, temperature 0 and length 1, and their sampled
+        tokens are discarded at readback.  Three layouts:
+
+        * fresh prompts (``cached`` False): ``_prefill_step`` over the
+          bucket's page table;
+        * prefix-cache hits: ``_suffix_prefill_step`` over the uncached
+          suffix.  The cached prefix pages are read-only shared KV; only
+          the suffix pages are written, and the pass attends the whole
+          context through a second table.  ``unaligned`` is the
+          copy-on-write group: each plan's page copy is dispatched first
+          (device program order guarantees the copy reads the source
+          before any later program could reuse it), the suffix then
+          starts mid-page and its table carries one extra column;
+        * a non-final chunk of a long prompt (``upto``: the one plan's
+          pass stops at that prompt position): the suffix program again,
+          with the padding row's sampling and no extras, since its
+          sample is discarded."""
+        B = 1 << (len(plans) - 1).bit_length()  # next power of two
         ps = self.geometry.page_size
-        n_suffix_pages = bucket // ps + (1 if unaligned else 0)
+        n_own_pages = bucket // ps + (1 if unaligned else 0)
+        seqs = [plan.seq for plan in plans]
         with self.perf.span("state", lambda: {"rows": B}):
             # copy-on-write: duplicate the shared head of each diverging
             # page into the sequence's own first page BEFORE the suffix
             # program that writes the rest of that page
             for plan in plans:
                 if plan.cow is not None:
-                    src, dst, upto = plan.cow
+                    src, dst, n_shared = plan.cow
                     self.k_pages, self.v_pages = _cow_copy_pages(
                         self.k_pages, self.v_pages,
                         jnp.asarray(src, jnp.int32),
                         jnp.asarray(dst, jnp.int32),
-                        jnp.asarray(upto, jnp.int32),
+                        jnp.asarray(n_shared, jnp.int32),
                     )
                     if self.radix_cache is not None:
                         self.radix_cache.total_cow_copies += 1
                     metrics.PREFIX_COW_COPIES.inc()
-            # context window bucketed to a power of two of pages: bounds both
-            # the KV gather and the compile-variant count
-            max_ctx_pages = max(
-                cdiv(p.seq.num_prompt_tokens, ps) for p in plans
-            )
+            ends = [upto or seq.num_prompt_tokens for seq in seqs]
+            # context window bucketed to a power of two of pages: bounds
+            # both the KV gather and the compile-variant count
             ctx_pages = min(
                 self.geometry.pages_per_seq,
-                1 << max(0, max_ctx_pages - 1).bit_length(),
+                1 << max(0, max(cdiv(end, ps) for end in ends) - 1)
+                .bit_length(),
             )
             tokens = np.zeros((B, bucket), np.int32)
             prefix_lens = np.zeros((B,), np.int32)
-            suffix_lens = np.ones((B,), np.int32)
-            suffix_pt = np.zeros((B, n_suffix_pages), np.int32)
-            full_pt = np.zeros((B, ctx_pages), np.int32)
-            temps = np.zeros((B,), np.float32)
-            top_ps = np.ones((B,), np.float32)
-            top_ks = np.zeros((B,), np.int32)
-            seeds = np.full((B,), -1, np.int32)
-            steps = np.zeros((B,), np.int32)
-            for row, plan in enumerate(plans):
+            lens = np.ones((B,), np.int32)
+            own_pt = np.zeros((B, n_own_pages), np.int32)
+            ctx_pt = np.zeros((B, ctx_pages), np.int32)
+            # row -> decode slot, for a spec with recurrent layers;
+            # padding rows point past the state (their update is dropped)
+            slots = np.full((B,), self.max_slots, np.int32)
+            for row, (plan, end) in enumerate(zip(plans, ends)):
                 seq = plan.seq
-                cached_pages = plan.cached_len // ps
-                suffix = seq.prompt_ids[plan.cached_len :]
-                tokens[row, : len(suffix)] = suffix
+                part = seq.prompt_ids[plan.cached_len : end]
+                tokens[row, : len(part)] = part
                 prefix_lens[row] = plan.cached_len
-                suffix_lens[row] = len(suffix)
-                own = seq.pages[cached_pages:]
-                suffix_pt[row, : len(own)] = own[:n_suffix_pages]
+                lens[row] = len(part)
+                own = seq.pages[plan.cached_len // ps :]
+                own_pt[row, : len(own)] = own[:n_own_pages]
+                # decode-side page table row: real pages then trash
+                # padding
                 slot_row = self._page_tables_np[plan.slot]
                 slot_row[:] = 0
                 slot_row[: len(seq.pages)] = seq.pages
-                full_pt[row, : len(seq.pages)] = seq.pages[:ctx_pages]
-                sp = seq.params
-                temps[row] = sp.temperature
-                top_ps[row] = sp.top_p
-                top_ks[row] = sp.top_k
-                if sp.seed is not None:
-                    seeds[row] = sp.seed
-                steps[row] = seq.num_generated
-            pen_counts, pen_freq, pen_pres = self._group_penalties(plans, B)
-            mt, mt_ids = self._min_token_arrays(
-                B, ((row, p.seq) for row, p in enumerate(plans))
+                ctx_pt[row, : len(seq.pages)] = seq.pages[:ctx_pages]
+                slots[row] = plan.slot
+            # the program's keyword arguments: the sampling rows' and,
+            # below, the layout's
+            kw = self._sampling_rows(
+                B, enumerate(seqs) if upto is None else ()
             )
-            lb_ids, lb_vals = self._logit_bias_arrays(
-                B, ((row, p.seq) for row, p in enumerate(plans))
+            temps, top_ps, top_ks = (
+                kw.pop(k) for k in ("temps", "top_ps", "top_ks")
             )
-        num_lp = (
-            LOGPROBS_K
-            if any(p.seq.params.logprobs for p in plans)
-            else 0
-        )
-        key = self._suffix_key(
-            bucket, B, ctx_pages, pen_counts is not None,
-            None if mt is None else mt_ids.shape[1], num_lp,
-            None if lb_ids is None else lb_ids.shape[1],
-            unaligned=unaligned,
-        )
-        fresh = key not in self._compiled_buckets
-        if fresh:
-            metrics.RECOMPILES.labels(kind="prefill").inc()
-            self._compiled_buckets.add(key)
-            self.flight.record_tick(
-                "recompile", program="suffix_prefill", bucket=bucket,
-                batch=B,
-            )
-            self._note_attention(
+            # a prompt program has no argmax variant (_sample_first)
+            pen, mt_width, num_lp, _, lb_width = kw.pop("variant")
+            del kw["all_greedy"]
+        # what the layouts differ in
+        if cached:
+            program = "suffix_prefill" if upto is None else "chunked_prefill"
+            key = ("suffix", bucket, B, ctx_pages, pen, mt_width, num_lp,
+                   lb_width, unaligned)
+            step, mesh = _suffix_prefill_step, self._mt_mesh
+            lens_args, tables = (prefix_lens, lens), (own_pt, ctx_pt)
+            kw["unaligned"] = unaligned
+            attention = (
                 "suffix_cow" if unaligned else "suffix",
-                multitok_attention_impl(
-                    self.use_pallas, self._mt_mesh, rows=bucket,
-                    unaligned=unaligned,
+                lambda: multitok_attention_impl(
+                    self.use_pallas, mesh, rows=bucket, unaligned=unaligned
                 ),
             )
-            for plan in plans:
-                if plan.seq.trace is not None:
-                    plan.seq.trace.event("xla_compile", bucket=bucket)
-        self._beat("prefill", compiling=fresh, bucket=bucket, batch=B)
-        with self.perf.span(
-            "prefill_dispatch",
+        else:
+            program = "prefill"
+            key = (bucket, B, pen, mt_width, num_lp, lb_width)
+            step, mesh = _prefill_step, self._attn_mesh
+            lens_args, tables = (lens,), (own_pt,)
+            attention = ("prefill", lambda: prefill_attention_impl(
+                self.spec, self.use_pallas, mesh
+            ))
+        with self._launch(
+            program, key, seqs, attention,
             lambda: {
-                "program": "suffix_prefill", "bucket": bucket, "rows": B,
-                "ctx_tokens": sum(p.seq.num_prompt_tokens for p in plans),
+                "program": program, "bucket": bucket, "rows": B,
+                "ctx_tokens": sum(ends),
             },
-        ) as disp:
-            out, *cache = _suffix_prefill_step(
-                self.params,
-                self.spec,
-                jnp.asarray(tokens),
-                jnp.asarray(prefix_lens),
-                jnp.asarray(suffix_lens),
-                self.k_pages,
-                self.v_pages,
-                jnp.asarray(suffix_pt),
-                jnp.asarray(full_pt),
-                jnp.asarray(temps),
-                jnp.asarray(top_ps),
-                jnp.asarray(top_ks),
-                self._step_key(),
-                seeds=jnp.asarray(seeds),
-                steps=jnp.asarray(steps),
-                num_logprobs=num_lp,
-                counts=pen_counts,
-                freq_pens=pen_freq,
-                pres_pens=pen_pres,
-                min_toks=mt,
-                stop_id_mat=mt_ids,
-                bias_ids=lb_ids,
-                bias_vals=lb_vals,
-                use_pallas=self.use_pallas,
-                mesh=self._mt_mesh,
-                unaligned=unaligned,
-                **self._state_args(self._prompt_slots(plans, B)),
+            bucket=bucket, batch=B,
+        ):
+            out, *cache = step(
+                self.params, self.spec, jnp.asarray(tokens),
+                *map(jnp.asarray, lens_args),
+                self.k_pages, self.v_pages,
+                *map(jnp.asarray, tables),
+                temps, top_ps, top_ks, self._step_key(),
+                mesh=mesh, use_pallas=self.use_pallas,
+                **kw, **self._state_args(slots),
             )
             self._set_cache(cache)
-        if fresh:
-            self.perf.record_compile(
-                "suffix_prefill", key, disp.seconds, trigger="bucket"
-            )
-        return out  # (first tokens [B], logprob triple or None)
+        return out
 
     @engine_thread_only
     def _dispatch_chunked_prefill(self, plan: PrefillPlan):
@@ -3298,100 +2800,28 @@ class EngineCore:
         each attending the full resident context.  Long prompts never
         compile a max_model_len-wide program — an 8k prompt at a 1k cap
         is eight dispatches of the SAME compiled 1k-suffix program.
-        Only the final chunk's sampled token is real (earlier chunks'
-        samples are discarded); the final chunk carries the request's
-        sampling extras.  Returns the (async) ([1] tokens, lp) handle of
-        the final chunk."""
+        Only the final chunk's sampled token is real and only it carries
+        the request's sampling surface: it is exactly a one-row suffix
+        group.  Returns the (async) ([1] tokens, lp) handle of the final
+        chunk."""
         seq = plan.seq
-        ps = self.geometry.page_size
         chunk = plan.bucket  # page-aligned (scheduler buckets are)
         total = seq.num_prompt_tokens
-        slot_row = self._page_tables_np[plan.slot]
-        slot_row[:] = 0
-        slot_row[: len(seq.pages)] = seq.pages
         start = plan.cached_len  # page-aligned (full cached pages)
-        # non-final chunks: lean suffix dispatches (temp 0, no sampling
-        # extras — every sampled token here is discarded)
+
+        def part(bucket: int) -> List[PrefillPlan]:
+            return [PrefillPlan(
+                seq=seq, slot=plan.slot, bucket=bucket, cached_len=start,
+                register_hashes=None,
+            )]
+
         while total - start > chunk:
-            n = chunk
-            start_page = start // ps
-            tokens = np.zeros((1, chunk), np.int32)
-            tokens[0] = seq.prompt_ids[start : start + n]
-            suffix_pt = np.asarray(
-                seq.pages[start_page : start_page + chunk // ps],
-                np.int32,
-            )[None]
-            # context window bucketed to the next power of two of pages
-            # (bounds compile variants exactly like _dispatch_suffix_group)
-            ctx_pages = min(
-                self.geometry.pages_per_seq,
-                1 << max(0, cdiv(start + n, ps) - 1).bit_length(),
+            self._dispatch_prompt(
+                part(chunk), chunk, cached=True, upto=start + chunk
             )
-            full_pt = np.zeros((1, ctx_pages), np.int32)
-            full_pt[0, : min(len(seq.pages), ctx_pages)] = seq.pages[
-                :ctx_pages
-            ]
-            key = self._suffix_key(
-                chunk, 1, ctx_pages, False, None, 0, None
-            )
-            fresh = key not in self._compiled_buckets
-            if fresh:
-                metrics.RECOMPILES.labels(kind="prefill").inc()
-                self._compiled_buckets.add(key)
-                self._note_attention("suffix", multitok_attention_impl(
-                    self.use_pallas, self._mt_mesh, rows=chunk
-                ))
-            self._beat(
-                "prefill_chunk", compiling=fresh, bucket=chunk, batch=1
-            )
-            with self.perf.span(
-                "prefill_dispatch",
-                lambda: {
-                    "program": "chunked_prefill", "bucket": chunk,
-                    "rows": 1, "ctx_tokens": start + n,
-                },
-            ) as disp:
-                _out, *cache = _suffix_prefill_step(
-                    self.params,
-                    self.spec,
-                    jnp.asarray(tokens),
-                    jnp.asarray([start], jnp.int32),
-                    jnp.asarray([n], jnp.int32),
-                    self.k_pages,
-                    self.v_pages,
-                    jnp.asarray(suffix_pt),
-                    jnp.asarray(full_pt),
-                    jnp.zeros((1,), jnp.float32),
-                    jnp.ones((1,), jnp.float32),
-                    jnp.zeros((1,), jnp.int32),
-                    self._step_key(),
-                    seeds=jnp.full((1,), -1, jnp.int32),
-                    steps=jnp.zeros((1,), jnp.int32),
-                    use_pallas=self.use_pallas,
-                    mesh=self._mt_mesh,
-                    **self._state_args(self._prompt_slots([plan], 1)),
-                )
-                self._set_cache(cache)
-            if fresh:
-                self.perf.record_compile(
-                    "chunked_prefill", key, disp.seconds,
-                    trigger="ctx_width",
-                )
-            start += n
-        # final chunk: exactly a B=1 suffix-group dispatch with
-        # cached_len=start — delegate so the full sampling surface
-        # (seeds/penalties/min_tokens/logprobs) can never drift from the
-        # unchunked path
-        final = PrefillPlan(
-            seq=seq,
-            slot=plan.slot,
-            bucket=bucket_for(
-                total - start, self.scheduler.prefill_buckets
-            ),
-            cached_len=start,
-            register_hashes=None,
-        )
-        return self._dispatch_suffix_group([final], final.bucket)
+            start += chunk
+        last = bucket_for(total - start, self.scheduler.prefill_buckets)
+        return self._dispatch_prompt(part(last), last, cached=True)
 
     # ------------------------------------------------------------- decode
 
@@ -3413,68 +2843,33 @@ class EngineCore:
 
     @engine_thread_only
     def _build_decode_state(self, seqs: List[Sequence]) -> None:
-        with self.perf.span("state", lambda: {"rows": len(seqs)}):
-            self._build_decode_state_arrays(seqs)
-
-    @engine_thread_only
-    def _build_decode_state_arrays(self, seqs: List[Sequence]) -> None:
         self.total_state_rebuilds += 1
         B = self.max_slots
-        tokens = np.zeros((B,), np.int32)
-        positions = np.zeros((B,), np.int32)
-        active = np.zeros((B,), bool)
-        temps = np.zeros((B,), np.float32)
-        top_ps = np.ones((B,), np.float32)
-        top_ks = np.zeros((B,), np.int32)
-        seeds = np.full((B,), -1, np.int32)
-        steps = np.zeros((B,), np.int32)
-        want_pen = any(s.params.has_penalties for s in seqs)
-        for seq in seqs:
-            slot = seq.slot
-            assert slot is not None
-            row = self._page_tables_np[slot]
-            row[:] = 0
-            row[: len(seq.pages)] = seq.pages
-            tokens[slot] = seq.output_ids[-1]
-            positions[slot] = seq.total_len - 1
-            active[slot] = True
-            temps[slot] = seq.params.temperature
-            top_ps[slot] = seq.params.top_p
-            top_ks[slot] = seq.params.top_k
-            if seq.params.seed is not None:
-                seeds[slot] = seq.params.seed
-            steps[slot] = seq.num_generated
-        if want_pen:
-            counts_j, freq_j, pres_j = self._penalty_arrays(
-                B, ((s.slot, s) for s in seqs)
-            )
-        else:
-            counts_j, freq_j, pres_j = None, jnp.zeros((B,)), jnp.zeros((B,))
-        mt_j, mt_ids_j = self._min_token_arrays(
-            B, ((s.slot, s) for s in seqs)
-        )
-        lb_j, lb_vals_j = self._logit_bias_arrays(
-            B, ((s.slot, s) for s in seqs)
-        )
-        self._dec_state = {
-            "tokens": jnp.asarray(tokens),
-            "positions": jnp.asarray(positions),
-            "page_tables": jnp.asarray(self._page_tables_np),
-            "active": jnp.asarray(active),
-            "temps": jnp.asarray(temps),
-            "top_ps": jnp.asarray(top_ps),
-            "top_ks": jnp.asarray(top_ks),
-            "seeds": jnp.asarray(seeds),
-            "steps": jnp.asarray(steps),
-            "counter": jnp.asarray(self._step_counter, jnp.uint32),
-            "counts": counts_j,
-            "freq_pens": freq_j,
-            "pres_pens": pres_j,
-            "min_toks": mt_j,
-            "stop_id_mat": mt_ids_j,
-            "bias_ids": lb_j,
-            "bias_vals": lb_vals_j,
-        }
+        with self.perf.span("state", lambda: {"rows": len(seqs)}):
+            tokens = np.zeros((B,), np.int32)
+            positions = np.zeros((B,), np.int32)
+            active = np.zeros((B,), bool)
+            for seq in seqs:
+                slot = seq.slot
+                assert slot is not None
+                row = self._page_tables_np[slot]
+                row[:] = 0
+                row[: len(seq.pages)] = seq.pages
+                tokens[slot] = seq.output_ids[-1]
+                positions[slot] = seq.total_len - 1
+                active[slot] = True
+            self._dec_state = state = {
+                "tokens": jnp.asarray(tokens),
+                "positions": jnp.asarray(positions),
+                "page_tables": jnp.asarray(self._page_tables_np),
+                "active": jnp.asarray(active),
+                "counter": jnp.asarray(self._step_counter, jnp.uint32),
+                **self._sampling_rows(B, ((s.slot, s) for s in seqs)),
+            }
+            if state["counts"] is None:
+                # the chunk program takes the two penalty rows whether
+                # or not a histogram rides with them
+                state["freq_pens"] = state["pres_pens"] = jnp.zeros((B,))
 
     @engine_thread_only
     def _refresh_page_tables(self, seqs: List[Sequence]) -> None:
@@ -3524,57 +2919,23 @@ class EngineCore:
     def _dispatch_chunk(self, active: List[Sequence], chunk: int) -> None:
         faults.check("decode_step")
         state = self._dec_state
-        num_lp = (
-            LOGPROBS_K
-            if any(s.params.logprobs for s in active)
-            else 0
-        )
-        all_greedy = self._all_greedy(active, num_lp)
-        chunk_key = (
-            chunk,
-            state["counts"] is not None,
-            None
-            if state["min_toks"] is None
-            else state["stop_id_mat"].shape[1],
-            num_lp,
-            all_greedy,
-            None
-            if state["bias_ids"] is None
-            else state["bias_ids"].shape[1],
-        )
-        fresh = chunk_key not in self._compiled_chunks
-        if fresh:
-            metrics.RECOMPILES.labels(kind="decode").inc()
-            self._compiled_chunks.add(chunk_key)
-            self.flight.record_tick(
-                "recompile", program="decode", chunk=chunk,
-                batch=len(active),
-            )
-            self._note_attention("decode", decode_attention_impl(
-                self.spec, self.use_pallas, self._attn_mesh
-            ))
-            for seq in active:
-                if seq.trace is not None:
-                    seq.trace.event("xla_compile", chunk=chunk)
-        self._beat(
-            "decode", compiling=fresh, chunk=chunk, batch=len(active)
-        )
+        chunk_key = (chunk, *state["variant"])
         guard = (
             self.integrity is not None and self.integrity.guard_enabled
         )
-        start = time.perf_counter()
-        # the jitted-call return is trace+enqueue (dispatch_s); a fresh
-        # variant's call also compiles synchronously, so its duration
-        # IS the compile cost the ledger records
-        with self.perf.span(
-            "decode_dispatch",
+        with self._launch(
+            "decode", chunk_key, active,
+            ("decode", lambda: decode_attention_impl(
+                self.spec, self.use_pallas, self._attn_mesh
+            )),
             lambda: {
                 "program": "decode", "steps": chunk, "rows": len(active),
                 "ctx_tokens": sum(s.total_len for s in active),
                 # steps in flight that ctx_tokens does not hold yet
                 "lead": sum(c[1] for c in self._pending_chunks),
             },
-        ) as disp:
+            chunk=chunk, batch=len(active),
+        ):
             (
                 chunk_tokens,
                 chunk_lp,
@@ -3607,13 +2968,13 @@ class EngineCore:
                 seeds=state["seeds"],
                 steps=state["steps"],
                 mesh=self._attn_mesh,
-                num_logprobs=num_lp,
+                num_logprobs=state["num_logprobs"],
                 counts=state["counts"],
                 freq_pens=state["freq_pens"],
                 pres_pens=state["pres_pens"],
                 min_toks=state["min_toks"],
                 stop_id_mat=state["stop_id_mat"],
-                all_greedy=all_greedy,
+                all_greedy=state["all_greedy"],
                 bias_ids=state["bias_ids"],
                 bias_vals=state["bias_vals"],
                 guard=guard,
@@ -3628,17 +2989,13 @@ class EngineCore:
             moe_stats = None
             if more:
                 self.state, moe_stats = more
-        if fresh:
-            self.perf.record_compile(
-                "decode", chunk_key, disp.seconds, trigger="chunk_variant"
-            )
         self._step_counter += chunk
         # snapshot preempt_count as an epoch: a sequence preempted while
         # this chunk is in flight (and possibly re-admitted before the
         # readback is processed) must NOT receive the stale tokens
         self._pending_chunks.append(
             ([(s, s.preempt_count) for s in active], chunk, chunk_tokens,
-             start, chunk_lp, chunk_flags, moe_stats)
+             chunk_lp, chunk_flags, moe_stats)
         )
 
     @engine_thread_only
@@ -3647,7 +3004,7 @@ class EngineCore:
         host state: append tokens in order, detect EOS/length stops, discard
         steps past a stop."""
         while self._pending_chunks:
-            seqs, chunk, tokens_dev, _start, lp_dev, flags_dev, moe_dev = (
+            seqs, chunk, tokens_dev, lp_dev, flags_dev, moe_dev = (
                 self._pending_chunks.pop(0)
             )
             # observe only the host-blocking readback time (kind="decode"):
@@ -3723,28 +3080,9 @@ class EngineCore:
                         )
                     ):
                         self.scheduler.fail_sequence(seq, soft_exc)
-            metrics.observe_with_exemplar(
-                metrics.ENGINE_STEP_TIME.labels(kind="decode"),
-                block_s,
-                trace_id=next(
-                    (
-                        s.trace.trace_id
-                        for s, _ in seqs
-                        if s.trace is not None and s.trace.trace_id
-                    ),
-                    None,
-                ),
-            )
-            self.flight.record_tick(
-                "decode",
-                batch=len(seqs),
-                chunk=chunk,
-                step_s=round(block_s, 6),
-                device_s=round(device_s, 6),
-                readback_s=round(block_s - device_s, 6),
-                kv_used=self.allocator.num_used,
-                kv_free=self.allocator.num_free,
-                queue_depth=len(self.scheduler.waiting),
+            self._record_decode_step(
+                "decode", [s for s, _ in seqs], chunk,
+                block_s, device_s, read.seconds,
             )
             # append under the readback lock (the blocking np.asarray
             # is above): see _admit_and_prefill — the epoch guard is
@@ -3787,6 +3125,38 @@ class EngineCore:
             self.total_steps += chunk
             if not drain:
                 break
+
+    @engine_thread_only
+    def _record_decode_step(
+        self, kind: str, seqs: List[Sequence], chunk: int,
+        step_s: float, device_s: float, readback_s: float,
+    ) -> None:
+        """A decode readback's two records (chunk or verify round): the
+        step-time histogram, with the first traced row's id as the
+        exemplar, and the flight recorder's tick."""
+        metrics.observe_with_exemplar(
+            metrics.ENGINE_STEP_TIME.labels(kind="decode"),
+            step_s,
+            trace_id=next(
+                (
+                    s.trace.trace_id
+                    for s in seqs
+                    if s.trace is not None and s.trace.trace_id
+                ),
+                None,
+            ),
+        )
+        self.flight.record_tick(
+            kind,
+            batch=len(seqs),
+            chunk=chunk,
+            step_s=round(step_s, 6),
+            device_s=round(device_s, 6),
+            readback_s=round(readback_s, 6),
+            kv_used=self.allocator.num_used,
+            kv_free=self.allocator.num_free,
+            queue_depth=len(self.scheduler.waiting),
+        )
 
     # --------------------------------------------------------- speculative
 
@@ -3839,10 +3209,6 @@ class EngineCore:
         positions0 = np.zeros((B,), np.int32)
         input_lens = np.ones((B,), np.int32)
         active_mask = np.zeros((B,), bool)
-        temps = np.zeros((B,), np.float32)
-        top_ps = np.ones((B,), np.float32)
-        top_ks = np.zeros((B,), np.int32)
-        seeds = np.full((B,), -1, np.int32)
         steps = np.zeros((B,), np.int32)
         for seq in active:
             slot = seq.slot
@@ -3852,11 +3218,6 @@ class EngineCore:
             tokens[slot, 0] = seq.output_ids[-1]
             positions0[slot] = seq.total_len - 1
             active_mask[slot] = True
-            temps[slot] = seq.params.temperature
-            top_ps[slot] = seq.params.top_p
-            top_ks[slot] = seq.params.top_k
-            if seq.params.seed is not None:
-                seeds[slot] = seq.params.seed
             steps[slot] = seq.num_generated
             # acceptance+bonus never exceeds input_len, so capping the
             # input at the remaining budget/length bounds overshoot
@@ -3893,68 +3254,34 @@ class EngineCore:
         if w_needed < width:
             width = min(width, 1 << (max(1, w_needed) - 1).bit_length())
             width = max(width, w_needed)
-        want_pen = any(s.params.has_penalties for s in active)
-        if want_pen:
-            sig = tuple(
-                (s.seq_id, s.slot, s.preempt_count) for s in active
-            )
-            if self._spec_pen is None or self._spec_pen["sig"] != sig:
-                counts_j, freq_j, pres_j = self._penalty_arrays(
-                    B, ((s.slot, s) for s in active)
-                )
-                self._spec_pen = {
-                    "sig": sig,
-                    "counts": counts_j,
-                    "freq": freq_j,
-                    "pres": pres_j,
-                }
-        else:
-            self._spec_pen = None
-        mt_sig = tuple((s.seq_id, s.slot) for s in active)
-        if self._spec_mt is None or self._spec_mt["sig"] != mt_sig:
-            mt, mt_ids = self._min_token_arrays(
-                B, ((s.slot, s) for s in active)
-            )
-            lb, lb_vals = self._logit_bias_arrays(
-                B, ((s.slot, s) for s in active)
-            )
-            self._spec_mt = {
-                "sig": mt_sig, "mt": mt, "ids": mt_ids,
-                "lb": lb, "lb_vals": lb_vals,
+        sig = tuple((s.seq_id, s.slot, s.preempt_count) for s in active)
+        if self._spec_rows is None or self._spec_rows["sig"] != sig:
+            self._spec_rows = {
+                "sig": sig,
+                **self._sampling_rows(B, ((s.slot, s) for s in active)),
             }
-        spec_mt = self._spec_mt["mt"]
-        spec_mt_ids = self._spec_mt["ids"]
-        spec_lb = self._spec_mt["lb"]
-        spec_lb_vals = self._spec_mt["lb_vals"]
+        rows = self._spec_rows
+        want_pen = rows["counts"] is not None
         faults.check("decode_step")
         # stale-wake epochs for the readback loop below (the verify
         # call + np.asarray block this thread; a stall declared there
         # may checkpoint + replay these sequences)
         spec_epochs = {s.seq_id: s.preempt_count for s in active}
         start = time.perf_counter()
-        num_lp = (
-            LOGPROBS_K
-            if any(s.params.logprobs for s in active)
-            else 0
-        )
-        all_greedy = self._all_greedy(active, num_lp)
+        num_lp, all_greedy = rows["num_logprobs"], rows["all_greedy"]
         spec_key = (S_round, width, num_lp, all_greedy, want_pen)
-        fresh = spec_key not in self._compiled_spec
-        self._beat(
-            "spec_verify",
-            compiling=fresh,
-            chunk=S_round,
-            batch=len(active),
-        )
-        self._compiled_spec.add(spec_key)
-        with self.perf.span(
-            "decode_dispatch",
+        with self._launch(
+            "spec_verify", spec_key, active,
+            ("spec_verify", lambda: multitok_attention_impl(
+                self.use_pallas, self._mt_mesh, rows=S_round
+            )),
             lambda: {
                 "program": "spec_verify", "steps": 1,
                 "rows": len(active),
                 "ctx_tokens": sum(s.total_len for s in active),
             },
-        ) as disp:
+            chunk=S_round, batch=len(active),
+        ):
             (
                 model_toks, accepted, lp_data, counts_out,
                 self.k_pages, self.v_pages,
@@ -3968,41 +3295,28 @@ class EngineCore:
                 self.v_pages,
                 jnp.asarray(self._page_tables_np[:, :width]),
                 jnp.asarray(active_mask),
-                jnp.asarray(temps),
-                jnp.asarray(top_ps),
-                jnp.asarray(top_ks),
+                rows["temps"],
+                rows["top_ps"],
+                rows["top_ks"],
                 self._base_key,
                 jnp.asarray(self._step_counter, jnp.uint32),
-                seeds=jnp.asarray(seeds),
+                seeds=rows["seeds"],
                 steps=jnp.asarray(steps),
                 use_pallas=self.use_pallas,
                 num_logprobs=num_lp,
-                counts=(
-                    self._spec_pen["counts"] if want_pen else None
-                ),
-                freq_pens=(
-                    self._spec_pen["freq"] if want_pen else None
-                ),
-                pres_pens=(
-                    self._spec_pen["pres"] if want_pen else None
-                ),
-                min_toks=spec_mt,
-                stop_id_mat=spec_mt_ids,
+                counts=rows["counts"],
+                freq_pens=rows["freq_pens"],
+                pres_pens=rows["pres_pens"],
+                min_toks=rows["min_toks"],
+                stop_id_mat=rows["stop_id_mat"],
                 all_greedy=all_greedy,
-                bias_ids=spec_lb,
-                bias_vals=spec_lb_vals,
+                bias_ids=rows["bias_ids"],
+                bias_vals=rows["bias_vals"],
                 mesh=self._mt_mesh,
             )
-        if fresh:
-            self.perf.record_compile(
-                "spec_verify", spec_key, disp.seconds,
-                trigger="spec_width",
-            )
-            self._note_attention("spec_verify", multitok_attention_impl(
-                self.use_pallas, self._mt_mesh, rows=S_round
-            ))
-        if want_pen:
-            self._spec_pen["counts"] = counts_out
+        # the histogram of the tokens this round appended, kept on the
+        # device for the next round (None without penalties)
+        rows["counts"] = counts_out
         self._step_counter += 1
         # perf split of the existing sync (see _process_chunks)
         with self.perf.span("device_wait") as wait:
@@ -4028,28 +3342,8 @@ class EngineCore:
                 device_s=device_s,
                 chunk=False,  # a verify pass, not a decode chunk
             )
-        metrics.observe_with_exemplar(
-            metrics.ENGINE_STEP_TIME.labels(kind="decode"),
-            spec_s,
-            trace_id=next(
-                (
-                    s.trace.trace_id
-                    for s in active
-                    if s.trace is not None and s.trace.trace_id
-                ),
-                None,
-            ),
-        )
-        self.flight.record_tick(
-            "spec_verify",
-            batch=len(active),
-            chunk=S_round,
-            step_s=round(spec_s, 6),
-            device_s=round(device_s, 6),
-            readback_s=round(readback_s, 6),
-            kv_used=self.allocator.num_used,
-            kv_free=self.allocator.num_free,
-            queue_depth=len(self.scheduler.waiting),
+        self._record_decode_step(
+            "spec_verify", active, S_round, spec_s, device_s, readback_s
         )
         # append under the readback lock (device waits all happened
         # above): see _admit_and_prefill for the interleaving hazard
@@ -4431,29 +3725,15 @@ class EngineCore:
         """The step programs' extra arguments for a spec with recurrent
         layers (the state, and for a prompt pass each row's slot);
         nothing for the others."""
-        return _state_kw(
-            self.state,
-            None if slots is None else jnp.asarray(slots, jnp.int32),
-        )
-
-    def _prompt_slots(self, plans, rows: int):
-        """Row -> decode slot for a prompt pass of ``rows`` padded rows;
-        padding rows point past the state (their update is dropped)."""
-        if self.state is None:
-            return None
-        slots = np.full((rows,), self.max_slots, np.int32)
-        for row, plan in enumerate(plans):
-            slots[row] = plan.slot
-        return slots
+        if self.state is None or slots is None:
+            return _state_kw(self.state)
+        return _state_kw(self.state, jnp.asarray(slots, jnp.int32))
 
     def _set_cache(self, cache) -> None:
         """Take back what a step program returned of the cache."""
         self.k_pages, self.v_pages, *rest = cache
         if rest:
             (self.state,) = rest
-
-    def _note_attention(self, program: str, impl: str) -> None:
-        self._attention.setdefault(program, set()).add(impl)
 
     def get_stats(self) -> Dict[str, Any]:
         """Engine counters for /stats.  ``steps`` counts *dispatched decode
