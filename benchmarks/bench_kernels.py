@@ -155,51 +155,116 @@ def decode_cell_case(name, B=256, ps=32, ctx=2048, layers=2, dead_len=0,
     return q, k_pages, v_pages, page_tables, jnp.asarray(lens, jnp.int32)
 
 
-def time_decode_kernel(kernel, case, loop=28):
-    """Median seconds of ONE launch: `loop` launches chained in one
-    program (a decode step's layers), layer index alternating."""
+def time_decode_layer(layer_fn, case, loop=28):
+    """Median seconds of ONE layer's cache work, `layer_fn(q, k_pages,
+    v_pages, page_tables, seq_lens, k_new, v_new, layer) -> (attention,
+    k_pages, v_pages)`: `loop` of them chained in one program (a decode
+    step's layers) with the pools on the carry, layer index alternating."""
+    q, k_pages, v_pages, page_tables, seq_lens = case
+    # the runs donate their pools: the case keeps its own
+    k_pages, v_pages = jnp.copy(k_pages), jnp.copy(v_pages)
+    KV, hd = k_pages.shape[1], k_pages.shape[-1]
+    k_new, v_new = jax.random.normal(
+        jax.random.PRNGKey(7), (2, q.shape[0], KV, hd), k_pages.dtype
+    )
 
-    @jax.jit
-    def run(q, k_pages, v_pages, page_tables, seq_lens):
+    @functools.partial(jax.jit, donate_argnums=(1, 2))
+    def run(q, k_pages, v_pages, page_tables, seq_lens, k_new, v_new):
         def body(carry, i):
-            out = kernel(
-                q + 0 * carry.astype(q.dtype), k_pages, v_pages,
-                page_tables, seq_lens, layer=i % k_pages.shape[0],
+            out, kp, vp = carry
+            out, kp, vp = layer_fn(
+                q + 0 * out.astype(q.dtype), kp, vp, page_tables, seq_lens,
+                k_new, v_new, i % kp.shape[0],
             )
-            return out.astype(jnp.float32), None
+            return (out.astype(jnp.float32), kp, vp), None
 
-        out, _ = jax.lax.scan(
-            body, jnp.zeros(q.shape, jnp.float32),
+        carry, _ = jax.lax.scan(
+            body, (jnp.zeros(q.shape, jnp.float32), k_pages, v_pages),
             jnp.arange(loop, dtype=jnp.int32),
         )
-        return out
+        return carry
 
-    return _median_time(run, *case, loop=loop)
+    times = []
+    for _ in range(12):
+        t0 = time.perf_counter()
+        _, k_pages, v_pages = _sync(run(
+            q, k_pages, v_pages, page_tables, seq_lens, k_new, v_new
+        ))
+        times.append(time.perf_counter() - t0)
+    return float(np.median(times[2:])) / loop
 
 
 def bench_decode_cells():
-    """The decode kernel alone at the cells' shapes: time a launch and
-    the share of the HBM roofline its LIVE tokens' bytes make of it (what
-    `kernel.decode_attn_roofline_live` reads in a traced run)."""
+    """A decode layer's cache work at the cells' shapes, three ways: the
+    kernel alone (reads only), the token's K and V scattered into the
+    pool and then the kernel (what a step did until PR 30), and the
+    kernel that writes the token's page itself (what it does now).  The
+    share of the HBM roofline counts the LIVE tokens' bytes read (what
+    `kernel.decode_attn_roofline_live` counts in a traced run)."""
+    from vgate_tpu.models.decoder import decode_attn_inputs
+    from vgate_tpu.ops.kv_quant import kv_write_tokens
     from vgate_tpu.ops.pallas.paged_attention import (
-        paged_decode_attention_pallas,
+        paged_decode_attention_pallas as kernel,
     )
+
+    def read_only(q, kp, vp, pt, sl, k_new, v_new, layer):
+        return kernel(q, kp, vp, pt, sl, layer=layer), kp, vp
+
+    def scatter_then_kernel(q, kp, vp, pt, sl, k_new, v_new, layer):
+        # a dead slot's token goes to trash page 0, as in a decode step
+        _, page_ids, page_off = decode_attn_inputs(
+            jnp.maximum(sl - 1, 0), pt, sl > 0, kp.shape[-2]
+        )
+        kp = kv_write_tokens(kp, page_ids, page_off, k_new, layer=layer)
+        vp = kv_write_tokens(vp, page_ids, page_off, v_new, layer=layer)
+        return kernel(q, kp, vp, pt, sl, layer=layer), kp, vp
+
+    def kernel_writes(q, kp, vp, pt, sl, k_new, v_new, layer):
+        return kernel(
+            q, kp, vp, pt, sl, layer=layer, k_new=k_new, v_new=v_new
+        )
+
+    @jax.jit
+    def same_bits(case, k_new, v_new):
+        """The kernel that writes against scatter-then-kernel, one
+        layer: attention and every page but the trash page, bit for bit
+        (a dead slot's token goes to the trash page only by scatter)."""
+        want = scatter_then_kernel(*case, k_new, v_new, 1)
+        got = kernel_writes(*case, k_new, v_new, 1)
+        return [
+            jnp.array_equal(got[0], want[0]),
+            *(jnp.array_equal(g[:, :, 1:], w[:, :, 1:])
+              for g, w in zip(got[1:], want[1:])),
+        ]
 
     for name, ((KV, G, hd), live, _) in DECODE_CELLS.items():
         case = decode_cell_case(name)
-        seconds = time_decode_kernel(paged_decode_attention_pallas, case)
+        new = jax.random.normal(
+            jax.random.PRNGKey(11), (2, case[0].shape[0], KV, hd),
+            jnp.bfloat16,
+        )
+        if not all(map(bool, same_bits(case, *new))):
+            raise SystemExit(f"{name}: the kernel's write differs from "
+                             "the scatter's")
         live_tokens = int(np.asarray(case[4]).sum())
         live_bytes = live_tokens * 2 * KV * hd * 2
-        yield {
+        line = {
             "kernel": "paged_decode_attention",
             "cell": name,
             "shape": f"B256 KV{KV} G{G} hd{hd} ps32, {live} live",
             "live_tokens": live_tokens,
-            "launch_us": round(seconds * 1e6, 1),
-            "hbm_roofline_pct": round(
-                100 * live_bytes / HBM_BYTES_PER_S / seconds, 1
-            ),
         }
+        for label, fn in (
+            ("kernel_alone", read_only),
+            ("scatter_then_kernel", scatter_then_kernel),
+            ("kernel_writes", kernel_writes),
+        ):
+            seconds = time_decode_layer(fn, case)
+            line[f"{label}_us"] = round(seconds * 1e6, 1)
+            line[f"{label}_hbm_roofline_pct"] = round(
+                100 * live_bytes / HBM_BYTES_PER_S / seconds, 1
+            )
+        yield line
 
 
 def bench_flash_prefill(B=8, S=1024, H=12, KV=2, hd=128):

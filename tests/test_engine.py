@@ -108,6 +108,42 @@ def test_stats_surface(engine):
     assert stats["mesh"]["tp"] == 1
 
 
+@pytest.mark.fast  # seconds: runs in tier-1, unlike this file
+def test_decode_spans_and_stats_name_the_kv_write_path(engine, monkeypatch):
+    """Who writes a decode step's K/V is fixed when the program is
+    traced, so it is a word, not a rate: the same one in /stats → engine
+    and on every ``vgt.engine.decode_dispatch`` span of a capture (here
+    the jnp twin's: XLA's scatter)."""
+    from vgate_tpu.observability import perf as perf_mod
+
+    spans = []
+
+    class Ann:
+        def __exit__(self, *exc):
+            pass
+
+        def set_metadata(self, **args):
+            pass
+
+    def fake_open(name, args):
+        spans.append((name, args() if args is not None else {}))
+        return Ann()
+
+    monkeypatch.setattr(perf_mod, "_open_annotation", fake_open)
+    perf_mod.set_capturing(True)
+    try:
+        engine.generate(["which path writes"], [greedy(4)])
+    finally:
+        perf_mod.set_capturing(False)
+    assert engine.get_stats()["kv_write"] == "scatter"
+    dispatched = [
+        args for name, args in spans
+        if name == "vgt.engine.decode_dispatch"
+    ]
+    assert dispatched
+    assert {args["kv_write"] for args in dispatched} == {"scatter"}
+
+
 def test_device_health(engine):
     health = engine.device_health()
     assert health["alive"] is True

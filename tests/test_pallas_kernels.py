@@ -605,25 +605,93 @@ def _live_rows_match(got, expect, seq_lens, tol=2e-5):
     assert not got[lens == 0].any(), "a row of length 0 must come out zero"
 
 
-def _decode_both(q, k_pages, v_pages, page_tables, seq_lens, **kw):
+def _scattered(k_pages, v_pages, page_tables, seq_lens, seed, layer=None):
+    """A new token a slot (its K and V at position length - 1) and the
+    pools with it written as a decode step's scatter writes it: a dead
+    slot's lands in trash page 0 (models/decoder.py decode_attn_inputs)."""
+    from vgate_tpu.models.decoder import decode_attn_inputs
+    from vgate_tpu.ops.kv_quant import kv_write_tokens
+
+    B, (KV, _, ps, hd) = seq_lens.shape[0], k_pages.shape[-4:]
+    rng = np.random.default_rng(seed)
+    k_new = jnp.asarray(rng.normal(size=(B, KV, hd)), k_pages.dtype)
+    v_new = jnp.asarray(rng.normal(size=(B, KV, hd)), v_pages.dtype)
+    _, page_ids, page_off = decode_attn_inputs(
+        jnp.maximum(seq_lens - 1, 0), page_tables, seq_lens > 0, ps
+    )
+    k_after = kv_write_tokens(k_pages, page_ids, page_off, k_new, layer=layer)
+    v_after = kv_write_tokens(v_pages, page_ids, page_off, v_new, layer=layer)
+    return k_new, v_new, k_after, v_after
+
+
+def _decode_both(q, k_pages, v_pages, page_tables, seq_lens, write=False,
+                 **kw):
+    """(kernel, twin).  With ``write`` the kernel is handed a new token a
+    slot and the pools WITHOUT it: the pools it returns must be the
+    scatter's bit for bit in every page but the trash page (a dead slot
+    writes nothing, the scatter dumps its token there), and its
+    attention exactly what it computes over the scattered pools."""
+    if write:
+        k_new, v_new, k_after, v_after = _scattered(
+            k_pages, v_pages, page_tables, seq_lens, seed=99,
+            layer=kw.get("layer"),
+        )
+        got, k_got, v_got = paged_decode_attention_pallas(
+            q, k_pages, v_pages, page_tables, seq_lens, k_new=k_new,
+            v_new=v_new, interpret=True, **kw
+        )
+        for pool, after, before in (
+            (k_got, k_after, k_pages), (v_got, v_after, v_pages),
+        ):
+            np.testing.assert_array_equal(
+                np.asarray(pool)[..., 1:, :, :],
+                np.asarray(after)[..., 1:, :, :],
+            )
+            np.testing.assert_array_equal(
+                np.asarray(pool)[..., 0, :, :],
+                np.asarray(before)[..., 0, :, :],
+            )
+        k_pages, v_pages = k_after, v_after
+        np.testing.assert_array_equal(
+            np.asarray(got),
+            np.asarray(paged_decode_attention_pallas(
+                q, k_pages, v_pages, page_tables, seq_lens, interpret=True,
+                **kw
+            )),
+        )
+    else:
+        got = paged_decode_attention_pallas(
+            q, k_pages, v_pages, page_tables, seq_lens, interpret=True, **kw
+        )
     # the twin never sees a 0: it would divide by an empty sum
     expect = paged_decode_attention(
         q, k_pages, v_pages, page_tables, jnp.maximum(seq_lens, 1), **kw
     )
-    got = paged_decode_attention_pallas(
-        q, k_pages, v_pages, page_tables, seq_lens, interpret=True, **kw
-    )
     return got, expect
 
 
-def test_decode_kernel_every_length_in_one_batch():
+# the kernel's write holds every served decode step's cache: its cases
+# run in tier-1 (tests/conftest.py counts this file's others as slow)
+reads_and_writes = pytest.mark.parametrize(
+    "write",
+    [
+        pytest.param(False, id="reads"),
+        pytest.param(True, id="writes", marks=pytest.mark.fast),
+    ],
+)
+
+
+@reads_and_writes
+def test_decode_kernel_every_length_in_one_batch(write):
     """Page and chunk edges (a chunk is 256 tokens at this geometry), an
-    empty row and a full context, side by side in one block."""
+    empty row and a full context, side by side in one block; written,
+    the new token lies at a page's first row (1, 33, 257), at its last
+    (32, 256, 2048) and in a chunk's first page and last."""
     lens = [0, 1, 31, 32, 33, 255, 256, 257, 2048]
     case = make_case(
         B=len(lens), H=4, KV=2, ps=32, pages_per_seq=64, lens=lens, seed=21
     )
-    got, expect = _decode_both(*case)
+    got, expect = _decode_both(*case, write=write)
     _live_rows_match(got, expect, case[4])
 
 
@@ -658,9 +726,12 @@ def _blocks_of_32(pattern, seed):
     ],
     ids=["dead-block-then-one-live", "live-among-dead", "all-live", "all-dead"],
 )
-def test_decode_kernel_block_patterns(pattern):
+@reads_and_writes
+def test_decode_kernel_block_patterns(pattern, write):
+    """Written: more live slots than staging pages in a program, none,
+    and fewer."""
     case = _blocks_of_32(pattern, seed=22)
-    got, expect = _decode_both(*case)
+    got, expect = _decode_both(*case, write=write)
     _live_rows_match(got, expect, case[4])
 
 
@@ -669,34 +740,40 @@ def test_decode_kernel_block_patterns(pattern):
     [(2, 6, 128), (4, 7, 128), (2, 8, 256), (1, 7, 128)],
     ids=["1.5B", "7B", "qwen3-next", "one-kv-head-tp-shard"],
 )
-def test_decode_kernel_cell_geometries(KV, G, hd):
+@reads_and_writes
+def test_decode_kernel_cell_geometries(KV, G, hd, write):
     """The three cells' (KV, G, hd) and one KV head (a tp shard of the
-    7B): all KV heads ride one iteration, whatever their number."""
+    7B): all KV heads ride one iteration, and one staged page back to
+    the pool, whatever their number."""
     lens = [0, 200, 3, 0, 129, 64]
     case = make_case(
         B=len(lens), H=KV * G, KV=KV, hd=hd, ps=32, pages_per_seq=8,
         lens=lens, seed=23,
     )
-    got, expect = _decode_both(*case)
+    got, expect = _decode_both(*case, write=write)
     _live_rows_match(got, expect, case[4])
 
 
-def test_decode_kernel_window_softcap_scale_across_blocks():
+@reads_and_writes
+def test_decode_kernel_window_softcap_scale_across_blocks(write):
     """Per-slot window starts differ inside one block and chunks below a
-    window are never fetched; softcap and the query scale ride along."""
+    window are never fetched (the chunk that holds the new token always
+    is); softcap and the query scale ride along."""
     case = _blocks_of_32(
         {1: 40, 2: 128, 7: 96, 33: 127, 34: 5, 67: 70}, seed=24
     )
     for win in (16, 64, 100):
         got, expect = _decode_both(
-            *case, window=jnp.asarray(win, jnp.int32), softcap=30.0,
-            scale=0.25,
+            *case, write=write, window=jnp.asarray(win, jnp.int32),
+            softcap=30.0, scale=0.25,
         )
         _live_rows_match(got, expect, case[4])
 
 
-def test_decode_kernel_layer_indexed_ragged_batch():
-    """Layer-indexed pools under a B that is no multiple of the block."""
+@reads_and_writes
+def test_decode_kernel_layer_indexed_ragged_batch(write):
+    """Layer-indexed pools under a B that is no multiple of the block;
+    written, the other layers stay as they were."""
     q, k_pages, v_pages, page_tables, seq_lens = _blocks_of_32(
         {0: 33, 30: 97, 45: 1, 69: 128}, seed=25
     )
@@ -705,7 +782,7 @@ def test_decode_kernel_layer_indexed_ragged_batch():
     kL = jnp.asarray(rng.normal(size=(L,) + k_pages.shape), jnp.float32)
     vL = jnp.asarray(rng.normal(size=(L,) + v_pages.shape), jnp.float32)
     got, expect = _decode_both(
-        q, kL, vL, page_tables, seq_lens, layer=jnp.asarray(1)
+        q, kL, vL, page_tables, seq_lens, write=write, layer=jnp.asarray(1)
     )
     _live_rows_match(got, expect, seq_lens)
 
@@ -804,3 +881,96 @@ def test_decode_forward_hands_the_kernel_length_zero_for_inactive_rows():
         np.asarray(k_pal)[:, :, 1:], np.asarray(k_jnp)[:, :, 1:],
         rtol=2e-4, atol=2e-4,
     )
+
+
+@pytest.mark.fast  # tier-1, as the kernel's write cases above
+@pytest.mark.parametrize("model", ["tiny-dense", "tiny-hybrid"])
+def test_decode_forward_kernel_writes_what_the_scatter_writes(model):
+    """Two pages and a token of decode steps through ``decode_forward``
+    on both write paths: the Pallas kernel that writes the token's page
+    itself (interpret mode) and the jnp twin behind ``kv_write_tokens``.
+    Same greedy tokens, same pools in every page but the trash page (the
+    inactive row's token goes there only on the scatter's path)."""
+    import functools
+    import unittest.mock as mock
+
+    from vgate_tpu.models import hybrid
+    from vgate_tpu.models.decoder import (
+        decode_forward, decode_kv_write, init_params,
+    )
+    from vgate_tpu.models.specs import spec_for_model_id
+    from vgate_tpu.ops import gated_delta
+    from vgate_tpu.ops.pallas import grouped_matmul
+    from vgate_tpu.ops.pallas import paged_attention as pa
+
+    spec = spec_for_model_id(model)
+    assert decode_kv_write(spec, True) == "kernel"
+    assert decode_kv_write(spec, False) == "scatter"
+    assert decode_kv_write(spec, True, quantized=True) == "scatter"
+    B, ps, pages_per_seq = 3, 4, 4
+    steps = 2 * ps + 1
+    params = init_params(spec, jax.random.PRNGKey(0), jnp.float32)
+    shape = (spec.attn_layers, spec.num_kv_heads, 1 + B * pages_per_seq, ps,
+             spec.head_dim)
+    rng = np.random.default_rng(41)
+    pools = tuple(
+        jnp.asarray(rng.normal(size=shape), jnp.float32) for _ in range(2)
+    )
+    pt = jnp.asarray(
+        np.arange(B * pages_per_seq, dtype=np.int32).reshape(B, -1) + 1
+    )
+    active = jnp.asarray([True, False, True])
+    more = (
+        {"state": hybrid.make_state(spec, B, jnp.float32)}
+        if spec.is_hybrid else {}
+    )
+
+    @functools.partial(jax.jit, static_argnames="use_pallas")
+    def run(k, v, more, use_pallas):
+        tokens = jnp.asarray([7, 11, 5], jnp.int32)
+        positions = jnp.asarray([3, 0, 0], jnp.int32)
+        out = []
+        for _ in range(steps):
+            logits, k, v, *rest = decode_forward(
+                params, spec, tokens, positions, k, v, pt, active=active,
+                use_pallas=use_pallas, **more,
+            )
+            if rest:
+                more = {"state": rest[0]}
+            tokens = jnp.argmax(logits, -1).astype(jnp.int32)
+            positions = positions + active
+            out.append(tokens)
+        return jnp.stack(out), k, v
+
+    want_tokens, want_k, want_v = run(*pools, more, use_pallas=False)
+    with mock.patch.multiple(
+        pa, paged_decode_attention_pallas=functools.partial(
+            pa.paged_decode_attention_pallas, interpret=True)
+    ), mock.patch.multiple(
+        gated_delta, gated_delta_step=functools.partial(
+            gated_delta.gated_delta_step, interpret=True)
+    ), mock.patch.multiple(
+        grouped_matmul, grouped_matmul_pallas=functools.partial(
+            grouped_matmul.grouped_matmul_pallas, interpret=True)
+    ):
+        got_tokens, got_k, got_v = run(*pools, more, use_pallas=True)
+    live = np.asarray(active)
+    np.testing.assert_array_equal(
+        np.asarray(got_tokens)[:, live], np.asarray(want_tokens)[:, live]
+    )
+    for got, want, before in (
+        (got_k, want_k, pools[0]), (got_v, want_v, pools[1]),
+    ):
+        np.testing.assert_allclose(
+            np.asarray(got)[:, :, 1:], np.asarray(want)[:, :, 1:],
+            rtol=2e-4, atol=2e-4,
+        )
+        # the inactive row's pages and the trash page: untouched
+        np.testing.assert_array_equal(
+            np.asarray(got)[:, :, [0, 5, 6, 7, 8]],
+            np.asarray(before)[:, :, [0, 5, 6, 7, 8]],
+        )
+        # every live row's 2 * ps + 1 new rows differ from what was there
+        assert not np.allclose(
+            np.asarray(got)[:, :, 1:4], np.asarray(before)[:, :, 1:4]
+        )

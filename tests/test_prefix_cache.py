@@ -405,3 +405,53 @@ def test_engine_radix_stats_surface(radix_engines):
     assert stats["mode"] == "radix"
     assert stats["inserted_pages"] > 0
     assert "evictions_pressure" in stats and "cow_copies" in stats
+
+
+@pytest.mark.fast  # tier-1: the decode kernel's write leans on it
+def test_the_page_a_decode_step_writes_has_one_owner(radix_engines):
+    """What the decode kernel's write leans on
+    (ops/pallas/paged_attention.py): it copies the new token's WHOLE
+    page back to the pool, so no other sequence, and not the tree, may
+    hold that page while a chunk that writes it is in flight.  Shared
+    prompts, a divergence inside a page (copy-on-write), a turn that
+    reuses generated pages and streams side by side: at every decode
+    dispatch every page the chunk can write has exactly one holder."""
+    cached, _ = radix_engines
+    real = cached._dispatch_chunk
+    checked, shared = [], []
+
+    def checking(active, chunk):
+        lead = sum(c[1] for c in cached._pending_chunks)
+        in_tree = set(cached.radix_cache.pages_in_tree())
+        for seq in active:
+            first = seq.total_len - 1
+            for pos in range(first, first + lead + chunk):
+                if pos // PS >= len(seq.pages):
+                    break  # past its pages: the trash page
+                page = seq.pages[pos // PS]
+                checked.append(page)
+                if cached.allocator.refcount(page) != 1 or page in in_tree:
+                    shared.append((seq.seq_id, pos, page))
+        return real(active, chunk)
+
+    cached._dispatch_chunk = checking
+    try:
+        base = [61, 62, 63, 64, 65, 66, 67, 68, 69, 70, 71, 72, 73, 74]
+        first = cached.submit_tokens(list(base), greedy(12))
+        assert first.done_event.wait(timeout=300)
+        turn = base + list(first.generated_ids) + [81, 82, 83]
+        seqs = [
+            cached.submit_tokens(ids, greedy(12))
+            for ids in (
+                list(base),  # the whole prompt again
+                base[:10] + [91, 92, 93],  # diverges inside page 3
+                base[:8] + [94, 95, 96, 97, 98],  # at a page's edge
+                turn,  # reuses generated pages
+            )
+        ]
+        for seq in seqs:
+            assert seq.done_event.wait(timeout=300)
+    finally:
+        cached._dispatch_chunk = real
+    assert len(checked) > 40, "no decode chunk was dispatched"
+    assert not shared, shared

@@ -59,17 +59,25 @@ def _pool(A, kv, hd, pages, layers=2, int8=False):
     )
 
 
-def _compile_decode_kernel(A, geom, int8=False, B=8, pages_per_seq=16):
+def _compile_decode_kernel(A, geom, int8=False, B=8, pages_per_seq=16,
+                           write=False):
+    """``write``: the kernel that also writes each slot's new token
+    (what a decode step on a plain pool launches); without, the one that
+    only reads (an int8 pool, a tp shard)."""
     from vgate_tpu.ops.pallas.paged_attention import (
         paged_decode_attention_pallas,
     )
 
     H, KV, hd = geom
     pool = _pool(A, KV, hd, 64, int8=int8)
+    new = (
+        {name: A((B, KV, hd), jnp.bfloat16) for name in ("k_new", "v_new")}
+        if write else {}
+    )
     return paged_decode_attention_pallas.lower(
         A((B, H, hd), jnp.bfloat16), pool, pool,
         A((B, pages_per_seq), jnp.int32), A((B,), jnp.int32),
-        layer=A((), jnp.int32),
+        layer=A((), jnp.int32), **new,
     ).compile()
 
 
@@ -132,7 +140,9 @@ def test_decode_kernel_compiles_at_the_cells_shapes_for_v5e(v5e, geom):
     assert sizes == {
         GEOM_1P5B: (8, 64), GEOM_7B: (8, 32), GEOM_QWEN3_NEXT: (8, 32),
     }[geom]
-    _compile_decode_kernel(_abstract(v5e), geom, B=256, pages_per_seq=64)
+    _compile_decode_kernel(
+        _abstract(v5e), geom, B=256, pages_per_seq=64, write=True
+    )
 
 
 def test_gated_delta_step_kernel_compiles_for_v5e(v5e):
@@ -307,6 +317,15 @@ def test_decode_chunk_holds_one_pool_on_v5e(v5e):
         all_greedy=True, guard=True,
     ).compile()
     _assert_one_pool(compiled, pool_bytes)
+    # the step's new K and V reach the pool through the decode kernel's
+    # own descriptors (models/decoder.py decode_kv_write): XLA scatters
+    # nothing into a pool
+    pool_shape = "[" + ",".join(map(str, pool.shape)) + "]"
+    scatters = [
+        line for line in compiled.as_text().splitlines()
+        if "scatter" in line and pool_shape in line
+    ]
+    assert not scatters, scatters[0][:300]
 
 
 def test_prefill_step_holds_one_pool_on_v5e(v5e):

@@ -37,7 +37,11 @@ from vgate_tpu.ops.attention import (
     paged_decode_attention,
     paged_suffix_attention,
 )
-from vgate_tpu.ops.kv_quant import kv_write_pages, kv_write_tokens
+from vgate_tpu.ops.kv_quant import (
+    is_quantized,
+    kv_write_pages,
+    kv_write_tokens,
+)
 from vgate_tpu.ops.norms import rms_norm
 from vgate_tpu.ops.quant import weighted_einsum
 from vgate_tpu.ops.rope import apply_rope
@@ -269,6 +273,24 @@ def decode_attention_impl(
     if _axis(mesh, "sp") > 1:
         return "sp_shard"
     return _kernel_or_twin(spec, use_pallas, mesh)
+
+
+def decode_kv_write(
+    spec: ModelSpec, use_pallas: bool, mesh=None, quantized: bool = False
+) -> str:
+    """Who puts a decode step's new K and V into the pool, for these
+    static arguments and this kind of pool: ``"kernel"``, the Pallas
+    decode kernel itself (ops/pallas/paged_attention.py: the token's
+    page goes back through its own DMA descriptors), or ``"scatter"``,
+    ``kv_write_tokens`` before the attention (the jnp twin, an int8
+    pool, the kernel per tp shard, the sp and pp paths).
+    ``decode_forward`` selects through this and the engine reports it
+    (/stats → engine.kv_write, the decode-dispatch spans)."""
+    kernel = (
+        decode_attention_impl(spec, use_pallas, mesh) == "pallas"
+        and not quantized
+    )
+    return "kernel" if kernel else "scatter"
 
 
 def multitok_attention_impl(
@@ -686,14 +708,36 @@ def decode_forward(
         # whose mean over nothing would be garbage
         seq_lens = jnp.where(active, seq_lens, 0)
 
+    kernel_writes = decode_kv_write(
+        spec, use_pallas, mesh, is_quantized(k_pages)
+    ) == "kernel"
+
+    def write_attend(q, k, v, kp, vp, layer, window=None):
+        """The new token's K and V into the pool at ``layer`` and its
+        attention over the pool: (attention, k_pages, v_pages)."""
+        if kernel_writes:
+            with jax.named_scope("attention"):
+                return attn_fn(
+                    q, kp, vp, page_tables, seq_lens, layer=layer,
+                    window=window, k_new=k, v_new=v,
+                )
+        with jax.named_scope("kv_write"):
+            kp = kv_write_tokens(kp, page_ids, page_off, k, layer=layer)
+            vp = kv_write_tokens(vp, page_ids, page_off, v, layer=layer)
+        with jax.named_scope("attention"):
+            attn = attn_fn(
+                q, kp, vp, page_tables, seq_lens, layer=layer,
+                window=window,
+            )
+        return attn, kp, vp
+
     x = _embed(params, spec, tokens)  # [B, D]
     if spec.is_hybrid:
         from vgate_tpu.models import hybrid
 
         x, k_pages, v_pages, state, stats = hybrid.decode_forward(
-            params, spec, x, positions, k_pages, v_pages, state,
-            page_tables, seq_lens, page_ids, page_off, active, attn_fn,
-            use_pallas,
+            params, spec, x, positions, k_pages, v_pages, state, active,
+            write_attend, use_pallas,
         )
         return _logits(params, spec, x), k_pages, v_pages, state, stats
 
@@ -702,14 +746,10 @@ def decode_forward(
     # (Pallas: layer-indexed DMA; jnp: one composed gather)
     def body(h, lp, win, kp, vp, layer):
         q, k, v = _decode_qkv(h, lp, spec, positions)
-        with jax.named_scope("kv_write"):
-            kp = kv_write_tokens(kp, page_ids, page_off, k, layer=layer)
-            vp = kv_write_tokens(vp, page_ids, page_off, v, layer=layer)
-        with jax.named_scope("attention"):
-            attn = attn_fn(
-                q, kp, vp, page_tables, seq_lens, layer=layer,
-                window=win if spec.sliding_window > 0 else None,
-            )
+        attn, kp, vp = write_attend(
+            q, k, v, kp, vp, layer,
+            window=win if spec.sliding_window > 0 else None,
+        )
         return _finish_layer(h, attn, lp, spec), kp, vp
 
     x, k_pages, v_pages = _kv_layer_scan(

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 
 from vgate_tpu.models.specs import ModelSpec
 from vgate_tpu.ops import gated_delta as gd
-from vgate_tpu.ops.kv_quant import kv_write_pages, kv_write_tokens
+from vgate_tpu.ops.kv_quant import kv_write_pages
 from vgate_tpu.ops.moe import STAT_NAMES, combine_stats, expert_layer
 from vgate_tpu.ops.norms import rms_norm
 from vgate_tpu.ops.rope import apply_rope
@@ -371,10 +371,11 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
 
 
 def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
-                   state, page_tables, seq_lens, page_ids, page_off,
-                   active, attn_fn, use_pallas: bool):
-    """One decode step over embedded rows x [B, D], row = slot.  Returns
-    (x, k_pages, v_pages, state, stats [4])."""
+                   state, active, write_attend, use_pallas: bool):
+    """One decode step over embedded rows x [B, D], row = slot.
+    ``write_attend(q, k, v, kp, vp, layer)`` is the caller's cache step:
+    the token's K and V into the pool and its attention over it.
+    Returns (x, k_pages, v_pages, state, stats [4])."""
     if active is None:
         active = jnp.ones(x.shape[:1], bool)
 
@@ -387,13 +388,8 @@ def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
         with jax.named_scope("gated_attn"):
             q, k, v, gate = _gated_qkv(
                 h[:, None], lp, spec, positions[:, None])
-            q, k, v = q[:, 0], k[:, 0], v[:, 0]
-            with jax.named_scope("kv_write"):
-                kp = kv_write_tokens(kp, page_ids, page_off, k, layer=p)
-                vp = kv_write_tokens(vp, page_ids, page_off, v, layer=p)
-            with jax.named_scope("attention"):
-                attn = attn_fn(q, kp, vp, page_tables, seq_lens, layer=p,
-                               window=None)
+            attn, kp, vp = write_attend(
+                q[:, 0], k[:, 0], v[:, 0], kp, vp, p)
             out = _gated_out(attn, None if gate is None else gate[:, 0],
                              lp, h.dtype)
         h, stats = _finish(h, out, lp, spec, active, use_pallas, p, stack)

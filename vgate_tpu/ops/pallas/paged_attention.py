@@ -16,7 +16,9 @@ two); the kernels' advantage is the memory path:
 
 ``paged_decode_attention_pallas`` (one query token a slot) runs one program
 per BLOCK of slots, which walks the block's live chunks and serves all KV
-heads an iteration; ``paged_multitok_attention_pallas`` (speculative
+heads an iteration, and, handed the slots' new tokens, writes each one's
+page into the pool itself (a decode step has no scatter before it);
+``paged_multitok_attention_pallas`` (speculative
 verify: S candidate tokens a slot) still runs one program per (slot,
 kv_head) over ``_chunk_dma``.
 """
@@ -163,7 +165,9 @@ def _scale_row(buf, slot):
 
 # VMEM the single-token decode kernel spends, half on its K and V chunk
 # buffers (which bounds the chunk's tokens) and half on the pipelined q and
-# out blocks (which sets the slots a program serves): _decode_sizes.
+# out blocks (which sets the slots a program serves): _decode_sizes.  The
+# write's own VMEM rides beside it, at most 1.3 MiB at the cells' shapes:
+# the new tokens' [BS, KV, hd] blocks and DECODE_WRITE_BUFFERS staged pages.
 DECODE_VMEM_BUDGET = 4 << 20
 # K/V chunk buffers: one computed, two in flight.  Two keep the HBM busy
 # only while an iteration's arithmetic outlasts its pages' transfer.
@@ -172,6 +176,9 @@ DECODE_BUFFERS = 3
 # a head.  Wider chunks mostly add tail work at the lengths served
 # (PERF.md section 6, PR 28: 512 tokens cost the 1.5B 5 % and the 7B 20 %).
 DECODE_CHUNK_TOKENS = 256
+# Staged pages of new tokens on their way back to the pool: a slot's
+# write is waited for only when its staging page comes round again.
+DECODE_WRITE_BUFFERS = 4
 
 
 def _decode_sizes(B, KV, G, hd, page_size, pages_per_seq, kv_dtype, q_dtype):
@@ -214,13 +221,16 @@ def _decode_kernel(
     # inputs: q_ref [BS, KV, G, hd] VMEM block of this program's slots;
     # k/v_pages_ref [KV, P, ps, hd] in ANY/HBM (head-major), or
     # [L, KV, P, ps, hd] when has_layer.  `quant` (int8 KV) adds
-    # k/v_scale_ref [KV, P, ps] bf16 pools after them.
-    # outputs: out_ref [BS, KV, G, hd]
+    # k/v_scale_ref [KV, P, ps] bf16 pools after them; `write` adds
+    # k/v_new_ref [BS, KV, hd] VMEM blocks, the slots' new token.
+    # outputs: out_ref [BS, KV, G, hd]; `write` adds k/v_out_ref, the
+    # pools again (aliased onto the inputs).
     # scratch: k_buf/v_buf [DECODE_BUFFERS, KV, CP*ps, hd] VMEM (+ sk/sv_buf
-    # [DECODE_BUFFERS, KV, CP*ps] when quant), acc [KV, G, hd] f32, m/l
-    # [KV, G, 128] f32 running max/denom (col-broadcast), slots_ref
+    # [DECODE_BUFFERS, KV, CP*ps] when quant; + wk/wv_buf
+    # [DECODE_WRITE_BUFFERS, KV, ps, hd] when write), acc [KV, G, hd] f32,
+    # m/l [KV, G, 128] f32 running max/denom (col-broadcast), slots_ref
     # [6, BS + 1] int32 SMEM (the block's slots, see below), DMA sems, one
-    # a buffer
+    # a buffer (+ one a staged page when write)
     *refs,
     page_size: int,
     chunk_pages: int,
@@ -229,6 +239,7 @@ def _decode_kernel(
     scale: float,
     has_layer: bool,
     quant: bool,
+    write: bool,
 ):
     """One program serves a BLOCK of slots: its work list is the live
     chunks of those slots in order, and the next chunks' pages are in
@@ -246,19 +257,41 @@ def _decode_kernel(
     destinations), no DMA bounds checks (the page id is clamped instead),
     per-slot scalars computed once a program into SMEM, and merged
     waits.  The products take 8 query rows against 128-token weight
-    tiles, so the MXU's time is its weight loads: operands stay bf16."""
+    tiles, so the MXU's time is its weight loads: operands stay bf16.
+
+    With `write` the slot's new token (position length - 1) reaches the
+    pool from here and not through a scatter before the call: the pool
+    does not hold it yet, so the iteration that serves the slot's LAST
+    chunk puts the row into the fetched page where it lies in the
+    buffer, the products run over the buffer as ever, and that one page
+    goes back to the pool through a staging page, one descriptor for K
+    and one for V, all KV heads in each (a one-row descriptor is a
+    slice Mosaic refuses).  The page's other rows go back as they came.
+    That leans on the cache's invariant: THE PAGE A DECODE STEP WRITES
+    BELONGS TO THAT ONE SEQUENCE (the radix cache shares whole pages
+    only and copies a partial one before anyone appends to it), so no
+    other slot reads or writes it during the call.  A slot of length 0
+    writes nothing."""
+    k_scale_ref = v_scale_ref = sk_buf = sv_buf = None
+    k_new_ref = v_new_ref = k_out_ref = v_out_ref = None
+    wk_buf = wv_buf = wsems = None
     if quant:
         (
             q_ref, k_pages_ref, v_pages_ref, k_scale_ref, v_scale_ref,
             out_ref, k_buf, v_buf, sk_buf, sv_buf, acc_ref, m_ref, l_ref,
             slots_ref, sems,
         ) = refs
+    elif write:
+        (
+            q_ref, k_pages_ref, v_pages_ref, k_new_ref, v_new_ref,
+            out_ref, k_out_ref, v_out_ref, k_buf, v_buf, wk_buf, wv_buf,
+            acc_ref, m_ref, l_ref, slots_ref, sems, wsems,
+        ) = refs
     else:
         (
             q_ref, k_pages_ref, v_pages_ref,
             out_ref, k_buf, v_buf, acc_ref, m_ref, l_ref, slots_ref, sems,
         ) = refs
-        k_scale_ref = v_scale_ref = sk_buf = sv_buf = None
     BS, KV, G, _ = q_ref.shape
     num_pages = k_pages_ref.shape[2 if has_layer else 1]
     CP = chunk_pages
@@ -366,6 +399,46 @@ def _decode_kernel(
                 functools.partial(wait_pages, 1 << bit)
             )
 
+    def wait_write(stage):
+        """Wait for the two copies that left staging page `stage`."""
+        for buffer in (wk_buf, wv_buf):
+            src = buffer.at[stage]
+            pltpu.make_async_copy(src, src, wsems.at[stage]).wait()
+
+    def write_token(item, buf, written):
+        """The slot's new K and V row into its page, in the chunk buffer
+        (the products read it there) and back to the pool."""
+        j, c = item
+        b = jnp.minimum(base + j, batch - 1)
+        last = slots_ref[_PAGES, j] - 1  # the page that holds the row
+        off = slots_ref[_LEN, j] - 1 - last * page_size
+        rows = pl.ds(
+            pl.multiple_of((last - c * CP) * page_size, page_size), page_size
+        )
+        page_id = jnp.clip(page_tables_ref[b, last], 0, num_pages - 1)
+        is_new = jax.lax.broadcasted_iota(
+            jnp.int32, (page_size, 1), 0
+        ) == off
+        stage = jax.lax.rem(written, nwrite)
+        pl.when(written >= nwrite)(functools.partial(wait_write, stage))
+        for new_ref, buffer, staged, pool in (
+            (k_new_ref, k_buf, wk_buf, k_out_ref),
+            (v_new_ref, v_buf, wv_buf, v_out_ref),
+        ):
+            new = new_ref[j]  # [KV, hd]
+            for kv in range(KV):
+                page = jnp.where(
+                    is_new, new[kv:kv + 1], buffer[buf, kv, rows, :]
+                )
+                buffer[buf, kv, rows, :] = page
+                staged[stage, kv] = page
+            pltpu.make_async_copy(
+                staged.at[stage],
+                pool.at[layer, :, page_id] if has_layer
+                else pool.at[:, page_id],
+                wsems.at[stage],
+            ).start()
+
     def following(item):
         """The next item of the work list: the slot's next chunk, or the
         first chunk of the next live slot."""
@@ -375,6 +448,7 @@ def _decode_kernel(
         return j2, jnp.where(same, c + 1, slots_ref[_FIRST, j2])
 
     nbuf = k_buf.shape[0]
+    nwrite = wk_buf.shape[0] if write else 0
     D = nbuf - 1  # chunks in flight ahead of the one computed
     items = [(next_live, slots_ref[_FIRST, next_live])]
     for d in range(D):
@@ -385,7 +459,8 @@ def _decode_kernel(
     # float32, as their scales are)
     mxu = jnp.float32 if quant else k_buf.dtype
 
-    def body(i, items):
+    def body(i, carry):
+        items, written = carry
         j, c = items[0]
         buf = jax.lax.rem(i, nbuf)
         ahead = jax.lax.rem(i + D, nbuf)
@@ -395,6 +470,12 @@ def _decode_kernel(
                 functools.partial(start_chunk, items[D], s)
             )
         wait_chunk(items[0], buf)
+        last_chunk = c == slots_ref[_END, j] - 1
+        if write:
+            pl.when(last_chunk)(
+                functools.partial(write_token, items[0], buf, written)
+            )
+            written = written + last_chunk.astype(jnp.int32)
 
         sl, lo = slots_ref[_LEN, j], slots_ref[_LO, j]
         # the softmax state restarts at a slot boundary
@@ -457,15 +538,20 @@ def _decode_kernel(
             m_ref[kv] = jnp.broadcast_to(m_new, m_ref.shape[1:])
             l_ref[kv] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
-        @pl.when(c == slots_ref[_END, j] - 1)
+        @pl.when(last_chunk)
         def _():
             for kv in range(KV):
                 denom = jnp.maximum(l_ref[kv, :, :1], 1e-30)
                 out_ref[j, kv] = (acc_ref[kv] / denom).astype(out_ref.dtype)
 
-        return items[1:] + (following(items[D]),)
+        return items[1:] + (following(items[D]),), written
 
-    jax.lax.fori_loop(0, total, body, tuple(items))
+    _, written = jax.lax.fori_loop(
+        0, total, body, (tuple(items), jnp.int32(0))
+    )
+    # the pool is whole again before the program ends
+    for stage in range(nwrite):
+        pl.when(stage < written)(functools.partial(wait_write, stage))
 
 
 @functools.partial(
@@ -480,22 +566,37 @@ def paged_decode_attention_pallas(
     seq_lens: jnp.ndarray,  # [B]; 0 => the row holds nothing: zeros out
     window=None,  # int32 scalar; >0 => attend only to the last `window`
     layer=None,  # int32 scalar: pool layer index (carry-threaded decode)
+    k_new=None,  # [B, KV, hd]: the K and V of each slot's token at
+    v_new=None,  # seq_lens - 1, not in the pool yet (plain pools only)
     interpret: bool = False,
     softcap: float = 0.0,
     scale=None,  # static query scale; default hd**-0.5
-) -> jnp.ndarray:
+):
     """Single-token decode attention over the paged pool, [B, H, hd].
 
     The jnp twin ``ops.attention.paged_decode_attention`` pins the
     semantics, with two differences: a row of length 0 costs nothing and
     comes out ZERO (the twin has no such row), and q meets K in the
     pages' own float type (a float32 q over bf16 pages is rounded to
-    bf16, as the model's q already is)."""
+    bf16, as the model's q already is).
+
+    With ``k_new`` / ``v_new`` the kernel also WRITES each live slot's
+    new token into the pool (cast to the pool's type, at position
+    ``seq_lens - 1``: what ``kv_write_tokens`` would have put there
+    before the call) and attends to it, and returns ``(attention,
+    k_pages, v_pages)`` with the pools updated in place (donate them).
+    The page written must belong to that slot alone: ``_decode_kernel``."""
     from vgate_tpu.ops.kv_quant import is_quantized
 
     B, H, hd = q.shape
     has_layer = layer is not None
     quant = is_quantized(k_pages)
+    write = k_new is not None
+    if write and quant:
+        raise ValueError(
+            "the decode kernel writes plain pools only: an int8 pool's "
+            "token goes through kv_write_tokens before the call"
+        )
     k_data, k_scale = (
         (k_pages.data, k_pages.scale) if quant else (k_pages, None)
     )
@@ -527,6 +628,7 @@ def paged_decode_attention_pallas(
         scale=float(scale) if scale is not None else hd ** -0.5,
         has_layer=has_layer,
         quant=quant,
+        write=write,
     )
     # q is laid out [B, KV, G, hd] so a program's block covers the FULL
     # trailing (G, hd) dims — Mosaic requires trailing block dims either
@@ -548,6 +650,13 @@ def paged_decode_attention_pallas(
             pltpu.VMEM((DECODE_BUFFERS, KV, chunk_tokens), k_scale.dtype),
             pltpu.VMEM((DECODE_BUFFERS, KV, chunk_tokens), v_scale.dtype),
         ]
+    if write:
+        # the new tokens' staging pages (beside the budget's buffers, as
+        # the [BS, KV, hd] blocks of the tokens themselves are)
+        scratch += [
+            pltpu.VMEM((DECODE_WRITE_BUFFERS, KV, ps, hd), k_data.dtype),
+            pltpu.VMEM((DECODE_WRITE_BUFFERS, KV, ps, hd), v_data.dtype),
+        ]
     scratch += [
         pltpu.VMEM((KV, G, hd), jnp.float32),
         pltpu.VMEM((KV, G, 128), jnp.float32),
@@ -555,21 +664,43 @@ def paged_decode_attention_pallas(
         pltpu.SMEM((6, BS + 1), jnp.int32),
         pltpu.SemaphoreType.DMA((DECODE_BUFFERS,)),
     ]
+    inputs = [q.reshape(B, KV, G, hd), k_data, v_data]
+    in_specs = [block, any_spec, any_spec]
+    out_specs = block
+    out_shape = jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype)
+    aliases = {}
+    if quant:
+        inputs += [k_scale, v_scale]
+        in_specs += [any_spec, any_spec]
+    if write:
+        scratch.append(pltpu.SemaphoreType.DMA((DECODE_WRITE_BUFFERS,)))
+        new_block = pl.BlockSpec(
+            (BS, KV, hd), lambda bb, *prefetch: (bb, 0, 0),
+            memory_space=pltpu.VMEM,
+        )
+        inputs += [k_new.astype(k_data.dtype), v_new.astype(v_data.dtype)]
+        in_specs += [new_block, new_block]
+        out_specs = [block, any_spec, any_spec]
+        out_shape = [
+            out_shape,
+            jax.ShapeDtypeStruct(k_data.shape, k_data.dtype),
+            jax.ShapeDtypeStruct(v_data.shape, v_data.dtype),
+        ]
+        # operands count from the scalar-prefetch arguments: the pools
+        # are outputs 1 and 2, in place
+        aliases = {5: 1, 6: 2}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(cdiv(B, BS),),
-        in_specs=[block, any_spec, any_spec]
-        + ([any_spec, any_spec] if quant else []),
-        out_specs=block,
+        in_specs=in_specs,
+        out_specs=out_specs,
         scratch_shapes=scratch,
     )
-    inputs = [q.reshape(B, KV, G, hd), k_data, v_data]
-    if quant:
-        inputs += [k_scale, v_scale]
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024,
@@ -578,6 +709,8 @@ def paged_decode_attention_pallas(
             disable_bounds_checks=True,
         ),
     )(page_tables, seq_lens, window_arr, layer_arr, *inputs)
+    if write:
+        return out[0].reshape(B, H, hd), out[1], out[2]
     return out.reshape(B, H, hd)
 
 

@@ -63,6 +63,7 @@ from vgate_tpu.config import (
 from vgate_tpu.logging_config import bound_request, get_logger
 from vgate_tpu.models.decoder import (
     decode_attention_impl,
+    decode_kv_write,
     multitok_attention_impl,
     prefill_attention_impl,
 )
@@ -2915,6 +2916,13 @@ class EngineCore:
             headroom = min(headroom, max(1, self.decode_chunk // 8))
         return 1 << (headroom.bit_length() - 1)
 
+    def _decode_kv_write(self) -> str:
+        """Who writes a decode step's K/V, as ``decode_forward`` traces
+        it for this engine: "kernel" or "scatter"."""
+        return decode_kv_write(
+            self.spec, self.use_pallas, self._attn_mesh, self._kv_quant
+        )
+
     @engine_thread_only
     def _dispatch_chunk(self, active: List[Sequence], chunk: int) -> None:
         faults.check("decode_step")
@@ -2933,6 +2941,7 @@ class EngineCore:
                 "ctx_tokens": sum(s.total_len for s in active),
                 # steps in flight that ctx_tokens does not hold yet
                 "lead": sum(c[1] for c in self._pending_chunks),
+                "kv_write": self._decode_kv_write(),
             },
             chunk=chunk, batch=len(active),
         ):
@@ -3783,6 +3792,8 @@ class EngineCore:
                 prog: sorted(impls)
                 for prog, impls in self._attention.items()
             },
+            # the decode kernel or XLA's scatter before it
+            "kv_write": self._decode_kv_write(),
             "load_time_s": round(self.load_time_s, 2),
             # what each chip of the mesh itself reports (empty on CPU)
             "device_memory": [
