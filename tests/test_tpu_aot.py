@@ -164,6 +164,43 @@ def test_gated_delta_step_kernel_compiles_for_v5e(v5e):
     assert mem.temp_size_in_bytes < state_bytes // 8
 
 
+def test_ssd_step_kernel_compiles_for_v5e(v5e):
+    """The Mamba-2 step kernel at Nemotron-H's sizes (192 slots, 128
+    heads of 64 x 128 float32 in 8 groups), the state updated in place."""
+    from vgate_tpu.ops.pallas.ssd import ssd_step_pallas
+
+    A = _abstract(v5e)
+    B, H, P, G, N, layers = 192, 128, 64, 8, 128, 5
+    f32 = jnp.float32
+    state_bytes = layers * B * H * P * N * 4
+    compiled = ssd_step_pallas.lower(
+        A((B, H, P), f32), A((B, H), f32), A((B, G, N), f32),
+        A((B, G, N), f32), A((layers, B, H, P, N), f32), A((), jnp.int32),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state_bytes, "the state is copied"
+    assert mem.temp_size_in_bytes < state_bytes // 8
+
+
+@pytest.mark.parametrize(
+    "rows, k, n, tm",
+    [(4224, 1024, 2688, 128), (4224, 2688, 1024, 128)],
+    ids=["latent-up", "latent-down"],
+)
+def test_latent_grouped_product_compiles_for_v5e(v5e, rows, k, n, tm):
+    """The grouped product at the latent experts' widths, through
+    ``grouped_product``'s own tile choice: 512 does not divide 2,688."""
+    from vgate_tpu.ops.moe import grouped_product
+
+    A = _abstract(v5e)
+    jax.jit(
+        lambda r, w, g, l: grouped_product(r, w, g, l, True)
+    ).lower(
+        A((rows, k), jnp.bfloat16), A((5, 128, k, n), jnp.bfloat16),
+        A((128,), jnp.int32), A((), jnp.int32),
+    ).compile()
+
+
 @pytest.mark.parametrize(
     "rows, k, n, tm, tn",
     [(2560, 2048, 512, 32, 512), (2560, 512, 2048, 32, 2048),
@@ -342,3 +379,47 @@ def test_prefill_step_holds_one_pool_on_v5e(v5e):
         seeds=A((B,), jnp.int32), steps=A((B,), jnp.int32),
     ).compile()
     _assert_one_pool(compiled, pool_bytes)
+
+
+def test_pattern_stack_decode_chunk_holds_one_state_on_v5e(v5e):
+    """The Nemotron-H cut as the cell serves it (one period
+    ``EMEMEMEMEM*``, 128 experts held, 192 slots): the decode chunk
+    compiles for the v5e with the Mamba-2 state (4.1 GB) and the pools
+    aliased input to output, and temporaries far under the state."""
+    import dataclasses
+
+    from vgate_tpu.models.decoder import init_params
+    from vgate_tpu.models.hybrid import make_state
+    from vgate_tpu.runtime.step_programs import _decode_chunk
+
+    A = _abstract(v5e)
+    spec = dataclasses.replace(
+        spec_for_model_id("nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16"),
+        name="nemotron-cut", num_layers=11, layer_pattern="EMEMEMEMEM*",
+        num_experts=128, vocab_size=32768, eos_token_id=32767,
+        bos_token_id=32766, extra_stop_ids=())
+    abstract = lambda tree: jax.tree.map(
+        lambda x: A(x.shape, x.dtype), jax.eval_shape(tree))
+    params = abstract(
+        lambda: init_params(spec, jax.random.PRNGKey(0), jnp.bfloat16))
+    B, ctx = 192, 2048
+    state = abstract(lambda: make_state(spec, B, jnp.bfloat16))
+    state_bytes = sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
+    assert abs(state_bytes - 4.0855e9) < 1e6
+    pool = A((spec.attn_layers, spec.num_kv_heads, 12289, PAGE,
+              spec.head_dim), jnp.bfloat16)
+    compiled = _decode_chunk.lower(
+        params, spec, A((B,), jnp.int32), A((B,), jnp.int32), pool, pool,
+        A((B, ctx // PAGE), jnp.int32), A((B,), jnp.bool_),
+        A((B,), jnp.float32), A((B,), jnp.float32), A((B,), jnp.int32),
+        A((2,), jnp.uint32), A((), jnp.uint32),
+        num_steps=8, use_pallas=True, max_position=ctx - 1,
+        seeds=A((B,), jnp.int32), steps=A((B,), jnp.int32),
+        all_greedy=True, guard=True, state=state,
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state_bytes, "the state is copied"
+    assert mem.temp_size_in_bytes < state_bytes // 8
+    text = compiled.as_text()
+    assert "ssd_step_pallas" in text and "moe_grouped_matmul_pallas" in text

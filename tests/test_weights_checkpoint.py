@@ -465,3 +465,118 @@ def test_qwen3_next_checkpoint_equals_the_reference(tmp_path, first, held):
         assert diffs and max(diffs) < 10 * TOL, max(diffs)
     finally:
         core.stop()
+
+
+# ------------------------------------------------- NemotronHForCausalLM
+
+def _nemotron_h_tensors(cfg, seed=13):
+    """Random tensors for tiny-nemotron-h TWICE: as the plain reference
+    takes them (one dict a layer) and under the checkpoint's names and
+    layouts (torch [out, in]; ``in_proj`` as ``[z | x | B | C | dt]``;
+    ``conv1d.weight`` ``[channels, 1, taps]``; every layer's sub-block
+    under ``mixer``, whatever its kind)."""
+    from perfbench.references import nemotron_h as ref
+
+    z = ref.sizes(cfg)
+    rng = np.random.default_rng(seed)
+    rand = lambda *shape, scale=0.05: (
+        rng.normal(size=shape) * scale).astype(np.float32)
+    D = z["D"]
+    plain = {"embed": rand(z["V"], D, scale=0.5), "lm_head": rand(D, z["V"]),
+             "final_norm": 1.0 + rand(D, scale=0.2), "layers": []}
+    hf = {
+        "backbone.embeddings.weight": plain["embed"],
+        "backbone.norm_f.weight": plain["final_norm"],
+        "lm_head.weight": plain["lm_head"].T,
+    }
+    scales = {"router": 0.5, "conv": 0.5}
+    for i, kind in enumerate(ref.kinds(cfg)):
+        w = {name: rand(*shape, scale=scales.get(name, 0.05))
+             for name, shape in ref.layer_shapes(z, kind).items()}
+        w["norm"] = 1.0 + rand(D, scale=0.2)
+        pre = f"backbone.layers.{i}."
+        hf[pre + "norm.weight"] = w["norm"]
+        if kind == "mamba":
+            w.update({
+                "a_log": np.log(rng.uniform(1, 16, z["Hm"])).astype(
+                    np.float32),
+                "dt_bias": rand(z["Hm"], scale=1.0) - 3.0,
+                "d": 1.0 + rand(z["Hm"], scale=0.3),
+                "ssm_norm": 1.0 + rand(z["di"], scale=0.2),
+            })
+            hf[pre + "mixer.in_proj.weight"] = w["in_proj"].T
+            hf[pre + "mixer.conv1d.weight"] = w["conv"][:, None, :]
+            hf[pre + "mixer.conv1d.bias"] = w["conv_bias"]
+            hf[pre + "mixer.A_log"] = w["a_log"]
+            hf[pre + "mixer.D"] = w["d"]
+            hf[pre + "mixer.dt_bias"] = w["dt_bias"]
+            hf[pre + "mixer.norm.weight"] = w["ssm_norm"]
+            hf[pre + "mixer.out_proj.weight"] = w["out"].T
+        elif kind == "attn":
+            for name in "qkvo":
+                hf[f"{pre}mixer.{name}_proj.weight"] = w[name].T
+        else:
+            w["router_bias"] = rand(z["R"], scale=0.1)
+            hf[pre + "mixer.gate.weight"] = w["router"].T
+            hf[pre + "mixer.gate.e_score_correction_bias"] = w["router_bias"]
+            for e in range(z["E"]):
+                hf[f"{pre}mixer.experts.{e}.up_proj.weight"] = w["up"][e].T
+                hf[f"{pre}mixer.experts.{e}.down_proj.weight"] = (
+                    w["down"][e].T)
+            hf[pre + "mixer.shared_experts.up_proj.weight"] = w["shared_up"].T
+            hf[pre + "mixer.shared_experts.down_proj.weight"] = (
+                w["shared_down"].T)
+            hf[pre + "mixer.fc1_latent_proj.weight"] = w["latent_in"].T
+            hf[pre + "mixer.fc2_latent_proj.weight"] = w["latent_out"].T
+        plain["layers"].append(w)
+    return plain, {k: np.ascontiguousarray(v) for k, v in hf.items()}
+
+
+@pytest.mark.parametrize("first, held", [(0, 8), (2, 4)],
+                         ids=["whole", "a-share-of-the-experts"])
+def test_nemotron_h_checkpoint_equals_the_reference(tmp_path, first, held):
+    """A synthetic ``NemotronHForCausalLM`` safetensors file through the
+    loader and the engine against the plain reference fed the same
+    tensors: whole, and holding experts 2..5 of the router's 8."""
+    import dataclasses
+
+    from safetensors.numpy import save_file
+
+    from perfbench.references import nemotron_h as ref
+    from tests.test_nemotron_h_model import (
+        TINY, TOL_F32, engine_config, lp_params)
+    from vgate_tpu.models.specs import spec_for_model_id
+    from vgate_tpu.runtime.engine_core import EngineCore
+
+    plain, hf = _nemotron_h_tensors(TINY)
+    ckpt = tmp_path / "ckpt"
+    ckpt.mkdir()
+    save_file(hf, str(ckpt / "model.safetensors"))
+    spec = dataclasses.replace(
+        spec_for_model_id("tiny-nemotron-h"), num_experts=held,
+        first_expert=first)
+    cfg = dict(TINY, n_routed_experts=held, router_width=8,
+               first_expert=first)
+    cut = lambda w: dict(w, **{
+        name: w[name][first:first + held] for name in ("up", "down")
+        if name in w})
+    weights = jax.tree.map(jnp.asarray, dict(
+        plain, layers=[cut(w) for w in plain["layers"]]))
+
+    config = engine_config()
+    config.model.checkpoint_path = str(ckpt)
+    core = EngineCore(config, spec=spec, devices=jax.devices()[:1])
+    core.start()
+    try:
+        prompt = [int(t) for t in
+                  np.random.default_rng(0).integers(3, 259, size=13)]
+        seq = core.submit_tokens(prompt, lp_params(5))
+        assert seq.done_event.wait(timeout=600) and seq.error is None
+        want = ref.logprobs(cfg, weights, [prompt + seq.generated_ids],
+                            [len(prompt)])[0]
+        diffs = [abs(t["logprob"] - want[pos, t["token_id"]])
+                 for pos, e in enumerate(core.logprob_entries(seq))
+                 for t in e["top_logprobs"]]
+        assert diffs and max(diffs) < 4 * TOL_F32, max(diffs)
+    finally:
+        core.stop()

@@ -148,10 +148,12 @@ def _project_qkv(x, lp, spec: ModelSpec):
 
 
 def _act(x32, spec: ModelSpec):
-    """MLP activation in fp32: SiLU (Qwen/Llama/Mixtral) or tanh-approx
-    GELU (Gemma's ``gelu_pytorch_tanh``)."""
+    """MLP activation in fp32: SiLU (Qwen/Llama/Mixtral), tanh-approx
+    GELU (Gemma's ``gelu_pytorch_tanh``) or squared ReLU."""
     if spec.act == "gelu_tanh":
         return jax.nn.gelu(x32, approximate=True)
+    if spec.act == "relu2":  # Nemotron-H's experts: relu(.)^2
+        return jnp.square(jax.nn.relu(x32))
     return jax.nn.silu(x32)
 
 

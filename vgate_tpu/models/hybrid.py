@@ -1,34 +1,52 @@
-"""The layer stack of two kinds (Qwen3-Next): Gated DeltaNet linear
-attention in three layers of four, gated softmax attention in the
-fourth, an expert layer behind each.
+"""The layer stack of several kinds: a period of residual sub-blocks
+``h <- h + f(norm(h))``, each ONE of a few kinds, given by the spec as
+data (``ModelSpec.period_blocks``):
+
+* ``gdn``   Gated DeltaNet linear attention (Qwen3-Next),
+* ``mamba`` a Mamba-2 state-space layer (Nemotron-H),
+* ``attn``  softmax attention over the paged pool (gated, with per-head
+  norms and partial rotary, or plain without rotary: the spec says),
+* ``moe``   the expert layer of ``ops/moe.py``.
+
+Qwen3-Next's layer is two sub-blocks (a mixer, then experts); its period
+is ``gdn moe gdn moe gdn moe attn moe``.  A Nemotron-H layer is one:
+``EMEMEMEMEM*`` is ``moe mamba`` five times, then ``attn``.
 
 ``models/decoder.py``'s forwards hand a hybrid spec's work here after
 they have chosen the attention implementation, so the step programs,
 their cache threading and the kernels are the dense models' own.  What
 differs:
 
-* the layer scan walks PERIODS (``spec.full_attention_interval`` layers:
-  the linear ones unrolled, then the full one), with the K/V pools of
-  the full layers only (``[periods, KV, P, ps, hd]``) and the recurrent
-  state of the linear ones riding the carry, all updated in place;
-* the recurrent state (``{"S": [Lr, slots, Hv, dk, dv] float32, "conv":
-  [Lr, slots, K-1, C]}``) is indexed by decode SLOT.  A prompt pass
-  starts from zeros (or, for a later chunk of a chunked prefill, from
-  the slot's row), runs the chunk-wise recurrence and overwrites the
-  row whole; a decode step updates the rows of active slots in place and
-  leaves idle rows alone.  Padded prompt positions get ``g = 0, beta =
-  0`` and the convolution tail is taken at the row's real length, so a
+* ONE walker (``_period_scan``) scans PERIODS and, inside one, runs the
+  sub-blocks in order: a unit of sub-blocks that repeats (``gdn moe`` x
+  3, ``moe mamba`` x 5) as an inner scan, one body compiled for all its
+  repeats, the rest unrolled.  The K/V pools of the attention sub-blocks
+  only (``[attention layers, KV, P, ps, hd]``) and the recurrent state
+  of the recurrent ones ride the carry, all updated in place;
+* the recurrent state (``{"S": [Lr, slots, heads, ., .] float32, "conv":
+  [Lr, slots, K-1, C]}``: ``[32, 128, 128]`` tiles over 8,192 channels
+  for ``gdn``, ``[128, 64, 128]`` over 10,240 for ``mamba`` at the
+  published sizes) is indexed by decode SLOT.  A prompt pass starts from
+  zeros (or, for a later chunk of a chunked prefill, from the slot's
+  row), runs the chunk-wise recurrence and overwrites the row whole; a
+  decode step updates the rows of active slots in place and leaves idle
+  rows alone.  Padded prompt positions get ``g = 0, beta = 0`` (``dt =
+  0``) and the convolution tail is taken at the row's real length, so a
   bucket's padding never moves the state;
 * the experts' matrices never ride the scan's per-period slices: the
   grouped product's kernel takes the full stack and a layer index.
 
-Parameters (``init_params``): ``layers = {"linear": {... [P, n, ...]},
-"full": {... [P, ...]}}`` with ``P`` periods and ``n`` linear layers a
-period.
+Parameters (``init_layers``): ``layers = {group: {name: [P, n, ...]}}``
+with ``P`` periods and ``n`` layers of the group a period.  Qwen3-Next's
+groups are ``linear`` (a Gated DeltaNet layer and its experts) and
+``full`` (the attention layer and its experts, ``[P, ...]``: it is one a
+period); a pattern's groups are its kinds, ``mamba`` / ``attn`` /
+``moe``.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict
 
 import jax
@@ -36,19 +54,112 @@ import jax.numpy as jnp
 
 from vgate_tpu.models.specs import ModelSpec
 from vgate_tpu.ops import gated_delta as gd
+from vgate_tpu.ops import ssd
 from vgate_tpu.ops.kv_quant import kv_write_pages
 from vgate_tpu.ops.moe import STAT_NAMES, combine_stats, expert_layer
 from vgate_tpu.ops.norms import rms_norm
 from vgate_tpu.ops.rope import apply_rope
 
-EXPERT_STACKS = ("gate", "up", "down")
-
 
 def init_layers(spec: ModelSpec, key, dtype, normal, norm_init
                 ) -> Dict[str, Any]:
-    """Random draw of the hybrid family's layer tensors, from keys of
-    its own (``fold_in(key, 27)`` split 32 ways: the dense and Mixtral
-    draws stay what they were).  ``dt_bias`` is drawn so that a head's
+    """Random draw of a hybrid spec's layer tensors, each family from
+    keys of its own."""
+    if spec.layer_pattern:
+        return _init_pattern_layers(spec, key, dtype, normal)
+    return _init_paired_layers(spec, key, dtype, normal, norm_init)
+
+
+def _init_pattern_layers(spec: ModelSpec, key, dtype, normal
+                         ) -> Dict[str, Any]:
+    """A ``layer_pattern`` spec's tensors from ``fold_in(key, 31)`` split
+    32 ways.  Every tensor is drawn a LAYER at a time (``fold_in(tensor's
+    key, layer of its group)``), so that no draw stands wider than one
+    layer's tensor.  The published initialisation where it matters:
+    ``A_log = log(U[1, 16])``, ``dt_bias`` the inverse softplus of a step
+    drawn log-uniformly in [0.001, 0.1], ``D = 1`` (per-step decays from
+    about 0.2 to 0.9999 across heads, so that a wrong or a stale state
+    shows); the router's selection bias N(0, 0.02) and NOT zero, so that
+    a program that forgets it, or lets it into the weights, differs."""
+    nk = jax.random.split(jax.random.fold_in(key, 31), 32)
+    D, P = spec.hidden_size, spec.num_periods
+    H, KV, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
+    E, R, Fe, W = (spec.num_experts, spec.router_experts, spec.expert_width,
+                   spec.expert_in)
+    Fs = spec.shared_expert_intermediate_size
+    Hm, di, C = spec.mamba_num_heads, spec.mamba_inner, spec.mamba_conv_dim
+    f32 = jnp.float32
+
+    def per_layer(group, fn):
+        """fn(key) -> one layer's tensor; returns key -> the group's
+        ``[P, n, ...]``, one fused program that draws a layer at a time."""
+        n = spec.group_layers(group)
+        return jax.jit(lambda k: jax.lax.map(
+            lambda i: fn(jax.random.fold_in(k, i)), jnp.arange(P * n)
+        ).reshape((P, n) + jax.eval_shape(fn, k).shape))
+
+    def draw(group, k, shape, scale=0.02):
+        return per_layer(group, lambda kk: normal(kk, shape, scale))(k)
+
+    out: Dict[str, Any] = {}
+    if spec.group_layers("mamba"):
+        lead = (P, spec.group_layers("mamba"))
+        step = lambda k: jnp.maximum(1e-4, jnp.exp(
+            jnp.log(1e-3) + jax.random.uniform(k, (Hm,))
+            * (jnp.log(0.1) - jnp.log(1e-3))))
+        out["mamba"] = {
+            "norm": jnp.ones(lead + (D,), dtype),
+            "in_proj": {"w": draw("mamba", nk[0], (D, di + C + Hm))},
+            "conv": draw("mamba", nk[1], (C, spec.mamba_conv_kernel), 0.5),
+            "a_log": per_layer("mamba", lambda k: jnp.log(
+                jax.random.uniform(k, (Hm,), f32, 1.0, 16.0)))(nk[3]),
+            "dt_bias": per_layer("mamba", lambda k: jnp.log(
+                jnp.expm1(step(k))).astype(f32))(nk[4]),
+            "d": jnp.ones(lead + (Hm,), f32),
+            "ssm_norm": jnp.ones(lead + (di,), dtype),
+            "out": {"w": draw("mamba", nk[5], (di, D))},
+        }
+        if spec.mamba_conv_bias:
+            out["mamba"]["conv_bias"] = draw("mamba", nk[2], (C,))
+    if spec.group_layers("attn"):
+        out["attn"] = {
+            "norm": jnp.ones((P, spec.group_layers("attn"), D), dtype),
+            "q": {"w": draw("attn", nk[8], (D, H * hd))},
+            "k": {"w": draw("attn", nk[9], (D, KV * hd))},
+            "v": {"w": draw("attn", nk[10], (D, KV * hd))},
+            "o": {"w": draw("attn", nk[11], (H * hd, D))},
+        }
+    if spec.group_layers("moe"):
+        moe = {
+            "norm": jnp.ones((P, spec.group_layers("moe"), D), dtype),
+            "router": draw("moe", nk[16], (D, R)),
+            "up": {"w": draw("moe", nk[20], (E, W, Fe))},
+            "down": {"w": draw("moe", nk[21], (E, Fe, W))},
+        }
+        if spec.router_scoring == "sigmoid":
+            moe["router_bias"] = per_layer("moe", lambda k: (
+                jax.random.normal(k, (R,), f32) * 0.02))(nk[17])
+        if spec.moe_latent_size:
+            moe["latent_in"] = {"w": draw("moe", nk[18], (D, W))}
+            moe["latent_out"] = {"w": draw("moe", nk[19], (W, D))}
+        if Fs:
+            moe["shared_up"] = {"w": draw("moe", nk[22], (D, Fs))}
+            moe["shared_down"] = {"w": draw("moe", nk[23], (Fs, D))}
+        if spec.moe_gated:
+            moe["gate"] = {"w": draw("moe", nk[24], (E, W, Fe))}
+            if Fs:
+                moe["shared_gate"] = {"w": draw("moe", nk[25], (D, Fs))}
+        if Fs and spec.shared_expert_gate:
+            moe["shared_router"] = draw("moe", nk[26], (D,))
+        out["moe"] = moe
+    return out
+
+
+def _init_paired_layers(spec: ModelSpec, key, dtype, normal, norm_init
+                ) -> Dict[str, Any]:
+    """Random draw of Qwen3-Next's layer tensors, from keys of its own
+    (``fold_in(key, 27)`` split 32 ways: the dense and Mixtral draws
+    stay what they were).  ``dt_bias`` is drawn so that a head's
     per-step decay ``exp(g)`` lies log-uniformly between about 0.5 and
     0.999 (at ``a = 0``): with every head forgetting in a token or two,
     neither a wrong state nor a stale one could show in a comparison."""
@@ -110,44 +221,49 @@ def init_layers(spec: ModelSpec, key, dtype, normal, norm_init
     return {"linear": linear, "full": full}
 
 
+def _state_shapes(spec: ModelSpec):
+    """(a slot's tile [heads, ., .] in one recurrent layer, its
+    convolution tail [K-1, C]), by the kind of the recurrent layers."""
+    if spec.recurrent_kind == "mamba":
+        return ((spec.mamba_num_heads, spec.mamba_head_dim,
+                 spec.mamba_state_size),
+                (spec.mamba_conv_kernel - 1, spec.mamba_conv_dim))
+    return ((spec.linear_num_value_heads, spec.linear_key_head_dim,
+             spec.linear_value_head_dim),
+            (spec.linear_conv_kernel_dim - 1, spec.linear_conv_dim))
+
+
 def make_state(spec: ModelSpec, slots: int, dtype) -> Dict[str, jax.Array]:
-    """The recurrent state of every linear layer, zeros, one row a slot."""
-    Lr = spec.linear_layers
-    return {
-        "S": jnp.zeros(
-            (Lr, slots, spec.linear_num_value_heads,
-             spec.linear_key_head_dim, spec.linear_value_head_dim),
-            jnp.float32,
-        ),
-        "conv": jnp.zeros(
-            (Lr, slots, spec.linear_conv_kernel_dim - 1,
-             spec.linear_conv_dim), dtype,
-        ),
-    }
+    """The recurrent state of every recurrent layer, zeros, one row a
+    slot."""
+    tile, tail = _state_shapes(spec)
+    lead = (spec.linear_layers, slots)
+    return {"S": jnp.zeros(lead + tile, jnp.float32),
+            "conv": jnp.zeros(lead + tail, dtype)}
 
 
 def state_bytes_per_slot(spec: ModelSpec, dtype_bytes: int) -> int:
-    """Bytes one slot's row holds over all linear layers."""
-    tile = (spec.linear_num_value_heads * spec.linear_key_head_dim
-            * spec.linear_value_head_dim * 4)
-    tail = ((spec.linear_conv_kernel_dim - 1) * spec.linear_conv_dim
-            * dtype_bytes)
-    return spec.linear_layers * (tile + tail)
+    """Bytes one slot's row holds over all recurrent layers."""
+    tile, tail = _state_shapes(spec)
+    return spec.linear_layers * (
+        math.prod(tile) * 4 + math.prod(tail) * dtype_bytes)
 
 
 def _rope(x, positions, spec: ModelSpec):
+    if not spec.use_rope:
+        return x
     return apply_rope(x, positions, spec.rope_theta, spec.rope_scaling,
                       rotary_dim=spec.rotary_dim)
 
 
 @jax.named_scope("qkv")
-def _gated_qkv(h, lp, spec: ModelSpec, positions):
-    """Full-attention front half: norm, q (with its gate beside it, per
-    head ``[query | gate]``), k, v, per-head norms on q and k, partial
-    rope.  h: [..., S, D] with positions [..., S]."""
+def _gated_qkv(normed, lp, spec: ModelSpec, positions):
+    """Attention front half on the normed rows: q (with its gate beside
+    it, per head ``[query | gate]``, where the spec has one), k, v,
+    per-head norms on q and k, rope, each where the spec says.  normed:
+    [..., S, D] with positions [..., S]."""
     eps, uo = spec.rms_eps, spec.unit_offset_norm
     H, KV, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
-    normed = rms_norm(h, lp["input_norm"], eps, uo)
     q = jnp.einsum("...d,dh->...h", normed, lp["q"]["w"])
     k = jnp.einsum("...d,dh->...h", normed, lp["k"]["w"])
     v = jnp.einsum("...d,dh->...h", normed, lp["v"]["w"])
@@ -174,24 +290,10 @@ def _gated_out(attn, gate, lp, dtype):
     return jnp.einsum("...h,hd->...d", attn, lp["o"]["w"])
 
 
-def _finish(h, mixer_out, lp, spec: ModelSpec, row_mask, use_pallas,
-            layer, stack):
-    h = h + mixer_out.astype(h.dtype)
-    normed = rms_norm(h, lp["post_norm"], spec.rms_eps,
-                      spec.unit_offset_norm)
-    out, stats = expert_layer(
-        normed, lp, spec, jax.nn.silu, row_mask=row_mask, use_pallas=use_pallas,
-        layer=layer, stack=stack,
-    )
-    return h + out, stats
-
-
-def _linear_inputs(h, lp, spec: ModelSpec):
-    """Norm and the two input projections of a linear layer: the
+def _linear_inputs(normed, lp, spec: ModelSpec):
+    """The two input projections of a Gated DeltaNet layer: the
     pre-convolution (q, k, v) channels, z, b and a."""
     C, Hv = spec.linear_conv_dim, spec.linear_num_value_heads
-    normed = rms_norm(h, lp["input_norm"], spec.rms_eps,
-                      spec.unit_offset_norm)
     qkvz = jnp.einsum("...d,dc->...c", normed, lp["in_qkvz"]["w"])
     ba = jnp.einsum("...d,dc->...c", normed, lp["in_ba"]["w"])
     return qkvz[..., :C], qkvz[..., C:], ba[..., :Hv], ba[..., Hv:]
@@ -222,16 +324,17 @@ def _linear_out(o, z, lp, spec: ModelSpec, dtype):
     return jnp.einsum("...v,vd->...d", o, lp["out"]["w"])
 
 
-# prompt tokens a linear layer's mixer takes at once: its float32
+# prompt tokens a recurrent layer's mixer takes at once: its float32
 # temporaries are ~12 x tokens x value heads x head size x 4 bytes
 PROMPT_BLOCK_TOKENS = 4096
 
 
-def _linear_rows(h, lp, spec: ModelSpec, lens, tail, S0):
-    """The mixer over rows h [B, S, D] from (tail, S0): returns (out,
-    final state, final tail).  Padded positions move nothing."""
-    S = h.shape[1]
-    qkv, z, b, a = _linear_inputs(h, lp, spec)
+def _linear_rows(normed, lp, spec: ModelSpec, lens, tail, S0):
+    """The Gated DeltaNet mixer over normed rows [B, S, D] from (tail,
+    S0): returns (out, final state, final tail).  Padded positions move
+    nothing."""
+    S = normed.shape[1]
+    qkv, z, b, a = _linear_inputs(normed, lp, spec)
     with jax.named_scope("conv"):
         y, new_tail = gd.causal_conv(qkv, tail, lp["conv"], lens)
     q, k, v = _linear_heads(y, spec)
@@ -239,23 +342,125 @@ def _linear_rows(h, lp, spec: ModelSpec, lens, tail, S0):
     valid = (jnp.arange(S)[None, :] < lens[:, None])[..., None]
     g, beta = jnp.where(valid, g, 0.0), jnp.where(valid, beta, 0.0)
     o, S1 = gd.gated_delta_chunked(q, k, v, g, beta, S0)
-    return _linear_out(o, z, lp, spec, h.dtype), S1, new_tail
+    return _linear_out(o, z, lp, spec, normed.dtype), S1, new_tail
 
 
-def _linear_prompt(h, lp, st, li, spec: ModelSpec, lens, slots, fresh):
-    """A linear layer's mixer over prompt rows h [B, S, D]: starts from
-    the slot's row (zeros where ``fresh``), ends with the row
-    overwritten whole.  A wide wave goes through in groups of rows."""
-    with jax.named_scope("linear_attn"):
-        B, S = h.shape[:2]
+def _conv_step(tail, row, w, bias, active):
+    """The causal convolution for one decode step: tail [B, K-1, C] the
+    rows before ``row`` [B, C].  Returns (SiLU(conv) [B, C] in the row's
+    type, the tail moved on by one row where ``active``)."""
+    with jax.named_scope("conv"):
+        cat = jnp.concatenate([tail, row[:, None].astype(tail.dtype)], 1)
+        y = jnp.einsum("bkc,ck->bc", cat.astype(jnp.float32),
+                       w.astype(jnp.float32))
+        if bias is not None:
+            y = y + bias.astype(jnp.float32)
+        new_tail = jnp.where(active[:, None, None], cat[:, 1:], tail)
+    return jax.nn.silu(y).astype(row.dtype), new_tail
+
+
+def _linear_step(normed, lp, st, li, spec: ModelSpec, active, use_pallas):
+    """A Gated DeltaNet mixer for one decode step, normed [B, D], row =
+    slot."""
+    qkv, z, b, a = _linear_inputs(normed, lp, spec)
+    y, new_tail = _conv_step(st["conv"][li], qkv, lp["conv"], None, active)
+    conv = st["conv"].at[li].set(new_tail)
+    q, k, v = _linear_heads(y, spec)
+    g, beta = gd.gates(a, b, lp["a_log"], lp["dt_bias"])
+    g = jnp.where(active[:, None], g, 0.0)
+    beta = jnp.where(active[:, None], beta, 0.0)
+    o, S = gd.gated_delta_step(q, k, v, g, beta, st["S"], li,
+                               use_pallas=use_pallas)
+    out = _linear_out(o, z, lp, spec, normed.dtype)
+    return out, {"S": S, "conv": conv}
+
+
+def _mamba_inputs(normed, lp, spec: ModelSpec):
+    """The input projection of a Mamba-2 layer, ``[z | x B C | dt]``:
+    z, the pre-convolution channels and dt."""
+    di, C = spec.mamba_inner, spec.mamba_conv_dim
+    proj = jnp.einsum("...d,dc->...c", normed, lp["in_proj"]["w"])
+    return proj[..., :di], proj[..., di:di + C], proj[..., di + C:]
+
+
+def _mamba_heads(y, dt, lp, spec: ModelSpec, valid):
+    """Post-convolution channels -> x [..., H, P], B and C [..., G, N];
+    the step ``softplus(dt + dt_bias)`` in float32, 0 where not
+    ``valid``; and the heads' negative ``A``."""
+    H, P = spec.mamba_num_heads, spec.mamba_head_dim
+    G, N, di = spec.mamba_n_groups, spec.mamba_state_size, spec.mamba_inner
+    lead = y.shape[:-1]
+    x = y[..., :di].reshape(*lead, H, P)
+    Bm = y[..., di:di + G * N].reshape(*lead, G, N)
+    Cm = y[..., di + G * N:].reshape(*lead, G, N)
+    step = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
+    A = -jnp.exp(lp["a_log"].astype(jnp.float32))
+    return x, Bm, Cm, jnp.where(valid, step, 0.0), A
+
+
+def _mamba_out(y, x, z, lp, spec: ModelSpec, dtype):
+    """The skip ``D x``, the gate FIRST (``y * SiLU(z)``), then RMSNorm
+    over each group's channels with the plain weight, then the output
+    projection."""
+    G, di = spec.mamba_n_groups, spec.mamba_inner
+    y = y + lp["d"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+    y = y.reshape(*y.shape[:-2], di) * jax.nn.silu(z.astype(jnp.float32))
+    y = rms_norm(y.reshape(*y.shape[:-1], G, di // G),
+                 lp["ssm_norm"].reshape(G, di // G), spec.rms_eps, False)
+    y = y.reshape(*y.shape[:-2], di).astype(dtype)
+    return jnp.einsum("...v,vd->...d", y, lp["out"]["w"])
+
+
+def _mamba_rows(normed, lp, spec: ModelSpec, lens, tail, S0):
+    """The Mamba-2 mixer over normed rows [B, S, D] from (tail, S0):
+    returns (out, final state, final tail).  Padded positions move
+    nothing (``dt = 0`` there)."""
+    S = normed.shape[1]
+    z, xbc, dt = _mamba_inputs(normed, lp, spec)
+    with jax.named_scope("conv"):
+        y, new_tail = gd.causal_conv(xbc, tail, lp["conv"], lens,
+                                     lp.get("conv_bias"))
+    valid = (jnp.arange(S)[None, :] < lens[:, None])[..., None]
+    x, Bm, Cm, step, A = _mamba_heads(y, dt, lp, spec, valid)
+    o, S1 = ssd.ssd_chunked(x, step, A, Bm, Cm, S0, spec.mamba_chunk_size)
+    return _mamba_out(o, x, z, lp, spec, normed.dtype), S1, new_tail
+
+
+def _mamba_step(normed, lp, st, li, spec: ModelSpec, active, use_pallas):
+    """A Mamba-2 mixer for one decode step, normed [B, D], row = slot."""
+    z, xbc, dt = _mamba_inputs(normed, lp, spec)
+    y, new_tail = _conv_step(st["conv"][li], xbc, lp["conv"],
+                             lp.get("conv_bias"), active)
+    conv = st["conv"].at[li].set(new_tail)
+    x, Bm, Cm, step, A = _mamba_heads(y, dt, lp, spec, active[:, None])
+    o, S = ssd.ssd_step(x, step, A, Bm, Cm, st["S"], li,
+                        use_pallas=use_pallas)
+    return _mamba_out(o, x, z, lp, spec, normed.dtype), {"S": S, "conv": conv}
+
+
+# a recurrent kind's (prompt rows, decode step) and its scope in a trace
+_RECURRENT = {
+    "gdn": (_linear_rows, _linear_step, "linear_attn"),
+    "mamba": (_mamba_rows, _mamba_step, "ssm"),
+}
+
+
+def _recurrent_prompt(kind, normed, lp, st, li, spec: ModelSpec, lens,
+                      slots, fresh):
+    """A recurrent layer's mixer over prompt rows normed [B, S, D]:
+    starts from the slot's row (zeros where ``fresh``), ends with the
+    row overwritten whole.  A wide wave goes through in groups of rows."""
+    rows_fn, _, scope = _RECURRENT[kind]
+    with jax.named_scope(scope):
+        B, S = normed.shape[:2]
         keep = jnp.logical_not(fresh)
         tail = jnp.where(keep[:, None, None], st["conv"][li][slots], 0)
         S0 = jnp.where(keep[:, None, None, None], st["S"][li][slots], 0.0)
         rows = max(1, PROMPT_BLOCK_TOKENS // S)
         # groups of rows unrolled (see ops/moe.py expert_layer)
         parts = [
-            _linear_rows(h[lo:lo + rows], lp, spec, lens[lo:lo + rows],
-                         tail[lo:lo + rows], S0[lo:lo + rows])
+            rows_fn(normed[lo:lo + rows], lp, spec, lens[lo:lo + rows],
+                    tail[lo:lo + rows], S0[lo:lo + rows])
             for lo in range(0, B, rows)
         ]
         out, S1, new_tail = (
@@ -269,67 +474,116 @@ def _linear_prompt(h, lp, st, li, spec: ModelSpec, lens, slots, fresh):
     return out, st
 
 
-def _linear_step(h, lp, st, li, spec: ModelSpec, active, use_pallas):
-    """A linear layer's mixer for one decode step, h [B, D], row = slot."""
-    with jax.named_scope("linear_attn"):
-        qkv, z, b, a = _linear_inputs(h, lp, spec)
-        with jax.named_scope("conv"):
-            tail = st["conv"][li]  # [B, K-1, C]
-            cat = jnp.concatenate([tail, qkv[:, None].astype(tail.dtype)], 1)
-            w32 = lp["conv"].astype(jnp.float32)
-            y = jax.nn.silu(jnp.einsum(
-                "bkc,ck->bc", cat.astype(jnp.float32), w32)).astype(h.dtype)
-            new_tail = jnp.where(active[:, None, None], cat[:, 1:], tail)
-            conv = st["conv"].at[li].set(new_tail)
-        q, k, v = _linear_heads(y, spec)
-        g, beta = gd.gates(a, b, lp["a_log"], lp["dt_bias"])
-        g = jnp.where(active[:, None], g, 0.0)
-        beta = jnp.where(active[:, None], beta, 0.0)
-        o, S = gd.gated_delta_step(q, k, v, g, beta, st["S"], li,
-                                   use_pallas=use_pallas)
-        out = _linear_out(o, z, lp, spec, h.dtype)
-    return out, {"S": S, "conv": conv}
+def _segments(blocks):
+    """A period's sub-blocks as runs ``(unit, repeats)``: the longest
+    run of a repeated unit of up to four sub-blocks at each place, else
+    the one sub-block once.  Blocks repeat when kind, group and norm do
+    (the layer's index moves on)."""
+    same = lambda a, b: a[:3] == b[:3]
+    out, i = [], 0
+    while i < len(blocks):
+        best = (1, 1)
+        for u in range(1, 5):
+            r = 1
+            while (i + (r + 1) * u <= len(blocks) and all(
+                    same(blocks[i + j], blocks[i + r * u + j])
+                    for j in range(u))):
+                r += 1
+            if r > 1 and u * r > best[0] * best[1]:
+                best = (u, r)
+        u, r = best
+        out.append((blocks[i:i + u], r))
+        i += u * r
+    return out
 
 
 def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
-                 linear_fn, full_fn):
-    """Scan over periods.  ``linear_fn(h, lp, st, li, stack)`` ->
-    ``(h, st, stats)`` runs one linear layer (``li`` its index among the
-    linear layers); ``full_fn(h, lp, kp, vp, p, stack)`` -> ``(h, kp,
-    vp, stats)`` the period's full-attention layer against the FULL
-    pools.  The experts' stacks stay outside the scanned slices.
+                 block_fn):
+    """THE stack walker: a scan over periods, each the spec's sub-blocks
+    in order.  ``block_fn(kind, normed, lp, kp, vp, st, index, stack)``
+    -> ``(out, kp, vp, st, stats | None)`` computes one sub-block on the
+    normed rows: ``index`` is the layer's index among the layers of its
+    kind over the whole stack (the pools' layer for ``attn``, the
+    state's for a recurrent kind, the expert stacks' for ``moe``), ``lp``
+    its tensors without the experts' stacks, which stay outside the
+    scanned slices (``stack``: the group's, ``[layers, E, ., .]``).
     Returns (x, k_pages, v_pages, state, stats [4])."""
-    layers = params["layers"]
-    n = spec.linear_per_period
-    light = lambda d: {k: v for k, v in d.items() if k not in EXPERT_STACKS}
+    layers = dict(params["layers"])
+    if not spec.layer_pattern:  # one attention layer a period: [P, ...]
+        layers["full"] = jax.tree.map(lambda a: a[:, None], layers["full"])
+    names = spec.expert_stacks
+    light = {g: {k: v for k, v in d.items() if k not in names}
+             for g, d in layers.items()}
     flat = lambda w: jax.tree.map(
         lambda a: a.reshape((-1,) + a.shape[2:]), w)
-    lin_stack = {k: flat(layers["linear"][k]) for k in EXPERT_STACKS}
-    full_stack = {k: layers["full"][k] for k in EXPERT_STACKS}
+    stacks = {g: {k: flat(d[k]) for k in names if k in d}
+              for g, d in layers.items()}
+    count = {g: spec.group_layers(g) for g in layers}
+    uo = spec.unit_offset_norm
 
-    def fn(carry, xs):
+    def run(carry, block, lp, index):
+        kind, group, norm, _ = block
         h, kp, vp, st = carry
-        lin_p, full_p, p = xs
+        normed = rms_norm(h, lp[norm], spec.rms_eps, uo)
+        out, kp, vp, st, stats = block_fn(
+            kind, normed, lp, kp, vp, st, index, stacks[group])
+        return (h + out.astype(h.dtype), kp, vp, st), stats
 
-        # the period's linear layers as an inner scan: one body traced,
-        # lowered and compiled for the three of them
-        def lin_fn(c, per_layer):
-            lp, j = per_layer
-            h_, st_, s = linear_fn(c[0], lp, c[1], p * n + j, lin_stack)
-            return (h_, st_), s
+    def period(carry, xs):
+        per, p = xs
+        all_stats = []
+        for unit, repeats in _segments(spec.period_blocks):
+            first = {}  # group -> the unit's first layer of it
+            for b in unit:
+                first.setdefault(b[1], b[3])
+            width = {g: len({b[3] for b in unit if b[1] == g})
+                     for g in first}
 
-        (h, st), lin_stats = jax.lax.scan(
-            lin_fn, (h, st), (lin_p, jnp.arange(n, dtype=jnp.int32)))
-        h, kp, vp, s = full_fn(h, full_p, kp, vp, p, full_stack)
-        return (h, kp, vp, st), jnp.concatenate([lin_stats, s[None]])
+            def unit_fn(c, xs_, unit=unit, first=first, width=width):
+                lps, j = xs_
+                stats = []
+                for b in unit:
+                    g, local = b[1], b[3] - first[b[1]]
+                    lp = jax.tree.map(lambda a: a[local], lps[g])
+                    index = p * count[g] + first[g] + j * width[g] + local
+                    c, s = run(c, b, lp, index)
+                    if s is not None:
+                        stats.append(s)
+                return c, (jnp.stack(stats) if stats
+                           else jnp.zeros((0, len(STAT_NAMES)), jnp.int32))
+
+            # the unit's repeats as an inner scan: one body traced,
+            # lowered and compiled for all of them
+            lps = {g: jax.tree.map(
+                lambda a: a[first[g]:first[g] + repeats * width[g]].reshape(
+                    (repeats, width[g]) + a.shape[1:]), per[g])
+                for g in first}
+            js = jnp.arange(repeats, dtype=jnp.int32)
+            if repeats > 1:
+                carry, stats = jax.lax.scan(unit_fn, carry, (lps, js))
+                stats = stats.reshape(-1, len(STAT_NAMES))
+            else:
+                carry, stats = unit_fn(
+                    carry, (jax.tree.map(lambda a: a[0], lps), js[0]))
+            all_stats.append(stats)
+        return carry, jnp.concatenate(all_stats)
 
     (x, k_pages, v_pages, state), stats = jax.lax.scan(
-        fn, (x0, k_pages, v_pages, state),
-        (light(layers["linear"]), light(layers["full"]),
-         jnp.arange(spec.num_periods, dtype=jnp.int32)),
+        period, (x0, k_pages, v_pages, state),
+        (light, jnp.arange(spec.num_periods, dtype=jnp.int32)),
     )
     stats = combine_stats(stats.reshape(-1, len(STAT_NAMES)))
     return x, k_pages, v_pages, state, stats
+
+
+def _experts(normed, lp, spec: ModelSpec, row_mask, use_pallas, index,
+             stack):
+    from vgate_tpu.models.decoder import _act
+
+    return expert_layer(
+        normed, lp, spec, lambda x32: _act(x32, spec), row_mask=row_mask,
+        use_pallas=use_pallas, layer=index, stack=stack,
+    )
 
 
 def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
@@ -348,25 +602,27 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
     to_pages = lambda t: jnp.transpose(
         t.reshape(B, n_pages, ps, KV, hd), (0, 1, 3, 2, 4))
 
-    def linear_fn(h, lp, st, li, stack):
-        out, st = _linear_prompt(h, lp, st, li, spec, lens, slots, fresh)
-        h, stats = _finish(h, out, lp, spec, row_mask, use_pallas, li, stack)
-        return h, st, stats
-
-    def full_fn(h, lp, kp, vp, p, stack):
+    def block_fn(kind, normed, lp, kp, vp, st, index, stack):
+        if kind == "moe":
+            out, stats = _experts(normed, lp, spec, row_mask, use_pallas,
+                                  index, stack)
+            return out, kp, vp, st, stats
+        if kind in _RECURRENT:
+            out, st = _recurrent_prompt(kind, normed, lp, st, index, spec,
+                                        lens, slots, fresh)
+            return out, kp, vp, st, None
         with jax.named_scope("gated_attn"):
-            q, k, v, gate = _gated_qkv(h, lp, spec, positions)
+            q, k, v, gate = _gated_qkv(normed, lp, spec, positions)
             pt = write_tables[:, :n_pages]
-            kp = kv_write_pages(kp, pt, to_pages(k), layer=p)
-            vp = kv_write_pages(vp, pt, to_pages(v), layer=p)
+            kp = kv_write_pages(kp, pt, to_pages(k), layer=index)
+            vp = kv_write_pages(vp, pt, to_pages(v), layer=index)
             with jax.named_scope("attention"):
-                attn = attend(q, k, v, kp, vp, p)
-            out = _gated_out(attn, gate, lp, h.dtype)
-        h, stats = _finish(h, out, lp, spec, row_mask, use_pallas, p, stack)
-        return h, kp, vp, stats
+                attn = attend(q, k, v, kp, vp, index)
+            out = _gated_out(attn, gate, lp, normed.dtype)
+        return out, kp, vp, st, None
 
     x, k_pages, v_pages, state, _stats = _period_scan(
-        params, spec, x, k_pages, v_pages, state, linear_fn, full_fn)
+        params, spec, x, k_pages, v_pages, state, block_fn)
     return x, k_pages, v_pages, state
 
 
@@ -379,21 +635,25 @@ def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
     if active is None:
         active = jnp.ones(x.shape[:1], bool)
 
-    def linear_fn(h, lp, st, li, stack):
-        out, st = _linear_step(h, lp, st, li, spec, active, use_pallas)
-        h, stats = _finish(h, out, lp, spec, active, use_pallas, li, stack)
-        return h, st, stats
-
-    def full_fn(h, lp, kp, vp, p, stack):
+    def block_fn(kind, normed, lp, kp, vp, st, index, stack):
+        if kind == "moe":
+            out, stats = _experts(normed, lp, spec, active, use_pallas,
+                                  index, stack)
+            return out, kp, vp, st, stats
+        if kind in _RECURRENT:
+            _, step_fn, scope = _RECURRENT[kind]
+            with jax.named_scope(scope):
+                out, st = step_fn(normed, lp, st, index, spec, active,
+                                  use_pallas)
+            return out, kp, vp, st, None
         with jax.named_scope("gated_attn"):
             q, k, v, gate = _gated_qkv(
-                h[:, None], lp, spec, positions[:, None])
+                normed[:, None], lp, spec, positions[:, None])
             attn, kp, vp = write_attend(
-                q[:, 0], k[:, 0], v[:, 0], kp, vp, p)
+                q[:, 0], k[:, 0], v[:, 0], kp, vp, index)
             out = _gated_out(attn, None if gate is None else gate[:, 0],
-                             lp, h.dtype)
-        h, stats = _finish(h, out, lp, spec, active, use_pallas, p, stack)
-        return h, kp, vp, stats
+                             lp, normed.dtype)
+        return out, kp, vp, st, None
 
     return _period_scan(
-        params, spec, x, k_pages, v_pages, state, linear_fn, full_fn)
+        params, spec, x, k_pages, v_pages, state, block_fn)

@@ -89,6 +89,29 @@ class ModelSpec:
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 0
+    # ---- the layer pattern as data (Nemotron-H): one letter a layer,
+    # each layer ONE residual sub-block ``h <- h + f(norm(h))``: ``M``
+    # Mamba-2, ``*`` attention, ``E`` expert layer.  Empty = the layers
+    # of two sub-blocks above (a mixer, then the expert layer)
+    layer_pattern: str = ""
+    use_rope: bool = True
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_state_size: int = 0
+    mamba_n_groups: int = 0
+    mamba_conv_kernel: int = 0
+    mamba_conv_bias: bool = False
+    mamba_chunk_size: int = 128
+    # ---- what else an expert layer is told (ops/moe.py): experts that
+    # work in a latent of this width (0 = the hidden), the router's
+    # scores ("softmax" | "sigmoid": selection by score + a bias, the
+    # weights from the scores alone), the factor on the routed sum,
+    # experts of three matrices (gated) or two, the shared expert's gate
+    moe_latent_size: int = 0
+    router_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    moe_gated: bool = True
+    shared_expert_gate: bool = True
 
     def __post_init__(self):
         # a preset changed from JSON (perfbench/serve.py overrides)
@@ -104,26 +127,104 @@ class ModelSpec:
 
     @property
     def is_hybrid(self) -> bool:
-        """Linear-attention layers beside full-attention ones: a
-        per-slot recurrent state beside the paged pool."""
-        return self.full_attention_interval > 1
+        """A stack of sub-blocks of several kinds (models/hybrid.py):
+        recurrent layers beside attention ones, a per-slot recurrent
+        state beside the paged pool."""
+        return bool(self.layer_pattern) or self.full_attention_interval > 1
+
+    @property
+    def layers_per_period(self) -> int:
+        """Layers of one period: the pattern's smallest repeating unit
+        (the whole of a pattern that does not repeat)."""
+        pat = self.layer_pattern
+        if not pat:
+            return max(1, self.full_attention_interval)
+        return next(n for n in range(1, len(pat) + 1)
+                    if len(pat) % n == 0 and pat[:n] * (len(pat) // n) == pat)
+
+    @property
+    def period_blocks(self) -> tuple:
+        """The residual sub-blocks of one period, in order, each ``(kind,
+        group, norm, index)``: what it computes (``gdn`` | ``mamba`` |
+        ``attn`` | ``moe``), the parameter group that holds its tensors
+        (``layers[group]``, stacked ``[periods, layers of the group a
+        period, ...]``), the name of its norm weight there and its
+        layer's index inside the group's period."""
+        if not self.is_hybrid:
+            return ()
+        if not self.layer_pattern:  # layers of two sub-blocks
+            n = self.full_attention_interval - 1
+            lin = [(k, "linear", nm, i) for i in range(n)
+                   for k, nm in (("gdn", "input_norm"), ("moe", "post_norm"))]
+            return tuple(lin) + (("attn", "full", "input_norm", 0),
+                                 ("moe", "full", "post_norm", 0))
+        kinds = {"M": "mamba", "*": "attn", "E": "moe"}
+        out, seen = [], {}
+        for letter in self.layer_pattern[:self.layers_per_period]:
+            kind = kinds[letter]
+            out.append((kind, kind, "norm", seen.get(kind, 0)))
+            seen[kind] = seen.get(kind, 0) + 1
+        return tuple(out)
+
+    def group_layers(self, group: str) -> int:
+        """Layers a period holds in a parameter group."""
+        return len({b[3] for b in self.period_blocks if b[1] == group})
+
+    def _layers_of(self, *kinds) -> int:
+        return self.num_periods * sum(
+            b[0] in kinds for b in self.period_blocks)
 
     @property
     def num_periods(self) -> int:
-        return self.num_layers // max(1, self.full_attention_interval)
+        return self.num_layers // self.layers_per_period
 
     @property
     def attn_layers(self) -> int:
         """Layers that hold K/V pages."""
-        return self.num_periods if self.is_hybrid else self.num_layers
+        return self._layers_of("attn") if self.is_hybrid else self.num_layers
 
     @property
     def linear_layers(self) -> int:
-        return self.num_layers - self.attn_layers
+        """Layers that hold a recurrent state, of either kind."""
+        return self._layers_of("gdn", "mamba")
+
+    @property
+    def moe_layers(self) -> int:
+        """Expert layers the stack runs a step."""
+        if self.is_hybrid:
+            return self._layers_of("moe")
+        return self.num_layers if self.is_moe else 0
+
+    @property
+    def recurrent_kind(self) -> str:
+        """``gdn`` | ``mamba`` | "" : the one kind of recurrent layer."""
+        kinds = {b[0] for b in self.period_blocks} & {"gdn", "mamba"}
+        assert len(kinds) <= 1, "one kind of recurrent state a spec"
+        return next(iter(kinds), "")
 
     @property
     def linear_per_period(self) -> int:
-        return self.full_attention_interval - 1 if self.is_hybrid else 0
+        return self.linear_layers // self.num_periods if self.is_hybrid else 0
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels the Mamba-2 convolution runs over: x, B and C."""
+        return (self.mamba_inner
+                + 2 * self.mamba_n_groups * self.mamba_state_size)
+
+    @property
+    def expert_stacks(self) -> tuple:
+        """Names of an expert's matrices."""
+        return ("gate", "up", "down") if self.moe_gated else ("up", "down")
+
+    @property
+    def expert_in(self) -> int:
+        """Width the routed experts read and write."""
+        return self.moe_latent_size or self.hidden_size
 
     @property
     def router_experts(self) -> int:
@@ -157,6 +258,8 @@ class ModelSpec:
         q/k/v/o (+bias), gate/up/down (per expert for MoE, + router),
         norms, embed, united or separate lm_head."""
         D, L, F = self.hidden_size, self.num_layers, self.intermediate_size
+        if self.layer_pattern:
+            return self._pattern_params()
         q_dim = self.num_heads * self.head_dim
         kv_dim = self.num_kv_heads * self.head_dim
         attn = D * q_dim + 2 * D * kv_dim + q_dim * D
@@ -188,6 +291,27 @@ class ModelSpec:
             self.attn_layers * attn + self.linear_layers * linear
             + L * (mlp + norms) + embed + head + D
         )
+
+    def _pattern_params(self) -> int:
+        """``num_params`` of a stack given by ``layer_pattern``: every
+        layer one sub-block and its norm (models/hybrid.py init_layers)."""
+        D, V = self.hidden_size, self.vocab_size
+        Hm, C, di = self.mamba_num_heads, self.mamba_conv_dim, self.mamba_inner
+        mamba = (D * (di + C + Hm) + C * self.mamba_conv_kernel
+                 + (C if self.mamba_conv_bias else 0) + 3 * Hm + di + di * D)
+        attn = 2 * D * self.q_dim + 2 * D * self.kv_dim
+        W, Fe = self.expert_in, self.expert_width
+        Fs, m = self.shared_expert_intermediate_size, len(self.expert_stacks)
+        moe = (D * self.router_experts + self.num_experts * m * W * Fe
+               + m * D * Fs)
+        if self.router_scoring == "sigmoid":
+            moe += self.router_experts
+        if self.moe_latent_size:
+            moe += 2 * D * W
+        per = {"mamba": mamba, "attn": attn, "moe": moe}
+        stack = self.num_periods * sum(
+            per[b[0]] + D for b in self.period_blocks)
+        return stack + (1 if self.tie_embeddings else 2) * V * D + D
 
     @property
     def layer_windows(self) -> tuple:
@@ -502,6 +626,55 @@ QWEN3_NEXT_80B = _register(
     )
 )
 
+# Published sizes.  The 88 layers' pattern repeats nowhere (runs of 7, 8,
+# 8, 10, 10, 10, 10, 8 and 9 layers between attention layers), so it is
+# ONE period whose repeated pairs the stack walker scans
+# (models/hybrid.py); a cut states its own pattern.  Stop ids: the
+# catalog row's config has none (assumed: <|im_end|> 11, <s> 1, </s> 2).
+NEMOTRON3_SUPER_120B = _register(
+    ModelSpec(
+        name="nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16",
+        vocab_size=131072,
+        hidden_size=4096,
+        num_layers=88,
+        num_heads=32,
+        num_kv_heads=2,
+        head_dim=128,
+        intermediate_size=2688,
+        rope_theta=10_000.0,
+        rms_eps=1e-5,
+        qkv_bias=False,
+        tie_embeddings=False,
+        eos_token_id=11,
+        bos_token_id=1,
+        extra_stop_ids=(2,),
+        max_position_embeddings=262144,
+        act="relu2",
+        num_experts=512,
+        experts_per_token=22,
+        moe_intermediate_size=2688,
+        shared_expert_intermediate_size=5376,
+        router_width=512,
+        layer_pattern=(
+            "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+            "EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME"
+        ),
+        use_rope=False,
+        mamba_num_heads=128,
+        mamba_head_dim=64,
+        mamba_state_size=128,
+        mamba_n_groups=8,
+        mamba_conv_kernel=4,
+        mamba_conv_bias=True,
+        mamba_chunk_size=128,
+        moe_latent_size=1024,
+        router_scoring="sigmoid",
+        routed_scaling_factor=5.0,
+        moe_gated=False,
+        shared_expert_gate=False,
+    )
+)
+
 BGE_BASE = _register(
     ModelSpec(
         name="BAAI/bge-base-en-v1.5",
@@ -609,6 +782,48 @@ TINY_HYBRID = _register(
         linear_key_head_dim=16,
         linear_value_head_dim=16,
         linear_conv_kernel_dim=4,
+    )
+)
+
+# one period of a stack whose layers are ONE sub-block each, every
+# mechanism of Nemotron-H at toy widths: Mamba-2, attention without
+# rotary, a sigmoid-routed expert layer in a latent
+TINY_NEMOTRON_H = _register(
+    ModelSpec(
+        name="tiny-nemotron-h",
+        vocab_size=512,
+        hidden_size=64,
+        num_layers=5,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        intermediate_size=48,
+        rope_theta=10000.0,
+        rms_eps=1e-5,
+        qkv_bias=False,
+        tie_embeddings=False,
+        eos_token_id=0,
+        bos_token_id=1,
+        act="relu2",
+        num_experts=8,
+        experts_per_token=3,
+        moe_intermediate_size=48,
+        shared_expert_intermediate_size=96,
+        router_width=8,
+        layer_pattern="EMEM*",
+        use_rope=False,
+        mamba_num_heads=4,
+        mamba_head_dim=16,
+        mamba_state_size=16,
+        mamba_n_groups=2,
+        mamba_conv_kernel=4,
+        mamba_conv_bias=True,
+        mamba_chunk_size=16,
+        moe_latent_size=32,
+        router_scoring="sigmoid",
+        routed_scaling_factor=2.5,
+        moe_gated=False,
+        shared_expert_gate=False,
     )
 )
 

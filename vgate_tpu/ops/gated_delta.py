@@ -22,8 +22,9 @@ CHUNK = 64  # the published kernels' chunk length
 _HI = jax.lax.Precision.HIGHEST
 
 
-def causal_conv(x, tail, w, lens=None):
-    """Depth-wise causal convolution, then SiLU.
+def causal_conv(x, tail, w, lens=None, bias=None):
+    """Depth-wise causal convolution (plus ``bias`` [C], where the layer
+    has one), then SiLU.
 
     x: [B, S, C] pre-convolution rows; tail: [B, K-1, C] the K-1 rows
     before them (zeros at a sequence's start); w: [C, K], ``w[:, K-1]``
@@ -38,6 +39,8 @@ def causal_conv(x, tail, w, lens=None):
     y = sum(
         cat[:, j:j + S].astype(jnp.float32) * w32[:, j] for j in range(K)
     )
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     if lens is None:
         new_tail = cat[:, S:]
     else:

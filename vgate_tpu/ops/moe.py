@@ -1,6 +1,11 @@
 """The expert layer: dropless top-k routing over the router's full
 width, the held experts' products as ONE grouped product over rows
-sorted by expert, and a gated shared expert.
+sorted by expert, and a shared expert.  ONE layer for every family; the
+spec says what differs: softmax or sigmoid scores (the latter chosen by
+score + a bias, weighted by the score alone), a factor on the routed
+sum, experts of three matrices (gated) or two, experts that work in a
+latent (one projection in front of the dispatch, one behind the
+weighted sum), a shared expert with or without its sigmoid gate.
 
 The layer is told which experts it holds (``spec.num_experts`` of the
 router's ``spec.router_experts``, from ``spec.first_expert``).  A chip
@@ -49,6 +54,17 @@ def _plain(w, dtype):
     return (q.astype(jnp.float32) * w.scale[..., None, :]).astype(dtype)
 
 
+def _column_tile(K: int, N: int, itemsize: int) -> int:
+    """Columns of an expert's matrix a program of the grouped product
+    holds: all of them while the matrix is at most 2 MiB, else 512, or
+    where 512 does not divide them (2,688 = 21 x 128) the widest
+    multiple of 128 up to 1,024 that does."""
+    if K * N * itemsize <= (2 << 20):
+        return N
+    return next(t for t in (512, 1024, 896, 768, 640, 384, 256, 128, N)
+                if N % t == 0)
+
+
 def grouped_product(rows, w, group_sizes, layer, use_pallas: bool):
     """rows [M, K] sorted by expert; w either ONE layer's ``[E, K, N]``
     (``layer`` None) or the stack ``[L, E, K, N]`` with the traced
@@ -60,7 +76,7 @@ def grouped_product(rows, w, group_sizes, layer, use_pallas: bool):
         K, N = w.shape[-2:]
         M = rows.shape[0]
         tm = 128 if M >= 4096 else 32
-        tn = N if K * N * w.dtype.itemsize <= (2 << 20) else 512
+        tn = _column_tile(K, N, w.dtype.itemsize)
         pad = cdiv(M, tm) * tm - M
         if pad:
             rows = jnp.pad(rows, ((0, pad), (0, 0)))
@@ -109,9 +125,9 @@ def expert_layer(x, lp, spec: ModelSpec, act, row_mask=None,
 def _expert_block(x, lp, spec: ModelSpec, act, row_mask=None,
                   use_pallas: bool = False, layer=None, stack=None):
     """x: [..., D].  ``lp`` holds this layer's ``router`` [D, R] and
-    either its experts' ``gate``/``up``/``down`` (``{"w": [E, ., .]}``)
-    or, with ``stack``/``layer``, nothing of them: ``stack`` is then the
-    ``{"gate","up","down"}`` dict of ``[L, E, ., .]`` stacks and
+    either its experts' matrices (``spec.expert_stacks``: ``{"w": [E, .,
+    .]}``) or, with ``stack``/``layer``, nothing of them: ``stack`` is
+    then the dict of their ``[L, E, ., .]`` stacks and
     ``layer`` the traced index into them (the Pallas path must not see
     a scan's per-layer slice).  ``row_mask`` ([...] bool) marks the rows
     that are real: padding and idle slots route nowhere.  Returns
@@ -127,9 +143,21 @@ def _expert_block(x, lp, spec: ModelSpec, act, row_mask=None,
             "td,de->te", xt.astype(jnp.float32),
             lp["router"].astype(jnp.float32),
         )
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, gate_idx = jax.lax.top_k(probs, K)  # [T, K]
-        gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+        if spec.router_scoring == "sigmoid":
+            # chosen by score + bias, weighted by the score alone
+            scores = jax.nn.sigmoid(logits)
+            _, gate_idx = jax.lax.top_k(
+                scores + lp["router_bias"].astype(jnp.float32), K)
+            gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
+            gate_vals = gate_vals / (
+                jnp.sum(gate_vals, axis=-1, keepdims=True) + 1e-20)
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)
+            gate_vals, gate_idx = jax.lax.top_k(probs, K)  # [T, K]
+            gate_vals = gate_vals / jnp.sum(
+                gate_vals, axis=-1, keepdims=True)
+        if spec.routed_scaling_factor != 1.0:
+            gate_vals = gate_vals * spec.routed_scaling_factor
         local = gate_idx - first
         real = jnp.ones((T, 1), bool) if row_mask is None else (
             row_mask.reshape(T, 1)
@@ -148,16 +176,24 @@ def _expert_block(x, lp, spec: ModelSpec, act, row_mask=None,
             jnp.max(group_sizes),
         ]).astype(jnp.int32)
 
+    latent = spec.moe_latent_size > 0
+    src = xt  # what the experts read: the rows, or their latent
+    if latent:  # ONE product in front of the dispatch, not one a choice
+        with jax.named_scope("moe_latent_in"):
+            src = jnp.einsum("td,dl->tl", xt, lp["latent_in"]["w"])
+
     with jax.named_scope("moe_experts"):
-        rows = xt[sorted_tok]  # [T*K, D], sorted by expert
+        rows = src[sorted_tok]  # [T*K, W], sorted by expert
         ws = stack if stack is not None else lp
         lay = layer if stack is not None else None
         gp = lambda r, name: grouped_product(
             r, ws[name]["w"], group_sizes, lay, use_pallas
         )
-        hidden = act(gp(rows, "gate").astype(jnp.float32)).astype(
-            xt.dtype) * gp(rows, "up").astype(xt.dtype)
-        y = gp(hidden, "down")  # [T*K, D]
+        hidden = act(gp(rows, "gate" if spec.moe_gated else "up").astype(
+            jnp.float32)).astype(xt.dtype)
+        if spec.moe_gated:
+            hidden = hidden * gp(rows, "up").astype(xt.dtype)
+        y = gp(hidden, "down")  # [T*K, W]
         w_sorted = jnp.where(held, gate_vals, 0.0).reshape(T * K)[order]
         in_group = jnp.arange(T * K) < jnp.sum(group_sizes)
         y = jnp.where(
@@ -168,20 +204,31 @@ def _expert_block(x, lp, spec: ModelSpec, act, row_mask=None,
         inverse = jnp.zeros((T * K,), jnp.int32).at[order].set(
             jnp.arange(T * K, dtype=jnp.int32)
         )
-        out = jnp.sum(y[inverse].reshape(T, K, D), axis=1)
+        out = jnp.sum(y[inverse].reshape(T, K, -1), axis=1)
+
+    if latent:
+        # on this chip's PARTIAL sum: the product is linear and has no
+        # bias, so the chips' results add up to the whole
+        with jax.named_scope("moe_latent_out"):
+            out = jnp.einsum(
+                "tl,ld->td", out.astype(xt.dtype), lp["latent_out"]["w"]
+            ).astype(jnp.float32)
 
     if spec.shared_expert_intermediate_size:
         with jax.named_scope("shared_expert"):
-            g = jnp.einsum("td,df->tf", xt, lp["shared_gate"]["w"])
             u = jnp.einsum("td,df->tf", xt, lp["shared_up"]["w"])
-            s = jnp.einsum(
-                "tf,fd->td",
-                act(g.astype(jnp.float32)).astype(xt.dtype) * u,
-                lp["shared_down"]["w"],
-            )
-            sg = jax.nn.sigmoid(jnp.einsum(
-                "td,d->t", xt.astype(jnp.float32),
-                lp["shared_router"].astype(jnp.float32),
-            ))
-            out = out + s.astype(jnp.float32) * sg[:, None]
+            if spec.moe_gated:
+                g = jnp.einsum("td,df->tf", xt, lp["shared_gate"]["w"])
+                u = act(g.astype(jnp.float32)).astype(xt.dtype) * u
+            else:
+                u = act(u.astype(jnp.float32)).astype(xt.dtype)
+            s = jnp.einsum("tf,fd->td", u, lp["shared_down"]["w"])
+            if spec.shared_expert_gate:
+                sg = jax.nn.sigmoid(jnp.einsum(
+                    "td,d->t", xt.astype(jnp.float32),
+                    lp["shared_router"].astype(jnp.float32),
+                ))
+                out = out + s.astype(jnp.float32) * sg[:, None]
+            else:
+                out = out + s.astype(jnp.float32)
     return out.astype(x.dtype).reshape(orig_shape), stats

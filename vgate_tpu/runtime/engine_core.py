@@ -394,7 +394,7 @@ def refuse_unsupported_recurrent(spec: ModelSpec, config: VGTConfig,
     state that belongs to it is a WRONG cache.  Each is refused here by
     name, at boot, not at the first request that would need it.  (Prefix
     matching is not refused but turned off: it is on by default.)"""
-    if not spec.is_hybrid:
+    if not spec.linear_layers:
         return
     what = None
     bad_axes = {
@@ -423,8 +423,8 @@ def refuse_unsupported_recurrent(spec: ModelSpec, config: VGTConfig,
                 "plain weights")
     if what:
         raise ValueError(
-            f"{spec.name} has recurrent (linear-attention) layers, which "
-            f"cannot run with {what}.  Preemption by recompute and "
+            f"{spec.name} has recurrent (linear-attention or state-space) "
+            f"layers, which cannot run with {what}.  Preemption by recompute and "
             "journal replay rebuild the state and are supported."
         )
 
@@ -3040,7 +3040,7 @@ class EngineCore:
                     # with the tokens (engine thread, once per chunk)
                     self.perf.note_moe(
                         np.asarray(moe_dev), rows=len(seqs),
-                        moe_layers=self.spec.num_layers,
+                        moe_layers=self.spec.moe_layers,
                         linear_layers=self.spec.linear_layers,
                     )
             device_s = wait.seconds
@@ -3774,6 +3774,7 @@ class EngineCore:
                         "bytes_per_slot": self._state_slot_bytes,
                         "bytes": self._state_slot_bytes * self.max_slots,
                         "linear_layers": self.spec.linear_layers,
+                        "kind": self.spec.recurrent_kind,
                         "dtype": "float32 state, "
                         f"{dtype_short_name(self._state_dtype)} "
                         "convolution tail",

@@ -7,7 +7,7 @@ per the mesh rules at placement time (safetensors -> jax.device_put per
 shard), so a v5e-8 load never materializes a full replica per host.
 
 Name mapping follows the HF `Qwen2ForCausalLM` / `MixtralForCausalLM` /
-`BertModel` conventions; torch linear weights are [out, in] and transposed
+`BertModel` / `Qwen3NextForCausalLM` / `NemotronHForCausalLM` conventions; torch linear weights are [out, in] and transposed
 into the einsum-friendly [in, out] layout used by models/decoder.py.
 """
 
@@ -152,10 +152,95 @@ def _hybrid_params_from_getter(
     return params
 
 
+def _pattern_params_from_getter(
+    spec: ModelSpec, getter: TensorGetter, dtype
+) -> Params:
+    """``NemotronHForCausalLM`` names -> the pytree of models/hybrid.py
+    for a ``layer_pattern`` spec (``layers = {"mamba" | "attn" | "moe":
+    [P, n, ...]}``).  Layer ``i`` of the checkpoint is the i-th letter of
+    the pattern; its one sub-block is ``backbone.layers.{i}.mixer``,
+    whatever its kind, behind ``backbone.layers.{i}.norm``.  ``in_proj``
+    is held as the checkpoint has it, ``[z | x | B | C | dt]``;
+    ``conv1d.weight`` ``[channels, 1, taps]`` loses its middle axis.  A
+    chip's share: the experts ``first_expert ..`` of the router's width,
+    and the first ``vocab_size`` rows of embedding and head."""
+    E, first = spec.num_experts, spec.first_expert
+    get = lambda i, name: np.asarray(getter(f"backbone.layers.{i}.{name}"))
+    lin = lambda i, name: {"w": get(i, f"mixer.{name}.weight").T}
+
+    def mamba(i):
+        out = {
+            "in_proj": lin(i, "in_proj"),
+            "conv": get(i, "mixer.conv1d.weight")[:, 0, :],
+            "a_log": get(i, "mixer.A_log"),
+            "d": get(i, "mixer.D"),
+            "dt_bias": get(i, "mixer.dt_bias"),
+            "ssm_norm": get(i, "mixer.norm.weight"),
+            "out": lin(i, "out_proj"),
+        }
+        if spec.mamba_conv_bias:
+            out["conv_bias"] = get(i, "mixer.conv1d.bias")
+        return out
+
+    def attn(i):
+        return {n: lin(i, f"{n}_proj") for n in "qkvo"}
+
+    def moe(i):
+        experts = lambda w: {"w": np.stack([
+            get(i, f"mixer.experts.{first + e}.{w}_proj.weight").T
+            for e in range(E)])}
+        out = {
+            "router": lin(i, "gate")["w"],
+            "router_bias": get(i, "mixer.gate.e_score_correction_bias"),
+            "up": experts("up"), "down": experts("down"),
+            "shared_up": lin(i, "shared_experts.up_proj"),
+            "shared_down": lin(i, "shared_experts.down_proj"),
+        }
+        if spec.moe_latent_size:
+            out["latent_in"] = lin(i, "fc1_latent_proj")
+            out["latent_out"] = lin(i, "fc2_latent_proj")
+        return out
+
+    np_dtype = np.dtype(dtype)
+    keep_f32 = ("a_log", "d", "dt_bias", "router_bias")
+    load = {"mamba": mamba, "attn": attn, "moe": moe}
+    per_kind: Dict[str, list] = {}
+    blocks, per = spec.period_blocks, spec.layers_per_period
+    for i in range(spec.num_layers):
+        kind = blocks[i % per][0]
+        tree = dict(load[kind](i), norm=get(i, "norm.weight"))
+        per_kind.setdefault(kind, []).append({
+            k: (np.asarray(v, np.float32) if k in keep_f32
+                else jax.tree.map(lambda x: x.astype(np_dtype), v))
+            for k, v in tree.items()
+        })
+    P = spec.num_periods
+    V = spec.vocab_size
+    params: Params = {
+        "embed": np.asarray(getter("backbone.embeddings.weight"))[:V]
+        .astype(np_dtype),
+        "layers": {
+            kind: jax.tree.map(
+                lambda *xs: np.stack(xs).reshape(
+                    (P, len(xs) // P) + xs[0].shape), *trees)
+            for kind, trees in per_kind.items()
+        },
+        "final_norm": np.asarray(getter("backbone.norm_f.weight"))
+        .astype(np_dtype),
+    }
+    if not spec.tie_embeddings:
+        params["lm_head"] = (
+            np.asarray(getter("lm_head.weight"))[:V].T.astype(np_dtype)
+        )
+    return params
+
+
 def params_from_getter(
     spec: ModelSpec, getter: TensorGetter, dtype=jnp.bfloat16
 ) -> Params:
     """Assemble the decoder pytree from HF-named tensors (host numpy)."""
+    if spec.layer_pattern:
+        return _pattern_params_from_getter(spec, getter, dtype)
     if spec.is_hybrid:
         return _hybrid_params_from_getter(spec, getter, dtype)
     L = spec.num_layers
