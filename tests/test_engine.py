@@ -436,6 +436,349 @@ def test_page_growth_does_not_rebuild_state():
         core.stop()
 
 
+# ---- a plain membership change edits the decode state's rows (PR 32):
+# streams that end at a readback and prompts that join through a prompt
+# program no longer drain the pipeline and rebuild the device state
+
+
+def _membership(core):
+    totals = core.perf.totals()
+    return totals["membership_changes"], totals["pipeline_drains"]
+
+
+def _drain_reasons(core):
+    """Record the reason of every rebuild of ``core``'s decode state."""
+    reasons = []
+    real = core._build_decode_state
+
+    def recording(seqs, reason="initial"):
+        reasons.append(reason)
+        return real(seqs, reason)
+
+    core._build_decode_state = recording
+    return reasons
+
+
+def _alone_then_together(core, prompts, params):
+    """Every prompt served alone, then all of them at once through the
+    core's few slots (a closed loop: each stream's end admits the next
+    prompt).  Returns both runs' token lists, and the membership changes
+    and pipeline drains of the second."""
+    alone = [
+        core.generate([p], [sp])[0]["token_ids"]
+        for p, sp in zip(prompts, params)
+    ]
+    before = _membership(core)
+    together = [r["token_ids"] for r in core.generate(prompts, params)]
+    counted = tuple(b - a for a, b in zip(before, _membership(core)))
+    return alone, together, counted
+
+
+# as many as the queue of tiny_config() holds
+_LOOP_PROMPTS = [f"closed loop prompt {i} of many" for i in range(16)]
+_LOOP_LENGTHS = [3 + (5 * i) % 17 for i in range(16)]
+
+
+@pytest.mark.fast  # seconds a case: runs in tier-1, unlike this file
+@pytest.mark.parametrize("radix", [True, False], ids=["radix", "no_cache"])
+def test_closed_loop_edits_rows_and_keeps_greedy_tokens(radix):
+    """More prompts than slots, answers of staggered lengths, greedy:
+    every stream's tokens are those of the same prompt served alone, and
+    after the first build no membership change drains the pipeline,
+    with the radix cache on (joiners come through suffix programs) and
+    off."""
+    core = EngineCore(
+        tiny_config(decode_chunk=4, decode_pipeline=2, prefix_cache=radix),
+        devices=jax.devices()[:1],
+    )
+    core.start()
+    try:
+        alone, together, (changes, drains) = _alone_then_together(
+            core, _LOOP_PROMPTS, [greedy(n) for n in _LOOP_LENGTHS]
+        )
+        assert together == alone
+        # its ends and joins (several may fall into one tick) left
+        # every chunk in flight in flight
+        assert changes >= 8 and drains == 0
+        # dozens by now, and the one build is the first, for the first
+        # prompt served alone
+        total, drains = _membership(core)
+        assert total >= 24 and drains == 1 == core.total_state_rebuilds
+        stats = core.get_stats()["scheduler"]
+        assert stats["running"] == 0
+        if not radix:
+            assert stats["used_pages"] == 0
+    finally:
+        core.stop()
+
+
+@pytest.mark.fast  # seconds a case: runs in tier-1, unlike this file
+@pytest.mark.parametrize(
+    "sampling",
+    [
+        {"temperature": 0.8},
+        {"temperature": 1.0, "top_p": 0.9},
+        {"temperature": 1.2, "top_k": 5},
+    ],
+    ids=["temperature", "top_p", "top_k"],
+)
+def test_closed_loop_edits_rows_and_keeps_seeded_tokens(sampling):
+    """The same closed loop with seeded sampling rows: a row that joins
+    the decode batch on the device keeps its ``(seed, token index)``
+    keys, so every stream's tokens are those of the same prompt and
+    seed served alone."""
+    core = EngineCore(
+        tiny_config(decode_chunk=4, decode_pipeline=2),
+        devices=jax.devices()[:1],
+    )
+    core.start()
+    try:
+        prompts = _LOOP_PROMPTS[:14]
+        params = [
+            SamplingParams(max_tokens=n, seed=1000 + i, **sampling)
+            for i, n in enumerate(_LOOP_LENGTHS[:14])
+        ]
+        alone, together, (changes, drains) = _alone_then_together(
+            core, prompts, params
+        )
+        assert together == alone
+        assert len({tuple(t) for t in together}) == len(together)
+        assert changes >= 7 and drains == 0
+    finally:
+        core.stop()
+
+
+@pytest.mark.fast  # seconds a case: runs in tier-1, unlike this file
+@pytest.mark.parametrize("case", ["penalties", "variant", "preempt"])
+def test_other_membership_changes_still_drain(case):
+    """What is not a plain change keeps the drain and the rebuild, names
+    its reason, and gives the tokens it gave: a first row with a penalty
+    (its histogram is built from host state), a ``logprobs`` row joining
+    (another program variant), a preemption."""
+    tight = case == "preempt"
+    core = EngineCore(
+        tiny_config(
+            decode_chunk=1 if tight else 4, decode_pipeline=2,
+            **({"kv_num_pages": 15} if tight else {}),
+        ),
+        devices=jax.devices()[:1],
+    )
+    core.start()
+    reasons = _drain_reasons(core)
+    try:
+        if tight:
+            # test_preemption_preserves_greedy_output's pool and check:
+            # the victim's re-prefill joins like any prompt, and decodes
+            # what a fresh request for its folded prompt decodes
+            seqs = [
+                core.submit_prompt(p, greedy(10))
+                for p in ("preempt probe one", "preempt probe two",
+                          "preempt pr three")
+            ]
+            for seq in seqs:
+                assert seq.done_event.wait(timeout=300)
+                assert seq.num_output_tokens == 10
+            assert core.scheduler.total_preemptions >= 1
+            victim = next(s for s in seqs if s.preempt_count >= 1)
+            folded = victim.num_prompt_tokens - victim.orig_prompt_len
+            replay = core.submit_tokens(
+                list(victim.prompt_ids), greedy(10 - folded)
+            )
+            assert replay.done_event.wait(timeout=300)
+            assert replay.generated_ids == victim.generated_ids[folded:]
+        else:
+            odd = (
+                {"frequency_penalty": 0.7} if case == "penalties"
+                else {"logprobs": True, "top_logprobs": 2}
+            )
+            params = [
+                SamplingParams(
+                    max_tokens=n, temperature=0.0,
+                    **(odd if i == 6 else {}),
+                )
+                for i, n in enumerate(_LOOP_LENGTHS[:10])
+            ]
+            alone, together, _ = _alone_then_together(
+                core, _LOOP_PROMPTS[:10], params
+            )
+            assert together == alone
+        assert case in reasons, reasons
+        changes, drains = _membership(core)
+        # (a drain that leaves no row to step builds nothing)
+        assert drains >= len(reasons) >= 2 and changes > drains
+    finally:
+        core.stop()
+
+
+@pytest.mark.fast  # seconds a case: runs in tier-1, unlike this file
+@pytest.mark.parametrize("ending", ["max_tokens", "stop_id"])
+def test_a_joiner_that_ends_at_its_first_token_leaves_no_live_row(ending):
+    """A prompt whose first token ends it (``max_tokens`` 1, a stop id)
+    joined the decode batch on the device before the host read that
+    token: the chunk dispatched behind it steps its row once for
+    nothing, and the next edit switches the row off."""
+    core = EngineCore(
+        tiny_config(decode_chunk=2, decode_pipeline=2, max_batch_slots=2,
+                    prefix_cache=False),
+        devices=jax.devices()[:1],
+    )
+    core.start()
+    try:
+        prompts = ["a long answer", "ends at once", "another long one",
+                   "ends at once too", "the last long answer"]
+        long = [greedy(14), greedy(11), greedy(16)]
+        firsts = [
+            core.generate([p], [greedy(1)])[0]["token_ids"][0]
+            for p in prompts
+        ]
+        short = [
+            greedy(1) if ending == "max_tokens" else SamplingParams(
+                max_tokens=8, temperature=0.0, stop_token_ids=[first]
+            )
+            for first in firsts
+        ]
+        params = [long[0], short[1], long[1], short[3], long[2]]
+        alone, together, (changes, drains) = _alone_then_together(
+            core, prompts, params
+        )
+        assert together == alone
+        assert [len(t) for t in together[1::2]] == [1, 1]
+        assert changes >= 4 and drains == 0
+        # the last stream standing decoded alone for several chunks:
+        # the state it left has its row live and no other
+        state = core._dec_state
+        assert len(state["members"]) == 1
+        assert int(np.asarray(state["active"]).sum()) == 1
+        stats = core.get_stats()["scheduler"]
+        assert stats["running"] == 0 and stats["used_pages"] == 0
+    finally:
+        core.stop()
+
+
+@pytest.mark.fast  # seconds a case: runs in tier-1, unlike this file
+def test_prefix_of_a_stream_whose_overshoot_is_in_flight():
+    """Radix cache on: a prompt that repeats a finished stream's prompt
+    and the head of its answer is admitted while the chunk that still
+    steps that stream (overshoot, discarded at readback) is in flight,
+    matches the pages the stream left in the tree, and decodes what it
+    decodes on an engine without a cache.  The overshoot writes at and
+    past the stream's last position, the tree holds full pages below it
+    (scheduler._radix_insert_final)."""
+    config = dict(decode_chunk=4, decode_pipeline=2, max_batch_slots=2)
+    cached = EngineCore(tiny_config(**config), devices=jax.devices()[:1])
+    plain = EngineCore(
+        tiny_config(prefix_cache=False, **config), devices=jax.devices()[:1]
+    )
+    cached.start()
+    plain.start()
+    try:
+        head = [41, 42, 43, 44, 45, 46, 47, 48, 49]
+        side = [71, 72, 73, 74, 75]
+
+        def serve(core, ids, n):
+            seq = core.submit_tokens(list(ids), greedy(n))
+            assert seq.done_event.wait(timeout=300)
+            return list(seq.generated_ids)
+
+        answer = serve(plain, head, 6)
+        turn = head + answer[:3] + [81, 82, 83, 84]
+        want = serve(plain, turn, 9)
+        # what the joiner's dispatch finds in flight
+        seen = []
+        real = cached._dispatch_prompt
+
+        def watching(plans, *args, **kwargs):
+            for plan in plans:
+                if plan.seq.prompt_ids == turn:
+                    seen.append((
+                        plan.cached_len,
+                        [
+                            s.status.value
+                            for chunk in cached._pending_chunks
+                            for s, _ in chunk[0]
+                        ],
+                    ))
+            return real(plans, *args, **kwargs)
+
+        cached._dispatch_prompt = watching
+        other = cached.submit_tokens(list(side), greedy(40))
+        first = cached.submit_tokens(list(head), greedy(6))
+        second = cached.submit_tokens(list(turn), greedy(9))
+        for seq in (other, first, second):
+            assert seq.done_event.wait(timeout=300)
+        assert list(first.generated_ids) == answer
+        assert list(second.generated_ids) == want
+        assert list(other.generated_ids) == serve(plain, side, 40)
+        [(cached_len, in_flight)] = seen
+        assert cached_len == 12, "the finished stream's pages did not match"
+        assert "finished" in in_flight, "no overshoot was in flight"
+        assert _membership(cached)[1] == 1
+    finally:
+        cached.stop()
+        plain.stop()
+
+
+@pytest.mark.fast  # seconds a case: runs in tier-1, unlike this file
+@pytest.mark.parametrize("model_id", ["tiny-hybrid", "tiny-nemotron-h"])
+def test_closed_loop_on_a_recurrent_state(model_id):
+    """The two hybrid families: a slot's recurrent state row is written
+    by the previous tenant's overshoot first and by the joiner's prompt
+    program after (device order), so a stream that joins on the device
+    decodes what it decodes alone."""
+    core = EngineCore(
+        load_config(
+            model={"model_id": model_id, "engine_type": "jax_tpu",
+                   "dtype": "float32", "max_model_len": 128},
+            tpu={"dp": 1, "tp": 1, "ep": 1, "sp": 1, "kv_num_pages": 64,
+                 "kv_page_size": 4, "max_batch_slots": 2,
+                 "prefill_buckets": [16, 32], "use_pallas": False,
+                 "decode_chunk": 2, "decode_pipeline": 2},
+            scheduler={"max_queue_size": 16},
+            logging={"level": "WARNING"},
+        ),
+        devices=jax.devices()[:1],
+    )
+    core.start()
+    try:
+        alone, together, (changes, drains) = _alone_then_together(
+            core, _LOOP_PROMPTS[:7],
+            [greedy(n) for n in (5, 9, 3, 12, 7, 4, 10)],
+        )
+        assert together == alone
+        assert changes >= 4 and drains == 0
+    finally:
+        core.stop()
+
+
+@pytest.mark.fast  # seconds: runs in tier-1, unlike this file
+def test_decode_state_page_tables_are_a_copy_of_the_host_table():
+    """The decode state's page tables are a device array of their own.
+    On a CPU ``jnp.asarray`` may alias the host table (by the buffer's
+    alignment, so by chance), and the next prompt dispatch rewrites a
+    slot's row there for its new tenant while the chunk that still steps
+    the old one (its overshoot) is in flight: that chunk then wrote the
+    old stream's K/V into the new one's pages, about once in thirty
+    runs of a chunked-prefill or preemption test under load."""
+    core = EngineCore(
+        tiny_config(decode_chunk=2, decode_pipeline=2),
+        devices=jax.devices()[:1],
+    )
+    core.start()
+    try:
+        core.generate(["page table probe"], [greedy(6)])
+        core._page_tables_np[:] = 7  # the engine is idle: nothing races
+        core._refresh_page_tables([])
+        np.testing.assert_array_equal(
+            np.asarray(core._dec_state["page_tables"]), 7
+        )
+        core._page_tables_np[:] = 0
+        np.testing.assert_array_equal(
+            np.asarray(core._dec_state["page_tables"]), 7
+        )
+    finally:
+        core.stop()
+
+
 def test_moe_engine_end_to_end_expert_parallel():
     """The MoE decoder serves through the full continuous-batching engine
     with experts sharded over the ep axis (SURVEY.md section 2.2: EP is a

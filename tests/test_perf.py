@@ -717,6 +717,57 @@ def test_chunk_lengths_are_whatever_the_engine_ran():
     assert merged["totals"]["chunks_by_steps"] == {"4": 1, "16": 3}
 
 
+def test_membership_changes_and_drains_are_counted_and_merge():
+    """``totals.membership_changes`` counts the ticks that found the
+    decode batch's membership changed and ``totals.pipeline_drains`` those
+    of them that drained the pipeline; both are monotone counters that
+    add across dp replicas."""
+    rec = recorder()
+    for drained in (True, False, False, False, True):
+        rec.note_membership_change(drained=drained)
+    totals = rec.snapshot()["totals"]
+    assert totals["membership_changes"] == 5
+    assert totals["pipeline_drains"] == 2
+    other = recorder()
+    other.note_membership_change(drained=False)
+    merged = perf_mod.merge_snapshots([rec.snapshot(), other.snapshot()])
+    assert merged["totals"]["membership_changes"] == 6
+    assert merged["totals"]["pipeline_drains"] == 2
+
+
+@pytest.mark.parametrize(
+    "name", ["engine.drain_share.tok", "engine.drain_share.tpot"]
+)
+def test_drain_share_is_read_from_the_two_counters(name):
+    """The benchmark's ``engine.drain_share.*`` (data files only): drains
+    over membership changes between the window's two ends, in percent,
+    and nothing (not an error) from a program without the counters."""
+    from perfbench import manifest
+
+    spec = manifest.metric(name)
+    assert spec["reducer"] == "perf_ratio"
+    assert spec["layer"] == "engine tick" and spec["better"] == "lower"
+    reduce = manifest.reducer(spec["reducer"])
+
+    def scrape(changes, drains):
+        rec = recorder()
+        for i in range(changes):
+            rec.note_membership_change(drained=i < drains)
+        return {"totals": rec.totals()}
+
+    ctx = {"perf": {"open": scrape(10, 1), "close": scrape(210, 4)}}
+    assert reduce(ctx, **spec["args"]) == pytest.approx(1.5)
+    parent = {"totals": {"ticks": 3}}
+    ctx = {"perf": {"open": parent, "close": parent}}
+    assert reduce(ctx, **spec["args"]) is None
+    by_name = {m["name"]: m for m in manifest.benchmark()["per_layer"]}
+    cells = by_name[name]["workloads"]
+    if name.endswith(".tpot"):
+        assert cells == ["qwen2.5-1.5b.chat"]
+    else:
+        assert len(cells) == 4 and "qwen2.5-1.5b.chat" not in cells
+
+
 def test_host_overhead_ratio_counts_schedule_and_state_as_host():
     """The gauge behind VgtHostOverheadHigh keeps the meaning it had
     before schedule/state got brackets of their own: the engine's own

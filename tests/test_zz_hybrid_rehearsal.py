@@ -37,6 +37,8 @@ def test_the_cell_rehearses_correct():
     got = result["metrics"]
     assert got["moe.held_assignment_share.tok"]["value"] == 100.0  # tiny
     assert got["device.state_gb.tok"]["value"] > 0
+    # the closed loop's ends and joins edit the decode state's rows (PR 32)
+    assert 0.0 <= got["engine.drain_share.tok"]["value"] < 50.0
     assert "kernel.gdn_step_roofline.tok" not in got  # no device metric
 
 

@@ -39,5 +39,7 @@ def test_the_cell_rehearses_correct():
     assert got["moe.held_assignment_share.tok"]["value"] == 100.0  # tiny
     assert got["moe.latent_load_max_over_mean.tok"]["value"] > 0
     assert got["device.state_gb.tok"]["value"] > 0
+    # the closed loop's ends and joins edit the decode state's rows (PR 32)
+    assert 0.0 <= got["engine.drain_share.tok"]["value"] < 50.0
     assert "kernel.ssd_step_roofline.tok" not in got  # no device metric
     assert result["in_window"]["compiled"] == 0

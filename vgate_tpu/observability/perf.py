@@ -83,6 +83,7 @@ REQUEST_TOTALS = ("admitted", "queue_wait_s", "first_tokens", "prefill_s")
 ADDITIVE_TOTALS = (
     "decode_device_s", "decode_ctx_token_steps",
     "engine_cpu_s", "engine_cpu_in_wait_s",
+    "membership_changes", "pipeline_drains",
 ) + REQUEST_TOTALS
 
 # the fixed phase taxonomy (docs/observability.md "Perf attribution")
@@ -289,6 +290,11 @@ class PerfRecorder:
         self.total_decode_ctx_token_steps = 0
         # chunk length (steps) -> decode chunks read back
         self._chunks_by_steps: Dict[int, int] = {}
+        # ticks whose decode membership differed from the last
+        # dispatch's, and those of them that drained the pipeline and
+        # rebuilt the device state (the others edited its rows)
+        self.total_membership_changes = 0
+        self.total_pipeline_drains = 0
         # expert-layer and recurrent-state counters (a hybrid spec's
         # decode chunks; empty otherwise and then left out of totals())
         self._moe: Dict[str, int] = {}
@@ -389,6 +395,14 @@ class PerfRecorder:
             cur.decode_bytes += steps * self.roofline.step_bytes(
                 ctx_tokens
             )
+
+    def note_membership_change(self, drained: bool) -> None:
+        """A tick found the decode batch's membership changed; it either
+        ``drained`` the pipeline and rebuilt the device state or edited
+        the state's rows with every chunk left in flight."""
+        self.total_membership_changes += 1
+        if drained:
+            self.total_pipeline_drains += 1
 
     def note_moe(self, stats, rows: int, moe_layers: int,
                  linear_layers: int) -> None:
@@ -622,6 +636,8 @@ class PerfRecorder:
             "chunks_by_steps": {
                 str(k): v for k, v in self._chunks_by_steps.items()
             },
+            "membership_changes": self.total_membership_changes,
+            "pipeline_drains": self.total_pipeline_drains,
             "decode_device_s": round(self.total_decode_device_s, 6),
             "decode_ctx_token_steps": self.total_decode_ctx_token_steps,
             "engine_cpu_s": round(self.total_engine_cpu_s, 6),

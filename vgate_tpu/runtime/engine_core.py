@@ -28,6 +28,7 @@ Architecture (SURVEY.md section 7, stages 3-4):
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import os
 import queue
 import tempfile
@@ -38,6 +39,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence as Seq
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 from vgate_tpu import faults, integrity, metrics
 from vgate_tpu.analysis.witness import named_lock
@@ -102,6 +104,7 @@ from vgate_tpu.runtime.step_programs import (
     _cow_copy_pages,
     _decode_chunk,
     _gather_swap_pages,
+    _join_decode_rows,
     _prefill_step,
     _scatter_swap_pages,
     _spec_verify_step,
@@ -455,6 +458,23 @@ class _EvacRequest:
         self.error: Optional[BaseException] = None
         self.lock = threading.Lock()
         self.cancelled = False
+
+
+@dataclasses.dataclass
+class _PrefillWave:
+    """One tick's admissions between their dispatch and the readback of
+    their first tokens: ``dispatched`` pairs each prompt program's plans
+    with its (async) result, whose token half the decode state's row
+    edit reads on the device before the host reads it."""
+
+    start: float
+    plans: List[PrefillPlan] = dataclasses.field(default_factory=list)
+    plan_epochs: Dict[int, int] = dataclasses.field(default_factory=dict)
+    dispatched: list = dataclasses.field(default_factory=list)
+    # how a row of the wave came in other than by one prompt program: a
+    # membership change with either rebuilds the decode state
+    chunked: bool = False
+    swapped: bool = False
 
 
 class EngineCore:
@@ -859,6 +879,9 @@ class EngineCore:
         self._compiled: set = set()
         self._dec_state: Optional[Dict[str, Any]] = None
         self._decode_signature_cache: Optional[tuple] = None
+        # why the cache was killed behind the state's back, for the
+        # rebuild's trace span (_invalidate_decode_state)
+        self._stale_reason: Optional[str] = None
         # in-flight decode chunks awaiting host readback:
         # (seq snapshot, chunk length, [chunk, B] device tokens, start time)
         self._pending_chunks: list = []
@@ -1003,8 +1026,6 @@ class EngineCore:
             else None
         )
         if self.config.model.quantization in ("int8", "int4"):
-            import dataclasses
-
             # the fused dequant kernels don't auto-partition under jit
             # sharding; model-parallel meshes keep the jnp einsum path.
             # Threaded on the spec (a static jit arg) so engines with
@@ -1127,6 +1148,33 @@ class EngineCore:
         self.total_state_rebuilds = 0
         # loop iterations completed (capture_profile waits on it)
         self._ticks_done = 0
+        self._replicated = named(self.mesh, PartitionSpec())
+        self._warm_row_edits()
+
+    def _carried(self, value):
+        """A host value placed as the step programs' own results are:
+        replicated over the engine's mesh.  The chunk programs thread
+        tokens, positions, step counts and the counter from call to
+        call, so a state built on the host then has the type of one the
+        last chunk left, and both run ONE compiled program."""
+        return jax.device_put(value, self._replicated)
+
+    def _warm_row_edits(self) -> None:
+        """Compile the decode state's row edit at boot, one variant per
+        batch size a prompt program can have (the powers of two that
+        ``tpu.prefill_batch_max`` pads to): a membership change in the serving
+        window then compiles nothing.  Cheap: three scatters of that
+        many int32 each."""
+        carried = (self._carried(np.zeros((self.max_slots,), np.int32)),) * 3
+        n = 1
+        while n < 2 * max(1, self.config.tpu.prefill_batch_max):
+            rows = np.zeros((n,), np.int32)
+            # every slot past the batch: the edit drops all its rows
+            _join_decode_rows(
+                *carried, np.full((n,), self.max_slots, np.int32),
+                self._carried(rows), rows, rows,
+            )
+            n *= 2
 
     # ------------------------------------------------------------- lifecycle
 
@@ -1768,7 +1816,7 @@ class EngineCore:
         # unseeded sampling, exactly like preemption)
         if self._pending_chunks:
             self._process_chunks(drain=True)
-            self._decode_signature_cache = None
+            self._invalidate_decode_state("evacuate")
             candidates = list(self.scheduler.running) + list(
                 self.scheduler.waiting
             )
@@ -1800,7 +1848,7 @@ class EngineCore:
             out.append(seq)
         if out:
             # membership changed: any device decode state is stale
-            self._decode_signature_cache = None
+            self._invalidate_decode_state("evacuate")
             logger.info(
                 "evacuated sequences for planned migration",
                 extra={
@@ -1872,7 +1920,7 @@ class EngineCore:
             # fold in-flight decode chunks first (like _evacuate_now):
             # the staged KV must cover every token already streamed
             self._process_chunks(drain=True)
-            self._decode_signature_cache = None
+            self._invalidate_decode_state("handoff")
         for seq in ready:
             seq.handoff_requested = False
             if seq.status is not SeqStatus.RUNNING or seq.abort_requested:
@@ -1886,7 +1934,7 @@ class EngineCore:
                     seq.kv_dtype = geo.kv_dtype
                 staged = self.scheduler.hold_for_handoff(seq)
             if staged:
-                self._decode_signature_cache = None
+                self._invalidate_decode_state("handoff")
                 self.flight.record_tick(
                     "handoff_stage", seq_id=seq.seq_id,
                     request_id=seq.request_id, tokens=seq.num_generated,
@@ -1904,12 +1952,29 @@ class EngineCore:
     def _tick(self) -> bool:
         """One iteration of the engine loop.
 
-        1. Dispatch every admissible prefill asynchronously, then read all
-           their first tokens back in a single transfer.
+        1. Dispatch every admissible prefill asynchronously
+           (``_admit_and_dispatch``).
         2. Keep up to ``pipeline_depth`` decode chunks in flight: dispatch
            the next chunk against device-resident state, then block on the
            *oldest* chunk's readback — host-side token processing overlaps
            device execution of the newer chunk.
+        3. Read the wave's first tokens back in a single transfer
+           (``_read_first_tokens``), behind that dispatch.
+
+        What a change of the decode batch's membership does between 1
+        and 2.  A PLAIN change (streams ended at a readback, prompts
+        joined through this wave's prompt programs, the program variant
+        the same; ``_drain_reason``) drains nothing: the device state
+        persists, ``_join_and_dispatch`` switches the ended rows off
+        and gives each joiner its prompt program's device token, and
+        the chunks in flight stay in flight.  Every other change
+        (preemption and swap-in, evacuation and handoff, an abort, a
+        deadline or a sentinel trip, speculative rounds, chunked
+        prefill, another program variant, a row with a penalty
+        histogram, the first dispatch) reads the first tokens FIRST,
+        then folds every chunk in flight into host state
+        (``_process_chunks(drain=True)``) and rebuilds the device state
+        from it (``_build_decode_state``), as every change once did.
 
         Returns False when there was no work (the loop then sleeps).
         """
@@ -1949,11 +2014,11 @@ class EngineCore:
             if self._pending_chunks:
                 # chunked decode ran while a brownout suspended
                 # speculation: fold the in-flight chunks into host
-                # state before a spec round reads last-token/positions,
-                # and kill the chunk path's signature cache (spec
-                # rounds advance positions behind its back)
+                # state before a spec round reads last-token/positions
                 self._process_chunks(drain=True)
-                self._decode_signature_cache = None
+            # and kill the chunk path's signature cache: spec rounds
+            # advance positions behind its device state's back
+            self._invalidate_decode_state("spec")
             worked = self._admit_and_prefill()
             worked = self._tick_speculative() or worked
             if (
@@ -1965,17 +2030,35 @@ class EngineCore:
                 # non-spec twin below)
                 self.integrity.idle_tick(self)
             return worked
-        worked = self._admit_and_prefill()
+        wave = self._admit_and_dispatch()
+        worked = bool(wave.plans) or wave.swapped
 
         # decode scheduling is bracketed statement by statement so the
         # leaf spans never overlap: _process_chunks, _build_decode_state
         # and _dispatch_chunk open their own
         active = self._running_seqs()
+        changed, reason = False, None
         if active:
             with self.perf.span("schedule"):
                 signature = self._decode_signature(active)
-            if signature != self._decode_signature_cache:
-                # membership changed: all in-flight chunks must be folded
+                changed = signature != self._decode_signature_cache
+                if changed:
+                    reason = self._drain_reason(active, wave)
+        if changed and reason is None:
+            # a plain change (streams ended at a readback, prompts
+            # joined through this wave's programs): edit the device
+            # state's rows and dispatch the next chunk behind the wave,
+            # with every chunk in flight left in flight
+            reason = self._join_and_dispatch(active, wave)
+        # the wave's first tokens, exactly when they always were read:
+        # behind a plain change's chunk dispatch, ahead of a rebuild
+        # (which takes every row's last token from host state)
+        self._read_first_tokens(wave)
+        if changed:
+            self.perf.note_membership_change(drained=reason is not None)
+            worked = True
+            if reason is not None:
+                # every other change: all in-flight chunks must be folded
                 # into host state before rebuilding the device state.  The
                 # cache is dead from here until a rebuild succeeds — leaving
                 # the old value would let a later identical-looking
@@ -1992,13 +2075,13 @@ class EngineCore:
                     else:
                         chunk = 0
                 if chunk and active:
-                    self._build_decode_state(active)
+                    self._build_decode_state(active, reason)
                     self._decode_signature_cache = (
                         self._decode_signature(active)
                     )
                     self._dispatch_chunk(active, chunk)
-                worked = True
-            elif len(self._pending_chunks) < self.pipeline_depth:
+        elif active:
+            if len(self._pending_chunks) < self.pipeline_depth:
                 survivors: List[Sequence] = []
                 new_sig = None
                 go = refresh = False
@@ -2224,12 +2307,23 @@ class EngineCore:
 
     @engine_thread_only
     def _admit_and_prefill(self) -> bool:
+        """Admit, dispatch the prompt programs, read their first tokens
+        back: the whole of an admission wave with nothing in between
+        (the speculative tick; the chunked tick dispatches its next
+        decode chunk between the two halves, see ``_tick``)."""
+        wave = self._admit_and_dispatch()
+        self._read_first_tokens(wave)
+        return bool(wave.plans) or wave.swapped
+
+    @engine_thread_only
+    def _admit_and_dispatch(self) -> _PrefillWave:
         """Admit waiting prompts a free slot + pages exist for, then prefill
         them in **batched programs**: same-bucket admissions stack into one
         ``[B, bucket]`` dispatch (B padded to the next power of two, padding
         rows writing trash page 0), so a burst of N prompts costs
-        ~N/prefill_batch_max dispatches instead of N.  First tokens for
-        the whole wave are read back in a single transfer.
+        ~N/prefill_batch_max dispatches instead of N.  Nothing here waits
+        for the device: the wave's first tokens are read back by
+        ``_read_first_tokens``, in a single transfer.
 
         While sequences are actively decoding, at most
         ``tpu.prefill_admit_limit`` prompts are admitted per tick, so a
@@ -2238,9 +2332,9 @@ class EngineCore:
         weak-2; the capability vLLM's continuous batching provides opaquely
         at the reference's vgate/backends/vllm_backend.py:51)."""
         limit = self.config.tpu.prefill_admit_limit
-        plans: List[PrefillPlan] = []
+        wave = _PrefillWave(start=time.perf_counter())
+        plans = wave.plans
         swap_plans: List[SwapInPlan] = []
-        start = time.perf_counter()
         with self.perf.span("schedule", self._schedule_args):
             decoding = bool(self._running_seqs())
             while True:
@@ -2260,14 +2354,19 @@ class EngineCore:
             # host-swap re-admission: a jitted host->device scatter
             # replaces the re-prefill entirely — zero recompute tokens
             self._dispatch_swap_in(plan)
+        wave.swapped = bool(swap_plans)
         if not plans:
-            return bool(swap_plans)
+            return wave
         with self.perf.span("schedule"):
-            plan_epochs, chunked, by_bucket = self._stage_prefills(plans)
-        dispatched = [  # (group plans, [B] device tokens)
+            wave.plan_epochs, chunked, by_bucket = self._stage_prefills(
+                plans
+            )
+        wave.chunked = bool(chunked)
+        dispatched = wave.dispatched  # (group plans, [B] device tokens)
+        dispatched.extend(
             ([plan], self._dispatch_chunked_prefill(plan))
             for plan in chunked
-        ]
+        )
         batch_max = max(1, self.config.tpu.prefill_batch_max)
         for (bucket, cached, unaligned), group in sorted(by_bucket.items()):
             for i in range(0, len(group), batch_max):
@@ -2285,9 +2384,20 @@ class EngineCore:
             for plan in plans:
                 stale = (
                     plan.seq.status is not SeqStatus.RUNNING
-                    or plan.seq.preempt_count != plan_epochs[id(plan)]
+                    or plan.seq.preempt_count != wave.plan_epochs[id(plan)]
                 )
                 self.scheduler.commit_prefill(plan, stale=stale)
+        return wave
+
+    @engine_thread_only
+    def _read_first_tokens(self, wave: _PrefillWave) -> None:
+        """The blocking half of an admission wave: wait for its prompt
+        programs (and whatever was queued ahead of them), read every
+        first token back in one transfer and emit them.  A decode chunk
+        dispatched behind the wave meanwhile keeps the device busy."""
+        plans, dispatched = wave.plans, wave.dispatched
+        if not plans:
+            return
         self._beat("prefill_readback", batch=len(plans))
         # the perf split of the one existing sync (see _process_chunks):
         # wait-for-compute (device_s), then the device_get transfer
@@ -2301,7 +2411,7 @@ class EngineCore:
         # batched admission costs one combined dispatch+readback; attribute
         # an equal share to each prefill so observation count stays
         # one-per-prefill and the histogram sum stays the true wall time
-        share = (time.perf_counter() - start) / len(plans)
+        share = (time.perf_counter() - wave.start) / len(plans)
         for plan in plans:
             metrics.observe_with_exemplar(
                 metrics.ENGINE_STEP_TIME.labels(kind="prefill"),
@@ -2310,17 +2420,16 @@ class EngineCore:
             )
         with self.perf.span("emit") as emit:
             delivered = self._emit_first_tokens(
-                dispatched, firsts, plans, plan_epochs,
+                dispatched, firsts, plans, wave.plan_epochs,
                 share, device_s, readback_s,
             )
             emit.note(tokens=delivered)
         self.perf.note_tokens(delivered)
-        return True
 
     @engine_thread_only
     def _stage_prefills(self, plans: List[PrefillPlan]):
         """Admission bookkeeping between try_admit and the dispatches
-        (one ``schedule`` bracket in _admit_and_prefill): the stale-wake
+        (one ``schedule`` bracket in _admit_and_dispatch): the stale-wake
         epochs, the flight/trace records, the fault probe, and the
         grouping into batched programs.  Returns ``(plan_epochs,
         chunked plans, {(bucket, cached, unaligned): plans})``."""
@@ -2393,7 +2502,7 @@ class EngineCore:
         share: float, device_s: float, readback_s: float,
     ) -> int:
         """Fold a prefill wave's first tokens into host state (the
-        ``emit`` bracket of _admit_and_prefill); returns how many were
+        ``emit`` bracket of _read_first_tokens); returns how many were
         delivered."""
         delivered = 0
         wakes: Dict[Any, None] = {}
@@ -2843,10 +2952,152 @@ class EngineCore:
         )
 
     @engine_thread_only
-    def _build_decode_state(self, seqs: List[Sequence]) -> None:
-        self.total_state_rebuilds += 1
+    def _invalidate_decode_state(self, reason: str) -> None:
+        """Host state moved behind the device decode state's back (rows
+        folded, staged or evacuated; speculative rounds advanced
+        positions): the next decode dispatch drains and rebuilds
+        whatever the membership looks like, and names ``reason``."""
+        self._decode_signature_cache = None
+        self._stale_reason = reason
+
+    @engine_thread_only
+    def _drain_reason(
+        self, active: List[Sequence], wave: _PrefillWave
+    ) -> Optional[str]:
+        """Why this change of the decode batch's membership has to drain
+        the pipeline and rebuild the device state from host state, or
+        None for a PLAIN change, which edits the state's rows instead
+        (``_join_and_dispatch``): every row that left ended at a
+        readback (stop or length), every row that came is a row of one
+        of this wave's prompt programs, the others are who and where
+        they were, and no row carries a penalty histogram.  Read off
+        what the tick sees; the program variant is compared where the
+        sampling rows are built."""
+        state = self._dec_state
+        if state is None or self._decode_signature_cache is None:
+            return self._stale_reason or "initial"
+        if wave.swapped:
+            return "swap_in"
+        if wave.chunked:
+            return "chunked_prefill"
+        known = {sig[0]: sig[1:3] for sig in self._decode_signature_cache}
+        joined = {id(plan.seq) for plan in wave.plans}
+        for seq in active:
+            where = known.get(seq.seq_id)
+            if where is None:
+                if id(seq) not in joined:
+                    # admitted by an earlier tick that dispatched no
+                    # chunk: its first token is host state by now
+                    return "late_join"
+            elif where != (seq.slot, seq.preempt_count):
+                return "preempt"
+            if seq.params.has_penalties:
+                return "penalties"
+        live = {id(seq) for seq in active}
+        for seq in state["members"]:
+            if id(seq) in live:
+                continue
+            if seq.status is SeqStatus.FAILED:
+                return "failed"  # deadline, sentinel, KV capacity
+            if seq.status is not SeqStatus.FINISHED:
+                return "preempt"  # or held for a handoff, evacuated
+            if seq.finish_reason == "abort":
+                return "abort"
+        return None
+
+    @engine_thread_only
+    def _join_and_dispatch(
+        self, active: List[Sequence], wave: _PrefillWave
+    ) -> Optional[str]:
+        """A plain membership change (``_drain_reason`` None): edit the
+        device decode state's rows and dispatch the next chunk, waiting
+        on nothing from the device.  Rows that ended are switched off; a
+        row that joined takes its first token from its prompt program's
+        DEVICE output and its position and step index from the host
+        (``_join_decode_rows``, behind the prompt programs in device
+        order); the page tables, the active mask and the sampling rows
+        are re-uploaded whole.  Surviving rows keep their device tokens,
+        positions and step counts, so the chunks in flight stay in
+        flight: each is folded by the ``(seq, epoch)`` list it was
+        dispatched with.
+
+        Returns None with the chunk dispatched, or the reason the change
+        was not plain after all (the pool ran dry, the rows need another
+        program variant, nothing in flight and no budget to step):
+        nothing was dispatched then and the caller drains and rebuilds."""
+        state = self._dec_state
         B = self.max_slots
-        with self.perf.span("state", lambda: {"rows": len(seqs)}):
+        with self.perf.span("schedule"):
+            in_flight = sum(c[1] for c in self._pending_chunks)
+            chunk = self._pick_chunk(active, lead=in_flight)
+            # a joiner's first token is not in host state yet, so its
+            # K/V writes start one position past what total_len says
+            horizon = in_flight + chunk + (1 if wave.plans else 0)
+            if chunk and (
+                not self.scheduler.prepare_decode(active, horizon=horizon)
+                or any(s.status is not SeqStatus.RUNNING for s in active)
+            ):
+                return "preempt"
+        if chunk == 0:
+            # the steps in flight cover every budget (a joiner's is its
+            # first token then): nothing to edit for, the change stands
+            # for the next tick and the oldest chunk is folded meanwhile
+            if not self._pending_chunks:
+                return "budget"
+            self._process_chunks()
+            return None
+        with self.perf.span(
+            "state",
+            lambda: {"rows": len(active), "joined": len(wave.plans)},
+        ):
+            rows = self._sampling_rows(B, ((s.slot, s) for s in active))
+            if rows["variant"] != state["variant"]:
+                return "variant"
+            self._refresh_page_tables(active)
+            live = np.zeros((B,), bool)
+            live[[seq.slot for seq in active]] = True
+            carried = (state["tokens"], state["positions"], state["steps"])
+            for group, (first_tokens, _) in wave.dispatched:
+                n = first_tokens.shape[0]
+                slots = np.full((n,), B, np.int32)  # padding: dropped
+                positions = np.zeros((n,), np.int32)
+                steps = np.zeros((n,), np.int32)
+                for row, plan in enumerate(group):
+                    seq = plan.seq
+                    slots[row] = plan.slot
+                    positions[row] = seq.total_len
+                    steps[row] = seq.num_generated + 1
+                carried = _join_decode_rows(
+                    *carried, slots, first_tokens, positions, steps
+                )
+            # survivors keep the device's step counts; nothing here has
+            # a histogram, so the penalty rows stay the state's zeros
+            for name in ("steps", "counts", "freq_pens", "pres_pens"):
+                del rows[name]
+            state.update(rows)
+            state["tokens"], state["positions"], state["steps"] = carried
+            state["active"] = jnp.asarray(live)
+            state["counter"] = self._carried(np.uint32(self._step_counter))
+            state["members"] = list(active)
+        self._decode_signature_cache = self._decode_signature(active)
+        self._dispatch_chunk(active, chunk)
+        return None
+
+    @engine_thread_only
+    def _build_decode_state(
+        self, seqs: List[Sequence], reason: str = "initial"
+    ) -> None:
+        """The decode state from HOST state, every row's last token and
+        position included: only current once every chunk in flight has
+        been folded, so the caller drained the pipeline first.
+        ``reason`` (``_drain_reason``) says in the trace why this
+        membership change could not edit the state's rows instead."""
+        self.total_state_rebuilds += 1
+        self._stale_reason = None
+        B = self.max_slots
+        with self.perf.span(
+            "state", lambda: {"rows": len(seqs), "reason": reason}
+        ):
             tokens = np.zeros((B,), np.int32)
             positions = np.zeros((B,), np.int32)
             active = np.zeros((B,), bool)
@@ -2860,31 +3111,45 @@ class EngineCore:
                 positions[slot] = seq.total_len - 1
                 active[slot] = True
             self._dec_state = state = {
-                "tokens": jnp.asarray(tokens),
-                "positions": jnp.asarray(positions),
-                "page_tables": jnp.asarray(self._page_tables_np),
+                "tokens": self._carried(tokens),
+                "positions": self._carried(positions),
+                "page_tables": self._page_tables_upload(),
                 "active": jnp.asarray(active),
-                "counter": jnp.asarray(self._step_counter, jnp.uint32),
+                "counter": self._carried(np.uint32(self._step_counter)),
                 **self._sampling_rows(B, ((s.slot, s) for s in seqs)),
+                # whose rows these are (_drain_reason tells a stream
+                # that ended from one that was taken away)
+                "members": list(seqs),
             }
+            state["steps"] = self._carried(state["steps"])
             if state["counts"] is None:
                 # the chunk program takes the two penalty rows whether
                 # or not a histogram rides with them
                 state["freq_pens"] = state["pres_pens"] = jnp.zeros((B,))
 
     @engine_thread_only
+    def _page_tables_upload(self):
+        """The decode state's page tables as a device array of its own:
+        a COPY.  On a CPU ``jnp.asarray`` may alias the host buffer,
+        which the next prompt dispatch rewrites in place for a slot's
+        new tenant while a chunk that still steps the old one (its
+        overshoot) is in flight and must keep the table it was given."""
+        return jnp.asarray(self._page_tables_np.copy())
+
+    @engine_thread_only
     def _refresh_page_tables(self, seqs: List[Sequence]) -> None:
-        """Re-upload ONLY the page tables after in-place page growth (same
-        sequences, same slots).  In-flight chunks keep their older table,
-        which is valid: the new page is only addressed at positions those
-        chunks never reach."""
+        """Re-upload ONLY the page tables: after in-place page growth (same
+        sequences, same slots), and as part of a membership change's row
+        edit.  In-flight chunks keep their older table, which is valid:
+        the new page is only addressed at positions those chunks never
+        reach."""
         state = self._dec_state
         assert state is not None
         for seq in seqs:
             row = self._page_tables_np[seq.slot]
             row[:] = 0
             row[: len(seq.pages)] = seq.pages
-        state["page_tables"] = jnp.asarray(self._page_tables_np)
+        state["page_tables"] = self._page_tables_upload()
 
     @engine_thread_only
     def _pick_chunk(self, active: List[Sequence], lead: int = 0) -> int:
@@ -2899,13 +3164,23 @@ class EngineCore:
         admission within a fraction of a full chunk — a mid-serving
         arrival's TTFT is then bounded by a short chunk, not up to
         ``decode_pipeline`` full ones.  With no free slot (or an empty
-        queue) full-size chunks keep throughput maximal."""
+        queue) full-size chunks keep throughput maximal.  A stream's end
+        is found at a tick's END (the readback) and its slot filled at
+        the head of the next, before the pick: at saturation no slot is
+        free here, and the rule fires for a burst larger than
+        ``prefill_admit_limit`` or for arrivals into free slots, where
+        it is meant to.  Only a change that drains the pipeline
+        (``_drain_reason``) reads chunks back just before the pick and
+        can free a slot there."""
         max_len = self.config.model.max_model_len
         headroom = 0
         for seq in active:
             rem_tokens = max(1, seq.params.max_tokens) - seq.num_generated
             rem_len = max_len - seq.total_len
-            headroom = max(headroom, min(rem_tokens, rem_len) - lead)
+            # a row that joined behind its prompt program this tick has
+            # no step in flight, and its first token not in host state
+            ahead = lead if seq.output_ids else 1
+            headroom = max(headroom, min(rem_tokens, rem_len) - ahead)
         if headroom <= 0:
             # in-flight steps already cover every budget: dispatching more
             # would be pure overshoot (possible only when lead > 0; a
@@ -3094,7 +3369,7 @@ class EngineCore:
                 block_s, device_s, read.seconds,
             )
             # append under the readback lock (the blocking np.asarray
-            # is above): see _admit_and_prefill — the epoch guard is
+            # is above): see _emit_first_tokens — the epoch guard is
             # check-then-append, and containment's fold must not
             # interleave with it
             delivered = 0
@@ -3355,13 +3630,13 @@ class EngineCore:
             "spec_verify", active, S_round, spec_s, device_s, readback_s
         )
         # append under the readback lock (device waits all happened
-        # above): see _admit_and_prefill for the interleaving hazard
+        # above): see _emit_first_tokens for the interleaving hazard
         delivered = 0
         wakes: Dict[Any, None] = {}
         with self.perf.span("emit") as emit:
             with self._readback_lock:
                 for seq in active:
-                    # stale-wake guard (see _admit_and_prefill): status
+                    # stale-wake guard (see _emit_first_tokens): status
                     # AND the epoch captured at dispatch — a watchdog
                     # stall during the blocking readback above may have
                     # checkpointed + replayed this sequence already

@@ -1,6 +1,7 @@
 """The device programs the engine launches, each a pure function of
 arrays and a ``ModelSpec``: the two prompt passes with their fused
-first-token sampling, the fused decode chunk, the speculative verify
+first-token sampling, the fused decode chunk and the row edit that lets
+a prompt pass's rows join its carried state, the speculative verify
 round, and the page copies of copy-on-write and the host swap tier.
 
 ``runtime/engine_core.py`` calls them and nothing here knows the engine:
@@ -312,6 +313,30 @@ def _decode_chunk(
     return (
         chunk_tokens, chunk_lp, tokens, positions, counter, steps, counts,
         k_pages, v_pages, chunk_flags, *tail,
+    )
+
+
+@jax.jit
+def _join_decode_rows(
+    tokens, positions, steps, slots, first_tokens, join_positions,
+    join_steps,
+):
+    """A prompt program's rows join the decode batch on the device:
+    ``first_tokens`` [J] is that program's sampled-token output, still
+    unread by the host, and ``slots`` [J] the decode slot of each of its
+    rows (its padding rows point past the batch and are dropped).  The
+    chunk programs' carried ``tokens`` / ``positions`` / ``steps`` [B]
+    get the joiners' first token, the position it is fed at and its
+    index among the generated tokens; every other row keeps what the
+    last chunk left there.  One compile per J, a prompt program's batch
+    size."""
+    def put(carried, rows):
+        return carried.at[slots].set(rows.astype(carried.dtype), mode="drop")
+
+    return (
+        put(tokens, first_tokens),
+        put(positions, join_positions),
+        put(steps, join_steps),
     )
 
 
