@@ -580,3 +580,116 @@ def test_nemotron_h_checkpoint_equals_the_reference(tmp_path, first, held):
         assert diffs and max(diffs) < 4 * TOL_F32, max(diffs)
     finally:
         core.stop()
+
+
+# ------------------------------------------------------------- mistral4
+
+def _mistral4_tensors(cfg, seed=17):
+    """Random tensors for tiny-mla-moe TWICE: as the plain reference
+    takes them (one dict a layer, rotary columns in halves) and under
+    the checkpoint's names and layouts (torch [out, in]; ``kv_b_proj``
+    whole, per head ``[nope | v]``; the rotary columns of every head of
+    ``q_b_proj`` and of ``kv_a_proj_with_mqa`` in PAIRS (2i, 2i + 1),
+    ``rope_interleave``)."""
+    from perfbench.references import mistral4 as ref
+
+    z = ref.sizes(cfg)
+    rng = np.random.default_rng(seed)
+    rand = lambda *shape, scale=0.05: (
+        rng.normal(size=shape) * scale).astype(np.float32)
+    D, H, rope = z["D"], z["H"], z["rope"]
+
+    def pair(w):  # halves -> pairs, on the last `rope` columns
+        head, tail = w[..., :-rope], w[..., -rope:]
+        out = np.empty_like(tail)
+        out[..., 0::2], out[..., 1::2] = (
+            tail[..., : rope // 2], tail[..., rope // 2:])
+        return np.concatenate([head, out], axis=-1)
+
+    plain = {"embed": rand(z["V"], D, scale=0.5), "lm_head": rand(D, z["V"]),
+             "final_norm": 1.0 + rand(D, scale=0.2), "layers": []}
+    hf = {
+        "model.embed_tokens.weight": plain["embed"],
+        "model.norm.weight": plain["final_norm"],
+        "lm_head.weight": plain["lm_head"].T,
+    }
+    scales = {"router": 0.5, "q_b": 0.2, "kv_b": 0.2}
+    for i in range(cfg["num_hidden_layers"]):
+        w = {name: rand(*shape, scale=scales.get(name, 0.05))
+             for name, shape in ref.layer_shapes(z).items()}
+        for name, n in (("input_norm", D), ("post_norm", D),
+                        ("q_a_norm", z["ql"]), ("kv_a_norm", z["kl"])):
+            w[name] = 1.0 + rand(n, scale=0.2)
+        pre = f"model.layers.{i}."
+        att = pre + "self_attn."
+        hf[pre + "input_layernorm.weight"] = w["input_norm"]
+        hf[pre + "post_attention_layernorm.weight"] = w["post_norm"]
+        hf[att + "q_a_proj.weight"] = w["q_a"].T
+        hf[att + "q_a_layernorm.weight"] = w["q_a_norm"]
+        q_b = pair(w["q_b"].reshape(z["ql"], H, -1)).reshape(z["ql"], -1)
+        hf[att + "q_b_proj.weight"] = q_b.T
+        hf[att + "kv_a_proj_with_mqa.weight"] = pair(w["kv_a"]).T
+        hf[att + "kv_a_layernorm.weight"] = w["kv_a_norm"]
+        hf[att + "kv_b_proj.weight"] = w["kv_b"].reshape(z["kl"], -1).T
+        hf[att + "o_proj.weight"] = w["o"].T
+        hf[pre + "mlp.gate.weight"] = w["router"].T
+        for e in range(z["E"]):
+            for n in ("gate", "up", "down"):
+                hf[f"{pre}mlp.experts.{e}.{n}_proj.weight"] = w[n][e].T
+        for n in ("gate", "up", "down"):
+            hf[f"{pre}mlp.shared_experts.{n}_proj.weight"] = (
+                w[f"shared_{n}"].T)
+        plain["layers"].append(w)
+    return plain, {k: np.ascontiguousarray(v) for k, v in hf.items()}
+
+
+@pytest.mark.parametrize("first, held", [(0, 8), (2, 4)],
+                         ids=["whole", "a-share-of-the-experts"])
+def test_mistral4_checkpoint_equals_the_reference(tmp_path, first, held):
+    """A synthetic ``mistral4`` safetensors file (names ASSUMED to be
+    DeepSeek-V3's) through the loader and the engine against the plain
+    reference fed the same tensors: ``kv_b_proj`` split per head, the
+    interleaved rotary columns undone, norms that are not 1; whole, and
+    holding experts 2..5 of the router's 8."""
+    import dataclasses
+
+    from safetensors.numpy import save_file
+
+    from perfbench.references import mistral4 as ref
+    from tests.test_mla_model import TINY, TOL_F32, engine_config, lp_params
+    from vgate_tpu.models.specs import spec_for_model_id
+    from vgate_tpu.runtime.engine_core import EngineCore
+
+    plain, hf = _mistral4_tensors(TINY)
+    ckpt = tmp_path / "ckpt"
+    ckpt.mkdir()
+    save_file(hf, str(ckpt / "model.safetensors"))
+    spec = dataclasses.replace(
+        spec_for_model_id("tiny-mla-moe"), num_experts=held,
+        first_expert=first)
+    cfg = dict(TINY, n_routed_experts=held, router_width=8,
+               first_expert=first)
+    cut = lambda w: dict(w, **{
+        name: w[name][first:first + held]
+        for name in ("gate", "up", "down")})
+    weights = jax.tree.map(jnp.asarray, dict(
+        plain, layers=[cut(w) for w in plain["layers"]]))
+
+    config = engine_config()
+    config.model.checkpoint_path = str(ckpt)
+    core = EngineCore(config, spec=spec, devices=jax.devices()[:1])
+    core.start()
+    try:
+        prompt = [int(t) for t in
+                  np.random.default_rng(0).integers(3, 259, size=45)]
+        seq = core.submit_tokens(prompt, lp_params(5))
+        assert seq.done_event.wait(timeout=600) and seq.error is None
+        want = ref.logprobs(cfg, 0, jnp.float32,
+                            [prompt + seq.generated_ids], [len(prompt)],
+                            weights=weights)[0]
+        diffs = [abs(t["logprob"] - want[pos, t["token_id"]])
+                 for pos, e in enumerate(core.logprob_entries(seq))
+                 for t in e["top_logprobs"]]
+        assert diffs and max(diffs) < 4 * TOL_F32, max(diffs)
+    finally:
+        core.stop()

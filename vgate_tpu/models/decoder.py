@@ -210,8 +210,11 @@ def _logits(params: Params, spec: ModelSpec, x: jnp.ndarray) -> jnp.ndarray:
 
 
 def _query_scale(spec: ModelSpec):
-    """Attention query scale override (Gemma-2's query_pre_attn_scalar);
-    None selects the default head_dim**-0.5 inside the attention ops."""
+    """Attention query scale override (Gemma-2's query_pre_attn_scalar,
+    latent attention's scale under YaRN); None selects the default
+    head_dim**-0.5 inside the attention ops."""
+    if spec.is_mla:
+        return spec.mla_softmax_scale
     return spec.query_scale ** -0.5 if spec.query_scale > 0 else None
 
 
@@ -295,8 +298,41 @@ def decode_kv_write(
     return "kernel" if kernel else "scatter"
 
 
+def _mla_write_attend(spec: ModelSpec, impl: str, kernel_writes: bool,
+                      page_tables, seq_lens, page_ids, page_off):
+    """``decode_forward``'s cache step for latent attention:
+    ``write_attend(q, row, None, pages, None, layer)`` puts the token's
+    latent row into the ONE pool and attends over it in the absorbed
+    form (ops/pallas/paged_attention.py mla_decode_attention_pallas, or
+    its jnp twin after ``kv_write_tokens``)."""
+    from vgate_tpu.ops.attention import mla_decode_attention
+    from vgate_tpu.ops.pallas.paged_attention import (
+        mla_decode_attention_pallas,
+    )
+
+    kw = dict(v_width=spec.kv_lora_rank, scale=spec.mla_softmax_scale)
+
+    def write_attend(q, row, _v, kp, vp, layer):
+        if kernel_writes:
+            with jax.named_scope("attention"):
+                attn, kp = mla_decode_attention_pallas(
+                    q, kp, page_tables, seq_lens, layer, row, **kw)
+            return attn, kp, vp
+        with jax.named_scope("kv_write"):
+            kp = kv_write_tokens(
+                kp, page_ids, page_off, row[:, None], layer=layer)
+        with jax.named_scope("attention"):
+            fn = (mla_decode_attention_pallas if impl == "pallas"
+                  else mla_decode_attention)
+            attn = fn(q, kp, page_tables, seq_lens, layer, **kw)
+        return attn, kp, vp
+
+    return write_attend
+
+
 def multitok_attention_impl(
-    use_pallas: bool, mesh=None, rows: int = 1, unaligned: bool = False
+    use_pallas: bool, mesh=None, rows: int = 1, unaligned: bool = False,
+    latent: bool = False,
 ) -> str:
     """As above, for the paged multi-token attention of
     ``prefill_suffix_forward`` (``rows`` = suffix bucket) and
@@ -310,6 +346,8 @@ def multitok_attention_impl(
     kernel would be GSPMD-replicated (parallel/tp_attention.py)."""
     if _axis(mesh, "sp") > 1:
         return "sp_shard"
+    if latent:  # no kernel yet for query rows against a latent prefix
+        return "jnp"
     kernel_fits = rows <= 1024 and not unaligned and _axis(mesh, "tp") == 1
     return "pallas" if use_pallas and kernel_fits else "jnp"
 
@@ -402,10 +440,16 @@ def prefill_forward(
             flash_prefill_attention_pallas,
         )
 
+        # a 4,096- or 8,192-row bucket walked in 256-row blocks is bound
+        # by grid steps, not arithmetic (32 x 32 blocks a head: 25.8 ms
+        # a layer at 8,192 rows x 32 heads against 6.9 ms in 1,024-row
+        # blocks; chip, PR 33).  No bucket under 4,096 rows changes
+        blocks = {"block_q": 1024, "block_k": 1024} if S >= 4096 else {}
         attn_fn = functools.partial(
             flash_prefill_attention_pallas,
             softcap=spec.attn_softcap,
             scale=_query_scale(spec),
+            **blocks,
         )
         if impl == "pallas_tp":
             # run the kernel per shard — GSPMD has no partition rule for
@@ -734,6 +778,10 @@ def decode_forward(
         return attn, kp, vp
 
     x = _embed(params, spec, tokens)  # [B, D]
+    if spec.is_mla:
+        write_attend = _mla_write_attend(
+            spec, impl, kernel_writes, page_tables, seq_lens, page_ids,
+            page_off)
     if spec.is_hybrid:
         from vgate_tpu.models import hybrid
 
@@ -803,7 +851,7 @@ def prefill_suffix_forward(
     x = _embed(params, spec, tokens)  # [B, S, D]
 
     impl = multitok_attention_impl(
-        use_pallas, mesh, rows=S, unaligned=unaligned
+        use_pallas, mesh, rows=S, unaligned=unaligned, latent=spec.is_mla
     )
     kernels = use_pallas  # below, use_pallas narrows to the multitok kernel
     sp_mesh = mesh if impl == "sp_shard" else None
@@ -853,7 +901,19 @@ def prefill_suffix_forward(
         assert not unaligned, "hybrid specs have no copy-on-write prefix"
         from vgate_tpu.models import hybrid
 
+        ps = k_pages.shape[-2]
+
         def attend(q, k, v, kp, vp, layer):
+            if spec.is_mla:
+                # K and V expanded from the context's latent rows
+                # (hybrid.py _mla_prompt): the blockwise jnp attention
+                # with the rows' offset.  A Pallas kernel for query rows
+                # against a latent prefix is not written yet
+                return flash_prefill_attention(
+                    q, k, v, total_lens, q_offset=prefix_lens,
+                    scale=_query_scale(spec),
+                    block_k=256 if k.shape[1] % 256 == 0 else ps,
+                )
             if use_pallas:
                 return paged_multitok_attention_pallas(
                     q, kp, vp, ctx_page_tables, prefix_lens, suffix_lens,
@@ -867,7 +927,7 @@ def prefill_suffix_forward(
         x, k_pages, v_pages, state = hybrid.prompt_forward(
             params, spec, x, suffix_lens, positions, k_pages, v_pages,
             state, slots, prefix_lens == 0, suffix_page_tables, attend,
-            kernels,
+            kernels, ctx_tables=ctx_page_tables if spec.is_mla else None,
         )
         return (_logits(params, spec, _last_rows(x, suffix_lens)),
                 k_pages, v_pages, state)

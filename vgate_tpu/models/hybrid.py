@@ -6,11 +6,18 @@ data (``ModelSpec.period_blocks``):
 * ``mamba`` a Mamba-2 state-space layer (Nemotron-H),
 * ``attn``  softmax attention over the paged pool (gated, with per-head
   norms and partial rotary, or plain without rotary: the spec says),
+* ``mla``   multi-head latent attention over the LATENT paged pool
+  (Mistral-Small-4, DeepSeek's form): the pool holds one row ``[c_kv |
+  k_rope]`` a token and no K or V; a prompt pass expands K and V from
+  the rows (non-absorbed), a decode step folds the expansion into the
+  query and the output and reads the rows alone (absorbed),
 * ``moe``   the expert layer of ``ops/moe.py``.
 
 Qwen3-Next's layer is two sub-blocks (a mixer, then experts); its period
 is ``gdn moe gdn moe gdn moe attn moe``.  A Nemotron-H layer is one:
-``EMEMEMEMEM*`` is ``moe mamba`` five times, then ``attn``.
+``EMEMEMEMEM*`` is ``moe mamba`` five times, then ``attn``.  A
+Mistral-Small-4 layer is ``mla moe``, and its stack has no recurrent
+layer: the state is then ``None`` and the second pool too.
 
 ``models/decoder.py``'s forwards hand a hybrid spec's work here after
 they have chosen the attention implementation, so the step programs,
@@ -58,16 +65,79 @@ from vgate_tpu.ops import ssd
 from vgate_tpu.ops.kv_quant import kv_write_pages
 from vgate_tpu.ops.moe import STAT_NAMES, combine_stats, expert_layer
 from vgate_tpu.ops.norms import rms_norm
-from vgate_tpu.ops.rope import apply_rope
+from vgate_tpu.ops.attention import mla_gather_rows
+from vgate_tpu.ops.rope import apply_rope, position_scale
 
 
 def init_layers(spec: ModelSpec, key, dtype, normal, norm_init
                 ) -> Dict[str, Any]:
     """Random draw of a hybrid spec's layer tensors, each family from
     keys of its own."""
+    if spec.is_mla:
+        return _init_mla_layers(spec, key, dtype, normal, norm_init)
     if spec.layer_pattern:
         return _init_pattern_layers(spec, key, dtype, normal)
     return _init_paired_layers(spec, key, dtype, normal, norm_init)
+
+
+def _per_layer(spec: ModelSpec, group: str, fn):
+    """fn(key) -> one layer's tensor; returns key -> the group's
+    ``[P, n, ...]``, one fused program that draws a layer at a time
+    (tensor of layer i from ``fold_in(key, i)``): no draw stands wider
+    than one layer's tensor."""
+    P, n = spec.num_periods, spec.group_layers(group)
+    return jax.jit(lambda k: jax.lax.map(
+        lambda i: fn(jax.random.fold_in(k, i)), jnp.arange(P * n)
+    ).reshape((P, n) + jax.eval_shape(fn, k).shape))
+
+
+def _init_mla_layers(spec: ModelSpec, key, dtype, normal, norm_init
+                     ) -> Dict[str, Any]:
+    """The tensors of a latent-attention stack (every layer ``mla
+    moe``), from ``fold_in(key, 33)`` split 32 ways, a layer at a time.
+    N(0, 0.02) but for the two up-projections ``q_b`` and ``kv_b``,
+    N(0, 0.05), and the norms at 1: at 0.02 throughout the scores are so
+    flat that a wrong rotary, a wrong softmax scale or a stale latent
+    row would hide under a comparison's tolerance.  ``kv_b`` is drawn as
+    the checkpoint holds it, ``[kv_lora_rank, heads x (nope + v)]``, and
+    split per head into ``kv_b_k`` (W_uk) and ``kv_b_v`` (W_uv)."""
+    mk = jax.random.split(jax.random.fold_in(key, 33), 32)
+    D, H = spec.hidden_size, spec.num_heads
+    ql, kl = spec.q_lora_rank, spec.kv_lora_rank
+    nope, rope, vd = (spec.qk_nope_head_dim, spec.qk_rope_head_dim,
+                      spec.v_head_dim)
+    E, R, Fe = spec.num_experts, spec.router_experts, spec.expert_width
+    Fs = spec.shared_expert_intermediate_size
+    lead = (spec.num_periods, 1)
+
+    def draw(k, shape, scale=0.02):
+        return _per_layer(spec, "layer",
+                          lambda kk: normal(kk, shape, scale))(k)
+
+    kv_b = draw(mk[3], (kl, H, nope + vd), 0.05)
+    out = {
+        "input_norm": norm_init(lead + (D,), dtype),
+        "post_norm": norm_init(lead + (D,), dtype),
+        "q_a": {"w": draw(mk[0], (D, ql))},
+        "q_a_norm": norm_init(lead + (ql,), dtype),
+        "q_b": {"w": draw(mk[1], (ql, H * (nope + rope)), 0.05)},
+        "kv_a": {"w": draw(mk[2], (D, kl + rope))},
+        "kv_a_norm": norm_init(lead + (kl,), dtype),
+        "kv_b_k": {"w": kv_b[..., :nope]},
+        "kv_b_v": {"w": kv_b[..., nope:]},
+        "o": {"w": draw(mk[4], (H * vd, D))},
+        "router": draw(mk[5], (D, R)),
+        "gate": {"w": draw(mk[6], (E, D, Fe))},
+        "up": {"w": draw(mk[7], (E, D, Fe))},
+        "down": {"w": draw(mk[8], (E, Fe, D))},
+    }
+    if Fs:
+        out["shared_gate"] = {"w": draw(mk[9], (D, Fs))}
+        out["shared_up"] = {"w": draw(mk[10], (D, Fs))}
+        out["shared_down"] = {"w": draw(mk[11], (Fs, D))}
+    if Fs and spec.shared_expert_gate:
+        out["shared_router"] = draw(mk[12], (D,))
+    return {"layer": out}
 
 
 def _init_pattern_layers(spec: ModelSpec, key, dtype, normal
@@ -90,13 +160,7 @@ def _init_pattern_layers(spec: ModelSpec, key, dtype, normal
     Hm, di, C = spec.mamba_num_heads, spec.mamba_inner, spec.mamba_conv_dim
     f32 = jnp.float32
 
-    def per_layer(group, fn):
-        """fn(key) -> one layer's tensor; returns key -> the group's
-        ``[P, n, ...]``, one fused program that draws a layer at a time."""
-        n = spec.group_layers(group)
-        return jax.jit(lambda k: jax.lax.map(
-            lambda i: fn(jax.random.fold_in(k, i)), jnp.arange(P * n)
-        ).reshape((P, n) + jax.eval_shape(fn, k).shape))
+    per_layer = lambda group, fn: _per_layer(spec, group, fn)
 
     def draw(group, k, shape, scale=0.02):
         return per_layer(group, lambda kk: normal(kk, shape, scale))(k)
@@ -474,6 +538,104 @@ def _recurrent_prompt(kind, normed, lp, st, li, spec: ModelSpec, lens,
     return out, st
 
 
+@jax.named_scope("mla_q")
+def _mla_q(normed, lp, spec: ModelSpec, positions):
+    """Latent attention's queries on normed rows [..., S, D]: through
+    the normed bottleneck, per head ``[q_nope | q_rope]`` with the
+    rotary part rotated, both scaled by the position's gamma.  Returns
+    (q_nope [..., S, H, nope], q_rope [..., S, H, rope])."""
+    H, nope = spec.num_heads, spec.qk_nope_head_dim
+    cq = jnp.einsum("...d,dr->...r", normed, lp["q_a"]["w"])
+    cq = rms_norm(cq, lp["q_a_norm"], spec.rms_eps, spec.unit_offset_norm)
+    q = jnp.einsum("...r,rh->...h", cq, lp["q_b"]["w"])
+    q = q.reshape(*q.shape[:-1], H, nope + spec.qk_rope_head_dim)
+    q_nope = q[..., :nope]
+    q_rope = apply_rope(q[..., nope:], positions, spec.rope_theta,
+                        spec.rope_scaling)
+    if spec.llama_4_scaling_beta:
+        gamma = position_scale(positions, spec.llama_4_scaling_beta,
+                               spec.yarn_original_max_pos)[..., None, None]
+        scaled = lambda t: (t.astype(jnp.float32) * gamma).astype(t.dtype)
+        q_nope, q_rope = scaled(q_nope), scaled(q_rope)
+    return q_nope, q_rope
+
+
+@jax.named_scope("mla_latent")
+def _mla_latent(normed, lp, spec: ModelSpec, positions, width: int):
+    """What the cache holds of normed rows [..., S, D]: ``[c_kv | k_rope
+    | 0]`` [..., S, width], the latent after its norm, the ONE rotary
+    key after its rotation, and the pool row's unused lanes."""
+    kl = spec.kv_lora_rank
+    kv = jnp.einsum("...d,dc->...c", normed, lp["kv_a"]["w"])
+    c = rms_norm(kv[..., :kl], lp["kv_a_norm"], spec.rms_eps,
+                 spec.unit_offset_norm)
+    k_rope = apply_rope(kv[..., None, kl:], positions, spec.rope_theta,
+                        spec.rope_scaling)[..., 0, :]
+    pad = jnp.zeros(kv.shape[:-1] + (width - spec.latent_dim,), kv.dtype)
+    return jnp.concatenate([c, k_rope, pad], axis=-1)
+
+
+@jax.named_scope("mla_expand")
+def _mla_expand(rows, lp, spec: ModelSpec):
+    """Latent rows [B, T, width] -> every head's k [B, T, H, nope + rope]
+    (its own ``c_kv W_uk`` beside the shared rotary key) and v [B, T, H,
+    v]: the NON-absorbed form."""
+    kl, rope = spec.kv_lora_rank, spec.qk_rope_head_dim
+    c = rows[..., :kl]
+    k_nope = jnp.einsum("btk,khn->bthn", c, lp["kv_b_k"]["w"])
+    v = jnp.einsum("btk,khv->bthv", c, lp["kv_b_v"]["w"])
+    k_rope = jnp.broadcast_to(
+        rows[..., None, kl:kl + rope], k_nope.shape[:-1] + (rope,))
+    return jnp.concatenate([k_nope, k_rope], axis=-1), v
+
+
+@jax.named_scope("o_proj")
+def _mla_out(attn, lp):
+    attn = attn.reshape(*attn.shape[:-2], -1)
+    return jnp.einsum("...h,hd->...d", attn, lp["o"]["w"])
+
+
+def _mla_prompt(normed, lp, spec: ModelSpec, positions, kp, vp, index,
+                write_tables, ctx_tables, attend):
+    """Latent attention over prompt rows normed [B, S, D]: the rows'
+    latent goes to the pool (whole pages), K and V are expanded from the
+    prompt's own rows or, for a suffix against a cached prefix
+    (``ctx_tables``), from the pool's rows of the whole context, and are
+    never cached."""
+    B, S = normed.shape[:2]
+    ps, width = kp.shape[-2], kp.shape[-1]
+    q = jnp.concatenate(_mla_q(normed, lp, spec, positions), axis=-1)
+    rows = _mla_latent(normed, lp, spec, positions, width)
+    kp = kv_write_pages(kp, write_tables[:, :S // ps],
+                        rows.reshape(B, S // ps, 1, ps, width), layer=index)
+    if ctx_tables is not None:
+        rows = mla_gather_rows(kp, ctx_tables, index)
+    k, v = _mla_expand(rows, lp, spec)
+    with jax.named_scope("attention"):
+        attn = attend(q, k, v, kp, vp, index)
+    return _mla_out(attn, lp), kp
+
+
+def _mla_step(normed, lp, spec: ModelSpec, positions, kp, vp, index,
+              write_attend):
+    """Latent attention for one decode step, normed [B, D], in the
+    ABSORBED form: ``W_uk`` folded into the query, ``W_uv`` into the
+    output, the step reads the pool's rows alone."""
+    q_nope, q_rope = _mla_q(normed[:, None], lp, spec, positions[:, None])
+    width = kp.shape[-1]
+    row = _mla_latent(normed[:, None], lp, spec, positions[:, None],
+                      width)[:, 0]
+    with jax.named_scope("mla_absorb"):
+        q_lat = jnp.einsum("bhn,khn->bhk", q_nope[:, 0], lp["kv_b_k"]["w"])
+        pad = jnp.zeros(q_lat.shape[:-1] + (width - spec.latent_dim,),
+                        q_lat.dtype)
+        q_abs = jnp.concatenate([q_lat, q_rope[:, 0], pad], axis=-1)
+    attn, kp, vp = write_attend(q_abs, row, None, kp, vp, index)
+    with jax.named_scope("mla_absorb"):
+        attn = jnp.einsum("bhk,khv->bhv", attn, lp["kv_b_v"]["w"])
+    return _mla_out(attn, lp), kp, vp
+
+
 def _segments(blocks):
     """A period's sub-blocks as runs ``(unit, repeats)``: the longest
     run of a repeated unit of up to four sub-blocks at each place, else
@@ -509,7 +671,7 @@ def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
     scanned slices (``stack``: the group's, ``[layers, E, ., .]``).
     Returns (x, k_pages, v_pages, state, stats [4])."""
     layers = dict(params["layers"])
-    if not spec.layer_pattern:  # one attention layer a period: [P, ...]
+    if "full" in layers:  # one attention layer a period: [P, ...]
         layers["full"] = jax.tree.map(lambda a: a[:, None], layers["full"])
     names = spec.expert_stacks
     light = {g: {k: v for k, v in d.items() if k not in names}
@@ -588,15 +750,17 @@ def _experts(normed, lp, spec: ModelSpec, row_mask, use_pallas, index,
 
 def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
                    v_pages, state, slots, fresh, write_tables, attend,
-                   use_pallas: bool):
+                   use_pallas: bool, ctx_tables=None):
     """The prompt pass over embedded rows x [B, S, D] (a whole prompt,
     or the suffix / one chunk of one).  ``write_tables`` are the pages
     the rows' K/V go to (whole pages from the rows' first position);
     ``attend(q, k, v, kp, vp, layer)`` is the attention the caller
-    chose.  Returns (x, k_pages, v_pages, state)."""
+    chose.  ``ctx_tables`` (a suffix or a chunk of a latent-attention
+    spec) are the pages of the whole context, whose rows K and V are
+    then expanded from.  Returns (x, k_pages, v_pages, state)."""
     B, S = x.shape[:2]
     ps = k_pages.shape[-2]
-    KV, hd = spec.num_kv_heads, spec.head_dim
+    KV, hd = spec.cache_heads, spec.cache_head_dim
     n_pages = S // ps
     row_mask = jnp.arange(S)[None, :] < lens[:, None]
     to_pages = lambda t: jnp.transpose(
@@ -610,6 +774,12 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
         if kind in _RECURRENT:
             out, st = _recurrent_prompt(kind, normed, lp, st, index, spec,
                                         lens, slots, fresh)
+            return out, kp, vp, st, None
+        if kind == "mla":
+            with jax.named_scope("mla_attn"):
+                out, kp = _mla_prompt(normed, lp, spec, positions, kp, vp,
+                                      index, write_tables, ctx_tables,
+                                      attend)
             return out, kp, vp, st, None
         with jax.named_scope("gated_attn"):
             q, k, v, gate = _gated_qkv(normed, lp, spec, positions)
@@ -645,6 +815,11 @@ def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
             with jax.named_scope(scope):
                 out, st = step_fn(normed, lp, st, index, spec, active,
                                   use_pallas)
+            return out, kp, vp, st, None
+        if kind == "mla":
+            with jax.named_scope("mla_attn"):
+                out, kp, vp = _mla_step(normed, lp, spec, positions, kp,
+                                        vp, index, write_attend)
             return out, kp, vp, st, None
         with jax.named_scope("gated_attn"):
             q, k, v, gate = _gated_qkv(

@@ -9,6 +9,7 @@ Gemma-2 (sliding-window + softcap attention, sandwich norms).
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field, replace
 from typing import Dict, Optional
 
@@ -112,8 +113,40 @@ class ModelSpec:
     routed_scaling_factor: float = 1.0
     moe_gated: bool = True
     shared_expert_gate: bool = True
+    # shared experts counted in experts' widths (DeepSeek's key): the
+    # shared expert is then n_shared_experts x moe_intermediate_size wide
+    n_shared_experts: int = 0
+    # ---- multi-head latent attention (kv_lora_rank > 0): queries
+    # through a normed rank-q_lora_rank bottleneck, K and V expanded
+    # from ONE normed latent of kv_lora_rank a token beside ONE rotary
+    # key of qk_rope_head_dim shared by all heads.  The paged cache then
+    # holds the latent row [c_kv | k_rope] and no K or V
+    # (``latent_dim``, ``cache_*`` below).  ``rope_interleave``: the
+    # checkpoint pairs rotary dimensions (2i, 2i+1); undone at load
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False
+    # ---- YaRN rotary scaling (yarn_factor > 0) and the position
+    # scaling of the queries, gamma(pos) = 1 + beta * ln(1 + pos //
+    # yarn_original_max_pos).  The softmax scale under YaRN is
+    # head^-0.5 * m^2, m = 0.1 * yarn_mscale_all_dim * ln(factor) + 1
+    yarn_factor: float = 0.0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_original_max_pos: int = 0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    llama_4_scaling_beta: float = 0.0
 
     def __post_init__(self):
+        if self.n_shared_experts and not self.shared_expert_intermediate_size:
+            object.__setattr__(
+                self, "shared_expert_intermediate_size",
+                self.n_shared_experts * self.expert_width,
+            )
         # a preset changed from JSON (perfbench/serve.py overrides)
         # brings lists; the spec is a static jit argument and must hash
         if not isinstance(self.extra_stop_ids, tuple):
@@ -130,7 +163,64 @@ class ModelSpec:
         """A stack of sub-blocks of several kinds (models/hybrid.py):
         recurrent layers beside attention ones, a per-slot recurrent
         state beside the paged pool."""
-        return bool(self.layer_pattern) or self.full_attention_interval > 1
+        return (bool(self.layer_pattern) or self.full_attention_interval > 1
+                or self.is_mla)
+
+    @property
+    def is_mla(self) -> bool:
+        """Multi-head latent attention over a latent paged cache."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Values a token holds in a layer of the latent cache."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def kv_pools(self) -> int:
+        """Arrays of the paged cache: K and V, or the one latent pool."""
+        return 1 if self.is_mla else 2
+
+    @property
+    def cache_heads(self) -> int:
+        return 1 if self.is_mla else self.num_kv_heads
+
+    @property
+    def cache_head_dim(self) -> int:
+        """Lanes of a cached row.  The latent row is padded to whole
+        128-lane tiles (320 -> 384): XLA's tiled HBM layout and the
+        kernel's page DMA hold such a row either way, so the pool and
+        ``kv_page_bytes`` count the padded row."""
+        return -(-self.latent_dim // 128) * 128 if self.is_mla else self.head_dim
+
+    @property
+    def mla_softmax_scale(self) -> float:
+        """sigma: head^-0.5, times m^2 under YaRN with mscale_all_dim."""
+        scale = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        if self.yarn_factor > 1 and self.yarn_mscale_all_dim:
+            m = 0.1 * self.yarn_mscale_all_dim * math.log(self.yarn_factor) + 1
+            scale *= m * m
+        return scale
+
+    @property
+    def rope_parameters(self) -> dict:
+        """The YaRN group as the published config.json spells it (what
+        perfbench/serve.py holds the program to)."""
+        if self.yarn_factor <= 0:
+            return {}
+        num = lambda v: int(v) if float(v).is_integer() else v
+        return {
+            "beta_fast": num(self.yarn_beta_fast),
+            "beta_slow": num(self.yarn_beta_slow),
+            "factor": num(self.yarn_factor),
+            "llama_4_scaling_beta": self.llama_4_scaling_beta,
+            "mscale": num(self.yarn_mscale),
+            "mscale_all_dim": num(self.yarn_mscale_all_dim),
+            "original_max_position_embeddings": self.yarn_original_max_pos,
+            "rope_theta": num(self.rope_theta),
+            "rope_type": "yarn",
+            "type": "yarn",
+        }
 
     @property
     def layers_per_period(self) -> int:
@@ -152,6 +242,9 @@ class ModelSpec:
         layer's index inside the group's period."""
         if not self.is_hybrid:
             return ()
+        if self.is_mla:  # every layer: latent attention, then experts
+            return (("mla", "layer", "input_norm", 0),
+                    ("moe", "layer", "post_norm", 0))
         if not self.layer_pattern:  # layers of two sub-blocks
             n = self.full_attention_interval - 1
             lin = [(k, "linear", nm, i) for i in range(n)
@@ -180,8 +273,9 @@ class ModelSpec:
 
     @property
     def attn_layers(self) -> int:
-        """Layers that hold K/V pages."""
-        return self._layers_of("attn") if self.is_hybrid else self.num_layers
+        """Layers that hold pages (K/V, or the latent)."""
+        return (self._layers_of("attn", "mla") if self.is_hybrid
+                else self.num_layers)
 
     @property
     def linear_layers(self) -> int:
@@ -269,11 +363,18 @@ class ModelSpec:
             attn += 2 * self.head_dim
         if self.qkv_bias:
             attn += q_dim + 2 * kv_dim
+        if self.is_mla:  # q_a, its norm, q_b, kv_a, its norm, kv_b, o
+            ql, kl = self.q_lora_rank, self.kv_lora_rank
+            nope, vd = self.qk_nope_head_dim, self.v_head_dim
+            H = self.num_heads
+            attn = (D * ql + ql + ql * H * (nope + self.qk_rope_head_dim)
+                    + D * self.latent_dim + kl + kl * H * (nope + vd)
+                    + H * vd * D)
         if self.is_moe:
             Fe, Fs = self.expert_width, self.shared_expert_intermediate_size
             mlp = self.num_experts * 3 * D * Fe + D * self.router_experts
             if Fs:
-                mlp += 3 * D * Fs + D
+                mlp += 3 * D * Fs + (D if self.shared_expert_gate else 0)
         else:
             mlp = 3 * D * F
         norms = 2 * D + (2 * D if self.ffn_sandwich else 0)
@@ -339,7 +440,12 @@ class ModelSpec:
 
     @property
     def rope_scaling(self):
-        """Tuple for ops/rope.py (None when scaling is off)."""
+        """Tuple for ops/rope.py (None when scaling is off): Llama-3's
+        four numbers, or ``("yarn", factor, beta_fast, beta_slow,
+        original_max_pos)``."""
+        if self.yarn_factor > 0:
+            return ("yarn", self.yarn_factor, self.yarn_beta_fast,
+                    self.yarn_beta_slow, self.yarn_original_max_pos)
         if self.rope_scaling_factor <= 0:
             return None
         return (
@@ -675,6 +781,48 @@ NEMOTRON3_SUPER_120B = _register(
     )
 )
 
+# Published sizes (config.json, model_type mistral4; the vision encoder
+# is not part of this spec).  Stop ids: the catalog row's config has
+# none (assumed: </s> 2, <s> 1).
+MISTRAL_SMALL4_119B = _register(
+    ModelSpec(
+        name="mistralai/Mistral-Small-4-119B-2603",
+        vocab_size=131072,
+        hidden_size=4096,
+        num_layers=36,
+        num_heads=32,
+        num_kv_heads=32,
+        head_dim=128,
+        intermediate_size=12288,  # published; no layer is dense
+        rope_theta=10_000.0,
+        rms_eps=1e-6,
+        qkv_bias=False,
+        tie_embeddings=False,
+        eos_token_id=2,
+        bos_token_id=1,
+        max_position_embeddings=1048576,
+        num_experts=128,
+        experts_per_token=4,
+        moe_intermediate_size=2048,
+        router_width=128,
+        shared_expert_gate=False,
+        n_shared_experts=1,
+        q_lora_rank=1024,
+        kv_lora_rank=256,
+        qk_nope_head_dim=64,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        rope_interleave=True,
+        yarn_factor=128.0,
+        yarn_beta_fast=32.0,
+        yarn_beta_slow=1.0,
+        yarn_original_max_pos=8192,
+        yarn_mscale=1.0,
+        yarn_mscale_all_dim=1.0,
+        llama_4_scaling_beta=0.1,
+    )
+)
+
 BGE_BASE = _register(
     ModelSpec(
         name="BAAI/bge-base-en-v1.5",
@@ -824,6 +972,49 @@ TINY_NEMOTRON_H = _register(
         routed_scaling_factor=2.5,
         moe_gated=False,
         shared_expert_gate=False,
+    )
+)
+
+# every mechanism of Mistral-Small-4 at toy widths: latent attention
+# over a latent cache, YaRN over an original maximum of 32 (so that a
+# CPU test's contexts pass it and the queries' position scaling leaves
+# 1), a softmax-routed expert layer with an ungated shared expert
+TINY_MLA_MOE = _register(
+    ModelSpec(
+        name="tiny-mla-moe",
+        vocab_size=512,
+        hidden_size=64,
+        num_layers=3,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=16,
+        intermediate_size=128,
+        rope_theta=10000.0,
+        rms_eps=1e-6,
+        qkv_bias=False,
+        tie_embeddings=False,
+        eos_token_id=0,
+        bos_token_id=1,
+        max_position_embeddings=4096,
+        num_experts=8,
+        experts_per_token=2,
+        moe_intermediate_size=48,
+        router_width=8,
+        shared_expert_gate=False,
+        n_shared_experts=1,
+        q_lora_rank=24,
+        kv_lora_rank=16,
+        qk_nope_head_dim=8,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        rope_interleave=True,
+        yarn_factor=4.0,
+        yarn_beta_fast=32.0,
+        yarn_beta_slow=1.0,
+        yarn_original_max_pos=32,
+        yarn_mscale=1.0,
+        yarn_mscale_all_dim=1.0,
+        llama_4_scaling_beta=0.1,
     )
 )
 

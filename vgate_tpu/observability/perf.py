@@ -299,6 +299,7 @@ class PerfRecorder:
         # decode chunks; empty otherwise and then left out of totals())
         self._moe: Dict[str, int] = {}
         self._state: Dict[str, int] = {}
+        self._mla: Dict[str, int] = {}
         self.total_engine_cpu_s = 0.0
         self.total_engine_cpu_in_wait_s = 0.0
         # the flight recorder's per-request phase sums (admitted /
@@ -429,6 +430,36 @@ class PerfRecorder:
             ("layer_steps", steps * linear_layers),
         ):
             self._state[name] = self._state.get(name, 0) + add
+
+    def note_mla_decode(self, steps: int, rows: int, ctx_tokens: int,
+                        layers: int) -> None:
+        """One decode chunk of a latent-attention spec, booked once per
+        readback: ``rows`` sequences holding ``ctx_tokens`` tokens in
+        all rode ``steps`` steps, and step k read each row's context so
+        far (its length at dispatch + k) in each of ``layers`` layers.
+        Only real rows of real lengths are counted: what the decode
+        kernel has to read, and no padding."""
+        reads = steps * ctx_tokens + rows * steps * (steps - 1) // 2
+        for name, add in (
+            ("decode_token_reads", reads * layers),
+            ("decode_steps", steps),
+            ("latent_rows_written", steps * rows * layers),
+        ):
+            self._mla[name] = self._mla.get(name, 0) + add
+
+    def note_mla_prefill(self, tokens: int, cached: int,
+                         layers: int) -> None:
+        """One prompt of a latent-attention spec, booked at its
+        readback: positions ``cached .. tokens - 1`` went through the
+        prompt pass, position i attending to i + 1 keys, in each of
+        ``layers`` layers (no padding, nothing above the diagonal)."""
+        pairs = (tokens * (tokens + 1) - cached * (cached + 1)) // 2
+        for name, add in (
+            ("prefill_pairs", pairs * layers),
+            ("prefill_prompts", 1),
+            ("latent_rows_written", (tokens - cached) * layers),
+        ):
+            self._mla[name] = self._mla.get(name, 0) + add
 
     def tick_end(self, worked: bool) -> None:
         """Close the tick: derive ``host_s`` as the unexplained wall
@@ -652,6 +683,8 @@ class PerfRecorder:
         if self._moe:
             out["moe"] = dict(self._moe)
             out["state"] = dict(self._state)
+        if self._mla:
+            out["mla"] = dict(self._mla)
         return out
 
     def snapshot(self) -> Dict[str, Any]:

@@ -259,3 +259,41 @@ def paged_suffix_attention(
         q, k, v, seq_lens, block_k=block_k, q_offset=prefix_lens,
         softcap=softcap, window=window, scale=scale,
     )
+
+
+def mla_gather_rows(pages, page_tables, layer):
+    """The latent pool's rows of each slot's page window, [B, ctx, W]
+    (pages [L, 1, P, ps, W])."""
+    sel = gather_pages(pages, page_tables, layer=layer)  # [1, B, n, ps, W]
+    B, n, ps, W = sel.shape[1:]
+    return sel.reshape(B, n * ps, W)
+
+
+def mla_decode_attention(
+    q: jnp.ndarray,  # [B, H, W] absorbed queries
+    pages: jnp.ndarray,  # [L, 1, P, ps, W]: the latent pool
+    page_tables: jnp.ndarray,  # [B, pages_per_seq]
+    seq_lens: jnp.ndarray,  # [B] context length (incl. the current token)
+    layer,
+    v_width: int,
+    scale: float,
+) -> jnp.ndarray:
+    """Decode attention of multi-head latent attention in its absorbed
+    form, [B, H, v_width]: every head's query meets a token's ONE cached
+    row as its key and takes the row's first ``v_width`` lanes as its
+    value.  The twin of ``mla_decode_attention_pallas``."""
+    rows = mla_gather_rows(pages, page_tables, layer)
+    scores = jnp.einsum(
+        "bhw,btw->bht", q, rows.astype(q.dtype),
+        preferred_element_type=jnp.float32,
+    ) * scale
+    valid = jnp.arange(rows.shape[1])[None, :] < seq_lens[:, None]
+    scores = jnp.where(valid[:, None, :], scores, -1e30)
+    probs = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
+    probs = probs / jnp.sum(probs, axis=-1, keepdims=True)
+    values = rows[..., :v_width]
+    out = jnp.einsum(
+        "bht,btv->bhv", probs.astype(values.dtype), values,
+        preferred_element_type=jnp.float32,
+    )
+    return out.astype(q.dtype)

@@ -181,13 +181,14 @@ DECODE_CHUNK_TOKENS = 256
 DECODE_WRITE_BUFFERS = 4
 
 
-def _decode_sizes(B, KV, G, hd, page_size, pages_per_seq, kv_dtype, q_dtype):
+def _decode_sizes(B, KV, G, hd, page_size, pages_per_seq, kv_dtype, q_dtype,
+                  pools: int = 2):
     """(pages a chunk, slots a program) for one geometry."""
     kv_bytes = jnp.dtype(kv_dtype).itemsize
     q_bytes = jnp.dtype(q_dtype).itemsize
-    # K and V of every KV head in every buffer; a power of two, so the
-    # kernel's page arithmetic is shifts
-    token_bytes = DECODE_BUFFERS * 2 * KV * hd * kv_bytes
+    # K and V (or the one latent pool's rows) of every KV head in every
+    # buffer; a power of two, so the kernel's page arithmetic is shifts
+    token_bytes = DECODE_BUFFERS * pools * KV * hd * kv_bytes
     chunk_tokens = min(
         DECODE_VMEM_BUDGET // 2 // token_bytes, DECODE_CHUNK_TOKENS
     )
@@ -240,6 +241,7 @@ def _decode_kernel(
     has_layer: bool,
     quant: bool,
     write: bool,
+    latent: int = 0,
 ):
     """One program serves a BLOCK of slots: its work list is the live
     chunks of those slots in order, and the next chunks' pages are in
@@ -271,11 +273,28 @@ def _decode_kernel(
     BELONGS TO THAT ONE SEQUENCE (the radix cache shares whole pages
     only and copies a partial one before anyone appends to it), so no
     other slot reads or writes it during the call.  A slot of length 0
-    writes nothing."""
+    writes nothing.
+
+    With `latent` > 0 (multi-head latent attention, absorbed form) there
+    is ONE pool and no V: a row of it is the key of every query head,
+    and its first `latent` lanes are the value.  The refs are then
+    q_ref [BS, 1, G, W], the pool, (`write`) new_ref [BS, 1, W]; out_ref
+    [BS, 1, G, latent], (`write`) the pool again; k_buf, (`write`)
+    wk_buf, acc [1, G, latent], m, l, slots_ref, sems, (`write`) wsems."""
     k_scale_ref = v_scale_ref = sk_buf = sv_buf = None
     k_new_ref = v_new_ref = k_out_ref = v_out_ref = None
-    wk_buf = wv_buf = wsems = None
-    if quant:
+    wk_buf = wv_buf = wsems = v_pages_ref = v_buf = None
+    if latent and write:
+        (
+            q_ref, k_pages_ref, k_new_ref, out_ref, k_out_ref, k_buf,
+            wk_buf, acc_ref, m_ref, l_ref, slots_ref, sems, wsems,
+        ) = refs
+    elif latent:
+        (
+            q_ref, k_pages_ref, out_ref, k_buf, acc_ref, m_ref, l_ref,
+            slots_ref, sems,
+        ) = refs
+    elif quant:
         (
             q_ref, k_pages_ref, v_pages_ref, k_scale_ref, v_scale_ref,
             out_ref, k_buf, v_buf, sk_buf, sv_buf, acc_ref, m_ref, l_ref,
@@ -292,6 +311,13 @@ def _decode_kernel(
             q_ref, k_pages_ref, v_pages_ref,
             out_ref, k_buf, v_buf, acc_ref, m_ref, l_ref, slots_ref, sems,
         ) = refs
+    # (pool, its chunk buffer), and what a new token's row goes through
+    pools = ((k_pages_ref, k_buf),) if latent else (
+        (k_pages_ref, k_buf), (v_pages_ref, v_buf))
+    writes = ((k_new_ref, k_buf, wk_buf, k_out_ref),) if latent else (
+        (k_new_ref, k_buf, wk_buf, k_out_ref),
+        (v_new_ref, v_buf, wv_buf, v_out_ref))
+    value_buf = k_buf if latent else v_buf
     BS, KV, G, _ = q_ref.shape
     num_pages = k_pages_ref.shape[2 if has_layer else 1]
     CP = chunk_pages
@@ -331,7 +357,7 @@ def _decode_kernel(
     # masked scores drop K's, but softmax weight 0 x stale NaN would
     # poison the accumulator through V (and V's scales), so the buffers
     # start finite and only ever hold pool rows after that
-    v_buf[...] = jnp.zeros_like(v_buf)
+    value_buf[...] = jnp.zeros_like(value_buf)
     if quant:
         sv_buf[...] = jnp.zeros_like(sv_buf)
 
@@ -363,9 +389,7 @@ def _decode_kernel(
                         else ref.at[:, page_id]
                     )
 
-                for pool, buffer in (
-                    (k_pages_ref, k_buf), (v_pages_ref, v_buf),
-                ):
+                for pool, buffer in pools:
                     pltpu.make_async_copy(
                         src(pool), buffer.at[buf, :, rows, :], sems.at[buf]
                     ).start()
@@ -386,7 +410,7 @@ def _decode_kernel(
 
         def wait_pages(n):
             rows = pl.ds(0, n * page_size)
-            for buffer in (k_buf, v_buf):
+            for _, buffer in pools:
                 dst = buffer.at[buf, :, rows, :]
                 pltpu.make_async_copy(dst, dst, sems.at[buf]).wait()
             if quant:
@@ -400,8 +424,8 @@ def _decode_kernel(
             )
 
     def wait_write(stage):
-        """Wait for the two copies that left staging page `stage`."""
-        for buffer in (wk_buf, wv_buf):
+        """Wait for the copies that left staging page `stage`."""
+        for _, _, buffer, _ in writes:
             src = buffer.at[stage]
             pltpu.make_async_copy(src, src, wsems.at[stage]).wait()
 
@@ -421,10 +445,7 @@ def _decode_kernel(
         ) == off
         stage = jax.lax.rem(written, nwrite)
         pl.when(written >= nwrite)(functools.partial(wait_write, stage))
-        for new_ref, buffer, staged, pool in (
-            (k_new_ref, k_buf, wk_buf, k_out_ref),
-            (v_new_ref, v_buf, wv_buf, v_out_ref),
-        ):
+        for new_ref, buffer, staged, pool in writes:
             new = new_ref[j]  # [KV, hd]
             for kv in range(KV):
                 page = jnp.where(
@@ -517,7 +538,9 @@ def _decode_kernel(
                 # scale, dot int8 V.  l uses the UNWEIGHTED p (it
                 # normalizes probabilities, not values).
                 p = p * sv_buf[buf, pl.ds(kv, 1), :].astype(jnp.float32)
-            v = v_buf[buf, kv]  # [T, hd]
+            # [T, hd]; the latent row's first lanes are its own value
+            v = (k_buf[buf, kv, :, pl.ds(0, latent)] if latent
+                 else v_buf[buf, kv])
             pv_dims = (((1,), (0,)), ((), ()))
             if mxu == jnp.bfloat16:
                 # the float32 weights as two bf16 terms (<= 2^-16
@@ -712,6 +735,104 @@ def paged_decode_attention_pallas(
     if write:
         return out[0].reshape(B, H, hd), out[1], out[2]
     return out.reshape(B, H, hd)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("interpret", "scale", "v_width"),
+)
+def mla_decode_attention_pallas(
+    q: jnp.ndarray,  # [B, H, W] absorbed queries, W the pool's row width
+    pages: jnp.ndarray,  # [L, 1, P, ps, W]: the latent pool
+    page_tables: jnp.ndarray,  # [B, pages_per_seq]
+    seq_lens: jnp.ndarray,  # [B]; 0 => the row holds nothing: zeros out
+    layer,  # int32 scalar: pool layer index
+    new=None,  # [B, W]: each slot's latent row at seq_lens - 1
+    *,
+    v_width: int,  # leading lanes of a row that are its value
+    scale: float,
+    interpret: bool = False,
+):
+    """Decode attention in the ABSORBED form of multi-head latent
+    attention over the latent pool, [B, H, v_width]: every query head
+    meets the ONE cached row of a token as its key (all W lanes) and
+    takes the row's first ``v_width`` lanes as its value.  It is
+    ``_decode_kernel`` with one pool (``latent``): a program per block
+    of slots, live pages only, all heads an iteration.  The jnp twin is
+    ``ops.attention.mla_decode_attention``.
+
+    With ``new`` the kernel also writes each live slot's new row into
+    the pool and attends to it, and returns ``(attention, pages)`` with
+    the pool updated in place (donate it): ``_decode_kernel``."""
+    B, H, W = q.shape
+    _, KV, P, ps, _ = pages.shape
+    write = new is not None
+    CP, BS = _decode_sizes(
+        B, KV, H, W, ps, page_tables.shape[1], pages.dtype, q.dtype, pools=1
+    )
+    chunk_tokens = CP * ps
+    kernel = functools.partial(
+        _decode_kernel, page_size=ps, chunk_pages=CP, batch=B, softcap=0.0,
+        scale=float(scale), has_layer=True, quant=False, write=write,
+        latent=v_width,
+    )
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    q_block = pl.BlockSpec(
+        (BS, KV, H, W), lambda bb, *prefetch: (bb, 0, 0, 0),
+        memory_space=pltpu.VMEM,
+    )
+    out_block = pl.BlockSpec(
+        (BS, KV, H, v_width), lambda bb, *prefetch: (bb, 0, 0, 0),
+        memory_space=pltpu.VMEM,
+    )
+    scratch = [pltpu.VMEM((DECODE_BUFFERS, KV, chunk_tokens, W), pages.dtype)]
+    if write:
+        scratch.append(
+            pltpu.VMEM((DECODE_WRITE_BUFFERS, KV, ps, W), pages.dtype))
+    scratch += [
+        pltpu.VMEM((KV, H, v_width), jnp.float32),
+        pltpu.VMEM((KV, H, 128), jnp.float32),
+        pltpu.VMEM((KV, H, 128), jnp.float32),
+        pltpu.SMEM((6, BS + 1), jnp.int32),
+        pltpu.SemaphoreType.DMA((DECODE_BUFFERS,)),
+    ]
+    inputs = [q.reshape(B, KV, H, W), pages]
+    in_specs = [q_block, any_spec]
+    out_specs = out_block
+    out_shape = jax.ShapeDtypeStruct((B, KV, H, v_width), q.dtype)
+    aliases = {}
+    if write:
+        scratch.append(pltpu.SemaphoreType.DMA((DECODE_WRITE_BUFFERS,)))
+        inputs.append(new.astype(pages.dtype).reshape(B, KV, W))
+        in_specs.append(pl.BlockSpec(
+            (BS, KV, W), lambda bb, *prefetch: (bb, 0, 0),
+            memory_space=pltpu.VMEM,
+        ))
+        out_specs = [out_block, any_spec]
+        out_shape = [out_shape,
+                     jax.ShapeDtypeStruct(pages.shape, pages.dtype)]
+        aliases = {5: 1}  # the pool, counted from the scalar prefetch
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(cdiv(B, BS),),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch,
+        ),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024,
+            disable_bounds_checks=True,
+        ),
+        name="mla_decode_attention_pallas",
+    )(page_tables, seq_lens, jnp.zeros((1,), jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), *inputs)
+    if write:
+        return out[0].reshape(B, H, v_width), out[1]
+    return out.reshape(B, H, v_width)
 
 
 def _mt_kernel(

@@ -25,6 +25,8 @@ def rope_frequencies(
     inv_freq = 1.0 / (theta ** exponent)
     if scaling is None:
         return inv_freq
+    if scaling[0] == "yarn":
+        return _yarn_frequencies(inv_freq, head_dim, theta, *scaling[1:])
     factor, low_f, high_f, orig_max = scaling
     low_wavelen = orig_max / low_f
     high_wavelen = orig_max / high_f
@@ -36,6 +38,34 @@ def rope_frequencies(
         inv_freq / factor,
         jnp.where(wavelen < high_wavelen, inv_freq, mid),
     )
+
+
+def _yarn_frequencies(inv_freq, dim, theta, factor, beta_fast, beta_slow,
+                      orig_max):
+    """YaRN (the published recipe, DeepSeek-V3's form): frequency i
+    makes ``orig_max * f_i / 2 pi`` rotations over the original context;
+    those that make more than ``beta_fast`` keep their frequency, those
+    that make fewer than ``beta_slow`` are interpolated (``f / factor``),
+    and between the two dimensions where that happens (the first rounded
+    down, the second up) a linear ramp blends them."""
+    def correction_dim(rotations):
+        return (dim * math.log(orig_max / (rotations * 2.0 * math.pi))
+                / (2.0 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip(
+        (jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    return inv_freq / factor * ramp + inv_freq * (1.0 - ramp)
+
+
+def position_scale(positions, beta: float, orig_max: int):
+    """gamma(pos) = 1 + beta * ln(1 + pos // orig_max), float32: the
+    scaling of a query by its position (1 inside the original context)."""
+    return 1.0 + beta * jnp.log1p(
+        (positions // orig_max).astype(jnp.float32))
 
 
 def apply_rope(

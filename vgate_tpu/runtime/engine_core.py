@@ -389,6 +389,30 @@ def refuse_unbuildable_kernels(spec: ModelSpec, kv_quant: bool) -> None:
         )
 
 
+def _pages_only_feature(config: VGTConfig, mesh):
+    """The first configured feature that knows K and V pages only (a
+    partitioned mesh, speculative decoding, the host swap tier,
+    disaggregated roles, int8 pages, quantized weights) as ``(what it
+    is called, its key)``; None when none is on."""
+    bad_axes = {
+        a: int(mesh.shape.get(a, 1)) for a in ("tp", "pp", "sp", "ep")
+        if int(mesh.shape.get(a, 1)) > 1
+    }
+    if bad_axes:
+        return f"a {bad_axes} mesh", "mesh"
+    if config.tpu.speculative_k > 0:
+        return "speculative decoding (tpu.speculative_k)", "speculative"
+    if int(config.kv_cache.host_swap_bytes) > 0:
+        return "the host swap tier (kv_cache.host_swap_bytes)", "swap"
+    if any(r in ("prefill", "decode") for r in config.pod.roles):
+        return "disaggregated prefill/decode roles (pod.roles)", "roles"
+    if config.kv_cache.dtype == "int8":
+        return "kv_cache.dtype=int8", "int8"
+    if config.model.quantization not in (None, "", "none"):
+        return f"model.quantization={config.model.quantization}", "quant"
+    return None
+
+
 def refuse_unsupported_recurrent(spec: ModelSpec, config: VGTConfig,
                                  mesh) -> None:
     """Engine-construction gate for a spec with recurrent layers
@@ -397,39 +421,57 @@ def refuse_unsupported_recurrent(spec: ModelSpec, config: VGTConfig,
     state that belongs to it is a WRONG cache.  Each is refused here by
     name, at boot, not at the first request that would need it.  (Prefix
     matching is not refused but turned off: it is on by default.)"""
-    if not spec.linear_layers:
+    found = _pages_only_feature(config, mesh) if spec.linear_layers else None
+    if not found:
         return
-    what = None
-    bad_axes = {
-        a: int(mesh.shape.get(a, 1)) for a in ("tp", "pp", "sp", "ep")
-        if int(mesh.shape.get(a, 1)) > 1
-    }
-    if bad_axes:
-        what = (f"a {bad_axes} mesh: the recurrent state and its kernel "
-                "are not partitioned (dp composes: a replica owns its "
-                "state)")
-    elif config.tpu.speculative_k > 0:
-        what = ("speculative decoding (tpu.speculative_k): rejected "
-                "drafts would have to be rolled back out of the state")
-    elif int(config.kv_cache.host_swap_bytes) > 0:
-        what = ("the host swap tier (kv_cache.host_swap_bytes): it parks "
-                "pages and would leave the state behind")
-    elif any(r in ("prefill", "decode") for r in config.pod.roles):
-        what = ("disaggregated prefill/decode roles (pod.roles): the "
-                "handoff of a live sequence moves pages and would leave "
-                "the state behind")
-    elif config.kv_cache.dtype == "int8":
-        what = "kv_cache.dtype=int8: the gated attention path writes bf16"
-    elif config.model.quantization not in (None, "", "none"):
-        what = (f"model.quantization={config.model.quantization}: the "
-                "grouped expert product and the recurrent layers take "
-                "plain weights")
-    if what:
-        raise ValueError(
-            f"{spec.name} has recurrent (linear-attention or state-space) "
-            f"layers, which cannot run with {what}.  Preemption by recompute and "
-            "journal replay rebuild the state and are supported."
-        )
+    why = {
+        "mesh": "the recurrent state and its kernel are not partitioned "
+                "(dp composes: a replica owns its state)",
+        "speculative": "rejected drafts would have to be rolled back out "
+                       "of the state",
+        "swap": "it parks pages and would leave the state behind",
+        "roles": "the handoff of a live sequence moves pages and would "
+                 "leave the state behind",
+        "int8": "the gated attention path writes bf16",
+        "quant": "the grouped expert product and the recurrent layers "
+                 "take plain weights",
+    }[found[1]]
+    raise ValueError(
+        f"{spec.name} has recurrent (linear-attention or state-space) "
+        f"layers, which cannot run with {found[0]}: {why}.  Preemption by "
+        "recompute and journal replay rebuild the state and are supported."
+    )
+
+
+def refuse_unsupported_latent(spec: ModelSpec, config: VGTConfig,
+                              mesh) -> None:
+    """Engine-construction gate for a spec with latent attention
+    (``ModelSpec.is_mla``): its cache is ONE pool of latent rows, and
+    what is listed here still assumes K and V pools, or has not been
+    tried over a latent one.  Each is refused by name, at boot.  (Prefix
+    sharing over latent pages works: whole pages only, the radix
+    cache's copy-on-write of a partial page is turned off.)"""
+    found = _pages_only_feature(config, mesh) if spec.is_mla else None
+    if not found:
+        return
+    why = {
+        "mesh": "the latent pool has one row a token for all heads and "
+                "its kernels are not partitioned (dp composes: a replica "
+                "owns its pool)",
+        "speculative": "the verify program attends K and V pools",
+        "swap": "its gather and scatter programs move K and V pools",
+        "roles": "the handoff of a live sequence ships K and V pages",
+        "int8": "latent rows are written and read in the model's float "
+                "type only",
+        "quant": "the latent projections and the grouped expert product "
+                 "take plain weights",
+    }[found[1]]
+    raise ValueError(
+        f"{spec.name} has latent attention (one pool of latent rows, no K "
+        f"or V pool), which cannot run with {found[0]}: {why}.  Prefix "
+        "sharing of whole pages, chunked prefill, preemption by recompute "
+        "and journal replay are supported."
+    )
 
 
 class _EvacRequest:
@@ -500,6 +542,7 @@ class EngineCore:
         self.mesh = build_mesh(tpu_cfg, devices)
         self.spec.check_expert_share()
         refuse_unsupported_recurrent(self.spec, self.config, self.mesh)
+        refuse_unsupported_latent(self.spec, self.config, self.mesh)
         # Pallas kernels require a real TPU backend (tests run interpret-
         # mode kernels separately; the engine's jnp twins serve CPU meshes)
         platform = self.mesh.devices.flat[0].platform
@@ -669,7 +712,7 @@ class EngineCore:
         self._state_slot_bytes = (
             hybrid_state_bytes_per_slot(
                 self.spec, jnp.dtype(self.dtype).itemsize)
-            if self.spec.is_hybrid else 0
+            if self.spec.linear_layers else 0
         )
         state_bytes = self._state_slot_bytes * tpu_cfg.max_batch_slots
         if tpu_cfg.kv_num_pages:
@@ -713,13 +756,14 @@ class EngineCore:
             num_layers=self.spec.attn_layers,
             num_pages=num_pages,
             page_size=tpu_cfg.kv_page_size,
-            kv_heads=self.spec.num_kv_heads,
-            head_dim=self.spec.head_dim,
+            kv_heads=self.spec.cache_heads,
+            head_dim=self.spec.cache_head_dim,
             max_model_len=self.config.model.max_model_len,
             dtype_bytes=kv_dtype_bytes,
             num_reserved=sp_shards,
             scale_bytes=kv_scale_bytes,
             kv_dtype=kv_dtype_name,
+            pools=self.spec.kv_pools,
         )
         kv_sharding = named(
             self.mesh, kv_pspec(self.spec, self.mesh, num_pages)
@@ -728,11 +772,12 @@ class EngineCore:
             self.geometry, kv_pool_dtype, kv_sharding
         )
         # None for every spec without recurrent layers: an empty pytree
-        # in the step programs, which then are what they were
+        # in the step programs, which then are what they were (as
+        # v_pages is for a latent pool)
         self.state = (
             make_hybrid_state(
                 self.spec, tpu_cfg.max_batch_slots, self._state_dtype)
-            if self.spec.is_hybrid else None
+            if self.spec.linear_layers else None
         )
         self.allocator = PageAllocator(num_pages, num_shards=sp_shards)
         self.allocator.quantized = self._kv_quant
@@ -750,9 +795,9 @@ class EngineCore:
         mesh_pp = int(self.mesh.shape.get("pp", 1))
         pc = tpu_cfg.prefix_cache
         # a prefix hit without the recurrent state that belongs to it
-        # would be wrong: matching is off for a hybrid spec
+        # would be wrong: matching is off for a spec with such layers
         self.prefix_cache_enabled = bool(
-            pc.enabled and mesh_pp == 1 and not self.spec.is_hybrid
+            pc.enabled and mesh_pp == 1 and not self.spec.linear_layers
         )
         # radix-tree prefix index (runtime/radix_cache.py): page-granular
         # cross-request sharing with COW partial pages and
@@ -767,7 +812,9 @@ class EngineCore:
                 self.allocator,
                 tpu_cfg.kv_page_size,
                 min_share_pages=pc.min_share_pages,
-                cow=bool(pc.cow and mesh_sp == 1),
+                # latent pages are shared whole: the suffix program of
+                # such a spec writes whole pages (models/hybrid.py)
+                cow=bool(pc.cow and mesh_sp == 1 and not self.spec.is_mla),
                 cow_min_tokens=pc.cow_min_tokens,
             )
             self.allocator.set_reclaimer(self.radix_cache)
@@ -2543,6 +2590,11 @@ class EngineCore:
                         continue
                     token = arr[row]
                     self.total_prefills += 1
+                    if self.spec.is_mla:
+                        self.perf.note_mla_prefill(
+                            plan.seq.total_len, plan.cached_len,
+                            self.spec.attn_layers,
+                        )
                     if lp is not None and plan.seq.params.logprobs:
                         self._attach_logprob(plan.seq, lp, 0, row)
                     # a RE-prefill (post-preemption) keeps the original
@@ -2871,7 +2923,8 @@ class EngineCore:
             attention = (
                 "suffix_cow" if unaligned else "suffix",
                 lambda: multitok_attention_impl(
-                    self.use_pallas, mesh, rows=bucket, unaligned=unaligned
+                    self.use_pallas, mesh, rows=bucket, unaligned=unaligned,
+                    latent=self.spec.is_mla,
                 ),
             )
         else:
@@ -3317,6 +3370,12 @@ class EngineCore:
                         np.asarray(moe_dev), rows=len(seqs),
                         moe_layers=self.spec.moe_layers,
                         linear_layers=self.spec.linear_layers,
+                    )
+                if self.spec.is_mla:
+                    self.perf.note_mla_decode(
+                        steps=chunk, rows=len(seqs),
+                        ctx_tokens=sum(s.total_len for s, _ in seqs),
+                        layers=self.spec.attn_layers,
                     )
             device_s = wait.seconds
             block_s = device_s + read.seconds
@@ -4042,6 +4101,16 @@ class EngineCore:
             # these so every recorded number names its KV config
             "kv_dtype": self.geometry.kv_dtype,
             "kv_page_bytes": self.geometry.page_bytes,
+            # what a page holds: K and V of every KV head, or latent
+            # attention's one row a token ("latent": values used of the
+            # row's lanes)
+            "kv_layout": {
+                "pools": self.geometry.pools,
+                "heads": self.geometry.kv_heads,
+                "row_lanes": self.geometry.head_dim,
+                **({"latent": self.spec.latent_dim}
+                   if self.spec.is_mla else {}),
+            },
             **(
                 {
                     "state_cache": {
@@ -4055,7 +4124,7 @@ class EngineCore:
                         "convolution tail",
                     }
                 }
-                if self.spec.is_hybrid else {}
+                if self.spec.linear_layers else {}
             ),
             "weights_bytes": self._params_bytes,
             "model": self.spec.name,

@@ -29,15 +29,17 @@ logger = get_logger(__name__)
 
 def _page_bytes(
     num_layers: int, page_size: int, kv_heads: int, head_dim: int,
-    dtype_bytes: int, scale_bytes: int = 0,
+    dtype_bytes: int, scale_bytes: int = 0, pools: int = 2,
 ) -> int:
     """Bytes one page occupies across all layers, K and V together — the
     single source of truth for page sizing (used by both KVGeometry and
     auto_num_pages).  ``scale_bytes`` is the per-token-per-head
     quantization-scale overhead (0 for plain bf16/f32 pools; int8 KV
-    stores one bf16 scale per (page, head, slot) — ops/kv_quant.py)."""
+    stores one bf16 scale per (page, head, slot) — ops/kv_quant.py).
+    ``pools``: 2 for K and V; 1 for latent attention's ONE pool, whose
+    "head" is the token's latent row (``ModelSpec.cache_head_dim``)."""
     return (
-        2 * num_layers * page_size * kv_heads
+        pools * num_layers * page_size * kv_heads
         * (head_dim * dtype_bytes + scale_bytes)
     )
 
@@ -59,6 +61,9 @@ class KVGeometry:
     scale_bytes: int = 0
     # reporting name for /stats, drills and bench artifacts
     kv_dtype: str = "bf16"
+    # arrays of the cache: K and V, or latent attention's one pool
+    # (kv_heads 1, head_dim the latent row's lanes: ModelSpec.cache_*)
+    pools: int = 2
 
     @property
     def pages_per_seq(self) -> int:
@@ -68,7 +73,7 @@ class KVGeometry:
     def page_bytes(self) -> int:
         return _page_bytes(
             self.num_layers, self.page_size, self.kv_heads, self.head_dim,
-            self.dtype_bytes, self.scale_bytes,
+            self.dtype_bytes, self.scale_bytes, self.pools,
         )
 
     @property
@@ -111,8 +116,8 @@ def auto_num_pages(
     device = device or jax.devices()[0]
     stats = getattr(device, "memory_stats", lambda: None)()
     page_bytes = _page_bytes(
-        spec.attn_layers, page_size, spec.num_kv_heads, spec.head_dim,
-        dtype_bytes, scale_bytes,
+        spec.attn_layers, page_size, spec.cache_heads, spec.cache_head_dim,
+        dtype_bytes, scale_bytes, spec.kv_pools,
     ) // max(1, shards)
     if stats and "bytes_limit" in stats:
         limit = stats["bytes_limit"] * hbm_utilization
@@ -325,7 +330,8 @@ class PageAllocator:
 
 
 def make_kv_buffers(geometry: KVGeometry, dtype=jnp.bfloat16, sharding=None):
-    """Allocate the K/V page pools (zeros) directly on device, each chip
+    """Allocate the page pools (zeros; ``(k, v)``, or ``(latent, None)``
+    for a geometry of ONE pool) directly on device, each chip
     of a mesh creating only its own shard (``device=sharding``): a global
     pool drawn on the default device and spread afterwards does not fit
     the one chip it is drawn on.
@@ -371,8 +377,9 @@ def make_kv_buffers(geometry: KVGeometry, dtype=jnp.bfloat16, sharding=None):
         k, v = pool(), pool()
     else:
         k = jnp.zeros(shape, dtype, device=sharding)
-        v = jnp.zeros(shape, dtype, device=sharding)
-    pool_bytes = 2 * sum(
+        v = (jnp.zeros(shape, dtype, device=sharding)
+             if geometry.pools == 2 else None)
+    pool_bytes = geometry.pools * sum(
         x.size * x.dtype.itemsize for x in jax.tree.leaves(k)
     )
     logger.info(

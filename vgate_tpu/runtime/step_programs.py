@@ -221,7 +221,8 @@ def _decode_chunk(
     program is byte-identical to the pre-integrity one.
 
     ``state`` (a spec with recurrent layers) rides the scan's carry
-    beside the pools, rows of active slots updated in place; the result
+    beside the pools, rows of active slots updated in place; for a spec
+    the stack walker of models/hybrid.py runs the result
     then ends ``..., chunk_flags, state, moe_stats`` with ``moe_stats``
     ``[num_steps, 4]`` int32, the expert layers' device counters summed
     over the layers of each step (ops/moe.py STAT_NAMES), read back
@@ -300,7 +301,7 @@ def _decode_chunk(
     (tokens, positions, counter, steps, counts, k_pages, v_pages,
      state) = carry
     tail = ()
-    if state is not None:
+    if spec.is_hybrid:  # its state (None without recurrent layers)
         tail, ys = (state, ys[-1]), ys[:-1]
     # [num_steps, B] uint8 sentinel words when guarded (host ORs the
     # step axis at readback), None otherwise

@@ -235,10 +235,85 @@ def _pattern_params_from_getter(
     return params
 
 
+def _deinterleave(w: np.ndarray, dim: int) -> np.ndarray:
+    """[..., n] whose last ``dim`` columns pair rotary dimensions (2i,
+    2i + 1) -> the same columns in halves (evens, then odds): what
+    rotate-half expects.  A dot product of two vectors re-ordered alike
+    is unchanged, so only the rotation has to know."""
+    head, tail = w[..., :-dim], w[..., -dim:]
+    return np.concatenate([head, tail[..., 0::2], tail[..., 1::2]], axis=-1)
+
+
+def _mla_params_from_getter(
+    spec: ModelSpec, getter: TensorGetter, dtype
+) -> Params:
+    """``mistral4`` names (ASSUMED to be DeepSeek-V3's, whose config keys
+    the published config's are one for one; no checkpoint was read) ->
+    the pytree of models/hybrid.py for a latent-attention spec (``layers
+    = {"layer": [L, 1, ...]}``).  ``kv_b_proj`` ``[heads x (nope + v),
+    kv_lora_rank]`` is split per head into ``kv_b_k`` (W_uk) and
+    ``kv_b_v`` (W_uv), ``[kv_lora_rank, heads, .]``.  With
+    ``rope_interleave`` the rotary columns of every head of ``q_b_proj``
+    and of ``kv_a_proj_with_mqa`` are de-interleaved, so that the
+    program rotates halves.  A chip's share: the experts ``first_expert
+    ..`` of the router's width, and the first ``vocab_size`` rows of
+    embedding and head."""
+    E, first, H = spec.num_experts, spec.first_expert, spec.num_heads
+    kl, nope, rope = (spec.kv_lora_rank, spec.qk_nope_head_dim,
+                      spec.qk_rope_head_dim)
+    get = lambda i, name: np.asarray(getter(f"model.layers.{i}.{name}"))
+    lin = lambda i, name: get(i, f"{name}.weight").T
+    unpair = _deinterleave if spec.rope_interleave else (lambda w, _: w)
+
+    def layer(i):
+        q_b = lin(i, "self_attn.q_b_proj")  # [q_lora, H x (nope + rope)]
+        q_b = unpair(q_b.reshape(-1, H, nope + rope), rope)
+        kv_b = lin(i, "self_attn.kv_b_proj").reshape(kl, H, -1)
+        experts = lambda w: {"w": np.stack([
+            lin(i, f"mlp.experts.{first + e}.{w}_proj")
+            for e in range(E)])}
+        out = {
+            "input_norm": get(i, "input_layernorm.weight"),
+            "post_norm": get(i, "post_attention_layernorm.weight"),
+            "q_a": {"w": lin(i, "self_attn.q_a_proj")},
+            "q_a_norm": get(i, "self_attn.q_a_layernorm.weight"),
+            "q_b": {"w": q_b.reshape(q_b.shape[0], -1)},
+            "kv_a": {"w": unpair(lin(i, "self_attn.kv_a_proj_with_mqa"),
+                                 rope)},
+            "kv_a_norm": get(i, "self_attn.kv_a_layernorm.weight"),
+            "kv_b_k": {"w": kv_b[..., :nope]},
+            "kv_b_v": {"w": kv_b[..., nope:]},
+            "o": {"w": lin(i, "self_attn.o_proj")},
+            "router": lin(i, "mlp.gate"),
+            "gate": experts("gate"), "up": experts("up"),
+            "down": experts("down"),
+        }
+        if spec.shared_expert_intermediate_size:
+            for n in ("gate", "up", "down"):
+                out[f"shared_{n}"] = {
+                    "w": lin(i, f"mlp.shared_experts.{n}_proj")}
+        return out
+
+    np_dtype, V = np.dtype(dtype), spec.vocab_size
+    cast = lambda x: np.asarray(x).astype(np_dtype)
+    trees = [layer(i) for i in range(spec.num_layers)]
+    params: Params = {
+        "embed": cast(np.asarray(getter("model.embed_tokens.weight"))[:V]),
+        "layers": {"layer": jax.tree.map(
+            lambda *xs: cast(np.stack(xs)[:, None]), *trees)},
+        "final_norm": cast(getter("model.norm.weight")),
+    }
+    if not spec.tie_embeddings:
+        params["lm_head"] = cast(np.asarray(getter("lm_head.weight"))[:V].T)
+    return params
+
+
 def params_from_getter(
     spec: ModelSpec, getter: TensorGetter, dtype=jnp.bfloat16
 ) -> Params:
     """Assemble the decoder pytree from HF-named tensors (host numpy)."""
+    if spec.is_mla:
+        return _mla_params_from_getter(spec, getter, dtype)
     if spec.layer_pattern:
         return _pattern_params_from_getter(spec, getter, dtype)
     if spec.is_hybrid:
