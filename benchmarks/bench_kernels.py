@@ -6,6 +6,7 @@ ops/pallas/flash_prefill.py (blockwise online softmax vs the jnp
 blockwise twin).  Run on hardware:
 
     python benchmarks/bench_kernels.py
+    python benchmarks/bench_kernels.py sample_edits   # that probe alone
 
 Prints one JSON line per (kernel, shape) with median step times and the
 speedup.  CPU-safe fallback: refuses to run (the kernels need a TPU).
@@ -267,6 +268,134 @@ def bench_decode_cells():
         yield line
 
 
+# (vocabulary, rows): the `sample` scope's shapes in the benchmark's
+# configurations (Qwen2.5's vocabulary; qwen3-next's quarter of it; the
+# quarter the nemotron and mistral cells hold, at nemotron's 192 slots)
+SAMPLE_SHAPES = ((151936, 256), (37984, 256), (32768, 192))
+SAMPLE_WIDTHS = (1, 16, 32, 64, 128, 256)
+
+
+def _edits_by_parent_scatter(logits, bias_ids, bias_vals, steps, min_toks,
+                             stop_ids):
+    """What a step did until PR 34: a scatter-add for `logit_bias`, then
+    a scatter into a copy and a select between copy and original for the
+    `min_tokens` floor."""
+    rows = jnp.arange(logits.shape[0])[:, None]
+    logits = logits.astype(jnp.float32).at[
+        jnp.broadcast_to(rows, bias_ids.shape), bias_ids
+    ].add(bias_vals, mode="drop")
+    masked = logits.at[
+        jnp.broadcast_to(rows, stop_ids.shape), stop_ids
+    ].set(-1e30, mode="drop")
+    return jnp.where((steps < min_toks)[:, None], masked, logits)
+
+
+def _edits_by(bias, floor):
+    """The program's own two edits (ops/sampling.py), one form forced."""
+
+    def edits(logits, bias_ids, bias_vals, steps, min_toks, stop_ids):
+        logits = bias(logits.astype(jnp.float32), bias_ids, bias_vals)
+        return floor(logits, steps, min_toks, stop_ids)
+
+    return edits
+
+
+def sample_edit_forms():
+    from vgate_tpu.ops import sampling
+
+    return {
+        "parent_scatter": _edits_by_parent_scatter,
+        "scatter": _edits_by(
+            sampling._bias_by_scatter, sampling._floor_by_scatter
+        ),
+        "compare": _edits_by(
+            sampling._bias_by_compare, sampling._floor_by_compare
+        ),
+    }
+
+
+def sample_edit_case(V, B, width, stop_width=2, seed=0):
+    """A decode step's sampling inputs at a cell's shape: float32 logits,
+    `width` distinct bias ids a row at +-100 (the benchmark's requests
+    carry 16), every row below its `min_tokens` floor with `stop_width`
+    stop ids (the cells': eos and one extra)."""
+    rng = np.random.default_rng(seed)
+    logits = jax.random.normal(jax.random.PRNGKey(seed), (B, V), jnp.float32)
+    ids = np.stack([rng.permutation(V)[:width] for _ in range(B)])
+    vals = rng.choice([100.0, -100.0], size=(B, width))
+    stops = np.stack([rng.permutation(V)[:stop_width] for _ in range(B)])
+    return (
+        logits, jnp.asarray(ids, jnp.int32), jnp.asarray(vals, jnp.float32),
+        jnp.zeros((B,), jnp.int32), jnp.full((B,), 1024, jnp.int32),
+        jnp.asarray(stops, jnp.int32),
+    )
+
+
+def time_sample_scope(edits, sampler, case, loop=LOOP):
+    """Median seconds of one `sample` scope (the edits, or none, then
+    the sampler) over `loop` of them in one program; the logits depend
+    on the previous step's tokens, so nothing is hoisted."""
+    from vgate_tpu.ops.sampling import sample_tokens
+
+    logits, bias_ids, bias_vals, steps, min_toks, stop_ids = case
+    B = logits.shape[0]
+    temps = jnp.full((B,), 0.0 if sampler == "argmax" else 0.8)
+    top_ps = jnp.full((B,), 0.95)
+    top_ks = jnp.zeros((B,), jnp.int32)
+    key = jax.random.PRNGKey(3)
+
+    @jax.jit
+    def run(logits):
+        def body(carry, i):
+            x = logits + carry
+            if edits is not None:
+                x = edits(x, bias_ids, bias_vals, steps + i, min_toks,
+                          stop_ids)
+            tokens = sample_tokens(
+                x, temps, top_ps, top_ks, jax.random.fold_in(key, i),
+                steps=steps + i, all_greedy=sampler == "argmax",
+            )
+            return tokens[0].astype(jnp.float32) * 0.0, tokens
+
+        return jax.lax.scan(
+            body, jnp.float32(0), jnp.arange(loop, dtype=jnp.int32)
+        )[1]
+
+    return _median_time(run, logits, loop=loop)
+
+
+def bench_sample_edits(shapes=SAMPLE_SHAPES, widths=SAMPLE_WIDTHS,
+                       forms=None):
+    """The decode step's `sample` scope alone, at the cells' shapes: the
+    sampler with no edit, then `logit_bias` and the `min_tokens` floor
+    ahead of it in each form, over the bias widths the API allows.  Every
+    form has to give the parent's bits."""
+    forms = forms or sample_edit_forms()
+    for V, B in shapes:
+        # top-k sampling at the widest vocabulary only (no cell samples)
+        for sampler in ("argmax", "topk") if V == shapes[0][0] else ("argmax",):
+            alone = time_sample_scope(None, sampler, sample_edit_case(V, B, 1))
+            for width in widths:
+                case = sample_edit_case(V, B, width)
+                want = jax.jit(forms["parent_scatter"])(*case)
+                line = {
+                    "scope": "sample", "vocab": V, "rows": B,
+                    "sampler": sampler, "bias_width": width,
+                    "stop_width": int(case[5].shape[1]),
+                    "sampler_alone_us": round(alone * 1e6, 1),
+                }
+                for label, edits in forms.items():
+                    if label != "parent_scatter" and not bool(
+                        jnp.array_equal(jax.jit(edits)(*case), want)
+                    ):
+                        raise SystemExit(
+                            f"{label} at width {width}: not the parent's bits"
+                        )
+                    seconds = time_sample_scope(edits, sampler, case)
+                    line[f"{label}_us"] = round(seconds * 1e6, 1)
+                yield line
+
+
 def bench_flash_prefill(B=8, S=1024, H=12, KV=2, hd=128):
     from vgate_tpu.ops.attention import flash_prefill_attention
     from vgate_tpu.ops.pallas.flash_prefill import (
@@ -393,9 +522,15 @@ def main() -> None:
             "bench_kernels needs a real TPU (Pallas kernels don't run on "
             f"{device.platform}); CPU CI covers parity in interpret mode"
         )
+    if sys.argv[1:] == ["sample_edits"]:
+        for line in bench_sample_edits():
+            print(json.dumps(line), flush=True)
+        return
     print(json.dumps(bench_paged_decode()))
     print(json.dumps(bench_paged_decode(ctx=2048)))
     for line in bench_decode_cells():
+        print(json.dumps(line))
+    for line in bench_sample_edits():
         print(json.dumps(line))
     print(json.dumps(bench_flash_prefill()))
     print(json.dumps(bench_flash_prefill(S=2048)))

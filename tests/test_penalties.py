@@ -258,6 +258,139 @@ def test_apply_logit_bias_op():
     assert np.all(out[1] == 0.0)  # out-of-vocab ids dropped
 
 
+# The two logit edits against a plain oracle (a loop over rows and ids),
+# bit for bit.  A case: (bias width or None, stop width or None, input
+# dtype, which rows stand below their floor).  Widths past
+# sampling.COMPARE_MAX_IDS take the scatter form, the others the
+# compares: one oracle holds both.
+_EDIT_V, _EDIT_B = 203, 6
+_EDIT_CASES = [
+    *[(w, None, dt, None) for w in (1, 16, 32, 128)
+      for dt in ("float32", "bfloat16")],
+    *[(None, w, dt, "mixed") for w in (1, 2, 4, 128)
+      for dt in ("float32", "bfloat16")],
+    (None, 2, "float32", "none"),
+    (None, 128, "float32", "none"),
+    (None, 2, "float32", "all"),
+    *[(bw, sw, dt, "mixed") for bw, sw in ((1, 1), (16, 2), (32, 4), (128, 2))
+      for dt in ("float32", "bfloat16")],
+]
+
+
+def _edit_inputs(bias_width, stop_width, dtype, below):
+    rng = np.random.default_rng([bias_width or 0, stop_width or 0, len(str(below))])
+    V, B = _EDIT_V, _EDIT_B
+    logits = rng.normal(size=(B, V)).astype(np.float32) * 5
+    logits[0, :4] = [0.0, -0.0, 1e30, -1e30]
+    logits = jnp.asarray(logits, dtype)
+    bias = floor = None
+    if bias_width is not None:
+        # distinct ids a row, a third of them padding (>= V, one far past)
+        ids = np.stack([
+            rng.permutation(V + V // 2)[:bias_width] for _ in range(B)
+        ]).astype(np.int32)
+        ids[1, 0] = 2**31 - 1
+        ids[2] = V  # a row with no bias among biased rows
+        vals = rng.choice(
+            [100.0, -100.0, 0.5, -0.25, 1e-3, 0.0], size=ids.shape
+        ).astype(np.float32)
+        # the first and the last vocabulary position, +100 and -100
+        ids[0, 0], vals[0, 0] = 0, 100.0
+        ids[3, 0], vals[3, 0] = V - 1, -100.0
+        ids[3, 1:][ids[3, 1:] == V - 1] = V
+        ids[0, 1:][ids[0, 1:] == 0] = V
+        bias = (ids, vals)
+    if stop_width is not None:
+        stops = rng.integers(0, V + V // 2, size=(B, stop_width))
+        stops[0, 0], stops[1, -1] = V - 1, 0
+        floors = np.asarray([4, 4, 4, 0, 7, 1], np.int32)
+        steps = {
+            # below, at, above, no floor, below, at
+            "mixed": [3, 4, 9, 0, 0, 1],
+            "none": [4, 5, 6, 0, 7, 2],
+            "all": [0, 0, 0, -1, 0, 0],
+        }[below]
+        floor = (np.asarray(steps, np.int32), floors,
+                 stops.astype(np.int32))
+    return logits, bias, floor
+
+
+def _edit_oracle(logits, bias, floor):
+    V = logits.shape[1]
+    out = np.array(logits)
+    if bias is not None:
+        out = out.astype(np.float32)
+        for b, (ids, vals) in enumerate(zip(*bias)):
+            for tid, val in zip(ids, vals):
+                if 0 <= tid < V:
+                    out[b, tid] = np.float32(out[b, tid]) + np.float32(val)
+    if floor is not None:
+        for b, (step, min_tokens, stops) in enumerate(zip(*floor)):
+            if step < min_tokens:
+                for tid in stops:
+                    if 0 <= tid < V:
+                        out[b, tid] = -1e30
+    return out
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+
+
+@pytest.mark.fast  # a second a case: runs in tier-1, unlike this file
+@pytest.mark.parametrize(
+    "bias_width,stop_width,dtype,below", _EDIT_CASES,
+    ids=lambda v: "-" if v is None else str(v),
+)
+def test_logit_edits_match_a_plain_oracle(bias_width, stop_width, dtype,
+                                          below):
+    """`apply_logit_bias`, `suppress_stop_tokens` and the two composed
+    in the program's order (bias, then floor), jitted as the step
+    programs trace them."""
+    from vgate_tpu.ops.sampling import apply_logit_bias, suppress_stop_tokens
+
+    logits, bias, floor = _edit_inputs(bias_width, stop_width, dtype, below)
+
+    @jax.jit
+    def edit(logits):
+        if bias is not None:
+            logits = apply_logit_bias(logits, *map(jnp.asarray, bias))
+        if floor is not None:
+            logits = suppress_stop_tokens(logits, *map(jnp.asarray, floor))
+        return logits
+
+    got, want = edit(logits), _edit_oracle(logits, bias, floor)
+    assert got.dtype == (jnp.float32 if bias is not None else logits.dtype)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    if below == "none":
+        np.testing.assert_array_equal(_bits(got), _bits(logits))
+    if below in ("mixed", "all"):  # the floor did fire
+        floored = lambda x: (np.asarray(x, np.float32) < -9e29).sum()
+        assert floored(got) > floored(logits)
+
+
+@pytest.mark.fast  # tier-1, as the oracle's cases above
+@pytest.mark.parametrize("width", [1, 16, 128])
+def test_logit_edit_forms_give_the_same_bits(width):
+    """The compare form and the scatter form are one function of the
+    width: either gives the other's bits at any width."""
+    from vgate_tpu.ops import sampling
+
+    logits, (ids, vals), (steps, floors, stops) = _edit_inputs(
+        width, width, "float32", "mixed"
+    )
+    np.testing.assert_array_equal(
+        _bits(jax.jit(sampling._bias_by_compare)(logits, ids, vals)),
+        _bits(jax.jit(sampling._bias_by_scatter)(logits, ids, vals)),
+    )
+    np.testing.assert_array_equal(
+        _bits(jax.jit(sampling._floor_by_compare)(logits, steps, floors, stops)),
+        _bits(jax.jit(sampling._floor_by_scatter)(logits, steps, floors, stops)),
+    )
+
+
 def test_logit_bias_forces_and_bans_tokens_through_engine():
     """+100 on one token makes greedy pick it every step (including the
     prefill's first token); -100 on the natural argmax bans it for a

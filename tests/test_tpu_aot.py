@@ -365,6 +365,46 @@ def test_decode_chunk_holds_one_pool_on_v5e(v5e):
     assert not scatters, scatters[0][:300]
 
 
+def test_decode_chunk_edits_logits_in_place_on_v5e(v5e):
+    """The variant the benchmark's cells run (256 slots, 8 steps, a
+    `min_tokens` floor over 2 stop ids, a 16-wide `logit_bias`, argmax,
+    the guard): `logit_bias` and the floor are selects fused into a pass
+    that reads the float32 logits anyway (ops/sampling.py).  As scatters
+    they cost the 155 MB array a flat relayout and back, a copy and an
+    update every step: 1.8 ms of a 9.5 ms step (PERF.md, PR 34)."""
+    from vgate_tpu.runtime.step_programs import _decode_chunk
+
+    A = _abstract(v5e)
+    spec, params, pool, _ = _qwen_1p5b(A)
+    B, ctx = 256, 2048
+    text = _decode_chunk.lower(
+        params, spec, A((B,), jnp.int32), A((B,), jnp.int32), pool, pool,
+        A((B, ctx // PAGE), jnp.int32), A((B,), jnp.bool_),
+        A((B,), jnp.float32), A((B,), jnp.float32), A((B,), jnp.int32),
+        A((2,), jnp.uint32), A((), jnp.uint32),
+        num_steps=8, use_pallas=True, max_position=ctx - 1,
+        seeds=A((B,), jnp.int32), steps=A((B,), jnp.int32),
+        min_toks=A((B,), jnp.int32), stop_id_mat=A((B, 2), jnp.int32),
+        bias_ids=A((B, 16), jnp.int32), bias_vals=A((B, 16), jnp.float32),
+        all_greedy=True, guard=True,
+    ).compile().as_text()
+    logits = f"f32[{B},{spec.vocab_size}]"
+    assert logits in text  # the lm-head's output is what is looked for
+    assert f"f32[{B * spec.vocab_size}]" not in text, (
+        "the logits are re-laid flat: an edit is a scatter again"
+    )
+    for line in text.splitlines():
+        if "=" not in line or logits not in line:
+            continue
+        op = line.split("=", 1)[1]
+        assert " scatter(" not in op, line[:300]
+        # a whole-array copy: `copy(` with the logits' shape as result
+        result = op.split("(", 1)[0]
+        assert not (logits in result and result.rstrip().endswith("copy")), (
+            line[:300]
+        )
+
+
 def test_prefill_step_holds_one_pool_on_v5e(v5e):
     from vgate_tpu.runtime.step_programs import _prefill_step
 

@@ -305,6 +305,49 @@ def apply_penalties(
     )
 
 
+# The two logit edits below are elementwise where they can be: a
+# vocabulary iota compared against the row's K ids, in the [B, V] layout
+# the lm-head produced, so that XLA fuses them into whatever pass reads
+# the logits next (the argmax, the top-k's input, the log-sum-exp).  A
+# scatter into the logits costs that array four passes on a TPU (a flat
+# relayout and back, a copy, the update) whatever K; a compare costs the
+# vector units K steps an element.  Past COMPARE_MAX_IDS ids a row the
+# compares cost more than the passes and the edit is a scatter again
+# (benchmarks/bench_kernels.py bench_sample_edits has the chip's
+# numbers).  K is the arrays' static shape: the choice is made when the
+# program is traced, and the two forms give the same bits.
+COMPARE_MAX_IDS = 64
+
+
+def _vocab_iota(logits: jnp.ndarray) -> jnp.ndarray:
+    return jax.lax.broadcasted_iota(
+        jnp.int32, logits.shape, logits.ndim - 1
+    )
+
+
+def _row_index(ids: jnp.ndarray) -> jnp.ndarray:
+    return jnp.broadcast_to(jnp.arange(ids.shape[0])[:, None], ids.shape)
+
+
+def _bias_by_compare(logits, bias_ids, bias_vals):
+    iota = _vocab_iota(logits)
+    # -0.0 and not 0.0: x + -0.0 is x for every x, so an element that no
+    # id names keeps its bits, as under the scatter-add.  Ids are unique
+    # within a row, so a chain of selects is the row's sum.
+    bias = jnp.full(logits.shape, -0.0, jnp.float32)
+    for k in range(bias_ids.shape[-1]):
+        bias = jnp.where(
+            iota == bias_ids[:, k, None], bias_vals[:, k, None], bias
+        )
+    return logits + bias
+
+
+def _bias_by_scatter(logits, bias_ids, bias_vals):
+    return logits.at[_row_index(bias_ids), bias_ids].add(
+        bias_vals, mode="drop"
+    )
+
+
 def apply_logit_bias(
     logits: jnp.ndarray,  # [B, V]
     bias_ids: jnp.ndarray,  # [B, K] int32 token ids; >= V entries pad
@@ -312,13 +355,40 @@ def apply_logit_bias(
 ) -> jnp.ndarray:
     """OpenAI ``logit_bias``: add per-request biases to selected token
     logits before sampling (-100 effectively bans a token, +100
-    effectively forces it).  Padding entries use an out-of-vocab id —
-    XLA scatter-add drops out-of-bounds updates, so they are no-ops by
+    effectively forces it).  Returns float32.  Ids are unique within a
+    row and never negative (the engine fills them from a validated
+    dict).  A padding entry carries an out-of-vocab id: it equals no
+    vocabulary position, and a scatter drops it, so it is a no-op by
     construction (the same trick as suppress_stop_tokens)."""
-    B = logits.shape[0]
-    b_idx = jnp.broadcast_to(jnp.arange(B)[:, None], bias_ids.shape)
-    return logits.astype(jnp.float32).at[b_idx, bias_ids].add(
-        bias_vals, mode="drop"
+    edit = (
+        _bias_by_compare
+        if bias_ids.shape[-1] <= COMPARE_MAX_IDS
+        else _bias_by_scatter
+    )
+    return edit(logits.astype(jnp.float32), bias_ids, bias_vals)
+
+
+def _live_stop_ids(logits, steps, min_tokens, stop_ids):
+    """The rows' stop ids, those of a row at or above its floor turned
+    into padding ([B, K] work): the logits' pass then only matches ids."""
+    return jnp.where(
+        (steps < min_tokens)[:, None], stop_ids, logits.shape[-1]
+    )
+
+
+def _floor_by_compare(logits, steps, min_tokens, stop_ids):
+    stop_ids = _live_stop_ids(logits, steps, min_tokens, stop_ids)
+    iota = _vocab_iota(logits)
+    hit = iota == stop_ids[:, 0, None]
+    for k in range(1, stop_ids.shape[-1]):
+        hit |= iota == stop_ids[:, k, None]
+    return jnp.where(hit, -1e30, logits)
+
+
+def _floor_by_scatter(logits, steps, min_tokens, stop_ids):
+    stop_ids = _live_stop_ids(logits, steps, min_tokens, stop_ids)
+    return logits.at[_row_index(stop_ids), stop_ids].set(
+        -1e30, mode="drop"
     )
 
 
@@ -330,15 +400,13 @@ def suppress_stop_tokens(
 ) -> jnp.ndarray:
     """min_tokens: slots below their floor cannot sample a stop token.
 
-    Padding entries use an out-of-vocab id — XLA scatter drops
-    out-of-bounds updates, so they are no-ops by construction.
+    Padding entries use an out-of-vocab id: it equals no vocabulary
+    position, and a scatter drops it, so they are no-ops by
+    construction; a row at or above its floor is all padding.
     """
-    B = logits.shape[0]
-    suppress = (steps < min_tokens)[:, None]  # [B, 1]
-    b_idx = jnp.broadcast_to(
-        jnp.arange(B)[:, None], stop_ids.shape
+    edit = (
+        _floor_by_compare
+        if stop_ids.shape[-1] <= COMPARE_MAX_IDS
+        else _floor_by_scatter
     )
-    masked = logits.at[b_idx, stop_ids].set(
-        -1e30, mode="drop"
-    )
-    return jnp.where(suppress, masked, logits)
+    return edit(logits, steps, min_tokens, stop_ids)

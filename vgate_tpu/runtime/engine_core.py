@@ -2720,7 +2720,9 @@ class EngineCore:
         * ``bias_ids`` / ``bias_vals`` [B, K]: ``logit_bias``.
 
         Both K bucket to a power of two (bounded variant count) and pad
-        with an out-of-vocab id, which the scatters drop.  With them the
+        with an out-of-vocab id, which no vocabulary position equals (the
+        edits of ops/sampling.py compare, or past COMPARE_MAX_IDS ids a
+        row scatter, and a scatter drops it).  With them the
         two static arguments the rows decide, ``num_logprobs`` (a row
         asked for them) and ``all_greedy`` (no row samples and none wants
         logprobs: the decode and verify programs' argmax variant), and
@@ -2754,7 +2756,7 @@ class EngineCore:
             if sp.has_penalties and seq.generated_ids:
                 penalised = True
             if sp.min_tokens > 0:
-                # only floor rows ever have their ids scattered, so only
+                # only floor rows ever have their ids applied, so only
                 # they size K (a zero-floor neighbour with many
                 # stop_token_ids must not fork extra compiled variants)
                 floors.append((row, sp))

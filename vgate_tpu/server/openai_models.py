@@ -33,8 +33,10 @@ def _logit_bias_ints(
     """OpenAI logit_bias uses stringified token-id keys; normalize to
     int keys with biases clamped to the documented [-100, 100] range.
     Non-numeric or NEGATIVE keys raise ValueError (surfaced as a 422 —
-    a negative id would wrap to the end of the vocab in the device
-    scatter instead of being dropped), and the entry count caps at 300
+    a negative id names no token: the device's compare form matches it
+    to nothing, and its scatter form, which takes over past
+    ops/sampling.py COMPARE_MAX_IDS entries, would wrap it to the end of
+    the vocab), and the entry count caps at 300
     (the OpenAI limit): K sizes device arrays and compiled program
     variants, so it must not be client-controlled without bound."""
     if not raw:
@@ -47,9 +49,10 @@ def _logit_bias_ints(
     for k, v in raw.items():
         tid = int(k)
         if not 0 <= tid <= 2**31 - 1:
-            # negative ids would WRAP in the device scatter; ids past
-            # int32 would overflow the device arrays (ids merely >= the
-            # vocab size drop harmlessly on device)
+            # negative ids name no token (and would WRAP in the device's
+            # scatter form); ids past int32 would overflow the device
+            # arrays (ids merely >= the vocab size equal no vocabulary
+            # position and change nothing on device)
             raise ValueError(
                 f"token id must be in [0, 2**31-1], got {tid}"
             )
