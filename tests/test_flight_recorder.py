@@ -381,3 +381,80 @@ def test_decode_fault_crash_log_includes_flight_snapshot():
     finally:
         faults.reset()
         sup.stop()
+
+
+# ------------------------------------- a pause, end to end (ISSUE 35)
+
+
+def test_a_provoked_pause_leaves_one_tick_one_record_and_one_log_line(
+    caplog,
+):
+    """The real engine, two streams running, a ``delay`` armed at the
+    ``stall`` site between two decode readbacks: the delivery gap it
+    makes is a PAUSE with exactly one flight-recorder tick, one
+    ``/debug/perf -> pauses`` record and one ``engine_pause`` log line,
+    cause ``host`` (the thread slept in its own ``schedule`` bracket:
+    not CPU time it lost)."""
+    import logging
+
+    from vgate_tpu.runtime.engine_core import EngineCore
+
+    core = EngineCore(load_config(
+        model={
+            "model_id": "tiny-dense", "engine_type": "jax_tpu",
+            "dtype": "float32", "max_model_len": 64,
+        },
+        tpu={
+            "dp": 1, "tp": 1, "ep": 1, "sp": 1, "num_devices": 1,
+            "kv_num_pages": 64, "kv_page_size": 4,
+            "max_batch_slots": 4, "prefill_buckets": [8, 16],
+            "use_pallas": False, "decode_chunk": 4,
+        },
+        logging={"level": "WARNING"},
+    ))
+    core.start()
+    params = [SamplingParams(max_tokens=40, min_tokens=40,
+                             temperature=0.0)] * 2
+    prompts = ["pause probe one", "pause probe two"]
+
+    def pause_ticks():
+        return [t for t in core.flight.ticks() if t["kind"] == "pause"]
+
+    try:
+        # every program variant compiles here, not inside a gap
+        core.generate(prompts, params)
+        core.generate(prompts, params)
+        seen = core.perf.totals()["deliveries"]
+        records, ticks = len(core.perf.pauses()), len(pause_ticks())
+        spec = faults.arm(
+            "stall", mode="delay", delay_s=0.7, times=1,
+            # once streams are running and have had two deliveries
+            match=lambda _: core.perf.totals()["deliveries"] >= seen + 2,
+        )
+        caplog.clear()  # the warm-up's compile pauses are logged too
+        with caplog.at_level(logging.WARNING):
+            core.generate(prompts, params)
+        assert spec.fired == 1
+        snap = core.perf_snapshot()
+        new = snap["pauses"][records:]
+        assert len(new) == 1, new
+        pause = new[0]
+        assert pause["cause"] == "host"
+        assert 0.7 <= pause["slept_s"] <= pause["gap_s"] < 3.0
+        assert pause["phases"]["schedule"] >= 0.7
+        assert pause["prompt_programs"] == 0 and pause["rows"] == 2
+        new_ticks = pause_ticks()[ticks:]
+        assert len(new_ticks) == 1
+        assert new_ticks[0]["gap_s"] == pause["gap_s"]
+        assert new_ticks[0]["cause"] == "host"
+        totals = snap["totals"]
+        assert totals["pauses"]["host_n"] >= 1
+        assert sum(totals["delivery_gaps"].values()) == totals["deliveries"]
+        assert core.flight.crash_snapshot()["ticks"][-1]["n"] >= (
+            new_ticks[0]["n"])
+        lines = [r for r in caplog.records if r.msg == "engine_pause"]
+        assert len(lines) == 1
+        assert lines[0].extra_data["gap_s"] == pause["gap_s"]
+    finally:
+        faults.reset()
+        core.stop()

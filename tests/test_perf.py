@@ -289,7 +289,7 @@ def _fake_snapshot(tokens=100, host=0.5, wall=1.0, mfu=0.1):
             "compiles": {"decode": 3, "prefill": 1},
             "compile_seconds": 2.0,
         },
-        "last_tick": None,
+        "pauses": [],
         "compile_ledger": [],
         "roofline": None,
         "last_profile": None,
@@ -861,7 +861,8 @@ def test_thread_time_is_read_per_tick_and_per_wait_never_per_token():
     for module in (engine_core, jax_backend, sequence):
         assert "thread_time" not in inspect.getsource(module)
     source = inspect.getsource(perf_mod)
-    assert source.count("time.thread_time()") == 4  # tick x2, wait x2
+    # tick x2, wait x2, and one a decode readback (note_delivery)
+    assert source.count("time.thread_time()") == 5
 
 
 def test_gateway_counters_follow_one_stream():
@@ -891,7 +892,9 @@ def test_gateway_counters_follow_one_stream():
     delivery(8)
     delivery(3, write=False)
     delivery(5)
-    gateway.note_handoff()
+    armed = gateway.handoff_armed()
+    clock.advance(0.012)  # the loop was busy: the wake-up waited
+    gateway.note_handoff(armed)
     totals = gateway.totals()
     assert totals["ingress_n"] == 1
     assert totals["ingress_s"] == pytest.approx(0.020)
@@ -901,6 +904,9 @@ def test_gateway_counters_follow_one_stream():
     assert totals["stream_deliveries"] == 3  # content writes
     assert totals["stream_tokens_delivered"] == 17
     assert totals["stream_handoffs"] == 1
+    assert totals["handoff_wait_s"] == pytest.approx(0.012)
+    assert totals["handoff_waits"]["16"] == 1
+    assert sum(totals["handoff_waits"].values()) == 1
     assert totals["first_chunk_n"] == 1  # the first chunk on the wire
     assert totals["first_chunk_s"] == pytest.approx(0.0051)
     gateway.ingress_close()
@@ -912,8 +918,13 @@ def test_gateway_counters_follow_one_stream():
     assert off.stream_clock() is None
     assert off.detok_begin(off.stream_clock()) is None
     assert off.write_begin() is None
-    off.note_handoff()
-    assert set(off.totals().values()) == {0, 0.0}
+    assert off.handoff_armed() is None  # no stamp, nothing booked
+    off.note_handoff(off.handoff_armed())
+    waits = off.totals().pop("handoff_waits")
+    assert set(waits.values()) == {0}
+    assert {
+        v for k, v in off.totals().items() if k != "handoff_waits"
+    } == {0, 0.0}
 
 
 def test_first_chunk_is_timed_when_the_first_token_writes_nothing():
@@ -1002,12 +1013,18 @@ async def test_the_chat_handler_closes_its_ingress_annotation(monkeypatch):
     events = []
 
     class Ann:
+        def __init__(self, log):
+            self.log = log
+
         def __exit__(self, *exc):
-            events.append("close")
+            self.log.append("close")
 
     def fake_open(name, args):
-        events.append(name)
-        return Ann()
+        # the app's collector clock annotates too (vgt.host.gc),
+        # whenever a collection falls inside the capture
+        log = events if name.startswith("vgt.gateway.") else []
+        log.append(name)
+        return Ann(log)
 
     monkeypatch.setattr(perf_mod, "_open_annotation", fake_open)
     monkeypatch.setattr(app_mod.GATEWAY, "enabled", True)
@@ -1098,3 +1115,456 @@ def test_capture_profile_is_quiet_by_default_and_frames_on_request(tmp_path):
                 > results["quiet"]["file_bytes"])
     finally:
         core.stop()
+
+
+# ------------------------------- delivery gaps and pauses (ISSUE 35)
+
+
+class FakeCpu:
+    """``time.thread_time`` under the test's hand: the engine thread is
+    on the CPU exactly when the test says so."""
+
+    def __init__(self):
+        self.t = 5.0
+
+    def __call__(self):
+        return self.t
+
+
+@pytest.fixture
+def cpu(monkeypatch):
+    fake = FakeCpu()
+    monkeypatch.setattr(perf_mod.time, "thread_time", fake)
+    return fake
+
+
+@pytest.fixture
+def gc_clock(monkeypatch):
+    """A collector's clock of the test's own in the module's place (the
+    process's real one must not tick into a constructed pause)."""
+    fake = perf_mod.GcClock(clock=FakeClock())
+    monkeypatch.setattr(perf_mod, "GC", fake)
+    return fake
+
+
+def deliver(rec, clock, cpu=None, gap=0.1, on_cpu=0.0, device=0.0,
+            readback=0.0, schedule=0.0, dispatch=0.0, new_tick=False,
+            before=None, **kw):
+    """One decode readback ``gap`` seconds after the last: so much of it
+    in each bracket, ``on_cpu`` of the rest with the thread running,
+    what is left in no bracket at all.  ``new_tick`` closes the open
+    tick first, so that the gap straddles a tick boundary."""
+    if new_tick:
+        rec.tick_end(worked=True)
+        rec.tick_begin()
+    if before is not None:
+        before()
+    rest = gap - device - readback - schedule - dispatch
+    assert rest >= 0
+    for span, seconds in (("schedule", schedule),
+                          ("decode_dispatch", dispatch),
+                          ("device_wait", device), ("readback", readback)):
+        if seconds:
+            with rec.span(span):
+                clock.advance(seconds)
+                if cpu is not None and span in ("schedule",
+                                                "decode_dispatch"):
+                    cpu.t += seconds  # the engine's Python: on the CPU
+    clock.advance(rest)
+    if cpu is not None:
+        cpu.t += on_cpu
+    return rec.note_delivery(kw.pop("steps", 8), kw.pop("rows", 4), **kw)
+
+
+def test_gaps_land_in_their_buckets_and_an_idle_engine_makes_none(
+    cpu, gc_clock
+):
+    clock = FakeClock()
+    rec = recorder(clock=clock)
+    assert perf_mod.GAP_KEYS[0] == "8" and perf_mod.GAP_KEYS[-2:] == (
+        "8192", "inf")
+    assert len(perf_mod.GAP_KEYS) == 22
+    for a, b in zip(perf_mod.GAP_EDGES_S, perf_mod.GAP_EDGES_S[1:]):
+        assert b / a == pytest.approx(2 ** 0.5)
+    rec.tick_begin()
+    assert deliver(rec, clock, cpu) is None  # the first: no gap yet
+    assert rec.totals()["deliveries"] == 0
+    for gap in (0.004, 0.0079, 0.0081, 0.1, 0.3, 0.3, 9.0):
+        deliver(rec, clock, cpu, gap=gap, device=gap)
+    totals = rec.totals()
+    gaps = {k: n for k, n in totals["delivery_gaps"].items() if n}
+    assert gaps == {"8": 2, "11.31": 1, "128": 1, "362.04": 2, "inf": 1}
+    assert totals["deliveries"] == 7 == sum(gaps.values())
+    assert totals["delivery_gap_s"] == pytest.approx(9.72)
+    # an edge is its bucket's upper end; past the last, the overflow
+    hist = perf_mod.GapHistogram()
+    for seconds in (0.0, 0.008, 8.192, 8.1921):
+        hist.add(seconds)
+    assert {k: n for k, n in hist.to_dict().items() if n} == {
+        "8": 2, "8192": 1, "inf": 1}
+    # a tick that finds no stream running clears the clock: the hour
+    # until the next delivery is nobody's silence
+    rec.clear_delivery_clock()
+    assert deliver(rec, clock, cpu, gap=3600.0) is None
+    assert rec.totals()["deliveries"] == 7
+    deliver(rec, clock, cpu, gap=0.05, device=0.05)
+    assert rec.totals()["deliveries"] == 8
+    # a membership change does not clear it
+    rec.note_membership_change(drained=True, reason="preempt")
+    deliver(rec, clock, cpu, gap=0.05, device=0.05)
+    assert rec.totals()["deliveries"] == 9
+
+
+def test_a_pause_differences_its_clocks_across_a_tick_boundary(
+    cpu, gc_clock
+):
+    clock = FakeClock()
+    rec = recorder(clock=clock)
+    rec.tick_begin()
+    with rec.span("schedule"):
+        clock.advance(0.25)  # before the first delivery: not the gap's
+    deliver(rec, clock, cpu)
+    with rec.span("emit"):
+        clock.advance(0.05)  # the closing tick's part of the gap
+    cpu.t += 0.05
+
+    def dispatched():
+        rec.count(decode_steps=8)
+        rec.count(prompt_programs=2, prompt_tokens=300, swap_ins=1)
+        rec.note_decode(steps=8, ctx_tokens=10, device_s=0.1)
+        rec.note_membership_change(drained=False)
+        rec.note_membership_change(drained=True, reason="preempt")
+
+    pause = deliver(
+        rec, clock, cpu, gap=0.95, schedule=0.6, device=0.2,
+        readback=0.01, on_cpu=0.09, new_tick=True, before=dispatched,
+        queue_depth=7, preemptions=3, steps=4, rows=2,
+    )
+    assert pause["gap_s"] == pytest.approx(1.0)
+    assert pause["phases"] == pytest.approx({
+        "host": 0.14, "schedule": 0.6, "state": 0.0, "dispatch": 0.0,
+        "device": 0.2, "readback": 0.01, "detok": 0.05,
+    })
+    assert sum(pause["phases"].values()) == pytest.approx(1.0)
+    assert pause["decode_device_s"] == pytest.approx(0.1)
+    assert pause["first_token_wait_s"] == pytest.approx(0.1)
+    assert pause["cpu_s"] == pytest.approx(0.74)
+    assert pause["off_cpu_s"] == pytest.approx(0.05)
+    assert (pause["prompt_programs"], pause["prompt_tokens"]) == (2, 300)
+    assert (pause["decode_steps"], pause["swap_ins"]) == (8, 1)
+    assert pause["membership_changes"] == 2
+    assert pause["drains"] == ["preempt"]
+    assert pause["preemptions"] == 3 and pause["queue_depth"] == 7
+    assert (pause["steps"], pause["rows"]) == (4, 2)
+    assert pause["cause"] == "host"  # 0.6 s of its own scheduling
+    assert rec.snapshot()["pauses"] == [pause]
+    # the next gap starts from this delivery's clocks, not from zero
+    quiet = deliver(rec, clock, cpu, gap=0.6, device=0.6)
+    assert quiet["prompt_programs"] == 0 and quiet["drains"] == []
+    assert quiet["phases"]["schedule"] == 0.0
+
+
+def _pause_of(cause, rec, clock, cpu, gc_clock):
+    """A one-second pause built to have ``cause``."""
+    if cause == "compile":
+        with rec.span("decode_dispatch") as disp:
+            clock.advance(0.6)
+        cpu.t += 0.6
+        rec.record_compile("decode", (8,), disp.seconds, "chunk_variant")
+        return deliver(rec, clock, cpu, gap=0.4, device=0.4)
+    if cause == "prefill":
+        # a wave's programs, the wait for their first tokens: even with
+        # a collection and lost CPU beside them, the wave comes first
+        return deliver(
+            rec, clock, cpu, gap=1.0, device=0.4, dispatch=0.15,
+            before=lambda: rec.count(prompt_programs=3, prompt_tokens=900),
+        )
+    if cause == "gc":
+        # a collection on ANOTHER thread holds the GIL: the engine
+        # thread is off the CPU for as long
+        def collect():
+            gc_clock._on_gc("start", {"generation": 2})
+            gc_clock._clock.advance(0.7)
+            gc_clock._on_gc("stop", {"generation": 2})
+
+        def elsewhere():
+            import threading
+
+            thread = threading.Thread(target=collect)
+            thread.start()
+            thread.join()
+        pause = deliver(rec, clock, cpu, gap=1.0, on_cpu=0.3,
+                        before=elsewhere)
+        assert pause["gc_s"] == 0.0
+        assert pause["gc_other_threads_s"] == pytest.approx(0.7)
+        return pause
+    if cause == "off_cpu":
+        return deliver(rec, clock, cpu, gap=1.0, on_cpu=0.2, device=0.1)
+    if cause == "device":
+        return deliver(rec, clock, cpu, gap=1.0, device=0.9, on_cpu=0.1)
+    return deliver(rec, clock, cpu, gap=1.0, device=0.3, on_cpu=0.7)
+
+
+@pytest.mark.parametrize("cause", perf_mod.PAUSE_CAUSES)
+def test_each_cause_is_reached_and_a_pause_has_exactly_one(
+    cause, cpu, gc_clock
+):
+    clock = FakeClock()
+    rec = recorder(clock=clock)
+    rec.tick_begin()
+    deliver(rec, clock, cpu)
+    pause = _pause_of(cause, rec, clock, cpu, gc_clock)
+    assert pause["cause"] == cause
+    pauses = rec.totals()["pauses"]
+    assert pauses[cause + "_n"] == 1
+    assert pauses[cause + "_s"] == pytest.approx(1.0)
+    assert sum(v for k, v in pauses.items() if k.endswith("_n")) == 1
+
+
+def test_a_wave_too_small_for_the_wait_is_the_devices_pause(cpu, gc_clock):
+    """What the chip showed (PR 35): 2.9 s of waiting for the first
+    token of ONE 182-token prompt program.  Once the recorder knows
+    what a prompt token costs, such a wave does not explain its gap."""
+    clock = FakeClock()
+    rec = recorder(clock=clock)
+    rec.tick_begin()
+    deliver(rec, clock, cpu)
+    wave = lambda n: (lambda: rec.count(prompt_programs=1, prompt_tokens=n))
+    for _ in range(10):  # 0.5 ms of waiting a prompt token, so far
+        deliver(rec, clock, cpu, gap=0.14, device=0.1, before=wave(200))
+    stood_still = deliver(
+        rec, clock, cpu, gap=2.9, device=2.85, before=wave(182))
+    assert stood_still["wave_at_pace_s"] == pytest.approx(0.091)
+    assert stood_still["first_token_wait_s"] == pytest.approx(2.85)
+    assert stood_still["cause"] == "device"
+    # the same wait behind a wave that large is the wave's
+    big = deliver(rec, clock, cpu, gap=2.9, device=2.85, before=wave(4000))
+    assert big["cause"] == "prefill"
+    # within the slack it is still the wave's: the pace is a mean
+    slow = deliver(rec, clock, cpu, gap=0.7, device=0.6, before=wave(182))
+    assert slow["cause"] == "prefill"
+    # decode chunks' own waits are not the waves' pace
+    pace = slow["wave_at_pace_s"]
+    deliver(rec, clock, cpu, gap=50.0, device=50.0, before=lambda:
+            rec.note_decode(steps=8, ctx_tokens=10, device_s=50.0))
+    again = deliver(rec, clock, cpu, gap=2.9, device=2.85, before=wave(182))
+    assert again["cause"] == "device"
+    assert again["wave_at_pace_s"] == pytest.approx(pace, rel=0.1)
+
+
+def test_a_collection_on_the_engine_thread_is_gc_not_lost_cpu(
+    cpu, gc_clock
+):
+    clock = FakeClock()
+    rec = recorder(clock=clock)
+    rec.tick_begin()  # the engine thread asks for a sum of its own
+    assert gc_clock.thread_s == {rec._gc_thread: 0.0}
+    deliver(rec, clock, cpu)
+
+    def collect():
+        gc_clock._on_gc("start", {"generation": 2})
+        gc_clock._clock.advance(0.8)
+        gc_clock._on_gc("stop", {"generation": 2})
+
+    # the thread ran the whole second: 0.8 s of it the collector
+    pause = deliver(rec, clock, cpu, gap=1.0, on_cpu=1.0, before=collect)
+    assert pause["cause"] == "gc" and pause["gc_s"] == pytest.approx(0.8)
+    assert pause["gc_other_threads_s"] == 0.0
+    assert pause["off_cpu_s"] == 0.0
+
+
+def test_a_sleep_taken_on_purpose_is_not_lost_cpu(cpu, gc_clock):
+    clock = FakeClock()
+    rec = recorder(clock=clock)
+    rec.tick_begin()
+    deliver(rec, clock, cpu)
+    with rec.span("schedule"):
+        clock.advance(0.7)  # an armed fault's delay: the thread slept
+    rec.note_sleep(0.7)
+    pause = deliver(rec, clock, cpu, gap=0.1, device=0.1)
+    assert pause["cause"] == "host" and pause["slept_s"] == 0.7
+    assert pause["off_cpu_s"] == pytest.approx(0.0)
+    assert pause["phases"]["schedule"] == pytest.approx(0.7)
+
+
+def test_causes_partition_the_seconds_and_merge_adds_them(cpu, gc_clock):
+    clocks = [FakeClock(), FakeClock()]
+    recs = [recorder(clock=clock) for clock in clocks]
+    for rec, clock in zip(recs, clocks):
+        rec.tick_begin()
+        deliver(rec, clock, cpu)
+    for cause in perf_mod.PAUSE_CAUSES:
+        _pause_of(cause, recs[0], clocks[0], cpu, gc_clock)
+    _pause_of("device", recs[1], clocks[1], cpu, gc_clock)
+    # a gap, no pause
+    deliver(recs[1], clocks[1], cpu, gap=0.4, device=0.4)
+    for rec in recs:
+        rec.tick_end(worked=True)
+    snaps = [rec.snapshot() for rec in recs]
+    totals = snaps[0]["totals"]
+    seconds = sum(
+        v for k, v in totals["pauses"].items() if k.endswith("_s"))
+    assert seconds == pytest.approx(6.0)
+    assert seconds == pytest.approx(
+        sum(p["gap_s"] for p in snaps[0]["pauses"]))
+    assert {p["cause"] for p in snaps[0]["pauses"]} == set(
+        perf_mod.PAUSE_CAUSES)
+    merged = perf_mod.merge_snapshots(snaps)
+    both = merged["totals"]
+    assert both["pauses"]["device_n"] == 2
+    assert both["pauses"]["device_s"] == pytest.approx(2.0)
+    assert both["pauses"]["host_n"] == 1
+    assert both["deliveries"] == 6 + 2
+    assert both["delivery_gap_s"] == pytest.approx(6.0 + 1.4)
+    assert sum(both["delivery_gaps"].values()) == both["deliveries"]
+    assert both["delivery_gaps"]["1024"] == 7
+    assert both["gc"] == totals["gc"]  # one process, one collector
+    assert [p["replica"] for p in merged["pauses"]].count(1) == 1
+    assert len(merged["pauses"]) == 7
+    times = [p["t"] for p in merged["pauses"]]
+    assert times == sorted(times)
+
+
+def test_only_the_last_pause_records_are_kept(cpu, gc_clock):
+    clock = FakeClock()
+    rec = recorder(clock=clock)
+    rec.tick_begin()
+    deliver(rec, clock, cpu)
+    for _ in range(perf_mod.PAUSES_KEPT + 3):
+        deliver(rec, clock, cpu, gap=0.5, device=0.5)  # 0.5 s IS a pause
+    assert len(rec.pauses()) == perf_mod.PAUSES_KEPT
+    assert rec.totals()["pauses"]["device_n"] == perf_mod.PAUSES_KEPT + 3
+
+
+def test_the_delivery_recorder_is_inert_when_observability_is_off(
+    monkeypatch
+):
+    """Off: note_delivery, the hand-off stamp and the collector's
+    callback test one flag; on with no capture, no annotation."""
+    opened = []
+    monkeypatch.setattr(
+        perf_mod, "_open_annotation",
+        lambda name, args: opened.append(name),
+    )
+    clock = FakeClock()
+    rec = recorder(clock=clock, enabled=False)
+    rec.tick_begin()
+    rec.count(decode_steps=8)
+    assert rec.note_delivery(8, 4) is None
+    clock.advance(2.0)
+    assert rec.note_delivery(8, 4) is None
+    assert rec._mark is None and rec._counts["decode_steps"] == 0
+    assert rec.snapshot() == {"enabled": False}
+    collector = perf_mod.GcClock(clock=clock)
+    collector.enabled = False
+    collector._on_gc("start", {"generation": 0})
+    clock.advance(1.0)
+    collector._on_gc("stop", {"generation": 0})
+    assert collector.totals()["gc_s"] == 0.0 and collector._t0 is None
+    collector.enabled = True
+    collector._on_gc("start", {"generation": 1})
+    clock.advance(0.25)
+    collector._on_gc("stop", {"generation": 1})
+    assert collector.totals() == {
+        "gc_s": 0.25, "gc_max_s": 0.25,
+        "gc_collections": {"0": 0, "1": 1, "2": 0},
+        "gc_seconds": {"0": 0.0, "1": 0.25, "2": 0.0}}
+    assert opened == []  # no capture: no annotation
+    perf_mod.set_capturing(True)
+    try:
+        collector._on_gc("start", {"generation": 2})
+    finally:
+        perf_mod.set_capturing(False)
+    assert opened == ["vgt.host.gc"]
+
+
+def test_the_collectors_clock_times_a_real_collection():
+    import gc
+
+    collector = perf_mod.GcClock()
+    collector.install()
+    collector.install()  # once, however often asked
+    try:
+        assert gc.callbacks.count(collector._on_gc) == 1
+        key = collector.watch()
+        before = collector.totals()
+        cycle = [[] for _ in range(200_000)]
+        for item in cycle:
+            item.append(cycle)
+        del cycle, item
+        gc.collect()
+        after = collector.totals()
+    finally:
+        collector.remove()
+    assert collector._on_gc not in gc.callbacks
+    assert after["gc_s"] > before["gc_s"]
+    assert after["gc_collections"]["2"] > before["gc_collections"]["2"]
+    # this thread asked for a sum of its own, and ran the collection
+    assert collector.thread_s[key] == pytest.approx(collector.gc_s)
+    collector.remove()  # twice is harmless
+
+
+async def test_the_app_installs_the_collectors_clock_once_and_removes_it():
+    import gc
+
+    assert perf_mod.GC._on_gc not in gc.callbacks
+    client = await _client()
+    try:
+        assert gc.callbacks.count(perf_mod.GC._on_gc) == 1
+    finally:
+        await client.close()
+    assert perf_mod.GC._on_gc not in gc.callbacks
+
+
+@pytest.mark.parametrize("suffix", [".tok", ".tpot"])
+def test_the_benchmark_reads_the_recorders_own_snapshots(
+    suffix, cpu, gc_clock
+):
+    """``engine.delivery_gap_p99_ms`` / ``engine.pause_*`` /
+    ``gateway.handoff*`` through their data files, from what the
+    recorder and the gateway really serve."""
+    from perfbench import manifest
+
+    clock = FakeClock()
+    rec = recorder(clock=clock)
+    gateway = perf_mod.GatewayPerf(clock=clock)
+
+    def scrape():
+        totals = rec.totals()
+        totals["gateway"] = gateway.totals()
+        return {"totals": totals}
+
+    def tick(gap, **kw):
+        rec.tick_begin()
+        pause = deliver(rec, clock, cpu, gap=gap, **kw)
+        armed = gateway.handoff_armed()
+        clock.advance(0.001)
+        gateway.note_handoff(armed)
+        rec.tick_end(worked=True)
+        return pause
+
+    tick(0.1)
+    tick(0.1, device=0.1)
+    first = scrape()
+    for _ in range(98):
+        tick(0.099, device=0.099)
+    tick(0.899, device=0.6, before=lambda: rec.count(prompt_programs=1))
+    tick(0.999, device=0.999)
+    ctx = {"perf": {"open": first, "close": scrape()}}
+
+    def read(base):
+        spec = manifest.metric(base + suffix)
+        return manifest.reducer(spec["reducer"])(ctx, **spec["args"])
+
+    # 100 gaps: the 99th is the 0.9 s one, in (724.08, 1024] ms
+    assert 724.08 < read("engine.delivery_gap_p99_ms") <= 1024
+    wall = 98 * 0.1 + 0.9 + 1.0
+    assert read("engine.pause_share") == pytest.approx(100 * 1.9 / wall)
+    assert read("engine.pause_prefill_share") == pytest.approx(
+        100 * 0.9 / wall)
+    assert read("engine.pause_device_share") == pytest.approx(
+        100 * 1.0 / wall)
+    assert read("engine.pause_host_share") == 0.0
+    assert read("engine.gc_share") == 0.0
+    assert read("gateway.handoffs_per_readback") == pytest.approx(1.0)
+    assert 0 < read("gateway.handoff_wait_p99_ms") <= 8

@@ -404,6 +404,52 @@ def test_a_wakeup_on_its_way_serves_what_is_posted_before_it_runs():
     assert len(calls) == 1  # drained: the next readback wakes again
 
 
+def test_a_wakeup_books_how_long_it_waited_for_the_loop(monkeypatch):
+    """The hand-off timed on its real path: the stamp is taken when a
+    wake-up is armed, the wait booked when the loop runs it; a wake-up
+    that serves two readbacks' posts is ONE hand-off, timed from the
+    first; with observability off nothing is stamped or booked."""
+    from vgate_tpu.backends import jax_backend
+    from vgate_tpu.observability.perf import GatewayPerf
+
+    now = [50.0]
+    gateway = GatewayPerf(clock=lambda: now[0])
+    monkeypatch.setattr(jax_backend, "GATEWAY", gateway)
+    calls = []
+    handoff = _LoopHandoff(SimpleNamespace(
+        call_soon_threadsafe=lambda fn: calls.append(fn)
+    ))
+    a = Inbox()
+    handoff.post(a, ([1], False))
+    handoff.wake()
+    now[0] += 0.020  # the loop is writing to other streams
+    handoff.post(a, ([2], False))  # the next readback's post
+    handoff.wake()  # rides the wake-up already on its way
+    now[0] += 0.010
+    calls.pop()()
+    assert a.items == [([1], False), ([2], False)]
+    totals = gateway.totals()
+    assert totals["stream_handoffs"] == 1
+    assert totals["handoff_wait_s"] == pytest.approx(0.030)
+    assert totals["handoff_waits"]["32"] == 1
+    handoff.post(a, ([3], True))
+    handoff.wake()
+    calls.pop()()  # at once
+    totals = gateway.totals()
+    assert totals["stream_handoffs"] == 2
+    assert totals["handoff_wait_s"] == pytest.approx(0.030)
+    assert totals["handoff_waits"]["8"] == 1
+    assert sum(totals["handoff_waits"].values()) == 2
+    gateway.enabled = False
+    handoff.post(a, ([4], True))
+    handoff.wake()
+    assert handoff._armed_t is None
+    now[0] += 1.0
+    calls.pop()()
+    assert a.items[-1] == ([4], True)  # delivered all the same
+    assert gateway.totals() == totals
+
+
 def test_a_closed_loop_drops_the_deliveries():
     def closed(fn):
         raise RuntimeError("Event loop is closed")

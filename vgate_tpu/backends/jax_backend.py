@@ -135,6 +135,9 @@ class _LoopHandoff:
         self._lock = threading.Lock()
         self._pending: List[tuple] = []  # (queue, item), in post order
         self._armed = False
+        # when the wake-up on its way was armed (GATEWAY's clock; None
+        # with observability off): _drain books how long it waited
+        self._armed_t: Optional[float] = None
 
     def post(self, q: "asyncio.Queue", item: Any) -> None:
         with self._lock:
@@ -145,6 +148,7 @@ class _LoopHandoff:
             if self._armed or not self._pending:
                 return
             self._armed = True
+            self._armed_t = GATEWAY.handoff_armed()
         try:
             self._loop.call_soon_threadsafe(self._drain)
         except RuntimeError:
@@ -157,7 +161,8 @@ class _LoopHandoff:
         with self._lock:
             batch, self._pending = self._pending, []
             self._armed = False
-        GATEWAY.note_handoff()
+            armed_t = self._armed_t
+        GATEWAY.note_handoff(armed_t)
         for q, item in batch:
             q.put_nowait(item)
 

@@ -246,6 +246,25 @@ HOST_OVERHEAD_RATIO = _safe_metric(
     "high values under decode load mean the engine is host-bound "
     "(VgtHostOverheadHigh, docs/operations.md)",
 )
+DELIVERY_GAP_SECONDS = _safe_metric(
+    Histogram,
+    "vgt_delivery_gap_seconds",
+    "Time between two decode readbacks that handed tokens to running "
+    "streams (PerfRecorder.note_delivery): the silence every stream of "
+    "the batch sees at once.  Near one decode chunk in steady state; "
+    "the tail is admission waves and pauses (/debug/perf -> pauses)",
+    buckets=(0.008, 0.016, 0.032, 0.064, 0.128, 0.256, 0.512, 1.024,
+             2.048, 4.096, 8.192),
+)
+ENGINE_PAUSES = _safe_metric(
+    Counter,
+    "vgt_engine_pauses",
+    "Delivery gaps of 0.5 s or more, by their one cause: compile | "
+    "prefill (an admission wave) | gc | off_cpu (GIL or OS) | device | "
+    "host.  Each has a record in /debug/perf -> pauses and a flight-"
+    "recorder tick of kind pause",
+    labelnames=("cause",),
+)
 
 # --- recovery / health state machine (runtime/supervisor.py) ---
 ENGINE_RESTARTS = _safe_metric(

@@ -57,6 +57,18 @@ snapshot readers copy defensively under the GIL):
   engine's own Python outside the jitted call and the device): the
   single number the megatick refactor exists to drive down.
 
+* **Delivery gaps and pauses** — the silence a running stream sees,
+  measured where it is made.  Every decode readback that handed tokens
+  to a running stream (:meth:`PerfRecorder.note_delivery`) closes a
+  *delivery gap*: the time since the readback before it.  All gaps go
+  into one monotone histogram; a gap of :data:`PAUSE_S` or more is a
+  *pause*, and is charged to exactly ONE cause (:data:`PAUSE_CAUSES`)
+  from clocks differenced between the two readbacks.  (``stall`` stays
+  the flight recorder's word for a loop the watchdog declared wedged.)
+  :class:`GatewayPerf` times the hand-off of each readback to the event
+  loop the same way, and :class:`GcClock` (``GC``) the process's
+  garbage collector.
+
 Surfaces: ``GET /debug/perf`` (auth-gated, drain-uncounted), the
 ``/stats`` engine block (``perf``), metrics
 ``vgt_tick_phase_seconds{phase}`` / ``vgt_recompiles_total{variant}`` /
@@ -68,8 +80,11 @@ QPS cell).
 
 from __future__ import annotations
 
+import bisect
 import contextvars
+import gc
 import os
+import threading
 import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
@@ -84,12 +99,15 @@ ADDITIVE_TOTALS = (
     "decode_device_s", "decode_ctx_token_steps",
     "engine_cpu_s", "engine_cpu_in_wait_s",
     "membership_changes", "pipeline_drains",
+    "deliveries", "delivery_gap_s",
 ) + REQUEST_TOTALS
 
 # the fixed phase taxonomy (docs/observability.md "Perf attribution")
 PHASES = (
     "host", "schedule", "state", "dispatch", "device", "readback", "detok",
 )
+# all but ``host``, which is what no bracket covers
+MEASURED_PHASES = PHASES[1:]
 # the phase each engine span accrues into; spans not listed here
 # (``idle_wait``) only annotate the trace
 SPAN_PHASE = {
@@ -104,6 +122,25 @@ SPAN_PHASE = {
 # spans in which the engine thread is blocked on the device: their CPU
 # time is ``engine_cpu_in_wait_s`` (time.thread_time, per chunk)
 WAIT_SPANS = frozenset(("device_wait", "readback"))
+
+# A delivery gap this long is a PAUSE: it gets a record and one cause.
+PAUSE_S = 0.5
+# first match wins (PerfRecorder._pause); the causes partition the
+# pauses' seconds
+PAUSE_CAUSES = ("compile", "prefill", "gc", "off_cpu", "device", "host")
+PAUSES_KEPT = 32
+# an admission wave explains a pause only if the wait for the device is
+# within this factor of what the gap's prompt tokens take at the pace
+# of the waits on waves so far (a 182-token wave does not take 2.9 s)
+WAVE_SLACK = 4.0
+# upper edges of the gap histograms (delivery gaps, hand-off waits): a
+# factor sqrt(2) apart from 8 ms to 8,192 ms, then an overflow.  The
+# keys are the edges in ms, as /debug/perf and the reducers read them.
+_GAP_EDGES_MS = tuple(8.0 * 2 ** (k / 2) for k in range(21))
+GAP_EDGES_S = tuple(ms / 1e3 for ms in _GAP_EDGES_MS)
+GAP_KEYS = tuple(f"{round(ms, 2):g}" for ms in _GAP_EDGES_MS) + ("inf",)
+# what the engine counts between two deliveries (PerfRecorder.count)
+GAP_COUNTS = ("prompt_programs", "prompt_tokens", "decode_steps", "swap_ins")
 
 # True only while EngineCore.capture_profile runs a profiler session:
 # the one flag every bracket tests before building a TraceAnnotation
@@ -161,6 +198,105 @@ class _Bracket:
         if self._ann is not None:
             self._ann.__exit__(*exc)
         return False
+
+
+class GapHistogram:
+    """Monotone counts of durations on the fixed edges ``GAP_EDGES_S``
+    (a duration equal to an edge falls under it), served as {upper edge
+    in ms: count}."""
+
+    __slots__ = ("counts",)
+
+    def __init__(self) -> None:
+        self.counts = [0] * len(GAP_KEYS)
+
+    def add(self, seconds: float) -> None:
+        self.counts[bisect.bisect_left(GAP_EDGES_S, seconds)] += 1
+
+    def to_dict(self) -> Dict[str, int]:
+        return dict(zip(GAP_KEYS, self.counts))
+
+
+class GcClock:
+    """The process's garbage collector on the recorder's clock: ONE
+    ``gc.callbacks`` entry (:meth:`install`, at app start-up) that times
+    every collection, whichever thread runs it.  A collection holds the
+    GIL from start to stop and none starts inside another, so one stamp
+    is enough.  ``thread_s`` keeps a running sum for each thread that
+    asked for one (:meth:`watch`: an engine thread, so that a pause can
+    tell its own collections from the event loop's).  While a profile
+    capture runs a collection is also a ``vgt.host.gc`` trace span on
+    the thread that collects.  ``enabled`` false: one flag test."""
+
+    def __init__(self, clock: Any = time.perf_counter) -> None:
+        self._clock = clock
+        self.enabled = True
+        self.installed = False
+        self.gc_s = 0.0
+        self.gc_max_s = 0.0  # the longest single collection
+        self.collections = [0, 0, 0]  # by generation
+        self.seconds = [0.0, 0.0, 0.0]
+        self.thread_s: Dict[int, float] = {}
+        self._t0: Optional[float] = None
+        self._ann = None
+
+    def install(self) -> None:
+        if not self.installed:
+            gc.callbacks.append(self._on_gc)
+            self.installed = True
+
+    def remove(self) -> None:
+        if self.installed:
+            gc.callbacks.remove(self._on_gc)
+            self.installed = False
+            self._t0 = None
+
+    def watch(self) -> int:
+        """Keep a sum of the calling thread's collections; returns the
+        key of ``thread_s`` it is kept under."""
+        ident = threading.get_ident()
+        self.thread_s.setdefault(ident, 0.0)
+        return ident
+
+    def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        if not self.enabled:
+            return
+        if phase == "start":
+            if _capturing:
+                self._ann = _open_annotation(
+                    "vgt.host.gc", lambda: {"gen": info["generation"]}
+                )
+            self._t0 = self._clock()
+            return
+        if self._t0 is None:
+            return  # installed between a start and its stop
+        seconds = self._clock() - self._t0
+        self._t0 = None
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        self.gc_s += seconds
+        self.gc_max_s = max(self.gc_max_s, seconds)
+        self.collections[info["generation"]] += 1
+        self.seconds[info["generation"]] += seconds
+        ident = threading.get_ident()
+        if ident in self.thread_s:
+            self.thread_s[ident] += seconds
+
+    def totals(self) -> Dict[str, Any]:
+        return {
+            "gc_s": round(self.gc_s, 6),
+            "gc_max_s": round(self.gc_max_s, 6),
+            "gc_collections": {
+                str(gen): n for gen, n in enumerate(self.collections)
+            },
+            "gc_seconds": {
+                str(gen): round(s, 6) for gen, s in enumerate(self.seconds)
+            },
+        }
+
+
+GC = GcClock()
 
 
 # boot phases of the process (seconds; the program's part of setup_s):
@@ -236,15 +372,6 @@ class TickProfile:
             "detok": self.detok,
         }
 
-    def to_dict(self) -> Dict[str, Any]:
-        out = {
-            f"{name}_s": round(value, 6)
-            for name, value in self.phases().items()
-        }
-        out["wall_s"] = round(self.wall, 6)
-        out["tokens"] = self.tokens
-        return out
-
 
 class PerfRecorder:
     """Owned by one EngineCore, rebuilt fresh on supervised restart like
@@ -295,6 +422,26 @@ class PerfRecorder:
         # rebuilt the device state (the others edited its rows)
         self.total_membership_changes = 0
         self.total_pipeline_drains = 0
+        self._drain_reasons: "deque[str]" = deque(maxlen=PAUSES_KEPT)
+        # delivery gaps (note_delivery): the histogram of all of them,
+        # the pauses by cause, and the clocks as they stood at the last
+        # delivery (None = no stream has been running since)
+        self._gaps = GapHistogram()
+        self.total_deliveries = 0
+        self.total_delivery_gap_s = 0.0
+        self._pause_totals: Dict[str, Any] = {
+            f"{cause}_{unit}": 0 for cause in PAUSE_CAUSES
+            for unit in ("n", "s")
+        }
+        self._pauses: "deque[Dict[str, Any]]" = deque(maxlen=PAUSES_KEPT)
+        self._mark: Optional[Dict[str, float]] = None
+        self._counts = dict.fromkeys(GAP_COUNTS, 0)
+        self.total_slept_s = 0.0
+        self._gc_thread: Optional[int] = None
+        self._pause_counters = {
+            cause: metrics.ENGINE_PAUSES.labels(cause=cause)
+            for cause in PAUSE_CAUSES
+        }
         # expert-layer and recurrent-state counters (a hybrid spec's
         # decode chunks; empty otherwise and then left out of totals())
         self._moe: Dict[str, int] = {}
@@ -327,6 +474,8 @@ class PerfRecorder:
             )
         if not self.enabled:
             return
+        if self._gc_thread is None:
+            self._gc_thread = GC.watch()
         self._cur = TickProfile(self._clock())
 
     def span(
@@ -397,13 +546,193 @@ class PerfRecorder:
                 ctx_tokens
             )
 
-    def note_membership_change(self, drained: bool) -> None:
+    def note_membership_change(
+        self, drained: bool, reason: Optional[str] = None
+    ) -> None:
         """A tick found the decode batch's membership changed; it either
-        ``drained`` the pipeline and rebuilt the device state or edited
-        the state's rows with every chunk left in flight."""
+        ``drained`` the pipeline and rebuilt the device state (``reason``
+        says why, ``_drain_reason``) or edited the state's rows with
+        every chunk left in flight."""
         self.total_membership_changes += 1
         if drained:
             self.total_pipeline_drains += 1
+            self._drain_reasons.append(reason or "?")
+
+    # ------------------------------------- delivery gaps and pauses
+
+    def count(self, **adds: int) -> None:
+        """What the engine dispatched since the last delivery
+        (``GAP_COUNTS``): a pause record says what stood in its way."""
+        if not self.enabled:
+            return
+        counts = self._counts
+        for name, n in adds.items():
+            counts[name] += n
+
+    def note_sleep(self, seconds: float) -> None:
+        """The engine thread slept on purpose (an armed fault's delay):
+        not CPU time, and not CPU time it lost either."""
+        self.total_slept_s += seconds
+
+    def clear_delivery_clock(self) -> None:
+        """A tick found no stream running: nobody is waiting, so the
+        time until the next delivery is no gap."""
+        self._mark = None
+
+    def _clocks(self, now: float, cur: TickProfile, preemptions: int
+                ) -> Dict[str, float]:
+        """Every clock and count a pause is differenced over, as of
+        ``now``: the closed ticks' sums plus the open tick's part."""
+        mark = {
+            name: self._phase_totals[name] + getattr(cur, name)
+            for name in MEASURED_PHASES
+        }
+        mark.update(self._counts)
+        mark.update(
+            t=now,
+            decode_device_s=self.total_decode_device_s,
+            cpu_s=time.thread_time(),
+            cpu_in_wait_s=(
+                self.total_engine_cpu_in_wait_s + cur.cpu_in_wait
+            ),
+            slept_s=self.total_slept_s,
+            gc_s=GC.thread_s.get(self._gc_thread, 0.0),
+            gc_process_s=GC.gc_s,
+            compile_s=self.total_compile_s,
+            membership_changes=self.total_membership_changes,
+            drains=self.total_pipeline_drains,
+            preemptions=preemptions,
+        )
+        return mark
+
+    def note_delivery(
+        self, steps: int, rows: int, queue_depth: int = 0,
+        preemptions: int = 0,
+    ) -> Optional[Dict[str, Any]]:
+        """A decode readback (``steps`` steps over ``rows`` sequences)
+        handed tokens to at least one running stream, its ``emit`` span
+        just closed.  The time since the last such call is a delivery
+        gap; one of ``PAUSE_S`` or more is a pause, whose record is
+        returned (the engine puts it on the flight recorder and in the
+        log).  ``preemptions`` is the scheduler's running count,
+        ``queue_depth`` the prompts waiting now.  Costs two clock reads
+        and one small dict; disabled, one test."""
+        cur = self._cur
+        if cur is None:
+            return None
+        now = self._clock()
+        mark = self._clocks(now, cur, preemptions)
+        last, self._mark = self._mark, mark
+        if last is None:
+            return None
+        gap = now - last["t"]
+        self._gaps.add(gap)
+        self.total_deliveries += 1
+        self.total_delivery_gap_s += gap
+        metrics.DELIVERY_GAP_SECONDS.observe(gap)
+        if gap < PAUSE_S:
+            return None
+        delta = {key: value - last[key] for key, value in mark.items()}
+        # seconds of device wait a prompt token has cost so far, the
+        # decode chunks' own waits left out
+        pace = (
+            (last["device"] - last["decode_device_s"])
+            / last["prompt_tokens"]
+            if last["prompt_tokens"] else None
+        )
+        return self._pause(gap, delta, pace, steps, rows, queue_depth)
+
+    def _pause(
+        self, gap: float, d: Dict[str, float], pace: Optional[float],
+        steps: int, rows: int, queue_depth: int,
+    ) -> Dict[str, Any]:
+        """The record of one pause from the clocks' growth ``d`` over
+        it, and its ONE cause, first match:
+
+        * ``compile``  a program variant compiled for half of it;
+        * ``prefill``  a prompt program was dispatched in it, the wait
+          for the device plus the dispatches took half of it, and that
+          wait is no more than ``WAVE_SLACK`` times what the gap's
+          prompt tokens take at ``pace`` (an admission wave between two
+          decode readbacks; without a pace yet, any wave will do);
+        * ``gc``       collections took half of it: the engine thread's
+          own, and another thread's (which holds the GIL) as far as the
+          engine thread was off the CPU;
+        * ``off_cpu``  for half of it the engine thread neither ran,
+          waited for the device nor slept on purpose: the GIL behind
+          another thread, or the operating system (``off_cpu_share``);
+        * ``device``   the wait for the device took half of it and no
+          wave explains it: the chip or its runtime stood still;
+        * ``host``     otherwise: the engine's own Python (``phases``
+          says in which bracket).
+        """
+        phases = {name: d[name] for name in MEASURED_PHASES}
+        host = max(0.0, gap - sum(phases.values()))
+        on_cpu = d["cpu_s"] - d["cpu_in_wait_s"]
+        off_cpu = max(
+            0.0,
+            gap - d["device"] - d["readback"] - on_cpu - d["slept_s"],
+        )
+        gc_other = d["gc_process_s"] - d["gc_s"]
+        wave = None if pace is None else d["prompt_tokens"] * pace
+        half = gap / 2
+        if d["compile_s"] >= half:
+            cause = "compile"
+        elif (
+            d["prompt_programs"]
+            and d["device"] + d["dispatch"] >= half
+            and (wave is None or d["device"] <= WAVE_SLACK * wave)
+        ):
+            cause = "prefill"
+        elif d["gc_s"] + min(gc_other, off_cpu) >= half:
+            cause = "gc"
+        elif off_cpu >= half:
+            cause = "off_cpu"
+        elif d["device"] >= half:
+            cause = "device"
+        else:
+            cause = "host"
+        drains = int(d["drains"])
+        record: Dict[str, Any] = {
+            "t": time.time(),
+            "gap_s": round(gap, 6),
+            "cause": cause,
+            "phases": {
+                "host": round(host, 6),
+                **{k: round(v, 6) for k, v in phases.items()},
+            },
+            "decode_device_s": round(d["decode_device_s"], 6),
+            # the rest of ``device``: the wait for prompt programs'
+            # first tokens (_read_first_tokens)
+            "first_token_wait_s": round(
+                max(0.0, d["device"] - d["decode_device_s"]), 6
+            ),
+            # what the gap's prompt tokens take at the pace so far
+            "wave_at_pace_s": None if wave is None else round(wave, 6),
+            "cpu_s": round(d["cpu_s"], 6),
+            "cpu_in_wait_s": round(d["cpu_in_wait_s"], 6),
+            "off_cpu_s": round(off_cpu, 6),
+            "slept_s": round(d["slept_s"], 6),
+            "gc_s": round(d["gc_s"], 6),
+            "gc_other_threads_s": round(gc_other, 6),
+            "compile_s": round(d["compile_s"], 6),
+            **{name: int(d[name]) for name in GAP_COUNTS},
+            "membership_changes": int(d["membership_changes"]),
+            "drains": list(self._drain_reasons)[-drains:] if drains else [],
+            "preemptions": int(d["preemptions"]),
+            "queue_depth": queue_depth,
+            "steps": steps,
+            "rows": rows,
+        }
+        self._pauses.append(record)
+        self._pause_totals[cause + "_n"] += 1
+        self._pause_totals[cause + "_s"] += gap
+        self._pause_counters[cause].inc()
+        return record
+
+    def pauses(self) -> List[Dict[str, Any]]:
+        """The last ``PAUSES_KEPT`` pause records, oldest first."""
+        return list(self._pauses)
 
     def note_moe(self, stats, rows: int, moe_layers: int,
                  linear_layers: int) -> None:
@@ -675,8 +1004,15 @@ class PerfRecorder:
             "engine_cpu_in_wait_s": round(
                 self.total_engine_cpu_in_wait_s, 6
             ),
+            "deliveries": self.total_deliveries,
+            "delivery_gap_s": round(self.total_delivery_gap_s, 6),
+            "delivery_gaps": self._gaps.to_dict(),
+            "pauses": {
+                k: round(v, 6) for k, v in self._pause_totals.items()
+            },
             **{name: 0 for name in REQUEST_TOTALS},
             "boot_seconds": dict(BOOT_SECONDS),
+            "gc": GC.totals(),
         }
         if self.request_totals is not None:
             out.update(self.request_totals())
@@ -691,12 +1027,11 @@ class PerfRecorder:
         """The full /debug/perf payload for one engine core."""
         if not self.enabled:
             return {"enabled": False}
-        last = list(self._ring)[-1:]
         return {
             "enabled": True,
             "window": self.window(),
             "totals": self.totals(),
-            "last_tick": last[0].to_dict() if last else None,
+            "pauses": self.pauses(),
             "compile_ledger": self.compile_ledger(),
             "roofline": (
                 self.roofline.to_dict()
@@ -771,6 +1106,9 @@ class GatewayPerf:
         self.stream_deliveries = 0
         self.stream_tokens_delivered = 0
         self.stream_handoffs = 0
+        # how long those wake-ups waited for the event loop
+        self.handoff_wait_s = 0.0
+        self._handoff_waits = GapHistogram()
         self._detok_ann = None
 
     def ingress_begin(self) -> None:
@@ -877,11 +1215,22 @@ class GatewayPerf:
             self.first_chunk_n += 1
             self.first_chunk_s += now - clock.t_first_token
 
-    def note_handoff(self) -> None:
+    def handoff_armed(self) -> Optional[float]:
+        """The engine thread armed a wake-up of the event loop: the
+        stamp :meth:`note_handoff` takes (None = nothing is timed)."""
+        return self._clock() if self.enabled else None
+
+    def note_handoff(self, t_armed: Optional[float]) -> None:
         """One cross-thread wake-up (engine thread -> event loop) was
-        served: a readback's deliveries fanned out to their streams."""
-        if self.enabled:
-            self.stream_handoffs += 1
+        served: a readback's deliveries fanned out to their streams,
+        this long after the wake-up was armed.  That wait is the event
+        loop's lag on the path the tokens take, once per readback."""
+        if t_armed is None:
+            return
+        wait = self._clock() - t_armed
+        self.stream_handoffs += 1
+        self.handoff_wait_s += wait
+        self._handoff_waits.add(wait)
 
     def totals(self) -> Dict[str, Any]:
         return {
@@ -895,6 +1244,8 @@ class GatewayPerf:
             "stream_deliveries": self.stream_deliveries,
             "stream_tokens_delivered": self.stream_tokens_delivered,
             "stream_handoffs": self.stream_handoffs,
+            "handoff_wait_s": round(self.handoff_wait_s, 6),
+            "handoff_waits": self._handoff_waits.to_dict(),
         }
 
 
@@ -902,6 +1253,15 @@ GATEWAY = GatewayPerf()
 
 
 # ------------------------------------------------------- dp aggregation
+
+def _sum_dicts(dicts: List[Dict[str, Any]]) -> Dict[str, Any]:
+    """Key-wise sum over the union of the keys, first-seen order."""
+    out: Dict[str, Any] = {}
+    for d in dicts:
+        for key, value in d.items():
+            out[key] = out.get(key, 0) + value
+    return out
+
 
 def _weighted_ratio(parts: List[tuple]) -> Optional[float]:
     """Weighted mean of (value, weight) pairs, None-tolerant."""
@@ -963,12 +1323,6 @@ def merge_snapshots(
             ]
         ),
     }
-    agg_compiles: Dict[str, int] = {}
-    for t in totals:
-        for program, count in t["compiles"].items():
-            agg_compiles[program] = (
-                agg_compiles.get(program, 0) + count
-            )
     agg_totals = {
         "ticks": sum(t["ticks"] for t in totals),
         "idle_ticks": sum(t["idle_ticks"] for t in totals),
@@ -981,7 +1335,7 @@ def merge_snapshots(
             )
             for name in PHASES
         },
-        "compiles": agg_compiles,
+        "compiles": _sum_dicts([t["compiles"] for t in totals]),
         "compile_seconds": round(
             sum(t["compile_seconds"] for t in totals), 6
         ),
@@ -996,11 +1350,28 @@ def merge_snapshots(
             name: round(sum(t.get(name, 0) for t in totals), 6)
             for name in ADDITIVE_TOTALS
         },
-        # one process, one boot: replicas share it
+        "delivery_gaps": _sum_dicts(
+            [t.get("delivery_gaps", {}) for t in totals]
+        ),
+        "pauses": {
+            k: round(v, 6) for k, v in _sum_dicts(
+                [t.get("pauses", {}) for t in totals]
+            ).items()
+        },
+        # one process, one boot, one collector: replicas share them
         "boot_seconds": dict(totals[0].get("boot_seconds", {})),
+        "gc": dict(totals[0].get("gc", {})),
     }
     out["window"] = agg_window
     out["totals"] = agg_totals
+    out["pauses"] = sorted(
+        (
+            {"replica": i, **pause}
+            for i, s in enumerate(snaps)
+            for pause in s.get("pauses", ())
+        ),
+        key=lambda pause: pause["t"],
+    )[-PAUSES_KEPT:]
     return out
 
 
@@ -1011,10 +1382,7 @@ def merge_stats(blocks: List[Dict[str, Any]]) -> Dict[str, Any]:
     enabled = [b for b in blocks if b.get("enabled")]
     if not enabled:
         return {"enabled": False}
-    compiles: Dict[str, int] = {}
-    for b in enabled:
-        for program, count in b.get("compiles", {}).items():
-            compiles[program] = compiles.get(program, 0) + count
+    compiles = _sum_dicts([b.get("compiles", {}) for b in enabled])
     wall_of = [
         sum(b["phase_seconds"].values()) for b in enabled
     ]

@@ -2041,7 +2041,11 @@ class EngineCore:
             # resident, so chaos arming cannot stall an idle engine
             # into a pointless restart.
             if faults.is_active() and self.scheduler.has_work():
+                t_probe = time.perf_counter()
                 faults.check("stall")
+                # an armed delay slept here: a pause it makes is the
+                # host's, not CPU time the thread lost
+                self.perf.note_sleep(time.perf_counter() - t_probe)
                 if not self._running:
                     # the watchdog declared this core stalled while the
                     # armed delay slept: containment already swept the
@@ -2085,7 +2089,9 @@ class EngineCore:
         # and _dispatch_chunk open their own
         active = self._running_seqs()
         changed, reason = False, None
-        if active:
+        if not active:
+            self.perf.clear_delivery_clock()
+        else:
             with self.perf.span("schedule"):
                 signature = self._decode_signature(active)
                 changed = signature != self._decode_signature_cache
@@ -2102,7 +2108,9 @@ class EngineCore:
         # (which takes every row's last token from host state)
         self._read_first_tokens(wave)
         if changed:
-            self.perf.note_membership_change(drained=reason is not None)
+            self.perf.note_membership_change(
+                drained=reason is not None, reason=reason
+            )
             worked = True
             if reason is not None:
                 # every other change: all in-flight chunks must be folded
@@ -2631,6 +2639,7 @@ class EngineCore:
         t0 = time.perf_counter()
         self._beat("swap_in", batch=1)
         n = self.kv_swap.swap_in_seq(seq, seq.pages)
+        self.perf.count(swap_ins=1)
         if self.flight.enabled:
             self.flight.on_admit(
                 seq, bucket=0, cached_len=seq.total_len - 1
@@ -2955,6 +2964,9 @@ class EngineCore:
                 **kw, **self._state_args(slots),
             )
             self._set_cache(cache)
+        self.perf.count(
+            prompt_programs=1, prompt_tokens=int(lens[: len(plans)].sum())
+        )
         return out
 
     @engine_thread_only
@@ -3329,6 +3341,7 @@ class EngineCore:
             if more:
                 self.state, moe_stats = more
         self._step_counter += chunk
+        self.perf.count(decode_steps=chunk)
         # snapshot preempt_count as an epoch: a sequence preempted while
         # this chunk is in flight (and possibly re-admitted before the
         # readback is processed) must NOT receive the stale tokens
@@ -3467,9 +3480,29 @@ class EngineCore:
                 self.total_decode_tokens += delivered
                 emit.note(tokens=delivered)
             self.perf.note_tokens(delivered)
+            if delivered:
+                self._note_delivery(chunk, len(seqs))
             self.total_steps += chunk
             if not drain:
                 break
+
+    @engine_thread_only
+    def _note_delivery(self, steps: int, rows: int) -> None:
+        """A decode readback handed tokens to running streams: close
+        the delivery gap (observability/perf.py).  A gap long enough to
+        be a PAUSE comes back as a record with its one cause: it goes
+        on the flight recorder (so /debug/flight and a crash snapshot
+        hold it) and, unless an admission wave made it, into the log."""
+        pause = self.perf.note_delivery(
+            steps, rows,
+            queue_depth=len(self.scheduler.waiting),
+            preemptions=self.scheduler.total_preemptions,
+        )
+        if pause is None:
+            return
+        self.flight.record_tick("pause", **pause)
+        if pause["cause"] != "prefill":
+            logger.warning("engine_pause", extra={"extra_data": pause})
 
     @engine_thread_only
     def _record_decode_step(
@@ -3531,6 +3564,7 @@ class EngineCore:
         """
         active = self._running_seqs()
         if not active:
+            self.perf.clear_delivery_clock()
             return False
         S = self.spec_k + 1
         if not self.scheduler.prepare_decode(active, horizon=S):
@@ -3663,6 +3697,7 @@ class EngineCore:
         # device for the next round (None without penalties)
         rows["counts"] = counts_out
         self._step_counter += 1
+        self.perf.count(decode_steps=1)
         # perf split of the existing sync (see _process_chunks)
         with self.perf.span("device_wait") as wait:
             jax.block_until_ready((model_toks, accepted))
@@ -3728,6 +3763,8 @@ class EngineCore:
             self.total_decode_tokens += delivered
             emit.note(tokens=delivered)
         self.perf.note_tokens(delivered)
+        if delivered:
+            self._note_delivery(1, len(active))
         self.total_steps += 1
         return True
 

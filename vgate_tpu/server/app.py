@@ -49,7 +49,12 @@ from vgate_tpu.errors import (
 )
 from vgate_tpu.lifecycle import CancelToken, DrainController
 from vgate_tpu.logging_config import get_logger, setup_logging
-from vgate_tpu.observability.perf import GATEWAY, note_boot, process_age_s
+from vgate_tpu.observability.perf import (
+    GATEWAY,
+    GC,
+    note_boot,
+    process_age_s,
+)
 from vgate_tpu.observability.reqtrace import RequestMeta
 from vgate_tpu.runtime.journal import (
     PENDING as _JOURNAL_PENDING,
@@ -2334,9 +2339,12 @@ async def _on_startup(app: web.Application) -> None:
     # Model load can take minutes; do it off the event loop.
     engine = await loop.run_in_executor(None, lambda: VGTEngine(config))
     app["engine"] = engine
-    GATEWAY.enabled = bool(
+    GATEWAY.enabled = GC.enabled = bool(
         config.observability.enabled and config.observability.perf_enabled
     )
+    # the garbage collector's clock (/debug/perf totals.gc): one
+    # gc.callbacks entry for the life of the app
+    GC.install()
     # /debug/perf totals.boot_seconds: process start -> engine ready
     age = process_age_s()
     if age is not None:
@@ -2456,6 +2464,7 @@ async def _on_cleanup(app: web.Application) -> None:
     engine: Optional[VGTEngine] = app.get("engine")
     if engine is not None:
         engine.shutdown()
+    GC.remove()
     journal: Optional[RequestJournal] = app.get("journal")
     if journal is not None:
         journal.close()
