@@ -7,6 +7,7 @@ blockwise twin).  Run on hardware:
 
     python benchmarks/bench_kernels.py
     python benchmarks/bench_kernels.py sample_edits   # that probe alone
+    python benchmarks/bench_kernels.py decode_cells [CELL ...]
 
 Prints one JSON line per (kernel, shape) with median step times and the
 speedup.  CPU-safe fallback: refuses to run (the kernels need a TPU).
@@ -27,6 +28,8 @@ sys.path.insert(
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
+
+from vgate_tpu.utils.math import cdiv  # noqa: E402
 
 
 LOOP = 8  # op invocations fused into one program
@@ -116,15 +119,22 @@ def bench_paged_decode(B=128, H=12, KV=2, hd=128, ps=16, ctx=512):
     }
 
 
-# The four cells' decode shapes (BENCHMARK.json), as the traced runs hold
-# them (PERF.md section 5): (KV, G, hd), live slots of 256, and the live
-# streams' lengths.  A dead slot has length 0 (models/decoder.py hands the
-# kernel that for an inactive row).
+# The cells' decode shapes (BENCHMARK.json), as the traced runs hold them
+# (PERF.md section 5): (KV, G, hd), slots, context, live slots, and the
+# live streams' lengths.  A dead slot has length 0 (models/decoder.py
+# hands the kernel that for an inactive row).  `latent` > 0: ONE pool of
+# hd-wide rows whose first `latent` lanes are the value (mistral's).
 DECODE_CELLS = {
-    "qwen2.5-1.5b.decode-heavy": ((2, 6, 128), 251, (80, 720)),
-    "qwen2.5-1.5b.chat": ((2, 6, 128), 55, (70, 690)),
-    "qwen2.5-7b-l14.prefill-heavy": ((4, 7, 128), 54, (1040, 1880)),
-    "qwen3-next-80b-a3b-l8e128.decode-heavy": ((2, 8, 256), 251, (80, 720)),
+    "qwen2.5-1.5b.decode-heavy": ((2, 6, 128), 256, 2048, 251, (80, 720), 0),
+    "qwen2.5-1.5b.chat": ((2, 6, 128), 256, 2048, 55, (70, 690), 0),
+    "qwen2.5-7b-l14.prefill-heavy": (
+        (4, 7, 128), 256, 2048, 54, (1040, 1880), 0),
+    "qwen3-next-80b-a3b-l8e128.decode-heavy": (
+        (2, 8, 256), 256, 2048, 251, (80, 720), 0),
+    "nemotron-3-super-120b-a12b-l11e128.decode-heavy": (
+        (2, 16, 128), 192, 2048, 188, (80, 720), 0),
+    "mistral-small-4-119b-l4e32.long-prompt": (
+        (1, 32, 384), 256, 8192, 256, (4200, 7900), 256),
 }
 with open(os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -133,54 +143,91 @@ with open(os.path.join(
     HBM_BYTES_PER_S = json.load(_fh)["TPU v5 lite"]["hbm_bytes_per_s"]
 
 
-def decode_cell_case(name, B=256, ps=32, ctx=2048, layers=2, dead_len=0,
-                     seed=0):
-    """Inputs of one decode-kernel launch at a cell's shape: a stacked
-    bf16 pool, scattered pages, `live` streams at random slots with
+def decode_cell_case(name, ps=32, layers=2, dead_len=0, seed=0):
+    """Inputs of one decode-kernel launch at a cell's shape, `(q, pools,
+    page_tables, seq_lens)`: stacked bf16 pools (K and V, or the one
+    latent pool), scattered pages, `live` streams at random slots with
     lengths uniform over the cell's range."""
-    (KV, G, hd), live, (lo, hi) = DECODE_CELLS[name]
+    (KV, G, hd), B, ctx, live, (lo, hi), latent = DECODE_CELLS[name]
     rng = np.random.default_rng(seed)
     pages_per_seq = ctx // ps
     P = 1 + B * pages_per_seq
-    key = jax.random.PRNGKey(seed)
-    kq, kk, kv_ = jax.random.split(key, 3)
+    kq, *kp = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(kq, (B, KV * G, hd), jnp.bfloat16)
-    k_pages = jax.random.normal(kk, (layers, KV, P, ps, hd), jnp.bfloat16)
-    v_pages = jax.random.normal(kv_, (layers, KV, P, ps, hd), jnp.bfloat16)
+    pools = tuple(
+        jax.random.normal(k, (layers, KV, P, ps, hd), jnp.bfloat16)
+        for k in kp[: 1 if latent else 2]
+    )
     page_tables = jnp.asarray(
         (rng.permutation(P - 1)[: B * pages_per_seq] + 1).reshape(B, -1),
         jnp.int32,
     )
     lens = np.full((B,), dead_len, np.int64)
     lens[rng.permutation(B)[:live]] = rng.integers(lo, hi + 1, size=live)
-    return q, k_pages, v_pages, page_tables, jnp.asarray(lens, jnp.int32)
+    return q, pools, page_tables, jnp.asarray(lens, jnp.int32)
+
+
+def decode_cell_kernel(name, **kw):
+    """The cell's decode kernel as one layer's cache work, `(q, pools,
+    page_tables, seq_lens, news, layer) -> (attention, pools)`: it
+    writes the slots' new rows (`news`, one a pool) and attends to them.
+    `kw` goes to the kernel (`items`, `hollow`)."""
+    from vgate_tpu.ops.pallas.paged_attention import (
+        mla_decode_attention_pallas, paged_decode_attention_pallas,
+    )
+
+    latent = DECODE_CELLS[name][-1]
+
+    def plain(q, pools, pt, sl, news, layer):
+        out, *pools = paged_decode_attention_pallas(
+            q, *pools, pt, sl, layer=layer, k_new=news[0], v_new=news[1],
+            **kw
+        )
+        return out, tuple(pools)
+
+    def absorbed(q, pools, pt, sl, news, layer):
+        out, pool = mla_decode_attention_pallas(
+            q, pools[0], pt, sl, layer, news[0][:, 0], v_width=latent,
+            scale=q.shape[-1] ** -0.5, **kw
+        )
+        # the value's width back to the query's: the probe chains layers
+        return jnp.pad(out, ((0, 0), (0, 0), (0, q.shape[-1] - latent))), (
+            pool,)
+
+    return absorbed if latent else plain
+
+
+def decode_cell_news(case, seed=7):
+    """A new row a slot a pool, [B, KV, hd] each."""
+    q, pools = case[:2]
+    KV, hd = pools[0].shape[1], pools[0].shape[-1]
+    return tuple(jax.random.normal(
+        jax.random.PRNGKey(seed), (len(pools), q.shape[0], KV, hd),
+        pools[0].dtype,
+    ))
 
 
 def time_decode_layer(layer_fn, case, loop=28):
-    """Median seconds of ONE layer's cache work, `layer_fn(q, k_pages,
-    v_pages, page_tables, seq_lens, k_new, v_new, layer) -> (attention,
-    k_pages, v_pages)`: `loop` of them chained in one program (a decode
-    step's layers) with the pools on the carry, layer index alternating."""
-    q, k_pages, v_pages, page_tables, seq_lens = case
+    """Median seconds of ONE layer's cache work (`decode_cell_kernel`'s
+    signature): `loop` of them chained in one program (a decode step's
+    layers) with the pools on the carry, layer index alternating."""
+    q, pools, page_tables, seq_lens = case
     # the runs donate their pools: the case keeps its own
-    k_pages, v_pages = jnp.copy(k_pages), jnp.copy(v_pages)
-    KV, hd = k_pages.shape[1], k_pages.shape[-1]
-    k_new, v_new = jax.random.normal(
-        jax.random.PRNGKey(7), (2, q.shape[0], KV, hd), k_pages.dtype
-    )
+    pools = tuple(jnp.copy(pool) for pool in pools)
+    news = decode_cell_news(case)
 
-    @functools.partial(jax.jit, donate_argnums=(1, 2))
-    def run(q, k_pages, v_pages, page_tables, seq_lens, k_new, v_new):
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def run(q, pools, page_tables, seq_lens, news):
         def body(carry, i):
-            out, kp, vp = carry
-            out, kp, vp = layer_fn(
-                q + 0 * out.astype(q.dtype), kp, vp, page_tables, seq_lens,
-                k_new, v_new, i % kp.shape[0],
+            out, pools = carry
+            out, pools = layer_fn(
+                q + 0 * out.astype(q.dtype), pools, page_tables, seq_lens,
+                news, i % pools[0].shape[0],
             )
-            return (out.astype(jnp.float32), kp, vp), None
+            return (out.astype(jnp.float32), pools), None
 
         carry, _ = jax.lax.scan(
-            body, (jnp.zeros(q.shape, jnp.float32), k_pages, v_pages),
+            body, (jnp.zeros(q.shape, jnp.float32), pools),
             jnp.arange(loop, dtype=jnp.int32),
         )
         return carry
@@ -188,83 +235,113 @@ def time_decode_layer(layer_fn, case, loop=28):
     times = []
     for _ in range(12):
         t0 = time.perf_counter()
-        _, k_pages, v_pages = _sync(run(
-            q, k_pages, v_pages, page_tables, seq_lens, k_new, v_new
-        ))
+        _, pools = _sync(run(q, pools, page_tables, seq_lens, news))
         times.append(time.perf_counter() - t0)
     return float(np.median(times[2:])) / loop
 
 
-def bench_decode_cells():
-    """A decode layer's cache work at the cells' shapes, three ways: the
-    kernel alone (reads only), the token's K and V scattered into the
-    pool and then the kernel (what a step did until PR 30), and the
-    kernel that writes the token's page itself (what it does now).  The
-    share of the HBM roofline counts the LIVE tokens' bytes read (what
-    `kernel.decode_attn_roofline_live` counts in a traced run)."""
+def decode_trips(seq_lens, chunk_tokens, block_slots, items):
+    """(work-list items, loop trips, trips that hold `items` items) of one
+    launch: a function of the lengths alone.  A program's list is the
+    live chunks of its block of slots, a trip takes `items` of them, and
+    an odd last item is a trip of its own."""
+    chunks = cdiv(np.asarray(seq_lens, np.int64), chunk_tokens)
+    per_program = [
+        int(chunks[i:i + block_slots].sum())
+        for i in range(0, len(chunks), block_slots)
+    ]
+    return (
+        sum(per_program), sum(cdiv(n, items) for n in per_program),
+        sum(n // items for n in per_program),
+    )
+
+
+def bench_decode_cells(cells=None):
+    """A decode layer's cache work (the kernel that writes the token's
+    page itself) at the cells' shapes, with ONE and with TWO items of the
+    work list a loop trip: µs a launch, the share of the HBM roofline
+    over the LIVE tokens' bytes read (what
+    `kernel.decode_attn_roofline_live` counts in a traced run), the
+    launch's items and trips with the share of trips that hold two, and
+    the hollow kernel's time (the trips' bookkeeping alone: no copy, no
+    product).  `rule_items` is what `_decode_sizes` picks for the shape.
+    Two items must give one item's bits, and on plain pools one item's
+    must be the scatter's (what a step did until PR 30)."""
     from vgate_tpu.models.decoder import decode_attn_inputs
     from vgate_tpu.ops.kv_quant import kv_write_tokens
     from vgate_tpu.ops.pallas.paged_attention import (
-        paged_decode_attention_pallas as kernel,
+        _decode_sizes, paged_decode_attention_pallas,
     )
 
-    def read_only(q, kp, vp, pt, sl, k_new, v_new, layer):
-        return kernel(q, kp, vp, pt, sl, layer=layer), kp, vp
-
-    def scatter_then_kernel(q, kp, vp, pt, sl, k_new, v_new, layer):
+    def scatter_then_kernel(q, pools, pt, sl, news, layer):
         # a dead slot's token goes to trash page 0, as in a decode step
         _, page_ids, page_off = decode_attn_inputs(
-            jnp.maximum(sl - 1, 0), pt, sl > 0, kp.shape[-2]
+            jnp.maximum(sl - 1, 0), pt, sl > 0, pools[0].shape[-2]
         )
-        kp = kv_write_tokens(kp, page_ids, page_off, k_new, layer=layer)
-        vp = kv_write_tokens(vp, page_ids, page_off, v_new, layer=layer)
-        return kernel(q, kp, vp, pt, sl, layer=layer), kp, vp
-
-    def kernel_writes(q, kp, vp, pt, sl, k_new, v_new, layer):
-        return kernel(
-            q, kp, vp, pt, sl, layer=layer, k_new=k_new, v_new=v_new
+        pools = tuple(
+            kv_write_tokens(pool, page_ids, page_off, new, layer=layer)
+            for pool, new in zip(pools, news)
         )
+        return paged_decode_attention_pallas(
+            q, *pools, pt, sl, layer=layer, items=1
+        ), pools
 
-    @jax.jit
-    def same_bits(case, k_new, v_new):
-        """The kernel that writes against scatter-then-kernel, one
-        layer: attention and every page but the trash page, bit for bit
-        (a dead slot's token goes to the trash page only by scatter)."""
-        want = scatter_then_kernel(*case, k_new, v_new, 1)
-        got = kernel_writes(*case, k_new, v_new, 1)
+    @functools.partial(jax.jit, static_argnums=(0, 1))
+    def same_bits(got_fn, want_fn, case, news):
+        """One layer, attention and every page but the trash page, bit
+        for bit (a dead slot's token goes there only by scatter)."""
+        got, want = got_fn(*case, news, 1), want_fn(*case, news, 1)
         return [
             jnp.array_equal(got[0], want[0]),
             *(jnp.array_equal(g[:, :, 1:], w[:, :, 1:])
-              for g, w in zip(got[1:], want[1:])),
+              for g, w in zip(got[1], want[1])),
         ]
 
-    for name, ((KV, G, hd), live, _) in DECODE_CELLS.items():
+    for name in cells or DECODE_CELLS:
+        (KV, G, hd), B, ctx, live, _, latent = DECODE_CELLS[name]
         case = decode_cell_case(name)
-        new = jax.random.normal(
-            jax.random.PRNGKey(11), (2, case[0].shape[0], KV, hd),
-            jnp.bfloat16,
-        )
-        if not all(map(bool, same_bits(case, *new))):
+        news = decode_cell_news(case, seed=11)
+        one, two = (decode_cell_kernel(name, items=n) for n in (1, 2))
+        if not all(map(bool, same_bits(two, one, case, news))):
+            raise SystemExit(f"{name}: two items a trip differ from one")
+        if not latent and not all(
+            map(bool, same_bits(one, scatter_then_kernel, case, news))
+        ):
             raise SystemExit(f"{name}: the kernel's write differs from "
                              "the scatter's")
-        live_tokens = int(np.asarray(case[4]).sum())
-        live_bytes = live_tokens * 2 * KV * hd * 2
+        lens, ps = np.asarray(case[3]), case[1][0].shape[-2]
+        CP, BS, rule = _decode_sizes(
+            B, KV, G, hd, ps, ctx // ps, jnp.bfloat16, jnp.bfloat16,
+            pools=len(case[1]),
+        )
+        live_bytes = int(lens.sum()) * len(case[1]) * KV * hd * 2
         line = {
-            "kernel": "paged_decode_attention",
+            "kernel": "mla_decode_attention" if latent
+            else "paged_decode_attention",
             "cell": name,
-            "shape": f"B256 KV{KV} G{G} hd{hd} ps32, {live} live",
-            "live_tokens": live_tokens,
+            "shape": f"B{B} KV{KV} G{G} hd{hd} ps{ps} ctx{ctx}, {live} live",
+            "live_tokens": int(lens.sum()),
+            "chunk_tokens": CP * ps, "block_slots": BS, "rule_items": rule,
+            "work_items": decode_trips(lens, CP * ps, BS, 1)[0],
         }
-        for label, fn in (
-            ("kernel_alone", read_only),
-            ("scatter_then_kernel", scatter_then_kernel),
-            ("kernel_writes", kernel_writes),
-        ):
-            seconds = time_decode_layer(fn, case)
-            line[f"{label}_us"] = round(seconds * 1e6, 1)
-            line[f"{label}_hbm_roofline_pct"] = round(
-                100 * live_bytes / HBM_BYTES_PER_S / seconds, 1
+        for n, kernel in ((1, one), (2, two)):
+            _, trips, full = decode_trips(lens, CP * ps, BS, n)
+            seconds = time_decode_layer(kernel, case)
+            hollow = time_decode_layer(
+                decode_cell_kernel(name, items=n, hollow=True), case
             )
+            line.update({
+                f"items_{n}_trips": trips,
+                f"items_{n}_full_trip_pct": round(100 * full / trips, 1),
+                f"items_{n}_us": round(seconds * 1e6, 1),
+                f"items_{n}_hbm_roofline_pct": round(
+                    100 * live_bytes / HBM_BYTES_PER_S / seconds, 1
+                ),
+                f"items_{n}_hollow_us": round(hollow * 1e6, 1),
+            })
+        line["items_2_gain_pct"] = round(
+            100 * (1 - line["items_2_us"] / line["items_1_us"]), 1
+        )
         yield line
 
 
@@ -524,6 +601,10 @@ def main() -> None:
         )
     if sys.argv[1:] == ["sample_edits"]:
         for line in bench_sample_edits():
+            print(json.dumps(line), flush=True)
+        return
+    if sys.argv[1:2] == ["decode_cells"]:
+        for line in bench_decode_cells(sys.argv[2:]):
             print(json.dumps(line), flush=True)
         return
     print(json.dumps(bench_paged_decode()))

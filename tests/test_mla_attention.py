@@ -107,6 +107,41 @@ def test_decode_kernel_equals_its_twin_on_a_ragged_batch(dtype, tol):
                                rtol=tol, atol=tol)
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "float32"])
+def test_decode_kernel_two_items_a_trip_give_one_items_bits(dtype):
+    """The latent form with `write`, a loop trip serving TWO items of the
+    work list against one (``_decode_kernel``): attention and the written
+    pool bit for bit.  A chunk is 256 rows here: slots of 2, 0, 1, 2, 1,
+    2 and 1 items, so pairs inside a slot, pairs across two slots, both
+    items a slot's last, and an odd ninth item."""
+    L, ps, W, H, V = 2, 8, 384, 8, 256
+    lens = jnp.array([300, 0, 8, 257, 1, 290, 5], jnp.int32)
+    B, pages_per_seq = len(lens), 40
+    P = 1 + B * pages_per_seq
+    rng = np.random.default_rng(1)
+    normal = lambda *s: jnp.asarray(rng.standard_normal(s), dtype)
+    pages, q, new = normal(L, 1, P, ps, W), normal(B, H, W), normal(B, W)
+    table = jnp.asarray(
+        rng.permutation(np.arange(1, P)).reshape(B, pages_per_seq), jnp.int32)
+    one, two = (
+        mla_decode_attention_pallas(
+            q, pages, table, lens, jnp.int32(1), new, v_width=V, scale=0.05,
+            interpret=True, items=items)
+        for items in (1, 2))
+    for got, want in zip(two, one):
+        np.testing.assert_array_equal(
+            np.array(got, np.float32), np.array(want, np.float32))
+    # and the rows are where a scatter would have put them
+    pos = jnp.maximum(lens - 1, 0)
+    page_ids = jnp.where(lens > 0, table[jnp.arange(B), pos // ps], 0)
+    written = kv_write_tokens(
+        pages, page_ids, pos % ps, new[:, None], layer=jnp.int32(1))
+    np.testing.assert_array_equal(
+        np.array(two[1], np.float32)[:, :, 1:],
+        np.array(written, np.float32)[:, :, 1:])
+
+
 def test_yarn_frequencies_on_hand_checked_cases():
     """theta 10,000, 8 dimensions, factor 4 over an original maximum of
     32: frequency i = 10000^(-i/4) makes 32 f / 2 pi rotations in 32

@@ -703,7 +703,7 @@ def _blocks_of_32(pattern, seed):
     B = 70  # three programs, the last one mostly outside the batch
     assert _decode_sizes(
         B, 2, 8, 256, 16, 8, jnp.float32, jnp.float32
-    ) == (8, 32)
+    ) == (8, 32, 2)
     lens = [pattern.get(b, 0) for b in range(B)]
     return make_case(
         B=B, H=16, KV=2, hd=256, ps=16, pages_per_seq=8, lens=lens,
@@ -785,6 +785,144 @@ def test_decode_kernel_layer_indexed_ragged_batch(write):
         q, kL, vL, page_tables, seq_lens, write=write, layer=jnp.asarray(1)
     )
     _live_rows_match(got, expect, seq_lens)
+
+
+# slot -> length; a chunk (one item of a program's work list) is 256
+# tokens and a program serves 64 slots at this geometry
+_TRIP_CASES = {
+    # (s0c0 s0c1) (s0c2 s5c0) (s9c0 s9c1) (s20c0): seven items, the last
+    # a trip of its own; the other program has nothing to do
+    "odd-item-count": ({0: 700, 5: 100, 9: 300, 20: 50}, {}),
+    # one program with one item, one with none
+    "one-item-program": ({40: 77}, {}),
+    # live slots among dead ones in both programs, chunk edges (256, 257)
+    "dead-slots-between-live": (
+        {0: 5, 3: 256, 4: 1, 9: 257, 31: 33, 63: 64, 65: 17, 69: 90}, {}),
+    # (s2c0 s2c1) (s2c2 s3c0) (s3c1 s4c0): a slot ends in a trip's first
+    # item and another starts in its second, twice
+    "pair-spans-two-slots": ({2: 600, 3: 300, 4: 10}, {}),
+    # twelve one-chunk slots: every trip ends two slots (two staged
+    # pages, two stores), twelve writes over four staging pages
+    "both-items-last-chunks": ({b: 1 + 3 * b for b in range(12)}, {}),
+    # first chunks 2, 1, 0 and 2: the chunks below a window are no items
+    "window-first-chunk-not-0": (
+        {0: 700, 1: 513, 2: 40, 7: 768}, {"window": 100}),
+    "layer-pools": ({0: 700, 5: 100, 9: 300, 20: 50, 66: 257}, {"layer": 1}),
+    "float32-pools": ({1: 300, 2: 0, 3: 31, 64: 513}, {"dtype": jnp.float32}),
+}
+
+
+def _trip_case(case):
+    """(arguments, keyword arguments, slot -> length, new K rows) of a
+    decode-kernel call that writes, for a case of _TRIP_CASES."""
+    from vgate_tpu.ops.pallas.paged_attention import _decode_sizes
+
+    pattern, kw = _TRIP_CASES[case]
+    kw = dict(kw)
+    dtype, layer = kw.pop("dtype", jnp.bfloat16), kw.pop("layer", None)
+    B, KV, G, hd, ps, pages_per_seq = 70, 2, 4, 128, 32, 24
+    assert _decode_sizes(
+        B, KV, G, hd, ps, pages_per_seq, dtype, dtype
+    )[:2] == (8, 64)
+    q, k_pages, v_pages, page_tables, seq_lens = (
+        x.astype(dtype) if x.dtype == jnp.float32 else x
+        for x in make_case(
+            B=B, H=KV * G, KV=KV, hd=hd, ps=ps, pages_per_seq=pages_per_seq,
+            lens=[pattern.get(b, 0) for b in range(B)], seed=31,
+        )
+    )
+    if layer is not None:
+        k_pages, v_pages = (
+            jnp.stack([pool * 0.5, pool, pool * 2.0])
+            for pool in (k_pages, v_pages)
+        )
+        kw["layer"] = jnp.asarray(layer)
+    if "window" in kw:
+        kw["window"] = jnp.asarray(kw["window"], jnp.int32)
+    rng = np.random.default_rng(32)
+    for name in ("k_new", "v_new"):
+        kw[name] = jnp.asarray(rng.normal(size=(B, KV, hd)), dtype)
+    return (q, k_pages, v_pages, page_tables, seq_lens), kw, pattern
+
+
+@pytest.mark.fast  # tier-1: what a served decode step's trips hold
+@pytest.mark.parametrize("case", list(_TRIP_CASES))
+def test_decode_kernel_two_items_a_trip_give_one_items_bits(case):
+    """A loop trip that serves TWO items of the work list against the
+    kernel whose trip serves one (the program as it was): attention and
+    both written pools BIT for bit, in the served arithmetic (bf16 pages,
+    float32 accumulation, the softmax weights as two bf16 terms)."""
+    args, kw, pattern = _trip_case(case)
+    one, two = (
+        paged_decode_attention_pallas(*args, interpret=True, items=items, **kw)
+        for items in (1, 2)
+    )
+    for got, want in zip(two, one):
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32), np.asarray(want, np.float32)
+        )
+    # and the row is where a scatter would have put it
+    pool, page_tables = np.asarray(two[1], np.float32), args[3]
+    if "layer" in kw:
+        pool = pool[int(kw["layer"])]
+    ps = pool.shape[-2]
+    for b, length in pattern.items():
+        if length:
+            page = int(page_tables[b, (length - 1) // ps])
+            np.testing.assert_array_equal(
+                pool[:, page, (length - 1) % ps],
+                np.asarray(kw["k_new"][b], np.float32),
+            )
+
+
+_BLOCKING_WAITS = """
+import sys
+sys.path[:0] = {paths!r}
+import jax
+from jax.experimental.pallas import tpu as pltpu
+from test_pallas_kernels import _trip_case, paged_decode_attention_pallas
+args, kw, _ = _trip_case({case!r})
+jax.block_until_ready(paged_decode_attention_pallas(
+    *args, interpret=pltpu.InterpretParams(), **{options!r}, **kw))
+"""
+
+
+@pytest.mark.fast
+@pytest.mark.parametrize(
+    "case, options",
+    [
+        ("odd-item-count", {"items": 2}),
+        ("both-items-last-chunks", {"items": 2}),
+        ("layer-pools", {"items": 1}),
+        ("odd-item-count", {"items": 2, "hollow": True}),
+        ("both-items-last-chunks", {"items": 1, "hollow": True}),
+    ],
+    ids=["two-items", "two-items-both-last", "one-item", "hollow-two-items",
+         "hollow-one-item"],
+)
+def test_decode_kernel_waits_for_no_more_than_it_started(case, options):
+    """The plain interpreter does not block on a DMA semaphore, the chip
+    does: a wait for bytes that no copy brings hangs it (the hollow
+    kernel's first version waited for staging pages it never sent, and
+    cost a chip call).  The TPU interpreter blocks as the chip does, so
+    the kernel runs under it in a process of its own, which is killed if
+    it does not come back."""
+    import os
+    import subprocess
+    import sys
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    code = _BLOCKING_WAITS.format(
+        paths=[os.path.dirname(here), here], case=case, options=options
+    )
+    try:
+        done = subprocess.run(
+            [sys.executable, "-c", code], timeout=300, capture_output=True,
+            text=True, env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        )
+    except subprocess.TimeoutExpired:
+        pytest.fail("the kernel waits for a copy it never started")
+    assert done.returncode == 0, done.stderr[-2000:]
 
 
 def test_decode_kernel_bf16_pages_keep_float32_softmax_weights():

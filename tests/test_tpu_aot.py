@@ -137,12 +137,62 @@ def test_decode_kernel_compiles_at_the_cells_shapes_for_v5e(v5e, geom):
     sizes = _decode_sizes(
         256, KV, H // KV, hd, PAGE, 64, jnp.bfloat16, jnp.bfloat16
     )
-    assert sizes == {
+    assert sizes[:2] == {
         GEOM_1P5B: (8, 64), GEOM_7B: (8, 32), GEOM_QWEN3_NEXT: (8, 32),
     }[geom]
     _compile_decode_kernel(
         _abstract(v5e), geom, B=256, pages_per_seq=64, write=True
     )
+
+
+# what serves a decode step in each of the benchmark's configurations,
+# (slots, KV heads, query heads a KV head, head size, pages a slot, pools),
+# and what `_decode_sizes` reads off it: (pages a chunk, slots a program,
+# work-list items a loop trip)
+SERVED_DECODE_SHAPES = {
+    "qwen2.5-1.5b": ((256, 2, 6, 128, 64, 2), (8, 64, 2)),
+    "qwen2.5-7b-l14": ((256, 4, 7, 128, 64, 2), (8, 32, 1)),
+    "qwen3-next-80b-a3b-l8e128": ((256, 2, 8, 256, 64, 2), (8, 32, 2)),
+    "nemotron-3-super-120b-a12b-l11e128": (
+        (192, 2, 16, 128, 64, 2), (8, 64, 2)),
+    # the latent pool: one "head" of 384-lane rows under 32 query heads
+    "mistral-small-4-119b-l4e32": ((256, 1, 32, 384, 256, 1), (8, 21, 2)),
+}
+
+
+@pytest.mark.parametrize("config", list(SERVED_DECODE_SHAPES))
+def test_decode_sizes_at_the_served_shapes(config):
+    """A change of the rule shows here.  Two items a trip where an item
+    holds at most two KV heads' products (PERF.md section 6, PR 37: the
+    probe at these shapes); the 7B's four keep one, the program as it
+    was."""
+    from vgate_tpu.ops.pallas.paged_attention import _decode_sizes
+
+    (B, KV, G, hd, pages_per_seq, pools), sizes = SERVED_DECODE_SHAPES[config]
+    assert _decode_sizes(
+        B, KV, G, hd, PAGE, pages_per_seq, jnp.bfloat16, jnp.bfloat16,
+        pools=pools,
+    ) == sizes
+
+
+@pytest.mark.parametrize("items", [1, 2])
+def test_latent_decode_kernel_compiles_at_the_cells_shape_for_v5e(v5e, items):
+    """`mla_decode_attention_pallas` as the mistral cell launches it (256
+    slots of 8,192 tokens, 32 heads over 384-lane rows, the new row
+    written by the kernel), with one and with two items a loop trip."""
+    from vgate_tpu.ops.pallas.paged_attention import (
+        mla_decode_attention_pallas,
+    )
+
+    A = _abstract(v5e)
+    B, _, H, W, pages_per_seq, _ = SERVED_DECODE_SHAPES[
+        "mistral-small-4-119b-l4e32"][0]
+    mla_decode_attention_pallas.lower(
+        A((B, H, W), jnp.bfloat16), A((4, 1, 1025, PAGE, W), jnp.bfloat16),
+        A((B, pages_per_seq), jnp.int32), A((B,), jnp.int32),
+        A((), jnp.int32), A((B, W), jnp.bfloat16), v_width=256, scale=0.05,
+        items=items,
+    ).compile()
 
 
 def test_gated_delta_step_kernel_compiles_for_v5e(v5e):
@@ -403,6 +453,44 @@ def test_decode_chunk_edits_logits_in_place_on_v5e(v5e):
         assert not (logits in result and result.rstrip().endswith("copy")), (
             line[:300]
         )
+
+
+def test_latent_decode_chunk_compiles_on_v5e(v5e):
+    """The Mistral-Small-4 cut as the cell serves it (4 layers, 32
+    experts held, 256 slots of 8,192 tokens): the decode chunk compiles
+    for the v5e with the ONE latent pool aliased input to output and the
+    latent decode kernel in it, its trips as `_decode_sizes` sets them."""
+    import dataclasses
+
+    from vgate_tpu.models.decoder import init_params
+    from vgate_tpu.runtime.step_programs import _decode_chunk
+
+    A = _abstract(v5e)
+    spec = dataclasses.replace(
+        spec_for_model_id("mistralai/Mistral-Small-4-119B-2603"),
+        name="mistral-cut", num_layers=4, num_experts=32, vocab_size=32768,
+        eos_token_id=32767, bos_token_id=32766, extra_stop_ids=())
+    params = jax.tree.map(
+        lambda x: A(x.shape, x.dtype),
+        jax.eval_shape(
+            lambda: init_params(spec, jax.random.PRNGKey(0), jnp.bfloat16)))
+    B, ctx = 256, 8192
+    pool = A((spec.attn_layers, spec.cache_heads, 16385, PAGE,
+              spec.cache_head_dim), jnp.bfloat16)
+    pool_bytes = 4 * 16385 * PAGE * 384 * 2
+    compiled = _decode_chunk.lower(
+        params, spec, A((B,), jnp.int32), A((B,), jnp.int32), pool, None,
+        A((B, ctx // PAGE), jnp.int32), A((B,), jnp.bool_),
+        A((B,), jnp.float32), A((B,), jnp.float32), A((B,), jnp.int32),
+        A((2,), jnp.uint32), A((), jnp.uint32),
+        num_steps=8, use_pallas=True, max_position=ctx - 1,
+        seeds=A((B,), jnp.int32), steps=A((B,), jnp.int32),
+        all_greedy=True, guard=True,
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, "the pool is copied"
+    assert mem.temp_size_in_bytes < pool_bytes // 2
+    assert "mla_decode_attention_pallas" in compiled.as_text()
 
 
 def test_prefill_step_holds_one_pool_on_v5e(v5e):

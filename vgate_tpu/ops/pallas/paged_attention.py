@@ -26,6 +26,7 @@ kv_head) over ``_chunk_dma``.
 from __future__ import annotations
 
 import functools
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -169,25 +170,48 @@ def _scale_row(buf, slot):
 # write's own VMEM rides beside it, at most 1.3 MiB at the cells' shapes:
 # the new tokens' [BS, KV, hd] blocks and DECODE_WRITE_BUFFERS staged pages.
 DECODE_VMEM_BUDGET = 4 << 20
-# K/V chunk buffers: one computed, two in flight.  Two keep the HBM busy
-# only while an iteration's arithmetic outlasts its pages' transfer.
+# K/V chunk buffers of a loop trip that serves ONE item of the work list:
+# one computed, two in flight.  Two keep the HBM busy only while a trip's
+# arithmetic outlasts its pages' transfer.  A trip of ITEMS items computes
+# ITEMS buffers and keeps as many chunks in flight: decode_buffers.
 DECODE_BUFFERS = 3
-# A chunk holds at most this many tokens: two 128-token MXU weight tiles
-# a head.  Wider chunks mostly add tail work at the lengths served
-# (PERF.md section 6, PR 28: 512 tokens cost the 1.5B 5 % and the 7B 20 %).
+# A chunk (one item of the work list) holds at most this many tokens: two
+# 128-token MXU weight tiles a head.  Wider chunks mostly add tail work at
+# the lengths served (PERF.md section 6, PR 28: 512 tokens cost the 1.5B
+# 5 % and the 7B 20 %); a trip of two items pays the trip's fixed cost once
+# per 512 tokens without those tails.
 DECODE_CHUNK_TOKENS = 256
 # Staged pages of new tokens on their way back to the pool: a slot's
-# write is waited for only when its staging page comes round again.
+# write is waited for only when its staging page comes round again, which
+# a trip of two items that both end their slots brings two pages nearer.
 DECODE_WRITE_BUFFERS = 4
+# The most KV heads an item may hold for a trip to serve two (the rule of
+# _decode_sizes; PERF.md section 6, PR 37 has the probe behind it).
+DECODE_PAIR_KV_HEADS = 2
+
+
+def decode_buffers(items: int) -> int:
+    """Chunk buffers of a kernel whose trip serves `items` items."""
+    return DECODE_BUFFERS - 1 + items
 
 
 def _decode_sizes(B, KV, G, hd, page_size, pages_per_seq, kv_dtype, q_dtype,
-                  pools: int = 2):
-    """(pages a chunk, slots a program) for one geometry."""
+                  pools: int = 2, items: int = 0):
+    """(pages a chunk, slots a program, items a loop trip) for one
+    geometry.  `items` = 0 asks the rule; the probe and the tests force 1
+    or 2."""
     kv_bytes = jnp.dtype(kv_dtype).itemsize
     q_bytes = jnp.dtype(q_dtype).itemsize
+    # An item's work is a chain of products and one softmax update a KV
+    # head.  Where that is little, the trip's fixed cost (its branches,
+    # the wait before the first product, the work list's step) is a
+    # large part of it, and a trip serves TWO items for it.
+    if not items:
+        items = 2 if KV <= DECODE_PAIR_KV_HEADS else 1
     # K and V (or the one latent pool's rows) of every KV head in every
-    # buffer; a power of two, so the kernel's page arithmetic is shifts
+    # buffer of a one-item trip (a second item's buffer rides beside the
+    # budget: a chunk is the same whatever a trip holds); a power of two,
+    # so the kernel's page arithmetic is shifts
     token_bytes = DECODE_BUFFERS * pools * KV * hd * kv_bytes
     chunk_tokens = min(
         DECODE_VMEM_BUDGET // 2 // token_bytes, DECODE_CHUNK_TOKENS
@@ -199,7 +223,9 @@ def _decode_sizes(B, KV, G, hd, page_size, pages_per_seq, kv_dtype, q_dtype,
     g_rows = cdiv(G, 32 // q_bytes) * (32 // q_bytes)
     slot_bytes = 2 * 2 * KV * g_rows * hd * q_bytes
     block_slots = DECODE_VMEM_BUDGET // 2 // slot_bytes
-    return min(chunk_pages, pages_per_seq), max(1, min(block_slots, B))
+    return (
+        min(chunk_pages, pages_per_seq), max(1, min(block_slots, B)), items
+    )
 
 
 def _div(x, d: int):
@@ -210,7 +236,7 @@ def _div(x, d: int):
 
 
 # rows of the decode kernel's per-slot SMEM table
-_LEN, _LO, _PAGES, _FIRST, _END, _NEXT = range(6)
+_LEN, _LO, _PAGES, _FIRST, _END, _NEXT, _TICK = range(7)
 
 
 def _decode_kernel(
@@ -226,15 +252,17 @@ def _decode_kernel(
     # k/v_new_ref [BS, KV, hd] VMEM blocks, the slots' new token.
     # outputs: out_ref [BS, KV, G, hd]; `write` adds k/v_out_ref, the
     # pools again (aliased onto the inputs).
-    # scratch: k_buf/v_buf [DECODE_BUFFERS, KV, CP*ps, hd] VMEM (+ sk/sv_buf
-    # [DECODE_BUFFERS, KV, CP*ps] when quant; + wk/wv_buf
+    # scratch: k_buf/v_buf [decode_buffers(items), KV, CP*ps, hd] VMEM (+
+    # sk/sv_buf [decode_buffers(items), KV, CP*ps] when quant; + wk/wv_buf
     # [DECODE_WRITE_BUFFERS, KV, ps, hd] when write), acc [KV, G, hd] f32,
     # m/l [KV, G, 128] f32 running max/denom (col-broadcast), slots_ref
-    # [6, BS + 1] int32 SMEM (the block's slots, see below), DMA sems, one
-    # a buffer (+ one a staged page when write)
+    # [6, BS + 1] int32 SMEM (the block's slots, see below; a seventh row
+    # when hollow), DMA sems, one a trip's buffers (+ one a staged page
+    # when write)
     *refs,
     page_size: int,
     chunk_pages: int,
+    items: int,
     batch: int,
     softcap: float,
     scale: float,
@@ -242,16 +270,34 @@ def _decode_kernel(
     quant: bool,
     write: bool,
     latent: int = 0,
+    hollow: bool = False,
 ):
     """One program serves a BLOCK of slots: its work list is the live
     chunks of those slots in order, and the next chunks' pages are in
     flight while a chunk is computed whether or not they belong to one
     slot, so the pipeline is primed once a program and never drains
     inside it.  A slot of length 0 is not on the list: no DMA, no
-    iteration, zeros out.  One iteration serves one chunk of one slot for
-    all KV heads.
+    item, zeros out.  An ITEM of the list is one chunk (at most
+    DECODE_CHUNK_TOKENS tokens) of one slot for all KV heads.
 
-    What an iteration costs is scalar work and MXU weight loads, not
+    A loop TRIP serves `items` consecutive items of the list (1 or 2:
+    _decode_sizes reads it off the shape), whichever slots they belong
+    to.  A trip's fixed cost is paid once whatever it holds: the loop's
+    step, ONE wait for all its items' pages (they share a semaphore,
+    which counts bytes), one issue site for the chunks that go in flight
+    behind it, the branch structure and the bubble before the first
+    product; the items' products and softmax updates lie side by side in
+    one basic block for the compiler to interleave, while each keeps its
+    own 256 tokens, so no dead tail grows (512-token chunks bought the
+    same saving with one: PERF.md section 6, PR 28 and PR 37).  Within a
+    slot the chunks' softmax updates keep their order, the second item
+    starting from the first one's state: every output and the written
+    pool are the one-item kernel's to the bit.  Two items may end two
+    slots in one trip (two staged pages, two stores); an odd last item
+    gets a trip of its own after the loop, and nothing stands in for a
+    second.  `items` = 1 is the program as it was before trips held two.
+
+    What an item costs is scalar work and MXU weight loads, not
     bytes (PERF.md section 6, PR 28).  A page of 32 tokens is one
     descriptor for K and one for V, and the compiler does not overlap a
     descriptor's scalar work with the vector work: hence one descriptor a
@@ -263,7 +309,7 @@ def _decode_kernel(
 
     With `write` the slot's new token (position length - 1) reaches the
     pool from here and not through a scatter before the call: the pool
-    does not hold it yet, so the iteration that serves the slot's LAST
+    does not hold it yet, so the trip that serves the slot's LAST
     chunk puts the row into the fetched page where it lies in the
     buffer, the products run over the buffer as ever, and that one page
     goes back to the pool through a staging page, one descriptor for K
@@ -280,7 +326,12 @@ def _decode_kernel(
     and its first `latent` lanes are the value.  The refs are then
     q_ref [BS, 1, G, W], the pool, (`write`) new_ref [BS, 1, W]; out_ref
     [BS, 1, G, latent], (`write`) the pool again; k_buf, (`write`)
-    wk_buf, acc [1, G, latent], m, l, slots_ref, sems, (`write`) wsems."""
+    wk_buf, acc [1, G, latent], m, l, slots_ref, sems, (`write`) wsems.
+
+    `hollow` is the probe's (benchmarks/bench_kernels.py): the trips'
+    bookkeeping alone.  Every descriptor's start and wait, the write and
+    the store are one SMEM counter's step each (so their branches stay),
+    and nothing is multiplied; the output is meaningless."""
     k_scale_ref = v_scale_ref = sk_buf = sv_buf = None
     k_new_ref = v_new_ref = k_out_ref = v_out_ref = None
     wk_buf = wv_buf = wsems = v_pages_ref = v_buf = None
@@ -361,6 +412,21 @@ def _decode_kernel(
     if quant:
         sv_buf[...] = jnp.zeros_like(sv_buf)
 
+    nbuf = k_buf.shape[0]
+    nwrite = wk_buf.shape[0] if write else 0
+    if hollow:
+        slots_ref[_TICK, 0] = 0
+    I = items
+    D = nbuf - I  # chunks in flight ahead of the trip's own
+    # trip t computes buffers I * phase + (0 .. I - 1), phase = t mod PHASES
+    PHASES = nbuf // I
+    assert I in (1, 2) and nbuf == PHASES * I and D % I == 0
+
+    def tick():
+        """The hollow kernel's stand-in for a descriptor: one SMEM
+        counter's step, so the branch that held it stays."""
+        slots_ref[_TICK, 0] = slots_ref[_TICK, 0] + 1
+
     def pages_of(item):
         """Pool row, first page position and live pages of a chunk."""
         j, c = item
@@ -370,18 +436,23 @@ def _decode_kernel(
     def start_chunk(item, buf: int):
         """Issue the copies of the chunk's live pages into buffer `buf`:
         per page one descriptor for K and one for V, all KV heads in
-        each (and one each for an int8 pool's scale rows)."""
+        each (and one each for an int8 pool's scale rows), on the
+        semaphore of the trip the buffer belongs to.  An item past the
+        work list's end has no live page and issues nothing."""
         b, page0, live = pages_of(item)
         for i in range(CP):  # static unroll
             rows = pl.ds(i * page_size, page_size)
 
             def page(i=i, rows=rows):
+                if hollow:
+                    return tick()
                 # in range by construction: the kernel is compiled
                 # without the DMA bounds checks, which cost more scalar
                 # work than the descriptor itself
                 page_id = jnp.clip(
                     page_tables_ref[b, page0 + i], 0, num_pages - 1
                 )
+                sem = sems.at[buf // I]
 
                 def src(ref):
                     return (
@@ -391,34 +462,41 @@ def _decode_kernel(
 
                 for pool, buffer in pools:
                     pltpu.make_async_copy(
-                        src(pool), buffer.at[buf, :, rows, :], sems.at[buf]
+                        src(pool), buffer.at[buf, :, rows, :], sem
                     ).start()
                 if quant:
                     for pool, buffer in (
                         (k_scale_ref, sk_buf), (v_scale_ref, sv_buf),
                     ):
                         pltpu.make_async_copy(
-                            src(pool), buffer.at[buf, :, rows], sems.at[buf]
+                            src(pool), buffer.at[buf, :, rows], sem
                         ).start()
 
             pl.when(i < live)(page)
 
-    def wait_chunk(item, buf):
-        """Wait for what start_chunk issued: the semaphore counts bytes,
-        so the live pages are waited for in power-of-two runs."""
-        _, _, live = pages_of(item)
+    def wait_chunks(trip_items, phase, buf0):
+        """Wait for what start_chunk issued for a trip's items (buffers
+        `buf0` on, semaphore `phase`), all at once: the semaphore counts
+        bytes, so the items' live pages together are waited for in
+        power-of-two runs."""
+        live = functools.reduce(
+            operator.add, (pages_of(item)[2] for item in trip_items)
+        )
 
         def wait_pages(n):
-            rows = pl.ds(0, n * page_size)
-            for _, buffer in pools:
-                dst = buffer.at[buf, :, rows, :]
-                pltpu.make_async_copy(dst, dst, sems.at[buf]).wait()
-            if quant:
-                for buffer in (sk_buf, sv_buf):
-                    dst = buffer.at[buf, :, rows]
-                    pltpu.make_async_copy(dst, dst, sems.at[buf]).wait()
+            if hollow:
+                return tick()
+            for buffer in [buffer for _, buffer in pools] + (
+                [sk_buf, sv_buf] if quant else []
+            ):
+                # a run longer than a chunk is whole buffers' bytes
+                dst = (
+                    buffer.at[pl.ds(buf0, n // CP)] if n > CP
+                    else buffer.at[buf0, :, pl.ds(0, n * page_size)]
+                )
+                pltpu.make_async_copy(dst, dst, sems.at[phase]).wait()
 
-        for bit in range(CP.bit_length()):
+        for bit in range((len(trip_items) * CP).bit_length()):
             pl.when((live & (1 << bit)) != 0)(
                 functools.partial(wait_pages, 1 << bit)
             )
@@ -432,6 +510,8 @@ def _decode_kernel(
     def write_token(item, buf, written):
         """The slot's new K and V row into its page, in the chunk buffer
         (the products read it there) and back to the pool."""
+        if hollow:
+            return tick()
         j, c = item
         b = jnp.minimum(base + j, batch - 1)
         last = slots_ref[_PAGES, j] - 1  # the page that holds the row
@@ -468,42 +548,31 @@ def _decode_kernel(
         j2 = jnp.where(same, j, slots_ref[_NEXT, j])
         return j2, jnp.where(same, c + 1, slots_ref[_FIRST, j2])
 
-    nbuf = k_buf.shape[0]
-    nwrite = wk_buf.shape[0] if write else 0
-    D = nbuf - 1  # chunks in flight ahead of the one computed
-    items = [(next_live, slots_ref[_FIRST, next_live])]
+    # the items the first trip computes, then those in flight behind them
+    work = [(next_live, slots_ref[_FIRST, next_live])]
     for d in range(D):
-        pl.when(d < total)(functools.partial(start_chunk, items[d], d))
-        items.append(following(items[d]))
+        pl.when(d < total)(functools.partial(start_chunk, work[d], d))
+        work.append(following(work[d]))
+    while len(work) < nbuf:
+        work.append(following(work[-1]))
 
     # operands go to the MXU in the pages' own type (int8 pages as
     # float32, as their scales are)
     mxu = jnp.float32 if quant else k_buf.dtype
 
-    def body(i, carry):
-        items, written = carry
-        j, c = items[0]
-        buf = jax.lax.rem(i, nbuf)
-        ahead = jax.lax.rem(i + D, nbuf)
-        # the issue code once per buffer: static destination addresses
-        for s in range(nbuf):
-            pl.when((ahead == s) & (i + D < total))(
-                functools.partial(start_chunk, items[D], s)
-            )
-        wait_chunk(items[0], buf)
-        last_chunk = c == slots_ref[_END, j] - 1
-        if write:
-            pl.when(last_chunk)(
-                functools.partial(write_token, items[0], buf, written)
-            )
-            written = written + last_chunk.astype(jnp.int32)
-
+    def attend(item, buf, state, keep):
+        """One item's products and softmax update for all KV heads, from
+        `state` (a KV head's (m, l, acc) after the trip's item before
+        this one; None: what the refs hold) to the state after it, which
+        goes to the refs when `keep`."""
+        j, c = item
         sl, lo = slots_ref[_LEN, j], slots_ref[_LO, j]
         # the softmax state restarts at a slot boundary
         first = c == slots_ref[_FIRST, j]
         token_pos = c * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
         valid = (token_pos >= lo) & (token_pos < sl)
-        for kv in range(KV):  # static unroll: all KV heads an iteration
+        after = []
+        for kv in range(KV):  # static unroll: all KV heads an item
             q = q_ref[j, kv].astype(mxu)  # [G, hd]
             k = k_buf[buf, kv].astype(mxu)  # [T, hd]
             # operands in the pages' own type, float32 accumulation, the
@@ -524,9 +593,16 @@ def _decode_kernel(
                 scores = jnp.tanh(scores / softcap) * softcap
             scores = jnp.where(valid, scores, -1e30)
 
-            m_prev = jnp.where(first, -1e30, m_ref[kv, :, :1])  # [G, 1]
-            l_prev = jnp.where(first, 0.0, l_ref[kv, :, :1])
-            acc_prev = jnp.where(first, 0.0, acc_ref[kv])
+            held = state is None
+            m_prev = jnp.where(  # [G, 1]
+                first, -1e30, m_ref[kv, :, :1] if held else state[kv][0]
+            )
+            l_prev = jnp.where(
+                first, 0.0, l_ref[kv, :, :1] if held else state[kv][1]
+            )
+            acc_prev = jnp.where(
+                first, 0.0, acc_ref[kv] if held else state[kv][2]
+            )
             m_new = jnp.maximum(
                 m_prev, jnp.max(scores, axis=-1, keepdims=True)
             )
@@ -557,29 +633,91 @@ def _decode_kernel(
                     p, v.astype(jnp.float32), pv_dims,
                     preferred_element_type=jnp.float32,
                 )
-            acc_ref[kv] = acc_prev * alpha + pv_acc
-            m_ref[kv] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-            l_ref[kv] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+            acc_new = acc_prev * alpha + pv_acc
+            if keep:
+                acc_ref[kv] = acc_new
+                m_ref[kv] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+                l_ref[kv] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+            after.append((m_new, l_new, acc_new))
+        return after
 
-        @pl.when(last_chunk)
-        def _():
-            for kv in range(KV):
-                denom = jnp.maximum(l_ref[kv, :, :1], 1e-30)
-                out_ref[j, kv] = (acc_ref[kv] / denom).astype(out_ref.dtype)
+    def put_out(j, state):
+        """A slot's attention, from its last chunk's state (None: what
+        the refs hold)."""
+        held = state is None
+        for kv in range(KV):
+            denom = jnp.maximum(
+                l_ref[kv, :, :1] if held else state[kv][1], 1e-30
+            )
+            acc = acc_ref[kv] if held else state[kv][2]
+            out_ref[j, kv] = (acc / denom).astype(out_ref.dtype)
 
-        return items[1:] + (following(items[D]),), written
+    def trip(t, carry, n: int = I):
+        """One loop trip: the next `n` items of the work list, whichever
+        slots they belong to.  The wait, the issue of the chunks D ahead,
+        the tests for a slot's end and the loop's own step are paid once;
+        the items' products and softmax updates lie side by side in one
+        basic block, a slot's chunks in their order."""
+        work, written = carry
+        own = work[:n]
+        phase = jax.lax.rem(t, PHASES)
+        # the trip's first item on the list, and its first buffer (no
+        # `* 1`, and no `+ 0` below: one item a trip traces to the
+        # program as it was, equation for equation)
+        at, buf0 = (t, phase) if I == 1 else (t * I, phase * I)
+        if n == I:  # the loop's trip (an odd last item issues nothing)
+            ahead = jax.lax.rem(t + D // I, PHASES)
+            # the issue code once per buffer: static destination addresses
+            for s in range(PHASES):
+                def issue(s=s):
+                    for e in range(I):
+                        start_chunk(work[D + e], s * I + e)
 
-    _, written = jax.lax.fori_loop(
-        0, total, body, (tuple(items), jnp.int32(0))
-    )
-    # the pool is whole again before the program ends
-    for stage in range(nwrite):
+                pl.when((ahead == s) & (at + D < total))(issue)
+        wait_chunks(own, phase, buf0)
+        ends = [c == slots_ref[_END, j] - 1 for j, c in own]
+        bufs = [buf0] + [buf0 + e for e in range(1, n)]
+        if write:
+            for item, buf, last_chunk in zip(own, bufs, ends):
+                pl.when(last_chunk)(
+                    functools.partial(write_token, item, buf, written)
+                )
+                written = written + last_chunk.astype(jnp.int32)
+        if not hollow:
+            states, state = [], None
+            for e, (item, buf) in enumerate(zip(own, bufs)):
+                state = attend(item, buf, state, keep=e == n - 1)
+                states.append(state)
+            # the trip's last item left its state in the refs
+            states[-1] = None
+        for e, ((j, _), last_chunk) in enumerate(zip(own, ends)):
+            pl.when(last_chunk)(
+                tick if hollow else functools.partial(put_out, j, states[e])
+            )
+        nxt = list(work[n:])
+        for _ in range(n):
+            nxt.append(following(nxt[-1]))
+        return tuple(nxt), written
+
+    full = total if I == 1 else _div(total, I)
+    carry = jax.lax.fori_loop(0, full, trip, (tuple(work), jnp.int32(0)))
+    written = carry[1]
+    if I > 1:
+        # an odd last item is a trip of its own, and nothing stands in
+        # for a second: no copies, no products, no store
+        written = jax.lax.cond(
+            full * I < total, lambda: trip(full, carry, 1)[1],
+            lambda: written,
+        )
+    # the pool is whole again before the program ends (the hollow
+    # kernel staged nothing)
+    for stage in range(0 if hollow else nwrite):
         pl.when(stage < written)(functools.partial(wait_write, stage))
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("interpret", "softcap", "scale"),
+    static_argnames=("interpret", "softcap", "scale", "items", "hollow"),
 )
 def paged_decode_attention_pallas(
     q: jnp.ndarray,  # [B, H, hd]
@@ -594,6 +732,8 @@ def paged_decode_attention_pallas(
     interpret: bool = False,
     softcap: float = 0.0,
     scale=None,  # static query scale; default hd**-0.5
+    items: int = 0,  # work-list items a loop trip serves; 0: _decode_sizes
+    hollow: bool = False,  # the probe's: _decode_kernel
 ):
     """Single-token decode attention over the paged pool, [B, H, hd].
 
@@ -628,10 +768,12 @@ def paged_decode_attention_pallas(
     )
     KV, P, ps, _ = k_data.shape[1:] if has_layer else k_data.shape
     G = H // KV
-    CP, BS = _decode_sizes(
-        B, KV, G, hd, ps, page_tables.shape[1], k_data.dtype, q.dtype
+    # an int8 pool's path is as it was: one item a trip
+    CP, BS, items = _decode_sizes(
+        B, KV, G, hd, ps, page_tables.shape[1], k_data.dtype, q.dtype,
+        items=1 if quant else items,
     )
-    chunk_tokens = CP * ps
+    chunk_tokens, nbuf = CP * ps, decode_buffers(items)
 
     if window is None:
         window_arr = jnp.zeros((1,), jnp.int32)
@@ -646,12 +788,14 @@ def paged_decode_attention_pallas(
         _decode_kernel,
         page_size=ps,
         chunk_pages=CP,
+        items=items,
         batch=B,
         softcap=float(softcap),
         scale=float(scale) if scale is not None else hd ** -0.5,
         has_layer=has_layer,
         quant=quant,
         write=write,
+        hollow=hollow,
     )
     # q is laid out [B, KV, G, hd] so a program's block covers the FULL
     # trailing (G, hd) dims — Mosaic requires trailing block dims either
@@ -664,14 +808,14 @@ def paged_decode_attention_pallas(
         memory_space=pltpu.VMEM,
     )
     scratch = [
-        pltpu.VMEM((DECODE_BUFFERS, KV, chunk_tokens, hd), k_data.dtype),
-        pltpu.VMEM((DECODE_BUFFERS, KV, chunk_tokens, hd), v_data.dtype),
+        pltpu.VMEM((nbuf, KV, chunk_tokens, hd), k_data.dtype),
+        pltpu.VMEM((nbuf, KV, chunk_tokens, hd), v_data.dtype),
     ]
     if quant:
         # per-token bf16 scale rows ride their own chunk buffers
         scratch += [
-            pltpu.VMEM((DECODE_BUFFERS, KV, chunk_tokens), k_scale.dtype),
-            pltpu.VMEM((DECODE_BUFFERS, KV, chunk_tokens), v_scale.dtype),
+            pltpu.VMEM((nbuf, KV, chunk_tokens), k_scale.dtype),
+            pltpu.VMEM((nbuf, KV, chunk_tokens), v_scale.dtype),
         ]
     if write:
         # the new tokens' staging pages (beside the budget's buffers, as
@@ -684,8 +828,8 @@ def paged_decode_attention_pallas(
         pltpu.VMEM((KV, G, hd), jnp.float32),
         pltpu.VMEM((KV, G, 128), jnp.float32),
         pltpu.VMEM((KV, G, 128), jnp.float32),
-        pltpu.SMEM((6, BS + 1), jnp.int32),
-        pltpu.SemaphoreType.DMA((DECODE_BUFFERS,)),
+        pltpu.SMEM((6 + hollow, BS + 1), jnp.int32),
+        pltpu.SemaphoreType.DMA((nbuf // items,)),
     ]
     inputs = [q.reshape(B, KV, G, hd), k_data, v_data]
     in_specs = [block, any_spec, any_spec]
@@ -738,7 +882,8 @@ def paged_decode_attention_pallas(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("interpret", "scale", "v_width"),
+    jax.jit,
+    static_argnames=("interpret", "scale", "v_width", "items", "hollow"),
 )
 def mla_decode_attention_pallas(
     q: jnp.ndarray,  # [B, H, W] absorbed queries, W the pool's row width
@@ -751,6 +896,8 @@ def mla_decode_attention_pallas(
     v_width: int,  # leading lanes of a row that are its value
     scale: float,
     interpret: bool = False,
+    items: int = 0,  # work-list items a loop trip serves; 0: _decode_sizes
+    hollow: bool = False,  # the probe's: _decode_kernel
 ):
     """Decode attention in the ABSORBED form of multi-head latent
     attention over the latent pool, [B, H, v_width]: every query head
@@ -766,14 +913,15 @@ def mla_decode_attention_pallas(
     B, H, W = q.shape
     _, KV, P, ps, _ = pages.shape
     write = new is not None
-    CP, BS = _decode_sizes(
-        B, KV, H, W, ps, page_tables.shape[1], pages.dtype, q.dtype, pools=1
+    CP, BS, items = _decode_sizes(
+        B, KV, H, W, ps, page_tables.shape[1], pages.dtype, q.dtype, pools=1,
+        items=items,
     )
-    chunk_tokens = CP * ps
+    chunk_tokens, nbuf = CP * ps, decode_buffers(items)
     kernel = functools.partial(
-        _decode_kernel, page_size=ps, chunk_pages=CP, batch=B, softcap=0.0,
-        scale=float(scale), has_layer=True, quant=False, write=write,
-        latent=v_width,
+        _decode_kernel, page_size=ps, chunk_pages=CP, items=items, batch=B,
+        softcap=0.0, scale=float(scale), has_layer=True, quant=False,
+        write=write, latent=v_width, hollow=hollow,
     )
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     q_block = pl.BlockSpec(
@@ -784,7 +932,7 @@ def mla_decode_attention_pallas(
         (BS, KV, H, v_width), lambda bb, *prefetch: (bb, 0, 0, 0),
         memory_space=pltpu.VMEM,
     )
-    scratch = [pltpu.VMEM((DECODE_BUFFERS, KV, chunk_tokens, W), pages.dtype)]
+    scratch = [pltpu.VMEM((nbuf, KV, chunk_tokens, W), pages.dtype)]
     if write:
         scratch.append(
             pltpu.VMEM((DECODE_WRITE_BUFFERS, KV, ps, W), pages.dtype))
@@ -792,8 +940,8 @@ def mla_decode_attention_pallas(
         pltpu.VMEM((KV, H, v_width), jnp.float32),
         pltpu.VMEM((KV, H, 128), jnp.float32),
         pltpu.VMEM((KV, H, 128), jnp.float32),
-        pltpu.SMEM((6, BS + 1), jnp.int32),
-        pltpu.SemaphoreType.DMA((DECODE_BUFFERS,)),
+        pltpu.SMEM((6 + hollow, BS + 1), jnp.int32),
+        pltpu.SemaphoreType.DMA((nbuf // items,)),
     ]
     inputs = [q.reshape(B, KV, H, W), pages]
     in_specs = [q_block, any_spec]
