@@ -510,6 +510,45 @@ def bench_flash_prefill(B=8, S=1024, H=12, KV=2, hd=128):
     }
 
 
+def bench_swa_prefill(S=8192, H=64, KV=8, hd=128, window=128,
+                      blocks=((128, 128), (256, 128), (512, 128),
+                              (1024, 128), (256, 256), (512, 256))):
+    """A window layer's prompt attention at the K-EXAONE cut's shape
+    (one 8,192-row prompt, 64 query heads on 8 KV heads, window 128):
+    the flash kernel over a BAND of key blocks at several block sizes,
+    against the same kernel over every block under the diagonal with the
+    window as a mask (1,024-row blocks: what a full layer's launch
+    costs), each checked against the first."""
+    from vgate_tpu.ops.pallas.flash_prefill import (
+        flash_prefill_attention_pallas,
+        swa_prefill_attention_pallas,
+    )
+
+    key = jax.random.PRNGKey(2)
+    q = jax.random.normal(key, (1, S, H, hd), jnp.bfloat16)
+    k = jax.random.normal(jax.random.fold_in(key, 1), (1, S, KV, hd),
+                          jnp.bfloat16)
+    v = jax.random.normal(jax.random.fold_in(key, 2), (1, S, KV, hd),
+                          jnp.bfloat16)
+    lens = jnp.asarray([S - 37], jnp.int32)
+    masked = lambda q, k, v, lens: flash_prefill_attention_pallas(
+        q, k, v, lens, block_q=1024, block_k=1024, window=window)
+    want = np.asarray(jax.jit(masked)(q, k, v, lens), np.float32)
+    yield {"kernel": "flash_prefill_attention (window as a mask)",
+           "shape": f"S{S} H{H} KV{KV} hd{hd} w{window}", "blocks": [1024] * 2,
+           "us": round(_median_time(_looped(masked), q, k, v, lens) * 1e6, 1)}
+    for bq, bk in blocks:
+        band = lambda q, k, v, lens, bq=bq, bk=bk: (
+            swa_prefill_attention_pallas(q, k, v, lens, window,
+                                         block_q=bq, block_k=bk))
+        got = np.asarray(jax.jit(band)(q, k, v, lens), np.float32)
+        yield {"kernel": "swa_prefill_attention (band)",
+               "blocks": [bq, bk],
+               "max_abs_diff": float(np.abs(got - want)[0, :S - 37].max()),
+               "us": round(
+                   _median_time(_looped(band), q, k, v, lens) * 1e6, 1)}
+
+
 def bench_decode_window(B=128, H=8, KV=4, hd=256, ps=16, ctx=4096,
                         window=1024):
     """Sliding-window decode (Gemma-2 local layers): the kernel skips DMA
@@ -601,6 +640,10 @@ def main() -> None:
         )
     if sys.argv[1:] == ["sample_edits"]:
         for line in bench_sample_edits():
+            print(json.dumps(line), flush=True)
+        return
+    if sys.argv[1:] == ["swa_prefill"]:
+        for line in bench_swa_prefill():
             print(json.dumps(line), flush=True)
         return
     if sys.argv[1:2] == ["decode_cells"]:

@@ -551,3 +551,65 @@ def test_pattern_stack_decode_chunk_holds_one_state_on_v5e(v5e):
     assert mem.temp_size_in_bytes < state_bytes // 8
     text = compiled.as_text()
     assert "ssd_step_pallas" in text and "moe_grouped_matmul_pallas" in text
+
+
+def test_window_stack_decode_chunk_and_prompt_kernel_compile_on_v5e(v5e):
+    """The K-EXAONE cut as the cell serves it (5 layers, 16 experts
+    held, 192 slots of 8,192 tokens): the decode chunk compiles for the
+    v5e with the pool of the ONE full layer and the rings of the four
+    window layers aliased input to output, a ring's launch under its own
+    name beside the full layer's; and the window layers' banded prompt
+    kernel compiles at the cell's one shape (8,192 rows, 64 heads on 8)."""
+    import dataclasses
+
+    from vgate_tpu.models.decoder import init_params
+    from vgate_tpu.models.hybrid import make_state
+    from vgate_tpu.ops.pallas.flash_prefill import (
+        swa_prefill_attention_pallas,
+    )
+    from vgate_tpu.runtime.step_programs import _decode_chunk
+
+    A = _abstract(v5e)
+    spec = dataclasses.replace(
+        spec_for_model_id("LGAI-EXAONE/K-EXAONE-236B-A23B"),
+        name="exaone-cut", num_layers=5, num_experts=16, vocab_size=19200,
+        eos_token_id=19199, bos_token_id=19198)
+    abstract = lambda tree: jax.tree.map(
+        lambda x: A(x.shape, x.dtype), jax.eval_shape(tree))
+    params = abstract(
+        lambda: init_params(spec, jax.random.PRNGKey(0), jnp.bfloat16))
+    B, ctx = 192, 8192
+    state = abstract(lambda: make_state(spec, B, jnp.bfloat16, PAGE))
+    state_bytes = sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
+    assert state_bytes == 2 * 4 * 8 * (1 + B * 5) * PAGE * 128 * 2
+    pages = 8193
+    pool = A((spec.attn_layers, spec.num_kv_heads, pages, PAGE,
+              spec.head_dim), jnp.bfloat16)
+    pool_bytes = 2 * pages * PAGE * 8 * 128 * 2
+    compiled = _decode_chunk.lower(
+        params, spec, A((B,), jnp.int32), A((B,), jnp.int32), pool, pool,
+        A((B, ctx // PAGE), jnp.int32), A((B,), jnp.bool_),
+        A((B,), jnp.float32), A((B,), jnp.float32), A((B,), jnp.int32),
+        A((2,), jnp.uint32), A((), jnp.uint32),
+        num_steps=8, use_pallas=True, max_position=ctx - 1,
+        seeds=A((B,), jnp.int32), steps=A((B,), jnp.int32),
+        all_greedy=True, guard=True, state=state,
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes + state_bytes, (
+        "the pool or the rings are copied")
+    # the weights' re-laid copies (q, k, v of five layers) and a step's
+    # activations: 1.16 GB when this was written, far under the rings +
+    # pool a deployment holds
+    assert mem.temp_size_in_bytes < 1.5e9
+    text = compiled.as_text()
+    assert "swa_decode_attention_pallas" in text
+    assert "paged_decode_attention_pallas" in text
+    assert "moe_grouped_matmul_pallas" in text
+    S, H, KV, hd = 8192, 64, 8, 128
+    band = jax.jit(lambda q, k, v, lens: swa_prefill_attention_pallas(
+        q, k, v, lens, 128)).lower(
+        A((1, S, H, hd), jnp.bfloat16), A((1, S, KV, hd), jnp.bfloat16),
+        A((1, S, KV, hd), jnp.bfloat16), A((1,), jnp.int32)).compile()
+    assert "swa_prefill_attention_pallas" in band.as_text()

@@ -330,6 +330,25 @@ def _mla_write_attend(spec: ModelSpec, impl: str, kernel_writes: bool,
     return write_attend
 
 
+def _swa_prefill_attend(spec: ModelSpec, impl: str, seq_lens):
+    """``prefill_forward``'s attention for a window layer of a
+    ``window_pattern`` spec, ``attend(q, k, v)`` over the prompt's own
+    rows: the flash kernel over a band of key blocks under a name of its
+    own (ops/pallas/flash_prefill.py swa_prefill_attention_pallas), or
+    the blockwise jnp twin with the window as a mask."""
+    if not spec.swa_layers:
+        return None
+    if impl == "pallas":
+        from vgate_tpu.ops.pallas.flash_prefill import (
+            swa_prefill_attention_pallas,
+        )
+
+        return lambda q, k, v: swa_prefill_attention_pallas(
+            q, k, v, seq_lens, spec.sliding_window)
+    return lambda q, k, v: flash_prefill_attention(
+        q, k, v, seq_lens, window=spec.sliding_window)
+
+
 def multitok_attention_impl(
     use_pallas: bool, mesh=None, rows: int = 1, unaligned: bool = False,
     latent: bool = False,
@@ -473,6 +492,7 @@ def prefill_forward(
             slots, jnp.ones((B,), bool), page_tables,
             lambda q, k, v, kp, vp, layer: attn_fn(q, k, v, seq_lens),
             use_pallas,
+            swa_attend=_swa_prefill_attend(spec, impl, seq_lens),
         )
         return (_logits(params, spec, _last_rows(x, seq_lens)),
                 k_pages, v_pages, state)
@@ -758,25 +778,31 @@ def decode_forward(
         spec, use_pallas, mesh, is_quantized(k_pages)
     ) == "kernel"
 
-    def write_attend(q, k, v, kp, vp, layer, window=None):
-        """The new token's K and V into the pool at ``layer`` and its
-        attention over the pool: (attention, k_pages, v_pages)."""
-        if kernel_writes:
+    def cache_step(attn_fn, tables, page_ids):
+        """``write_attend(q, k, v, kp, vp, layer, window=None)`` over
+        the pools that ``tables`` index: the new token's K and V into
+        them at ``layer`` and its attention over them: (attention,
+        k_pages, v_pages)."""
+        def write_attend(q, k, v, kp, vp, layer, window=None):
+            if kernel_writes:
+                with jax.named_scope("attention"):
+                    return attn_fn(
+                        q, kp, vp, tables, seq_lens, layer=layer,
+                        window=window, k_new=k, v_new=v,
+                    )
+            with jax.named_scope("kv_write"):
+                kp = kv_write_tokens(kp, page_ids, page_off, k, layer=layer)
+                vp = kv_write_tokens(vp, page_ids, page_off, v, layer=layer)
             with jax.named_scope("attention"):
-                return attn_fn(
-                    q, kp, vp, page_tables, seq_lens, layer=layer,
-                    window=window, k_new=k, v_new=v,
+                attn = attn_fn(
+                    q, kp, vp, tables, seq_lens, layer=layer,
+                    window=window,
                 )
-        with jax.named_scope("kv_write"):
-            kp = kv_write_tokens(kp, page_ids, page_off, k, layer=layer)
-            vp = kv_write_tokens(vp, page_ids, page_off, v, layer=layer)
-        with jax.named_scope("attention"):
-            attn = attn_fn(
-                q, kp, vp, page_tables, seq_lens, layer=layer,
-                window=window,
-            )
-        return attn, kp, vp
+            return attn, kp, vp
 
+        return write_attend
+
+    write_attend = cache_step(attn_fn, page_tables, page_ids)
     x = _embed(params, spec, tokens)  # [B, D]
     if spec.is_mla:
         write_attend = _mla_write_attend(
@@ -785,9 +811,31 @@ def decode_forward(
     if spec.is_hybrid:
         from vgate_tpu.models import hybrid
 
+        ring_step = None
+        if spec.swa_layers:
+            # a window layer's cache step: the same, over the slots'
+            # rings (row = slot) through the ring's arithmetic table
+            R = hybrid.ring_pages(spec, ps)
+            ring_tables = hybrid.ring_tables(
+                jnp.arange(tokens.shape[0]), page_tables.shape[1],
+                (state["ring_k"].shape[2] - 1) // R, R)
+            ring_fn = attn_fn
+            if impl == "pallas":
+                from vgate_tpu.ops.pallas.paged_attention import (
+                    swa_decode_attention_pallas,
+                )
+
+                ring_fn = functools.partial(
+                    swa_decode_attention_pallas,
+                    softcap=spec.attn_softcap, scale=_query_scale(spec))
+            ring_step = functools.partial(
+                cache_step(
+                    ring_fn, ring_tables,
+                    decode_attn_inputs(positions, ring_tables, active, ps)[1]),
+                window=spec.sliding_window)
         x, k_pages, v_pages, state, stats = hybrid.decode_forward(
             params, spec, x, positions, k_pages, v_pages, state, active,
-            write_attend, use_pallas,
+            write_attend, use_pallas, ring_write_attend=ring_step,
         )
         return _logits(params, spec, x), k_pages, v_pages, state, stats
 
@@ -928,6 +976,7 @@ def prefill_suffix_forward(
             params, spec, x, suffix_lens, positions, k_pages, v_pages,
             state, slots, prefix_lens == 0, suffix_page_tables, attend,
             kernels, ctx_tables=ctx_page_tables if spec.is_mla else None,
+            prefix_lens=prefix_lens if spec.swa_layers else None,
         )
         return (_logits(params, spec, _last_rows(x, suffix_lens)),
                 k_pages, v_pages, state)

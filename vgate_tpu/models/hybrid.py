@@ -11,13 +11,20 @@ data (``ModelSpec.period_blocks``):
   k_rope]`` a token and no K or V; a prompt pass expands K and V from
   the rows (non-absorbed), a decode step folds the expansion into the
   query and the output and reads the rows alone (absorbed),
+* ``swa``   softmax attention over the last ``sliding_window`` tokens
+  (K-EXAONE's window layers), whose K/V is the decode slot's RING and
+  holds no page of the pool (below),
+* ``mlp``   a dense SwiGLU feed-forward (a leading layer's),
 * ``moe``   the expert layer of ``ops/moe.py``.
 
 Qwen3-Next's layer is two sub-blocks (a mixer, then experts); its period
 is ``gdn moe gdn moe gdn moe attn moe``.  A Nemotron-H layer is one:
 ``EMEMEMEMEM*`` is ``moe mamba`` five times, then ``attn``.  A
 Mistral-Small-4 layer is ``mla moe``, and its stack has no recurrent
-layer: the state is then ``None`` and the second pool too.
+layer: the state is then ``None`` and the second pool too.  A K-EXAONE
+layer is ``swa moe`` three times to one ``attn moe``, behind LEADING
+layers that the walker runs once, ahead of the scan (layer 0, ``swa
+mlp``, and as many more as leave whole periods: ``ModelSpec.lead_layers``).
 
 ``models/decoder.py``'s forwards hand a hybrid spec's work here after
 they have chosen the attention implementation, so the step programs,
@@ -40,6 +47,19 @@ differs:
   rows alone.  Padded prompt positions get ``g = 0, beta = 0`` (``dt =
   0``) and the convolution tail is taken at the row's real length, so a
   bucket's padding never moves the state;
+* a window layer's K/V is a RING, and a ring is state: ``{"ring_k",
+  "ring_v": [window layers, KV, 1 + slots x R, ps, hd]}``, a pool of
+  its own whose page 0 is trash and whose pages ``1 + slot x R ..`` are
+  the slot's ``R = ceil(window / ps) + 1`` ring pages.  The ring's page
+  table is arithmetic, not allocation: a sequence's page ``p`` is its
+  slot's ring page ``p mod R`` (``ring_tables``), so the decode kernel
+  with its ``window`` argument fetches the live pages alone and writes
+  the step's token where ``kv_write_tokens`` would; the page a token
+  lands in held positions ``R x ps`` back, all below the window.  A
+  prompt pass attends in flight and writes only the pages that survive
+  (the last ``R`` that hold a real token; the others go to the trash
+  page: a scatter that hits one ring page twice has no defined order);
+  a later chunk reads the ring BEFORE it writes, beside its own rows;
 * the experts' matrices never ride the scan's per-period slices: the
   grouped product's kernel takes the full stack and a layer index.
 
@@ -48,7 +68,9 @@ with ``P`` periods and ``n`` layers of the group a period.  Qwen3-Next's
 groups are ``linear`` (a Gated DeltaNet layer and its experts) and
 ``full`` (the attention layer and its experts, ``[P, ...]``: it is one a
 period); a pattern's groups are its kinds, ``mamba`` / ``attn`` /
-``moe``.
+``moe``; K-EXAONE's are ``window`` and ``global`` (attention and
+experts of a window or a full layer) and ``lead``, a TUPLE of the
+leading layers' own trees.
 """
 
 from __future__ import annotations
@@ -62,11 +84,12 @@ import jax.numpy as jnp
 from vgate_tpu.models.specs import ModelSpec
 from vgate_tpu.ops import gated_delta as gd
 from vgate_tpu.ops import ssd
-from vgate_tpu.ops.kv_quant import kv_write_pages
+from vgate_tpu.ops.kv_quant import gather_pages, kv_write_pages
 from vgate_tpu.ops.moe import STAT_NAMES, combine_stats, expert_layer
 from vgate_tpu.ops.norms import rms_norm
-from vgate_tpu.ops.attention import mla_gather_rows
+from vgate_tpu.ops.attention import flash_prefill_attention, mla_gather_rows
 from vgate_tpu.ops.rope import apply_rope, position_scale
+from vgate_tpu.utils.math import cdiv
 
 
 def init_layers(spec: ModelSpec, key, dtype, normal, norm_init
@@ -75,6 +98,8 @@ def init_layers(spec: ModelSpec, key, dtype, normal, norm_init
     keys of its own."""
     if spec.is_mla:
         return _init_mla_layers(spec, key, dtype, normal, norm_init)
+    if spec.window_pattern:
+        return _init_window_layers(spec, key, dtype, normal)
     if spec.layer_pattern:
         return _init_pattern_layers(spec, key, dtype, normal)
     return _init_paired_layers(spec, key, dtype, normal, norm_init)
@@ -138,6 +163,67 @@ def _init_mla_layers(spec: ModelSpec, key, dtype, normal, norm_init
     if Fs and spec.shared_expert_gate:
         out["shared_router"] = draw(mk[12], (D,))
     return {"layer": out}
+
+
+def _init_window_layers(spec: ModelSpec, key, dtype, normal
+                        ) -> Dict[str, Any]:
+    """A ``window_pattern`` spec's tensors from ``fold_in(key, 38)``
+    split 32 ways: tensor ``j`` of layer ``i`` (its index in the WHOLE
+    stack, leading layers included) from ``fold_in(key j, i)``, N(0,
+    0.02), every norm weight 1.  With per-head norms on q and k a
+    query's scores have a standard deviation near 1, so attention is
+    not flat and a wrong window, a stale ring row or a rotary on the
+    wrong kind of layer shows.  The router's selection bias N(0, 0.02)
+    and NOT zero, as for a pattern's sigmoid router."""
+    wk = jax.random.split(jax.random.fold_in(key, 38), 32)
+    D, H, KV, hd = (spec.hidden_size, spec.num_heads, spec.num_kv_heads,
+                    spec.head_dim)
+    E, R, Fe = spec.num_experts, spec.router_experts, spec.expert_width
+    F, Fs = spec.intermediate_size, spec.shared_expert_intermediate_size
+    ones = lambda *shape: jnp.ones(shape, dtype)
+    attn = {"q": (0, (D, H * hd)), "k": (1, (D, KV * hd)),
+            "v": (2, (D, KV * hd)), "o": (3, (H * hd, D))}
+    ff = {
+        "mlp": {"gate": (4, (D, F)), "up": (5, (D, F)), "down": (6, (F, D))},
+        "moe": {"gate": (8, (E, D, Fe)), "up": (9, (E, D, Fe)),
+                "down": (10, (E, Fe, D)), "shared_gate": (12, (D, Fs)),
+                "shared_up": (13, (D, Fs)), "shared_down": (14, (Fs, D))},
+    }
+
+    def draw(layers, j, shape):
+        """Tensor ``j`` of the ``layers`` (stack indices), stacked."""
+        return jax.jit(lambda k: jax.lax.map(
+            lambda i: normal(jax.random.fold_in(k, i), shape, 0.02),
+            jnp.asarray(layers)))(wk[j])
+
+    def tree(layers, lead, kind):
+        """The tensors of ``layers`` (all of one feed-forward ``kind``),
+        each ``lead + its shape``."""
+        put = lambda a: a.reshape(lead + a.shape[1:])
+        out = {"input_norm": ones(*lead, D), "post_norm": ones(*lead, D)}
+        if spec.qk_norm:
+            out.update(q_norm=ones(*lead, hd), k_norm=ones(*lead, hd))
+        for name, (j, shape) in {**attn, **ff[kind]}.items():
+            if 0 not in shape:  # no shared expert: no tensor
+                out[name] = {"w": put(draw(layers, j, shape))}
+        if kind == "moe":
+            out["router"] = put(draw(layers, 7, (D, R)))
+            out["router_bias"] = put(jax.jit(lambda k: jax.lax.map(
+                lambda i: jax.random.normal(
+                    jax.random.fold_in(k, i), (R,), jnp.float32) * 0.02,
+                jnp.asarray(layers)))(wk[11]))
+        return out
+
+    lead, P = spec.lead_layers, spec.num_periods
+    kinds = [spec._window_layer(i) for i in range(spec.num_layers)]
+    out: Dict[str, Any] = {"lead": tuple(
+        tree([i], (), kinds[i][1]) for i in range(lead))}
+    for group, mixer in (("window", "swa"), ("global", "attn")):
+        layers = [i for i in range(lead, spec.num_layers)
+                  if kinds[i][0] == mixer]
+        if layers:
+            out[group] = tree(layers, (P, len(layers) // P), "moe")
+    return out
 
 
 def _init_pattern_layers(spec: ModelSpec, key, dtype, normal
@@ -297,34 +383,84 @@ def _state_shapes(spec: ModelSpec):
             (spec.linear_conv_kernel_dim - 1, spec.linear_conv_dim))
 
 
-def make_state(spec: ModelSpec, slots: int, dtype) -> Dict[str, jax.Array]:
-    """The recurrent state of every recurrent layer, zeros, one row a
-    slot."""
-    tile, tail = _state_shapes(spec)
-    lead = (spec.linear_layers, slots)
-    return {"S": jnp.zeros(lead + tile, jnp.float32),
-            "conv": jnp.zeros(lead + tail, dtype)}
+def ring_pages(spec: ModelSpec, page_size: int) -> int:
+    """Pages of a slot's ring in one window layer: the window and one
+    page of slack, so that the page a token lands in holds nothing the
+    window still reaches."""
+    return cdiv(spec.sliding_window, page_size) + 1
 
 
-def state_bytes_per_slot(spec: ModelSpec, dtype_bytes: int) -> int:
-    """Bytes one slot's row holds over all recurrent layers."""
-    tile, tail = _state_shapes(spec)
-    return spec.linear_layers * (
-        math.prod(tile) * 4 + math.prod(tail) * dtype_bytes)
+def _ring_shape(spec: ModelSpec, slots: int, page_size: int) -> tuple:
+    return (spec.swa_layers, spec.num_kv_heads,
+            1 + slots * ring_pages(spec, page_size), page_size,
+            spec.head_dim)
 
 
-def _rope(x, positions, spec: ModelSpec):
-    if not spec.use_rope:
+def make_state(spec: ModelSpec, slots: int, dtype, page_size: int = 0
+               ) -> Dict[str, jax.Array]:
+    """What a spec keeps a decode SLOT beside the paged pool, zeros: the
+    recurrent state of every recurrent layer (one row a slot), the K
+    and V rings of every window layer (``page_size`` the pool's)."""
+    out = {}
+    if spec.linear_layers:
+        tile, tail = _state_shapes(spec)
+        lead = (spec.linear_layers, slots)
+        out.update(S=jnp.zeros(lead + tile, jnp.float32),
+                   conv=jnp.zeros(lead + tail, dtype))
+    if spec.swa_layers:
+        shape = _ring_shape(spec, slots, page_size)
+        out.update(ring_k=jnp.zeros(shape, dtype),
+                   ring_v=jnp.zeros(shape, dtype))
+    return out
+
+
+def state_bytes_per_slot(spec: ModelSpec, dtype_bytes: int,
+                         page_size: int = 0) -> int:
+    """Bytes one slot holds over all recurrent layers and all rings."""
+    out = 0
+    if spec.linear_layers:
+        tile, tail = _state_shapes(spec)
+        out += spec.linear_layers * (
+            math.prod(tile) * 4 + math.prod(tail) * dtype_bytes)
+    if spec.swa_layers:
+        _, KV, _, ps, hd = _ring_shape(spec, 1, page_size)
+        out += (spec.swa_layers * 2 * KV * ring_pages(spec, page_size)
+                * ps * hd * dtype_bytes)
+    return out
+
+
+def ring_tables(slots, n_pages: int, rings: int, R: int, first=None,
+                last=None):
+    """The ring's page table: ``[len(slots), n_pages]``, a sequence's
+    page ``p`` (counted from ``first``, default 0) -> its slot's ring
+    page ``1 + slot x R + p mod R``; page 0 (trash) for a slot that is
+    none of the ``rings`` slots (a padding row).  With ``last`` ([B]:
+    the page of each row's last real token) only the pages ``last - R +
+    1 .. last`` keep their place, which are ``R`` different ring pages,
+    and every other goes to the trash: what a prompt pass WRITES."""
+    p = jnp.arange(n_pages, dtype=jnp.int32)[None, :]
+    if first is not None:
+        p = p + first[:, None]
+    slots = slots.astype(jnp.int32)[:, None]
+    keep = (slots >= 0) & (slots < rings)
+    if last is not None:
+        keep &= (p <= last[:, None]) & (p > last[:, None] - R)
+    return jnp.where(keep, 1 + slots * R + p % R, 0)
+
+
+def _rope(x, positions, spec: ModelSpec, rotate: bool = True):
+    if not (spec.use_rope and rotate):
         return x
     return apply_rope(x, positions, spec.rope_theta, spec.rope_scaling,
                       rotary_dim=spec.rotary_dim)
 
 
 @jax.named_scope("qkv")
-def _gated_qkv(normed, lp, spec: ModelSpec, positions):
+def _gated_qkv(normed, lp, spec: ModelSpec, positions, rotate: bool = True):
     """Attention front half on the normed rows: q (with its gate beside
     it, per head ``[query | gate]``, where the spec has one), k, v,
-    per-head norms on q and k, rope, each where the spec says.  normed:
+    per-head norms on q and k, rope, each where the spec says
+    (``rotate`` False: a layer of a kind that takes none).  normed:
     [..., S, D] with positions [..., S]."""
     eps, uo = spec.rms_eps, spec.unit_offset_norm
     H, KV, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
@@ -342,7 +478,8 @@ def _gated_qkv(normed, lp, spec: ModelSpec, positions):
     if spec.qk_norm:
         q = rms_norm(q, lp["q_norm"], eps, uo)
         k = rms_norm(k, lp["k_norm"], eps, uo)
-    return _rope(q, positions, spec), _rope(k, positions, spec), v, gate
+    return (_rope(q, positions, spec, rotate),
+            _rope(k, positions, spec, rotate), v, gate)
 
 
 @jax.named_scope("o_proj")
@@ -661,16 +798,20 @@ def _segments(blocks):
 
 def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
                  block_fn):
-    """THE stack walker: a scan over periods, each the spec's sub-blocks
-    in order.  ``block_fn(kind, normed, lp, kp, vp, st, index, stack)``
-    -> ``(out, kp, vp, st, stats | None)`` computes one sub-block on the
-    normed rows: ``index`` is the layer's index among the layers of its
-    kind over the whole stack (the pools' layer for ``attn``, the
-    state's for a recurrent kind, the expert stacks' for ``moe``), ``lp``
-    its tensors without the experts' stacks, which stay outside the
-    scanned slices (``stack``: the group's, ``[layers, E, ., .]``).
+    """THE stack walker: the leading layers once (a ``window_pattern``
+    spec's ``layers["lead"]``, each its own tensors), then a scan over
+    periods, each the spec's sub-blocks in order.  ``block_fn(kind,
+    normed, lp, kp, vp, st, index, stack)`` -> ``(out, kp, vp, st, stats
+    | None)`` computes one sub-block on the normed rows: ``index`` is
+    the layer's index among the layers of its kind over the whole stack,
+    leading ones first (the pools' layer for ``attn``, the state's for a
+    recurrent kind, the rings' for ``swa``); for ``moe`` it indexes
+    ``stack``, the expert matrices of the layer's group ``[layers, E, .,
+    .]`` (a leading layer's own, ``[1, E, ., .]``), which stay outside
+    the scanned slices; ``lp`` is the layer's tensors without them.
     Returns (x, k_pages, v_pages, state, stats [4])."""
     layers = dict(params["layers"])
+    lead = layers.pop("lead", ())
     if "full" in layers:  # one attention layer a period: [P, ...]
         layers["full"] = jax.tree.map(lambda a: a[:, None], layers["full"])
     names = spec.expert_stacks
@@ -683,13 +824,30 @@ def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
     count = {g: spec.group_layers(g) for g in layers}
     uo = spec.unit_offset_norm
 
-    def run(carry, block, lp, index):
-        kind, group, norm, _ = block
+    def run(carry, block, lp, index, stack):
+        kind, _, norm, _ = block
         h, kp, vp, st = carry
         normed = rms_norm(h, lp[norm], spec.rms_eps, uo)
         out, kp, vp, st, stats = block_fn(
-            kind, normed, lp, kp, vp, st, index, stacks[group])
+            kind, normed, lp, kp, vp, st, index, stack)
         return (h + out.astype(h.dtype), kp, vp, st), stats
+
+    # the leading layers, unrolled: layer i's mixer is the i-th of its
+    # kind, its experts a stack of one
+    carry, lead_stats, base = (x0, k_pages, v_pages, state), [], {}
+    for lp, kinds in zip(lead, spec.lead_blocks):
+        for kind, norm in zip(kinds, ("input_norm", "post_norm")):
+            own = kind == "moe"
+            carry, s = run(
+                carry, (kind, "lead", norm, 0),
+                {k: v for k, v in lp.items() if not own or k not in names},
+                0 if own else base.get(kind, 0),
+                {k: jax.tree.map(lambda a: a[None], lp[k])
+                 for k in names} if own else None)
+            base[kind] = base.get(kind, 0) + 1
+            if s is not None:
+                lead_stats.append(s)
+    base.pop("moe", None)  # a group's expert stacks start at its own 0
 
     def period(carry, xs):
         per, p = xs
@@ -708,7 +866,9 @@ def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
                     g, local = b[1], b[3] - first[b[1]]
                     lp = jax.tree.map(lambda a: a[local], lps[g])
                     index = p * count[g] + first[g] + j * width[g] + local
-                    c, s = run(c, b, lp, index)
+                    if base.get(b[0]):  # behind the leading layers' own
+                        index = base[b[0]] + index
+                    c, s = run(c, b, lp, index, stacks[g])
                     if s is not None:
                         stats.append(s)
                 return c, (jnp.stack(stats) if stats
@@ -721,21 +881,27 @@ def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
                     (repeats, width[g]) + a.shape[1:]), per[g])
                 for g in first}
             js = jnp.arange(repeats, dtype=jnp.int32)
-            if repeats > 1:
+            if repeats > 2:
                 carry, stats = jax.lax.scan(unit_fn, carry, (lps, js))
                 stats = stats.reshape(-1, len(STAT_NAMES))
-            else:
-                carry, stats = unit_fn(
-                    carry, (jax.tree.map(lambda a: a[0], lps), js[0]))
+            else:  # once, or twice: a scan's slices of the weights are
+                # copies, which two bodies cost less than
+                for j in range(repeats):
+                    carry, stats = unit_fn(
+                        carry, (jax.tree.map(lambda a: a[j], lps), js[j]))
+                    all_stats.append(stats)
+                continue
             all_stats.append(stats)
         return carry, jnp.concatenate(all_stats)
 
     (x, k_pages, v_pages, state), stats = jax.lax.scan(
-        period, (x0, k_pages, v_pages, state),
+        period, carry,
         (light, jnp.arange(spec.num_periods, dtype=jnp.int32)),
     )
-    stats = combine_stats(stats.reshape(-1, len(STAT_NAMES)))
-    return x, k_pages, v_pages, state, stats
+    stats = stats.reshape(-1, len(STAT_NAMES))
+    if lead_stats:
+        stats = jnp.concatenate([jnp.stack(lead_stats), stats])
+    return x, k_pages, v_pages, state, combine_stats(stats)
 
 
 def _experts(normed, lp, spec: ModelSpec, row_mask, use_pallas, index,
@@ -748,16 +914,61 @@ def _experts(normed, lp, spec: ModelSpec, row_mask, use_pallas, index,
     )
 
 
+@jax.named_scope("dense_mlp")
+def _dense(normed, lp, spec: ModelSpec):
+    from vgate_tpu.models.decoder import _dense_mlp
+
+    return _dense_mlp(normed, lp, spec)
+
+
+def _attn_scope(spec: ModelSpec) -> str:
+    """The full-attention sub-block's scope in a trace."""
+    return "full_attn" if spec.window_pattern else "gated_attn"
+
+
+def _swa_chunk_attend(q, k, v, ring_k, ring_v, index, spec: ModelSpec,
+                      slots, prefix_lens, lens):
+    """Window attention of a LATER chunk's rows (q, k, v [B, S, ., hd],
+    starting at ``prefix_lens``, page-aligned): against the slot's ring
+    as the chunks before left it (the ``R`` pages before the chunk's
+    first, gathered in position order) and the chunk's own rows.  The
+    blockwise ``jax.numpy`` attention: no kernel yet for query rows
+    against a ring."""
+    ps = ring_k.shape[-2]
+    R = ring_pages(spec, ps)
+    rings = (ring_k.shape[2] - 1) // R
+    first = prefix_lens // ps
+    tables = ring_tables(slots, R, rings, R, first=first - R)
+    past = lambda ring: jnp.transpose(
+        gather_pages(ring, tables, layer=index), (1, 2, 3, 0, 4)).reshape(
+            q.shape[0], R * ps, ring.shape[1], ring.shape[-1])
+    keys = jnp.concatenate([past(ring_k), k], axis=1)
+    vals = jnp.concatenate([past(ring_v), v], axis=1)
+    held = jnp.full_like(prefix_lens, R * ps)
+    return flash_prefill_attention(
+        q, keys, vals, held + lens, q_offset=held,
+        window=spec.sliding_window,
+        # ring rows of positions before the sequence's first are nobody's
+        k_start=held - jnp.minimum(prefix_lens, held),
+        block_k=ps,
+    )
+
+
 def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
                    v_pages, state, slots, fresh, write_tables, attend,
-                   use_pallas: bool, ctx_tables=None):
+                   use_pallas: bool, ctx_tables=None, swa_attend=None,
+                   prefix_lens=None):
     """The prompt pass over embedded rows x [B, S, D] (a whole prompt,
     or the suffix / one chunk of one).  ``write_tables`` are the pages
     the rows' K/V go to (whole pages from the rows' first position);
     ``attend(q, k, v, kp, vp, layer)`` is the attention the caller
     chose.  ``ctx_tables`` (a suffix or a chunk of a latent-attention
     spec) are the pages of the whole context, whose rows K and V are
-    then expanded from.  Returns (x, k_pages, v_pages, state)."""
+    then expanded from.  A window layer attends in flight
+    (``swa_attend(q, k, v)``, a whole prompt's) or, for rows that start
+    at ``prefix_lens``, against the ring and themselves, and leaves the
+    rows that survive in the slot's ring.  Returns (x, k_pages, v_pages,
+    state)."""
     B, S = x.shape[:2]
     ps = k_pages.shape[-2]
     KV, hd = spec.cache_heads, spec.cache_head_dim
@@ -765,12 +976,21 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
     row_mask = jnp.arange(S)[None, :] < lens[:, None]
     to_pages = lambda t: jnp.transpose(
         t.reshape(B, n_pages, ps, KV, hd), (0, 1, 3, 2, 4))
+    if spec.swa_layers:
+        R = ring_pages(spec, ps)
+        start = 0 if prefix_lens is None else prefix_lens
+        ring_write = ring_tables(
+            slots, n_pages, (state["ring_k"].shape[2] - 1) // R, R,
+            first=None if prefix_lens is None else prefix_lens // ps,
+            last=(start + lens - 1) // ps)
 
     def block_fn(kind, normed, lp, kp, vp, st, index, stack):
         if kind == "moe":
             out, stats = _experts(normed, lp, spec, row_mask, use_pallas,
                                   index, stack)
             return out, kp, vp, st, stats
+        if kind == "mlp":
+            return _dense(normed, lp, spec), kp, vp, st, None
         if kind in _RECURRENT:
             out, st = _recurrent_prompt(kind, normed, lp, st, index, spec,
                                         lens, slots, fresh)
@@ -781,8 +1001,25 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
                                       index, write_tables, ctx_tables,
                                       attend)
             return out, kp, vp, st, None
-        with jax.named_scope("gated_attn"):
-            q, k, v, gate = _gated_qkv(normed, lp, spec, positions)
+        if kind == "swa":
+            with jax.named_scope("swa_attn"):
+                q, k, v, _ = _gated_qkv(normed, lp, spec, positions)
+                rk, rv = st["ring_k"], st["ring_v"]
+                with jax.named_scope("attention"):
+                    attn = (swa_attend(q, k, v) if prefix_lens is None
+                            else _swa_chunk_attend(
+                                q, k, v, rk, rv, index, spec, slots,
+                                prefix_lens, lens))
+                st = {**st,
+                      "ring_k": kv_write_pages(
+                          rk, ring_write, to_pages(k), layer=index),
+                      "ring_v": kv_write_pages(
+                          rv, ring_write, to_pages(v), layer=index)}
+                out = _gated_out(attn, None, lp, normed.dtype)
+            return out, kp, vp, st, None
+        with jax.named_scope(_attn_scope(spec)):
+            q, k, v, gate = _gated_qkv(normed, lp, spec, positions,
+                                       spec.global_rope)
             pt = write_tables[:, :n_pages]
             kp = kv_write_pages(kp, pt, to_pages(k), layer=index)
             vp = kv_write_pages(vp, pt, to_pages(v), layer=index)
@@ -797,19 +1034,32 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
 
 
 def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
-                   state, active, write_attend, use_pallas: bool):
+                   state, active, write_attend, use_pallas: bool,
+                   ring_write_attend=None):
     """One decode step over embedded rows x [B, D], row = slot.
     ``write_attend(q, k, v, kp, vp, layer)`` is the caller's cache step:
-    the token's K and V into the pool and its attention over it.
+    the token's K and V into the pool and its attention over it;
+    ``ring_write_attend`` the same over a window layer's rings.
     Returns (x, k_pages, v_pages, state, stats [4])."""
     if active is None:
         active = jnp.ones(x.shape[:1], bool)
+
+    def attention(normed, lp, cache_step, k_cache, v_cache, index, rotate):
+        q, k, v, gate = _gated_qkv(
+            normed[:, None], lp, spec, positions[:, None], rotate)
+        attn, k_cache, v_cache = cache_step(
+            q[:, 0], k[:, 0], v[:, 0], k_cache, v_cache, index)
+        out = _gated_out(attn, None if gate is None else gate[:, 0],
+                         lp, normed.dtype)
+        return out, k_cache, v_cache
 
     def block_fn(kind, normed, lp, kp, vp, st, index, stack):
         if kind == "moe":
             out, stats = _experts(normed, lp, spec, active, use_pallas,
                                   index, stack)
             return out, kp, vp, st, stats
+        if kind == "mlp":
+            return _dense(normed, lp, spec), kp, vp, st, None
         if kind in _RECURRENT:
             _, step_fn, scope = _RECURRENT[kind]
             with jax.named_scope(scope):
@@ -821,13 +1071,15 @@ def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
                 out, kp, vp = _mla_step(normed, lp, spec, positions, kp,
                                         vp, index, write_attend)
             return out, kp, vp, st, None
-        with jax.named_scope("gated_attn"):
-            q, k, v, gate = _gated_qkv(
-                normed[:, None], lp, spec, positions[:, None])
-            attn, kp, vp = write_attend(
-                q[:, 0], k[:, 0], v[:, 0], kp, vp, index)
-            out = _gated_out(attn, None if gate is None else gate[:, 0],
-                             lp, normed.dtype)
+        if kind == "swa":
+            with jax.named_scope("swa_attn"):
+                out, rk, rv = attention(
+                    normed, lp, ring_write_attend, st["ring_k"],
+                    st["ring_v"], index, True)
+            return out, kp, vp, {**st, "ring_k": rk, "ring_v": rv}, None
+        with jax.named_scope(_attn_scope(spec)):
+            out, kp, vp = attention(normed, lp, write_attend, kp, vp,
+                                    index, spec.global_rope)
         return out, kp, vp, st, None
 
     return _period_scan(

@@ -140,6 +140,17 @@ class ModelSpec:
     yarn_mscale: float = 1.0
     yarn_mscale_all_dim: float = 0.0
     llama_4_scaling_beta: float = 0.0
+    # ---- window and full attention layers in ONE stack (K-EXAONE): one
+    # letter a layer by period, ``L`` a layer that attends to the last
+    # ``sliding_window`` tokens and keeps them in a per-slot RING
+    # (models/hybrid.py: no page a token), ``G`` a full layer over the
+    # paged pool.  Empty = Gemma-2's even/odd rule above, a mask only.
+    # ``global_rope`` False: the full layers take no rotary
+    window_pattern: str = ""
+    global_rope: bool = True
+    # leading layers whose feed-forward is a dense SwiGLU of
+    # ``intermediate_size`` (DeepSeek's key ``first_k_dense_replace``)
+    first_k_dense: int = 0
 
     def __post_init__(self):
         if self.n_shared_experts and not self.shared_expert_intermediate_size:
@@ -164,7 +175,7 @@ class ModelSpec:
         recurrent layers beside attention ones, a per-slot recurrent
         state beside the paged pool."""
         return (bool(self.layer_pattern) or self.full_attention_interval > 1
-                or self.is_mla)
+                or self.is_mla or bool(self.window_pattern))
 
     @property
     def is_mla(self) -> bool:
@@ -206,9 +217,10 @@ class ModelSpec:
     def rope_parameters(self) -> dict:
         """The YaRN group as the published config.json spells it (what
         perfbench/serve.py holds the program to)."""
-        if self.yarn_factor <= 0:
-            return {}
         num = lambda v: int(v) if float(v).is_integer() else v
+        if self.yarn_factor <= 0:
+            return {"rope_theta": num(self.rope_theta),
+                    "rope_type": "default"} if self.window_pattern else {}
         return {
             "beta_fast": num(self.yarn_beta_fast),
             "beta_slow": num(self.yarn_beta_slow),
@@ -227,6 +239,8 @@ class ModelSpec:
         """Layers of one period: the pattern's smallest repeating unit
         (the whole of a pattern that does not repeat)."""
         pat = self.layer_pattern
+        if self.window_pattern:
+            return len(self.window_pattern)
         if not pat:
             return max(1, self.full_attention_interval)
         return next(n for n in range(1, len(pat) + 1)
@@ -242,6 +256,17 @@ class ModelSpec:
         layer's index inside the group's period."""
         if not self.is_hybrid:
             return ()
+        if self.window_pattern:  # the layers that follow the leading ones
+            out, seen = [], {}
+            lead = self.lead_layers
+            for i in range(lead, lead + self.layers_per_period):
+                mixer, ff = self._window_layer(i)
+                group = "window" if mixer == "swa" else "global"
+                j = seen.setdefault(group, 0)
+                seen[group] = j + 1
+                out += [(mixer, group, "input_norm", j),
+                        (ff, group, "post_norm", j)]
+            return tuple(out)
         if self.is_mla:  # every layer: latent attention, then experts
             return (("mla", "layer", "input_norm", 0),
                     ("moe", "layer", "post_norm", 0))
@@ -259,17 +284,42 @@ class ModelSpec:
             seen[kind] = seen.get(kind, 0) + 1
         return tuple(out)
 
+    def _window_layer(self, i: int) -> tuple:
+        """Layer ``i`` of a ``window_pattern`` spec as its two
+        sub-blocks' kinds: (``swa`` | ``attn``, ``mlp`` | ``moe``)."""
+        pat = self.window_pattern
+        return ("swa" if pat[i % len(pat)] == "L" else "attn",
+                "mlp" if i < self.first_k_dense else "moe")
+
+    @property
+    def lead_layers(self) -> int:
+        """Layers the stack walker runs once, ahead of the scanned
+        periods: the leading dense ones, and as many more as leave a
+        whole number of periods behind them."""
+        if not self.window_pattern:
+            return 0
+        lead = self.first_k_dense
+        while (self.num_layers - lead) % len(self.window_pattern):
+            lead += 1
+        return lead
+
+    @property
+    def lead_blocks(self) -> tuple:
+        """The leading layers, each its two sub-blocks' kinds."""
+        return tuple(self._window_layer(i) for i in range(self.lead_layers))
+
     def group_layers(self, group: str) -> int:
         """Layers a period holds in a parameter group."""
         return len({b[3] for b in self.period_blocks if b[1] == group})
 
     def _layers_of(self, *kinds) -> int:
-        return self.num_periods * sum(
+        lead = sum(k in kinds for pair in self.lead_blocks for k in pair)
+        return lead + self.num_periods * sum(
             b[0] in kinds for b in self.period_blocks)
 
     @property
     def num_periods(self) -> int:
-        return self.num_layers // self.layers_per_period
+        return (self.num_layers - self.lead_layers) // self.layers_per_period
 
     @property
     def attn_layers(self) -> int:
@@ -281,6 +331,18 @@ class ModelSpec:
     def linear_layers(self) -> int:
         """Layers that hold a recurrent state, of either kind."""
         return self._layers_of("gdn", "mamba")
+
+    @property
+    def swa_layers(self) -> int:
+        """Window layers whose K/V is the slot's ring, not pages."""
+        return self._layers_of("swa")
+
+    @property
+    def slot_state_layers(self) -> int:
+        """Layers whose cache is a row a decode SLOT beside the paged
+        pool (recurrent state or ring): what pages alone cannot move,
+        share or roll back."""
+        return self.linear_layers + self.swa_layers
 
     @property
     def moe_layers(self) -> int:
@@ -354,6 +416,8 @@ class ModelSpec:
         D, L, F = self.hidden_size, self.num_layers, self.intermediate_size
         if self.layer_pattern:
             return self._pattern_params()
+        if self.window_pattern:
+            return self._window_params()
         q_dim = self.num_heads * self.head_dim
         kv_dim = self.num_kv_heads * self.head_dim
         attn = D * q_dim + 2 * D * kv_dim + q_dim * D
@@ -414,17 +478,58 @@ class ModelSpec:
             per[b[0]] + D for b in self.period_blocks)
         return stack + (1 if self.tie_embeddings else 2) * V * D + D
 
+    def _window_params(self) -> int:
+        """``num_params`` of a ``window_pattern`` stack: every layer GQA
+        attention (window or full, the same tensors) and its two norms,
+        then a dense SwiGLU (the leading layers) or the expert layer."""
+        D, V, hd = self.hidden_size, self.vocab_size, self.head_dim
+        attn = 2 * D * self.q_dim + 2 * D * self.kv_dim + 2 * D
+        if self.qk_norm:
+            attn += 2 * hd
+        Fe, Fs = self.expert_width, self.shared_expert_intermediate_size
+        moe = (D * self.router_experts + self.num_experts * 3 * D * Fe
+               + 3 * D * Fs)
+        if self.router_scoring == "sigmoid":
+            moe += self.router_experts
+        dense = self.first_k_dense
+        return (self.num_layers * attn
+                + dense * 3 * D * self.intermediate_size
+                + (self.num_layers - dense) * moe
+                + (1 if self.tie_embeddings else 2) * V * D + D)
+
     @property
     def layer_windows(self) -> tuple:
-        """Per-layer attention window (0 = global).  Gemma-2 alternates:
-        even-indexed layers are sliding-window, odd layers are global
-        (HF ``Gemma2Config.layer_types``)."""
+        """Per-layer attention window (0 = global): ``window_pattern``'s
+        letters, or Gemma-2's alternation: even-indexed layers are
+        sliding-window, odd layers are global (HF
+        ``Gemma2Config.layer_types``)."""
         if self.sliding_window <= 0:
             return tuple(0 for _ in range(self.num_layers))
+        if self.window_pattern:
+            return tuple(
+                self.sliding_window
+                if self._window_layer(i)[0] == "swa" else 0
+                for i in range(self.num_layers))
         return tuple(
             self.sliding_window if i % 2 == 0 else 0
             for i in range(self.num_layers)
         )
+
+    # a ``window_pattern`` spec's layers as the published config.json
+    # lists them (what perfbench/serve.py holds the program to)
+    @property
+    def layer_types(self) -> list:
+        return ["sliding_attention" if w else "full_attention"
+                for w in self.layer_windows]
+
+    @property
+    def sliding_windows(self) -> list:
+        return list(self.layer_windows)
+
+    @property
+    def mlp_layer_types(self) -> list:
+        return ["dense" if i < self.first_k_dense else "sparse"
+                for i in range(self.num_layers)]
 
     def check_expert_share(self) -> None:
         """The held experts lie inside the router's width."""
@@ -823,6 +928,45 @@ MISTRAL_SMALL4_119B = _register(
     )
 )
 
+# Published sizes (config.json, model_type exaone_moe; the multi-token-
+# prediction module is not part of this spec).  Stop ids: the catalog
+# row's config has none (assumed: 2 / 1).  Not in the config and assumed
+# (the cut's configuration file says from what): pre-norm residual
+# sub-blocks, per-head RMSNorm on q and k, rotary on the window layers
+# only, a selection bias on the router's sigmoid scores
+K_EXAONE_236B = _register(
+    ModelSpec(
+        name="LGAI-EXAONE/K-EXAONE-236B-A23B",
+        vocab_size=153600,
+        hidden_size=6144,
+        num_layers=48,
+        num_heads=64,
+        num_kv_heads=8,
+        head_dim=128,
+        intermediate_size=18432,  # the leading dense layer's
+        rope_theta=1_000_000.0,
+        rms_eps=1e-5,
+        qkv_bias=False,
+        tie_embeddings=False,
+        eos_token_id=2,
+        bos_token_id=1,
+        max_position_embeddings=262144,
+        num_experts=128,
+        experts_per_token=8,
+        moe_intermediate_size=2048,
+        router_width=128,
+        shared_expert_gate=False,
+        n_shared_experts=1,
+        router_scoring="sigmoid",
+        routed_scaling_factor=2.5,
+        qk_norm=True,
+        sliding_window=128,
+        window_pattern="LLLG",
+        global_rope=False,
+        first_k_dense=1,
+    )
+)
+
 BGE_BASE = _register(
     ModelSpec(
         name="BAAI/bge-base-en-v1.5",
@@ -1015,6 +1159,43 @@ TINY_MLA_MOE = _register(
         yarn_mscale=1.0,
         yarn_mscale_all_dim=1.0,
         llama_4_scaling_beta=0.1,
+    )
+)
+
+# every mechanism of K-EXAONE at toy widths: a leading dense layer, then
+# two periods of three window layers (8 tokens) to one full layer, the
+# window layers' K/V a per-slot ring (at page size 4 it wraps within
+# tens of tokens), a sigmoid-routed expert layer with a shared expert
+TINY_SWA_MOE = _register(
+    ModelSpec(
+        name="tiny-swa-moe",
+        vocab_size=512,
+        hidden_size=64,
+        num_layers=9,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        intermediate_size=128,
+        rope_theta=10000.0,
+        rms_eps=1e-5,
+        qkv_bias=False,
+        tie_embeddings=False,
+        eos_token_id=0,
+        bos_token_id=1,
+        max_position_embeddings=4096,
+        num_experts=8,
+        experts_per_token=2,
+        moe_intermediate_size=32,
+        router_width=8,
+        shared_expert_gate=False,
+        n_shared_experts=1,
+        router_scoring="sigmoid",
+        routed_scaling_factor=2.5,
+        qk_norm=True,
+        sliding_window=8,
+        window_pattern="LLLG",
+        global_rope=False,
+        first_k_dense=1,
     )
 )
 

@@ -447,6 +447,7 @@ class PerfRecorder:
         self._moe: Dict[str, int] = {}
         self._state: Dict[str, int] = {}
         self._mla: Dict[str, int] = {}
+        self._swa: Dict[str, int] = {}
         self.total_engine_cpu_s = 0.0
         self.total_engine_cpu_in_wait_s = 0.0
         # the flight recorder's per-request phase sums (admitted /
@@ -790,6 +791,48 @@ class PerfRecorder:
         ):
             self._mla[name] = self._mla.get(name, 0) + add
 
+    def note_swa_decode(self, steps: int, lens, layers: int,
+                        window: int) -> None:
+        """One decode chunk of a spec with window layers, booked once
+        per readback: the sequences of lengths ``lens`` (at dispatch)
+        rode ``steps`` steps, and step k read the last ``window`` rows
+        of each one's ring (its whole length so far while that is
+        shorter) in each of ``layers`` window layers, one kernel launch
+        a layer a step, and wrote one row."""
+        reads = sum(
+            steps * window if n >= window
+            else sum(min(n + k, window) for k in range(steps))
+            for n in lens)
+        for name, add in (
+            ("decode_launches", steps * layers),
+            ("decode_row_reads", reads * layers),
+            ("decode_steps", steps),
+            ("ring_rows_written_decode", steps * len(lens) * layers),
+        ):
+            self._swa[name] = self._swa.get(name, 0) + add
+
+    def note_swa_prefill(self, tokens: int, cached: int, layers: int,
+                         ring_tokens: int, page_size: int) -> None:
+        """One prompt pass of a spec with window layers, booked at its
+        readback: positions ``cached .. tokens - 1`` went through it;
+        the rows of the last ``ring_tokens / page_size`` pages that hold
+        a real token went to the slot's ring in each of ``layers``
+        window layers, the earlier rows to the trash page; position i
+        attended to ``min(i + 1, window)`` keys (counted by the
+        benchmark's shapes module from ``prefill_rows``)."""
+        last = (tokens - 1) // page_size
+        first_kept = max(cached, (last + 1) * page_size - ring_tokens, 0)
+        kept = max(0, tokens - first_kept)
+        rows = tokens - cached
+        for name, add in (
+            ("prefill_launches", layers),
+            ("prefill_rows", rows * layers),
+            ("prefill_prompts", 1),
+            ("ring_rows_written_prefill", kept * layers),
+            ("prefill_rows_to_trash", (rows - kept) * layers),
+        ):
+            self._swa[name] = self._swa.get(name, 0) + add
+
     def tick_end(self, worked: bool) -> None:
         """Close the tick: derive ``host_s`` as the unexplained wall
         remainder (clamped at 0 — the explained phases can overshoot
@@ -1021,6 +1064,8 @@ class PerfRecorder:
             out["state"] = dict(self._state)
         if self._mla:
             out["mla"] = dict(self._mla)
+        if self._swa:
+            out["swa"] = dict(self._swa)
         return out
 
     def snapshot(self) -> Dict[str, Any]:

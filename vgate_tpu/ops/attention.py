@@ -87,6 +87,7 @@ def flash_prefill_attention(
     softcap: float = 0.0,
     window=None,  # int32 scalar; >0 => attend only to the last `window` keys
     scale=None,  # query scale; default hd**-0.5
+    k_start=None,  # [B] int32: keys before this index are nobody's
 ) -> jnp.ndarray:
     """Blockwise causal attention with online softmax. Returns [B, S, H, hd].
 
@@ -131,6 +132,8 @@ def flash_prefill_attention(
         if window is not None:
             dist = q_pos[:, :, None] - k_pos[None, None, :]
             mask = mask & ((window <= 0) | (dist < window))
+        if k_start is not None:
+            mask = mask & (k_pos[None, None, :] >= k_start[:, None, None])
         scores = jnp.einsum(
             "bshd,bthd->bsth", q32, k_blk,
             preferred_element_type=jnp.float32,

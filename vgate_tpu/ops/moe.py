@@ -58,11 +58,16 @@ def _column_tile(K: int, N: int, itemsize: int) -> int:
     """Columns of an expert's matrix a program of the grouped product
     holds: all of them while the matrix is at most 2 MiB, else 512, or
     where 512 does not divide them (2,688 = 21 x 128) the widest
-    multiple of 128 up to 1,024 that does."""
+    multiple of 128 up to 1,024 that does; halved while a block of
+    them is over 4 MiB (a contraction of 6,144 takes 256: at 512 the
+    v5e's compiler finds no room for the block in fast memory)."""
     if K * N * itemsize <= (2 << 20):
         return N
-    return next(t for t in (512, 1024, 896, 768, 640, 384, 256, 128, N)
+    tile = next(t for t in (512, 1024, 896, 768, 640, 384, 256, 128, N)
                 if N % t == 0)
+    while K * tile * itemsize > (4 << 20) and tile % 256 == 0:
+        tile //= 2
+    return tile
 
 
 def grouped_product(rows, w, group_sizes, layer, use_pallas: bool):
@@ -92,17 +97,32 @@ def grouped_product(rows, w, group_sizes, layer, use_pallas: bool):
 # rows a call routes at once: a prompt wave of 8 x 2,048 tokens would
 # otherwise hold (tokens x choices) x hidden temporaries of gigabytes
 BLOCK_TOKENS = 4096
+# (row, choice) x width values a block may hold, which is what its
+# float32 temporaries go by: the most a block has been run with on the
+# chip (4,096 rows x 22 choices of a 1,024-wide latent)
+BLOCK_VALUES = BLOCK_TOKENS * 22 * 1024
+
+
+def block_tokens(spec: ModelSpec) -> int:
+    """Rows a block of the expert layer takes: ``BLOCK_TOKENS``, halved
+    while a block would hold more than ``BLOCK_VALUES`` (8 choices of a
+    6,144-wide hidden: 1,024 rows, 512 a held expert of 16)."""
+    rows = BLOCK_TOKENS
+    while rows * spec.experts_per_token * spec.expert_in > BLOCK_VALUES:
+        rows //= 2
+    return rows
 
 
 def expert_layer(x, lp, spec: ModelSpec, act, row_mask=None,
                  use_pallas: bool = False, layer=None, stack=None):
-    """``_expert_block`` over blocks of ``BLOCK_TOKENS`` rows (one block
-    for a decode step or a small wave): the weights are read once a
-    block, the temporaries stay bounded.  Arguments and result as
+    """``_expert_block`` over blocks of ``block_tokens(spec)`` rows (one
+    block for a decode step or a small wave): the weights are read once
+    a block, the temporaries stay bounded.  Arguments and result as
     ``_expert_block``."""
     D = x.shape[-1]
     T = x.size // D
-    if T <= BLOCK_TOKENS:
+    block = block_tokens(spec)
+    if T <= block:
         return _expert_block(x, lp, spec, act, row_mask, use_pallas,
                              layer, stack)
     # blocks unrolled: XLA runs them one after the other and reuses one
@@ -110,10 +130,10 @@ def expert_layer(x, lp, spec: ModelSpec, act, row_mask=None,
     xt = x.reshape(T, D)
     mask = None if row_mask is None else row_mask.reshape(T)
     outs, stats = [], []
-    for lo in range(0, T, BLOCK_TOKENS):
+    for lo in range(0, T, block):
         out, st = _expert_block(
-            xt[lo:lo + BLOCK_TOKENS], lp, spec, act,
-            None if mask is None else mask[lo:lo + BLOCK_TOKENS],
+            xt[lo:lo + block], lp, spec, act,
+            None if mask is None else mask[lo:lo + block],
             use_pallas, layer, stack,
         )
         outs.append(out)

@@ -17,6 +17,12 @@ k-blocks (entirely above the diagonal) skip their compute via ``pl.when``.
 
 Supports chunked prefill via ``q_offsets``: the query rows may start at a
 nonzero global position while keys cover the context from position 0.
+
+A window layer (``swa_prefill_attention_pallas``) walks a BAND: its grid's
+key axis holds only the ``band`` key blocks that end at a query block's own
+last one, which is all a window of ``window`` keys reaches, so a prompt of
+8,192 rows costs what 128 + a block's own rows of keys cost a block, not a
+sweep of every block below the diagonal.
 """
 
 from __future__ import annotations
@@ -51,6 +57,7 @@ def _kernel(
     n_k: int,
     softcap: float,
     scale: float,
+    band: int = 0,
 ):
     b = pl.program_id(0)
     qi = pl.program_id(2)
@@ -58,6 +65,10 @@ def _kernel(
     seq_len = seq_lens_ref[b]
     q_off = q_offsets_ref[b]
     window = window_ref[0]
+    # the key block this step holds: the grid's own, or in a band the
+    # one ``band - 1 - ki`` before the query block's last (a block before
+    # the first key is the first one again, and dead)
+    kb = ki if not band else (qi + 1) * (block_q // block_k) - band + ki
 
     @pl.when(ki == 0)
     def _():
@@ -67,7 +78,7 @@ def _kernel(
 
     # global positions of this block's queries and keys
     q_start = q_off + qi * block_q
-    k_start = ki * block_k
+    k_start = kb * block_k
 
     # a k-block strictly above the causal diagonal — or entirely below the
     # sliding window of every query row in the block — contributes nothing
@@ -76,7 +87,11 @@ def _kernel(
         k_start + block_k - 1 >= q_start - window + 1
     )
 
-    @pl.when(causal_live & window_live)
+    live = causal_live & window_live
+    if band:
+        live = live & (kb >= 0)
+
+    @pl.when(live)
     def _():
         q = q_ref[0, 0].astype(jnp.float32) * scale  # [block_q, hd]
         k = k_ref[0, 0].astype(jnp.float32)  # [block_k, hd]
@@ -121,7 +136,8 @@ def _kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_q", "block_k", "interpret", "softcap", "scale"),
+    static_argnames=("block_q", "block_k", "interpret", "softcap", "scale",
+                     "band", "name"),
 )
 def flash_prefill_attention_pallas(
     q: jnp.ndarray,  # [B, S, H, hd]
@@ -135,8 +151,12 @@ def flash_prefill_attention_pallas(
     softcap: float = 0.0,
     window=None,  # int32 scalar; >0 => attend only to the last `window`
     scale=None,  # static query scale; default hd**-0.5
+    band: int = 0,  # >0: only the last `band` key blocks of a query block
+    name=None,  # the launch's name in a device trace
 ) -> jnp.ndarray:
-    """Causal (optionally offset) attention. Returns [B, S, H, hd]."""
+    """Causal (optionally offset) attention. Returns [B, S, H, hd].
+    ``band`` (with ``window``, no ``q_offsets`` and ``block_q`` a
+    multiple of ``block_k``): see the module's text."""
     B, S, H, hd = q.shape
     Sk, KV = k.shape[1], k.shape[2]
     G = H // KV
@@ -147,6 +167,14 @@ def flash_prefill_attention_pallas(
             f"S={S}/Sk={Sk} must divide block_q={block_q}/block_k={block_k}"
         )
     n_q, n_k = S // block_q, Sk // block_k
+    if band:
+        if q_offsets is not None or block_q % block_k or Sk != S:
+            raise ValueError("a band needs rows from position 0, keys of "
+                             "their own and block_q a multiple of block_k")
+        n_k = min(band, n_k)
+    ratio = block_q // block_k
+    k_block = (lambda qi, ki: ki) if not band else (
+        lambda qi, ki: jnp.maximum((qi + 1) * ratio - n_k + ki, 0))
     if q_offsets is None:
         q_offsets = jnp.zeros((B,), jnp.int32)
     if window is None:
@@ -163,6 +191,7 @@ def flash_prefill_attention_pallas(
         _kernel, block_q=block_q, block_k=block_k, n_k=n_k,
         softcap=float(softcap),
         scale=float(scale) if scale is not None else hd ** -0.5,
+        band=n_k if band else 0,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -175,12 +204,12 @@ def flash_prefill_attention_pallas(
             ),
             pl.BlockSpec(
                 (1, 1, block_k, hd),
-                lambda b, h, qi, ki, *pf: (b, h // G, ki, 0),
+                lambda b, h, qi, ki, *pf: (b, h // G, k_block(qi, ki), 0),
                 memory_space=pltpu.VMEM,
             ),
             pl.BlockSpec(
                 (1, 1, block_k, hd),
-                lambda b, h, qi, ki, *pf: (b, h // G, ki, 0),
+                lambda b, h, qi, ki, *pf: (b, h // G, k_block(qi, ki), 0),
                 memory_space=pltpu.VMEM,
             ),
         ],
@@ -205,8 +234,36 @@ def flash_prefill_attention_pallas(
                                  "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
+        name=name,
     )(
         seq_lens.astype(jnp.int32), q_offsets.astype(jnp.int32),
         window_arr, qt, kt, vt,
     )
     return jnp.transpose(out, (0, 2, 1, 3))
+
+
+# a window layer's blocks: 128-row key blocks (the window's own size at
+# the published 128: a band's first block is the only one the window
+# cuts) under query blocks of up to 256 rows, so a query block visits
+# 128 + its own 256 keys.  On the v5e at 8,192 rows x 64 heads
+# (benchmarks/bench_kernels.py swa_prefill): 6.0 ms, against 7.3 at
+# (128, 128), 11.5 at (512, 128), 7.0 at (256, 256) and 6.6 for the
+# window as a mask under 1,024-row blocks
+SWA_BLOCK_Q, SWA_BLOCK_K = 256, 128
+
+
+def swa_prefill_attention_pallas(q, k, v, seq_lens, window: int,
+                                 block_q: int = SWA_BLOCK_Q,
+                                 block_k: int = SWA_BLOCK_K, **kw):
+    """A window layer's prompt attention ([B, S, H, hd], every row
+    attending to its last ``window`` keys, a static count): the flash
+    kernel over a band of ``block_q // block_k + ceil((window - 1) /
+    block_k)`` key blocks a query block, launched under a name of its
+    own so that a device trace tells it from a full layer's."""
+    S = q.shape[1]
+    block_k = min(block_k, S)
+    block_q = max(block_k, min(block_q, S))
+    band = block_q // block_k + -(-(window - 1) // block_k)
+    return flash_prefill_attention_pallas(
+        q, k, v, seq_lens, block_q=block_q, block_k=block_k,
+        window=window, band=band, name="swa_prefill_attention_pallas", **kw)

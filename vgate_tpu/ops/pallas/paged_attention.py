@@ -717,7 +717,8 @@ def _decode_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("interpret", "softcap", "scale", "items", "hollow"),
+    static_argnames=("interpret", "softcap", "scale", "items", "hollow",
+                     "name"),
 )
 def paged_decode_attention_pallas(
     q: jnp.ndarray,  # [B, H, hd]
@@ -734,6 +735,7 @@ def paged_decode_attention_pallas(
     scale=None,  # static query scale; default hd**-0.5
     items: int = 0,  # work-list items a loop trip serves; 0: _decode_sizes
     hollow: bool = False,  # the probe's: _decode_kernel
+    name=None,  # the launch's name in a device trace
 ):
     """Single-token decode attention over the paged pool, [B, H, hd].
 
@@ -875,10 +877,24 @@ def paged_decode_attention_pallas(
             # the descriptor; page ids are clamped in the kernel instead
             disable_bounds_checks=True,
         ),
+        name=name,
     )(page_tables, seq_lens, window_arr, layer_arr, *inputs)
     if write:
         return out[0].reshape(B, H, hd), out[1], out[2]
     return out.reshape(B, H, hd)
+
+
+def swa_decode_attention_pallas(q, ring_k, ring_v, ring_tables, seq_lens,
+                                window, **kw):
+    """A window layer's decode attention over the slots' RINGS
+    (models/hybrid.py: ``ring_tables`` sends a sequence's page ``p`` to
+    its slot's ring page ``p mod R``): ``_decode_kernel`` as it stands,
+    whose ``window`` fetches the live pages alone, launched under a name
+    of its own so that a device trace tells a ring's launch from the
+    full layer's."""
+    return paged_decode_attention_pallas(
+        q, ring_k, ring_v, ring_tables, seq_lens, window=window,
+        name="swa_decode_attention_pallas", **kw)
 
 
 @functools.partial(

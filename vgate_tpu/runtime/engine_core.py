@@ -474,6 +474,39 @@ def refuse_unsupported_latent(spec: ModelSpec, config: VGTConfig,
     )
 
 
+def refuse_unsupported_rings(spec: ModelSpec, config: VGTConfig,
+                             mesh) -> None:
+    """Engine-construction gate for a spec whose window layers keep a
+    per-slot RING beside the pool (``ModelSpec.swa_layers``,
+    models/hybrid.py): a sequence's pages are then the full layers' K/V
+    alone, and whatever moves, shares or rolls back pages would leave
+    the rings behind.  Each is refused by name, at boot.  (Prefix
+    matching is not refused but turned off: it is on by default.)"""
+    found = _pages_only_feature(config, mesh) if spec.swa_layers else None
+    if not found:
+        return
+    why = {
+        "mesh": "the rings and their kernel launches are not partitioned "
+                "(dp composes: a replica owns its rings)",
+        "speculative": "the verify program attends K and V pools, and "
+                       "rejected drafts would have to be rolled back out "
+                       "of a ring",
+        "swap": "it parks pages and would leave the rings behind",
+        "roles": "the handoff of a live sequence ships pages and would "
+                 "leave the rings behind",
+        "int8": "the rings and the window layers' prompt pass hold the "
+                "model's float type only",
+        "quant": "the window layers, the dense layer and the grouped "
+                 "expert product take plain weights",
+    }[found[1]]
+    raise ValueError(
+        f"{spec.name} has window layers whose K/V is a per-slot ring "
+        f"(no page a token), which cannot run with {found[0]}: {why}.  "
+        "Chunked prefill, preemption by recompute and journal replay "
+        "rebuild the rings and are supported."
+    )
+
+
 class _EvacRequest:
     """One planned-evacuation command in flight between a caller thread
     (dp drain/rebalance coordinator, admin surface) and the engine
@@ -543,6 +576,7 @@ class EngineCore:
         self.spec.check_expert_share()
         refuse_unsupported_recurrent(self.spec, self.config, self.mesh)
         refuse_unsupported_latent(self.spec, self.config, self.mesh)
+        refuse_unsupported_rings(self.spec, self.config, self.mesh)
         # Pallas kernels require a real TPU backend (tests run interpret-
         # mode kernels separately; the engine's jnp twins serve CPU meshes)
         platform = self.mesh.devices.flat[0].platform
@@ -706,13 +740,15 @@ class EngineCore:
         max_useful = (
             tpu_cfg.max_batch_slots * pages_per_seq + sp_shards
         )
-        # the recurrent state of a hybrid spec: one row a decode slot,
-        # sized before the pool so that the pool gets what is left
+        # what a hybrid spec keeps a decode slot beside the pool (the
+        # recurrent state's row, the window layers' rings), sized
+        # before the pool so that the pool gets what is left
         self._state_dtype = self.dtype
         self._state_slot_bytes = (
             hybrid_state_bytes_per_slot(
-                self.spec, jnp.dtype(self.dtype).itemsize)
-            if self.spec.linear_layers else 0
+                self.spec, jnp.dtype(self.dtype).itemsize,
+                tpu_cfg.kv_page_size)
+            if self.spec.slot_state_layers else 0
         )
         state_bytes = self._state_slot_bytes * tpu_cfg.max_batch_slots
         if tpu_cfg.kv_num_pages:
@@ -771,13 +807,20 @@ class EngineCore:
         self.k_pages, self.v_pages = make_kv_buffers(
             self.geometry, kv_pool_dtype, kv_sharding
         )
-        # None for every spec without recurrent layers: an empty pytree
-        # in the step programs, which then are what they were (as
-        # v_pages is for a latent pool)
+        # None for every spec that keeps nothing a slot: an empty
+        # pytree in the step programs, which then are what they were
+        # (as v_pages is for a latent pool)
         self.state = (
             make_hybrid_state(
-                self.spec, tpu_cfg.max_batch_slots, self._state_dtype)
-            if self.spec.linear_layers else None
+                self.spec, tpu_cfg.max_batch_slots, self._state_dtype,
+                tpu_cfg.kv_page_size)
+            if self.spec.slot_state_layers else None
+        )
+        # tokens a slot's ring holds in one window layer (0: no rings)
+        self._ring_tokens = (
+            (self.state["ring_k"].shape[2] - 1) // tpu_cfg.max_batch_slots
+            * tpu_cfg.kv_page_size
+            if self.spec.swa_layers else 0
         )
         self.allocator = PageAllocator(num_pages, num_shards=sp_shards)
         self.allocator.quantized = self._kv_quant
@@ -794,10 +837,10 @@ class EngineCore:
         mesh_sp = int(self.mesh.shape.get("sp", 1))
         mesh_pp = int(self.mesh.shape.get("pp", 1))
         pc = tpu_cfg.prefix_cache
-        # a prefix hit without the recurrent state that belongs to it
-        # would be wrong: matching is off for a spec with such layers
+        # a prefix hit without the recurrent state, or the rings, that
+        # belong to it would be wrong: matching is off for such a spec
         self.prefix_cache_enabled = bool(
-            pc.enabled and mesh_pp == 1 and not self.spec.linear_layers
+            pc.enabled and mesh_pp == 1 and not self.spec.slot_state_layers
         )
         # radix-tree prefix index (runtime/radix_cache.py): page-granular
         # cross-request sharing with COW partial pages and
@@ -2603,6 +2646,12 @@ class EngineCore:
                             plan.seq.total_len, plan.cached_len,
                             self.spec.attn_layers,
                         )
+                    if self.spec.swa_layers:
+                        self.perf.note_swa_prefill(
+                            plan.seq.total_len, plan.cached_len,
+                            self.spec.swa_layers,
+                            self._ring_tokens, self.geometry.page_size,
+                        )
                     if lp is not None and plan.seq.params.logprobs:
                         self._attach_logprob(plan.seq, lp, 0, row)
                     # a RE-prefill (post-preemption) keeps the original
@@ -3392,6 +3441,13 @@ class EngineCore:
                         ctx_tokens=sum(s.total_len for s, _ in seqs),
                         layers=self.spec.attn_layers,
                     )
+                if self.spec.swa_layers:
+                    self.perf.note_swa_decode(
+                        steps=chunk,
+                        lens=[s.total_len for s, _ in seqs],
+                        layers=self.spec.swa_layers,
+                        window=self.spec.sliding_window,
+                    )
             device_s = wait.seconds
             block_s = device_s + read.seconds
             if self.perf.enabled:
@@ -3534,6 +3590,7 @@ class EngineCore:
             kv_used=self.allocator.num_used,
             kv_free=self.allocator.num_free,
             queue_depth=len(self.scheduler.waiting),
+            **self._ring_tick(),
         )
 
     # --------------------------------------------------------- speculative
@@ -4111,6 +4168,32 @@ class EngineCore:
             return _state_kw(self.state)
         return _state_kw(self.state, jnp.asarray(slots, jnp.int32))
 
+    def _state_cache_kind(self) -> Dict[str, Any]:
+        """What ``/stats -> engine.state_cache`` holds a slot: the
+        recurrent layers' rows, or the window layers' rings."""
+        name = dtype_short_name(self._state_dtype)
+        if self.spec.swa_layers:
+            return {
+                "kind": "ring",
+                "layers": self.spec.swa_layers,
+                "tokens_per_slot": self._ring_tokens,
+                "window": self.spec.sliding_window,
+                "dtype": f"{name} K and V",
+            }
+        return {
+            "linear_layers": self.spec.linear_layers,
+            "kind": self.spec.recurrent_kind,
+            "dtype": f"float32 state, {name} convolution tail",
+        }
+
+    def _ring_tick(self) -> Dict[str, int]:
+        """A flight tick's cache line beside ``kv_used``: the bytes the
+        running sequences' rings hold (none for a spec without them)."""
+        if not self.spec.swa_layers:
+            return {}
+        return {"ring_bytes": (
+            self._state_slot_bytes * len(self.scheduler.running))}
+
     def _set_cache(self, cache) -> None:
         """Take back what a step program returned of the cache."""
         self.k_pages, self.v_pages, *rest = cache
@@ -4156,14 +4239,10 @@ class EngineCore:
                         "slots": self.max_slots,
                         "bytes_per_slot": self._state_slot_bytes,
                         "bytes": self._state_slot_bytes * self.max_slots,
-                        "linear_layers": self.spec.linear_layers,
-                        "kind": self.spec.recurrent_kind,
-                        "dtype": "float32 state, "
-                        f"{dtype_short_name(self._state_dtype)} "
-                        "convolution tail",
+                        **self._state_cache_kind(),
                     }
                 }
-                if self.spec.linear_layers else {}
+                if self.spec.slot_state_layers else {}
             ),
             "weights_bytes": self._params_bytes,
             "model": self.spec.name,

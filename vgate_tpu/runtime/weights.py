@@ -308,12 +308,78 @@ def _mla_params_from_getter(
     return params
 
 
+def _window_params_from_getter(
+    spec: ModelSpec, getter: TensorGetter, dtype
+) -> Params:
+    """``exaone_moe`` names (ASSUMED: the attention and norm names of
+    ``transformers``' EXAONE-4, the expert layer's of DeepSeek-V3, whose
+    config keys the published config's are; no checkpoint was read) ->
+    the pytree of models/hybrid.py for a ``window_pattern`` spec:
+    ``layers = {"lead": (a tree a leading layer, ...), "window" |
+    "global": [P, n, ...]}``, layer ``i`` of the checkpoint in the place
+    ``ModelSpec`` gives it.  A chip's share: the experts ``first_expert
+    ..`` of the router's width, and the first ``vocab_size`` rows of
+    embedding and head.  The multi-token-prediction module's tensors are
+    not read."""
+    E, first = spec.num_experts, spec.first_expert
+    get = lambda i, name: np.asarray(getter(f"model.layers.{i}.{name}"))
+    lin = lambda i, name: {"w": get(i, f"{name}.weight").T}
+    np_dtype, V = np.dtype(dtype), spec.vocab_size
+    cast = lambda x: np.asarray(x).astype(np_dtype)
+
+    def layer(i):
+        out = {
+            "input_norm": get(i, "input_layernorm.weight"),
+            "post_norm": get(i, "post_attention_layernorm.weight"),
+            **{n: lin(i, f"self_attn.{n}_proj") for n in "qkvo"},
+        }
+        if spec.qk_norm:
+            out["q_norm"] = get(i, "self_attn.q_norm.weight")
+            out["k_norm"] = get(i, "self_attn.k_norm.weight")
+        if spec._window_layer(i)[1] == "mlp":
+            for n in ("gate", "up", "down"):
+                out[n] = lin(i, f"mlp.{n}_proj")
+            return jax.tree.map(cast, out)
+        out["router"] = lin(i, "mlp.gate")["w"]
+        for n in ("gate", "up", "down"):
+            out[n] = {"w": np.stack([
+                lin(i, f"mlp.experts.{first + e}.{n}_proj")["w"]
+                for e in range(E)])}
+            if spec.shared_expert_intermediate_size:
+                out[f"shared_{n}"] = lin(i, f"mlp.shared_experts.{n}_proj")
+        out = jax.tree.map(cast, out)
+        out["router_bias"] = np.asarray(
+            get(i, "mlp.gate.e_score_correction_bias"), np.float32)
+        return out
+
+    lead, P = spec.lead_layers, spec.num_periods
+    layers: Dict[str, Any] = {
+        "lead": tuple(layer(i) for i in range(lead))}
+    for group, mixer in (("window", "swa"), ("global", "attn")):
+        trees = [layer(i) for i in range(lead, spec.num_layers)
+                 if spec._window_layer(i)[0] == mixer]
+        if trees:
+            layers[group] = jax.tree.map(
+                lambda *xs: np.stack(xs).reshape(
+                    (P, len(xs) // P) + xs[0].shape), *trees)
+    params: Params = {
+        "embed": cast(np.asarray(getter("model.embed_tokens.weight"))[:V]),
+        "layers": layers,
+        "final_norm": cast(getter("model.norm.weight")),
+    }
+    if not spec.tie_embeddings:
+        params["lm_head"] = cast(np.asarray(getter("lm_head.weight"))[:V].T)
+    return params
+
+
 def params_from_getter(
     spec: ModelSpec, getter: TensorGetter, dtype=jnp.bfloat16
 ) -> Params:
     """Assemble the decoder pytree from HF-named tensors (host numpy)."""
     if spec.is_mla:
         return _mla_params_from_getter(spec, getter, dtype)
+    if spec.window_pattern:
+        return _window_params_from_getter(spec, getter, dtype)
     if spec.layer_pattern:
         return _pattern_params_from_getter(spec, getter, dtype)
     if spec.is_hybrid:
