@@ -8,6 +8,7 @@ blockwise twin).  Run on hardware:
     python benchmarks/bench_kernels.py
     python benchmarks/bench_kernels.py sample_edits   # that probe alone
     python benchmarks/bench_kernels.py decode_cells [CELL ...]
+    python benchmarks/bench_kernels.py expert_layer [CONFIG ...]
 
 Prints one JSON line per (kernel, shape) with median step times and the
 speedup.  CPU-safe fallback: refuses to run (the kernels need a TPU).
@@ -549,6 +550,172 @@ def bench_swa_prefill(S=8192, H=64, KV=8, hd=128, window=128,
                    _median_time(_looped(band), q, k, v, lens) * 1e6, 1)}
 
 
+# ONE expert layer of each configuration that holds a share of its
+# experts, as its cell runs it: (preset, held experts, rows of the
+# prompt program's wave, of them real, rows of a decode step)
+EXPERT_LAYER_CONFIGS = {
+    "k-exaone-236b-a23b-l5e16": (
+        "LGAI-EXAONE/K-EXAONE-236B-A23B", 16, 8192, 5550, 192),
+    "mistral-small-4-119b-l4e32": (
+        "mistralai/Mistral-Small-4-119B-2603", 32, 8192, 5550, 256),
+    "qwen3-next-80b-a3b-l8e128": (
+        "Qwen/Qwen3-Next-80B-A3B-Instruct", 128, 1024, 640, 256),
+    "nemotron-3-super-120b-a12b-l11e128": (
+        "nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16", 128, 1024, 640,
+        192),
+}
+
+
+def expert_layer_case(name, seed=0, abstract=None):
+    """(spec, lp, stack) of one expert layer of configuration ``name``:
+    the routed part alone (no shared expert), bf16 tensors N(0, 0.02),
+    the experts' matrices as a stack of one layer.  ``abstract(shape,
+    dtype)`` makes shapes instead (an AOT compile)."""
+    import dataclasses
+
+    from vgate_tpu.models.specs import spec_for_model_id
+
+    preset, held = EXPERT_LAYER_CONFIGS[name][:2]
+    spec = dataclasses.replace(
+        spec_for_model_id(preset), name=name, num_experts=held,
+        shared_expert_intermediate_size=0, n_shared_experts=0)
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 16))
+    draw = abstract or (lambda shape, dtype: (
+        0.02 * jax.random.normal(next(keys), shape, jnp.float32)
+    ).astype(dtype))
+    D, W, F = spec.hidden_size, spec.expert_in, spec.expert_width
+    lp = {"router": draw((D, spec.router_experts), jnp.bfloat16)}
+    if spec.router_scoring == "sigmoid":
+        lp["router_bias"] = draw((spec.router_experts,), jnp.float32)
+    if spec.moe_latent_size:
+        lp["latent_in"] = {"w": draw((D, W), jnp.bfloat16)}
+        lp["latent_out"] = {"w": draw((W, D), jnp.bfloat16)}
+    stack = {n: {"w": draw((1, held) + ((F, W) if n == "down" else (W, F)),
+                           jnp.bfloat16)}
+             for n in spec.expert_stacks}
+    return spec, lp, stack
+
+
+def _combine_by_gather(out, y, pairs, live, K):
+    """The other form of ops/moe.py ``_combine``: an inverse of the
+    trip's pairs over all T x K choices, dead ones pointing at one zero
+    row, a gather through it and the choices' sum."""
+    T, C = out.shape[0], y.shape[0]
+    inverse = jnp.full((T * K,), C, jnp.int32).at[
+        jnp.where(live, pairs, T * K)].set(
+            jnp.arange(C, dtype=jnp.int32), mode="drop")
+    y = jnp.concatenate([y, jnp.zeros_like(y[:1])])
+    return out + jnp.sum(y[inverse].reshape(T, K, -1), axis=1)
+
+
+def expert_layer_program(spec, rows, real, block, form, combine, loop=4,
+                         parent=None):
+    """``loop`` expert layers chained in one program over ``rows`` rows
+    of which ``real`` are (the rest masked).  ``form`` ``capacity``: this
+    tree's layer in blocks of ``block`` rows; ``all``: its dispatch
+    takes every pair at a time, as before PR 39; ``parent``: the layer
+    of ``parent``, another checkout's ``ops/moe.py`` (its own dispatch,
+    its own blocks).  ``combine`` ``gather``: the combine's other form."""
+    from vgate_tpu.models.decoder import _act
+    from vgate_tpu.ops import moe
+
+    layer_fn = (parent if form == "parent" else moe).expert_layer
+    mask = jnp.arange(rows) < real
+    act = lambda x32: _act(x32, spec)
+
+    def run(x, lp, stack):
+        def body(carry, _):
+            out, stats = layer_fn(
+                x + 0 * carry.astype(x.dtype), lp, spec, act, row_mask=mask,
+                use_pallas=True, layer=jnp.int32(0), stack=stack)
+            return out.astype(jnp.float32), stats
+
+        return jax.lax.scan(
+            body, jnp.zeros(x.shape, jnp.float32), None, length=loop)
+
+    def traced(x, lp, stack):
+        # the module's rule and combine as this variant wants them, for
+        # the duration of the trace
+        saved = moe.block_tokens, moe.capacity, moe._combine
+        try:
+            if form != "parent":
+                moe.block_tokens = lambda spec: block
+            if form == "all":
+                moe.capacity = lambda spec, pairs: pairs
+            if combine == "gather":
+                moe._combine = _combine_by_gather
+            return run(x, lp, stack)
+        finally:
+            moe.block_tokens, moe.capacity, moe._combine = saved
+
+    return jax.jit(traced)
+
+
+def _parent_moe(path="_parent/vgate_tpu/ops/moe.py"):
+    """ops/moe.py of the checkout under ``_parent`` (`git archive` of
+    the parent commit), or None."""
+    import importlib.util
+
+    if not os.path.exists(path):
+        return None
+    found = importlib.util.spec_from_file_location("parent_moe", path)
+    module = importlib.util.module_from_spec(found)
+    found.loader.exec_module(module)
+    return module
+
+
+def bench_expert_layer(configs=None, loop=4):
+    """ONE expert layer (router, dispatch, grouped products, combine; no
+    shared expert) at the four share-holding cells' prompt and decode
+    shapes: µs a layer by block rows x what the dispatch takes at a time
+    (``capacity``: this tree's rule; ``all``: every pair) x the
+    combine's form (``scatter``-add, or ``gather`` through an inverse),
+    with the parent's layer first where ``_parent`` holds a checkout;
+    each variant's result against the first's, and its counters."""
+    from vgate_tpu.ops import moe
+
+    parent = _parent_moe()
+    for name in configs or EXPERT_LAYER_CONFIGS:
+        _, _, wave, real, step = EXPERT_LAYER_CONFIGS[name]
+        spec, lp, stack = expert_layer_case(name)
+        K = spec.experts_per_token
+        for phase, rows, live in (("prompt", wave, real),
+                                  ("decode", step, step)):
+            x = jax.random.normal(
+                jax.random.PRNGKey(3), (rows, spec.hidden_size),
+                jnp.bfloat16)
+            variants = ([("parent", min(parent.block_tokens(spec), rows),
+                          "inverse")] if parent else [])
+            for block in (1024, 2048, 4096, 8192):
+                block = min(block, rows)
+                if ("all", block, "scatter") in variants:
+                    break  # the whole wave was one block already
+                variants += [("all", block, "scatter"),
+                             ("capacity", block, "scatter"),
+                             ("capacity", block, "gather")]
+            want = None
+            for form, block, combine in variants:
+                if form == "all" and block * K * spec.expert_in > (
+                        moe.BLOCK_VALUES):
+                    continue  # the temporaries the old rule refused
+                fn = expert_layer_program(
+                    spec, rows, live, block, form, combine, loop, parent)
+                out, stats = fn(x, lp, stack)
+                got = np.asarray(out, np.float32)[:live]
+                want = got if want is None else want
+                yield {
+                    "probe": "expert_layer", "config": name, "phase": phase,
+                    "rows": rows, "real": live, "form": form, "block": block,
+                    "at_a_time": (moe.capacity(spec, block * K)
+                                  if form == "capacity" else block * K),
+                    "combine": combine,
+                    "us": round(_median_time(
+                        fn, x, lp, stack, iters=6, loop=loop) * 1e6, 1),
+                    "max_abs_diff": float(np.abs(got - want).max()),
+                    "stats": np.asarray(stats)[0].tolist(),
+                }
+
+
 def bench_decode_window(B=128, H=8, KV=4, hd=256, ps=16, ctx=4096,
                         window=1024):
     """Sliding-window decode (Gemma-2 local layers): the kernel skips DMA
@@ -644,6 +811,10 @@ def main() -> None:
         return
     if sys.argv[1:] == ["swa_prefill"]:
         for line in bench_swa_prefill():
+            print(json.dumps(line), flush=True)
+        return
+    if sys.argv[1:2] == ["expert_layer"]:
+        for line in bench_expert_layer(sys.argv[2:]):
             print(json.dumps(line), flush=True)
         return
     if sys.argv[1:2] == ["decode_cells"]:

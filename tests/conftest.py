@@ -131,6 +131,29 @@ def _reset_globals(monkeypatch):
     faults.reset()
 
 
+@pytest.fixture(params=["rule", "under", "at", "over"])
+def at_a_time(request, monkeypatch):
+    """What the expert layer's dispatch takes at a time (ops/moe.py
+    ``capacity``), set against the ``held`` pairs of the call under
+    test: the layer's own rule, more than fell here, exactly as many, or
+    a third of them (so the dispatch makes three trips or four).
+    ``at_a_time(held)`` sets it and returns the trips beyond the first
+    that a block of ``held`` pairs then makes; ``.case`` names the case."""
+    from vgate_tpu.ops import moe
+    from vgate_tpu.utils.math import cdiv
+
+    def fix(held: int) -> int:
+        if request.param == "rule":
+            return 0
+        take = {"under": held + 8, "at": held,
+                "over": max(1, held // 3)}[request.param]
+        monkeypatch.setattr(moe, "capacity", lambda spec, pairs: take)
+        return cdiv(held, take) - 1
+
+    fix.case = request.param
+    return fix
+
+
 @pytest.fixture
 def dry_config():
     """A config wired for dry-run testing."""

@@ -189,10 +189,12 @@ def test_parameter_counts_and_layer_kinds():
             SPEC.attn_layers) == (1, 2, 7, 2)
 
 
-def test_the_eight_shares_add_up_to_the_uncut_reference():
+def test_the_eight_shares_add_up_to_the_uncut_reference(at_a_time):
     """128 experts over eight chips, sixteen each, the router 128 wide in
     every share: the shares' routed parts plus the shared expert counted
-    once are the uncut reference's layer."""
+    once are the uncut reference's layer, whether a share's held pairs
+    (a quarter of the 320 at a time by the layer's rule: 96) go in one
+    trip or in several."""
     spec = dataclasses.replace(
         SPEC, name="tiny-128", num_experts=128, router_width=128,
         experts_per_token=8)
@@ -207,6 +209,8 @@ def test_the_eight_shares_add_up_to_the_uncut_reference():
           for k, v in lw.items()}
     act = jax.nn.silu
     total = jnp.zeros_like(x)
+    assert moe.capacity(
+        dataclasses.replace(spec, num_experts=16), 40 * 8) == 96
     for chip in range(8):
         first = 16 * chip
         cut = dataclasses.replace(
@@ -215,6 +219,8 @@ def test_the_eight_shares_add_up_to_the_uncut_reference():
         held = {n: lw[n][first:first + 16] for n in ("gate", "up", "down")}
         part = dict(lp, **{n: {"w": w} for n, w in held.items()})
         assert part["router"].shape == (spec.hidden_size, 128)
+        _, stats = moe.expert_layer(x, part, cut, act)
+        extra = at_a_time(int(stats[1]))
         out, stats = moe.expert_layer(x, part, cut, act)
         total = total + out
         # the reference's own share agrees with the program's
@@ -222,7 +228,7 @@ def test_the_eight_shares_add_up_to_the_uncut_reference():
             mine = ref.moe(x, dict(lw, **held), cfg, shared=False,
                            first=first, count=16)
         assert np.abs(np.asarray(out - mine)).max() < 1e-5
-        assert int(stats[0]) == 40 * 8
+        assert int(stats[0]) == 40 * 8 and int(stats[4]) == extra
     assert np.abs(np.asarray(total + shared - want)).max() < 1e-5
 
 

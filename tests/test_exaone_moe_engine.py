@@ -138,6 +138,32 @@ def test_chunked_prefill_and_a_slot_reused_after_a_longer_tenant():
         core.stop()
 
 
+def test_held_pairs_past_the_capacity_are_served_and_counted(monkeypatch):
+    """The dispatch takes three pairs at a time where a decode step of
+    two rows has four and a 16-row prompt 32: every trip beyond the
+    first is in ``/debug/perf -> totals.moe.overflow``, and the tokens'
+    logprobs are the reference's all the same."""
+    from vgate_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "capacity", lambda spec, pairs: 3)
+    core = EngineCore(
+        engine_config({"prefill_buckets": [16], "max_batch_slots": 2},
+                      model_id=SHORT.name),
+        devices=jax.devices()[:1])
+    core.start()
+    try:
+        rng = np.random.default_rng(6)
+        prompts = [tokens(rng, 9), tokens(rng, 14)]
+        for p, s in zip(prompts, run(core, prompts, max_tokens=5)):
+            assert max(differences(core, s, p, TINY_SHORT)) < TOL
+        booked = core.perf.totals()["moe"]
+        # four expert layers a step, a trip more in each at the least
+        assert booked["overflow"] >= booked["layer_steps"] > 0
+        assert booked["held_assignments"] == booked["assignments"]
+    finally:
+        core.stop()
+
+
 def test_preemption_by_recompute_rebuilds_the_rings():
     core = EngineCore(
         engine_config({"kv_num_pages": 15, "decode_chunk": 1,

@@ -100,6 +100,7 @@ def test_unequal_rows_in_one_wave_and_a_prompt_shorter_than_its_bucket(
     assert stats["kv_page_bytes"] == 4 * ref.sizes(TINY)["KV"] * 16 * 2 * 4
     moe = engine.perf.totals()["moe"]
     assert moe["held_assignments"] == moe["assignments"] > 0
+    assert moe["overflow"] == 0  # every expert held: one trip
 
 
 def test_common_prefix_matches_reference_without_prefix_hits(

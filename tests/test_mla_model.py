@@ -118,6 +118,7 @@ def test_prompt_pass_then_absorbed_decode_match_the_reference(dtype, tol):
         moe = totals["moe"]
         assert moe["held_assignments"] == moe["assignments"] > 0
         assert moe["layer_steps"] == 3 * moe["steps"]
+        assert moe["overflow"] == 0  # every expert held: one trip
         mla = totals["mla"]
         assert mla["prefill_prompts"] == 3
         assert mla["prefill_pairs"] == 3 * sum(

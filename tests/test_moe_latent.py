@@ -61,7 +61,7 @@ def test_the_program_draws_what_the_reference_draws(whole):
     assert float(jnp.abs(w["router_bias"]).max()) > 0.0
 
 
-def test_the_four_shares_add_up_to_the_uncut_reference(whole):
+def test_the_four_shares_add_up_to_the_uncut_reference(whole, at_a_time):
     """The latent projection out is linear and has no bias: applied to
     each chip's partial sum, the four results add to the whole; the
     shared expert, which every chip computes alike, counts once."""
@@ -73,9 +73,12 @@ def test_the_four_shares_add_up_to_the_uncut_reference(whole):
     for first in (0, 2, 4, 6):  # four chips, two experts each
         cut, part = share_of(spec, lp, first, 2)
         cut = dataclasses.replace(cut, shared_expert_intermediate_size=0)
+        _, stats = layer(x, part, cut)
+        extra = at_a_time(int(stats[1]))
         out, stats = layer(x, part, cut)
         routed = routed + out
         assert int(stats[0]) == 48 * 3 and 0 < int(stats[1]) < 48 * 3
+        assert int(stats[4]) == extra
     np.testing.assert_allclose(routed + shared_only, want, atol=2e-5)
     got, stats = layer(x, lp, spec)
     np.testing.assert_allclose(got, want, atol=2e-5)
@@ -146,13 +149,19 @@ def test_a_token_none_of_whose_choices_is_held_gets_the_shared_expert(
     np.testing.assert_allclose(got, shared, atol=2e-5)
 
 
-def test_every_token_to_one_expert_loses_nothing(whole):
+def test_every_token_to_one_expert_loses_nothing(whole, at_a_time):
+    """One of eight experts held and every row on it: four times what a
+    uniform router sends, so the layer's own rule (a quarter of the 144
+    pairs, in tiles of 32: 64 at a time) is already a trip short."""
     spec, lp, w, x = whole
     bias = jnp.zeros((8,)).at[3].set(10.0)
     lifted, wl = dict(lp, router_bias=bias), dict(w, router_bias=bias)
     cut, part = share_of(spec, lifted, 3, 1)  # the chip holds expert 3
+    assert moe.capacity(cut, 48 * 3) == 64
+    extra = at_a_time(48) if at_a_time.case != "rule" else 0
     got, stats = layer(x, part, routed_only(cut))
     assert int(stats[3]) == 48 and int(stats[1]) == 48
+    assert int(stats[4]) == extra
     cfg = dict(TINY, n_routed_experts=1, router_width=8, first_expert=3)
     wpart = dict(wl, **{n: w[n][3:4] for n in STACKS})
     with jax.default_matmul_precision("highest"):
@@ -160,22 +169,31 @@ def test_every_token_to_one_expert_loses_nothing(whole):
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
-def test_masked_rows_route_nowhere(whole):
+def test_masked_rows_route_nowhere(whole, at_a_time):
+    """On a share (experts 2-5 of 8): the masked rows' pairs sort last
+    with the pairs of experts held elsewhere."""
     spec, lp, _, x = whole
+    cut, part = share_of(spec, lp, 2, 4)
     mask = jnp.arange(48) < 20
-    got, stats = layer(x, lp, spec, row_mask=mask)
-    full, _ = layer(x, lp, spec)
+    full, _ = layer(x, part, cut)
+    _, stats = layer(x, part, cut, row_mask=mask)
+    extra = at_a_time(int(stats[1]))
+    got, stats = layer(x, part, cut, row_mask=mask)
     np.testing.assert_allclose(got[:20], full[:20], atol=1e-6)
-    assert int(stats[0]) == 20 * 3
+    assert int(stats[0]) == 20 * 3 and 0 < int(stats[1]) < 20 * 3
+    assert int(stats[4]) == extra
 
 
-def test_blocks_of_rows_give_what_one_block_gives(whole, monkeypatch):
+def test_blocks_of_rows_give_what_one_block_gives(whole, monkeypatch,
+                                                  at_a_time):
     spec, lp, _, x = whole
     one, s1 = layer(x, lp, spec)
     monkeypatch.setattr(moe, "BLOCK_TOKENS", 16)
+    extra = at_a_time(16 * 3)  # a block's pairs, all held
     three, s3 = layer(x, lp, spec)
     np.testing.assert_allclose(three, one, atol=1e-6)
     assert s1[:2].tolist() == s3[:2].tolist()
+    assert int(s1[4]) == 0 and int(s3[4]) == 3 * extra
 
 
 @pytest.mark.parametrize("K, N", [(2048, 512), (512, 2048), (1024, 2688),

@@ -109,6 +109,7 @@ def test_unequal_rows_in_one_wave_match_the_reference(dtype, tol):
         moe = core.perf.totals()["moe"]
         assert moe["held_assignments"] == moe["assignments"] > 0
         assert moe["layer_steps"] == 2 * moe["steps"]
+        assert moe["overflow"] == 0  # every expert held: one trip
         state = core.perf.totals()["state"]
         assert state["layer_steps"] == 2 * moe["steps"]
     finally:

@@ -694,6 +694,27 @@ def test_new_totals_are_monotone_and_survive_the_dp_merge(monkeypatch):
     assert merged["boot_seconds"] == {"weights": 3.0}  # one process
 
 
+def test_the_expert_layers_counters_are_booked_with_their_overflow():
+    """Five device counters a decode step (ops/moe.py STAT_NAMES), one
+    readback a chunk: the first three and the overflow add up over the
+    steps, the largest load is summed as ``load_max_sum``."""
+    import numpy as np
+
+    from vgate_tpu.ops.moe import STAT_NAMES, combine_stats
+
+    assert STAT_NAMES[-1] == "overflow" and len(STAT_NAMES) == 5
+    layers = np.array([[16, 4, 2, 3, 0], [16, 9, 3, 5, 2]], np.int32)
+    assert combine_stats(layers).tolist() == [32, 13, 5, 5, 2]
+    rec = recorder()
+    rec.note_moe(np.array([[32, 13, 5, 5, 2], [32, 6, 4, 2, 0]]), rows=4,
+                 moe_layers=2, linear_layers=0)
+    rec.note_moe(np.array([[32, 8, 4, 3, 1]]), rows=4, moe_layers=2,
+                 linear_layers=0)
+    assert rec.totals()["moe"] == {
+        "assignments": 96, "held_assignments": 27, "experts_hit": 13,
+        "load_max_sum": 10, "overflow": 3, "layer_steps": 6, "steps": 3}
+
+
 def test_chunk_lengths_are_whatever_the_engine_ran():
     """No fixed ladder: tpu.decode_chunk is configurable, so a 16-step
     chunk counts; a spec-verify pass is steps, not a chunk; the dp merge

@@ -455,25 +455,39 @@ def test_decode_chunk_edits_logits_in_place_on_v5e(v5e):
         )
 
 
+# the benchmark's cuts of two presets (perfbench/configs): what the
+# cells serve
+MISTRAL_CUT = ("mistralai/Mistral-Small-4-119B-2603", dict(
+    name="mistral-cut", num_layers=4, num_experts=32, vocab_size=32768,
+    eos_token_id=32767, bos_token_id=32766, extra_stop_ids=()))
+EXAONE_CUT = ("LGAI-EXAONE/K-EXAONE-236B-A23B", dict(
+    name="exaone-cut", num_layers=5, num_experts=16, vocab_size=19200,
+    eos_token_id=19199, bos_token_id=19198))
+
+
+def _cut_and_shapes(A, preset, changes):
+    """(spec, abstract bf16 parameters) of a preset cut to a cell's."""
+    import dataclasses
+
+    from vgate_tpu.models.decoder import init_params
+
+    spec = dataclasses.replace(spec_for_model_id(preset), **changes)
+    params = jax.tree.map(
+        lambda x: A(x.shape, x.dtype),
+        jax.eval_shape(
+            lambda: init_params(spec, jax.random.PRNGKey(0), jnp.bfloat16)))
+    return spec, params
+
+
 def test_latent_decode_chunk_compiles_on_v5e(v5e):
     """The Mistral-Small-4 cut as the cell serves it (4 layers, 32
     experts held, 256 slots of 8,192 tokens): the decode chunk compiles
     for the v5e with the ONE latent pool aliased input to output and the
     latent decode kernel in it, its trips as `_decode_sizes` sets them."""
-    import dataclasses
-
-    from vgate_tpu.models.decoder import init_params
     from vgate_tpu.runtime.step_programs import _decode_chunk
 
     A = _abstract(v5e)
-    spec = dataclasses.replace(
-        spec_for_model_id("mistralai/Mistral-Small-4-119B-2603"),
-        name="mistral-cut", num_layers=4, num_experts=32, vocab_size=32768,
-        eos_token_id=32767, bos_token_id=32766, extra_stop_ids=())
-    params = jax.tree.map(
-        lambda x: A(x.shape, x.dtype),
-        jax.eval_shape(
-            lambda: init_params(spec, jax.random.PRNGKey(0), jnp.bfloat16)))
+    spec, params = _cut_and_shapes(A, *MISTRAL_CUT)
     B, ctx = 256, 8192
     pool = A((spec.attn_layers, spec.cache_heads, 16385, PAGE,
               spec.cache_head_dim), jnp.bfloat16)
@@ -560,9 +574,6 @@ def test_window_stack_decode_chunk_and_prompt_kernel_compile_on_v5e(v5e):
     window layers aliased input to output, a ring's launch under its own
     name beside the full layer's; and the window layers' banded prompt
     kernel compiles at the cell's one shape (8,192 rows, 64 heads on 8)."""
-    import dataclasses
-
-    from vgate_tpu.models.decoder import init_params
     from vgate_tpu.models.hybrid import make_state
     from vgate_tpu.ops.pallas.flash_prefill import (
         swa_prefill_attention_pallas,
@@ -570,14 +581,9 @@ def test_window_stack_decode_chunk_and_prompt_kernel_compile_on_v5e(v5e):
     from vgate_tpu.runtime.step_programs import _decode_chunk
 
     A = _abstract(v5e)
-    spec = dataclasses.replace(
-        spec_for_model_id("LGAI-EXAONE/K-EXAONE-236B-A23B"),
-        name="exaone-cut", num_layers=5, num_experts=16, vocab_size=19200,
-        eos_token_id=19199, bos_token_id=19198)
+    spec, params = _cut_and_shapes(A, *EXAONE_CUT)
     abstract = lambda tree: jax.tree.map(
         lambda x: A(x.shape, x.dtype), jax.eval_shape(tree))
-    params = abstract(
-        lambda: init_params(spec, jax.random.PRNGKey(0), jnp.bfloat16))
     B, ctx = 192, 8192
     state = abstract(lambda: make_state(spec, B, jnp.bfloat16, PAGE))
     state_bytes = sum(
@@ -613,3 +619,93 @@ def test_window_stack_decode_chunk_and_prompt_kernel_compile_on_v5e(v5e):
         A((1, S, H, hd), jnp.bfloat16), A((1, S, KV, hd), jnp.bfloat16),
         A((1, S, KV, hd), jnp.bfloat16), A((1,), jnp.int32)).compile()
     assert "swa_prefill_attention_pallas" in band.as_text()
+
+
+def _prompt_program(A, spec, params, pool, v_pool, state, bucket=8192):
+    """The cell's prompt program: ONE prompt in the 8,192 bucket."""
+    from vgate_tpu.runtime.step_programs import _prefill_step
+
+    B = 1
+    return _prefill_step.lower(
+        params, spec, A((B, bucket), jnp.int32), A((B,), jnp.int32),
+        pool, v_pool, A((B, bucket // PAGE), jnp.int32),
+        A((B,), jnp.float32), A((B,), jnp.float32), A((B,), jnp.int32),
+        A((2,), jnp.uint32), use_pallas=True,
+        seeds=A((B,), jnp.int32), steps=A((B,), jnp.int32),
+        **({} if state is None else {"state": state,
+                                     "slots": A((B,), jnp.int32)}),
+    ).compile()
+
+
+def _assert_no_buffer(text, rows, width, dtypes=("f32", "bf16", "s32")):
+    """No array of ``rows x width`` in the compiled program, whatever
+    its type: a temporary that goes by ALL (row, choice) pairs."""
+    for dtype in dtypes:
+        shape = f"{dtype}[{rows},{width}]"
+        found = [line for line in text.splitlines() if shape in line]
+        assert not found, found[0][:300]
+
+
+def test_window_stack_prompt_program_dispatches_held_pairs_on_v5e(v5e):
+    """The K-EXAONE cut's 8,192-row prompt program (four expert layers,
+    8 choices of 128 experts, 16 held): the expert layer runs in two
+    blocks of 4,096 rows and dispatches 8,192 of a block's 32,768 pairs
+    at a time, so nothing in the program is sized by ALL pairs x the
+    hidden width (805 MB in float32 a block), its temporaries stand far
+    under the pool and the rings beside them, and the program fits a
+    chip that holds them and the weights."""
+    from vgate_tpu.models.hybrid import make_state
+    from vgate_tpu.ops import moe
+
+    A = _abstract(v5e)
+    spec, params = _cut_and_shapes(A, *EXAONE_CUT)
+    assert moe.block_tokens(spec) == 4096
+    assert moe.capacity(spec, 4096 * 8) == 8192
+    slots = 192
+    state = jax.tree.map(
+        lambda x: A(x.shape, x.dtype),
+        jax.eval_shape(lambda: make_state(spec, slots, jnp.bfloat16, PAGE)))
+    pages = 36000  # 4.7 GB of K+V: the cell's pool of the one full layer
+    pool = A((spec.attn_layers, spec.num_kv_heads, pages, PAGE,
+              spec.head_dim), jnp.bfloat16)
+    nbytes = lambda tree: sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+    held = nbytes((params, state, pool, pool))
+    compiled = _prompt_program(A, spec, params, pool, pool, state)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes((state, pool, pool)), (
+        "the pool or the rings are copied")
+    # 1.62 GB at the parent's 1,024-row blocks (PERF.md section 4)
+    assert mem.temp_size_in_bytes < 1.7e9, mem.temp_size_in_bytes
+    assert held + mem.temp_size_in_bytes < 15.9e9  # of the chip's 16.9 GB
+    text = compiled.as_text()
+    assert "moe_grouped_matmul_pallas" in text
+    for pairs in (8192 * 8, 4096 * 8):
+        _assert_no_buffer(text, pairs, spec.hidden_size)
+        _assert_no_buffer(text, pairs, spec.expert_width)
+
+
+def test_latent_prompt_program_dispatches_held_pairs_on_v5e(v5e):
+    """The Mistral-Small-4 cut's 8,192-row prompt program (4 choices of
+    128 experts, 32 held): ONE block, 16,384 of its 32,768 pairs at a
+    time."""
+    from vgate_tpu.ops import moe
+
+    A = _abstract(v5e)
+    spec, params = _cut_and_shapes(A, *MISTRAL_CUT)
+    assert moe.block_tokens(spec) == 8192
+    assert moe.capacity(spec, 8192 * 4) == 16384
+    pages = 65537  # 6.44 GB: the cell's latent pool
+    pool = A((spec.attn_layers, spec.cache_heads, pages, PAGE,
+              spec.cache_head_dim), jnp.bfloat16)
+    pool_bytes = pool.size * 2
+    compiled = _prompt_program(A, spec, params, pool, None, None)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, "the pool is copied"
+    assert mem.temp_size_in_bytes < pool_bytes // 4
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert weights + pool_bytes + mem.temp_size_in_bytes < 15.9e9
+    text = compiled.as_text()
+    _assert_no_buffer(text, 8192 * 4, spec.expert_width)
+    # bf16[32768, 4096] is the embedding table
+    _assert_no_buffer(text, 8192 * 4, spec.hidden_size, ("f32", "s32"))

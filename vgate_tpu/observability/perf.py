@@ -738,10 +738,12 @@ class PerfRecorder:
     def note_moe(self, stats, rows: int, moe_layers: int,
                  linear_layers: int) -> None:
         """One decode chunk's device counters, booked once per readback.
-        ``stats`` is ``[steps, 4]``: per step, over its expert layers,
+        ``stats`` is ``[steps, 5]``: per step, over its expert layers,
         the (row, choice) assignments made, those that fell on experts
-        held here, the held experts hit (summed over layers) and the
-        largest load of a held expert (the max over layers).  ``rows``
+        held here, the held experts hit (summed over layers), the
+        largest load of a held expert (the max over layers) and the
+        dispatch trips beyond a block's first (held pairs past the
+        capacity, ops/moe.py ``capacity``).  ``rows``
         sequences rode the chunk: each step updated that many rows of
         the recurrent state in each linear layer."""
         steps = int(stats.shape[0])
@@ -751,6 +753,7 @@ class PerfRecorder:
             ("held_assignments", int(sums[1])),
             ("experts_hit", int(sums[2])),
             ("load_max_sum", int(sums[3])),
+            ("overflow", int(sums[4])),
             ("layer_steps", steps * moe_layers),
             ("steps", steps),
         ):

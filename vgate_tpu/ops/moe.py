@@ -16,6 +16,12 @@ would have added (model-configs guide, section 4) -- nothing stands in
 for the absent chips.  No row is ever dropped, whatever the imbalance:
 the grouped product takes every (row, choice) pair that fell on a held
 expert, however many fell on one.
+
+What is gathered, multiplied and summed is the HELD pairs alone: they
+sort first, and the dispatch works on a static ``capacity`` of them at
+a time (all ``T x K`` where the chip holds every expert, else twice
+what a uniform router would send here).  Held pairs past the capacity
+are a second trip of the same loop, counted in ``overflow``.
 """
 
 from __future__ import annotations
@@ -29,14 +35,16 @@ from vgate_tpu.models.specs import ModelSpec
 from vgate_tpu.utils.math import cdiv
 
 # the device counters a routed layer returns beside its output
-STAT_NAMES = ("assignments", "held_assignments", "experts_hit", "load_max")
+STAT_NAMES = ("assignments", "held_assignments", "experts_hit", "load_max",
+              "overflow")
 
 
 def combine_stats(stats):
-    """[n, 4] counters of n layers (or blocks) -> [4]: the first three
-    add up, the largest load is the largest."""
-    return jnp.concatenate(
-        [jnp.sum(stats[:, :3], axis=0), jnp.max(stats[:, 3:], axis=0)])
+    """[n, 5] counters of n layers (or blocks) -> [5]: each a sum, but
+    the largest load, which is the largest."""
+    return jnp.concatenate([
+        jnp.sum(stats[:, :3], axis=0), jnp.max(stats[:, 3:4], axis=0),
+        jnp.sum(stats[:, 4:], axis=0)])
 
 
 def _plain(w, dtype):
@@ -70,6 +78,11 @@ def _column_tile(K: int, N: int, itemsize: int) -> int:
     return tile
 
 
+def _row_tile(M: int) -> int:
+    """Rows of a program of the grouped product."""
+    return 128 if M >= 4096 else 32
+
+
 def grouped_product(rows, w, group_sizes, layer, use_pallas: bool):
     """rows [M, K] sorted by expert; w either ONE layer's ``[E, K, N]``
     (``layer`` None) or the stack ``[L, E, K, N]`` with the traced
@@ -80,7 +93,7 @@ def grouped_product(rows, w, group_sizes, layer, use_pallas: bool):
 
         K, N = w.shape[-2:]
         M = rows.shape[0]
-        tm = 128 if M >= 4096 else 32
+        tm = _row_tile(M)
         tn = _column_tile(K, N, w.dtype.itemsize)
         pad = cdiv(M, tm) * tm - M
         if pad:
@@ -94,21 +107,39 @@ def grouped_product(rows, w, group_sizes, layer, use_pallas: bool):
     return jax.lax.ragged_dot(rows.astype(w.dtype), w, group_sizes)
 
 
-# rows a call routes at once: a prompt wave of 8 x 2,048 tokens would
-# otherwise hold (tokens x choices) x hidden temporaries of gigabytes
+# rows whose (row, choice) pairs a block dispatches at a time: a prompt
+# wave of 8 x 2,048 tokens would otherwise hold (tokens x choices) x
+# hidden temporaries of gigabytes
 BLOCK_TOKENS = 4096
-# (row, choice) x width values a block may hold, which is what its
-# float32 temporaries go by: the most a block has been run with on the
-# chip (4,096 rows x 22 choices of a 1,024-wide latent)
+# dispatched (row, choice) x width values a block may hold, which is
+# what its float32 temporaries go by: the most a block has been run with
+# on the chip (4,096 rows x 22 choices of a 1,024-wide latent)
 BLOCK_VALUES = BLOCK_TOKENS * 22 * 1024
 
 
+def capacity(spec: ModelSpec, pairs: int) -> int:
+    """Of a block's ``pairs`` (row, choice) pairs, how many the dispatch
+    takes at a time: all of them where the chip holds every expert, else
+    twice the share a uniform router sends to the held ones, in whole
+    row tiles of the grouped product."""
+    if spec.num_experts >= spec.router_experts:
+        return pairs
+    c = cdiv(2 * pairs * spec.num_experts, spec.router_experts)
+    tile = _row_tile(c)
+    return min(pairs, cdiv(c, tile) * tile)
+
+
 def block_tokens(spec: ModelSpec) -> int:
-    """Rows a block of the expert layer takes: ``BLOCK_TOKENS``, halved
-    while a block would hold more than ``BLOCK_VALUES`` (8 choices of a
-    6,144-wide hidden: 1,024 rows, 512 a held expert of 16)."""
-    rows = BLOCK_TOKENS
-    while rows * spec.experts_per_token * spec.expert_in > BLOCK_VALUES:
+    """Rows a block of the expert layer takes: as many as dispatch
+    ``BLOCK_TOKENS`` rows' pairs at a time (those rows where the chip
+    holds every expert or half of them, twice as many where it holds a
+    quarter), halved while what the block dispatches at a time would
+    hold more than ``BLOCK_VALUES`` (8 choices of a 6,144-wide hidden,
+    16 of 128 experts held: 4,096 rows, 8,192 pairs at a time)."""
+    K = spec.experts_per_token
+    rows = BLOCK_TOKENS * max(
+        1, spec.router_experts // (2 * spec.num_experts))
+    while capacity(spec, rows * K) * spec.expert_in > BLOCK_VALUES:
         rows //= 2
     return rows
 
@@ -142,6 +173,14 @@ def expert_layer(x, lp, spec: ModelSpec, act, row_mask=None,
             combine_stats(jnp.stack(stats)))
 
 
+def _combine(out, y, pairs, live, K: int):
+    """out [T, W] float32 plus the trip's weighted products y [C, W],
+    each added to the row of its pair (``pairs // K``); a pair that is
+    not ``live`` goes nowhere."""
+    return out.at[jnp.where(live, pairs // K, out.shape[0])].add(
+        y, mode="drop")
+
+
 def _expert_block(x, lp, spec: ModelSpec, act, row_mask=None,
                   use_pallas: bool = False, layer=None, stack=None):
     """x: [..., D].  ``lp`` holds this layer's ``router`` [D, R] and
@@ -151,7 +190,7 @@ def _expert_block(x, lp, spec: ModelSpec, act, row_mask=None,
     ``layer`` the traced index into them (the Pallas path must not see
     a scan's per-layer slice).  ``row_mask`` ([...] bool) marks the rows
     that are real: padding and idle slots route nowhere.  Returns
-    (out [..., D], stats [4] int32 in ``STAT_NAMES`` order)."""
+    (out [..., D], stats [5] int32 in ``STAT_NAMES`` order)."""
     orig_shape = x.shape
     D = orig_shape[-1]
     xt = x.reshape(-1, D)
@@ -185,15 +224,19 @@ def _expert_block(x, lp, spec: ModelSpec, act, row_mask=None,
         held = (local >= 0) & (local < E) & real
         # choices on experts held elsewhere sort last, under group E
         flat_e = jnp.where(held, local, E).reshape(T * K)
-        order = jnp.argsort(flat_e, stable=True)
-        sorted_tok = order // K
-        counts = jnp.zeros((E + 1,), jnp.int32).at[flat_e].add(1)
-        group_sizes = counts[:E]
+        order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
+        group_sizes = jnp.sum(
+            flat_e[:, None] == jnp.arange(E)[None, :], axis=0,
+            dtype=jnp.int32)
+        n_held = jnp.sum(group_sizes)
+        # the held pairs are order[:n_held]; C of them go at a time
+        C = capacity(spec, T * K)
+        trips = cdiv(n_held, C)
         n_real = jnp.sum(real.astype(jnp.int32))
         stats = jnp.stack([
-            n_real * K, jnp.sum(group_sizes),
+            n_real * K, n_held,
             jnp.sum((group_sizes > 0).astype(jnp.int32)),
-            jnp.max(group_sizes),
+            jnp.max(group_sizes), jnp.maximum(trips - 1, 0),
         ]).astype(jnp.int32)
 
     latent = spec.moe_latent_size > 0
@@ -203,28 +246,42 @@ def _expert_block(x, lp, spec: ModelSpec, act, row_mask=None,
             src = jnp.einsum("td,dl->tl", xt, lp["latent_in"]["w"])
 
     with jax.named_scope("moe_experts"):
-        rows = src[sorted_tok]  # [T*K, W], sorted by expert
         ws = stack if stack is not None else lp
         lay = layer if stack is not None else None
-        gp = lambda r, name: grouped_product(
-            r, ws[name]["w"], group_sizes, lay, use_pallas
-        )
-        hidden = act(gp(rows, "gate" if spec.moe_gated else "up").astype(
-            jnp.float32)).astype(xt.dtype)
-        if spec.moe_gated:
-            hidden = hidden * gp(rows, "up").astype(xt.dtype)
-        y = gp(hidden, "down")  # [T*K, W]
-        w_sorted = jnp.where(held, gate_vals, 0.0).reshape(T * K)[order]
-        in_group = jnp.arange(T * K) < jnp.sum(group_sizes)
-        y = jnp.where(
-            in_group[:, None],
-            y.astype(jnp.float32) * w_sorted[:, None], 0.0,
-        )
-        # back to (token, choice) order, then the choices add up
-        inverse = jnp.zeros((T * K,), jnp.int32).at[order].set(
-            jnp.arange(T * K, dtype=jnp.int32)
-        )
-        out = jnp.sum(y[inverse].reshape(T, K, -1), axis=1)
+        weights = jnp.where(held, gate_vals, 0.0).reshape(T * K)
+        ends = jnp.cumsum(group_sizes)
+        # whole trips: the order is padded with pairs past n_held
+        order = jnp.pad(order, (0, cdiv(T * K, C) * C - T * K))
+
+        def dispatch(trip, out):
+            """Pairs ``trip * C ..`` of the sorted order: gathered,
+            multiplied by their experts, weighted, added to their rows."""
+            lo = trip * C
+            pairs = jax.lax.dynamic_slice(order, (lo,), (C,))
+            live = lo + jnp.arange(C) < n_held
+            # what of each expert's group lies in lo .. lo + C
+            cut = lambda at: jnp.clip(at - lo, 0, C)
+            sizes = cut(ends) - cut(ends - group_sizes)
+            gp = lambda r, name: grouped_product(
+                r, ws[name]["w"], sizes, lay, use_pallas
+            )
+            rows = src[pairs // K]  # [C, W], sorted by expert
+            hidden = act(gp(rows, "gate" if spec.moe_gated else "up").astype(
+                jnp.float32)).astype(xt.dtype)
+            if spec.moe_gated:
+                hidden = hidden * gp(rows, "up").astype(xt.dtype)
+            y = gp(hidden, "down")  # [C, W]; rows of no group undefined
+            y = jnp.where(
+                live[:, None],
+                y.astype(jnp.float32) * weights[pairs][:, None], 0.0,
+            )
+            return _combine(out, y, pairs, live, K)
+
+        out = jnp.zeros((T, src.shape[-1]), jnp.float32)
+        if C == T * K:  # one trip holds whatever fell here
+            out = dispatch(0, out)
+        else:
+            out = jax.lax.fori_loop(0, trips, dispatch, out)
 
     if latent:
         # on this chip's PARTIAL sum: the product is linear and has no

@@ -224,7 +224,7 @@ def _decode_chunk(
     beside the pools, rows of active slots updated in place; for a spec
     the stack walker of models/hybrid.py runs the result
     then ends ``..., chunk_flags, state, moe_stats`` with ``moe_stats``
-    ``[num_steps, 4]`` int32, the expert layers' device counters summed
+    ``[num_steps, 5]`` int32, the expert layers' device counters summed
     over the layers of each step (ops/moe.py STAT_NAMES), read back
     with the chunk's tokens.
     """
