@@ -9,6 +9,7 @@ blockwise twin).  Run on hardware:
     python benchmarks/bench_kernels.py sample_edits   # that probe alone
     python benchmarks/bench_kernels.py decode_cells [CELL ...]
     python benchmarks/bench_kernels.py expert_layer [CONFIG ...]
+    python benchmarks/bench_kernels.py dsa_index dsa_select dsa_attend
 
 Prints one JSON line per (kernel, shape) with median step times and the
 speedup.  CPU-safe fallback: refuses to run (the kernels need a TPU).
@@ -798,6 +799,192 @@ def bench_multitok_verify(B=64, S=4, H=12, KV=2, hd=128, ps=16, ctx=512):
     }
 
 
+# ---- learned sparse attention (GLM-5.2's cut): the cell's shapes ----
+DSA = dict(B=48, ctx=16384, topk=2048, ps=32, W=640, v_width=512, H=64,
+           layers=5, lens=(8193, 16047))
+
+
+def _timed(fn, *args, iters=8):
+    """Median seconds of one call of a jitted ``fn`` (first call apart)."""
+    _sync(fn(*args))
+    times = []
+    for _ in range(iters):
+        t0 = time.perf_counter()
+        _sync(fn(*args))
+        times.append(time.perf_counter() - t0)
+    return float(np.median(times))
+
+
+def bench_dsa_select(loop=4):
+    """The top-2,048 of a decode step's [48, 16,384] scores and of a
+    512-row block of a prompt's, by each method: ``jax.lax.top_k``
+    (positions), the threshold found by counting (a mask)."""
+    from vgate_tpu.ops.dsa import select_mask
+
+    rng = np.random.default_rng(0)
+    k = DSA["topk"]
+    for rows in (DSA["B"], 512):
+        scores = jnp.asarray(
+            rng.standard_normal((rows, DSA["ctx"])), jnp.float32)
+        lens = jnp.asarray(rng.integers(*DSA["lens"], size=rows), jnp.int32)
+        live = jnp.arange(DSA["ctx"])[None, :] < lens[:, None]
+        scores = jnp.where(live, scores, -jnp.inf)
+
+        def chained(fn):
+            def run(s):
+                def body(c, _):
+                    out = fn(s + 0 * c)
+                    return jnp.sum(out.astype(jnp.float32)) * 0, None
+                return jax.lax.scan(body, jnp.float32(0), None,
+                                    length=loop)[0]
+            return jax.jit(run)
+
+        forms = {
+            "lax.top_k": lambda s: jax.lax.top_k(s, k)[1],
+            "threshold_mask": lambda s: select_mask(s, k),
+        }
+        want = np.sort(np.asarray(jax.lax.top_k(scores, k)[1]), axis=-1)
+        mask = np.asarray(select_mask(scores, k))
+        same = all(
+            np.array_equal(np.nonzero(mask[i])[0], want[i])
+            for i in range(rows))
+        for name, fn in forms.items():
+            t = _timed(chained(fn), scores) / loop
+            yield {"probe": "dsa_select", "rows": rows, "keys": DSA["ctx"],
+                   "k": k, "form": name, "us": round(t * 1e6, 1),
+                   "mask_is_top_k_set": bool(same)}
+
+
+def bench_dsa_index():
+    """The scoring pass at the cell's shapes, us a launch and the share
+    of its roofline (a key row's 256 B against 819 GB/s, or 8,192
+    operations against 197 TFLOP/s, the larger): a decode step's 48
+    slots over their live pages, and a prompt's block of 1,024 query
+    rows against 16,384 keys (its last block: every tile live)."""
+    from vgate_tpu.ops.pallas.dsa import (
+        dsa_index_scores_pallas, dsa_prompt_scores_pallas,
+    )
+
+    rng = np.random.default_rng(0)
+    B, ps, Hi, d = DSA["B"], DSA["ps"], 32, 128
+    n = DSA["ctx"] // ps
+    P = 1 + B * n
+    keys = jax.jit(lambda k: jax.random.normal(
+        k, (2, 1, P, ps, d), jnp.bfloat16))(jax.random.PRNGKey(0))
+    tables = jnp.asarray(
+        (rng.permutation(P - 1)[:B * n] + 1).reshape(B, n), jnp.int32)
+    lens = jnp.asarray(rng.integers(*DSA["lens"], size=B), jnp.int32)
+    qi = jax.random.normal(jax.random.PRNGKey(1), (B, Hi, d), jnp.bfloat16)
+    w = jax.random.normal(jax.random.PRNGKey(2), (B, Hi), jnp.float32)
+    loop = 6
+
+    def decode(qi, keys):
+        def body(c, i):
+            out = dsa_index_scores_pallas(
+                qi + 0 * c.astype(qi.dtype), w, keys, tables, lens, i % 2)
+            return jnp.max(jnp.where(jnp.isfinite(out), out, 0)) * 0, None
+        return jax.lax.scan(body, jnp.float32(0),
+                            jnp.arange(loop, dtype=jnp.int32))[0]
+
+    t = _timed(jax.jit(decode), qi, keys) / loop
+    rows = int(jnp.sum(lens))
+    least = max(rows * 256 / 819e9, rows * 8192 / 197e12)
+    yield {"probe": "dsa_index", "form": "decode, 48 slots' live pages",
+           "us": round(t * 1e6, 1), "rows_scored": rows,
+           "roofline_pct": round(100 * least / t, 1)}
+    R, T = 1024, DSA["ctx"]
+    q_rows = jax.random.normal(jax.random.PRNGKey(3), (R, Hi, d), jnp.bfloat16)
+    w_rows = jax.random.normal(jax.random.PRNGKey(4), (R, Hi), jnp.float32)
+    k_all = jax.random.normal(jax.random.PRNGKey(5), (T, d), jnp.bfloat16)
+
+    def prompt(q_rows, k_all):
+        def body(c, _):
+            out = dsa_prompt_scores_pallas(
+                q_rows + 0 * c.astype(q_rows.dtype), w_rows, k_all, T - R)
+            return out[0, 0] * 0, None
+        return jax.lax.scan(body, jnp.float32(0), None, length=loop)[0]
+
+    t = _timed(jax.jit(prompt), q_rows, k_all) / loop
+    pairs = R * (T - R) + R * (R + 1) // 2
+    yield {"probe": "dsa_index", "form": "prompt, 1,024 rows x 16,384 keys",
+           "us": round(t * 1e6, 1), "pairs": pairs,
+           "tflops": round(pairs * 8192 / t / 1e12, 1),
+           "roofline_pct": round(100 * pairs * 8192 / 197e12 / t, 1)}
+
+
+def dsa_attend_case(seed=0):
+    """A decode step's latent pool at the cell's size (48 slots x 16,384
+    tokens of pages, 5 layers), scattered pages, lengths over the
+    cell's range, 2,048 selected positions a slot."""
+    rng = np.random.default_rng(seed)
+    B, ps, W, L = DSA["B"], DSA["ps"], DSA["W"], DSA["layers"]
+    n = DSA["ctx"] // ps
+    P = 1 + B * n
+    pool = jax.jit(lambda k: jax.random.normal(
+        k, (L, 1, P, ps, W), jnp.bfloat16))(jax.random.PRNGKey(seed))
+    tables = jnp.asarray(
+        (rng.permutation(P - 1)[:B * n] + 1).reshape(B, n), jnp.int32)
+    lens = rng.integers(*DSA["lens"], size=B)
+    sel = np.stack([rng.permutation(int(l))[:DSA["topk"]] for l in lens])
+    q = jax.random.normal(jax.random.PRNGKey(seed + 1),
+                          (B, DSA["H"], W), jnp.bfloat16)
+    return (q, pool, tables, jnp.asarray(lens, jnp.int32),
+            jnp.asarray(sel, jnp.int32))
+
+
+def bench_dsa_attend(loop=5):
+    """One layer's decode attention under the selection, us a layer: the
+    selected rows gathered through the page table into [48, 2,048, 640]
+    and the dense latent kernel over them (``dsa_decode_attention``),
+    the gather alone, and what is NOT served: the dense latent kernel
+    over the whole context (every row read, no selection)."""
+    from vgate_tpu.ops.dsa import dsa_decode_attention, gather_selected
+    from vgate_tpu.ops.pallas.paged_attention import (
+        mla_decode_attention_pallas,
+    )
+
+    q, pool, tables, lens, sel = dsa_attend_case()
+    kw = dict(v_width=DSA["v_width"], scale=576 ** -0.5)
+    n_sel = jnp.minimum(lens, DSA["topk"])
+    def gather_pairs(pool, l):
+        """The same rows by an ALIGNED gather: the pair of rows (two
+        rows share a 32-bit sublane in a bf16 tile) that holds each,
+        then the one of the two."""
+        L, _, P, ps, W = pool.shape
+        page = jnp.take_along_axis(tables, sel // ps, axis=1)
+        flat = (l * P + page) * ps + sel % ps
+        two = pool.reshape(L * P * ps // 2, 2, W)[flat // 2]
+        return jnp.where((flat % 2 == 0)[..., None], two[..., 0, :],
+                         two[..., 1, :])
+
+    forms = {
+        "gather_pairs_alone": lambda q, pool, l: gather_pairs(
+            pool, l)[:, :DSA["H"], :DSA["v_width"]],
+        "gather_alone": lambda q, pool, l: gather_selected(
+            pool, tables, sel, l)[:, :DSA["H"], :DSA["v_width"]],
+        "gather+dense_kernel": lambda q, pool, l: dsa_decode_attention(
+            q, pool, tables, sel, n_sel, l, use_pallas=True, **kw),
+        "whole_context_dense_kernel": lambda q, pool, l:
+            mla_decode_attention_pallas(q, pool, tables, lens, l, **kw),
+    }
+    for name, fn in forms.items():
+        def run(q, pool, fn=fn):
+            def body(c, i):
+                out = fn(q + 0 * c.astype(q.dtype), pool,
+                         i % DSA["layers"])
+                return jnp.pad(out.astype(jnp.float32), (
+                    (0, 0), (0, 0), (0, q.shape[-1] - out.shape[-1]))), None
+            return jax.lax.scan(
+                body, jnp.zeros(q.shape, jnp.float32),
+                jnp.arange(loop, dtype=jnp.int32))[0]
+        t = _timed(jax.jit(run), q, pool) / loop
+        rows = (int(jnp.sum(lens)) if name.startswith("whole")
+                else int(jnp.sum(n_sel)))
+        yield {"probe": "dsa_attend", "form": name,
+               "us_a_layer": round(t * 1e6, 1), "rows_read": rows,
+               "gb_s": round(rows * DSA["W"] * 2 / t / 1e9, 1)}
+
+
 def main() -> None:
     device = jax.devices()[0]
     if device.platform != "tpu":
@@ -816,6 +1003,13 @@ def main() -> None:
     if sys.argv[1:2] == ["expert_layer"]:
         for line in bench_expert_layer(sys.argv[2:]):
             print(json.dumps(line), flush=True)
+        return
+    dsa = {"dsa_index": bench_dsa_index, "dsa_select": bench_dsa_select,
+           "dsa_attend": bench_dsa_attend}
+    if sys.argv[1:] and all(a in dsa for a in sys.argv[1:]):
+        for a in sys.argv[1:]:
+            for line in dsa[a]():
+                print(json.dumps(line), flush=True)
         return
     if sys.argv[1:2] == ["decode_cells"]:
         for line in bench_decode_cells(sys.argv[2:]):

@@ -709,3 +709,96 @@ def test_latent_prompt_program_dispatches_held_pairs_on_v5e(v5e):
     _assert_no_buffer(text, 8192 * 4, spec.expert_width)
     # bf16[32768, 4096] is the embedding table
     _assert_no_buffer(text, 8192 * 4, spec.hidden_size, ("f32", "s32"))
+
+
+# ---- learned sparse attention: the GLM-5.2 cut as its cell serves it
+
+GLM_CUT = ("zai-org/GLM-5.2", dict(
+    name="glm-cut", num_layers=5, first_layer=2, num_experts=16,
+    vocab_size=19360, eos_token_id=19359, bos_token_id=19358))
+GLM_SLOTS, GLM_CTX = 48, 16384
+
+
+def _glm_cut(A):
+    """(spec, abstract parameters, the latent pool, the index keys'
+    array) at the cell's size: 48 slots x 16,384 tokens of pages."""
+    spec, params = _cut_and_shapes(A, *GLM_CUT)
+    pages = GLM_SLOTS * GLM_CTX // PAGE + 1
+    pool = A((spec.attn_layers, 1, pages, PAGE, spec.cache_head_dim),
+             jnp.bfloat16)
+    keys = A((spec.index_layers, 1, pages, PAGE, spec.index_head_dim),
+             jnp.bfloat16)
+    assert pool.shape[0] == 5 and pool.shape[-1] == 640
+    assert keys.shape[0] == 2 and keys.shape[-1] == 128
+    return spec, params, pool, keys
+
+
+def _nbytes(tree):
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
+def test_selection_decode_chunk_compiles_on_v5e(v5e):
+    """The decode chunk of the cut: both arrays of the pool aliased input
+    to output and never re-laid, the scoring pass and the attention over
+    gathered rows in it under their own names beside the dense latent
+    kernel (the branch for contexts of at most 2,048 tokens)."""
+    from vgate_tpu.runtime.step_programs import _decode_chunk
+
+    A = _abstract(v5e)
+    spec, params, pool, keys = _glm_cut(A)
+    B = GLM_SLOTS
+    compiled = _decode_chunk.lower(
+        params, spec, A((B,), jnp.int32), A((B,), jnp.int32), pool, keys,
+        A((B, GLM_CTX // PAGE), jnp.int32), A((B,), jnp.bool_),
+        A((B,), jnp.float32), A((B,), jnp.float32), A((B,), jnp.int32),
+        A((2,), jnp.uint32), A((), jnp.uint32),
+        num_steps=8, use_pallas=True, max_position=GLM_CTX - 1,
+        seeds=A((B,), jnp.int32), steps=A((B,), jnp.int32),
+        all_greedy=True, guard=True,
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _nbytes((pool, keys)), (
+        "a pool is copied")
+    # 0.61 GB (PERF.md section 4): the gathered rows of one layer, the
+    # scores of one picking layer, the weights' re-laid copies
+    assert mem.temp_size_in_bytes < 0.8e9, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    for name in ("dsa_index_scores_pallas", "dsa_decode_attention_pallas",
+                 "mla_decode_attention_pallas"):
+        assert name in text, name
+    # neither array re-laid with another minor dimension
+    for shape in ("bf16[5,1,24577,32,640]", "bf16[2,1,24577,32,128]"):
+        layouts = {line.split(shape, 1)[1].split("}", 1)[0]
+                   for line in text.splitlines() if shape + "{" in line}
+        assert layouts and all(
+            l.startswith(("{4,3,2,1,0", "{4,3,2,0,1")) for l in layouts
+        ), layouts
+
+
+def test_selection_prompt_program_fits_beside_the_pool_on_v5e(v5e):
+    """The 16,384-row prompt program of the cut: the scoring kernel and
+    the flash kernel under a mask in it, both pool arrays aliased, no
+    [16,384, 16,384] float32 scores and no [16,384, 12,288] activation
+    of the dense layer in the HLO, and the temporaries small enough
+    beside 7.76 GB of weights and 5.44 GB of pages."""
+    A = _abstract(v5e)
+    spec, params, pool, keys = _glm_cut(A)
+    compiled = _prompt_program(A, spec, params, pool, keys, None,
+                               bucket=GLM_CTX)
+    mem = compiled.memory_analysis()
+    held = _nbytes((params, pool, keys))
+    assert 13.1e9 < held < 13.3e9
+    assert mem.alias_size_in_bytes >= _nbytes((pool, keys)), (
+        "a pool is copied")
+    # 2.48 GB with groups of 8 heads (PERF.md section 4)
+    assert mem.temp_size_in_bytes < 2.7e9, mem.temp_size_in_bytes
+    assert held + mem.temp_size_in_bytes < 15.9e9  # of the chip's 16.9 GB
+    text = compiled.as_text()
+    for name in ("dsa_index_scores_pallas", "dsa_prefill_attention_pallas",
+                 "moe_grouped_matmul_pallas"):
+        assert name in text, name
+    _assert_no_buffer(text, GLM_CTX, GLM_CTX, ("f32", "bf16", "s32", "u32"))
+    _assert_no_buffer(text, GLM_CTX, spec.intermediate_size)
+    _assert_no_buffer(text, f"1,{GLM_CTX}", spec.intermediate_size)
+    # the selection itself stands once, as bytes
+    assert f"s8[1,{GLM_CTX},{GLM_CTX}]" in text

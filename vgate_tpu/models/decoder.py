@@ -294,6 +294,8 @@ def decode_kv_write(
     kernel = (
         decode_attention_impl(spec, use_pallas, mesh) == "pallas"
         and not quantized
+        # under a selection the rows are gathered after the write
+        and not spec.is_dsa
     )
     return "kernel" if kernel else "scatter"
 
@@ -328,6 +330,99 @@ def _mla_write_attend(spec: ModelSpec, impl: str, kernel_writes: bool,
         return attn, kp, vp
 
     return write_attend
+
+
+def _dsa_decode_steps(spec: ModelSpec, impl: str, page_tables, seq_lens,
+                      page_ids, page_off, page_size: int):
+    """``decode_forward``'s cache steps for latent attention under a
+    learned selection (models/hybrid.py ``_dsa_step``), ``(pick, attend,
+    the positions a pick holds)``:
+
+    * ``pick(qi, w, key, index_pages, layer)`` puts the token's index key
+      into the pool's second array, scores the slot's live keys
+      (ops/pallas/dsa.py ``dsa_index_scores_pallas``, or the jnp twin)
+      and returns the ``index_topk`` positions picked, [B, k];
+    * ``attend(q, row, sel, pages, layer)`` puts the token's latent row
+      into the pool and attends over the selected rows alone
+      (ops/dsa.py ``dsa_decode_attention``).
+
+    While no live context is longer than ``index_topk`` the pick is
+    everything: neither scores nor gather then, and the layer is the
+    dense latent kernel over the pool as it stands."""
+    from vgate_tpu.ops import dsa
+    from vgate_tpu.ops.attention import mla_decode_attention, mla_gather_rows
+    from vgate_tpu.ops.pallas.dsa import dsa_index_scores_pallas
+    from vgate_tpu.ops.pallas.paged_attention import (
+        mla_decode_attention_pallas,
+    )
+
+    kernel = impl == "pallas"
+    B, n_pages = page_tables.shape
+    tokens = n_pages * page_size
+    k = min(spec.index_topk, tokens)
+    short = jnp.max(seq_lens) <= k
+    n_sel = jnp.minimum(seq_lens, k)
+    kw = dict(v_width=spec.kv_lora_rank, scale=spec.mla_softmax_scale)
+
+    def pick(qi, w, key, ip, layer):
+        with jax.named_scope("kv_write"):
+            ip = kv_write_tokens(
+                ip, page_ids, page_off, key[:, None], layer=layer)
+
+        def scored():
+            with jax.named_scope("dsa_index"):
+                if kernel:
+                    scores = dsa_index_scores_pallas(
+                        qi, w, ip, page_tables, seq_lens, layer)
+                else:
+                    keys = mla_gather_rows(ip, page_tables, layer)
+                    scores = dsa.index_scores(
+                        qi[:, None], w[:, None], keys)[:, 0]
+                    scores = jnp.where(
+                        jnp.arange(tokens)[None, :] < seq_lens[:, None],
+                        scores, dsa.NEG_INF)
+            with jax.named_scope("dsa_select"):
+                return dsa.select_positions(scores, k)
+
+        everything = lambda: jnp.broadcast_to(
+            jnp.arange(k, dtype=jnp.int32), (B, k))
+        return jax.lax.cond(short, everything, scored), ip
+
+    def attend(q, row, sel, kp, layer):
+        with jax.named_scope("kv_write"):
+            kp = kv_write_tokens(
+                kp, page_ids, page_off, row[:, None], layer=layer)
+        dense = mla_decode_attention_pallas if kernel else (
+            mla_decode_attention)
+        with jax.named_scope("dsa_attend"):
+            attn = jax.lax.cond(
+                short,
+                lambda: dense(q, kp, page_tables, seq_lens, layer, **kw),
+                lambda: dsa.dsa_decode_attention(
+                    q, kp, page_tables, sel, n_sel, layer,
+                    use_pallas=kernel, **kw))
+        return attn, kp
+
+    return pick, attend, k
+
+
+def _dsa_prefill_attend(spec: ModelSpec, impl: str, seq_lens, rows: int):
+    """A prompt pass's attention under a selection, ``attend(q, k, v,
+    mask)`` (models/hybrid.py ``_dsa_prompt``): the flash kernel with the
+    mask beside each key block, under a name of its own
+    (ops/pallas/dsa.py), for a whole prompt's rows against their own
+    keys; else the plain jnp twin."""
+    from vgate_tpu.ops import dsa
+
+    scale = spec.mla_softmax_scale
+    if impl == "pallas":
+        from vgate_tpu.ops.pallas.dsa import dsa_prefill_attention_pallas
+
+        block = 1024 if rows >= 4096 else 256
+        return lambda q, k, v, mask: dsa_prefill_attention_pallas(
+            q, k, v, seq_lens, mask, scale=scale, block_q=block,
+            block_k=block)
+    return lambda q, k, v, mask: dsa.masked_attention(q, k, v, mask, scale)
 
 
 def _swa_prefill_attend(spec: ModelSpec, impl: str, seq_lens):
@@ -493,6 +588,8 @@ def prefill_forward(
             lambda q, k, v, kp, vp, layer: attn_fn(q, k, v, seq_lens),
             use_pallas,
             swa_attend=_swa_prefill_attend(spec, impl, seq_lens),
+            dsa_attend=(_dsa_prefill_attend(spec, impl, seq_lens, S)
+                        if spec.is_dsa else None),
         )
         return (_logits(params, spec, _last_rows(x, seq_lens)),
                 k_pages, v_pages, state)
@@ -808,6 +905,10 @@ def decode_forward(
         write_attend = _mla_write_attend(
             spec, impl, kernel_writes, page_tables, seq_lens, page_ids,
             page_off)
+    dsa_steps = None
+    if spec.is_dsa:
+        dsa_steps = _dsa_decode_steps(
+            spec, impl, page_tables, seq_lens, page_ids, page_off, ps)
     if spec.is_hybrid:
         from vgate_tpu.models import hybrid
 
@@ -836,6 +937,7 @@ def decode_forward(
         x, k_pages, v_pages, state, stats = hybrid.decode_forward(
             params, spec, x, positions, k_pages, v_pages, state, active,
             write_attend, use_pallas, ring_write_attend=ring_step,
+            dsa_steps=dsa_steps,
         )
         return _logits(params, spec, x), k_pages, v_pages, state, stats
 
@@ -977,6 +1079,11 @@ def prefill_suffix_forward(
             state, slots, prefix_lens == 0, suffix_page_tables, attend,
             kernels, ctx_tables=ctx_page_tables if spec.is_mla else None,
             prefix_lens=prefix_lens if spec.swa_layers else None,
+            # a later chunk's or a suffix's rows under a selection: the
+            # jnp twin over the gathered context (no kernel yet)
+            dsa_attend=(_dsa_prefill_attend(spec, "jnp", total_lens, S)
+                        if spec.is_dsa else None),
+            total_lens=total_lens,
         )
         return (_logits(params, spec, _last_rows(x, suffix_lens)),
                 k_pages, v_pages, state)

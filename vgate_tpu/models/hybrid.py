@@ -11,6 +11,14 @@ data (``ModelSpec.period_blocks``):
   k_rope]`` a token and no K or V; a prompt pass expands K and V from
   the rows (non-absorbed), a decode step folds the expansion into the
   query and the output and reads the rows alone (absorbed),
+* ``dsa``   the same latent attention under a learned SELECTION
+  (GLM-5.2, DeepSeek-V3.2's DSA; ops/dsa.py): the layer's indexer scores
+  every cached token against ONE index key a token (the pool's second
+  array, same page table) and picks ``index_topk``; its attention, and
+  that of the ``mla`` layers behind it up to the next ``dsa`` layer,
+  runs over the pick alone.  The pick rides the walker's carry (``"sel"``
+  in the state dict for the walk: positions ``[B, k]`` in a decode step,
+  a mask ``[B, S, T]`` in a prompt pass): it is activations, not cache,
 * ``swa``   softmax attention over the last ``sliding_window`` tokens
   (K-EXAONE's window layers), whose K/V is the decode slot's RING and
   holds no page of the pool (below),
@@ -70,11 +78,14 @@ groups are ``linear`` (a Gated DeltaNet layer and its experts) and
 period); a pattern's groups are its kinds, ``mamba`` / ``attn`` /
 ``moe``; K-EXAONE's are ``window`` and ``global`` (attention and
 experts of a window or a full layer) and ``lead``, a TUPLE of the
-leading layers' own trees.
+leading layers' own trees; an ``indexer_pattern`` spec's are ``pick``
+and ``reuse`` (latent attention with and without an indexer, and the
+layer's experts) and ``lead``.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict
 
@@ -82,6 +93,7 @@ import jax
 import jax.numpy as jnp
 
 from vgate_tpu.models.specs import ModelSpec
+from vgate_tpu.ops import dsa
 from vgate_tpu.ops import gated_delta as gd
 from vgate_tpu.ops import ssd
 from vgate_tpu.ops.kv_quant import gather_pages, kv_write_pages
@@ -96,6 +108,8 @@ def init_layers(spec: ModelSpec, key, dtype, normal, norm_init
                 ) -> Dict[str, Any]:
     """Random draw of a hybrid spec's layer tensors, each family from
     keys of its own."""
+    if spec.is_dsa:
+        return _init_dsa_layers(spec, key, dtype, normal)
     if spec.is_mla:
         return _init_mla_layers(spec, key, dtype, normal, norm_init)
     if spec.window_pattern:
@@ -165,6 +179,83 @@ def _init_mla_layers(spec: ModelSpec, key, dtype, normal, norm_init
     return {"layer": out}
 
 
+def _init_dsa_layers(spec: ModelSpec, key, dtype, normal
+                     ) -> Dict[str, Any]:
+    """An ``indexer_pattern`` spec's tensors from ``fold_in(key, 40)``
+    split 32 ways: tensor ``j`` of layer ``i`` (its index in the WHOLE
+    stack, leading layers included) from ``fold_in(key j, i)``.  The
+    latent attention as ``_init_mla_layers`` draws it (N(0, 0.02) but
+    the two up-projections N(0, 0.05), norms 1); the indexer N(0, 0.02),
+    its key's LayerNorm weight 1 and bias 0: with the query latent and
+    the key both normed an index score's dot products have a standard
+    deviation near 10, so the pick is far from the first 2,048 or the
+    last, and a wrong rotation or a stale index key moves it.  The
+    router's selection bias N(0, 0.02) and NOT zero."""
+    dk = jax.random.split(jax.random.fold_in(key, 40), 32)
+    D, H = spec.hidden_size, spec.num_heads
+    ql, kl = spec.q_lora_rank, spec.kv_lora_rank
+    nope, rope, vd = (spec.qk_nope_head_dim, spec.qk_rope_head_dim,
+                      spec.v_head_dim)
+    Hi, di = spec.index_n_heads, spec.index_head_dim
+    E, R, Fe = spec.num_experts, spec.router_experts, spec.expert_width
+    F, Fs = spec.intermediate_size, spec.shared_expert_intermediate_size
+    ones = lambda *shape: jnp.ones(shape, dtype)
+    attn = {"q_a": (0, (D, ql), 0.02), "q_b": (1, (ql, H * (nope + rope)), 0.05),
+            "kv_a": (2, (D, kl + rope), 0.02), "o": (4, (H * vd, D), 0.02)}
+    index = {"index_q": (16, (ql, Hi * di), 0.02),
+             "index_k": (17, (D, di), 0.02), "index_w": (18, (D, Hi), 0.02)}
+    ff = {
+        "mlp": {"gate": (5, (D, F), 0.02), "up": (6, (D, F), 0.02),
+                "down": (7, (F, D), 0.02)},
+        "moe": {"gate": (9, (E, D, Fe), 0.02), "up": (10, (E, D, Fe), 0.02),
+                "down": (11, (E, Fe, D), 0.02),
+                "shared_gate": (13, (D, Fs), 0.02),
+                "shared_up": (14, (D, Fs), 0.02),
+                "shared_down": (15, (Fs, D), 0.02)},
+    }
+
+    def draw(layers, j, shape, scale, rnd=normal):
+        """Tensor ``j`` of the ``layers`` (stack indices), stacked."""
+        return jax.jit(lambda k: jax.lax.map(
+            lambda i: rnd(jax.random.fold_in(k, i), shape, scale),
+            jnp.asarray(layers)))(dk[j])
+
+    def tree(layers, lead, mixer, kind):
+        """The tensors of ``layers`` (all of one mixer and one
+        feed-forward kind), each ``lead + its shape``."""
+        put = lambda a: a.reshape(lead + a.shape[1:])
+        out = {"input_norm": ones(*lead, D), "post_norm": ones(*lead, D),
+               "q_a_norm": ones(*lead, ql), "kv_a_norm": ones(*lead, kl)}
+        kv_b = put(draw(layers, 3, (kl, H, nope + vd), 0.05))
+        out["kv_b_k"] = {"w": kv_b[..., :nope]}
+        out["kv_b_v"] = {"w": kv_b[..., nope:]}
+        tensors = {**attn, **ff[kind], **(index if mixer == "dsa" else {})}
+        for name, (j, shape, scale) in tensors.items():
+            if 0 not in shape:  # no shared expert: no tensor
+                out[name] = {"w": put(draw(layers, j, shape, scale))}
+        if mixer == "dsa":
+            out["index_k_norm"] = ones(*lead, di)
+            out["index_k_bias"] = jnp.zeros(lead + (di,), dtype)
+        if kind == "moe":
+            out["router"] = put(draw(layers, 8, (D, R), 0.02))
+            out["router_bias"] = put(draw(
+                layers, 12, (R,), 0.02,
+                lambda k, shape, scale: jax.random.normal(
+                    k, shape, jnp.float32) * scale))
+        return out
+
+    lead, P = spec.lead_layers, spec.num_periods
+    kinds = [spec._stack_layer(i) for i in range(spec.num_layers)]
+    out: Dict[str, Any] = {"lead": tuple(
+        tree([i], (), *kinds[i]) for i in range(lead))}
+    for group, mixer in (("pick", "dsa"), ("reuse", "mla")):
+        layers = [i for i in range(lead, spec.num_layers)
+                  if kinds[i][0] == mixer]
+        if layers:
+            out[group] = tree(layers, (P, len(layers) // P), mixer, "moe")
+    return out
+
+
 def _init_window_layers(spec: ModelSpec, key, dtype, normal
                         ) -> Dict[str, Any]:
     """A ``window_pattern`` spec's tensors from ``fold_in(key, 38)``
@@ -215,7 +306,7 @@ def _init_window_layers(spec: ModelSpec, key, dtype, normal
         return out
 
     lead, P = spec.lead_layers, spec.num_periods
-    kinds = [spec._window_layer(i) for i in range(spec.num_layers)]
+    kinds = [spec._stack_layer(i) for i in range(spec.num_layers)]
     out: Dict[str, Any] = {"lead": tuple(
         tree([i], (), kinds[i][1]) for i in range(lead))}
     for group, mixer in (("window", "swa"), ("global", "attn")):
@@ -675,17 +766,26 @@ def _recurrent_prompt(kind, normed, lp, st, li, spec: ModelSpec, lens,
     return out, st
 
 
-@jax.named_scope("mla_q")
-def _mla_q(normed, lp, spec: ModelSpec, positions):
-    """Latent attention's queries on normed rows [..., S, D]: through
-    the normed bottleneck, per head ``[q_nope | q_rope]`` with the
-    rotary part rotated, both scaled by the position's gamma.  Returns
-    (q_nope [..., S, H, nope], q_rope [..., S, H, rope])."""
-    H, nope = spec.num_heads, spec.qk_nope_head_dim
+def _mla_cq(normed, lp, spec: ModelSpec):
+    """The queries' normed bottleneck ``c_q`` [..., S, q_lora_rank]."""
     cq = jnp.einsum("...d,dr->...r", normed, lp["q_a"]["w"])
-    cq = rms_norm(cq, lp["q_a_norm"], spec.rms_eps, spec.unit_offset_norm)
-    q = jnp.einsum("...r,rh->...h", cq, lp["q_b"]["w"])
-    q = q.reshape(*q.shape[:-1], H, nope + spec.qk_rope_head_dim)
+    return rms_norm(cq, lp["q_a_norm"], spec.rms_eps, spec.unit_offset_norm)
+
+
+@jax.named_scope("mla_q")
+def _mla_q(normed, lp, spec: ModelSpec, positions, cq=None, q_b=None):
+    """Latent attention's queries on normed rows [..., S, D]: through
+    the normed bottleneck (``cq`` if the caller has it), per head
+    ``[q_nope | q_rope]`` with the rotary part rotated, both scaled by
+    the position's gamma; ``q_b``: some heads' columns of the
+    up-projection in place of all.  Returns (q_nope [..., S, H, nope],
+    q_rope [..., S, H, rope])."""
+    nope = spec.qk_nope_head_dim
+    if cq is None:
+        cq = _mla_cq(normed, lp, spec)
+    q = jnp.einsum("...r,rh->...h", cq,
+                   lp["q_b"]["w"] if q_b is None else q_b)
+    q = q.reshape(*q.shape[:-1], -1, nope + spec.qk_rope_head_dim)
     q_nope = q[..., :nope]
     q_rope = apply_rope(q[..., nope:], positions, spec.rope_theta,
                         spec.rope_scaling)
@@ -733,7 +833,7 @@ def _mla_out(attn, lp):
 
 
 def _mla_prompt(normed, lp, spec: ModelSpec, positions, kp, vp, index,
-                write_tables, ctx_tables, attend):
+                write_tables, ctx_tables, attend, cq=None):
     """Latent attention over prompt rows normed [B, S, D]: the rows'
     latent goes to the pool (whole pages), K and V are expanded from the
     prompt's own rows or, for a suffix against a cached prefix
@@ -741,7 +841,7 @@ def _mla_prompt(normed, lp, spec: ModelSpec, positions, kp, vp, index,
     never cached."""
     B, S = normed.shape[:2]
     ps, width = kp.shape[-2], kp.shape[-1]
-    q = jnp.concatenate(_mla_q(normed, lp, spec, positions), axis=-1)
+    q = jnp.concatenate(_mla_q(normed, lp, spec, positions, cq), axis=-1)
     rows = _mla_latent(normed, lp, spec, positions, width)
     kp = kv_write_pages(kp, write_tables[:, :S // ps],
                         rows.reshape(B, S // ps, 1, ps, width), layer=index)
@@ -754,11 +854,12 @@ def _mla_prompt(normed, lp, spec: ModelSpec, positions, kp, vp, index,
 
 
 def _mla_step(normed, lp, spec: ModelSpec, positions, kp, vp, index,
-              write_attend):
+              write_attend, cq=None):
     """Latent attention for one decode step, normed [B, D], in the
     ABSORBED form: ``W_uk`` folded into the query, ``W_uv`` into the
     output, the step reads the pool's rows alone."""
-    q_nope, q_rope = _mla_q(normed[:, None], lp, spec, positions[:, None])
+    q_nope, q_rope = _mla_q(normed[:, None], lp, spec, positions[:, None],
+                            cq)
     width = kp.shape[-1]
     row = _mla_latent(normed[:, None], lp, spec, positions[:, None],
                       width)[:, 0]
@@ -771,6 +872,211 @@ def _mla_step(normed, lp, spec: ModelSpec, positions, kp, vp, index,
     with jax.named_scope("mla_absorb"):
         attn = jnp.einsum("bhk,khv->bhv", attn, lp["kv_b_v"]["w"])
     return _mla_out(attn, lp), kp, vp
+
+
+def _index_rotate(t, positions, spec: ModelSpec):
+    """Rotary on the FIRST ``qk_rope_head_dim`` dimensions of index
+    heads t [..., S, heads, di]."""
+    return apply_rope(t, positions, spec.rope_theta, spec.rope_scaling,
+                      rotary_dim=spec.qk_rope_head_dim)
+
+
+@jax.named_scope("dsa_index")
+def _dsa_index_key(normed, lp, spec: ModelSpec, positions):
+    """A picking layer's ONE index key a token on normed rows [..., S,
+    D]: a LayerNorm with weight and bias, then the rotation, [..., S,
+    di]."""
+    k32 = jnp.einsum("...d,dk->...k", normed,
+                     lp["index_k"]["w"]).astype(jnp.float32)
+    k32 = k32 - jnp.mean(k32, axis=-1, keepdims=True)
+    k32 = k32 * jax.lax.rsqrt(
+        jnp.mean(k32 * k32, axis=-1, keepdims=True) + 1e-6)
+    key = (k32 * lp["index_k_norm"].astype(jnp.float32)
+           + lp["index_k_bias"].astype(jnp.float32)).astype(normed.dtype)
+    return _index_rotate(key[..., None, :], positions, spec)[..., 0, :]
+
+
+@jax.named_scope("dsa_index")
+def _dsa_index_query(normed, cq, lp, spec: ModelSpec, positions):
+    """A picking layer's index queries on normed rows [..., S, D] with
+    their query latent ``cq``: [..., S, Hi, di], rotated, and the heads'
+    weights [..., S, Hi] float32 with the two scales in (``Hi^-0.5 x
+    di^-0.5``)."""
+    Hi, di = spec.index_n_heads, spec.index_head_dim
+    qi = jnp.einsum("...r,rh->...h", cq, lp["index_q"]["w"])
+    qi = _index_rotate(qi.reshape(*qi.shape[:-1], Hi, di), positions, spec)
+    w = jnp.einsum("...d,dh->...h", normed, lp["index_w"]["w"],
+                   preferred_element_type=jnp.float32)
+    return qi, w * (Hi ** -0.5 * di ** -0.5)
+
+
+# rows of a prompt's index scores held at once, [rows, keys] float32
+DSA_SCORE_ROWS = 1024
+# bytes one expanded K (or V, or Q) of a prompt may take: beyond it a
+# prompt's attention goes through in groups of heads
+DSA_EXPAND_BYTES = 80 << 20
+
+
+@jax.named_scope("dsa_select")
+def _dsa_prompt_mask(scores, live, topk: int):
+    """scores [..., S, T] float32 (``-inf`` where not ``live``) -> the
+    selection as an int8 mask."""
+    return (dsa.select_mask(scores, topk) & live).astype(jnp.int8)
+
+
+def _dsa_prompt_select(normed, cq, lp, keys, positions, total_lens,
+                       spec: ModelSpec, kernel: bool):
+    """The selection of prompt rows (normed [B, S, D] with their query
+    latent, at ``positions`` [B, S]) over the context's index keys [B, T,
+    di] (from position 0) as a mask [B, S, T] int8: nonzero where the
+    row attends.  ``kernel``: a whole prompt's rows against their own
+    keys, a block of rows at a time (its index queries, its scores
+    through the scoring kernel, its threshold), so that neither an [S,
+    S] float32 array nor all rows' index queries stand."""
+    B, S = normed.shape[:2]
+    T = keys.shape[1]
+    if kernel and S % DSA_SCORE_ROWS == 0:
+        from vgate_tpu.ops.pallas.dsa import dsa_prompt_scores_pallas
+
+        R = DSA_SCORE_ROWS
+        blocks = lambda t, b: t[b].reshape((S // R, R) + t.shape[2:])
+
+        def block(xs, b):
+            rows, cq_rows, pos = xs
+            qi, w = _dsa_index_query(rows, cq_rows, lp, spec, pos)
+            with jax.named_scope("dsa_index"):
+                scores = dsa_prompt_scores_pallas(qi, w, keys[b], pos[0])
+            return _dsa_prompt_mask(scores, scores > dsa.NEG_INF,
+                                    spec.index_topk)
+
+        return jnp.stack([
+            jax.lax.map(
+                functools.partial(block, b=b),
+                (blocks(normed, b), blocks(cq, b), blocks(positions, b)),
+            ).reshape(S, T)
+            for b in range(B)])
+    qi, w = _dsa_index_query(normed, cq, lp, spec, positions)
+    k_pos = jnp.arange(T)[None, None, :]
+    live = ((k_pos <= positions[..., None])
+            & (k_pos < total_lens[:, None, None]))
+    with jax.named_scope("dsa_index"):
+        scores = jnp.where(live, dsa.index_scores(qi, w, keys), dsa.NEG_INF)
+    return _dsa_prompt_mask(scores, live, spec.index_topk)
+
+
+def _head_groups(spec: ModelSpec, rows: int, itemsize: int) -> int:
+    """Groups of heads a prompt's attention under a selection goes
+    through in: the fewest that keep one expanded K under
+    ``DSA_EXPAND_BYTES``."""
+    H = spec.num_heads
+    per_head = rows * (spec.qk_nope_head_dim + spec.qk_rope_head_dim) * itemsize
+    return next(g for g in range(1, H + 1)
+                if H % g == 0 and per_head * (H // g) <= DSA_EXPAND_BYTES)
+
+
+def _latent_layer(spec: ModelSpec, index, picks: bool):
+    """The latent pool's layer of a sub-block under a selection: the
+    reusing layers' rows first (``index`` among them), the picking
+    layers' behind them (``index`` among those, which is also their
+    layer of the index keys' array)."""
+    return index + (spec.attn_layers - spec.index_layers if picks else 0)
+
+
+def _dsa_prompt(normed, lp, spec: ModelSpec, positions, kp, vp, st, index,
+                picks: bool, write_tables, ctx_tables, total_lens, attend,
+                attend_selected, kernel: bool):
+    """Latent attention under a selection over prompt rows normed [B, S,
+    D] (``_mla_prompt``'s pass: the rows' latent to the pool, K and V
+    expanded and never cached).  A layer that ``picks`` writes the rows'
+    index keys to the pool's second array (``vp``, the same pages),
+    scores the context's and leaves the selection in ``st["sel"]``; its
+    attention, as a reusing layer's, runs under that mask
+    (``attend_selected(q, k, v, mask)``), a group of heads at a time
+    where the expansion is large.  A context of at most ``index_topk``
+    tokens is attended whole: ``_mla_prompt`` with ``attend``."""
+    B, S = normed.shape[:2]
+    ps, width = kp.shape[-2], kp.shape[-1]
+    layer = _latent_layer(spec, index, picks)
+    T = S if ctx_tables is None else ctx_tables.shape[1] * ps
+    cq = _mla_cq(normed, lp, spec)
+    if picks:
+        key = _dsa_index_key(normed, lp, spec, positions)
+        vp = kv_write_pages(
+            vp, write_tables[:, :S // ps],
+            key.reshape(B, S // ps, 1, ps, key.shape[-1]), layer=index)
+    if T <= spec.index_topk:  # nothing to leave out
+        out, kp = _mla_prompt(normed, lp, spec, positions, kp, None, layer,
+                              write_tables, ctx_tables, attend, cq=cq)
+        return out, kp, vp, st
+    if picks:
+        keys = key if ctx_tables is None else mla_gather_rows(
+            vp, ctx_tables, index)
+        st = {**st, "sel": _dsa_prompt_select(
+            normed, cq, lp, keys, positions, total_lens, spec,
+            kernel and ctx_tables is None)}
+    mask = st["sel"]
+    rows = _mla_latent(normed, lp, spec, positions, width)
+    kp = kv_write_pages(kp, write_tables[:, :S // ps],
+                        rows.reshape(B, S // ps, 1, ps, width), layer=layer)
+    if ctx_tables is not None:
+        rows = mla_gather_rows(kp, ctx_tables, layer)
+    H, kl = spec.num_heads, spec.kv_lora_rank
+    nope, rope, vd = (spec.qk_nope_head_dim, spec.qk_rope_head_dim,
+                      spec.v_head_dim)
+    G = _head_groups(spec, T, normed.dtype.itemsize)
+    by_group = lambda t, axis: jnp.moveaxis(
+        t.reshape(t.shape[:axis] + (G, H // G) + t.shape[axis + 1:]), axis, 0)
+    weights = (
+        by_group(lp["q_b"]["w"].reshape(-1, H, nope + rope), 1),
+        by_group(lp["kv_b_k"]["w"], 1), by_group(lp["kv_b_v"]["w"], 1),
+        lp["o"]["w"].reshape(G, H // G * vd, -1),
+    )
+
+    def group(acc, ws):
+        q_b, w_uk, w_uv, w_o = ws
+        q = jnp.concatenate(_mla_q(
+            None, lp, spec, positions, cq=cq,
+            q_b=q_b.reshape(q_b.shape[0], -1)), axis=-1)
+        k, v = _mla_expand(rows, {"kv_b_k": {"w": w_uk},
+                                  "kv_b_v": {"w": w_uv}}, spec)
+        with jax.named_scope("dsa_attend"):
+            attn = attend_selected(q, k, v, mask)
+        with jax.named_scope("o_proj"):
+            out = jnp.einsum("bsh,hd->bsd", attn.reshape(B, S, -1), w_o,
+                             preferred_element_type=jnp.float32)
+        return acc + out, None
+
+    if G == 1:
+        out, _ = group(0.0, jax.tree.map(lambda a: a[0], weights))
+    else:
+        out, _ = jax.lax.scan(
+            group, jnp.zeros(normed.shape, jnp.float32), weights)
+    return out.astype(normed.dtype), kp, vp, st
+
+
+def _dsa_step(normed, lp, spec: ModelSpec, positions, kp, vp, st, index,
+              picks: bool, pick, attend):
+    """Latent attention under a selection for one decode step, normed
+    [B, D], absorbed as ``_mla_step``: a layer that ``picks`` hands its
+    index queries, weights and the token's index key to ``pick`` (the
+    caller's: the key into the pool's second array, the scores over the
+    slot's pages, the ``index_topk`` positions) and leaves the positions
+    in ``st["sel"]``; ``attend`` writes the token's latent row and
+    attends over the selected rows alone."""
+    layer = _latent_layer(spec, index, picks)
+    cq = _mla_cq(normed[:, None], lp, spec)
+    if picks:
+        at = positions[:, None]
+        qi, w = _dsa_index_query(normed[:, None], cq, lp, spec, at)
+        key = _dsa_index_key(normed[:, None], lp, spec, at)
+        sel, vp = pick(qi[:, 0], w[:, 0], key[:, 0], vp, index)
+        st = {**st, "sel": sel}
+    out, kp, _ = _mla_step(
+        normed, lp, spec, positions, kp, None, layer,
+        lambda q, row, _v, kp_, vp_, layer_: attend(
+            q, row, st["sel"], kp_, layer_) + (None,),
+        cq=cq)
+    return out, kp, vp, st
 
 
 def _segments(blocks):
@@ -914,11 +1220,27 @@ def _experts(normed, lp, spec: ModelSpec, row_mask, use_pallas, index,
     )
 
 
+# rows x width of a dense feed-forward's float32 activations held at once
+DENSE_BLOCK_ELEMS = 8192 * 18432
+
+
 @jax.named_scope("dense_mlp")
 def _dense(normed, lp, spec: ModelSpec):
+    """The dense SwiGLU; a long prompt's rows [B, S, D] in blocks, so
+    that the float32 gate of 16,384 rows never stands whole."""
     from vgate_tpu.models.decoder import _dense_mlp
 
-    return _dense_mlp(normed, lp, spec)
+    S = normed.shape[-2]
+    blocks = 1
+    while (S // blocks * spec.intermediate_size > DENSE_BLOCK_ELEMS
+           and S % (2 * blocks) == 0):
+        blocks *= 2
+    if normed.ndim < 3 or blocks == 1:
+        return _dense_mlp(normed, lp, spec)
+    B, _, D = normed.shape
+    rows = jnp.moveaxis(normed.reshape(B, blocks, S // blocks, D), 1, 0)
+    out = jax.lax.map(lambda r: _dense_mlp(r, lp, spec), rows)
+    return jnp.moveaxis(out, 0, 1).reshape(B, S, D)
 
 
 def _attn_scope(spec: ModelSpec) -> str:
@@ -954,10 +1276,17 @@ def _swa_chunk_attend(q, k, v, ring_k, ring_v, index, spec: ModelSpec,
     )
 
 
+def _without_selection(state):
+    """The state without the walk's own ``"sel"`` (None if that was all)."""
+    if state and "sel" in state:
+        state = {k: v for k, v in state.items() if k != "sel"} or None
+    return state
+
+
 def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
                    v_pages, state, slots, fresh, write_tables, attend,
                    use_pallas: bool, ctx_tables=None, swa_attend=None,
-                   prefix_lens=None):
+                   prefix_lens=None, dsa_attend=None, total_lens=None):
     """The prompt pass over embedded rows x [B, S, D] (a whole prompt,
     or the suffix / one chunk of one).  ``write_tables`` are the pages
     the rows' K/V go to (whole pages from the rows' first position);
@@ -967,8 +1296,11 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
     then expanded from.  A window layer attends in flight
     (``swa_attend(q, k, v)``, a whole prompt's) or, for rows that start
     at ``prefix_lens``, against the ring and themselves, and leaves the
-    rows that survive in the slot's ring.  Returns (x, k_pages, v_pages,
-    state)."""
+    rows that survive in the slot's ring.  A spec that picks
+    (``is_dsa``): ``v_pages`` is the index keys' array,
+    ``dsa_attend(q, k, v, mask)`` the attention under a selection and
+    ``total_lens`` the contexts' lengths (``lens`` for a whole prompt).
+    Returns (x, k_pages, v_pages, state)."""
     B, S = x.shape[:2]
     ps = k_pages.shape[-2]
     KV, hd = spec.cache_heads, spec.cache_head_dim
@@ -994,6 +1326,14 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
         if kind in _RECURRENT:
             out, st = _recurrent_prompt(kind, normed, lp, st, index, spec,
                                         lens, slots, fresh)
+            return out, kp, vp, st, None
+        if kind in ("mla", "dsa") and spec.is_dsa:
+            with jax.named_scope("mla_attn"), jax.named_scope("dsa_prompt"):
+                out, kp, vp, st = _dsa_prompt(
+                    normed, lp, spec, positions, kp, vp, st, index,
+                    kind == "dsa", write_tables, ctx_tables,
+                    lens if total_lens is None else total_lens, attend,
+                    dsa_attend, use_pallas)
             return out, kp, vp, st, None
         if kind == "mla":
             with jax.named_scope("mla_attn"):
@@ -1028,18 +1368,24 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
             out = _gated_out(attn, gate, lp, normed.dtype)
         return out, kp, vp, st, None
 
+    if spec.is_dsa:  # the selection rides the carry: a mask [B, S, T]
+        T = S if ctx_tables is None else ctx_tables.shape[1] * ps
+        state = {**(state or {}), "sel": jnp.zeros(
+            (B, S, T) if T > spec.index_topk else (B, 1, 1), jnp.int8)}
     x, k_pages, v_pages, state, _stats = _period_scan(
         params, spec, x, k_pages, v_pages, state, block_fn)
-    return x, k_pages, v_pages, state
+    return x, k_pages, v_pages, _without_selection(state)
 
 
 def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
                    state, active, write_attend, use_pallas: bool,
-                   ring_write_attend=None):
+                   ring_write_attend=None, dsa_steps=None):
     """One decode step over embedded rows x [B, D], row = slot.
     ``write_attend(q, k, v, kp, vp, layer)`` is the caller's cache step:
     the token's K and V into the pool and its attention over it;
-    ``ring_write_attend`` the same over a window layer's rings.
+    ``ring_write_attend`` the same over a window layer's rings;
+    ``dsa_steps`` (a spec that picks, ``v_pages`` its index keys' array)
+    ``(pick, attend, the positions a pick holds)``: ``_dsa_step``.
     Returns (x, k_pages, v_pages, state, stats [4])."""
     if active is None:
         active = jnp.ones(x.shape[:1], bool)
@@ -1066,6 +1412,12 @@ def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
                 out, st = step_fn(normed, lp, st, index, spec, active,
                                   use_pallas)
             return out, kp, vp, st, None
+        if kind in ("mla", "dsa") and spec.is_dsa:
+            with jax.named_scope("mla_attn"):
+                out, kp, vp, st = _dsa_step(
+                    normed, lp, spec, positions, kp, vp, st, index,
+                    kind == "dsa", *dsa_steps[:2])
+            return out, kp, vp, st, None
         if kind == "mla":
             with jax.named_scope("mla_attn"):
                 out, kp, vp = _mla_step(normed, lp, spec, positions, kp,
@@ -1082,5 +1434,9 @@ def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
                                     index, spec.global_rope)
         return out, kp, vp, st, None
 
-    return _period_scan(
+    if spec.is_dsa:  # the selection rides the carry: positions [B, k]
+        state = {**(state or {}), "sel": jnp.zeros(
+            (x.shape[0], dsa_steps[2]), jnp.int32)}
+    x, k_pages, v_pages, state, stats = _period_scan(
         params, spec, x, k_pages, v_pages, state, block_fn)
+    return x, k_pages, v_pages, _without_selection(state), stats

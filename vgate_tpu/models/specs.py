@@ -151,6 +151,22 @@ class ModelSpec:
     # leading layers whose feed-forward is a dense SwiGLU of
     # ``intermediate_size`` (DeepSeek's key ``first_k_dense_replace``)
     first_k_dense: int = 0
+    # ---- learned sparse attention over the latent cache (GLM-5.2,
+    # DeepSeek-V3.2's DSA): one letter a layer over the WHOLE stack, ``F``
+    # a layer whose indexer scores every cached token (``index_n_heads``
+    # query heads of ``index_head_dim`` against ONE key a token, held in
+    # a second array of the paged pool) and picks the ``index_topk`` it
+    # attends to, ``S`` a layer that attends to what the nearest ``F``
+    # layer below it picked.  Empty = every cached token, always
+    indexer_pattern: str = ""
+    # the stack is layers ``first_layer .. first_layer + num_layers - 1``
+    # of that pattern (and of ``first_k_dense``'s count): a pipeline
+    # stage's layers, stated against the published lists
+    first_layer: int = 0
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    indexer_rope_interleave: bool = False
 
     def __post_init__(self):
         if self.n_shared_experts and not self.shared_expert_intermediate_size:
@@ -178,6 +194,12 @@ class ModelSpec:
                 or self.is_mla or bool(self.window_pattern))
 
     @property
+    def is_dsa(self) -> bool:
+        """Latent attention over the ``index_topk`` cached tokens an
+        indexer picks (``indexer_pattern``), not over all of them."""
+        return bool(self.indexer_pattern)
+
+    @property
     def is_mla(self) -> bool:
         """Multi-head latent attention over a latent paged cache."""
         return self.kv_lora_rank > 0
@@ -189,7 +211,10 @@ class ModelSpec:
 
     @property
     def kv_pools(self) -> int:
-        """Arrays of the paged cache: K and V, or the one latent pool."""
+        """Arrays of the paged cache that hold ``cache_head_dim`` lanes a
+        layer of ``attn_layers``: K and V, or the one latent pool (the
+        index keys of a spec that picks ride beside it, in an array of
+        their own shape: ``index_layers`` x ``index_head_dim``)."""
         return 1 if self.is_mla else 2
 
     @property
@@ -220,7 +245,8 @@ class ModelSpec:
         num = lambda v: int(v) if float(v).is_integer() else v
         if self.yarn_factor <= 0:
             return {"rope_theta": num(self.rope_theta),
-                    "rope_type": "default"} if self.window_pattern else {}
+                    "rope_type": "default"} if (
+                        self.window_pattern or self.indexer_pattern) else {}
         return {
             "beta_fast": num(self.yarn_beta_fast),
             "beta_slow": num(self.yarn_beta_slow),
@@ -239,6 +265,8 @@ class ModelSpec:
         """Layers of one period: the pattern's smallest repeating unit
         (the whole of a pattern that does not repeat)."""
         pat = self.layer_pattern
+        if self.indexer_pattern:
+            return self._lead_and_period[1]
         if self.window_pattern:
             return len(self.window_pattern)
         if not pat:
@@ -260,8 +288,19 @@ class ModelSpec:
             out, seen = [], {}
             lead = self.lead_layers
             for i in range(lead, lead + self.layers_per_period):
-                mixer, ff = self._window_layer(i)
+                mixer, ff = self._stack_layer(i)
                 group = "window" if mixer == "swa" else "global"
+                j = seen.setdefault(group, 0)
+                seen[group] = j + 1
+                out += [(mixer, group, "input_norm", j),
+                        (ff, group, "post_norm", j)]
+            return tuple(out)
+        if self.indexer_pattern:  # the layers behind the leading ones
+            out, seen = [], {}
+            lead = self.lead_layers
+            for i in range(lead, lead + self.layers_per_period):
+                mixer, ff = self._stack_layer(i)
+                group = "pick" if mixer == "dsa" else "reuse"
                 j = seen.setdefault(group, 0)
                 seen[group] = j + 1
                 out += [(mixer, group, "input_norm", j),
@@ -284,18 +323,50 @@ class ModelSpec:
             seen[kind] = seen.get(kind, 0) + 1
         return tuple(out)
 
-    def _window_layer(self, i: int) -> tuple:
-        """Layer ``i`` of a ``window_pattern`` spec as its two
-        sub-blocks' kinds: (``swa`` | ``attn``, ``mlp`` | ``moe``)."""
+    def _stack_layer(self, i: int) -> tuple:
+        """Layer ``i`` of a ``window_pattern`` or an ``indexer_pattern``
+        spec as its two sub-blocks' kinds: (``swa`` | ``attn``, or
+        ``dsa`` (latent attention that picks) | ``mla`` (that reuses the
+        pick); ``mlp`` | ``moe``)."""
+        if self.indexer_pattern:
+            at = self.first_layer + i
+            return ("dsa" if self.indexer_pattern[at] == "F" else "mla",
+                    "mlp" if at < self.first_k_dense else "moe")
+        ff = "mlp" if i < self.first_k_dense else "moe"
         pat = self.window_pattern
-        return ("swa" if pat[i % len(pat)] == "L" else "attn",
-                "mlp" if i < self.first_k_dense else "moe")
+        return ("swa" if pat[i % len(pat)] == "L" else "attn", ff)
+
+    @property
+    def _dense_layers(self) -> int:
+        """Layers of THIS stack whose feed-forward is dense."""
+        return min(self.num_layers,
+                   max(0, self.first_k_dense - self.first_layer))
+
+    @property
+    def _lead_and_period(self) -> tuple:
+        """(leading layers, layers a period) of an ``indexer_pattern``
+        spec: behind the leading dense layers the pattern's rest must be
+        whole repeats of one unit; of all such cuts the one that leaves
+        the fewest layers unrolled (leading ones and one period)."""
+        n = self.num_layers
+        pat = self.indexer_pattern[self.first_layer:self.first_layer + n]
+        best = None
+        for lead in range(self._dense_layers, n):
+            rest = pat[lead:]
+            unit = next(u for u in range(1, len(rest) + 1)
+                        if len(rest) % u == 0
+                        and rest[:u] * (len(rest) // u) == rest)
+            if best is None or lead + unit < sum(best):
+                best = (lead, unit)
+        return best
 
     @property
     def lead_layers(self) -> int:
         """Layers the stack walker runs once, ahead of the scanned
         periods: the leading dense ones, and as many more as leave a
         whole number of periods behind them."""
+        if self.indexer_pattern:
+            return self._lead_and_period[0]
         if not self.window_pattern:
             return 0
         lead = self.first_k_dense
@@ -306,7 +377,7 @@ class ModelSpec:
     @property
     def lead_blocks(self) -> tuple:
         """The leading layers, each its two sub-blocks' kinds."""
-        return tuple(self._window_layer(i) for i in range(self.lead_layers))
+        return tuple(self._stack_layer(i) for i in range(self.lead_layers))
 
     def group_layers(self, group: str) -> int:
         """Layers a period holds in a parameter group."""
@@ -324,8 +395,14 @@ class ModelSpec:
     @property
     def attn_layers(self) -> int:
         """Layers that hold pages (K/V, or the latent)."""
-        return (self._layers_of("attn", "mla") if self.is_hybrid
+        return (self._layers_of("attn", "mla", "dsa") if self.is_hybrid
                 else self.num_layers)
+
+    @property
+    def index_layers(self) -> int:
+        """Layers that pick: each holds an index key a token in the
+        pool's second array."""
+        return self._layers_of("dsa")
 
     @property
     def linear_layers(self) -> int:
@@ -418,6 +495,8 @@ class ModelSpec:
             return self._pattern_params()
         if self.window_pattern:
             return self._window_params()
+        if self.indexer_pattern:
+            return self._dsa_params()
         q_dim = self.num_heads * self.head_dim
         kv_dim = self.num_kv_heads * self.head_dim
         attn = D * q_dim + 2 * D * kv_dim + q_dim * D
@@ -497,6 +576,38 @@ class ModelSpec:
                 + (self.num_layers - dense) * moe
                 + (1 if self.tie_embeddings else 2) * V * D + D)
 
+    def _dsa_params(self) -> int:
+        """``num_params`` of an ``indexer_pattern`` stack: every layer
+        latent attention and its two norms, a picking layer its indexer
+        (queries from the query latent, ONE key a token under a
+        LayerNorm, the heads' weights), then a dense SwiGLU (the leading
+        layers) or the expert layer."""
+        D, V, H = self.hidden_size, self.vocab_size, self.num_heads
+        ql, kl = self.q_lora_rank, self.kv_lora_rank
+        nope, vd = self.qk_nope_head_dim, self.v_head_dim
+        attn = (D * ql + ql + ql * H * (nope + self.qk_rope_head_dim)
+                + D * self.latent_dim + kl + kl * H * (nope + vd)
+                + H * vd * D + 2 * D)
+        Hi, di = self.index_n_heads, self.index_head_dim
+        indexer = ql * Hi * di + D * di + 2 * di + D * Hi
+        Fe, Fs = self.expert_width, self.shared_expert_intermediate_size
+        moe = (D * self.router_experts + self.num_experts * 3 * D * Fe
+               + 3 * D * Fs)
+        if self.router_scoring == "sigmoid":
+            moe += self.router_experts
+        dense = self._dense_layers
+        return (self.num_layers * attn + self.index_layers * indexer
+                + dense * 3 * D * self.intermediate_size
+                + (self.num_layers - dense) * moe
+                + (1 if self.tie_embeddings else 2) * V * D + D)
+
+    # an ``indexer_pattern`` spec's pattern as the published config.json
+    # lists it, whole (what perfbench/serve.py holds the program to)
+    @property
+    def indexer_types(self) -> list:
+        return ["full" if c == "F" else "shared"
+                for c in self.indexer_pattern]
+
     @property
     def layer_windows(self) -> tuple:
         """Per-layer attention window (0 = global): ``window_pattern``'s
@@ -508,7 +619,7 @@ class ModelSpec:
         if self.window_pattern:
             return tuple(
                 self.sliding_window
-                if self._window_layer(i)[0] == "swa" else 0
+                if self._stack_layer(i)[0] == "swa" else 0
                 for i in range(self.num_layers))
         return tuple(
             self.sliding_window if i % 2 == 0 else 0
@@ -528,8 +639,10 @@ class ModelSpec:
 
     @property
     def mlp_layer_types(self) -> list:
+        # an ``indexer_pattern`` spec states the published stack's
+        depth = len(self.indexer_pattern) or self.num_layers
         return ["dense" if i < self.first_k_dense else "sparse"
-                for i in range(self.num_layers)]
+                for i in range(depth)]
 
     def check_expert_share(self) -> None:
         """The held experts lie inside the router's width."""
@@ -1196,6 +1309,97 @@ TINY_SWA_MOE = _register(
         window_pattern="LLLG",
         global_rope=False,
         first_k_dense=1,
+    )
+)
+
+# GLM-5.2 (zai-org, model_type glm_moe_dsa) at the published sizes: 78
+# layers of latent attention under a learned selection (an indexer in
+# layers 0-2 and every fourth from 6 on picks 2,048 cached tokens a
+# query, the three layers after it reuse the pick), three leading dense
+# layers, then 256 sigmoid-routed experts top 8 with one ungated shared
+# expert.  The multi-token-prediction module is not part of the stack
+_GLM52_INDEXER = "FFF" + "SSSF" * 18 + "SSS"
+GLM_5_2 = _register(
+    ModelSpec(
+        name="zai-org/GLM-5.2",
+        vocab_size=154880,
+        hidden_size=6144,
+        num_layers=78,
+        num_heads=64,
+        num_kv_heads=64,
+        head_dim=192,  # the published key's value (= qk_nope_head_dim)
+        intermediate_size=12288,
+        rope_theta=8_000_000.0,
+        rms_eps=1e-5,
+        qkv_bias=False,
+        tie_embeddings=False,
+        eos_token_id=154820,
+        bos_token_id=154822,
+        max_position_embeddings=1048576,
+        num_experts=256,
+        experts_per_token=8,
+        moe_intermediate_size=2048,
+        router_width=256,
+        shared_expert_gate=False,
+        n_shared_experts=1,
+        router_scoring="sigmoid",
+        routed_scaling_factor=2.5,
+        q_lora_rank=2048,
+        kv_lora_rank=512,
+        qk_nope_head_dim=192,
+        qk_rope_head_dim=64,
+        v_head_dim=256,
+        rope_interleave=True,
+        first_k_dense=3,
+        indexer_pattern=_GLM52_INDEXER,
+        index_topk=2048,
+        index_n_heads=32,
+        index_head_dim=128,
+        indexer_rope_interleave=True,
+    )
+)
+
+# every mechanism of GLM-5.2 at toy widths: a leading dense layer whose
+# indexer picks, then two periods of three layers that reuse a pick to
+# one that picks; 16 tokens picked, so that a CPU test's contexts pass
+# the count within a few pages of 8
+TINY_DSA_MOE = _register(
+    ModelSpec(
+        name="tiny-dsa-moe",
+        vocab_size=512,
+        hidden_size=64,
+        num_layers=9,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=16,
+        intermediate_size=128,
+        rope_theta=10000.0,
+        rms_eps=1e-5,
+        qkv_bias=False,
+        tie_embeddings=False,
+        eos_token_id=0,
+        bos_token_id=1,
+        max_position_embeddings=4096,
+        num_experts=8,
+        experts_per_token=2,
+        moe_intermediate_size=32,
+        router_width=8,
+        shared_expert_gate=False,
+        n_shared_experts=1,
+        router_scoring="sigmoid",
+        routed_scaling_factor=2.5,
+        q_lora_rank=24,
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=24,
+        rope_interleave=True,
+        first_k_dense=1,
+        indexer_pattern="F" + "SSSF" * 2,
+        index_topk=16,
+        index_n_heads=4,
+        index_head_dim=16,
+        indexer_rope_interleave=True,
     )
 )
 

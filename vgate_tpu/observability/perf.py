@@ -448,6 +448,7 @@ class PerfRecorder:
         self._state: Dict[str, int] = {}
         self._mla: Dict[str, int] = {}
         self._swa: Dict[str, int] = {}
+        self._dsa: Dict[str, int] = {}
         self.total_engine_cpu_s = 0.0
         self.total_engine_cpu_in_wait_s = 0.0
         # the flight recorder's per-request phase sums (admitted /
@@ -794,6 +795,43 @@ class PerfRecorder:
         ):
             self._mla[name] = self._mla.get(name, 0) + add
 
+    def note_dsa_decode(self, steps: int, lens, layers: int,
+                        index_layers: int, topk: int) -> None:
+        """One decode chunk of a spec that attends under a learned
+        selection, booked once per readback: the sequences of lengths
+        ``lens`` (at dispatch) rode ``steps`` steps; step k scored a
+        row's whole context so far (its length + k) in each of
+        ``index_layers`` picking layers, wrote one index key there, and
+        attended to ``min(context, topk)`` latent rows in each of
+        ``layers`` layers, ``layers - index_layers`` of which reused a
+        pick.  What the kernels have to read, and no padding."""
+        ctx = sum(steps * n + steps * (steps - 1) // 2 for n in lens)
+        attended = sum(min(n + k, topk) for n in lens for k in range(steps))
+        for name, add in (
+            ("index_layer_steps", steps * index_layers),
+            ("rows_scored", ctx * index_layers),
+            ("rows_attended", attended * layers),
+            ("rows_in_context", ctx * layers),
+            ("selections_reused", steps * (layers - index_layers)),
+            ("index_rows_written", steps * len(lens) * index_layers),
+            ("decode_steps", steps),
+        ):
+            self._dsa[name] = self._dsa.get(name, 0) + add
+
+    def note_dsa_prefill(self, tokens: int, cached: int,
+                         index_layers: int) -> None:
+        """One prompt of such a spec, booked at its readback: positions
+        ``cached .. tokens - 1`` went through the prompt pass, position
+        i scored against i + 1 index keys in each of ``index_layers``
+        picking layers, and left one index key there."""
+        pairs = (tokens * (tokens + 1) - cached * (cached + 1)) // 2
+        for name, add in (
+            ("prefill_pairs_scored", pairs * index_layers),
+            ("index_rows_written", (tokens - cached) * index_layers),
+            ("prefill_prompts", 1),
+        ):
+            self._dsa[name] = self._dsa.get(name, 0) + add
+
     def note_swa_decode(self, steps: int, lens, layers: int,
                         window: int) -> None:
         """One decode chunk of a spec with window layers, booked once
@@ -1069,6 +1107,8 @@ class PerfRecorder:
             out["mla"] = dict(self._mla)
         if self._swa:
             out["swa"] = dict(self._swa)
+        if self._dsa:
+            out["dsa"] = dict(self._dsa)
         return out
 
     def snapshot(self) -> Dict[str, Any]:

@@ -1,0 +1,147 @@
+"""Learned sparse attention over the latent cache (DeepSeek-V3.2's DSA,
+GLM-5.2's ``glm_moe_dsa``): the indexer's scores, the selection, and
+decode attention over the selected rows alone.
+
+A layer that picks scores every cached position ``s <= t`` for the query
+at ``t``::
+
+    I(t, s) = sum_j w_j(t) * relu(q^I_j(t) . k^I_s)        (float32)
+
+with ``index_n_heads`` query heads against ONE key a token, and keeps the
+``index_topk`` positions of largest ``I(t, .)`` (all of them while ``t <
+index_topk``; ties to the lower position).  The layer's latent attention,
+and that of the layers that reuse the pick, runs over those positions
+only.
+
+Two forms of the selection: POSITIONS ``[B, k]`` (a decode step: the
+rows are then gathered through the page table, ``dsa_decode_attention``)
+and a MASK ``[B, S, T]`` (a prompt pass: the flash kernel takes it tile
+by tile).  The Pallas kernels are in ``ops/pallas/dsa.py``; what is here
+is their ``jax.numpy`` twins and what XLA does as well as a kernel
+would.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+NEG_INF = float("-inf")
+
+
+def index_scores(qi, w, keys):
+    """The indexer's scores of query rows against keys, float32:
+    qi [B, S, Hi, d], w [B, S, Hi] (float32, the heads' weights with
+    their two scales in), keys [B, T, d] -> [B, S, T].  No mask."""
+    s = jnp.einsum("bshd,btd->bsht", qi, keys.astype(qi.dtype),
+                   preferred_element_type=jnp.float32)
+    return jnp.sum(jnp.maximum(s, 0.0) * w[..., None], axis=2)
+
+
+def _ordered_bits(x):
+    """float32 -> uint32 whose order is the floats' (no NaN)."""
+    b = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    b = b ^ ((b >> 31) & jnp.int32(0x7FFFFFFF))
+    return jax.lax.bitcast_convert_type(b, jnp.uint32) ^ jnp.uint32(1 << 31)
+
+
+def select_mask(scores, k: int):
+    """The ``k`` largest of each row of scores [..., T] as a bool mask,
+    ties to the lower position; a row of fewer than ``k`` entries above
+    ``-inf`` keeps some of those too (the caller's causal mask takes
+    them out).  No sort: the k-th largest value by a binary search over
+    the floats' ordered bits (32 counts over the row), and only where a
+    row has more ties at that value than places left, the ties' cut-off
+    position by a second search (a count a bit of the position)."""
+    T = scores.shape[-1]
+    if k >= T:
+        return jnp.ones(scores.shape, bool)
+    u = _ordered_bits(scores)
+    count = lambda m: jnp.sum(m, axis=-1, keepdims=True, dtype=jnp.int32)
+
+    def value_bit(i, thr):
+        cand = thr | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        return jnp.where(count(u >= cand) >= k, cand, thr)
+
+    thr = jax.lax.fori_loop(
+        0, 32, value_bit, jnp.zeros(scores.shape[:-1] + (1,), jnp.uint32))
+    above, ties = u > thr, u == thr
+    room = k - count(above)  # places left for the ties, >= 1
+    pos = jnp.arange(T, dtype=jnp.int32)
+    bits = max(1, (T - 1).bit_length())
+
+    def cut_ties():
+        def pos_bit(i, cut):
+            cand = cut | (jnp.int32(1) << (bits - 1 - i))
+            return jnp.where(count(ties & (pos < cand)) < room, cand, cut)
+
+        cut = jax.lax.fori_loop(0, bits, pos_bit, jnp.zeros_like(room))
+        return above | (ties & (pos <= cut))
+
+    return jax.lax.cond(jnp.any(count(ties) > room), cut_ties,
+                        lambda: above | ties)
+
+
+def select_positions(scores, k: int):
+    """The positions of the ``k`` largest of each row of scores [B, T],
+    [B, min(k, T)] int32 (``jax.lax.top_k``: of equal scores the lower
+    position first).  Entries at ``-inf`` come last, in position order:
+    a row of ``n < k`` live entries has its pick in the first ``n``."""
+    return jax.lax.top_k(scores, min(k, scores.shape[-1]))[1].astype(
+        jnp.int32)
+
+
+def gather_selected(pool, page_tables, sel, layer):
+    """The pool's rows at the selected positions, through the page table:
+    pool [L, 1, P, ps, W], page_tables [B, n], sel [B, k] (positions) ->
+    [B, k, W].  ONE gather of rows over the pool seen as ``[L x P x ps,
+    W]`` (the leading dimensions merge without moving a byte)."""
+    L, _, P, ps, W = pool.shape
+    page = jnp.take_along_axis(page_tables, sel // ps, axis=1)
+    flat = (layer * P + page) * ps + sel % ps
+    return pool.reshape(L * P * ps, W)[flat]
+
+
+def dsa_decode_attention(q, pool, page_tables, sel, n_sel, layer, *,
+                         v_width: int, scale: float, use_pallas: bool):
+    """Absorbed latent decode attention over the SELECTED rows only:
+    q [B, H, W], sel [B, k] the positions (a slot's first ``n_sel`` are
+    its pick) -> [B, H, v_width].  The rows are gathered through the
+    page table into ``[B, k, W]`` and met as a pool of their own, ``k /
+    ps`` pages a slot in order, by the dense latent kernel under the
+    name ``dsa_decode_attention_pallas`` (or its jnp twin): no row
+    outside the pick is read."""
+    from vgate_tpu.ops.attention import mla_decode_attention
+
+    B, k = sel.shape
+    ps, W = pool.shape[-2], pool.shape[-1]
+    with jax.named_scope("dsa_gather"):
+        rows = gather_selected(pool, page_tables, sel, layer)
+    pad = -k % ps  # a pick that is no whole number of pages (tiny sizes)
+    if pad:
+        rows = jnp.pad(rows, ((0, 0), (0, pad), (0, 0)))
+    n = (k + pad) // ps
+    own = rows.reshape(1, 1, B * n, ps, W)
+    tables = jnp.arange(B * n, dtype=jnp.int32).reshape(B, n)
+    if use_pallas:
+        from vgate_tpu.ops.pallas.dsa import dsa_decode_attention_pallas
+
+        return dsa_decode_attention_pallas(
+            q, own, tables, n_sel, v_width=v_width, scale=scale)
+    return mla_decode_attention(q, own, tables, n_sel, 0, v_width, scale)
+
+
+def masked_attention(q, k, v, mask, scale: float):
+    """Plain softmax attention under a mask, float32 inside: q [B, S, H,
+    hd], k / v [B, T, H, .], mask [B, S, T] (nonzero = attend; every
+    real row attends to itself) -> [B, S, H, v].  The ``jax.numpy`` twin
+    of the prompt kernel under a selection, whole score matrix and all:
+    for a CPU's sizes and for the passes no kernel covers yet."""
+    s = jnp.einsum("bshd,bthd->bhst", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(mask[:, None] != 0, s, -1e30)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    out = jnp.einsum("bhst,bthv->bshv", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)

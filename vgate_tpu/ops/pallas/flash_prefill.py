@@ -45,20 +45,22 @@ def _kernel(
     q_ref,  # [1, 1, block_q, hd]
     k_ref,  # [1, 1, block_k, hd]
     v_ref,  # [1, 1, block_k, hd]
-    # output
-    out_ref,  # [1, 1, block_q, hd]
-    # scratch
-    acc_ref,  # [block_q, hd] f32
-    m_ref,  # [block_q, 128] f32 running max (column-broadcast)
-    l_ref,  # [block_q, 128] f32 running denom
-    *,
+    # then: mask_ref [1, block_q, block_k] int8 where ``masked``;
+    # the output out_ref [1, 1, block_q, hd]; the scratch acc_ref
+    # [block_q, hd] f32, m_ref [block_q, 128] f32 running max
+    # (column-broadcast) and l_ref [block_q, 128] f32 running denom
+    *rest,
     block_q: int,
     block_k: int,
     n_k: int,
     softcap: float,
     scale: float,
     band: int = 0,
+    masked: bool = False,
+    skip_padding: bool = False,
 ):
+    mask_ref = rest[0] if masked else None
+    out_ref, acc_ref, m_ref, l_ref = rest[-4:]
     b = pl.program_id(0)
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -87,7 +89,15 @@ def _kernel(
         k_start + block_k - 1 >= q_start - window + 1
     )
 
-    live = causal_live & window_live
+    # a block of keys past the row's length holds nothing a query sees;
+    # a block of QUERIES past it is padding, which a caller that never
+    # reads such rows leaves out (``skip_padding``: they come out zero;
+    # a prompt that fills two thirds of its bucket skips a third of the
+    # lower triangle)
+    real = k_start < seq_len
+    if skip_padding:
+        real = real & (q_start < seq_len)
+    live = causal_live & window_live & real
     if band:
         live = live & (kb >= 0)
 
@@ -111,6 +121,8 @@ def _kernel(
         )
         mask = (k_pos <= q_pos) & (k_pos < seq_len)
         mask = mask & ((window <= 0) | (q_pos - k_pos < window))
+        if masked:  # a selection (ops/dsa.py): the caller's tile
+            mask = mask & (mask_ref[0] != 0)
         scores = jnp.where(mask, scores, -1e30)
 
         m_prev = m_ref[:, :1]  # [block_q, 1]
@@ -118,6 +130,9 @@ def _kernel(
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(scores - m_new)  # [block_q, block_k]
+        if masked:  # a row with nothing picked in this block and none
+            # before it: exp(-1e30 + 1e30) would count every key
+            p = jnp.where(mask, p, 0.0)
         l_ref[...] = jnp.broadcast_to(
             alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True),
             l_ref.shape,
@@ -137,8 +152,8 @@ def _kernel(
 @functools.partial(
     jax.jit,
     static_argnames=("block_q", "block_k", "interpret", "softcap", "scale",
-                     "band", "name"),
-)
+                     "band", "name", "skip_padding"),
+)  # (``mask`` is an array: its presence alone is static)
 def flash_prefill_attention_pallas(
     q: jnp.ndarray,  # [B, S, H, hd]
     k: jnp.ndarray,  # [B, Sk, KV, hd]
@@ -153,6 +168,8 @@ def flash_prefill_attention_pallas(
     scale=None,  # static query scale; default hd**-0.5
     band: int = 0,  # >0: only the last `band` key blocks of a query block
     name=None,  # the launch's name in a device trace
+    mask=None,  # [B, S, Sk] int8, nonzero = attend: beside causal + length
+    skip_padding: bool = False,  # query blocks past seq_lens: zeros
 ) -> jnp.ndarray:
     """Causal (optionally offset) attention. Returns [B, S, H, hd].
     ``band`` (with ``window``, no ``q_offsets`` and ``block_q`` a
@@ -192,6 +209,8 @@ def flash_prefill_attention_pallas(
         softcap=float(softcap),
         scale=float(scale) if scale is not None else hd ** -0.5,
         band=n_k if band else 0,
+        masked=mask is not None,
+        skip_padding=skip_padding,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -212,7 +231,13 @@ def flash_prefill_attention_pallas(
                 lambda b, h, qi, ki, *pf: (b, h // G, k_block(qi, ki), 0),
                 memory_space=pltpu.VMEM,
             ),
-        ],
+        ] + ([] if mask is None else [
+            pl.BlockSpec(
+                (1, block_q, block_k),
+                lambda b, h, qi, ki, *pf: (b, qi, k_block(qi, ki)),
+                memory_space=pltpu.VMEM,
+            ),
+        ]),
         out_specs=pl.BlockSpec(
             (1, 1, block_q, hd),
             lambda b, h, qi, ki, *pf: (b, h, qi, 0),
@@ -237,7 +262,7 @@ def flash_prefill_attention_pallas(
         name=name,
     )(
         seq_lens.astype(jnp.int32), q_offsets.astype(jnp.int32),
-        window_arr, qt, kt, vt,
+        window_arr, qt, kt, vt, *(() if mask is None else (mask,)),
     )
     return jnp.transpose(out, (0, 2, 1, 3))
 

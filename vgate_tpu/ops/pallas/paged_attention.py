@@ -899,7 +899,8 @@ def swa_decode_attention_pallas(q, ring_k, ring_v, ring_tables, seq_lens,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("interpret", "scale", "v_width", "items", "hollow"),
+    static_argnames=("interpret", "scale", "v_width", "items", "hollow",
+                     "name"),
 )
 def mla_decode_attention_pallas(
     q: jnp.ndarray,  # [B, H, W] absorbed queries, W the pool's row width
@@ -914,6 +915,7 @@ def mla_decode_attention_pallas(
     interpret: bool = False,
     items: int = 0,  # work-list items a loop trip serves; 0: _decode_sizes
     hollow: bool = False,  # the probe's: _decode_kernel
+    name: str = "mla_decode_attention_pallas",  # in a device trace
 ):
     """Decode attention in the ABSORBED form of multi-head latent
     attention over the latent pool, [B, H, v_width]: every query head
@@ -991,7 +993,7 @@ def mla_decode_attention_pallas(
             vmem_limit_bytes=64 * 1024 * 1024,
             disable_bounds_checks=True,
         ),
-        name="mla_decode_attention_pallas",
+        name=name,
     )(page_tables, seq_lens, jnp.zeros((1,), jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), *inputs)
     if write:

@@ -379,9 +379,10 @@ def refuse_unbuildable_kernels(spec: ModelSpec, kv_quant: bool) -> None:
             "aligned to tiling (8), but is 1'.  Use kv_cache.dtype=bf16, "
             "or tpu.use_pallas=false (jnp twins)."
         )
-    if spec.head_dim % 128:
+    # what a page's row holds: head_dim, or the latent row's lanes
+    if spec.cache_head_dim % 128:
         raise ValueError(
-            f"{spec.name} (head_dim {spec.head_dim}) cannot run the "
+            f"{spec.name} (head_dim {spec.cache_head_dim}) cannot run the "
             "Pallas paged-attention kernels on this TPU toolchain — "
             "Mosaic refuses the page DMA: 'Slice shape along dimension 4 "
             f"must be aligned to tiling (128), but is {spec.head_dim}'.  "
@@ -466,8 +467,10 @@ def refuse_unsupported_latent(spec: ModelSpec, config: VGTConfig,
         "quant": "the latent projections and the grouped expert product "
                  "take plain weights",
     }[found[1]]
+    rows = ("a pool of latent rows and one of index keys under one page "
+            "table" if spec.is_dsa else "one pool of latent rows")
     raise ValueError(
-        f"{spec.name} has latent attention (one pool of latent rows, no K "
+        f"{spec.name} has latent attention ({rows}, no K "
         f"or V pool), which cannot run with {found[0]}: {why}.  Prefix "
         "sharing of whole pages, chunked prefill, preemption by recompute "
         "and journal replay are supported."
@@ -800,6 +803,8 @@ class EngineCore:
             scale_bytes=kv_scale_bytes,
             kv_dtype=kv_dtype_name,
             pools=self.spec.kv_pools,
+            index_layers=self.spec.index_layers,
+            index_dim=self.spec.index_head_dim,
         )
         kv_sharding = named(
             self.mesh, kv_pspec(self.spec, self.mesh, num_pages)
@@ -2646,6 +2651,11 @@ class EngineCore:
                             plan.seq.total_len, plan.cached_len,
                             self.spec.attn_layers,
                         )
+                    if self.spec.is_dsa:
+                        self.perf.note_dsa_prefill(
+                            plan.seq.total_len, plan.cached_len,
+                            self.spec.index_layers,
+                        )
                     if self.spec.swa_layers:
                         self.perf.note_swa_prefill(
                             plan.seq.total_len, plan.cached_len,
@@ -3333,6 +3343,11 @@ class EngineCore:
                 # steps in flight that ctx_tokens does not hold yet
                 "lead": sum(c[1] for c in self._pending_chunks),
                 "kv_write": self._decode_kv_write(),
+                # latent rows a step attends to under a learned
+                # selection, over the rows (a layer's; at dispatch)
+                **({"sel_rows": sum(
+                    min(s.total_len, self.spec.index_topk)
+                    for s in active)} if self.spec.is_dsa else {}),
             },
             chunk=chunk, batch=len(active),
         ):
@@ -3440,6 +3455,14 @@ class EngineCore:
                         steps=chunk, rows=len(seqs),
                         ctx_tokens=sum(s.total_len for s, _ in seqs),
                         layers=self.spec.attn_layers,
+                    )
+                if self.spec.is_dsa:
+                    self.perf.note_dsa_decode(
+                        steps=chunk,
+                        lens=[s.total_len for s, _ in seqs],
+                        layers=self.spec.attn_layers,
+                        index_layers=self.spec.index_layers,
+                        topk=self.spec.index_topk,
                     )
                 if self.spec.swa_layers:
                     self.perf.note_swa_decode(
@@ -4188,7 +4211,13 @@ class EngineCore:
 
     def _ring_tick(self) -> Dict[str, int]:
         """A flight tick's cache line beside ``kv_used``: the bytes the
-        running sequences' rings hold (none for a spec without them)."""
+        running sequences' rings hold (none for a spec without them),
+        or those of the used pages' index keys (a spec that picks)."""
+        if self.spec.is_dsa:  # the index keys among the pages' bytes
+            geo = self.geometry
+            return {"index_bytes": (
+                self.allocator.num_used * geo.page_size * geo.index_layers
+                * geo.index_dim * geo.dtype_bytes)}
         if not self.spec.swa_layers:
             return {}
         return {"ring_bytes": (
@@ -4232,6 +4261,13 @@ class EngineCore:
                 "row_lanes": self.geometry.head_dim,
                 **({"latent": self.spec.latent_dim}
                    if self.spec.is_mla else {}),
+                # the index keys a page holds beside them (a spec that
+                # picks): one row a token in each picking layer
+                **({"index": {
+                    "layers": self.geometry.index_layers,
+                    "row_bytes": (self.geometry.index_dim
+                                  * self.geometry.dtype_bytes),
+                }} if self.geometry.index_layers else {}),
             },
             **(
                 {

@@ -30,6 +30,7 @@ logger = get_logger(__name__)
 def _page_bytes(
     num_layers: int, page_size: int, kv_heads: int, head_dim: int,
     dtype_bytes: int, scale_bytes: int = 0, pools: int = 2,
+    index_layers: int = 0, index_dim: int = 0,
 ) -> int:
     """Bytes one page occupies across all layers, K and V together — the
     single source of truth for page sizing (used by both KVGeometry and
@@ -37,10 +38,14 @@ def _page_bytes(
     quantization-scale overhead (0 for plain bf16/f32 pools; int8 KV
     stores one bf16 scale per (page, head, slot) — ops/kv_quant.py).
     ``pools``: 2 for K and V; 1 for latent attention's ONE pool, whose
-    "head" is the token's latent row (``ModelSpec.cache_head_dim``)."""
+    "head" is the token's latent row (``ModelSpec.cache_head_dim``).
+    ``index_layers`` x ``index_dim``: the index keys a page holds beside
+    them under the same page id, one row a token a picking layer
+    (learned sparse attention: ``ModelSpec.index_layers``)."""
     return (
         pools * num_layers * page_size * kv_heads
         * (head_dim * dtype_bytes + scale_bytes)
+        + index_layers * page_size * index_dim * dtype_bytes
     )
 
 
@@ -64,6 +69,11 @@ class KVGeometry:
     # arrays of the cache: K and V, or latent attention's one pool
     # (kv_heads 1, head_dim the latent row's lanes: ModelSpec.cache_*)
     pools: int = 2
+    # the second array of a spec that picks (learned sparse attention):
+    # ONE index key of ``index_dim`` a token in each of ``index_layers``
+    # layers, addressed by the same page ids
+    index_layers: int = 0
+    index_dim: int = 0
 
     @property
     def pages_per_seq(self) -> int:
@@ -74,6 +84,7 @@ class KVGeometry:
         return _page_bytes(
             self.num_layers, self.page_size, self.kv_heads, self.head_dim,
             self.dtype_bytes, self.scale_bytes, self.pools,
+            self.index_layers, self.index_dim,
         )
 
     @property
@@ -118,6 +129,7 @@ def auto_num_pages(
     page_bytes = _page_bytes(
         spec.attn_layers, page_size, spec.cache_heads, spec.cache_head_dim,
         dtype_bytes, scale_bytes, spec.kv_pools,
+        spec.index_layers, spec.index_head_dim,
     ) // max(1, shards)
     if stats and "bytes_limit" in stats:
         limit = stats["bytes_limit"] * hbm_utilization
@@ -331,7 +343,8 @@ class PageAllocator:
 
 def make_kv_buffers(geometry: KVGeometry, dtype=jnp.bfloat16, sharding=None):
     """Allocate the page pools (zeros; ``(k, v)``, or ``(latent, None)``
-    for a geometry of ONE pool) directly on device, each chip
+    for a geometry of ONE pool, or ``(latent, index keys)`` for one with
+    ``index_layers``) directly on device, each chip
     of a mesh creating only its own shard (``device=sharding``): a global
     pool drawn on the default device and spread afterwards does not fit
     the one chip it is drawn on.
@@ -379,8 +392,12 @@ def make_kv_buffers(geometry: KVGeometry, dtype=jnp.bfloat16, sharding=None):
         k = jnp.zeros(shape, dtype, device=sharding)
         v = (jnp.zeros(shape, dtype, device=sharding)
              if geometry.pools == 2 else None)
-    pool_bytes = geometry.pools * sum(
-        x.size * x.dtype.itemsize for x in jax.tree.leaves(k)
+        if geometry.index_layers:
+            v = jnp.zeros(
+                (geometry.index_layers, 1) + shape[2:4]
+                + (geometry.index_dim,), dtype, device=sharding)
+    pool_bytes = sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves((k, v))
     )
     logger.info(
         "kv cache allocated",

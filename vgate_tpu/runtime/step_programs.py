@@ -10,6 +10,14 @@ The function names are the device trace's module names
 (``jit__decode_chunk``, ``jit__prefill_step``,
 ``jit__suffix_prefill_step``) that the benchmark's reducers match by
 pattern.
+
+The cache a program threads is ``(k_pages, v_pages[, state])``: K and V
+pools; or a latent pool and ``None``; or, for a spec that attends under
+a learned selection, a latent pool and the index keys' array under the
+same page table (``models/hybrid.py``).  What such a spec's programs
+read and score is booked by the engine into ``/debug/perf ->
+totals.dsa``, and its decode launches carry ``sel_rows`` on the
+``vgt.engine.decode_dispatch`` span (docs/observability.md).
 """
 
 from __future__ import annotations

@@ -308,6 +308,102 @@ def _mla_params_from_getter(
     return params
 
 
+def _dsa_params_from_getter(
+    spec: ModelSpec, getter: TensorGetter, dtype
+) -> Params:
+    """``glm_moe_dsa`` names (ASSUMED: DeepSeek-V3's for the latent
+    attention and the expert layer, DeepSeek-V3.2's for the indexer,
+    ``self_attn.indexer.{wq_b, wk, k_norm, weights_proj}``; no checkpoint
+    was read) -> the pytree of models/hybrid.py for an
+    ``indexer_pattern`` spec: ``layers = {"lead": (a tree a leading
+    layer, ...), "pick" | "reuse": [P, n, ...]}``, layer ``i`` of the
+    checkpoint in the place ``ModelSpec`` gives it.  With
+    ``rope_interleave`` / ``indexer_rope_interleave`` the rotary columns
+    are de-interleaved, so that the program rotates halves: the LAST
+    ``qk_rope_head_dim`` of every head of ``q_b_proj`` and of
+    ``kv_a_proj_with_mqa``, the FIRST ``qk_rope_head_dim`` of every index
+    head and of the index key (its LayerNorm's weight and bias with it).
+    A chip's share: the experts ``first_expert ..`` of the router's
+    width, and the first ``vocab_size`` rows of embedding and head.  The
+    multi-token-prediction module's tensors are not read."""
+    E, first, H = spec.num_experts, spec.first_expert, spec.num_heads
+    kl, nope, rope = (spec.kv_lora_rank, spec.qk_nope_head_dim,
+                      spec.qk_rope_head_dim)
+    Hi, di = spec.index_n_heads, spec.index_head_dim
+    get = lambda i, name: np.asarray(getter(f"model.layers.{i}.{name}"))
+    lin = lambda i, name: get(i, f"{name}.weight").T
+    unpair = _deinterleave if spec.rope_interleave else (lambda w, _: w)
+    # the indexer rotates its FIRST dimensions: flip, unpair the last, flip
+    head = (lambda w: _deinterleave(w[..., ::-1], rope)[..., ::-1]
+            ) if spec.indexer_rope_interleave else (lambda w: w)
+    np_dtype, V = np.dtype(dtype), spec.vocab_size
+    cast = lambda x: np.asarray(x).astype(np_dtype)
+
+    def layer(i):
+        mixer, ff = spec._stack_layer(i)
+        q_b = unpair(lin(i, "self_attn.q_b_proj").reshape(
+            -1, H, nope + rope), rope)
+        kv_b = lin(i, "self_attn.kv_b_proj").reshape(kl, H, -1)
+        out = {
+            "input_norm": get(i, "input_layernorm.weight"),
+            "post_norm": get(i, "post_attention_layernorm.weight"),
+            "q_a": {"w": lin(i, "self_attn.q_a_proj")},
+            "q_a_norm": get(i, "self_attn.q_a_layernorm.weight"),
+            "q_b": {"w": q_b.reshape(q_b.shape[0], -1)},
+            "kv_a": {"w": unpair(lin(i, "self_attn.kv_a_proj_with_mqa"),
+                                 rope)},
+            "kv_a_norm": get(i, "self_attn.kv_a_layernorm.weight"),
+            "kv_b_k": {"w": kv_b[..., :nope]},
+            "kv_b_v": {"w": kv_b[..., nope:]},
+            "o": {"w": lin(i, "self_attn.o_proj")},
+        }
+        if mixer == "dsa":
+            pre = "self_attn.indexer."
+            wq = head(lin(i, pre + "wq_b").reshape(-1, Hi, di))
+            out.update({
+                "index_q": {"w": wq.reshape(wq.shape[0], -1)},
+                "index_k": {"w": head(lin(i, pre + "wk"))},
+                "index_k_norm": head(get(i, pre + "k_norm.weight")),
+                "index_k_bias": head(get(i, pre + "k_norm.bias")),
+                "index_w": {"w": lin(i, pre + "weights_proj")},
+            })
+        if ff == "mlp":
+            for n in ("gate", "up", "down"):
+                out[n] = {"w": lin(i, f"mlp.{n}_proj")}
+            return jax.tree.map(cast, out)
+        out["router"] = lin(i, "mlp.gate")
+        for n in ("gate", "up", "down"):
+            out[n] = {"w": np.stack([
+                lin(i, f"mlp.experts.{first + e}.{n}_proj")
+                for e in range(E)])}
+            if spec.shared_expert_intermediate_size:
+                out[f"shared_{n}"] = {
+                    "w": lin(i, f"mlp.shared_experts.{n}_proj")}
+        out = jax.tree.map(cast, out)
+        out["router_bias"] = np.asarray(
+            get(i, "mlp.gate.e_score_correction_bias"), np.float32)
+        return out
+
+    lead, P = spec.lead_layers, spec.num_periods
+    layers: Dict[str, Any] = {
+        "lead": tuple(layer(i) for i in range(lead))}
+    for group, mixer in (("pick", "dsa"), ("reuse", "mla")):
+        trees = [layer(i) for i in range(lead, spec.num_layers)
+                 if spec._stack_layer(i)[0] == mixer]
+        if trees:
+            layers[group] = jax.tree.map(
+                lambda *xs: np.stack(xs).reshape(
+                    (P, len(xs) // P) + xs[0].shape), *trees)
+    params: Params = {
+        "embed": cast(np.asarray(getter("model.embed_tokens.weight"))[:V]),
+        "layers": layers,
+        "final_norm": cast(getter("model.norm.weight")),
+    }
+    if not spec.tie_embeddings:
+        params["lm_head"] = cast(np.asarray(getter("lm_head.weight"))[:V].T)
+    return params
+
+
 def _window_params_from_getter(
     spec: ModelSpec, getter: TensorGetter, dtype
 ) -> Params:
@@ -336,7 +432,7 @@ def _window_params_from_getter(
         if spec.qk_norm:
             out["q_norm"] = get(i, "self_attn.q_norm.weight")
             out["k_norm"] = get(i, "self_attn.k_norm.weight")
-        if spec._window_layer(i)[1] == "mlp":
+        if spec._stack_layer(i)[1] == "mlp":
             for n in ("gate", "up", "down"):
                 out[n] = lin(i, f"mlp.{n}_proj")
             return jax.tree.map(cast, out)
@@ -357,7 +453,7 @@ def _window_params_from_getter(
         "lead": tuple(layer(i) for i in range(lead))}
     for group, mixer in (("window", "swa"), ("global", "attn")):
         trees = [layer(i) for i in range(lead, spec.num_layers)
-                 if spec._window_layer(i)[0] == mixer]
+                 if spec._stack_layer(i)[0] == mixer]
         if trees:
             layers[group] = jax.tree.map(
                 lambda *xs: np.stack(xs).reshape(
@@ -376,6 +472,8 @@ def params_from_getter(
     spec: ModelSpec, getter: TensorGetter, dtype=jnp.bfloat16
 ) -> Params:
     """Assemble the decoder pytree from HF-named tensors (host numpy)."""
+    if spec.is_dsa:
+        return _dsa_params_from_getter(spec, getter, dtype)
     if spec.is_mla:
         return _mla_params_from_getter(spec, getter, dtype)
     if spec.window_pattern:
