@@ -10,6 +10,7 @@ blockwise twin).  Run on hardware:
     python benchmarks/bench_kernels.py decode_cells [CELL ...]
     python benchmarks/bench_kernels.py expert_layer [CONFIG ...]
     python benchmarks/bench_kernels.py dsa_index dsa_select dsa_attend
+    python benchmarks/bench_kernels.py dsa_attend dsa_attend_64k
 
 Prints one JSON line per (kernel, shape) with median step times and the
 speedup.  CPU-safe fallback: refuses to run (the kernels need a TPU).
@@ -912,19 +913,22 @@ def bench_dsa_index():
            "roofline_pct": round(100 * pairs * 8192 / 197e12 / t, 1)}
 
 
-def dsa_attend_case(seed=0):
+def dsa_attend_case(seed=0, ctx=None, layers=None):
     """A decode step's latent pool at the cell's size (48 slots x 16,384
-    tokens of pages, 5 layers), scattered pages, lengths over the
-    cell's range, 2,048 selected positions a slot."""
+    tokens of pages, 5 layers; or ``ctx`` tokens a slot over ``layers``),
+    scattered pages, lengths over the cell's range (the upper half of
+    another ``ctx``), 2,048 selected positions a slot."""
     rng = np.random.default_rng(seed)
-    B, ps, W, L = DSA["B"], DSA["ps"], DSA["W"], DSA["layers"]
-    n = DSA["ctx"] // ps
+    B, ps, W = DSA["B"], DSA["ps"], DSA["W"]
+    L = layers or DSA["layers"]
+    lo, hi = DSA["lens"] if ctx is None else (ctx // 2 + 1, ctx - 300)
+    n = (ctx or DSA["ctx"]) // ps
     P = 1 + B * n
     pool = jax.jit(lambda k: jax.random.normal(
         k, (L, 1, P, ps, W), jnp.bfloat16))(jax.random.PRNGKey(seed))
     tables = jnp.asarray(
         (rng.permutation(P - 1)[:B * n] + 1).reshape(B, n), jnp.int32)
-    lens = rng.integers(*DSA["lens"], size=B)
+    lens = rng.integers(lo, hi, size=B)
     sel = np.stack([rng.permutation(int(l))[:DSA["topk"]] for l in lens])
     q = jax.random.normal(jax.random.PRNGKey(seed + 1),
                           (B, DSA["H"], W), jnp.bfloat16)
@@ -932,57 +936,102 @@ def dsa_attend_case(seed=0):
             jnp.asarray(sel, jnp.int32))
 
 
-def bench_dsa_attend(loop=5):
-    """One layer's decode attention under the selection, us a layer: the
-    selected rows gathered through the page table into [48, 2,048, 640]
-    and the dense latent kernel over them (``dsa_decode_attention``),
-    the gather alone, and what is NOT served: the dense latent kernel
-    over the whole context (every row read, no selection)."""
-    from vgate_tpu.ops.dsa import dsa_decode_attention, gather_selected
+# the fused kernel's forms the probe compares: (what a trip does with a
+# fetched pair: "" the kernel's own choice, "select" the form for pools
+# that are not bf16, "hollow" the fetch alone; picks a trip)
+DSA_FUSED_FORMS = (
+    ("", 512), ("", 256), ("", 1024), ("select", 512), ("hollow", 512),
+)
+
+
+def bench_dsa_attend(loop=5, ctx=None, layers=None):
+    """One layer's decode attention under the selection, us a layer:
+    ``fused`` (the kernel that fetches its picked rows itself, a pair of
+    token rows a descriptor, from the pool by pairs) in each of its
+    forms, and ``order_picks`` (once a pick: the rows' places through
+    the page table, in the kernel's order); what they replaced: the
+    selected rows gathered by XLA through the page table into [48,
+    2,048, 640] (``gather_alone``) and the dense latent kernel over them
+    (``gather+dense_kernel``); and what is NOT served: the dense latent
+    kernel over the whole context (every row read, no selection).  Each
+    fused form's output against the gather's, on the chip."""
+    from vgate_tpu.ops.dsa import order_picks
+    from vgate_tpu.ops.pallas.dsa import dsa_decode_attention_pallas
     from vgate_tpu.ops.pallas.paged_attention import (
         mla_decode_attention_pallas,
     )
 
-    q, pool, tables, lens, sel = dsa_attend_case()
+    q, pool, tables, lens, sel = dsa_attend_case(ctx=ctx, layers=layers)
+    L, _, P, ps, W = pool.shape
+    B, k = DSA["B"], DSA["topk"]
     kw = dict(v_width=DSA["v_width"], scale=576 ** -0.5)
-    n_sel = jnp.minimum(lens, DSA["topk"])
-    def gather_pairs(pool, l):
-        """The same rows by an ALIGNED gather: the pair of rows (two
-        rows share a 32-bit sublane in a bf16 tile) that holds each,
-        then the one of the two."""
-        L, _, P, ps, W = pool.shape
-        page = jnp.take_along_axis(tables, sel // ps, axis=1)
-        flat = (l * P + page) * ps + sel % ps
-        two = pool.reshape(L * P * ps // 2, 2, W)[flat // 2]
-        return jnp.where((flat % 2 == 0)[..., None], two[..., 0, :],
-                         two[..., 1, :])
+    n_sel = jnp.minimum(lens, k)
+    rows = order_picks(tables, sel, n_sel, ps)
+
+    def gather(pool, l, zero):
+        # ``zero``: 0 that hangs on the loop's carry, so that nothing
+        # here is the same from one trip to the next and lifted out
+        page = jnp.take_along_axis(tables, sel // ps + zero, axis=1)
+        return pool.reshape(L * P * ps, W)[(l * P + page) * ps + sel % ps]
+
+    def gather_dense(q, pool, l, zero):
+        own = gather(pool, l, zero).reshape(1, 1, B * k // ps, ps, W)
+        own_tables = jnp.arange(B * k // ps, dtype=jnp.int32).reshape(B, -1)
+        return mla_decode_attention_pallas(
+            q, own, own_tables, n_sel, 0, **kw)
 
     forms = {
-        "gather_pairs_alone": lambda q, pool, l: gather_pairs(
-            pool, l)[:, :DSA["H"], :DSA["v_width"]],
-        "gather_alone": lambda q, pool, l: gather_selected(
-            pool, tables, sel, l)[:, :DSA["H"], :DSA["v_width"]],
-        "gather+dense_kernel": lambda q, pool, l: dsa_decode_attention(
-            q, pool, tables, sel, n_sel, l, use_pallas=True, **kw),
-        "whole_context_dense_kernel": lambda q, pool, l:
+        "gather_alone": lambda q, pool, l, zero: gather(
+            pool, l, zero)[:, :DSA["H"], :DSA["v_width"]],
+        "gather+dense_kernel": gather_dense,
+        "whole_context_dense_kernel": lambda q, pool, l, zero:
             mla_decode_attention_pallas(q, pool, tables, lens, l, **kw),
+        "order_picks": lambda q, pool, l, zero: jnp.broadcast_to(
+            order_picks(tables, sel + zero, n_sel, ps)[:, None, :512],
+            (B, DSA["H"], 512)),
     }
-    for name, fn in forms.items():
+    for form, chunk in DSA_FUSED_FORMS:
+        forms[f"fused/{form or 'kernel'}/{chunk}"] = functools.partial(
+            lambda q, pool, l, zero, **how: dsa_decode_attention_pallas(
+                q, pool, rows + zero, n_sel, l, **kw, **how),
+            form=form, chunk=chunk)
+    want = np.asarray(
+        jax.jit(gather_dense)(q, pool, L - 1, 0), np.float32)
+    # the fused forms last, over the SAME rows by pairs: the one layout
+    # is let go before the other is made
+    names = sorted(forms, key=lambda name: name.startswith("fused"))
+    where = {"ctx": ctx or DSA["ctx"], "layers": L}
+    for name in names:
+        fn, fused = forms[name], name.startswith("fused")
+        if fused and pool.ndim == 5:
+            pool = jax.jit(lambda p: p.reshape(L, 1, P, ps // 2, 2, W),
+                           donate_argnums=0)(pool)
+
         def run(q, pool, fn=fn):
             def body(c, i):
-                out = fn(q + 0 * c.astype(q.dtype), pool,
-                         i % DSA["layers"])
+                zero = (0 * c[0, 0, 0]).astype(jnp.int32)
+                out = fn(q + 0 * c.astype(q.dtype), pool, i % L + zero, zero)
                 return jnp.pad(out.astype(jnp.float32), (
                     (0, 0), (0, 0), (0, q.shape[-1] - out.shape[-1]))), None
             return jax.lax.scan(
                 body, jnp.zeros(q.shape, jnp.float32),
                 jnp.arange(loop, dtype=jnp.int32))[0]
         t = _timed(jax.jit(run), q, pool) / loop
-        rows = (int(jnp.sum(lens)) if name.startswith("whole")
+        read = (int(jnp.sum(lens)) if name.startswith("whole")
                 else int(jnp.sum(n_sel)))
-        yield {"probe": "dsa_attend", "form": name,
-               "us_a_layer": round(t * 1e6, 1), "rows_read": rows,
-               "gb_s": round(rows * DSA["W"] * 2 / t / 1e9, 1)}
+        line = {"probe": "dsa_attend", **where, "form": name,
+                "us_a_layer": round(t * 1e6, 1), "rows_read": read,
+                "gb_s": round(read * W * 2 / t / 1e9, 1)}
+        if fused and "hollow" not in name:
+            got = np.asarray(jax.jit(fn)(q, pool, L - 1, 0), np.float32)
+            line["max_abs_diff_to_gather"] = float(np.abs(got - want).max())
+        yield line
+
+
+def bench_dsa_attend_64k():
+    """The same at a 64 k context (one layer of pages: five do not fit
+    beside each other's two layouts)."""
+    yield from bench_dsa_attend(ctx=65536, layers=1)
 
 
 def main() -> None:
@@ -1005,7 +1054,8 @@ def main() -> None:
             print(json.dumps(line), flush=True)
         return
     dsa = {"dsa_index": bench_dsa_index, "dsa_select": bench_dsa_select,
-           "dsa_attend": bench_dsa_attend}
+           "dsa_attend": bench_dsa_attend,
+           "dsa_attend_64k": bench_dsa_attend_64k}
     if sys.argv[1:] and all(a in dsa for a in sys.argv[1:]):
         for a in sys.argv[1:]:
             for line in dsa[a]():

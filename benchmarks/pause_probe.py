@@ -10,8 +10,9 @@ line, printed first) and then one more JSON line, ``{"probe": ...}``:
 ``/debug/perf -> pauses`` as the window's closing snapshot and a last
 fetch after the load held them, the growth inside the window of
 ``totals.pauses`` / ``delivery_gaps`` / ``gc`` / the hand-off's
-counters, the collector's callbacks a second, ``totals.moe`` at the
-end (the expert layers' counters, ``overflow`` among them), and the
+counters, the collector's callbacks a second, ``totals.moe`` and
+``totals.dsa`` at the end (the expert layers' counters, ``overflow``
+among them; the learned selection's), and the
 server log's
 ``engine_pause`` lines (but the warm-up's, whose cause is ``compile``).
 ``--stall-at S`` arms the ``stall`` fault's ``delay`` once, S seconds
@@ -104,6 +105,9 @@ def report(seconds: float) -> Dict[str, Any]:
         # the expert layers' counters over the whole run (PR 39:
         # ``overflow``, the dispatch trips beyond a block's first)
         "moe_at_end": dig(KEPT.get("last"), "totals.moe"),
+        # the learned selection's (PR 41: ``rows_fetched`` over
+        # ``rows_attended`` is the fetch's amplification)
+        "dsa_at_end": dig(KEPT.get("last"), "totals.dsa"),
         "in_window": {
             name: grown(window, name) for name in (
                 "wall_s", "deliveries", "delivery_gap_s", "delivery_gaps",

@@ -246,7 +246,8 @@ def test_a_page_holds_a_latent_row_a_layer_and_an_index_key_a_picking_layer():
     assert geo.page_bytes == 32 * (5 * 1280 + 2 * 256) == 221184
     assert geo.pages_per_seq == 512
     pools = jax.eval_shape(lambda: make_kv_buffers(geo, jnp.bfloat16))
-    assert pools[0].shape == (5, 1, 24577, 32, 640)
+    # the latent rows by pairs of tokens: the decode kernel's fetch
+    assert pools[0].shape == (5, 1, 24577, 16, 2, 640)
     assert pools[1].shape == (2, 1, 24577, 32, 128)
     assert 24577 * geo.page_bytes / 1e9 == pytest.approx(5.436, abs=1e-3)
     # a spec without an indexer keeps its one pool
@@ -381,10 +382,31 @@ def test_the_prompt_kernel_under_a_mask_is_the_masked_softmax():
             rtol=2e-5, atol=2e-5)
 
 
-def test_decode_attention_over_gathered_rows_reads_the_pick_alone():
-    """``dsa_decode_attention`` (the dense latent kernel over the rows a
-    gather put in order, interpreted) is the dense twin over a pool in
-    which every row outside the pick is poisoned."""
+def picked_attention(q, pool, tables, sel, n_sel, layer, vw, scale):
+    """numpy: a softmax over the slot's picked positions alone (zeros
+    for a slot with none), from a pool [L, 1, P, ps, W]."""
+    B, n = tables.shape
+    ps, W = pool.shape[-2:]
+    keep = np.zeros((B, n * ps), bool)
+    for b in range(B):
+        keep[b, sel[b, :int(n_sel[b])]] = True
+    rows = np.asarray(pool[layer, 0][np.asarray(tables)]).reshape(
+        B, n * ps, W)
+    scores = np.einsum("bhw,btw->bht", np.asarray(q), rows) * scale
+    scores = np.where(keep[:, None], scores, -np.inf)
+    with np.errstate(invalid="ignore"):
+        p = np.exp(scores - scores.max(-1, keepdims=True))
+        out = np.einsum("bht,btv->bhv", p / p.sum(-1, keepdims=True),
+                        rows[..., :vw])
+    return np.where((np.asarray(n_sel) > 0)[:, None, None], out, 0.0)
+
+
+@pytest.mark.parametrize("pairs", [False, True], ids=["rows", "by-pairs"])
+def test_decode_attention_over_gathered_rows_reads_the_pick_alone(pairs):
+    """``dsa_decode_attention`` (the jnp twin: the picked rows gathered
+    at the places ``order_picks`` gives) is the dense softmax over a
+    pool in which every row outside the pick is poisoned, whichever way
+    the pool's rows lie."""
     rng = np.random.default_rng(8)
     B, H, W, ps, n, k, vw = 2, 4, 128, 8, 8, 16, 64
     pool = np.asarray(rng.normal(size=(3, 1, 40, ps, W)), np.float32)
@@ -395,17 +417,164 @@ def test_decode_attention_over_gathered_rows_reads_the_pick_alone():
         rng.permutation(l)[:k], np.zeros(max(0, k - l), np.int64)])
         for l in lens]).astype(np.int32)
     n_sel = jnp.asarray(np.minimum(lens, k), jnp.int32)
+    rows = dsa.order_picks(tables, jnp.asarray(sel), n_sel, ps)
+    held = pool.reshape(3, 1, 40, ps // 2, 2, W) if pairs else pool
+    assert dsa.gather_selected(jnp.asarray(held), rows, 2).shape == (B, k, W)
     got = dsa.dsa_decode_attention(
-        q, jnp.asarray(pool), tables, jnp.asarray(sel), n_sel, 2,
-        v_width=vw, scale=0.1, use_pallas=False)
-    # the twin: everything outside the pick set to rows no softmax sees
-    keep = np.zeros((B, n * ps), bool)
-    for b in range(B):
-        keep[b, sel[b, :int(n_sel[b])]] = True
-    rows = np.asarray(pool[2, 0][np.asarray(tables)]).reshape(B, n * ps, W)
-    scores = np.einsum("bhw,btw->bht", np.asarray(q), rows) * 0.1
-    scores = np.where(keep[:, None], scores, -np.inf)
-    p = np.exp(scores - scores.max(-1, keepdims=True))
-    want = np.einsum("bht,btv->bhv", p / p.sum(-1, keepdims=True),
-                     rows[..., :vw])
+        q, jnp.asarray(held), rows, n_sel, 2, v_width=vw, scale=0.1,
+        use_pallas=False)
+    want = picked_attention(q, pool, tables, sel, n_sel, 2, vw, 0.1)
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+
+
+def test_order_picks_is_the_same_set_first_rows_of_pairs_first():
+    """``order_picks``: a slot's real picks as places ``page * ps +
+    offset`` through its page table, those at even places first, each
+    half ascending; what is past ``n_sel`` reads place 0."""
+    rng = np.random.default_rng(3)
+    ps, n, k = 8, 6, 20
+    tables = rng.permutation(40)[:3 * n].reshape(3, n) + 1
+    lens = [48, 5, 0]
+    sel = np.stack([np.concatenate([
+        rng.permutation(l)[:k], np.full(max(0, k - l), 7)])
+        for l in lens]).astype(np.int32)
+    n_sel = np.minimum(lens, k)
+    rows = np.asarray(dsa.order_picks(
+        jnp.asarray(tables), jnp.asarray(sel), jnp.asarray(n_sel), ps))
+    for b, m in enumerate(n_sel):
+        want = tables[b, sel[b, :m] // ps] * ps + sel[b, :m] % ps
+        got = rows[b, :m]
+        assert sorted(got) == sorted(want)
+        even = got[got % 2 == 0]
+        assert list(got) == sorted(even) + sorted(got[got % 2 == 1])
+        assert not rows[b, m:].any()
+
+
+# what a slot's pick looks like -> (its length, its picked positions);
+# k = 20 of chunks of 8: no whole number of the kernel's chunks
+FETCH_K, FETCH_CHUNK = 20, 8
+FETCH_CASES = {
+    "even positions": (64, list(range(0, 40, 2))),
+    "odd positions": (64, list(range(1, 41, 2))),
+    "both rows of a pair": (64, list(range(12, 32))),
+    "fewer than k": (9, [8, 3, 4, 0, 7]),
+    "a slot of length 0": (0, []),
+    "across pages in any order": (
+        64, [63, 0, 31, 32, 8, 7, 56, 1, 40, 39, 17, 62, 2, 33, 24, 25,
+             9, 48, 47, 16]),
+    "one pick": (3, [2]),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(FETCH_CASES))
+def test_the_fetching_decode_kernel_is_the_twin(case, dtype):
+    """``dsa_decode_attention_pallas`` (interpreted): the kernel that
+    fetches the pair of rows holding each pick and keeps the picked one
+    is the jnp twin over the same places, beside a slot that picks
+    otherwise, over a pool by pairs whose other rows are poisoned; in
+    bfloat16 the pair's rows come apart as halves of a 32-bit word."""
+    from vgate_tpu.ops.pallas.dsa import dsa_decode_attention_pallas
+
+    rng = np.random.default_rng(11)
+    H, W, ps, n, vw = 4, 128, 8, 8, 64
+    k = FETCH_K
+    picks = [FETCH_CASES[case], (64, list(rng.permutation(64)[:k]))]
+    if case == "a slot of length 0":  # dead slots around a live one
+        picks = [picks[0], picks[1], picks[0]]
+    B = len(picks)
+    lens = [length for length, _ in picks]
+    n_sel = jnp.asarray([len(p) for _, p in picks], jnp.int32)
+    sel = np.asarray([p + [5] * (k - len(p)) for _, p in picks], np.int32)
+    tables = jnp.asarray(rng.permutation(39)[:B * n].reshape(B, n) + 1)
+    pool = jnp.asarray(rng.normal(size=(3, 1, 40, ps, W)), dtype)
+    q = jnp.asarray(rng.normal(size=(B, H, W)), dtype)
+    rows = dsa.order_picks(tables, jnp.asarray(sel), n_sel, ps)
+    by_pairs = pool.reshape(3, 1, 40, ps // 2, 2, W)
+    got = dsa_decode_attention_pallas(
+        q, by_pairs, rows, n_sel, 1, v_width=vw, scale=0.1,
+        chunk=FETCH_CHUNK, interpret=True)
+    twin = dsa.dsa_decode_attention(
+        q, by_pairs, rows, n_sel, 1, v_width=vw, scale=0.1,
+        use_pallas=False)
+    want = picked_attention(
+        q.astype(jnp.float32), np.asarray(pool.astype(jnp.float32)),
+        tables, sel, n_sel, 1, vw, 0.1)
+    live = np.asarray(n_sel) > 0
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    got = np.asarray(got.astype(jnp.float32))
+    assert all(length >= len(p) for length, p in picks) and lens
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    np.testing.assert_allclose(
+        got[live], np.asarray(twin.astype(jnp.float32))[live], rtol=tol,
+        atol=tol)
+    assert not got[~live].any()  # nothing fetched: zeros
+
+
+def test_writes_and_page_copies_follow_a_pool_by_pairs():
+    """A pool by pairs holds what the same writes leave in a pool by
+    rows, byte for byte in the same order: a prompt's pages
+    (``kv_write_pages``), a step's rows at even and odd offsets
+    (``kv_write_tokens``), the rows read back through a page table
+    (``mla_gather_rows``), and a page swapped out and in again."""
+    from vgate_tpu.ops.attention import mla_gather_rows
+    from vgate_tpu.ops.kv_quant import (
+        by_pairs, kv_write_pages, kv_write_tokens, page_tokens,
+    )
+    from vgate_tpu.runtime.step_programs import (
+        _gather_swap_pages, _scatter_swap_pages,
+    )
+
+    rng = np.random.default_rng(5)
+    L, P, ps, W = 3, 12, 8, 128
+    flat = jnp.zeros((L, 1, P, ps, W), jnp.float32)
+    pairs = jnp.zeros((L, 1, P, ps // 2, 2, W), jnp.float32)
+    assert by_pairs(pairs) and not by_pairs(flat)
+    assert page_tokens(pairs) == page_tokens(flat) == ps
+    tables = jnp.asarray([[3, 7], [9, 1]])
+    prompt = jnp.asarray(rng.normal(size=(2, 2, 1, ps, W)), jnp.float32)
+    ids, off = jnp.asarray([4, 5, 7]), jnp.asarray([0, 3, 6])
+    step = jnp.asarray(rng.normal(size=(3, 1, W)), jnp.float32)
+    for layer in (0, 2):
+        flat = kv_write_pages(flat, tables, prompt + layer, layer=layer)
+        pairs = kv_write_pages(pairs, tables, prompt + layer, layer=layer)
+        flat = kv_write_tokens(flat, ids, off, step - layer, layer=layer)
+        pairs = kv_write_tokens(pairs, ids, off, step - layer, layer=layer)
+    same = lambda: np.array_equal(
+        np.asarray(pairs).reshape(flat.shape), np.asarray(flat))
+    assert same() and np.asarray(flat).any()
+    np.testing.assert_array_equal(
+        np.asarray(mla_gather_rows(pairs, tables, 2)),
+        np.asarray(mla_gather_rows(flat, tables, 2)))
+    # a page out to the host and back into another page id
+    idx = jnp.asarray([7, 4])
+    out = _gather_swap_pages(pairs, pairs, idx)[0]
+    assert out.shape == (L, 1, 2, ps // 2, 2, W)
+    back = jnp.asarray([10, 11])
+    pairs = _scatter_swap_pages(pairs, pairs + 0, back, out, out)[0]
+    flat = flat.at[:, :, back].set(flat[:, :, idx])
+    assert same()
+
+
+
+
+@pytest.mark.parametrize("pages", [3, 16, 37],
+                         ids=["under a group", "one group", "groups and a rest"])
+def test_the_prompts_page_writer_is_the_scatter(pages):
+    """``dsa_write_pages_pallas`` (interpreted): a prompt's rows into a
+    pool by pairs, a page a copy, leave what ``kv_write_pages`` leaves,
+    in the named layer alone."""
+    from vgate_tpu.ops.kv_quant import kv_write_pages
+    from vgate_tpu.ops.pallas.dsa import dsa_write_pages_pallas
+
+    rng = np.random.default_rng(pages)
+    L, P, ps, W = 3, 48, 8, 128
+    pool = jnp.asarray(rng.normal(size=(L, 1, P, ps // 2, 2, W)),
+                       jnp.float32)
+    tables = jnp.asarray(rng.permutation(P - 1)[:pages].reshape(1, pages) + 1)
+    value = jnp.asarray(rng.normal(size=(1, pages, 1, ps, W)), jnp.float32)
+    want = kv_write_pages(pool, tables, value, layer=1)
+    got = dsa_write_pages_pallas(pool + 0, tables, value, 1, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not np.array_equal(np.asarray(got[1]), np.asarray(pool[1]))

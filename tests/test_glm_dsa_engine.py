@@ -104,9 +104,10 @@ def test_unequal_rows_through_the_engine_and_what_it_reports(engine):
     assert stats["kv_page_bytes"] == PS * (9 * 128 + 3 * 16) * 4
     assert stats["kv_layout"] == {
         "pools": 1, "heads": 1, "row_lanes": 128, "latent": 40,
-        "index": {"layers": 3, "row_bytes": 64}}
+        "row_pairs": True, "index": {"layers": 3, "row_bytes": 64}}
     assert stats["kv_write"] == "scatter"
-    assert engine.k_pages.shape == (9, 1, 96, PS, 128)
+    # the latent rows by pairs of tokens, the index keys by rows
+    assert engine.k_pages.shape == (9, 1, 96, PS // 2, 2, 128)
     assert engine.v_pages.shape == (3, 1, 96, PS, 16)
     dsa = engine.perf.totals()["dsa"]
     assert dsa["prefill_prompts"] == 3
@@ -120,6 +121,8 @@ def test_unequal_rows_through_the_engine_and_what_it_reports(engine):
     assert dsa["decode_steps"] == steps - 1
     assert dsa["rows_scored"] == 3 * ctx and dsa["rows_in_context"] == 9 * ctx
     assert dsa["rows_attended"] == 9 * attended < dsa["rows_in_context"]
+    # the jnp twin gathers the picked rows alone; the kernel moves pairs
+    assert dsa["rows_fetched"] == dsa["rows_attended"]
     assert dsa["index_layer_steps"] == 3 * (steps - 1)
     assert dsa["selections_reused"] == 6 * (steps - 1)
     assert dsa["index_rows_written"] == 3 * (sum(lens) + 3 * (steps - 1))

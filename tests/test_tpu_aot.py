@@ -724,8 +724,9 @@ def _glm_cut(A):
     array) at the cell's size: 48 slots x 16,384 tokens of pages."""
     spec, params = _cut_and_shapes(A, *GLM_CUT)
     pages = GLM_SLOTS * GLM_CTX // PAGE + 1
-    pool = A((spec.attn_layers, 1, pages, PAGE, spec.cache_head_dim),
-             jnp.bfloat16)
+    # the latent rows by pairs of tokens (runtime/kv_cache.py)
+    pool = A((spec.attn_layers, 1, pages, PAGE // 2, 2,
+              spec.cache_head_dim), jnp.bfloat16)
     keys = A((spec.index_layers, 1, pages, PAGE, spec.index_head_dim),
              jnp.bfloat16)
     assert pool.shape[0] == 5 and pool.shape[-1] == 640
@@ -739,9 +740,11 @@ def _nbytes(tree):
 
 def test_selection_decode_chunk_compiles_on_v5e(v5e):
     """The decode chunk of the cut: both arrays of the pool aliased input
-    to output and never re-laid, the scoring pass and the attention over
-    gathered rows in it under their own names beside the dense latent
-    kernel (the branch for contexts of at most 2,048 tokens)."""
+    to output and never re-laid, the scoring pass and the attention that
+    fetches its picked rows in it under their own names and NO gather of
+    rows: no [48 x 2,048, 640] temporary, nothing under the scope the
+    gather had, no dense latent kernel (contexts of at most 2,048 tokens
+    go through the same kernel)."""
     from vgate_tpu.runtime.step_programs import _decode_chunk
 
     A = _abstract(v5e)
@@ -759,25 +762,114 @@ def test_selection_decode_chunk_compiles_on_v5e(v5e):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= _nbytes((pool, keys)), (
         "a pool is copied")
-    # 0.61 GB (PERF.md section 4): the gathered rows of one layer, the
-    # scores of one picking layer, the weights' re-laid copies
-    assert mem.temp_size_in_bytes < 0.8e9, mem.temp_size_in_bytes
+    # 0.46 GB: the scores of one picking layer, the weights' re-laid
+    # copies (0.61 GB with the gathered rows of one layer, PR 40)
+    assert mem.temp_size_in_bytes < 0.55e9, mem.temp_size_in_bytes
     text = compiled.as_text()
-    for name in ("dsa_index_scores_pallas", "dsa_decode_attention_pallas",
-                 "mla_decode_attention_pallas"):
+    for name in ("dsa_index_scores_pallas", "dsa_decode_attention_pallas"):
         assert name in text, name
+    assert "mla_decode_attention_pallas" not in text
+    assert "dsa_gather" not in text
+    _assert_no_buffer(text, B * spec.index_topk, spec.cache_head_dim)
+    _assert_no_buffer(text, f"{B},{spec.index_topk}", spec.cache_head_dim)
+    # no gather of page ids either (ops/dsa.py order_picks)
+    assert not [l for l in text.splitlines()
+                if "mla_attn" in l and "take_along_axis" in l]
     # neither array re-laid with another minor dimension
-    for shape in ("bf16[5,1,24577,32,640]", "bf16[2,1,24577,32,128]"):
+    for shape, minor in (("bf16[5,1,24577,16,2,640]", "{5,4,3,2,"),
+                         ("bf16[2,1,24577,32,128]", "{4,3,2,")):
         layouts = {line.split(shape, 1)[1].split("}", 1)[0]
                    for line in text.splitlines() if shape + "{" in line}
-        assert layouts and all(
-            l.startswith(("{4,3,2,1,0", "{4,3,2,0,1")) for l in layouts
-        ), layouts
+        assert layouts and all(l.startswith(minor) for l in layouts), layouts
+
+
+def _compile_fetching_kernel(A, pool, index, pair):
+    """One descriptor a pick, ``pair`` token rows from ``pool`` at
+    ``index(pool, i)`` into a place of VMEM scratch: the least of
+    ``_fetch_decode_kernel``."""
+    import functools
+
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    K, W = 16, pool.shape[-1]
+
+    def kernel(at_ref, pool_ref, out_ref, buf, sem):
+        copies = [pltpu.make_async_copy(
+            index(pool_ref, at_ref[i]), buf.at[i], sem.at[0])
+            for i in range(K)]
+        for cp in copies:
+            cp.start()
+        for cp in copies:
+            cp.wait()
+        out_ref[...] = buf[...]
+
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[pltpu.VMEM((K, pair, W), pool.dtype),
+                            pltpu.SemaphoreType.DMA((1,))]),
+        out_shape=jax.ShapeDtypeStruct((K, pair, W), pool.dtype))
+    return jax.jit(call).lower(A((K,), jnp.int32), pool).compile()
+
+
+def test_a_pair_of_token_rows_is_a_descriptor_on_v5e(v5e):
+    """What the pool by pairs stands on: Mosaic takes a PAIR of bf16
+    token rows as a trailing block under a leading index, and XLA holds
+    such an array without padding the 2 to a tile."""
+    A = _abstract(v5e)
+    pool = A((1 << 16, 2, 640), jnp.bfloat16)
+    compiled = _compile_fetching_kernel(
+        A, pool, lambda ref, n: ref.at[n], pair=2)
+    held = compiled.memory_analysis().argument_size_in_bytes
+    assert pool.size * 2 <= held < pool.size * 2 + 4096
+
+
+@pytest.mark.xfail(
+    strict=True,
+    raises=MosaicRefusal,
+    reason="Mosaic: 'Slice shape along dimension 1 must be aligned to "
+    "tiling (8), but is 1' — ONE token row of a page [P, 32, W] as a "
+    "descriptor's source: why a spec that picks holds its latent rows by "
+    "pairs (ops/kv_quant.py by_pairs).  A toolchain that takes this can "
+    "fetch half the bytes",
+)
+def test_one_token_row_of_a_page_is_a_descriptor_on_v5e(v5e):
+    from jax.experimental import pallas as pl
+
+    A = _abstract(v5e)
+    pool = A((2048, PAGE, 640), jnp.bfloat16)
+    _compile_expecting(
+        "aligned to tiling (8), but is 1", _compile_fetching_kernel, A,
+        pool, lambda ref, n: ref.at[n // PAGE, pl.ds(n % PAGE, 1)], pair=1)
+
+
+def test_the_fetching_decode_kernel_compiles_at_the_cells_widths_on_v5e(v5e):
+    """``dsa_decode_attention_pallas`` for the v5e at the cell's widths:
+    48 slots, 2,048 picks, 64 heads over rows of 640 lanes, 5 layers of
+    24,577 pages by pairs; nothing beside its operands."""
+    from vgate_tpu.ops.pallas.dsa import dsa_decode_attention_pallas
+
+    A = _abstract(v5e)
+    spec, _, pool, _ = _glm_cut(A)
+    B, k = GLM_SLOTS, spec.index_topk
+    assert (spec.num_heads, k, spec.kv_lora_rank) == (64, 2048, 512)
+    compiled = dsa_decode_attention_pallas.lower(
+        A((B, spec.num_heads, spec.cache_head_dim), jnp.bfloat16), pool,
+        A((B, k), jnp.int32), A((B,), jnp.int32), A((), jnp.int32),
+        v_width=spec.kv_lora_rank, scale=spec.mla_softmax_scale,
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+    assert "dsa_decode_attention_pallas" in compiled.as_text()
 
 
 def test_selection_prompt_program_fits_beside_the_pool_on_v5e(v5e):
-    """The 16,384-row prompt program of the cut: the scoring kernel and
-    the flash kernel under a mask in it, both pool arrays aliased, no
+    """The 16,384-row prompt program of the cut: the scoring kernel, the
+    flash kernel under a mask and the page writer of the pool by pairs
+    in it, both pool arrays aliased (no scatter re-lays the pool), no
     [16,384, 16,384] float32 scores and no [16,384, 12,288] activation
     of the dense layer in the HLO, and the temporaries small enough
     beside 7.76 GB of weights and 5.44 GB of pages."""
@@ -795,7 +887,7 @@ def test_selection_prompt_program_fits_beside_the_pool_on_v5e(v5e):
     assert held + mem.temp_size_in_bytes < 15.9e9  # of the chip's 16.9 GB
     text = compiled.as_text()
     for name in ("dsa_index_scores_pallas", "dsa_prefill_attention_pallas",
-                 "moe_grouped_matmul_pallas"):
+                 "dsa_write_pages_pallas", "moe_grouped_matmul_pallas"):
         assert name in text, name
     _assert_no_buffer(text, GLM_CTX, GLM_CTX, ("f32", "bf16", "s32", "u32"))
     _assert_no_buffer(text, GLM_CTX, spec.intermediate_size)

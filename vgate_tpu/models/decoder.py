@@ -41,6 +41,7 @@ from vgate_tpu.ops.kv_quant import (
     is_quantized,
     kv_write_pages,
     kv_write_tokens,
+    page_tokens,
 )
 from vgate_tpu.ops.norms import rms_norm
 from vgate_tpu.ops.quant import weighted_einsum
@@ -294,7 +295,7 @@ def decode_kv_write(
     kernel = (
         decode_attention_impl(spec, use_pallas, mesh) == "pallas"
         and not quantized
-        # under a selection the rows are gathered after the write
+        # under a selection the kernel fetches rows and writes none
         and not spec.is_dsa
     )
     return "kernel" if kernel else "scatter"
@@ -336,25 +337,24 @@ def _dsa_decode_steps(spec: ModelSpec, impl: str, page_tables, seq_lens,
                       page_ids, page_off, page_size: int):
     """``decode_forward``'s cache steps for latent attention under a
     learned selection (models/hybrid.py ``_dsa_step``), ``(pick, attend,
-    the positions a pick holds)``:
+    the rows a pick holds)``:
 
     * ``pick(qi, w, key, index_pages, layer)`` puts the token's index key
       into the pool's second array, scores the slot's live keys
-      (ops/pallas/dsa.py ``dsa_index_scores_pallas``, or the jnp twin)
-      and returns the ``index_topk`` positions picked, [B, k];
-    * ``attend(q, row, sel, pages, layer)`` puts the token's latent row
-      into the pool and attends over the selected rows alone
-      (ops/dsa.py ``dsa_decode_attention``).
+      (ops/pallas/dsa.py ``dsa_index_scores_pallas``, or the jnp twin),
+      takes the ``index_topk`` positions and returns where those rows
+      lie in a layer of the latent pool, [B, k] (ops/dsa.py
+      ``order_picks``);
+    * ``attend(q, row, rows, pages, layer)`` puts the token's latent row
+      into the pool and attends over the picked rows alone, which the
+      kernel fetches itself (ops/dsa.py ``dsa_decode_attention``).
 
     While no live context is longer than ``index_topk`` the pick is
-    everything: neither scores nor gather then, and the layer is the
-    dense latent kernel over the pool as it stands."""
+    everything, without scores: the same attention over the first
+    ``seq_lens`` rows of each slot."""
     from vgate_tpu.ops import dsa
-    from vgate_tpu.ops.attention import mla_decode_attention, mla_gather_rows
+    from vgate_tpu.ops.attention import mla_gather_rows
     from vgate_tpu.ops.pallas.dsa import dsa_index_scores_pallas
-    from vgate_tpu.ops.pallas.paged_attention import (
-        mla_decode_attention_pallas,
-    )
 
     kernel = impl == "pallas"
     B, n_pages = page_tables.shape
@@ -386,21 +386,17 @@ def _dsa_decode_steps(spec: ModelSpec, impl: str, page_tables, seq_lens,
 
         everything = lambda: jnp.broadcast_to(
             jnp.arange(k, dtype=jnp.int32), (B, k))
-        return jax.lax.cond(short, everything, scored), ip
+        sel = jax.lax.cond(short, everything, scored)
+        with jax.named_scope("dsa_select"):
+            return dsa.order_picks(page_tables, sel, n_sel, page_size), ip
 
-    def attend(q, row, sel, kp, layer):
+    def attend(q, row, rows, kp, layer):
         with jax.named_scope("kv_write"):
             kp = kv_write_tokens(
                 kp, page_ids, page_off, row[:, None], layer=layer)
-        dense = mla_decode_attention_pallas if kernel else (
-            mla_decode_attention)
         with jax.named_scope("dsa_attend"):
-            attn = jax.lax.cond(
-                short,
-                lambda: dense(q, kp, page_tables, seq_lens, layer, **kw),
-                lambda: dsa.dsa_decode_attention(
-                    q, kp, page_tables, sel, n_sel, layer,
-                    use_pallas=kernel, **kw))
+            attn = dsa.dsa_decode_attention(
+                q, kp, rows, n_sel, layer, use_pallas=kernel, **kw)
         return attn, kp
 
     return pick, attend, k
@@ -861,7 +857,7 @@ def decode_forward(
             attn_fn = functools.partial(
                 tp_paged_decode_attention, attn_fn, mesh
             )
-    ps = k_pages.shape[3]
+    ps = page_tokens(k_pages)
     seq_lens, page_ids, page_off = decode_attn_inputs(
         positions, page_tables, active, ps
     )
@@ -997,7 +993,7 @@ def prefill_suffix_forward(
     B, S = tokens.shape
     positions = prefix_lens[:, None] + jnp.arange(S)[None, :]  # absolute
     total_lens = prefix_lens + suffix_lens
-    offsets = (prefix_lens % k_pages.shape[-2]) if unaligned else None
+    offsets = (prefix_lens % page_tokens(k_pages)) if unaligned else None
     x = _embed(params, spec, tokens)  # [B, S, D]
 
     impl = multitok_attention_impl(
@@ -1051,7 +1047,7 @@ def prefill_suffix_forward(
         assert not unaligned, "hybrid specs have no copy-on-write prefix"
         from vgate_tpu.models import hybrid
 
-        ps = k_pages.shape[-2]
+        ps = page_tokens(k_pages)
 
         def attend(q, k, v, kp, vp, layer):
             if spec.is_mla:

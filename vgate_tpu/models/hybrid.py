@@ -96,7 +96,9 @@ from vgate_tpu.models.specs import ModelSpec
 from vgate_tpu.ops import dsa
 from vgate_tpu.ops import gated_delta as gd
 from vgate_tpu.ops import ssd
-from vgate_tpu.ops.kv_quant import gather_pages, kv_write_pages
+from vgate_tpu.ops.kv_quant import (
+    by_pairs, gather_pages, kv_write_pages, page_tokens,
+)
 from vgate_tpu.ops.moe import STAT_NAMES, combine_stats, expert_layer
 from vgate_tpu.ops.norms import rms_norm
 from vgate_tpu.ops.attention import flash_prefill_attention, mla_gather_rows
@@ -832,19 +834,32 @@ def _mla_out(attn, lp):
     return jnp.einsum("...h,hd->...d", attn, lp["o"]["w"])
 
 
+def _write_latent_pages(kp, tables, rows, layer, kernel: bool):
+    """A prompt's latent rows [B, n, 1, ps, W] into the pool: by pairs
+    on the chip through a kernel of page copies (ops/pallas/dsa.py), the
+    scatter everywhere else."""
+    if kernel and by_pairs(kp):
+        from vgate_tpu.ops.pallas.dsa import dsa_write_pages_pallas
+
+        return dsa_write_pages_pallas(kp, tables, rows, layer)
+    return kv_write_pages(kp, tables, rows, layer=layer)
+
+
 def _mla_prompt(normed, lp, spec: ModelSpec, positions, kp, vp, index,
-                write_tables, ctx_tables, attend, cq=None):
+                write_tables, ctx_tables, attend, cq=None,
+                kernel: bool = False):
     """Latent attention over prompt rows normed [B, S, D]: the rows'
     latent goes to the pool (whole pages), K and V are expanded from the
     prompt's own rows or, for a suffix against a cached prefix
     (``ctx_tables``), from the pool's rows of the whole context, and are
     never cached."""
     B, S = normed.shape[:2]
-    ps, width = kp.shape[-2], kp.shape[-1]
+    ps, width = page_tokens(kp), kp.shape[-1]
     q = jnp.concatenate(_mla_q(normed, lp, spec, positions, cq), axis=-1)
     rows = _mla_latent(normed, lp, spec, positions, width)
-    kp = kv_write_pages(kp, write_tables[:, :S // ps],
-                        rows.reshape(B, S // ps, 1, ps, width), layer=index)
+    kp = _write_latent_pages(
+        kp, write_tables[:, :S // ps],
+        rows.reshape(B, S // ps, 1, ps, width), index, kernel)
     if ctx_tables is not None:
         rows = mla_gather_rows(kp, ctx_tables, index)
     k, v = _mla_expand(rows, lp, spec)
@@ -995,7 +1010,7 @@ def _dsa_prompt(normed, lp, spec: ModelSpec, positions, kp, vp, st, index,
     where the expansion is large.  A context of at most ``index_topk``
     tokens is attended whole: ``_mla_prompt`` with ``attend``."""
     B, S = normed.shape[:2]
-    ps, width = kp.shape[-2], kp.shape[-1]
+    ps, width = page_tokens(kp), kp.shape[-1]
     layer = _latent_layer(spec, index, picks)
     T = S if ctx_tables is None else ctx_tables.shape[1] * ps
     cq = _mla_cq(normed, lp, spec)
@@ -1006,7 +1021,8 @@ def _dsa_prompt(normed, lp, spec: ModelSpec, positions, kp, vp, st, index,
             key.reshape(B, S // ps, 1, ps, key.shape[-1]), layer=index)
     if T <= spec.index_topk:  # nothing to leave out
         out, kp = _mla_prompt(normed, lp, spec, positions, kp, None, layer,
-                              write_tables, ctx_tables, attend, cq=cq)
+                              write_tables, ctx_tables, attend, cq=cq,
+                              kernel=kernel)
         return out, kp, vp, st
     if picks:
         keys = key if ctx_tables is None else mla_gather_rows(
@@ -1016,8 +1032,9 @@ def _dsa_prompt(normed, lp, spec: ModelSpec, positions, kp, vp, st, index,
             kernel and ctx_tables is None)}
     mask = st["sel"]
     rows = _mla_latent(normed, lp, spec, positions, width)
-    kp = kv_write_pages(kp, write_tables[:, :S // ps],
-                        rows.reshape(B, S // ps, 1, ps, width), layer=layer)
+    kp = _write_latent_pages(
+        kp, write_tables[:, :S // ps],
+        rows.reshape(B, S // ps, 1, ps, width), layer, kernel)
     if ctx_tables is not None:
         rows = mla_gather_rows(kp, ctx_tables, layer)
     H, kl = spec.num_heads, spec.kv_lora_rank
@@ -1302,7 +1319,7 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
     ``total_lens`` the contexts' lengths (``lens`` for a whole prompt).
     Returns (x, k_pages, v_pages, state)."""
     B, S = x.shape[:2]
-    ps = k_pages.shape[-2]
+    ps = page_tokens(k_pages)
     KV, hd = spec.cache_heads, spec.cache_head_dim
     n_pages = S // ps
     row_mask = jnp.arange(S)[None, :] < lens[:, None]

@@ -796,7 +796,8 @@ class PerfRecorder:
             self._mla[name] = self._mla.get(name, 0) + add
 
     def note_dsa_decode(self, steps: int, lens, layers: int,
-                        index_layers: int, topk: int) -> None:
+                        index_layers: int, topk: int,
+                        fetch_chunk: int = 0) -> None:
         """One decode chunk of a spec that attends under a learned
         selection, booked once per readback: the sequences of lengths
         ``lens`` (at dispatch) rode ``steps`` steps; step k scored a
@@ -804,13 +805,21 @@ class PerfRecorder:
         ``index_layers`` picking layers, wrote one index key there, and
         attended to ``min(context, topk)`` latent rows in each of
         ``layers`` layers, ``layers - index_layers`` of which reused a
-        pick.  What the kernels have to read, and no padding."""
+        pick.  What the kernels have to read, and no padding.
+        ``rows_fetched`` is what the attention MOVED for those rows: the
+        kernel a pair of token rows a pick, whole chunks of
+        ``fetch_chunk`` picks (ops/pallas/dsa.py); the jnp twin
+        (``fetch_chunk`` 0) the picked rows themselves."""
         ctx = sum(steps * n + steps * (steps - 1) // 2 for n in lens)
-        attended = sum(min(n + k, topk) for n in lens for k in range(steps))
+        picks = [min(n + k, topk) for n in lens for k in range(steps)]
+        attended = sum(picks)
+        fetched = (sum(2 * fetch_chunk * -(-m // fetch_chunk) for m in picks)
+                   if fetch_chunk else attended)
         for name, add in (
             ("index_layer_steps", steps * index_layers),
             ("rows_scored", ctx * index_layers),
             ("rows_attended", attended * layers),
+            ("rows_fetched", fetched * layers),
             ("rows_in_context", ctx * layers),
             ("selections_reused", steps * (layers - index_layers)),
             ("index_rows_written", steps * len(lens) * index_layers),

@@ -266,10 +266,9 @@ def paged_suffix_attention(
 
 def mla_gather_rows(pages, page_tables, layer):
     """The latent pool's rows of each slot's page window, [B, ctx, W]
-    (pages [L, 1, P, ps, W])."""
+    (pages [L, 1, P, ps, W], or by pairs [L, 1, P, ps / 2, 2, W])."""
     sel = gather_pages(pages, page_tables, layer=layer)  # [1, B, n, ps, W]
-    B, n, ps, W = sel.shape[1:]
-    return sel.reshape(B, n * ps, W)
+    return sel.reshape(sel.shape[1], -1, sel.shape[-1])
 
 
 def mla_decode_attention(
@@ -285,12 +284,19 @@ def mla_decode_attention(
     form, [B, H, v_width]: every head's query meets a token's ONE cached
     row as its key and takes the row's first ``v_width`` lanes as its
     value.  The twin of ``mla_decode_attention_pallas``."""
-    rows = mla_gather_rows(pages, page_tables, layer)
+    return mla_attend_rows(
+        q, mla_gather_rows(pages, page_tables, layer), seq_lens, v_width,
+        scale)
+
+
+def mla_attend_rows(q, rows, lens, v_width: int, scale: float):
+    """The absorbed form over rows in hand: q [B, H, W] against the
+    first ``lens`` of rows [B, T, W] -> [B, H, v_width]."""
     scores = jnp.einsum(
         "bhw,btw->bht", q, rows.astype(q.dtype),
         preferred_element_type=jnp.float32,
     ) * scale
-    valid = jnp.arange(rows.shape[1])[None, :] < seq_lens[:, None]
+    valid = jnp.arange(rows.shape[1])[None, :] < lens[:, None]
     scores = jnp.where(valid[:, None, :], scores, -1e30)
     probs = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
     probs = probs / jnp.sum(probs, axis=-1, keepdims=True)

@@ -13,9 +13,10 @@ index_topk``; ties to the lower position).  The layer's latent attention,
 and that of the layers that reuse the pick, runs over those positions
 only.
 
-Two forms of the selection: POSITIONS ``[B, k]`` (a decode step: the
-rows are then gathered through the page table, ``dsa_decode_attention``)
-and a MASK ``[B, S, T]`` (a prompt pass: the flash kernel takes it tile
+Two forms of the selection: POSITIONS ``[B, k]`` (a decode step: they
+become the rows' places in the pool once a pick, ``order_picks``, and
+the attention fetches those rows alone, ``dsa_decode_attention``) and a
+MASK ``[B, S, T]`` (a prompt pass: the flash kernel takes it tile
 by tile).  The Pallas kernels are in ``ops/pallas/dsa.py``; what is here
 is their ``jax.numpy`` twins and what XLA does as well as a kernel
 would.
@@ -91,44 +92,61 @@ def select_positions(scores, k: int):
         jnp.int32)
 
 
-def gather_selected(pool, page_tables, sel, layer):
-    """The pool's rows at the selected positions, through the page table:
-    pool [L, 1, P, ps, W], page_tables [B, n], sel [B, k] (positions) ->
-    [B, k, W].  ONE gather of rows over the pool seen as ``[L x P x ps,
-    W]`` (the leading dimensions merge without moving a byte)."""
-    L, _, P, ps, W = pool.shape
-    page = jnp.take_along_axis(page_tables, sel // ps, axis=1)
-    flat = (layer * P + page) * ps + sel % ps
-    return pool.reshape(L * P * ps, W)[flat]
+def order_picks(page_tables, sel, n_sel, page_size: int):
+    """Where a slot's picks lie in a layer of the pool, ``page *
+    page_size + offset``, [B, k] int32: the first ``n_sel`` of ``sel``
+    (positions) through the page table, put in the order (the row's
+    parity, its place) so that the rows at EVEN places come first (the
+    first rows of their pairs, in a pool by pairs) and neighbours in the
+    list are neighbours in the pool; the rest 0.  The same set: done
+    once a pick, for every layer that attends under it.  The page of a
+    position by comparison against every entry of the slot's table
+    (summed over a major dimension), not by a gather: XLA's gather of
+    98,304 page ids is 1.0 ms on the v5e (PERF.md section 6, PR 41),
+    this whole function under 0.2."""
+    k, n = sel.shape[1], page_tables.shape[1]
+    hit = (sel // page_size)[:, None, :] == jnp.arange(
+        n, dtype=jnp.int32)[None, :, None]
+    page = jnp.sum(
+        jnp.where(hit, page_tables.astype(jnp.int32)[:, :, None], 0),
+        axis=1)
+    row = page * page_size + sel % page_size
+    real = jnp.arange(k, dtype=jnp.int32)[None, :] < n_sel[:, None]
+    # one sort: parity above the place, the rest above both
+    key = jnp.sort(
+        jnp.where(real, ((row & 1) << 29) | row, 1 << 30), axis=1)
+    return jnp.where(real, key & ((1 << 29) - 1), 0)
 
 
-def dsa_decode_attention(q, pool, page_tables, sel, n_sel, layer, *,
-                         v_width: int, scale: float, use_pallas: bool):
+def gather_selected(pool, rows, layer):
+    """The pool's rows at the places ``rows`` [B, k] of layer ``layer``
+    (``order_picks``), [B, k, W]: pool [L, 1, P, ps, W] or by pairs [L,
+    1, P, ps / 2, 2, W], seen as ``[L x P x ps, W]`` (the leading
+    dimensions merge without moving a byte)."""
+    W = pool.shape[-1]
+    flat = pool.reshape(-1, W)
+    return flat[layer * (flat.shape[0] // pool.shape[0]) + rows]
+
+
+def dsa_decode_attention(q, pool, rows, n_sel, layer, *, v_width: int,
+                         scale: float, use_pallas: bool):
     """Absorbed latent decode attention over the SELECTED rows only:
-    q [B, H, W], sel [B, k] the positions (a slot's first ``n_sel`` are
-    its pick) -> [B, H, v_width].  The rows are gathered through the
-    page table into ``[B, k, W]`` and met as a pool of their own, ``k /
-    ps`` pages a slot in order, by the dense latent kernel under the
-    name ``dsa_decode_attention_pallas`` (or its jnp twin): no row
-    outside the pick is read."""
-    from vgate_tpu.ops.attention import mla_decode_attention
-
-    B, k = sel.shape
-    ps, W = pool.shape[-2], pool.shape[-1]
-    with jax.named_scope("dsa_gather"):
-        rows = gather_selected(pool, page_tables, sel, layer)
-    pad = -k % ps  # a pick that is no whole number of pages (tiny sizes)
-    if pad:
-        rows = jnp.pad(rows, ((0, 0), (0, pad), (0, 0)))
-    n = (k + pad) // ps
-    own = rows.reshape(1, 1, B * n, ps, W)
-    tables = jnp.arange(B * n, dtype=jnp.int32).reshape(B, n)
+    q [B, H, W], rows [B, k] the picks' places in a layer of the pool (a
+    slot's first ``n_sel`` real, as ``order_picks`` leaves them) -> [B,
+    H, v_width].  The kernel (ops/pallas/dsa.py
+    ``dsa_decode_attention_pallas``, a pool by pairs) fetches each
+    picked row itself; the jnp twin gathers them.  No row outside the
+    pick meets a score."""
     if use_pallas:
         from vgate_tpu.ops.pallas.dsa import dsa_decode_attention_pallas
 
         return dsa_decode_attention_pallas(
-            q, own, tables, n_sel, v_width=v_width, scale=scale)
-    return mla_decode_attention(q, own, tables, n_sel, 0, v_width, scale)
+            q, pool, rows, n_sel, layer, v_width=v_width, scale=scale)
+    from vgate_tpu.ops.attention import mla_attend_rows
+
+    with jax.named_scope("dsa_gather"):
+        picked = gather_selected(pool, rows, layer)
+    return mla_attend_rows(q, picked, n_sel, v_width, scale)
 
 
 def masked_attention(q, k, v, mask, scale: float):

@@ -91,6 +91,21 @@ def is_quantized(pool) -> bool:
     return isinstance(pool, QuantPages)
 
 
+def by_pairs(pool) -> bool:
+    """Whether ``pool`` holds its token rows by PAIRS, ``[L, KV, P, ps /
+    2, 2, hd]``: the latent array of a spec that picks (learned sparse
+    attention), whose decode kernel fetches single picked rows and can
+    address nothing smaller than a pair (ops/pallas/dsa.py).  The same
+    bytes in the same order as ``[L, KV, P, ps, hd]``; a page is still
+    one leading index.  Such a pool always has its layer dimension."""
+    return pool.ndim == 6
+
+
+def page_tokens(pool) -> int:
+    """Tokens a page of ``pool`` holds, whichever way its rows lie."""
+    return pool.shape[3] * 2 if by_pairs(pool) else pool.shape[-2]
+
+
 def dtype_short_name(dtype) -> str:
     """Reporting name for /stats, drills and bench artifacts — one
     definition site (engine_core stamps KVGeometry.kv_dtype with it)."""
@@ -160,7 +175,10 @@ def kv_write_tokens(
     the decode loop, plus a pool-sized temporary (PERF.md "Bring-up",
     memory_analysis table; tests/test_tpu_aot.py pins the fix)."""
     kv = jnp.arange(value.shape[-2], dtype=jnp.int32)
-    idx = (kv, page_ids[..., None], page_off[..., None])
+    off = page_off[..., None]
+    # a pool by pairs: the token's row is one of its pair's two
+    where = (off // 2, off % 2) if by_pairs(pool) else (off,)
+    idx = (kv, page_ids[..., None]) + where
     if layer is not None:
         idx = (layer,) + idx
     return kv_write(pool, idx, value)
@@ -174,6 +192,12 @@ def kv_write_pages(
     — the kv-head dim indexed explicitly for the same reason as
     ``kv_write_tokens``, so the window is one contiguous (ps, hd) page."""
     kv = jnp.arange(value.shape[-3], dtype=jnp.int32)
+    if by_pairs(pool):
+        # the jnp path's (a CPU): on the chip either window, a page of
+        # pairs or a row, makes XLA:TPU re-lay or flatten the WHOLE pool
+        # (4.7 GB in the prompt program of the GLM-5.2 cut), and the
+        # prompt's pages go by ops/pallas/dsa.py dsa_write_pages_pallas
+        value = value.reshape(value.shape[:-2] + pool.shape[-3:])
     idx = (kv, page_tables[..., None])
     if layer is not None:
         idx = (layer,) + idx
@@ -190,7 +214,8 @@ def gather_pages(pool: KVPool, page_tables: jax.Array, layer=None):
     carries a leading [L] dim and ONE gather indexes (layer, page) —
     only the live pages of that layer are read, never a full per-layer
     slice — with the kv-head dim passed through as a window dim, so a
-    tp-sharded pool partitions along it.  (Folding layer and head into
+    tp-sharded pool partitions along it.  A pool by pairs comes out by
+    pairs, ``[KV, B, n_pages, ps / 2, 2, hd]``.  (Folding layer and head into
     one flat index reshapes across the sharded dim, and GSPMD then
     all-gathers the whole pool onto every chip.)
     """

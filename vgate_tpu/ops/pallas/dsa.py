@@ -10,9 +10,14 @@ mathematics and the ``jax.numpy`` twins):
 * ``dsa_prompt_scores_pallas`` (the same launch name): a block of a
   prompt's query rows against all of the prompt's keys, tile by tile;
   tiles above the diagonal are ``-inf`` and cost nothing.
-* ``dsa_decode_attention_pallas``: the dense latent decode kernel
-  (``_decode_kernel``) over the rows a gather has put in order, under a
-  name of its own.
+* ``dsa_decode_attention_pallas``: a decode step's attention over the
+  picked rows, which the kernel fetches itself from the pool BY PAIRS
+  of token rows (``[L, 1, P, ps / 2, 2, W]``: one row of a page is no
+  descriptor Mosaic takes, a pair is), a descriptor a pick, the next
+  chunk's in flight; the picked row of each pair kept by the pick's
+  place in the slot's list (ops/dsa.py ``order_picks``).
+* ``dsa_write_pages_pallas``: a prompt's latent rows into the pool by
+  pairs, a page a descriptor.
 * ``dsa_prefill_attention_pallas``: the flash prompt kernel with the
   selection as a mask tile beside each key block, under a name of its
   own.
@@ -21,6 +26,7 @@ mathematics and the ``jax.numpy`` twins):
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -234,20 +240,274 @@ def dsa_prompt_scores_pallas(
       keys)
 
 
-def dsa_decode_attention_pallas(q, rows, tables, n_sel, *, v_width: int,
-                                scale: float, interpret: bool = False):
-    """Absorbed latent decode attention over gathered rows (``rows`` [1,
-    1, B x n, ps, W], a slot's pick in its ``n`` pages in order, the
-    first ``n_sel`` rows real): ``_decode_kernel`` as the dense latent
-    layer launches it, under a name of its own so that a device trace
-    tells a layer under a selection from one without."""
-    from vgate_tpu.ops.pallas.paged_attention import (
-        mla_decode_attention_pallas,
-    )
+# picks a loop trip of the decode attention fetches and attends to: one
+# descriptor a pick, the next trip's in flight while this one's multiply
+# (us a layer of 48 x 2,048 picks on the v5e, PERF.md section 6, PR 41:
+# 1,299 at 256, 1,231 at 512, 1,193 at 1,024, the loop of 8 below), and
+# descriptors a trip of the loop that issues them (1,242 at 8, 1,160 at
+# 16, 1,128 at 32, 1,113 at 64; the issue alone is 783 of them)
+FETCH_CHUNK = 512
+FETCH_UNROLL = 32
 
-    return mla_decode_attention_pallas(
-        q, rows, tables, n_sel, 0, v_width=v_width, scale=scale,
-        interpret=interpret, name="dsa_decode_attention_pallas")
+
+def fetch_chunk(k: int, chunk: int = FETCH_CHUNK) -> int:
+    """Picks a trip of the decode attention over ``k`` picks a slot."""
+    return min(chunk, -(-k // 8) * 8)
+
+
+def _fetch_decode_kernel(
+    # scalar prefetch (SMEM)
+    pairs_ref,  # [B, n_chunks * K]: each pick's pair of rows in the pool
+    nsel_ref,  # [B] picks that are real (the first of a slot's)
+    neven_ref,  # [B] of those, how many sit in their pair's FIRST row
+    base_ref,  # [B] chunks of the slots before this one
+    next_ref,  # [B] the next slot with a pick (B: none)
+    # inputs
+    q_ref,  # [1, H, W] VMEM
+    pool,  # [N, 2, W] HBM: the latent rows, a pair of tokens a block
+    # output
+    out_ref,  # [1, H, v_width]
+    # scratch
+    buf,  # [2, K, 2, W]
+    sem,  # DMA [2]
+    *, chunk: int, batch: int, v_width: int, scale: float, form: str,
+):
+    """One slot's attention over its picked rows: the pair that holds
+    each pick comes HBM -> VMEM by a descriptor of its own, ``chunk``
+    picks a trip, the next trip's descriptors issued before this trip's
+    products (a slot's last trip issues the next slot's first), and the
+    picked row of each pair is kept by the pick's place in the slot's
+    list: the first ``n_even`` picks sit in their pair's first row, the
+    others in the second.  The partner row meets no score."""
+    b = pl.program_id(0)
+    K = chunk
+    n_sel = nsel_ref[b]
+    n_even = neven_ref[b]
+    base = base_ref[b]
+    nxt = next_ref[b]
+    n = (n_sel + K - 1) // K
+    unroll = math.gcd(K, FETCH_UNROLL)
+
+    def issue(slot_b, c, at):
+        def some(g, carry):
+            for j in range(unroll):
+                i = g * unroll + j
+                pltpu.make_async_copy(
+                    pool.at[pairs_ref[slot_b, c * K + i]], buf.at[at, i],
+                    sem.at[at]).start()
+            return carry
+
+        jax.lax.fori_loop(0, K // unroll, some, 0)
+
+    def wait(at):
+        # every descriptor of the chunk signals the one semaphore: one
+        # wait for the chunk's bytes
+        pltpu.make_async_copy(buf.at[at], buf.at[at], sem.at[at]).wait()
+
+    # the first slot with a pick starts its own first chunk; every other
+    # finds it started by the slot before
+    @pl.when((n > 0) & (base == 0))
+    def _():
+        issue(b, 0, 0)
+
+    q = q_ref[0]  # [H, W]
+    H, W = q.shape
+
+    def attend(carry, at, first):
+        """The softmax state after the chunk in ``buf[at]``, whose first
+        pick is the slot's ``first``-th."""
+        m_prev, l_prev, acc_prev = carry
+        pairs = buf[at]  # [K, 2, W]
+        place = first + jax.lax.broadcasted_iota(jnp.int32, (K, 1), 0)
+        if form == "words":
+            # a pair's two bf16 rows lie in one 32-bit word a lane, the
+            # first row in the low half: either half, moved to the top,
+            # is that row's value as a float32
+            w = pltpu.bitcast(pairs.reshape(2 * K, W), jnp.uint32)
+            w = jnp.where(place < n_even, w << 16,
+                          w & jnp.uint32(0xFFFF0000))
+            rows = pltpu.bitcast(w, jnp.float32).astype(buf.dtype)
+        else:
+            rows = jnp.where(place < n_even, pairs[:, 0, :], pairs[:, 1, :])
+        live = first + jax.lax.broadcasted_iota(
+            jnp.int32, (1, K), 1) < n_sel
+        s = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [H, K]
+        s = jnp.where(live, s, -1e30)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        v = rows[:, :v_width]
+        dims = (((1,), (0,)), ((), ()))
+        if rows.dtype == jnp.bfloat16:
+            # the float32 weights as two bf16 terms, as _decode_kernel
+            p_hi = p.astype(jnp.bfloat16)
+            p_lo = (p - p_hi.astype(jnp.float32)).astype(jnp.bfloat16)
+            pv = jax.lax.dot_general(
+                p_hi, v, dims, preferred_element_type=jnp.float32
+            ) + jax.lax.dot_general(
+                p_lo, v, dims, preferred_element_type=jnp.float32)
+        else:
+            pv = jax.lax.dot_general(
+                p, v.astype(jnp.float32), dims,
+                preferred_element_type=jnp.float32)
+        return m_new, l_new, acc_prev * alpha + pv
+
+    def trip(c, carry):
+        at = (base + c) % 2
+        more = c + 1 < n
+
+        @pl.when(more | (nxt < batch))
+        def _():
+            issue(jnp.where(more, b, nxt), jnp.where(more, c + 1, 0),
+                  1 - at)
+
+        wait(at)
+        if form == "hollow":  # the probe's: the fetch alone
+            return carry
+        return attend(carry, at, c * K)
+
+    m, l, acc = jax.lax.fori_loop(0, n, trip, (
+        jnp.full((H, 1), -1e30, jnp.float32),
+        jnp.zeros((H, 1), jnp.float32),
+        jnp.zeros((H, v_width), jnp.float32)))
+    out_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(out_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("v_width", "scale", "interpret", "chunk",
+                              "form"))
+def dsa_decode_attention_pallas(
+    q: jnp.ndarray,  # [B, H, W] absorbed queries
+    pool: jnp.ndarray,  # [L, 1, P, ps / 2, 2, W]: the latent pool, by pairs
+    rows: jnp.ndarray,  # [B, k] the picks' places in a layer (order_picks)
+    n_sel: jnp.ndarray,  # [B] of them real; 0 => nothing fetched: zeros out
+    layer,  # int32 scalar: the pool's layer
+    *, v_width: int, scale: float, interpret: bool = False,
+    chunk: int = FETCH_CHUNK, form: str = "",
+):
+    """Absorbed latent decode attention over the SELECTED rows, fetched
+    by the kernel itself: [B, H, v_width].  ``rows`` as
+    ops/dsa.py ``order_picks`` leaves them: a slot's real picks first,
+    those at even places (the first rows of their pairs) before the
+    others, so the kernel tells a pick's row of its pair by the pick's
+    place in the list.  The jnp twin is
+    ``ops.dsa.dsa_decode_attention(use_pallas=False)``."""
+    B, H, W = q.shape
+    L, _, P, half, _, _ = pool.shape
+    if P * half >= 1 << 28:  # order_picks' sort key holds 29 bits of it
+        raise ValueError(f"{P} pages of {2 * half} tokens in a layer")
+    k = rows.shape[1]
+    K = fetch_chunk(k, chunk)
+    form = form or ("words" if pool.dtype == jnp.bfloat16 else "select")
+    n_chunks = cdiv(k, K)
+    n_sel = n_sel.astype(jnp.int32)
+    real = jnp.arange(k, dtype=jnp.int32)[None, :] < n_sel[:, None]
+    n_even = jnp.sum(real & (rows % 2 == 0), axis=1, dtype=jnp.int32)
+    pairs = jnp.asarray(layer, jnp.int32) * (P * half) + rows // 2
+    pairs = jnp.pad(pairs, ((0, 0), (0, n_chunks * K - k)))
+    chunks = (n_sel + K - 1) // K
+    base = jnp.cumsum(chunks) - chunks
+    # the next slot with a pick: a running minimum from the right
+    slot = jnp.where(chunks > 0, jnp.arange(B, dtype=jnp.int32), B)
+    after = jnp.concatenate([slot[1:], jnp.full((1,), B, jnp.int32)])
+    nxt = jax.lax.cummin(after, reverse=True)
+    kernel = functools.partial(
+        _fetch_decode_kernel, chunk=K, batch=B, v_width=v_width,
+        scale=float(scale), form=form)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, H, W), lambda b, *pf: (b, 0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, H, v_width), lambda b, *pf: (b, 0, 0),
+                memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((2, K, 2, W), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, H, v_width), q.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024,
+            disable_bounds_checks=True,
+        ),
+        name="dsa_decode_attention_pallas",
+    )(pairs, n_sel, n_even, base.astype(jnp.int32), nxt,
+      q.astype(pool.dtype), pool.reshape(L * P * half, 2, W))
+
+
+# page copies a group of the prompt's page writer has in flight
+WRITE_GROUP = 16
+
+
+def _write_pages_kernel(tables_ref, layer_ref, value, pool_in, pool_out,
+                        sem, *, pages: int):
+    """Each of ``pages`` blocks of ``value`` to its page of the pool's
+    layer, HBM -> HBM, a descriptor a page."""
+    del pool_in  # the same array as pool_out
+    layer = layer_ref[0]
+
+    def copy(i):
+        return pltpu.make_async_copy(
+            value.at[i], pool_out.at[layer, 0, tables_ref[i]], sem.at[0])
+
+    def group(first, count):
+        for j in range(count):
+            copy(first + j).start()
+        for j in range(count):
+            copy(first + j).wait()
+
+    whole = pages // WRITE_GROUP
+
+    def some(g, carry):
+        group(g * WRITE_GROUP, WRITE_GROUP)
+        return carry
+
+    jax.lax.fori_loop(0, whole, some, 0)
+    group(whole * WRITE_GROUP, pages % WRITE_GROUP)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",),
+                   donate_argnames=("pool",))
+def dsa_write_pages_pallas(pool, page_tables, value, layer,
+                           interpret: bool = False):
+    """A prompt's latent rows into a pool BY PAIRS, whole pages: pool
+    [L, 1, P, ps / 2, 2, W], page_tables [..., n], value [..., n, 1, ps,
+    W] -> the pool, updated in place (donate it).  XLA's scatter into
+    such a pool re-lays or flattens the WHOLE pool first, whichever
+    window it is given (tests/test_tpu_aot.py); a page is one leading
+    index and one copy.  Pages named twice (the trash page of a prompt's
+    padding) hold either writer's rows."""
+    L, _, P, half, two, W = pool.shape
+    tables = page_tables.reshape(-1).astype(jnp.int32)
+    n = tables.shape[0]
+    kernel = functools.partial(_write_pages_kernel, pages=n)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[any_spec, any_spec],
+            out_specs=any_spec,
+            scratch_shapes=[pltpu.SemaphoreType.DMA((1,))],
+        ),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={3: 0},  # the pool, after the prefetch
+        interpret=interpret,
+        name="dsa_write_pages_pallas",
+    )(tables, jnp.asarray(layer, jnp.int32).reshape(1),
+      value.astype(pool.dtype).reshape(n, half, two, W), pool)
 
 
 def dsa_prefill_attention_pallas(q, k, v, seq_lens, mask, *, scale: float,

@@ -87,7 +87,7 @@ from vgate_tpu.observability.roofline import (
     kv_bytes_per_token,
     stream_weight_bytes,
 )
-from vgate_tpu.ops.kv_quant import SCALE_BYTES, dtype_short_name
+from vgate_tpu.ops.kv_quant import SCALE_BYTES, by_pairs, dtype_short_name
 from vgate_tpu.parallel.mesh import build_mesh, initialize_distributed
 from vgate_tpu.parallel.sharding import kv_pspec, named, shard_params
 from vgate_tpu.runtime.kv_cache import (
@@ -3324,6 +3324,18 @@ class EngineCore:
             self.spec, self.use_pallas, self._attn_mesh, self._kv_quant
         )
 
+    @property
+    def _dsa_fetch_chunk(self) -> int:
+        """Picks a loop trip of the decode kernel under a selection
+        fetches, a pair of token rows each (0: the jnp twin gathers the
+        picked rows alone): /debug/perf -> totals.dsa.rows_fetched."""
+        from vgate_tpu.ops.pallas.dsa import fetch_chunk
+
+        kernel = decode_attention_impl(
+            self.spec, self.use_pallas, self._attn_mesh) == "pallas"
+        context = self.geometry.pages_per_seq * self.geometry.page_size
+        return fetch_chunk(min(self.spec.index_topk, context)) if kernel else 0
+
     @engine_thread_only
     def _dispatch_chunk(self, active: List[Sequence], chunk: int) -> None:
         faults.check("decode_step")
@@ -3463,6 +3475,7 @@ class EngineCore:
                         layers=self.spec.attn_layers,
                         index_layers=self.spec.index_layers,
                         topk=self.spec.index_topk,
+                        fetch_chunk=self._dsa_fetch_chunk,
                     )
                 if self.spec.swa_layers:
                     self.perf.note_swa_decode(
@@ -4261,6 +4274,9 @@ class EngineCore:
                 "row_lanes": self.geometry.head_dim,
                 **({"latent": self.spec.latent_dim}
                    if self.spec.is_mla else {}),
+                # a spec that picks: the latent rows by pairs of tokens,
+                # what its decode kernel fetches a pick
+                **({"row_pairs": True} if by_pairs(self.k_pages) else {}),
                 # the index keys a page holds beside them (a spec that
                 # picks): one row a token in each picking layer
                 **({"index": {

@@ -343,8 +343,8 @@ class PageAllocator:
 
 def make_kv_buffers(geometry: KVGeometry, dtype=jnp.bfloat16, sharding=None):
     """Allocate the page pools (zeros; ``(k, v)``, or ``(latent, None)``
-    for a geometry of ONE pool, or ``(latent, index keys)`` for one with
-    ``index_layers``) directly on device, each chip
+    for a geometry of ONE pool, or ``(latent by pairs of rows, index
+    keys)`` for one with ``index_layers``) directly on device, each chip
     of a mesh creating only its own shard (``device=sharding``): a global
     pool drawn on the default device and spread afterwards does not fit
     the one chip it is drawn on.
@@ -389,7 +389,19 @@ def make_kv_buffers(geometry: KVGeometry, dtype=jnp.bfloat16, sharding=None):
 
         k, v = pool(), pool()
     else:
-        k = jnp.zeros(shape, dtype, device=sharding)
+        # a spec that picks holds its latent rows by PAIRS of tokens:
+        # its decode kernel fetches single picked rows, and a pair is the
+        # least Mosaic lets a descriptor address (ops/kv_quant.py
+        # by_pairs).  The same bytes in the same order
+        latent = shape
+        if geometry.index_layers:
+            if geometry.page_size % 2:
+                raise ValueError(
+                    f"kv_cache.page_size={geometry.page_size}: a spec under "
+                    "a learned selection holds its rows by pairs, an even "
+                    "page")
+            latent = shape[:3] + (geometry.page_size // 2, 2) + shape[4:]
+        k = jnp.zeros(latent, dtype, device=sharding)
         v = (jnp.zeros(shape, dtype, device=sharding)
              if geometry.pools == 2 else None)
         if geometry.index_layers:
