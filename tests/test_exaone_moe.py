@@ -18,6 +18,7 @@ import pytest
 
 from perfbench import manifest
 from perfbench.references import exaone_moe as ref
+from tests import prompt_row_blocks as row_blocks
 from vgate_tpu.models import decoder, hybrid
 from vgate_tpu.models.specs import spec_for_model_id
 from vgate_tpu.ops import moe
@@ -261,3 +262,16 @@ def test_the_banded_prompt_kernel_is_the_window_as_a_mask(
         np.testing.assert_allclose(
             np.asarray(got[b, :n]), np.asarray(want[b, :n]),
             rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("fill", list(row_blocks.FILLS))
+def test_a_long_prompt_pass_works_on_its_own_row_blocks(fill):
+    """A bucket of four blocks of rows (the block patched to 8): the
+    window and full layers' projections, the dense layer and the expert
+    layer's position-wise parts in a counted loop over the blocks the
+    longer prompt reaches, against the pass over the whole bucket."""
+    row_blocks.check_prompt_pass("tiny-swa-moe", row_blocks.FILLS[fill])
+
+
+def test_greedy_tokens_are_the_same_with_the_row_loop(monkeypatch):
+    row_blocks.check_greedy_identity(monkeypatch, "tiny-swa-moe")

@@ -20,6 +20,7 @@ import pytest
 
 from perfbench import manifest
 from perfbench.references import glm_moe_dsa as ref
+from tests import prompt_row_blocks as row_blocks
 from vgate_tpu.models import decoder, hybrid
 from vgate_tpu.models.specs import spec_for_model_id
 from vgate_tpu.ops import dsa, moe
@@ -578,3 +579,18 @@ def test_the_prompts_page_writer_is_the_scatter(pages):
     got = dsa_write_pages_pallas(pool + 0, tables, value, 1, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert not np.array_equal(np.asarray(got[1]), np.asarray(pool[1]))
+
+
+@pytest.mark.parametrize("fill", list(row_blocks.FILLS))
+def test_a_long_prompt_pass_works_on_its_own_row_blocks(fill):
+    """A bucket of four blocks of rows (the block patched to 8; 32 rows
+    against an ``index_topk`` of 16, so the layers pick): the query
+    latent, the index keys and queries, the latent rows, each group of
+    heads' expansion and output projection in a counted loop over the
+    blocks the longer prompt reaches, against the pass over the whole
+    bucket."""
+    row_blocks.check_prompt_pass("tiny-dsa-moe", row_blocks.FILLS[fill])
+
+
+def test_greedy_tokens_are_the_same_with_the_row_loop(monkeypatch):
+    row_blocks.check_greedy_identity(monkeypatch, "tiny-dsa-moe")

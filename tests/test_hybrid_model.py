@@ -14,6 +14,7 @@ import pytest
 
 from perfbench import manifest
 from perfbench.references import qwen3_next as ref
+from tests import prompt_row_blocks as row_blocks
 from vgate_tpu.backends.base import SamplingParams
 from vgate_tpu.config import load_config
 from vgate_tpu.models.specs import spec_for_model_id
@@ -200,3 +201,16 @@ def test_engine_construction_refuses_by_name(sections, devices, named):
     with pytest.raises(ValueError, match="recurrent") as exc:
         EngineCore(cfg, devices=jax.devices()[:devices])
     assert named in str(exc.value)
+
+
+
+@pytest.mark.parametrize("model_id", [
+    "tiny-hybrid", "tiny-nemotron-h", "tiny-mla-moe", "tiny-swa-moe",
+    "tiny-dsa-moe"])
+def test_a_decode_step_and_a_short_wave_hold_no_loop_over_row_blocks(
+        model_id):
+    """The loop over a long prompt's row blocks engages by the traced
+    shape alone: a decode step and a wave of 1,024-row prompts are, to
+    the letter, the jaxprs they are with the loop off; a 2,048-row
+    prompt program holds more ``while``."""
+    row_blocks.check_small_programs_hold_no_loop(model_id)

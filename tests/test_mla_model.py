@@ -16,6 +16,7 @@ import pytest
 
 from perfbench import manifest
 from perfbench.references import mistral4 as ref
+from tests import prompt_row_blocks as row_blocks
 from vgate_tpu.backends.base import SamplingParams
 from vgate_tpu.config import load_config
 from vgate_tpu.models.specs import spec_for_model_id
@@ -221,3 +222,16 @@ def test_published_preset_counts_its_name():
     published = manifest.load_json(
         manifest.HERE, "configs", "mistral-small-4-119b-l4e32.json")
     assert spec.rope_parameters == published["rope_parameters"]
+
+
+@pytest.mark.parametrize("fill", list(row_blocks.FILLS))
+def test_a_long_prompt_pass_works_on_its_own_row_blocks(fill):
+    """A bucket of four blocks of rows (the block patched to 8): the
+    queries, the latent rows, their expansion and the output projection
+    in a counted loop over the blocks the longer prompt reaches, against
+    the pass over the whole bucket."""
+    row_blocks.check_prompt_pass("tiny-mla-moe", row_blocks.FILLS[fill])
+
+
+def test_greedy_tokens_are_the_same_with_the_row_loop(monkeypatch):
+    row_blocks.check_greedy_identity(monkeypatch, "tiny-mla-moe")

@@ -715,6 +715,50 @@ def test_the_expert_layers_counters_are_booked_with_their_overflow():
         "load_max_sum": 10, "overflow": 3, "layer_steps": 6, "steps": 3}
 
 
+@pytest.mark.parametrize("model_id, rows, bucket, lens, whole, worked", [
+    # a long whole prompt of a stack of several kinds: the blocks of
+    # 1,024 rows up to the longest prompt, in every row of the program
+    ("tiny-swa-moe", 1, 8192, [5500], True, 6 * 1024),
+    ("tiny-mla-moe", 2, 8192, [4097, 1], True, 2 * 5 * 1024),
+    ("tiny-dsa-moe", 1, 16384, [8193], True, 9 * 1024),
+    ("tiny-swa-moe", 1, 2048, [1024], True, 1024),
+    # the loop does not engage: under two blocks, a suffix or a chunk of
+    # a prompt, a stack of one kind (models/decoder.py's own pass)
+    ("tiny-swa-moe", 4, 1024, [300, 900, 7, 1], True, 4 * 1024),
+    ("tiny-mla-moe", 1, 8192, [5500], False, 8192),
+    ("tiny-dense", 2, 2048, [1820, 1025], True, 2 * 2048),
+])
+def test_prefill_rows_are_counted_by_the_models_own_rule(
+        model_id, rows, bucket, lens, whole, worked):
+    """``totals.prefill``: what ``engine.prefill_pad_share.tok`` reads.
+    The engine books a prompt program's rows through ``prompt_rows``,
+    the function the program's own loop takes its trips from."""
+    import jax
+    import jax.numpy as jnp
+
+    from vgate_tpu.models import hybrid
+    from vgate_tpu.models.specs import spec_for_model_id
+
+    spec = spec_for_model_id(model_id)
+    got = rows * int(hybrid.prompt_rows(spec, bucket, max(lens), whole))
+    assert got == worked
+    # the same rule on a traced length gives the loop its trips
+    traced = jax.jit(lambda n: jnp.asarray(
+        hybrid.prompt_rows(spec, bucket, n, whole)))(max(lens))
+    assert rows * int(traced) == worked
+    rec = recorder()
+    assert rec.totals()["prefill"] == {
+        "rows_worked": 0, "rows_real": 0, "rows_padding": 0}
+    real = sum(n for n in lens if n > 1)  # a padding row holds one
+    rec.note_prefill_rows(got, real)
+    rec.note_prefill_rows(got, real)
+    assert rec.totals()["prefill"] == {
+        "rows_worked": 2 * worked, "rows_real": 2 * real,
+        "rows_padding": 2 * (worked - real)}
+    merged = perf_mod.merge_snapshots([rec.snapshot(), rec.snapshot()])
+    assert merged["totals"]["prefill"]["rows_padding"] == 4 * (worked - real)
+
+
 def test_chunk_lengths_are_whatever_the_engine_ran():
     """No fixed ladder: tpu.decode_chunk is configurable, so a 16-step
     chunk counts; a spec-verify pass is steps, not a chunk; the dp merge

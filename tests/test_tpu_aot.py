@@ -637,6 +637,10 @@ def _prompt_program(A, spec, params, pool, v_pool, state, bucket=8192):
     ).compile()
 
 
+def _nbytes(tree):
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
 def _assert_no_buffer(text, rows, width, dtypes=("f32", "bf16", "s32")):
     """No array of ``rows x width`` in the compiled program, whatever
     its type: a temporary that goes by ALL (row, choice) pairs."""
@@ -646,21 +650,15 @@ def _assert_no_buffer(text, rows, width, dtypes=("f32", "bf16", "s32")):
         assert not found, found[0][:300]
 
 
-def test_window_stack_prompt_program_dispatches_held_pairs_on_v5e(v5e):
-    """The K-EXAONE cut's 8,192-row prompt program (four expert layers,
-    8 choices of 128 experts, 16 held): the expert layer runs in two
-    blocks of 4,096 rows and dispatches 8,192 of a block's 32,768 pairs
-    at a time, so nothing in the program is sized by ALL pairs x the
-    hidden width (805 MB in float32 a block), its temporaries stand far
-    under the pool and the rings beside them, and the program fits a
-    chip that holds them and the weights."""
+@pytest.fixture(scope="module")
+def exaone_prompt(v5e):
+    """(the K-EXAONE cut's 8,192-row prompt program compiled for the
+    v5e, the spec, the bytes it holds beside its temporaries, those of
+    them it must update in place)."""
     from vgate_tpu.models.hybrid import make_state
-    from vgate_tpu.ops import moe
 
     A = _abstract(v5e)
     spec, params = _cut_and_shapes(A, *EXAONE_CUT)
-    assert moe.block_tokens(spec) == 4096
-    assert moe.capacity(spec, 4096 * 8) == 8192
     slots = 192
     state = jax.tree.map(
         lambda x: A(x.shape, x.dtype),
@@ -668,14 +666,41 @@ def test_window_stack_prompt_program_dispatches_held_pairs_on_v5e(v5e):
     pages = 36000  # 4.7 GB of K+V: the cell's pool of the one full layer
     pool = A((spec.attn_layers, spec.num_kv_heads, pages, PAGE,
               spec.head_dim), jnp.bfloat16)
-    nbytes = lambda tree: sum(
-        x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
-    held = nbytes((params, state, pool, pool))
-    compiled = _prompt_program(A, spec, params, pool, pool, state)
+    return (_prompt_program(A, spec, params, pool, pool, state), spec,
+            _nbytes((params, state, pool, pool)),
+            _nbytes((state, pool, pool)))
+
+
+@pytest.fixture(scope="module")
+def mistral_prompt(v5e):
+    """The same of the Mistral-Small-4 cut's 8,192-row prompt program."""
+    A = _abstract(v5e)
+    spec, params = _cut_and_shapes(A, *MISTRAL_CUT)
+    pages = 65537  # 6.44 GB: the cell's latent pool
+    pool = A((spec.attn_layers, spec.cache_heads, pages, PAGE,
+              spec.cache_head_dim), jnp.bfloat16)
+    return (_prompt_program(A, spec, params, pool, None, None), spec,
+            _nbytes((params, pool)), _nbytes(pool))
+
+
+def test_window_stack_prompt_program_dispatches_held_pairs_on_v5e(
+        exaone_prompt):
+    """The K-EXAONE cut's 8,192-row prompt program (four expert layers,
+    8 choices of 128 experts, 16 held): the expert layer runs in two
+    blocks of 4,096 rows and dispatches 8,192 of a block's 32,768 pairs
+    at a time, so nothing in the program is sized by ALL pairs x the
+    hidden width (805 MB in float32 a block), its temporaries stand far
+    under the pool and the rings beside them, and the program fits a
+    chip that holds them and the weights."""
+    from vgate_tpu.ops import moe
+
+    compiled, spec, held, in_place = exaone_prompt
+    assert moe.block_tokens(spec) == 4096
+    assert moe.capacity(spec, 4096 * 8) == 8192
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= nbytes((state, pool, pool)), (
+    assert mem.alias_size_in_bytes >= in_place, (
         "the pool or the rings are copied")
-    # 1.62 GB at the parent's 1,024-row blocks (PERF.md section 4)
+    # 1.62 GB at PR 39, 1.18 at PR 41, 1.47 since PR 42 (PERF.md section 4)
     assert mem.temp_size_in_bytes < 1.7e9, mem.temp_size_in_bytes
     assert held + mem.temp_size_in_bytes < 15.9e9  # of the chip's 16.9 GB
     text = compiled.as_text()
@@ -685,26 +710,19 @@ def test_window_stack_prompt_program_dispatches_held_pairs_on_v5e(v5e):
         _assert_no_buffer(text, pairs, spec.expert_width)
 
 
-def test_latent_prompt_program_dispatches_held_pairs_on_v5e(v5e):
+def test_latent_prompt_program_dispatches_held_pairs_on_v5e(mistral_prompt):
     """The Mistral-Small-4 cut's 8,192-row prompt program (4 choices of
     128 experts, 32 held): ONE block, 16,384 of its 32,768 pairs at a
     time."""
     from vgate_tpu.ops import moe
 
-    A = _abstract(v5e)
-    spec, params = _cut_and_shapes(A, *MISTRAL_CUT)
+    compiled, spec, held, pool_bytes = mistral_prompt
     assert moe.block_tokens(spec) == 8192
     assert moe.capacity(spec, 8192 * 4) == 16384
-    pages = 65537  # 6.44 GB: the cell's latent pool
-    pool = A((spec.attn_layers, spec.cache_heads, pages, PAGE,
-              spec.cache_head_dim), jnp.bfloat16)
-    pool_bytes = pool.size * 2
-    compiled = _prompt_program(A, spec, params, pool, None, None)
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes, "the pool is copied"
     assert mem.temp_size_in_bytes < pool_bytes // 4
-    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
-    assert weights + pool_bytes + mem.temp_size_in_bytes < 15.9e9
+    assert held + mem.temp_size_in_bytes < 15.9e9
     text = compiled.as_text()
     _assert_no_buffer(text, 8192 * 4, spec.expert_width)
     # bf16[32768, 4096] is the embedding table
@@ -732,10 +750,6 @@ def _glm_cut(A):
     assert pool.shape[0] == 5 and pool.shape[-1] == 640
     assert keys.shape[0] == 2 and keys.shape[-1] == 128
     return spec, params, pool, keys
-
-
-def _nbytes(tree):
-    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
 
 def test_selection_decode_chunk_compiles_on_v5e(v5e):
@@ -866,23 +880,28 @@ def test_the_fetching_decode_kernel_compiles_at_the_cells_widths_on_v5e(v5e):
     assert "dsa_decode_attention_pallas" in compiled.as_text()
 
 
-def test_selection_prompt_program_fits_beside_the_pool_on_v5e(v5e):
+@pytest.fixture(scope="module")
+def glm_prompt(v5e):
+    """The same of the GLM-5.2 cut's 16,384-row prompt program."""
+    A = _abstract(v5e)
+    spec, params, pool, keys = _glm_cut(A)
+    return (_prompt_program(A, spec, params, pool, keys, None,
+                            bucket=GLM_CTX), spec,
+            _nbytes((params, pool, keys)), _nbytes((pool, keys)))
+
+
+def test_selection_prompt_program_fits_beside_the_pool_on_v5e(glm_prompt):
     """The 16,384-row prompt program of the cut: the scoring kernel, the
     flash kernel under a mask and the page writer of the pool by pairs
     in it, both pool arrays aliased (no scatter re-lays the pool), no
     [16,384, 16,384] float32 scores and no [16,384, 12,288] activation
     of the dense layer in the HLO, and the temporaries small enough
     beside 7.76 GB of weights and 5.44 GB of pages."""
-    A = _abstract(v5e)
-    spec, params, pool, keys = _glm_cut(A)
-    compiled = _prompt_program(A, spec, params, pool, keys, None,
-                               bucket=GLM_CTX)
+    compiled, spec, held, pools = glm_prompt
     mem = compiled.memory_analysis()
-    held = _nbytes((params, pool, keys))
     assert 13.1e9 < held < 13.3e9
-    assert mem.alias_size_in_bytes >= _nbytes((pool, keys)), (
-        "a pool is copied")
-    # 2.48 GB with groups of 8 heads (PERF.md section 4)
+    assert mem.alias_size_in_bytes >= pools, "a pool is copied"
+    # 2.48 GB with groups of 8 heads, 2.44 since PR 42 (PERF.md section 4)
     assert mem.temp_size_in_bytes < 2.7e9, mem.temp_size_in_bytes
     assert held + mem.temp_size_in_bytes < 15.9e9  # of the chip's 16.9 GB
     text = compiled.as_text()
@@ -894,3 +913,53 @@ def test_selection_prompt_program_fits_beside_the_pool_on_v5e(v5e):
     _assert_no_buffer(text, f"1,{GLM_CTX}", spec.intermediate_size)
     # the selection itself stands once, as bytes
     assert f"s8[1,{GLM_CTX},{GLM_CTX}]" in text
+
+
+def _counted_loops(text, scope):
+    """The ``while`` of the compiled program traced under ``scope``
+    whose condition holds no constant: its trips are an operand."""
+    import re
+
+    found = 0
+    for line in text.splitlines():
+        at = re.search(r" while\(.*condition=%([\w.\-]+)", line)
+        if not at or f'{scope}/while"' not in line:
+            continue
+        start = text.index(f"\n%{at.group(1)} (")
+        body = text[start:text.index("\n}", start)]
+        found += "compare(" in body and " constant(" not in body
+    return found
+
+
+# temporary bytes of the parent's (PR 41, commit 62289a6) prompt programs
+# by the same compile: what the whole bucket at once takes
+PARENT_TEMP_BYTES = {"exaone": 1_182_179_328, "mistral": 813_282_304,
+                     "glm": 2_481_588_736}
+
+
+@pytest.mark.parametrize("cell, scopes", [
+    ("exaone", ("swa_attn", "full_attn", "dense_mlp", "moe_route",
+                "shared_expert")),
+    ("mistral", ("mla_attn", "moe_route", "shared_expert")),
+    ("glm", ("dsa_prompt", "dense_mlp", "moe_route", "shared_expert")),
+])
+def test_long_prompt_programs_loop_over_their_row_blocks_on_v5e(
+        cell, scopes, request):
+    """The three long cells' prompt programs (8,192 rows; 16,384 the
+    GLM cut's) hold, around the projections of every kind of sub-block,
+    a ``while`` whose trips are an operand (the blocks of 1,024 rows the
+    prompt reaches, models/hybrid.py ``_by_row_blocks``), and count
+    their temporaries against the parent's: as many in the two latent
+    stacks (0.814 against 0.813 GB, 2.44 against 2.48), 0.29 GB more in
+    the window stack, where a layer's matrices, operands of a nested
+    loop, stand as copies and the attention's result is re-laid by rows
+    ahead of its projection (PERF.md section 7)."""
+    compiled, _, held, _ = request.getfixturevalue(f"{cell}_prompt")
+    text = compiled.as_text()
+    for scope in scopes:
+        assert _counted_loops(text, scope), scope
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    parent = PARENT_TEMP_BYTES[cell]
+    assert temp <= parent + (320 << 20), (
+        f"{cell}: {temp} temporary bytes against the parent's {parent}")
+    assert held + temp < 15.9e9

@@ -434,8 +434,10 @@ def _swa_prefill_attend(spec: ModelSpec, impl: str, seq_lens):
             swa_prefill_attention_pallas,
         )
 
+        # nothing reads a padding row's attention: their query blocks
+        # are left out
         return lambda q, k, v: swa_prefill_attention_pallas(
-            q, k, v, seq_lens, spec.sliding_window)
+            q, k, v, seq_lens, spec.sliding_window, skip_padding=True)
     return lambda q, k, v: flash_prefill_attention(
         q, k, v, seq_lens, window=spec.sliding_window)
 
@@ -578,10 +580,14 @@ def prefill_forward(
     if spec.is_hybrid:
         from vgate_tpu.models import hybrid
 
+        # the kernel leaves out the query blocks of padding rows, which
+        # this pass never reads
+        skip = {"skip_padding": True} if impl == "pallas" else {}
         x, k_pages, v_pages, state = hybrid.prompt_forward(
             params, spec, x, seq_lens, positions, k_pages, v_pages, state,
             slots, jnp.ones((B,), bool), page_tables,
-            lambda q, k, v, kp, vp, layer: attn_fn(q, k, v, seq_lens),
+            lambda q, k, v, kp, vp, layer: attn_fn(
+                q, k, v, seq_lens, **skip),
             use_pallas,
             swa_attend=_swa_prefill_attend(spec, impl, seq_lens),
             dsa_attend=(_dsa_prefill_attend(spec, impl, seq_lens, S)

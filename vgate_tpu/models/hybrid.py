@@ -69,7 +69,14 @@ differs:
   page: a scatter that hits one ring page twice has no defined order);
   a later chunk reads the ring BEFORE it writes, beside its own rows;
 * the experts' matrices never ride the scan's per-period slices: the
-  grouped product's kernel takes the full stack and a layer index.
+  grouped product's kernel takes the full stack and a layer index;
+* a long whole prompt's pass (a bucket of two ``PROMPT_ROW_BLOCK`` or
+  more) does its position-wise work in a counted loop over the blocks
+  of rows its longest prompt reaches (``_by_row_blocks``), and its
+  attention kernels skip the query blocks past them: a 5,500-token
+  prompt in the 8,192 bucket pays for 6,144 rows.  What engages it is
+  the traced shape alone; a decode step, a short wave, a suffix and a
+  chunk are traced as ever.
 
 Parameters (``init_layers``): ``layers = {group: {name: [P, n, ...]}}``
 with ``P`` periods and ``n`` layers of the group a period.  Qwen3-Next's
@@ -87,6 +94,7 @@ from __future__ import annotations
 
 import functools
 import math
+from collections.abc import Mapping
 from typing import Any, Dict
 
 import jax
@@ -541,6 +549,60 @@ def ring_tables(slots, n_pages: int, rings: int, R: int, first=None,
     return jnp.where(keep, 1 + slots * R + p % R, 0)
 
 
+# rows of a long prompt program that a position-wise sub-block takes at
+# a time: the flash kernel's query block at those buckets and a whole
+# row tile of the grouped product, so that a block is either real rows
+# (and a bucket's padding in the last one) or nothing
+PROMPT_ROW_BLOCK = 1024
+
+
+def prompt_rows(spec: ModelSpec, S: int, longest, whole: bool = True):
+    """Of the ``S`` rows a sequence has in a prompt program, how many
+    the position-wise sub-blocks work on when its longest prompt holds
+    ``longest`` tokens (an int on the host, a traced scalar in the
+    program: ONE rule for both).  ``S`` itself, as an int, where the
+    program is traced as it always was: a stack of one kind
+    (models/decoder.py's own pass), under two blocks of rows, or a
+    suffix or a chunk (not the ``whole`` prompt)."""
+    R = PROMPT_ROW_BLOCK
+    if not (spec.is_hybrid and whole and S >= 2 * R and S % R == 0):
+        return S
+    return cdiv(longest, R) * R
+
+
+def _as_is(rows):
+    """The norm of rows that come normed."""
+    return rows
+
+
+def _by_row_blocks(fn, rows, n_rows, axis: int = 1):
+    """``fn`` (position-wise along ``axis``: arrays ``[B, S, ...]``, or
+    None, -> a tree of arrays ``[B, S, ...]``) over the blocks of
+    ``PROMPT_ROW_BLOCK`` rows that hold one of the first ``n_rows`` (a
+    traced scalar), in a counted loop: a prompt that fills two thirds of
+    its bucket pays for two thirds of the rows.  The rows of the blocks
+    past them come out zero.  ``n_rows`` None: ``fn(*rows)``."""
+    if n_rows is None:
+        return fn(*rows)
+    R = PROMPT_ROW_BLOCK
+    S = rows[0].shape[axis]
+    take = lambda i: jax.tree.map(
+        lambda t: jax.lax.dynamic_slice_in_dim(t, i * R, R, axis),
+        list(rows))
+    out = jax.tree.map(
+        lambda s: jnp.zeros(
+            s.shape[:axis] + (S,) + s.shape[axis + 1:], s.dtype),
+        jax.eval_shape(fn, *take(0)))
+
+    def block(i, out):
+        return jax.tree.map(
+            lambda o, part: jax.lax.dynamic_update_slice_in_dim(
+                o, part, i * R, axis),
+            out, fn(*take(i)))
+
+    return jax.lax.fori_loop(0, cdiv(n_rows, R), block, out)
+
+
 def _rope(x, positions, spec: ModelSpec, rotate: bool = True):
     if not (spec.use_rope and rotate):
         return x
@@ -575,12 +637,22 @@ def _gated_qkv(normed, lp, spec: ModelSpec, positions, rotate: bool = True):
             _rope(k, positions, spec, rotate), v, gate)
 
 
+def _heads_flat(t):
+    """[..., H, hd] -> [..., H x hd] (None stays None).  Ahead of a loop
+    over blocks of rows: the attention kernels leave their result
+    head-major, and a block of rows cut from THAT is 64 strided pieces
+    the output projection reads at half its pace (27.1 ms a K-EXAONE
+    prompt program against 19.5 for the whole bucket; chip, PR 42)."""
+    return None if t is None else t.reshape(*t.shape[:-2], -1)
+
+
 @jax.named_scope("o_proj")
 def _gated_out(attn, gate, lp, dtype):
+    """The output side of an attention sub-block on ``_heads_flat`` rows
+    (and their gate's)."""
     if gate is not None:
         attn = (attn.astype(jnp.float32)
                 * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dtype)
-    attn = attn.reshape(*attn.shape[:-2], -1)
     return jnp.einsum("...h,hd->...d", attn, lp["o"]["w"])
 
 
@@ -830,7 +902,7 @@ def _mla_expand(rows, lp, spec: ModelSpec):
 
 @jax.named_scope("o_proj")
 def _mla_out(attn, lp):
-    attn = attn.reshape(*attn.shape[:-2], -1)
+    """On ``_heads_flat`` rows."""
     return jnp.einsum("...h,hd->...d", attn, lp["o"]["w"])
 
 
@@ -847,25 +919,34 @@ def _write_latent_pages(kp, tables, rows, layer, kernel: bool):
 
 def _mla_prompt(normed, lp, spec: ModelSpec, positions, kp, vp, index,
                 write_tables, ctx_tables, attend, cq=None,
-                kernel: bool = False):
+                kernel: bool = False, n_rows=None, norm=_as_is):
     """Latent attention over prompt rows normed [B, S, D]: the rows'
     latent goes to the pool (whole pages), K and V are expanded from the
     prompt's own rows or, for a suffix against a cached prefix
     (``ctx_tables``), from the pool's rows of the whole context, and are
-    never cached."""
+    never cached.  ``n_rows``: ``_by_row_blocks``, whose blocks come
+    un-normed where the caller hands their ``norm``."""
     B, S = normed.shape[:2]
     ps, width = page_tokens(kp), kp.shape[-1]
-    q = jnp.concatenate(_mla_q(normed, lp, spec, positions, cq), axis=-1)
-    rows = _mla_latent(normed, lp, spec, positions, width)
+    by_rows = lambda fn, *rows: _by_row_blocks(fn, rows, n_rows)
+
+    def front(normed, positions, *cq):
+        normed = norm(normed)
+        q = jnp.concatenate(
+            _mla_q(normed, lp, spec, positions, *cq), axis=-1)
+        return q, _mla_latent(normed, lp, spec, positions, width)
+
+    q, rows = by_rows(front, normed, positions,
+                      *(() if cq is None else (cq,)))
     kp = _write_latent_pages(
         kp, write_tables[:, :S // ps],
         rows.reshape(B, S // ps, 1, ps, width), index, kernel)
     if ctx_tables is not None:
         rows = mla_gather_rows(kp, ctx_tables, index)
-    k, v = _mla_expand(rows, lp, spec)
+    k, v = by_rows(lambda rows: _mla_expand(rows, lp, spec), rows)
     with jax.named_scope("attention"):
         attn = attend(q, k, v, kp, vp, index)
-    return _mla_out(attn, lp), kp
+    return by_rows(lambda attn: _mla_out(attn, lp), _heads_flat(attn)), kp
 
 
 def _mla_step(normed, lp, spec: ModelSpec, positions, kp, vp, index,
@@ -886,7 +967,7 @@ def _mla_step(normed, lp, spec: ModelSpec, positions, kp, vp, index,
     attn, kp, vp = write_attend(q_abs, row, None, kp, vp, index)
     with jax.named_scope("mla_absorb"):
         attn = jnp.einsum("bhk,khv->bhv", attn, lp["kv_b_v"]["w"])
-    return _mla_out(attn, lp), kp, vp
+    return _mla_out(_heads_flat(attn), lp), kp, vp
 
 
 def _index_rotate(t, positions, spec: ModelSpec):
@@ -940,37 +1021,47 @@ def _dsa_prompt_mask(scores, live, topk: int):
 
 
 def _dsa_prompt_select(normed, cq, lp, keys, positions, total_lens,
-                       spec: ModelSpec, kernel: bool):
+                       spec: ModelSpec, kernel: bool, n_rows=None,
+                       norm=_as_is):
     """The selection of prompt rows (normed [B, S, D] with their query
     latent, at ``positions`` [B, S]) over the context's index keys [B, T,
     di] (from position 0) as a mask [B, S, T] int8: nonzero where the
     row attends.  ``kernel``: a whole prompt's rows against their own
     keys, a block of rows at a time (its index queries, its scores
     through the scoring kernel, its threshold), so that neither an [S,
-    S] float32 array nor all rows' index queries stand."""
+    S] float32 array nor all rows' index queries stand; with ``n_rows``
+    (``_by_row_blocks``) the blocks of real rows alone, the others'
+    mask zero."""
     B, S = normed.shape[:2]
     T = keys.shape[1]
     if kernel and S % DSA_SCORE_ROWS == 0:
         from vgate_tpu.ops.pallas.dsa import dsa_prompt_scores_pallas
 
-        R = DSA_SCORE_ROWS
-        blocks = lambda t, b: t[b].reshape((S // R, R) + t.shape[2:])
-
-        def block(xs, b):
-            rows, cq_rows, pos = xs
-            qi, w = _dsa_index_query(rows, cq_rows, lp, spec, pos)
+        def block(rows, cq_rows, pos, b):
+            qi, w = _dsa_index_query(norm(rows), cq_rows, lp, spec, pos)
             with jax.named_scope("dsa_index"):
                 scores = dsa_prompt_scores_pallas(qi, w, keys[b], pos[0])
             return _dsa_prompt_mask(scores, scores > dsa.NEG_INF,
                                     spec.index_topk)
 
+        if n_rows is not None:
+            return jnp.stack([
+                _by_row_blocks(
+                    functools.partial(block, b=b),
+                    (normed[b], cq[b], positions[b]), n_rows, axis=0)
+                for b in range(B)])
+        R = DSA_SCORE_ROWS
+        blocks = lambda t, b: t[b].reshape((S // R, R) + t.shape[2:])
         return jnp.stack([
             jax.lax.map(
-                functools.partial(block, b=b),
+                lambda xs, b=b: block(*xs, b),
                 (blocks(normed, b), blocks(cq, b), blocks(positions, b)),
             ).reshape(S, T)
             for b in range(B)])
-    qi, w = _dsa_index_query(normed, cq, lp, spec, positions)
+    qi, w = _by_row_blocks(
+        lambda rows, cq, positions: _dsa_index_query(
+            norm(rows), cq, lp, spec, positions),
+        (normed, cq, positions), n_rows)
     k_pos = jnp.arange(T)[None, None, :]
     live = ((k_pos <= positions[..., None])
             & (k_pos < total_lens[:, None, None]))
@@ -999,7 +1090,7 @@ def _latent_layer(spec: ModelSpec, index, picks: bool):
 
 def _dsa_prompt(normed, lp, spec: ModelSpec, positions, kp, vp, st, index,
                 picks: bool, write_tables, ctx_tables, total_lens, attend,
-                attend_selected, kernel: bool):
+                attend_selected, kernel: bool, n_rows=None, norm=_as_is):
     """Latent attention under a selection over prompt rows normed [B, S,
     D] (``_mla_prompt``'s pass: the rows' latent to the pool, K and V
     expanded and never cached).  A layer that ``picks`` writes the rows'
@@ -1008,30 +1099,35 @@ def _dsa_prompt(normed, lp, spec: ModelSpec, positions, kp, vp, st, index,
     attention, as a reusing layer's, runs under that mask
     (``attend_selected(q, k, v, mask)``), a group of heads at a time
     where the expansion is large.  A context of at most ``index_topk``
-    tokens is attended whole: ``_mla_prompt`` with ``attend``."""
+    tokens is attended whole: ``_mla_prompt`` with ``attend``.
+    ``n_rows``: ``_by_row_blocks``, whose blocks come un-normed where
+    the caller hands their ``norm``."""
     B, S = normed.shape[:2]
     ps, width = page_tokens(kp), kp.shape[-1]
     layer = _latent_layer(spec, index, picks)
     T = S if ctx_tables is None else ctx_tables.shape[1] * ps
-    cq = _mla_cq(normed, lp, spec)
+    by_rows = lambda fn, *rows: _by_row_blocks(fn, rows, n_rows)
+    cq = by_rows(lambda rows: _mla_cq(norm(rows), lp, spec), normed)
     if picks:
-        key = _dsa_index_key(normed, lp, spec, positions)
+        key = by_rows(lambda rows, positions: _dsa_index_key(
+            norm(rows), lp, spec, positions), normed, positions)
         vp = kv_write_pages(
             vp, write_tables[:, :S // ps],
             key.reshape(B, S // ps, 1, ps, key.shape[-1]), layer=index)
     if T <= spec.index_topk:  # nothing to leave out
         out, kp = _mla_prompt(normed, lp, spec, positions, kp, None, layer,
                               write_tables, ctx_tables, attend, cq=cq,
-                              kernel=kernel)
+                              kernel=kernel, n_rows=n_rows, norm=norm)
         return out, kp, vp, st
     if picks:
         keys = key if ctx_tables is None else mla_gather_rows(
             vp, ctx_tables, index)
         st = {**st, "sel": _dsa_prompt_select(
             normed, cq, lp, keys, positions, total_lens, spec,
-            kernel and ctx_tables is None)}
+            kernel and ctx_tables is None, n_rows, norm)}
     mask = st["sel"]
-    rows = _mla_latent(normed, lp, spec, positions, width)
+    rows = by_rows(lambda rows, positions: _mla_latent(
+        norm(rows), lp, spec, positions, width), normed, positions)
     kp = _write_latent_pages(
         kp, write_tables[:, :S // ps],
         rows.reshape(B, S // ps, 1, ps, width), layer, kernel)
@@ -1051,23 +1147,27 @@ def _dsa_prompt(normed, lp, spec: ModelSpec, positions, kp, vp, st, index,
 
     def group(acc, ws):
         q_b, w_uk, w_uv, w_o = ws
-        q = jnp.concatenate(_mla_q(
+        q = by_rows(lambda cq, positions: jnp.concatenate(_mla_q(
             None, lp, spec, positions, cq=cq,
-            q_b=q_b.reshape(q_b.shape[0], -1)), axis=-1)
-        k, v = _mla_expand(rows, {"kv_b_k": {"w": w_uk},
-                                  "kv_b_v": {"w": w_uv}}, spec)
+            q_b=q_b.reshape(q_b.shape[0], -1)), axis=-1), cq, positions)
+        k, v = by_rows(lambda rows: _mla_expand(
+            rows, {"kv_b_k": {"w": w_uk}, "kv_b_v": {"w": w_uv}}, spec),
+            rows)
         with jax.named_scope("dsa_attend"):
             attn = attend_selected(q, k, v, mask)
-        with jax.named_scope("o_proj"):
-            out = jnp.einsum("bsh,hd->bsd", attn.reshape(B, S, -1), w_o,
-                             preferred_element_type=jnp.float32)
-        return acc + out, None
 
+        @jax.named_scope("o_proj")
+        def o_proj(acc, attn):
+            return acc + jnp.einsum("bsh,hd->bsd", attn, w_o,
+                                    preferred_element_type=jnp.float32)
+
+        return by_rows(o_proj, acc, _heads_flat(attn)), None
+
+    zero = jnp.zeros(normed.shape, jnp.float32)
     if G == 1:
-        out, _ = group(0.0, jax.tree.map(lambda a: a[0], weights))
+        out, _ = group(zero, jax.tree.map(lambda a: a[0], weights))
     else:
-        out, _ = jax.lax.scan(
-            group, jnp.zeros(normed.shape, jnp.float32), weights)
+        out, _ = jax.lax.scan(group, zero, weights)
     return out.astype(normed.dtype), kp, vp, st
 
 
@@ -1096,6 +1196,34 @@ def _dsa_step(normed, lp, spec: ModelSpec, positions, kp, vp, st, index,
     return out, kp, vp, st
 
 
+class _LayerTensors(Mapping):
+    """ONE layer's tensors out of its group's stacked ``[P, n, ...]``
+    ones, each sliced where it is read.  A matrix that a loop over
+    blocks of rows (``_by_row_blocks``) reads is an operand of that
+    loop, and XLA holds an operand as a copy (it lifts the slice out of
+    the loop's body however late it is taken).  A scan's own slices are
+    taken at the top of its body, every matrix of the layer at once
+    (0.3 GB of a K-EXAONE window layer, live through all its loops);
+    sliced here, one stands while its loop runs: the 8,192-row program's
+    temporaries 1.53 -> 1.42 GB, the GLM cut's 16,384-row one's 2.90 ->
+    2.62 (compiled for the v5e, PR 42)."""
+
+    def __init__(self, tree, index):
+        self._tree, self._index = tree, index
+
+    def __getitem__(self, name):
+        value = self._tree[name]
+        if isinstance(value, dict):
+            return _LayerTensors(value, self._index)
+        return value[self._index]
+
+    def __iter__(self):
+        return iter(self._tree)
+
+    def __len__(self):
+        return len(self._tree)
+
+
 def _segments(blocks):
     """A period's sub-blocks as runs ``(unit, repeats)``: the longest
     run of a repeated unit of up to four sub-blocks at each place, else
@@ -1120,7 +1248,7 @@ def _segments(blocks):
 
 
 def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
-                 block_fn):
+                 block_fn, slice_late: bool = False):
     """THE stack walker: the leading layers once (a ``window_pattern``
     spec's ``layers["lead"]``, each its own tensors), then a scan over
     periods, each the spec's sub-blocks in order.  ``block_fn(kind,
@@ -1131,7 +1259,10 @@ def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
     recurrent kind, the rings' for ``swa``); for ``moe`` it indexes
     ``stack``, the expert matrices of the layer's group ``[layers, E, .,
     .]`` (a leading layer's own, ``[1, E, ., .]``), which stay outside
-    the scanned slices; ``lp`` is the layer's tensors without them.
+    the scanned slices; ``lp`` is the layer's tensors without them:
+    the scans' own slices or, with ``slice_late`` (a pass that loops
+    over blocks of rows), ``_LayerTensors``, and ``block_fn`` is then
+    handed the rows as they are and their ``norm`` to apply.
     Returns (x, k_pages, v_pages, state, stats [4])."""
     layers = dict(params["layers"])
     lead = layers.pop("lead", ())
@@ -1150,9 +1281,13 @@ def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
     def run(carry, block, lp, index, stack):
         kind, _, norm, _ = block
         h, kp, vp, st = carry
-        normed = rms_norm(h, lp[norm], spec.rms_eps, uo)
-        out, kp, vp, st, stats = block_fn(
-            kind, normed, lp, kp, vp, st, index, stack)
+        normed = lambda h: rms_norm(h, lp[norm], spec.rms_eps, uo)
+        if slice_late:  # the norm too: in each loop over blocks of rows
+            out, kp, vp, st, stats = block_fn(
+                kind, h, lp, kp, vp, st, index, stack, norm=normed)
+        else:
+            out, kp, vp, st, stats = block_fn(
+                kind, normed(h), lp, kp, vp, st, index, stack)
         return (h + out.astype(h.dtype), kp, vp, st), stats
 
     # the leading layers, unrolled: layer i's mixer is the i-th of its
@@ -1187,7 +1322,11 @@ def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
                 stats = []
                 for b in unit:
                     g, local = b[1], b[3] - first[b[1]]
-                    lp = jax.tree.map(lambda a: a[local], lps[g])
+                    if slice_late:
+                        lp = _LayerTensors(
+                            light[g], (p, first[g] + j * width[g] + local))
+                    else:
+                        lp = jax.tree.map(lambda a: a[local], lps[g])
                     index = p * count[g] + first[g] + j * width[g] + local
                     if base.get(b[0]):  # behind the leading layers' own
                         index = base[b[0]] + index
@@ -1199,7 +1338,7 @@ def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
 
             # the unit's repeats as an inner scan: one body traced,
             # lowered and compiled for all of them
-            lps = {g: jax.tree.map(
+            lps = {} if slice_late else {g: jax.tree.map(
                 lambda a: a[first[g]:first[g] + repeats * width[g]].reshape(
                     (repeats, width[g]) + a.shape[1:]), per[g])
                 for g in first}
@@ -1219,7 +1358,8 @@ def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
 
     (x, k_pages, v_pages, state), stats = jax.lax.scan(
         period, carry,
-        (light, jnp.arange(spec.num_periods, dtype=jnp.int32)),
+        ({} if slice_late else light,
+         jnp.arange(spec.num_periods, dtype=jnp.int32)),
     )
     stats = stats.reshape(-1, len(STAT_NAMES))
     if lead_stats:
@@ -1228,12 +1368,12 @@ def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
 
 
 def _experts(normed, lp, spec: ModelSpec, row_mask, use_pallas, index,
-             stack):
+             stack, by_rows=None):
     from vgate_tpu.models.decoder import _act
 
     return expert_layer(
         normed, lp, spec, lambda x32: _act(x32, spec), row_mask=row_mask,
-        use_pallas=use_pallas, layer=index, stack=stack,
+        use_pallas=use_pallas, layer=index, stack=stack, by_rows=by_rows,
     )
 
 
@@ -1242,11 +1382,17 @@ DENSE_BLOCK_ELEMS = 8192 * 18432
 
 
 @jax.named_scope("dense_mlp")
-def _dense(normed, lp, spec: ModelSpec):
+def _dense(normed, lp, spec: ModelSpec, n_rows=None, norm=_as_is):
     """The dense SwiGLU; a long prompt's rows [B, S, D] in blocks, so
-    that the float32 gate of 16,384 rows never stands whole."""
+    that the float32 gate of 16,384 rows never stands whole: the blocks
+    of real rows (``n_rows``: ``_by_row_blocks``, which may ``norm`` the
+    block it reads), or all of them."""
     from vgate_tpu.models.decoder import _dense_mlp
 
+    if n_rows is not None:
+        return _by_row_blocks(
+            lambda rows: _dense_mlp(norm(rows), lp, spec), (normed,),
+            n_rows)
     S = normed.shape[-2]
     blocks = 1
     while (S // blocks * spec.intermediate_size > DENSE_BLOCK_ELEMS
@@ -1323,6 +1469,12 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
     KV, hd = spec.cache_heads, spec.cache_head_dim
     n_pages = S // ps
     row_mask = jnp.arange(S)[None, :] < lens[:, None]
+    # a long whole prompt: the position-wise work in blocks of rows, as
+    # far as the longest prompt reaches (None: the whole bucket at once)
+    n_rows = prompt_rows(spec, S, jnp.max(lens),
+                         prefix_lens is None and ctx_tables is None)
+    n_rows = None if isinstance(n_rows, int) else n_rows
+    by_rows = lambda fn, *rows: _by_row_blocks(fn, rows, n_rows)
     to_pages = lambda t: jnp.transpose(
         t.reshape(B, n_pages, ps, KV, hd), (0, 1, 3, 2, 4))
     if spec.swa_layers:
@@ -1333,16 +1485,21 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
             first=None if prefix_lens is None else prefix_lens // ps,
             last=(start + lens - 1) // ps)
 
-    def block_fn(kind, normed, lp, kp, vp, st, index, stack):
-        if kind == "moe":
-            out, stats = _experts(normed, lp, spec, row_mask, use_pallas,
-                                  index, stack)
+    def block_fn(kind, normed, lp, kp, vp, st, index, stack, norm=_as_is):
+        # ``norm``: the rows come as the stream holds them, and a loop
+        # over blocks of rows norms the block it reads
+        if kind == "moe":  # gathers its rows: the normed rows stand
+            out, stats = _experts(
+                by_rows(norm, normed), lp, spec, row_mask, use_pallas,
+                index, stack, None if n_rows is None else _by_row_blocks)
             return out, kp, vp, st, stats
         if kind == "mlp":
-            return _dense(normed, lp, spec), kp, vp, st, None
+            return (_dense(normed, lp, spec, n_rows, norm), kp, vp, st,
+                    None)
         if kind in _RECURRENT:
-            out, st = _recurrent_prompt(kind, normed, lp, st, index, spec,
-                                        lens, slots, fresh)
+            out, st = _recurrent_prompt(
+                kind, by_rows(norm, normed), lp, st, index, spec, lens,
+                slots, fresh)
             return out, kp, vp, st, None
         if kind in ("mla", "dsa") and spec.is_dsa:
             with jax.named_scope("mla_attn"), jax.named_scope("dsa_prompt"):
@@ -1350,17 +1507,23 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
                     normed, lp, spec, positions, kp, vp, st, index,
                     kind == "dsa", write_tables, ctx_tables,
                     lens if total_lens is None else total_lens, attend,
-                    dsa_attend, use_pallas)
+                    dsa_attend, use_pallas, n_rows, norm)
             return out, kp, vp, st, None
         if kind == "mla":
             with jax.named_scope("mla_attn"):
                 out, kp = _mla_prompt(normed, lp, spec, positions, kp, vp,
                                       index, write_tables, ctx_tables,
-                                      attend)
+                                      attend, n_rows=n_rows, norm=norm)
             return out, kp, vp, st, None
+        qkv = lambda rotate: by_rows(
+            lambda rows, positions: _gated_qkv(
+                norm(rows), lp, spec, positions, rotate), normed, positions)
+        o_proj = lambda attn, gate: by_rows(
+            lambda attn, gate: _gated_out(attn, gate, lp, normed.dtype),
+            _heads_flat(attn), _heads_flat(gate))
         if kind == "swa":
             with jax.named_scope("swa_attn"):
-                q, k, v, _ = _gated_qkv(normed, lp, spec, positions)
+                q, k, v, _ = qkv(True)
                 rk, rv = st["ring_k"], st["ring_v"]
                 with jax.named_scope("attention"):
                     attn = (swa_attend(q, k, v) if prefix_lens is None
@@ -1372,17 +1535,16 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
                           rk, ring_write, to_pages(k), layer=index),
                       "ring_v": kv_write_pages(
                           rv, ring_write, to_pages(v), layer=index)}
-                out = _gated_out(attn, None, lp, normed.dtype)
+                out = o_proj(attn, None)
             return out, kp, vp, st, None
         with jax.named_scope(_attn_scope(spec)):
-            q, k, v, gate = _gated_qkv(normed, lp, spec, positions,
-                                       spec.global_rope)
+            q, k, v, gate = qkv(spec.global_rope)
             pt = write_tables[:, :n_pages]
             kp = kv_write_pages(kp, pt, to_pages(k), layer=index)
             vp = kv_write_pages(vp, pt, to_pages(v), layer=index)
             with jax.named_scope("attention"):
                 attn = attend(q, k, v, kp, vp, index)
-            out = _gated_out(attn, gate, lp, normed.dtype)
+            out = o_proj(attn, gate)
         return out, kp, vp, st, None
 
     if spec.is_dsa:  # the selection rides the carry: a mask [B, S, T]
@@ -1390,7 +1552,8 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
         state = {**(state or {}), "sel": jnp.zeros(
             (B, S, T) if T > spec.index_topk else (B, 1, 1), jnp.int8)}
     x, k_pages, v_pages, state, _stats = _period_scan(
-        params, spec, x, k_pages, v_pages, state, block_fn)
+        params, spec, x, k_pages, v_pages, state, block_fn,
+        slice_late=n_rows is not None)
     return x, k_pages, v_pages, _without_selection(state)
 
 
@@ -1412,7 +1575,8 @@ def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
             normed[:, None], lp, spec, positions[:, None], rotate)
         attn, k_cache, v_cache = cache_step(
             q[:, 0], k[:, 0], v[:, 0], k_cache, v_cache, index)
-        out = _gated_out(attn, None if gate is None else gate[:, 0],
+        out = _gated_out(_heads_flat(attn), _heads_flat(
+            None if gate is None else gate[:, 0]),
                          lp, normed.dtype)
         return out, k_cache, v_cache
 

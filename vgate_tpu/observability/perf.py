@@ -449,6 +449,9 @@ class PerfRecorder:
         self._mla: Dict[str, int] = {}
         self._swa: Dict[str, int] = {}
         self._dsa: Dict[str, int] = {}
+        # rows of the prompt programs read back (every family)
+        self._prefill = dict.fromkeys(
+            ("rows_worked", "rows_real", "rows_padding"), 0)
         self.total_engine_cpu_s = 0.0
         self.total_engine_cpu_in_wait_s = 0.0
         # the flight recorder's per-request phase sums (admitted /
@@ -764,6 +767,16 @@ class PerfRecorder:
             ("layer_steps", steps * linear_layers),
         ):
             self._state[name] = self._state.get(name, 0) + add
+
+    def note_prefill_rows(self, worked: int, real: int) -> None:
+        """One prompt program, booked at its readback: ``worked`` rows
+        went through its position-wise sub-blocks (the whole ``[B, S]``
+        or, of a long prompt's, the blocks of rows up to the longest
+        prompt: models/hybrid.py ``prompt_rows``), ``real`` of them
+        held a prompt's token; the others were padding."""
+        for name, add in (("rows_worked", worked), ("rows_real", real),
+                          ("rows_padding", worked - real)):
+            self._prefill[name] += add
 
     def note_mla_decode(self, steps: int, rows: int, ctx_tokens: int,
                         layers: int) -> None:
@@ -1106,6 +1119,7 @@ class PerfRecorder:
             **{name: 0 for name in REQUEST_TOTALS},
             "boot_seconds": dict(BOOT_SECONDS),
             "gc": GC.totals(),
+            "prefill": dict(self._prefill),
         }
         if self.request_totals is not None:
             out.update(self.request_totals())
@@ -1458,6 +1472,7 @@ def merge_snapshots(
         # one process, one boot, one collector: replicas share them
         "boot_seconds": dict(totals[0].get("boot_seconds", {})),
         "gc": dict(totals[0].get("gc", {})),
+        "prefill": _sum_dicts([t.get("prefill", {}) for t in totals]),
     }
     out["window"] = agg_window
     out["totals"] = agg_totals

@@ -145,7 +145,8 @@ def block_tokens(spec: ModelSpec) -> int:
 
 
 def expert_layer(x, lp, spec: ModelSpec, act, row_mask=None,
-                 use_pallas: bool = False, layer=None, stack=None):
+                 use_pallas: bool = False, layer=None, stack=None,
+                 by_rows=None):
     """``_expert_block`` over blocks of ``block_tokens(spec)`` rows (one
     block for a decode step or a small wave): the weights are read once
     a block, the temporaries stay bounded.  Arguments and result as
@@ -155,7 +156,7 @@ def expert_layer(x, lp, spec: ModelSpec, act, row_mask=None,
     block = block_tokens(spec)
     if T <= block:
         return _expert_block(x, lp, spec, act, row_mask, use_pallas,
-                             layer, stack)
+                             layer, stack, by_rows)
     # blocks unrolled: XLA runs them one after the other and reuses one
     # block's temporaries for the next
     xt = x.reshape(T, D)
@@ -165,7 +166,7 @@ def expert_layer(x, lp, spec: ModelSpec, act, row_mask=None,
         out, st = _expert_block(
             xt[lo:lo + block], lp, spec, act,
             None if mask is None else mask[lo:lo + block],
-            use_pallas, layer, stack,
+            use_pallas, layer, stack, by_rows,
         )
         outs.append(out)
         stats.append(st)
@@ -182,26 +183,35 @@ def _combine(out, y, pairs, live, K: int):
 
 
 def _expert_block(x, lp, spec: ModelSpec, act, row_mask=None,
-                  use_pallas: bool = False, layer=None, stack=None):
+                  use_pallas: bool = False, layer=None, stack=None,
+                  by_rows=None):
     """x: [..., D].  ``lp`` holds this layer's ``router`` [D, R] and
     either its experts' matrices (``spec.expert_stacks``: ``{"w": [E, .,
     .]}``) or, with ``stack``/``layer``, nothing of them: ``stack`` is
     then the dict of their ``[L, E, ., .]`` stacks and
     ``layer`` the traced index into them (the Pallas path must not see
     a scan's per-layer slice).  ``row_mask`` ([...] bool) marks the rows
-    that are real: padding and idle slots route nowhere.  Returns
-    (out [..., D], stats [5] int32 in ``STAT_NAMES`` order)."""
+    that are real: padding and idle slots route nowhere.  ``by_rows``
+    (a long prompt's pass: models/hybrid.py ``_by_row_blocks``) runs
+    what is position-wise (the router's product, the latent's two, the
+    shared expert) over the blocks of rows up to the last real one.
+    Returns (out [..., D], stats [5] int32 in ``STAT_NAMES`` order)."""
     orig_shape = x.shape
     D = orig_shape[-1]
     xt = x.reshape(-1, D)
     T = xt.shape[0]
     E, K, first = spec.num_experts, spec.experts_per_token, spec.first_expert
+    rows_of = lambda fn, *rows: fn(*rows)
+    if by_rows is not None and row_mask is not None:
+        n_rows = jnp.max(jnp.where(
+            row_mask.reshape(T), jnp.arange(1, T + 1), 0))
+        rows_of = lambda fn, *rows: by_rows(fn, rows, n_rows, axis=0)
 
     with jax.named_scope("moe_route"):
-        logits = jnp.einsum(
-            "td,de->te", xt.astype(jnp.float32),
+        logits = rows_of(lambda rows: jnp.einsum(
+            "td,de->te", rows.astype(jnp.float32),
             lp["router"].astype(jnp.float32),
-        )
+        ), xt)
         if spec.router_scoring == "sigmoid":
             # chosen by score + bias, weighted by the score alone
             scores = jax.nn.sigmoid(logits)
@@ -243,7 +253,8 @@ def _expert_block(x, lp, spec: ModelSpec, act, row_mask=None,
     src = xt  # what the experts read: the rows, or their latent
     if latent:  # ONE product in front of the dispatch, not one a choice
         with jax.named_scope("moe_latent_in"):
-            src = jnp.einsum("td,dl->tl", xt, lp["latent_in"]["w"])
+            src = rows_of(lambda rows: jnp.einsum(
+                "td,dl->tl", rows, lp["latent_in"]["w"]), xt)
 
     with jax.named_scope("moe_experts"):
         ws = stack if stack is not None else lp
@@ -287,12 +298,12 @@ def _expert_block(x, lp, spec: ModelSpec, act, row_mask=None,
         # on this chip's PARTIAL sum: the product is linear and has no
         # bias, so the chips' results add up to the whole
         with jax.named_scope("moe_latent_out"):
-            out = jnp.einsum(
-                "tl,ld->td", out.astype(xt.dtype), lp["latent_out"]["w"]
-            ).astype(jnp.float32)
+            out = rows_of(lambda rows: jnp.einsum(
+                "tl,ld->td", rows.astype(xt.dtype), lp["latent_out"]["w"]
+            ).astype(jnp.float32), out)
 
     if spec.shared_expert_intermediate_size:
-        with jax.named_scope("shared_expert"):
+        def shared(out, xt):
             u = jnp.einsum("td,df->tf", xt, lp["shared_up"]["w"])
             if spec.moe_gated:
                 g = jnp.einsum("td,df->tf", xt, lp["shared_gate"]["w"])
@@ -300,12 +311,15 @@ def _expert_block(x, lp, spec: ModelSpec, act, row_mask=None,
             else:
                 u = act(u.astype(jnp.float32)).astype(xt.dtype)
             s = jnp.einsum("tf,fd->td", u, lp["shared_down"]["w"])
-            if spec.shared_expert_gate:
-                sg = jax.nn.sigmoid(jnp.einsum(
-                    "td,d->t", xt.astype(jnp.float32),
-                    lp["shared_router"].astype(jnp.float32),
-                ))
-                out = out + s.astype(jnp.float32) * sg[:, None]
-            else:
-                out = out + s.astype(jnp.float32)
+            if not spec.shared_expert_gate:
+                return out + s.astype(jnp.float32)
+            sg = jax.nn.sigmoid(jnp.einsum(
+                "td,d->t", xt.astype(jnp.float32),
+                lp["shared_router"].astype(jnp.float32),
+            ))
+            return out + s.astype(jnp.float32) * sg[:, None]
+
+        with jax.named_scope("shared_expert"):
+            # (a block past the real rows holds no routed sum either)
+            out = rows_of(shared, out, xt)
     return out.astype(x.dtype).reshape(orig_shape), stats

@@ -70,6 +70,7 @@ from vgate_tpu.models.decoder import (
     prefill_attention_impl,
 )
 from vgate_tpu.models.hybrid import make_state as make_hybrid_state
+from vgate_tpu.models.hybrid import prompt_rows
 from vgate_tpu.models.hybrid import (
     state_bytes_per_slot as hybrid_state_bytes_per_slot,
 )
@@ -1239,6 +1240,9 @@ class EngineCore:
         self._fatal_suspects: List[tuple] = []
         self.total_steps = 0
         self.total_prefills = 0
+        # (rows worked, real rows) of the prompt programs dispatched
+        # and not read back yet (perf.note_prefill_rows)
+        self._prompt_rows: List[tuple] = []
         self.total_decode_tokens = 0
         self.total_state_rebuilds = 0
         # loop iterations completed (capture_profile waits on it)
@@ -2511,6 +2515,9 @@ class EngineCore:
         with self.perf.span("readback") as read:
             firsts = jax.device_get(handles)  # [(tok, lp)]
         device_s, readback_s = wait.seconds, read.seconds
+        for worked, real in self._prompt_rows:
+            self.perf.note_prefill_rows(worked, real)
+        self._prompt_rows.clear()
         # batched admission costs one combined dispatch+readback; attribute
         # an equal share to each prefill so observation count stays
         # one-per-prefill and the histogram sum stays the true wall time
@@ -3023,9 +3030,11 @@ class EngineCore:
                 **kw, **self._state_args(slots),
             )
             self._set_cache(cache)
-        self.perf.count(
-            prompt_programs=1, prompt_tokens=int(lens[: len(plans)].sum())
-        )
+        real = int(lens[: len(plans)].sum())
+        self.perf.count(prompt_programs=1, prompt_tokens=real)
+        # the rows the program works on, by the model layer's own rule
+        self._prompt_rows.append((B * int(prompt_rows(
+            self.spec, bucket, int(lens.max()), whole=not cached)), real))
         return out
 
     @engine_thread_only
