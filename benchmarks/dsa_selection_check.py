@@ -142,7 +142,7 @@ def serve_side(cfg_path: str, cpu: bool) -> dict:
            "served": []}
     served_sel = []  # per prompt: {layer: {position: set of positions}}
     layers = [i for i in range(spec.num_layers)
-              if spec._stack_layer(i)[0] == "dsa"]
+              if spec.stack[i][0] == "dsa"]
     for ids in prompts:
         n = len(ids)
         S = min(b for b in buckets if b >= n)

@@ -86,9 +86,10 @@ def _clear_jax_caches_per_file(request):
     so the sentinel would thrash clear_caches() between nearly every
     test (slow) while doing nothing for the per-process accumulation it
     exists to bound (each xdist worker compiles far fewer programs than
-    a full serial run anyway).  Skip the clearing under xdist; the
-    tier-1 runner pins `-p no:xdist` (ROADMAP.md) so serial runs keep
-    the protection."""
+    a full serial run anyway).  Skip the clearing under xdist: the
+    tier-1 command runs six xdist workers with `--dist loadfile` (a
+    file is one worker's; /root/TESTS_LAST_RUN.json has the command),
+    and a serial run (`-p no:xdist`) keeps the protection."""
     if os.environ.get("PYTEST_XDIST_WORKER"):
         yield
         return

@@ -356,13 +356,30 @@ async def test_drain_completes_inflight_dry_run():
         responses = await asyncio.gather(*inflight)
         assert [r.status for r in responses] == [200] * 4
         assert await app["drain"].wait_drained(5.0)
-        # on_complete runs through call_soon (lifecycle.py): one turn of
-        # the loop after the drained event
-        await asyncio.sleep(0)
         assert done == [True]
         assert app["drain"].aborted_stragglers == 0
     finally:
         await client.close()
+
+
+async def test_wait_drained_returns_after_on_complete_ran():
+    """A waiter that was already waiting when the drain finished finds
+    ``on_complete`` run: the callback is scheduled ahead of the event
+    that wakes the waiter.  Fifty drains, each with one request in
+    flight while the waiter waits."""
+    for _ in range(50):
+        inflight = [1]
+        done = []
+        drain = DrainController(
+            poll_s=0.005, inflight=lambda: inflight[0],
+            on_complete=lambda: done.append(True))
+        drain.begin()
+        waiter = asyncio.ensure_future(drain.wait_drained(5.0))
+        await asyncio.sleep(0.01)  # the waiter waits, the drain polls
+        assert not waiter.done()
+        inflight[0] = 0
+        assert await waiter
+        assert done == [True]
 
 
 # --------------------------------------------------------------- slow tier

@@ -9,102 +9,47 @@ seeded weights: log-probabilities, not tokens."""
 
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from perfbench import manifest
 from perfbench.references import mistral4 as ref
+from tests import family_contract as contract
 from tests import prompt_row_blocks as row_blocks
-from vgate_tpu.backends.base import SamplingParams
-from vgate_tpu.config import load_config
+from tests.family_contract import tokens
 from vgate_tpu.models.specs import spec_for_model_id
-from vgate_tpu.runtime.engine_core import EngineCore
 
-# the tiny-mla-moe preset under the published config's keys: what the
-# configuration's rehearsal serves
-TINY = manifest.load_json(
-    manifest.HERE, "configs", "mistral-small-4-119b-l4e32.json"
-)["rehearse"]["model"]
-# float32 on both sides; only the order of sums and the form differ (the
-# absorbed product against the expanded one, blockwise softmax against
-# one masked softmax, the grouped product against one expert at a time):
-# measured 9.5e-7 at most
-TOL_F32 = 5e-5
-# bf16 weights AND activations in the engine against float32 arithmetic
-# on the same bf16 weights: three layers of bf16 rounding (2^-9 a
-# product) and a bf16 latent row in the pool; measured 2.1e-3 at most and
-# 7.8e-4 in the mean.  Five times that: a top-2 choice among 8 experts
-# that flips on another platform's rounding moves one token's values
-# together
-TOL_BF16 = 0.01
-
-
-def engine_config(dtype="float32", tpu=None, max_model_len=256):
-    base = {
-        "dp": 1, "tp": 1, "ep": 1, "sp": 1, "kv_num_pages": 160,
-        "kv_page_size": 4, "max_batch_slots": 4,
-        "prefill_buckets": [16, 32, 64, 128], "use_pallas": False,
-        "decode_chunk": 2,
-    }
-    base.update(tpu or {})
-    return load_config(
-        model={"model_id": "tiny-mla-moe", "engine_type": "jax_tpu",
-               "dtype": dtype, "max_model_len": max_model_len},
-        tpu=base, scheduler={"max_queue_size": 16},
-        logging={"level": "WARNING"},
-    )
-
-
-def lp_params(max_tokens):
-    return SamplingParams(max_tokens=max_tokens, temperature=0.0,
-                          logprobs=True, top_logprobs=5)
-
-
-def tokens(rng, n):
-    return [int(t) for t in rng.integers(3, 259, size=n)]
-
-
-def differences(core, dtype, seq, prompt):
-    """|served - reference| over the top log-probabilities of every
-    generated token, the reference's full forward on prompt +
-    generated."""
-    full = list(prompt) + list(seq.generated_ids)
-    want = ref.logprobs(TINY, 0, jnp.dtype(dtype), [full], [len(prompt)])[0]
-    entries = core.logprob_entries(seq)
-    assert len(entries) == len(seq.generated_ids)
-    return [
-        abs(t["logprob"] - want[pos, t["token_id"]])
-        for pos, e in enumerate(entries) for t in e["top_logprobs"]
-    ]
-
-
-def run(core, prompts, max_tokens=6):
-    seqs = [core.submit_tokens(p, lp_params(max_tokens)) for p in prompts]
-    for s in seqs:
-        assert s.done_event.wait(timeout=600)
-        assert s.error is None, s.error
-    return seqs
+FAMILY = contract.Family(
+    "mistral-small-4-119b-l4e32.json", ref=ref, max_model_len=256,
+    tol={
+        # float32 on both sides; only the order of sums and the form
+        # differ (the absorbed product against the expanded one,
+        # blockwise softmax against one masked softmax, the grouped
+        # product against one expert at a time): measured 9.5e-7 at most
+        "float32": 5e-5,
+        # bf16 weights AND activations in the engine against float32
+        # arithmetic on the same bf16 weights: three layers of bf16
+        # rounding (2^-9 a product) and a bf16 latent row in the pool;
+        # measured 2.1e-3 at most and 7.8e-4 in the mean.  Five times
+        # that: a top-2 choice among 8 experts that flips on another
+        # platform's rounding moves one token's values together
+        "bfloat16": 0.01},
+    tpu={"kv_num_pages": 160, "kv_page_size": 4, "max_batch_slots": 4,
+         "prefill_buckets": [16, 32, 64, 128], "decode_chunk": 2})
+TINY, TOL_F32 = FAMILY.cfg, FAMILY.tol["float32"]
 
 
 @pytest.mark.parametrize(
-    "dtype, tol", [("float32", TOL_F32), ("bfloat16", TOL_BF16)])
+    "dtype, tol", [(d, FAMILY.tol[d]) for d in ("float32", "bfloat16")])
 def test_prompt_pass_then_absorbed_decode_match_the_reference(dtype, tol):
     """Three prompts of unequal length, two of them past the original
     maximum of 32 (the queries' position scaling leaves 1, YaRN's
     interpolated frequencies turn), each a whole-prompt pass
     (non-absorbed) and six absorbed decode steps through the latent
     pool."""
-    core = EngineCore(engine_config(dtype), devices=jax.devices()[:1])
-    core.start()
-    try:
-        rng = np.random.default_rng(1)
-        prompts = [tokens(rng, n) for n in (19, 70, 101)]
-        diffs = []
-        for p, s in zip(prompts, run(core, prompts)):
-            diffs += differences(core, dtype, s, p)
-        assert diffs and max(diffs) < tol, (max(diffs), np.mean(diffs))
+    with contract.booted(FAMILY, dtype=dtype) as core:
+        contract.unequal_rows(FAMILY, core, (19, 70, 101), dtype=dtype)
         stats = core.get_stats()
         assert "state_cache" not in stats
         width = jnp.dtype(dtype).itemsize
@@ -128,62 +73,22 @@ def test_prompt_pass_then_absorbed_decode_match_the_reference(dtype, tol):
         # every decode step reads at least the prompts and writes a row
         assert mla["decode_token_reads"] >= 3 * mla["decode_steps"] * 19
         assert mla["latent_rows_written"] >= 3 * (19 + 70 + 101)
-    finally:
-        core.stop()
 
 
 def test_chunked_prefill_and_a_slot_reused_after_a_longer_tenant():
-    """ONE slot.  A 75-token prompt goes in as chunks of 32 + 32 + 11:
-    each later chunk attends to the latent rows the earlier ones left in
-    the pool (expanded again, never cached), across the original
-    maximum.  Then a 9-token prompt takes the same slot and pages."""
-    core = EngineCore(
-        engine_config(tpu={"prefill_chunk": 32,
-                           "prefill_buckets": [16, 32],
-                           "max_batch_slots": 1,
-                           "prefix_cache": {"enabled": False}}),
-        devices=jax.devices()[:1])
-    core.start()
-    try:
-        rng = np.random.default_rng(4)
-        long_prompt, short_prompt = tokens(rng, 75), tokens(rng, 9)
-        (a,) = run(core, [long_prompt], max_tokens=8)
-        (b,) = run(core, [short_prompt])
-        for seq, prompt in ((a, long_prompt), (b, short_prompt)):
-            diffs = differences(core, "float32", seq, prompt)
-            assert max(diffs) < TOL_F32, max(diffs)
-    finally:
-        core.stop()
+    """75 tokens go in as chunks of 32 + 32 + 11: each later chunk
+    attends to the latent rows the earlier ones left in the pool
+    (expanded again, never cached), across the original maximum."""
+    contract.chunked_prefill_and_slot_reuse(
+        FAMILY, 32, (75, 9), tpu={"prefix_cache": {"enabled": False}})
 
 
 def test_a_prefix_hit_prefills_only_the_suffix_against_latent_pages():
-    """Two prompts share their first 64 tokens (16 whole pages).  The
-    second is a prefix hit: its suffix alone goes through the prompt
-    pass, against the first one's latent pages, and its answer is the
-    reference's all the same."""
-    core = EngineCore(engine_config(), devices=jax.devices()[:1])
-    core.start()
-    try:
-        assert core.prefix_cache_enabled
-        rng = np.random.default_rng(9)
-        shared = tokens(rng, 64)
-        first, second = shared + tokens(rng, 7), shared + tokens(rng, 21)
-        (a,) = run(core, [first])
-        before = core.perf.totals()["mla"]
-        (b,) = run(core, [second])
-        after = core.perf.totals()["mla"]
-        for seq, prompt in ((a, first), (b, second)):
-            diffs = differences(core, "float32", seq, prompt)
-            assert max(diffs) < TOL_F32, max(diffs)
-        assert core.allocator.prefix_hits > 0 or (
-            core.radix_cache is not None
-            and core.radix_cache.get_stats()["hits"] > 0)
-        # only the suffix's rows were written: 85 - 64 a layer, and its
-        # six decode steps' rows
-        written = after["latent_rows_written"] - before["latent_rows_written"]
-        assert written < 3 * (21 + 2 * 6 + 4), written
-    finally:
-        core.stop()
+    written = contract.prefix_hit_on_whole_pages(
+        FAMILY, lambda core: core.perf.totals()["mla"]["latent_rows_written"])
+    # only the suffix's rows were written: 85 - 64 a layer, and its six
+    # decode steps' rows
+    assert written < 3 * (21 + 2 * 6 + 4), written
 
 
 def test_attention_is_not_flat_at_the_draws():

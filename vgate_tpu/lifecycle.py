@@ -234,13 +234,17 @@ class DrainController:
                 }
             },
         )
-        self._drained.set()
         if self.on_complete is not None:
             # via call_soon, not inline: on_complete typically raises
             # GracefulExit (a SystemExit), which propagates cleanly out
             # of run_forever from a callback but would land in this
-            # task's result slot (never retrieved) if raised here
+            # task's result slot (never retrieved) if raised here.
+            # Scheduled AHEAD of the event: set() wakes wait_drained's
+            # waiters through call_soon too, and the loop runs callbacks
+            # in order, so whoever awaited the drain finds the callback
+            # already run
             asyncio.get_running_loop().call_soon(self.on_complete)
+        self._drained.set()
 
     async def wait_drained(self, timeout: Optional[float] = None) -> bool:
         """Test/ops helper: block until the drain finished."""

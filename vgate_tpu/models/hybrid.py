@@ -1,6 +1,8 @@
-"""The layer stack of several kinds: a period of residual sub-blocks
-``h <- h + f(norm(h))``, each ONE of a few kinds, given by the spec as
-data (``ModelSpec.period_blocks``):
+"""The layer stack of several kinds: residual sub-blocks ``h <- h +
+f(norm(h))``, each ONE of a few kinds, given by the spec as data
+(``ModelSpec.stack``: an entry a layer, its sub-blocks' kinds in order,
+whichever way the spec's fields spell them; the walker takes from it
+``lead_blocks``, ``period_blocks`` and ``num_periods``):
 
 * ``gdn``   Gated DeltaNet linear attention (Qwen3-Next),
 * ``mamba`` a Mamba-2 state-space layer (Nemotron-H),
@@ -32,7 +34,8 @@ Mistral-Small-4 layer is ``mla moe``, and its stack has no recurrent
 layer: the state is then ``None`` and the second pool too.  A K-EXAONE
 layer is ``swa moe`` three times to one ``attn moe``, behind LEADING
 layers that the walker runs once, ahead of the scan (layer 0, ``swa
-mlp``, and as many more as leave whole periods: ``ModelSpec.lead_layers``).
+mlp``, and as many more as leave whole periods: ``ModelSpec.lead_layers``,
+one search over the stack for every spelling).
 
 ``models/decoder.py``'s forwards hand a hybrid spec's work here after
 they have chosen the attention implementation, so the step programs,
@@ -255,7 +258,7 @@ def _init_dsa_layers(spec: ModelSpec, key, dtype, normal
         return out
 
     lead, P = spec.lead_layers, spec.num_periods
-    kinds = [spec._stack_layer(i) for i in range(spec.num_layers)]
+    kinds = spec.stack
     out: Dict[str, Any] = {"lead": tuple(
         tree([i], (), *kinds[i]) for i in range(lead))}
     for group, mixer in (("pick", "dsa"), ("reuse", "mla")):
@@ -316,7 +319,7 @@ def _init_window_layers(spec: ModelSpec, key, dtype, normal
         return out
 
     lead, P = spec.lead_layers, spec.num_periods
-    kinds = [spec._stack_layer(i) for i in range(spec.num_layers)]
+    kinds = spec.stack
     out: Dict[str, Any] = {"lead": tuple(
         tree([i], (), kinds[i][1]) for i in range(lead))}
     for group, mixer in (("window", "swa"), ("global", "attn")):

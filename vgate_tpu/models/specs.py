@@ -10,8 +10,9 @@ Gemma-2 (sliding-window + softcap attention, sandwich norms).
 from __future__ import annotations
 
 import math
-from dataclasses import dataclass, field, replace
-from typing import Dict, Optional
+from dataclasses import dataclass, replace
+from functools import cached_property
+from typing import Callable, Dict, NamedTuple
 
 
 @dataclass(frozen=True)
@@ -190,14 +191,13 @@ class ModelSpec:
         """A stack of sub-blocks of several kinds (models/hybrid.py):
         recurrent layers beside attention ones, a per-slot recurrent
         state beside the paged pool."""
-        return (bool(self.layer_pattern) or self.full_attention_interval > 1
-                or self.is_mla or bool(self.window_pattern))
+        return self._spelling is not _DENSE
 
     @property
     def is_dsa(self) -> bool:
         """Latent attention over the ``index_topk`` cached tokens an
-        indexer picks (``indexer_pattern``), not over all of them."""
-        return bool(self.indexer_pattern)
+        indexer picks, not over all of them."""
+        return self._spelling is _INDEXER
 
     @property
     def is_mla(self) -> bool:
@@ -245,8 +245,8 @@ class ModelSpec:
         num = lambda v: int(v) if float(v).is_integer() else v
         if self.yarn_factor <= 0:
             return {"rope_theta": num(self.rope_theta),
-                    "rope_type": "default"} if (
-                        self.window_pattern or self.indexer_pattern) else {}
+                    "rope_type": "default"} if self._spelling in (
+                        _WINDOW, _INDEXER) else {}
         return {
             "beta_fast": num(self.yarn_beta_fast),
             "beta_slow": num(self.yarn_beta_slow),
@@ -261,142 +261,104 @@ class ModelSpec:
         }
 
     @property
-    def layers_per_period(self) -> int:
-        """Layers of one period: the pattern's smallest repeating unit
-        (the whole of a pattern that does not repeat)."""
-        pat = self.layer_pattern
-        if self.indexer_pattern:
-            return self._lead_and_period[1]
+    def _spelling(self) -> "_Spelling":
+        """How THIS spec states its stack: the one place that looks."""
+        if self.layer_pattern:
+            return _LETTERS
         if self.window_pattern:
-            return len(self.window_pattern)
-        if not pat:
-            return max(1, self.full_attention_interval)
-        return next(n for n in range(1, len(pat) + 1)
-                    if len(pat) % n == 0 and pat[:n] * (len(pat) // n) == pat)
-
-    @property
-    def period_blocks(self) -> tuple:
-        """The residual sub-blocks of one period, in order, each ``(kind,
-        group, norm, index)``: what it computes (``gdn`` | ``mamba`` |
-        ``attn`` | ``moe``), the parameter group that holds its tensors
-        (``layers[group]``, stacked ``[periods, layers of the group a
-        period, ...]``), the name of its norm weight there and its
-        layer's index inside the group's period."""
-        if not self.is_hybrid:
-            return ()
-        if self.window_pattern:  # the layers that follow the leading ones
-            out, seen = [], {}
-            lead = self.lead_layers
-            for i in range(lead, lead + self.layers_per_period):
-                mixer, ff = self._stack_layer(i)
-                group = "window" if mixer == "swa" else "global"
-                j = seen.setdefault(group, 0)
-                seen[group] = j + 1
-                out += [(mixer, group, "input_norm", j),
-                        (ff, group, "post_norm", j)]
-            return tuple(out)
-        if self.indexer_pattern:  # the layers behind the leading ones
-            out, seen = [], {}
-            lead = self.lead_layers
-            for i in range(lead, lead + self.layers_per_period):
-                mixer, ff = self._stack_layer(i)
-                group = "pick" if mixer == "dsa" else "reuse"
-                j = seen.setdefault(group, 0)
-                seen[group] = j + 1
-                out += [(mixer, group, "input_norm", j),
-                        (ff, group, "post_norm", j)]
-            return tuple(out)
-        if self.is_mla:  # every layer: latent attention, then experts
-            return (("mla", "layer", "input_norm", 0),
-                    ("moe", "layer", "post_norm", 0))
-        if not self.layer_pattern:  # layers of two sub-blocks
-            n = self.full_attention_interval - 1
-            lin = [(k, "linear", nm, i) for i in range(n)
-                   for k, nm in (("gdn", "input_norm"), ("moe", "post_norm"))]
-            return tuple(lin) + (("attn", "full", "input_norm", 0),
-                                 ("moe", "full", "post_norm", 0))
-        kinds = {"M": "mamba", "*": "attn", "E": "moe"}
-        out, seen = [], {}
-        for letter in self.layer_pattern[:self.layers_per_period]:
-            kind = kinds[letter]
-            out.append((kind, kind, "norm", seen.get(kind, 0)))
-            seen[kind] = seen.get(kind, 0) + 1
-        return tuple(out)
-
-    def _stack_layer(self, i: int) -> tuple:
-        """Layer ``i`` of a ``window_pattern`` or an ``indexer_pattern``
-        spec as its two sub-blocks' kinds: (``swa`` | ``attn``, or
-        ``dsa`` (latent attention that picks) | ``mla`` (that reuses the
-        pick); ``mlp`` | ``moe``)."""
+            return _WINDOW
         if self.indexer_pattern:
-            at = self.first_layer + i
-            return ("dsa" if self.indexer_pattern[at] == "F" else "mla",
-                    "mlp" if at < self.first_k_dense else "moe")
-        ff = "mlp" if i < self.first_k_dense else "moe"
-        pat = self.window_pattern
-        return ("swa" if pat[i % len(pat)] == "L" else "attn", ff)
+            return _INDEXER
+        if self.is_mla:
+            return _LATENT
+        if self.full_attention_interval > 1:
+            return _INTERVAL
+        return _DENSE
 
-    @property
-    def _dense_layers(self) -> int:
-        """Layers of THIS stack whose feed-forward is dense."""
-        return min(self.num_layers,
-                   max(0, self.first_k_dense - self.first_layer))
+    @cached_property
+    def stack(self) -> tuple:
+        """The layers of this spec, one entry each (``num_layers`` of
+        them, ``first_layer`` applied): the layer's residual sub-blocks'
+        kinds in order, of the eight the stack walker knows
+        (models/hybrid.py).  Everything below derives from it alone."""
+        first = self.first_layer
+        mine = self._spelling.parse(self)[first:first + self.num_layers]
+        if len(mine) != self.num_layers:
+            raise ValueError(f"{self.name}: its stack is stated for "
+                             f"{len(mine)} of its {self.num_layers} layers")
+        return mine
 
-    @property
-    def _lead_and_period(self) -> tuple:
-        """(leading layers, layers a period) of an ``indexer_pattern``
-        spec: behind the leading dense layers the pattern's rest must be
-        whole repeats of one unit; of all such cuts the one that leaves
-        the fewest layers unrolled (leading ones and one period)."""
-        n = self.num_layers
-        pat = self.indexer_pattern[self.first_layer:self.first_layer + n]
-        best = None
-        for lead in range(self._dense_layers, n):
-            rest = pat[lead:]
-            unit = next(u for u in range(1, len(rest) + 1)
+    @cached_property
+    def _cut(self) -> tuple:
+        """(leading layers, layers a period).  The walker runs the
+        leading layers once and scans the rest, which is whole repeats
+        of one unit (itself, where it does not repeat).  Where the
+        spelling has leading layers, of the cuts behind the dense ones
+        the first that leaves the fewest layers unrolled (the leading
+        ones and one period)."""
+        def unit(rest):
+            return next(u for u in range(1, len(rest) + 1)
                         if len(rest) % u == 0
                         and rest[:u] * (len(rest) // u) == rest)
-            if best is None or lead + unit < sum(best):
-                best = (lead, unit)
-        return best
+
+        dense = sum("mlp" in layer for layer in self.stack)
+        leads = range(dense, self.num_layers) if self._spelling.leads else (0,)
+        return min(((n, unit(self.stack[n:])) for n in leads), key=sum)
 
     @property
     def lead_layers(self) -> int:
         """Layers the stack walker runs once, ahead of the scanned
         periods: the leading dense ones, and as many more as leave a
         whole number of periods behind them."""
-        if self.indexer_pattern:
-            return self._lead_and_period[0]
-        if not self.window_pattern:
-            return 0
-        lead = self.first_k_dense
-        while (self.num_layers - lead) % len(self.window_pattern):
-            lead += 1
-        return lead
+        return self._cut[0]
 
     @property
-    def lead_blocks(self) -> tuple:
-        """The leading layers, each its two sub-blocks' kinds."""
-        return tuple(self._stack_layer(i) for i in range(self.lead_layers))
-
-    def group_layers(self, group: str) -> int:
-        """Layers a period holds in a parameter group."""
-        return len({b[3] for b in self.period_blocks if b[1] == group})
-
-    def _layers_of(self, *kinds) -> int:
-        lead = sum(k in kinds for pair in self.lead_blocks for k in pair)
-        return lead + self.num_periods * sum(
-            b[0] in kinds for b in self.period_blocks)
+    def layers_per_period(self) -> int:
+        return self._cut[1]
 
     @property
     def num_periods(self) -> int:
         return (self.num_layers - self.lead_layers) // self.layers_per_period
 
     @property
+    def lead_blocks(self) -> tuple:
+        """The leading layers, each its two sub-blocks' kinds."""
+        return self.stack[:self.lead_layers]
+
+    @property
+    def period_blocks(self) -> tuple:
+        """The residual sub-blocks of one period, in order, each ``(kind,
+        group, norm, index)``: what it computes, the parameter group
+        that holds its tensors (``layers[group]``, stacked ``[periods,
+        layers of the group a period, ...]``; a layer's group goes by
+        its first sub-block), the name of its norm weight there and its
+        layer's index inside the group's period."""
+        groups = self._spelling.groups
+        if not groups:  # models/decoder.py's own layers: no walker
+            return ()
+        out, seen = [], {}
+        lead, per = self._cut
+        for layer in self.stack[lead:lead + per]:
+            group = groups[layer[0]]
+            j = seen.get(group, 0)
+            seen[group] = j + 1
+            norms = ("input_norm", "post_norm") if len(layer) > 1 else (
+                "norm",)
+            out += [(kind, group, norm, j)
+                    for kind, norm in zip(layer, norms)]
+        return tuple(out)
+
+    def group_layers(self, group: str) -> int:
+        """Layers a period holds in a parameter group."""
+        return len({b[3] for b in self.period_blocks if b[1] == group})
+
+    def _layers_of(self, *kinds) -> int:
+        return sum(k in kinds for layer in self.stack for k in layer)
+
+    @property
     def attn_layers(self) -> int:
         """Layers that hold pages (K/V, or the latent)."""
-        return (self._layers_of("attn", "mla", "dsa") if self.is_hybrid
-                else self.num_layers)
+        return self._layers_of("attn", "mla", "dsa")
 
     @property
     def index_layers(self) -> int:
@@ -424,20 +386,18 @@ class ModelSpec:
     @property
     def moe_layers(self) -> int:
         """Expert layers the stack runs a step."""
-        if self.is_hybrid:
-            return self._layers_of("moe")
-        return self.num_layers if self.is_moe else 0
+        return self._layers_of("moe")
 
     @property
     def recurrent_kind(self) -> str:
         """``gdn`` | ``mamba`` | "" : the one kind of recurrent layer."""
-        kinds = {b[0] for b in self.period_blocks} & {"gdn", "mamba"}
+        kinds = {k for layer in self.stack for k in layer} & {"gdn", "mamba"}
         assert len(kinds) <= 1, "one kind of recurrent state a spec"
         return next(iter(kinds), "")
 
     @property
     def linear_per_period(self) -> int:
-        return self.linear_layers // self.num_periods if self.is_hybrid else 0
+        return self.linear_layers // self.num_periods
 
     @property
     def mamba_inner(self) -> int:
@@ -484,150 +444,93 @@ class ModelSpec:
     def rotary_dim(self) -> int:
         return int(self.head_dim * self.partial_rotary_factor)
 
-    @property
-    def num_params(self) -> int:
-        """Analytic parameter count (embeddings + decoder stack), used
-        for the MFU gauge (observability/roofline.py).  Matches init_params' layout:
-        q/k/v/o (+bias), gate/up/down (per expert for MoE, + router),
-        norms, embed, united or separate lm_head."""
-        D, L, F = self.hidden_size, self.num_layers, self.intermediate_size
-        if self.layer_pattern:
-            return self._pattern_params()
-        if self.window_pattern:
-            return self._window_params()
-        if self.indexer_pattern:
-            return self._dsa_params()
-        q_dim = self.num_heads * self.head_dim
-        kv_dim = self.num_kv_heads * self.head_dim
-        attn = D * q_dim + 2 * D * kv_dim + q_dim * D
+    def _kind_params(self) -> dict:
+        """Sub-block kind -> the parameters of one such sub-block, its
+        norm apart (models/decoder.py init_params, models/hybrid.py
+        init_layers)."""
+        D, H, hd = self.hidden_size, self.num_heads, self.head_dim
+        # GQA attention: q, k, v, o; the output gate beside q; the
+        # per-head norms on q and k; the biases of q, k and v
+        gqa = 2 * D * self.q_dim + 2 * D * self.kv_dim
         if self.attn_output_gate:
-            attn += D * q_dim
+            gqa += D * self.q_dim
         if self.qk_norm:
-            attn += 2 * self.head_dim
+            gqa += 2 * hd
         if self.qkv_bias:
-            attn += q_dim + 2 * kv_dim
-        if self.is_mla:  # q_a, its norm, q_b, kv_a, its norm, kv_b, o
-            ql, kl = self.q_lora_rank, self.kv_lora_rank
-            nope, vd = self.qk_nope_head_dim, self.v_head_dim
-            H = self.num_heads
-            attn = (D * ql + ql + ql * H * (nope + self.qk_rope_head_dim)
-                    + D * self.latent_dim + kl + kl * H * (nope + vd)
-                    + H * vd * D)
-        if self.is_moe:
-            Fe, Fs = self.expert_width, self.shared_expert_intermediate_size
-            mlp = self.num_experts * 3 * D * Fe + D * self.router_experts
-            if Fs:
-                mlp += 3 * D * Fs + (D if self.shared_expert_gate else 0)
-        else:
-            mlp = 3 * D * F
-        norms = 2 * D + (2 * D if self.ffn_sandwich else 0)
-        embed = self.vocab_size * D
-        head = 0 if self.tie_embeddings else self.vocab_size * D
-        linear = 0
-        if self.is_hybrid:
-            Hv, vd = self.linear_num_value_heads, self.linear_value_dim
-            linear = (
-                D * (self.linear_conv_dim + vd) + D * 2 * Hv
-                + self.linear_conv_dim * self.linear_conv_kernel_dim
-                + 2 * Hv + self.linear_value_head_dim + vd * D
-            )
-        return (
-            self.attn_layers * attn + self.linear_layers * linear
-            + L * (mlp + norms) + embed + head + D
-        )
-
-    def _pattern_params(self) -> int:
-        """``num_params`` of a stack given by ``layer_pattern``: every
-        layer one sub-block and its norm (models/hybrid.py init_layers)."""
-        D, V = self.hidden_size, self.vocab_size
-        Hm, C, di = self.mamba_num_heads, self.mamba_conv_dim, self.mamba_inner
-        mamba = (D * (di + C + Hm) + C * self.mamba_conv_kernel
-                 + (C if self.mamba_conv_bias else 0) + 3 * Hm + di + di * D)
-        attn = 2 * D * self.q_dim + 2 * D * self.kv_dim
-        W, Fe = self.expert_in, self.expert_width
-        Fs, m = self.shared_expert_intermediate_size, len(self.expert_stacks)
-        moe = (D * self.router_experts + self.num_experts * m * W * Fe
-               + m * D * Fs)
-        if self.router_scoring == "sigmoid":
-            moe += self.router_experts
-        if self.moe_latent_size:
-            moe += 2 * D * W
-        per = {"mamba": mamba, "attn": attn, "moe": moe}
-        stack = self.num_periods * sum(
-            per[b[0]] + D for b in self.period_blocks)
-        return stack + (1 if self.tie_embeddings else 2) * V * D + D
-
-    def _window_params(self) -> int:
-        """``num_params`` of a ``window_pattern`` stack: every layer GQA
-        attention (window or full, the same tensors) and its two norms,
-        then a dense SwiGLU (the leading layers) or the expert layer."""
-        D, V, hd = self.hidden_size, self.vocab_size, self.head_dim
-        attn = 2 * D * self.q_dim + 2 * D * self.kv_dim + 2 * D
-        if self.qk_norm:
-            attn += 2 * hd
-        Fe, Fs = self.expert_width, self.shared_expert_intermediate_size
-        moe = (D * self.router_experts + self.num_experts * 3 * D * Fe
-               + 3 * D * Fs)
-        if self.router_scoring == "sigmoid":
-            moe += self.router_experts
-        dense = self.first_k_dense
-        return (self.num_layers * attn
-                + dense * 3 * D * self.intermediate_size
-                + (self.num_layers - dense) * moe
-                + (1 if self.tie_embeddings else 2) * V * D + D)
-
-    def _dsa_params(self) -> int:
-        """``num_params`` of an ``indexer_pattern`` stack: every layer
-        latent attention and its two norms, a picking layer its indexer
-        (queries from the query latent, ONE key a token under a
-        LayerNorm, the heads' weights), then a dense SwiGLU (the leading
-        layers) or the expert layer."""
-        D, V, H = self.hidden_size, self.vocab_size, self.num_heads
+            gqa += self.q_dim + 2 * self.kv_dim
+        # latent attention: q_a, its norm, q_b, kv_a, its norm, kv_b, o
         ql, kl = self.q_lora_rank, self.kv_lora_rank
         nope, vd = self.qk_nope_head_dim, self.v_head_dim
-        attn = (D * ql + ql + ql * H * (nope + self.qk_rope_head_dim)
-                + D * self.latent_dim + kl + kl * H * (nope + vd)
-                + H * vd * D + 2 * D)
+        mla = (D * ql + ql + ql * H * (nope + self.qk_rope_head_dim)
+               + D * self.latent_dim + kl + kl * H * (nope + vd)
+               + H * vd * D)
+        # the indexer: queries from the query latent, ONE key a token
+        # under a LayerNorm, the heads' weights
         Hi, di = self.index_n_heads, self.index_head_dim
         indexer = ql * Hi * di + D * di + 2 * di + D * Hi
-        Fe, Fs = self.expert_width, self.shared_expert_intermediate_size
-        moe = (D * self.router_experts + self.num_experts * 3 * D * Fe
-               + 3 * D * Fs)
+        Hv, lv = self.linear_num_value_heads, self.linear_value_dim
+        gdn = (D * (self.linear_conv_dim + lv) + D * 2 * Hv
+               + self.linear_conv_dim * self.linear_conv_kernel_dim
+               + 2 * Hv + self.linear_value_head_dim + lv * D)
+        Hm, C, inner = (self.mamba_num_heads, self.mamba_conv_dim,
+                        self.mamba_inner)
+        mamba = (D * (inner + C + Hm) + C * self.mamba_conv_kernel
+                 + (C if self.mamba_conv_bias else 0) + 3 * Hm + inner
+                 + inner * D)
+        # the expert layer: the router (a selection bias under sigmoid
+        # scores), the held experts' two or three matrices in the width
+        # they work in, the projections into and out of a latent, the
+        # shared expert and its gate
+        W, R, m = self.expert_in, self.router_experts, len(self.expert_stacks)
+        Fs = self.shared_expert_intermediate_size
+        moe = (D * R + self.num_experts * m * W * self.expert_width
+               + m * D * Fs)
         if self.router_scoring == "sigmoid":
-            moe += self.router_experts
-        dense = self._dense_layers
-        return (self.num_layers * attn + self.index_layers * indexer
-                + dense * 3 * D * self.intermediate_size
-                + (self.num_layers - dense) * moe
-                + (1 if self.tie_embeddings else 2) * V * D + D)
+            moe += R
+        if self.moe_latent_size:
+            moe += 2 * D * W
+        if Fs and self.shared_expert_gate:
+            moe += D
+        return {"attn": gqa, "swa": gqa, "mla": mla, "dsa": mla + indexer,
+                "gdn": gdn, "mamba": mamba,
+                "mlp": 3 * D * self.intermediate_size, "moe": moe}
 
-    # an ``indexer_pattern`` spec's pattern as the published config.json
-    # lists it, whole (what perfbench/serve.py holds the program to)
+    @property
+    def num_params(self) -> int:
+        """Analytic parameter count, used for the MFU gauge
+        (observability/roofline.py): every sub-block of the stack and
+        its norm (two under ``ffn_sandwich``), the embedding, a head of
+        its own unless tied, the final norm."""
+        D, per = self.hidden_size, self._kind_params()
+        norm = 2 * D if self.ffn_sandwich else D
+        blocks = sum(per[k] + norm for layer in self.stack for k in layer)
+        tables = (1 if self.tie_embeddings else 2) * self.vocab_size * D
+        return blocks + tables + D
+
+    # an indexer spec's pattern as the published config.json lists it,
+    # whole (what perfbench/serve.py holds the program to)
     @property
     def indexer_types(self) -> list:
-        return ["full" if c == "F" else "shared"
-                for c in self.indexer_pattern]
+        if not self.is_dsa:
+            return []
+        return ["full" if layer[0] == "dsa" else "shared"
+                for layer in self._spelling.parse(self)]
 
     @property
     def layer_windows(self) -> tuple:
-        """Per-layer attention window (0 = global): ``window_pattern``'s
-        letters, or Gemma-2's alternation: even-indexed layers are
-        sliding-window, odd layers are global (HF
+        """Per-layer attention window (0 = global): the stack's window
+        layers, or Gemma-2's alternation, a mask only: even-indexed
+        layers are sliding-window, odd layers are global (HF
         ``Gemma2Config.layer_types``)."""
-        if self.sliding_window <= 0:
-            return tuple(0 for _ in range(self.num_layers))
-        if self.window_pattern:
-            return tuple(
-                self.sliding_window
-                if self._stack_layer(i)[0] == "swa" else 0
-                for i in range(self.num_layers))
-        return tuple(
-            self.sliding_window if i % 2 == 0 else 0
-            for i in range(self.num_layers)
-        )
+        window = max(0, self.sliding_window)
+        if self.is_hybrid:
+            return tuple(window if "swa" in layer else 0
+                         for layer in self.stack)
+        return tuple(window if i % 2 == 0 else 0
+                     for i in range(self.num_layers))
 
-    # a ``window_pattern`` spec's layers as the published config.json
-    # lists them (what perfbench/serve.py holds the program to)
+    # a window spec's layers as the published config.json lists them
+    # (what perfbench/serve.py holds the program to)
     @property
     def layer_types(self) -> list:
         return ["sliding_attention" if w else "full_attention"
@@ -639,10 +542,9 @@ class ModelSpec:
 
     @property
     def mlp_layer_types(self) -> list:
-        # an ``indexer_pattern`` spec states the published stack's
-        depth = len(self.indexer_pattern) or self.num_layers
+        # an indexer spec states the published stack's
         return ["dense" if i < self.first_k_dense else "sparse"
-                for i in range(depth)]
+                for i in range(len(self._spelling.parse(self)))]
 
     def check_expert_share(self) -> None:
         """The held experts lie inside the router's width."""
@@ -692,6 +594,76 @@ class ModelSpec:
     @property
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
+
+
+# ---- the spellings of a stack.  A spec states "which layer is what" in
+# ONE of these (``ModelSpec._spelling`` chooses); ``ModelSpec.stack`` and
+# all that derives from it read the parser's answer and no spelling.
+
+
+class _Spelling(NamedTuple):
+    # spec -> the layers the spelling states, each its sub-blocks' kinds
+    # in order: the PUBLISHED stack where the fields hold it whole, of
+    # which this spec's layers are ``first_layer ..``
+    parse: Callable
+    # may the walker run layers ahead of the scanned periods?  Only a
+    # spelling whose initialiser draws a tree a leading layer
+    # (models/hybrid.py init_layers); the others keep every layer in
+    # the periods even where a later cut would unroll fewer
+    leads: bool
+    # a layer's first sub-block's kind -> the parameter group that holds
+    # the layer's tensors (the parameter tree's names)
+    groups: dict
+
+
+def _depth(spec: ModelSpec) -> int:
+    return spec.first_layer + spec.num_layers
+
+
+def _feed_forward(spec: ModelSpec, i: int) -> str:
+    return "mlp" if i < spec.first_k_dense else "moe"
+
+
+def _parse_interval(spec: ModelSpec) -> tuple:
+    n = spec.full_attention_interval
+    if spec.num_layers % n:
+        raise ValueError(
+            f"{spec.name}: {spec.num_layers} layers are not whole periods "
+            f"of {n - 1} linear-attention layers to one full"
+        )
+    return tuple(("gdn" if (i + 1) % n else "attn", "moe")
+                 for i in range(_depth(spec)))
+
+
+def _parse_letters(spec: ModelSpec) -> tuple:
+    kinds = {"M": "mamba", "*": "attn", "E": "moe"}
+    return tuple((kinds[c],) for c in spec.layer_pattern)
+
+
+def _parse_window(spec: ModelSpec) -> tuple:
+    pat = spec.window_pattern
+    return tuple(("swa" if pat[i % len(pat)] == "L" else "attn",
+                  _feed_forward(spec, i)) for i in range(_depth(spec)))
+
+
+def _parse_indexer(spec: ModelSpec) -> tuple:
+    return tuple(("dsa" if c == "F" else "mla", _feed_forward(spec, i))
+                 for i, c in enumerate(spec.indexer_pattern))
+
+
+_INTERVAL = _Spelling(_parse_interval, False,
+                      {"gdn": "linear", "attn": "full"})
+_LETTERS = _Spelling(_parse_letters, False,
+                     {"mamba": "mamba", "attn": "attn", "moe": "moe"})
+_WINDOW = _Spelling(_parse_window, True, {"swa": "window", "attn": "global"})
+_INDEXER = _Spelling(_parse_indexer, True, {"dsa": "pick", "mla": "reuse"})
+# latent attention without an indexer: every layer alike
+_LATENT = _Spelling(lambda spec: (("mla", "moe"),) * _depth(spec), False,
+                    {"mla": "layer"})
+# models/decoder.py's own layers: a stack for counting only
+_DENSE = _Spelling(
+    lambda spec: (("attn", "moe" if spec.is_moe else "mlp"),) * _depth(spec),
+    False, {})
 
 
 # Dims follow the published HF configs for each model id.

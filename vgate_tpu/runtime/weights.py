@@ -340,7 +340,7 @@ def _dsa_params_from_getter(
     cast = lambda x: np.asarray(x).astype(np_dtype)
 
     def layer(i):
-        mixer, ff = spec._stack_layer(i)
+        mixer, ff = spec.stack[i]
         q_b = unpair(lin(i, "self_attn.q_b_proj").reshape(
             -1, H, nope + rope), rope)
         kv_b = lin(i, "self_attn.kv_b_proj").reshape(kl, H, -1)
@@ -389,7 +389,7 @@ def _dsa_params_from_getter(
         "lead": tuple(layer(i) for i in range(lead))}
     for group, mixer in (("pick", "dsa"), ("reuse", "mla")):
         trees = [layer(i) for i in range(lead, spec.num_layers)
-                 if spec._stack_layer(i)[0] == mixer]
+                 if spec.stack[i][0] == mixer]
         if trees:
             layers[group] = jax.tree.map(
                 lambda *xs: np.stack(xs).reshape(
@@ -432,7 +432,7 @@ def _window_params_from_getter(
         if spec.qk_norm:
             out["q_norm"] = get(i, "self_attn.q_norm.weight")
             out["k_norm"] = get(i, "self_attn.k_norm.weight")
-        if spec._stack_layer(i)[1] == "mlp":
+        if spec.stack[i][1] == "mlp":
             for n in ("gate", "up", "down"):
                 out[n] = lin(i, f"mlp.{n}_proj")
             return jax.tree.map(cast, out)
@@ -453,7 +453,7 @@ def _window_params_from_getter(
         "lead": tuple(layer(i) for i in range(lead))}
     for group, mixer in (("window", "swa"), ("global", "attn")):
         trees = [layer(i) for i in range(lead, spec.num_layers)
-                 if spec._stack_layer(i)[0] == mixer]
+                 if spec.stack[i][0] == mixer]
         if trees:
             layers[group] = jax.tree.map(
                 lambda *xs: np.stack(xs).reshape(
