@@ -1034,6 +1034,83 @@ def bench_dsa_attend_64k():
     yield from bench_dsa_attend(ctx=65536, layers=1)
 
 
+def bench_eva_decode(B=20, H=32, hd=128, ps=32, window=2048, chunk=16,
+                     layers=2, loop=LOOP):
+    """The EVA decode attention at the EvaByte cell's shape: 20 slots, 32
+    heads of 128 (MHA: one query row a KV head), contexts of 8,193-16,047
+    bytes (``long-agent``'s), so about 1,024 live window rows and 768
+    summary rows a slot: the paged decode kernel over the step's ONE
+    sequence of rows (ops/eva.py ``decode_view``), writing the step's
+    row, then the open chunk's summary row (``decode_summarize``).
+    Prints microseconds a launch and GB/s over the rows a step HAS to
+    read and write (live rows, real lengths), for the kernel alone and
+    with the summary's rewrite."""
+    from vgate_tpu.ops import eva
+    from vgate_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention_pallas,
+    )
+
+    rng = np.random.default_rng(0)
+    ctx = 16384
+    pages = B * (ctx // chunk // ps) + 1
+    R = window // ps
+    pool = jnp.asarray(rng.standard_normal(
+        (layers, H, pages + B * R, ps, hd)), jnp.bfloat16)
+    positions = jnp.asarray(rng.integers(8193, 16047, size=B), jnp.int32)
+    n = ctx // chunk // ps
+    tables = jnp.asarray(
+        1 + np.arange(B * n).reshape(B, n), jnp.int32)
+    win = eva.window_pages(pages, B, R)[:B]
+    view, rows = eva.decode_view(tables, win, positions, window, chunk, ps)
+    q = jnp.asarray(rng.standard_normal((B, H, hd)), jnp.bfloat16)
+    new = jnp.asarray(rng.standard_normal((B, H, hd)), jnp.bfloat16)
+    phi = jnp.asarray(rng.standard_normal((H, hd)), jnp.bfloat16)
+    live = int(np.sum(np.asarray(rows) + 1))
+    moved = (live + B) * 2 * H * hd * 2  # rows read + the row written
+
+    def attend(q, kp, vp):
+        return paged_decode_attention_pallas(
+            q, kp, vp, view, rows + 1, layer=0, k_new=new, v_new=new)
+
+    def attend_and_summarize(q, kp, vp):
+        out, kp, vp = attend(q, kp, vp)
+        kp, vp = eva.decode_summarize(
+            kp, vp, phi, phi, 0, tables, win, positions, None, window,
+            chunk, hd ** -0.5)
+        return out, kp, vp
+
+    for name, fn in (("kernel", attend),
+                     ("kernel + summary row", attend_and_summarize)):
+        @functools.partial(jax.jit, donate_argnums=(1, 2))
+        def run(q, kp, vp, fn=fn):
+            def body(carry, _):
+                kp, vp, acc = carry
+                out, kp, vp = fn(q + 0 * acc.astype(q.dtype), kp, vp)
+                return (kp, vp, out.astype(jnp.float32)), None
+
+            (kp, vp, acc), _ = jax.lax.scan(
+                body, (kp, vp, jnp.zeros(q.shape, jnp.float32)), None,
+                length=loop)
+            return acc, kp, vp
+
+        kp, vp = pool + 0, pool + 0  # fresh: a run donates its pools
+        times = []
+        for i in range(6):
+            t0 = time.perf_counter()
+            acc, kp, vp = run(q, kp, vp)
+            _sync(acc)
+            if i:
+                times.append((time.perf_counter() - t0) / loop)
+        us = float(np.median(times)) * 1e6
+        yield {"probe": "eva_decode", "form": name, "slots": B,
+               "heads": H, "live_rows_mean": live / B,
+               "window_rows_mean": float(np.mean(
+                   np.asarray(positions) % window + 1)),
+               "us_per_launch": round(us, 1),
+               "gb_per_s": round(moved / us / 1e3, 1),
+               "hbm_roofline_pct": round(100 * moved / us / 1e3 / 819, 1)}
+
+
 def main() -> None:
     device = jax.devices()[0]
     if device.platform != "tpu":
@@ -1047,6 +1124,10 @@ def main() -> None:
         return
     if sys.argv[1:] == ["swa_prefill"]:
         for line in bench_swa_prefill():
+            print(json.dumps(line), flush=True)
+        return
+    if sys.argv[1:] == ["eva_decode"]:
+        for line in bench_eva_decode():
             print(json.dumps(line), flush=True)
         return
     if sys.argv[1:2] == ["expert_layer"]:

@@ -726,6 +726,45 @@ FROZEN = {
         ),
         "mlp": "densex3 sparsex75",
     },
+    "EvaByte/EvaByte": {
+        "cut": (0, 1, 32),
+        "lead": "",
+        "period": "eva/layer/input_norm/0 mlp/layer/post_norm/0",
+        "layers": (32, 0, 0, 0, 0),
+        "kv_pools": 2,
+        "windows": "0x32",
+        "num_params": 6488330240,
+        "hybrid": True,
+        "recurrent": "",
+        "indexer": "",
+        "mlp": "sparsex32",
+    },
+    "tiny-eva": {
+        "cut": (0, 1, 4),
+        "lead": "",
+        "period": "eva/layer/input_norm/0 mlp/layer/post_norm/0",
+        "layers": (4, 0, 0, 0, 0),
+        "kv_pools": 2,
+        "windows": "0x4",
+        "num_params": 349248,
+        "hybrid": True,
+        "recurrent": "",
+        "indexer": "",
+        "mlp": "sparsex4",
+    },
+    "config:evabyte-6.5b-l8.json": {
+        "cut": (0, 1, 8),
+        "lead": "",
+        "period": "eva/layer/input_norm/0 mlp/layer/post_norm/0",
+        "layers": (8, 0, 0, 0, 0),
+        "kv_pools": 2,
+        "windows": "0x8",
+        "num_params": 1630932992,
+        "hybrid": True,
+        "recurrent": "",
+        "indexer": "",
+        "mlp": "sparsex8",
+    },
 }
 
 FIELDS = [
@@ -764,7 +803,8 @@ FIELDS = [
     ("window_pattern", ""), ("global_rope", True), ("first_k_dense", 0),
     ("indexer_pattern", ""), ("first_layer", 0), ("index_topk", 0),
     ("index_n_heads", 0), ("index_head_dim", 0),
-    ("indexer_rope_interleave", False),
+    ("indexer_rope_interleave", False), ("eva_window", 0),
+    ("eva_chunk", 0), ("num_pred_heads", 1), ("fp32_residual", False),
 ]
 
 

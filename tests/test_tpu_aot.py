@@ -963,3 +963,83 @@ def test_long_prompt_programs_loop_over_their_row_blocks_on_v5e(
     assert temp <= parent + (320 << 20), (
         f"{cell}: {temp} temporary bytes against the parent's {parent}")
     assert held + temp < 15.9e9
+
+
+# the EvaByte cut as its cell serves it: 8 of 32 layers, 20 slots of
+# 16,384 bytes of context
+EVA_CUT = ("EvaByte/EvaByte", {"num_layers": 8})
+EVA_SLOTS, EVA_CTX = 20, 16384
+
+
+def _eva_cut(A):
+    """(spec, abstract parameters, the pool's K (= V) array with the
+    slots' windows behind the allocator's pages, the state that names
+    them)."""
+    from vgate_tpu.models.hybrid import make_state
+
+    spec, params = _cut_and_shapes(A, *EVA_CUT)
+    pages = EVA_SLOTS * (EVA_CTX // spec.eva_chunk // PAGE) + 1
+    windows = EVA_SLOTS * spec.eva_window // PAGE
+    pool = A((spec.attn_layers, spec.num_kv_heads, pages + windows, PAGE,
+              spec.head_dim), jnp.bfloat16)
+    assert pool.shape == (8, 32, 641 + 1280, 32, 128)
+    state = jax.tree.map(lambda x: A(x.shape, x.dtype), jax.eval_shape(
+        lambda: make_state(spec, EVA_SLOTS, jnp.bfloat16, PAGE, pages)))
+    return spec, params, pool, state
+
+
+def test_eva_decode_chunk_compiles_on_v5e(v5e):
+    """The decode chunk of the cut at the published widths: both pool
+    arrays (summary pages and the slots' windows in one) aliased input
+    to output and never re-laid, the paged decode kernel launched under
+    its own name over the step's ONE sequence of rows, and its
+    temporaries (0.81 GB when written: the weights' re-laid copies)
+    far under the 8.06 GB of cache."""
+    from vgate_tpu.runtime.step_programs import _decode_chunk
+
+    A = _abstract(v5e)
+    spec, params, pool, state = _eva_cut(A)
+    B = EVA_SLOTS
+    compiled = _decode_chunk.lower(
+        params, spec, A((B,), jnp.int32), A((B,), jnp.int32), pool, pool,
+        A((B, EVA_CTX // spec.eva_chunk // PAGE), jnp.int32),
+        A((B,), jnp.bool_),
+        A((B,), jnp.float32), A((B,), jnp.float32), A((B,), jnp.int32),
+        A((2,), jnp.uint32), A((), jnp.uint32),
+        num_steps=8, use_pallas=True, max_position=EVA_CTX - 1,
+        seeds=A((B,), jnp.int32), steps=A((B,), jnp.int32),
+        all_greedy=True, guard=True, state=state,
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert _nbytes((pool, pool)) == 2 * 8 * 32 * 1921 * 32 * 128 * 2
+    assert mem.alias_size_in_bytes >= _nbytes((pool, pool)), (
+        "a pool is copied")
+    assert mem.temp_size_in_bytes < 1.0e9, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    assert "paged_decode_attention_pallas" in text
+    assert "{4,1,3,2,0" not in text, "XLA re-laid the pool out"
+
+
+def test_eva_prompt_program_fits_beside_the_cache_on_v5e(v5e):
+    """The 16,384-row prompt program of the cut: eight windows through
+    the flash kernel under the EVA layer's name, each behind every
+    chunk's summary; the pools aliased; no [16,384, 16,384] scores and no
+    [16,384, 11,008] activation of the feed-forward in the HLO (the
+    row-block loop); temporaries (1.91 GB when written) that fit beside
+    3.26 GB of weights and 8.06 GB of cache."""
+    A = _abstract(v5e)
+    spec, params, pool, state = _eva_cut(A)
+    compiled = _prompt_program(A, spec, params, pool, pool, state,
+                               bucket=EVA_CTX)
+    mem = compiled.memory_analysis()
+    held = _nbytes((params, pool, pool))
+    assert 11.2e9 < held < 11.4e9
+    assert mem.alias_size_in_bytes >= _nbytes((pool, pool))
+    assert mem.temp_size_in_bytes < 2.1e9, mem.temp_size_in_bytes
+    assert held + mem.temp_size_in_bytes < 0.86 * 16.9e9
+    text = compiled.as_text()
+    assert "eva_prefill_attention_pallas" in text
+    assert "{4,1,3,2,0" not in text, "XLA re-laid the pool out"
+    _assert_no_buffer(text, EVA_CTX, EVA_CTX)
+    _assert_no_buffer(text, EVA_CTX, spec.intermediate_size)
+    _assert_no_buffer(text, f"1,{EVA_CTX}", spec.intermediate_size)

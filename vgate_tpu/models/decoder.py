@@ -26,6 +26,7 @@ capability the reference delegates to vLLM (vgate/backends/vllm_backend.py:51).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -84,8 +85,8 @@ def init_params(
             "layers": init_layers(spec, key, dtype, draw, norm_init),
             "final_norm": norm_init((D,), dtype),
         }
-        if not spec.tie_embeddings:
-            params["lm_head"] = draw(keys[9], (D, V))
+        if not spec.tie_embeddings:  # ``num_pred_heads`` heads' columns
+            params["lm_head"] = draw(keys[9], (D, V * spec.num_pred_heads))
         return params
     layers: Dict[str, Any] = {
         "input_norm": norm_init((L, D), dtype),
@@ -185,12 +186,22 @@ def _mlp(x, lp, spec: ModelSpec):
 
 
 @jax.named_scope("logits")
-def _logits(params: Params, spec: ModelSpec, x: jnp.ndarray) -> jnp.ndarray:
+def _logits(params: Params, spec: ModelSpec, x: jnp.ndarray,
+            all_heads: bool = False) -> jnp.ndarray:
+    """The final norm and the output layer.  A spec with several
+    prediction heads (``num_pred_heads``: head p scores the token at t +
+    1 + p) serves head 0's ``vocab_size`` columns; ``all_heads``: every
+    head's, ``[..., heads x vocab]``."""
     from vgate_tpu.ops.attention import _softcap
 
     x = rms_norm(
         x, params["final_norm"], spec.rms_eps, spec.unit_offset_norm
     )
+    if spec.fp32_residual:  # the product takes the weights' type
+        x = x.astype(params["final_norm"].dtype)
+    if spec.num_pred_heads > 1 and not all_heads:
+        params = {**params,
+                  "lm_head": params["lm_head"][:, :spec.vocab_size]}
     if spec.tie_embeddings:
         # embeddings are never quantized (gathers stay high-precision)
         logits = jnp.einsum(
@@ -442,6 +453,34 @@ def _swa_prefill_attend(spec: ModelSpec, impl: str, seq_lens):
         q, k, v, seq_lens, window=spec.sliding_window)
 
 
+def _eva_prefill_attend(spec: ModelSpec, impl: str):
+    """``prefill_forward``'s attention for a whole prompt of whole
+    windows of an EVA spec (models/hybrid.py ``_eva_prompt``),
+    ``attend(q, k, v, lens, q_offset, k_start)``: a window's queries
+    from key ``q_offset`` on, causal, over the keys ``k_start .. lens -
+    1``.  The flash kernel under a name of its own, in blocks of up to
+    1,024 rows that divide the summaries' count and the window, or the
+    blockwise jnp twin."""
+    if not spec.eva_layers:
+        return None
+    if impl == "pallas":
+        from vgate_tpu.ops.pallas.flash_prefill import (
+            flash_prefill_attention_pallas,
+        )
+
+        def attend(q, k, v, lens, q_offset, k_start):
+            return flash_prefill_attention_pallas(
+                q, k, v, lens, q_offsets=q_offset, k_starts=k_start,
+                block_q=min(1024, q.shape[1]),
+                block_k=min(1024, math.gcd(k.shape[1], q.shape[1])),
+                skip_padding=True, name="eva_prefill_attention_pallas")
+
+        return attend
+    return lambda q, k, v, lens, q_offset, k_start: flash_prefill_attention(
+        q, k, v, lens, q_offset=q_offset, k_start=k_start,
+        block_k=math.gcd(k.shape[1], 256))
+
+
 def multitok_attention_impl(
     use_pallas: bool, mesh=None, rows: int = 1, unaligned: bool = False,
     latent: bool = False,
@@ -509,6 +548,7 @@ def prefill_forward(
     use_pallas: bool = False,
     state=None,  # hybrid specs: the recurrent state (models/hybrid.py)
     slots=None,  # [B] decode slot of each row (its row of the state)
+    all_heads: bool = False,  # every prediction head's logits (_logits)
 ) -> Tuple[jnp.ndarray, ...]:
     """Run the prompt pass: returns (last-token logits [B, V], k_pages,
     v_pages), and the recurrent state after them for a hybrid spec.
@@ -592,8 +632,9 @@ def prefill_forward(
             swa_attend=_swa_prefill_attend(spec, impl, seq_lens),
             dsa_attend=(_dsa_prefill_attend(spec, impl, seq_lens, S)
                         if spec.is_dsa else None),
+            eva_attend=_eva_prefill_attend(spec, impl),
         )
-        return (_logits(params, spec, _last_rows(x, seq_lens)),
+        return (_logits(params, spec, _last_rows(x, seq_lens), all_heads),
                 k_pages, v_pages, state)
 
     def body(h, lp, win, kp, vp, layer):
@@ -611,7 +652,8 @@ def prefill_forward(
     x, k_pages, v_pages = _kv_layer_scan(
         params, spec, body, x, k_pages, v_pages
     )
-    return _logits(params, spec, _last_rows(x, seq_lens)), k_pages, v_pages
+    return (_logits(params, spec, _last_rows(x, seq_lens), all_heads),
+            k_pages, v_pages)
 
 
 @jax.named_scope("qkv")
@@ -793,6 +835,7 @@ def decode_forward(
     use_pallas: bool = False,
     mesh=None,  # pp>1 routes through the pipeline-parallel stage relay
     state=None,  # hybrid specs: the recurrent state, row = slot
+    all_heads: bool = False,  # every prediction head's logits (_logits)
 ) -> Tuple[jnp.ndarray, ...]:
     """One continuous-batching decode step: returns (logits [B, V],
     caches); a hybrid spec adds the recurrent state and the expert
@@ -864,8 +907,18 @@ def decode_forward(
                 tp_paged_decode_attention, attn_fn, mesh
             )
     ps = page_tokens(k_pages)
+    # the rows a step attends to and writes among, as a paged sequence:
+    # the sequence's own pages, or an EVA spec's view of them
+    attn_tables, attn_rows = page_tables, positions
+    if spec.eva_layers:
+        from vgate_tpu.ops import eva
+
+        win_pages = state["eva_pages"][:tokens.shape[0]]
+        attn_tables, attn_rows = eva.decode_view(
+            page_tables, win_pages, positions, spec.eva_window,
+            spec.eva_chunk, ps)
     seq_lens, page_ids, page_off = decode_attn_inputs(
-        positions, page_tables, active, ps
+        attn_rows, attn_tables, active, ps
     )
     if impl != "jnp" and active is not None:
         # the kernel spends nothing on a row of length 0 (no DMA, no
@@ -901,7 +954,7 @@ def decode_forward(
 
         return write_attend
 
-    write_attend = cache_step(attn_fn, page_tables, page_ids)
+    write_attend = cache_step(attn_fn, attn_tables, page_ids)
     x = _embed(params, spec, tokens)  # [B, D]
     if spec.is_mla:
         write_attend = _mla_write_attend(
@@ -936,12 +989,19 @@ def decode_forward(
                     ring_fn, ring_tables,
                     decode_attn_inputs(positions, ring_tables, active, ps)[1]),
                 window=spec.sliding_window)
+        eva_step = None
+        if spec.eva_layers:
+            eva_step = lambda kp, vp, lp, layer: eva.decode_summarize(
+                kp, vp, lp["eva_phi"], lp["eva_mu"], layer, page_tables,
+                win_pages, positions, active, spec.eva_window,
+                spec.eva_chunk, spec.head_dim ** -0.5)
         x, k_pages, v_pages, state, stats = hybrid.decode_forward(
             params, spec, x, positions, k_pages, v_pages, state, active,
             write_attend, use_pallas, ring_write_attend=ring_step,
-            dsa_steps=dsa_steps,
+            dsa_steps=dsa_steps, eva_summarize=eva_step,
         )
-        return _logits(params, spec, x), k_pages, v_pages, state, stats
+        return (_logits(params, spec, x, all_heads), k_pages, v_pages,
+                state, stats)
 
     # the FULL [L, ...] pools ride the scan carry with layer-indexed
     # in-place updates, and attention reads the pool at layer l directly
@@ -957,7 +1017,7 @@ def decode_forward(
     x, k_pages, v_pages = _kv_layer_scan(
         params, spec, body, x, k_pages, v_pages
     )
-    return _logits(params, spec, x), k_pages, v_pages
+    return _logits(params, spec, x, all_heads), k_pages, v_pages
 
 
 def prefill_suffix_forward(
@@ -1079,8 +1139,10 @@ def prefill_suffix_forward(
         x, k_pages, v_pages, state = hybrid.prompt_forward(
             params, spec, x, suffix_lens, positions, k_pages, v_pages,
             state, slots, prefix_lens == 0, suffix_page_tables, attend,
-            kernels, ctx_tables=ctx_page_tables if spec.is_mla else None,
-            prefix_lens=prefix_lens if spec.swa_layers else None,
+            kernels, ctx_tables=(
+                ctx_page_tables if spec.is_mla or spec.eva_layers else None),
+            prefix_lens=(prefix_lens if spec.swa_layers or spec.eva_layers
+                         else None),
             # a later chunk's or a suffix's rows under a selection: the
             # jnp twin over the gathered context (no kernel yet)
             dsa_attend=(_dsa_prefill_attend(spec, "jnp", total_lens, S)

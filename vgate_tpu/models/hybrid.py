@@ -24,7 +24,13 @@ whichever way the spec's fields spell them; the walker takes from it
 * ``swa``   softmax attention over the last ``sliding_window`` tokens
   (K-EXAONE's window layers), whose K/V is the decode slot's RING and
   holds no page of the pool (below),
-* ``mlp``   a dense SwiGLU feed-forward (a leading layer's),
+* ``eva``   EVA attention (EvaByte; ops/eva.py): a token is held exactly
+  while its window of ``eva_window`` is open, in the slot's pages BEHIND
+  the allocator's in the same pool arrays, and as its chunk's summary
+  row in the sequence's pages once the window has closed; exact rows
+  and summary rows meet in one softmax,
+* ``mlp``   a dense SwiGLU feed-forward (a leading layer's, or every
+  layer's of an ``eva`` stack),
 * ``moe``   the expert layer of ``ops/moe.py``.
 
 Qwen3-Next's layer is two sub-blocks (a mixer, then experts); its period
@@ -104,11 +110,11 @@ import jax
 import jax.numpy as jnp
 
 from vgate_tpu.models.specs import ModelSpec
-from vgate_tpu.ops import dsa
+from vgate_tpu.ops import dsa, eva
 from vgate_tpu.ops import gated_delta as gd
 from vgate_tpu.ops import ssd
 from vgate_tpu.ops.kv_quant import (
-    by_pairs, gather_pages, kv_write_pages, page_tokens,
+    by_pairs, gather_pages, kv_write_pages, kv_write_tokens, page_tokens,
 )
 from vgate_tpu.ops.moe import STAT_NAMES, combine_stats, expert_layer
 from vgate_tpu.ops.norms import rms_norm
@@ -123,6 +129,8 @@ def init_layers(spec: ModelSpec, key, dtype, normal, norm_init
     keys of its own."""
     if spec.is_dsa:
         return _init_dsa_layers(spec, key, dtype, normal)
+    if spec.eva_layers:
+        return _init_eva_layers(spec, key, dtype, normal, norm_init)
     if spec.is_mla:
         return _init_mla_layers(spec, key, dtype, normal, norm_init)
     if spec.window_pattern:
@@ -141,6 +149,42 @@ def _per_layer(spec: ModelSpec, group: str, fn):
     return jax.jit(lambda k: jax.lax.map(
         lambda i: fn(jax.random.fold_in(k, i)), jnp.arange(P * n)
     ).reshape((P, n) + jax.eval_shape(fn, k).shape))
+
+
+def _init_eva_layers(spec: ModelSpec, key, dtype, normal, norm_init
+                     ) -> Dict[str, Any]:
+    """An EVA stack's tensors (every layer ``eva mlp``) from
+    ``fold_in(key, 44)`` split 16 ways, tensor ``j`` of layer ``i`` from
+    ``fold_in(key j, i)``.  The matrices N(0, 0.02); the norms at their
+    identity.  ``q`` and ``k`` N(0, 1 / hidden): a query's and a key's
+    elements then have a standard deviation near 1 and so have the
+    scores ``s q . k``, at the published width and at a toy one (at 0.02
+    and a hidden size of 64 they spread by 0.03: attention is flat, and
+    a wrong window, a stale summary row or a summary of the wrong chunk
+    would hide under a comparison's tolerance).  The two learned vectors
+    of a head N(0, 1): ``s k . phi`` spreads by about 1 inside a chunk,
+    so the chunk softmax is far from uniform, and ``mu`` is of the keys'
+    own size."""
+    ek = jax.random.split(jax.random.fold_in(key, 44), 16)
+    D, H, KV, hd = (spec.hidden_size, spec.num_heads, spec.num_kv_heads,
+                    spec.head_dim)
+    F = spec.intermediate_size
+    lead = (spec.num_periods, 1)
+    draw = lambda j, shape, scale=0.02: _per_layer(
+        spec, "layer", lambda kk: normal(kk, shape, scale))(ek[j])
+    return {"layer": {
+        "input_norm": norm_init(lead + (D,), dtype),
+        "post_norm": norm_init(lead + (D,), dtype),
+        "q": {"w": draw(0, (D, H * hd), D ** -0.5)},
+        "k": {"w": draw(1, (D, KV * hd), D ** -0.5)},
+        "v": {"w": draw(2, (D, KV * hd))},
+        "o": {"w": draw(3, (H * hd, D))},
+        "eva_phi": draw(4, (KV, hd), 1.0),
+        "eva_mu": draw(5, (KV, hd), 1.0),
+        "gate": {"w": draw(6, (D, F))},
+        "up": {"w": draw(7, (D, F))},
+        "down": {"w": draw(8, (F, D))},
+    }}
 
 
 def _init_mla_layers(spec: ModelSpec, key, dtype, normal, norm_init
@@ -500,12 +544,23 @@ def _ring_shape(spec: ModelSpec, slots: int, page_size: int) -> tuple:
             spec.head_dim)
 
 
-def make_state(spec: ModelSpec, slots: int, dtype, page_size: int = 0
-               ) -> Dict[str, jax.Array]:
+def eva_window_pages(spec: ModelSpec, page_size: int) -> int:
+    """Pool pages a slot's open window holds in an EVA layer."""
+    return spec.eva_window // page_size
+
+
+def make_state(spec: ModelSpec, slots: int, dtype, page_size: int = 0,
+               pool_pages: int = 0) -> Dict[str, jax.Array]:
     """What a spec keeps a decode SLOT beside the paged pool, zeros: the
     recurrent state of every recurrent layer (one row a slot), the K
-    and V rings of every window layer (``page_size`` the pool's)."""
+    and V rings of every window layer (``page_size`` the pool's).  An
+    EVA spec's open windows are pages of the POOL arrays behind the
+    allocator's ``pool_pages`` (runtime/kv_cache.py ``slot_pages``);
+    its state is their ids alone (``ops/eva.py window_pages``)."""
     out = {}
+    if spec.eva_layers:
+        out.update(eva_pages=eva.window_pages(
+            pool_pages, slots, eva_window_pages(spec, page_size)))
     if spec.linear_layers:
         tile, tail = _state_shapes(spec)
         lead = (spec.linear_layers, slots)
@@ -530,6 +585,9 @@ def state_bytes_per_slot(spec: ModelSpec, dtype_bytes: int,
         _, KV, _, ps, hd = _ring_shape(spec, 1, page_size)
         out += (spec.swa_layers * 2 * KV * ring_pages(spec, page_size)
                 * ps * hd * dtype_bytes)
+    if spec.eva_layers:  # K and V of the open window's rows
+        out += (spec.eva_layers * 2 * spec.num_kv_heads * spec.eva_window
+                * spec.head_dim * dtype_bytes)
     return out
 
 
@@ -1271,7 +1329,8 @@ def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
     lead = layers.pop("lead", ())
     if "full" in layers:  # one attention layer a period: [P, ...]
         layers["full"] = jax.tree.map(lambda a: a[:, None], layers["full"])
-    names = spec.expert_stacks
+    # (a stack without expert layers: its dense matrices ride the slices)
+    names = spec.expert_stacks if spec.moe_layers else ()
     light = {g: {k: v for k, v in d.items() if k not in names}
              for g, d in layers.items()}
     flat = lambda w: jax.tree.map(
@@ -1285,6 +1344,9 @@ def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
         kind, _, norm, _ = block
         h, kp, vp, st = carry
         normed = lambda h: rms_norm(h, lp[norm], spec.rms_eps, uo)
+        if spec.fp32_residual:  # the products take the weights' type
+            normed = lambda h: rms_norm(
+                h, lp[norm], spec.rms_eps, uo).astype(lp[norm].dtype)
         if slice_late:  # the norm too: in each loop over blocks of rows
             out, kp, vp, st, stats = block_fn(
                 kind, h, lp, kp, vp, st, index, stack, norm=normed)
@@ -1367,6 +1429,8 @@ def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
     stats = stats.reshape(-1, len(STAT_NAMES))
     if lead_stats:
         stats = jnp.concatenate([jnp.stack(lead_stats), stats])
+    if not spec.moe_layers:  # no expert layer: nothing counted
+        stats = jnp.zeros((1, len(STAT_NAMES)), jnp.int32)
     return x, k_pages, v_pages, state, combine_stats(stats)
 
 
@@ -1442,6 +1506,105 @@ def _swa_chunk_attend(q, k, v, ring_k, ring_v, index, spec: ModelSpec,
     )
 
 
+def _eva_prompt(normed, lp, spec: ModelSpec, positions, kp, vp, win, index,
+                lens, write_tables, ctx_tables, prefix_lens, attend,
+                eva_attend, n_rows=None, norm=_as_is):
+    """EVA attention over prompt rows normed [B, S, D] (``win`` [B,
+    pages a window]: the pool pages of each row's slot's open window).
+    The rows' chunk summaries go to the sequence's pages, the rows of
+    the last window they reach to the slot's window pages.  A WHOLE
+    prompt of whole windows attends window by window through the
+    caller's flash attention (``eva_attend(q, k, v, lens, q_offset,
+    k_start)``): a window's queries meet ``[every chunk's summary,
+    newest first | the window's own rows]``, of which they see the
+    closed windows' summaries (``k_start``) and, causally, their own
+    rows: one softmax, one launch, and no block of keys that nobody
+    sees is read; a prompt inside one window is plain causal attention
+    (``attend``).  Any other rows (a later chunk of a chunked prefill,
+    from ``prefix_lens``; a bucket that is no whole number of windows)
+    attend to ``[the pool's summary rows | the window's pages as they
+    stand | their own rows]`` under ``ops/eva.py interval_attention``.
+    Returns (out, k_pages, v_pages)."""
+    B, S = normed.shape[:2]
+    ps = page_tokens(kp)
+    W, c = spec.eva_window, spec.eva_chunk
+    H, KV, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
+    R, scale = W // ps, hd ** -0.5
+    by_rows = lambda fn, *rows: _by_row_blocks(fn, rows, n_rows)
+    to_pages = lambda t: jnp.transpose(
+        t.reshape(B, -1, ps, KV, hd), (0, 1, 3, 2, 4))
+    q, k, v, _ = by_rows(
+        lambda rows, positions: _gated_qkv(norm(rows), lp, spec, positions),
+        normed, positions)
+    valid = jnp.arange(S)[None, :] < lens[:, None]
+    ks, vs = eva.summarize(k, v, lp["eva_phi"], lp["eva_mu"], valid, c,
+                           scale)  # [B, S / c, KV, hd]
+    if prefix_lens is None and (S <= W or S % W == 0):
+        n_sum = cdiv(S // c, ps)  # whole pages from the sequence's first
+        pad = ((0, 0), (0, n_sum * ps - S // c), (0, 0), (0, 0))
+        with jax.named_scope("eva_summarize"):
+            kp = kv_write_pages(kp, write_tables[:, :n_sum],
+                                to_pages(jnp.pad(ks, pad)), layer=index)
+            vp = kv_write_pages(vp, write_tables[:, :n_sum],
+                                to_pages(jnp.pad(vs, pad)), layer=index)
+        if S <= W:
+            with jax.named_scope("eva_attend"):
+                attn = attend(q, k, v, kp, vp, index)
+            tables, last_k, last_v = win[:, :S // ps], k, v
+        else:
+            nw, ns = S // W, S // c
+            fold = lambda t: t.reshape((B * nw, W) + t.shape[2:])
+            spread = lambda t: jnp.broadcast_to(
+                jnp.flip(t, axis=1)[:, None], (B, nw) + t.shape[1:]
+            ).reshape((B * nw,) + t.shape[1:])
+            cat = lambda a, b: jnp.concatenate([spread(a), fold(b)], axis=1)
+            first = jnp.arange(nw, dtype=jnp.int32)[None, :] * W
+            own = jnp.clip(lens[:, None] - first, 0, W).reshape(-1)
+            closed = jnp.broadcast_to(first // c, (B, nw)).reshape(-1)
+            with jax.named_scope("eva_attend"):
+                attn = eva_attend(
+                    fold(q), cat(ks, k), cat(vs, v), ns + own,
+                    jnp.full_like(own, ns), ns - closed,
+                ).reshape(B, S, H, hd)
+            at = (lens - 1) // W * W  # the last window a row reaches
+            cut = lambda t: jax.vmap(
+                lambda rows, lo: jax.lax.dynamic_slice_in_dim(rows, lo, W)
+            )(t, at)
+            tables, last_k, last_v = win, cut(k), cut(v)
+    else:
+        start = jnp.zeros_like(lens) if prefix_lens is None else prefix_lens
+        ctx = write_tables if ctx_tables is None else ctx_tables
+        # the rows' summaries into the pool rows their chunks own
+        j = start[:, None] // c + jnp.arange(S // c)[None, :]
+        there = jnp.arange(S // c)[None, :] < cdiv(lens, c)[:, None]
+        ids = jnp.where(there, jnp.take_along_axis(
+            ctx, jnp.minimum(j // ps, ctx.shape[1] - 1), axis=1), 0)
+        held = (eva.gather_rows(kp, win, index),
+                eva.gather_rows(vp, win, index))  # before the rows land
+        with jax.named_scope("eva_summarize"):
+            kp = kv_write_tokens(kp, ids, j % ps, ks, layer=index)
+            vp = kv_write_tokens(vp, ids, j % ps, vs, layer=index)
+        keys = jnp.concatenate(
+            [eva.gather_rows(kp, ctx, index), held[0], k], axis=1)
+        vals = jnp.concatenate(
+            [eva.gather_rows(vp, ctx, index), held[1], v], axis=1)
+        lo, hi = eva.chunk_keys(start, lens, S, ctx.shape[1] * ps, W, c)
+        attn = eva.interval_attention(q, positions, keys, vals, lo, hi,
+                                      scale)
+        # the pages of the last window the rows reach keep their place
+        page = start[:, None] // ps + jnp.arange(S // ps)[None, :]
+        keep = page // R == ((start + lens - 1) // W)[:, None]
+        tables = jnp.where(
+            keep, jnp.take_along_axis(win, page % R, axis=1), 0)
+        last_k, last_v = k, v
+    with jax.named_scope("kv_write"):
+        kp = kv_write_pages(kp, tables, to_pages(last_k), layer=index)
+        vp = kv_write_pages(vp, tables, to_pages(last_v), layer=index)
+    out = by_rows(lambda attn: _gated_out(attn, None, lp, attn.dtype),
+                  _heads_flat(attn))
+    return out, kp, vp
+
+
 def _without_selection(state):
     """The state without the walk's own ``"sel"`` (None if that was all)."""
     if state and "sel" in state:
@@ -1452,7 +1615,8 @@ def _without_selection(state):
 def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
                    v_pages, state, slots, fresh, write_tables, attend,
                    use_pallas: bool, ctx_tables=None, swa_attend=None,
-                   prefix_lens=None, dsa_attend=None, total_lens=None):
+                   prefix_lens=None, dsa_attend=None, total_lens=None,
+                   eva_attend=None):
     """The prompt pass over embedded rows x [B, S, D] (a whole prompt,
     or the suffix / one chunk of one).  ``write_tables`` are the pages
     the rows' K/V go to (whole pages from the rows' first position);
@@ -1466,8 +1630,12 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
     (``is_dsa``): ``v_pages`` is the index keys' array,
     ``dsa_attend(q, k, v, mask)`` the attention under a selection and
     ``total_lens`` the contexts' lengths (``lens`` for a whole prompt).
+    An EVA spec: ``eva_attend`` is ``_eva_prompt``'s, and a later
+    chunk's rows come with ``prefix_lens`` and ``ctx_tables``.
     Returns (x, k_pages, v_pages, state)."""
     B, S = x.shape[:2]
+    if spec.fp32_residual:
+        x = x.astype(jnp.float32)
     ps = page_tokens(k_pages)
     KV, hd = spec.cache_heads, spec.cache_head_dim
     n_pages = S // ps
@@ -1524,6 +1692,14 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
         o_proj = lambda attn, gate: by_rows(
             lambda attn, gate: _gated_out(attn, gate, lp, normed.dtype),
             _heads_flat(attn), _heads_flat(gate))
+        if kind == "eva":
+            with jax.named_scope("eva_attn"):
+                out, kp, vp = _eva_prompt(
+                    normed, lp, spec, positions, kp, vp,
+                    st["eva_pages"][slots], index, lens, write_tables,
+                    ctx_tables, prefix_lens, attend, eva_attend, n_rows,
+                    norm)
+            return out, kp, vp, st, None
         if kind == "swa":
             with jax.named_scope("swa_attn"):
                 q, k, v, _ = qkv(True)
@@ -1562,14 +1738,20 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
 
 def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
                    state, active, write_attend, use_pallas: bool,
-                   ring_write_attend=None, dsa_steps=None):
+                   ring_write_attend=None, dsa_steps=None,
+                   eva_summarize=None):
     """One decode step over embedded rows x [B, D], row = slot.
     ``write_attend(q, k, v, kp, vp, layer)`` is the caller's cache step:
     the token's K and V into the pool and its attention over it;
     ``ring_write_attend`` the same over a window layer's rings;
     ``dsa_steps`` (a spec that picks, ``v_pages`` its index keys' array)
-    ``(pick, attend, the positions a pick holds)``: ``_dsa_step``.
+    ``(pick, attend, the positions a pick holds)``: ``_dsa_step``.  An
+    EVA spec: ``write_attend`` runs over the step's rows as ONE paged
+    sequence (ops/eva.py ``decode_view``), and ``eva_summarize(kp, vp,
+    lp, layer)`` then rewrites the open chunk's summary row.
     Returns (x, k_pages, v_pages, state, stats [4])."""
+    if spec.fp32_residual:
+        x = x.astype(jnp.float32)
     if active is None:
         active = jnp.ones(x.shape[:1], bool)
 
@@ -1606,6 +1788,16 @@ def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
             with jax.named_scope("mla_attn"):
                 out, kp, vp = _mla_step(normed, lp, spec, positions, kp,
                                         vp, index, write_attend)
+            return out, kp, vp, st, None
+        if kind == "eva":
+            with jax.named_scope("eva_attn"):
+                q, k, v, _ = _gated_qkv(
+                    normed[:, None], lp, spec, positions[:, None])
+                with jax.named_scope("eva_attend"):
+                    attn, kp, vp = write_attend(
+                        q[:, 0], k[:, 0], v[:, 0], kp, vp, index)
+                kp, vp = eva_summarize(kp, vp, lp, index)
+                out = _gated_out(_heads_flat(attn), None, lp, normed.dtype)
             return out, kp, vp, st, None
         if kind == "swa":
             with jax.named_scope("swa_attn"):

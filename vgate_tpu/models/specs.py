@@ -168,6 +168,20 @@ class ModelSpec:
     index_n_heads: int = 0
     index_head_dim: int = 0
     indexer_rope_interleave: bool = False
+    # ---- EVA attention (EvaByte; ops/eva.py): every layer holds a token
+    # EXACTLY while its window of ``eva_window`` tokens is open (a buffer
+    # a decode slot) and, once the window has closed, as its share of ONE
+    # learned summary row for every ``eva_chunk`` tokens in the paged
+    # pool (``cache_row_tokens``); exact rows and summary rows meet in
+    # one softmax.  0 = softmax attention over every cached token
+    eva_window: int = 0
+    eva_chunk: int = 0
+    # heads of the untied output layer, ``vocab_size`` columns each: head
+    # p scores the token at t + 1 + p; serving samples from head 0
+    num_pred_heads: int = 1
+    # the residual stream is float32 (the published ``fp32_skip_add``);
+    # every matrix product still takes the weights' type
+    fp32_residual: bool = False
 
     def __post_init__(self):
         if self.n_shared_experts and not self.shared_expert_intermediate_size:
@@ -265,6 +279,8 @@ class ModelSpec:
         """How THIS spec states its stack: the one place that looks."""
         if self.layer_pattern:
             return _LETTERS
+        if self.eva_window:
+            return _EVA
         if self.window_pattern:
             return _WINDOW
         if self.indexer_pattern:
@@ -279,7 +295,7 @@ class ModelSpec:
     def stack(self) -> tuple:
         """The layers of this spec, one entry each (``num_layers`` of
         them, ``first_layer`` applied): the layer's residual sub-blocks'
-        kinds in order, of the eight the stack walker knows
+        kinds in order, of the nine the stack walker knows
         (models/hybrid.py).  Everything below derives from it alone."""
         first = self.first_layer
         mine = self._spelling.parse(self)[first:first + self.num_layers]
@@ -357,8 +373,9 @@ class ModelSpec:
 
     @property
     def attn_layers(self) -> int:
-        """Layers that hold pages (K/V, or the latent)."""
-        return self._layers_of("attn", "mla", "dsa")
+        """Layers that hold pages (K/V, the latent, or EVA's summary
+        rows)."""
+        return self._layers_of("attn", "mla", "dsa", "eva")
 
     @property
     def index_layers(self) -> int:
@@ -377,11 +394,24 @@ class ModelSpec:
         return self._layers_of("swa")
 
     @property
+    def eva_layers(self) -> int:
+        """Layers whose open window is a buffer a decode slot and whose
+        pages hold one summary row for every ``eva_chunk`` tokens."""
+        return self._layers_of("eva")
+
+    @property
+    def cache_row_tokens(self) -> int:
+        """Tokens ONE row of the paged pool stands for: what a
+        sequence's pages are counted by (runtime/kv_cache.py
+        ``KVGeometry.row_tokens``, the scheduler's ``page_tokens``)."""
+        return self.eva_chunk if self.eva_layers else 1
+
+    @property
     def slot_state_layers(self) -> int:
         """Layers whose cache is a row a decode SLOT beside the paged
-        pool (recurrent state or ring): what pages alone cannot move,
-        share or roll back."""
-        return self.linear_layers + self.swa_layers
+        pool (recurrent state, ring or open window): what pages alone
+        cannot move, share or roll back."""
+        return self.linear_layers + self.swa_layers + self.eva_layers
 
     @property
     def moe_layers(self) -> int:
@@ -491,8 +521,11 @@ class ModelSpec:
             moe += 2 * D * W
         if Fs and self.shared_expert_gate:
             moe += D
+        # EVA attention: q, k, v, o and a head's two learned vectors
+        # (the chunk softmax's query and the pooled key's shift)
+        eva = 2 * D * self.q_dim + 2 * D * self.kv_dim + 2 * H * hd
         return {"attn": gqa, "swa": gqa, "mla": mla, "dsa": mla + indexer,
-                "gdn": gdn, "mamba": mamba,
+                "eva": eva, "gdn": gdn, "mamba": mamba,
                 "mlp": 3 * D * self.intermediate_size, "moe": moe}
 
     @property
@@ -504,7 +537,8 @@ class ModelSpec:
         D, per = self.hidden_size, self._kind_params()
         norm = 2 * D if self.ffn_sandwich else D
         blocks = sum(per[k] + norm for layer in self.stack for k in layer)
-        tables = (1 if self.tie_embeddings else 2) * self.vocab_size * D
+        heads = 0 if self.tie_embeddings else self.num_pred_heads
+        tables = (1 + heads) * self.vocab_size * D
         return blocks + tables + D
 
     # an indexer spec's pattern as the published config.json lists it,
@@ -660,6 +694,9 @@ _INDEXER = _Spelling(_parse_indexer, True, {"dsa": "pick", "mla": "reuse"})
 # latent attention without an indexer: every layer alike
 _LATENT = _Spelling(lambda spec: (("mla", "moe"),) * _depth(spec), False,
                     {"mla": "layer"})
+# EVA attention: every layer alike, its feed-forward a dense SwiGLU
+_EVA = _Spelling(lambda spec: (("eva", "mlp"),) * _depth(spec), False,
+                 {"eva": "layer"})
 # models/decoder.py's own layers: a stack for counting only
 _DENSE = _Spelling(
     lambda spec: (("attn", "moe" if spec.is_moe else "mlp"),) * _depth(spec),
@@ -1372,6 +1409,64 @@ TINY_DSA_MOE = _register(
         index_n_heads=4,
         index_head_dim=16,
         indexer_rope_interleave=True,
+    )
+)
+
+# EvaByte 6.5B (model_type evabyte): a byte-level model, 32 layers of
+# EVA attention (32 heads of 128, MHA; windows of 2,048 tokens held
+# exactly, chunks of 16 summarised once their window has closed) and a
+# SwiGLU of 11,008, RMSNorm with weight 1 + w, a float32 residual
+# stream, an untied output layer of 8 heads x 320 bytes
+EVABYTE = _register(
+    ModelSpec(
+        name="EvaByte/EvaByte",
+        vocab_size=320,
+        hidden_size=4096,
+        num_layers=32,
+        num_heads=32,
+        num_kv_heads=32,
+        head_dim=128,
+        intermediate_size=11008,
+        rope_theta=100_000.0,
+        rms_eps=1e-5,
+        qkv_bias=False,
+        tie_embeddings=False,
+        eos_token_id=2,
+        bos_token_id=1,
+        max_position_embeddings=32768,
+        unit_offset_norm=True,
+        eva_window=2048,
+        eva_chunk=16,
+        num_pred_heads=8,
+        fp32_residual=True,
+    )
+)
+
+# every mechanism of EvaByte at toy widths: windows of 32 tokens and
+# chunks of 4 (8 summary rows a window: two pages of 4), so that a CPU
+# test's contexts close several windows
+TINY_EVA = _register(
+    ModelSpec(
+        name="tiny-eva",
+        vocab_size=320,
+        hidden_size=64,
+        num_layers=4,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=16,
+        intermediate_size=128,
+        rope_theta=10000.0,
+        rms_eps=1e-5,
+        qkv_bias=False,
+        tie_embeddings=False,
+        eos_token_id=0,
+        bos_token_id=1,
+        max_position_embeddings=4096,
+        unit_offset_norm=True,
+        eva_window=32,
+        eva_chunk=4,
+        num_pred_heads=8,
+        fp32_residual=True,
     )
 )
 

@@ -448,6 +448,7 @@ class PerfRecorder:
         self._state: Dict[str, int] = {}
         self._mla: Dict[str, int] = {}
         self._swa: Dict[str, int] = {}
+        self._eva: Dict[str, int] = {}
         self._dsa: Dict[str, int] = {}
         # rows of the prompt programs read back (every family)
         self._prefill = dict.fromkeys(
@@ -874,6 +875,50 @@ class PerfRecorder:
         ):
             self._swa[name] = self._swa.get(name, 0) + add
 
+    def note_eva_decode(self, steps: int, lens, layers: int, window: int,
+                        chunk: int) -> None:
+        """One decode chunk of a spec of EVA layers, booked once per
+        readback from the sequences' real lengths ``lens`` (at
+        dispatch): step k of a sequence is the token at ``t = n - 1 +
+        k``, which reads the ``t mod window + 1`` live rows of its open
+        window and one summary row for every chunk of the ``t //
+        window`` closed ones, writes its own row and the open chunk's
+        summary row, in each of ``layers`` layers, and closes a window
+        when it fills the last row."""
+        per = window // chunk
+        win = ch = closed = 0
+        for n in lens:
+            for t in range(n - 1, n - 1 + steps):
+                win += t % window + 1
+                ch += per * (t // window)
+                closed += (t + 1) % window == 0
+        for name, add in (
+            ("decode_steps", steps),
+            ("window_rows_read", win * layers),
+            ("chunk_rows_read", ch * layers),
+            ("window_rows_written", steps * len(lens) * layers),
+            ("chunk_rows_written", steps * len(lens) * layers),
+            ("windows_closed", closed),
+        ):
+            self._eva[name] = self._eva.get(name, 0) + add
+
+    def note_eva_prefill(self, tokens: int, cached: int, layers: int,
+                         window: int, chunk: int) -> None:
+        """One prompt pass of such a spec, booked at its readback:
+        positions ``cached .. tokens - 1`` went through it in each of
+        ``layers`` layers and left a summary row for every chunk they
+        touch; ``prompt_windows_closed`` of their windows closed inside
+        the pass (the ones a decode step closes are
+        ``windows_closed``)."""
+        for name, add in (
+            ("prompt_rows", (tokens - cached) * layers),
+            ("prompt_chunk_rows_written",
+             (-(-tokens // chunk) - cached // chunk) * layers),
+            ("prompt_windows_closed", tokens // window - cached // window),
+            ("prompts", 1),
+        ):
+            self._eva[name] = self._eva.get(name, 0) + add
+
     def note_swa_prefill(self, tokens: int, cached: int, layers: int,
                          ring_tokens: int, page_size: int) -> None:
         """One prompt pass of a spec with window layers, booked at its
@@ -1130,6 +1175,8 @@ class PerfRecorder:
             out["mla"] = dict(self._mla)
         if self._swa:
             out["swa"] = dict(self._swa)
+        if self._eva:
+            out["eva"] = dict(self._eva)
         if self._dsa:
             out["dsa"] = dict(self._dsa)
         return out

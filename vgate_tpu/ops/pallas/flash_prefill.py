@@ -41,11 +41,10 @@ def _kernel(
     seq_lens_ref,  # [B] int32 — real key length per batch row
     q_offsets_ref,  # [B] int32 — global position of query row 0
     window_ref,  # [1] int32; >0 => attend only to the last `window` keys
-    # inputs (VMEM blocks)
-    q_ref,  # [1, 1, block_q, hd]
-    k_ref,  # [1, 1, block_k, hd]
-    v_ref,  # [1, 1, block_k, hd]
-    # then: mask_ref [1, block_q, block_k] int8 where ``masked``;
+    # then: k_starts_ref [B] int32 where ``from_key`` (a fourth scalar
+    # prefetch: keys before it are nobody's); the inputs (VMEM blocks)
+    # q_ref [1, 1, block_q, hd], k_ref and v_ref [1, 1, block_k, hd];
+    # mask_ref [1, block_q, block_k] int8 where ``masked``;
     # the output out_ref [1, 1, block_q, hd]; the scratch acc_ref
     # [block_q, hd] f32, m_ref [block_q, 128] f32 running max
     # (column-broadcast) and l_ref [block_q, 128] f32 running denom
@@ -58,8 +57,11 @@ def _kernel(
     band: int = 0,
     masked: bool = False,
     skip_padding: bool = False,
+    from_key: bool = False,
 ):
-    mask_ref = rest[0] if masked else None
+    k_starts_ref = rest[0] if from_key else None
+    q_ref, k_ref, v_ref = rest[from_key:from_key + 3]
+    mask_ref = rest[from_key + 3] if masked else None
     out_ref, acc_ref, m_ref, l_ref = rest[-4:]
     b = pl.program_id(0)
     qi = pl.program_id(2)
@@ -98,6 +100,8 @@ def _kernel(
     if skip_padding:
         real = real & (q_start < seq_len)
     live = causal_live & window_live & real
+    if from_key:  # a block of keys before the row's first
+        live = live & (k_start + block_k - 1 >= k_starts_ref[b])
     if band:
         live = live & (kb >= 0)
 
@@ -121,6 +125,8 @@ def _kernel(
         )
         mask = (k_pos <= q_pos) & (k_pos < seq_len)
         mask = mask & ((window <= 0) | (q_pos - k_pos < window))
+        if from_key:
+            mask = mask & (k_pos >= k_starts_ref[b])
         if masked:  # a selection (ops/dsa.py): the caller's tile
             mask = mask & (mask_ref[0] != 0)
         scores = jnp.where(mask, scores, -1e30)
@@ -130,8 +136,8 @@ def _kernel(
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(scores - m_new)  # [block_q, block_k]
-        if masked:  # a row with nothing picked in this block and none
-            # before it: exp(-1e30 + 1e30) would count every key
+        if masked or from_key:  # a row with nothing seen in this block
+            # and none before it: exp(-1e30 + 1e30) would count every key
             p = jnp.where(mask, p, 0.0)
         l_ref[...] = jnp.broadcast_to(
             alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True),
@@ -170,6 +176,7 @@ def flash_prefill_attention_pallas(
     name=None,  # the launch's name in a device trace
     mask=None,  # [B, S, Sk] int8, nonzero = attend: beside causal + length
     skip_padding: bool = False,  # query blocks past seq_lens: zeros
+    k_starts: jnp.ndarray | None = None,  # [B] keys before it: nobody's
 ) -> jnp.ndarray:
     """Causal (optionally offset) attention. Returns [B, S, H, hd].
     ``band`` (with ``window``, no ``q_offsets`` and ``block_q`` a
@@ -211,9 +218,10 @@ def flash_prefill_attention_pallas(
         band=n_k if band else 0,
         masked=mask is not None,
         skip_padding=skip_padding,
+        from_key=k_starts is not None,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=3 + (k_starts is not None),
         grid=(B, H, n_q, n_k),
         in_specs=[
             pl.BlockSpec(
@@ -262,7 +270,9 @@ def flash_prefill_attention_pallas(
         name=name,
     )(
         seq_lens.astype(jnp.int32), q_offsets.astype(jnp.int32),
-        window_arr, qt, kt, vt, *(() if mask is None else (mask,)),
+        window_arr,
+        *(() if k_starts is None else (k_starts.astype(jnp.int32),)),
+        qt, kt, vt, *(() if mask is None else (mask,)),
     )
     return jnp.transpose(out, (0, 2, 1, 3))
 

@@ -69,6 +69,7 @@ from vgate_tpu.models.decoder import (
     multitok_attention_impl,
     prefill_attention_impl,
 )
+from vgate_tpu.models.hybrid import eva_window_pages
 from vgate_tpu.models.hybrid import make_state as make_hybrid_state
 from vgate_tpu.models.hybrid import prompt_rows
 from vgate_tpu.models.hybrid import (
@@ -511,6 +512,54 @@ def refuse_unsupported_rings(spec: ModelSpec, config: VGTConfig,
     )
 
 
+def refuse_unsupported_eva(spec: ModelSpec, config: VGTConfig,
+                           mesh) -> None:
+    """Engine-construction gate for a spec of EVA attention layers
+    (``ModelSpec.eva_layers``, ops/eva.py): a sequence's pages hold ONE
+    summary row for every ``eva_chunk`` tokens of its closed windows,
+    the open window's exact rows are the decode slot's, and whatever
+    moves, shares or rolls back pages would leave the window behind.
+    Each is refused by name, at boot; so is a page size that does not
+    cut a window's rows and its summary rows into whole pages.  (Prefix
+    matching is not refused but turned off: it is on by default.)"""
+    if not spec.eva_layers:
+        return
+    ps, W, c = config.tpu.kv_page_size, spec.eva_window, spec.eva_chunk
+    if ps % c or (W // c) % ps:
+        raise ValueError(
+            f"{spec.name} holds windows of {W} tokens in chunks of {c}: "
+            f"tpu.kv_page_size={ps} has to be a multiple of {c} (a chunk "
+            f"of a chunked prefill starts at a page of tokens) and divide "
+            f"{W // c} (a closed window's summary rows are whole pages)"
+        )
+    found = _pages_only_feature(config, mesh)
+    if not found:
+        return
+    why = {
+        "mesh": "the windows, the summary rows and their kernel launches "
+                "are not partitioned (dp composes: a replica owns its "
+                "pool)",
+        "speculative": "the verify program attends K and V pools a token "
+                       "a row, and rejected drafts would have to be "
+                       "rolled back out of a window (the model's own "
+                       "extra heads as drafts: not yet)",
+        "swap": "it parks pages and would leave the open window behind",
+        "roles": "the handoff of a live sequence ships pages and would "
+                 "leave the open window behind",
+        "int8": "the windows and the summary rows hold the model's "
+                "float type only",
+        "quant": "the EVA layers and their feed-forward take plain "
+                 "weights",
+    }[found[1]]
+    raise ValueError(
+        f"{spec.name} has EVA attention layers (a window of exact rows a "
+        f"decode slot, a summary row for every {c} tokens in the pages), "
+        f"which cannot run with {found[0]}: {why}.  Chunked prefill, "
+        "preemption by recompute and journal replay rebuild the window "
+        "and are supported."
+    )
+
+
 class _EvacRequest:
     """One planned-evacuation command in flight between a caller thread
     (dp drain/rebalance coordinator, admin surface) and the engine
@@ -581,6 +630,7 @@ class EngineCore:
         refuse_unsupported_recurrent(self.spec, self.config, self.mesh)
         refuse_unsupported_latent(self.spec, self.config, self.mesh)
         refuse_unsupported_rings(self.spec, self.config, self.mesh)
+        refuse_unsupported_eva(self.spec, self.config, self.mesh)
         # Pallas kernels require a real TPU backend (tests run interpret-
         # mode kernels separately; the engine's jnp twins serve CPU meshes)
         platform = self.mesh.devices.flat[0].platform
@@ -738,7 +788,8 @@ class EngineCore:
         # more pages than every slot's full context can never be used, and
         # bounding the pool keeps the page-scatter/gather programs small
         pages_per_seq = cdiv(
-            self.config.model.max_model_len, tpu_cfg.kv_page_size
+            self.config.model.max_model_len,
+            tpu_cfg.kv_page_size * self.spec.cache_row_tokens,
         )
         sp_shards = int(self.mesh.shape.get("sp", 1))
         max_useful = (
@@ -806,6 +857,15 @@ class EngineCore:
             pools=self.spec.kv_pools,
             index_layers=self.spec.index_layers,
             index_dim=self.spec.index_head_dim,
+            row_tokens=self.spec.cache_row_tokens,
+            # an EVA spec's open windows: pages of the pool arrays a
+            # slot, behind the allocator's (their bytes are the
+            # ``state_bytes`` taken out above)
+            slot_pages=(
+                tpu_cfg.max_batch_slots
+                * eva_window_pages(self.spec, tpu_cfg.kv_page_size)
+                if self.spec.eva_layers else 0
+            ),
         )
         kv_sharding = named(
             self.mesh, kv_pspec(self.spec, self.mesh, num_pages)
@@ -819,7 +879,7 @@ class EngineCore:
         self.state = (
             make_hybrid_state(
                 self.spec, tpu_cfg.max_batch_slots, self._state_dtype,
-                tpu_cfg.kv_page_size)
+                tpu_cfg.kv_page_size, num_pages)
             if self.spec.slot_state_layers else None
         )
         # tokens a slot's ring holds in one window layer (0: no rings)
@@ -962,6 +1022,7 @@ class EngineCore:
             insert_generated=pc.insert_generated,
             evict_watermark=pc.evict_watermark,
             swap=self.kv_swap,
+            page_tokens=self.geometry.page_tokens,
         )
 
         # host-side mirror of the device page tables, one row per slot
@@ -2669,6 +2730,12 @@ class EngineCore:
                             self.spec.swa_layers,
                             self._ring_tokens, self.geometry.page_size,
                         )
+                    if self.spec.eva_layers:
+                        self.perf.note_eva_prefill(
+                            plan.seq.total_len, plan.cached_len,
+                            self.spec.eva_layers, self.spec.eva_window,
+                            self.spec.eva_chunk,
+                        )
                     if lp is not None and plan.seq.params.logprobs:
                         self._attach_logprob(plan.seq, lp, 0, row)
                     # a RE-prefill (post-preemption) keeps the original
@@ -2930,6 +2997,9 @@ class EngineCore:
         B = 1 << (len(plans) - 1).bit_length()  # next power of two
         ps = self.geometry.page_size
         n_own_pages = bucket // ps + (1 if unaligned else 0)
+        # tokens a page of a sequence stands for (ps, but for a spec
+        # whose pool row is several tokens)
+        pt = self.geometry.page_tokens
         seqs = [plan.seq for plan in plans]
         with self.perf.span("state", lambda: {"rows": B}):
             # copy-on-write: duplicate the shared head of each diverging
@@ -2952,7 +3022,7 @@ class EngineCore:
             # both the KV gather and the compile-variant count
             ctx_pages = min(
                 self.geometry.pages_per_seq,
-                1 << max(0, max(cdiv(end, ps) for end in ends) - 1)
+                1 << max(0, max(cdiv(end, pt) for end in ends) - 1)
                 .bit_length(),
             )
             tokens = np.zeros((B, bucket), np.int32)
@@ -2969,7 +3039,7 @@ class EngineCore:
                 tokens[row, : len(part)] = part
                 prefix_lens[row] = plan.cached_len
                 lens[row] = len(part)
-                own = seq.pages[plan.cached_len // ps :]
+                own = seq.pages[plan.cached_len // pt :]
                 own_pt[row, : len(own)] = own[:n_own_pages]
                 # decode-side page table row: real pages then trash
                 # padding
@@ -3492,6 +3562,14 @@ class EngineCore:
                         lens=[s.total_len for s, _ in seqs],
                         layers=self.spec.swa_layers,
                         window=self.spec.sliding_window,
+                    )
+                if self.spec.eva_layers:
+                    self.perf.note_eva_decode(
+                        steps=chunk,
+                        lens=[s.total_len for s, _ in seqs],
+                        layers=self.spec.eva_layers,
+                        window=self.spec.eva_window,
+                        chunk=self.spec.eva_chunk,
                     )
             device_s = wait.seconds
             block_s = device_s + read.seconds
@@ -4217,6 +4295,18 @@ class EngineCore:
         """What ``/stats -> engine.state_cache`` holds a slot: the
         recurrent layers' rows, or the window layers' rings."""
         name = dtype_short_name(self._state_dtype)
+        if self.spec.eva_layers:
+            return {
+                "kind": "eva_window",
+                "layers": self.spec.eva_layers,
+                "tokens_per_slot": self.spec.eva_window,
+                "pages_per_slot": eva_window_pages(
+                    self.spec, self.geometry.page_size),
+                "window": self.spec.eva_window,
+                "chunk": self.spec.eva_chunk,
+                "dtype": f"{name} K and V, pages of the pool arrays "
+                         "behind the allocator's",
+            }
         if self.spec.swa_layers:
             return {
                 "kind": "ring",
@@ -4240,6 +4330,9 @@ class EngineCore:
             return {"index_bytes": (
                 self.allocator.num_used * geo.page_size * geo.index_layers
                 * geo.index_dim * geo.dtype_bytes)}
+        if self.spec.eva_layers:
+            return {"window_bytes": (
+                self._state_slot_bytes * len(self.scheduler.running))}
         if not self.spec.swa_layers:
             return {}
         return {"ring_bytes": (
@@ -4270,6 +4363,18 @@ class EngineCore:
             ),
             "kv_sized_by": self._kv_sized_by,
             "kv_token_capacity": self.geometry.total_tokens,
+            # the pool in ROWS and in tokens: a row is a token, but for
+            # a spec whose row stands for several (row_tokens)
+            "kv_pool": {
+                "row_tokens": self.geometry.row_tokens,
+                "rows": self.allocator.num_allocatable
+                * self.geometry.page_size,
+                "rows_used": self.allocator.num_used
+                * self.geometry.page_size,
+                "tokens": self.geometry.total_tokens,
+                "tokens_used": self.allocator.num_used
+                * self.geometry.page_tokens,
+            },
             # KV storage attribution: drills and bench artifacts read
             # these so every recorded number names its KV config
             "kv_dtype": self.geometry.kv_dtype,

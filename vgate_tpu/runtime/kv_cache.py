@@ -74,10 +74,23 @@ class KVGeometry:
     # layers, addressed by the same page ids
     index_layers: int = 0
     index_dim: int = 0
+    # tokens ONE row of a page stands for (``ModelSpec.cache_row_tokens``:
+    # an EVA spec's row is a chunk's summary), so a page holds
+    # ``page_tokens`` tokens and a sequence ``ceil(ceil(len / row_tokens)
+    # / page_size)`` pages
+    row_tokens: int = 1
+    # pages of the arrays BEHIND the allocator's ``num_pages``, held a
+    # decode slot and never allocated (an EVA spec's open windows:
+    # ops/eva.py window_pages)
+    slot_pages: int = 0
+
+    @property
+    def page_tokens(self) -> int:
+        return self.page_size * self.row_tokens
 
     @property
     def pages_per_seq(self) -> int:
-        return cdiv(self.max_model_len, self.page_size)
+        return cdiv(self.max_model_len, self.page_tokens)
 
     @property
     def page_bytes(self) -> int:
@@ -89,7 +102,7 @@ class KVGeometry:
 
     @property
     def total_tokens(self) -> int:
-        return (self.num_pages - self.num_reserved) * self.page_size
+        return (self.num_pages - self.num_reserved) * self.page_tokens
 
 
 def auto_num_pages(
@@ -362,7 +375,7 @@ def make_kv_buffers(geometry: KVGeometry, dtype=jnp.bfloat16, sharding=None):
     shape = (
         geometry.num_layers,
         geometry.kv_heads,
-        geometry.num_pages,
+        geometry.num_pages + geometry.slot_pages,
         geometry.page_size,
         geometry.head_dim,
     )

@@ -136,6 +136,7 @@ class Scheduler:
         insert_generated: bool = True,
         evict_watermark: float = 0.0,
         swap: Optional[KVSwapManager] = None,
+        page_tokens: int = 0,
     ) -> None:
         # optional flight recorder (observability/flight.py): residency
         # events (preempt/shed/abort) become post-mortem ring entries
@@ -148,6 +149,11 @@ class Scheduler:
         self.text_fn = text_fn
         self.allocator = allocator
         self.page_size = page_size
+        # tokens a page stands for: what a sequence's pages are COUNTED
+        # by (admission, growth, preemption).  ``page_size`` rows of
+        # ``ModelSpec.cache_row_tokens`` tokens each; the buckets and the
+        # prefix index below go by ``page_size``, the rows a page holds
+        self.page_tokens = page_tokens or page_size
         # buckets: page-aligned, capped at max_model_len, and always
         # including a top bucket that can hold any admissible prompt
         # (preempted sequences re-prefill with their grown context).
@@ -298,7 +304,7 @@ class Scheduler:
                 and head.preempt_count == ticket.epoch
             ):
                 return self.allocator.num_free >= ticket.num_pages
-        n_pages = cdiv(max(1, head.num_prompt_tokens), self.page_size)
+        n_pages = cdiv(max(1, head.num_prompt_tokens), self.page_tokens)
         if self.radix is not None:
             # mirror try_admit's radix accounting: matched pages are
             # shared, not allocated, but matched pages of UNLOCKED
@@ -618,7 +624,7 @@ class Scheduler:
             ticket = self.swap.ticket_for(seq)
             if ticket is not None:
                 return self._admit_swap_in(seq, slot, ticket)
-        n_pages = cdiv(max(1, seq.num_prompt_tokens), self.page_size)
+        n_pages = cdiv(max(1, seq.num_prompt_tokens), self.page_tokens)
 
         # prefix cache: match the longest shared prefix already resident;
         # only the remainder allocates + prefills.  Radix mode walks the
@@ -824,7 +830,7 @@ class Scheduler:
         (KV writes land at positions ``pos .. pos+horizon-1``) without
         crossing into unowned memory; preempt the youngest sequences on
         exhaustion.  Returns True when a decode step can proceed."""
-        max_pages = cdiv(self.max_model_len, self.page_size)
+        max_pages = cdiv(self.max_model_len, self.page_tokens)
         # higher tiers claim pages first, so when the pool runs dry
         # mid-loop it is the lower tiers that trigger preemption
         for seq in sorted(active, key=lambda s: (_rank(s), s.seq_id)):
@@ -840,7 +846,7 @@ class Scheduler:
                 # last position written within the horizon (clamped: steps
                 # past max_model_len clip into the final page harmlessly)
                 pos = seq.total_len - 1
-                needed = min((pos + steps - 1) // self.page_size + 1,
+                needed = min((pos + steps - 1) // self.page_tokens + 1,
                              max_pages)
                 if len(seq.pages) >= needed:
                     break
@@ -911,7 +917,7 @@ class Scheduler:
         # happen on this engine thread, so reading first is sufficient.
         swapped = False
         if self.swap is not None:
-            n_valid = cdiv(max(1, seq.total_len - 1), self.page_size)
+            n_valid = cdiv(max(1, seq.total_len - 1), self.page_tokens)
             swapped = self.swap.swap_out_seq(seq, seq.pages[:n_valid])
         logger.warning(
             "preempting sequence for KV pressure",
@@ -979,7 +985,7 @@ class Scheduler:
         caller reports the monolithic fallback."""
         if self.swap is None or seq.status is not SeqStatus.RUNNING:
             return False
-        n_valid = cdiv(max(1, seq.total_len - 1), self.page_size)
+        n_valid = cdiv(max(1, seq.total_len - 1), self.page_tokens)
         if not self.swap.swap_out_seq(seq, seq.pages[:n_valid]):
             return False
         self._event(

@@ -472,6 +472,13 @@ def params_from_getter(
     spec: ModelSpec, getter: TensorGetter, dtype=jnp.bfloat16
 ) -> Params:
     """Assemble the decoder pytree from HF-named tensors (host numpy)."""
+    if spec.eva_layers:
+        # no checkpoint or index file of this family is in the repository
+        # to hold tensor names against: random weights alone
+        raise NotImplementedError(
+            f"{spec.name}: loading a checkpoint of an EVA stack is not "
+            "supported (its tensor names are unverified); serve it on "
+            "random weights")
     if spec.is_dsa:
         return _dsa_params_from_getter(spec, getter, dtype)
     if spec.is_mla:
