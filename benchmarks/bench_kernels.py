@@ -11,6 +11,7 @@ blockwise twin).  Run on hardware:
     python benchmarks/bench_kernels.py expert_layer [CONFIG ...]
     python benchmarks/bench_kernels.py dsa_index dsa_select dsa_attend
     python benchmarks/bench_kernels.py dsa_attend dsa_attend_64k
+    python benchmarks/bench_kernels.py eva_decode [PAGESxITEMS ...]
 
 Prints one JSON line per (kernel, shape) with median step times and the
 speedup.  CPU-safe fallback: refuses to run (the kernels need a TPU).
@@ -1034,20 +1035,24 @@ def bench_dsa_attend_64k():
     yield from bench_dsa_attend(ctx=65536, layers=1)
 
 
-def bench_eva_decode(B=20, H=32, hd=128, ps=32, window=2048, chunk=16,
-                     layers=2, loop=LOOP):
+def bench_eva_decode(forced=(), B=20, H=32, hd=128, ps=32, window=2048,
+                     chunk=16, layers=2, loop=LOOP):
     """The EVA decode attention at the EvaByte cell's shape: 20 slots, 32
     heads of 128 (MHA: one query row a KV head), contexts of 8,193-16,047
     bytes (``long-agent``'s), so about 1,024 live window rows and 768
     summary rows a slot: the paged decode kernel over the step's ONE
     sequence of rows (ops/eva.py ``decode_view``), writing the step's
     row, then the open chunk's summary row (``decode_summarize``).
-    Prints microseconds a launch and GB/s over the rows a step HAS to
-    read and write (live rows, real lengths), for the kernel alone and
-    with the summary's rewrite."""
+    Prints the (pages a chunk, slots a program, items a trip) a launch
+    ran with and its loop trips, microseconds a launch and GB/s over the
+    rows a step HAS to read and write (live rows, real lengths), for the
+    kernel alone, for its hollow twin (the trips' bookkeeping) and with
+    the summary's rewrite.  ``forced`` is (pages a chunk, items a trip)
+    pairs to run beside the rule's own (0: the rule's), the probe's
+    alone; each forced form's attention is held against the rule's."""
     from vgate_tpu.ops import eva
     from vgate_tpu.ops.pallas.paged_attention import (
-        paged_decode_attention_pallas,
+        _decode_sizes, paged_decode_attention_pallas,
     )
 
     rng = np.random.default_rng(0)
@@ -1068,21 +1073,20 @@ def bench_eva_decode(B=20, H=32, hd=128, ps=32, window=2048, chunk=16,
     live = int(np.sum(np.asarray(rows) + 1))
     moved = (live + B) * 2 * H * hd * 2  # rows read + the row written
 
-    def attend(q, kp, vp):
+    def attend(q, kp, vp, **kw):
         return paged_decode_attention_pallas(
-            q, kp, vp, view, rows + 1, layer=0, k_new=new, v_new=new)
+            q, kp, vp, view, rows + 1, layer=0, k_new=new, v_new=new, **kw)
 
-    def attend_and_summarize(q, kp, vp):
-        out, kp, vp = attend(q, kp, vp)
+    def attend_and_summarize(q, kp, vp, **kw):
+        out, kp, vp = attend(q, kp, vp, **kw)
         kp, vp = eva.decode_summarize(
             kp, vp, phi, phi, 0, tables, win, positions, None, window,
             chunk, hd ** -0.5)
         return out, kp, vp
 
-    for name, fn in (("kernel", attend),
-                     ("kernel + summary row", attend_and_summarize)):
+    def us_a_launch(fn):
         @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def run(q, kp, vp, fn=fn):
+        def run(q, kp, vp):
             def body(carry, _):
                 kp, vp, acc = carry
                 out, kp, vp = fn(q + 0 * acc.astype(q.dtype), kp, vp)
@@ -1101,14 +1105,37 @@ def bench_eva_decode(B=20, H=32, hd=128, ps=32, window=2048, chunk=16,
             _sync(acc)
             if i:
                 times.append((time.perf_counter() - t0) / loop)
-        us = float(np.median(times)) * 1e6
-        yield {"probe": "eva_decode", "form": name, "slots": B,
-               "heads": H, "live_rows_mean": live / B,
-               "window_rows_mean": float(np.mean(
-                   np.asarray(positions) % window + 1)),
-               "us_per_launch": round(us, 1),
-               "gb_per_s": round(moved / us / 1e3, 1),
-               "hbm_roofline_pct": round(100 * moved / us / 1e3 / 819, 1)}
+        return float(np.median(times)) * 1e6
+
+    ruled = None
+    for chunk_pages, items in ((0, 0), *forced):
+        kw = {"chunk_pages": chunk_pages, "items": items}
+        CP, BS, I = _decode_sizes(
+            B, H, 1, hd, ps, view.shape[1], pool.dtype, q.dtype, **kw)
+        out = np.asarray(attend(q, pool, pool, **kw)[0], np.float32)
+        ruled = out if ruled is None else ruled
+        line = {"probe": "eva_decode", "slots": B, "heads": H,
+                "forced": bool(chunk_pages or items),
+                "chunk_pages": CP, "block_slots": BS, "items": I,
+                "chunk_tokens": CP * ps,
+                "trips": decode_trips(np.asarray(rows) + 1, CP * ps, BS, I)[1],
+                "live_rows_mean": live / B,
+                "window_rows_mean": float(np.mean(
+                    np.asarray(positions) % window + 1)),
+                "max_abs_diff_from_rule": float(np.abs(out - ruled).max())}
+        for name, fn in (
+            ("kernel", functools.partial(attend, **kw)),
+            ("hollow", functools.partial(attend, hollow=True, **kw)),
+            ("kernel_and_summary_row",
+             functools.partial(attend_and_summarize, **kw)),
+        ):
+            us = us_a_launch(fn)
+            line[f"{name}_us"] = round(us, 1)
+            if name != "hollow":
+                line[f"{name}_gb_per_s"] = round(moved / us / 1e3, 1)
+                line[f"{name}_hbm_roofline_pct"] = round(
+                    100 * moved / HBM_BYTES_PER_S / (us * 1e-6), 1)
+        yield line
 
 
 def main() -> None:
@@ -1126,8 +1153,10 @@ def main() -> None:
         for line in bench_swa_prefill():
             print(json.dumps(line), flush=True)
         return
-    if sys.argv[1:] == ["eva_decode"]:
-        for line in bench_eva_decode():
+    if sys.argv[1:2] == ["eva_decode"]:
+        # eva_decode [PAGESxITEMS ...]: forced sizes beside the rule's
+        forced = [tuple(map(int, a.split("x"))) for a in sys.argv[2:]]
+        for line in bench_eva_decode(forced):
             print(json.dumps(line), flush=True)
         return
     if sys.argv[1:2] == ["expert_layer"]:

@@ -735,21 +735,40 @@ def test_decode_kernel_block_patterns(pattern, write):
     _live_rows_match(got, expect, case[4])
 
 
+_CELL_LENS = [0, 200, 3, 0, 129, 64]
+# lengths that cross several chunks of EvaByte's 128 tokens and end inside
+# one, over 24 pages a slot
+_MHA_LENS = [0, 1, 127, 129, 300, 700]
+
+
 @pytest.mark.parametrize(
-    "KV, G, hd",
-    [(2, 6, 128), (4, 7, 128), (2, 8, 256), (1, 7, 128)],
-    ids=["1.5B", "7B", "qwen3-next", "one-kv-head-tp-shard"],
+    "KV, G, hd, lens, pages_per_seq, chunk_pages",
+    [
+        (2, 6, 128, _CELL_LENS, 8, 8), (4, 7, 128, _CELL_LENS, 8, 4),
+        (2, 8, 256, _CELL_LENS, 8, 4), (1, 7, 128, _CELL_LENS, 8, 8),
+        # float32 pools: the budget's share is 21 tokens, the floor 128
+        (32, 1, 128, _MHA_LENS, 24, 4),
+    ],
+    ids=["1.5B", "7B", "qwen3-next", "one-kv-head-tp-shard", "evabyte-mha"],
 )
 @reads_and_writes
-def test_decode_kernel_cell_geometries(KV, G, hd, write):
-    """The three cells' (KV, G, hd) and one KV head (a tp shard of the
-    7B): all KV heads ride one iteration, and one staged page back to
-    the pool, whatever their number."""
-    lens = [0, 200, 3, 0, 129, 64]
+def test_decode_kernel_cell_geometries(KV, G, hd, lens, pages_per_seq,
+                                       chunk_pages, write):
+    """The cells' (KV, G, hd) and one KV head (a tp shard of the 7B):
+    all KV heads ride one iteration, and one staged page back to the
+    pool, whatever their number.  EvaByte's is one query row a KV head
+    under 32 of them, where a chunk is the floor of `_decode_sizes` (one
+    128-token tile a head) and not the budget's share."""
+    from vgate_tpu.ops.pallas.paged_attention import _decode_sizes
+
     case = make_case(
-        B=len(lens), H=KV * G, KV=KV, hd=hd, ps=32, pages_per_seq=8,
-        lens=lens, seed=23,
+        B=len(lens), H=KV * G, KV=KV, hd=hd, ps=32,
+        pages_per_seq=pages_per_seq, lens=lens, seed=23,
     )
+    assert _decode_sizes(
+        len(lens), KV, G, hd, 32, pages_per_seq, case[1].dtype,
+        case[0].dtype,
+    )[0] == chunk_pages
     got, expect = _decode_both(*case, write=write)
     _live_rows_match(got, expect, case[4])
 

@@ -157,6 +157,11 @@ SERVED_DECODE_SHAPES = {
         (192, 2, 16, 128, 64, 2), (8, 64, 2)),
     # the latent pool: one "head" of 384-lane rows under 32 query heads
     "mistral-small-4-119b-l4e32": ((256, 1, 32, 384, 256, 1), (8, 21, 2)),
+    # the full layers (the rings of five pages a slot get the same chunk)
+    "k-exaone-236b-a23b-l5e16": ((192, 8, 8, 128, 256, 2), (4, 16, 1)),
+    # MHA, one query row a KV head: 64 window pages behind 32 of summaries.
+    # The 4 MiB budget alone gave ONE page a chunk here (PR 45)
+    "evabyte-6.5b-l8": ((20, 32, 1, 128, 96, 2), (4, 4, 1)),
 }
 
 
@@ -173,6 +178,19 @@ def test_decode_sizes_at_the_served_shapes(config):
         B, KV, G, hd, PAGE, pages_per_seq, jnp.bfloat16, jnp.bfloat16,
         pools=pools,
     ) == sizes
+
+
+def test_decode_kernel_compiles_at_the_evabyte_cells_shape_for_v5e(v5e):
+    """The kernel alone as the EvaByte cell launches it (20 slots of 96
+    pages, 32 KV heads of one query row each, the step's row written),
+    at the chunk `_decode_sizes` gives it: a chunk that Mosaic refuses
+    fails here, before a chip is asked."""
+    B, KV, G, hd, pages_per_seq, _ = SERVED_DECODE_SHAPES[
+        "evabyte-6.5b-l8"][0]
+    _compile_decode_kernel(
+        _abstract(v5e), (KV * G, KV, hd), B=B, pages_per_seq=pages_per_seq,
+        write=True,
+    )
 
 
 @pytest.mark.parametrize("items", [1, 2])

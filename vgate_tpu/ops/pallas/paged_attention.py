@@ -165,10 +165,11 @@ def _scale_row(buf, slot):
 
 
 # VMEM the single-token decode kernel spends, half on its K and V chunk
-# buffers (which bounds the chunk's tokens) and half on the pipelined q and
-# out blocks (which sets the slots a program serves): _decode_sizes.  The
-# write's own VMEM rides beside it, at most 1.3 MiB at the cells' shapes:
-# the new tokens' [BS, KV, hd] blocks and DECODE_WRITE_BUFFERS staged pages.
+# buffers (which bounds the chunk's tokens, down to DECODE_TILE_TOKENS) and
+# half on the pipelined q and out blocks (which sets the slots a program
+# serves): _decode_sizes.  The write's own VMEM rides beside it, at most
+# 2.1 MiB at the cells' shapes (32 KV heads): the new tokens' [BS, KV, hd]
+# blocks and DECODE_WRITE_BUFFERS staged pages.
 DECODE_VMEM_BUDGET = 4 << 20
 # K/V chunk buffers of a loop trip that serves ONE item of the work list:
 # one computed, two in flight.  Two keep the HBM busy only while a trip's
@@ -181,6 +182,14 @@ DECODE_BUFFERS = 3
 # 5 % and the 7B 20 %); a trip of two items pays the trip's fixed cost once
 # per 512 tokens without those tails.
 DECODE_CHUNK_TOKENS = 256
+# ... and at least this many, whatever the budget's share comes to: one
+# 128-token MXU weight tile a head.  Under it a head's products meet a part
+# of a tile and a softmax update a part-filled vreg: 32 KV heads of 128 in
+# the budget came to ONE page of 32 tokens a chunk and 4.88 ms a launch
+# where four pages take 1.02 (two pages 1.44, eight 1.01: PERF.md section
+# 6, PR 45).  The chunk buffers then take what that needs (6.3 MB there),
+# beside the budget and inside the launch's `vmem_limit_bytes`.
+DECODE_TILE_TOKENS = 128
 # Staged pages of new tokens on their way back to the pool: a slot's
 # write is waited for only when its staging page comes round again, which
 # a trip of two items that both end their slots brings two pages nearer.
@@ -196,10 +205,11 @@ def decode_buffers(items: int) -> int:
 
 
 def _decode_sizes(B, KV, G, hd, page_size, pages_per_seq, kv_dtype, q_dtype,
-                  pools: int = 2, items: int = 0):
+                  pools: int = 2, items: int = 0, chunk_pages: int = 0):
     """(pages a chunk, slots a program, items a loop trip) for one
     geometry.  `items` = 0 asks the rule; the probe and the tests force 1
-    or 2."""
+    or 2.  `chunk_pages` = 0 asks the rule; the probe forces a power of
+    two."""
     kv_bytes = jnp.dtype(kv_dtype).itemsize
     q_bytes = jnp.dtype(q_dtype).itemsize
     # An item's work is a chain of products and one softmax update a KV
@@ -210,14 +220,17 @@ def _decode_sizes(B, KV, G, hd, page_size, pages_per_seq, kv_dtype, q_dtype,
         items = 2 if KV <= DECODE_PAIR_KV_HEADS else 1
     # K and V (or the one latent pool's rows) of every KV head in every
     # buffer of a one-item trip (a second item's buffer rides beside the
-    # budget: a chunk is the same whatever a trip holds); a power of two,
-    # so the kernel's page arithmetic is shifts
-    token_bytes = DECODE_BUFFERS * pools * KV * hd * kv_bytes
-    chunk_tokens = min(
-        DECODE_VMEM_BUDGET // 2 // token_bytes, DECODE_CHUNK_TOKENS
-    )
-    chunk_pages = max(1, chunk_tokens // page_size)
-    chunk_pages = 1 << (chunk_pages.bit_length() - 1)
+    # budget: a chunk is the same whatever a trip holds), but no fewer
+    # than a weight tile's, whatever the heads; a power of two, so the
+    # kernel's page arithmetic is shifts
+    if not chunk_pages:
+        token_bytes = DECODE_BUFFERS * pools * KV * hd * kv_bytes
+        chunk_tokens = min(
+            max(DECODE_VMEM_BUDGET // 2 // token_bytes, DECODE_TILE_TOKENS),
+            DECODE_CHUNK_TOKENS,
+        )
+        chunk_pages = max(1, chunk_tokens // page_size)
+        chunk_pages = 1 << (chunk_pages.bit_length() - 1)
     # q and out rows of every head (G pads to the dtype's sublane tile),
     # two buffers each
     g_rows = cdiv(G, 32 // q_bytes) * (32 // q_bytes)
@@ -717,8 +730,8 @@ def _decode_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("interpret", "softcap", "scale", "items", "hollow",
-                     "name"),
+    static_argnames=("interpret", "softcap", "scale", "items", "chunk_pages",
+                     "hollow", "name"),
 )
 def paged_decode_attention_pallas(
     q: jnp.ndarray,  # [B, H, hd]
@@ -734,6 +747,7 @@ def paged_decode_attention_pallas(
     softcap: float = 0.0,
     scale=None,  # static query scale; default hd**-0.5
     items: int = 0,  # work-list items a loop trip serves; 0: _decode_sizes
+    chunk_pages: int = 0,  # pages an item holds; 0: _decode_sizes
     hollow: bool = False,  # the probe's: _decode_kernel
     name=None,  # the launch's name in a device trace
 ):
@@ -773,7 +787,7 @@ def paged_decode_attention_pallas(
     # an int8 pool's path is as it was: one item a trip
     CP, BS, items = _decode_sizes(
         B, KV, G, hd, ps, page_tables.shape[1], k_data.dtype, q.dtype,
-        items=1 if quant else items,
+        items=1 if quant else items, chunk_pages=chunk_pages,
     )
     chunk_tokens, nbuf = CP * ps, decode_buffers(items)
 
