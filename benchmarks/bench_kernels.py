@@ -12,6 +12,7 @@ blockwise twin).  Run on hardware:
     python benchmarks/bench_kernels.py dsa_index dsa_select dsa_attend
     python benchmarks/bench_kernels.py dsa_attend dsa_attend_64k
     python benchmarks/bench_kernels.py eva_decode [PAGESxITEMS ...]
+    python benchmarks/bench_kernels.py dense_prompt [CONFIG ...]
 
 Prints one JSON line per (kernel, shape) with median step times and the
 speedup.  CPU-safe fallback: refuses to run (the kernels need a TPU).
@@ -719,6 +720,122 @@ def bench_expert_layer(configs=None, loop=4):
                 }
 
 
+# the two configurations whose prompt programs are models/decoder.py's
+# own pass: (preset, layers the probe keeps)
+DENSE_PROMPT_CONFIGS = {
+    "qwen2.5-1.5b": ("Qwen/Qwen2.5-1.5B-Instruct", 4),
+    "qwen2.5-7b-l14": ("Qwen/Qwen2.5-7B-Instruct", 4),
+}
+# what a group of 8 rows of 2,048 holds: every row filled so far, and
+# the cells' own worst group (five prompts run as eight rows)
+DENSE_PROMPT_FILLS = {
+    "50": [1024] * 8, "62": [1280] * 8, "75": [1536] * 8,
+    "100": [2048] * 8, "5-of-8-at-62": [1280] * 5 + [1] * 3}
+
+
+def bench_dense_prompt(configs=None, B=8, S=2048, ps=32, iters=6,
+                       blocks=(1024, 2048), fills=None, use_pallas=True):
+    """The dense stack's prompt program at ``[8, 2048]`` (models/
+    decoder.py ``prefill_forward``, four layers of each configuration's
+    widths, bf16, the Pallas flash kernel), ms a LAYER by what the group
+    holds: the whole bucket (the loop off: the pass as it was, every
+    row of the bucket and every query block) beside the packed pass in
+    blocks of 1,024 and of 2,048 rows; the same for the position-wise
+    work of a layer alone (norms, projections and rotary, ``o_proj``,
+    the feed-forward: no attention, no pages, no packing), and the flash
+    launch with and without ``skip_padding``.  One line a configuration
+    and form, a column a fill."""
+    import dataclasses
+
+    from vgate_tpu.models import decoder, hybrid
+    from vgate_tpu.models.specs import spec_for_model_id
+    from vgate_tpu.ops.pallas.flash_prefill import (
+        flash_prefill_attention_pallas,
+    )
+
+    OFF = 1 << 20
+    for name in configs or DENSE_PROMPT_CONFIGS:
+        preset, layers = DENSE_PROMPT_CONFIGS[name]
+        spec = dataclasses.replace(
+            spec_for_model_id(preset), name=name, num_layers=layers)
+        params = decoder.init_params(
+            spec, jax.random.PRNGKey(0), jnp.bfloat16)
+        pool = jnp.zeros((layers, spec.num_kv_heads, B * S // ps + 1, ps,
+                          spec.head_dim), jnp.bfloat16)
+        tables = jnp.asarray(
+            1 + np.arange(B * S // ps).reshape(B, S // ps), jnp.int32)
+        toks = jax.random.randint(
+            jax.random.PRNGKey(1), (B, S), 3, 1000, jnp.int32)
+        lens = {fill: jnp.asarray(n, jnp.int32)
+                for fill, n in (fills or DENSE_PROMPT_FILLS).items()}
+        lp = jax.tree.map(lambda w: w[0], params["layers"])
+
+        def forms(block):
+            # traced anew for each block (jit keys on the function)
+            def program(params, toks, lens, kp, vp):
+                return decoder.prefill_forward(
+                    params, spec, toks, lens, kp, vp, tables,
+                    use_pallas=use_pallas)
+
+            def position_wise(lp, x, lens):
+                # ``layers`` layers' position-wise work alone, on the
+                # rows a packed pass of these lengths would work on
+                at = jnp.broadcast_to(
+                    jnp.arange(x.shape[1])[None], x.shape[:2])
+                n_rows = None if block == OFF else hybrid.prompt_rows(
+                    spec, S, lens)[1]
+
+                def layer(h, _):
+                    q, _, _ = hybrid._by_row_blocks(
+                        lambda r, at: decoder._prefill_qkv(r, lp, spec, at),
+                        (h, at), n_rows)
+                    return hybrid._by_row_blocks(
+                        lambda r, a: decoder._finish_layer(r, a, lp, spec),
+                        (h, hybrid._heads_flat(q)), n_rows), None
+
+                return jax.lax.scan(layer, x, None, length=layers)[0]
+
+            return jax.jit(program), jax.jit(position_wise)
+
+        rows = 0.02 * jax.random.normal(
+            jax.random.PRNGKey(2), (1, B * S, spec.hidden_size),
+            jnp.bfloat16)
+        for block in (OFF, *blocks):
+            was, hybrid.PROMPT_ROW_BLOCK = hybrid.PROMPT_ROW_BLOCK, block
+            try:
+                run, alone = forms(block)
+                form = "whole_bucket" if block == OFF else f"packed/{block}"
+                shaped = rows.reshape(B, S, -1) if block == OFF else rows
+                line = {"probe": "dense_prompt", "config": name,
+                        "form": form, "group": f"[{B}, {S}]",
+                        "layers": layers}
+                for fill, n in lens.items():
+                    line[f"layer_ms@{fill}"] = round(
+                        _timed(run, params, toks, n, pool, pool,
+                               iters=iters) / layers * 1e3, 3)
+                    line[f"position_wise_ms@{fill}"] = round(
+                        _timed(alone, lp, shaped, n, iters=iters)
+                        / layers * 1e3, 3)
+                yield line
+            finally:
+                hybrid.PROMPT_ROW_BLOCK = was
+        if not use_pallas:
+            continue
+        H, KV, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
+        q, k, v = (jax.random.normal(
+            jax.random.PRNGKey(3 + i), (B, S, h, hd), jnp.bfloat16)
+            for i, h in enumerate((H, KV, KV)))
+        line = {"probe": "dense_prompt", "config": name,
+                "form": "flash_launch", "group": f"[{B}, {S}]"}
+        for skip in (False, True):
+            fn = jax.jit(functools.partial(
+                flash_prefill_attention_pallas, skip_padding=skip))
+            for fill, n in lens.items():
+                line[f"{'skip' if skip else 'all'}_ms@{fill}"] = round(
+                    _timed(fn, q, k, v, n, iters=iters) * 1e3, 3)
+        yield line
+
+
 def bench_decode_window(B=128, H=8, KV=4, hd=256, ps=16, ctx=4096,
                         window=1024):
     """Sliding-window decode (Gemma-2 local layers): the kernel skips DMA
@@ -1157,6 +1274,10 @@ def main() -> None:
         # eva_decode [PAGESxITEMS ...]: forced sizes beside the rule's
         forced = [tuple(map(int, a.split("x"))) for a in sys.argv[2:]]
         for line in bench_eva_decode(forced):
+            print(json.dumps(line), flush=True)
+        return
+    if sys.argv[1:2] == ["dense_prompt"]:
+        for line in bench_dense_prompt(sys.argv[2:]):
             print(json.dumps(line), flush=True)
         return
     if sys.argv[1:2] == ["expert_layer"]:

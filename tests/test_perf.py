@@ -715,37 +715,60 @@ def test_the_expert_layers_counters_are_booked_with_their_overflow():
         "load_max_sum": 10, "overflow": 3, "layer_steps": 6, "steps": 3}
 
 
-@pytest.mark.parametrize("model_id, rows, bucket, lens, whole, worked", [
+@pytest.mark.parametrize("model_id, bucket, lens, whole, worked", [
     # a long whole prompt of a stack of several kinds: the blocks of
     # 1,024 rows up to the longest prompt, in every row of the program
-    ("tiny-swa-moe", 1, 8192, [5500], True, 6 * 1024),
-    ("tiny-mla-moe", 2, 8192, [4097, 1], True, 2 * 5 * 1024),
-    ("tiny-dsa-moe", 1, 16384, [8193], True, 9 * 1024),
-    ("tiny-swa-moe", 1, 2048, [1024], True, 1024),
+    ("tiny-swa-moe", 8192, [5500], True, 6 * 1024),
+    ("tiny-mla-moe", 8192, [4097, 1], True, 2 * 5 * 1024),
+    ("tiny-dsa-moe", 16384, [8193], True, 9 * 1024),
+    ("tiny-swa-moe", 2048, [1024], True, 1024),
+    # a whole group of a stack of one kind (models/decoder.py's own
+    # pass): the blocks its real rows fill, end to end
+    ("tiny-dense", 2048, [1820, 1025], True, 3 * 1024),
+    ("tiny-dense", 2048, [1280, 1290, 1500, 1025, 1100, 1, 1, 1], True,
+     7 * 1024),
+    ("tiny-dense", 2048, [1024, 1024], True, 2 * 1024),
+    ("tiny-dense", 2048, [1024, 1025], True, 3 * 1024),
+    ("tiny-dense", 256, [250, 130, 256, 1, 200, 90, 1, 1], True, 1024),
     # the loop does not engage: under two blocks, a suffix or a chunk of
-    # a prompt, a stack of one kind (models/decoder.py's own pass)
-    ("tiny-swa-moe", 4, 1024, [300, 900, 7, 1], True, 4 * 1024),
-    ("tiny-mla-moe", 1, 8192, [5500], False, 8192),
-    ("tiny-dense", 2, 2048, [1820, 1025], True, 2 * 2048),
+    # a prompt, rows sharded or relayed (``whole`` False)
+    ("tiny-swa-moe", 1024, [300, 900, 7, 1], True, 4 * 1024),
+    ("tiny-mla-moe", 8192, [5500], False, 8192),
+    ("tiny-dense", 1024, [1000], True, 1024),
+    ("tiny-dense", 128, [100] * 8, True, 8 * 128),
+    ("tiny-dense", 2048, [1820, 1025], False, 2 * 2048),
 ])
 def test_prefill_rows_are_counted_by_the_models_own_rule(
-        model_id, rows, bucket, lens, whole, worked):
+        model_id, bucket, lens, whole, worked):
     """``totals.prefill``: what ``engine.prefill_pad_share.tok`` reads.
     The engine books a prompt program's rows through ``prompt_rows``,
-    the function the program's own loop takes its trips from."""
+    the function the program's own loop takes its trips from: on the
+    host's ints, on traced lengths, and by the rows the loop itself
+    (``_by_row_blocks``) touches."""
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
     from vgate_tpu.models import hybrid
     from vgate_tpu.models.specs import spec_for_model_id
 
     spec = spec_for_model_id(model_id)
-    got = rows * int(hybrid.prompt_rows(spec, bucket, max(lens), whole))
+    arrays, rows = hybrid.prompt_rows(
+        spec, bucket, np.asarray(lens, np.int32), whole)
+    got = arrays * int(rows)
     assert got == worked
-    # the same rule on a traced length gives the loop its trips
-    traced = jax.jit(lambda n: jnp.asarray(
-        hybrid.prompt_rows(spec, bucket, n, whole)))(max(lens))
-    assert rows * int(traced) == worked
+
+    # the same rule on traced lengths gives the loop its trips: the
+    # rows of each array that the loop's blocks reach
+    @jax.jit
+    def touched(lens):
+        arrays, n_rows = hybrid.prompt_rows(spec, bucket, lens, whole)
+        ones = jnp.zeros((arrays, len(lens) * bucket // arrays, 1))
+        return jnp.sum(hybrid._by_row_blocks(
+            lambda r: r + 1, (ones,),
+            None if isinstance(n_rows, int) else n_rows))
+
+    assert int(touched(jnp.asarray(lens, jnp.int32))) == worked
     rec = recorder()
     assert rec.totals()["prefill"] == {
         "rows_worked": 0, "rows_real": 0, "rows_padding": 0}

@@ -639,11 +639,12 @@ def test_window_stack_decode_chunk_and_prompt_kernel_compile_on_v5e(v5e):
     assert "swa_prefill_attention_pallas" in band.as_text()
 
 
-def _prompt_program(A, spec, params, pool, v_pool, state, bucket=8192):
-    """The cell's prompt program: ONE prompt in the 8,192 bucket."""
+def _prompt_program(A, spec, params, pool, v_pool, state, bucket=8192,
+                    B=1):
+    """The cell's prompt program: ``B`` prompts (ONE, in the long cells)
+    in ``bucket``."""
     from vgate_tpu.runtime.step_programs import _prefill_step
 
-    B = 1
     return _prefill_step.lower(
         params, spec, A((B, bucket), jnp.int32), A((B,), jnp.int32),
         pool, v_pool, A((B, bucket // PAGE), jnp.int32),
@@ -981,6 +982,41 @@ def test_long_prompt_programs_loop_over_their_row_blocks_on_v5e(
     assert temp <= parent + (320 << 20), (
         f"{cell}: {temp} temporary bytes against the parent's {parent}")
     assert held + temp < 15.9e9
+
+
+# temporary bytes of the parent's (PR 45, commit 32a8c2e) dense prompt
+# programs at [8, 2048] by the same compile: the whole bucket at once
+DENSE_PARENT_TEMP_BYTES = {"1.5b": 664_240_640, "7b-l14": 1_410_883_072}
+
+
+@pytest.mark.parametrize("cell, preset, changes", [
+    ("1.5b", "Qwen/Qwen2.5-1.5B-Instruct", {}),
+    ("7b-l14", "Qwen/Qwen2.5-7B-Instruct", {"num_layers": 14}),
+])
+def test_dense_prompt_program_packs_its_groups_rows_on_v5e(
+        v5e, cell, preset, changes):
+    """The two dense configurations' prompt program at ``[8, 2048]``
+    (models/decoder.py ``_packed_prompt_pass``) builds for the v5e: the
+    flash kernel in it, a ``while`` whose trips are an operand around
+    the layer's two position-wise halves, the pools aliased input to
+    output, and temporaries no more than the parent's whole-bucket pass
+    took by the same compile (PERF.md section 6, PR 46)."""
+    A = _abstract(v5e)
+    spec, params = _cut_and_shapes(A, preset, changes)
+    pool = A((spec.num_layers, spec.num_kv_heads, 2049, PAGE,
+              spec.head_dim), jnp.bfloat16)
+    compiled = _prompt_program(
+        A, spec, params, pool, pool, None, bucket=2048, B=8)
+    text = compiled.as_text()
+    assert "flash_prefill_attention_pallas" in text
+    # the front half's loop, and with the back half's two in all
+    assert _counted_loops(text, "qkv") and _counted_loops(text, "") >= 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _nbytes((pool, pool))
+    parent = DENSE_PARENT_TEMP_BYTES[cell]
+    assert mem.temp_size_in_bytes <= parent, (
+        f"{cell}: {mem.temp_size_in_bytes} temporary bytes against the "
+        f"parent's {parent}")
 
 
 # the EvaByte cut as its cell serves it: 8 of 32 layers, 20 slots of

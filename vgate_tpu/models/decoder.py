@@ -266,6 +266,11 @@ def _kernel_or_twin(spec: ModelSpec, use_pallas: bool, mesh) -> str:
     return "pallas"
 
 
+# the prompt passes that shard a sequence's rows or relay them between
+# stages: theirs is the whole bucket, whatever the prompts hold
+WHOLE_BUCKET_IMPLS = ("pp_relay", "ring")
+
+
 def prefill_attention_impl(
     spec: ModelSpec, use_pallas: bool, mesh=None
 ) -> str:
@@ -613,13 +618,24 @@ def prefill_forward(
             attn_fn = functools.partial(
                 tp_flash_prefill_attention, attn_fn, mesh
             )
+    from vgate_tpu.models import hybrid
+
+    # a stack of one kind: a group of two row blocks or more is worked
+    # on packed, the real rows end to end
+    n_rows = S if spec.is_hybrid else hybrid.prompt_rows(
+        spec, S, seq_lens, whole=impl not in WHOLE_BUCKET_IMPLS)[1]
+    if not isinstance(n_rows, int):
+        if impl == "pallas":  # this pass never reads a padding row
+            attn_fn = functools.partial(attn_fn, skip_padding=True)
+        x, k_pages, v_pages = _packed_prompt_pass(
+            params, spec, tokens, seq_lens, k_pages, v_pages, page_tables,
+            attn_fn, n_rows)
+        return _logits(params, spec, x, all_heads), k_pages, v_pages
     x = _embed(params, spec, tokens)  # [B, S, D]
     # the prompt pass only WRITES pages (attention runs over the fresh
     # k/v): layer-indexed in-place writes on the carried pools
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
     if spec.is_hybrid:
-        from vgate_tpu.models import hybrid
-
         # the kernel leaves out the query blocks of padding rows, which
         # this pass never reads
         skip = {"skip_padding": True} if impl == "pallas" else {}
@@ -656,6 +672,64 @@ def prefill_forward(
             k_pages, v_pages)
 
 
+def _packed_prompt_pass(params, spec: ModelSpec, tokens, seq_lens, k_pages,
+                        v_pages, page_tables, attn_fn, n_rows):
+    """The dense stack's whole-prompt pass over the group's REAL rows:
+    row ``s < seq_lens[b]`` of sequence ``b`` lies at packed row
+    ``offset[b] + s``, the sequences end to end, and the residual stream
+    stays packed ``[1, B x S, D]`` through the layer scan.  What is
+    position-wise (the norms, the projections and rotary at the rows'
+    own positions, ``o_proj``, the feed-forward, the adds) runs over the
+    ``n_rows`` (models/hybrid.py ``prompt_rows``: traced) packed rows
+    that hold a real row, in blocks (``_by_row_blocks``); a layer unpacks
+    only q, k and v, for the page write (whole pages of ``[B, S]``,
+    padding to the trash page as ever) and for the attention, which
+    stays one launch over ``[B, S]``, rows apart, and packs only the
+    attention's result.  A padding row of the group (length 1) costs one
+    packed row.  Behind a sequence's last real row ``unpack`` leaves the
+    next sequences' rows, which are finite and which nothing reads.
+    Returns (each sequence's last real row [B, D], k_pages, v_pages)."""
+    from vgate_tpu.models.hybrid import _by_row_blocks, _heads_flat
+
+    B, S = tokens.shape
+    offsets = jnp.cumsum(seq_lens) - seq_lens  # offset[b] + S <= B x S
+
+    def pack(t):  # [B, S, ...] -> [1, B x S, ...]; later rows win
+        out = jnp.zeros((B * S,) + t.shape[2:], t.dtype)
+        for b in range(B):
+            out = jax.lax.dynamic_update_slice_in_dim(
+                out, t[b], offsets[b], 0)
+        return out[None]
+
+    def unpack(t):  # [1, B x S, ...] -> [B, S, ...]
+        return jnp.stack([
+            jax.lax.dynamic_slice_in_dim(t[0], offsets[b], S, 0)
+            for b in range(B)])
+
+    by_rows = lambda fn, *rows: _by_row_blocks(fn, rows, n_rows)
+    positions = pack(jnp.broadcast_to(
+        jnp.arange(S, dtype=jnp.int32)[None, :], (B, S)))
+    x = _embed(params, spec, pack(tokens))  # [1, B x S, D]
+
+    def body(h, lp, win, kp, vp, layer):
+        with jax.named_scope("qkv"):
+            q, k, v = map(unpack, by_rows(
+                lambda rows, at: _prefill_qkv(rows, lp, spec, at),
+                h, positions))
+            kp, vp = _write_whole_pages(
+                k, v, spec, page_tables, kp, vp, layer)
+        window = {"window": win} if spec.sliding_window > 0 else {}
+        with jax.named_scope("attention"):
+            attn = attn_fn(q, k, v, seq_lens, **window)
+        h = by_rows(lambda rows, attn: _finish_layer(rows, attn, lp, spec),
+                    h, pack(_heads_flat(attn)))
+        return h, kp, vp
+
+    x, k_pages, v_pages = _kv_layer_scan(
+        params, spec, body, x, k_pages, v_pages)
+    return _last_rows(unpack(x), seq_lens), k_pages, v_pages
+
+
 @jax.named_scope("qkv")
 def _prefill_qkv_write(
     h, lp, spec: ModelSpec, positions, page_tables, k_pages_l, v_pages_l,
@@ -680,13 +754,7 @@ def _prefill_qkv_write(
     suffix into one more page."""
     B, S = h.shape[:2]
     ps = k_pages_l.shape[-2]
-    n_pages = S // ps
-    normed = rms_norm(
-        h, lp["input_norm"], spec.rms_eps, spec.unit_offset_norm
-    )
-    q, k, v = _project_qkv(normed, lp, spec)
-    q = apply_rope(q, positions, spec.rope_theta, spec.rope_scaling)
-    k = apply_rope(k, positions, spec.rope_theta, spec.rope_scaling)
+    q, k, v = _prefill_qkv(h, lp, spec, positions)
     if offsets is not None:
         idx = offsets[:, None] + jnp.arange(S)[None, :]  # [B, S] in-suffix
         slot = idx % ps
@@ -698,6 +766,29 @@ def _prefill_qkv_write(
             v_pages_l, pages_bs, slot, v, layer=layer
         )
         return q, k, v, k_pages_l, v_pages_l
+    return (q, k, v, *_write_whole_pages(
+        k, v, spec, page_tables, k_pages_l, v_pages_l, layer))
+
+
+def _prefill_qkv(h, lp, spec: ModelSpec, positions):
+    """Rows [..., S, D] at ``positions`` [..., S] -> q [..., S, H, hd], k
+    and v [..., S, KV, hd]: input norm, projections, rope."""
+    normed = rms_norm(
+        h, lp["input_norm"], spec.rms_eps, spec.unit_offset_norm
+    )
+    q, k, v = _project_qkv(normed, lp, spec)
+    q = apply_rope(q, positions, spec.rope_theta, spec.rope_scaling)
+    k = apply_rope(k, positions, spec.rope_theta, spec.rope_scaling)
+    return q, k, v
+
+
+def _write_whole_pages(k, v, spec: ModelSpec, page_tables, k_pages_l,
+                       v_pages_l, layer):
+    """A prompt's fresh k and v [B, S, KV, hd] into its pages, whole
+    pages from the first row on."""
+    B, S = k.shape[:2]
+    ps = k_pages_l.shape[-2]
+    n_pages = S // ps
     pt = page_tables[:, :n_pages]
     to_pages = lambda t: jnp.transpose(
         t.reshape(B, n_pages, ps, spec.num_kv_heads, spec.head_dim),
@@ -705,7 +796,7 @@ def _prefill_qkv_write(
     )  # [B, n_pages, KV, ps, hd]
     k_pages_l = kv_write_pages(k_pages_l, pt, to_pages(k), layer=layer)
     v_pages_l = kv_write_pages(v_pages_l, pt, to_pages(v), layer=layer)
-    return q, k, v, k_pages_l, v_pages_l
+    return k_pages_l, v_pages_l
 
 
 def _finish_layer(h, attn, lp, spec: ModelSpec):

@@ -85,7 +85,9 @@ differs:
   attention kernels skip the query blocks past them: a 5,500-token
   prompt in the 8,192 bucket pays for 6,144 rows.  What engages it is
   the traced shape alone; a decode step, a short wave, a suffix and a
-  chunk are traced as ever.
+  chunk are traced as ever.  A stack of ONE kind (models/decoder.py
+  ``_packed_prompt_pass``) runs the same loop over its group's real
+  rows laid end to end; ``prompt_rows`` is the rule for both.
 
 Parameters (``init_layers``): ``layers = {group: {name: [P, n, ...]}}``
 with ``P`` periods and ``n`` layers of the group a period.  Qwen3-Next's
@@ -617,18 +619,27 @@ def ring_tables(slots, n_pages: int, rings: int, R: int, first=None,
 PROMPT_ROW_BLOCK = 1024
 
 
-def prompt_rows(spec: ModelSpec, S: int, longest, whole: bool = True):
-    """Of the ``S`` rows a sequence has in a prompt program, how many
-    the position-wise sub-blocks work on when its longest prompt holds
-    ``longest`` tokens (an int on the host, a traced scalar in the
-    program: ONE rule for both).  ``S`` itself, as an int, where the
-    program is traced as it always was: a stack of one kind
-    (models/decoder.py's own pass), under two blocks of rows, or a
-    suffix or a chunk (not the ``whole`` prompt)."""
-    R = PROMPT_ROW_BLOCK
-    if not (spec.is_hybrid and whole and S >= 2 * R and S % R == 0):
-        return S
-    return cdiv(longest, R) * R
+def prompt_rows(spec: ModelSpec, S: int, lens, whole: bool = True):
+    """What the position-wise sub-blocks of a prompt program over
+    ``[len(lens), S]`` rows work on when its prompts hold ``lens`` tokens
+    (an array of ints on the host, a traced one in the program: ONE rule
+    for both), as ``(arrays, rows)``: so many arrays of so many rows
+    each.  A ``whole`` prompt (not a suffix or a chunk, and all of a
+    sequence's rows in one place) that spans two blocks of rows or more
+    works on the blocks that hold a real row, ``rows`` then traced:
+
+    * a stack of several kinds, every sequence as far as the longest
+      reaches: ``(B, ceil(max(lens) / R) x R)``;
+    * a stack of one kind (models/decoder.py's own pass), the group's
+      real rows laid end to end: ``(1, ceil(sum(lens) / R) x R)``.
+
+    Anything else is traced as it always was, ``(B, S)`` in ints."""
+    B, R = len(lens), PROMPT_ROW_BLOCK
+    if whole and spec.is_hybrid and S >= 2 * R and S % R == 0:
+        return B, cdiv(lens.max(), R) * R
+    if whole and not spec.is_hybrid and B * S >= 2 * R and B * S % R == 0:
+        return 1, cdiv(lens.sum(), R) * R
+    return B, S
 
 
 def _as_is(rows):
@@ -1642,8 +1653,8 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
     row_mask = jnp.arange(S)[None, :] < lens[:, None]
     # a long whole prompt: the position-wise work in blocks of rows, as
     # far as the longest prompt reaches (None: the whole bucket at once)
-    n_rows = prompt_rows(spec, S, jnp.max(lens),
-                         prefix_lens is None and ctx_tables is None)
+    _, n_rows = prompt_rows(
+        spec, S, lens, prefix_lens is None and ctx_tables is None)
     n_rows = None if isinstance(n_rows, int) else n_rows
     by_rows = lambda fn, *rows: _by_row_blocks(fn, rows, n_rows)
     to_pages = lambda t: jnp.transpose(

@@ -772,9 +772,10 @@ class PerfRecorder:
     def note_prefill_rows(self, worked: int, real: int) -> None:
         """One prompt program, booked at its readback: ``worked`` rows
         went through its position-wise sub-blocks (the whole ``[B, S]``
-        or, of a long prompt's, the blocks of rows up to the longest
-        prompt: models/hybrid.py ``prompt_rows``), ``real`` of them
-        held a prompt's token; the others were padding."""
+        or, of a long prompt's or a packed group's, the blocks of rows
+        that hold a real one: models/hybrid.py ``prompt_rows``),
+        ``real`` of them held a prompt's token; the others were
+        padding."""
         for name, add in (("rows_worked", worked), ("rows_real", real),
                           ("rows_padding", worked - real)):
             self._prefill[name] += add

@@ -64,6 +64,7 @@ from vgate_tpu.config import (
 )
 from vgate_tpu.logging_config import bound_request, get_logger
 from vgate_tpu.models.decoder import (
+    WHOLE_BUCKET_IMPLS,
     decode_attention_impl,
     decode_kv_write,
     multitok_attention_impl,
@@ -3103,8 +3104,10 @@ class EngineCore:
         real = int(lens[: len(plans)].sum())
         self.perf.count(prompt_programs=1, prompt_tokens=real)
         # the rows the program works on, by the model layer's own rule
-        self._prompt_rows.append((B * int(prompt_rows(
-            self.spec, bucket, int(lens.max()), whole=not cached)), real))
+        arrays, rows = prompt_rows(
+            self.spec, bucket, lens,
+            whole=not cached and attention[1]() not in WHOLE_BUCKET_IMPLS)
+        self._prompt_rows.append((arrays * int(rows), real))
         return out
 
     @engine_thread_only
