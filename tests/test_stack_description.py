@@ -765,6 +765,60 @@ FROZEN = {
         "indexer": "",
         "mlp": "sparsex8",
     },
+    "LiquidAI/LFM2-24B-A2B": {
+        "cut": (4, 4, 9),
+        "lead": "conv+mlp conv+mlp attn+moe conv+moe",
+        "period": (
+            "conv/conv/input_norm/0 moe/conv/post_norm/0 "
+            "conv/conv/input_norm/1 moe/conv/post_norm/1 "
+            "attn/attn/input_norm/0 moe/attn/post_norm/0 "
+            "conv/conv/input_norm/2 moe/conv/post_norm/2"
+        ),
+        "layers": (10, 0, 0, 0, 38),
+        "kv_pools": 2,
+        "windows": "0x40",
+        "num_params": 23843661440,
+        "hybrid": True,
+        "recurrent": "conv",
+        "indexer": "",
+        "mlp": "densex2 sparsex38",
+    },
+    "tiny-lfm2-moe": {
+        "cut": (4, 4, 2),
+        "lead": "conv+mlp conv+mlp attn+moe conv+moe",
+        "period": (
+            "conv/conv/input_norm/0 moe/conv/post_norm/0 "
+            "conv/conv/input_norm/1 moe/conv/post_norm/1 "
+            "attn/attn/input_norm/0 moe/attn/post_norm/0 "
+            "conv/conv/input_norm/2 moe/conv/post_norm/2"
+        ),
+        "layers": (3, 0, 0, 0, 10),
+        "kv_pools": 2,
+        "windows": "0x12",
+        "num_params": 766384,
+        "hybrid": True,
+        "recurrent": "conv",
+        "indexer": "",
+        "mlp": "densex2 sparsex10",
+    },
+    "config:lfm2-24b-a2b-e8.json": {
+        "cut": (4, 4, 9),
+        "lead": "conv+mlp conv+mlp attn+moe conv+moe",
+        "period": (
+            "conv/conv/input_norm/0 moe/conv/post_norm/0 "
+            "conv/conv/input_norm/1 moe/conv/post_norm/1 "
+            "attn/attn/input_norm/0 moe/attn/post_norm/0 "
+            "conv/conv/input_norm/2 moe/conv/post_norm/2"
+        ),
+        "layers": (10, 0, 0, 0, 38),
+        "kv_pools": 2,
+        "windows": "0x40",
+        "num_params": 3761333888,
+        "hybrid": True,
+        "recurrent": "conv",
+        "indexer": "",
+        "mlp": "densex2 sparsex38",
+    },
 }
 
 FIELDS = [
@@ -805,6 +859,8 @@ FIELDS = [
     ("index_n_heads", 0), ("index_head_dim", 0),
     ("indexer_rope_interleave", False), ("eva_window", 0),
     ("eva_chunk", 0), ("num_pred_heads", 1), ("fp32_residual", False),
+    ("conv_pattern", ""), ("conv_L_cache", 0), ("conv_bias", False),
+    ("router_norm_eps", 1e-20), ("kv_head_pack", 1),
 ]
 
 
@@ -824,6 +880,41 @@ def test_frozen_fields_of_the_spec():
             else f.default) for f in dataclasses.fields(ModelSpec)]
     assert got == FIELDS
     assert all(type(a) is type(b) for (_, a), (_, b) in zip(got, FIELDS))
+
+
+def test_the_conv_spelling_finds_its_leading_layers_by_the_one_search():
+    """LFM2's ``layer_types`` with ``num_dense_layers``: ``conv mlp`` x 2,
+    then ``attn moe``, ``conv moe`` x 3 repeating, ending two layers into
+    a repeat.  ``_cut`` finds 4 leading layers and 9 periods of ``conv
+    conv attn conv`` (the tiny preset: the first 12 types, 2 periods);
+    the state is a tail a conv layer and no tile."""
+    full = spec_for_model_id("LiquidAI/LFM2-24B-A2B")
+    assert full.conv_pattern == "CCA" + "CCCA" * 9 + "C"
+    assert [i for i, t in enumerate(full.layer_types)
+            if t == "full_attention"] == list(range(2, 40, 4))
+    assert (full.lead_layers, full.layers_per_period, full.num_periods) == (
+        4, 4, 9)
+    assert [layer[0] for layer in full.stack[4:8]] == [
+        "conv", "conv", "attn", "conv"]
+    assert [layer[1] for layer in full.stack[:3]] == ["mlp", "mlp", "moe"]
+    assert (full.conv_layers, full.attn_layers, full.linear_layers,
+            full.slot_state_layers) == (30, 10, 0, 30)
+    assert [full.group_layers(g) for g in ("conv", "attn")] == [3, 1]
+    assert abs(full.num_params / 1e9 - 23.84) < 0.01  # tied
+    cut = dataclasses.replace(full, num_experts=8)
+    assert abs(cut.num_params / 1e6 - 3761) < 1
+    kinds = full._kind_params()
+    assert kinds["conv"] == 12582912 + 4194304 + 6144
+    assert kinds["attn"] == 10485760 + 2 * 64  # and the per-head norms
+    assert kinds["mlp"] == 72351744
+    tiny = spec_for_model_id("tiny-lfm2-moe")
+    assert tiny.layer_types == full.layer_types[:12]
+    assert (tiny.lead_layers, tiny.num_periods, tiny.conv_layers) == (4, 2, 9)
+    # a head of 64 pairs into a 128-lane row; a head of 16 does not
+    assert full.kv_heads_pair and not tiny.kv_heads_pair
+    packed = full.pack_kv_heads()
+    assert (packed.cache_heads, packed.cache_head_dim) == (4, 128)
+    assert packed.stack == full.stack and packed.num_params == full.num_params
 
 
 class _Mixed(ModelSpec):

@@ -44,6 +44,7 @@ from vgate_tpu.ops.kv_quant import (
     kv_write_tokens,
     page_tokens,
 )
+from vgate_tpu.ops.head_pack import over_packed_pool, pack_rows
 from vgate_tpu.ops.norms import rms_norm
 from vgate_tpu.ops.quant import weighted_einsum
 from vgate_tpu.ops.rope import apply_rope
@@ -486,9 +487,15 @@ def _eva_prefill_attend(spec: ModelSpec, impl: str):
         block_k=math.gcd(k.shape[1], 256))
 
 
+def packed_group(spec: ModelSpec) -> int:
+    """Query heads a row of a PACKED pool serves (2 G); 0 where rows
+    hold one head."""
+    return spec.num_heads // spec.cache_heads if spec.kv_head_pack > 1 else 0
+
+
 def multitok_attention_impl(
     use_pallas: bool, mesh=None, rows: int = 1, unaligned: bool = False,
-    latent: bool = False,
+    latent: bool = False, group: int = 0,
 ) -> str:
     """As above, for the paged multi-token attention of
     ``prefill_suffix_forward`` (``rows`` = suffix bucket) and
@@ -504,7 +511,12 @@ def multitok_attention_impl(
         return "sp_shard"
     if latent:  # no kernel yet for query rows against a latent prefix
         return "jnp"
-    kernel_fits = rows <= 1024 and not unaligned and _axis(mesh, "tp") == 1
+    # over packed rows (``group`` = ``packed_group``: 2 G query heads a
+    # row of 128 lanes) the kernel's blocks grow with the group: 1,024
+    # rows x 14 heads (the 0.5B) run out of VMEM where 1,024 x 8 (LFM2)
+    # compile (tests/test_tpu_aot.py), so the rows it takes shrink
+    kernel_fits = (rows * max(group, 8) <= 8192 and not unaligned
+                   and _axis(mesh, "tp") == 1)
     return "pallas" if use_pallas and kernel_fits else "jnp"
 
 
@@ -760,10 +772,12 @@ def _prefill_qkv_write(
         slot = idx % ps
         pages_bs = jnp.take_along_axis(page_tables, idx // ps, axis=1)
         k_pages_l = kv_write_tokens(
-            k_pages_l, pages_bs, slot, k, layer=layer
+            k_pages_l, pages_bs, slot, pack_rows(k, spec.kv_head_pack),
+            layer=layer,
         )
         v_pages_l = kv_write_tokens(
-            v_pages_l, pages_bs, slot, v, layer=layer
+            v_pages_l, pages_bs, slot, pack_rows(v, spec.kv_head_pack),
+            layer=layer,
         )
         return q, k, v, k_pages_l, v_pages_l
     return (q, k, v, *_write_whole_pages(
@@ -785,13 +799,14 @@ def _prefill_qkv(h, lp, spec: ModelSpec, positions):
 def _write_whole_pages(k, v, spec: ModelSpec, page_tables, k_pages_l,
                        v_pages_l, layer):
     """A prompt's fresh k and v [B, S, KV, hd] into its pages, whole
-    pages from the first row on."""
+    pages from the first row on (the pool's rows: ``kv_head_pack`` heads
+    side by side, which of k's flat heads is a reshape)."""
     B, S = k.shape[:2]
     ps = k_pages_l.shape[-2]
     n_pages = S // ps
     pt = page_tables[:, :n_pages]
     to_pages = lambda t: jnp.transpose(
-        t.reshape(B, n_pages, ps, spec.num_kv_heads, spec.head_dim),
+        t.reshape(B, n_pages, ps, spec.cache_heads, spec.cache_head_dim),
         (0, 1, 3, 2, 4),
     )  # [B, n_pages, KV, ps, hd]
     k_pages_l = kv_write_pages(k_pages_l, pt, to_pages(k), layer=layer)
@@ -997,6 +1012,9 @@ def decode_forward(
             attn_fn = functools.partial(
                 tp_paged_decode_attention, attn_fn, mesh
             )
+    # a pool of packed rows (two heads of 64 a row): the same launch at
+    # (KV / 2, 2 G, 128), each head's own lanes taken of the result
+    attn_fn = over_packed_pool(attn_fn, spec)
     ps = page_tokens(k_pages)
     # the rows a step attends to and writes among, as a paged sequence:
     # the sequence's own pages, or an EVA spec's view of them
@@ -1034,8 +1052,12 @@ def decode_forward(
                         window=window, k_new=k, v_new=v,
                     )
             with jax.named_scope("kv_write"):
-                kp = kv_write_tokens(kp, page_ids, page_off, k, layer=layer)
-                vp = kv_write_tokens(vp, page_ids, page_off, v, layer=layer)
+                kp = kv_write_tokens(
+                    kp, page_ids, page_off,
+                    pack_rows(k, spec.kv_head_pack), layer=layer)
+                vp = kv_write_tokens(
+                    vp, page_ids, page_off,
+                    pack_rows(v, spec.kv_head_pack), layer=layer)
             with jax.named_scope("attention"):
                 attn = attn_fn(
                     q, kp, vp, tables, seq_lens, layer=layer,
@@ -1154,7 +1176,8 @@ def prefill_suffix_forward(
     x = _embed(params, spec, tokens)  # [B, S, D]
 
     impl = multitok_attention_impl(
-        use_pallas, mesh, rows=S, unaligned=unaligned, latent=spec.is_mla
+        use_pallas, mesh, rows=S, unaligned=unaligned, latent=spec.is_mla,
+        group=packed_group(spec),
     )
     kernels = use_pallas  # below, use_pallas narrows to the multitok kernel
     sp_mesh = mesh if impl == "sp_shard" else None
@@ -1196,6 +1219,9 @@ def prefill_suffix_forward(
             paged_multitok_attention_pallas,
         )
 
+        multitok_fn = over_packed_pool(paged_multitok_attention_pallas, spec)
+    suffix_fn = over_packed_pool(paged_suffix_attention, spec)
+
     if spec.is_hybrid:
         # a later chunk of a chunked prefill: the rows continue from the
         # slot's recurrent state (zeros where nothing precedes them);
@@ -1218,11 +1244,11 @@ def prefill_suffix_forward(
                     block_k=256 if k.shape[1] % 256 == 0 else ps,
                 )
             if use_pallas:
-                return paged_multitok_attention_pallas(
+                return multitok_fn(
                     q, kp, vp, ctx_page_tables, prefix_lens, suffix_lens,
                     layer=layer, scale=_query_scale(spec),
                 )
-            return paged_suffix_attention(
+            return suffix_fn(
                 q, kp, vp, ctx_page_tables, prefix_lens, total_lens,
                 scale=_query_scale(spec), layer=layer,
             )
@@ -1258,13 +1284,13 @@ def prefill_suffix_forward(
                 # starting at an arbitrary position, causal within the
                 # rows, live-page DMA only (the suffix KV was just
                 # written)
-                attn = paged_multitok_attention_pallas(
+                attn = multitok_fn(
                     q, kp, vp, ctx_page_tables, prefix_lens, suffix_lens,
                     window=window, layer=layer,
                     softcap=spec.attn_softcap, scale=_query_scale(spec),
                 )
             else:
-                attn = paged_suffix_attention(
+                attn = suffix_fn(
                     q, kp, vp, ctx_page_tables, prefix_lens,
                     total_lens, softcap=spec.attn_softcap,
                     window=window, scale=_query_scale(spec), layer=layer,
@@ -1327,7 +1353,8 @@ def spec_verify_forward(
     total_lens = positions0 + input_lens
     x = _embed(params, spec, tokens)  # [B, S, D]
 
-    impl = multitok_attention_impl(use_pallas, mesh, rows=S)
+    impl = multitok_attention_impl(use_pallas, mesh, rows=S,
+                                   group=packed_group(spec))
     sp_mesh = mesh if impl == "sp_shard" else None
     use_pallas = impl == "pallas"
     if sp_mesh is not None:
@@ -1367,6 +1394,9 @@ def spec_verify_forward(
             paged_multitok_attention_pallas,
         )
 
+        multitok_fn = over_packed_pool(paged_multitok_attention_pallas, spec)
+    suffix_fn = over_packed_pool(paged_suffix_attention, spec)
+
     def body(h, lp, win, kp, vp, layer):
         """One verify layer against the full stacked pools."""
         normed = rms_norm(
@@ -1375,18 +1405,20 @@ def spec_verify_forward(
         q, k, v = _project_qkv(normed, lp, spec)
         q = apply_rope(q, positions, spec.rope_theta, spec.rope_scaling)
         k = apply_rope(k, positions, spec.rope_theta, spec.rope_scaling)
-        kp = kv_write_tokens(kp, page_ids, page_off, k, layer=layer)
-        vp = kv_write_tokens(vp, page_ids, page_off, v, layer=layer)
+        kp = kv_write_tokens(kp, page_ids, page_off,
+                             pack_rows(k, spec.kv_head_pack), layer=layer)
+        vp = kv_write_tokens(vp, page_ids, page_off,
+                             pack_rows(v, spec.kv_head_pack), layer=layer)
         window = win if spec.sliding_window > 0 else None
         with jax.named_scope("attention"):
             if use_pallas:
-                attn = paged_multitok_attention_pallas(
+                attn = multitok_fn(
                     q, kp, vp, page_tables, positions0,
                     input_lens, window=window, layer=layer,
                     softcap=spec.attn_softcap, scale=_query_scale(spec),
                 )
             else:
-                attn = paged_suffix_attention(
+                attn = suffix_fn(
                     q, kp, vp, page_tables, positions0,
                     total_lens, softcap=spec.attn_softcap, window=window,
                     scale=_query_scale(spec), layer=layer,

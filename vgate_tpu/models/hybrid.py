@@ -29,6 +29,12 @@ whichever way the spec's fields spell them; the walker takes from it
   the allocator's in the same pool arrays, and as its chunk's summary
   row in the sequence's pages once the window has closed; exact rows
   and summary rows meet in one softmax,
+* ``conv``  LFM2's gated short convolution: ``[B | C | X] = u W_in``, a
+  depth-wise causal convolution of three taps over ``B * X`` with no
+  activation, ``(C * c) W_out``.  What it carries from token to token
+  is the last two rows of ``B * X``: a convolution tail a decode slot
+  and NO tile (``{"conv": [conv layers, slots, K-1, D]}`` is the whole
+  state),
 * ``mlp``   a dense SwiGLU feed-forward (a leading layer's, or every
   layer's of an ``eva`` stack),
 * ``moe``   the expert layer of ``ops/moe.py``.
@@ -98,7 +104,8 @@ period); a pattern's groups are its kinds, ``mamba`` / ``attn`` /
 experts of a window or a full layer) and ``lead``, a TUPLE of the
 leading layers' own trees; an ``indexer_pattern`` spec's are ``pick``
 and ``reuse`` (latent attention with and without an indexer, and the
-layer's experts) and ``lead``.
+layer's experts) and ``lead``; a ``conv_pattern`` spec's are ``conv``
+and ``attn`` (the mixer and the layer's experts) and ``lead``.
 """
 
 from __future__ import annotations
@@ -137,6 +144,8 @@ def init_layers(spec: ModelSpec, key, dtype, normal, norm_init
         return _init_mla_layers(spec, key, dtype, normal, norm_init)
     if spec.window_pattern:
         return _init_window_layers(spec, key, dtype, normal)
+    if spec.conv_pattern:
+        return _init_conv_layers(spec, key, dtype, normal)
     if spec.layer_pattern:
         return _init_pattern_layers(spec, key, dtype, normal)
     return _init_paired_layers(spec, key, dtype, normal, norm_init)
@@ -376,6 +385,77 @@ def _init_window_layers(spec: ModelSpec, key, dtype, normal
     return out
 
 
+def _init_conv_layers(spec: ModelSpec, key, dtype, normal
+                      ) -> Dict[str, Any]:
+    """A ``conv_pattern`` spec's tensors from ``fold_in(key, 47)`` split
+    32 ways: tensor ``j`` of layer ``i`` (its index in the WHOLE stack,
+    leading layers included) from ``fold_in(key j, i)``, N(0, 0.02),
+    every norm weight 1, but for the short convolution's input
+    projection, N(0, 1 / hidden), and its taps, N(0, 0.5): ``B``, ``C``
+    and ``X`` then have a standard deviation near 1 at any width and so
+    has the mixer's output before its projection (at 0.02 and a hidden
+    size of 64 the product of the three spreads by 0.004, and a wrong
+    tap order, a stale tail or an activation that is not there would
+    hide under a comparison's tolerance).  The router's selection bias
+    N(0, 0.02) and NOT zero."""
+    ck = jax.random.split(jax.random.fold_in(key, 47), 32)
+    D, H, KV, hd = (spec.hidden_size, spec.num_heads, spec.num_kv_heads,
+                    spec.head_dim)
+    E, R, Fe = spec.num_experts, spec.router_experts, spec.expert_width
+    F, K = spec.intermediate_size, spec.conv_L_cache
+    ones = lambda *shape: jnp.ones(shape, dtype)
+    mixer = {
+        "conv": {"in_proj": (0, (D, 3 * D), D ** -0.5),
+                 "out_proj": (2, (D, D), 0.02)},
+        "attn": {"q": (4, (D, H * hd), 0.02), "k": (5, (D, KV * hd), 0.02),
+                 "v": (6, (D, KV * hd), 0.02), "o": (7, (H * hd, D), 0.02)},
+    }
+    ff = {
+        "mlp": {"gate": (8, (D, F), 0.02), "up": (9, (D, F), 0.02),
+                "down": (10, (F, D), 0.02)},
+        "moe": {"gate": (12, (E, D, Fe), 0.02), "up": (13, (E, D, Fe), 0.02),
+                "down": (14, (E, Fe, D), 0.02)},
+    }
+
+    def draw(layers, j, shape, scale, rnd=normal):
+        """Tensor ``j`` of the ``layers`` (stack indices), stacked."""
+        return jax.jit(lambda k: jax.lax.map(
+            lambda i: rnd(jax.random.fold_in(k, i), shape, scale),
+            jnp.asarray(layers)))(ck[j])
+
+    def tree(layers, lead, kind, feed):
+        """The tensors of ``layers`` (all of one mixer ``kind`` and one
+        feed-forward), each ``lead + its shape``."""
+        put = lambda a: a.reshape(lead + a.shape[1:])
+        out = {"input_norm": ones(*lead, D), "post_norm": ones(*lead, D)}
+        for name, (j, shape, scale) in {**mixer[kind], **ff[feed]}.items():
+            out[name] = {"w": put(draw(layers, j, shape, scale))}
+        if kind == "conv":
+            out["conv"] = put(draw(layers, 1, (D, K), 0.5))
+            if spec.conv_bias:
+                out["conv_bias"] = put(draw(layers, 3, (D,), 0.02))
+        else:
+            out.update(q_norm=ones(*lead, hd), k_norm=ones(*lead, hd))
+        if feed == "moe":
+            out["router"] = put(draw(layers, 11, (D, R), 0.02))
+            out["router_bias"] = put(draw(
+                layers, 15, (R,), 0.02,
+                lambda k, shape, scale: jax.random.normal(
+                    k, shape, jnp.float32) * scale))
+        return out
+
+    lead, P = spec.lead_layers, spec.num_periods
+    kinds = spec.stack
+    out: Dict[str, Any] = {"lead": tuple(
+        tree([i], (), *kinds[i]) for i in range(lead))}
+    for group in ("conv", "attn"):
+        layers = [i for i in range(lead, spec.num_layers)
+                  if kinds[i][0] == group]
+        if layers:
+            out[group] = tree(layers, (P, len(layers) // P), group, "moe")
+    return out
+
+
 def _init_pattern_layers(spec: ModelSpec, key, dtype, normal
                          ) -> Dict[str, Any]:
     """A ``layer_pattern`` spec's tensors from ``fold_in(key, 31)`` split
@@ -522,8 +602,11 @@ def _init_paired_layers(spec: ModelSpec, key, dtype, normal, norm_init
 
 
 def _state_shapes(spec: ModelSpec):
-    """(a slot's tile [heads, ., .] in one recurrent layer, its
-    convolution tail [K-1, C]), by the kind of the recurrent layers."""
+    """(a slot's tile [heads, ., .] in one recurrent layer, or None
+    where the kind keeps none; its convolution tail [K-1, C]), by the
+    kind of the layers that carry a state."""
+    if spec.recurrent_kind == "conv":
+        return None, (spec.conv_L_cache - 1, spec.hidden_size)
     if spec.recurrent_kind == "mamba":
         return ((spec.mamba_num_heads, spec.mamba_head_dim,
                  spec.mamba_state_size),
@@ -554,7 +637,8 @@ def eva_window_pages(spec: ModelSpec, page_size: int) -> int:
 def make_state(spec: ModelSpec, slots: int, dtype, page_size: int = 0,
                pool_pages: int = 0) -> Dict[str, jax.Array]:
     """What a spec keeps a decode SLOT beside the paged pool, zeros: the
-    recurrent state of every recurrent layer (one row a slot), the K
+    recurrent state of every recurrent layer (one row a slot; a gated
+    short convolution's is its tail alone), the K
     and V rings of every window layer (``page_size`` the pool's).  An
     EVA spec's open windows are pages of the POOL arrays behind the
     allocator's ``pool_pages`` (runtime/kv_cache.py ``slot_pages``);
@@ -563,11 +647,12 @@ def make_state(spec: ModelSpec, slots: int, dtype, page_size: int = 0,
     if spec.eva_layers:
         out.update(eva_pages=eva.window_pages(
             pool_pages, slots, eva_window_pages(spec, page_size)))
-    if spec.linear_layers:
+    if spec.recurrent_kind:
         tile, tail = _state_shapes(spec)
-        lead = (spec.linear_layers, slots)
-        out.update(S=jnp.zeros(lead + tile, jnp.float32),
-                   conv=jnp.zeros(lead + tail, dtype))
+        lead = (spec.recurrent_layers, slots)
+        out.update(conv=jnp.zeros(lead + tail, dtype))
+        if tile is not None:
+            out.update(S=jnp.zeros(lead + tile, jnp.float32))
     if spec.swa_layers:
         shape = _ring_shape(spec, slots, page_size)
         out.update(ring_k=jnp.zeros(shape, dtype),
@@ -579,10 +664,10 @@ def state_bytes_per_slot(spec: ModelSpec, dtype_bytes: int,
                          page_size: int = 0) -> int:
     """Bytes one slot holds over all recurrent layers and all rings."""
     out = 0
-    if spec.linear_layers:
+    if spec.recurrent_kind:
         tile, tail = _state_shapes(spec)
-        out += spec.linear_layers * (
-            math.prod(tile) * 4 + math.prod(tail) * dtype_bytes)
+        out += spec.recurrent_layers * (
+            math.prod(tile or (0,)) * 4 + math.prod(tail) * dtype_bytes)
     if spec.swa_layers:
         _, KV, _, ps, hd = _ring_shape(spec, 1, page_size)
         out += (spec.swa_layers * 2 * KV * ring_pages(spec, page_size)
@@ -767,6 +852,18 @@ def _linear_out(o, z, lp, spec: ModelSpec, dtype):
 PROMPT_BLOCK_TOKENS = 4096
 
 
+def _in_row_groups(fn, normed, *per_row):
+    """``fn(rows, *per-row arrays) -> a tuple of arrays by row`` over a
+    wave normed [B, S, D] in groups of ``PROMPT_BLOCK_TOKENS // S`` rows,
+    unrolled (see ops/moe.py expert_layer), each result concatenated."""
+    B, S = normed.shape[:2]
+    rows = max(1, PROMPT_BLOCK_TOKENS // S)
+    parts = [fn(*(a[lo:lo + rows] for a in (normed,) + per_row))
+             for lo in range(0, B, rows)]
+    return tuple(jnp.concatenate([p[i] for p in parts])
+                 for i in range(len(parts[0])))
+
+
 def _linear_rows(normed, lp, spec: ModelSpec, lens, tail, S0):
     """The Gated DeltaNet mixer over normed rows [B, S, D] from (tail,
     S0): returns (out, final state, final tail).  Padded positions move
@@ -783,18 +880,22 @@ def _linear_rows(normed, lp, spec: ModelSpec, lens, tail, S0):
     return _linear_out(o, z, lp, spec, normed.dtype), S1, new_tail
 
 
-def _conv_step(tail, row, w, bias, active):
+def _conv_step(tail, row, w, bias, active, act=jax.nn.silu,
+               scope: str = "conv"):
     """The causal convolution for one decode step: tail [B, K-1, C] the
-    rows before ``row`` [B, C].  Returns (SiLU(conv) [B, C] in the row's
-    type, the tail moved on by one row where ``active``)."""
-    with jax.named_scope("conv"):
+    rows before ``row`` [B, C].  Returns (``act`` of the convolution [B,
+    C] in the row's type (``ops/gated_delta.py causal_conv``'s: None
+    for none), the tail moved on by one row where ``active``)."""
+    with jax.named_scope(scope):
         cat = jnp.concatenate([tail, row[:, None].astype(tail.dtype)], 1)
         y = jnp.einsum("bkc,ck->bc", cat.astype(jnp.float32),
                        w.astype(jnp.float32))
         if bias is not None:
             y = y + bias.astype(jnp.float32)
+        if act is not None:
+            y = act(y)
         new_tail = jnp.where(active[:, None, None], cat[:, 1:], tail)
-    return jax.nn.silu(y).astype(row.dtype), new_tail
+    return y.astype(row.dtype), new_tail
 
 
 def _linear_step(normed, lp, st, li, spec: ModelSpec, active, use_pallas):
@@ -876,6 +977,51 @@ def _mamba_step(normed, lp, st, li, spec: ModelSpec, active, use_pallas):
     return _mamba_out(o, x, z, lp, spec, normed.dtype), {"S": S, "conv": conv}
 
 
+def _conv_gates(normed, lp):
+    """The gated short convolution's input projection ``[B | C | X]``:
+    what the taps run over, ``B * X``, and the gate on their result."""
+    b, c, x = jnp.split(
+        jnp.einsum("...d,dc->...c", normed, lp["in_proj"]["w"]), 3, axis=-1)
+    return b * x, c
+
+
+def _conv_rows(normed, lp, lens, tail):
+    """The gated short convolution over normed rows [B, S, D] from
+    ``tail`` [B, K-1, D]: returns (out, final tail), the tail taken at
+    the rows' real lengths."""
+    z, gate = _conv_gates(normed, lp)
+    with jax.named_scope("short_conv"):
+        c, new_tail = gd.causal_conv(z, tail, lp["conv"], lens,
+                                     lp.get("conv_bias"), act=None)
+    return jnp.einsum("...c,cd->...d", gate * c,
+                      lp["out_proj"]["w"]), new_tail
+
+
+@jax.named_scope("conv_mixer")
+def _conv_prompt(normed, lp, st, li, lens, slots, fresh):
+    """A gated short convolution over prompt rows normed [B, S, D]:
+    starts from the slot's tail (zeros where ``fresh``), ends with the
+    tail overwritten.  A wide wave goes through in groups of rows."""
+    tail = jnp.where(fresh[:, None, None], 0, st["conv"][li][slots])
+    out, new_tail = _in_row_groups(
+        lambda rows, lens, tail: _conv_rows(rows, lp, lens, tail),
+        normed, lens, tail)
+    return out, {**st, "conv": st["conv"].at[li, slots].set(
+        new_tail.astype(st["conv"].dtype), mode="drop")}
+
+
+@jax.named_scope("conv_mixer")
+def _conv_mixer_step(normed, lp, st, li, active):
+    """A gated short convolution for one decode step, normed [B, D], row
+    = slot: active slots' tails move on in place, idle rows stay."""
+    z, gate = _conv_gates(normed, lp)
+    c, new_tail = _conv_step(st["conv"][li], z, lp["conv"],
+                             lp.get("conv_bias"), active, act=None,
+                             scope="short_conv")
+    out = jnp.einsum("...c,cd->...d", gate * c, lp["out_proj"]["w"])
+    return out, {**st, "conv": st["conv"].at[li].set(new_tail)}
+
+
 # a recurrent kind's (prompt rows, decode step) and its scope in a trace
 _RECURRENT = {
     "gdn": (_linear_rows, _linear_step, "linear_attn"),
@@ -890,20 +1036,12 @@ def _recurrent_prompt(kind, normed, lp, st, li, spec: ModelSpec, lens,
     row overwritten whole.  A wide wave goes through in groups of rows."""
     rows_fn, _, scope = _RECURRENT[kind]
     with jax.named_scope(scope):
-        B, S = normed.shape[:2]
         keep = jnp.logical_not(fresh)
         tail = jnp.where(keep[:, None, None], st["conv"][li][slots], 0)
         S0 = jnp.where(keep[:, None, None, None], st["S"][li][slots], 0.0)
-        rows = max(1, PROMPT_BLOCK_TOKENS // S)
-        # groups of rows unrolled (see ops/moe.py expert_layer)
-        parts = [
-            rows_fn(normed[lo:lo + rows], lp, spec, lens[lo:lo + rows],
-                    tail[lo:lo + rows], S0[lo:lo + rows])
-            for lo in range(0, B, rows)
-        ]
-        out, S1, new_tail = (
-            jnp.concatenate([p[i] for p in parts]) for i in range(3)
-        )
+        out, S1, new_tail = _in_row_groups(
+            lambda rows, lens, tail, S0: rows_fn(
+                rows, lp, spec, lens, tail, S0), normed, lens, tail, S0)
         st = {
             "S": st["S"].at[li, slots].set(S1, mode="drop"),
             "conv": st["conv"].at[li, slots].set(
@@ -1486,7 +1624,8 @@ def _dense(normed, lp, spec: ModelSpec, n_rows=None, norm=_as_is):
 
 def _attn_scope(spec: ModelSpec) -> str:
     """The full-attention sub-block's scope in a trace."""
-    return "full_attn" if spec.window_pattern else "gated_attn"
+    return ("full_attn" if spec.window_pattern or spec.conv_pattern
+            else "gated_attn")
 
 
 def _swa_chunk_attend(q, k, v, ring_k, ring_v, index, spec: ModelSpec,
@@ -1683,6 +1822,10 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
                 kind, by_rows(norm, normed), lp, st, index, spec, lens,
                 slots, fresh)
             return out, kp, vp, st, None
+        if kind == "conv":
+            out, st = _conv_prompt(by_rows(norm, normed), lp, st, index,
+                                   lens, slots, fresh)
+            return out, kp, vp, st, None
         if kind in ("mla", "dsa") and spec.is_dsa:
             with jax.named_scope("mla_attn"), jax.named_scope("dsa_prompt"):
                 out, kp, vp, st = _dsa_prompt(
@@ -1788,6 +1931,9 @@ def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
             with jax.named_scope(scope):
                 out, st = step_fn(normed, lp, st, index, spec, active,
                                   use_pallas)
+            return out, kp, vp, st, None
+        if kind == "conv":
+            out, st = _conv_mixer_step(normed, lp, st, index, active)
             return out, kp, vp, st, None
         if kind in ("mla", "dsa") and spec.is_dsa:
             with jax.named_scope("mla_attn"):

@@ -182,6 +182,26 @@ class ModelSpec:
     # the residual stream is float32 (the published ``fp32_skip_add``);
     # every matrix product still takes the weights' type
     fp32_residual: bool = False
+    # ---- gated short convolution (LFM2; models/hybrid.py ``conv``): one
+    # letter a layer over the WHOLE stack, ``C`` a layer whose mixer is
+    # ``[B | C | X] = u W_in``, a depth-wise causal convolution of
+    # ``conv_L_cache`` taps over ``B * X`` (no activation), ``(C * c)
+    # W_out``, and which keeps the last ``conv_L_cache - 1`` rows of ``B *
+    # X`` a decode slot and nothing else; ``A`` a full-attention layer
+    # over the paged pool.  The leading ``first_k_dense`` layers' feed-
+    # forward is dense (the published ``num_dense_layers``)
+    conv_pattern: str = ""
+    conv_L_cache: int = 0
+    conv_bias: bool = False
+    # what the sigmoid router's chosen scores' sum is kept from zero by
+    # (ops/moe.py; LFM2 publishes 1e-6)
+    router_norm_eps: float = 1e-20
+    # KV heads a row of the paged pool holds side by side (1 | 2).  Set
+    # per ENGINE like ``quant_kernel`` (``pack_kv_heads``): a head of 64
+    # is half a lane tile, which Mosaic's page DMA refuses and XLA's
+    # tiled HBM layout would pad to 128, so two heads share a 128-lane
+    # row, ``[layers, KV / 2, pages, page, 128]`` (ops/head_pack.py)
+    kv_head_pack: int = 1
 
     def __post_init__(self):
         if self.n_shared_experts and not self.shared_expert_intermediate_size:
@@ -233,15 +253,33 @@ class ModelSpec:
 
     @property
     def cache_heads(self) -> int:
-        return 1 if self.is_mla else self.num_kv_heads
+        """Rows a token holds in a layer of a pool: its KV heads, by
+        ``kv_head_pack`` to a row."""
+        return 1 if self.is_mla else self.num_kv_heads // self.kv_head_pack
 
     @property
     def cache_head_dim(self) -> int:
         """Lanes of a cached row.  The latent row is padded to whole
         128-lane tiles (320 -> 384): XLA's tiled HBM layout and the
         kernel's page DMA hold such a row either way, so the pool and
-        ``kv_page_bytes`` count the padded row."""
-        return -(-self.latent_dim // 128) * 128 if self.is_mla else self.head_dim
+        ``kv_page_bytes`` count the padded row.  Packed heads fill
+        theirs: no padding lanes."""
+        if self.is_mla:
+            return -(-self.latent_dim // 128) * 128
+        return self.head_dim * self.kv_head_pack
+
+    @property
+    def kv_heads_pair(self) -> bool:
+        """K and V heads of 64 in even number: two fit a 128-lane row."""
+        return (not self.is_mla and self.head_dim == 64
+                and self.num_kv_heads % 2 == 0
+                # a ring and an open window are laid out by KV head
+                and not self.swa_layers and not self.eva_layers)
+
+    def pack_kv_heads(self) -> "ModelSpec":
+        """This spec with its pool's rows holding two heads each, where
+        they pair (itself where they do not)."""
+        return replace(self, kv_head_pack=2) if self.kv_heads_pair else self
 
     @property
     def mla_softmax_scale(self) -> float:
@@ -260,7 +298,7 @@ class ModelSpec:
         if self.yarn_factor <= 0:
             return {"rope_theta": num(self.rope_theta),
                     "rope_type": "default"} if self._spelling in (
-                        _WINDOW, _INDEXER) else {}
+                        _WINDOW, _INDEXER, _CONV) else {}
         return {
             "beta_fast": num(self.yarn_beta_fast),
             "beta_slow": num(self.yarn_beta_slow),
@@ -279,6 +317,8 @@ class ModelSpec:
         """How THIS spec states its stack: the one place that looks."""
         if self.layer_pattern:
             return _LETTERS
+        if self.conv_pattern:
+            return _CONV
         if self.eva_window:
             return _EVA
         if self.window_pattern:
@@ -295,7 +335,7 @@ class ModelSpec:
     def stack(self) -> tuple:
         """The layers of this spec, one entry each (``num_layers`` of
         them, ``first_layer`` applied): the layer's residual sub-blocks'
-        kinds in order, of the nine the stack walker knows
+        kinds in order, of the ten the stack walker knows
         (models/hybrid.py).  Everything below derives from it alone."""
         first = self.first_layer
         mine = self._spelling.parse(self)[first:first + self.num_layers]
@@ -389,6 +429,18 @@ class ModelSpec:
         return self._layers_of("gdn", "mamba")
 
     @property
+    def conv_layers(self) -> int:
+        """Gated short-convolution layers: each keeps a convolution
+        tail a decode slot, ``[conv_L_cache - 1, hidden]``, and no tile."""
+        return self._layers_of("conv")
+
+    @property
+    def recurrent_layers(self) -> int:
+        """Layers that carry a row of state a slot from token to token:
+        a tile and a tail, or a tail alone."""
+        return self.linear_layers + self.conv_layers
+
+    @property
     def swa_layers(self) -> int:
         """Window layers whose K/V is the slot's ring, not pages."""
         return self._layers_of("swa")
@@ -411,7 +463,7 @@ class ModelSpec:
         """Layers whose cache is a row a decode SLOT beside the paged
         pool (recurrent state, ring or open window): what pages alone
         cannot move, share or roll back."""
-        return self.linear_layers + self.swa_layers + self.eva_layers
+        return self.recurrent_layers + self.swa_layers + self.eva_layers
 
     @property
     def moe_layers(self) -> int:
@@ -420,8 +472,11 @@ class ModelSpec:
 
     @property
     def recurrent_kind(self) -> str:
-        """``gdn`` | ``mamba`` | "" : the one kind of recurrent layer."""
-        kinds = {k for layer in self.stack for k in layer} & {"gdn", "mamba"}
+        """``gdn`` | ``mamba`` | ``conv`` | "" : the one kind of layer
+        that carries a state from token to token (``conv``: a
+        convolution tail alone)."""
+        kinds = {k for layer in self.stack for k in layer} & {
+            "gdn", "mamba", "conv"}
         assert len(kinds) <= 1, "one kind of recurrent state a spec"
         return next(iter(kinds), "")
 
@@ -524,8 +579,11 @@ class ModelSpec:
         # EVA attention: q, k, v, o and a head's two learned vectors
         # (the chunk softmax's query and the pooled key's shift)
         eva = 2 * D * self.q_dim + 2 * D * self.kv_dim + 2 * H * hd
+        # the gated short convolution: [B | C | X], the taps, the output
+        conv = (3 * D * D + D * self.conv_L_cache
+                + (D if self.conv_bias else 0) + D * D)
         return {"attn": gqa, "swa": gqa, "mla": mla, "dsa": mla + indexer,
-                "eva": eva, "gdn": gdn, "mamba": mamba,
+                "eva": eva, "gdn": gdn, "mamba": mamba, "conv": conv,
                 "mlp": 3 * D * self.intermediate_size, "moe": moe}
 
     @property
@@ -567,8 +625,21 @@ class ModelSpec:
     # (what perfbench/serve.py holds the program to)
     @property
     def layer_types(self) -> list:
+        if self._spelling is _CONV:
+            return ["conv" if layer[0] == "conv" else "full_attention"
+                    for layer in self.stack]
         return ["sliding_attention" if w else "full_attention"
                 for w in self.layer_windows]
+
+    # the sigmoid router as LFM2's config.json spells it: a selection
+    # bias, the chosen scores renormalised (ops/moe.py does both)
+    @property
+    def use_expert_bias(self) -> bool:
+        return self.router_scoring == "sigmoid"
+
+    @property
+    def norm_topk_prob(self) -> bool:
+        return self.is_moe
 
     @property
     def sliding_windows(self) -> list:
@@ -691,6 +762,12 @@ _LETTERS = _Spelling(_parse_letters, False,
                      {"mamba": "mamba", "attn": "attn", "moe": "moe"})
 _WINDOW = _Spelling(_parse_window, True, {"swa": "window", "attn": "global"})
 _INDEXER = _Spelling(_parse_indexer, True, {"dsa": "pick", "mla": "reuse"})
+# gated short convolutions beside full attention, by letter
+_CONV = _Spelling(
+    lambda spec: tuple(("conv" if c == "C" else "attn",
+                        _feed_forward(spec, i))
+                       for i, c in enumerate(spec.conv_pattern)),
+    True, {"conv": "conv", "attn": "attn"})
 # latent attention without an indexer: every layer alike
 _LATENT = _Spelling(lambda spec: (("mla", "moe"),) * _depth(spec), False,
                     {"mla": "layer"})
@@ -1467,6 +1544,79 @@ TINY_EVA = _register(
         eva_chunk=4,
         num_pred_heads=8,
         fp32_residual=True,
+    )
+)
+
+# LFM2-24B-A2B (LiquidAI, model_type lfm2_moe) at the published sizes: 40
+# layers, 30 gated short convolutions of 3 taps and 10 GQA attention
+# layers (32 heads on 8 KV heads of 64, per-head norms on q and k before
+# the rotation), two leading dense layers, then 64 sigmoid-routed experts
+# top 4 of width 1,536 with a selection bias and no shared expert.
+# Assumed (the row's config has no key for them; the cut's configuration
+# file says from what): tied embeddings, the stop ids
+_LFM2_LAYERS = "CCA" + "CCCA" * 9 + "C"
+LFM2_24B_A2B = _register(
+    ModelSpec(
+        name="LiquidAI/LFM2-24B-A2B",
+        vocab_size=65536,
+        hidden_size=2048,
+        num_layers=40,
+        num_heads=32,
+        num_kv_heads=8,
+        head_dim=64,
+        intermediate_size=11776,
+        rope_theta=1_000_000.0,
+        rms_eps=1e-5,
+        qkv_bias=False,
+        tie_embeddings=True,
+        eos_token_id=7,
+        bos_token_id=1,
+        max_position_embeddings=128000,
+        num_experts=64,
+        experts_per_token=4,
+        moe_intermediate_size=1536,
+        router_width=64,
+        router_scoring="sigmoid",
+        routed_scaling_factor=1.0,
+        router_norm_eps=1e-6,
+        qk_norm=True,
+        first_k_dense=2,
+        conv_pattern=_LFM2_LAYERS,
+        conv_L_cache=3,
+    )
+)
+
+# every mechanism of LFM2-24B-A2B at toy widths: the first 12 published
+# layer types (4 leading layers, the first two dense, and two periods
+# ``conv conv attn conv``)
+TINY_LFM2_MOE = _register(
+    ModelSpec(
+        name="tiny-lfm2-moe",
+        vocab_size=512,
+        hidden_size=64,
+        num_layers=12,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        intermediate_size=128,
+        rope_theta=10000.0,
+        rms_eps=1e-5,
+        qkv_bias=False,
+        tie_embeddings=True,
+        eos_token_id=0,
+        bos_token_id=1,
+        max_position_embeddings=4096,
+        num_experts=8,
+        experts_per_token=2,
+        moe_intermediate_size=32,
+        router_width=8,
+        router_scoring="sigmoid",
+        routed_scaling_factor=1.0,
+        router_norm_eps=1e-6,
+        qk_norm=True,
+        first_k_dense=2,
+        conv_pattern=_LFM2_LAYERS[:12],
+        conv_L_cache=3,
     )
 )
 

@@ -450,6 +450,10 @@ class PerfRecorder:
         self._swa: Dict[str, int] = {}
         self._eva: Dict[str, int] = {}
         self._dsa: Dict[str, int] = {}
+        # what a spec of gated short convolutions keeps a slot (the
+        # engine's: layers, a slot's tail bytes, the state's GB); its
+        # steps' rows ride ``_state`` as every state's do
+        self.conv_block: Dict[str, Any] = {}
         # rows of the prompt programs read back (every family)
         self._prefill = dict.fromkeys(
             ("rows_worked", "rows_real", "rows_padding"), 0)
@@ -1180,6 +1184,12 @@ class PerfRecorder:
             out["eva"] = dict(self._eva)
         if self._dsa:
             out["dsa"] = dict(self._dsa)
+        if self.conv_block:
+            out["conv"] = {
+                **self.conv_block,
+                "tails_moved": self._state.get("rows_updated", 0),
+                "layer_steps": self._state.get("layer_steps", 0),
+            }
         return out
 
     def snapshot(self) -> Dict[str, Any]:

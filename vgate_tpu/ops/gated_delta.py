@@ -22,9 +22,10 @@ CHUNK = 64  # the published kernels' chunk length
 _HI = jax.lax.Precision.HIGHEST
 
 
-def causal_conv(x, tail, w, lens=None, bias=None):
+def causal_conv(x, tail, w, lens=None, bias=None, act=jax.nn.silu):
     """Depth-wise causal convolution (plus ``bias`` [C], where the layer
-    has one), then SiLU.
+    has one), then ``act`` in float32 (SiLU for Gated DeltaNet and
+    Mamba-2; None for LFM2's gated short convolution, which has none).
 
     x: [B, S, C] pre-convolution rows; tail: [B, K-1, C] the K-1 rows
     before them (zeros at a sequence's start); w: [C, K], ``w[:, K-1]``
@@ -55,7 +56,9 @@ def causal_conv(x, tail, w, lens=None, bias=None):
             "bjt,btc->bjc", pick.astype(cat.dtype), cat,
             preferred_element_type=jnp.float32,
         ).astype(cat.dtype)
-    return jax.nn.silu(y).astype(x.dtype), new_tail
+    if act is not None:
+        y = act(y)
+    return y.astype(x.dtype), new_tail
 
 
 def gates(a, b, a_log, dt_bias):

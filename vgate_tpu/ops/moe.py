@@ -218,8 +218,13 @@ def _expert_block(x, lp, spec: ModelSpec, act, row_mask=None,
             _, gate_idx = jax.lax.top_k(
                 scores + lp["router_bias"].astype(jnp.float32), K)
             gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
+            # the sum kept from zero by the spec's own epsilon: 1e-20
+            # (DeepSeek-V3's form) but for LFM2's published 1e-6, which
+            # moves a weight by 5e-7 of itself at a sum near 2: under
+            # every tolerance here, and stated as data all the same
             gate_vals = gate_vals / (
-                jnp.sum(gate_vals, axis=-1, keepdims=True) + 1e-20)
+                jnp.sum(gate_vals, axis=-1, keepdims=True)
+                + spec.router_norm_eps)
         else:
             probs = jax.nn.softmax(logits, axis=-1)
             gate_vals, gate_idx = jax.lax.top_k(probs, K)  # [T, K]

@@ -68,6 +68,7 @@ from vgate_tpu.models.decoder import (
     decode_attention_impl,
     decode_kv_write,
     multitok_attention_impl,
+    packed_group,
     prefill_attention_impl,
 )
 from vgate_tpu.models.hybrid import eva_window_pages
@@ -366,14 +367,23 @@ def replay_into(
     return "replayed"
 
 
-def refuse_unbuildable_kernels(spec: ModelSpec, kv_quant: bool) -> None:
+def _plain_mesh(mesh) -> bool:
+    return all(int(mesh.shape.get(a, 1)) == 1
+               for a in ("tp", "pp", "sp", "ep"))
+
+
+def refuse_unbuildable_kernels(spec: ModelSpec, kv_quant: bool,
+                               plain_mesh: bool = True) -> None:
     """Engine-construction gate for the paged kernels Mosaic refuses on
     the v5e toolchain (jax 0.9.0 / libtpu 0.0.34; tests/test_tpu_aot.py
     holds each case as a strict xfail, so the day one compiles the suite
     says so).  Raised at boot with the compiler's own message: left to
     the first request, the failure would surface inside a supervised
     restart loop.  ``tpu.use_pallas: false`` serves either combination
-    through the jnp twins."""
+    through the jnp twins.  A head of 64 is NOT refused where KV heads
+    pair on a ``plain_mesh``: the pool then holds two heads a 128-lane
+    row (ops/head_pack.py; ``ModelSpec.pack_kv_heads``, which the
+    engine has applied by then)."""
     if kv_quant:
         raise ValueError(
             "kv_cache.dtype=int8 cannot run the Pallas paged-attention "
@@ -382,14 +392,20 @@ def refuse_unbuildable_kernels(spec: ModelSpec, kv_quant: bool) -> None:
             "aligned to tiling (8), but is 1'.  Use kv_cache.dtype=bf16, "
             "or tpu.use_pallas=false (jnp twins)."
         )
-    # what a page's row holds: head_dim, or the latent row's lanes
+    # what a page's row holds: head_dim (two heads of 64 where they
+    # pair), or the latent row's lanes
+    if plain_mesh:
+        spec = spec.pack_kv_heads()
     if spec.cache_head_dim % 128:
         raise ValueError(
             f"{spec.name} (head_dim {spec.cache_head_dim}) cannot run the "
             "Pallas paged-attention kernels on this TPU toolchain — "
             "Mosaic refuses the page DMA: 'Slice shape along dimension 4 "
             f"must be aligned to tiling (128), but is {spec.head_dim}'.  "
-            "Set tpu.use_pallas=false (jnp twins)."
+            "Heads of 64 are served two to a 128-lane row where the KV "
+            f"heads pair (an even number of them: {spec.num_kv_heads} "
+            "here) on an unpartitioned mesh.  Set tpu.use_pallas=false "
+            "(jnp twins)."
         )
 
 
@@ -425,7 +441,8 @@ def refuse_unsupported_recurrent(spec: ModelSpec, config: VGTConfig,
     state that belongs to it is a WRONG cache.  Each is refused here by
     name, at boot, not at the first request that would need it.  (Prefix
     matching is not refused but turned off: it is on by default.)"""
-    found = _pages_only_feature(config, mesh) if spec.linear_layers else None
+    found = (_pages_only_feature(config, mesh)
+             if spec.recurrent_layers else None)
     if not found:
         return
     why = {
@@ -440,10 +457,13 @@ def refuse_unsupported_recurrent(spec: ModelSpec, config: VGTConfig,
         "quant": "the grouped expert product and the recurrent layers "
                  "take plain weights",
     }[found[1]]
+    has = ("gated short-convolution layers (a convolution tail a slot "
+           "beside the pages)" if spec.conv_layers
+           else "recurrent (linear-attention or state-space) layers")
     raise ValueError(
-        f"{spec.name} has recurrent (linear-attention or state-space) "
-        f"layers, which cannot run with {found[0]}: {why}.  Preemption by "
-        "recompute and journal replay rebuild the state and are supported."
+        f"{spec.name} has {has}, which cannot run with {found[0]}: {why}.  "
+        "Preemption by recompute and journal replay rebuild the state and "
+        "are supported."
     )
 
 
@@ -628,6 +648,13 @@ class EngineCore:
         self.dtype = _DTYPES[self.config.model.dtype]
         self.mesh = build_mesh(tpu_cfg, devices)
         self.spec.check_expert_share()
+        # K and V heads of 64: two a 128-lane row of the pool, whichever
+        # attention reads it (ops/head_pack.py).  A partitioned mesh
+        # splits the pool by KV head and int8 pages scale by head: both
+        # keep a head a row
+        if (_plain_mesh(self.mesh)
+                and self.config.kv_cache.dtype != "int8"):
+            self.spec = self.spec.pack_kv_heads()
         refuse_unsupported_recurrent(self.spec, self.config, self.mesh)
         refuse_unsupported_latent(self.spec, self.config, self.mesh)
         refuse_unsupported_rings(self.spec, self.config, self.mesh)
@@ -638,7 +665,8 @@ class EngineCore:
         self.use_pallas = bool(tpu_cfg.use_pallas and platform == "tpu")
         if self.use_pallas:
             refuse_unbuildable_kernels(
-                self.spec, self.config.kv_cache.dtype == "int8"
+                self.spec, self.config.kv_cache.dtype == "int8",
+                _plain_mesh(self.mesh),
             )
         elif tpu_cfg.use_pallas:
             # the gate stays (Tier-1 builds engines on CPU with the
@@ -991,6 +1019,15 @@ class EngineCore:
             ),
         )
         self.perf.request_totals = self.flight.phase_totals
+        if self.spec.conv_layers:
+            self.perf.conv_block = {
+                "layers": self.spec.conv_layers,
+                "taps": self.spec.conv_L_cache,
+                "tail_bytes_per_slot": self._state_slot_bytes,
+                "state_gb": round(
+                    self._state_slot_bytes * tpu_cfg.max_batch_slots / 1e9,
+                    6),
+            }
         # see the long rationale further down where the readback paths
         # use it; constructed here so the swap manager can share it
         self._readback_lock = named_lock("EngineCore._readback_lock")
@@ -3072,7 +3109,7 @@ class EngineCore:
                 "suffix_cow" if unaligned else "suffix",
                 lambda: multitok_attention_impl(
                     self.use_pallas, mesh, rows=bucket, unaligned=unaligned,
-                    latent=self.spec.is_mla,
+                    latent=self.spec.is_mla, group=packed_group(self.spec),
                 ),
             )
         else:
@@ -3542,7 +3579,7 @@ class EngineCore:
                     self.perf.note_moe(
                         np.asarray(moe_dev), rows=len(seqs),
                         moe_layers=self.spec.moe_layers,
-                        linear_layers=self.spec.linear_layers,
+                        linear_layers=self.spec.recurrent_layers,
                     )
                 if self.spec.is_mla:
                     self.perf.note_mla_decode(
@@ -3835,7 +3872,8 @@ class EngineCore:
         with self._launch(
             "spec_verify", spec_key, active,
             ("spec_verify", lambda: multitok_attention_impl(
-                self.use_pallas, self._mt_mesh, rows=S_round
+                self.use_pallas, self._mt_mesh, rows=S_round,
+                group=packed_group(self.spec),
             )),
             lambda: {
                 "program": "spec_verify", "steps": 1,
@@ -4318,6 +4356,13 @@ class EngineCore:
                 "window": self.spec.sliding_window,
                 "dtype": f"{name} K and V",
             }
+        if self.spec.conv_layers:
+            return {
+                "kind": "conv",
+                "conv_layers": self.spec.conv_layers,
+                "rows_per_slot": self.spec.conv_L_cache - 1,
+                "dtype": f"{name} convolution tail, no tile",
+            }
         return {
             "linear_layers": self.spec.linear_layers,
             "kind": self.spec.recurrent_kind,
@@ -4391,6 +4436,9 @@ class EngineCore:
                 "row_lanes": self.geometry.head_dim,
                 **({"latent": self.spec.latent_dim}
                    if self.spec.is_mla else {}),
+                # heads of 64 two to a row (ops/head_pack.py)
+                **({"heads_per_row": self.spec.kv_head_pack}
+                   if self.spec.kv_head_pack > 1 else {}),
                 # a spec that picks: the latent rows by pairs of tokens,
                 # what its decode kernel fetches a pick
                 **({"row_pairs": True} if by_pairs(self.k_pages) else {}),

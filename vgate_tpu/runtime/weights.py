@@ -468,6 +468,78 @@ def _window_params_from_getter(
     return params
 
 
+def _conv_params_from_getter(
+    spec: ModelSpec, getter: TensorGetter, dtype
+) -> Params:
+    """``lfm2_moe`` names (ASSUMED from ``transformers``' LFM2 family:
+    ``operator_norm`` / ``ffn_norm``, ``conv.in_proj`` / ``conv.conv`` /
+    ``conv.out_proj``, ``self_attn.{q,k,v}_proj`` / ``out_proj`` with
+    ``q_layernorm`` / ``k_layernorm``, ``feed_forward.w1`` / ``w3`` /
+    ``w2`` dense and ``feed_forward.experts.N.*`` beside
+    ``feed_forward.gate`` and ``feed_forward.expert_bias``,
+    ``model.embedding_norm``; no checkpoint was read) -> the pytree of
+    models/hybrid.py for a ``conv_pattern`` spec: ``layers = {"lead": (a
+    tree a leading layer, ...), "conv" | "attn": [P, n, ...]}``.  The
+    depth-wise taps come as torch's ``conv1d`` holds them, ``[C, 1, K]``,
+    squeezed.  A chip's share: the experts ``first_expert ..`` of the
+    router's width."""
+    E, first = spec.num_experts, spec.first_expert
+    get = lambda i, name: np.asarray(getter(f"model.layers.{i}.{name}"))
+    lin = lambda i, name: {"w": get(i, f"{name}.weight").T}
+    np_dtype = np.dtype(dtype)
+    cast = lambda x: np.asarray(x).astype(np_dtype)
+    ff = {"gate": "w1", "up": "w3", "down": "w2"}
+
+    def layer(i):
+        out = {"input_norm": get(i, "operator_norm.weight"),
+               "post_norm": get(i, "ffn_norm.weight")}
+        if spec.stack[i][0] == "conv":
+            out["in_proj"] = lin(i, "conv.in_proj")
+            out["out_proj"] = lin(i, "conv.out_proj")
+            taps = get(i, "conv.conv.weight")
+            out["conv"] = taps.reshape(taps.shape[0], taps.shape[-1])
+            if spec.conv_bias:
+                out["conv_bias"] = get(i, "conv.conv.bias")
+        else:
+            for n in "qkv":
+                out[n] = lin(i, f"self_attn.{n}_proj")
+            out["o"] = lin(i, "self_attn.out_proj")
+            out["q_norm"] = get(i, "self_attn.q_layernorm.weight")
+            out["k_norm"] = get(i, "self_attn.k_layernorm.weight")
+        if spec.stack[i][1] == "mlp":
+            for n, theirs in ff.items():
+                out[n] = lin(i, f"feed_forward.{theirs}")
+            return jax.tree.map(cast, out)
+        out["router"] = lin(i, "feed_forward.gate")["w"]
+        for n, theirs in ff.items():
+            out[n] = {"w": np.stack([
+                lin(i, f"feed_forward.experts.{first + e}.{theirs}")["w"]
+                for e in range(E)])}
+        out = jax.tree.map(cast, out)
+        out["router_bias"] = np.asarray(
+            get(i, "feed_forward.expert_bias"), np.float32)
+        return out
+
+    lead, P = spec.lead_layers, spec.num_periods
+    layers: Dict[str, Any] = {
+        "lead": tuple(layer(i) for i in range(lead))}
+    for group in ("conv", "attn"):
+        trees = [layer(i) for i in range(lead, spec.num_layers)
+                 if spec.stack[i][0] == group]
+        if trees:
+            layers[group] = jax.tree.map(
+                lambda *xs: np.stack(xs).reshape(
+                    (P, len(xs) // P) + xs[0].shape), *trees)
+    params: Params = {
+        "embed": cast(getter("model.embed_tokens.weight")),
+        "layers": layers,
+        "final_norm": cast(getter("model.embedding_norm.weight")),
+    }
+    if not spec.tie_embeddings:
+        params["lm_head"] = cast(np.asarray(getter("lm_head.weight")).T)
+    return params
+
+
 def params_from_getter(
     spec: ModelSpec, getter: TensorGetter, dtype=jnp.bfloat16
 ) -> Params:
@@ -485,6 +557,8 @@ def params_from_getter(
         return _mla_params_from_getter(spec, getter, dtype)
     if spec.window_pattern:
         return _window_params_from_getter(spec, getter, dtype)
+    if spec.conv_pattern:
+        return _conv_params_from_getter(spec, getter, dtype)
     if spec.layer_pattern:
         return _pattern_params_from_getter(spec, getter, dtype)
     if spec.is_hybrid:
