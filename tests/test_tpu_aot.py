@@ -648,6 +648,9 @@ def test_window_stack_decode_chunk_and_prompt_kernel_compile_on_v5e(v5e):
     assert "swa_decode_attention_pallas" in text
     assert "paged_decode_attention_pallas" in text
     assert "moe_grouped_matmul_pallas" in text
+    # the window layers' matrices are read where they stand (seven
+    # asynchronous copies of 25-75 MB a step before PR 48)
+    _assert_no_copy_of(text, (1, 3, 2048, 6144), (1, 3, 6144, 2048))
     S, H, KV, hd = 8192, 64, 8, 128
     band = jax.jit(lambda q, k, v, lens: swa_prefill_attention_pallas(
         q, k, v, lens, 128)).lower(
@@ -683,6 +686,21 @@ def _assert_no_buffer(text, rows, width, dtypes=("f32", "bf16", "s32")):
     for dtype in dtypes:
         shape = f"{dtype}[{rows},{width}]"
         found = [line for line in text.splitlines() if shape in line]
+        assert not found, found[0][:300]
+
+
+def _assert_no_copy_of(text, *shapes, dtype="bf16"):
+    """No operation of the compiled program MAKES an array of one of
+    ``shapes``: a period's (or a unit's repeats') matrices sliced out of
+    the stacked parameters into a buffer of their own (a fusion of a
+    dynamic slice, a ``copy-done``), which the products would then read
+    in place of the parameter (PR 48).  A parameter of that shape, a
+    tuple's element and a bitcast move nothing."""
+    views = (" parameter(", " get-tuple-element(", " bitcast(")
+    for shape in shapes:
+        made = f" = {dtype}[{','.join(map(str, shape))}]"
+        found = [line for line in text.splitlines()
+                 if made in line and not any(v in line for v in views)]
         assert not found, found[0][:300]
 
 
@@ -996,6 +1014,7 @@ def test_long_prompt_programs_loop_over_their_row_blocks_on_v5e(
         assert _counted_loops(text, scope), scope
     temp = compiled.memory_analysis().temp_size_in_bytes
     parent = PARENT_TEMP_BYTES[cell]
+    print(cell, "prompt program temporaries", temp)
     assert temp <= parent + (320 << 20), (
         f"{cell}: {temp} temporary bytes against the parent's {parent}")
     assert held + temp < 15.9e9
@@ -1264,6 +1283,54 @@ def test_lfm2_decode_chunk_compiles_on_v5e(lfm2_cut):
     text = compiled.as_text()
     assert "paged_decode_attention_pallas" in text
     assert "moe_grouped_matmul_pallas" in text
+    # no copy of a period's mixers' matrices (the walker's scans carry
+    # indices): before PR 48 these four a period, nine periods a step,
+    # were 2.28 ms of the cell's 23.6 ms step
+    _assert_no_copy_of(text, (3, 2048, 6144), (2, 1, 2048, 6144),
+                       (3, 2048, 2048), (2, 1, 2048, 2048))
+
+
+def test_qwen3_next_decode_chunk_reads_its_matrices_in_place_on_v5e(v5e):
+    """The Qwen3-Next cut as the cell serves it (8 layers = two periods
+    of three Gated DeltaNet layers and one attention layer, 128 experts
+    held, vocabulary 37,984, 256 slots of 2,048 tokens): the decode
+    chunk holds no copy of a period's mixers' matrices (ten of them,
+    ~ 450 MB a step, before PR 48), and its temporaries are the
+    activations' (234.9 MB when this was written; the parent's 505.7)."""
+    from vgate_tpu.models.hybrid import make_state
+    from vgate_tpu.runtime.step_programs import _decode_chunk
+
+    A = _abstract(v5e)
+    spec, params = _cut_and_shapes(
+        A, "Qwen/Qwen3-Next-80B-A3B-Instruct", dict(
+            name="qwen3-next-cut", num_layers=8, num_experts=128,
+            vocab_size=37984, first_expert=0, eos_token_id=37983,
+            bos_token_id=37982, extra_stop_ids=()))
+    B, ctx = 256, 2048
+    state = jax.tree.map(
+        lambda x: A(x.shape, x.dtype),
+        jax.eval_shape(lambda: make_state(spec, B, jnp.bfloat16)))
+    pool = A((spec.attn_layers, spec.num_kv_heads, 16385, PAGE,
+              spec.head_dim), jnp.bfloat16)
+    compiled = _decode_chunk.lower(
+        params, spec, A((B,), jnp.int32), A((B,), jnp.int32), pool, pool,
+        A((B, ctx // PAGE), jnp.int32), A((B,), jnp.bool_),
+        A((B,), jnp.float32), A((B,), jnp.float32), A((B,), jnp.int32),
+        A((2,), jnp.uint32), A((), jnp.uint32),
+        num_steps=8, use_pallas=True, max_position=ctx - 1,
+        seeds=A((B,), jnp.int32), steps=A((B,), jnp.int32),
+        all_greedy=True, guard=True, state=state,
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * _nbytes(pool) + _nbytes(state), (
+        "the pool or the state is copied")
+    print("qwen3-next decode chunk temporaries", mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes < 300e6
+    text = compiled.as_text()
+    assert "gated_delta_step_pallas" in text
+    assert "moe_grouped_matmul_pallas" in text
+    _assert_no_copy_of(text, (3, 1, 2048, 12288), (3, 1, 4096, 2048),
+                       (3, 1, 2048, 512), (3, 1, 512, 2048))
 
 
 @pytest.mark.parametrize("B, bucket", [(8, 128), (1, 2048), (8, 2048)],

@@ -1408,15 +1408,22 @@ def _dsa_step(normed, lp, spec: ModelSpec, positions, kp, vp, st, index,
 
 class _LayerTensors(Mapping):
     """ONE layer's tensors out of its group's stacked ``[P, n, ...]``
-    ones, each sliced where it is read.  A matrix that a loop over
-    blocks of rows (``_by_row_blocks``) reads is an operand of that
-    loop, and XLA holds an operand as a copy (it lifts the slice out of
-    the loop's body however late it is taken).  A scan's own slices are
-    taken at the top of its body, every matrix of the layer at once
-    (0.3 GB of a K-EXAONE window layer, live through all its loops);
-    sliced here, one stands while its loop runs: the 8,192-row program's
-    temporaries 1.53 -> 1.42 GB, the GLM cut's 16,384-row one's 2.90 ->
-    2.62 (compiled for the v5e, PR 42)."""
+    ones, each sliced by ``(period, layer)`` where it is read: what the
+    stack walker hands every sub-block of every pass.  A matrix sliced
+    inside the product that reads it is read where it stands (the
+    product's fusion takes the whole stacked parameter and the two
+    indices).  A scan that carried the weights as its ``xs`` sliced
+    them at the top of its body, every matrix of the layer at once, and
+    a slice that feeds an inner loop, or that several layers slice
+    again, is a buffer: a copy of the period's matrices every step
+    (2.28 ms of the LFM2 cut's 23.6 ms decode step, 450 MB a step of
+    the qwen3-next cut's, whose chunk's temporaries fell 505.7 -> 234.9
+    MB; compiled for the v5e, PR 48).  Only a matrix that a loop over
+    blocks of rows (``_by_row_blocks``) reads is still copied, one at a
+    time: it is an operand of that loop, and XLA holds an operand as a
+    copy however late the slice is taken (the K-EXAONE cut's 8,192-row
+    program's temporaries 1.53 -> 1.42 GB, the GLM cut's 16,384-row
+    one's 2.90 -> 2.62, PR 42)."""
 
     def __init__(self, tree, index):
         self._tree, self._index = tree, index
@@ -1458,27 +1465,30 @@ def _segments(blocks):
 
 
 def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
-                 block_fn, slice_late: bool = False):
+                 block_fn):
     """THE stack walker: the leading layers once (a ``window_pattern``
     spec's ``layers["lead"]``, each its own tensors), then a scan over
     periods, each the spec's sub-blocks in order.  ``block_fn(kind,
-    normed, lp, kp, vp, st, index, stack)`` -> ``(out, kp, vp, st, stats
-    | None)`` computes one sub-block on the normed rows: ``index`` is
+    rows, lp, kp, vp, st, index, stack, norm)`` -> ``(out, kp, vp, st,
+    stats | None)`` computes one sub-block on the stream's rows as they
+    stand, which it norms itself (``norm(rows)``: at once, or a block
+    at a time in a pass that loops over blocks of rows): ``index`` is
     the layer's index among the layers of its kind over the whole stack,
     leading ones first (the pools' layer for ``attn``, the state's for a
     recurrent kind, the rings' for ``swa``); for ``moe`` it indexes
     ``stack``, the expert matrices of the layer's group ``[layers, E, .,
-    .]`` (a leading layer's own, ``[1, E, ., .]``), which stay outside
-    the scanned slices; ``lp`` is the layer's tensors without them:
-    the scans' own slices or, with ``slice_late`` (a pass that loops
-    over blocks of rows), ``_LayerTensors``, and ``block_fn`` is then
-    handed the rows as they are and their ``norm`` to apply.
+    .]`` (a leading layer's own, ``[1, E, ., .]``); ``lp`` is the
+    layer's tensors without them: ``_LayerTensors`` over the group's
+    stacked ``[P, n, ...]`` ones.  The scans carry INDICES alone (the
+    period, a unit's repeat): no weight is an operand of a scan, so none
+    is copied out of the parameters, and a product reads its matrix
+    where it stands.
     Returns (x, k_pages, v_pages, state, stats [4])."""
     layers = dict(params["layers"])
     lead = layers.pop("lead", ())
     if "full" in layers:  # one attention layer a period: [P, ...]
         layers["full"] = jax.tree.map(lambda a: a[:, None], layers["full"])
-    # (a stack without expert layers: its dense matrices ride the slices)
+    # (a stack without expert layers: its dense matrices are ``light``)
     names = spec.expert_stacks if spec.moe_layers else ()
     light = {g: {k: v for k, v in d.items() if k not in names}
              for g, d in layers.items()}
@@ -1496,12 +1506,8 @@ def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
         if spec.fp32_residual:  # the products take the weights' type
             normed = lambda h: rms_norm(
                 h, lp[norm], spec.rms_eps, uo).astype(lp[norm].dtype)
-        if slice_late:  # the norm too: in each loop over blocks of rows
-            out, kp, vp, st, stats = block_fn(
-                kind, h, lp, kp, vp, st, index, stack, norm=normed)
-        else:
-            out, kp, vp, st, stats = block_fn(
-                kind, normed(h), lp, kp, vp, st, index, stack)
+        out, kp, vp, st, stats = block_fn(
+            kind, h, lp, kp, vp, st, index, stack, normed)
         return (h + out.astype(h.dtype), kp, vp, st), stats
 
     # the leading layers, unrolled: layer i's mixer is the i-th of its
@@ -1521,60 +1527,51 @@ def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
                 lead_stats.append(s)
     base.pop("moe", None)  # a group's expert stacks start at its own 0
 
-    def period(carry, xs):
-        per, p = xs
+    def period(carry, p):
         all_stats = []
         for unit, repeats in _segments(spec.period_blocks):
-            first = {}  # group -> the unit's first layer of it
-            for b in unit:
-                first.setdefault(b[1], b[3])
+            # group -> its layers that one repeat of the unit passes
             width = {g: len({b[3] for b in unit if b[1] == g})
-                     for g in first}
+                     for g in {b[1] for b in unit}}
 
-            def unit_fn(c, xs_, unit=unit, first=first, width=width):
-                lps, j = xs_
+            def unit_fn(c, j, unit=unit, width=width):
                 stats = []
                 for b in unit:
-                    g, local = b[1], b[3] - first[b[1]]
-                    if slice_late:
-                        lp = _LayerTensors(
-                            light[g], (p, first[g] + j * width[g] + local))
-                    else:
-                        lp = jax.tree.map(lambda a: a[local], lps[g])
-                    index = p * count[g] + first[g] + j * width[g] + local
+                    g = b[1]
+                    layer = b[3] + j * width[g]
+                    index = p * count[g] + layer
                     if base.get(b[0]):  # behind the leading layers' own
                         index = base[b[0]] + index
-                    c, s = run(c, b, lp, index, stacks[g])
+                    c, s = run(c, b, _LayerTensors(light[g], (p, layer)),
+                               index, stacks[g])
                     if s is not None:
                         stats.append(s)
                 return c, (jnp.stack(stats) if stats
                            else jnp.zeros((0, len(STAT_NAMES)), jnp.int32))
 
-            # the unit's repeats as an inner scan: one body traced,
-            # lowered and compiled for all of them
-            lps = {} if slice_late else {g: jax.tree.map(
-                lambda a: a[first[g]:first[g] + repeats * width[g]].reshape(
-                    (repeats, width[g]) + a.shape[1:]), per[g])
-                for g in first}
             js = jnp.arange(repeats, dtype=jnp.int32)
             if repeats > 2:
-                carry, stats = jax.lax.scan(unit_fn, carry, (lps, js))
-                stats = stats.reshape(-1, len(STAT_NAMES))
-            else:  # once, or twice: a scan's slices of the weights are
-                # copies, which two bodies cost less than
+                # the unit's repeats as an inner scan: one body traced,
+                # lowered and compiled for all of them
+                carry, stats = jax.lax.scan(unit_fn, carry, js)
+                all_stats.append(stats.reshape(-1, len(STAT_NAMES)))
+            else:  # once, or twice: unrolled.  A scan of two buys
+                # nothing (the LFM2 cut compiled for the v5e either way,
+                # PR 48: the decode chunk's temporaries 150.5 MB
+                # unrolled, 150.8 scanned; its three prompt programs
+                # compile 0.5-1.3 s slower scanned, the chunk 1 s
+                # faster); the reason is no longer the weights, which
+                # neither form copies.  The repeat stays a traced index
+                # (``js[j]``): under a constant one XLA lifts the slice
+                # out of the periods' loop, + 79 MB in the K-EXAONE
+                # cut's 8,192-row prompt program
                 for j in range(repeats):
-                    carry, stats = unit_fn(
-                        carry, (jax.tree.map(lambda a: a[j], lps), js[j]))
+                    carry, stats = unit_fn(carry, js[j])
                     all_stats.append(stats)
-                continue
-            all_stats.append(stats)
         return carry, jnp.concatenate(all_stats)
 
     (x, k_pages, v_pages, state), stats = jax.lax.scan(
-        period, carry,
-        ({} if slice_late else light,
-         jnp.arange(spec.num_periods, dtype=jnp.int32)),
-    )
+        period, carry, jnp.arange(spec.num_periods, dtype=jnp.int32))
     stats = stats.reshape(-1, len(STAT_NAMES))
     if lead_stats:
         stats = jnp.concatenate([jnp.stack(lead_stats), stats])
@@ -1806,9 +1803,11 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
             first=None if prefix_lens is None else prefix_lens // ps,
             last=(start + lens - 1) // ps)
 
-    def block_fn(kind, normed, lp, kp, vp, st, index, stack, norm=_as_is):
-        # ``norm``: the rows come as the stream holds them, and a loop
-        # over blocks of rows norms the block it reads
+    def block_fn(kind, normed, lp, kp, vp, st, index, stack, norm):
+        # the rows come as the stream holds them: a loop over blocks of
+        # rows norms the block it reads, any other pass all of them here
+        if n_rows is None:
+            normed, norm = norm(normed), _as_is
         if kind == "moe":  # gathers its rows: the normed rows stand
             out, stats = _experts(
                 by_rows(norm, normed), lp, spec, row_mask, use_pallas,
@@ -1885,8 +1884,7 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
         state = {**(state or {}), "sel": jnp.zeros(
             (B, S, T) if T > spec.index_topk else (B, 1, 1), jnp.int8)}
     x, k_pages, v_pages, state, _stats = _period_scan(
-        params, spec, x, k_pages, v_pages, state, block_fn,
-        slice_late=n_rows is not None)
+        params, spec, x, k_pages, v_pages, state, block_fn)
     return x, k_pages, v_pages, _without_selection(state)
 
 
@@ -1919,7 +1917,8 @@ def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
                          lp, normed.dtype)
         return out, k_cache, v_cache
 
-    def block_fn(kind, normed, lp, kp, vp, st, index, stack):
+    def block_fn(kind, rows, lp, kp, vp, st, index, stack, norm):
+        normed = norm(rows)
         if kind == "moe":
             out, stats = _experts(normed, lp, spec, active, use_pallas,
                                   index, stack)
