@@ -13,6 +13,7 @@ once.
 """
 
 import os
+import threading
 import time
 
 import pytest
@@ -40,6 +41,43 @@ class FakeClock:
 
     def advance(self, dt):
         self.t += dt
+
+
+class FakeDevice:
+    """The device of a test of the device clock: a launch's output is
+    its name, and the wait for it returns when the test says the launch
+    finished, and at what time on the fake clock."""
+
+    def __init__(self, clock):
+        self.clock = clock
+        self.gates = {}
+        self.poisoned = set()
+        self.waited = []
+
+    def gate(self, output):
+        return self.gates.setdefault(output, threading.Event())
+
+    def wait(self, output):
+        assert self.gate(output).wait(10), output
+        self.waited.append(output)
+        if output in self.poisoned:
+            raise RuntimeError(f"{output}: poisoned launch")
+
+    @staticmethod
+    def stamped(dev):
+        totals = dev.totals()
+        return totals["dropped"] + sum(
+            row["n"] for row in totals["programs"].values())
+
+    def finish(self, dev, output, at):
+        """``output`` is done at ``at``; returns once it is stamped."""
+        before = self.stamped(dev)
+        self.clock.t = at
+        self.gate(output).set()
+        deadline = time.monotonic() + 10
+        while self.stamped(dev) == before:
+            assert time.monotonic() < deadline, output
+            time.sleep(0.001)
 
 
 def recorder(clock=None, roofline=None, **cfg):
@@ -166,15 +204,21 @@ def test_window_gauges_match_roofline_hand_computed():
     equal roofline.py hand-computed on the pinned geometry."""
     clock = FakeClock()
     rec = recorder(clock=clock, roofline=PINNED, perf_window_s=60.0)
+    device = FakeDevice(clock)
+    rec.device = perf_mod.DeviceClock(clock=clock, wait=device.wait)
     # one decode tick: 8 fused steps over 500 resident ctx tokens,
-    # 0.040 s of device time, 8 tokens delivered
+    # 0.040 s of the DEVICE's time by its clock (the host waited 1 ms
+    # of it: hidden behind the device), 8 tokens delivered
     rec.tick_begin()
     rec.phase("dispatch", 0.002)
-    rec.phase("device", 0.040)
-    rec.note_decode(steps=8, ctx_tokens=500, device_s=0.040)
+    rec.device.post("decode", clock.t, "chunk", steps=8, rows=4)
+    device.finish(rec.device, "chunk", at=clock.t + 0.040)
+    rec.phase("device", 0.001)
+    rec.note_decode(steps=8, ctx_tokens=500, device_s=0.001)
     rec.note_tokens(8)
-    clock.advance(0.050)
+    clock.advance(0.010)
     rec.tick_end(worked=True)
+    rec.device.shutdown()
     clock.advance(1.950)  # window spans exactly 2 s since the tick began
     win = rec.window()
     assert win["ticks"] == 1
@@ -187,8 +231,9 @@ def test_window_gauges_match_roofline_hand_computed():
     assert win["hbm_roofline_pct"] == pytest.approx(
         PINNED.hbm_roofline_pct(modeled, 0.040), abs=0.01
     )
+    assert win["device_decode_s"] == pytest.approx(0.040)
     assert win["host_overhead_ratio"] == pytest.approx(
-        (0.050 - 0.042) / 0.050, abs=1e-3
+        (0.050 - 0.003) / 0.050, abs=1e-3
     )
 
 
@@ -272,7 +317,7 @@ def _fake_snapshot(tokens=100, host=0.5, wall=1.0, mfu=0.1):
             "tokens": tokens,
             "tokens_per_s": tokens / 10.0,
             "decode_steps": 50,
-            "decode_device_s": wall - host,
+            "device_decode_s": wall - host,
             "phase_seconds": dict(phases),
             "wall_s": wall,
             "host_overhead_ratio": host / wall,
@@ -1656,3 +1701,400 @@ def test_the_benchmark_reads_the_recorders_own_snapshots(
     assert read("engine.gc_share") == 0.0
     assert read("gateway.handoffs_per_readback") == pytest.approx(1.0)
     assert 0 < read("gateway.handoff_wait_p99_ms") <= 8
+
+
+# ----------------------------------------- the device clock (ISSUE 49)
+
+
+def device_clock(t=10.0, **kw):
+    clock = FakeClock(t)
+    device = FakeDevice(clock)
+    return clock, device, perf_mod.DeviceClock(
+        clock=clock, wait=device.wait, **kw)
+
+
+def test_every_counter_of_the_device_clock_is_there_at_zero_from_boot():
+    """The benchmark's ratios read a missing path as no reading, so the
+    keys exist before the first launch; and no thread before it."""
+    rec = recorder()
+    clock = rec.totals()["device_clock"]
+    assert clock == {
+        "busy_s": 0.0, "idle_s": 0.0, "decode_steps": 0,
+        "prompt_tokens": 0, "dropped": 0,
+        "programs": {
+            name: {"n": 0, "s": 0.0, "queued_s": 0.0}
+            for name in ("prefill", "suffix_prefill", "chunked_prefill",
+                         "decode", "spec_verify")
+        },
+    }
+    assert tuple(clock["programs"]) == perf_mod.DEVICE_PROGRAMS
+    assert rec.device._thread is None and rec.device.launches_since(0) == []
+
+
+@pytest.mark.parametrize("late", [0.0, 0.125], ids=["on-time", "late-stamp"])
+def test_completion_times_tile_the_devices_time_by_program(late):
+    """Launches finish in order; busy + idle seconds are the time from
+    the first call to the last stamp, to the float (the times are
+    dyadic).  A stamp that comes late moves seconds from the launch
+    behind it to the one it closes and loses none."""
+    clock, device, dev = device_clock()
+    try:
+        dev.post("prefill", 10.0, "a", prompt_tokens=100, rows=2, bucket=64)
+        # called while ``a`` is on the device: it queues behind it
+        dev.post("decode", 10.25, "b", steps=8, rows=4)
+        device.finish(dev, "a", at=11.0 + late)
+        device.finish(dev, "b", at=11.5)
+        # called half a second after the device went idle
+        dev.post("decode", 12.0, "c", steps=4, rows=3)
+        device.finish(dev, "c", at=12.25)
+        totals = dev.totals()
+    finally:
+        dev.shutdown()
+    assert totals["busy_s"] + totals["idle_s"] == 12.25 - 10.0
+    assert totals["idle_s"] == 0.5  # only before ``c``
+    programs = totals["programs"]
+    assert programs["prefill"] == {"n": 1, "s": 1.0 + late, "queued_s": 0.0}
+    assert programs["decode"] == {
+        "n": 2, "s": 0.5 - late + 0.25, "queued_s": 0.75 + late}
+    assert totals["decode_steps"] == 12 and totals["prompt_tokens"] == 100
+    assert totals["dropped"] == 0
+    assert dev.decode_s == programs["decode"]["s"]
+
+
+def test_the_clock_drops_past_its_cap_and_skips_a_wait_that_raises():
+    clock, device, dev = device_clock()
+    try:
+        for i in range(perf_mod.DEVICE_QUEUE_MAX + 3):
+            dev.post("decode", 10.0, f"chunk{i}", steps=1, rows=1)
+        assert dev.totals()["dropped"] == 3
+        assert len(dev._open) == perf_mod.DEVICE_QUEUE_MAX
+        # the first finishes; the second is a poisoned launch: counted,
+        # skipped, and its second goes to the launch behind it
+        device.poisoned.add("chunk1")
+        device.finish(dev, "chunk0", at=11.0)
+        device.finish(dev, "chunk1", at=12.0)
+        assert dev._thread.is_alive()
+        device.finish(dev, "chunk2", at=12.5)
+        totals = dev.totals()
+        assert totals["dropped"] == 4
+        assert totals["programs"]["decode"] == {
+            "n": 2, "s": 2.5, "queued_s": 1.0}
+        assert totals["busy_s"] == 2.5 and totals["idle_s"] == 0.0
+        assert totals["decode_steps"] == 2
+    finally:
+        thread = dev._thread
+        dev.shutdown()
+    # mortal: no output is held past the shutdown, and the thread ends
+    # once the wait it stood in returns, stamping nothing
+    assert not dev._open and dev._thread is None
+    device.gate("chunk3").set()
+    thread.join(5)
+    assert not thread.is_alive() and device.waited[-1] == "chunk3"
+    assert dev.totals() == totals
+    # a core started again (``warmup`` stops the core it started) posts
+    # again: a fresh thread, and the time in between was the device's idle
+    dev.post("decode", 13.0, "again", steps=1, rows=1)
+    device.finish(dev, "again", at=13.25)
+    again = dev._thread
+    assert again is not thread and again.is_alive()
+    dev.shutdown()
+    assert not again.is_alive()
+    assert dev.totals()["idle_s"] == 0.5 and dev.totals()["busy_s"] == 2.75
+
+
+def test_the_device_clock_is_off_with_the_recorder(monkeypatch):
+    """``observability.perf_enabled: false``: a post is one flag test,
+    no thread exists, nothing is held."""
+    for off in ({"enabled": False}, {"perf_enabled": False}):
+        rec = recorder(**off)
+        assert rec.device.enabled is False
+        rec.device.post("decode", 1.0, object(), steps=8, rows=4)
+        assert rec.device._thread is None and not rec.device._open
+        rec.device.shutdown()
+    started = []
+    monkeypatch.setattr(
+        perf_mod.threading, "Thread",
+        lambda **kw: started.append(kw) or pytest.fail("a thread"))
+    recorder(enabled=False).device.post("decode", 1.0, "x")
+    assert started == []
+
+
+def test_the_waits_are_trace_spans_only_while_a_capture_runs(monkeypatch):
+    """``vgt.device.<program>`` with what the launch carried, NOT under
+    ``vgt.engine.`` (the benchmark charges device pauses to that
+    prefix); with no capture nothing builds an annotation."""
+    opened = []
+
+    class Ann:
+        def __exit__(self, *exc):
+            opened.append("closed")
+
+    def fake(name, args):
+        opened.append((name, args()))
+        return Ann()
+
+    monkeypatch.setattr(perf_mod, "_open_annotation", fake)
+    clock, device, dev = device_clock()
+    try:
+        dev.post("decode", 10.0, "quiet", steps=8, rows=4)
+        device.finish(dev, "quiet", at=10.5)
+        assert opened == []
+        perf_mod.set_capturing(True)
+        dev.post("suffix_prefill", 10.5, "traced", prompt_tokens=182,
+                 rows=1, bucket=256)
+        device.finish(dev, "traced", at=11.0)
+    finally:
+        perf_mod.set_capturing(False)
+        dev.shutdown()
+    assert opened == [
+        ("vgt.device.suffix_prefill",
+         {"prompt_tokens": 182, "rows": 1, "bucket": 256}),
+        "closed",
+    ]
+
+
+def test_a_pause_names_its_launches_and_the_devices_brings_its_memory(
+    cpu, gc_clock
+):
+    """S13's accident as the record now reads it: the wait for the
+    device took the gap, no wave explains it (cause ``device``), and
+    ``programs`` says which launch the device sat in, ``memory`` what
+    the chip had free."""
+    clock = FakeClock()
+    rec = recorder(clock=clock)
+    device = FakeDevice(clock)
+    rec.device = perf_mod.DeviceClock(clock=clock, wait=device.wait)
+    rec.device_memory = lambda: [
+        {"id": 0, "bytes_in_use": 15_000, "bytes_limit": 16_900}]
+    try:
+        rec.tick_begin()
+        t0 = clock.t
+        rec.device.post("decode", t0 - 1.0, "old", steps=8, rows=4)
+        device.finish(rec.device, "old", at=t0 - 0.5)  # before the gap
+        clock.t = t0
+        deliver(rec, clock, cpu)
+        t1 = clock.t
+
+        def launches():
+            rec.device.post("decode", t1, "ahead", steps=8, rows=4)
+            rec.device.post("prefill", t1 + 0.0625, "small",
+                            prompt_tokens=182, rows=1, bucket=256)
+            device.finish(rec.device, "ahead", at=t1 + 0.125)
+            clock.t = t1
+
+        pause = deliver(rec, clock, cpu, gap=3.0, device=2.9,
+                        before=launches)
+        assert pause["cause"] == "device"
+        assert pause["programs"] == [
+            {"program": "decode", "steps": 8, "rows": 4, "queued_s": 0.0,
+             "device_s": 0.125},
+            {"program": "prefill", "prompt_tokens": 182, "rows": 1,
+             "bucket": 256, "queued_s": 0.0625, "device_s": 2.875,
+             "open": True},
+        ]
+        assert pause["memory"] == rec.device_memory()
+        assert rec.pauses()[-1] is pause
+        # any other cause: the launches, no memory
+        device.finish(rec.device, "small", at=clock.t)
+        host = deliver(rec, clock, cpu, gap=1.0, schedule=0.9)
+        assert host["cause"] == "host" and "memory" not in host
+        assert [p["program"] for p in host["programs"]] == ["prefill"]
+        assert "open" not in host["programs"][0]
+        # at most the last eight of a gap
+        t2 = clock.t
+
+        def many():
+            for i in range(10):
+                rec.device.post("decode", t2, f"m{i}", steps=i, rows=1)
+                device.finish(rec.device, f"m{i}", at=t2)
+
+        last = deliver(rec, clock, cpu, gap=1.0, schedule=0.9, before=many)
+        assert [p["steps"] for p in last["programs"]] == list(range(2, 10))
+    finally:
+        rec.device.shutdown()
+
+
+def test_the_device_clock_adds_across_replicas():
+    clock, device, dev = device_clock()
+    rec = recorder(clock=clock)
+    rec.device = dev
+    try:
+        dev.post("chunked_prefill", 10.0, "a", prompt_tokens=512, rows=1,
+                 bucket=512)
+        dev.post("spec_verify", 10.5, "b", steps=1, rows=2)
+        device.finish(dev, "a", at=10.75)
+        device.finish(dev, "b", at=11.0)
+        rec.tick_begin()
+        clock.advance(0.5)
+        rec.tick_end(worked=True)
+        snap = rec.snapshot()
+    finally:
+        rec.device.shutdown()
+    one = snap["totals"]["device_clock"]
+    assert one["programs"]["chunked_prefill"] == {
+        "n": 1, "s": 0.75, "queued_s": 0.0}
+    assert one["programs"]["spec_verify"] == {
+        "n": 1, "s": 0.25, "queued_s": 0.25}
+    # a verify round's steps are not a decode chunk's
+    assert one["decode_steps"] == 0 and one["prompt_tokens"] == 512
+    both = perf_mod.merge_snapshots([snap, snap])["totals"]["device_clock"]
+    assert _numbers(both) == {
+        key: 2 * value for key, value in _numbers(one).items()}
+    assert tuple(both["programs"]) == perf_mod.DEVICE_PROGRAMS
+
+
+def _settled_clock(core, launches, timeout_s=30.0):
+    """``totals.device_clock`` once the clock's thread has stamped
+    ``launches`` launches (it runs behind the engine thread)."""
+    deadline = time.monotonic() + timeout_s
+    while True:
+        clock = core.perf.totals()["device_clock"]
+        stamped = sum(row["n"] for row in clock["programs"].values())
+        if stamped >= launches or time.monotonic() > deadline:
+            return clock
+        time.sleep(0.01)
+
+
+def test_the_engine_posts_every_launch_and_a_pause_names_them():
+    """A real engine on the CPU: every decode chunk read back and every
+    prompt program dispatched is one launch of the clock, whose steps
+    and prompt tokens are the recorder's own ``count()`` sums; a
+    provoked pause carries the launches of its gap; the thread ends
+    with the core."""
+    from vgate_tpu import faults
+    from vgate_tpu.backends.base import SamplingParams
+    from vgate_tpu.runtime.engine_core import EngineCore
+
+    core = EngineCore(_engine_config())
+    core.start()
+    params = [SamplingParams(max_tokens=40, min_tokens=40,
+                             temperature=0.0)] * 2
+    prompts = ["device clock probe one", "device clock probe two, longer"]
+    try:
+        core.generate(prompts, params)
+        core.generate(prompts[::-1], params)
+        counts = dict(core.perf._counts)
+        totals = core.perf.totals()
+        chunks = sum(totals["chunks_by_steps"].values())
+        clock = _settled_clock(core, chunks + counts["prompt_programs"])
+        programs = clock["programs"]
+        assert programs["decode"]["n"] == chunks > 0
+        assert (programs["prefill"]["n"] + programs["suffix_prefill"]["n"]
+                == counts["prompt_programs"] > 0)
+        assert clock["decode_steps"] == counts["decode_steps"]
+        assert clock["prompt_tokens"] == counts["prompt_tokens"] > 0
+        assert clock["dropped"] == 0
+        assert clock["busy_s"] == pytest.approx(
+            sum(row["s"] for row in programs.values()), abs=1e-4)
+        assert clock["busy_s"] > 0 and clock["idle_s"] > 0
+        thread = core.perf.device._thread
+        assert thread.is_alive() and thread.daemon
+        assert thread is not core._thread
+
+        seen = totals["deliveries"]
+        records = len(core.perf.pauses())
+        spec = faults.arm(
+            "stall", mode="delay", delay_s=0.7, times=1,
+            match=lambda _: core.perf.totals()["deliveries"] >= seen + 2,
+        )
+        core.generate(prompts, params)
+        assert spec.fired == 1
+        (pause,) = core.perf.pauses()[records:]
+        assert pause["cause"] == "host" and "memory" not in pause
+        assert 1 <= len(pause["programs"]) <= perf_mod.PAUSE_PROGRAMS_KEPT
+        for launch in pause["programs"]:
+            assert launch["program"] in perf_mod.DEVICE_PROGRAMS
+            assert launch["device_s"] >= 0 and launch["queued_s"] >= 0
+            assert "rows" in launch
+        assert any(p["program"] == "decode" and p["steps"] >= 1
+                   for p in pause["programs"])
+        tick = [t for t in core.flight.ticks() if t["kind"] == "pause"][-1]
+        assert tick["programs"] == pause["programs"]
+    finally:
+        faults.reset()
+        core.stop()
+    assert core.perf.device._thread is None and not thread.is_alive()
+    assert not core.perf.device._open
+
+
+@pytest.mark.parametrize("tpu, program", [
+    ({"prefill_chunk": 16, "prefill_buckets": [8, 16]}, "chunked_prefill"),
+    ({"speculative_k": 2}, "spec_verify"),
+], ids=["chunked-prefill", "spec-verify"])
+def test_a_posted_output_is_never_one_a_later_launch_donates(tpu, program):
+    """The clock's thread waits on an array while the engine launches
+    on: a pool, a ring or a state among the posted outputs would be
+    deleted under it.  A wait that raised is counted under ``dropped``,
+    so it is the deleted buffer this fails on, not a number."""
+    from vgate_tpu.backends.base import SamplingParams
+    from vgate_tpu.runtime.engine_core import EngineCore
+
+    base = _engine_config()
+    core = EngineCore(load_config(
+        model=base.model.model_dump(),
+        tpu={**base.tpu.model_dump(), **tpu},
+        logging={"level": "WARNING"},
+    ))
+    raised = []
+    wait = __import__("jax").block_until_ready
+
+    def checked(output):
+        try:
+            return wait(output)
+        except Exception as exc:  # a deleted buffer: RuntimeError
+            raised.append(repr(exc))
+            raise
+
+    core.perf.device._wait = checked
+    core.start()
+    try:
+        greedy = SamplingParams(max_tokens=12, temperature=0.0)
+        long_prompt = [3 + (i % 31) for i in range(40)]
+        for _ in range(2):
+            seqs = [core.submit_tokens(long_prompt, greedy),
+                    core.submit_tokens(long_prompt[::-1], greedy)]
+            assert all(seq.done_event.wait(300) for seq in seqs)
+        launches = core.perf._counts["prompt_programs"] + 1
+        clock = _settled_clock(core, launches)
+    finally:
+        core.stop()
+    assert raised == [] and clock["dropped"] == 0
+    assert clock["programs"][program]["n"] >= 2
+    assert clock["programs"][program]["s"] > 0
+
+
+def test_a_capture_holds_the_clocks_spans_on_a_thread_of_their_own(tmp_path):
+    """``vgt.device.<program>`` spans lie on ONE line of the trace, and
+    it is not the line that ticks: the benchmark's reduction of the
+    engine's spans (``vgt.engine.*`` of the thread that ticks) reads
+    what it read before."""
+    from vgate_tpu.backends.base import SamplingParams
+    from vgate_tpu.runtime.engine_core import EngineCore
+
+    core = EngineCore(_engine_config())
+    core.start()
+    try:
+        params = [SamplingParams(max_tokens=24, min_tokens=24,
+                                 temperature=0.0)]
+        core.generate(["0 probe"], params)
+
+        def load():
+            time.sleep(0.15)  # let the capture begin first
+            core.generate(["1 probe"], params)
+
+        thread = threading.Thread(target=load)
+        thread.start()
+        result = core.capture_profile(0.6, str(tmp_path / "trace"))
+        thread.join()
+    finally:
+        core.stop()
+    lines = _trace_names(result["trace_dir"])
+    clock = [names for names in lines
+             if any(n.startswith("vgt.device.") for n in names)]
+    assert len(clock) == 1
+    (names,) = clock
+    assert "vgt.device.decode" in names and "vgt.device.prefill" in names
+    assert not [n for n in names if n.startswith("vgt.engine.")]
+    engine = max(lines, key=lambda n: n.count("vgt.engine.tick"))
+    assert engine.count("vgt.engine.tick") >= 2
+    assert not [n for n in engine if n.startswith("vgt.device.")]

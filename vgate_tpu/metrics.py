@@ -114,7 +114,9 @@ DEDUP_RATIO = _safe_metric(
 ENGINE_STEP_TIME = _safe_metric(
     Histogram,
     "vgt_engine_step_seconds",
-    "Device time per continuous-batching step",
+    "The device's seconds on one launch of a step program, from the "
+    "device clock (observability/perf.py DeviceClock): a decode chunk "
+    "or verify round (decode), a prompt program (prefill)",
     labelnames=("kind",),  # prefill | decode
     buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1, 5),
 )
@@ -264,6 +266,18 @@ ENGINE_PAUSES = _safe_metric(
     "host.  Each has a record in /debug/perf -> pauses and a flight-"
     "recorder tick of kind pause",
     labelnames=("cause",),
+)
+DEVICE_SECONDS = _safe_metric(
+    Counter,
+    "vgt_device_seconds",
+    "The device's seconds by step program (prefill | suffix_prefill | "
+    "chunked_prefill | decode | spec_verify) and idle between two "
+    "launches, from the times the launches FINISHED (the device clock, "
+    "observability/perf.py DeviceClock; /debug/perf -> "
+    "totals.device_clock).  The labels sum to the wall time since the "
+    "first launch: rate() of one over rate() of all is that program's "
+    "share of the chip",
+    labelnames=("program",),
 )
 
 # --- recovery / health state machine (runtime/supervisor.py) ---

@@ -69,11 +69,22 @@ snapshot readers copy defensively under the GIL):
   loop the same way, and :class:`GcClock` (``GC``) the process's
   garbage collector.
 
+* **The device clock** (:class:`DeviceClock`) — the device's seconds
+  by program.  ``device_s`` above is the time the HOST is blocked, which
+  says nothing of the device once the host is hidden behind it.  The
+  device runs launches in order, so the time each launch FINISHED,
+  stamped by one thread that waits for one launch after another, tiles
+  the device's time by program with no profiler: ``totals.device_clock``
+  (busy and idle seconds, seconds / launches / queued seconds a
+  program), the roofline gauge's denominator, the step-time histogram,
+  and the launches a pause record names.
+
 Surfaces: ``GET /debug/perf`` (auth-gated, drain-uncounted), the
 ``/stats`` engine block (``perf``), metrics
 ``vgt_tick_phase_seconds{phase}`` / ``vgt_recompiles_total{variant}`` /
 ``vgt_decode_mfu`` / ``vgt_decode_hbm_roofline_pct`` /
-``vgt_host_overhead_ratio``, and the loadlab artifact's per-cell
+``vgt_host_overhead_ratio`` / ``vgt_device_seconds_total{program}``,
+and the loadlab artifact's per-cell
 ``perf`` block (loadlab/runner.py scrapes ``/debug/perf`` around every
 QPS cell).
 """
@@ -142,6 +153,19 @@ GAP_KEYS = tuple(f"{round(ms, 2):g}" for ms in _GAP_EDGES_MS) + ("inf",)
 # what the engine counts between two deliveries (PerfRecorder.count)
 GAP_COUNTS = ("prompt_programs", "prompt_tokens", "decode_steps", "swap_ins")
 
+# the step programs, as EngineCore._PROGRAMS names them: the device
+# clock's rows.  The first three are the prompt programs.
+DEVICE_PROGRAMS = (
+    "prefill", "suffix_prefill", "chunked_prefill", "decode", "spec_verify",
+)
+PROMPT_PROGRAMS = frozenset(DEVICE_PROGRAMS[:3])
+# launches the device clock holds at most, the one it waits for among
+# them (an engine has two chunks and one wave in flight); a post past
+# that is dropped and counted
+DEVICE_QUEUE_MAX = 64
+# launches a pause record names at most (the last of its gap)
+PAUSE_PROGRAMS_KEPT = 8
+
 # True only while EngineCore.capture_profile runs a profiler session:
 # the one flag every bracket tests before building a TraceAnnotation
 _capturing = False
@@ -171,7 +195,7 @@ class _Bracket:
     clock, hands ``(name, seconds)`` to the recorder, and mirrors the
     block into the profiler trace while a capture runs."""
 
-    __slots__ = ("_sink", "name", "_args", "_t0", "_ann", "seconds")
+    __slots__ = ("_sink", "name", "_args", "t0", "_ann", "seconds")
 
     def __init__(self, sink: "PerfRecorder", name: str, args) -> None:
         self._sink = sink
@@ -185,7 +209,8 @@ class _Bracket:
             self._ann = _open_annotation(
                 "vgt.engine." + self.name, self._args
             )
-        self._t0 = self._sink._span_begin(self.name)
+        # when the block began, on the recorder's clock
+        self.t0 = self._sink._span_begin(self.name)
         return self
 
     def note(self, **args: Any) -> None:
@@ -194,7 +219,7 @@ class _Bracket:
             self._ann.set_metadata(**args)
 
     def __exit__(self, *exc) -> bool:
-        self.seconds = self._sink._span_end(self.name, self._t0)
+        self.seconds = self._sink._span_end(self.name, self.t0)
         if self._ann is not None:
             self._ann.__exit__(*exc)
         return False
@@ -324,6 +349,235 @@ def process_age_s() -> Optional[float]:
 _FLUSH_INTERVAL_S = 0.5
 
 
+class DeviceClock:
+    """The device's seconds by program, from the times its launches
+    FINISHED.  The engine thread posts every launch that goes through
+    ``EngineCore._launch`` (:meth:`post`: the program, the time the
+    jitted call began, what it carried and one small output that nothing
+    donates); ONE daemon thread takes them in order, waits for each
+    one's output and stamps ``t_done``.  The device runs launches in
+    order, so for launch i::
+
+        start    = max(t_done[i-1], t_call[i])
+        seconds  = t_done[i] - start               the device's, on i
+        idle     = max(0, t_call[i] - t_done[i-1])   the device's, before i
+        queued_s = start - t_call[i]     i's wait behind the launch ahead
+
+    and busy + idle seconds tile the time from the first call to the
+    last stamp exactly.  A late stamp (the thread wants the GIL back: at
+    most the switch interval) moves a boundary between two neighbours
+    and loses nothing.
+
+    What it cannot see: device work launched outside ``_launch`` (the
+    joining rows' edit, page-table and sampling-row uploads, swap and
+    copy-on-write copies, a first token's sampling) falls into the
+    seconds of the next launch; a launch's seconds begin at its CALL, so
+    where the device had nothing queued ahead the call's own time (trace
+    and enqueue; a fresh variant's compile) counts as the device's; and
+    what happens INSIDE a program, by kernel and by scope, is the
+    profile's to say.
+
+    Bounded and mortal: at most ``DEVICE_QUEUE_MAX`` launches are held
+    (a post past that is ``dropped``), the thread starts at the first
+    post and ends at :meth:`shutdown` (the core's ``stop``), which also
+    lets go of every output held; a core started again (``warmup`` stops
+    the core it started) gets a fresh thread at its next post.  A wait
+    that raises (a poisoned launch after a device fault) is counted
+    under ``dropped`` and skipped, its seconds going to the next launch.
+    ``enabled`` false: a post tests one flag and no thread ever
+    starts."""
+
+    def __init__(
+        self,
+        clock: Any = time.perf_counter,
+        wait: Optional[Callable[[Any], Any]] = None,
+        enabled: bool = True,
+    ) -> None:
+        self._clock = clock
+        # None: jax.block_until_ready, which releases the GIL (imported
+        # on the thread; tests hand in a fake)
+        self._wait = wait
+        self.enabled = enabled
+        self._lock = threading.Lock()
+        self._wake = threading.Condition(self._lock)
+        # launches posted and not stamped yet, the one waited for first
+        self._open: "deque[tuple]" = deque()
+        # the last launches stamped, (t_done, record): for a pause record
+        self._done: "deque[tuple]" = deque(maxlen=PAUSE_PROGRAMS_KEPT)
+        self._thread: Optional[threading.Thread] = None
+        self._last_done: Optional[float] = None
+        self.busy_s = 0.0
+        self.idle_s = 0.0
+        self.decode_steps = 0
+        self.prompt_tokens = 0
+        self.dropped = 0
+        self._programs = {
+            name: {"n": 0, "s": 0.0, "queued_s": 0.0}
+            for name in DEVICE_PROGRAMS
+        }
+        self._seconds = {
+            name: metrics.DEVICE_SECONDS.labels(program=name)
+            for name in DEVICE_PROGRAMS + ("idle",)
+        }
+        self._step_time = {
+            name: metrics.ENGINE_STEP_TIME.labels(
+                kind="prefill" if name in PROMPT_PROGRAMS else "decode"
+            )
+            for name in DEVICE_PROGRAMS
+        }
+
+    def post(
+        self, program: str, t_call: float, output: Any,
+        trace_id: Optional[str] = None, **carried: int,
+    ) -> None:
+        """THE post (engine thread, after the jitted call returned):
+        ``output`` is an array of the launch that no later launch takes
+        as a donated argument, never a pool, a ring or a state (it would
+        be deleted under the waiter); ``carried`` is what the launch
+        worked on (a decode chunk's ``steps`` and ``rows``; a prompt
+        program's ``prompt_tokens``, ``rows`` and ``bucket``);
+        ``trace_id`` the step-time histogram's exemplar."""
+        if not self.enabled:
+            return
+        with self._lock:
+            if len(self._open) >= DEVICE_QUEUE_MAX:
+                self.dropped += 1
+                return
+            self._open.append((program, t_call, carried, trace_id, output))
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, name="vgt-device-clock", daemon=True
+                )
+                self._thread.start()
+            self._wake.notify()
+
+    def _run(self) -> None:
+        me = threading.current_thread()
+        wait = self._wait
+        if wait is None:
+            import jax
+
+            wait = jax.block_until_ready
+        while True:
+            with self._lock:
+                while self._thread is me and not self._open:
+                    self._wake.wait()
+                if self._thread is not me:
+                    return  # shut down
+                program, t_call, carried, trace_id, output = self._open[0]
+            # while a capture runs the wait is a trace span that should
+            # lie on top of the device's module event.  NOT under
+            # ``vgt.engine.``: the benchmark charges device pauses to
+            # the engine thread's spans of that prefix
+            ann = (
+                _open_annotation("vgt.device." + program, lambda: carried)
+                if _capturing else None
+            )
+            raised = False
+            try:
+                wait(output)
+            except Exception:
+                raised = True
+            t_done = self._clock()  # the stamp comes first
+            del output
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            with self._lock:
+                if self._thread is not me:
+                    return  # shut down during the wait: nothing to stamp
+                self._open.popleft()
+                if raised:
+                    self.dropped += 1
+                    continue
+                seconds, idle = self._book(program, t_call, carried, t_done)
+            self._seconds[program].inc(seconds)
+            if idle:
+                self._seconds["idle"].inc(idle)
+            metrics.observe_with_exemplar(
+                self._step_time[program], seconds, trace_id=trace_id
+            )
+
+    def _book(
+        self, program: str, t_call: float, carried: Dict[str, int],
+        t_done: float,
+    ) -> tuple:
+        last = self._last_done
+        start = t_call if last is None else max(last, t_call)
+        idle = 0.0 if last is None else max(0.0, t_call - last)
+        seconds, queued = t_done - start, start - t_call
+        self._last_done = t_done
+        self.busy_s += seconds
+        self.idle_s += idle
+        row = self._programs[program]
+        row["n"] += 1
+        row["s"] += seconds
+        row["queued_s"] += queued
+        if program == "decode":
+            self.decode_steps += carried.get("steps", 0)
+        self.prompt_tokens += carried.get("prompt_tokens", 0)
+        self._done.append((t_done, {
+            "program": program, **carried, "queued_s": round(queued, 6),
+            "device_s": round(seconds, 6),
+        }))
+        return seconds, idle
+
+    @property
+    def decode_s(self) -> float:
+        """The device's seconds on decode chunks and verify rounds: what
+        the roofline gauge divides the modelled decode bytes by."""
+        rows = self._programs
+        return rows["decode"]["s"] + rows["spec_verify"]["s"]
+
+    def launches_since(self, t0: float) -> List[Dict[str, Any]]:
+        """The launches stamped at ``t0`` or later and those still open
+        (``open``: seconds so far), oldest first, the last
+        ``PAUSE_PROGRAMS_KEPT`` of them: what a pause record names."""
+        now = self._clock()
+        with self._lock:
+            out = [launch for t_done, launch in self._done if t_done >= t0]
+            last = self._last_done
+            for program, t_call, carried, _, _ in self._open:
+                start = min(now, t_call if last is None
+                            else max(last, t_call))
+                out.append({
+                    "program": program, **carried,
+                    "queued_s": round(start - t_call, 6),
+                    "device_s": round(now - start, 6), "open": True,
+                })
+                last = now  # the launches behind it have not started
+        return out[-PAUSE_PROGRAMS_KEPT:]
+
+    def totals(self) -> Dict[str, Any]:
+        """Monotone, every key there from boot (the benchmark's ratios
+        read a missing path as no reading)."""
+        with self._lock:
+            return {
+                "busy_s": round(self.busy_s, 6),
+                "idle_s": round(self.idle_s, 6),
+                "decode_steps": self.decode_steps,
+                "prompt_tokens": self.prompt_tokens,
+                "dropped": self.dropped,
+                "programs": {
+                    name: {
+                        "n": row["n"], "s": round(row["s"], 6),
+                        "queued_s": round(row["queued_s"], 6),
+                    }
+                    for name, row in self._programs.items()
+                },
+            }
+
+    def shutdown(self, timeout_s: float = 1.0) -> None:
+        """The thread ends with its core and no output outlives it (a
+        thread stuck in a wait on a wedged device is a daemon, and holds
+        the one array it waits for)."""
+        with self._lock:
+            self._open.clear()
+            thread, self._thread = self._thread, None
+            self._wake.notify_all()
+        if thread is not None:
+            thread.join(timeout=timeout_s)
+
+
 class TickProfile:
     """One engine tick's phase decomposition (mutable accumulator while
     the tick runs; frozen by :meth:`PerfRecorder.tick_end`)."""
@@ -331,10 +585,10 @@ class TickProfile:
     __slots__ = (
         "t", "wall", "host", "schedule", "state", "dispatch", "device",
         "readback", "detok", "tokens", "decode_steps", "decode_bytes",
-        "decode_device_s", "cpu0", "cpu_in_wait",
+        "clock_decode_s", "cpu0", "cpu_in_wait",
     )
 
-    def __init__(self, t: float) -> None:
+    def __init__(self, t: float, clock_decode_s: float) -> None:
         self.t = t
         self.wall = 0.0
         self.host = 0.0
@@ -347,7 +601,9 @@ class TickProfile:
         self.tokens = 0
         self.decode_steps = 0
         self.decode_bytes = 0
-        self.decode_device_s = 0.0
+        # the device clock's running decode seconds at tick begin: the
+        # window's decode seconds are the growth since its oldest tick
+        self.clock_decode_s = clock_decode_s
         # engine-thread CPU clock at tick begin, and the CPU seconds
         # burnt inside the device_wait/readback brackets
         self.cpu0 = time.thread_time()
@@ -459,6 +715,11 @@ class PerfRecorder:
             ("rows_worked", "rows_real", "rows_padding"), 0)
         self.total_engine_cpu_s = 0.0
         self.total_engine_cpu_in_wait_s = 0.0
+        # the device's seconds by program (EngineCore._launch posts)
+        self.device = DeviceClock(clock=clock, enabled=self.enabled)
+        # the chips' memory_stats(), for a pause the device caused (the
+        # engine's; None in a recorder that stands alone)
+        self.device_memory: Optional[Callable[[], List[Dict[str, int]]]] = None
         # the flight recorder's per-request phase sums (admitted /
         # queue_wait_s / first_tokens / prefill_s), folded into totals()
         self.request_totals: Optional[Callable[[], Dict[str, Any]]] = None
@@ -486,7 +747,7 @@ class PerfRecorder:
             return
         if self._gc_thread is None:
             self._gc_thread = GC.watch()
-        self._cur = TickProfile(self._clock())
+        self._cur = TickProfile(self._clock(), self.device.decode_s)
 
     def span(
         self, name: str, args: Optional[Callable[[], dict]] = None
@@ -536,15 +797,15 @@ class PerfRecorder:
     ) -> None:
         """One decode-chunk (or spec-verify, ``chunk=False``) readback:
         ``steps`` fused steps over sequences holding ``ctx_tokens``
-        resident context tokens in all, with ``device_s`` of
-        host-observed device time — feeds the modeled HBM traffic the
-        roofline gauge divides by, and the chunk-length / live-context
-        window counters."""
+        resident context tokens in all, with ``device_s`` of the host's
+        wait for it (``totals.decode_device_s``; the DEVICE's seconds
+        are the device clock's) — feeds the modeled HBM traffic of the
+        roofline gauge, and the chunk-length / live-context window
+        counters."""
         cur = self._cur
         if cur is None:
             return
         cur.decode_steps += steps
-        cur.decode_device_s += device_s
         self.total_decode_device_s += device_s
         self.total_decode_ctx_token_steps += steps * ctx_tokens
         if chunk:
@@ -675,6 +936,11 @@ class PerfRecorder:
           wave explains it: the chip or its runtime stood still;
         * ``host``     otherwise: the engine's own Python (``phases``
           says in which bracket).
+
+        Beside the cause, not part of its rule: ``programs``, the
+        launches that finished or stood open in the gap by the device
+        clock (:meth:`DeviceClock.launches_since`), and for the cause
+        ``device`` the chips' ``memory`` as the pause closed.
         """
         phases = {name: d[name] for name in MEASURED_PHASES}
         host = max(0.0, gap - sum(phases.values()))
@@ -733,7 +999,10 @@ class PerfRecorder:
             "queue_depth": queue_depth,
             "steps": steps,
             "rows": rows,
+            "programs": self.device.launches_since(self._clock() - gap),
         }
+        if cause == "device" and self.device_memory is not None:
+            record["memory"] = self.device_memory()
         self._pauses.append(record)
         self._pause_totals[cause + "_n"] += 1
         self._pause_totals[cause + "_s"] += gap
@@ -1060,7 +1329,9 @@ class PerfRecorder:
 
     def window(self) -> Dict[str, Any]:
         """Rolling-window aggregates: live tok/s, MFU, %-of-HBM-roofline
-        and the host-overhead ratio.  Safe from any thread."""
+        (the modelled bytes of the decode readbacks in the window over
+        the device clock's decode seconds in it) and the host-overhead
+        ratio.  Safe from any thread."""
         now = self._clock()
         profs = self._window_profiles(now)
         phases = {name: 0.0 for name in PHASES}
@@ -1068,7 +1339,6 @@ class PerfRecorder:
         tokens = 0
         decode_steps = 0
         decode_bytes = 0
-        decode_device_s = 0.0
         for prof in profs:
             for name, value in prof.phases().items():
                 phases[name] += value
@@ -1076,7 +1346,10 @@ class PerfRecorder:
             tokens += prof.tokens
             decode_steps += prof.decode_steps
             decode_bytes += prof.decode_bytes
-            decode_device_s += prof.decode_device_s
+        # the DEVICE's seconds on the window's decode launches
+        device_decode_s = (
+            self.device.decode_s - profs[0].clock_decode_s if profs else 0.0
+        )
         # offered span: from the oldest in-window tick to now (the
         # engine may have gone idle — tok/s decays over real time)
         span = (now - profs[0].t) if profs else 0.0
@@ -1085,7 +1358,7 @@ class PerfRecorder:
         if self.roofline is not None:
             mfu = self.roofline.mfu(tok_s)
             hbm_pct = self.roofline.hbm_roofline_pct(
-                decode_bytes, decode_device_s
+                decode_bytes, device_decode_s
             )
         return {
             "window_s": self.window_s,
@@ -1094,7 +1367,7 @@ class PerfRecorder:
             "tokens": tokens,
             "tokens_per_s": round(tok_s, 2),
             "decode_steps": decode_steps,
-            "decode_device_s": round(decode_device_s, 6),
+            "device_decode_s": round(device_decode_s, 6),
             "phase_seconds": {
                 k: round(v, 6) for k, v in phases.items()
             },
@@ -1170,6 +1443,7 @@ class PerfRecorder:
             "boot_seconds": dict(BOOT_SECONDS),
             "gc": GC.totals(),
             "prefill": dict(self._prefill),
+            "device_clock": self.device.totals(),
         }
         if self.request_totals is not None:
             out.update(self.request_totals())
@@ -1432,6 +1706,24 @@ def _sum_dicts(dicts: List[Dict[str, Any]]) -> Dict[str, Any]:
     return out
 
 
+def _sum_device_clocks(clocks: List[Dict[str, Any]]) -> Dict[str, Any]:
+    """The replicas' ``totals.device_clock`` as one: chip-seconds."""
+    flat = _sum_dicts([
+        {k: v for k, v in c.items() if k != "programs"} for c in clocks
+    ])
+    return {
+        **{k: round(v, 6) for k, v in flat.items()},
+        "programs": {
+            name: {
+                k: round(v, 6) for k, v in _sum_dicts(
+                    [c["programs"][name] for c in clocks]
+                ).items()
+            }
+            for name in DEVICE_PROGRAMS
+        },
+    }
+
+
 def _weighted_ratio(parts: List[tuple]) -> Optional[float]:
     """Weighted mean of (value, weight) pairs, None-tolerant."""
     num = den = 0.0
@@ -1487,7 +1779,7 @@ def merge_snapshots(
         ),
         "hbm_roofline_pct": _weighted_ratio(
             [
-                (w["hbm_roofline_pct"], w["decode_device_s"])
+                (w["hbm_roofline_pct"], w["device_decode_s"])
                 for w in windows
             ]
         ),
@@ -1531,6 +1823,9 @@ def merge_snapshots(
         "boot_seconds": dict(totals[0].get("boot_seconds", {})),
         "gc": dict(totals[0].get("gc", {})),
         "prefill": _sum_dicts([t.get("prefill", {}) for t in totals]),
+        "device_clock": _sum_device_clocks(
+            [t["device_clock"] for t in totals if "device_clock" in t]
+        ),
     }
     out["window"] = agg_window
     out["totals"] = agg_totals

@@ -1019,6 +1019,10 @@ class EngineCore:
             ),
         )
         self.perf.request_totals = self.flight.phase_totals
+        self.perf.device_memory = lambda: self._device_memory(
+            "bytes_in_use", "peak_bytes_in_use",
+            "largest_free_block_bytes", "bytes_limit",
+        )
         if self.spec.conv_layers:
             self.perf.conv_block = {
                 "layers": self.spec.conv_layers,
@@ -1395,6 +1399,8 @@ class EngineCore:
             # checks discard anything it does if it ever wakes)
             self._thread.join(timeout=1 if self._stalled else 30)
             self._thread = None
+        # the device clock's thread ends with its core
+        self.perf.device.shutdown()
         # resolve every owed future: a sequence still resident (or still
         # in the submit queue) when the loop exits would leave its
         # waiter blocked on done_event forever.  Runs after the join, so
@@ -2617,16 +2623,9 @@ class EngineCore:
         for worked, real in self._prompt_rows:
             self.perf.note_prefill_rows(worked, real)
         self._prompt_rows.clear()
-        # batched admission costs one combined dispatch+readback; attribute
-        # an equal share to each prefill so observation count stays
-        # one-per-prefill and the histogram sum stays the true wall time
+        # batched admission costs one combined dispatch+readback: each
+        # prefill's flight record and trace get an equal share of it
         share = (time.perf_counter() - wave.start) / len(plans)
-        for plan in plans:
-            metrics.observe_with_exemplar(
-                metrics.ENGINE_STEP_TIME.labels(kind="prefill"),
-                share,
-                trace_id=getattr(plan.seq.trace, "trace_id", None),
-            )
         with self.perf.span("emit") as emit:
             delivered = self._emit_first_tokens(
                 dispatched, firsts, plans, wave.plan_epochs,
@@ -2859,7 +2858,13 @@ class EngineCore:
         attention implementation (``attention`` = name, impl()) noted,
         the heartbeat given the compile grace, and the span's seconds
         entered in the compile ledger as the compile's cost.  ``fields``
-        (bucket or chunk, batch) go to the heartbeat and those records."""
+        (bucket or chunk, batch) go to the heartbeat and those records.
+
+        Yields a dict the call site fills for the device clock
+        (observability/perf.py ``DeviceClock.post``): ``output``, ONE
+        small array of the launch that no later launch takes as a
+        donated argument (never a pool, a ring or a state), and what the
+        launch carried.  It is posted once the call has returned."""
         family, span, trigger = self._PROGRAMS[program]
         fresh = (family, key) not in self._compiled
         if fresh:
@@ -2875,11 +2880,25 @@ class EngineCore:
         # the jitted call's return is trace+enqueue; a fresh variant's
         # call also compiles synchronously, so its duration IS the
         # compile cost the ledger records
+        posted: Dict[str, Any] = {}
         with self.perf.span(span, span_args) as disp:
-            yield
+            yield posted
         if fresh:
             self.perf.record_compile(
                 program, key, disp.seconds, trigger=trigger
+            )
+        if posted and self.perf.enabled:
+            self.perf.device.post(
+                program, disp.t0,
+                # the step-time histogram's exemplar: the first traced row
+                trace_id=next(
+                    (
+                        s.trace.trace_id for s in seqs
+                        if s.trace is not None and s.trace.trace_id
+                    ),
+                    None,
+                ),
+                **posted,
             )
 
     @engine_thread_only
@@ -3120,6 +3139,7 @@ class EngineCore:
             attention = ("prefill", lambda: prefill_attention_impl(
                 self.spec, self.use_pallas, mesh
             ))
+        real = int(lens[: len(plans)].sum())
         with self._launch(
             program, key, seqs, attention,
             lambda: {
@@ -3127,7 +3147,7 @@ class EngineCore:
                 "ctx_tokens": sum(ends),
             },
             bucket=bucket, batch=B,
-        ):
+        ) as posted:
             out, *cache = step(
                 self.params, self.spec, jnp.asarray(tokens),
                 *map(jnp.asarray, lens_args),
@@ -3138,7 +3158,9 @@ class EngineCore:
                 **kw, **self._state_args(slots),
             )
             self._set_cache(cache)
-        real = int(lens[: len(plans)].sum())
+            posted.update(
+                output=out[0], prompt_tokens=real, rows=B, bucket=bucket
+            )
         self.perf.count(prompt_programs=1, prompt_tokens=real)
         # the rows the program works on, by the model layer's own rule
         arrays, rows = prompt_rows(
@@ -3481,7 +3503,7 @@ class EngineCore:
                     for s in active)} if self.spec.is_dsa else {}),
             },
             chunk=chunk, batch=len(active),
-        ):
+        ) as posted:
             (
                 chunk_tokens,
                 chunk_lp,
@@ -3535,6 +3557,9 @@ class EngineCore:
             moe_stats = None
             if more:
                 self.state, moe_stats = more
+            posted.update(
+                output=chunk_tokens, steps=chunk, rows=len(active)
+            )
         self._step_counter += chunk
         self.perf.count(decode_steps=chunk)
         # snapshot preempt_count as an epoch: a sequence preempted while
@@ -3728,21 +3753,10 @@ class EngineCore:
         self, kind: str, seqs: List[Sequence], chunk: int,
         step_s: float, device_s: float, readback_s: float,
     ) -> None:
-        """A decode readback's two records (chunk or verify round): the
-        step-time histogram, with the first traced row's id as the
-        exemplar, and the flight recorder's tick."""
-        metrics.observe_with_exemplar(
-            metrics.ENGINE_STEP_TIME.labels(kind="decode"),
-            step_s,
-            trace_id=next(
-                (
-                    s.trace.trace_id
-                    for s in seqs
-                    if s.trace is not None and s.trace.trace_id
-                ),
-                None,
-            ),
-        )
+        """A decode readback's flight-recorder tick (chunk or verify
+        round), with the HOST's times for it; the device's seconds on
+        the launch are the device clock's, which feeds the step-time
+        histogram (observability/perf.py DeviceClock)."""
         self.flight.record_tick(
             kind,
             batch=len(seqs),
@@ -3881,7 +3895,7 @@ class EngineCore:
                 "ctx_tokens": sum(s.total_len for s in active),
             },
             chunk=S_round, batch=len(active),
-        ):
+        ) as posted:
             (
                 model_toks, accepted, lp_data, counts_out,
                 self.k_pages, self.v_pages,
@@ -3914,6 +3928,7 @@ class EngineCore:
                 bias_vals=rows["bias_vals"],
                 mesh=self._mt_mesh,
             )
+            posted.update(output=model_toks, steps=1, rows=len(active))
         # the histogram of the tokens this round appended, kept on the
         # device for the next round (None without penalties)
         rows["counts"] = counts_out
@@ -4392,6 +4407,21 @@ class EngineCore:
         if rest:
             (self.state,) = rest
 
+    def _device_memory(self, *keys: str) -> List[Dict[str, int]]:
+        """What each chip of the mesh itself reports of ``keys``, as far
+        as its runtime gives them (nothing on a CPU)."""
+        return [
+            {
+                "id": int(dev.id),
+                **{
+                    k: int(v)
+                    for k, v in (dev.memory_stats() or {}).items()
+                    if k in keys
+                },
+            }
+            for dev in self.mesh.devices.flat
+        ]
+
     def get_stats(self) -> Dict[str, Any]:
         """Engine counters for /stats.  ``steps`` counts *dispatched decode
         steps* (chunk lengths summed, including overshoot steps discarded at
@@ -4476,17 +4506,9 @@ class EngineCore:
             "kv_write": self._decode_kv_write(),
             "load_time_s": round(self.load_time_s, 2),
             # what each chip of the mesh itself reports (empty on CPU)
-            "device_memory": [
-                {
-                    "id": int(dev.id),
-                    **{
-                        k: int(v)
-                        for k, v in (dev.memory_stats() or {}).items()
-                        if k in ("bytes_in_use", "bytes_limit")
-                    },
-                }
-                for dev in self.mesh.devices.flat
-            ],
+            "device_memory": self._device_memory(
+                "bytes_in_use", "bytes_limit"
+            ),
             **(
                 {"kv_swap": self.kv_swap.get_stats()}
                 if self.kv_swap is not None
