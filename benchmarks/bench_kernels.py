@@ -7,6 +7,7 @@ blockwise twin).  Run on hardware:
 
     python benchmarks/bench_kernels.py
     python benchmarks/bench_kernels.py sample_edits   # that probe alone
+    python benchmarks/bench_kernels.py greedy_head    # a decode step's tail
     python benchmarks/bench_kernels.py decode_cells [CELL ...]
     python benchmarks/bench_kernels.py expert_layer [CONFIG ...]
     python benchmarks/bench_kernels.py dsa_index dsa_select dsa_attend
@@ -476,6 +477,188 @@ def bench_sample_edits(shapes=SAMPLE_SHAPES, widths=SAMPLE_WIDTHS,
                     seconds = time_sample_scope(edits, sampler, case)
                     line[f"{label}_us"] = round(seconds * 1e6, 1)
                 yield line
+
+
+# the decode chunk's tail at the cells' shapes: (rows, width, vocabulary,
+# tied): the 1.5B; the LFM2 cut; the 7B-l14; nemotron's / mistral's
+# quarter vocabulary at nemotron's 192 slots; qwen3-next's; K-EXAONE's;
+# and GLM's 48 slots, where the kernel loses and the shape rule
+# (ops/pallas/greedy_head.py worth_fusing) keeps the three passes
+HEAD_SHAPES = (
+    (256, 1536, 151936, True), (256, 2048, 65536, True),
+    (256, 3584, 152064, False), (192, 4096, 32768, False),
+    (256, 2048, 37984, True), (192, 6144, 19200, False),
+    (48, 6144, 19360, False),
+)
+HEAD_TILES = (512, 1024)  # beside the rule's; 2,048 outgrow VMEM
+HEAD_THRESHOLD = 1.0e4
+
+
+def greedy_head_case(B, D, V, tied, ids="shared", seed=0):
+    """A greedy decode step's tail inputs: the head (bf16, drawn as
+    `init_params` draws it), a 16-wide `logit_bias` at +-100 and two
+    stop ids a row, every row below its floor.  ``shared``: every row
+    names the same ids, low in the vocabulary and at its end (the
+    benchmark's requests: 16 printable bytes and the model's stops);
+    ``random``: each row its own, anywhere (the edits' worst case)."""
+    rng = np.random.default_rng(seed)
+    head = (0.02 * jax.random.normal(
+        jax.random.PRNGKey(seed), (V, D) if tied else (D, V), jnp.float32
+    )).astype(jnp.bfloat16)
+    if ids == "shared":
+        bias = np.tile(3 + np.arange(65, 81), (B, 1))
+        stops = np.tile([V - 1, V - 2], (B, 1))
+    else:
+        bias = np.stack([rng.permutation(V)[:16] for _ in range(B)])
+        stops = np.stack([rng.permutation(V)[:2] for _ in range(B)])
+    vals = rng.choice([100.0, -100.0], size=(B, 16))
+    return (
+        head, jnp.asarray(bias, jnp.int32), jnp.asarray(vals, jnp.float32),
+        jnp.full((B,), 1 << 20, jnp.int32), jnp.asarray(stops, jnp.int32),
+    )
+
+
+def _head_product(x, head, tied):
+    return jnp.einsum(
+        "bd,vd->bv" if tied else "bd,dv->bv", x, head,
+        preferred_element_type=jnp.float32)
+
+
+def head_by_three_passes(x, head, bias_ids, bias_vals, steps, min_toks,
+                         stop_ids, tied):
+    """The present path, as `_decode_chunk` traces it: the head's
+    product written as `f32[B, V]`, the guard, the edits, the argmax."""
+    from vgate_tpu import integrity
+    from vgate_tpu.ops.sampling import (
+        apply_logit_bias, sample_tokens, suppress_stop_tokens)
+
+    logits = _head_product(x, head, tied)
+    flags = integrity.logit_guard(logits, HEAD_THRESHOLD)
+    logits = suppress_stop_tokens(
+        apply_logit_bias(logits, bias_ids, bias_vals), steps, min_toks,
+        stop_ids)
+    return sample_tokens(logits, None, None, None, None,
+                         all_greedy=True), flags
+
+
+def head_by_one_reduce(x, head, bias_ids, bias_vals, steps, min_toks,
+                       stop_ids, tied):
+    """Form (i): the same consumers as ONE variadic reduce whose only
+    producer is the product (value and first index of the edited
+    maximum, and the guard's maximum of |raw| as bits)."""
+    from vgate_tpu import integrity
+    from vgate_tpu.ops.sampling import apply_logit_bias, suppress_stop_tokens
+
+    logits = _head_product(x, head, tied)
+    edited = suppress_stop_tokens(
+        apply_logit_bias(logits, bias_ids, bias_vals), steps, min_toks,
+        stop_ids)
+    bits = jax.lax.bitcast_convert_type(logits, jnp.int32) & 0x7FFFFFFF
+    iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+
+    def fold(a, b):
+        (va, ia, ba), (vb, ib, bb) = a, b
+        take = (vb > va) | ((vb == va) & (ib < ia))
+        return (jnp.where(take, vb, va), jnp.where(take, ib, ia),
+                jnp.maximum(ba, bb))
+
+    _, tokens, most = jax.lax.reduce(
+        (edited, iota, bits),
+        (jnp.float32(-jnp.inf), jnp.int32(0x7FFFFFFF), jnp.int32(0)),
+        fold, (1,))
+    flags = (
+        jnp.where(most >= 0x7F800000, integrity.FLAG_NONFINITE, 0)
+        | jnp.where(most == 0, integrity.FLAG_ZERO, 0)
+        | jnp.where(
+            jax.lax.bitcast_convert_type(most, jnp.float32)
+            >= HEAD_THRESHOLD, integrity.FLAG_SATURATED, 0))
+    return tokens, flags.astype(jnp.uint8)
+
+
+def head_by_kernel(tile):
+    """Form (ii): ops/pallas/greedy_head.py at a forced tile (0: the
+    rule's)."""
+    from vgate_tpu.ops.pallas.greedy_head import greedy_head_pallas
+    from vgate_tpu.ops.sampling import live_stop_ids
+
+    def run(x, head, bias_ids, bias_vals, steps, min_toks, stop_ids, tied):
+        V = head.shape[0] if tied else head.shape[1]
+        live = live_stop_ids(V, steps, min_toks, stop_ids)
+        return greedy_head_pallas(
+            x, head, bias_ids, bias_vals, live, tied=tied, guard=True,
+            threshold=HEAD_THRESHOLD, tile=tile)
+
+    return run
+
+
+def head_forms(tiles=HEAD_TILES):
+    return {
+        "three_passes": head_by_three_passes,
+        "one_reduce": head_by_one_reduce,
+        "kernel": head_by_kernel(0),
+        **{f"kernel_{t}": head_by_kernel(t) for t in tiles},
+    }
+
+
+def head_program(form, B, D, tied, loop):
+    """`loop` steps of one form in one program: each step's rows are
+    drawn from the step's index and lean on the last step's tokens, so
+    nothing is hoisted and no two steps see the same rows.  Returns
+    ([loop, B] tokens, [loop, B] flags)."""
+
+    @jax.jit
+    def run(head, bias_ids, bias_vals, min_toks, stop_ids):
+        def body(carry, i):
+            x = jax.random.normal(
+                jax.random.fold_in(jax.random.PRNGKey(11), i), (B, D),
+                jnp.float32) + carry
+            tokens, flags = form(
+                x.astype(jnp.bfloat16), head, bias_ids, bias_vals,
+                jnp.zeros((B,), jnp.int32) + i, min_toks, stop_ids, tied)
+            return tokens[0].astype(jnp.float32) * 0.0, (tokens, flags)
+
+        return jax.lax.scan(
+            body, jnp.float32(0), jnp.arange(loop, dtype=jnp.int32))[1]
+
+    return run
+
+
+def bench_greedy_head(shapes=HEAD_SHAPES, loop=64):
+    """A greedy decode step's tail (the head's product, the guard, the
+    edits, the argmax), us a step of the present three passes and of
+    each form tried, beside the two floors (the head's bytes at the
+    chip's bandwidth, the product at 197 TFLOP/s); each form's tokens
+    and flags against the present path's over `loop` steps of random
+    rows.  The drawing of a step's rows (B x D normals) is in every
+    form's time alike."""
+    for B, D, V, tied in shapes:
+        for ids in ("shared", "random"):
+            case = greedy_head_case(B, D, V, tied, ids)
+            line = {
+                "scope": "greedy_head", "rows": B, "width": D, "vocab": V,
+                "tied": tied, "ids": ids,
+                "bytes_floor_us": round(
+                    1e6 * V * D * 2 / HBM_BYTES_PER_S, 1),
+                "product_floor_us": round(1e6 * 2 * B * D * V / 197e12, 1),
+            }
+            want = None
+            for label, form in head_forms().items():
+                run = head_program(form, B, D, tied, loop)
+                try:
+                    got = jax.tree.map(np.asarray, run(*case))
+                except Exception as exc:  # noqa: BLE001: a form the chip refuses
+                    line[f"{label}_error"] = str(exc)[:200]
+                    continue
+                seconds = _median_time(run, *case, iters=5, loop=loop)
+                line[f"{label}_us"] = round(seconds * 1e6, 1)
+                if want is None:
+                    want = got
+                    continue
+                line[f"{label}_token_diff_pct"] = round(
+                    100 * float(np.mean(got[0] != want[0])), 4)
+                line[f"{label}_flag_diff_pct"] = round(
+                    100 * float(np.mean(got[1] != want[1])), 4)
+            yield line
 
 
 def bench_flash_prefill(B=8, S=1024, H=12, KV=2, hd=128):
@@ -1264,6 +1447,10 @@ def main() -> None:
         )
     if sys.argv[1:] == ["sample_edits"]:
         for line in bench_sample_edits():
+            print(json.dumps(line), flush=True)
+        return
+    if sys.argv[1:] == ["greedy_head"]:
+        for line in bench_greedy_head():
             print(json.dumps(line), flush=True)
         return
     if sys.argv[1:] == ["swa_prefill"]:

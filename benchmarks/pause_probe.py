@@ -12,7 +12,10 @@ fetch after the load held them, the growth inside the window of
 ``totals.pauses`` / ``delivery_gaps`` / ``gc`` / the hand-off's
 counters, the collector's callbacks a second, ``totals.moe`` and
 ``totals.dsa`` at the end (the expert layers' counters, ``overflow``
-among them; the learned selection's), and the
+among them; the learned selection's), the growth of ``decode_steps``
+beside ``decode_steps_fused_head`` (PR 50: the steps whose program kept
+its logits on the chip), after a traced run the device's seconds under
+the scopes ``head`` / ``logits`` / ``sample`` (``head_scopes``), and the
 server log's
 ``engine_pause`` lines (but the warm-up's, whose cause is ``compile``).
 ``--stall-at S`` arms the ``stall`` fault's ``delay`` once, S seconds
@@ -95,7 +98,26 @@ def probing(stall_at: Optional[float], stall_s: float) -> None:
         os.environ["VGT_FAULTS_HTTP"] = "1"
 
 
-def report(seconds: float) -> Dict[str, Any]:
+def head_scopes() -> Optional[Dict[str, Any]]:
+    """Seconds and share of busy time a traced run's device spent ending
+    its steps: the scopes ``head`` (the fused pass), ``logits`` and
+    ``sample`` (the head's array, the edits and the sampler; the prompt
+    programs' few rows among them), through the harness's own reading
+    of ``perfbench.trace_scopes`` (its ``scope_share`` reducer's)."""
+    from perfbench.reducers import scope_share
+
+    data = scope_share.summary({"trace": 1})
+    if not data or data["busy_s"] <= 0:
+        return None
+    seconds = {
+        scope: sum(s for name, s in data["scope_seconds"].items()
+                   if scope in name.split("/"))
+        for scope in ("head", "logits", "sample")}
+    return {"busy_s": data["busy_s"], "seconds": seconds,
+            "share_pct": 100 * sum(seconds.values()) / data["busy_s"]}
+
+
+def report(seconds: float, trace: int = 0) -> Dict[str, Any]:
     window = KEPT["window"]
     close = window.perf.get("close") or {}
     gc_n = grown(window, "gc.gc_collections")
@@ -113,9 +135,11 @@ def report(seconds: float) -> Dict[str, Any]:
                 "wall_s", "deliveries", "delivery_gap_s", "delivery_gaps",
                 "pauses", "gc.gc_s", "gc.gc_collections", "gc.gc_seconds",
                 "gateway.stream_handoffs", "gateway.handoff_wait_s",
-                "gateway.handoff_waits",
+                "gateway.handoff_waits", "decode_steps",
+                "decode_steps_fused_head",
             )
         },
+        "head_scopes": head_scopes() if trace else None,
         "gc_callbacks_per_s": (
             None if gc_n is None else sum(gc_n.values()) / seconds),
         "gc_max_s": dig(close, "totals.gc.gc_max_s"),
@@ -141,7 +165,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     except bench.BenchFailure as exc:
         print(f"pause_probe: FAILED: {exc}", file=sys.stderr, flush=True)
         return 1
-    lines = [json.dumps(result), json.dumps({"probe": report(args.seconds)})]
+    lines = [json.dumps(result),
+             json.dumps({"probe": report(args.seconds, args.trace)})]
     for line in lines:
         print(line, flush=True)
     if args.out:

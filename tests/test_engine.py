@@ -142,6 +142,39 @@ def test_decode_spans_and_stats_name_the_kv_write_path(engine, monkeypatch):
     ]
     assert dispatched
     assert {args["kv_write"] for args in dispatched} == {"scatter"}
+    # and what ends a step: without the kernel, the logits' array
+    assert {args["head"] for args in dispatched} == {"logits"}
+    totals = engine.perf.totals()
+    assert totals["decode_steps"] > 0 == totals["decode_steps_fused_head"]
+
+
+@pytest.mark.fast
+def test_fused_head_steps_are_booked_as_the_dispatch_judged(
+        engine, monkeypatch):
+    """The engine reads ``decode_head_impl`` once a dispatch, from the
+    variant's static facts, and books the chunk's steps at its readback
+    under that word (the rule itself: tests/test_greedy_head.py).  Here
+    its answer is forced for the engine's report alone, the program
+    being the CPU's either way."""
+    from vgate_tpu.runtime import engine_core
+
+    asked = []
+
+    def rule(params, spec, use_pallas, mesh=None, **facts):
+        asked.append(facts)
+        return "fused"
+
+    monkeypatch.setattr(engine_core, "decode_head_impl", rule)
+    before = engine.perf.totals()
+    engine.generate(["which head ends a step"], [greedy(6)])
+    after = engine.perf.totals()
+    steps = after["decode_steps"] - before["decode_steps"]
+    assert steps > 0
+    assert (after["decode_steps_fused_head"]
+            - before["decode_steps_fused_head"]) == steps
+    assert asked and all(
+        facts["all_greedy"] and facts["num_logprobs"] == 0
+        and not facts["penalised"] and facts["rows"] > 0 for facts in asked)
 
 
 def test_device_health(engine):

@@ -850,6 +850,32 @@ def test_chunk_lengths_are_whatever_the_engine_ran():
     assert merged["totals"]["chunks_by_steps"] == {"4": 1, "16": 3}
 
 
+def test_fused_head_steps_count_beside_the_decode_steps_and_merge():
+    """``totals.decode_steps_fused_head``: the steps of the chunks whose
+    program kept its logits on the chip, booked with ``decode_steps`` at
+    the tick's end (a scrape never sees one without the other); a
+    verify round or a chunk that needs the logits adds none."""
+    rec = recorder()
+    assert rec.snapshot()["totals"]["decode_steps_fused_head"] == 0
+    rec.tick_begin()
+    rec.note_decode(steps=8, ctx_tokens=10, device_s=0.01, fused_head=True)
+    rec.note_decode(steps=8, ctx_tokens=10, device_s=0.01)
+    rec.note_decode(steps=4, ctx_tokens=10, device_s=0.01, chunk=False)
+    mid = rec.totals()
+    assert mid["decode_steps"] == mid["decode_steps_fused_head"] == 0
+    rec.tick_end(worked=True)
+    totals = rec.snapshot()["totals"]
+    assert totals["decode_steps"] == 20
+    assert totals["decode_steps_fused_head"] == 8
+    merged = perf_mod.merge_snapshots([rec.snapshot(), rec.snapshot()])
+    assert merged["totals"]["decode_steps_fused_head"] == 16
+    # a replica of an older build carries no such key: it counts 0
+    old = rec.snapshot()
+    del old["totals"]["decode_steps_fused_head"]
+    merged = perf_mod.merge_snapshots([rec.snapshot(), old])
+    assert merged["totals"]["decode_steps_fused_head"] == 8
+
+
 def test_membership_changes_and_drains_are_counted_and_merge():
     """``totals.membership_changes`` counts the ticks that found the
     decode batch's membership changed and ``totals.pipeline_drains`` those

@@ -450,31 +450,58 @@ def test_decode_chunk_holds_one_pool_on_v5e(v5e):
     assert not scatters, scatters[0][:300]
 
 
+def _assert_no_logits_array(text, rows, vocab):
+    """The fused head's program: no float32 logits as a buffer, in the
+    head's layout or flat, and the pass itself in it."""
+    assert f"f32[{rows},{vocab}]" not in text
+    assert f"f32[{rows * vocab}]" not in text
+    assert "greedy_head" in text
+
+
 def test_decode_chunk_edits_logits_in_place_on_v5e(v5e):
     """The variant the benchmark's cells run (256 slots, 8 steps, a
     `min_tokens` floor over 2 stop ids, a 16-wide `logit_bias`, argmax,
-    the guard): `logit_bias` and the floor are selects fused into a pass
-    that reads the float32 logits anyway (ops/sampling.py).  As scatters
-    they cost the 155 MB array a flat relayout and back, a copy and an
-    update every step: 1.8 ms of a 9.5 ms step (PERF.md, PR 34)."""
+    the guard) keeps a step's logits on the chip: the head's product,
+    the guard, the edits and the argmax are one pass over vocabulary
+    tiles (ops/pallas/greedy_head.py) and NO `f32[256, 151936]` stands
+    in the program: 156 MB written once and read twice a step, 1.59 ms
+    of a 14.7 / 7.3 ms step (PERF.md, PR 50).  A variant that needs the
+    array (a row wants logprobs) keeps it, and there `logit_bias` and
+    the floor stay selects fused into a pass that reads it anyway
+    (ops/sampling.py): as scatters they cost the array a flat relayout
+    and back, a copy and an update every step: 1.8 ms of a 9.5 ms step
+    (PERF.md, PR 34)."""
     from vgate_tpu.runtime.step_programs import _decode_chunk
 
     A = _abstract(v5e)
     spec, params, pool, _ = _qwen_1p5b(A)
     B, ctx = 256, 2048
-    text = _decode_chunk.lower(
-        params, spec, A((B,), jnp.int32), A((B,), jnp.int32), pool, pool,
-        A((B, ctx // PAGE), jnp.int32), A((B,), jnp.bool_),
-        A((B,), jnp.float32), A((B,), jnp.float32), A((B,), jnp.int32),
-        A((2,), jnp.uint32), A((), jnp.uint32),
-        num_steps=8, use_pallas=True, max_position=ctx - 1,
-        seeds=A((B,), jnp.int32), steps=A((B,), jnp.int32),
-        min_toks=A((B,), jnp.int32), stop_id_mat=A((B, 2), jnp.int32),
-        bias_ids=A((B, 16), jnp.int32), bias_vals=A((B, 16), jnp.float32),
-        all_greedy=True, guard=True,
-    ).compile().as_text()
+
+    def compiled(**variant):
+        return _decode_chunk.lower(
+            params, spec, A((B,), jnp.int32), A((B,), jnp.int32), pool, pool,
+            A((B, ctx // PAGE), jnp.int32), A((B,), jnp.bool_),
+            A((B,), jnp.float32), A((B,), jnp.float32), A((B,), jnp.int32),
+            A((2,), jnp.uint32), A((), jnp.uint32),
+            num_steps=8, use_pallas=True, max_position=ctx - 1,
+            seeds=A((B,), jnp.int32), steps=A((B,), jnp.int32),
+            min_toks=A((B,), jnp.int32), stop_id_mat=A((B, 2), jnp.int32),
+            bias_ids=A((B, 16), jnp.int32),
+            bias_vals=A((B, 16), jnp.float32), guard=True, **variant,
+        ).compile()
+
+    cells = compiled(all_greedy=True)
+    _assert_no_logits_array(cells.as_text(), B, spec.vocab_size)
+    wants_logprobs = compiled(num_logprobs=8)
+    fell = (wants_logprobs.memory_analysis().temp_size_in_bytes
+            - cells.memory_analysis().temp_size_in_bytes)
+    print("decode chunk temporaries fell by", fell)
+    assert fell >= B * spec.vocab_size * 4, "the array's bytes stayed"
+
+    text = wants_logprobs.as_text()
     logits = f"f32[{B},{spec.vocab_size}]"
     assert logits in text  # the lm-head's output is what is looked for
+    assert "greedy_head" not in text
     assert f"f32[{B * spec.vocab_size}]" not in text, (
         "the logits are re-laid flat: an edit is a scatter again"
     )
@@ -1283,6 +1310,8 @@ def test_lfm2_decode_chunk_compiles_on_v5e(lfm2_cut):
     text = compiled.as_text()
     assert "paged_decode_attention_pallas" in text
     assert "moe_grouped_matmul_pallas" in text
+    # the greedy chunk's 67 MB of logits stay on the chip here too
+    _assert_no_logits_array(text, B, spec.vocab_size)
     # no copy of a period's mixers' matrices (the walker's scans carry
     # indices): before PR 48 these four a period, nine periods a step,
     # were 2.28 ms of the cell's 23.6 ms step
@@ -1329,6 +1358,10 @@ def test_qwen3_next_decode_chunk_reads_its_matrices_in_place_on_v5e(v5e):
     text = compiled.as_text()
     assert "gated_delta_step_pallas" in text
     assert "moe_grouped_matmul_pallas" in text
+    # the cut's untied head is `[2048, 37984]`, 296.75 lane groups wide:
+    # XLA would re-lay it for the fused head's kernel, 155 MB a step, so
+    # the shape rule (ops/pallas/greedy_head.py) keeps the three passes
+    assert "greedy_head" not in text
     _assert_no_copy_of(text, (3, 1, 2048, 12288), (3, 1, 4096, 2048),
                        (3, 1, 2048, 512), (3, 1, 512, 2048))
 

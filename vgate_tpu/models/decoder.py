@@ -46,7 +46,7 @@ from vgate_tpu.ops.kv_quant import (
 )
 from vgate_tpu.ops.head_pack import over_packed_pool, pack_rows
 from vgate_tpu.ops.norms import rms_norm
-from vgate_tpu.ops.quant import weighted_einsum
+from vgate_tpu.ops.quant import PackedQTensor, QTensor, weighted_einsum
 from vgate_tpu.ops.rope import apply_rope
 
 Params = Dict[str, Any]
@@ -186,6 +186,15 @@ def _mlp(x, lp, spec: ModelSpec):
     return expert_layer(x, lp, spec, lambda x32: _act(x32, spec))[0]
 
 
+def _final_norm(params: Params, spec: ModelSpec, x: jnp.ndarray):
+    x = rms_norm(
+        x, params["final_norm"], spec.rms_eps, spec.unit_offset_norm
+    )
+    if spec.fp32_residual:  # the product takes the weights' type
+        x = x.astype(params["final_norm"].dtype)
+    return x
+
+
 @jax.named_scope("logits")
 def _logits(params: Params, spec: ModelSpec, x: jnp.ndarray,
             all_heads: bool = False) -> jnp.ndarray:
@@ -195,11 +204,7 @@ def _logits(params: Params, spec: ModelSpec, x: jnp.ndarray,
     head's, ``[..., heads x vocab]``."""
     from vgate_tpu.ops.attention import _softcap
 
-    x = rms_norm(
-        x, params["final_norm"], spec.rms_eps, spec.unit_offset_norm
-    )
-    if spec.fp32_residual:  # the product takes the weights' type
-        x = x.astype(params["final_norm"].dtype)
+    x = _final_norm(params, spec, x)
     if spec.num_pred_heads > 1 and not all_heads:
         params = {**params,
                   "lm_head": params["lm_head"][:, :spec.vocab_size]}
@@ -220,6 +225,58 @@ def _logits(params: Params, spec: ModelSpec, x: jnp.ndarray,
             quant_kernel=spec.quant_kernel,
         )
     return _softcap(logits, spec.final_softcap)
+
+
+def decode_head_impl(
+    params: Params, spec: ModelSpec, use_pallas: bool, mesh=None, *,
+    rows: int, all_greedy: bool = False, num_logprobs: int = 0,
+    penalised: bool = False, bias_width: int = 0, stop_width: int = 0,
+) -> str:
+    """What ends a decode chunk's step, for these static arguments:
+    ``"fused"``, ``greedy_head`` below (the step's logits stay on the
+    chip: a token and a flag word a row come out), or ``"logits"``,
+    ``_logits`` and what the chunk does with the array.  The fused pass
+    serves a chunk whose rows need nothing else of the logits: every
+    row greedy and none asking for logprobs, no penalties, edits narrow
+    enough to be compares, no soft cap, an unquantised head on one
+    device.  ``_decode_chunk`` selects through this and the engine
+    reports it (the decode-dispatch spans' ``head``, /debug/perf ->
+    totals.decode_steps_fused_head)."""
+    from vgate_tpu.ops.pallas.greedy_head import worth_fusing
+    from vgate_tpu.ops.sampling import COMPARE_MAX_IDS
+
+    head = params["embed" if spec.tie_embeddings else "lm_head"]
+    fused = (
+        use_pallas
+        and all_greedy and num_logprobs == 0 and not penalised
+        and max(bias_width, stop_width) <= COMPARE_MAX_IDS
+        and spec.final_softcap == 0
+        and (mesh is None or mesh.size == 1)
+        and not isinstance(head, (QTensor, PackedQTensor))
+        and worth_fusing(rows, spec.vocab_size, head.shape)
+    )
+    return "fused" if fused else "logits"
+
+
+@jax.named_scope("head")
+def greedy_head(params: Params, spec: ModelSpec, x: jnp.ndarray,
+                bias_ids, bias_vals, stop_ids, guard: bool,
+                guard_threshold: float):
+    """The final norm and the output layer of a greedy step whose
+    logits nobody reads (``decode_head_impl``): ``(next_tokens [B]
+    int32, guard flags [B] uint8)`` of ``_logits``' array under the
+    ``logit_bias`` and the live stop ids' floor, the array itself never
+    in HBM (ops/pallas/greedy_head.py).  A spec with several prediction
+    heads serves head 0's ``vocab_size`` columns where they stand."""
+    from vgate_tpu.ops.pallas.greedy_head import greedy_head_pallas
+
+    tied = spec.tie_embeddings
+    return greedy_head_pallas(
+        _final_norm(params, spec, x),
+        params["embed" if tied else "lm_head"],
+        bias_ids, bias_vals, stop_ids, tied=tied, vocab=spec.vocab_size,
+        guard=guard, threshold=guard_threshold,
+    )
 
 
 def _query_scale(spec: ModelSpec):
@@ -942,17 +999,22 @@ def decode_forward(
     mesh=None,  # pp>1 routes through the pipeline-parallel stage relay
     state=None,  # hybrid specs: the recurrent state, row = slot
     all_heads: bool = False,  # every prediction head's logits (_logits)
+    head=None,  # what ends the step instead of _logits (greedy_head)
 ) -> Tuple[jnp.ndarray, ...]:
     """One continuous-batching decode step: returns (logits [B, V],
     caches); a hybrid spec adds the recurrent state and the expert
-    layers' counters (ops/moe.py STAT_NAMES)."""
+    layers' counters (ops/moe.py STAT_NAMES).  ``head(params, spec,
+    x)`` takes the last layer's rows ``[B, D]`` in ``_logits``' place,
+    and its result the logits' place in what comes back."""
+    if head is None:
+        head = functools.partial(_logits, all_heads=all_heads)
     impl = decode_attention_impl(spec, use_pallas, mesh)
     if impl == "pp_relay":
         from vgate_tpu.parallel.pipeline import pp_decode_forward
 
         return pp_decode_forward(
             params, spec, tokens, positions, k_pages, v_pages, page_tables,
-            active=active, mesh=mesh, use_pallas=use_pallas,
+            active=active, mesh=mesh, use_pallas=use_pallas, head=head,
         )
     sp_mesh = mesh if impl == "sp_shard" else None
     if sp_mesh is not None:
@@ -980,7 +1042,7 @@ def decode_forward(
         x, (k_pages, v_pages) = jax.lax.scan(
             sp_layer_fn, x, (params["layers"], windows, k_pages, v_pages)
         )
-        return _logits(params, spec, x), k_pages, v_pages
+        return head(params, spec, x), k_pages, v_pages
     if impl == "jnp":
         attn_fn = functools.partial(
             paged_decode_attention,
@@ -1113,8 +1175,7 @@ def decode_forward(
             write_attend, use_pallas, ring_write_attend=ring_step,
             dsa_steps=dsa_steps, eva_summarize=eva_step,
         )
-        return (_logits(params, spec, x, all_heads), k_pages, v_pages,
-                state, stats)
+        return head(params, spec, x), k_pages, v_pages, state, stats
 
     # the FULL [L, ...] pools ride the scan carry with layer-indexed
     # in-place updates, and attention reads the pool at layer l directly
@@ -1130,7 +1191,7 @@ def decode_forward(
     x, k_pages, v_pages = _kv_layer_scan(
         params, spec, body, x, k_pages, v_pages
     )
-    return _logits(params, spec, x, all_heads), k_pages, v_pages
+    return head(params, spec, x), k_pages, v_pages
 
 
 def prefill_suffix_forward(

@@ -585,7 +585,7 @@ class TickProfile:
     __slots__ = (
         "t", "wall", "host", "schedule", "state", "dispatch", "device",
         "readback", "detok", "tokens", "decode_steps", "decode_bytes",
-        "clock_decode_s", "cpu0", "cpu_in_wait",
+        "fused_head_steps", "clock_decode_s", "cpu0", "cpu_in_wait",
     )
 
     def __init__(self, t: float, clock_decode_s: float) -> None:
@@ -600,6 +600,7 @@ class TickProfile:
         self.detok = 0.0
         self.tokens = 0
         self.decode_steps = 0
+        self.fused_head_steps = 0
         self.decode_bytes = 0
         # the device clock's running decode seconds at tick begin: the
         # window's decode seconds are the growth since its oldest tick
@@ -664,6 +665,9 @@ class PerfRecorder:
         self.total_idle_ticks = 0
         self.total_tokens = 0
         self.total_decode_steps = 0
+        # those of them whose program kept the step's logits on the
+        # chip (models/decoder.py decode_head_impl == "fused")
+        self.total_decode_steps_fused_head = 0
         self.total_wall_s = 0.0
         self.total_compile_s = 0.0
         self._phase_totals = {name: 0.0 for name in PHASES}
@@ -793,7 +797,7 @@ class PerfRecorder:
 
     def note_decode(
         self, steps: int, ctx_tokens: int, device_s: float,
-        chunk: bool = True,
+        chunk: bool = True, fused_head: bool = False,
     ) -> None:
         """One decode-chunk (or spec-verify, ``chunk=False``) readback:
         ``steps`` fused steps over sequences holding ``ctx_tokens``
@@ -801,11 +805,14 @@ class PerfRecorder:
         wait for it (``totals.decode_device_s``; the DEVICE's seconds
         are the device clock's) — feeds the modeled HBM traffic of the
         roofline gauge, and the chunk-length / live-context window
-        counters."""
+        counters.  ``fused_head``: the chunk's program kept each step's
+        logits on the chip (``totals.decode_steps_fused_head``)."""
         cur = self._cur
         if cur is None:
             return
         cur.decode_steps += steps
+        if fused_head:
+            cur.fused_head_steps += steps
         self.total_decode_device_s += device_s
         self.total_decode_ctx_token_steps += steps * ctx_tokens
         if chunk:
@@ -1245,6 +1252,7 @@ class PerfRecorder:
         self.total_ticks += 1
         self.total_tokens += cur.tokens
         self.total_decode_steps += cur.decode_steps
+        self.total_decode_steps_fused_head += cur.fused_head_steps
         self.total_wall_s += cur.wall
         # one thread_time per worked tick: wall - device - readback -
         # (cpu - cpu_in_wait) is the time the engine thread neither ran
@@ -1416,6 +1424,7 @@ class PerfRecorder:
             "idle_ticks": self.total_idle_ticks,
             "tokens": self.total_tokens,
             "decode_steps": self.total_decode_steps,
+            "decode_steps_fused_head": self.total_decode_steps_fused_head,
             "wall_s": round(self.total_wall_s, 6),
             "phase_seconds": {
                 k: round(v, 6) for k, v in self._phase_totals.items()
@@ -1789,6 +1798,8 @@ def merge_snapshots(
         "idle_ticks": sum(t["idle_ticks"] for t in totals),
         "tokens": sum(t["tokens"] for t in totals),
         "decode_steps": sum(t["decode_steps"] for t in totals),
+        "decode_steps_fused_head": sum(
+            t.get("decode_steps_fused_head", 0) for t in totals),
         "wall_s": round(sum(t["wall_s"] for t in totals), 6),
         "phase_seconds": {
             name: round(

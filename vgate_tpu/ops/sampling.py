@@ -368,16 +368,14 @@ def apply_logit_bias(
     return edit(logits.astype(jnp.float32), bias_ids, bias_vals)
 
 
-def _live_stop_ids(logits, steps, min_tokens, stop_ids):
+def live_stop_ids(vocab: int, steps, min_tokens, stop_ids):
     """The rows' stop ids, those of a row at or above its floor turned
     into padding ([B, K] work): the logits' pass then only matches ids."""
-    return jnp.where(
-        (steps < min_tokens)[:, None], stop_ids, logits.shape[-1]
-    )
+    return jnp.where((steps < min_tokens)[:, None], stop_ids, vocab)
 
 
 def _floor_by_compare(logits, steps, min_tokens, stop_ids):
-    stop_ids = _live_stop_ids(logits, steps, min_tokens, stop_ids)
+    stop_ids = live_stop_ids(logits.shape[-1], steps, min_tokens, stop_ids)
     iota = _vocab_iota(logits)
     hit = iota == stop_ids[:, 0, None]
     for k in range(1, stop_ids.shape[-1]):
@@ -386,7 +384,7 @@ def _floor_by_compare(logits, steps, min_tokens, stop_ids):
 
 
 def _floor_by_scatter(logits, steps, min_tokens, stop_ids):
-    stop_ids = _live_stop_ids(logits, steps, min_tokens, stop_ids)
+    stop_ids = live_stop_ids(logits.shape[-1], steps, min_tokens, stop_ids)
     return logits.at[_row_index(stop_ids), stop_ids].set(
         -1e30, mode="drop"
     )

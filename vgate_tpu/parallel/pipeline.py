@@ -186,6 +186,7 @@ def pp_decode_forward(
     active: Optional[jnp.ndarray] = None,
     mesh=None,
     use_pallas: bool = False,
+    head=_logits,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One decode step through the pipeline; same contract as
     models/decoder.py decode_forward."""
@@ -216,7 +217,7 @@ def pp_decode_forward(
         seq_lens.reshape(M, mb),
     )
     hidden = out.reshape(B, D)
-    return _logits(params, spec, hidden), k_pages, v_pages
+    return head(params, spec, hidden), k_pages, v_pages
 
 
 @functools.lru_cache(maxsize=32)

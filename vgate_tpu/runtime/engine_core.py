@@ -66,6 +66,7 @@ from vgate_tpu.logging_config import bound_request, get_logger
 from vgate_tpu.models.decoder import (
     WHOLE_BUCKET_IMPLS,
     decode_attention_impl,
+    decode_head_impl,
     decode_kv_write,
     multitok_attention_impl,
     packed_group,
@@ -3465,6 +3466,18 @@ class EngineCore:
             self.spec, self.use_pallas, self._attn_mesh, self._kv_quant
         )
 
+    def _decode_head(self, variant: tuple, rows: int) -> str:
+        """What ends a step of the decode chunk this variant compiles,
+        as ``_decode_chunk`` traces it: "fused" (the logits stay on the
+        chip) or "logits"."""
+        penalised, mt_width, num_lp, all_greedy, lb_width = variant
+        return decode_head_impl(
+            self.params, self.spec, self.use_pallas, self._attn_mesh,
+            rows=rows, all_greedy=all_greedy, num_logprobs=num_lp,
+            penalised=penalised, bias_width=lb_width or 0,
+            stop_width=mt_width or 0,
+        )
+
     @property
     def _dsa_fetch_chunk(self) -> int:
         """Picks a loop trip of the decode kernel under a selection
@@ -3485,6 +3498,8 @@ class EngineCore:
         guard = (
             self.integrity is not None and self.integrity.guard_enabled
         )
+        head = self._decode_head(
+            state["variant"], int(state["tokens"].shape[0]))
         with self._launch(
             "decode", chunk_key, active,
             ("decode", lambda: decode_attention_impl(
@@ -3496,6 +3511,9 @@ class EngineCore:
                 # steps in flight that ctx_tokens does not hold yet
                 "lead": sum(c[1] for c in self._pending_chunks),
                 "kv_write": self._decode_kv_write(),
+                # what ends a step: "fused", its logits never an array
+                # in HBM (models/decoder.py greedy_head), or "logits"
+                "head": head,
                 # latent rows a step attends to under a learned
                 # selection, over the rows (a layer's; at dispatch)
                 **({"sel_rows": sum(
@@ -3567,7 +3585,7 @@ class EngineCore:
         # readback is processed) must NOT receive the stale tokens
         self._pending_chunks.append(
             ([(s, s.preempt_count) for s in active], chunk, chunk_tokens,
-             chunk_lp, chunk_flags, moe_stats)
+             chunk_lp, chunk_flags, moe_stats, head == "fused")
         )
 
     @engine_thread_only
@@ -3576,9 +3594,8 @@ class EngineCore:
         host state: append tokens in order, detect EOS/length stops, discard
         steps past a stop."""
         while self._pending_chunks:
-            seqs, chunk, tokens_dev, lp_dev, flags_dev, moe_dev = (
-                self._pending_chunks.pop(0)
-            )
+            (seqs, chunk, tokens_dev, lp_dev, flags_dev, moe_dev,
+             fused_head) = self._pending_chunks.pop(0)
             # observe only the host-blocking readback time (kind="decode"):
             # dispatch-to-now would double-count deliberate pipeline
             # queueing when more than one chunk is in flight
@@ -3642,7 +3659,7 @@ class EngineCore:
                 self.perf.note_decode(
                     steps=chunk,
                     ctx_tokens=sum(s.total_len for s, _ in seqs),
-                    device_s=device_s,
+                    device_s=device_s, fused_head=fused_head,
                 )
             if self.integrity is not None and flags_dev is not None:
                 # the flags readback + fault hooks stay OUTSIDE the

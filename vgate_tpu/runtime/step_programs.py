@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from vgate_tpu import integrity
 from vgate_tpu.models.decoder import (
     decode_forward,
+    decode_head_impl,
+    greedy_head,
     prefill_forward,
     prefill_suffix_forward,
     spec_verify_forward,
@@ -40,6 +42,7 @@ from vgate_tpu.ops.kv_quant import copy_page_prefix
 from vgate_tpu.ops.sampling import (
     apply_logit_bias,
     apply_penalties,
+    live_stop_ids,
     sample_tokens,
     sample_tokens_with_logprobs,
     suppress_stop_tokens,
@@ -235,49 +238,73 @@ def _decode_chunk(
     ``[num_steps, 5]`` int32, the expert layers' device counters summed
     over the layers of each step (ops/moe.py STAT_NAMES), read back
     with the chunk's tokens.
+
+    A chunk whose rows need nothing of a step's logits but the token
+    and the guard's flags (models/decoder.py ``decode_head_impl``, read
+    from these static arguments) never holds them as an array: the
+    head's product, the guard, the two edits and the argmax are one pass
+    (``greedy_head``) with the same tokens and flags.
     """
 
     if steps is None:
         steps = jnp.zeros_like(positions)
+    fused_head = decode_head_impl(
+        params, spec, use_pallas, mesh, rows=tokens.shape[0],
+        all_greedy=all_greedy, num_logprobs=num_logprobs,
+        penalised=counts is not None,
+        bias_width=0 if bias_ids is None else bias_ids.shape[-1],
+        stop_width=0 if min_toks is None else stop_id_mat.shape[-1],
+    ) == "fused"
+
+    @jax.named_scope("sample")
+    def sample(logits, key, steps, counts):
+        if counts is not None:
+            # frequency/presence penalties over the generated-token
+            # histogram (ops/sampling.py apply_penalties)
+            logits = apply_penalties(logits, counts, freq_pens, pres_pens)
+        if bias_ids is not None:
+            logits = apply_logit_bias(logits, bias_ids, bias_vals)
+        if min_toks is not None:
+            logits = suppress_stop_tokens(
+                logits, steps, min_toks, stop_id_mat
+            )
+        if num_logprobs > 0:
+            return sample_tokens_with_logprobs(
+                logits, temps, top_ps, top_ks, key, seeds=seeds,
+                steps=steps, num_top=num_logprobs,
+            )
+        return (sample_tokens(
+            logits, temps, top_ps, top_ks, key, seeds=seeds,
+            steps=steps, all_greedy=all_greedy,
+        ),)
 
     def body(carry, _):
         (tokens, positions, counter, steps, counts, k_pages, v_pages,
          state) = carry
         key = jax.random.fold_in(base_key, counter)
+        head = None
+        if fused_head:
+            head = functools.partial(
+                greedy_head, bias_ids=bias_ids, bias_vals=bias_vals,
+                stop_ids=None if min_toks is None else live_stop_ids(
+                    spec.vocab_size, steps, min_toks, stop_id_mat),
+                guard=guard, guard_threshold=guard_threshold,
+            )
         logits, k_pages, v_pages, *more = decode_forward(
             params, spec, tokens, positions, k_pages, v_pages, page_tables,
-            active=active, use_pallas=use_pallas, mesh=mesh,
+            active=active, use_pallas=use_pallas, mesh=mesh, head=head,
             **_state_kw(state),
         )
         if more:
             state, moe_stats = more
-        if guard:
-            step_flags = integrity.logit_guard(logits, guard_threshold)
-        with jax.named_scope("sample"):
-            if counts is not None:
-                # frequency/presence penalties over the generated-token
-                # histogram (ops/sampling.py apply_penalties)
-                logits = apply_penalties(
-                    logits, counts, freq_pens, pres_pens
-                )
-            if bias_ids is not None:
-                logits = apply_logit_bias(logits, bias_ids, bias_vals)
-            if min_toks is not None:
-                logits = suppress_stop_tokens(
-                    logits, steps, min_toks, stop_id_mat
-                )
-            if num_logprobs > 0:
-                next_tokens, lp, tids, tlps = sample_tokens_with_logprobs(
-                    logits, temps, top_ps, top_ks, key, seeds=seeds,
-                    steps=steps, num_top=num_logprobs,
-                )
-                ys = (next_tokens, lp, tids, tlps)
-            else:
-                next_tokens = sample_tokens(
-                    logits, temps, top_ps, top_ks, key, seeds=seeds,
-                    steps=steps, all_greedy=all_greedy,
-                )
-                ys = (next_tokens,)
+        if fused_head:
+            next_tokens, step_flags = logits
+            ys = (next_tokens,)
+        else:
+            if guard:
+                step_flags = integrity.logit_guard(logits, guard_threshold)
+            ys = sample(logits, key, steps, counts)
+            next_tokens = ys[0]
         if guard:
             ys = ys + (step_flags,)
         if more:
