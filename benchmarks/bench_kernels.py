@@ -14,6 +14,7 @@ blockwise twin).  Run on hardware:
     python benchmarks/bench_kernels.py dsa_attend dsa_attend_64k
     python benchmarks/bench_kernels.py eva_decode [PAGESxITEMS ...]
     python benchmarks/bench_kernels.py dense_prompt [CONFIG ...]
+    python benchmarks/bench_kernels.py ssd_step [HEADS ...]
 
 Prints one JSON line per (kernel, shape) with median step times and the
 speedup.  CPU-safe fallback: refuses to run (the kernels need a TPU).
@@ -1438,6 +1439,79 @@ def bench_eva_decode(forced=(), B=20, H=32, hd=128, ps=32, window=2048,
         yield line
 
 
+# the Mamba-2 step's served shapes: (slots, heads, groups, layers held)
+SSD_STEP_SHAPES = {
+    "granite-4.0-h-micro": (80, 64, 1, 12),
+    "nemotron-3-super-120b-a12b-l11e128": (192, 128, 8, 5),
+}
+
+
+def bench_ssd_step(blocks=(), P=64, N=128, loop=8):
+    """The Mamba-2 step (``ops/ssd.py ssd_step`` as a decode step calls
+    it: the scalars folded in by XLA, then the kernel) at the two served
+    shapes, by the kernel's heads a program: the rule's own
+    (``ops/pallas/ssd.py block_heads``) and each of ``blocks`` that the
+    shape takes (default 16, 32, 64).  A program of ``loop`` steps walks
+    the held layers in turn, as a decode chunk does; microseconds a
+    launch, GB/s over the float32 tiles it has to read and write, and
+    that as a share of the chip's 819 GB/s; each form's result against
+    the rule's."""
+    from vgate_tpu.ops import ssd
+    from vgate_tpu.ops.pallas.ssd import block_heads
+
+    peak = 819e9
+    for name, (B, H, G, L) in SSD_STEP_SHAPES.items():
+        ks = jax.random.split(jax.random.PRNGKey(0), 6)
+        x = jax.random.normal(ks[0], (B, H, P), jnp.bfloat16)
+        dt = jnp.exp(jax.random.uniform(
+            ks[1], (B, H), minval=jnp.log(1e-3), maxval=jnp.log(0.1)))
+        A = -jax.random.uniform(ks[2], (H,), minval=1.0, maxval=16.0)
+        Bm = jax.random.normal(ks[3], (B, G, N), jnp.bfloat16)
+        Cm = jax.random.normal(ks[4], (B, G, N), jnp.bfloat16)
+        rule = block_heads(H, G)
+        per_group = H // G
+        forms = [rule] + [b for b in (blocks or (16, 32, 64))
+                          if b != rule and H % b == 0
+                          and (b % per_group == 0 or per_group % b == 0)]
+        first = None
+        for hb in forms:
+            @functools.partial(jax.jit, donate_argnums=(0,))
+            def run(state, x, hb=hb):
+                def step(carry, i):
+                    state, acc = carry
+                    y, state = ssd.ssd_step(
+                        x + 0 * acc.astype(x.dtype), dt, A, Bm, Cm, state,
+                        i % L, use_pallas=True, block=hb)
+                    return (state, y), None
+
+                (state, y), _ = jax.lax.scan(
+                    step, (state, jnp.zeros((B, H, P), jnp.float32)),
+                    jnp.arange(loop * L, dtype=jnp.int32))
+                return state, y
+
+            state = jax.random.normal(ks[5], (L, B, H, P, N)) * 0.1
+            state, y = _sync(run(state, x))
+            if first is None:
+                first = np.asarray(y)
+            err = float(np.abs(np.asarray(y) - first).max())
+            times = []
+            for _ in range(5):
+                t0 = time.perf_counter()
+                state, y = _sync(run(state, x))
+                times.append(time.perf_counter() - t0)
+            t = float(np.median(times)) / (loop * L)
+            moved = 2 * B * H * P * N * 4
+            yield {
+                "probe": "ssd_step", "shape": name, "slots": B, "heads": H,
+                "groups": G, "block_heads": hb, "rule": hb == rule,
+                "grid": [B, H // hb], "us_per_launch": round(t * 1e6, 1),
+                "gb_per_s": round(moved / t / 1e9, 1),
+                "roofline_pct": round(100 * moved / t / peak, 1),
+                "max_abs_vs_rule": err,
+            }
+            del state
+
+
 def main() -> None:
     device = jax.devices()[0]
     if device.platform != "tpu":
@@ -1461,6 +1535,10 @@ def main() -> None:
         # eva_decode [PAGESxITEMS ...]: forced sizes beside the rule's
         forced = [tuple(map(int, a.split("x"))) for a in sys.argv[2:]]
         for line in bench_eva_decode(forced):
+            print(json.dumps(line), flush=True)
+        return
+    if sys.argv[1:2] == ["ssd_step"]:
+        for line in bench_ssd_step(tuple(map(int, sys.argv[2:]))):
             print(json.dumps(line), flush=True)
         return
     if sys.argv[1:2] == ["dense_prompt"]:

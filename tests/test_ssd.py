@@ -62,8 +62,19 @@ def test_padded_positions_do_not_move_the_state():
     np.testing.assert_allclose(padded[1:], exact, atol=5e-6)
 
 
-@pytest.mark.parametrize("H, P, G, N", [(4, 16, 2, 128), (128, 64, 8, 128)])
-def test_step_kernel_equals_its_twin_in_interpret_mode(H, P, G, N):
+# (heads, head size, groups, state size, heads a program: 0 = the rule's)
+STEP_CASES = [
+    (4, 16, 2, 128, 0), (128, 64, 8, 128, 0),  # Nemotron-H: 4 groups of 16
+    # Granite 4.0-H: ONE group of 64 heads, each blocking inside it
+    (64, 64, 1, 128, 0), (64, 64, 1, 128, 16), (64, 64, 1, 128, 32),
+    (64, 64, 1, 128, 64),
+    # Nemotron-H's at one group and at two a program
+    (128, 64, 8, 128, 16), (128, 64, 8, 128, 32),
+]
+
+
+@pytest.mark.parametrize("H, P, G, N, block", STEP_CASES)
+def test_step_kernel_equals_its_twin_in_interpret_mode(H, P, G, N, block):
     B = 3
     x, dt, A, Bm, Cm, S0 = case(B=B, S=1, H=H, P=P, G=G, N=N, seed=1)
     x, dt, Bm, Cm = x[:, 0], dt[:, 0], Bm[:, 0], Cm[:, 0]
@@ -72,7 +83,7 @@ def test_step_kernel_equals_its_twin_in_interpret_mode(H, P, G, N):
     layer = jnp.int32(1)
     y_t, s_t = ssd.ssd_step(x, dt, A, Bm, Cm, stack(), layer)
     y_k, s_k = ssd.ssd_step(x, dt, A, Bm, Cm, stack(), layer,
-                            interpret=True)
+                            interpret=True, block=block)
     np.testing.assert_allclose(y_k, y_t, atol=1e-5)
     np.testing.assert_allclose(s_k, s_t, atol=1e-6)
     # the other layers, and the idle row, bit for bit
@@ -80,6 +91,23 @@ def test_step_kernel_equals_its_twin_in_interpret_mode(H, P, G, N):
         np.testing.assert_array_equal(got[0], S0)
         np.testing.assert_array_equal(got[2], 3.0 * S0)
         np.testing.assert_array_equal(got[1, 1], 2.0 * S0[1])
+
+
+@pytest.mark.parametrize("H, G, want", [
+    (128, 8, 64),  # Nemotron-H: four groups of 16
+    (64, 1, 64),  # Granite 4.0-H: its one group
+    (4, 2, 4), (4, 1, 4),  # the tiny presets: everything
+    (48, 3, 48), (80, 5, 16),  # whole groups that divide the groups
+    (96, 1, 48), (80, 1, 40),  # a part that divides the group
+])
+def test_the_step_kernels_block_is_whole_groups_or_a_part_of_one(
+        H, G, want):
+    from vgate_tpu.ops.pallas.ssd import block_heads
+
+    hb = block_heads(H, G)
+    assert hb == want
+    per_group = H // G
+    assert H % hb == 0 and (hb % per_group == 0 or per_group % hb == 0)
 
 
 def test_one_step_equals_one_token_of_the_recurrence():

@@ -819,6 +819,73 @@ FROZEN = {
         "indexer": "",
         "mlp": "densex2 sparsex38",
     },
+    "ibm-granite/granite-4.0-h-micro": {
+        "cut": (0, 10, 4),
+        "lead": "",
+        "period": (
+            "mamba/mamba/input_norm/0 mlp/mamba/post_norm/0 "
+            "mamba/mamba/input_norm/1 mlp/mamba/post_norm/1 "
+            "mamba/mamba/input_norm/2 mlp/mamba/post_norm/2 "
+            "mamba/mamba/input_norm/3 mlp/mamba/post_norm/3 "
+            "mamba/mamba/input_norm/4 mlp/mamba/post_norm/4 "
+            "attn/attn/input_norm/0 mlp/attn/post_norm/0 "
+            "mamba/mamba/input_norm/5 mlp/mamba/post_norm/5 "
+            "mamba/mamba/input_norm/6 mlp/mamba/post_norm/6 "
+            "mamba/mamba/input_norm/7 mlp/mamba/post_norm/7 "
+            "mamba/mamba/input_norm/8 mlp/mamba/post_norm/8"
+        ),
+        "layers": (4, 0, 36, 0, 0),
+        "kv_pools": 2,
+        "windows": "0x40",
+        "num_params": 3191396096,
+        "hybrid": True,
+        "recurrent": "mamba",
+        "indexer": "",
+        "mlp": "densex40",
+    },
+    "tiny-granite-hybrid": {
+        "cut": (0, 5, 1),
+        "lead": "",
+        "period": (
+            "mamba/mamba/input_norm/0 mlp/mamba/post_norm/0 "
+            "mamba/mamba/input_norm/1 mlp/mamba/post_norm/1 "
+            "mamba/mamba/input_norm/2 mlp/mamba/post_norm/2 "
+            "attn/attn/input_norm/0 mlp/attn/post_norm/0 "
+            "mamba/mamba/input_norm/3 mlp/mamba/post_norm/3"
+        ),
+        "layers": (1, 0, 4, 0, 0),
+        "kv_pools": 2,
+        "windows": "0x5",
+        "num_params": 229232,
+        "hybrid": True,
+        "recurrent": "mamba",
+        "indexer": "",
+        "mlp": "densex5",
+    },
+    "config:granite-4.0-h-micro.json": {
+        "cut": (0, 10, 4),
+        "lead": "",
+        "period": (
+            "mamba/mamba/input_norm/0 mlp/mamba/post_norm/0 "
+            "mamba/mamba/input_norm/1 mlp/mamba/post_norm/1 "
+            "mamba/mamba/input_norm/2 mlp/mamba/post_norm/2 "
+            "mamba/mamba/input_norm/3 mlp/mamba/post_norm/3 "
+            "mamba/mamba/input_norm/4 mlp/mamba/post_norm/4 "
+            "attn/attn/input_norm/0 mlp/attn/post_norm/0 "
+            "mamba/mamba/input_norm/5 mlp/mamba/post_norm/5 "
+            "mamba/mamba/input_norm/6 mlp/mamba/post_norm/6 "
+            "mamba/mamba/input_norm/7 mlp/mamba/post_norm/7 "
+            "mamba/mamba/input_norm/8 mlp/mamba/post_norm/8"
+        ),
+        "layers": (4, 0, 36, 0, 0),
+        "kv_pools": 2,
+        "windows": "0x40",
+        "num_params": 3191396096,
+        "hybrid": True,
+        "recurrent": "mamba",
+        "indexer": "",
+        "mlp": "densex40",
+    },
 }
 
 FIELDS = [
@@ -861,6 +928,9 @@ FIELDS = [
     ("eva_chunk", 0), ("num_pred_heads", 1), ("fp32_residual", False),
     ("conv_pattern", ""), ("conv_L_cache", 0), ("conv_bias", False),
     ("router_norm_eps", 1e-20), ("kv_head_pack", 1),
+    ("mamba_pattern", ""), ("embedding_multiplier", 1.0),
+    ("attention_multiplier", 0.0), ("residual_multiplier", 1.0),
+    ("logits_scaling", 1.0),
 ]
 
 

@@ -235,18 +235,27 @@ def test_gated_delta_step_kernel_compiles_for_v5e(v5e):
     assert mem.temp_size_in_bytes < state_bytes // 8
 
 
-def test_ssd_step_kernel_compiles_for_v5e(v5e):
+@pytest.mark.parametrize("B, H, G, layers, block", [
+    (192, 128, 8, 5, 0), (192, 128, 8, 5, 16), (192, 128, 8, 5, 32),
+    (80, 64, 1, 36, 0), (80, 64, 1, 36, 16), (80, 64, 1, 36, 32),
+], ids=["nemotron", "nemotron-16", "nemotron-32",
+        "granite", "granite-16", "granite-32"])
+def test_ssd_step_kernel_compiles_for_v5e(v5e, B, H, G, layers, block):
     """The Mamba-2 step kernel at Nemotron-H's sizes (192 slots, 128
-    heads of 64 x 128 float32 in 8 groups), the state updated in place."""
+    heads of 64 x 128 float32 in 8 groups) and at Granite 4.0-H's (80
+    slots, 64 heads in ONE group, 36 layers: 6.04 GB of tiles), at the
+    rule's block of heads and at the others the chip was asked about,
+    the state updated in place."""
     from vgate_tpu.ops.pallas.ssd import ssd_step_pallas
 
     A = _abstract(v5e)
-    B, H, P, G, N, layers = 192, 128, 64, 8, 128, 5
+    P, N = 64, 128
     f32 = jnp.float32
     state_bytes = layers * B * H * P * N * 4
     compiled = ssd_step_pallas.lower(
         A((B, H, P), f32), A((B, H), f32), A((B, G, N), f32),
         A((B, G, N), f32), A((layers, B, H, P, N), f32), A((), jnp.int32),
+        block=block,
     ).compile()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= state_bytes, "the state is copied"
@@ -1382,4 +1391,96 @@ def test_lfm2_prompt_program_fits_beside_the_cache_on_v5e(
           mem.temp_size_in_bytes)
     assert mem.alias_size_in_bytes >= 2 * _nbytes(pool) + _nbytes(state)
     assert mem.temp_size_in_bytes < LFM2_PROGRAM_ROOM
+    assert "flash_prefill_attention_pallas" in compiled.as_text()
+
+
+# ---- Granite 4.0-H Micro, whole: the state (6.1 GB) sets the batch
+
+# what the configuration's hbm_utilization 0.9 leaves the programs: 0.9
+# of the chip's 16.9 GB less weights 6.39, state 6.11 and pages 1.34 GB
+GRANITE_PROGRAM_ROOM = int(0.9 * 16.9e9 - 13.85e9)
+
+
+@pytest.fixture(scope="module")
+def granite(v5e):
+    from vgate_tpu.models.hybrid import make_state
+
+    A = _abstract(v5e)
+    spec, params = _cut_and_shapes(
+        A, "ibm-granite/granite-4.0-h-micro", {})
+    spec = spec.pack_kv_heads()
+    state = jax.tree.map(
+        lambda x: A(x.shape, x.dtype),
+        jax.eval_shape(lambda: make_state(spec, 80, jnp.bfloat16, PAGE)))
+    assert _nbytes(state) == 80 * 76_437_504
+    # (64 zero columns behind dt a Mamba-2 layer: hybrid.mamba_proj_pad;
+    # A_log, D and dt_bias are float32: 3 x 36 x 64 values of 4 B)
+    assert _nbytes(params) == (
+        2 * (spec.num_params + 36 * 2048 * 64) + 2 * 3 * 36 * 64)
+    pool = A((spec.attn_layers, spec.cache_heads, 80 * 64 + 1, PAGE,
+              spec.cache_head_dim), jnp.bfloat16)
+    assert pool.shape == (4, 4, 5121, PAGE, 128)
+    return A, spec, params, pool, state
+
+
+def test_granite_decode_chunk_updates_the_state_in_place_on_v5e(granite):
+    """The whole published model as the cell serves it (80 slots of
+    2,048 tokens): the decode chunk compiles for the v5e with the state
+    (6.1 GB, more than the pages) and the packed pool aliased input to
+    output, NO second array of the state's tiles among its temporaries
+    (weights 6.4 + state 6.1 + a copy 6.0 would pass the chip), the
+    step kernel at the rule's block, and the scaled head on the fused
+    pass: no ``[80, 100352]`` float32 logits."""
+    from vgate_tpu.runtime.step_programs import _decode_chunk
+
+    A, spec, params, pool, state = granite
+    B, ctx = 80, 2048
+    compiled = _decode_chunk.lower(
+        params, spec, A((B,), jnp.int32), A((B,), jnp.int32), pool, pool,
+        A((B, ctx // PAGE), jnp.int32), A((B,), jnp.bool_),
+        A((B,), jnp.float32), A((B,), jnp.float32), A((B,), jnp.int32),
+        A((2,), jnp.uint32), A((), jnp.uint32),
+        num_steps=8, use_pallas=True, max_position=ctx - 1,
+        seeds=A((B,), jnp.int32), steps=A((B,), jnp.int32),
+        all_greedy=True, guard=True, state=state,
+        bias_ids=A((B, 16), jnp.int32), bias_vals=A((B, 16), jnp.float32),
+        min_toks=A((B,), jnp.int32), stop_id_mat=A((B, 2), jnp.int32),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * _nbytes(pool) + _nbytes(state), (
+        "the pool or the state is copied")
+    print("granite decode chunk temporaries", mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes < GRANITE_PROGRAM_ROOM
+    assert mem.temp_size_in_bytes < state["S"].size * 4 // 16
+    text = compiled.as_text()
+    assert "ssd_step_pallas" in text
+    assert "paged_decode_attention_pallas" in text
+    assert "greedy_head" in text
+    _assert_no_logits_array(text, B, spec.vocab_size)
+    # no copy of a period's matrices either (the walker's scans carry
+    # indices): the nine Mamba-2 layers' in_proj, the SwiGLU's three
+    _assert_no_copy_of(text, (9, 2048, 8512), (5, 2048, 8512),
+                       (4, 2048, 8512), (9, 2048, 8192), (5, 2048, 8192),
+                       (4, 2048, 8192))
+
+
+@pytest.mark.parametrize("B, bucket", [(8, 128), (1, 2048), (8, 2048)],
+                         ids=["wave-8x128", "1x2048", "wave-8x2048"])
+def test_granite_prompt_program_fits_beside_the_state_on_v5e(
+        granite, B, bucket):
+    """The cell's prompt programs at their ends (a wave of 8 in the 128
+    bucket, what fresh requests send; the 2,048 bucket, what a resumed
+    request takes, one row and a wave of 8): the state and the pool are
+    updated in place and the temporaries (0.11, 0.22 and 1.27 GB: the
+    last the chunk-wise recurrence's float32 arrays) fit what
+    ``hbm_utilization`` leaves."""
+    A, spec, params, pool, state = granite
+    compiled = _prompt_program(A, spec, params, pool, pool, state,
+                               bucket=bucket, B=B)
+    mem = compiled.memory_analysis()
+    print("granite prompt program", B, bucket, "temporaries",
+          mem.temp_size_in_bytes)
+    assert mem.alias_size_in_bytes >= 2 * _nbytes(pool) + _nbytes(state), (
+        "the pool or the state is copied")
+    assert mem.temp_size_in_bytes < GRANITE_PROGRAM_ROOM
     assert "flash_prefill_attention_pallas" in compiled.as_text()

@@ -224,6 +224,8 @@ def _logits(params: Params, spec: ModelSpec, x: jnp.ndarray,
             preferred_element_type=jnp.float32,
             quant_kernel=spec.quant_kernel,
         )
+    if spec.logits_scaling != 1.0:  # Granite's: before any edit
+        logits = logits / spec.logits_scaling
     return _softcap(logits, spec.final_softcap)
 
 
@@ -276,15 +278,18 @@ def greedy_head(params: Params, spec: ModelSpec, x: jnp.ndarray,
         params["embed" if tied else "lm_head"],
         bias_ids, bias_vals, stop_ids, tied=tied, vocab=spec.vocab_size,
         guard=guard, threshold=guard_threshold,
+        divisor=spec.logits_scaling,
     )
 
 
 def _query_scale(spec: ModelSpec):
     """Attention query scale override (Gemma-2's query_pre_attn_scalar,
-    latent attention's scale under YaRN); None selects the default
-    head_dim**-0.5 inside the attention ops."""
+    latent attention's scale under YaRN, Granite's attention_multiplier);
+    None selects the default head_dim**-0.5 inside the attention ops."""
     if spec.is_mla:
         return spec.mla_softmax_scale
+    if spec.attention_multiplier > 0:
+        return spec.attention_multiplier
     return spec.query_scale ** -0.5 if spec.query_scale > 0 else None
 
 
@@ -295,6 +300,8 @@ def _embed(params: Params, spec: ModelSpec, tokens: jnp.ndarray):
         # Gemma scales embeddings by sqrt(hidden), cast to the model dtype
         # BEFORE the multiply (the HF convention, needed for parity).
         x = x * jnp.asarray(spec.hidden_size ** 0.5, x.dtype)
+    if spec.embedding_multiplier != 1.0:  # Granite's: a published number
+        x = x * jnp.asarray(spec.embedding_multiplier, x.dtype)
     return x
 
 

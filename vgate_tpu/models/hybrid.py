@@ -5,7 +5,8 @@ whichever way the spec's fields spell them; the walker takes from it
 ``lead_blocks``, ``period_blocks`` and ``num_periods``):
 
 * ``gdn``   Gated DeltaNet linear attention (Qwen3-Next),
-* ``mamba`` a Mamba-2 state-space layer (Nemotron-H),
+* ``mamba`` a Mamba-2 state-space layer (Nemotron-H's, with eight B/C
+  groups; Granite 4.0-H's, with one for all its heads),
 * ``attn``  softmax attention over the paged pool (gated, with per-head
   norms and partial rotary, or plain without rotary: the spec says),
 * ``mla``   multi-head latent attention over the LATENT paged pool
@@ -36,14 +37,17 @@ whichever way the spec's fields spell them; the walker takes from it
   and NO tile (``{"conv": [conv layers, slots, K-1, D]}`` is the whole
   state),
 * ``mlp``   a dense SwiGLU feed-forward (a leading layer's, or every
-  layer's of an ``eva`` stack),
+  layer's of an ``eva`` or a ``mamba_pattern`` stack),
 * ``moe``   the expert layer of ``ops/moe.py``.
 
 Qwen3-Next's layer is two sub-blocks (a mixer, then experts); its period
 is ``gdn moe gdn moe gdn moe attn moe``.  A Nemotron-H layer is one:
 ``EMEMEMEMEM*`` is ``moe mamba`` five times, then ``attn``.  A
 Mistral-Small-4 layer is ``mla moe``, and its stack has no recurrent
-layer: the state is then ``None`` and the second pool too.  A K-EXAONE
+layer: the state is then ``None`` and the second pool too.  A Granite
+4.0-H layer is ``mamba mlp`` or ``attn mlp``, ten to a period, and every
+sub-block's output is scaled by ``residual_multiplier`` at the walker's
+ONE residual add.  A K-EXAONE
 layer is ``swa moe`` three times to one ``attn moe``, behind LEADING
 layers that the walker runs once, ahead of the scan (layer 0, ``swa
 mlp``, and as many more as leave whole periods: ``ModelSpec.lead_layers``,
@@ -62,8 +66,9 @@ differs:
   of the recurrent ones ride the carry, all updated in place;
 * the recurrent state (``{"S": [Lr, slots, heads, ., .] float32, "conv":
   [Lr, slots, K-1, C]}``: ``[32, 128, 128]`` tiles over 8,192 channels
-  for ``gdn``, ``[128, 64, 128]`` over 10,240 for ``mamba`` at the
-  published sizes) is indexed by decode SLOT.  A prompt pass starts from
+  for ``gdn``; for ``mamba`` ``[128, 64, 128]`` over 10,240 at
+  Nemotron-H's published sizes and ``[64, 64, 128]`` over 4,352 at
+  Granite 4.0-H's) is indexed by decode SLOT.  A prompt pass starts from
   zeros (or, for a later chunk of a chunked prefill, from the slot's
   row), runs the chunk-wise recurrence and overwrites the row whole; a
   decode step updates the rows of active slots in place and leaves idle
@@ -105,7 +110,9 @@ experts of a window or a full layer) and ``lead``, a TUPLE of the
 leading layers' own trees; an ``indexer_pattern`` spec's are ``pick``
 and ``reuse`` (latent attention with and without an indexer, and the
 layer's experts) and ``lead``; a ``conv_pattern`` spec's are ``conv``
-and ``attn`` (the mixer and the layer's experts) and ``lead``.
+and ``attn`` (the mixer and the layer's experts) and ``lead``; a
+``mamba_pattern`` spec's are ``mamba`` and ``attn`` (the mixer and the
+layer's dense SwiGLU).
 """
 
 from __future__ import annotations
@@ -117,6 +124,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from vgate_tpu.models.specs import ModelSpec
 from vgate_tpu.ops import dsa, eva
@@ -146,6 +154,8 @@ def init_layers(spec: ModelSpec, key, dtype, normal, norm_init
         return _init_window_layers(spec, key, dtype, normal)
     if spec.conv_pattern:
         return _init_conv_layers(spec, key, dtype, normal)
+    if spec.mamba_pattern:
+        return _init_mamba_mlp_layers(spec, key, dtype, normal)
     if spec.layer_pattern:
         return _init_pattern_layers(spec, key, dtype, normal)
     return _init_paired_layers(spec, key, dtype, normal, norm_init)
@@ -456,6 +466,93 @@ def _init_conv_layers(spec: ModelSpec, key, dtype, normal
     return out
 
 
+def _init_mamba_mlp_layers(spec: ModelSpec, key, dtype, normal
+                           ) -> Dict[str, Any]:
+    """A ``mamba_pattern`` spec's tensors (every layer a Mamba-2 or an
+    attention mixer, then a dense SwiGLU) from ``fold_in(key, 51)`` split
+    32 ways: tensor ``j`` of layer ``i`` (its index in the WHOLE stack)
+    from ``fold_in(key j, i)``, N(0, 0.02), every norm weight 1.  The
+    Mamba-2 draws are ``_init_pattern_layers``': the taps N(0, 0.5),
+    ``A_log = log(U[1, 16])``, ``dt_bias`` the inverse softplus of a step
+    drawn log-uniformly in [0.001, 0.1] floored at 1e-4, ``D = 1``, so
+    that a wrong or a stale state shows.  The multipliers are the spec's
+    and no tensor's."""
+    gk = jax.random.split(jax.random.fold_in(key, 51), 32)
+    D, H, KV, hd = (spec.hidden_size, spec.num_heads, spec.num_kv_heads,
+                    spec.head_dim)
+    F = spec.intermediate_size
+    Hm, di, C = spec.mamba_num_heads, spec.mamba_inner, spec.mamba_conv_dim
+    f32 = jnp.float32
+    mixer = {
+        "mamba": {"in_proj": (0, (D, di + C + Hm)), "out": (5, (di, D))},
+        "attn": {"q": (8, (D, H * hd)), "k": (9, (D, KV * hd)),
+                 "v": (10, (D, KV * hd)), "o": (11, (H * hd, D))},
+    }
+    ff = {"gate": (12, (D, F)), "up": (13, (D, F)), "down": (14, (F, D))}
+
+    def draw(layers, j, fn):
+        """Tensor ``j`` of the ``layers`` (stack indices), stacked:
+        ``fn(key)`` a layer."""
+        return jax.jit(lambda k: jax.lax.map(
+            lambda i: fn(jax.random.fold_in(k, i)),
+            jnp.asarray(layers)))(gk[j])
+
+    def tree(layers, lead, kind):
+        put = lambda a: a.reshape(lead + a.shape[1:])
+        gauss = lambda j, shape, scale=0.02: put(draw(
+            layers, j, lambda k: normal(k, shape, scale)))
+        out = {"input_norm": jnp.ones(lead + (D,), dtype),
+               "post_norm": jnp.ones(lead + (D,), dtype)}
+        for name, (j, shape) in {**mixer[kind], **ff}.items():
+            out[name] = {"w": gauss(j, shape)}
+        if kind == "mamba":
+            out["in_proj"]["w"] = mamba_proj_pad(out["in_proj"]["w"])
+            out.update(
+                conv=gauss(1, (C, spec.mamba_conv_kernel), 0.5),
+                a_log=put(draw(layers, 3, lambda k: jnp.log(
+                    jax.random.uniform(k, (Hm,), f32, 1.0, 16.0)))),
+                dt_bias=put(draw(layers, 4, lambda k: _mamba_dt_bias(k, Hm))),
+                d=jnp.ones(lead + (Hm,), f32),
+                ssm_norm=jnp.ones(lead + (di,), dtype))
+            if spec.mamba_conv_bias:
+                out["conv_bias"] = gauss(2, (C,))
+        return out
+
+    P, out = spec.num_periods, {}
+    for group in ("mamba", "attn"):
+        layers = [i for i, kinds in enumerate(spec.stack)
+                  if kinds[0] == group]
+        if layers:
+            out[group] = tree(layers, (P, len(layers) // P), group)
+    return out
+
+
+def _mamba_dt_bias(key, heads: int):
+    """A Mamba-2 layer's ``dt_bias`` [heads] float32: the inverse
+    softplus of a step drawn log-uniformly in [0.001, 0.1], floored at
+    1e-4 (the published initialisation)."""
+    step = jnp.maximum(1e-4, jnp.exp(
+        jnp.log(1e-3) + jax.random.uniform(key, (heads,))
+        * (jnp.log(0.1) - jnp.log(1e-3))))
+    return jnp.log(jnp.expm1(step)).astype(jnp.float32)
+
+
+def mamba_proj_pad(w):
+    """A ``mamba_pattern`` layer's input projection ``[..., D, z | x B C
+    | dt]`` with zero columns behind ``dt`` up to whole 128-lane groups
+    (Granite's 8,512 -> 8,576).  A matrix whose minor dimension is no
+    whole number of lane groups the TPU holds transposed, and a step
+    program then opens with a copy of ALL of it into the layout its
+    products read: 1.26 GB of temporaries and 2.5 GB of traffic a decode
+    chunk at the published sizes (the AOT memory analysis, tests/
+    test_tpu_aot.py -k granite).  Works on numpy and jax arrays."""
+    pad = -w.shape[-1] % 128
+    if not pad:
+        return w
+    xp = jnp if isinstance(w, jax.Array) else np
+    return xp.pad(w, ((0, 0),) * (w.ndim - 1) + ((0, pad),))
+
+
 def _init_pattern_layers(spec: ModelSpec, key, dtype, normal
                          ) -> Dict[str, Any]:
     """A ``layer_pattern`` spec's tensors from ``fold_in(key, 31)`` split
@@ -484,17 +581,14 @@ def _init_pattern_layers(spec: ModelSpec, key, dtype, normal
     out: Dict[str, Any] = {}
     if spec.group_layers("mamba"):
         lead = (P, spec.group_layers("mamba"))
-        step = lambda k: jnp.maximum(1e-4, jnp.exp(
-            jnp.log(1e-3) + jax.random.uniform(k, (Hm,))
-            * (jnp.log(0.1) - jnp.log(1e-3))))
         out["mamba"] = {
             "norm": jnp.ones(lead + (D,), dtype),
             "in_proj": {"w": draw("mamba", nk[0], (D, di + C + Hm))},
             "conv": draw("mamba", nk[1], (C, spec.mamba_conv_kernel), 0.5),
             "a_log": per_layer("mamba", lambda k: jnp.log(
                 jax.random.uniform(k, (Hm,), f32, 1.0, 16.0)))(nk[3]),
-            "dt_bias": per_layer("mamba", lambda k: jnp.log(
-                jnp.expm1(step(k))).astype(f32))(nk[4]),
+            "dt_bias": per_layer(
+                "mamba", lambda k: _mamba_dt_bias(k, Hm))(nk[4]),
             "d": jnp.ones(lead + (Hm,), f32),
             "ssm_norm": jnp.ones(lead + (di,), dtype),
             "out": {"w": draw("mamba", nk[5], (di, D))},
@@ -919,7 +1013,9 @@ def _mamba_inputs(normed, lp, spec: ModelSpec):
     z, the pre-convolution channels and dt."""
     di, C = spec.mamba_inner, spec.mamba_conv_dim
     proj = jnp.einsum("...d,dc->...c", normed, lp["in_proj"]["w"])
-    return proj[..., :di], proj[..., di:di + C], proj[..., di + C:]
+    Hm = spec.mamba_num_heads  # (columns past dt: ``mamba_proj_pad``)
+    return (proj[..., :di], proj[..., di:di + C],
+            proj[..., di + C:di + C + Hm])
 
 
 def _mamba_heads(y, dt, lp, spec: ModelSpec, valid):
@@ -1508,6 +1604,8 @@ def _period_scan(params, spec: ModelSpec, x0, k_pages, v_pages, state,
                 h, lp[norm], spec.rms_eps, uo).astype(lp[norm].dtype)
         out, kp, vp, st, stats = block_fn(
             kind, h, lp, kp, vp, st, index, stack, normed)
+        if spec.residual_multiplier != 1.0:  # Granite's, every sub-block
+            out = out * jnp.asarray(spec.residual_multiplier, out.dtype)
         return (h + out.astype(h.dtype), kp, vp, st), stats
 
     # the leading layers, unrolled: layer i's mixer is the i-th of its

@@ -97,6 +97,9 @@ class ModelSpec:
     # of two sub-blocks above (a mixer, then the expert layer)
     layer_pattern: str = ""
     use_rope: bool = True
+    # a Mamba-2 layer's sizes, for either spelling that has one (this
+    # one's ``M`` and ``mamba_pattern``'s below): heads x head size the
+    # inner width, B and C ``mamba_n_groups`` rows of ``mamba_state_size``
     mamba_num_heads: int = 0
     mamba_head_dim: int = 0
     mamba_state_size: int = 0
@@ -202,6 +205,22 @@ class ModelSpec:
     # tiled HBM layout would pad to 128, so two heads share a 128-lane
     # row, ``[layers, KV / 2, pages, page, 128]`` (ops/head_pack.py)
     kv_head_pack: int = 1
+    # ---- Mamba-2 beside attention, every layer followed by a dense
+    # SwiGLU (Granite 4.0-H; the published ``layer_types``): one letter a
+    # layer over the WHOLE stack, ``M`` a layer ``(mamba, mlp)``, ``A`` a
+    # layer ``(attn, mlp)``; the Mamba-2 sizes are the ``mamba_*`` above
+    mamba_pattern: str = ""
+    # ---- Granite's four published multipliers, each applied in ONE
+    # place: the embedded rows (models/decoder.py ``_embed``), the
+    # softmax scale in place of ``head_dim ** -0.5`` (``_query_scale``;
+    # 0 = that default), every sub-block's output at the stack walker's
+    # residual add (models/hybrid.py ``_period_scan``), and what the
+    # logits are DIVIDED by before any edit and before the log-softmax
+    # (``_logits``, ``greedy_head``)
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     def __post_init__(self):
         if self.n_shared_experts and not self.shared_expert_intermediate_size:
@@ -319,6 +338,8 @@ class ModelSpec:
             return _LETTERS
         if self.conv_pattern:
             return _CONV
+        if self.mamba_pattern:
+            return _MAMBA
         if self.eva_window:
             return _EVA
         if self.window_pattern:
@@ -628,8 +649,28 @@ class ModelSpec:
         if self._spelling is _CONV:
             return ["conv" if layer[0] == "conv" else "full_attention"
                     for layer in self.stack]
+        if self._spelling is _MAMBA:
+            return ["mamba" if layer[0] == "mamba" else "attention"
+                    for layer in self.stack]
         return ["sliding_attention" if w else "full_attention"
                 for w in self.layer_windows]
+
+    @property
+    def multipliers(self) -> dict:
+        """The published multipliers that are not the identity, under
+        their published names (``/stats -> engine.multipliers``)."""
+        out = {"embedding_multiplier": self.embedding_multiplier,
+               "residual_multiplier": self.residual_multiplier,
+               "logits_scaling": self.logits_scaling}
+        out = {k: v for k, v in out.items() if v != 1.0}
+        if self.attention_multiplier > 0:
+            out["attention_multiplier"] = self.attention_multiplier
+        return out
+
+    # Granite's key for "the attention layers take no rotary embedding"
+    @property
+    def position_embedding_type(self) -> str:
+        return "rope" if self.use_rope else "nope"
 
     # the sigmoid router as LFM2's config.json spells it: a selection
     # bias, the chosen scores renormalised (ops/moe.py does both)
@@ -647,6 +688,8 @@ class ModelSpec:
 
     @property
     def mlp_layer_types(self) -> list:
+        if self._spelling is _MAMBA:  # a dense SwiGLU behind every mixer
+            return ["dense"] * len(self.stack)
         # an indexer spec states the published stack's
         return ["dense" if i < self.first_k_dense else "sparse"
                 for i in range(len(self._spelling.parse(self)))]
@@ -690,6 +733,7 @@ class ModelSpec:
             self.sliding_window > 0
             or self.attn_softcap > 0
             or self.query_scale > 0
+            or self.attention_multiplier > 0
         )
 
     @property
@@ -741,6 +785,8 @@ def _parse_interval(spec: ModelSpec) -> tuple:
 
 
 def _parse_letters(spec: ModelSpec) -> tuple:
+    # ONE sub-block a layer; a layer of two (a mixer, then a dense
+    # block) is ``_MAMBA``'s or another spelling's below
     kinds = {"M": "mamba", "*": "attn", "E": "moe"}
     return tuple((kinds[c],) for c in spec.layer_pattern)
 
@@ -768,6 +814,12 @@ _CONV = _Spelling(
                         _feed_forward(spec, i))
                        for i, c in enumerate(spec.conv_pattern)),
     True, {"conv": "conv", "attn": "attn"})
+# Mamba-2 beside attention by letter, a dense SwiGLU behind each: the
+# groups go by the layer's first sub-block and hold its feed-forward too
+_MAMBA = _Spelling(
+    lambda spec: tuple(("mamba" if c == "M" else "attn", "mlp")
+                       for c in spec.mamba_pattern),
+    False, {"mamba": "mamba", "attn": "attn"})
 # latent attention without an indexer: every layer alike
 _LATENT = _Spelling(lambda spec: (("mla", "moe"),) * _depth(spec), False,
                     {"mla": "layer"})
@@ -1617,6 +1669,86 @@ TINY_LFM2_MOE = _register(
         first_k_dense=2,
         conv_pattern=_LFM2_LAYERS[:12],
         conv_L_cache=3,
+    )
+)
+
+# Granite 4.0-H Micro (ibm-granite, model_type granitemoehybrid) at the
+# published sizes, nothing cut: 40 layers, 36 Mamba-2 (64 heads of 64,
+# ONE B/C group of 128 for all of them) and 4 GQA attention layers without
+# rotary embedding (32 heads on 8 KV heads of 64) at 5, 15, 25, 35, each
+# followed by a dense SwiGLU of 8,192 (``num_local_experts`` 0: the
+# feed-forward is ``shared_mlp`` alone), a tied head, and the four
+# multipliers.  Assumed (the row's config has no key for them): the stop
+# ids
+_GRANITE_LAYERS = ("MMMMMA" + "MMMM") + "MMMMMAMMMM" * 3
+GRANITE_4_H_MICRO = _register(
+    ModelSpec(
+        name="ibm-granite/granite-4.0-h-micro",
+        vocab_size=100352,
+        hidden_size=2048,
+        num_layers=40,
+        num_heads=32,
+        num_kv_heads=8,
+        head_dim=64,
+        intermediate_size=8192,
+        rope_theta=10_000.0,  # published, unused: position is "nope"
+        rms_eps=1e-5,
+        qkv_bias=False,
+        tie_embeddings=True,
+        eos_token_id=100257,
+        bos_token_id=100257,
+        max_position_embeddings=131072,
+        use_rope=False,
+        mamba_pattern=_GRANITE_LAYERS,
+        mamba_num_heads=64,
+        mamba_head_dim=64,
+        mamba_state_size=128,
+        mamba_n_groups=1,
+        mamba_conv_kernel=4,
+        mamba_conv_bias=True,
+        mamba_chunk_size=256,
+        embedding_multiplier=12.0,
+        attention_multiplier=0.015625,
+        residual_multiplier=0.22,
+        logits_scaling=8.0,
+    )
+)
+
+# every mechanism of Granite 4.0-H at toy widths: one period with both
+# kinds of layer (three Mamba-2 layers in a row, so that the walker's
+# inner scan runs), ONE B/C group for the four Mamba-2 heads, all four
+# multipliers at values other than 1 (and the softmax scale other than
+# ``head_dim ** -0.5`` = 0.25)
+TINY_GRANITE_HYBRID = _register(
+    ModelSpec(
+        name="tiny-granite-hybrid",
+        vocab_size=512,
+        hidden_size=64,
+        num_layers=5,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        intermediate_size=128,
+        rope_theta=10000.0,
+        rms_eps=1e-5,
+        qkv_bias=False,
+        tie_embeddings=True,
+        eos_token_id=0,
+        bos_token_id=1,
+        max_position_embeddings=4096,
+        use_rope=False,
+        mamba_pattern="MMMAM",
+        mamba_num_heads=4,
+        mamba_head_dim=16,
+        mamba_state_size=16,
+        mamba_n_groups=1,
+        mamba_conv_kernel=4,
+        mamba_conv_bias=True,
+        mamba_chunk_size=16,
+        embedding_multiplier=6.0,
+        attention_multiplier=0.5,
+        residual_multiplier=0.5,
+        logits_scaling=4.0,
     )
 )
 

@@ -89,6 +89,7 @@ def _kernel(
     idx_ref,  # [B, 128] int32: its first column
     bits_ref,  # [B, 128] int32: the running maximum of |raw| as bits
     *, vocab: int, tile: int, tied: bool, guard: bool, threshold: float,
+    divisor: float,
 ):
     j = pl.program_id(0)
     last = pl.num_programs(0) - 1
@@ -101,11 +102,14 @@ def _kernel(
         idx_ref[...] = jnp.zeros_like(idx_ref)
         bits_ref[...] = jnp.zeros_like(bits_ref)
 
-    s_ref[...] = jax.lax.dot_general(
+    s = jax.lax.dot_general(
         x_ref[...], w_ref[...],
         (((1,), (1 if tied else 0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
+    # a spec's ``logits_scaling``: what the guard reads and the edits
+    # are added to is the scaled value, as ``_logits`` hands it on
+    s_ref[...] = s if divisor == 1.0 else s / divisor
     ragged = vocab % tile != 0
     if ragged:
         # the last tile's columns past V hold whatever the block's
@@ -198,7 +202,7 @@ def _tile_hits(ids, tile: int, tiles: int):
 @functools.partial(
     jax.jit,
     static_argnames=("tied", "vocab", "guard", "threshold", "tile",
-                     "interpret"),
+                     "interpret", "divisor"),
 )
 def greedy_head_pallas(
     x: jnp.ndarray,  # [B, D] normed rows, the head's dtype
@@ -208,11 +212,12 @@ def greedy_head_pallas(
     stop_ids=None,  # [B, Ks] int32 LIVE stop ids, ids >= V pad
     *, tied: bool, vocab: int = 0, guard: bool = False,
     threshold: float = 1.0e4, tile: int = 0, interpret: bool = False,
+    divisor: float = 1.0,
 ):
     """``(next_tokens [B] int32, flags [B] uint8)`` of
-    ``argmax(floor(bias(x @ head)))`` and ``logit_guard(x @ head)``
-    over the head's first ``vocab`` columns (0: all it has); the flags
-    are zeros without ``guard``."""
+    ``argmax(floor(bias(x @ head / divisor)))`` and ``logit_guard(x @
+    head / divisor)`` over the head's first ``vocab`` columns (0: all it
+    has); the flags are zeros without ``guard``."""
     B, D = x.shape
     V = vocab or (head.shape[0] if tied else head.shape[1])
     tile = tile or head_tile(V, D, head.dtype.itemsize)
@@ -248,7 +253,7 @@ def greedy_head_pallas(
         _kernel(
             hits_ref, x_ref, w_ref, *bias_refs, stop_ref, *refs,
             vocab=V, tile=tile, tied=tied, guard=guard,
-            threshold=threshold,
+            threshold=threshold, divisor=float(divisor),
         )
 
     out = pl.BlockSpec((rows, 1), lambda j, hits: (0, 0))

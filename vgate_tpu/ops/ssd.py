@@ -105,11 +105,12 @@ def ssd_recurrent(x, dt, A, Bm, Cm, state):
 
 
 def ssd_step(x, dt, A, Bm, Cm, state, layer, use_pallas=False,
-             interpret=False):
+             interpret=False, block=0):
     """One decode step on the FULL ``[Lm, B, H, P, N]`` state, at
     ``layer`` (a traced scalar).  x: [B, H, P], dt: [B, H] float32 (0
-    for a row that must not move), A: [H], Bm, Cm: [B, G, N].  Returns
-    (y [B, H, P] float32, state)."""
+    for a row that must not move), A: [H], Bm, Cm: [B, G, N]; ``block``:
+    the kernel's heads a program (0: its own rule).  Returns (y [B, H,
+    P] float32, state)."""
     f32 = jnp.float32
     x, Bm, Cm = x.astype(f32), Bm.astype(f32), Cm.astype(f32)
     decay = jnp.exp(dt * A.astype(f32))
@@ -118,7 +119,7 @@ def ssd_step(x, dt, A, Bm, Cm, state, layer, use_pallas=False,
         from vgate_tpu.ops.pallas.ssd import ssd_step_pallas
 
         return ssd_step_pallas(xdt, decay, Bm, Cm, state, layer,
-                               interpret=interpret)
+                               interpret=interpret, block=block)
     R = x.shape[1] // Bm.shape[1]
     B_h, C_h = jnp.repeat(Bm, R, axis=1), jnp.repeat(Cm, R, axis=1)
     S_ = (state[layer] * decay[..., None, None]

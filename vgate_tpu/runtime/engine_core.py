@@ -4395,11 +4395,21 @@ class EngineCore:
                 "rows_per_slot": self.spec.conv_L_cache - 1,
                 "dtype": f"{name} convolution tail, no tile",
             }
-        return {
+        out = {
             "linear_layers": self.spec.linear_layers,
             "kind": self.spec.recurrent_kind,
             "dtype": f"float32 state, {name} convolution tail",
         }
+        if self.spec.recurrent_kind == "mamba":
+            # heads a program of the step kernel takes (ops/pallas/
+            # ssd.py): what a trace's ``ssd_step_pallas`` time was made
+            # at; 0: the jax.numpy twin, no kernel
+            from vgate_tpu.ops.pallas.ssd import block_heads
+
+            out["step_block_heads"] = block_heads(
+                self.spec.mamba_num_heads, self.spec.mamba_n_groups
+            ) if self.use_pallas else 0
+        return out
 
     def _ring_tick(self) -> Dict[str, int]:
         """A flight tick's cache line beside ``kv_used``: the bytes the
@@ -4510,6 +4520,10 @@ class EngineCore:
             ),
             "weights_bytes": self._params_bytes,
             "model": self.spec.name,
+            # the spec's published multipliers as the program applies
+            # them (a preset that lost one shows here); none: not there
+            **({"multipliers": self.spec.multipliers}
+               if self.spec.multipliers else {}),
             "mesh": {
                 axis: int(size) for axis, size in self.mesh.shape.items()
             },

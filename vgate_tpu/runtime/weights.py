@@ -540,6 +540,74 @@ def _conv_params_from_getter(
     return params
 
 
+def _mamba_mlp_params_from_getter(
+    spec: ModelSpec, getter: TensorGetter, dtype
+) -> Params:
+    """``GraniteMoeHybridForCausalLM`` names (ASSUMED from
+    ``transformers``' module as the writer knows it: ``input_layernorm``
+    / ``post_attention_layernorm``, ``mamba.{in_proj, conv1d, A_log, D,
+    dt_bias, norm, out_proj}``, ``self_attn.{q,k,v,o}_proj``,
+    ``shared_mlp.input_linear`` holding ``[gate | up]`` and
+    ``shared_mlp.output_linear``, ``model.norm``; no checkpoint was
+    read) -> the pytree of models/hybrid.py for a ``mamba_pattern``
+    spec: ``layers = {"mamba" | "attn": [P, n, ...]}``, a layer's dense
+    SwiGLU in its mixer's group.  ``in_proj`` is held as the checkpoint
+    has it, ``[z | x | B | C | dt]``, with zero columns up to whole lane
+    groups behind it (models/hybrid.py ``mamba_proj_pad``);
+    ``conv1d.weight`` ``[channels, 1, taps]`` loses its middle axis.
+    The head is the embedding (``tie_word_embeddings``), unscaled by
+    ``embedding_multiplier``."""
+    from vgate_tpu.models.hybrid import mamba_proj_pad
+
+    get = lambda i, name: np.asarray(getter(f"model.layers.{i}.{name}"))
+    lin = lambda i, name: {"w": get(i, f"{name}.weight").T}
+    np_dtype = np.dtype(dtype)
+    cast = lambda x: np.asarray(x).astype(np_dtype)
+    keep_f32 = ("a_log", "d", "dt_bias")
+    F = spec.intermediate_size
+
+    def layer(i):
+        out = {"input_norm": get(i, "input_layernorm.weight"),
+               "post_norm": get(i, "post_attention_layernorm.weight")}
+        if spec.stack[i][0] == "mamba":
+            out.update(
+                in_proj={"w": mamba_proj_pad(
+                    lin(i, "mamba.in_proj")["w"])},
+                conv=get(i, "mamba.conv1d.weight")[:, 0, :],
+                a_log=get(i, "mamba.A_log"), d=get(i, "mamba.D"),
+                dt_bias=get(i, "mamba.dt_bias"),
+                ssm_norm=get(i, "mamba.norm.weight"),
+                out=lin(i, "mamba.out_proj"))
+            if spec.mamba_conv_bias:
+                out["conv_bias"] = get(i, "mamba.conv1d.bias")
+        else:
+            for n in "qkvo":
+                out[n] = lin(i, f"self_attn.{n}_proj")
+        both = lin(i, "shared_mlp.input_linear")["w"]  # [D, 2 F]
+        out["gate"], out["up"] = {"w": both[:, :F]}, {"w": both[:, F:]}
+        out["down"] = lin(i, "shared_mlp.output_linear")
+        return {k: (np.asarray(v, np.float32) if k in keep_f32
+                    else jax.tree.map(cast, v)) for k, v in out.items()}
+
+    P = spec.num_periods
+    layers: Dict[str, Any] = {}
+    for group in ("mamba", "attn"):
+        trees = [layer(i) for i in range(spec.num_layers)
+                 if spec.stack[i][0] == group]
+        if trees:
+            layers[group] = jax.tree.map(
+                lambda *xs: np.stack(xs).reshape(
+                    (P, len(xs) // P) + xs[0].shape), *trees)
+    params: Params = {
+        "embed": cast(getter("model.embed_tokens.weight")),
+        "layers": layers,
+        "final_norm": cast(getter("model.norm.weight")),
+    }
+    if not spec.tie_embeddings:
+        params["lm_head"] = cast(np.asarray(getter("lm_head.weight")).T)
+    return params
+
+
 def params_from_getter(
     spec: ModelSpec, getter: TensorGetter, dtype=jnp.bfloat16
 ) -> Params:
@@ -559,6 +627,8 @@ def params_from_getter(
         return _window_params_from_getter(spec, getter, dtype)
     if spec.conv_pattern:
         return _conv_params_from_getter(spec, getter, dtype)
+    if spec.mamba_pattern:
+        return _mamba_mlp_params_from_getter(spec, getter, dtype)
     if spec.layer_pattern:
         return _pattern_params_from_getter(spec, getter, dtype)
     if spec.is_hybrid:
