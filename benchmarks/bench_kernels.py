@@ -1343,14 +1343,21 @@ def bench_eva_decode(forced=(), B=20, H=32, hd=128, ps=32, window=2048,
     bytes (``long-agent``'s), so about 1,024 live window rows and 768
     summary rows a slot: the paged decode kernel over the step's ONE
     sequence of rows (ops/eva.py ``decode_view``), writing the step's
-    row, then the open chunk's summary row (``decode_summarize``).
+    row, then the summary rows of the windows the step closes
+    (``decode_close``).
     Prints the (pages a chunk, slots a program, items a trip) a launch
     ran with and its loop trips, microseconds a launch and GB/s over the
     rows a step HAS to read and write (live rows, real lengths), for the
-    kernel alone, for its hollow twin (the trips' bookkeeping) and with
-    the summary's rewrite.  ``forced`` is (pages a chunk, items a trip)
-    pairs to run beside the rule's own (0: the rule's), the probe's
-    alone; each forced form's attention is held against the rule's."""
+    kernel alone, for its hollow twin (the trips' bookkeeping), and for
+    the kernel with the closers' loop behind it: with no slot closing
+    (every step but one in 2,048 a slot: should read as the kernel
+    alone) and with two, whose difference is ``close_us_a_slot``, what
+    one window's 128 summary rows cost a layer.  Beside them
+    ``rewrite_a_step_us``, the form PR 52 took off the path: the open
+    chunk's row of every slot rewritten after every launch.  ``forced``
+    is (pages a chunk, items a trip) pairs to run beside the rule's own
+    (0: the rule's), the probe's alone; each forced form's attention is
+    held against the rule's."""
     from vgate_tpu.ops import eva
     from vgate_tpu.ops.pallas.paged_attention import (
         _decode_sizes, paged_decode_attention_pallas,
@@ -1378,11 +1385,40 @@ def bench_eva_decode(forced=(), B=20, H=32, hd=128, ps=32, window=2048,
         return paged_decode_attention_pallas(
             q, kp, vp, view, rows + 1, layer=0, k_new=new, v_new=new, **kw)
 
-    def attend_and_summarize(q, kp, vp, **kw):
+    def attend_and_close(q, kp, vp, closing=0, **kw):
+        """The launch, then the closers' loop with the first ``closing``
+        slots at their window's last row (the loop's alone: the launch
+        keeps its rows)."""
         out, kp, vp = attend(q, kp, vp, **kw)
-        kp, vp = eva.decode_summarize(
-            kp, vp, phi, phi, 0, tables, win, positions, None, window,
-            chunk, hd ** -0.5)
+        at = jnp.where(jnp.arange(B) < closing,
+                       positions | (window - 1), positions & ~1)
+        closers = eva.decode_closers(tables, win, at, None, window, chunk,
+                                     ps)
+        kp, vp = eva.decode_close(kp, vp, phi, phi, 0, closers, chunk,
+                                  hd ** -0.5)
+        return out, kp, vp
+
+    def attend_and_rewrite(q, kp, vp, **kw):
+        """What a step did before PR 52 (``decode_summarize``, kept here
+        alone): XLA's row gather of the open chunk's <= 16 rows of every
+        slot, their pooling, a row scattered a slot."""
+        from vgate_tpu.ops.kv_quant import kv_write_tokens
+
+        out, kp, vp = attend(q, kp, vp, **kw)
+        first = positions // chunk * chunk
+        at = (first % window)[:, None] + jnp.arange(chunk)[None, :]
+        of = jnp.take_along_axis(win, at // ps, axis=1)
+        kv = jnp.arange(H, dtype=jnp.int32)
+        take = lambda pool: pool[0, kv[None, None, :], of[..., None],
+                                 (at % ps)[..., None]]  # [B, c, KV, hd]
+        valid = first[:, None] + jnp.arange(chunk)[None, :] <= positions[
+            :, None]
+        ks, vs = eva.summarize(take(kp), take(vp), phi, phi, valid, chunk,
+                               hd ** -0.5)
+        row = positions // chunk
+        ids = tables[jnp.arange(B), row // ps]
+        kp = kv_write_tokens(kp, ids, row % ps, ks[:, 0], layer=0)
+        vp = kv_write_tokens(vp, ids, row % ps, vs[:, 0], layer=0)
         return out, kp, vp
 
     def us_a_launch(fn):
@@ -1427,15 +1463,21 @@ def bench_eva_decode(forced=(), B=20, H=32, hd=128, ps=32, window=2048,
         for name, fn in (
             ("kernel", functools.partial(attend, **kw)),
             ("hollow", functools.partial(attend, hollow=True, **kw)),
-            ("kernel_and_summary_row",
-             functools.partial(attend_and_summarize, **kw)),
+            ("no_closer", functools.partial(attend_and_close, **kw)),
+            ("two_closers",
+             functools.partial(attend_and_close, closing=2, **kw)),
+            ("rewrite_a_step", functools.partial(attend_and_rewrite, **kw)),
         ):
             us = us_a_launch(fn)
             line[f"{name}_us"] = round(us, 1)
-            if name != "hollow":
+            if name in ("kernel", "no_closer"):
                 line[f"{name}_gb_per_s"] = round(moved / us / 1e3, 1)
                 line[f"{name}_hbm_roofline_pct"] = round(
                     100 * moved / HBM_BYTES_PER_S / (us * 1e-6), 1)
+        line["close_us_a_slot"] = round(
+            (line["two_closers_us"] - line["no_closer_us"]) / 2, 1)
+        line["rewrite_us_a_step"] = round(
+            line["rewrite_a_step_us"] - line["kernel_us"], 1)
         yield line
 
 

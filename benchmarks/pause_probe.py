@@ -14,10 +14,9 @@ counters, the collector's callbacks a second, ``totals.moe`` and
 ``totals.dsa`` at the end (the expert layers' counters, ``overflow``
 among them; the learned selection's), the growth of ``decode_steps``
 beside ``decode_steps_fused_head`` (PR 50: the steps whose program kept
-its logits on the chip), after a traced run the device's seconds under
-the scopes ``head`` / ``logits`` / ``sample`` (``head_scopes``), and the
-server log's
-``engine_pause`` lines (but the warm-up's, whose cause is ``compile``).
+its logits on the chip) and of ``totals.eva`` (PR 52), after a traced
+run the device's seconds under the scopes ``head`` / ``logits`` /
+``sample`` (``head_scopes``), and the server log's ``engine_pause`` lines (but the warm-up's, whose cause is ``compile``).
 ``--stall-at S`` arms the ``stall`` fault's ``delay`` once, S seconds
 into the window, through ``POST /debug/faults`` (the server is started
 with ``VGT_FAULTS_HTTP=1``): the provoked pause of the issue's
@@ -137,6 +136,11 @@ def report(seconds: float, trace: int = 0) -> Dict[str, Any]:
                 "gateway.stream_handoffs", "gateway.handoff_wait_s",
                 "gateway.handoff_waits", "decode_steps",
                 "decode_steps_fused_head",
+                # an EVA spec's rows and windows (PR 52: the summary
+                # rows a decode step writes are 128 a layer of every
+                # window it closes, ``chunk_rows_written`` over
+                # ``windows_closed``)
+                "eva",
             )
         },
         "head_scopes": head_scopes() if trace else None,

@@ -90,10 +90,14 @@ def suffix(params, cache, slot, toks, start, bucket):
     return np.asarray(logits)[0]
 
 
-def decode(params, cache, feeds, spec=SPEC):
-    """feeds: {slot: (token, position)}; the other slots idle."""
+def decode(params, cache, feeds, spec=SPEC, parked=None):
+    """feeds: {slot: (token, position)}; the other slots idle, at the
+    stale positions ``parked`` {slot: position} (0 where none is
+    given)."""
     tk, pos = np.zeros((SLOTS,), np.int32), np.zeros((SLOTS,), np.int32)
     active = np.zeros((SLOTS,), bool)
+    for slot, p in (parked or {}).items():
+        pos[slot] = p
     for slot, (t, p) in feeds.items():
         tk[slot], pos[slot], active[slot] = t, p, True
     logits, cache.kp, cache.vp, cache.state, _ = decoder.decode_forward(
@@ -109,13 +113,38 @@ def reference(toks, first):
                            [first])[0]
 
 
+def open_summary_pages(cache, slot, position):
+    """The pages the sequence holds for the summary rows of the window
+    ``position`` lies in: the OPEN window's, which no query reads."""
+    per = W // C // PS
+    first = per * (position // W)
+    return [int(p) for p in cache.tables[slot, first:first + per] if p]
+
+
+def poison(cache, pages):
+    """NaN in every row of ``pages``, K and V, all layers."""
+    at = jnp.asarray(pages, jnp.int32)
+    cache.kp = cache.kp.at[:, :, at].set(jnp.nan)
+    cache.vp = cache.vp.at[:, :, at].set(jnp.nan)
+
+
 def served(params, cache, slot, toks, n_prompt, bucket):
     """Prompt pass then decode steps over ``toks``: the logits at
-    positions n_prompt - 1 .. len - 1."""
+    positions n_prompt - 1 .. len - 1.  The open window's summary rows
+    are DEAD until the step that fills the window's last row writes
+    them (ops/eva.py ``decode_close``): every decode step finds NaN
+    there, whatever the prompt pass left, and the step that closes the
+    window leaves its pages finite in every layer."""
     out = [prefill(params, cache, [(slot, toks[:n_prompt], len(toks))],
                    bucket)[0]]
     for p in range(n_prompt, len(toks)):
+        dead = open_summary_pages(cache, slot, p)
+        poison(cache, dead)
         out.append(decode(params, cache, {slot: (toks[p], p)})[slot])
+        if p % W == W - 1:
+            assert dead and all(
+                np.isfinite(np.asarray(pool)[:, :, dead]).all()
+                for pool in (cache.kp, cache.vp)), p
     return np.stack(out)
 
 
@@ -149,6 +178,43 @@ def test_unequal_rows_in_one_batch_with_padding(params):
     for s in (0, 1):
         want = reference(seqs[s], len(seqs[s]) - 1)[1]
         assert np.abs(step[s] - want).max() < TOL
+
+
+def test_two_slots_close_on_one_step_and_a_parked_slot_writes_nothing(
+        params):
+    """Slots 0 and 2 fill row 31 of their windows (the first and the
+    second) on the SAME step and both get their summary rows; slot 1
+    idles at a stale position that is also = 31 mod 32 and writes
+    nothing: of the allocator's pages (the trash page apart) only the
+    two closers' summary pages change.  The step after reads them."""
+    cache = Cache()
+    seqs = {0: _tokens(21, 34), 1: _tokens(22, 40), 2: _tokens(23, 66)}
+    first = {0: 31, 1: 39, 2: 63}  # the prompt, then decode from there
+    for s, t in seqs.items():
+        prefill(params, cache, [(s, t[:first[s]], len(t))],
+                32 if first[s] <= 32 else 96)
+    closed = sorted(open_summary_pages(cache, 0, 31)
+                    + open_summary_pages(cache, 2, 63))
+    assert len(closed) == 4
+    poison(cache, closed)  # what the prompts left there is not read
+    before = (np.asarray(cache.kp), np.asarray(cache.vp))
+    feeds = lambda k: {s: (seqs[s][first[s] + k], first[s] + k)
+                       for s in (0, 2)}
+    got = [decode(params, cache, feeds(0), parked={1: 63})]
+    parked = open_summary_pages(cache, 1, 63)
+    assert parked and not set(parked) & set(closed)
+    for old, pool in zip(before, (cache.kp, cache.vp)):
+        now = np.asarray(pool)
+        moved = np.flatnonzero(
+            ((now != old) & ~(np.isnan(now) & np.isnan(old)))[
+                :, :, 1:POOL].any(axis=(0, 1, 3, 4))) + 1
+        assert moved.tolist() == closed
+        assert np.isfinite(now[:, :, closed]).all()
+    got.append(decode(params, cache, feeds(1), parked={1: 63}))
+    for s in (0, 2):
+        want = reference(seqs[s][:first[s] + 2], first[s] + 1)
+        for k in (0, 1):
+            assert np.abs(got[k][s] - want[k]).max() < TOL, (s, k)
 
 
 def test_a_later_chunk_that_starts_mid_window(params):

@@ -84,8 +84,43 @@ def test_unequal_rows_through_the_engine_and_what_it_reports(engine):
         W // C * (t // W) for n in lens for t in range(n, n + steps - 1))
     assert eva["windows_closed"] >= sum(
         (n + steps - 1) // W - n // W for n in lens)
+    # the summary rows a decode step writes: W / C a layer of a window
+    # it closes, and none at any other step
+    assert eva["chunk_rows_written"] == W // C * 4 * eva["windows_closed"]
     ticks = [t for t in engine.flight.ticks() if "window_bytes" in t]
     assert ticks and max(t["window_bytes"] for t in ticks) <= cache["bytes"]
+
+
+def test_windows_close_inside_a_chunk_beside_a_parked_slot(engine):
+    """Three prompts in one wave.  The one of 23 has its five tokens
+    after one chunk of 4 steps and is switched off behind the chunk
+    that was in flight by then: its slot idles at position 31, = W - 1,
+    beside the others for the rest of the run, its pages given back,
+    and closes nothing (if it did, it would write the rows of pages
+    that are no longer its own; that it writes NOTHING is held page by
+    page in tests/test_evabyte.py).  The prompts of 58 and 90 (one
+    prompt program, so one decode step for both) each fill their
+    window's last row at their sixth step, the second of a chunk of 4:
+    two closers on ONE step, mid-chunk, and the steps after read what
+    it wrote.  The summary rows a decode step writes are the closes'
+    alone."""
+    before = dict(engine.perf.totals().get("eva", {}))
+    rng = np.random.default_rng(8)
+    prompts = [contract.tokens(rng, n) for n in (23, 58, 90)]
+    seqs = [engine.submit_tokens(p, contract.lp_params(m))
+            for p, m in zip(prompts, (5, 14, 14))]
+    for s, p in zip(seqs, prompts):
+        assert s.done_event.wait(timeout=600) and s.error is None, s.error
+        contract.agree(FAMILY, engine, s, p)
+    assert engine.allocator.num_used == 0
+    grew = {k: v - before.get(k, 0)
+            for k, v in engine.perf.totals()["eva"].items()}
+    assert grew["windows_closed"] >= 2
+    assert grew["chunk_rows_written"] == (
+        W // C * 4 * grew["windows_closed"])
+    state = engine._dec_state
+    idle = np.asarray(state["positions"])[~np.asarray(state["active"])]
+    assert W - 1 in idle.tolist()
 
 
 def test_a_sequence_holds_a_page_for_every_page_of_summary_rows(engine):

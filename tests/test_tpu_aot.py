@@ -1114,13 +1114,15 @@ def _eva_cut(A):
     return spec, params, pool, state
 
 
-def test_eva_decode_chunk_compiles_on_v5e(v5e):
-    """The decode chunk of the cut at the published widths: both pool
-    arrays (summary pages and the slots' windows in one) aliased input
-    to output and never re-laid, the paged decode kernel launched under
-    its own name over the step's ONE sequence of rows, and its
-    temporaries (0.81 GB when written: the weights' re-laid copies)
-    far under the 8.06 GB of cache."""
+# the decode chunk's temporaries at the parent of PR 52, which rewrote
+# the open chunk's summary row at every step
+EVA_PARENT_DECODE_TEMP_BYTES = 806_518_272
+
+
+@pytest.fixture(scope="module")
+def eva_decode_chunk(v5e):
+    """(the compiled decode chunk of the cut at the published widths,
+    its pool array)."""
     from vgate_tpu.runtime.step_programs import _decode_chunk
 
     A = _abstract(v5e)
@@ -1136,6 +1138,18 @@ def test_eva_decode_chunk_compiles_on_v5e(v5e):
         seeds=A((B,), jnp.int32), steps=A((B,), jnp.int32),
         all_greedy=True, guard=True, state=state,
     ).compile()
+    return compiled, pool
+
+
+def test_eva_decode_chunk_compiles_on_v5e(eva_decode_chunk):
+    """The decode chunk of the cut at the published widths: both pool
+    arrays (summary pages and the slots' windows in one) aliased input
+    to output and never re-laid, though they now pass through the
+    closers' loop in every EVA layer; the paged decode kernel launched
+    under its own name over the step's ONE sequence of rows, and its
+    temporaries (0.81 GB when written: the weights' re-laid copies)
+    far under the 8.06 GB of cache."""
+    compiled, pool = eva_decode_chunk
     mem = compiled.memory_analysis()
     assert _nbytes((pool, pool)) == 2 * 8 * 32 * 1921 * 32 * 128 * 2
     assert mem.alias_size_in_bytes >= _nbytes((pool, pool)), (
@@ -1144,6 +1158,34 @@ def test_eva_decode_chunk_compiles_on_v5e(v5e):
     text = compiled.as_text()
     assert "paged_decode_attention_pallas" in text
     assert "{4,1,3,2,0" not in text, "XLA re-laid the pool out"
+
+
+@pytest.mark.parametrize("what", ["no_rewrite_a_step", "temporaries"])
+def test_eva_decode_chunk_pools_chunks_where_a_window_closes(
+        eva_decode_chunk, what):
+    """A summary row is written when its window closes (ops/eva.py
+    ``decode_close``, PR 52).  ``no_rewrite_a_step``: no gather of the
+    open chunk's rows of every slot (``[20, 16, 32, 128]``, the parent's
+    eight a step) is left, every operation of the pooling lies inside
+    the closers' loop, and what that loop reads is one contiguous slice
+    of a pool, the 16 window pages under a page of summary rows.
+    ``temporaries``: the loop costs no more than the rewrite did,
+    806,487,040 bytes against the parent's 806,518,272 (806,163,968 with
+    neither; 808,321,024 with a whole window a trip, 806,744,576 with
+    the loop's page ids computed inside it)."""
+    compiled, _ = eva_decode_chunk
+    if what == "temporaries":
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes <= EVA_PARENT_DECODE_TEMP_BYTES, (
+            mem.temp_size_in_bytes)
+        return
+    text = compiled.as_text()
+    _assert_no_buffer(text, "20,16", "32,128")
+    lines = [l for l in text.splitlines() if "eva_summarize" in l]
+    assert lines and all("eva_summarize/while" in l for l in lines)
+    assert not any(" gather(" in l for l in lines)
+    assert any(" dynamic-slice(" in l and "bf16[1,32,16,32,128]" in l
+               for l in lines)
 
 
 def test_eva_prompt_program_fits_beside_the_cache_on_v5e(v5e):

@@ -1173,14 +1173,17 @@ def decode_forward(
                 window=spec.sliding_window)
         eva_step = None
         if spec.eva_layers:
-            eva_step = lambda kp, vp, lp, layer: eva.decode_summarize(
-                kp, vp, lp["eva_phi"], lp["eva_mu"], layer, page_tables,
-                win_pages, positions, active, spec.eva_window,
+            # the windows this step's rows close: once, for every layer
+            closers = eva.decode_closers(
+                page_tables, win_pages, positions, active, spec.eva_window,
+                spec.eva_chunk, ps)
+            eva_step = lambda kp, vp, lp, layer: eva.decode_close(
+                kp, vp, lp["eva_phi"], lp["eva_mu"], layer, closers,
                 spec.eva_chunk, spec.head_dim ** -0.5)
         x, k_pages, v_pages, state, stats = hybrid.decode_forward(
             params, spec, x, positions, k_pages, v_pages, state, active,
             write_attend, use_pallas, ring_write_attend=ring_step,
-            dsa_steps=dsa_steps, eva_summarize=eva_step,
+            dsa_steps=dsa_steps, eva_close=eva_step,
         )
         return head(params, spec, x), k_pages, v_pages, state, stats
 

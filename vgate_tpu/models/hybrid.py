@@ -1989,7 +1989,7 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
 def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
                    state, active, write_attend, use_pallas: bool,
                    ring_write_attend=None, dsa_steps=None,
-                   eva_summarize=None):
+                   eva_close=None):
     """One decode step over embedded rows x [B, D], row = slot.
     ``write_attend(q, k, v, kp, vp, layer)`` is the caller's cache step:
     the token's K and V into the pool and its attention over it;
@@ -1997,8 +1997,9 @@ def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
     ``dsa_steps`` (a spec that picks, ``v_pages`` its index keys' array)
     ``(pick, attend, the positions a pick holds)``: ``_dsa_step``.  An
     EVA spec: ``write_attend`` runs over the step's rows as ONE paged
-    sequence (ops/eva.py ``decode_view``), and ``eva_summarize(kp, vp,
-    lp, layer)`` then rewrites the open chunk's summary row.
+    sequence (ops/eva.py ``decode_view``), and ``eva_close(kp, vp, lp,
+    layer)`` then writes the summary rows of every window the step
+    filled (ops/eva.py ``decode_close``): on most steps, of none.
     Returns (x, k_pages, v_pages, state, stats [4])."""
     if spec.fp32_residual:
         x = x.astype(jnp.float32)
@@ -2050,7 +2051,7 @@ def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
                 with jax.named_scope("eva_attend"):
                     attn, kp, vp = write_attend(
                         q[:, 0], k[:, 0], v[:, 0], kp, vp, index)
-                kp, vp = eva_summarize(kp, vp, lp, index)
+                kp, vp = eva_close(kp, vp, lp, index)
                 out = _gated_out(_heads_flat(attn), None, lp, normed.dtype)
             return out, kp, vp, st, None
         if kind == "swa":

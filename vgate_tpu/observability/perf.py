@@ -1163,9 +1163,11 @@ class PerfRecorder:
         dispatch): step k of a sequence is the token at ``t = n - 1 +
         k``, which reads the ``t mod window + 1`` live rows of its open
         window and one summary row for every chunk of the ``t //
-        window`` closed ones, writes its own row and the open chunk's
-        summary row, in each of ``layers`` layers, and closes a window
-        when it fills the last row."""
+        window`` closed ones and writes its own row, in each of
+        ``layers`` layers; the step that fills a window's last row
+        closes it, and writes its ``window / chunk`` summary rows a
+        layer (ops/eva.py ``decode_close``): ``chunk_rows_written ==
+        window / chunk x layers x windows_closed``."""
         per = window // chunk
         win = ch = closed = 0
         for n in lens:
@@ -1178,7 +1180,7 @@ class PerfRecorder:
             ("window_rows_read", win * layers),
             ("chunk_rows_read", ch * layers),
             ("window_rows_written", steps * len(lens) * layers),
-            ("chunk_rows_written", steps * len(lens) * layers),
+            ("chunk_rows_written", per * closed * layers),
             ("windows_closed", closed),
         ):
             self._eva[name] = self._eva.get(name, 0) + add

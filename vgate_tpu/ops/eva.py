@@ -18,11 +18,26 @@ arrays (``window_pages``), so that a decode step's rows, the closed
 windows' summary pages and then the window's own, are ONE sequence to
 the paged decode kernel (``decode_view``): it reads the live rows alone,
 under one online softmax, and writes the step's row where
-``kv_write_tokens`` would.  A summary row is rewritten from its chunk's
-rows every step (``decode_summarize``), so a window closes inside a
-decode chunk with no host round trip and no pass of its own: by the
-step that fills row ``W - 1`` every row of the window is in the pool.
-256 KB a slot and layer read against the ~29 MB the attention reads.
+``kv_write_tokens`` would.
+
+WHEN a summary row is written.  A query in window ``w`` reads the
+summary rows of CLOSED windows alone (``decode_view``'s table stops at
+``per x closed`` summary pages; ``chunk_keys``: a row is seen from
+``closes`` on), so the rows of the open window are first read by the
+step AFTER the one that fills row ``W - 1``.  A decode step therefore
+writes none; the step that fills row ``W - 1`` of a slot's window, once
+its own row is in the window's pages, writes ALL ``W / c`` summary rows
+of that window from the window's ``W`` exact rows, into the ``W / c /
+ps`` whole pages of the sequence that own them (``decode_close``).  That
+is early enough: the window's pages hold every row of the window until
+the next window's first step overwrites row 0, which is the step after;
+and a window closes inside a decode chunk with no host round trip.  A
+prompt pass writes the summaries of the chunks its own rows touch
+(``models/hybrid.py _eva_prompt``), the open window's partial ones among
+them: nobody reads those, and the close overwrites them from the exact
+rows.  Only a closing slot pays (one step in ``W`` a slot): 33.6 MB of
+contiguous reads a layer at the published sizes, where a rewrite of the
+open chunk's row at every step was a sixth of the step.
 """
 
 from __future__ import annotations
@@ -30,7 +45,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from vgate_tpu.ops.kv_quant import gather_pages, kv_write_tokens
+from vgate_tpu.ops.kv_quant import gather_pages, kv_write_pages
 
 # a position no query reaches: the ``lo`` of a key nobody sees
 NEVER = 1 << 30
@@ -87,30 +102,56 @@ def decode_view(page_tables, win_pages, positions, window: int, chunk: int,
     return tables, (window // chunk) * closed + positions % window
 
 
-@jax.named_scope("eva_summarize")
-def decode_summarize(kp, vp, phi, mu, layer, page_tables, win_pages,
-                     positions, active, window: int, chunk: int,
-                     scale: float):
-    """After a decode step wrote its row: the open chunk's summary from
-    its rows so far (the window's pages), into the pool row the chunk
-    owns.  Idle slots write the trash page."""
-    ps = kp.shape[-2]
-    B = positions.shape[0]
-    first = positions // chunk * chunk
-    rows = (first % window)[:, None] + jnp.arange(chunk)[None, :]  # [B, c]
-    pages = jnp.take_along_axis(win_pages, rows // ps, axis=1)
-    kv = jnp.arange(kp.shape[1], dtype=jnp.int32)
-    take = lambda pool: pool[layer, kv[None, None, :], pages[..., None],
-                             (rows % ps)[..., None]]  # [B, c, KV, hd]
-    valid = first[:, None] + jnp.arange(chunk)[None, :] <= positions[:, None]
-    ks, vs = summarize(take(kp), take(vp), phi, mu, valid, chunk, scale)
-    row = positions // chunk
-    ids = page_tables[jnp.arange(B), row // ps]
+def decode_closers(page_tables, win_pages, positions, active, window: int,
+                   chunk: int, ps: int):
+    """What a decode step's rows close, as ``decode_close``'s work list:
+    ``(src [B x per], dst [B x per], count)``, one entry for every page
+    of summary rows (``per`` a window).  The slots whose step fills row
+    ``window - 1`` come first; ``src`` is the first of the ``chunk``
+    CONSECUTIVE window pages (``window_pages``) that hold the page's
+    ``ps x chunk`` exact rows, ``dst`` the sequence's page that owns its
+    ``ps`` summary rows, ``count`` the entries that are work.  An idle
+    slot closes nothing, wherever its stale position stands.  Once a
+    step, for every layer."""
+    per = window // chunk // ps
+    closing = positions % window == window - 1
     if active is not None:
-        ids = jnp.where(active, ids, 0)
-    kp = kv_write_tokens(kp, ids, row % ps, ks[:, 0], layer=layer)
-    vp = kv_write_tokens(vp, ids, row % ps, vs[:, 0], layer=layer)
-    return kp, vp
+        closing &= active
+    order = jnp.argsort(~closing, stable=True)
+    page = jnp.arange(per, dtype=jnp.int32)[None, :]
+    src = win_pages[order, :1] + chunk * page
+    dst = jnp.take_along_axis(
+        page_tables[order], per * (positions[order] // window)[:, None] + page,
+        axis=1)
+    return (src.reshape(-1), dst.reshape(-1),
+            per * jnp.sum(closing, dtype=jnp.int32))
+
+
+@jax.named_scope("eva_summarize")
+def decode_close(kp, vp, phi, mu, layer, closers, chunk: int, scale: float):
+    """After a decode step wrote its row: every window that row filled
+    gets its summary rows, from its exact rows, into the whole pages of
+    the sequence that own them.  A loop over ``closers``
+    (``decode_closers``), a page of summary rows a trip: one contiguous
+    slice of each pool in, one page out.  A step with no closer runs no
+    trip of it.  (A page a trip, its ids from a list made once a step:
+    a whole window a trip, or ids worked out inside the loop, left the
+    compiled chunk more temporaries than the rewrite of every step had:
+    tests/test_tpu_aot.py.)"""
+    KV, ps, hd = kp.shape[1], kp.shape[-2], kp.shape[-1]
+    src, dst, count = closers
+    seen = jnp.ones((ps * chunk,), bool)
+
+    def close(i, pools):
+        rows = lambda pool: jnp.moveaxis(jax.lax.dynamic_slice(
+            pool, (layer, 0, src[i], 0, 0), (1, KV, chunk, ps, hd)
+        ).reshape(KV, ps * chunk, hd), 0, 1)
+        ks, vs = summarize(*map(rows, pools), phi, mu, seen, chunk, scale)
+        return tuple(
+            kv_write_pages(pool, dst[i], jnp.moveaxis(t, 0, 1), layer=layer)
+            for pool, t in zip(pools, (ks, vs)))
+
+    return jax.lax.fori_loop(0, count, close, (kp, vp))
 
 
 def gather_rows(pool, tables, layer):
