@@ -1,7 +1,8 @@
 """How close the served SELECTION comes to the reference's, on the chip.
 
     chiprun --timeout 3000 -- python benchmarks/dsa_selection_check.py
-        [--config perfbench/configs/glm-5.2-l5e16.json] [--cpu]
+        [--config perfbench/configs/glm-5.2-l5e16.json |
+                  perfbench/configs/keye-vl-2.0-30b-a3b-l12e32.json] [--cpu]
 
 ``correct`` compares log-probabilities; what a learned sparse attention
 adds to the comparison is a discrete choice (``index_topk`` of a
@@ -39,16 +40,19 @@ OUT = os.path.join(ROOT, "chiprun_out", "dsa_selection")
 
 
 def reference_side(cfg_path: str, job_path: str, out_path: str,
-                   rounding: str) -> None:
+                   rounding: str, module: str = "") -> None:
     """The CPU process: the reference's log-probabilities of the job's
     top ids and its selections in the picking layers (packed bits)."""
     import jax.numpy as jnp
     import numpy as np
 
-    from perfbench.references import glm_moe_dsa as ref
+    import importlib
 
     with open(cfg_path) as fh:
         cfg = json.load(fh)
+    # the configuration's own reference (GLM-5.2's, Keye-VL-2.0's): each
+    # has draw_layer, draw_ends, picks and logprobs(selections=)
+    ref = importlib.import_module(module or cfg["reference"]["module"])
     with open(job_path) as fh:
         job = json.load(fh)
     if rounding == "float8":
@@ -112,9 +116,10 @@ def serve_side(cfg_path: str, cpu: bool) -> dict:
     n_pages = min(b for b in buckets if b >= longest) // ps
     geo = KVGeometry(
         num_layers=spec.attn_layers, num_pages=n_pages + 1, page_size=ps,
-        kv_heads=1, head_dim=spec.cache_head_dim, max_model_len=n_pages * ps,
-        dtype_bytes=jnp.dtype(dtype).itemsize, pools=1,
-        index_layers=spec.index_layers, index_dim=spec.index_head_dim)
+        kv_heads=spec.cache_heads, head_dim=spec.cache_head_dim,
+        max_model_len=n_pages * ps, dtype_bytes=jnp.dtype(dtype).itemsize,
+        pools=spec.kv_pools, index_layers=spec.index_layers,
+        index_dim=spec.index_key_lanes)
     # what the forwards picked, taken out of the traced programs
     masks, picks = [], []
     select, positions = hybrid._dsa_prompt_select, dsa.select_positions
@@ -202,7 +207,8 @@ def serve_side(cfg_path: str, cpu: bool) -> dict:
     out_path = os.path.join(OUT, "reference.npz")
     subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--job", job_path,
-         "--config", ref_cfg, "--out", out_path],
+         "--config", ref_cfg, "--out", out_path,
+         "--module", cfg["reference"]["module"]],
         check=True, env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=ROOT)
     want = np.load(out_path)
     expected = [want[f"lp_{s}"].tolist() for s in range(len(prompts))]
@@ -247,9 +253,13 @@ def main() -> int:
     ap.add_argument("--job", help="reference side: the job to compute")
     ap.add_argument("--out")
     ap.add_argument("--round", default="", choices=("", "float8"))
+    ap.add_argument("--module", default="",
+                    help="reference side: its module, where --config is "
+                    "the tiny preset's sizes and names none")
     args = ap.parse_args()
     if args.job:
-        reference_side(args.config, args.job, args.out, args.round)
+        reference_side(args.config, args.job, args.out, args.round,
+                       args.module)
         if args.round:  # the reading against the served values
             import numpy as np
 

@@ -67,10 +67,12 @@ def test_auto_num_pages_counts_the_latent_row():
 
 @pytest.mark.parametrize(
     "name", sorted(n for n, s in _PRESETS.items()
-                   if not s.is_mla and not s.is_encoder))
+                   if not s.rows_cache and not s.is_encoder))
 def test_every_other_preset_keeps_its_page_bytes(name):
     """K and V of every KV head in every layer that has pages: the
-    formula the parent had, for every preset without latent attention."""
+    formula the parent had, for every preset whose pool is head-major K
+    and V (not a row a token: latent attention, or K over V under a
+    selection, whose page tests/test_keye_dsa.py holds)."""
     spec = _PRESETS[name]
     for width, scale in ((2, 0), (4, 0), (1, 2)):
         geo = KVGeometry(

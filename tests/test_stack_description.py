@@ -886,6 +886,47 @@ FROZEN = {
         "indexer": "",
         "mlp": "densex40",
     },
+    # GQA attention under a selection of EVERY layer's own (PR 53): one
+    # group, K over V in one array (``kv_pools`` counts its two rows)
+    "Kwai-Keye/Keye-VL-2.0-30B-A3B": {
+        "cut": (0, 1, 48),
+        "lead": "",
+        "period": "dsa/layer/input_norm/0 moe/layer/post_norm/0",
+        "layers": (48, 48, 0, 0, 48),
+        "kv_pools": 2,
+        "windows": "0x48",
+        "num_params": 30640656384,
+        "hybrid": True,
+        "recurrent": "",
+        "indexer": "fullx48",
+        "mlp": "sparsex48",
+    },
+    "tiny-keye-dsa": {
+        "cut": (0, 1, 4),
+        "lead": "",
+        "period": "dsa/layer/input_norm/0 moe/layer/post_norm/0",
+        "layers": (4, 4, 0, 0, 4),
+        "kv_pools": 2,
+        "windows": "0x4",
+        "num_params": 325376,
+        "hybrid": True,
+        "recurrent": "",
+        "indexer": "fullx4",
+        "mlp": "sparsex4",
+    },
+    "config:keye-vl-2.0-30b-a3b-l12e32.json": {
+        "cut": (0, 1, 12),
+        "lead": "",
+        "period": "dsa/layer/input_norm/0 moe/layer/post_norm/0",
+        "layers": (12, 12, 0, 0, 12),
+        "kv_pools": 2,
+        "windows": "0x12",
+        "num_params": 2224347648,
+        "hybrid": True,
+        "recurrent": "",
+        "indexer": "fullx12",
+        "mlp": "sparsex12",
+    },
 }
 
 FIELDS = [
@@ -924,7 +965,8 @@ FIELDS = [
     ("window_pattern", ""), ("global_rope", True), ("first_k_dense", 0),
     ("indexer_pattern", ""), ("first_layer", 0), ("index_topk", 0),
     ("index_n_heads", 0), ("index_head_dim", 0),
-    ("indexer_rope_interleave", False), ("eva_window", 0),
+    ("indexer_rope_interleave", False), ("mrope_section", ()),
+    ("eva_window", 0),
     ("eva_chunk", 0), ("num_pred_heads", 1), ("fp32_residual", False),
     ("conv_pattern", ""), ("conv_L_cache", 0), ("conv_bias", False),
     ("router_norm_eps", 1e-20), ("kv_head_pack", 1),

@@ -1526,3 +1526,143 @@ def test_granite_prompt_program_fits_beside_the_state_on_v5e(
         "the pool or the state is copied")
     assert mem.temp_size_in_bytes < GRANITE_PROGRAM_ROOM
     assert "flash_prefill_attention_pallas" in compiled.as_text()
+
+
+# ---- GQA attention under a selection: the Keye-VL-2.0 cut as its cell
+# serves it (16 slots x 16,384 tokens; a token's K over its V in one
+# array, its index key of 64 in a row of 128 lanes in the other)
+
+KEYE_CUT = ("Kwai-Keye/Keye-VL-2.0-30B-A3B", dict(
+    name="keye-cut", num_layers=12, num_experts=32, vocab_size=37984,
+    eos_token_id=37983, bos_token_id=37982))
+KEYE_SLOTS, KEYE_CTX = 16, 16384
+
+
+def _keye_cut(A):
+    """(spec, abstract parameters, the pool of K over V, the index keys'
+    array) at the cell's size."""
+    spec, params = _cut_and_shapes(A, *KEYE_CUT)
+    pages = KEYE_SLOTS * KEYE_CTX // PAGE + 1
+    pool = A((spec.attn_layers, 1, pages, PAGE, 2, spec.cache_head_dim),
+             jnp.bfloat16)
+    keys = A((spec.index_layers, 1, pages, PAGE, spec.index_key_lanes),
+             jnp.bfloat16)
+    assert pool.shape == (12, 1, 8193, 32, 2, 512)
+    assert keys.shape == (12, 1, 8193, 32, 128)
+    return spec, params, pool, keys
+
+
+def test_the_kv_selection_kernels_compile_at_the_cells_shapes_on_v5e(v5e):
+    """The three launches that are new at this cell's shapes: the
+    scoring pass at 16 heads x 64 against keys held in 128 lanes (a
+    decode step's over 512 pages a slot, a prompt's 1,024-row block
+    against 16,384 keys), the attention that fetches a picked token's K
+    over V (32 heads in 4 groups over rows of 512 lanes, 2,048 picks a
+    slot), and the page writer over the pool of pairs; nothing beside
+    their operands."""
+    from vgate_tpu.ops.pallas.dsa import (
+        dsa_index_scores_pallas, dsa_kv_decode_attention_pallas,
+        dsa_prompt_scores_pallas, dsa_write_pages_pallas)
+
+    A = _abstract(v5e)
+    spec, _, pool, keys = _keye_cut(A)
+    B, k, Hi, lanes = KEYE_SLOTS, spec.index_topk, 16, 128
+    assert (spec.index_n_heads, spec.index_head_dim) == (Hi, 64)
+    scores = dsa_index_scores_pallas.lower(
+        A((B, Hi, lanes), jnp.bfloat16), A((B, Hi), jnp.float32), keys,
+        A((B, KEYE_CTX // PAGE), jnp.int32), A((B,), jnp.int32),
+        A((), jnp.int32)).compile()
+    assert "dsa_index_scores_pallas" in scores.as_text()
+    block = dsa_prompt_scores_pallas.lower(
+        A((1024, Hi, lanes), jnp.bfloat16), A((1024, Hi), jnp.float32),
+        A((KEYE_CTX, lanes), jnp.bfloat16), A((), jnp.int32)).compile()
+    assert block.memory_analysis().temp_size_in_bytes < 4 << 20
+    attend = dsa_kv_decode_attention_pallas.lower(
+        A((B, spec.num_heads, spec.head_dim), jnp.bfloat16), pool,
+        A((B, k), jnp.int32), A((B,), jnp.int32), A((), jnp.int32),
+        scale=spec.head_dim ** -0.5).compile()
+    # the row-wide query and result, [16, 32, 512] each, and no more
+    assert attend.memory_analysis().temp_size_in_bytes < 4 << 20
+    assert "dsa_decode_attention_pallas" in attend.as_text()
+    write = dsa_write_pages_pallas.lower(
+        pool, A((1, KEYE_CTX // PAGE), jnp.int32),
+        A((1, KEYE_CTX // PAGE, PAGE, 2, 512), jnp.bfloat16),
+        A((), jnp.int32)).compile()
+    assert write.memory_analysis().alias_size_in_bytes >= _nbytes(pool)
+
+
+def test_engine_refuses_a_kv_row_that_is_no_whole_lane_tile():
+    """A token's K (or V) row goes HBM -> VMEM as a descriptor's trailing
+    block: whole 128-lane tiles.  The published 4 x 128 passes; the tiny
+    preset's 2 x 16 is refused by name under ``tpu.use_pallas``."""
+    from vgate_tpu.runtime.engine_core import refuse_unbuildable_kernels
+
+    refuse_unbuildable_kernels(
+        spec_for_model_id(KEYE_CUT[0]), kv_quant=False)
+    with pytest.raises(ValueError, match=r"aligned to tiling \(128\)"):
+        refuse_unbuildable_kernels(
+            spec_for_model_id("tiny-keye-dsa"), kv_quant=False)
+
+
+def test_kv_selection_decode_chunk_compiles_on_v5e(v5e):
+    """The decode chunk of the cut: both arrays aliased input to output
+    and never re-laid, twelve layers' scoring pass and fetching
+    attention under their own names, NO gather of rows and no dense
+    paged kernel (a context of at most 2,048 tokens goes through the
+    same kernel).  Temporaries 0.23 GB (the configuration's
+    ``server.why``)."""
+    from vgate_tpu.runtime.step_programs import _decode_chunk
+
+    A = _abstract(v5e)
+    spec, params, pool, keys = _keye_cut(A)
+    B = KEYE_SLOTS
+    compiled = _decode_chunk.lower(
+        params, spec, A((B,), jnp.int32), A((B,), jnp.int32), pool, keys,
+        A((B, KEYE_CTX // PAGE), jnp.int32), A((B,), jnp.bool_),
+        A((B,), jnp.float32), A((B,), jnp.float32), A((B,), jnp.int32),
+        A((2,), jnp.uint32), A((), jnp.uint32),
+        num_steps=8, use_pallas=True, max_position=KEYE_CTX - 1,
+        seeds=A((B,), jnp.int32), steps=A((B,), jnp.int32),
+        all_greedy=True, guard=True,
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _nbytes((pool, keys)), (
+        "a pool is copied")
+    assert mem.temp_size_in_bytes < 0.3e9, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    for name in ("dsa_index_scores_pallas", "dsa_decode_attention_pallas",
+                 "moe_grouped_matmul_pallas"):
+        assert name in text, name
+    assert "paged_decode_attention" not in text
+    assert "dsa_gather" not in text
+    _assert_no_buffer(text, B * spec.index_topk, spec.cache_head_dim)
+    _assert_no_buffer(text, f"{B},{spec.index_topk}", spec.cache_head_dim)
+    for shape, minor in (("bf16[12,1,8193,32,2,512]", "{5,4,3,2,"),
+                         ("bf16[12,1,8193,32,128]", "{4,3,2,")):
+        layouts = {line.split(shape, 1)[1].split("}", 1)[0]
+                   for line in text.splitlines() if shape + "{" in line}
+        assert layouts and all(l.startswith(minor) for l in layouts), layouts
+
+
+@pytest.mark.slow  # 20 s alone; the builder's command (CHANGES.md, PR 53)
+def test_kv_selection_prompt_program_fits_beside_the_pool_on_v5e(v5e):
+    """The 16,384-row prompt program of the cut: the scoring kernel, the
+    flash kernel under a mask (one mask for the four KV groups) and the
+    page writer in it, both arrays aliased, no [16,384, 16,384] float32
+    scores, the selection once as bytes, and the temporaries (0.82 GB)
+    small enough beside 4.45 GB of weights and 7.25 GB of pages."""
+    A = _abstract(v5e)
+    spec, params, pool, keys = _keye_cut(A)
+    compiled = _prompt_program(A, spec, params, pool, keys, None,
+                               bucket=KEYE_CTX)
+    mem = compiled.memory_analysis()
+    held = _nbytes((params, pool, keys))
+    assert 11.6e9 < held < 11.8e9
+    assert mem.alias_size_in_bytes >= _nbytes((pool, keys))
+    assert mem.temp_size_in_bytes < 1.0e9, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    for name in ("dsa_index_scores_pallas", "dsa_prefill_attention_pallas",
+                 "dsa_write_pages_pallas", "moe_grouped_matmul_pallas"):
+        assert name in text, name
+    _assert_no_buffer(text, KEYE_CTX, KEYE_CTX, ("f32", "bf16", "s32", "u32"))
+    assert f"s8[1,{KEYE_CTX},{KEYE_CTX}]" in text

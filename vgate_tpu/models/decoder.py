@@ -416,9 +416,11 @@ def _mla_write_attend(spec: ModelSpec, impl: str, kernel_writes: bool,
 
 def _dsa_decode_steps(spec: ModelSpec, impl: str, page_tables, seq_lens,
                       page_ids, page_off, page_size: int):
-    """``decode_forward``'s cache steps for latent attention under a
-    learned selection (models/hybrid.py ``_dsa_step``), ``(pick, attend,
-    the rows a pick holds)``:
+    """``decode_forward``'s cache steps for attention under a learned
+    selection (models/hybrid.py ``_dsa_step``; ``_kv_dsa_step`` for GQA
+    attention over a pool of K over V, whose ``attend`` takes the
+    token's ``(k, v)`` where the latent form's takes its row), ``(pick,
+    attend, the rows a pick holds)``:
 
     * ``pick(qi, w, key, index_pages, layer)`` puts the token's index key
       into the pool's second array, scores the slot's live keys
@@ -443,7 +445,6 @@ def _dsa_decode_steps(spec: ModelSpec, impl: str, page_tables, seq_lens,
     k = min(spec.index_topk, tokens)
     short = jnp.max(seq_lens) <= k
     n_sel = jnp.minimum(seq_lens, k)
-    kw = dict(v_width=spec.kv_lora_rank, scale=spec.mla_softmax_scale)
 
     def pick(qi, w, key, ip, layer):
         with jax.named_scope("kv_write"):
@@ -472,12 +473,25 @@ def _dsa_decode_steps(spec: ModelSpec, impl: str, page_tables, seq_lens,
             return dsa.order_picks(page_tables, sel, n_sel, page_size), ip
 
     def attend(q, row, rows, kp, layer):
+        # ``row``: the token's latent row or, for GQA over a pool of K
+        # over V (``ModelSpec.kv_rows``), its (k, v)
         with jax.named_scope("kv_write"):
-            kp = kv_write_tokens(
-                kp, page_ids, page_off, row[:, None], layer=layer)
+            if spec.kv_rows:
+                kp = dsa.kv_rows_write_tokens(
+                    kp, page_ids, page_off, *row, layer)
+            else:
+                kp = kv_write_tokens(
+                    kp, page_ids, page_off, row[:, None], layer=layer)
         with jax.named_scope("dsa_attend"):
-            attn = dsa.dsa_decode_attention(
-                q, kp, rows, n_sel, layer, use_pallas=kernel, **kw)
+            if spec.kv_rows:
+                attn = dsa.kv_rows_decode_attention(
+                    q, kp, rows, n_sel, layer, use_pallas=kernel,
+                    scale=_query_scale(spec) or spec.head_dim ** -0.5)
+            else:
+                attn = dsa.dsa_decode_attention(
+                    q, kp, rows, n_sel, layer, use_pallas=kernel,
+                    v_width=spec.kv_lora_rank,
+                    scale=spec.mla_softmax_scale)
         return attn, kp
 
     return pick, attend, k
@@ -491,7 +505,7 @@ def _dsa_prefill_attend(spec: ModelSpec, impl: str, seq_lens, rows: int):
     keys; else the plain jnp twin."""
     from vgate_tpu.ops import dsa
 
-    scale = spec.mla_softmax_scale
+    scale = _query_scale(spec) or spec.head_dim ** -0.5
     if impl == "pallas":
         from vgate_tpu.ops.pallas.dsa import dsa_prefill_attention_pallas
 
@@ -1084,7 +1098,7 @@ def decode_forward(
     # a pool of packed rows (two heads of 64 a row): the same launch at
     # (KV / 2, 2 G, 128), each head's own lanes taken of the result
     attn_fn = over_packed_pool(attn_fn, spec)
-    ps = page_tokens(k_pages)
+    ps = page_tokens(k_pages, spec.kv_rows)
     # the rows a step attends to and writes among, as a paged sequence:
     # the sequence's own pages, or an EVA spec's view of them
     attn_tables, attn_rows = page_tables, positions
@@ -1243,11 +1257,13 @@ def prefill_suffix_forward(
     B, S = tokens.shape
     positions = prefix_lens[:, None] + jnp.arange(S)[None, :]  # absolute
     total_lens = prefix_lens + suffix_lens
-    offsets = (prefix_lens % page_tokens(k_pages)) if unaligned else None
+    offsets = (prefix_lens % page_tokens(k_pages, spec.kv_rows)
+               ) if unaligned else None
     x = _embed(params, spec, tokens)  # [B, S, D]
 
     impl = multitok_attention_impl(
-        use_pallas, mesh, rows=S, unaligned=unaligned, latent=spec.is_mla,
+        use_pallas, mesh, rows=S, unaligned=unaligned,
+        latent=spec.rows_cache,
         group=packed_group(spec),
     )
     kernels = use_pallas  # below, use_pallas narrows to the multitok kernel
@@ -1301,14 +1317,15 @@ def prefill_suffix_forward(
         assert not unaligned, "hybrid specs have no copy-on-write prefix"
         from vgate_tpu.models import hybrid
 
-        ps = page_tokens(k_pages)
+        ps = page_tokens(k_pages, spec.kv_rows)
 
         def attend(q, k, v, kp, vp, layer):
-            if spec.is_mla:
+            if spec.rows_cache:
                 # K and V expanded from the context's latent rows
-                # (hybrid.py _mla_prompt): the blockwise jnp attention
+                # (hybrid.py _mla_prompt), or gathered from a pool of K
+                # over V (_kv_dsa_prompt): the blockwise jnp attention
                 # with the rows' offset.  A Pallas kernel for query rows
-                # against a latent prefix is not written yet
+                # against such a prefix is not written yet
                 return flash_prefill_attention(
                     q, k, v, total_lens, q_offset=prefix_lens,
                     scale=_query_scale(spec),
@@ -1328,7 +1345,8 @@ def prefill_suffix_forward(
             params, spec, x, suffix_lens, positions, k_pages, v_pages,
             state, slots, prefix_lens == 0, suffix_page_tables, attend,
             kernels, ctx_tables=(
-                ctx_page_tables if spec.is_mla or spec.eva_layers else None),
+                ctx_page_tables if spec.rows_cache or spec.eva_layers
+                else None),
             prefix_lens=(prefix_lens if spec.swa_layers or spec.eva_layers
                          else None),
             # a later chunk's or a suffix's rows under a selection: the

@@ -21,7 +21,12 @@ whichever way the spec's fields spell them; the walker takes from it
   that of the ``mla`` layers behind it up to the next ``dsa`` layer,
   runs over the pick alone.  The pick rides the walker's carry (``"sel"``
   in the state dict for the walk: positions ``[B, k]`` in a decode step,
-  a mask ``[B, S, T]`` in a prompt pass): it is activations, not cache,
+  a mask ``[B, S, T]`` in a prompt pass): it is activations, not cache.
+  Under ``ModelSpec.kv_rows`` (Keye-VL-2.0's language model) the kind is
+  GQA attention (``attn``'s front half) under a selection that EVERY
+  layer makes for itself from its normed input: the pool holds a token's
+  K over its V as one pair of rows, nothing rides the carry
+  (``_kv_dsa_prompt`` / ``_kv_dsa_step``),
 * ``swa``   softmax attention over the last ``sliding_window`` tokens
   (K-EXAONE's window layers), whose K/V is the decode slot's RING and
   holds no page of the pool (below),
@@ -109,7 +114,8 @@ period); a pattern's groups are its kinds, ``mamba`` / ``attn`` /
 experts of a window or a full layer) and ``lead``, a TUPLE of the
 leading layers' own trees; an ``indexer_pattern`` spec's are ``pick``
 and ``reuse`` (latent attention with and without an indexer, and the
-layer's experts) and ``lead``; a ``conv_pattern`` spec's are ``conv``
+layer's experts) and ``lead``; a ``kv_rows`` spec's is ``layer``
+(attention, indexer and experts); a ``conv_pattern`` spec's are ``conv``
 and ``attn`` (the mixer and the layer's experts) and ``lead``; a
 ``mamba_pattern`` spec's are ``mamba`` and ``attn`` (the mixer and the
 layer's dense SwiGLU).
@@ -144,6 +150,8 @@ def init_layers(spec: ModelSpec, key, dtype, normal, norm_init
                 ) -> Dict[str, Any]:
     """Random draw of a hybrid spec's layer tensors, each family from
     keys of its own."""
+    if spec.kv_rows:
+        return _init_kv_dsa_layers(spec, key, dtype, normal)
     if spec.is_dsa:
         return _init_dsa_layers(spec, key, dtype, normal)
     if spec.eva_layers:
@@ -332,6 +340,48 @@ def _init_dsa_layers(spec: ModelSpec, key, dtype, normal
         if layers:
             out[group] = tree(layers, (P, len(layers) // P), mixer, "moe")
     return out
+
+
+def _init_kv_dsa_layers(spec: ModelSpec, key, dtype, normal
+                        ) -> Dict[str, Any]:
+    """The tensors of a stack of GQA attention under a selection (every
+    layer ``dsa moe``; ``ModelSpec.kv_rows``) from ``fold_in(key, 53)``
+    split 32 ways, tensor ``j`` of layer ``i`` from ``fold_in(key j,
+    i)``.  N(0, 0.02) (q and k meet a per-head norm, so their scores
+    spread by about 1 whatever the draw) but the indexer's queries and
+    head weights, N(0, 1 / hidden): on normed rows an index query's
+    elements then have a standard deviation near 1 at the published
+    width AND at a toy one, against a LayerNormed key: a dot product
+    spreads by the root of the index head's size, so the pick is neither
+    the first ``index_topk`` positions nor the last, and a wrong
+    rotation or a stale index key moves it.  Norm weights 1, the
+    LayerNorm's bias 0."""
+    sk = jax.random.split(jax.random.fold_in(key, 53), 32)
+    D, H, KV, hd = (spec.hidden_size, spec.num_heads, spec.num_kv_heads,
+                    spec.head_dim)
+    Hi, di = spec.index_n_heads, spec.index_head_dim
+    E, R, Fe = spec.num_experts, spec.router_experts, spec.expert_width
+    lead = (spec.num_periods, 1)
+    ones = lambda n: jnp.ones(lead + (n,), dtype)
+    draw = lambda j, shape, scale=0.02: _per_layer(
+        spec, "layer", lambda kk: normal(kk, shape, scale))(sk[j])
+    return {"layer": {
+        "input_norm": ones(D), "post_norm": ones(D),
+        "q": {"w": draw(0, (D, H * hd))},
+        "k": {"w": draw(1, (D, KV * hd))},
+        "v": {"w": draw(2, (D, KV * hd))},
+        "o": {"w": draw(3, (H * hd, D))},
+        "q_norm": ones(hd), "k_norm": ones(hd),
+        "index_q": {"w": draw(4, (D, Hi * di), D ** -0.5)},
+        "index_k": {"w": draw(5, (D, di))},
+        "index_w": {"w": draw(6, (D, Hi), D ** -0.5)},
+        "index_k_norm": ones(di),
+        "index_k_bias": jnp.zeros(lead + (di,), dtype),
+        "router": draw(7, (D, R)),
+        "gate": {"w": draw(8, (E, D, Fe))},
+        "up": {"w": draw(9, (E, D, Fe))},
+        "down": {"w": draw(10, (E, Fe, D))},
+    }}
 
 
 def _init_window_layers(spec: ModelSpec, key, dtype, normal
@@ -858,7 +908,8 @@ def _rope(x, positions, spec: ModelSpec, rotate: bool = True):
     if not (spec.use_rope and rotate):
         return x
     return apply_rope(x, positions, spec.rope_theta, spec.rope_scaling,
-                      rotary_dim=spec.rotary_dim)
+                      rotary_dim=spec.rotary_dim,
+                      sections=spec.mrope_section)
 
 
 @jax.named_scope("qkv")
@@ -1277,10 +1328,16 @@ def _mla_step(normed, lp, spec: ModelSpec, positions, kp, vp, index,
 
 
 def _index_rotate(t, positions, spec: ModelSpec):
-    """Rotary on the FIRST ``qk_rope_head_dim`` dimensions of index
-    heads t [..., S, heads, di]."""
-    return apply_rope(t, positions, spec.rope_theta, spec.rope_scaling,
-                      rotary_dim=spec.qk_rope_head_dim)
+    """Rotary on the FIRST ``index_rotary_dim`` dimensions of index
+    heads t [..., S, heads, di] (at the temporal component of a position
+    of several), then zeros up to the pool row's lanes
+    (``index_key_lanes``: a dot product gains nothing)."""
+    if spec.mrope_section and positions.ndim == t.ndim - 1:
+        positions = positions[0]
+    t = apply_rope(t, positions, spec.rope_theta, spec.rope_scaling,
+                   rotary_dim=spec.index_rotary_dim)
+    pad = spec.index_key_lanes - spec.index_head_dim
+    return jnp.pad(t, ((0, 0),) * (t.ndim - 1) + ((0, pad),)) if pad else t
 
 
 @jax.named_scope("dsa_index")
@@ -1301,11 +1358,13 @@ def _dsa_index_key(normed, lp, spec: ModelSpec, positions):
 @jax.named_scope("dsa_index")
 def _dsa_index_query(normed, cq, lp, spec: ModelSpec, positions):
     """A picking layer's index queries on normed rows [..., S, D] with
-    their query latent ``cq``: [..., S, Hi, di], rotated, and the heads'
-    weights [..., S, Hi] float32 with the two scales in (``Hi^-0.5 x
-    di^-0.5``)."""
+    their query latent ``cq`` (None: the attention has none, and they
+    come from the normed rows themselves): [..., S, Hi, di], rotated,
+    and the heads' weights [..., S, Hi] float32 with the two scales in
+    (``Hi^-0.5 x di^-0.5``)."""
     Hi, di = spec.index_n_heads, spec.index_head_dim
-    qi = jnp.einsum("...r,rh->...h", cq, lp["index_q"]["w"])
+    qi = jnp.einsum("...r,rh->...h", normed if cq is None else cq,
+                    lp["index_q"]["w"])
     qi = _index_rotate(qi.reshape(*qi.shape[:-1], Hi, di), positions, spec)
     w = jnp.einsum("...d,dh->...h", normed, lp["index_w"]["w"],
                    preferred_element_type=jnp.float32)
@@ -1354,10 +1413,12 @@ def _dsa_prompt_select(normed, cq, lp, keys, positions, total_lens,
             return jnp.stack([
                 _by_row_blocks(
                     functools.partial(block, b=b),
-                    (normed[b], cq[b], positions[b]), n_rows, axis=0)
+                    (normed[b], None if cq is None else cq[b],
+                     positions[b]), n_rows, axis=0)
                 for b in range(B)])
         R = DSA_SCORE_ROWS
-        blocks = lambda t, b: t[b].reshape((S // R, R) + t.shape[2:])
+        blocks = lambda t, b: None if t is None else t[b].reshape(
+            (S // R, R) + t.shape[2:])
         return jnp.stack([
             jax.lax.map(
                 lambda xs, b=b: block(*xs, b),
@@ -1500,6 +1561,85 @@ def _dsa_step(normed, lp, spec: ModelSpec, positions, kp, vp, st, index,
             q, row, st["sel"], kp_, layer_) + (None,),
         cq=cq)
     return out, kp, vp, st
+
+
+def _write_kv_rows(kp, tables, k, v, layer, kernel: bool):
+    """A prompt's k, v [B, S, KV, hd] into a pool of K over V, whole
+    pages: through the kernel of page copies on the chip (XLA's scatter
+    into a pool whose trailing dimensions are a pair re-lays the WHOLE
+    pool), the scatter everywhere else."""
+    pages = dsa.kv_rows_pages(k, v, kp.shape[3])
+    if kernel:
+        from vgate_tpu.ops.pallas.dsa import dsa_write_pages_pallas
+
+        return dsa_write_pages_pallas(kp, tables, pages, layer)
+    return kp.at[layer, 0, tables].set(pages.astype(kp.dtype))
+
+
+def _kv_dsa_prompt(normed, lp, spec: ModelSpec, positions, kp, vp, index,
+                   write_tables, ctx_tables, total_lens, attend,
+                   attend_selected, kernel: bool, n_rows=None,
+                   norm=_as_is):
+    """GQA attention under the layer's OWN selection over prompt rows
+    normed [B, S, D] (``ModelSpec.kv_rows``): the rows' K over V go to
+    the pool ``kp`` and their index keys to ``vp`` (the same pages), the
+    layer's indexer scores the context's keys and the attention runs
+    under that mask (``attend_selected(q, k, v, mask)``).  A context of
+    at most ``index_topk`` tokens is attended whole, with ``attend``.  A
+    suffix or a later chunk (``ctx_tables``) reads the whole context's
+    K, V and index keys back from the pool.  ``n_rows``:
+    ``_by_row_blocks``, whose blocks come un-normed where the caller
+    hands their ``norm``."""
+    B, S = normed.shape[:2]
+    ps = kp.shape[3]
+    T = S if ctx_tables is None else ctx_tables.shape[1] * ps
+    by_rows = lambda fn, *rows: _by_row_blocks(fn, rows, n_rows)
+
+    def front(rows, positions):
+        rows = norm(rows)
+        q, k, v, _ = _gated_qkv(rows, lp, spec, positions)
+        return q, k, v, _dsa_index_key(rows, lp, spec, positions)
+
+    q, k, v, key = by_rows(front, normed, positions)
+    tables = write_tables[:, :S // ps]
+    vp = kv_write_pages(
+        vp, tables, key.reshape(B, S // ps, 1, ps, key.shape[-1]),
+        layer=index)
+    kp = _write_kv_rows(kp, tables, k, v, index, kernel)
+    if ctx_tables is not None:
+        k, v = dsa.kv_rows_gather(kp, ctx_tables, index, spec.num_kv_heads)
+    if T <= spec.index_topk:  # nothing to leave out
+        with jax.named_scope("attention"):
+            attn = attend(q, k, v, kp, vp, index)
+    else:
+        keys = key if ctx_tables is None else mla_gather_rows(
+            vp, ctx_tables, index)
+        mask = _dsa_prompt_select(
+            normed, None, lp, keys, positions, total_lens, spec,
+            kernel and ctx_tables is None, n_rows, norm)
+        with jax.named_scope("dsa_attend"):
+            attn = attend_selected(q, k, v, mask)
+    out = by_rows(lambda attn: _gated_out(attn, None, lp, normed.dtype),
+                  _heads_flat(attn))
+    return out, kp, vp
+
+
+def _kv_dsa_step(normed, lp, spec: ModelSpec, positions, kp, vp, index,
+                 pick, attend):
+    """GQA attention under the layer's own selection for one decode
+    step, normed [B, D]: the token's index key to ``pick`` (the
+    caller's: into ``vp``, the scores over the slot's pages, the picks'
+    places), its K over V into ``kp`` and the attention over the picked
+    tokens alone by ``attend``."""
+    at = positions[:, None]
+    rows = normed[:, None]
+    q, k, v, _ = _gated_qkv(rows, lp, spec, at)
+    qi, w = _dsa_index_query(rows, None, lp, spec, at)
+    key = _dsa_index_key(rows, lp, spec, at)
+    sel, vp = pick(qi[:, 0], w[:, 0], key[:, 0], vp, index)
+    attn, kp = attend(q[:, 0], (k[:, 0], v[:, 0]), sel, kp, index)
+    out = _gated_out(_heads_flat(attn), None, lp, normed.dtype)
+    return out, kp, vp
 
 
 class _LayerTensors(Mapping):
@@ -1881,7 +2021,7 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
     B, S = x.shape[:2]
     if spec.fp32_residual:
         x = x.astype(jnp.float32)
-    ps = page_tokens(k_pages)
+    ps = page_tokens(k_pages, spec.kv_rows)
     KV, hd = spec.cache_heads, spec.cache_head_dim
     n_pages = S // ps
     row_mask = jnp.arange(S)[None, :] < lens[:, None]
@@ -1922,6 +2062,15 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
         if kind == "conv":
             out, st = _conv_prompt(by_rows(norm, normed), lp, st, index,
                                    lens, slots, fresh)
+            return out, kp, vp, st, None
+        if kind == "dsa" and spec.kv_rows:
+            with jax.named_scope("gated_attn"), jax.named_scope(
+                    "dsa_prompt"):
+                out, kp, vp = _kv_dsa_prompt(
+                    normed, lp, spec, positions, kp, vp, index,
+                    write_tables, ctx_tables,
+                    lens if total_lens is None else total_lens, attend,
+                    dsa_attend, use_pallas, n_rows, norm)
             return out, kp, vp, st, None
         if kind in ("mla", "dsa") and spec.is_dsa:
             with jax.named_scope("mla_attn"), jax.named_scope("dsa_prompt"):
@@ -1977,7 +2126,8 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
             out = o_proj(attn, gate)
         return out, kp, vp, st, None
 
-    if spec.is_dsa:  # the selection rides the carry: a mask [B, S, T]
+    if spec.is_dsa and not spec.kv_rows:
+        # a pick that later layers reuse rides the carry: a mask [B, S, T]
         T = S if ctx_tables is None else ctx_tables.shape[1] * ps
         state = {**(state or {}), "sel": jnp.zeros(
             (B, S, T) if T > spec.index_topk else (B, 1, 1), jnp.int8)}
@@ -2033,6 +2183,12 @@ def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
         if kind == "conv":
             out, st = _conv_mixer_step(normed, lp, st, index, active)
             return out, kp, vp, st, None
+        if kind == "dsa" and spec.kv_rows:
+            with jax.named_scope("gated_attn"):
+                out, kp, vp = _kv_dsa_step(
+                    normed, lp, spec, positions, kp, vp, index,
+                    *dsa_steps[:2])
+            return out, kp, vp, st, None
         if kind in ("mla", "dsa") and spec.is_dsa:
             with jax.named_scope("mla_attn"):
                 out, kp, vp, st = _dsa_step(
@@ -2065,7 +2221,8 @@ def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
                                     index, spec.global_rope)
         return out, kp, vp, st, None
 
-    if spec.is_dsa:  # the selection rides the carry: positions [B, k]
+    if spec.is_dsa and not spec.kv_rows:
+        # a pick that later layers reuse rides the carry: positions [B, k]
         state = {**(state or {}), "sel": jnp.zeros(
             (x.shape[0], dsa_steps[2]), jnp.int32)}
     x, k_pages, v_pages, state, stats = _period_scan(

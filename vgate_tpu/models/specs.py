@@ -171,6 +171,14 @@ class ModelSpec:
     index_n_heads: int = 0
     index_head_dim: int = 0
     indexer_rope_interleave: bool = False
+    # ``index_topk`` WITHOUT a pattern and without a latent (Keye-VL-2.0's
+    # ``sa_config``): EVERY layer is GQA attention under a selection of
+    # its own, its index queries from the normed hidden state, its pages
+    # a token's K over its V in ONE array beside the index keys
+    # (``kv_rows``).  The rotary's frequencies by section over a
+    # position's three components (M-RoPE; ops/rope.py ``sections``): a
+    # text token's components are equal, the served path's
+    mrope_section: tuple = ()
     # ---- EVA attention (EvaByte; ops/eva.py): every layer holds a token
     # EXACTLY while its window of ``eva_window`` tokens is open (a buffer
     # a decode slot) and, once the window has closed, as its share of ONE
@@ -230,10 +238,9 @@ class ModelSpec:
             )
         # a preset changed from JSON (perfbench/serve.py overrides)
         # brings lists; the spec is a static jit argument and must hash
-        if not isinstance(self.extra_stop_ids, tuple):
-            object.__setattr__(
-                self, "extra_stop_ids", tuple(self.extra_stop_ids)
-            )
+        for name in ("extra_stop_ids", "mrope_section"):
+            if not isinstance(getattr(self, name), tuple):
+                object.__setattr__(self, name, tuple(getattr(self, name)))
 
     @property
     def is_moe(self) -> bool:
@@ -248,9 +255,27 @@ class ModelSpec:
 
     @property
     def is_dsa(self) -> bool:
-        """Latent attention over the ``index_topk`` cached tokens an
-        indexer picks, not over all of them."""
-        return self._spelling is _INDEXER
+        """Attention over the ``index_topk`` cached tokens an indexer
+        picks, not over all of them: latent attention by a pattern of
+        layers that pick and layers that reuse a pick, or GQA attention
+        whose every layer picks (``kv_rows``)."""
+        return self._spelling in (_INDEXER, _SELECT)
+
+    @property
+    def kv_rows(self) -> bool:
+        """GQA attention under a selection: a token's cache in a layer
+        is ONE pair of rows, its KV heads' K over their V, ``[2,
+        num_kv_heads x head_dim]`` (2,048 B at 4 x 128 in bf16), so that
+        a picked token is one fetch (runtime/kv_cache.py
+        ``make_kv_buffers``; ops/pallas/dsa.py)."""
+        return self._spelling is _SELECT
+
+    @property
+    def rows_cache(self) -> bool:
+        """The pool holds rows a TOKEN (a latent row, or K over V) and
+        no head-major K and V pools: what moves K and V pages by head
+        does not know it."""
+        return self.is_mla or self.kv_rows
 
     @property
     def is_mla(self) -> bool:
@@ -264,17 +289,20 @@ class ModelSpec:
 
     @property
     def kv_pools(self) -> int:
-        """Arrays of the paged cache that hold ``cache_head_dim`` lanes a
-        layer of ``attn_layers``: K and V, or the one latent pool (the
-        index keys of a spec that picks ride beside it, in an array of
-        their own shape: ``index_layers`` x ``index_head_dim``)."""
+        """Rows of ``cache_head_dim`` lanes a token holds a cache head
+        and layer: K and V (two arrays; under ``kv_rows`` the two rows
+        of ONE array's pairs), or the one latent pool's (the index keys
+        of a spec that picks ride beside either, in an array of their
+        own shape: ``index_layers`` x ``index_key_lanes``)."""
         return 1 if self.is_mla else 2
 
     @property
     def cache_heads(self) -> int:
         """Rows a token holds in a layer of a pool: its KV heads, by
         ``kv_head_pack`` to a row."""
-        return 1 if self.is_mla else self.num_kv_heads // self.kv_head_pack
+        if self.rows_cache:
+            return 1
+        return self.num_kv_heads // self.kv_head_pack
 
     @property
     def cache_head_dim(self) -> int:
@@ -285,12 +313,31 @@ class ModelSpec:
         theirs: no padding lanes."""
         if self.is_mla:
             return -(-self.latent_dim // 128) * 128
+        if self.kv_rows:  # all KV heads of K (or of V) side by side
+            return self.kv_dim
         return self.head_dim * self.kv_head_pack
+
+    @property
+    def index_key_lanes(self) -> int:
+        """Lanes of an index key's row in the pool.  Over K and V
+        (``kv_rows``) whole 128-lane tiles: a key of 64 in 128 (Mosaic
+        refuses a 64-lane page, PERF.md section 6, PR 47; the other 64
+        hold zeros, which add nothing to a dot product).  The latent
+        form's key is 128 wide as published and is held as it is."""
+        d = self.index_head_dim
+        return -(-d // 128) * 128 if self.kv_rows else d
+
+    @property
+    def index_rotary_dim(self) -> int:
+        """The FIRST dimensions of an index head that rotate: the latent
+        attention's rope width, or the whole head where the attention
+        has no such width (``kv_rows``)."""
+        return self.qk_rope_head_dim if self.is_mla else self.index_head_dim
 
     @property
     def kv_heads_pair(self) -> bool:
         """K and V heads of 64 in even number: two fit a 128-lane row."""
-        return (not self.is_mla and self.head_dim == 64
+        return (not self.rows_cache and self.head_dim == 64
                 and self.num_kv_heads % 2 == 0
                 # a ring and an open window are laid out by KV head
                 and not self.swa_layers and not self.eva_layers)
@@ -348,6 +395,8 @@ class ModelSpec:
             return _INDEXER
         if self.is_mla:
             return _LATENT
+        if self.index_topk:
+            return _SELECT
         if self.full_attention_interval > 1:
             return _INTERVAL
         return _DENSE
@@ -570,10 +619,11 @@ class ModelSpec:
         mla = (D * ql + ql + ql * H * (nope + self.qk_rope_head_dim)
                + D * self.latent_dim + kl + kl * H * (nope + vd)
                + H * vd * D)
-        # the indexer: queries from the query latent, ONE key a token
-        # under a LayerNorm, the heads' weights
+        # the indexer: queries from the query latent (from the hidden
+        # state where the attention has none), ONE key a token under a
+        # LayerNorm, the heads' weights
         Hi, di = self.index_n_heads, self.index_head_dim
-        indexer = ql * Hi * di + D * di + 2 * di + D * Hi
+        indexer = (ql or D) * Hi * di + D * di + 2 * di + D * Hi
         Hv, lv = self.linear_num_value_heads, self.linear_value_dim
         gdn = (D * (self.linear_conv_dim + lv) + D * 2 * Hv
                + self.linear_conv_dim * self.linear_conv_kernel_dim
@@ -603,7 +653,8 @@ class ModelSpec:
         # the gated short convolution: [B | C | X], the taps, the output
         conv = (3 * D * D + D * self.conv_L_cache
                 + (D if self.conv_bias else 0) + D * D)
-        return {"attn": gqa, "swa": gqa, "mla": mla, "dsa": mla + indexer,
+        return {"attn": gqa, "swa": gqa, "mla": mla,
+                "dsa": (mla if self.is_mla else gqa) + indexer,
                 "eva": eva, "gdn": gdn, "mamba": mamba, "conv": conv,
                 "mlp": 3 * D * self.intermediate_size, "moe": moe}
 
@@ -641,6 +692,29 @@ class ModelSpec:
                          for layer in self.stack)
         return tuple(window if i % 2 == 0 else 0
                      for i in range(self.num_layers))
+
+    # Keye-VL-2.0's groups as its config.json spells them (what
+    # perfbench/serve.py holds the program to)
+    @property
+    def sa_config(self) -> dict:
+        if not self.kv_rows:
+            return {}
+        return {"indexer_head_dim": self.index_head_dim,
+                "indexer_num_heads": self.index_n_heads,
+                "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
+                "q_chunk_size": 512, "topk": self.index_topk}
+
+    @property
+    def mrope_scaling(self) -> dict:
+        if not self.mrope_section:
+            return {}
+        return {"mrope_section": list(self.mrope_section),
+                "rope_type": "default", "type": "default"}
+
+    # the experts held, under the config's second key for them
+    @property
+    def num_local_experts(self) -> int:
+        return self.num_experts
 
     # a window spec's layers as the published config.json lists them
     # (what perfbench/serve.py holds the program to)
@@ -820,6 +894,9 @@ _MAMBA = _Spelling(
     lambda spec: tuple(("mamba" if c == "M" else "attn", "mlp")
                        for c in spec.mamba_pattern),
     False, {"mamba": "mamba", "attn": "attn"})
+# GQA attention under a selection of every layer's own (``kv_rows``)
+_SELECT = _Spelling(lambda spec: (("dsa", "moe"),) * _depth(spec), False,
+                    {"dsa": "layer"})
 # latent attention without an indexer: every layer alike
 _LATENT = _Spelling(lambda spec: (("mla", "moe"),) * _depth(spec), False,
                     {"mla": "layer"})
@@ -1538,6 +1615,72 @@ TINY_DSA_MOE = _register(
         index_n_heads=4,
         index_head_dim=16,
         indexer_rope_interleave=True,
+    )
+)
+
+# Keye-VL-2.0-30B-A3B's LANGUAGE model (Kwai-Keye, model_type KeyeVL2) at
+# the published sizes: 48 layers alike, GQA 32 query heads on 4 KV heads
+# of 128 with per-head norms on q and k (ASSUMED: the Qwen3 family's),
+# every layer under a selection of its own (``sa_config``: 16 index heads
+# of 64 against ONE key a token pick 2,048), then 128 softmax-routed
+# experts of 768 top 8, no shared expert.  The vision tower is not part
+# of the stack (its sizes are not in the repository)
+KEYE_VL2_30B = _register(
+    ModelSpec(
+        name="Kwai-Keye/Keye-VL-2.0-30B-A3B",
+        vocab_size=151936,
+        hidden_size=2048,
+        num_layers=48,
+        num_heads=32,
+        num_kv_heads=4,
+        head_dim=128,
+        intermediate_size=6144,  # the published key; read by nothing
+        rope_theta=10_000_000.0,
+        rms_eps=1e-6,
+        qkv_bias=False,
+        tie_embeddings=False,
+        max_position_embeddings=262144,
+        num_experts=128,
+        experts_per_token=8,
+        moe_intermediate_size=768,
+        router_width=128,
+        qk_norm=True,
+        index_topk=2048,
+        index_n_heads=16,
+        index_head_dim=64,
+        mrope_section=(16, 24, 24),
+    )
+)
+
+# the same mechanisms at toy widths: 4 query heads on 2 KV heads of 16,
+# 16 tokens picked by 4 index heads of 8 in each of 4 layers, 8 experts
+# top 2, so that a CPU test's contexts pass the pick within a few pages
+TINY_KEYE_DSA = _register(
+    ModelSpec(
+        name="tiny-keye-dsa",
+        vocab_size=512,
+        hidden_size=64,
+        num_layers=4,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        intermediate_size=128,
+        rope_theta=10000.0,
+        rms_eps=1e-6,
+        qkv_bias=False,
+        tie_embeddings=False,
+        eos_token_id=0,
+        bos_token_id=1,
+        max_position_embeddings=4096,
+        num_experts=8,
+        experts_per_token=2,
+        moe_intermediate_size=32,
+        router_width=8,
+        qk_norm=True,
+        index_topk=16,
+        index_n_heads=4,
+        index_head_dim=8,
+        mrope_section=(2, 3, 3),
     )
 )
 

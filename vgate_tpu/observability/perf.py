@@ -1092,7 +1092,7 @@ class PerfRecorder:
 
     def note_dsa_decode(self, steps: int, lens, layers: int,
                         index_layers: int, topk: int,
-                        fetch_chunk: int = 0) -> None:
+                        fetch_chunk: int = 0, pair_tokens: int = 2) -> None:
         """One decode chunk of a spec that attends under a learned
         selection, booked once per readback: the sequences of lengths
         ``lens`` (at dispatch) rode ``steps`` steps; step k scored a
@@ -1103,12 +1103,14 @@ class PerfRecorder:
         pick.  What the kernels have to read, and no padding.
         ``rows_fetched`` is what the attention MOVED for those rows: the
         kernel a pair of token rows a pick, whole chunks of
-        ``fetch_chunk`` picks (ops/pallas/dsa.py); the jnp twin
-        (``fetch_chunk`` 0) the picked rows themselves."""
+        ``fetch_chunk`` picks (ops/pallas/dsa.py; ``pair_tokens`` 1: a
+        pick's pair is ONE token's K over its V, nothing beside it); the
+        jnp twin (``fetch_chunk`` 0) the picked rows themselves."""
         ctx = sum(steps * n + steps * (steps - 1) // 2 for n in lens)
         picks = [min(n + k, topk) for n in lens for k in range(steps)]
         attended = sum(picks)
-        fetched = (sum(2 * fetch_chunk * -(-m // fetch_chunk) for m in picks)
+        fetched = (sum(pair_tokens * fetch_chunk * -(-m // fetch_chunk)
+                       for m in picks)
                    if fetch_chunk else attended)
         for name, add in (
             ("index_layer_steps", steps * index_layers),

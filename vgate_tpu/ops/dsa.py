@@ -1,6 +1,7 @@
-"""Learned sparse attention over the latent cache (DeepSeek-V3.2's DSA,
-GLM-5.2's ``glm_moe_dsa``): the indexer's scores, the selection, and
-decode attention over the selected rows alone.
+"""Learned sparse attention (DeepSeek-V3.2's DSA) over the latent cache
+(GLM-5.2's ``glm_moe_dsa``) or over a GQA cache of K and V (Keye-VL-2.0's
+``sa_config``; the ``kv_rows_*`` functions at the end): the indexer's
+scores, the selection, and decode attention over the selected rows alone.
 
 A layer that picks scores every cached position ``s <= t`` for the query
 at ``t``::
@@ -151,10 +152,14 @@ def dsa_decode_attention(q, pool, rows, n_sel, layer, *, v_width: int,
 
 def masked_attention(q, k, v, mask, scale: float):
     """Plain softmax attention under a mask, float32 inside: q [B, S, H,
-    hd], k / v [B, T, H, .], mask [B, S, T] (nonzero = attend; every
+    hd], k / v [B, T, H, .] (or [B, T, KV, .], each KV head the H / KV
+    query heads' of its group), mask [B, S, T] (nonzero = attend; every
     real row attends to itself) -> [B, S, H, v].  The ``jax.numpy`` twin
     of the prompt kernel under a selection, whole score matrix and all:
     for a CPU's sizes and for the passes no kernel covers yet."""
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     s = jnp.einsum("bshd,bthd->bhst", q, k,
                    preferred_element_type=jnp.float32) * scale
     s = jnp.where(mask[:, None] != 0, s, -1e30)
@@ -163,3 +168,67 @@ def masked_attention(q, k, v, mask, scale: float):
     out = jnp.einsum("bhst,bthv->bshv", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
     return out.astype(q.dtype)
+
+
+# ---- GQA attention under a selection (``ModelSpec.kv_rows``): the pool
+# holds a token's K over its V, ``[L, 1, P, ps, 2, W]`` with ``W`` = KV
+# heads x head size, so a picked token is ONE pair of rows
+
+
+def kv_rows_write_tokens(pool, page_ids, page_off, k, v, layer):
+    """A decode step's K and V (k, v [B, KV, hd]) into their tokens'
+    pairs of rows: the pair's dimension indexed explicitly, so that the
+    scatter's window is one row (ops/kv_quant.py ``kv_write_tokens``)."""
+    B = k.shape[0]
+    rows = jnp.stack([k.reshape(B, -1), v.reshape(B, -1)], axis=1)
+    which = jnp.arange(2, dtype=jnp.int32)[None, :]
+    return pool.at[layer, 0, page_ids[:, None], page_off[:, None],
+                   which].set(rows.astype(pool.dtype))
+
+
+def kv_rows_pages(k, v, page_size: int):
+    """A prompt's k, v [B, S, KV, hd] as whole pages of pairs of rows,
+    [B, S / ps, ps, 2, W]."""
+    B, S = k.shape[:2]
+    flat = lambda t: t.reshape(B, S // page_size, page_size, -1)
+    return jnp.stack([flat(k), flat(v)], axis=3)
+
+
+def kv_rows_gather(pool, page_tables, layer, kv_heads: int):
+    """The K and V of each slot's page window, k, v [B, ctx, KV, hd]."""
+    rows = pool[layer, 0, page_tables]  # [B, n, ps, 2, W]
+    B = rows.shape[0]
+    heads = lambda t: t.reshape(B, -1, kv_heads, t.shape[-1] // kv_heads)
+    return heads(rows[..., 0, :]), heads(rows[..., 1, :])
+
+
+def kv_rows_decode_attention(q, pool, rows, n_sel, layer, *, scale: float,
+                             use_pallas: bool):
+    """GQA decode attention over the SELECTED tokens only: q [B, H, hd],
+    rows [B, k] the picks' places in a layer of the pool (``page x ps +
+    offset``; a slot's first ``n_sel`` real) -> [B, H, hd].  The kernel
+    (ops/pallas/dsa.py ``dsa_kv_decode_attention_pallas``) fetches each
+    picked token's pair of rows itself; the jnp twin gathers them."""
+    if use_pallas:
+        from vgate_tpu.ops.pallas.dsa import dsa_kv_decode_attention_pallas
+
+        return dsa_kv_decode_attention_pallas(
+            q, pool, rows, n_sel, layer, scale=scale)
+    B, H, hd = q.shape
+    W = pool.shape[-1]
+    KV = W // hd
+    with jax.named_scope("dsa_gather"):
+        flat = pool.reshape(-1, 2, W)
+        picked = flat[layer * (flat.shape[0] // pool.shape[0]) + rows]
+    k = picked[:, :, 0].reshape(B, -1, KV, hd)
+    v = picked[:, :, 1].reshape(B, -1, KV, hd)
+    qg = q.reshape(B, KV, H // KV, hd)
+    s = jnp.einsum("bgjd,btgd->bgjt", qg, k.astype(q.dtype),
+                   preferred_element_type=jnp.float32) * scale
+    live = jnp.arange(rows.shape[1])[None, :] < n_sel[:, None]
+    s = jnp.where(live[:, None, None, :], s, -1e30)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    out = jnp.einsum("bgjt,btgd->bgjd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, H, hd).astype(q.dtype)

@@ -97,12 +97,20 @@ def by_pairs(pool) -> bool:
     attention), whose decode kernel fetches single picked rows and can
     address nothing smaller than a pair (ops/pallas/dsa.py).  The same
     bytes in the same order as ``[L, KV, P, ps, hd]``; a page is still
-    one leading index.  Such a pool always has its layer dimension."""
+    one leading index.  Such a pool always has its layer dimension.
+    (The pool of GQA attention under a selection has six dimensions too,
+    its pairs a token's K over its V: ``ModelSpec.kv_rows`` says which,
+    and its writes and reads are ops/dsa.py ``kv_rows_*``, not this
+    module's.)"""
     return pool.ndim == 6
 
 
-def page_tokens(pool) -> int:
-    """Tokens a page of ``pool`` holds, whichever way its rows lie."""
+def page_tokens(pool, kv_rows: bool = False) -> int:
+    """Tokens a page of ``pool`` holds, whichever way its rows lie.
+    ``kv_rows`` (``ModelSpec.kv_rows``): the pool's pairs are a token's
+    K over its V, ``[L, 1, P, ps, 2, W]``, not two tokens."""
+    if kv_rows:
+        return pool.shape[3]
     return pool.shape[3] * 2 if by_pairs(pool) else pool.shape[-2]
 
 

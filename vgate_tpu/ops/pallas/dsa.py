@@ -16,8 +16,12 @@ mathematics and the ``jax.numpy`` twins):
   descriptor Mosaic takes, a pair is), a descriptor a pick, the next
   chunk's in flight; the picked row of each pair kept by the pick's
   place in the slot's list (ops/dsa.py ``order_picks``).
-* ``dsa_write_pages_pallas``: a prompt's latent rows into the pool by
-  pairs, a page a descriptor.
+* ``dsa_kv_decode_attention_pallas`` (the same kernel and launch name):
+  GQA attention over picked TOKENS of a pool whose pairs are a token's K
+  over its V (``[L, 1, P, ps, 2, KV x hd]``, ``ModelSpec.kv_rows``): a
+  descriptor a pick, both rows of the pair read.
+* ``dsa_write_pages_pallas``: a prompt's rows into either pool of pairs,
+  a page a descriptor.
 * ``dsa_prefill_attention_pallas``: the flash prompt kernel with the
   selection as a mask tile beside each key block, under a name of its
   own.
@@ -318,8 +322,17 @@ def _fetch_decode_kernel(
         pick is the slot's ``first``-th."""
         m_prev, l_prev, acc_prev = carry
         pairs = buf[at]  # [K, 2, W]
+        # (the latent forms': which row of its pair a pick is)
         place = first + jax.lax.broadcasted_iota(jnp.int32, (K, 1), 0)
-        if form == "words":
+        values = None  # where they are not the rows' own first lanes
+        if form == "kv" and buf.dtype == jnp.bfloat16:
+            # a pair is ONE token's K over its V: both halves of a word
+            w = pltpu.bitcast(pairs.reshape(2 * K, W), jnp.uint32)
+            half = lambda u: pltpu.bitcast(u, jnp.float32).astype(buf.dtype)
+            rows, values = half(w << 16), half(w & jnp.uint32(0xFFFF0000))
+        elif form == "kv":
+            rows, values = pairs[:, 0, :], pairs[:, 1, :]
+        elif form == "words":
             # a pair's two bf16 rows lie in one 32-bit word a lane, the
             # first row in the low half: either half, moved to the top,
             # is that row's value as a float32
@@ -339,7 +352,7 @@ def _fetch_decode_kernel(
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        v = rows[:, :v_width]
+        v = rows[:, :v_width] if values is None else values
         dims = (((1,), (0,)), ((), ()))
         if rows.dtype == jnp.bfloat16:
             # the float32 weights as two bf16 terms, as _decode_kernel
@@ -395,18 +408,30 @@ def dsa_decode_attention_pallas(
     others, so the kernel tells a pick's row of its pair by the pick's
     place in the list.  The jnp twin is
     ``ops.dsa.dsa_decode_attention(use_pallas=False)``."""
-    B, H, W = q.shape
-    L, _, P, half, _, _ = pool.shape
+    L, _, P, half, _, W = pool.shape
     if P * half >= 1 << 28:  # order_picks' sort key holds 29 bits of it
         raise ValueError(f"{P} pages of {2 * half} tokens in a layer")
     k = rows.shape[1]
-    K = fetch_chunk(k, chunk)
     form = form or ("words" if pool.dtype == jnp.bfloat16 else "select")
-    n_chunks = cdiv(k, K)
     n_sel = n_sel.astype(jnp.int32)
     real = jnp.arange(k, dtype=jnp.int32)[None, :] < n_sel[:, None]
     n_even = jnp.sum(real & (rows % 2 == 0), axis=1, dtype=jnp.int32)
     pairs = jnp.asarray(layer, jnp.int32) * (P * half) + rows // 2
+    return _fetch_attend(
+        q, pool.reshape(L * P * half, 2, W), pairs, n_sel, n_even,
+        v_width=v_width, scale=scale, chunk=chunk, form=form,
+        interpret=interpret)
+
+
+def _fetch_attend(q, pool, pairs, n_sel, n_even, *, v_width: int,
+                  scale: float, chunk: int, form: str, interpret: bool):
+    """The launch both decode attentions under a selection share: q [B,
+    H, W] against the pairs of rows ``pairs`` [B, k] of pool [N, 2, W],
+    a slot's first ``n_sel`` real."""
+    B, H, W = q.shape
+    k = pairs.shape[1]
+    K = fetch_chunk(k, chunk)
+    n_chunks = cdiv(k, K)
     pairs = jnp.pad(pairs, ((0, 0), (0, n_chunks * K - k)))
     chunks = (n_sel + K - 1) // K
     base = jnp.cumsum(chunks) - chunks
@@ -443,7 +468,42 @@ def dsa_decode_attention_pallas(
         ),
         name="dsa_decode_attention_pallas",
     )(pairs, n_sel, n_even, base.astype(jnp.int32), nxt,
-      q.astype(pool.dtype), pool.reshape(L * P * half, 2, W))
+      q.astype(pool.dtype), pool)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "interpret", "chunk"))
+def dsa_kv_decode_attention_pallas(
+    q: jnp.ndarray,  # [B, H, hd]
+    pool: jnp.ndarray,  # [L, 1, P, ps, 2, KV x hd]: a token's K over its V
+    rows: jnp.ndarray,  # [B, k] the picks' places in a layer (order_picks)
+    n_sel: jnp.ndarray,  # [B] of them real; 0 => nothing fetched: zeros out
+    layer,  # int32 scalar: the pool's layer
+    *, scale: float, interpret: bool = False, chunk: int = FETCH_CHUNK,
+):
+    """GQA decode attention over the SELECTED tokens, [B, H, hd]: the
+    same launch as the latent form's, a descriptor a pick, and a pick's
+    pair of rows is the token's K and its V (2,048 B at 4 heads of 128
+    in bf16, every byte of it read).  The KV heads lie side by side in a
+    row, so a query head rides in its group's lanes of a row-wide query
+    (zeros elsewhere: ONE product for all heads, its weights the chunk's
+    K either way) and takes its group's lanes of the row-wide result.
+    The jnp twin is ``ops.dsa.kv_rows_decode_attention(use_pallas=
+    False)``."""
+    B, H, hd = q.shape
+    L, _, P, ps, _, W = pool.shape
+    KV = W // hd
+    own = (jnp.arange(H)[:, None] // (H // KV) == jnp.arange(KV)[None, :])
+    wide = (q[:, :, None, :] * own[None, :, :, None].astype(q.dtype)
+            ).reshape(B, H, W)
+    pairs = jnp.asarray(layer, jnp.int32) * (P * ps) + rows
+    n_sel = n_sel.astype(jnp.int32)
+    out = _fetch_attend(
+        wide, pool.reshape(L * P * ps, 2, W), pairs, n_sel,
+        jnp.zeros_like(n_sel), v_width=W, scale=scale, chunk=chunk,
+        form="kv", interpret=interpret)
+    out = out.reshape(B, H, KV, hd)
+    return jnp.sum(out * own[None, :, :, None].astype(out.dtype), axis=2)
 
 
 # page copies a group of the prompt's page writer has in flight
@@ -483,12 +543,13 @@ def dsa_write_pages_pallas(pool, page_tables, value, layer,
                            interpret: bool = False):
     """A prompt's latent rows into a pool BY PAIRS, whole pages: pool
     [L, 1, P, ps / 2, 2, W], page_tables [..., n], value [..., n, 1, ps,
-    W] -> the pool, updated in place (donate it).  XLA's scatter into
+    W] -> the pool, updated in place (donate it); or a prompt's K over V
+    into a pool of such pairs, [L, 1, P, ps, 2, W] with value [..., n,
+    ps, 2, W]: a page is the pool's trailing three dimensions either way.  XLA's scatter into
     such a pool re-lays or flattens the WHOLE pool first, whichever
     window it is given (tests/test_tpu_aot.py); a page is one leading
     index and one copy.  Pages named twice (the trash page of a prompt's
     padding) hold either writer's rows."""
-    L, _, P, half, two, W = pool.shape
     tables = page_tables.reshape(-1).astype(jnp.int32)
     n = tables.shape[0]
     kernel = functools.partial(_write_pages_kernel, pages=n)
@@ -507,7 +568,7 @@ def dsa_write_pages_pallas(pool, page_tables, value, layer,
         interpret=interpret,
         name="dsa_write_pages_pallas",
     )(tables, jnp.asarray(layer, jnp.int32).reshape(1),
-      value.astype(pool.dtype).reshape(n, half, two, W), pool)
+      value.astype(pool.dtype).reshape((n,) + pool.shape[3:]), pool)
 
 
 def dsa_prefill_attention_pallas(q, k, v, seq_lens, mask, *, scale: float,

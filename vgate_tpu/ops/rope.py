@@ -7,6 +7,7 @@ from __future__ import annotations
 import math
 
 import jax.numpy as jnp
+import numpy as np
 
 
 def rope_frequencies(
@@ -74,6 +75,7 @@ def apply_rope(
     theta: float = 10000.0,
     scaling=None,
     rotary_dim: int = 0,
+    sections=(),
 ) -> jnp.ndarray:
     """Rotate ``x`` of shape [..., seq, heads, head_dim] by per-token angles.
 
@@ -81,19 +83,33 @@ def apply_rope(
     Computed in fp32, returned in the input dtype.  With ``rotary_dim``
     (partial rotary, Qwen3-Next's 64 of 256) only the first
     ``rotary_dim`` dimensions of a head rotate, with frequencies counted
-    over those; the rest pass through.
+    over those; the rest pass through.  With ``sections`` (M-RoPE:
+    ``(16, 24, 24)`` of 64 frequencies) and positions of SEVERAL
+    components, ``[len(sections), ..., seq]``, frequency ``i`` turns by
+    the component of its section; positions of one component (a text
+    token's are equal) take the plain path, whatever ``sections``.
     """
     if 0 < rotary_dim < x.shape[-1]:
         return jnp.concatenate(
             [
-                apply_rope(x[..., :rotary_dim], positions, theta, scaling),
+                apply_rope(x[..., :rotary_dim], positions, theta, scaling,
+                           sections=sections),
                 x[..., rotary_dim:],
             ],
             axis=-1,
         )
     head_dim = x.shape[-1]
     inv_freq = rope_frequencies(head_dim, theta, scaling)
-    angles = positions.astype(jnp.float32)[..., None] * inv_freq  # [..., S, hd/2]
+    if sections and positions.ndim == x.ndim - 1:
+        if sum(sections) != head_dim // 2:
+            raise ValueError(f"sections {sections} of {head_dim // 2} "
+                             "frequencies")
+        comp = np.repeat(np.arange(len(sections)), sections)
+        # [..., S, hd/2]: each frequency's own component
+        positions = jnp.moveaxis(positions, 0, -1)[..., comp]
+        angles = positions.astype(jnp.float32) * inv_freq
+    else:
+        angles = positions.astype(jnp.float32)[..., None] * inv_freq
     cos = jnp.cos(angles)[..., None, :]  # [..., S, 1, hd/2]
     sin = jnp.sin(angles)[..., None, :]
     x32 = x.astype(jnp.float32)

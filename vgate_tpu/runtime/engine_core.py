@@ -476,9 +476,32 @@ def refuse_unsupported_latent(spec: ModelSpec, config: VGTConfig,
     tried over a latent one.  Each is refused by name, at boot.  (Prefix
     sharing over latent pages works: whole pages only, the radix
     cache's copy-on-write of a partial page is turned off.)"""
-    found = _pages_only_feature(config, mesh) if spec.is_mla else None
+    found = _pages_only_feature(config, mesh) if spec.rows_cache else None
     if not found:
         return
+    if spec.kv_rows:
+        why = {
+            "mesh": "its pool holds all KV heads in one row a token and "
+                    "its kernels are not partitioned (dp composes: a "
+                    "replica owns its pool)",
+            "speculative": "the verify program attends head-major K and V "
+                           "pools, and under no selection",
+            "swap": "its gather and scatter programs move head-major K "
+                    "and V pools and would leave the index keys behind",
+            "roles": "the handoff of a live sequence ships head-major K "
+                     "and V pages and would leave the index keys behind",
+            "int8": "K over V and the index keys are written and read in "
+                    "the model's float type only",
+            "quant": "the indexer and the grouped expert product take "
+                     "plain weights",
+        }[found[1]]
+        raise ValueError(
+            f"{spec.name} has GQA attention under a learned selection (a "
+            "pool of K over V a token and one of index keys under one "
+            f"page table), which cannot run with {found[0]}: {why}.  "
+            "Prefix sharing of whole pages, chunked prefill, preemption "
+            "by recompute and journal replay are supported."
+        )
     why = {
         "mesh": "the latent pool has one row a token for all heads and "
                 "its kernels are not partitioned (dp composes: a replica "
@@ -886,7 +909,7 @@ class EngineCore:
             kv_dtype=kv_dtype_name,
             pools=self.spec.kv_pools,
             index_layers=self.spec.index_layers,
-            index_dim=self.spec.index_head_dim,
+            index_dim=self.spec.index_key_lanes,
             row_tokens=self.spec.cache_row_tokens,
             # an EVA spec's open windows: pages of the pool arrays a
             # slot, behind the allocator's (their bytes are the
@@ -953,7 +976,8 @@ class EngineCore:
                 min_share_pages=pc.min_share_pages,
                 # latent pages are shared whole: the suffix program of
                 # such a spec writes whole pages (models/hybrid.py)
-                cow=bool(pc.cow and mesh_sp == 1 and not self.spec.is_mla),
+                cow=bool(pc.cow and mesh_sp == 1
+                         and not self.spec.rows_cache),
                 cow_min_tokens=pc.cow_min_tokens,
             )
             self.allocator.set_reclaimer(self.radix_cache)
@@ -3129,7 +3153,8 @@ class EngineCore:
                 "suffix_cow" if unaligned else "suffix",
                 lambda: multitok_attention_impl(
                     self.use_pallas, mesh, rows=bucket, unaligned=unaligned,
-                    latent=self.spec.is_mla, group=packed_group(self.spec),
+                    latent=self.spec.rows_cache,
+                    group=packed_group(self.spec),
                 ),
             )
         else:
@@ -3637,6 +3662,7 @@ class EngineCore:
                         index_layers=self.spec.index_layers,
                         topk=self.spec.index_topk,
                         fetch_chunk=self._dsa_fetch_chunk,
+                        pair_tokens=1 if self.spec.kv_rows else 2,
                     )
                 if self.spec.swa_layers:
                     self.perf.note_swa_decode(
@@ -4498,7 +4524,11 @@ class EngineCore:
                    if self.spec.kv_head_pack > 1 else {}),
                 # a spec that picks: the latent rows by pairs of tokens,
                 # what its decode kernel fetches a pick
-                **({"row_pairs": True} if by_pairs(self.k_pages) else {}),
+                **({"row_pairs": True} if by_pairs(self.k_pages)
+                   and not self.spec.kv_rows else {}),
+                # GQA under a selection: a token's K over its V, one
+                # pair of rows, what its decode kernel fetches a pick
+                **({"kv_rows": True} if self.spec.kv_rows else {}),
                 # the index keys a page holds beside them (a spec that
                 # picks): one row a token in each picking layer
                 **({"index": {

@@ -41,7 +41,10 @@ def _page_bytes(
     "head" is the token's latent row (``ModelSpec.cache_head_dim``).
     ``index_layers`` x ``index_dim``: the index keys a page holds beside
     them under the same page id, one row a token a picking layer
-    (learned sparse attention: ``ModelSpec.index_layers``)."""
+    (learned sparse attention: ``ModelSpec.index_layers``, the row's
+    lanes AS HELD, ``ModelSpec.index_key_lanes``).  GQA attention under
+    a selection counts ``pools`` 2 and ``kv_heads`` 1: a token's K over
+    its V, each all KV heads wide, in ONE array."""
     return (
         pools * num_layers * page_size * kv_heads
         * (head_dim * dtype_bytes + scale_bytes)
@@ -142,7 +145,7 @@ def auto_num_pages(
     page_bytes = _page_bytes(
         spec.attn_layers, page_size, spec.cache_heads, spec.cache_head_dim,
         dtype_bytes, scale_bytes, spec.kv_pools,
-        spec.index_layers, spec.index_head_dim,
+        spec.index_layers, spec.index_key_lanes,
     ) // max(1, shards)
     if stats and "bytes_limit" in stats:
         limit = stats["bytes_limit"] * hbm_utilization
@@ -407,7 +410,13 @@ def make_kv_buffers(geometry: KVGeometry, dtype=jnp.bfloat16, sharding=None):
         # least Mosaic lets a descriptor address (ops/kv_quant.py
         # by_pairs).  The same bytes in the same order
         latent = shape
-        if geometry.index_layers:
+        if geometry.index_layers and geometry.pools == 2:
+            # GQA attention under a selection: a token's K over its V,
+            # ``[L, 1, P, ps, 2, KV x hd]``, ONE array (a pick is one
+            # fetch of the pair; ops/pallas/dsa.py); ``pools`` counts
+            # the two rows
+            latent = shape[:4] + (2,) + shape[4:]
+        elif geometry.index_layers:
             if geometry.page_size % 2:
                 raise ValueError(
                     f"kv_cache.page_size={geometry.page_size}: a spec under "
@@ -416,7 +425,7 @@ def make_kv_buffers(geometry: KVGeometry, dtype=jnp.bfloat16, sharding=None):
             latent = shape[:3] + (geometry.page_size // 2, 2) + shape[4:]
         k = jnp.zeros(latent, dtype, device=sharding)
         v = (jnp.zeros(shape, dtype, device=sharding)
-             if geometry.pools == 2 else None)
+             if geometry.pools == 2 and not geometry.index_layers else None)
         if geometry.index_layers:
             v = jnp.zeros(
                 (geometry.index_layers, 1) + shape[2:4]

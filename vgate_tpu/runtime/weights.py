@@ -404,6 +404,71 @@ def _dsa_params_from_getter(
     return params
 
 
+def _kv_dsa_params_from_getter(
+    spec: ModelSpec, getter: TensorGetter, dtype
+) -> Params:
+    """``KeyeVL2`` names (ASSUMED: the language model's are Qwen3-MoE's,
+    ``self_attn.{q,k,v,o}_proj``, ``self_attn.{q,k}_norm``, ``mlp.gate``
+    and ``mlp.experts.<e>.{gate,up,down}_proj``, under ``model.`` or,
+    where the checkpoint nests it beside the tower, under
+    ``model.language_model.``; the indexer's are DeepSeek-V3.2's,
+    ``self_attn.indexer.{wq_b, wk, k_norm, weights_proj}``; no checkpoint
+    was read) -> the pytree of models/hybrid.py for a ``kv_rows`` spec:
+    ``layers = {"layer": [layers, 1, ...]}``.  The tower's ``visual.*``
+    tensors are not read.  A chip's share: the experts ``first_expert ..``
+    of the router's width, and the first ``vocab_size`` rows of
+    embedding and head."""
+    E, first = spec.num_experts, spec.first_expert
+    np_dtype, V = np.dtype(dtype), spec.vocab_size
+    cast = lambda x: np.asarray(x).astype(np_dtype)
+
+    def base(name):
+        """The tensor under the language model's prefix, whichever the
+        checkpoint uses."""
+        for prefix in ("model.language_model.", "model."):
+            try:
+                return np.asarray(getter(prefix + name))
+            except KeyError:
+                continue
+        raise KeyError(name)
+
+    get = lambda i, name: base(f"layers.{i}.{name}")
+    lin = lambda i, name: get(i, f"{name}.weight").T
+
+    def layer(i):
+        pre = "self_attn.indexer."
+        out = {
+            "input_norm": get(i, "input_layernorm.weight"),
+            "post_norm": get(i, "post_attention_layernorm.weight"),
+            "q_norm": get(i, "self_attn.q_norm.weight"),
+            "k_norm": get(i, "self_attn.k_norm.weight"),
+            "index_q": {"w": lin(i, pre + "wq_b")},
+            "index_k": {"w": lin(i, pre + "wk")},
+            "index_k_norm": get(i, pre + "k_norm.weight"),
+            "index_k_bias": get(i, pre + "k_norm.bias"),
+            "index_w": {"w": lin(i, pre + "weights_proj")},
+            "router": lin(i, "mlp.gate"),
+        }
+        for n in ("q", "k", "v", "o"):
+            out[n] = {"w": lin(i, f"self_attn.{n}_proj")}
+        for n in ("gate", "up", "down"):
+            out[n] = {"w": np.stack([
+                lin(i, f"mlp.experts.{first + e}.{n}_proj")
+                for e in range(E)])}
+        return jax.tree.map(cast, out)
+
+    trees = [layer(i) for i in range(spec.num_layers)]
+    params: Params = {
+        "embed": cast(base("embed_tokens.weight")[:V]),
+        "layers": {"layer": jax.tree.map(
+            lambda *xs: np.stack(xs)[:, None], *trees)},
+        "final_norm": cast(base("norm.weight")),
+    }
+    if not spec.tie_embeddings:
+        params["lm_head"] = cast(np.asarray(getter("lm_head.weight"))[:V].T)
+    return params
+
+
 def _window_params_from_getter(
     spec: ModelSpec, getter: TensorGetter, dtype
 ) -> Params:
@@ -619,6 +684,8 @@ def params_from_getter(
             f"{spec.name}: loading a checkpoint of an EVA stack is not "
             "supported (its tensor names are unverified); serve it on "
             "random weights")
+    if spec.kv_rows:
+        return _kv_dsa_params_from_getter(spec, getter, dtype)
     if spec.is_dsa:
         return _dsa_params_from_getter(spec, getter, dtype)
     if spec.is_mla:
