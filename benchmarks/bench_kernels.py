@@ -15,6 +15,8 @@ blockwise twin).  Run on hardware:
     python benchmarks/bench_kernels.py eva_decode [PAGESxITEMS ...]
     python benchmarks/bench_kernels.py dense_prompt [CONFIG ...]
     python benchmarks/bench_kernels.py ssd_step [HEADS ...]
+    python benchmarks/bench_kernels.py flash_prefill_cells [CELL ...]
+    python benchmarks/bench_kernels.py flash_prefill_hollow
 
 Prints one JSON line per (kernel, shape) with median step times and the
 speedup.  CPU-safe fallback: refuses to run (the kernels need a TPU).
@@ -738,6 +740,196 @@ def bench_swa_prefill(S=8192, H=64, KV=8, hd=128, window=128,
                    _median_time(_looped(band), q, k, v, lens) * 1e6, 1)}
 
 
+# the prompt attention launch at the cells' shapes: (B, bucket rows, of
+# them real, H, KV, head width, block rows, keys a row picks under a
+# selection or 0, ``skip_padding``).  GLM's is ONE of its eight groups
+# of eight heads (models/hybrid.py ``_head_groups``); the dense bucket
+# of 2,048 rows in 256-row blocks is the control: it is bound by grid
+# steps, and two bodies under ``pl.when`` must not cost it one
+FLASH_PREFILL_CELLS = {
+    "glm-5.2-l5e16": (1, 16384, 11000, 8, 8, 256, 1024, 2048, True),
+    "keye-vl-2.0-30b-a3b-l12e32": (
+        1, 16384, 12288, 32, 4, 128, 1024, 2048, True),
+    "mistral-small-4-119b-l4e32": (
+        1, 8192, 5550, 32, 32, 128, 1024, 0, True),
+    "qwen2.5-7b-l14": (8, 2048, 1280, 28, 4, 128, 256, 0, False),
+    # no cell's: a score cap and a window that cuts some tiles (Gemma-2's
+    # form), for the bits (interpret mode on a CPU cannot hold them: XLA
+    # fuses the cap's product into the subtraction behind it)
+    "softcap-window": (1, 4096, 3000, 8, 4, 256, 1024, 0, False,
+                       {"softcap": 50.0, "window": 2500}),
+}
+
+
+def _selection_mask(key, rows, picks, block=2048):
+    """[1, rows, rows] int8: row i keeps each of its i + 1 causal keys
+    with probability ``picks / (i + 1)`` (all of them while it has no
+    more): the density a selection of ``picks`` keys a row has, spread
+    uniformly as seed-made index weights spread it."""
+    def some(first):
+        i = first + jnp.arange(block)[:, None]
+        j = jnp.arange(rows)[None, :]
+        u = jax.random.uniform(jax.random.fold_in(key, first), (block, rows))
+        return ((j <= i) & (u * (i + 1) < picks)).astype(jnp.int8)
+
+    return jnp.concatenate(
+        [jax.jit(some)(first) for first in range(0, rows, block)])[None]
+
+
+def bench_flash_prefill_cells(cells=None, forced=()):
+    """The prompt attention launch (ops/pallas/flash_prefill.py) at the
+    shapes of ``FLASH_PREFILL_CELLS``: us a launch and us a live tile a
+    head for the parent's kernel (where ``_parent`` holds a checkout),
+    for this tree's with every tile through the edge body
+    (``_all_edge``: under a selection that is the bias shared by a block
+    of heads ALONE) and for this tree's as served, each result
+    ``array_equal`` to the first's on the chip; ``forced``: heads a
+    program of the masked form beside the rule's (the probe's alone)."""
+    from vgate_tpu.ops.pallas import flash_prefill as tree
+
+    parent = _parent_module(
+        "_parent/vgate_tpu/ops/pallas/flash_prefill.py", "parent_flash")
+    for name in cells or FLASH_PREFILL_CELLS:
+        B, S, real, H, KV, hd, block, picks, skip, *extra = (
+            FLASH_PREFILL_CELLS[name])
+        key = jax.random.PRNGKey(54)
+        q = jax.random.normal(key, (B, S, H, hd), jnp.bfloat16)
+        k = jax.random.normal(
+            jax.random.fold_in(key, 1), (B, S, KV, hd), jnp.bfloat16)
+        v = jax.random.normal(
+            jax.random.fold_in(key, 2), (B, S, KV, hd), jnp.bfloat16)
+        lens = jnp.full((B,), real, jnp.int32)
+        kw = dict(block_q=block, block_k=block, skip_padding=skip,
+                  **(extra[0] if extra else {}))
+        rest = (k, v, lens)
+        if picks:
+            kw["name"] = "dsa_prefill_attention_pallas"
+            rest += (_selection_mask(jax.random.fold_in(key, 3), S, picks),)
+        tiles, interior = tree.tile_counts(
+            [real] * B, S, S, block, block, skip_padding=skip,
+            window=kw.get("window", 0))
+        rule = tree.head_block(H, H // KV, block, block, hd, 2)
+        forms = [("parent", parent, {}, None)] if parent else []
+        forms += [("all_edge", tree, {"_all_edge": True}, None),
+                  ("change", tree, {}, None)]
+        forms += [(f"change_hb{hb}", tree, {}, hb) for hb in forced
+                  if picks and hb != rule and H % hb == 0
+                  and hb % (H // KV) == 0]
+        want = None
+        for form, module, more, hb in forms:
+            def op(q, k, v, lens, *mask, module=module, more=more):
+                return module.flash_prefill_attention_pallas(
+                    q, k, v, lens, **({"mask": mask[0]} if mask else {}),
+                    **kw, **more)
+
+            rule_fn = tree.head_block
+            if hb:  # the probe's alone: no caller forces it (the
+                # kernel's own jit does not know the rule changed)
+                jax.clear_caches()
+                tree.head_block = lambda *a, hb=hb: hb
+            try:
+                got = np.asarray(jax.jit(op)(q, *rest), np.float32)
+                t = _median_time(_looped(op), q, *rest, iters=6)
+            except Exception as e:  # a forced size that does not fit
+                yield {"probe": "flash_prefill_cells", "cell": name,
+                       "form": form, "error": str(e)[:200]}
+                continue
+            finally:
+                if hb:
+                    tree.head_block = rule_fn
+                    jax.clear_caches()
+            want = got if want is None else want
+            yield {
+                "probe": "flash_prefill_cells", "cell": name, "form": form,
+                "shape": f"B{B} S{S} real{real} H{H} KV{KV} hd{hd}",
+                "blocks": block, "picks": picks,
+                "heads_a_program": (hb or rule) if picks and module is tree
+                else 1,
+                "tiles_a_head": tiles, "interior_tiles_a_head": interior,
+                "us_a_launch": round(t * 1e6, 1),
+                "us_a_tile": round(t * 1e6 / (tiles * H), 3),
+                "equal_to_first": bool(np.array_equal(got, want)),
+            }
+
+
+def bench_flash_prefill_hollow(widths=(128, 256), block=1024, tiles=64):
+    """Where a prompt attention tile's time is, by hollow bodies: a
+    kernel of the prompt kernel's blocks and scratch (``[block, hd]``
+    bf16 q, k, v in VMEM, fetched once; a float32 accumulator) whose
+    every grid step is ONE interior tile of one head, with only its two
+    products at float32 operands as the prompt kernel makes them
+    (``products``), the same at bf16 operands (``products_bf16``), only
+    its softmax over a standing ``[block, block]`` float32 tile (max,
+    subtract, exponent, sum: ``softmax``), and both (``tile``): us a
+    tile of each, beside the products' floor at the bf16 peak."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(q_ref, k_ref, v_ref, out_ref, acc, m, s_ref, *, form):
+        step = pl.program_id(0)
+
+        @pl.when(step == 0)
+        def _():
+            acc[...] = jnp.zeros_like(acc)
+            m[...] = jnp.full_like(m, -1e30)
+            s_ref[...] = jax.lax.dot_general(
+                q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        wide = jnp.bfloat16 if form == "products_bf16" else jnp.float32
+        dims = (((1,), (1,)), ((), ()))
+        if form == "softmax":
+            scores = s_ref[...]
+        else:
+            scores = jax.lax.dot_general(
+                q_ref[...].astype(wide), k_ref[...].astype(wide), dims,
+                preferred_element_type=jnp.float32)
+        if form in ("softmax", "tile"):
+            m_prev = m[:, :1]
+            m_new = jnp.maximum(
+                m_prev, jnp.max(scores, axis=-1, keepdims=True))
+            p = jnp.exp(scores - m_new)
+            m[...] = jnp.broadcast_to(
+                m_new + jnp.sum(p, axis=-1, keepdims=True) * 0, m.shape)
+        else:
+            p = scores
+        if form == "softmax":
+            acc[...] = acc[...] + p[:, :acc.shape[1]]
+        else:
+            acc[...] = acc[...] + jax.lax.dot_general(
+                p.astype(wide), v_ref[...].astype(wide),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        @pl.when(step == tiles - 1)
+        def _():
+            out_ref[...] = acc[...] + m[:, :1]
+
+    for hd in widths:
+        key = jax.random.PRNGKey(hd)
+        q, k, v = (jax.random.normal(jax.random.fold_in(key, i),
+                                     (block, hd), jnp.bfloat16)
+                   for i in range(3))
+        floor = 4 * block * block * hd / 197e12 * 1e6
+        for form in ("products", "products_bf16", "softmax", "tile"):
+            whole = pl.BlockSpec((block, hd), lambda i: (0, 0),
+                                 memory_space=pltpu.VMEM)
+            call = pl.pallas_call(
+                functools.partial(kernel, form=form),
+                grid=(tiles,), in_specs=[whole] * 3, out_specs=whole,
+                out_shape=jax.ShapeDtypeStruct((block, hd), jnp.float32),
+                scratch_shapes=[pltpu.VMEM((block, hd), jnp.float32),
+                                pltpu.VMEM((block, 128), jnp.float32),
+                                pltpu.VMEM((block, block), jnp.float32)],
+                compiler_params=pltpu.CompilerParams(
+                    dimension_semantics=("arbitrary",),
+                    vmem_limit_bytes=64 * 1024 * 1024))
+            t = _median_time(_looped(call), q, k, v, iters=6)
+            yield {"probe": "flash_prefill_hollow", "hd": hd, "block": block,
+                   "form": form, "us_a_tile": round(t * 1e6 / tiles, 3),
+                   "products_floor_us_at_bf16_peak": round(floor, 3)}
+
+
 # ONE expert layer of each configuration that holds a share of its
 # experts, as its cell runs it: (preset, held experts, rows of the
 # prompt program's wave, of them real, rows of a decode step)
@@ -839,17 +1031,22 @@ def expert_layer_program(spec, rows, real, block, form, combine, loop=4,
     return jax.jit(traced)
 
 
-def _parent_moe(path="_parent/vgate_tpu/ops/moe.py"):
-    """ops/moe.py of the checkout under ``_parent`` (`git archive` of
-    the parent commit), or None."""
+def _parent_module(path, name):
+    """A module of the checkout under ``_parent`` (`git archive` of the
+    parent commit), or None."""
     import importlib.util
 
     if not os.path.exists(path):
         return None
-    found = importlib.util.spec_from_file_location("parent_moe", path)
+    found = importlib.util.spec_from_file_location(name, path)
     module = importlib.util.module_from_spec(found)
     found.loader.exec_module(module)
     return module
+
+
+def _parent_moe(path="_parent/vgate_tpu/ops/moe.py"):
+    """ops/moe.py of the parent commit, or None."""
+    return _parent_module(path, "parent_moe")
 
 
 def bench_expert_layer(configs=None, loop=4):
@@ -1567,6 +1764,18 @@ def main() -> None:
         return
     if sys.argv[1:] == ["greedy_head"]:
         for line in bench_greedy_head():
+            print(json.dumps(line), flush=True)
+        return
+    if sys.argv[1:] == ["flash_prefill_hollow"]:
+        for line in bench_flash_prefill_hollow():
+            print(json.dumps(line), flush=True)
+        return
+    if sys.argv[1:2] == ["flash_prefill_cells"]:
+        # flash_prefill_cells [CELL ...] [hb=N ...]: forced heads a program
+        args = sys.argv[2:]
+        forced = tuple(int(a[3:]) for a in args if a.startswith("hb="))
+        cells = [a for a in args if not a.startswith("hb=")]
+        for line in bench_flash_prefill_cells(cells, forced=forced):
             print(json.dumps(line), flush=True)
         return
     if sys.argv[1:] == ["swa_prefill"]:

@@ -141,6 +141,10 @@ def report(seconds: float, trace: int = 0) -> Dict[str, Any]:
                 # window it closes, ``chunk_rows_written`` over
                 # ``windows_closed``)
                 "eva",
+                # the prompt kernel's tiles a head (PR 54:
+                # ``interior_tiles`` over ``tiles`` is how often the body
+                # without position tests runs)
+                "prefill_attn",
             )
         },
         "head_scopes": head_scopes() if trace else None,

@@ -383,6 +383,43 @@ def test_the_prompt_kernel_under_a_mask_is_the_masked_softmax():
             rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("lens", [[128, 70], [96, 33], [64, 128]],
+                         ids=lambda lens: "-".join(map(str, lens)))
+def test_the_prompt_kernel_makes_its_bias_once_for_a_block_of_heads(lens):
+    """The launch under a selection at the latent form's shape (a key
+    head a query head): every head of the program adds ONE bias made of
+    the int8 tile, interior tiles without a position test, and the rows
+    are bit for bit those of the kernel with every tile through the edge
+    body, and the masked softmax's at the kernel's tolerance; lengths
+    inside a block, on a block's edge and at the bucket's end."""
+    from vgate_tpu.ops.pallas.dsa import dsa_prefill_attention_pallas
+    from vgate_tpu.ops.pallas.flash_prefill import (
+        flash_prefill_attention_pallas, head_block,
+    )
+
+    rng = np.random.default_rng(54)
+    B, S, H, KV, hd = 2, 128, 4, 4, 32
+    assert head_block(H, H // KV, 32, 32, hd, 4) == H  # one program
+    q, k, v = (jnp.asarray(rng.normal(size=(B, S, h, hd)), jnp.float32)
+               for h in (H, KV, KV))
+    scores = jnp.asarray(rng.normal(size=(B, S, S)), jnp.float32)
+    causal = np.tri(S, dtype=bool)[None]
+    mask = jnp.asarray((np.asarray(dsa.select_mask(
+        jnp.where(causal, scores, -jnp.inf), TOPK)) & causal).astype(np.int8))
+    seq_lens = jnp.asarray(lens, jnp.int32)
+    got = np.asarray(dsa_prefill_attention_pallas(
+        q, k, v, seq_lens, mask, scale=hd ** -0.5, block_q=32, block_k=32,
+        interpret=True))
+    edge = np.asarray(flash_prefill_attention_pallas(
+        q, k, v, seq_lens, mask=mask, scale=hd ** -0.5, block_q=32,
+        block_k=32, skip_padding=True, interpret=True, _all_edge=True))
+    assert np.array_equal(got, edge)
+    want = np.asarray(dsa.masked_attention(q, k, v, mask, hd ** -0.5))
+    for b, n in enumerate(lens):
+        np.testing.assert_allclose(got[b, :n], want[b, :n],
+                                   rtol=2e-5, atol=2e-5)
+
+
 def picked_attention(q, pool, tables, sel, n_sel, layer, vw, scale):
     """numpy: a softmax over the slot's picked positions alone (zeros
     for a slot with none), from a pool [L, 1, P, ps, W]."""

@@ -390,6 +390,43 @@ def test_the_scoring_kernels_take_a_key_of_64_in_a_row_of_128():
                                rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("lens", [[128, 70], [96, 33], [64, 128]],
+                         ids=lambda lens: "-".join(map(str, lens)))
+def test_the_prompt_kernel_makes_its_bias_once_for_a_block_of_heads(lens):
+    """The launch under a selection at the GQA form's shape (the query
+    heads of a KV head stay in one program and share its K and V
+    blocks): ONE bias of the int8 tile for all of them, interior tiles
+    without a position test, bit for bit the kernel with every tile
+    through the edge body and the masked softmax's rows at the kernel's
+    tolerance."""
+    from vgate_tpu.ops.pallas.dsa import dsa_prefill_attention_pallas
+    from vgate_tpu.ops.pallas.flash_prefill import (
+        flash_prefill_attention_pallas, head_block,
+    )
+
+    rng = np.random.default_rng(54)
+    B, S, H, KV, hd = 2, 128, 8, 2, 32
+    assert head_block(H, H // KV, 32, 32, hd, 4) == H  # one program
+    q, k, v = (jnp.asarray(rng.normal(size=(B, S, h, hd)), jnp.float32)
+               for h in (H, KV, KV))
+    scores = jnp.asarray(rng.normal(size=(B, S, S)), jnp.float32)
+    causal = np.tri(S, dtype=bool)[None]
+    mask = jnp.asarray((np.asarray(dsa.select_mask(
+        jnp.where(causal, scores, -jnp.inf), TOPK)) & causal).astype(np.int8))
+    seq_lens = jnp.asarray(lens, jnp.int32)
+    got = np.asarray(dsa_prefill_attention_pallas(
+        q, k, v, seq_lens, mask, scale=hd ** -0.5, block_q=32, block_k=32,
+        interpret=True))
+    edge = np.asarray(flash_prefill_attention_pallas(
+        q, k, v, seq_lens, mask=mask, scale=hd ** -0.5, block_q=32,
+        block_k=32, skip_padding=True, interpret=True, _all_edge=True))
+    assert np.array_equal(got, edge)
+    want = np.asarray(dsa.masked_attention(q, k, v, mask, hd ** -0.5))
+    for b, n in enumerate(lens):
+        np.testing.assert_allclose(got[b, :n], want[b, :n],
+                                   rtol=2e-5, atol=2e-5)
+
+
 @pytest.mark.parametrize("pages", [3, 37], ids=lambda n: f"{n}-pages")
 def test_the_page_writer_moves_k_over_v_like_the_scatter(pages):
     from vgate_tpu.ops.pallas.dsa import dsa_write_pages_pallas

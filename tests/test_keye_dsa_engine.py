@@ -65,6 +65,45 @@ def test_unequal_rows_through_the_engine_and_what_it_reports(engine):
     assert engine.allocator.num_used == 0 or engine.prefix_cache_enabled
 
 
+def test_the_prompt_kernels_tiles_are_booked_where_the_kernel_runs(
+        engine, monkeypatch):
+    """``/debug/perf -> totals.prefill_attn``: a whole-prompt program
+    whose attention is the Pallas prompt kernel books the tiles a head
+    computes and how many of them run without position tests, once a
+    program, by the model layer's own count (models/decoder.py
+    ``prefill_attn_tiles``); the jnp twin's programs book nothing."""
+    import numpy as np
+
+    from vgate_tpu.runtime import engine_core
+
+    rng = np.random.default_rng(54)
+    before = dict(engine.perf.totals()["prefill_attn"])
+    contract.run(engine, [contract.tokens(rng, 45)], 2)
+    assert engine.perf.totals()["prefill_attn"] == before  # the twin's
+    booked, count = [], engine_core.prefill_attn_tiles
+
+    def counted(spec, bucket, lens):
+        booked.append((bucket, [int(n) for n in lens],
+                       count(spec, bucket, lens)))
+        return booked[-1][2]
+
+    monkeypatch.setattr(engine_core, "prefill_attn_tiles", counted)
+    # (what the engine REPORTS of the program alone: it still runs the twin)
+    monkeypatch.setattr(engine_core, "prefill_attention_impl",
+                        lambda *a: "pallas")
+    contract.run(engine, [contract.tokens(rng, 45),
+                          contract.tokens(rng, 19)], 2)
+    after = engine.perf.totals()["prefill_attn"]
+    assert booked and all(bucket == 64 for bucket, _, _ in booked)
+    assert sorted(n for _, lens, _ in booked for n in lens if n > 1) == [
+        19, 45]
+    assert after["programs"] - before["programs"] == len(booked)
+    assert after["tiles"] - before["tiles"] == sum(
+        t for _, _, (t, _) in booked) > 0
+    assert after["interior_tiles"] - before["interior_tiles"] == sum(
+        i for _, _, (_, i) in booked)
+
+
 def test_a_kernel_fetch_counts_one_token_a_pick():
     """Under the kernel a pick's pair of rows is ONE token (the latent
     form's is two): whole chunks of picks, no partner rows."""

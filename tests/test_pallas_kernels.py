@@ -246,6 +246,220 @@ def test_flash_prefill_kernel_window_softcap_scale():
     )
 
 
+# every form the prompt kernel is launched in, at 128 rows in 32-row
+# blocks (a 4 x 4 grid of tiles a head: tiles under the diagonal and
+# inside the lengths are interior, the others edge or dead): (q rows,
+# H, KV, lengths, the launch's arguments).  The lengths end inside a
+# block (77), on a block's edge (96, 64) and at the bucket's end (128)
+_TILE_FORMS = {
+    "plain": (128, 4, 4, [128, 77], {}),
+    "plain-block-edge": (128, 4, 4, [96, 64], {}),
+    "gqa": (128, 8, 2, [128, 77], {}),
+    "skip-padding": (128, 4, 2, [50, 128], {"skip_padding": True}),
+    "q-offsets": (64, 4, 2, [128, 90], {"q_offsets": [64, 32]}),
+    "window-scale": (128, 4, 2, [128, 77], {"window": 70, "scale": 0.2}),
+    "window-softcap-scale": (128, 4, 2, [128, 77], {
+        "window": 70, "softcap": 30.0, "scale": 0.2}),
+    "window-inside-a-block": (128, 4, 2, [96, 128], {"window": 9}),
+    "k-starts": (64, 4, 2, [128, 100], {
+        "q_offsets": [64, 64], "k_starts": [16, 40], "skip_padding": True}),
+    "band": (128, 4, 2, [100, 128], {
+        "window": 33, "band": 3, "block_q": 64}),
+    "masked": (128, 4, 4, [128, 77], {"mask": 0.3, "skip_padding": True}),
+    "masked-gqa": (128, 8, 2, [96, 128], {"mask": 0.3,
+                                          "skip_padding": True}),
+    "masked-sparse": (128, 8, 4, [128, 64], {"mask": 0.05,
+                                             "skip_padding": True}),
+}
+
+
+def _tile_form_case(form):
+    """(q, k, v, lens, the kernel's arguments, the jnp oracle's rows,
+    which rows are real) of one of ``_TILE_FORMS``."""
+    from vgate_tpu.ops import dsa
+    from vgate_tpu.ops.attention import flash_prefill_attention
+
+    Sq, H, KV, lens, kw = _TILE_FORMS[form]
+    kw = dict(kw)
+    B, Sk, hd = len(lens), 128, 32
+    rng = np.random.default_rng(54)
+    q = jnp.asarray(rng.normal(size=(B, Sq, H, hd)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, Sk, KV, hd)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, Sk, KV, hd)), jnp.float32)
+    seq_lens = jnp.asarray(lens, jnp.int32)
+    for name in ("q_offsets", "k_starts"):
+        if name in kw:
+            kw[name] = jnp.asarray(kw[name], jnp.int32)
+    offsets = np.asarray(kw.get("q_offsets", [0] * B))
+    real = (offsets[:, None] + np.arange(Sq)[None, :]
+            < np.asarray(lens)[:, None])
+    if "mask" in kw:
+        causal = np.tri(Sk, dtype=bool)
+        mask = (rng.uniform(size=(B, Sk, Sk)) < kw["mask"]) & causal
+        mask |= np.eye(Sk, dtype=bool)  # a row attends to itself
+        # rows 64..95 pick nothing in the tile of keys 32..63: a row
+        # whose whole tile is hidden, among tiles that are interior
+        mask[:, 64:96, 32:64] = False
+        kw["mask"] = jnp.asarray(mask.astype(np.int8))
+        want = dsa.masked_attention(q, k, v, kw["mask"], hd ** -0.5)
+    else:
+        want = flash_prefill_attention(
+            q, k, v, seq_lens, block_k=32, q_offset=kw.get("q_offsets"),
+            softcap=kw.get("softcap", 0.0), window=kw.get("window"),
+            scale=kw.get("scale"), k_start=kw.get("k_starts"))
+    kw.setdefault("block_q", 32)
+    return q, k, v, seq_lens, {"block_k": 32, **kw}, want, real
+
+
+@pytest.mark.fast  # tier-1: the prompt kernel every family launches
+@pytest.mark.parametrize("form", list(_TILE_FORMS))
+def test_flash_prefill_interior_tiles_are_the_edge_body_bit_for_bit(form):
+    """A tile that neither the diagonal, a length, a window nor a first
+    key cuts runs a body without position tests (and under a selection
+    the int8 tile is a bias made once for a block of heads): the rows
+    are the jnp oracle's at the kernel's tolerance, and BIT FOR BIT
+    those of the same kernel with every tile through the edge body."""
+    from vgate_tpu.ops.pallas.flash_prefill import (
+        flash_prefill_attention_pallas,
+    )
+
+    q, k, v, lens, kw, want, real = _tile_form_case(form)
+    got = np.asarray(flash_prefill_attention_pallas(
+        q, k, v, lens, interpret=True, **kw))
+    edge = np.asarray(flash_prefill_attention_pallas(
+        q, k, v, lens, interpret=True, _all_edge=True, **kw))
+    if kw.get("softcap"):
+        # XLA:CPU makes ONE fused multiply-add of the cap's product and
+        # the subtraction of the maximum where no select stands between
+        # them, as in the interior body: interpret mode's last bit, not
+        # Mosaic's (the probe's softcap row holds the bits on the chip)
+        np.testing.assert_allclose(got, edge, rtol=0, atol=1e-6)
+    else:
+        assert np.array_equal(got, edge)
+    np.testing.assert_allclose(
+        got[real], np.asarray(want)[real], rtol=2e-5, atol=2e-5)
+    if kw.get("skip_padding"):  # blocks of padding rows come out zero
+        whole = real.reshape(real.shape[0], -1, kw["block_q"]).any(-1)
+        assert not got.reshape(
+            whole.shape + (kw["block_q"], -1))[~whole].any()
+
+
+@pytest.mark.fast  # tier-1: the prompt kernel every family launches
+@pytest.mark.parametrize("form", list(_TILE_FORMS))
+def test_tile_counts_is_the_kernels_predicate_counted(form):
+    """``tile_counts`` (what ``totals.prefill_attn`` books) against a
+    count, element by element, of the tests an edge tile makes: a tile
+    is live where some element passes, interior where every one does."""
+    from vgate_tpu.ops.pallas.flash_prefill import tile_counts
+
+    q, k, _, lens, kw, _, _ = _tile_form_case(form)
+    Sq, Sk, bq, bk = q.shape[1], k.shape[1], kw["block_q"], kw["block_k"]
+    window, band = kw.get("window", 0), kw.get("band", 0)
+    offsets = np.asarray(kw.get("q_offsets", [0] * len(lens)))
+    firsts = np.asarray(kw.get("k_starts", [0] * len(lens)))
+    tiles = interior = 0
+    for b, n in enumerate(np.asarray(lens)):
+        q_pos = offsets[b] + np.arange(Sq)[:, None]
+        k_pos = np.arange(Sk)[None, :]
+        seen = (k_pos <= q_pos) & (k_pos < n) & (k_pos >= firsts[b])
+        if window:
+            seen &= q_pos - k_pos < window
+        for qi in range(Sq // bq):
+            if kw.get("skip_padding") and offsets[b] + qi * bq >= n:
+                continue
+            for ki in range(Sk // bk):
+                last = (qi + 1) * (bq // bk) - 1  # a band ends here
+                if band and not last - band < ki <= last:
+                    continue
+                tile = seen[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+                # (the kernel keeps a tile by its corners: a tile that
+                # the window alone empties is dead either way)
+                tiles += bool(tile.any())
+                interior += bool(tile.all())
+    got = tile_counts(
+        [int(n) for n in np.asarray(lens)], Sq, Sk, bq, bk,
+        q_offsets=offsets.tolist() if "q_offsets" in kw else None,
+        window=window, band=band,
+        k_starts=firsts.tolist() if "k_starts" in kw else None,
+        skip_padding=kw.get("skip_padding", False))
+    assert got == (tiles, interior)
+    # (a window shorter than a block leaves no tile whole)
+    assert interior > 0 or 0 < window < bq + bk
+
+
+@pytest.mark.fast  # tier-1: the prompt kernel every family launches
+@pytest.mark.parametrize("form", [
+    form for form, case in _TILE_FORMS.items() if "band" not in case[4]])
+def test_a_dead_step_fetches_no_tile(form):
+    """The index map of the K, V and mask blocks (``_key_block``): a
+    live step holds its own key block; every step behind a query
+    block's last live one names THAT block again (the pipeline copies a
+    block only when its index changes), and a query block with no live
+    step names one block throughout."""
+    from vgate_tpu.ops.pallas.flash_prefill import _key_block, _live
+
+    q, k, _, lens, kw, _, _ = _tile_form_case(form)
+    Sq, Sk, bq, bk = q.shape[1], k.shape[1], kw["block_q"], kw["block_k"]
+    offsets = np.asarray(kw.get("q_offsets", [0] * len(lens))).tolist()
+    firsts = np.asarray(kw.get("k_starts", [0] * len(lens))).tolist()
+    dead_steps = 0
+    for b, n in enumerate(np.asarray(lens).tolist()):
+        for qi in range(Sq // bq):
+            held = [int(_key_block(qi, ki, bq, bk, n, offsets[b],
+                                   kw.get("skip_padding", False)))
+                    for ki in range(Sk // bk)]
+            live = [bool(_live(offsets[b] + qi * bq, ki * bk, bq, bk, n,
+                               kw.get("window", 0), firsts[b],
+                               kw.get("skip_padding", False)))
+                    for ki in range(Sk // bk)]
+            assert all(h == ki for ki, h in enumerate(held) if live[ki])
+            last = max((ki for ki in range(len(live)) if live[ki]),
+                       default=-1)
+            behind = held[last + 1:]
+            dead_steps += len(behind)
+            assert len(set(held[max(last, 0):])) == 1, (qi, held, live)
+    assert dead_steps > 0
+
+
+@pytest.mark.fast  # tier-1: the prompt kernel every family launches
+@pytest.mark.parametrize("model, S, lens, by_kind", [
+    # 11 live query blocks of 1,024: 66 tiles under the diagonal, 55 of
+    # them off it with their last key inside 11,000
+    ("tiny-dsa-moe", 16384, [11000], {"attn_layers": (66, 55)}),
+    ("tiny-keye-dsa", 16384, [12288], {"attn_layers": (78, 66)}),
+    ("tiny-mla-moe", 8192, [5550], {"attn_layers": (21, 15)}),
+    # a window layer's band: three key blocks of 128 a query block of
+    # 256 (22 of them live, the first without a block before it), every
+    # one cut by the window of 8 or the diagonal
+    ("tiny-swa-moe", 8192, [5550], {"attn_layers": (21, 15),
+                                    "swa_layers": (22 * 3 - 1, 0)}),
+    # EVA: two windows of 32 a row against [16 summaries | the window]
+    # in key blocks of 16: the first window's two own blocks, the second
+    # window's and the block of the first's 8 summaries (cut by
+    # ``k_starts``)
+    ("tiny-eva", 64, [50, 64], {"attn_layers": (2 * (2 + 3), 0)}),
+    # the dense stack's packed group in 256-row blocks: 15 tiles a row of
+    # 1,280 (10 interior), one edge tile a padding row
+    ("Qwen/Qwen2.5-1.5B-Instruct", 2048, [1280] * 5 + [1] * 3,
+     {"attn_layers": (5 * 15 + 3, 5 * 10)}),
+    # a short bucket is one tile a row, cut by its diagonal
+    ("Qwen/Qwen2.5-1.5B-Instruct", 128, [100] * 8, {"attn_layers": (8, 0)}),
+])
+def test_a_prompt_programs_tiles_by_the_cells_shapes(model, S, lens, by_kind):
+    """models/decoder.py ``prefill_attn_tiles`` (what the engine books
+    into ``totals.prefill_attn``) at the long cells' shapes against
+    counts by hand, a layer of each kind: five of six tiles interior
+    where a prompt of 11-12 k rows runs in 1,024-row blocks, none in a
+    window layer's band or a short bucket."""
+    from vgate_tpu.models.decoder import prefill_attn_tiles
+    from vgate_tpu.models.specs import spec_for_model_id
+
+    spec = spec_for_model_id(model)
+    assert prefill_attn_tiles(spec, S, lens) == tuple(
+        sum(getattr(spec, layers) * count[i]
+            for layers, count in by_kind.items()) for i in range(2))
+
+
 def test_paged_multitok_kernel_matches_suffix_attention():
     """The speculative-verify kernel vs the jnp suffix path: S candidate
     rows per slot, varying input_lens, window on/off, softcap+scale."""

@@ -827,6 +827,23 @@ def test_prefill_rows_are_counted_by_the_models_own_rule(
     assert merged["totals"]["prefill"]["rows_padding"] == 4 * (worked - real)
 
 
+def test_the_prompt_kernels_tiles_add_up_and_merge():
+    """``totals.prefill_attn``: a program's tiles and those of them the
+    kernel runs without position tests, there from the start (a
+    ``decode-heavy`` cell reads 0 interior tiles, not a missing key),
+    summed over the replicas."""
+    rec = recorder()
+    assert rec.totals()["prefill_attn"] == {
+        "programs": 0, "tiles": 0, "interior_tiles": 0}
+    rec.note_prefill_attn(66 * 5, 55 * 5)
+    rec.note_prefill_attn(8, 0)
+    assert rec.totals()["prefill_attn"] == {
+        "programs": 2, "tiles": 338, "interior_tiles": 275}
+    merged = perf_mod.merge_snapshots([rec.snapshot(), rec.snapshot()])
+    assert merged["totals"]["prefill_attn"] == {
+        "programs": 4, "tiles": 676, "interior_tiles": 550}
+
+
 def test_chunk_lengths_are_whatever_the_engine_ran():
     """No fixed ladder: tpu.decode_chunk is configurable, so a 16-step
     chunk counts; a spec-verify pass is steps, not a chunk; the dp merge

@@ -1005,6 +1005,72 @@ def test_selection_prompt_program_fits_beside_the_pool_on_v5e(glm_prompt):
     assert f"s8[1,{GLM_CTX},{GLM_CTX}]" in text
 
 
+# the prompt attention launch at the three long cells' shapes (B, rows,
+# H, KV, head width, whether under a selection, its name in a trace,
+# heads a program): GLM's is one of its eight groups of eight heads
+PROMPT_LAUNCHES = {
+    "glm": (1, GLM_CTX, 8, 8, 256, True, "dsa_prefill_attention_pallas", 4),
+    "keye": (1, 16384, 32, 4, 128, True, "dsa_prefill_attention_pallas", 8),
+    "mistral": (1, 8192, 32, 32, 128, False, None, 1),
+}
+
+
+@pytest.mark.parametrize("cell", list(PROMPT_LAUNCHES))
+def test_prompt_attention_launch_fits_its_vmem_on_v5e(v5e, cell):
+    """The flash prompt kernel in 1,024-row blocks at the cell's shape:
+    two bodies a tile (an interior one without position tests) and,
+    under a selection, the int8 tile as a float32 bias in VMEM for the
+    heads of a program, as many as ``head_block`` reckons: Mosaic takes
+    the launch under its ``vmem_limit_bytes``, under the name the
+    metrics match."""
+    from vgate_tpu.ops.pallas import flash_prefill
+
+    B, S, H, KV, hd, masked, name, heads = PROMPT_LAUNCHES[cell]
+    A = _abstract(v5e)
+    if masked:
+        assert flash_prefill.head_block(H, H // KV, 1024, 1024, hd, 2) == (
+            heads)
+    args = [A((B, S, H, hd), jnp.bfloat16), A((B, S, KV, hd), jnp.bfloat16),
+            A((B, S, KV, hd), jnp.bfloat16), A((B,), jnp.int32)]
+    kw = dict(block_q=1024, block_k=1024, skip_padding=True, name=name)
+    if masked:
+        launch = jax.jit(
+            lambda q, k, v, lens, mask:
+            flash_prefill.flash_prefill_attention_pallas(
+                q, k, v, lens, mask=mask, **kw))
+        args.append(A((B, S, S), jnp.int8))
+    else:
+        launch = jax.jit(
+            lambda q, k, v, lens:
+            flash_prefill.flash_prefill_attention_pallas(
+                q, k, v, lens, **kw))
+    text = launch.lower(*args).compile().as_text()
+    assert (name or "flash_prefill_attention_pallas") in text
+
+
+# temporary bytes of the parent's (PR 53, commit 1dddb33) prompt programs
+# by the same compile: the bias of a selection's tile is VMEM scratch of
+# the launch, no array of the program's
+PARENT_53_TEMP_BYTES = {"glm": 2_439_488_512, "mistral": 814_459_904,
+                        "keye": 822_795_776}
+
+
+@pytest.mark.parametrize("cell, launch", [
+    ("glm", "dsa_prefill_attention_pallas"),
+    ("mistral", "flash_prefill_attention_pallas"),
+])
+def test_prompt_programs_keep_the_parents_temporaries_on_v5e(
+        cell, launch, request):
+    """The GLM and mistral cuts' prompt programs with the kernel of two
+    bodies a tile: the launch under the name the metrics match, and
+    temporaries within 16 MB of the parent's (the Keye cut's:
+    ``test_kv_selection_prompt_program_fits_beside_the_pool_on_v5e``)."""
+    compiled, _, _, _ = request.getfixturevalue(f"{cell}_prompt")
+    assert launch in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= PARENT_53_TEMP_BYTES[cell] + (16 << 20), temp
+
+
 def _counted_loops(text, scope):
     """The ``while`` of the compiled program traced under ``scope``
     whose condition holds no constant: its trips are an operand."""
@@ -1660,6 +1726,9 @@ def test_kv_selection_prompt_program_fits_beside_the_pool_on_v5e(v5e):
     assert 11.6e9 < held < 11.8e9
     assert mem.alias_size_in_bytes >= _nbytes((pool, keys))
     assert mem.temp_size_in_bytes < 1.0e9, mem.temp_size_in_bytes
+    # (the selection's bias is the launch's VMEM scratch: the parent's)
+    assert mem.temp_size_in_bytes <= PARENT_53_TEMP_BYTES["keye"] + (
+        16 << 20), mem.temp_size_in_bytes
     text = compiled.as_text()
     for name in ("dsa_index_scores_pallas", "dsa_prefill_attention_pallas",
                  "dsa_write_pages_pallas", "moe_grouped_matmul_pallas"):

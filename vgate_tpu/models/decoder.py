@@ -565,6 +565,63 @@ def _eva_prefill_attend(spec: ModelSpec, impl: str):
         block_k=math.gcd(k.shape[1], 256))
 
 
+def prefill_attn_tiles(spec: ModelSpec, S: int, lens) -> Tuple[int, int]:
+    """(tiles, interior tiles) a query head computes in the Pallas prompt
+    attention launches of ONE ``prefill_forward`` over ``[len(lens), S]``
+    rows that hold ``lens`` tokens (ints on the host), summed over the
+    spec's layers, each in the blocks its launch takes above (a full
+    layer's, plain or under a selection; a window layer's band; an EVA
+    layer's windows): ops/pallas/flash_prefill.py ``tile_counts``, the
+    kernel's own predicate, for ``/debug/perf -> totals.prefill_attn``."""
+    import numpy as np
+
+    from vgate_tpu.models.hybrid import prompt_rows
+    from vgate_tpu.ops.pallas.flash_prefill import (
+        SWA_BLOCK_K, SWA_BLOCK_Q, tile_counts,
+    )
+
+    lens = [int(n) for n in lens]
+    block = 1024 if S >= 4096 else 256
+    # (the launch leaves padding query blocks out where the pass reads
+    # none: a stack of several kinds, and the dense stack's packed pass)
+    skip = spec.is_hybrid or not isinstance(
+        prompt_rows(spec, S, np.asarray(lens))[1], int)
+    W, c = spec.eva_window, spec.eva_chunk
+    # ``tile_counts``' arguments of a layer's launch -> layers that make it
+    launches: Dict[tuple, int] = {}
+    form = lambda *args, **kw: (args, tuple(sorted(kw.items())))
+    for kinds, window in zip(spec.stack, spec.layer_windows):
+        if "swa" in kinds:
+            bk = min(SWA_BLOCK_K, S)
+            bq = max(bk, min(SWA_BLOCK_Q, S))
+            launch = form(
+                tuple(lens), S, S, bq, bk, window=window,
+                band=bq // bk + -(-(window - 1) // bk), skip_padding=True)
+        elif "eva" in kinds and S > W:
+            if S % W:
+                continue  # no whole windows: the jnp twin's
+            nw, ns = S // W, S // c
+            own = [min(max(n - w * W, 0), W) for n in lens
+                   for w in range(nw)]
+            launch = form(
+                tuple(ns + n for n in own), W, ns + W, min(1024, W),
+                min(1024, math.gcd(ns + W, W)), q_offsets=(ns,) * len(own),
+                k_starts=tuple(ns - w * W // c
+                               for _ in lens for w in range(nw)),
+                skip_padding=True)
+        elif {"attn", "mla", "dsa", "eva"} & set(kinds):
+            launch = form(tuple(lens), S, S, block, block, window=window,
+                          skip_padding=skip)
+        else:
+            continue
+        launches[launch] = launches.get(launch, 0) + 1
+    tiles = interior = 0
+    for (args, kw), layers in launches.items():
+        some, inside = tile_counts(*args, **dict(kw))
+        tiles, interior = tiles + layers * some, interior + layers * inside
+    return tiles, interior
+
+
 def packed_group(spec: ModelSpec) -> int:
     """Query heads a row of a PACKED pool serves (2 G); 0 where rows
     hold one head."""

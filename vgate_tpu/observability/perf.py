@@ -717,6 +717,9 @@ class PerfRecorder:
         # rows of the prompt programs read back (every family)
         self._prefill = dict.fromkeys(
             ("rows_worked", "rows_real", "rows_padding"), 0)
+        # tiles of the Pallas prompt attention launches (every family)
+        self._prefill_attn = dict.fromkeys(
+            ("programs", "tiles", "interior_tiles"), 0)
         self.total_engine_cpu_s = 0.0
         self.total_engine_cpu_in_wait_s = 0.0
         # the device's seconds by program (EngineCore._launch posts)
@@ -1059,6 +1062,17 @@ class PerfRecorder:
         for name, add in (("rows_worked", worked), ("rows_real", real),
                           ("rows_padding", worked - real)):
             self._prefill[name] += add
+
+    def note_prefill_attn(self, tiles: int, interior: int) -> None:
+        """One whole-prompt program whose attention is the Pallas prompt
+        kernel, booked at its dispatch: a query head computed ``tiles``
+        tiles of keys in its layers' launches, ``interior`` of them
+        wholly under the diagonal and inside the length, where the
+        kernel makes no position test (models/decoder.py
+        ``prefill_attn_tiles``, by the kernel's own predicate)."""
+        for name, add in (("programs", 1), ("tiles", tiles),
+                          ("interior_tiles", interior)):
+            self._prefill_attn[name] += add
 
     def note_mla_decode(self, steps: int, rows: int, ctx_tokens: int,
                         layers: int) -> None:
@@ -1456,6 +1470,7 @@ class PerfRecorder:
             "boot_seconds": dict(BOOT_SECONDS),
             "gc": GC.totals(),
             "prefill": dict(self._prefill),
+            "prefill_attn": dict(self._prefill_attn),
             "device_clock": self.device.totals(),
         }
         if self.request_totals is not None:
@@ -1838,6 +1853,8 @@ def merge_snapshots(
         "boot_seconds": dict(totals[0].get("boot_seconds", {})),
         "gc": dict(totals[0].get("gc", {})),
         "prefill": _sum_dicts([t.get("prefill", {}) for t in totals]),
+        "prefill_attn": _sum_dicts(
+            [t.get("prefill_attn", {}) for t in totals]),
         "device_clock": _sum_device_clocks(
             [t["device_clock"] for t in totals if "device_clock" in t]
         ),

@@ -23,8 +23,8 @@ mathematics and the ``jax.numpy`` twins):
 * ``dsa_write_pages_pallas``: a prompt's rows into either pool of pairs,
   a page a descriptor.
 * ``dsa_prefill_attention_pallas``: the flash prompt kernel with the
-  selection as a mask tile beside each key block, under a name of its
-  own.
+  selection as a mask tile beside each key block, made a bias once for
+  the block of heads a program takes, under a name of its own.
 """
 
 from __future__ import annotations
@@ -577,9 +577,11 @@ def dsa_prefill_attention_pallas(q, k, v, seq_lens, mask, *, scale: float,
     """A prompt's attention under the selection: q [B, S, H, hd], k / v
     [B, S, H, .] expanded from the prompt's own latent rows, mask [B, S,
     S] int8 (nonzero = the query attends to the key; nothing above the
-    diagonal).  The flash kernel with a mask tile beside each key block,
-    under a name of its own; blocks of padding rows (past ``seq_lens``)
-    are left out and come out zero: a prompt pass reads no such row."""
+    diagonal).  The flash kernel with a mask tile beside each key block
+    (a float32 bias once for the heads that share it:
+    ops/pallas/flash_prefill.py ``head_block``), under a name of its
+    own; blocks of padding rows (past ``seq_lens``) are left out and
+    come out zero: a prompt pass reads no such row."""
     from vgate_tpu.ops.pallas.flash_prefill import (
         flash_prefill_attention_pallas,
     )

@@ -71,6 +71,7 @@ from vgate_tpu.models.decoder import (
     multitok_attention_impl,
     packed_group,
     prefill_attention_impl,
+    prefill_attn_tiles,
 )
 from vgate_tpu.models.hybrid import eva_window_pages
 from vgate_tpu.models.hybrid import make_state as make_hybrid_state
@@ -3188,6 +3189,9 @@ class EngineCore:
                 output=out[0], prompt_tokens=real, rows=B, bucket=bucket
             )
         self.perf.count(prompt_programs=1, prompt_tokens=real)
+        if not cached and attention[1]() == "pallas":
+            self.perf.note_prefill_attn(
+                *prefill_attn_tiles(self.spec, bucket, lens))
         # the rows the program works on, by the model layer's own rule
         arrays, rows = prompt_rows(
             self.spec, bucket, lens,
