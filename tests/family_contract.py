@@ -5,9 +5,10 @@ Each family of ``models/hybrid.py`` serves its tiny preset through
 reference's full forward on the same seeded weights, in a test file of
 its own (``test_hybrid_model``, ``test_nemotron_h_model``,
 ``test_mla_model``, ``test_exaone_moe_engine``, ``test_glm_dsa_engine``:
-one file is one xdist worker's, so one worker holds one family's
-compiles).  A ``Family`` says what differs; the functions below are the
-behaviours every family is held to, each a body that takes the record.
+one file is one xdist worker's (``tests/conftest.py`` has the rule), so
+one worker holds one family's compiles).  A ``Family`` says what
+differs; the functions below are the behaviours every family is held
+to, each a body that takes the record.
 A family's own checks (what ``/stats`` reports, its counters) stay in
 its file, after the call.  ROADMAP.md D0 lists the (family, behaviour)
 pairs no file calls yet.
@@ -17,6 +18,9 @@ import contextlib
 import dataclasses
 import functools
 import json
+import os
+import subprocess
+import sys
 from typing import Any
 
 import jax
@@ -75,10 +79,25 @@ class Family:
     def reference(self, cfg, dtype, full, n_prompt):
         """The reference's log-probabilities of ``full[n_prompt:]``."""
         if self.draws_weights:
-            weights = _drawn(self.ref, json.dumps(cfg, sort_keys=True), dtype)
-            return self.ref.logprobs(cfg, weights, [full], [n_prompt])[0]
-        return self.ref.logprobs(
-            cfg, 0, jnp.dtype(dtype), [full], [n_prompt])[0]
+            logprobs = functools.partial(self.ref.logprobs, cfg, _drawn(
+                self.ref, json.dumps(cfg, sort_keys=True), dtype))
+        else:
+            logprobs = functools.partial(
+                self.ref.logprobs, cfg, 0, jnp.dtype(dtype))
+        return one_length(logprobs, full, n_prompt, self.max_model_len)
+
+
+def one_length(logprobs, seq, first, length):
+    """``logprobs([seq], [first])[0]`` by way of ONE shape: the sequence
+    padded with token 0 to ``length``, and the rows of what was padded
+    dropped.  The references are causal (no row reads a later token),
+    and dispatch their operations one by one, each a program compiled a
+    shape: at its own length every sequence pays for all of them again
+    (``tests/test_granite_hybrid_engine.py`` alone: 86 s, 66 s at one
+    length; an expert layer's loop still compiles a count of rows)."""
+    assert first <= len(seq) <= length, (first, len(seq), length)
+    padded = list(seq) + [0] * (length - len(seq))
+    return logprobs([padded], [first])[0][:len(seq) - first]
 
 
 @functools.lru_cache(maxsize=None)
@@ -126,6 +145,47 @@ def agree(family, core, seq, prompt, cfg=None, dtype="float32"):
              for pos, e in enumerate(entries) for t in e["top_logprobs"]]
     assert diffs and max(diffs) < family.tol[dtype], (
         max(diffs), np.mean(diffs))
+
+
+# ---- the rehearsal: a family's benchmark cell end to end on the CPU
+
+# ONE window: in 4 s a loaded host answers nothing and the window closes
+# with ``attempted: 0`` (PERF.md section 7, PR 43 item 2)
+REHEARSAL_WINDOW_S = "12"
+# ONE limit: alone a rehearsal takes 60-260 s; beside five other workers
+# one has taken five times its time alone (CHANGES.md, PR 31)
+REHEARSAL_LIMIT_S = 1400
+
+
+def rehearse(cell, seed):
+    """``cell`` rehearsed as the benchmark runs it: the family's tiny
+    preset behind the real gateway in a process of its own, every phase
+    of a traced run, the answers held to the configuration's own plain
+    reference.  Returns the run's result line, which ended correct, with
+    nothing failed and something attempted; the family's file asserts
+    what is its own (``compared``, its tolerance, the metrics it must
+    and must not report).  Every family's rehearsal is tier-1, and the
+    files are apart so that they land on different workers
+    (``conftest.py LONGEST_FIRST``); ``tests/test_rehearsal_lint.py``
+    keeps this the only place that spells the command."""
+    # the server's own XLA flags, not the tests' eight virtual devices
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run(
+        [sys.executable, "-m", "perfbench.run", "--workload", cell,
+         "--seed", str(seed), "--seconds", REHEARSAL_WINDOW_S,
+         "--trace", "1", "--rehearse"],
+        cwd=manifest.ROOT, env=env, capture_output=True, text=True,
+        timeout=REHEARSAL_LIMIT_S,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    result = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert result["correct"] is True and result["failed"] == 0, {
+        k: result[k] for k in ("attempted", "failed", "reference",
+                               "in_window")}
+    assert result["attempted"] >= 1 and result["rehearsal"] is True
+    assert result["reference"]["ok"]
+    return result
 
 
 # ---- the contract

@@ -10,6 +10,7 @@ and the expert layer's shares.  ``tests/test_exaone_moe_engine.py`` has
 the same through the engine."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +20,8 @@ import pytest
 from perfbench import manifest
 from perfbench.references import exaone_moe as ref
 from tests import prompt_row_blocks as row_blocks
-from vgate_tpu.models import decoder, hybrid
+from tests.family_contract import one_length
+from vgate_tpu.models import decoder, hybrid, specs
 from vgate_tpu.models.specs import spec_for_model_id
 from vgate_tpu.ops import moe
 from vgate_tpu.runtime.kv_cache import KVGeometry, make_kv_buffers
@@ -39,6 +41,10 @@ TINY = manifest.load_json(
 # product against one expert at a time): measured 9.5e-7 at most
 TOL = 1e-4
 PS, SLOTS, RING = 4, 4, 12  # page, decode slots, a ring's tokens (3 pages)
+# the rows of every whole-prompt pass (what the serving path's buckets
+# do: a length is ``seq_lens``, not a shape), and the length every
+# sequence has for the reference
+BUCKET, REF_LEN = 32, 64
 # the forwards as the step programs run them: jitted, the spec static
 PREFILL = jax.jit(decoder.prefill_forward, static_argnums=1)
 SUFFIX = jax.jit(decoder.prefill_suffix_forward, static_argnums=1)
@@ -59,6 +65,12 @@ def fresh_cache():
             hybrid.make_state(SPEC, SLOTS, jnp.float32, PS))
 
 
+def reference(seq, prompt_len):
+    """The plain reference's rows for ``seq[prompt_len:]``."""
+    return one_length(functools.partial(ref.logprobs, TINY, 0, jnp.float32),
+                      seq, prompt_len, REF_LEN)
+
+
 def served_logprobs(params, seq, prompt_len, slot=2, chunks=None):
     """Log-softmax rows for positions ``prompt_len - 1 .. len(seq) - 2``
     from the program's forwards: the prompt whole (or in ``chunks``),
@@ -67,7 +79,8 @@ def served_logprobs(params, seq, prompt_len, slot=2, chunks=None):
     table = np.arange(1, 33, dtype=np.int32)[None]
     one = lambda v: jnp.asarray([v])
     if chunks is None:
-        S = -(-prompt_len // 16) * 16
+        S = BUCKET  # one program whatever the prompt's length
+        assert prompt_len <= S
         toks = np.zeros((1, S), np.int32)
         toks[0, :prompt_len] = seq[:prompt_len]
         logits, kp, vp, st = PREFILL(
@@ -111,7 +124,7 @@ def test_whole_prompt_then_decode_through_ring_and_pool(
     rng = np.random.default_rng(prompt_len)
     seq = [int(t) for t in rng.integers(3, 500, prompt_len + decoded)]
     got = served_logprobs(params, seq, prompt_len)
-    want = ref.logprobs(TINY, 0, jnp.float32, [seq], [prompt_len])[0]
+    want = reference(seq, prompt_len)
     assert got.shape == want.shape
     assert np.abs(got - want).max() < TOL, what
 
@@ -125,7 +138,7 @@ def test_a_chunked_prefill_reads_the_ring_and_gives_the_whole_prompts_logits(
     seq = [int(t) for t in rng.integers(3, 500, 30 + 5)]
     whole = served_logprobs(params, seq, 30)
     chunked = served_logprobs(params, seq, 30, chunks=chunks)
-    want = ref.logprobs(TINY, 0, jnp.float32, [seq], [30])[0]
+    want = reference(seq, 30)
     assert np.abs(chunked - whole).max() < TOL
     assert np.abs(chunked - want).max() < TOL
 
@@ -264,14 +277,21 @@ def test_the_banded_prompt_kernel_is_the_window_as_a_mask(
             rtol=2e-5, atol=2e-5)
 
 
+# one period behind the leading layer (L L L G L, the cell's own stack,
+# as tests/test_exaone_moe_engine.py has it): every kind of sub-block
+# the row loop wraps, in the fewest layers the row-block passes compile
+SHORT = specs._register(dataclasses.replace(
+    SPEC, name="tiny-swa-moe-l5", num_layers=5))
+
+
 @pytest.mark.parametrize("fill", list(row_blocks.FILLS))
 def test_a_long_prompt_pass_works_on_its_own_row_blocks(fill):
     """A bucket of four blocks of rows (the block patched to 8): the
     window and full layers' projections, the dense layer and the expert
     layer's position-wise parts in a counted loop over the blocks the
     longer prompt reaches, against the pass over the whole bucket."""
-    row_blocks.check_prompt_pass("tiny-swa-moe", row_blocks.FILLS[fill])
+    row_blocks.check_prompt_pass(SHORT.name, row_blocks.FILLS[fill])
 
 
 def test_greedy_tokens_are_the_same_with_the_row_loop(monkeypatch):
-    row_blocks.check_greedy_identity(monkeypatch, "tiny-swa-moe")
+    row_blocks.check_greedy_identity(monkeypatch, SHORT.name)

@@ -11,6 +11,7 @@ published preset's stack, counts and cache geometry.
 engine."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +20,7 @@ import pytest
 
 from perfbench import manifest
 from perfbench.references import granite_hybrid as ref
+from tests.family_contract import one_length
 from vgate_tpu.models import decoder, hybrid
 from vgate_tpu.models.specs import spec_for_model_id
 from vgate_tpu.runtime.kv_cache import KVGeometry, make_kv_buffers
@@ -104,7 +106,8 @@ def served_logprobs(params, seq, prompt_len, slot=2, chunks=None, spec=SPEC,
 
 
 def reference_logprobs(seq, prompt_len, cfg=TINY):
-    return ref.logprobs(cfg, 0, jnp.float32, [seq], [prompt_len])[0]
+    return one_length(functools.partial(ref.logprobs, cfg, 0, jnp.float32),
+                      seq, prompt_len, 64)
 
 
 def sequence(seed, n):

@@ -266,7 +266,7 @@ def test_decode_kernel_int8_scale_rows_across_blocks():
     kernel: two programs (64 slots each at this geometry), live slots
     among dead ones, so a chunk's scale rows follow its pages across a
     slot boundary.  A dead row comes out zero."""
-    from tests.test_pallas_kernels import _live_rows_match
+    from tests.pallas_cases import live_rows_match
     from vgate_tpu.ops.attention import paged_decode_attention
     from vgate_tpu.ops.pallas.paged_attention import (
         paged_decode_attention_pallas,
@@ -282,7 +282,7 @@ def test_decode_kernel_int8_scale_rows_across_blocks():
     got = paged_decode_attention_pallas(
         q, kq, vq, pt, jnp.asarray(lens), interpret=True
     )
-    _live_rows_match(got, expect, lens)
+    live_rows_match(got, expect, lens)
 
 
 # ------------------------------------------------- engine-level quality

@@ -11,6 +11,7 @@ the cache's geometry; and the expert layer's shares.
 ``tests/test_lfm2_moe_engine.py`` has the same through the engine."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,7 @@ import pytest
 from perfbench import manifest
 from perfbench.references import lfm2_moe as ref
 from tests import prompt_row_blocks as row_blocks
+from tests.family_contract import one_length
 from vgate_tpu.models import decoder, hybrid, specs
 from vgate_tpu.models.specs import spec_for_model_id
 from vgate_tpu.ops import attention, head_pack, moe
@@ -55,6 +57,12 @@ def fresh_cache(spec=SPEC):
         max_model_len=128, dtype_bytes=4)
     return (*make_kv_buffers(geo, jnp.float32),
             hybrid.make_state(spec, SLOTS, jnp.float32, PS))
+
+
+def reference(seq, prompt_len, cfg=TINY):
+    """The plain reference's rows for ``seq[prompt_len:]``."""
+    return one_length(functools.partial(ref.logprobs, cfg, 0, jnp.float32),
+                      seq, prompt_len, 64)
 
 
 def served_logprobs(params, seq, prompt_len, slot=2, chunks=None, spec=SPEC,
@@ -118,7 +126,7 @@ def test_whole_prompt_then_decode_through_tails_and_pool(
     rng = np.random.default_rng(prompt_len)
     seq = [int(t) for t in rng.integers(3, 500, prompt_len + decoded)]
     got = served_logprobs(params, seq, prompt_len, dirty=True)
-    want = ref.logprobs(TINY, 0, jnp.float32, [seq], [prompt_len])[0]
+    want = reference(seq, prompt_len)
     assert got.shape == want.shape
     assert np.abs(got - want).max() < TOL, what
 
@@ -133,7 +141,7 @@ def test_a_chunked_prefill_carries_the_tail_from_chunk_to_chunk(
     seq = [int(t) for t in rng.integers(3, 500, 30 + 5)]
     whole = served_logprobs(params, seq, 30)
     chunked = served_logprobs(params, seq, 30, chunks=chunks, dirty=True)
-    want = ref.logprobs(TINY, 0, jnp.float32, [seq], [30])[0]
+    want = reference(seq, 30)
     assert np.abs(chunked - whole).max() < TOL
     assert np.abs(chunked - want).max() < TOL
 
@@ -274,7 +282,7 @@ def test_a_stack_at_head_64_serves_the_same_packed_and_unpacked():
                layer_types=TINY["layer_types"][:8])
     rng = np.random.default_rng(11)
     seq = [int(t) for t in rng.integers(3, 500, 30 + 6)]
-    want = ref.logprobs(cfg, 0, jnp.float32, [seq], [30])[0]
+    want = reference(seq, 30, cfg)
     plain = served_logprobs(params, seq, 30, chunks=(16, 16), spec=WIDE)
     packed = served_logprobs(params, seq, 30, chunks=(16, 16), spec=PACKED)
     assert np.abs(packed - plain).max() < 1e-6

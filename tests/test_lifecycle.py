@@ -356,6 +356,16 @@ async def test_drain_completes_inflight_dry_run():
         responses = await asyncio.gather(*inflight)
         assert [r.status for r in responses] == [200] * 4
         assert await app["drain"].wait_drained(5.0)
+        # `on_complete` is a callback of its own (`call_soon`, ahead of
+        # the event).  A waiter that was waiting finds it run; this one
+        # may come when the drain has ALREADY finished, in the very turn
+        # of the loop in which the last response woke it, and then stands
+        # ahead of the callback in the loop's queue (seen beside five
+        # busy workers, PR 55): wait for the callback, not for a time
+        for _ in range(100):
+            if done:
+                break
+            await asyncio.sleep(0)
         assert done == [True]
         assert app["drain"].aborted_stragglers == 0
     finally:

@@ -7,27 +7,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from tests.pallas_cases import make_case
 from vgate_tpu.ops.attention import paged_decode_attention
 from vgate_tpu.ops.pallas.paged_attention import paged_decode_attention_pallas
-
-
-def make_case(B=4, H=8, KV=2, hd=128, ps=16, pages_per_seq=16, seed=0,
-              lens=None):
-    rng = np.random.default_rng(seed)
-    P = 1 + B * pages_per_seq
-    q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
-    k_pages = jnp.asarray(rng.normal(size=(KV, P, ps, hd)), jnp.float32)
-    v_pages = jnp.asarray(rng.normal(size=(KV, P, ps, hd)), jnp.float32)
-    page_tables = jnp.asarray(
-        rng.permutation(np.arange(1, P))[: B * pages_per_seq].reshape(
-            B, pages_per_seq
-        ),
-        jnp.int32,
-    )
-    if lens is None:
-        lens = rng.integers(1, pages_per_seq * ps, size=B)
-    seq_lens = jnp.asarray(lens, jnp.int32)
-    return q, k_pages, v_pages, page_tables, seq_lens
 
 
 @pytest.mark.parametrize(
@@ -806,315 +788,14 @@ def test_suffix_prefill_pallas_matches_jnp():
     )
 
 
-# ------------------------------- decode kernel: one program, a block of slots
-
-def _live_rows_match(got, expect, seq_lens, tol=2e-5):
-    """Live rows are held to the twin; a row of length 0 is held to
-    ZEROS (the twin averages garbage there, and the logits' integrity
-    guard reduces over every row)."""
-    got, lens = np.asarray(got), np.asarray(seq_lens)
-    np.testing.assert_allclose(
-        got[lens > 0], np.asarray(expect)[lens > 0], rtol=tol, atol=tol
-    )
-    assert not got[lens == 0].any(), "a row of length 0 must come out zero"
-
-
-def _scattered(k_pages, v_pages, page_tables, seq_lens, seed, layer=None):
-    """A new token a slot (its K and V at position length - 1) and the
-    pools with it written as a decode step's scatter writes it: a dead
-    slot's lands in trash page 0 (models/decoder.py decode_attn_inputs)."""
-    from vgate_tpu.models.decoder import decode_attn_inputs
-    from vgate_tpu.ops.kv_quant import kv_write_tokens
-
-    B, (KV, _, ps, hd) = seq_lens.shape[0], k_pages.shape[-4:]
-    rng = np.random.default_rng(seed)
-    k_new = jnp.asarray(rng.normal(size=(B, KV, hd)), k_pages.dtype)
-    v_new = jnp.asarray(rng.normal(size=(B, KV, hd)), v_pages.dtype)
-    _, page_ids, page_off = decode_attn_inputs(
-        jnp.maximum(seq_lens - 1, 0), page_tables, seq_lens > 0, ps
-    )
-    k_after = kv_write_tokens(k_pages, page_ids, page_off, k_new, layer=layer)
-    v_after = kv_write_tokens(v_pages, page_ids, page_off, v_new, layer=layer)
-    return k_new, v_new, k_after, v_after
-
-
-def _decode_both(q, k_pages, v_pages, page_tables, seq_lens, write=False,
-                 **kw):
-    """(kernel, twin).  With ``write`` the kernel is handed a new token a
-    slot and the pools WITHOUT it: the pools it returns must be the
-    scatter's bit for bit in every page but the trash page (a dead slot
-    writes nothing, the scatter dumps its token there), and its
-    attention exactly what it computes over the scattered pools."""
-    if write:
-        k_new, v_new, k_after, v_after = _scattered(
-            k_pages, v_pages, page_tables, seq_lens, seed=99,
-            layer=kw.get("layer"),
-        )
-        got, k_got, v_got = paged_decode_attention_pallas(
-            q, k_pages, v_pages, page_tables, seq_lens, k_new=k_new,
-            v_new=v_new, interpret=True, **kw
-        )
-        for pool, after, before in (
-            (k_got, k_after, k_pages), (v_got, v_after, v_pages),
-        ):
-            np.testing.assert_array_equal(
-                np.asarray(pool)[..., 1:, :, :],
-                np.asarray(after)[..., 1:, :, :],
-            )
-            np.testing.assert_array_equal(
-                np.asarray(pool)[..., 0, :, :],
-                np.asarray(before)[..., 0, :, :],
-            )
-        k_pages, v_pages = k_after, v_after
-        np.testing.assert_array_equal(
-            np.asarray(got),
-            np.asarray(paged_decode_attention_pallas(
-                q, k_pages, v_pages, page_tables, seq_lens, interpret=True,
-                **kw
-            )),
-        )
-    else:
-        got = paged_decode_attention_pallas(
-            q, k_pages, v_pages, page_tables, seq_lens, interpret=True, **kw
-        )
-    # the twin never sees a 0: it would divide by an empty sum
-    expect = paged_decode_attention(
-        q, k_pages, v_pages, page_tables, jnp.maximum(seq_lens, 1), **kw
-    )
-    return got, expect
-
-
-# the kernel's write holds every served decode step's cache: its cases
-# run in tier-1 (tests/conftest.py counts this file's others as slow)
-reads_and_writes = pytest.mark.parametrize(
-    "write",
-    [
-        pytest.param(False, id="reads"),
-        pytest.param(True, id="writes", marks=pytest.mark.fast),
-    ],
-)
-
-
-@reads_and_writes
-def test_decode_kernel_every_length_in_one_batch(write):
-    """Page and chunk edges (a chunk is 256 tokens at this geometry), an
-    empty row and a full context, side by side in one block; written,
-    the new token lies at a page's first row (1, 33, 257), at its last
-    (32, 256, 2048) and in a chunk's first page and last."""
-    lens = [0, 1, 31, 32, 33, 255, 256, 257, 2048]
-    case = make_case(
-        B=len(lens), H=4, KV=2, ps=32, pages_per_seq=64, lens=lens, seed=21
-    )
-    got, expect = _decode_both(*case, write=write)
-    _live_rows_match(got, expect, case[4])
-
-
-def _blocks_of_32(pattern, seed):
-    """(2, 8, 256) in float32 gives 32 slots a program: `pattern` maps a
-    slot to its length, every other slot is dead."""
-    from vgate_tpu.ops.pallas.paged_attention import _decode_sizes
-
-    B = 70  # three programs, the last one mostly outside the batch
-    assert _decode_sizes(
-        B, 2, 8, 256, 16, 8, jnp.float32, jnp.float32
-    ) == (8, 32, 2)
-    lens = [pattern.get(b, 0) for b in range(B)]
-    return make_case(
-        B=B, H=16, KV=2, hd=256, ps=16, pages_per_seq=8, lens=lens,
-        seed=seed,
-    )
-
-
-@pytest.mark.parametrize(
-    "pattern",
-    [
-        # a block that is all dead, then one with a single live slot
-        {40: 77},
-        # live slots separated by dead ones: the pipeline crosses them,
-        # and a block boundary (31 | 32) and the ragged last block (64+)
-        {0: 5, 3: 128, 4: 1, 9: 100, 31: 33, 32: 127, 63: 64, 65: 17, 69: 90},
-        # every slot live, lengths all over
-        {b: 1 + (37 * b) % 128 for b in range(70)},
-        # nothing live anywhere
-        {},
-    ],
-    ids=["dead-block-then-one-live", "live-among-dead", "all-live", "all-dead"],
-)
-@reads_and_writes
-def test_decode_kernel_block_patterns(pattern, write):
-    """Written: more live slots than staging pages in a program, none,
-    and fewer."""
-    case = _blocks_of_32(pattern, seed=22)
-    got, expect = _decode_both(*case, write=write)
-    _live_rows_match(got, expect, case[4])
-
-
-_CELL_LENS = [0, 200, 3, 0, 129, 64]
-# lengths that cross several chunks of EvaByte's 128 tokens and end inside
-# one, over 24 pages a slot
-_MHA_LENS = [0, 1, 127, 129, 300, 700]
-
-
-@pytest.mark.parametrize(
-    "KV, G, hd, lens, pages_per_seq, chunk_pages",
-    [
-        (2, 6, 128, _CELL_LENS, 8, 8), (4, 7, 128, _CELL_LENS, 8, 4),
-        (2, 8, 256, _CELL_LENS, 8, 4), (1, 7, 128, _CELL_LENS, 8, 8),
-        # float32 pools: the budget's share is 21 tokens, the floor 128
-        (32, 1, 128, _MHA_LENS, 24, 4),
-    ],
-    ids=["1.5B", "7B", "qwen3-next", "one-kv-head-tp-shard", "evabyte-mha"],
-)
-@reads_and_writes
-def test_decode_kernel_cell_geometries(KV, G, hd, lens, pages_per_seq,
-                                       chunk_pages, write):
-    """The cells' (KV, G, hd) and one KV head (a tp shard of the 7B):
-    all KV heads ride one iteration, and one staged page back to the
-    pool, whatever their number.  EvaByte's is one query row a KV head
-    under 32 of them, where a chunk is the floor of `_decode_sizes` (one
-    128-token tile a head) and not the budget's share."""
-    from vgate_tpu.ops.pallas.paged_attention import _decode_sizes
-
-    case = make_case(
-        B=len(lens), H=KV * G, KV=KV, hd=hd, ps=32,
-        pages_per_seq=pages_per_seq, lens=lens, seed=23,
-    )
-    assert _decode_sizes(
-        len(lens), KV, G, hd, 32, pages_per_seq, case[1].dtype,
-        case[0].dtype,
-    )[0] == chunk_pages
-    got, expect = _decode_both(*case, write=write)
-    _live_rows_match(got, expect, case[4])
-
-
-@reads_and_writes
-def test_decode_kernel_window_softcap_scale_across_blocks(write):
-    """Per-slot window starts differ inside one block and chunks below a
-    window are never fetched (the chunk that holds the new token always
-    is); softcap and the query scale ride along."""
-    case = _blocks_of_32(
-        {1: 40, 2: 128, 7: 96, 33: 127, 34: 5, 67: 70}, seed=24
-    )
-    for win in (16, 64, 100):
-        got, expect = _decode_both(
-            *case, write=write, window=jnp.asarray(win, jnp.int32),
-            softcap=30.0, scale=0.25,
-        )
-        _live_rows_match(got, expect, case[4])
-
-
-@reads_and_writes
-def test_decode_kernel_layer_indexed_ragged_batch(write):
-    """Layer-indexed pools under a B that is no multiple of the block;
-    written, the other layers stay as they were."""
-    q, k_pages, v_pages, page_tables, seq_lens = _blocks_of_32(
-        {0: 33, 30: 97, 45: 1, 69: 128}, seed=25
-    )
-    L = 3
-    rng = np.random.default_rng(26)
-    kL = jnp.asarray(rng.normal(size=(L,) + k_pages.shape), jnp.float32)
-    vL = jnp.asarray(rng.normal(size=(L,) + v_pages.shape), jnp.float32)
-    got, expect = _decode_both(
-        q, kL, vL, page_tables, seq_lens, write=write, layer=jnp.asarray(1)
-    )
-    _live_rows_match(got, expect, seq_lens)
-
-
-# slot -> length; a chunk (one item of a program's work list) is 256
-# tokens and a program serves 64 slots at this geometry
-_TRIP_CASES = {
-    # (s0c0 s0c1) (s0c2 s5c0) (s9c0 s9c1) (s20c0): seven items, the last
-    # a trip of its own; the other program has nothing to do
-    "odd-item-count": ({0: 700, 5: 100, 9: 300, 20: 50}, {}),
-    # one program with one item, one with none
-    "one-item-program": ({40: 77}, {}),
-    # live slots among dead ones in both programs, chunk edges (256, 257)
-    "dead-slots-between-live": (
-        {0: 5, 3: 256, 4: 1, 9: 257, 31: 33, 63: 64, 65: 17, 69: 90}, {}),
-    # (s2c0 s2c1) (s2c2 s3c0) (s3c1 s4c0): a slot ends in a trip's first
-    # item and another starts in its second, twice
-    "pair-spans-two-slots": ({2: 600, 3: 300, 4: 10}, {}),
-    # twelve one-chunk slots: every trip ends two slots (two staged
-    # pages, two stores), twelve writes over four staging pages
-    "both-items-last-chunks": ({b: 1 + 3 * b for b in range(12)}, {}),
-    # first chunks 2, 1, 0 and 2: the chunks below a window are no items
-    "window-first-chunk-not-0": (
-        {0: 700, 1: 513, 2: 40, 7: 768}, {"window": 100}),
-    "layer-pools": ({0: 700, 5: 100, 9: 300, 20: 50, 66: 257}, {"layer": 1}),
-    "float32-pools": ({1: 300, 2: 0, 3: 31, 64: 513}, {"dtype": jnp.float32}),
-}
-
-
-def _trip_case(case):
-    """(arguments, keyword arguments, slot -> length, new K rows) of a
-    decode-kernel call that writes, for a case of _TRIP_CASES."""
-    from vgate_tpu.ops.pallas.paged_attention import _decode_sizes
-
-    pattern, kw = _TRIP_CASES[case]
-    kw = dict(kw)
-    dtype, layer = kw.pop("dtype", jnp.bfloat16), kw.pop("layer", None)
-    B, KV, G, hd, ps, pages_per_seq = 70, 2, 4, 128, 32, 24
-    assert _decode_sizes(
-        B, KV, G, hd, ps, pages_per_seq, dtype, dtype
-    )[:2] == (8, 64)
-    q, k_pages, v_pages, page_tables, seq_lens = (
-        x.astype(dtype) if x.dtype == jnp.float32 else x
-        for x in make_case(
-            B=B, H=KV * G, KV=KV, hd=hd, ps=ps, pages_per_seq=pages_per_seq,
-            lens=[pattern.get(b, 0) for b in range(B)], seed=31,
-        )
-    )
-    if layer is not None:
-        k_pages, v_pages = (
-            jnp.stack([pool * 0.5, pool, pool * 2.0])
-            for pool in (k_pages, v_pages)
-        )
-        kw["layer"] = jnp.asarray(layer)
-    if "window" in kw:
-        kw["window"] = jnp.asarray(kw["window"], jnp.int32)
-    rng = np.random.default_rng(32)
-    for name in ("k_new", "v_new"):
-        kw[name] = jnp.asarray(rng.normal(size=(B, KV, hd)), dtype)
-    return (q, k_pages, v_pages, page_tables, seq_lens), kw, pattern
-
-
-@pytest.mark.fast  # tier-1: what a served decode step's trips hold
-@pytest.mark.parametrize("case", list(_TRIP_CASES))
-def test_decode_kernel_two_items_a_trip_give_one_items_bits(case):
-    """A loop trip that serves TWO items of the work list against the
-    kernel whose trip serves one (the program as it was): attention and
-    both written pools BIT for bit, in the served arithmetic (bf16 pages,
-    float32 accumulation, the softmax weights as two bf16 terms)."""
-    args, kw, pattern = _trip_case(case)
-    one, two = (
-        paged_decode_attention_pallas(*args, interpret=True, items=items, **kw)
-        for items in (1, 2)
-    )
-    for got, want in zip(two, one):
-        np.testing.assert_array_equal(
-            np.asarray(got, np.float32), np.asarray(want, np.float32)
-        )
-    # and the row is where a scatter would have put it
-    pool, page_tables = np.asarray(two[1], np.float32), args[3]
-    if "layer" in kw:
-        pool = pool[int(kw["layer"])]
-    ps = pool.shape[-2]
-    for b, length in pattern.items():
-        if length:
-            page = int(page_tables[b, (length - 1) // ps])
-            np.testing.assert_array_equal(
-                pool[:, page, (length - 1) % ps],
-                np.asarray(kw["k_new"][b], np.float32),
-            )
-
-
 _BLOCKING_WAITS = """
 import sys
 sys.path[:0] = {paths!r}
 import jax
 from jax.experimental.pallas import tpu as pltpu
-from test_pallas_kernels import _trip_case, paged_decode_attention_pallas
-args, kw, _ = _trip_case({case!r})
+from tests.pallas_cases import trip_case
+from vgate_tpu.ops.pallas.paged_attention import paged_decode_attention_pallas
+args, kw, _ = trip_case({case!r})
 jax.block_until_ready(paged_decode_attention_pallas(
     *args, interpret=pltpu.InterpretParams(), **{options!r}, **kw))
 """

@@ -218,7 +218,7 @@ def test_tp_wrapper_one_kv_head_a_shard_and_dead_rows():
     as decode_forward hands it over) comes out zero on every shard."""
     if jax.device_count() < 2:
         pytest.skip("needs devices")
-    from tests.test_pallas_kernels import _live_rows_match
+    from tests.pallas_cases import live_rows_match
     from vgate_tpu.ops.attention import paged_decode_attention
     from vgate_tpu.ops.pallas.paged_attention import (
         paged_decode_attention_pallas,
@@ -247,4 +247,4 @@ def test_tp_wrapper_one_kv_head_a_shard_and_dead_rows():
     got = tp_paged_decode_attention(
         kernel, mesh, q, k_pages, v_pages, pt, seq_lens
     )
-    _live_rows_match(got, expect, seq_lens)
+    live_rows_match(got, expect, seq_lens)
