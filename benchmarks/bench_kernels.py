@@ -8,7 +8,7 @@ blockwise twin).  Run on hardware:
     python benchmarks/bench_kernels.py
     python benchmarks/bench_kernels.py sample_edits   # that probe alone
     python benchmarks/bench_kernels.py greedy_head    # a decode step's tail
-    python benchmarks/bench_kernels.py decode_cells [CELL ...]
+    python benchmarks/bench_kernels.py decode_cells [CELL ...] [buffers=N ...]
     python benchmarks/bench_kernels.py expert_layer [CONFIG ...]
     python benchmarks/bench_kernels.py dsa_index dsa_select dsa_attend
     python benchmarks/bench_kernels.py dsa_attend dsa_attend_64k
@@ -144,6 +144,11 @@ DECODE_CELLS = {
         (2, 16, 128), 192, 2048, 188, (80, 720), 0),
     "mistral-small-4-119b-l4e32.long-prompt": (
         (1, 32, 384), 256, 8192, 256, (4200, 7900), 256),
+    # PR 56: every slot live at 1,025-1,536 prompt tokens and up to 319
+    # answered (perfbench/traffic/long-context.json): five to eight items
+    # a slot, all but the last a full chunk
+    "qwen2.5-1.5b.long-context": (
+        (2, 6, 128), 256, 2048, 256, (1030, 1850), 0),
 }
 with open(os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -176,15 +181,17 @@ def decode_cell_case(name, ps=32, layers=2, dead_len=0, seed=0):
     return q, pools, page_tables, jnp.asarray(lens, jnp.int32)
 
 
-def decode_cell_kernel(name, **kw):
+def decode_cell_kernel(name, module=None, **kw):
     """The cell's decode kernel as one layer's cache work, `(q, pools,
     page_tables, seq_lens, news, layer) -> (attention, pools)`: it
     writes the slots' new rows (`news`, one a pool) and attends to them.
-    `kw` goes to the kernel (`items`, `hollow`)."""
-    from vgate_tpu.ops.pallas.paged_attention import (
-        mla_decode_attention_pallas, paged_decode_attention_pallas,
-    )
+    `kw` goes to the kernel (`items`, `buffers`, `hollow`); `module` is
+    the `paged_attention` to take it from (the parent commit's)."""
+    from vgate_tpu.ops.pallas import paged_attention
 
+    module = module or paged_attention
+    mla_decode_attention_pallas = module.mla_decode_attention_pallas
+    paged_decode_attention_pallas = module.paged_decode_attention_pallas
     latent = DECODE_CELLS[name][-1]
 
     def plain(q, pools, pt, sl, news, layer):
@@ -216,71 +223,108 @@ def decode_cell_news(case, seed=7):
     ))
 
 
-def time_decode_layer(layer_fn, case, loop=28):
-    """Median seconds of ONE layer's cache work (`decode_cell_kernel`'s
-    signature): `loop` of them chained in one program (a decode step's
-    layers) with the pools on the carry, layer index alternating."""
+def time_decode_layers(layer_fns, case, loop=28, rounds=10):
+    """Median seconds of ONE layer's cache work for each of `layer_fns`
+    (name -> `decode_cell_kernel`'s signature): `loop` of them chained in
+    one program (a decode step's layers) with the pools on the carry,
+    layer index alternating.  The programs are timed in turn, round by
+    round, so that what drifts between rounds falls on all alike, and
+    they hand ONE copy of the pools on (each donates it)."""
     q, pools, page_tables, seq_lens = case
     # the runs donate their pools: the case keeps its own
     pools = tuple(jnp.copy(pool) for pool in pools)
     news = decode_cell_news(case)
 
-    @functools.partial(jax.jit, donate_argnums=(1,))
-    def run(q, pools, page_tables, seq_lens, news):
-        def body(carry, i):
-            out, pools = carry
-            out, pools = layer_fn(
-                q + 0 * out.astype(q.dtype), pools, page_tables, seq_lens,
-                news, i % pools[0].shape[0],
+    def program(layer_fn):
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def run(q, pools, page_tables, seq_lens, news):
+            def body(carry, i):
+                out, pools = carry
+                out, pools = layer_fn(
+                    q + 0 * out.astype(q.dtype), pools, page_tables,
+                    seq_lens, news, i % pools[0].shape[0],
+                )
+                return (out.astype(jnp.float32), pools), None
+
+            carry, _ = jax.lax.scan(
+                body, (jnp.zeros(q.shape, jnp.float32), pools),
+                jnp.arange(loop, dtype=jnp.int32),
             )
-            return (out.astype(jnp.float32), pools), None
+            return carry
 
-        carry, _ = jax.lax.scan(
-            body, (jnp.zeros(q.shape, jnp.float32), pools),
-            jnp.arange(loop, dtype=jnp.int32),
-        )
-        return carry
+        return run
 
-    times = []
-    for _ in range(12):
-        t0 = time.perf_counter()
-        _, pools = _sync(run(q, pools, page_tables, seq_lens, news))
-        times.append(time.perf_counter() - t0)
-    return float(np.median(times[2:])) / loop
+    programs = {form: program(fn) for form, fn in layer_fns.items()}
+    times = {form: [] for form in programs}
+    for _ in range(2 + rounds):  # the first two compile and warm
+        for form, run in programs.items():
+            t0 = time.perf_counter()
+            _, pools = _sync(run(q, pools, page_tables, seq_lens, news))
+            times[form].append(time.perf_counter() - t0)
+    return {
+        form: float(np.median(seconds[2:])) / loop
+        for form, seconds in times.items()
+    }
 
 
 def decode_trips(seq_lens, chunk_tokens, block_slots, items):
-    """(work-list items, loop trips, trips that hold `items` items) of one
-    launch: a function of the lengths alone.  A program's list is the
-    live chunks of its block of slots, a trip takes `items` of them, and
-    an odd last item is a trip of its own."""
-    chunks = cdiv(np.asarray(seq_lens, np.int64), chunk_tokens)
+    """(work-list items, loop trips, trips that hold `items` items,
+    `full_chunk_share`: the share of items whose chunk has no dead page)
+    of one launch: a function of the lengths alone.  A program's list is the live chunks of its block of
+    slots, a trip takes `items` of them, and an odd last item is a trip
+    of its own."""
+    lens = np.asarray(seq_lens, np.int64)
+    chunks = cdiv(lens, chunk_tokens)
     per_program = [
         int(chunks[i:i + block_slots].sum())
         for i in range(0, len(chunks), block_slots)
     ]
+    work = sum(per_program)
     return (
-        sum(per_program), sum(cdiv(n, items) for n in per_program),
+        work, sum(cdiv(n, items) for n in per_program),
         sum(n // items for n in per_program),
+        float((lens // chunk_tokens).sum()) / max(work, 1),
     )
 
 
-def bench_decode_cells(cells=None):
+def _parent_paged_attention(
+    path="_parent/vgate_tpu/ops/pallas/paged_attention.py",
+):
+    """ops/pallas/paged_attention.py of the parent commit, or None."""
+    return _parent_module(path, "parent_paged_attention")
+
+
+def bench_decode_cells(cells=None, forced=()):
     """A decode layer's cache work (the kernel that writes the token's
-    page itself) at the cells' shapes, with ONE and with TWO items of the
-    work list a loop trip: µs a launch, the share of the HBM roofline
-    over the LIVE tokens' bytes read (what
+    page itself) at the cells' shapes: µs a launch, the share of the HBM
+    roofline over the LIVE tokens' bytes read (what
     `kernel.decode_attn_roofline_live` counts in a traced run), the
-    launch's items and trips with the share of trips that hold two, and
-    the hollow kernel's time (the trips' bookkeeping alone: no copy, no
-    product).  `rule_items` is what `_decode_sizes` picks for the shape.
-    Two items must give one item's bits, and on plain pools one item's
-    must be the scatter's (what a step did until PR 30)."""
+    launch's items and trips with the share of trips that hold two and
+    the share of items that are full chunks, and the hollow kernel's time
+    (the trips' bookkeeping alone: no copy, no product).  `rule_items`
+    and `rule_buffers` are what `_decode_sizes` picks for the shape.
+
+    The forms stand side by side, each with its hollow twin, timed in
+    turn round by round: `rule` (the kernel as served), `bN` for each of
+    `forced` (the rule's items at N chunk buffers: N / items trips, one
+    computed and the others in flight), `items_N` (the other items count
+    at its rule's buffers) and `parent` (the parent commit's kernel where
+    `_parent/` holds a `git archive` of it, at its own rule's buffers:
+    `"buffers": 0`).  Every form must give the
+    first form's bits (the parent's where it is there), and on plain
+    pools one item's must be the scatter's (what a step did until PR
+    30)."""
     from vgate_tpu.models.decoder import decode_attn_inputs
     from vgate_tpu.ops.kv_quant import kv_write_tokens
     from vgate_tpu.ops.pallas.paged_attention import (
         _decode_sizes, paged_decode_attention_pallas,
     )
+
+    parent = _parent_paged_attention()
+
+    def parent_kernel(name, buffers, **kw):
+        """The parent commit's kernel: its buffers are its own rule's."""
+        return decode_cell_kernel(name, module=parent, **kw)
 
     def scatter_then_kernel(q, pools, pt, sl, news, layer):
         # a dead slot's token goes to trash page 0, as in a decode step
@@ -310,19 +354,46 @@ def bench_decode_cells(cells=None):
         (KV, G, hd), B, ctx, live, _, latent = DECODE_CELLS[name]
         case = decode_cell_case(name)
         news = decode_cell_news(case, seed=11)
-        one, two = (decode_cell_kernel(name, items=n) for n in (1, 2))
-        if not all(map(bool, same_bits(two, one, case, news))):
-            raise SystemExit(f"{name}: two items a trip differ from one")
+        lens, ps = np.asarray(case[3]), case[1][0].shape[-2]
+        sizes = dict(
+            B=B, KV=KV, G=G, hd=hd, page_size=ps, pages_per_seq=ctx // ps,
+            kv_dtype=jnp.bfloat16, q_dtype=jnp.bfloat16, pools=len(case[1]),
+        )
+        CP, BS, rule, rule_buffers = _decode_sizes(**sizes)
+        other = 3 - rule
+        work, _, _, full_chunks = decode_trips(lens, CP * ps, BS, 1)
+        # form -> (items, chunk buffers, what makes the kernel); a form
+        # that another already is (a forced count the rule gives) goes
+        forms = {}
+        if parent is not None:
+            forms["parent"] = (rule, 0, parent_kernel)
+        forms["rule"] = (rule, rule_buffers, decode_cell_kernel)
+        for n in forced:
+            forms[f"b{n}"] = (rule, n, decode_cell_kernel)
+        forms[f"items_{other}"] = (
+            other, _decode_sizes(**sizes, items=other)[3],
+            decode_cell_kernel,
+        )
+        kernels = {}
+        for form, key in forms.items():
+            if key not in kernels.values():
+                kernels[form] = key
+        first = next(iter(kernels))
+        made = {
+            form: make(name, items=n, buffers=buffers)
+            for form, (n, buffers, make) in kernels.items()
+        }
+        for form in list(made)[1:]:
+            if not all(map(bool, same_bits(
+                made[form], made[first], case, news
+            ))):
+                raise SystemExit(f"{name}: {form} differs from {first}")
+        one = made["rule"] if rule == 1 else made[f"items_{other}"]
         if not latent and not all(
             map(bool, same_bits(one, scatter_then_kernel, case, news))
         ):
             raise SystemExit(f"{name}: the kernel's write differs from "
                              "the scatter's")
-        lens, ps = np.asarray(case[3]), case[1][0].shape[-2]
-        CP, BS, rule = _decode_sizes(
-            B, KV, G, hd, ps, ctx // ps, jnp.bfloat16, jnp.bfloat16,
-            pools=len(case[1]),
-        )
         live_bytes = int(lens.sum()) * len(case[1]) * KV * hd * 2
         line = {
             "kernel": "mla_decode_attention" if latent
@@ -331,26 +402,33 @@ def bench_decode_cells(cells=None):
             "shape": f"B{B} KV{KV} G{G} hd{hd} ps{ps} ctx{ctx}, {live} live",
             "live_tokens": int(lens.sum()),
             "chunk_tokens": CP * ps, "block_slots": BS, "rule_items": rule,
-            "work_items": decode_trips(lens, CP * ps, BS, 1)[0],
+            "rule_buffers": rule_buffers, "work_items": work,
+            "full_chunk_share": round(full_chunks, 3),
+            "same_bits_as": first,
         }
-        for n, kernel in ((1, one), (2, two)):
-            _, trips, full = decode_trips(lens, CP * ps, BS, n)
-            seconds = time_decode_layer(kernel, case)
-            hollow = time_decode_layer(
-                decode_cell_kernel(name, items=n, hollow=True), case
-            )
-            line.update({
-                f"items_{n}_trips": trips,
-                f"items_{n}_full_trip_pct": round(100 * full / trips, 1),
-                f"items_{n}_us": round(seconds * 1e6, 1),
-                f"items_{n}_hbm_roofline_pct": round(
-                    100 * live_bytes / HBM_BYTES_PER_S / seconds, 1
+        seconds = time_decode_layers({
+            **made,
+            **{
+                (form, "hollow"): make(
+                    name, items=n, buffers=buffers, hollow=True
+                )
+                for form, (n, buffers, make) in kernels.items()
+            },
+        }, case)
+        for form, (n, buffers, _) in kernels.items():
+            _, trips, full, _ = decode_trips(lens, CP * ps, BS, n)
+            took, hollow = seconds[form], seconds[form, "hollow"]
+            line[form] = {
+                "items": n, "buffers": buffers, "trips": trips,
+                "full_trip_pct": round(100 * full / trips, 1),
+                "us": round(took * 1e6, 1),
+                "hbm_roofline_pct": round(
+                    100 * live_bytes / HBM_BYTES_PER_S / took, 1
                 ),
-                f"items_{n}_hollow_us": round(hollow * 1e6, 1),
-            })
-        line["items_2_gain_pct"] = round(
-            100 * (1 - line["items_2_us"] / line["items_1_us"]), 1
-        )
+                "hollow_us": round(hollow * 1e6, 1),
+                "us_an_item": round(took * 1e6 / work, 4),
+                "hollow_us_an_item": round(hollow * 1e6 / work, 4),
+            }
         yield line
 
 
@@ -1644,7 +1722,7 @@ def bench_eva_decode(forced=(), B=20, H=32, hd=128, ps=32, window=2048,
     ruled = None
     for chunk_pages, items in ((0, 0), *forced):
         kw = {"chunk_pages": chunk_pages, "items": items}
-        CP, BS, I = _decode_sizes(
+        CP, BS, I, _ = _decode_sizes(
             B, H, 1, hd, ps, view.shape[1], pool.dtype, q.dtype, **kw)
         out = np.asarray(attend(q, pool, pool, **kw)[0], np.float32)
         ruled = out if ruled is None else ruled
@@ -1809,7 +1887,11 @@ def main() -> None:
                 print(json.dumps(line), flush=True)
         return
     if sys.argv[1:2] == ["decode_cells"]:
-        for line in bench_decode_cells(sys.argv[2:]):
+        # decode_cells [CELL ...] [buffers=N ...]: forced chunk buffers
+        args = sys.argv[2:]
+        forced = tuple(int(a[8:]) for a in args if a.startswith("buffers="))
+        cells = [a for a in args if not a.startswith("buffers=")]
+        for line in bench_decode_cells(cells, forced=forced):
             print(json.dumps(line), flush=True)
         return
     print(json.dumps(bench_paged_decode()))

@@ -1,8 +1,9 @@
 """What the files of Pallas kernel tests share (``test_pallas_kernels``,
-``test_pallas_decode_kernel``, ``test_pallas_decode_trips``; the int8 and
-tp files read ``live_rows_match``): a paged decode case, the rule for
-its rows, and the decode kernel's work-list cases.  The three files are
-apart by the kernel they trace, none past 300 s alone
+``test_pallas_decode_kernel``, ``test_pallas_decode_trips``,
+``test_pallas_decode_pipeline``; the int8 and tp files read
+``live_rows_match``): a paged decode case, the rule for its rows, and
+the decode kernel's work-list cases.  The four files are apart by the
+kernel they trace, none past 300 s alone
 (``tests/conftest.py LONGEST_FIRST``)."""
 
 import jax.numpy as jnp

@@ -111,7 +111,7 @@ def _blocks_of_32(pattern, seed):
     B = 70  # three programs, the last one mostly outside the batch
     assert _decode_sizes(
         B, 2, 8, 256, 16, 8, jnp.float32, jnp.float32
-    ) == (8, 32, 2)
+    ) == (8, 32, 2, 6)
     lens = [pattern.get(b, 0) for b in range(B)]
     return make_case(
         B=B, H=16, KV=2, hd=256, ps=16, pages_per_seq=8, lens=lens,

@@ -801,6 +801,25 @@ jax.block_until_ready(paged_decode_attention_pallas(
 """
 
 
+def _under_the_tpu_interpreter(script, **fields):
+    """Run `script` (a template that takes `paths` and `fields`) in a
+    process of its own, which is killed if it does not come back."""
+    import os
+    import subprocess
+    import sys
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    code = script.format(paths=[os.path.dirname(here), here], **fields)
+    try:
+        done = subprocess.run(
+            [sys.executable, "-c", code], timeout=300, capture_output=True,
+            text=True, env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        )
+    except subprocess.TimeoutExpired:
+        pytest.fail("the kernel waits for a copy it never started")
+    assert done.returncode == 0, done.stderr[-2000:]
+
+
 @pytest.mark.fast
 @pytest.mark.parametrize(
     "case, options",
@@ -810,9 +829,17 @@ jax.block_until_ready(paged_decode_attention_pallas(
         ("layer-pools", {"items": 1}),
         ("odd-item-count", {"items": 2, "hollow": True}),
         ("both-items-last-chunks", {"items": 1, "hollow": True}),
+        # the chunk buffers forced: one, two and three trips' chunks in
+        # flight behind a trip of two, one chunk behind a trip of one
+        ("window-first-chunk-not-0", {"items": 2, "buffers": 6}),
+        ("pair-spans-two-slots", {"items": 2, "buffers": 4}),
+        ("dead-slots-between-live", {"items": 2, "buffers": 8}),
+        ("pair-spans-two-slots", {"items": 1, "buffers": 2}),
     ],
     ids=["two-items", "two-items-both-last", "one-item", "hollow-two-items",
-         "hollow-one-item"],
+         "hollow-one-item", "two-trips-in-flight",
+         "one-trip-in-flight", "three-trips-in-flight",
+         "one-item-one-in-flight"],
 )
 def test_decode_kernel_waits_for_no_more_than_it_started(case, options):
     """The plain interpreter does not block on a DMA semaphore, the chip
@@ -820,23 +847,46 @@ def test_decode_kernel_waits_for_no_more_than_it_started(case, options):
     kernel's first version waited for staging pages it never sent, and
     cost a chip call).  The TPU interpreter blocks as the chip does, so
     the kernel runs under it in a process of its own, which is killed if
-    it does not come back."""
-    import os
-    import subprocess
-    import sys
+    it does not come back.  The cases that name no `buffers` run at the
+    depth `_decode_sizes` serves (two trips' chunks in flight)."""
+    _under_the_tpu_interpreter(_BLOCKING_WAITS, case=case, options=options)
 
-    here = os.path.dirname(os.path.abspath(__file__))
-    code = _BLOCKING_WAITS.format(
-        paths=[os.path.dirname(here), here], case=case, options=options
-    )
-    try:
-        done = subprocess.run(
-            [sys.executable, "-c", code], timeout=300, capture_output=True,
-            text=True, env={**os.environ, "JAX_PLATFORMS": "cpu"},
-        )
-    except subprocess.TimeoutExpired:
-        pytest.fail("the kernel waits for a copy it never started")
-    assert done.returncode == 0, done.stderr[-2000:]
+
+_FIVE_PAGE_CHUNKS = """
+import sys
+sys.path[:0] = {paths!r}
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental.pallas import tpu as pltpu
+from tests.pallas_cases import make_case
+from vgate_tpu.ops.pallas.paged_attention import paged_decode_attention_pallas
+lens = [80, 48, 0, 33, 80, 80, 7]
+case = tuple(
+    x.astype(jnp.bfloat16) if x.dtype == jnp.float32 else x
+    for x in make_case(B=len(lens), H=4, KV=2, hd=128, ps=16,
+                       pages_per_seq=5, lens=lens, seed=5)
+)
+got = paged_decode_attention_pallas(
+    *case, interpret=pltpu.InterpretParams(), **{options!r})
+want = paged_decode_attention_pallas(*case, interpret=True, items=1)
+np.testing.assert_array_equal(
+    np.asarray(got, np.float32), np.asarray(want, np.float32))
+"""
+
+
+@pytest.mark.fast
+@pytest.mark.parametrize(
+    "options", [{"items": 2}, {"items": 2, "buffers": 4}, {"items": 1}],
+    ids=["two-items", "two-items-one-trip-in-flight", "one-item"],
+)
+def test_decode_kernel_waits_for_every_page_of_a_five_page_chunk(options):
+    """A ring of five pages a slot is a chunk of five: a trip of two
+    such chunks waits for its 8 live pages as ONE whole buffer's bytes
+    and three pages more, not as `8 // 5` buffers (which left three
+    pages unwaited for: NaN under the TPU interpreter, whose copies land
+    when they are waited for, and a race on the chip; no cell serves
+    such a shape: a two-KV-head shard over rings)."""
+    _under_the_tpu_interpreter(_FIVE_PAGE_CHUNKS, options=options)
 
 
 def test_decode_kernel_bf16_pages_keep_float32_softmax_weights():

@@ -126,23 +126,25 @@ def test_decode_kernel_compiles_at_the_cells_shapes_for_v5e(v5e, geom):
 # what serves a decode step in each of the benchmark's configurations,
 # (slots, KV heads, query heads a KV head, head size, pages a slot, pools),
 # and what `_decode_sizes` reads off it: (pages a chunk, slots a program,
-# work-list items a loop trip)
+# work-list items a loop trip, chunk buffers: the trip computed and the
+# trips in flight behind it)
 SERVED_DECODE_SHAPES = {
-    "qwen2.5-1.5b": ((256, 2, 6, 128, 64, 2), (8, 64, 2)),
-    "qwen2.5-7b-l14": ((256, 4, 7, 128, 64, 2), (8, 32, 1)),
-    "qwen3-next-80b-a3b-l8e128": ((256, 2, 8, 256, 64, 2), (8, 32, 2)),
+    "qwen2.5-1.5b": ((256, 2, 6, 128, 64, 2), (8, 64, 2, 6)),
+    "qwen2.5-7b-l14": ((256, 4, 7, 128, 64, 2), (8, 32, 1, 3)),
+    "qwen3-next-80b-a3b-l8e128": ((256, 2, 8, 256, 64, 2), (8, 32, 2, 6)),
     "nemotron-3-super-120b-a12b-l11e128": (
-        (192, 2, 16, 128, 64, 2), (8, 64, 2)),
-    # the latent pool: one "head" of 384-lane rows under 32 query heads
-    "mistral-small-4-119b-l4e32": ((256, 1, 32, 384, 256, 1), (8, 21, 2)),
+        (192, 2, 16, 128, 64, 2), (8, 64, 2, 6)),
+    # the latent pool: one "head" of 384-lane rows under 32 query heads,
+    # ONE trip's chunks in flight behind a trip of two (PR 56)
+    "mistral-small-4-119b-l4e32": ((256, 1, 32, 384, 256, 1), (8, 21, 2, 4)),
     # the full layers (the rings of five pages a slot get the same chunk)
-    "k-exaone-236b-a23b-l5e16": ((192, 8, 8, 128, 256, 2), (4, 16, 1)),
+    "k-exaone-236b-a23b-l5e16": ((192, 8, 8, 128, 256, 2), (4, 16, 1, 3)),
     # MHA, one query row a KV head: 64 window pages behind 32 of summaries.
     # The 4 MiB budget alone gave ONE page a chunk here (PR 45)
-    "evabyte-6.5b-l8": ((20, 32, 1, 128, 96, 2), (4, 4, 1)),
+    "evabyte-6.5b-l8": ((20, 32, 1, 128, 96, 2), (4, 4, 1, 3)),
     # 8 KV heads of 64 as 4 pair rows of 128 lanes, 2 x 4 query heads a
     # row (ops/head_pack.py): the 7B's item of four rows, one a trip
-    "lfm2-24b-a2b-e8": ((256, 4, 8, 128, 64, 2), (8, 32, 1)),
+    "lfm2-24b-a2b-e8": ((256, 4, 8, 128, 64, 2), (8, 32, 1, 3)),
 }
 
 

@@ -171,11 +171,13 @@ def _scale_row(buf, slot):
 # 2.1 MiB at the cells' shapes (32 KV heads): the new tokens' [BS, KV, hd]
 # blocks and DECODE_WRITE_BUFFERS staged pages.
 DECODE_VMEM_BUDGET = 4 << 20
-# K/V chunk buffers of a loop trip that serves ONE item of the work list:
-# one computed, two in flight.  Two keep the HBM busy only while a trip's
-# arithmetic outlasts its pages' transfer.  A trip of ITEMS items computes
-# ITEMS buffers and keeps as many chunks in flight: decode_buffers.
-DECODE_BUFFERS = 3
+# Loop trips whose K/V chunk buffers a launch holds: the trip computed and
+# two in flight behind it.  Two in flight keep the HBM busy only while a
+# trip's arithmetic outlasts its pages' transfer.  A trip of ITEMS items
+# computes ITEMS buffers, so the launch holds DECODE_PHASES x ITEMS of
+# them: decode_buffers, which also says where a two-item trip keeps ONE
+# trip in flight and not two.
+DECODE_PHASES = 3
 # A chunk (one item of the work list) holds at most this many tokens: two
 # 128-token MXU weight tiles a head.  Wider chunks mostly add tail work at
 # the lengths served (PERF.md section 6, PR 28: 512 tokens cost the 1.5B
@@ -199,17 +201,32 @@ DECODE_WRITE_BUFFERS = 4
 DECODE_PAIR_KV_HEADS = 2
 
 
-def decode_buffers(items: int) -> int:
-    """Chunk buffers of a kernel whose trip serves `items` items."""
-    return DECODE_BUFFERS - 1 + items
+def decode_buffers(items: int, pools: int = 2) -> int:
+    """Chunk buffers of a kernel whose trip serves `items` items, a whole
+    number of trips' worth: the trip computed and the trips in flight
+    behind it.  A trip of one item has two behind it.  A trip of two has
+    two where K and V are pools of their own: an item is little there,
+    and with ONE trip in flight (PR 37's four buffers) the scalar core's
+    issue of the next trip's 32 descriptors stood between a wait and the
+    bytes it waited for; two took 5.8 % off a launch at the 1.5B's
+    `decode-heavy` shape, 11.0 % at `long-context`'s, 5.2 % at
+    nemotron's, 2.1 % at qwen3-next's and 1.8 % at `chat`'s.  Over the
+    ONE latent pool it has one: an item there is 32 heads' products over
+    384 lanes, a trip's arithmetic outlasts its pages' transfer, and a
+    second trip in flight cost 1.2 % (in PR 37's probe and again in PR
+    56's; three cost 3.5 %: PERF.md section 6, PR 56)."""
+    behind = 1 if items > 1 and pools == 1 else DECODE_PHASES - 1
+    return (1 + behind) * items
 
 
 def _decode_sizes(B, KV, G, hd, page_size, pages_per_seq, kv_dtype, q_dtype,
-                  pools: int = 2, items: int = 0, chunk_pages: int = 0):
-    """(pages a chunk, slots a program, items a loop trip) for one
-    geometry.  `items` = 0 asks the rule; the probe and the tests force 1
-    or 2.  `chunk_pages` = 0 asks the rule; the probe forces a power of
-    two."""
+                  pools: int = 2, items: int = 0, chunk_pages: int = 0,
+                  buffers: int = 0):
+    """(pages a chunk, slots a program, items a loop trip, chunk buffers)
+    for one geometry.  `items` = 0 asks the rule; the probe and the tests
+    force 1 or 2.  `chunk_pages` = 0 asks the rule; the probe forces a
+    power of two.  `buffers` = 0 asks decode_buffers; the probe and the
+    tests force a multiple of `items`, at least twice it."""
     kv_bytes = jnp.dtype(kv_dtype).itemsize
     q_bytes = jnp.dtype(q_dtype).itemsize
     # An item's work is a chain of products and one softmax update a KV
@@ -218,13 +235,18 @@ def _decode_sizes(B, KV, G, hd, page_size, pages_per_seq, kv_dtype, q_dtype,
     # large part of it, and a trip serves TWO items for it.
     if not items:
         items = 2 if KV <= DECODE_PAIR_KV_HEADS else 1
+    buffers = buffers or decode_buffers(items, pools)
+    if buffers % items or buffers < 2 * items:
+        raise ValueError(
+            f"{buffers} chunk buffers are no two or more trips of {items}"
+        )
     # K and V (or the one latent pool's rows) of every KV head in every
-    # buffer of a one-item trip (a second item's buffer rides beside the
+    # buffer of a one-item trip (a second item's buffers ride beside the
     # budget: a chunk is the same whatever a trip holds), but no fewer
     # than a weight tile's, whatever the heads; a power of two, so the
     # kernel's page arithmetic is shifts
     if not chunk_pages:
-        token_bytes = DECODE_BUFFERS * pools * KV * hd * kv_bytes
+        token_bytes = DECODE_PHASES * pools * KV * hd * kv_bytes
         chunk_tokens = min(
             max(DECODE_VMEM_BUDGET // 2 // token_bytes, DECODE_TILE_TOKENS),
             DECODE_CHUNK_TOKENS,
@@ -237,7 +259,8 @@ def _decode_sizes(B, KV, G, hd, page_size, pages_per_seq, kv_dtype, q_dtype,
     slot_bytes = 2 * 2 * KV * g_rows * hd * q_bytes
     block_slots = DECODE_VMEM_BUDGET // 2 // slot_bytes
     return (
-        min(chunk_pages, pages_per_seq), max(1, min(block_slots, B)), items
+        min(chunk_pages, pages_per_seq), max(1, min(block_slots, B)), items,
+        buffers,
     )
 
 
@@ -265,8 +288,9 @@ def _decode_kernel(
     # k/v_new_ref [BS, KV, hd] VMEM blocks, the slots' new token.
     # outputs: out_ref [BS, KV, G, hd]; `write` adds k/v_out_ref, the
     # pools again (aliased onto the inputs).
-    # scratch: k_buf/v_buf [decode_buffers(items), KV, CP*ps, hd] VMEM (+
-    # sk/sv_buf [decode_buffers(items), KV, CP*ps] when quant; + wk/wv_buf
+    # scratch: k_buf/v_buf [buffers, KV, CP*ps, hd] VMEM, `buffers` as
+    # _decode_sizes gives them (+ sk/sv_buf [buffers, KV, CP*ps] when
+    # quant; + wk/wv_buf
     # [DECODE_WRITE_BUFFERS, KV, ps, hd] when write), acc [KV, G, hd] f32,
     # m/l [KV, G, 128] f32 running max/denom (col-broadcast), slots_ref
     # [6, BS + 1] int32 SMEM (the block's slots, see below; a seventh row
@@ -332,7 +356,15 @@ def _decode_kernel(
     BELONGS TO THAT ONE SEQUENCE (the radix cache shares whole pages
     only and copies a partial one before anyone appends to it), so no
     other slot reads or writes it during the call.  A slot of length 0
-    writes nothing.
+    writes nothing.  However many trips' chunks stand in flight
+    (_decode_sizes gives the buffers: two trips' behind the one
+    computed, or one), the chunks further ahead belong to LATER items of
+    the list, and the page a slot writes is its LAST chunk's: nothing
+    after it on the list is that slot's, and no other slot's chunk holds
+    the page, so no copy in flight reads a page that a write behind it
+    changes.  A staged page is waited for when its staging page comes
+    round again (and all of them before the program ends), whatever the
+    depth.
 
     With `latent` > 0 (multi-head latent attention, absorbed form) there
     is ONE pool and no V: a row of it is the key of every query head,
@@ -451,7 +483,12 @@ def _decode_kernel(
         per page one descriptor for K and one for V, all KV heads in
         each (and one each for an int8 pool's scale rows), on the
         semaphore of the trip the buffer belongs to.  An item past the
-        work list's end has no live page and issues nothing."""
+        work list's end has no live page and issues nothing.  Every page
+        stands under its own test, a full chunk's too: all of a full
+        chunk's descriptors in ONE straight-line block under one test,
+        the page-by-page form as the other arm, read 2.3 to 3.1 % SLOWER
+        a launch at three shapes in four forms (PERF.md section 6, PR
+        56)."""
         b, page0, live = pages_of(item)
         for i in range(CP):  # static unroll
             rows = pl.ds(i * page_size, page_size)
@@ -491,7 +528,9 @@ def _decode_kernel(
         """Wait for what start_chunk issued for a trip's items (buffers
         `buf0` on, semaphore `phase`), all at once: the semaphore counts
         bytes, so the items' live pages together are waited for in
-        power-of-two runs."""
+        power-of-two runs.  (One test for a trip of full chunks before
+        the runs' tests moved no launch by more than 0.5 %, either way:
+        PERF.md section 6, PR 56.)"""
         live = functools.reduce(
             operator.add, (pages_of(item)[2] for item in trip_items)
         )
@@ -499,15 +538,21 @@ def _decode_kernel(
         def wait_pages(n):
             if hollow:
                 return tick()
+            # a run longer than a chunk is whole buffers' bytes, and the
+            # pages it holds past them (none where a chunk's pages are a
+            # power of two; a ring of five pages is a chunk of five)
+            whole, rest = divmod(n, CP) if n > CP else (0, n)
             for buffer in [buffer for _, buffer in pools] + (
                 [sk_buf, sv_buf] if quant else []
             ):
-                # a run longer than a chunk is whole buffers' bytes
-                dst = (
-                    buffer.at[pl.ds(buf0, n // CP)] if n > CP
-                    else buffer.at[buf0, :, pl.ds(0, n * page_size)]
-                )
-                pltpu.make_async_copy(dst, dst, sems.at[phase]).wait()
+                dsts = [buffer.at[pl.ds(buf0, whole)]] if whole else []
+                if rest:
+                    dsts.append(buffer.at[
+                        buf0 + whole if whole else buf0, :,
+                        pl.ds(0, rest * page_size),
+                    ])
+                for dst in dsts:
+                    pltpu.make_async_copy(dst, dst, sems.at[phase]).wait()
 
         for bit in range((len(trip_items) * CP).bit_length()):
             pl.when((live & (1 << bit)) != 0)(
@@ -731,7 +776,7 @@ def _decode_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=("interpret", "softcap", "scale", "items", "chunk_pages",
-                     "hollow", "name"),
+                     "buffers", "hollow", "name"),
 )
 def paged_decode_attention_pallas(
     q: jnp.ndarray,  # [B, H, hd]
@@ -748,6 +793,7 @@ def paged_decode_attention_pallas(
     scale=None,  # static query scale; default hd**-0.5
     items: int = 0,  # work-list items a loop trip serves; 0: _decode_sizes
     chunk_pages: int = 0,  # pages an item holds; 0: _decode_sizes
+    buffers: int = 0,  # chunk buffers, trips of `items`; 0: _decode_sizes
     hollow: bool = False,  # the probe's: _decode_kernel
     name=None,  # the launch's name in a device trace
 ):
@@ -785,11 +831,12 @@ def paged_decode_attention_pallas(
     KV, P, ps, _ = k_data.shape[1:] if has_layer else k_data.shape
     G = H // KV
     # an int8 pool's path is as it was: one item a trip
-    CP, BS, items = _decode_sizes(
+    CP, BS, items, nbuf = _decode_sizes(
         B, KV, G, hd, ps, page_tables.shape[1], k_data.dtype, q.dtype,
         items=1 if quant else items, chunk_pages=chunk_pages,
+        buffers=buffers,
     )
-    chunk_tokens, nbuf = CP * ps, decode_buffers(items)
+    chunk_tokens = CP * ps
 
     if window is None:
         window_arr = jnp.zeros((1,), jnp.int32)
@@ -913,8 +960,8 @@ def swa_decode_attention_pallas(q, ring_k, ring_v, ring_tables, seq_lens,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("interpret", "scale", "v_width", "items", "hollow",
-                     "name"),
+    static_argnames=("interpret", "scale", "v_width", "items", "buffers",
+                     "hollow", "name"),
 )
 def mla_decode_attention_pallas(
     q: jnp.ndarray,  # [B, H, W] absorbed queries, W the pool's row width
@@ -928,6 +975,7 @@ def mla_decode_attention_pallas(
     scale: float,
     interpret: bool = False,
     items: int = 0,  # work-list items a loop trip serves; 0: _decode_sizes
+    buffers: int = 0,  # chunk buffers, trips of `items`; 0: _decode_sizes
     hollow: bool = False,  # the probe's: _decode_kernel
     name: str = "mla_decode_attention_pallas",  # in a device trace
 ):
@@ -945,11 +993,11 @@ def mla_decode_attention_pallas(
     B, H, W = q.shape
     _, KV, P, ps, _ = pages.shape
     write = new is not None
-    CP, BS, items = _decode_sizes(
+    CP, BS, items, nbuf = _decode_sizes(
         B, KV, H, W, ps, page_tables.shape[1], pages.dtype, q.dtype, pools=1,
-        items=items,
+        items=items, buffers=buffers,
     )
-    chunk_tokens, nbuf = CP * ps, decode_buffers(items)
+    chunk_tokens = CP * ps
     kernel = functools.partial(
         _decode_kernel, page_size=ps, chunk_pages=CP, items=items, batch=B,
         softcap=0.0, scale=float(scale), has_layer=True, quant=False,
