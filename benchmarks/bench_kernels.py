@@ -154,7 +154,9 @@ with open(os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     "perfbench", "peaks.json",
 )) as _fh:
-    HBM_BYTES_PER_S = json.load(_fh)["TPU v5 lite"]["hbm_bytes_per_s"]
+    _peaks = json.load(_fh)["TPU v5 lite"]
+HBM_BYTES_PER_S, BF16_FLOPS_PER_S = (
+    _peaks["hbm_bytes_per_s"], _peaks["bf16_flops"])
 
 
 def decode_cell_case(name, ps=32, layers=2, dead_len=0, seed=0):
@@ -806,16 +808,125 @@ def bench_swa_prefill(S=8192, H=64, KV=8, hd=128, window=128,
     yield {"kernel": "flash_prefill_attention (window as a mask)",
            "shape": f"S{S} H{H} KV{KV} hd{hd} w{window}", "blocks": [1024] * 2,
            "us": round(_median_time(_looped(masked), q, k, v, lens) * 1e6, 1)}
+    from vgate_tpu.ops.pallas.flash_prefill import swa_blocks
+
     for bq, bk in blocks:
         band = lambda q, k, v, lens, bq=bq, bk=bk: (
             swa_prefill_attention_pallas(q, k, v, lens, window,
                                          block_q=bq, block_k=bk))
         got = np.asarray(jax.jit(band)(q, k, v, lens), np.float32)
         yield {"kernel": "swa_prefill_attention (band)",
-               "blocks": [bq, bk],
+               "blocks": [bq, bk], "rule": (bq, bk) == swa_blocks(window),
                "max_abs_diff": float(np.abs(got - want)[0, :S - 37].max()),
                "us": round(
                    _median_time(_looped(band), q, k, v, lens) * 1e6, 1)}
+
+
+# a window layer's prompt launch at the two cells that have one: the
+# arguments of ``bench_swa_prefill`` (K-EXAONE's are its defaults)
+SWA_PREFILL_CASES = {
+    "k-exaone-236b-a23b-l5e16": {},
+    "mellum2-12b-a2.5b-l8": dict(
+        S=16384, H=32, KV=4, window=1024,
+        blocks=((256, 128), (256, 256), (512, 256), (512, 512),
+                (1024, 256), (1024, 512), (1024, 1024), (2048, 512))),
+}
+
+
+def bench_mellum_decode(B=80, H=32, KV=4, hd=128, ps=32, window=1024,
+                        lens=(9700, 16000)):
+    """A decode step's two attention launches at the Mellum2 cut's shape
+    (80 slots, 32 query heads on 4 KV heads of 128, contexts uniform
+    over the long-agent traffic's live range): a WINDOW layer's over the
+    slots' rings of 33 pages (``swa_decode_attention_pallas``: five
+    work-list items a slot) and a FULL layer's over the pool
+    (``paged_decode_attention_pallas``: 38-63 items a slot), each us a
+    launch and its share of the HBM roofline over the rows it has to
+    read (min(context, window) and context)."""
+    from vgate_tpu.models import hybrid
+    from vgate_tpu.ops.pallas.paged_attention import (
+        _decode_sizes, paged_decode_attention_pallas,
+        swa_decode_attention_pallas,
+    )
+
+    R = -(-window // ps) + 1
+    rng = np.random.default_rng(57)
+    seq_lens = jnp.asarray(rng.integers(*lens, size=B), jnp.int32)
+    n_pages = -(-lens[1] // ps)
+    key = jax.random.PRNGKey(57)
+    q = jax.random.normal(key, (B, H, hd), jnp.bfloat16)
+    draw = lambda i, pages: jax.random.normal(
+        jax.random.fold_in(key, i), (KV, pages, ps, hd), jnp.bfloat16)
+    ring_tables = hybrid.ring_tables(jnp.arange(B), n_pages, B, R)
+    pool_tables = jnp.asarray(
+        1 + rng.permutation(B * n_pages).reshape(B, n_pages), jnp.int32)
+    row_bytes = 2 * KV * hd * 2
+    sizes = _decode_sizes(B, KV, H // KV, hd, ps, n_pages, jnp.bfloat16,
+                          jnp.bfloat16)
+    # attention alone: a launch that also writes the step's row aliases
+    # its pool, and a probe that throws the written pool away makes XLA
+    # copy 1.3 GB a launch (the first reading of this probe, PR 57)
+    cases = {
+        "swa_decode_attention (33-page rings)": (
+            lambda q, k, v, t: swa_decode_attention_pallas(
+                q, k, v, t, seq_lens, window),
+            draw(2, 1 + B * R), draw(3, 1 + B * R), ring_tables,
+            int(jnp.minimum(seq_lens, window).sum())),
+        "paged_decode_attention (the full layers' pool)": (
+            lambda q, k, v, t: paged_decode_attention_pallas(
+                q, k, v, t, seq_lens),
+            draw(4, 1 + B * n_pages), draw(5, 1 + B * n_pages), pool_tables,
+            int(seq_lens.sum())),
+    }
+    for name, (fn, k, v, tables, rows) in cases.items():
+        took = _median_time(_looped(fn), q, k, v, tables)
+        yield {"kernel": name,
+               "shape": f"B{B} H{H} KV{KV} hd{hd} ps{ps} w{window}",
+               "chunk_pages": sizes[0], "block_slots": sizes[1],
+               "items_a_trip": sizes[2], "buffers": sizes[3],
+               "rows_read": rows, "us": round(took * 1e6, 1),
+               "hbm_roofline_pct": round(
+                   100 * rows * row_bytes / HBM_BYTES_PER_S / took, 1)}
+
+
+def bench_mellum_grouped(E=64, D=2304, F=896, pairs=(640, 32768)):
+    """The grouped expert product at the Mellum2 cut's two shapes, (2,304
+    -> 896) and (896 -> 2,304), over 64 experts' uniform groups at a
+    decode step's 640 pairs and a prompt block's 32,768: us a launch at
+    the rule's column tile (ops/moe.py ``_column_tile``: 896 and 768)
+    and at the other multiples of 128 that divide the columns, beside
+    the larger of the weights' bytes over the HBM rate and the products'
+    operations over the bf16 rate."""
+    from vgate_tpu.ops.moe import _column_tile, _row_tile
+    from vgate_tpu.ops.pallas.grouped_matmul import grouped_matmul_pallas
+
+    key = jax.random.PRNGKey(57)
+    for K, N in ((D, F), (F, D)):
+        w = (0.02 * jax.random.normal(key, (1, E, K, N), jnp.float32)
+             ).astype(jnp.bfloat16)
+        rule = _column_tile(K, N, 2)
+        tiles = [rule] + [t for t in range(N, 0, -128)
+                          if N % t == 0 and t != rule
+                          and K * t * 2 <= (4 << 20)]
+        for M in pairs:
+            tm = _row_tile(M)
+            rows = jax.random.normal(
+                jax.random.fold_in(key, M), (M, K), jnp.bfloat16)
+            sizes = jnp.full((E,), M // E, jnp.int32)
+            least = max(E * K * N * 2 / HBM_BYTES_PER_S,
+                        2 * M * K * N / BF16_FLOPS_PER_S)
+            # the loop's carry is the lhs's shape: the result cut or padded
+            fit = (lambda out: out[:, :K]) if N >= K else (
+                lambda out: jnp.pad(out, ((0, 0), (0, K - N))))
+            for tn in tiles:
+                fn = _looped(lambda r, w, sizes, tn=tn: fit(
+                    grouped_matmul_pallas(
+                        r, w, sizes, jnp.int32(0), tm=tm, tn=tn)))
+                took = _median_time(fn, rows, w, sizes)
+                yield {"kernel": "grouped_matmul", "shape": f"E{E} {K}->{N}",
+                       "pairs": M, "row_tile": tm, "column_tile": tn,
+                       "rule": tn == rule, "us": round(took * 1e6, 1),
+                       "roofline_pct": round(100 * least / took, 1)}
 
 
 # the prompt attention launch at the cells' shapes: (B, bucket rows, of
@@ -1856,8 +1967,18 @@ def main() -> None:
         for line in bench_flash_prefill_cells(cells, forced=forced):
             print(json.dumps(line), flush=True)
         return
-    if sys.argv[1:] == ["swa_prefill"]:
-        for line in bench_swa_prefill():
+    if sys.argv[1:2] == ["swa_prefill"]:
+        # swa_prefill [CONFIG ...]: K-EXAONE's shape alone by default
+        for name in sys.argv[2:] or ["k-exaone-236b-a23b-l5e16"]:
+            for line in bench_swa_prefill(**SWA_PREFILL_CASES[name]):
+                print(json.dumps(line), flush=True)
+        return
+    if sys.argv[1:] == ["mellum_decode"]:
+        for line in bench_mellum_decode():
+            print(json.dumps(line), flush=True)
+        return
+    if sys.argv[1:] == ["mellum_grouped"]:
+        for line in bench_mellum_grouped():
             print(json.dumps(line), flush=True)
         return
     if sys.argv[1:2] == ["eva_decode"]:

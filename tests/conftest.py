@@ -90,11 +90,13 @@ LONGEST_FIRST = (
     "test_rehearse_lfm2_moe", "test_tpu_aot", "test_tpu_aot_head_64",
     "test_tpu_aot_long_prompts", "test_exaone_moe", "test_keye_dsa",
     "test_evabyte", "test_lfm2_moe_engine", "test_exaone_moe_engine",
-    "test_rehearse_exaone_moe", "test_rehearse_hybrid",
+    "test_rehearse_exaone_moe", "test_rehearse_mellum",
+    "test_rehearse_hybrid",
     "test_mla_attention", "test_engine", "test_rehearse_nemotron_h",
-    "test_rehearse_evabyte", "test_keye_dsa_engine", "test_tpu_aot_states",
+    "test_rehearse_evabyte", "test_keye_dsa_engine", "test_mellum_engine",
+    "test_tpu_aot_states",
     "test_rehearse_mistral4", "test_glm_dsa", "test_pallas_decode_pipeline",
-    "test_granite_hybrid_engine",
+    "test_granite_hybrid_engine", "test_mellum",
     "test_granite_hybrid", "test_rehearse_granite_hybrid",
     "test_chip_smoke", "test_kv_quant", "test_evabyte_engine",
     "test_moe_share", "test_ssd", "test_moe_latent",

@@ -20,11 +20,12 @@ import pytest
 from perfbench import manifest
 from perfbench.references import exaone_moe as ref
 from tests import prompt_row_blocks as row_blocks
+from tests import window_stack_forwards as forwards
 from tests.family_contract import one_length
 from vgate_tpu.models import decoder, hybrid, specs
 from vgate_tpu.models.specs import spec_for_model_id
 from vgate_tpu.ops import moe
-from vgate_tpu.runtime.kv_cache import KVGeometry, make_kv_buffers
+from vgate_tpu.runtime.kv_cache import KVGeometry
 
 SPEC = spec_for_model_id("tiny-swa-moe")
 PUBLISHED = spec_for_model_id("LGAI-EXAONE/K-EXAONE-236B-A23B")
@@ -45,24 +46,9 @@ PS, SLOTS, RING = 4, 4, 12  # page, decode slots, a ring's tokens (3 pages)
 # do: a length is ``seq_lens``, not a shape), and the length every
 # sequence has for the reference
 BUCKET, REF_LEN = 32, 64
-# the forwards as the step programs run them: jitted, the spec static
-PREFILL = jax.jit(decoder.prefill_forward, static_argnums=1)
-SUFFIX = jax.jit(decoder.prefill_suffix_forward, static_argnums=1)
-DECODE = jax.jit(decoder.decode_forward, static_argnums=1)
-
-
 @pytest.fixture(scope="module")
 def params():
     return decoder.init_params(SPEC, jax.random.PRNGKey(0), jnp.float32)
-
-
-def fresh_cache():
-    geo = KVGeometry(
-        num_layers=SPEC.attn_layers, num_pages=64, page_size=PS,
-        kv_heads=SPEC.num_kv_heads, head_dim=SPEC.head_dim,
-        max_model_len=128, dtype_bytes=4)
-    return (*make_kv_buffers(geo, jnp.float32),
-            hybrid.make_state(SPEC, SLOTS, jnp.float32, PS))
 
 
 def reference(seq, prompt_len):
@@ -71,46 +57,10 @@ def reference(seq, prompt_len):
                       seq, prompt_len, REF_LEN)
 
 
-def served_logprobs(params, seq, prompt_len, slot=2, chunks=None):
-    """Log-softmax rows for positions ``prompt_len - 1 .. len(seq) - 2``
-    from the program's forwards: the prompt whole (or in ``chunks``),
-    then one decode step a token through ring and pool."""
-    kp, vp, st = fresh_cache()
-    table = np.arange(1, 33, dtype=np.int32)[None]
-    one = lambda v: jnp.asarray([v])
-    if chunks is None:
-        S = BUCKET  # one program whatever the prompt's length
-        assert prompt_len <= S
-        toks = np.zeros((1, S), np.int32)
-        toks[0, :prompt_len] = seq[:prompt_len]
-        logits, kp, vp, st = PREFILL(
-            params, SPEC, jnp.asarray(toks), one(prompt_len), kp, vp,
-            jnp.asarray(table[:, :S // PS]), state=st, slots=one(slot))
-    else:
-        done = 0
-        for want in chunks:
-            n = min(want, prompt_len - done)
-            S = -(-n // 8) * 8
-            toks = np.zeros((1, S), np.int32)
-            toks[0, :n] = seq[done:done + n]
-            own = table[:, done // PS: (done + S) // PS]
-            logits, kp, vp, st = SUFFIX(
-                params, SPEC, jnp.asarray(toks), one(done), one(n), kp, vp,
-                jnp.asarray(own), jnp.asarray(table), state=st,
-                slots=one(slot))
-            done += n
-    rows = [jax.nn.log_softmax(logits[0])]
-    tables = np.zeros((SLOTS, 32), np.int32)
-    tables[slot] = table[0]
-    active = np.arange(SLOTS) == slot
-    for pos in range(prompt_len, len(seq) - 1):
-        tok = np.where(active, seq[pos], 0).astype(np.int32)
-        at = np.where(active, pos, 0).astype(np.int32)
-        logits, kp, vp, st, _ = DECODE(
-            params, SPEC, jnp.asarray(tok), jnp.asarray(at), kp, vp,
-            jnp.asarray(tables), active=jnp.asarray(active), state=st)
-        rows.append(jax.nn.log_softmax(logits[slot]))
-    return np.stack([np.asarray(r) for r in rows])
+def served_logprobs(params, seq, prompt_len, chunks=None):
+    return forwards.served_logprobs(
+        SPEC, params, seq, prompt_len, page=PS, slots=SLOTS, bucket=BUCKET,
+        chunks=chunks)
 
 
 @pytest.mark.parametrize("prompt_len, decoded, what", [

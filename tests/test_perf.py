@@ -941,7 +941,7 @@ def test_drain_share_is_read_from_the_two_counters(name):
     if name.endswith(".tpot"):
         assert cells == ["qwen2.5-1.5b.chat"]
     else:
-        assert len(cells) == 8 and "qwen2.5-1.5b.chat" not in cells
+        assert len(cells) == 9 and "qwen2.5-1.5b.chat" not in cells
 
 
 def test_host_overhead_ratio_counts_schedule_and_state_as_host():

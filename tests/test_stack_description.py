@@ -927,6 +927,60 @@ FROZEN = {
         "indexer": "fullx12",
         "mlp": "sparsex12",
     },
+    "JetBrains/Mellum2-12B-A2.5B-Instruct": {
+        "cut": (0, 4, 7),
+        "lead": "",
+        "period": (
+            "swa/window/input_norm/0 moe/window/post_norm/0 "
+            "swa/window/input_norm/1 moe/window/post_norm/1 "
+            "swa/window/input_norm/2 moe/window/post_norm/2 "
+            "attn/global/input_norm/0 moe/global/post_norm/0"
+        ),
+        "layers": (7, 0, 0, 21, 28),
+        "kv_pools": 2,
+        "windows": " ".join(["1024x3 0x1"] * 7),
+        "num_params": 12149923072,
+        "hybrid": True,
+        "recurrent": "",
+        "indexer": "",
+        "mlp": "sparsex28",
+    },
+    "tiny-mellum": {
+        "cut": (0, 4, 2),
+        "lead": "",
+        "period": (
+            "swa/window/input_norm/0 moe/window/post_norm/0 "
+            "swa/window/input_norm/1 moe/window/post_norm/1 "
+            "swa/window/input_norm/2 moe/window/post_norm/2 "
+            "attn/global/input_norm/0 moe/global/post_norm/0"
+        ),
+        "layers": (2, 0, 0, 6, 8),
+        "kv_pools": 2,
+        "windows": "8x3 0x1 8x3 0x1",
+        "num_params": 562496,
+        "hybrid": True,
+        "recurrent": "",
+        "indexer": "",
+        "mlp": "sparsex8",
+    },
+    "config:mellum2-12b-a2.5b-l8.json": {
+        "cut": (0, 4, 2),
+        "lead": "",
+        "period": (
+            "swa/window/input_norm/0 moe/window/post_norm/0 "
+            "swa/window/input_norm/1 moe/window/post_norm/1 "
+            "swa/window/input_norm/2 moe/window/post_norm/2 "
+            "attn/global/input_norm/0 moe/global/post_norm/0"
+        ),
+        "layers": (2, 0, 0, 6, 8),
+        "kv_pools": 2,
+        "windows": "1024x3 0x1 1024x3 0x1",
+        "num_params": 3794968832,
+        "hybrid": True,
+        "recurrent": "",
+        "indexer": "",
+        "mlp": "sparsex8",
+    },
 }
 
 FIELDS = [
@@ -962,7 +1016,9 @@ FIELDS = [
     ("yarn_beta_fast", 32.0), ("yarn_beta_slow", 1.0),
     ("yarn_original_max_pos", 0), ("yarn_mscale", 1.0),
     ("yarn_mscale_all_dim", 0.0), ("llama_4_scaling_beta", 0.0),
-    ("window_pattern", ""), ("global_rope", True), ("first_k_dense", 0),
+    ("window_pattern", ""), ("global_rope", True),
+    ("yarn_full_only", False), ("yarn_attention_factor", 0.0),
+    ("first_k_dense", 0),
     ("indexer_pattern", ""), ("first_layer", 0), ("index_topk", 0),
     ("index_n_heads", 0), ("index_head_dim", 0),
     ("indexer_rope_interleave", False), ("mrope_section", ()),

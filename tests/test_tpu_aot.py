@@ -9,8 +9,8 @@ import pytest
 
 from tests.tpu_aot import (  # noqa: F401 (v5e: a fixture)
     abstract_on, assert_no_copy_of, assert_no_logits_array, compile_expecting,
-    counted_loops, cut_and_shapes, EXAONE_CUT, MISTRAL_CUT, MosaicRefusal,
-    nbytes, operations, PAGE, prompt_program, v5e,
+    counted_loops, cut_and_shapes, EXAONE_CUT, MELLUM_CUT, MISTRAL_CUT,
+    MosaicRefusal, nbytes, operations, PAGE, prompt_program, v5e,
 )
 from vgate_tpu.models.specs import spec_for_model_id
 
@@ -139,6 +139,9 @@ SERVED_DECODE_SHAPES = {
     "mistral-small-4-119b-l4e32": ((256, 1, 32, 384, 256, 1), (8, 21, 2, 4)),
     # the full layers (the rings of five pages a slot get the same chunk)
     "k-exaone-236b-a23b-l5e16": ((192, 8, 8, 128, 256, 2), (4, 16, 1, 3)),
+    # the Mellum2 cut's two full layers over 16,384-token contexts (its
+    # rings of 33 pages a slot get the same chunk: five items a slot)
+    "mellum2-12b-a2.5b-l8": ((80, 4, 8, 128, 512, 2), (8, 32, 1, 3)),
     # MHA, one query row a KV head: 64 window pages behind 32 of summaries.
     # The 4 MiB budget alone gave ONE page a chunk here (PR 45)
     "evabyte-6.5b-l8": ((20, 32, 1, 128, 96, 2), (4, 4, 1, 3)),
@@ -626,6 +629,91 @@ def test_window_stack_decode_chunk_and_prompt_kernel_compile_on_v5e(v5e):
         A((1, S, H, hd), jnp.bfloat16), A((1, S, KV, hd), jnp.bfloat16),
         A((1, S, KV, hd), jnp.bfloat16), A((1,), jnp.int32)).compile()
     assert "swa_prefill_attention_pallas" in band.as_text()
+
+
+def _mellum_cell(A):
+    """(spec, params, rings, their bytes, pool, its bytes) of the Mellum2
+    cut as its cell serves it: 80 slots of 16,384 tokens."""
+    from vgate_tpu.models.hybrid import make_state
+
+    spec, params = cut_and_shapes(A, *MELLUM_CUT)
+    B = 80
+    state = jax.tree.map(
+        lambda x: A(x.shape, x.dtype),
+        jax.eval_shape(lambda: make_state(spec, B, jnp.bfloat16, PAGE)))
+    assert nbytes(state) == 2 * 6 * 4 * (1 + B * 33) * PAGE * 128 * 2
+    pages = 40961  # the pool at its cap: 80 x 512 + 1
+    pool = A((spec.attn_layers, spec.num_kv_heads, pages, PAGE,
+              spec.head_dim), jnp.bfloat16)
+    assert 2 * nbytes(pool) == pages * 131072
+    return spec, params, state, pool
+
+
+def test_mellum_decode_chunk_and_its_window_kernel_compile_on_v5e(v5e):
+    """The Mellum2 cut as the cell serves it (8 layers, all 64 experts,
+    80 slots of 16,384 tokens): the decode chunk compiles for the v5e
+    with the pool of the TWO full layers and the rings of the six window
+    layers (33 pages a slot) aliased input to output, a ring's launch
+    under its own name beside a full layer's; and the window layers'
+    banded prompt kernel compiles at the cell's one shape (16,384 rows,
+    32 heads on 4) in the blocks the window's rule gives."""
+    from vgate_tpu.ops.pallas.flash_prefill import (
+        swa_blocks, swa_prefill_attention_pallas,
+    )
+    from vgate_tpu.runtime.step_programs import _decode_chunk
+
+    A = abstract_on(v5e)
+    spec, params, state, pool = _mellum_cell(A)
+    B, ctx = 80, 16384
+    compiled = _decode_chunk.lower(
+        params, spec, A((B,), jnp.int32), A((B,), jnp.int32), pool, pool,
+        A((B, ctx // PAGE), jnp.int32), A((B,), jnp.bool_),
+        A((B,), jnp.float32), A((B,), jnp.float32), A((B,), jnp.int32),
+        A((2,), jnp.uint32), A((), jnp.uint32),
+        num_steps=8, use_pallas=True, max_position=ctx - 1,
+        seeds=A((B,), jnp.int32), steps=A((B,), jnp.int32),
+        all_greedy=True, guard=True, state=state,
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes((state, pool, pool)), (
+        "the pool or the rings are copied")
+    # a step's activations and the weights' re-laid copies: 0.197 GB
+    # when this was written (PR 57)
+    assert mem.temp_size_in_bytes < 0.4e9, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    assert "swa_decode_attention_pallas" in text
+    assert "paged_decode_attention_pallas" in text
+    assert "moe_grouped_matmul_pallas" in text
+    assert "greedy_head" in text
+    S, H, KV, hd = 16384, 32, 4, 128
+    assert swa_blocks(spec.sliding_window) == (1024, 1024)
+    band = jax.jit(lambda q, k, v, lens: swa_prefill_attention_pallas(
+        q, k, v, lens, spec.sliding_window, skip_padding=True)).lower(
+        A((1, S, H, hd), jnp.bfloat16), A((1, S, KV, hd), jnp.bfloat16),
+        A((1, S, KV, hd), jnp.bfloat16), A((1,), jnp.int32)).compile()
+    assert "swa_prefill_attention_pallas" in band.as_text()
+
+
+@pytest.mark.slow  # a 16,384-row program of eight expert layers: the
+# builder's command (CHANGES.md, PR 57)
+def test_mellum_prompt_program_fits_beside_pool_and_rings_on_v5e(v5e):
+    """The 16,384-row prompt program of the Mellum2 cut: pool and rings
+    updated in place, and its temporaries inside what ``HBM_UTILIZATION``
+    0.86 leaves beside 7.59 GB of weights, 5.37 GB of pool and 1.04 GB
+    of rings: 0.868 GB when this was written (PR 57: q and the
+    attention's result 134 MB each, the experts' 4,096-row blocks of 8
+    choices x 2,304 in float32 302 MB, the weights' re-laid copies), so
+    the run's peak is near 14.9 GB of the chip's 16.9."""
+    A = abstract_on(v5e)
+    spec, params, state, pool = _mellum_cell(A)
+    compiled = prompt_program(A, spec, params, pool, pool, state,
+                              bucket=16384)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes((state, pool, pool))
+    assert mem.temp_size_in_bytes < 1.2e9, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    assert "swa_prefill_attention_pallas" in text
+    assert "flash_prefill_attention_pallas" in text
 
 
 # temporary bytes of the parent's (PR 45, commit 32a8c2e) dense prompt

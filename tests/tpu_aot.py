@@ -91,6 +91,8 @@ MISTRAL_CUT = ("mistralai/Mistral-Small-4-119B-2603", dict(
 EXAONE_CUT = ("LGAI-EXAONE/K-EXAONE-236B-A23B", dict(
     name="exaone-cut", num_layers=5, num_experts=16, vocab_size=19200,
     eos_token_id=19199, bos_token_id=19198))
+MELLUM_CUT = ("JetBrains/Mellum2-12B-A2.5B-Instruct", dict(
+    name="mellum-cut", num_layers=8))
 
 
 def cut_and_shapes(A, preset, changes):

@@ -576,9 +576,7 @@ def prefill_attn_tiles(spec: ModelSpec, S: int, lens) -> Tuple[int, int]:
     import numpy as np
 
     from vgate_tpu.models.hybrid import prompt_rows
-    from vgate_tpu.ops.pallas.flash_prefill import (
-        SWA_BLOCK_K, SWA_BLOCK_Q, tile_counts,
-    )
+    from vgate_tpu.ops.pallas.flash_prefill import swa_blocks, tile_counts
 
     lens = [int(n) for n in lens]
     block = 1024 if S >= 4096 else 256
@@ -592,8 +590,7 @@ def prefill_attn_tiles(spec: ModelSpec, S: int, lens) -> Tuple[int, int]:
     form = lambda *args, **kw: (args, tuple(sorted(kw.items())))
     for kinds, window in zip(spec.stack, spec.layer_windows):
         if "swa" in kinds:
-            bk = min(SWA_BLOCK_K, S)
-            bq = max(bk, min(SWA_BLOCK_Q, S))
+            bq, bk = swa_blocks(window, S)
             launch = form(
                 tuple(lens), S, S, bq, bk, window=window,
                 band=bq // bk + -(-(window - 1) // bk), skip_padding=True)

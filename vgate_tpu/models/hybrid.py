@@ -392,8 +392,8 @@ def _init_window_layers(spec: ModelSpec, key, dtype, normal
     0.02), every norm weight 1.  With per-head norms on q and k a
     query's scores have a standard deviation near 1, so attention is
     not flat and a wrong window, a stale ring row or a rotary on the
-    wrong kind of layer shows.  The router's selection bias N(0, 0.02)
-    and NOT zero, as for a pattern's sigmoid router."""
+    wrong kind of layer shows.  A sigmoid router's selection bias N(0,
+    0.02) and NOT zero, as for a pattern's; a softmax router has none."""
     wk = jax.random.split(jax.random.fold_in(key, 38), 32)
     D, H, KV, hd = (spec.hidden_size, spec.num_heads, spec.num_kv_heads,
                     spec.head_dim)
@@ -427,6 +427,7 @@ def _init_window_layers(spec: ModelSpec, key, dtype, normal
                 out[name] = {"w": put(draw(layers, j, shape))}
         if kind == "moe":
             out["router"] = put(draw(layers, 7, (D, R)))
+        if kind == "moe" and spec.router_scoring == "sigmoid":
             out["router_bias"] = put(jax.jit(lambda k: jax.lax.map(
                 lambda i: jax.random.normal(
                     jax.random.fold_in(k, i), (R,), jnp.float32) * 0.02,
@@ -904,21 +905,23 @@ def _by_row_blocks(fn, rows, n_rows, axis: int = 1):
     return jax.lax.fori_loop(0, cdiv(n_rows, R), block, out)
 
 
-def _rope(x, positions, spec: ModelSpec, rotate: bool = True):
-    if not (spec.use_rope and rotate):
+def _rope(x, positions, spec: ModelSpec, kind: str = "attn"):
+    rotary = spec.rotary(kind)
+    if rotary is None:
         return x
-    return apply_rope(x, positions, spec.rope_theta, spec.rope_scaling,
+    return apply_rope(x, positions, rotary.theta, rotary.scaling,
                       rotary_dim=spec.rotary_dim,
-                      sections=spec.mrope_section)
+                      sections=spec.mrope_section,
+                      amplitude=rotary.amplitude)
 
 
 @jax.named_scope("qkv")
-def _gated_qkv(normed, lp, spec: ModelSpec, positions, rotate: bool = True):
+def _gated_qkv(normed, lp, spec: ModelSpec, positions, kind: str = "attn"):
     """Attention front half on the normed rows: q (with its gate beside
     it, per head ``[query | gate]``, where the spec has one), k, v,
-    per-head norms on q and k, rope, each where the spec says
-    (``rotate`` False: a layer of a kind that takes none).  normed:
-    [..., S, D] with positions [..., S]."""
+    per-head norms on q and k, and the rotary of the layer's ``kind``
+    (``ModelSpec.rotary``: its frequencies and amplitude, or none).
+    normed: [..., S, D] with positions [..., S]."""
     eps, uo = spec.rms_eps, spec.unit_offset_norm
     H, KV, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
     q = jnp.einsum("...d,dh->...h", normed, lp["q"]["w"])
@@ -935,8 +938,8 @@ def _gated_qkv(normed, lp, spec: ModelSpec, positions, rotate: bool = True):
     if spec.qk_norm:
         q = rms_norm(q, lp["q_norm"], eps, uo)
         k = rms_norm(k, lp["k_norm"], eps, uo)
-    return (_rope(q, positions, spec, rotate),
-            _rope(k, positions, spec, rotate), v, gate)
+    return (_rope(q, positions, spec, kind),
+            _rope(k, positions, spec, kind), v, gate)
 
 
 def _heads_flat(t):
@@ -2086,9 +2089,9 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
                                       index, write_tables, ctx_tables,
                                       attend, n_rows=n_rows, norm=norm)
             return out, kp, vp, st, None
-        qkv = lambda rotate: by_rows(
+        qkv = lambda kind: by_rows(
             lambda rows, positions: _gated_qkv(
-                norm(rows), lp, spec, positions, rotate), normed, positions)
+                norm(rows), lp, spec, positions, kind), normed, positions)
         o_proj = lambda attn, gate: by_rows(
             lambda attn, gate: _gated_out(attn, gate, lp, normed.dtype),
             _heads_flat(attn), _heads_flat(gate))
@@ -2102,7 +2105,7 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
             return out, kp, vp, st, None
         if kind == "swa":
             with jax.named_scope("swa_attn"):
-                q, k, v, _ = qkv(True)
+                q, k, v, _ = qkv("swa")
                 rk, rv = st["ring_k"], st["ring_v"]
                 with jax.named_scope("attention"):
                     attn = (swa_attend(q, k, v) if prefix_lens is None
@@ -2117,7 +2120,7 @@ def prompt_forward(params, spec: ModelSpec, x, lens, positions, k_pages,
                 out = o_proj(attn, None)
             return out, kp, vp, st, None
         with jax.named_scope(_attn_scope(spec)):
-            q, k, v, gate = qkv(spec.global_rope)
+            q, k, v, gate = qkv("attn")
             pt = write_tables[:, :n_pages]
             kp = kv_write_pages(kp, pt, to_pages(k), layer=index)
             vp = kv_write_pages(vp, pt, to_pages(v), layer=index)
@@ -2156,9 +2159,9 @@ def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
     if active is None:
         active = jnp.ones(x.shape[:1], bool)
 
-    def attention(normed, lp, cache_step, k_cache, v_cache, index, rotate):
+    def attention(normed, lp, cache_step, k_cache, v_cache, index, kind):
         q, k, v, gate = _gated_qkv(
-            normed[:, None], lp, spec, positions[:, None], rotate)
+            normed[:, None], lp, spec, positions[:, None], kind)
         attn, k_cache, v_cache = cache_step(
             q[:, 0], k[:, 0], v[:, 0], k_cache, v_cache, index)
         out = _gated_out(_heads_flat(attn), _heads_flat(
@@ -2214,11 +2217,11 @@ def decode_forward(params, spec: ModelSpec, x, positions, k_pages, v_pages,
             with jax.named_scope("swa_attn"):
                 out, rk, rv = attention(
                     normed, lp, ring_write_attend, st["ring_k"],
-                    st["ring_v"], index, True)
+                    st["ring_v"], index, "swa")
             return out, kp, vp, {**st, "ring_k": rk, "ring_v": rv}, None
         with jax.named_scope(_attn_scope(spec)):
             out, kp, vp = attention(normed, lp, write_attend, kp, vp,
-                                    index, spec.global_rope)
+                                    index, "attn")
         return out, kp, vp, st, None
 
     if spec.is_dsa and not spec.kv_rows:

@@ -12,7 +12,15 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, replace
 from functools import cached_property
-from typing import Callable, Dict, NamedTuple
+from typing import Callable, Dict, NamedTuple, Optional
+
+
+class Rotary(NamedTuple):
+    """A layer kind's rotary: ``theta`` and ``scaling`` as ops/rope.py
+    takes them, ``amplitude`` what cos and sin are multiplied by."""
+    theta: float
+    scaling: Optional[tuple]
+    amplitude: float
 
 
 @dataclass(frozen=True)
@@ -152,6 +160,14 @@ class ModelSpec:
     # ``global_rope`` False: the full layers take no rotary
     window_pattern: str = ""
     global_rope: bool = True
+    # the rotary by layer KIND (Mellum2's ``rope_parameters``, one group
+    # a layer type): the ``yarn_*`` numbers above are the FULL layers'
+    # alone and a window layer rotates with the plain frequencies of
+    # ``rope_theta``.  ``yarn_attention_factor`` (the published
+    # ``attention_factor``; 0 = none) multiplies cos and sin of a layer
+    # under YaRN, on q and on k, so its scores carry the square
+    yarn_full_only: bool = False
+    yarn_attention_factor: float = 0.0
     # leading layers whose feed-forward is a dense SwiGLU of
     # ``intermediate_size`` (DeepSeek's key ``first_k_dense_replace``)
     first_k_dense: int = 0
@@ -361,6 +377,21 @@ class ModelSpec:
         """The YaRN group as the published config.json spells it (what
         perfbench/serve.py holds the program to)."""
         num = lambda v: int(v) if float(v).is_integer() else v
+        if self.yarn_full_only:  # one group a layer type
+            return {
+                "full_attention": {
+                    "rope_type": "yarn", "rope_theta": num(self.rope_theta),
+                    "factor": num(self.yarn_factor),
+                    "original_max_position_embeddings":
+                        self.yarn_original_max_pos,
+                    "beta_fast": num(self.yarn_beta_fast),
+                    "beta_slow": num(self.yarn_beta_slow),
+                    "attention_factor": self.yarn_attention_factor,
+                },
+                "sliding_attention": {
+                    "rope_type": "default",
+                    "rope_theta": num(self.rope_theta)},
+            }
         if self.yarn_factor <= 0:
             return {"rope_theta": num(self.rope_theta),
                     "rope_type": "default"} if self._spelling in (
@@ -377,6 +408,34 @@ class ModelSpec:
             "rope_type": "yarn",
             "type": "yarn",
         }
+
+    def rotary(self, kind: str) -> Optional["Rotary"]:
+        """The rotary of a layer whose mixer is ``kind`` (``swa``: a
+        window layer; anything else: the spec's other attention), None
+        where it takes no positions: the ONE place that says which
+        layers rotate, with which frequencies and at what amplitude."""
+        if not self.use_rope or (kind != "swa" and not self.global_rope):
+            return None
+        if kind == "swa" and self.yarn_full_only:
+            return Rotary(self.rope_theta, None, 1.0)
+        return Rotary(self.rope_theta, self.rope_scaling,
+                      self.yarn_attention_factor or 1.0)
+
+    @property
+    def rotary_by_kind(self) -> dict:
+        """Each layer type's rotary as served, for a stack whose kinds
+        differ in it (``/stats -> engine.rotary``); {} for every other."""
+        if self._spelling is not _WINDOW:
+            return {}
+        def say(r):
+            if r is None:
+                return {"type": "none"}
+            return {"type": r.scaling[0] if r.scaling else "default",
+                    "theta": r.theta,
+                    "factor": r.scaling[1] if r.scaling else 1.0,
+                    "amplitude": r.amplitude}
+        return {"sliding_attention": say(self.rotary("swa")),
+                "full_attention": say(self.rotary("attn"))}
 
     @property
     def _spelling(self) -> "_Spelling":
@@ -1295,6 +1354,50 @@ K_EXAONE_236B = _register(
     )
 )
 
+# Mellum2-12B-A2.5B-Instruct (JetBrains, model_type mellum) at the
+# published sizes: 28 layers, three window layers (1,024 tokens, a ring
+# a slot) to one full layer, GQA 32 query heads on 4 KV heads of 128,
+# EVERY layer's feed-forward 64 softmax-routed experts of 896 top 8 with
+# no shared expert (``intermediate_size`` names no matrix).  The rotary
+# is the layer kind's own (``rope_parameters`` by layer type): plain
+# frequencies on the window layers, YaRN (factor 16 over 8,192) on the
+# full layers with cos and sin times ``attention_factor``.  ASSUMED (the
+# cut's configuration file says from what): pre-norm sub-blocks, per-head
+# RMSNorm on q and k (the Qwen3-MoE lineage's), stop ids 2 / 1; the
+# multi-token-prediction head is not part of this spec
+MELLUM2_12B = _register(
+    ModelSpec(
+        name="JetBrains/Mellum2-12B-A2.5B-Instruct",
+        vocab_size=98304,
+        hidden_size=2304,
+        num_layers=28,
+        num_heads=32,
+        num_kv_heads=4,
+        head_dim=128,
+        intermediate_size=7168,  # the published key; read by nothing
+        rope_theta=500_000.0,
+        rms_eps=1e-6,
+        qkv_bias=False,
+        tie_embeddings=False,
+        eos_token_id=2,
+        bos_token_id=1,
+        max_position_embeddings=131072,
+        num_experts=64,
+        experts_per_token=8,
+        moe_intermediate_size=896,
+        router_width=64,
+        qk_norm=True,
+        sliding_window=1024,
+        window_pattern="LLLG",
+        yarn_factor=16.0,
+        yarn_beta_fast=32.0,
+        yarn_beta_slow=1.0,
+        yarn_original_max_pos=8192,
+        yarn_full_only=True,
+        yarn_attention_factor=1.2772588722239782,  # 0.1 ln 16 + 1
+    )
+)
+
 BGE_BASE = _register(
     ModelSpec(
         name="BAAI/bge-base-en-v1.5",
@@ -1524,6 +1627,43 @@ TINY_SWA_MOE = _register(
         window_pattern="LLLG",
         global_rope=False,
         first_k_dense=1,
+    )
+)
+
+# every mechanism of Mellum2 at toy widths: two periods of three window
+# layers (8 tokens, a ring a slot) to one full layer, no leading layer,
+# 8 softmax-routed experts top 2 and no shared expert, YaRN (factor 4
+# over an original 32, amplitude 0.1 ln 4 + 1) on the full layers alone
+TINY_MELLUM = _register(
+    ModelSpec(
+        name="tiny-mellum",
+        vocab_size=512,
+        hidden_size=64,
+        num_layers=8,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        intermediate_size=128,
+        rope_theta=10000.0,
+        rms_eps=1e-6,
+        qkv_bias=False,
+        tie_embeddings=False,
+        eos_token_id=0,
+        bos_token_id=1,
+        max_position_embeddings=4096,
+        num_experts=8,
+        experts_per_token=2,
+        moe_intermediate_size=32,
+        router_width=8,
+        qk_norm=True,
+        sliding_window=8,
+        window_pattern="LLLG",
+        yarn_factor=4.0,
+        yarn_beta_fast=32.0,
+        yarn_beta_slow=1.0,
+        yarn_original_max_pos=32,
+        yarn_full_only=True,
+        yarn_attention_factor=1.1386294361119891,  # 0.1 ln 4 + 1
     )
 )
 

@@ -476,27 +476,48 @@ def flash_prefill_attention_pallas(
     return jnp.transpose(out, (0, 2, 1, 3))
 
 
-# a window layer's blocks: 128-row key blocks (the window's own size at
-# the published 128: a band's first block is the only one the window
-# cuts) under query blocks of up to 256 rows, so a query block visits
-# 128 + its own 256 keys.  On the v5e at 8,192 rows x 64 heads
-# (benchmarks/bench_kernels.py swa_prefill): 6.0 ms, against 7.3 at
+# a window layer's blocks, by its window: key blocks of the window's own
+# size (a band's first block is then the only one the window cuts),
+# under query blocks of that size and no fewer than 256 rows.  On the
+# v5e (benchmarks/bench_kernels.py swa_prefill), at K-EXAONE's window of
+# 128, 8,192 rows x 64 heads on 8: (256, 128) 6.0 ms, against 7.3 at
 # (128, 128), 11.5 at (512, 128), 7.0 at (256, 256) and 6.6 for the
-# window as a mask under 1,024-row blocks
-SWA_BLOCK_Q, SWA_BLOCK_K = 256, 128
+# window as a mask under 1,024-row blocks (PR 38); at Mellum2's window of
+# 1,024, 16,384 rows x 32 heads on 4: (1,024, 1,024) 6.4 ms, a band of
+# two blocks, against 8.7 at (512, 512), 10.2 at (1,024, 512), 13.8 at
+# (256, 256), 15.0 at (256, 128) (ten grid steps a query block) and 7.2
+# for the window as a mask (my chip run, PR 57)
+SWA_BLOCK_Q_MIN, SWA_BLOCK_K_MIN, SWA_BLOCK_K_MAX = 256, 128, 1024
+
+
+def swa_blocks(window: int, rows: int = 0, block_q: int = 0,
+               block_k: int = 0) -> tuple:
+    """(query rows, key rows) of a window layer's blocks: the largest
+    power of two the window holds, between ``SWA_BLOCK_K_MIN`` and
+    ``SWA_BLOCK_K_MAX`` (what the full layers' launches take), under
+    query blocks of that and at least ``SWA_BLOCK_Q_MIN``: (256, 128)
+    at a window of 128, a band of three blocks; (1,024, 1,024) at 1,024,
+    a band of two.  ``block_q`` / ``block_k`` force one (the probe, a
+    test); ``rows`` holds both to a launch of so many rows."""
+    rule_k = min(max(1 << (max(window, 1).bit_length() - 1),
+                     SWA_BLOCK_K_MIN), SWA_BLOCK_K_MAX)
+    block_k = block_k or rule_k
+    block_q = block_q or max(rule_k, SWA_BLOCK_Q_MIN)
+    if rows:
+        block_k = min(block_k, rows)
+        block_q = max(block_k, min(block_q, rows))
+    return block_q, block_k
 
 
 def swa_prefill_attention_pallas(q, k, v, seq_lens, window: int,
-                                 block_q: int = SWA_BLOCK_Q,
-                                 block_k: int = SWA_BLOCK_K, **kw):
+                                 block_q: int = 0, block_k: int = 0, **kw):
     """A window layer's prompt attention ([B, S, H, hd], every row
     attending to its last ``window`` keys, a static count): the flash
     kernel over a band of ``block_q // block_k + ceil((window - 1) /
-    block_k)`` key blocks a query block, launched under a name of its
-    own so that a device trace tells it from a full layer's."""
-    S = q.shape[1]
-    block_k = min(block_k, S)
-    block_q = max(block_k, min(block_q, S))
+    block_k)`` key blocks a query block (``swa_blocks``), launched under
+    a name of its own so that a device trace tells it from a full
+    layer's."""
+    block_q, block_k = swa_blocks(window, q.shape[1], block_q, block_k)
     band = block_q // block_k + -(-(window - 1) // block_k)
     return flash_prefill_attention_pallas(
         q, k, v, seq_lens, block_q=block_q, block_k=block_k,

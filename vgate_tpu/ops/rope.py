@@ -76,6 +76,7 @@ def apply_rope(
     scaling=None,
     rotary_dim: int = 0,
     sections=(),
+    amplitude: float = 1.0,
 ) -> jnp.ndarray:
     """Rotate ``x`` of shape [..., seq, heads, head_dim] by per-token angles.
 
@@ -88,12 +89,14 @@ def apply_rope(
     components, ``[len(sections), ..., seq]``, frequency ``i`` turns by
     the component of its section; positions of one component (a text
     token's are equal) take the plain path, whatever ``sections``.
+    ``amplitude`` (YaRN's published ``attention_factor``) multiplies cos
+    and sin: the rotated part comes out that much longer.
     """
     if 0 < rotary_dim < x.shape[-1]:
         return jnp.concatenate(
             [
                 apply_rope(x[..., :rotary_dim], positions, theta, scaling,
-                           sections=sections),
+                           sections=sections, amplitude=amplitude),
                 x[..., rotary_dim:],
             ],
             axis=-1,
@@ -112,6 +115,8 @@ def apply_rope(
         angles = positions.astype(jnp.float32)[..., None] * inv_freq
     cos = jnp.cos(angles)[..., None, :]  # [..., S, 1, hd/2]
     sin = jnp.sin(angles)[..., None, :]
+    if amplitude != 1.0:
+        cos, sin = cos * amplitude, sin * amplitude
     x32 = x.astype(jnp.float32)
     x1, x2 = jnp.split(x32, 2, axis=-1)
     # rotate_half: (x1, x2) -> (x1*cos - x2*sin, x2*cos + x1*sin)

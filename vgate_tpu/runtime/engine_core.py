@@ -4558,6 +4558,11 @@ class EngineCore:
             # them (a preset that lost one shows here); none: not there
             **({"multipliers": self.spec.multipliers}
                if self.spec.multipliers else {}),
+            # a window stack's rotary by layer type as served (type,
+            # theta, factor, amplitude; "none": a kind that takes no
+            # positions), so a run's record says which layers took which
+            **({"rotary": self.spec.rotary_by_kind}
+               if self.spec.rotary_by_kind else {}),
             "mesh": {
                 axis: int(size) for axis, size in self.mesh.shape.items()
             },

@@ -380,8 +380,9 @@ def _dsa_params_from_getter(
                 out[f"shared_{n}"] = {
                     "w": lin(i, f"mlp.shared_experts.{n}_proj")}
         out = jax.tree.map(cast, out)
-        out["router_bias"] = np.asarray(
-            get(i, "mlp.gate.e_score_correction_bias"), np.float32)
+        if spec.router_scoring == "sigmoid":
+            out["router_bias"] = np.asarray(
+                get(i, "mlp.gate.e_score_correction_bias"), np.float32)
         return out
 
     lead, P = spec.lead_layers, spec.num_periods
@@ -474,7 +475,13 @@ def _window_params_from_getter(
 ) -> Params:
     """``exaone_moe`` names (ASSUMED: the attention and norm names of
     ``transformers``' EXAONE-4, the expert layer's of DeepSeek-V3, whose
-    config keys the published config's are; no checkpoint was read) ->
+    config keys the published config's are; no checkpoint was read),
+    and ``mellum`` names (ASSUMED: the Qwen3-MoE lineage's, whose config
+    keys the published config's are, which are the SAME names:
+    ``self_attn.{q,k,v,o}_proj``, ``self_attn.{q,k}_norm``, ``mlp.gate``,
+    ``mlp.experts.<e>.{gate,up,down}_proj``, and no selection bias under
+    its softmax router; no checkpoint was read; the multi-token-
+    prediction head's tensors are not read) ->
     the pytree of models/hybrid.py for a ``window_pattern`` spec:
     ``layers = {"lead": (a tree a leading layer, ...), "window" |
     "global": [P, n, ...]}``, layer ``i`` of the checkpoint in the place
@@ -509,8 +516,9 @@ def _window_params_from_getter(
             if spec.shared_expert_intermediate_size:
                 out[f"shared_{n}"] = lin(i, f"mlp.shared_experts.{n}_proj")
         out = jax.tree.map(cast, out)
-        out["router_bias"] = np.asarray(
-            get(i, "mlp.gate.e_score_correction_bias"), np.float32)
+        if spec.router_scoring == "sigmoid":
+            out["router_bias"] = np.asarray(
+                get(i, "mlp.gate.e_score_correction_bias"), np.float32)
         return out
 
     lead, P = spec.lead_layers, spec.num_periods

@@ -1328,7 +1328,10 @@ class WorkerServer:
             record["exit_reason"] = exit_reason
         if checkpoints is not None:
             record["checkpoints"] = checkpoints
-        tmp = f"{path}.tmp.{os.getpid()}"
+        # a name of the THREAD's own: the beat loop and the drain write
+        # side by side, and one temporary file between them tore the
+        # record (tests/test_adoption.py under load, PR 57)
+        tmp = f"{path}.tmp.{os.getpid()}.{threading.get_ident()}"
         try:
             with open(tmp, "w") as fh:
                 json.dump(record, fh, separators=(",", ":"))
